@@ -669,13 +669,17 @@ async def run(args: argparse.Namespace) -> None:
             runtime, roles, standby=args.standby,
             status_extra={"backend": "tpu", "model": model_name},
             metrics=runtime.metrics)
-        if not args.standby:
-            await roles.start()
         # Fleet inventory digests (KV & capacity plane): published from
         # the engine loop alongside KV events + ForwardPassMetrics, with
         # a periodic republish so an idle worker still shows up.
         engine.inventory_publisher = inventory_pub
+        # Warm up BEFORE registering: a program that cannot compile fails
+        # the worker here (wait_ready raises), not a routed request.
         engine.start()
+        await asyncio.get_running_loop().run_in_executor(
+            None, engine.wait_ready)
+        if not args.standby:
+            await roles.start()
         inventory_pub.start_periodic(engine.inventory_digest)
         # Observability plane (docs/OBSERVABILITY.md): flight-recorder
         # bundle context for THIS worker, and the per-worker system

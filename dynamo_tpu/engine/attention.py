@@ -41,11 +41,6 @@ from dynamo_tpu.engine.kv_quant import QuantKV
 PAGES_PER_CHUNK = 8  # tokens per chunk = 8 * page_size (128 for 16-tok pages)
 NEG_INF = -1e30
 
-# jax renamed pltpu.TPUCompilerParams -> CompilerParams across releases;
-# accept either so the kernel imports on every toolchain the repo targets.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 
 class _ChunkCopy:
     """Async copy of PAGES_PER_CHUNK K/V pages for one (layer, head, chunk)
@@ -71,16 +66,17 @@ class _ChunkCopy:
 
 def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
                    q_ref, k_hbm, v_hbm,  # q2 VMEM block; k/v packed (ANY)
-                   *rest,  # [ks_hbm, vs_hbm if quantized], outputs, scratch
+                   *rest,  # [ks_ref, vs_ref if quantized], outputs, scratch
                    page_size: int, max_pages: int, tpr: int, qpk: int,
                    quantized: bool = False):
     if quantized:
-        # int8 pages + per-token f32 scale rows ([L, Nkv, P, page] in
-        # HBM): the scale chunks ride their own DMAs beside the pages and
-        # dequantization happens in-register below — no bf16 copy of the
-        # history is ever materialized.
-        (ks_hbm, vs_hbm, acc_ref, m_ref, l_ref,
-         k_buf, v_buf, ks_buf, vs_buf, sems) = rest
+        # int8 pages; the per-token f32 scales arrive as a VMEM block
+        # already laid out per chunk in score space ([chunks, tpr, rows],
+        # see _chunk_scales) and multiply the scores / probabilities
+        # below — no bf16 copy of the history is ever materialized and
+        # the kernel never reshapes a scale vector (Mosaic refuses the
+        # [pages, page] -> [rows, tpr] shape cast).
+        ks_ref, vs_ref, acc_ref, m_ref, l_ref, k_buf, v_buf, sems = rest
     else:
         acc_ref, m_ref, l_ref, k_buf, v_buf, sems = rest
     b = pl.program_id(0)
@@ -97,19 +93,11 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
     scale = 1.0 / (d ** 0.5)
 
     def make_copies(c, slot):
-        copies = [
+        return [
             _ChunkCopy(k_hbm, k_buf.at[slot], sems.at[0, slot], layer,
                        page_table_ref, b, h, c, max_pages),
             _ChunkCopy(v_hbm, v_buf.at[slot], sems.at[1, slot], layer,
                        page_table_ref, b, h, c, max_pages)]
-        if quantized:
-            copies.append(_ChunkCopy(ks_hbm, ks_buf.at[slot],
-                                     sems.at[2, slot], layer,
-                                     page_table_ref, b, h, c, max_pages))
-            copies.append(_ChunkCopy(vs_hbm, vs_buf.at[slot],
-                                     sems.at[3, slot], layer,
-                                     page_table_ref, b, h, c, max_pages))
-        return copies
 
     for cp in make_copies(0, 0):
         cp.start()
@@ -119,19 +107,15 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
     group = jax.lax.broadcasted_iota(jnp.int32, (n, rows), 0) // qpk
     row = jax.lax.broadcasted_iota(jnp.int32, (n, rows), 1)
 
-    def dequant_expand(sbuf_slot):
-        # Scale chunk [PAGES_PER_CHUNK, page_size] -> lane-expanded
-        # [rows, 128]: packed row r lane-group t holds token r*tpr+t, so
-        # its scale is flat[r*tpr+t] = reshape(rows, tpr)[r, t]. The
-        # [rows, 1] -> [rows, 128] lane broadcast per group keeps the
-        # expansion Mosaic-friendly (no cross-sublane relayout).
-        s2 = sbuf_slot.reshape(rows, tpr)
-        lane_t = jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 1) // d
-        out = jnp.zeros((rows, 128), jnp.float32)
-        for t in range(tpr):
-            out = out + jnp.where(
-                lane_t == t,
-                jnp.broadcast_to(s2[:, t:t + 1], (rows, 128)), 0.0)
+    def score_scales(s_ref, c):
+        # Chunk c's scales [tpr, rows] -> [n, rows]: score row t*qpk+i,
+        # column r belongs to token r*tpr+t, whose scale is s[t, r]. A
+        # sublane broadcast per group; nothing crosses lanes.
+        s = s_ref[0, 0, c]
+        out = jnp.broadcast_to(s[0:1, :], (n, rows))
+        for t in range(1, tpr):
+            out = jnp.where(group == t,
+                            jnp.broadcast_to(s[t:t + 1, :], (n, rows)), out)
         return out
 
     def body(c, carry):
@@ -147,12 +131,12 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
             cp.wait()
         k2 = k_buf[slot].astype(jnp.float32).reshape(rows, 128)
         v2 = v_buf[slot].astype(jnp.float32).reshape(rows, 128)
-        if quantized:
-            k2 = k2 * dequant_expand(ks_buf[slot])
-            v2 = v2 * dequant_expand(vs_buf[slot])
         scores = jax.lax.dot_general(
             q2, k2, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [n, rows]
+        if quantized:
+            # q . (k_int8 * s) == (q . k_int8) * s: dequantize the scores.
+            scores = scores * score_scales(ks_ref, c)
         token_idx = c * chunk_tokens + row * tpr + group
         scores = jnp.where(token_idx < seq_len, scores, NEG_INF)
         # Per-row online softmax (groups merged outside the kernel).
@@ -160,6 +144,9 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
         p = jnp.exp(scores - m_new)
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        if quantized:
+            # p . (v_int8 * s) == (p * s) . v_int8 (l keeps the bare p).
+            p = p * score_scales(vs_ref, c)
         acc_new = acc * alpha + jax.lax.dot_general(
             p, v2, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -174,8 +161,28 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
     l_ref[0, 0] = jnp.broadcast_to(l, (n, 128))
 
 
+def _chunk_scales(scale, layer, page_table, tpr: int):
+    """Per-token scales of the sequences' pages, gathered in XLA and laid
+    out the way the kernel multiplies them: [B, Nkv, chunks, tpr, rows]
+    with [c, t, r] = the scale of token c*chunk_tokens + r*tpr + t.
+    scale [L, Nkv, P, page] f32. The gather covers the page-table bucket
+    (not only live pages), but scales are 4 bytes per token beside D of
+    data; page-table padding is masked by seq_len in the kernel."""
+    b, maxp = page_table.shape
+    nkv, page = scale.shape[1], scale.shape[3]
+    chunks = pl.cdiv(maxp, PAGES_PER_CHUNK)
+    pt = jnp.pad(page_table, ((0, 0), (0, chunks * PAGES_PER_CHUNK - maxp)))
+    # Layer and head stay ADVANCED indices (kv_quant.gather_pages_folded:
+    # a basic cache[layer] is a dynamic-slice copy of the pool).
+    idx_l = jnp.broadcast_to(layer, (b, nkv, pt.shape[1]))
+    idx_n = jnp.arange(nkv)[None, :, None]
+    s = scale[idx_l, idx_n, pt[:, None, :]]     # [B, Nkv, pages, page]
+    rows = PAGES_PER_CHUNK * page // tpr
+    return s.reshape(b, nkv, chunks, rows, tpr).transpose(0, 1, 2, 4, 3)
+
+
 def _hist_flash_pallas(q, k_cache, v_cache, layer, page_table, hist_lens,
-                       q_per_kv):
+                       q_per_kv, interpret: bool):
     """Run the kernel over the cache-resident history; returns the flash
     triple (num [b,nkv,qpk,d] unnormalized, l_star [b,nkv,qpk,1],
     m_s [b,nkv,qpk,1]) for the wrapper to merge with out-of-cache columns
@@ -201,8 +208,8 @@ def _hist_flash_pallas(q, k_cache, v_cache, layer, page_table, hist_lens,
 
     # Pack the caches: view each page as [rows_per_page, 128] (zero-cost
     # reshape: same row-major layout). int8 pools (QuantKV) pack their
-    # data pages the same way and additionally ship the per-token scale
-    # rows; the kernel dequantizes in-register after the HBM->VMEM DMA.
+    # data pages the same way; their scales ride in as a blocked VMEM
+    # operand (_chunk_scales) and the kernel dequantizes in-register.
     quantized = isinstance(k_cache, QuantKV)
     L = k_cache.shape[0]
     k_pages = k_cache.data if quantized else k_cache
@@ -222,29 +229,26 @@ def _hist_flash_pallas(q, k_cache, v_cache, layer, page_table, hist_lens,
             q2 = q2.at[:, :, t * qpk:(t + 1) * qpk, t * d:(t + 1) * d].set(qg)
 
     blk = pl.BlockSpec((1, 1, n, tpr * d), lambda i, j, *_: (i, j, 0, 0))
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [blk, any_spec, any_spec]
     operands = [q2, kp, vp]
-    scratch = [
-        pltpu.VMEM((2, PAGES_PER_CHUNK, rows_per_page, 128), kp.dtype),
-        pltpu.VMEM((2, PAGES_PER_CHUNK, rows_per_page, 128), vp.dtype),
-    ]
     if quantized:
-        # Scale rows [L, Nkv, P, page] ride their own chunk DMAs; the
-        # extra semaphore pairs below fence them independently.
-        in_specs += [any_spec, any_spec]
-        operands += [k_cache.scale, v_cache.scale]
-        scratch += [
-            pltpu.VMEM((2, PAGES_PER_CHUNK, page_size), jnp.float32),
-            pltpu.VMEM((2, PAGES_PER_CHUNK, page_size), jnp.float32),
-        ]
-    scratch.append(pltpu.SemaphoreType.DMA((4 if quantized else 2, 2)))
+        ks = _chunk_scales(k_cache.scale, layer, page_table, tpr)
+        vs = _chunk_scales(v_cache.scale, layer, page_table, tpr)
+        s_blk = pl.BlockSpec((1, 1, *ks.shape[2:]),
+                             lambda i, j, *_: (i, j, 0, 0, 0))
+        in_specs += [s_blk, s_blk]
+        operands += [ks, vs]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, nkv),
         in_specs=in_specs,
         out_specs=(blk, blk, blk),
-        scratch_shapes=scratch,
+        scratch_shapes=[
+            pltpu.VMEM((2, PAGES_PER_CHUNK, rows_per_page, 128), kp.dtype),
+            pltpu.VMEM((2, PAGES_PER_CHUNK, rows_per_page, 128), vp.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ],
     )
     kernel = functools.partial(_decode_kernel, page_size=page_size,
                                max_pages=maxp, tpr=tpr, qpk=qpk,
@@ -254,11 +258,9 @@ def _hist_flash_pallas(q, k_cache, v_cache, layer, page_table, hist_lens,
         kernel,
         grid_spec=grid_spec,
         out_shape=(shape, shape, shape),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-        # CPU (CI / the virtual test mesh) runs the TPU kernel through the
-        # Pallas interpreter; Mosaic compiles it on real chips.
-        interpret=jax.default_backend() == "cpu",
+        interpret=interpret,
     )(layer_arr, page_table, seq_lens, *operands)
     m = m[..., :1]  # broadcast lanes -> scalar stat per row
     l = l[..., :1]
@@ -305,12 +307,13 @@ def _merge_extra(q, num, l_star, m_s, k_extra, v_extra, s_mask, q_per_kv):
 
 
 # dtpu: ignore[unregistered-jit] -- inner kernel: only ever traced INSIDE registered runner programs (inlined), never dispatched standalone from the serving loop
-@functools.partial(jax.jit, static_argnames=("q_per_kv",))
+@functools.partial(jax.jit, static_argnames=("q_per_kv", "interpret"))
 def paged_decode_attention_pallas(q: jax.Array, k_cache: jax.Array,
                                   v_cache: jax.Array, layer: jax.Array,
                                   page_table: jax.Array, hist_lens: jax.Array,
                                   k_self: jax.Array, v_self: jax.Array,
-                                  q_per_kv: int) -> jax.Array:
+                                  q_per_kv: int, interpret: bool = False
+                                  ) -> jax.Array:
     """Drop-in replacement for model.paged_decode_attention_xla.
 
     q [B,Nh,D]; k_cache/v_cache [L,Nkv,P,page,D] (the FULL stacked cache —
@@ -319,33 +322,38 @@ def paged_decode_attention_pallas(q: jax.Array, k_cache: jax.Array,
     already cache-resident); k_self/v_self [B,Nkv,D] (the new token's K/V,
     merged as an extra flash column outside the kernel). Returns [B,Nh,D].
     Requires page_size*D % 128 == 0 and 128 % D == 0 (packed) or
-    D % 128 == 0 (natural).
+    D % 128 == 0 (natural). ``interpret`` runs the kernel through the
+    Pallas interpreter (the only way it runs on a CPU); the caller decides
+    it from the platform its arrays live on (ModelRunner does, once), never
+    from the process default — a TPU run compiles through Mosaic or fails.
     """
     b = q.shape[0]
     nkv = k_cache.shape[1]
     num, l_star, m_s = _hist_flash_pallas(q, k_cache, v_cache, layer,
-                                          page_table, hist_lens, q_per_kv)
+                                          page_table, hist_lens, q_per_kv,
+                                          interpret)
     mask = jnp.ones((b, 1, 1, 1), bool)
     return _merge_extra(q, num, l_star, m_s, k_self[:, :, None, :],
                         v_self[:, :, None, :], mask, q_per_kv)
 
 
 # dtpu: ignore[unregistered-jit] -- inner kernel: only ever traced INSIDE registered runner programs (inlined), never dispatched standalone from the serving loop
-@functools.partial(jax.jit, static_argnames=("q_per_kv",))
+@functools.partial(jax.jit, static_argnames=("q_per_kv", "interpret"))
 def paged_window_attention_pallas(q: jax.Array, k_cache: jax.Array,
                                   v_cache: jax.Array, layer: jax.Array,
                                   page_table: jax.Array, hist_lens: jax.Array,
                                   k_win: jax.Array, v_win: jax.Array,
                                   m: jax.Array, k_self: jax.Array,
-                                  v_self: jax.Array, q_per_kv: int
-                                  ) -> jax.Array:
+                                  v_self: jax.Array, q_per_kv: int,
+                                  interpret: bool = False) -> jax.Array:
     """Window variant (model.paged_window_attention_xla interface): kernel
     over the cache-resident history + XLA flash-merge of the in-window
     buffer (cols j < m) and the current token. k_win/v_win [Nkv,B,M,D]."""
     b = q.shape[0]
     M = k_win.shape[2]
     num, l_star, m_s = _hist_flash_pallas(q, k_cache, v_cache, layer,
-                                          page_table, hist_lens, q_per_kv)
+                                          page_table, hist_lens, q_per_kv,
+                                          interpret)
     k_extra = jnp.concatenate(
         [k_win.transpose(1, 0, 2, 3), k_self[:, :, None, :]], axis=2)
     v_extra = jnp.concatenate(

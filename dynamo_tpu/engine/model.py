@@ -335,10 +335,7 @@ def ring_causal_attention(mesh, q: jax.Array, k: jax.Array, v: jax.Array,
     operands — same numerics recipe as the dense path. The reference has
     no sequence parallelism at all (SURVEY §2.7); this is a
     beyond-parity capability."""
-    try:
-        from jax import shard_map  # jax >= 0.8 home
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     n_shards = mesh.shape["sp"]
     scale = 1.0 / math.sqrt(q.shape[-1])

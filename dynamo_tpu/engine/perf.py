@@ -39,6 +39,11 @@ This module makes them live series:
   ints into ``dynamo_tpu_perf_*`` counters/gauges, plus periodic
   ``device.memory_stats()`` HBM gauges from the runner.
 
+This module also owns the persistent XLA compile cache
+(:func:`configure_compile_cache`: ``JAX_COMPILATION_CACHE_DIR`` when set,
+else a fixed ``.jax_cache`` in the checkout), because every program it
+would hold is built through :func:`instrumented_jit`.
+
 Env knobs: ``DTPU_PERF_COST`` = ``lower`` (default: cheap unoptimized-
 HLO estimate) | ``compile`` (accurate, pays a second XLA compile per
 program family) | ``off``.
@@ -112,6 +117,67 @@ try:  # pragma: no branch — registration is once at import
 except Exception:  # noqa: BLE001 — older jax: degrade to first-call counting
     log.info("jax.monitoring unavailable; compile observatory degrades "
              "to first-call counting")
+
+
+# -- persistent compile cache ---------------------------------------------------
+#: Where compiled programs persist when JAX_COMPILATION_CACHE_DIR is not
+#: set: a fixed path inside the checkout (the path is part of what makes a
+#: cache findable again — never a temp name, pid or timestamp).
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+# Process-wide persistent-cache traffic (jax.monitoring events; plain ints).
+_cache_events = {"hits": 0, "misses": 0}
+
+
+def _on_cache_event(event: str, **_kw) -> None:
+    if event == "/jax/compilation_cache/cache_hits":
+        _cache_events["hits"] += 1
+    elif event == "/jax/compilation_cache/cache_misses":
+        _cache_events["misses"] += 1
+
+
+jax.monitoring.register_event_listener(_on_cache_event)
+
+
+def compile_cache_dir() -> str:
+    """The persistent compile cache directory this process uses: what
+    JAX_COMPILATION_CACHE_DIR says when it is set (jax reads that variable
+    itself and no other directory is set in code), else the fixed
+    in-checkout default."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or DEFAULT_COMPILE_CACHE_DIR)
+
+
+def configure_compile_cache() -> str | None:
+    """Turn on jax's persistent compilation cache before the first
+    compile (ModelRunner construction calls this, so every entry point
+    passes through it). Thresholds drop to zero so the many small bucket
+    programs are kept, not only those that take a second to build.
+    Returns the directory, or None where the cache is switched off
+    (``JAX_ENABLE_COMPILATION_CACHE=false``, as tests/conftest.py does)."""
+    if not jax.config.jax_enable_compilation_cache:
+        return None
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          DEFAULT_COMPILE_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return compile_cache_dir()
+
+
+def compile_cache_status() -> dict:
+    """Directory, entry count and this process's hit/miss counts."""
+    path = compile_cache_dir()
+    enabled = bool(jax.config.jax_enable_compilation_cache)
+    try:
+        entries = sum(1 for name in os.listdir(path)
+                      if name.endswith("-cache"))
+    except FileNotFoundError:
+        entries = 0
+    return {"dir": path if enabled else None, "enabled": enabled,
+            "entries": entries, **_cache_events}
 
 
 class _InstrumentedJit:

@@ -151,6 +151,7 @@ def _build_engine(args, metrics_registry=None):
     engine = TPUEngine(cfg, params=params,
                        metrics_registry=metrics_registry)
     engine.start()
+    engine.wait_ready()  # a warm-up or compile failure fails the launch
     return engine, tokenizer
 
 
@@ -303,7 +304,11 @@ async def run_batch(served: ServedModel, args) -> None:
         "results": out_path}), flush=True)
 
 
-async def run(args) -> None:
+async def run(args, ready=None) -> None:
+    """Assemble and serve until shutdown. ``ready(runtime, service,
+    engine)`` is called once the HTTP service listens (an embedding
+    caller — chip_smoke.py, a test — gets the bound port and the handle
+    to ``runtime.shutdown()`` without scraping stdout)."""
     if args.output == "dyn":
         cfg = RuntimeConfig.from_settings()
         if args.coordinator_url:
@@ -363,6 +368,8 @@ async def run(args) -> None:
         await service.start()
         print(f"LAUNCH_READY in={args.input} out={args.output} "
               f"port={service.port}", flush=True)
+        if ready is not None:
+            ready(runtime, service, engine)
         await runtime.wait_for_shutdown()
         await service.stop()
     finally:

@@ -166,7 +166,8 @@ def make_test_tokenizer(vocab_texts: list[str] | None = None) -> Tokenizer:
     hf.decoder = decoders.ByteLevel()
     trainer = trainers.BpeTrainer(
         vocab_size=512, special_tokens=["<|endoftext|>", "<|im_end|>"],
-        initial_alphabet=pre_tokenizers.ByteLevel.alphabet())
+        initial_alphabet=pre_tokenizers.ByteLevel.alphabet(),
+        show_progress=False)  # the bar writes blank lines to fd 1
     corpus = vocab_texts or [
         "hello world this is a test of the tpu native serving framework",
         "the quick brown fox jumps over the lazy dog 0123456789",

@@ -11,6 +11,8 @@ implementation if compilation fails or DTPU_NATIVE=0.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 
@@ -23,14 +25,18 @@ _DIR = os.path.dirname(os.path.abspath(__file__))
 
 def load_library(name: str) -> ctypes.CDLL | None:
     """Load (building if needed) lib ``name`` (e.g. "radix_tree" ->
-    _radix_tree.so). Returns None when native is disabled or the build
-    fails."""
+    _radix_tree.<source hash>.so). The object's name carries the hash of
+    the source it was built from, so a stale one left in the tree — by an
+    older checkout, or by a copy that reset mtimes — can never stand in
+    for the committed source. Returns None when native is disabled or
+    the build fails (logged; doctor.check_native reports it)."""
     if os.environ.get("DTPU_NATIVE", "1").lower() in ("0", "false"):
         return None
     src = os.path.join(_DIR, f"{name}.cpp")
-    so = os.path.join(_DIR, f"_{name}.so")
-    if (not os.path.exists(so)
-            or os.path.getmtime(so) < os.path.getmtime(src)):
+    with open(src, "rb") as fh:
+        digest = hashlib.sha256(fh.read()).hexdigest()[:12]
+    so = os.path.join(_DIR, f"_{name}.{digest}.so")
+    if not os.path.exists(so):
         tmp = f"{so}.{os.getpid()}.tmp"  # concurrent builders can't collide
         try:
             subprocess.run(
@@ -49,6 +55,12 @@ def load_library(name: str) -> ctypes.CDLL | None:
             except OSError:
                 pass
             return None
+        for old in glob.glob(os.path.join(_DIR, f"_{name}*.so")):
+            if old != so:
+                try:
+                    os.unlink(old)  # built from a source that is gone
+                except OSError:
+                    pass
     try:
         return ctypes.CDLL(so)
     except OSError as exc:

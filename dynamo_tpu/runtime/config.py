@@ -9,18 +9,11 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import tomllib
 from typing import Any
 
 from dynamo_tpu.runtime.overload import OverloadConfig
 from dynamo_tpu.runtime.slo import SloConfig
-
-try:  # tomllib is stdlib from 3.11; fall back to tomli, else TOML-less.
-    import tomllib
-except ModuleNotFoundError:  # pragma: no cover - depends on interpreter
-    try:
-        import tomli as tomllib  # type: ignore[no-redef]
-    except ModuleNotFoundError:
-        tomllib = None  # type: ignore[assignment]
 
 ENV_PREFIX = "DTPU_"
 
@@ -130,10 +123,6 @@ class RuntimeConfig:
         cfg = cls()
         toml_path = path or _env("CONFIG_PATH")
         if toml_path and os.path.exists(toml_path):
-            if tomllib is None:
-                raise RuntimeError(
-                    f"config file {toml_path!r} given but no TOML parser is "
-                    "available (python < 3.11 without tomli)")
             # dtpu: ignore[blocking-call-in-async] -- tiny local settings file, read once at process startup (allowed-to-block leaf)
             with open(toml_path, "rb") as fh:
                 data: dict[str, Any] = tomllib.load(fh)

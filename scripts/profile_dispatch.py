@@ -1,4 +1,4 @@
-"""Measure per-call dispatch/transfer overhead on this TPU attachment."""
+"""Measure per-call dispatch/transfer overhead on the attached TPU."""
 
 import time
 

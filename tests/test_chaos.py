@@ -419,6 +419,13 @@ async def test_scenario_kv_pull_failure_retries_then_succeeds():
             ticket = server.stage(kv=kv, prompt_len=7)
             out = await client.pull(ticket)
         np.testing.assert_array_equal(out, kv)
+        # The server thread releases the entry after its send returns,
+        # which can be after the client has every byte: wait for the
+        # event (seen racing in a loaded full-suite run, PR 21).
+        for _ in range(500):
+            if not server._staged and server.transfers == 1:
+                break
+            await asyncio.sleep(0.01)
         assert server._staged == {}  # released after the successful pull
         assert server.transfers == 1  # exactly one full parcel served
         # Partial parcel: server sends half then severs; retry refetches.

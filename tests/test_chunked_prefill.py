@@ -290,11 +290,12 @@ def test_resolve_prefill_chunk_tokens(monkeypatch):
     monkeypatch.delenv("DTPU_PREFILL_CHUNK_TOKENS", raising=False)
     monkeypatch.delenv("DTPU_WINDOW_TARGET_MS", raising=False)
     monkeypatch.delenv("DTPU_PREFILL_KNEE_TOK", raising=False)
-    monkeypatch.delenv("DTPU_HBM_GBPS", raising=False)
+    from dynamo_tpu.engine.config import DEVICE_PEAKS
+    v5e = DEVICE_PEAKS["TPU v5 lite"]
 
     def res(model="tiny-test", **kw):
         return EngineConfig(model=PRESETS[model],
-                            **kw).resolve_prefill_chunk_tokens()
+                            **kw).resolve_prefill_chunk_tokens(v5e)
 
     # Tiny model: effectively free prefill -> budget caps at the largest
     # usable chunk (min of max_prefill_tokens and the bucket ladder).

@@ -278,14 +278,15 @@ def test_auto_decode_window_sizing(monkeypatch):
     weight-read step estimate: small models get long windows, big shards
     short ones (docs/PERF_NOTES.md sweep)."""
     import pytest
-    from dynamo_tpu.engine.config import EngineConfig, PRESETS
+    from dynamo_tpu.engine.config import (DEVICE_PEAKS, EngineConfig,
+                                          PRESETS)
 
     monkeypatch.delenv("DTPU_WINDOW_TARGET_MS", raising=False)
-    monkeypatch.delenv("DTPU_HBM_GBPS", raising=False)
+    v5e = DEVICE_PEAKS["TPU v5 lite"]
 
-    def win(model, **kw):
+    def win(model, peaks=v5e, **kw):
         return EngineConfig(model=PRESETS[model], decode_window="auto",
-                            **kw).resolve_decode_window()
+                            **kw).resolve_decode_window(peaks)
 
     w_small = win("qwen2.5-0.5b")
     w_8b = win("llama-3-8b")
@@ -294,15 +295,19 @@ def test_auto_decode_window_sizing(monkeypatch):
     assert w_8b < w_small
     # tp shrinks the shard -> longer windows again.
     assert win("llama-3-8b", tp=8) > w_8b
+    # No published peak (the CPU backend): no bandwidth model, the window
+    # is sized from the host-overhead term alone, whatever the model.
+    assert win("llama-3-8b", peaks=None) == win("tiny-test", peaks=None) \
+        == 64
     # Explicit int passes through; junk and non-positive rejected.
     assert EngineConfig(model=PRESETS["tiny-test"],
-                        decode_window=6).resolve_decode_window() == 6
+                        decode_window=6).resolve_decode_window(None) == 6
     with pytest.raises(ValueError):
         EngineConfig(model=PRESETS["tiny-test"],
-                     decode_window="big").resolve_decode_window()
+                     decode_window="big").resolve_decode_window(None)
     with pytest.raises(ValueError):
         EngineConfig(model=PRESETS["tiny-test"],
-                     decode_window=0).resolve_decode_window()
+                     decode_window=0).resolve_decode_window(None)
     # The target knob moves the answer.
     monkeypatch.setenv("DTPU_WINDOW_TARGET_MS", "10")
     assert win("qwen2.5-0.5b") < w_small

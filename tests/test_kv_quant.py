@@ -103,6 +103,8 @@ def test_capacity_pages_double_at_fixed_hbm_budget():
     spec = PRESETS["llama-3-8b"]
 
     class Dev:
+        platform = "tpu"
+
         def memory_stats(self):
             return {"bytes_limit": 16 << 30, "bytes_in_use": 0}
 
@@ -112,6 +114,7 @@ def test_capacity_pages_double_at_fixed_hbm_budget():
                              quant_kv=cfg.resolve_quant_kv())
         ns._kv_token_head_bytes = \
             lambda: ModelRunner._kv_token_head_bytes(ns)
+        ns._memory_stats = ModelRunner._memory_stats
         ModelRunner._sized_pages(ns, Dev())
         return ns.num_pages
 
@@ -326,7 +329,8 @@ def test_disk_tier_stores_packed_parcels(tmp_path):
 # pallas kernel: fused in-register dequant (interpret mode on CPU)
 # ---------------------------------------------------------------------------
 
-def test_pallas_fused_dequant_matches_xla_quant_path():
+@pytest.mark.parametrize("d", [64, 128])
+def test_pallas_fused_dequant_matches_xla_quant_path(d):
     import jax.numpy as jnp
     import ml_dtypes
 
@@ -334,7 +338,7 @@ def test_pallas_fused_dequant_matches_xla_quant_path():
     from dynamo_tpu.engine.model import paged_decode_attention_xla
 
     rng = np.random.default_rng(0)
-    d, page = 64, 16  # packed case: tpr=2 tokens per 128-lane row
+    page = 16  # d=64 packs tpr=2 tokens per 128-lane row; d=128 is natural
     L, nkv, P, B, qpk = 2, 2, 12, 3, 4
     k = rng.standard_normal((L, nkv, P, page, d)).astype(ml_dtypes.bfloat16)
     v = rng.standard_normal((L, nkv, P, page, d)).astype(ml_dtypes.bfloat16)
@@ -352,7 +356,8 @@ def test_pallas_fused_dequant_matches_xla_quant_path():
         rng.standard_normal((B, nkv, d)).astype(ml_dtypes.bfloat16))
     layer = jnp.asarray(1, jnp.int32)
     out_p = paged_decode_attention_pallas(q, kc, vc, layer, pt, hist,
-                                          k_self, v_self, qpk)
+                                          k_self, v_self, qpk,
+                                          interpret=True)
     out_x = paged_decode_attention_xla(q, kc, vc, layer, pt, hist,
                                        k_self, v_self, qpk)
     err = float(jnp.max(jnp.abs(out_p.astype(jnp.float32)

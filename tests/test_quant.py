@@ -197,8 +197,8 @@ def test_quant_tp2_and_kv_extract():
 
 def test_weight_read_accounting_halves():
     spec8 = dataclasses.replace(PRESETS["llama-3-8b"], quant="int8")
-    bf = PRESETS["llama-3-8b"].weight_read_step_ms()
-    q8 = spec8.weight_read_step_ms()
+    bf = PRESETS["llama-3-8b"].weight_read_step_ms(819.0)
+    q8 = spec8.weight_read_step_ms(819.0)
     assert abs(q8 - bf / 2) < 1e-6
     assert weight_dtype_bytes("int8") == 1.0
     assert weight_dtype_bytes(None) == 2.0

@@ -1,0 +1,93 @@
+"""The Pallas decode kernel compiles for a TPU v5e — without the chip.
+
+The TPU's compiler is installed with jax and compiles for a chip that is
+described, not attached (section 2.3 of the on-chip-measurement guide).
+Interpret mode (tests/test_attention_pallas.py, test_kv_quant.py) checks the
+kernel's results; it cannot see what Mosaic refuses. PR 12's int8-KV kernel
+passed every interpret test and had never compiled: Mosaic refused the scale
+reshape. These compiles, at the real widths of the two head sizes the repo
+serves, guard every later PR at no chip time. Nothing runs here, so nothing
+is said about results or speed.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from dynamo_tpu.engine.attention import (paged_decode_attention_pallas,
+                                         paged_window_attention_pallas)
+from dynamo_tpu.engine.kv_quant import QuantKV
+
+PAGE = 16
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described v5e device; the module is skipped where the topology
+    cannot be described (no libtpu in the installation)."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 — any reason means "not here"
+        pytest.skip(f"TPU topology cannot be described here: {exc}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip (the next one warns and
+    compiles again): keep these out of it."""
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+# (head_dim, kv heads, q heads per kv head, layers): qwen2.5-0.5b, llama-3-8b.
+WIDTHS = {"qwen2.5-0.5b": (64, 2, 7, 24), "llama-3-8b": (128, 8, 4, 32)}
+
+
+def _shapes(one, model, quantized, b=40, maxp=64):
+    d, nkv, qpk, layers = WIDTHS[model]
+    pool = (layers, nkv, b * maxp + 16, PAGE, d)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    if quantized:
+        cache = QuantKV(s(pool, jnp.int8), s(pool[:-1], jnp.float32))
+    else:
+        cache = s(pool, jnp.bfloat16)
+    q = s((b, nkv * qpk, d), jnp.bfloat16)
+    self_kv = s((b, nkv, d), jnp.bfloat16)
+    return (q, cache, cache, s((), jnp.int32), s((b, maxp), jnp.int32),
+            s((b,), jnp.int32)), self_kv, qpk
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8kv"])
+@pytest.mark.parametrize("model", list(WIDTHS))
+def test_decode_kernel_compiles_for_v5e(v5e, model, quantized):
+    head, self_kv, qpk = _shapes(v5e, model, quantized)
+    compiled = jax.jit(
+        lambda *a: paged_decode_attention_pallas(*a, q_per_kv=qpk)
+    ).lower(*head, self_kv, self_kv).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8kv"])
+def test_window_kernel_compiles_for_v5e(v5e, quantized):
+    """The variant the serving window program calls: kernel over the
+    cache-resident history, in-window buffer merged in XLA."""
+    head, self_kv, qpk = _shapes(v5e, "qwen2.5-0.5b", quantized)
+    b, nkv, d = self_kv.shape
+    win = jax.ShapeDtypeStruct((nkv, b, 32, d), jnp.bfloat16, sharding=v5e)
+    step = jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e)
+    compiled = jax.jit(
+        lambda *a: paged_window_attention_pallas(*a, q_per_kv=qpk)
+    ).lower(*head, win, win, step, self_kv, self_kv).compile()
+    assert "tpu_custom_call" in compiled.as_text()
