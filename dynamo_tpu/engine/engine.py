@@ -42,7 +42,7 @@ from dynamo_tpu.llm.kv_router.protocols import (ForwardPassMetrics, KvStats,
 from dynamo_tpu.llm.protocols import FinishReason, LLMEngineOutput, PreprocessedRequest
 from dynamo_tpu.llm.tokens import TokenBlockSequence
 from dynamo_tpu.engine import perf as perf_plane
-from dynamo_tpu.runtime import chaos, flight, journal
+from dynamo_tpu.runtime import chaos, flight, journal, tracing
 from dynamo_tpu.runtime.journal import EventKind
 from dynamo_tpu.runtime.context import Context
 from dynamo_tpu.runtime.engine import AsyncEngine
@@ -98,6 +98,11 @@ class _Request:
     prefilling: bool = False
     prefill_pos: int = 0
     prefill_t0: float = 0.0
+    # The request's ONE engine.decode span: first token emitted -> finish
+    # (attrs: tokens, decode windows that emitted for it, preemptions).
+    decode_t0: float = 0.0
+    decode_windows: int = 0
+    preemptions: int = 0
     # Batched LoRA (engine/lora.py): the resident device slot this
     # request's adapter occupies (0 = base model) and the store
     # reference held while the request is live (released at slot
@@ -116,7 +121,13 @@ class _Window:
     frozen: dict  # slot -> (request, epoch, "requeue" | "oom")
     size: int
     serial: int = 0  # dispatch order (pipelined deferred-release fencing)
-    t0: float = 0.0  # dispatch time (decode_step_seconds + decode spans)
+    t0: float = 0.0  # dispatch time (latency through the pipeline)
+    page_bucket: int = 0   # page-table width of the program dispatched
+    t_ready: float = 0.0   # readback complete (set when processed)
+    # t_ready minus the previous window's, when this window was already
+    # queued behind it (the device ran them back to back): one window of
+    # the device. 0 when the pipe was not full.
+    period_s: float = 0.0
     # Speculative windows: toks = (outs [m,B,S], emits [m,B],
     # ndrafts [m,B]); slots snaps carry the ASSUMED advance so
     # processing can correct the host's upper-bound positions.
@@ -306,6 +317,13 @@ class TPUEngine(AsyncEngine):
         self._flight_chunk_last = 0
         self._flight_stall_last = 0.0
         self._flight_tokens_last = 0
+        # The engine thread's phases (runtime/tracing.py ENGINE_PHASES) and
+        # what of them the last flight row already carries.
+        self.phase_clock = tracing.PhaseClock()
+        self._flight_busy_last = 0.0
+        self._flight_wait_last = 0.0
+        self._flight_idle_last = 0.0
+        self._last_ready_t: float | None = None
         # Perf plane (engine/perf.py): per-window roofline attribution
         # feeds the process-global compile registry; the exporter turns
         # it into dynamo_tpu_perf_* series alongside HBM gauges.
@@ -1020,6 +1038,10 @@ class TPUEngine(AsyncEngine):
             },
             "hbm": self.runner.hbm_stats(),
             "memory": self.runner.memory_breakdown(),
+            # Engine-thread self time by loop phase, seconds since the
+            # loop started (engine_phase_seconds_total on /metrics).
+            "phases": {k: round(v, 6)
+                       for k, v in self.phase_clock.totals().items()},
         }
         if self.config.spec_decode:
             # Verify-of-k bandwidth: the spec program runs m_outer verify
@@ -1195,6 +1217,11 @@ class TPUEngine(AsyncEngine):
         self._perf.mark_ready()
         self._ready.set()
         depth = max(1, self.config.pipeline_depth)
+        # Each phase below is a TraceAnnotation on this thread's line of a
+        # profiler trace and self time in phase_clock; what an iteration
+        # spends in none of them is engine.other.
+        phase = self.phase_clock.phase
+        self.phase_clock.restart()
         while self._running:
             if chaos.ACTIVE:
                 # Chaos site "engine": engine.stall_ms freezes the loop
@@ -1204,13 +1231,18 @@ class TPUEngine(AsyncEngine):
                 stall = chaos.value("engine.stall_ms", "engine")
                 if stall is not None:
                     time.sleep(stall / 1e3)
-            self._run_jobs()
-            self._resolve_ready_first()
-            self._resolve_spills()
-            self._maintain_kvbm()
-            self._retire_chunks()
+            with phase("engine.jobs"):
+                self._run_jobs()
+            with phase("engine.resolve_first"):
+                self._resolve_ready_first()
+            with phase("engine.kvbm"):
+                self._resolve_spills()
+                self._maintain_kvbm()
+            with phase("engine.retire_chunks"):
+                self._retire_chunks()
             try:
-                admitted = self._admit()
+                with phase("engine.admit"):
+                    admitted = self._admit()
             except Exception:  # noqa: BLE001
                 log.exception("admission failed")
                 admitted = False
@@ -1218,7 +1250,8 @@ class TPUEngine(AsyncEngine):
             # chunk work BEFORE the decode window, so a long prompt's
             # interference with live decode slots is bounded by ~one
             # chunk's compute per window instead of the whole prompt.
-            chunk_dispatched = self._dispatch_prefill_chunks()
+            with phase("engine.dispatch_chunks"):
+                chunk_dispatched = self._dispatch_prefill_chunks()
             have_active = any(r is not None and not r.prefilling
                               for r in self.slot_req)
             dispatched = False
@@ -1239,7 +1272,8 @@ class TPUEngine(AsyncEngine):
                         flight.trigger(f"decode_stall_{gap:.2f}s")
                 self._last_decode_dispatch = now
                 try:
-                    window = self._dispatch_window()
+                    with phase("engine.dispatch_window"):
+                        window = self._dispatch_window()
                 except Exception as exc:  # noqa: BLE001 — fail all, keep serving
                     log.exception("decode window dispatch failed")
                     for i, r in enumerate(self.slot_req):
@@ -1250,7 +1284,8 @@ class TPUEngine(AsyncEngine):
                     if window.toks is None:
                         # No device work (every live slot frozen): handle
                         # the preemption records immediately.
-                        self._do_process(window)
+                        with phase("engine.process_window"):
+                            self._do_process(window)
                     else:
                         self._inflight.append(window)
                         dispatched = True
@@ -1261,9 +1296,11 @@ class TPUEngine(AsyncEngine):
             if self._inflight and (len(self._inflight) >= depth
                                    or not dispatched):
                 window = self._inflight.popleft()
-                self._do_process(window)
+                with phase("engine.process_window"):
+                    self._do_process(window)
                 self.step_count += 1
-                self._publish()
+                with phase("engine.publish"):
+                    self._publish()
                 self._note_flight(window)
             self._release_ready_pages()
             if self._inflight or chunk_dispatched:
@@ -1271,14 +1308,18 @@ class TPUEngine(AsyncEngine):
             if not have_active and self._chunk_inflight:
                 # Prefill-only phase at full chunk depth: block on the
                 # oldest chunk program instead of spinning.
-                self._retire_chunks(block=True)
+                with phase("engine.retire_chunks"):
+                    self._retire_chunks(block=True)
             elif self._pending_first:
                 # Nothing left on the device but first tokens unfetched
                 # (e.g. a lone max_tokens=1 request): block on them now.
-                self._resolve_ready_first(force=True)
+                with phase("engine.resolve_first"):
+                    self._resolve_ready_first(force=True)
             elif not admitted and not have_active and not self._prefilling:
-                self._resolve_spills(force=True)
-                time.sleep(0.002)  # fully idle
+                with phase("engine.kvbm"):
+                    self._resolve_spills(force=True)
+                with phase("engine.idle"):
+                    time.sleep(0.002)  # fully idle
 
     # -- KV tiering (G2/G3 offload + onboard) ---------------------------------
     @property
@@ -1415,15 +1456,16 @@ class TPUEngine(AsyncEngine):
         never carry device work and never enter the deque)."""
         if not self._pending_release:
             return
-        fence = (self._inflight[0].serial - 1 if self._inflight
-                 else self._dispatch_serial)
-        keep = []
-        for serial, pages in self._pending_release:
-            if serial <= fence:
-                self.allocator.release(pages)
-            else:
-                keep.append((serial, pages))
-        self._pending_release = keep
+        with self.phase_clock.phase("engine.release_pages"):
+            fence = (self._inflight[0].serial - 1 if self._inflight
+                     else self._dispatch_serial)
+            keep = []
+            for serial, pages in self._pending_release:
+                if serial <= fence:
+                    self.allocator.release(pages)
+                else:
+                    keep.append((serial, pages))
+            self._pending_release = keep
 
     def _resolve_ready_first(self, force: bool = False) -> None:
         for entry in list(self._pending_first):
@@ -1438,11 +1480,12 @@ class TPUEngine(AsyncEngine):
         """Block on the fetches whose first tokens the caller is about to
         need (their windows are being processed — the fetch predates those
         windows' compute, so it is effectively ready)."""
-        for entry in list(self._pending_first):
-            if any(slot in slots_needed and self.slot_req[slot] is r
-                   for _, r, slot, _ in entry["rows"]):
-                self._pending_first.remove(entry)
-                self._resolve_first(entry)
+        with self.phase_clock.phase("engine.resolve_first"):
+            for entry in list(self._pending_first):
+                if any(slot in slots_needed and self.slot_req[slot] is r
+                       for _, r, slot, _ in entry["rows"]):
+                    self._pending_first.remove(entry)
+                    self._resolve_first(entry)
 
     def _resolve_first(self, entry: dict) -> None:
         cold = entry.get("cold", 0)
@@ -1978,7 +2021,8 @@ class TPUEngine(AsyncEngine):
                 if not block:
                     break
                 try:
-                    arr.block_until_ready()
+                    with self.phase_clock.phase("engine.readback_wait"):
+                        arr.block_until_ready()
                 except Exception:  # noqa: BLE001 — surfaces at final fetch
                     pass
                 block = False  # only ever block on the oldest
@@ -2292,7 +2336,8 @@ class TPUEngine(AsyncEngine):
             adv = min(M, max(0, cap - start))
             self.disp_positions[i] += adv
             self.disp_seq_lens[i] += adv
-        self._flush_spills()
+        with self.phase_clock.phase("engine.kvbm"):
+            self._flush_spills()
         # Brownout degradation hook: drop back to plain decode windows
         # while the engine-local pressure level is at/above the
         # configured threshold (0 in config disables the hook).
@@ -2315,7 +2360,8 @@ class TPUEngine(AsyncEngine):
         return _Window(toks=outs, slots=slots, frozen=frozen, size=M,
                        serial=self._dispatch_serial,
                        spec=use_spec,
-                       t0=time.monotonic())
+                       t0=time.monotonic(),
+                       page_bucket=packed.shape[1] - PK_PREFIX)
 
     def _process_window(self, w: _Window) -> None:
         if w.spec and w.toks is not None:
@@ -2323,18 +2369,18 @@ class TPUEngine(AsyncEngine):
             return
         page = self.config.page_size
         if w.toks is not None:
-            toks = np.asarray(w.toks[0])
             want_lp = any(
                 snap is not None
                 and snap[0].req.sampling_options.logprobs is not None
                 for snap in w.slots)
-            lps = np.asarray(w.toks[1]) if want_lp else None
-            top_vs = np.asarray(w.toks[2]) if want_lp else None
-            top_is = np.asarray(w.toks[3]) if want_lp else None
-            # Decode phase: dispatch -> readback complete (asarray blocks
-            # on the device program).
-            if self.phase is not None and w.t0:
-                self.phase.decode.observe(time.monotonic() - w.t0)
+            # asarray blocks on the device program: the one place the
+            # engine thread waits for the device.
+            with self.phase_clock.phase("engine.readback_wait"):
+                toks = np.asarray(w.toks[0])
+                lps = np.asarray(w.toks[1]) if want_lp else None
+                top_vs = np.asarray(w.toks[2]) if want_lp else None
+                top_is = np.asarray(w.toks[3]) if want_lp else None
+            self._note_ready(w)
         else:
             toks = None
         self._release_ready_pages()
@@ -2366,6 +2412,7 @@ class TPUEngine(AsyncEngine):
             if self.slot_req[i] is not r or r.epoch != epoch:
                 continue  # slot was re-assigned since dispatch
             if r.ctx.is_killed:
+                self._end_decode_span(r)
                 r.push(None)
                 self._finish_slot(i, register=True)
                 continue
@@ -2405,11 +2452,8 @@ class TPUEngine(AsyncEngine):
             if finish is None and r.ctx.is_stopped:
                 finish = FinishReason.CANCELLED
             self.tokens_generated_total += len(accepted)
-            if self._recorder.enabled and accepted:
-                self._recorder.add(
-                    "engine.decode", r.ctx.trace_id, r.ctx.span_id,
-                    w.t0, time.monotonic(),
-                    attrs={"tokens": len(accepted), "window": w.size})
+            if accepted:
+                r.decode_windows += 1
             self._emit(r, accepted, finish, lp_out)
             if finish is not None:
                 self._finish_slot(i, register=True)
@@ -2421,11 +2465,11 @@ class TPUEngine(AsyncEngine):
         and CORRECTS its dispatch-time position upper bound down to the
         actual advance (pipelined dispatches assumed the worst case)."""
         page = self.config.page_size
-        outs = np.asarray(w.toks[0])     # [m, B, S]
-        emits = np.asarray(w.toks[1])    # [m, B]
-        ndrafts = np.asarray(w.toks[2])  # [m, B]
-        if self.phase is not None and w.t0:
-            self.phase.decode.observe(time.monotonic() - w.t0)
+        with self.phase_clock.phase("engine.readback_wait"):
+            outs = np.asarray(w.toks[0])     # [m, B, S]
+            emits = np.asarray(w.toks[1])    # [m, B]
+            ndrafts = np.asarray(w.toks[2])  # [m, B]
+        self._note_ready(w)
         self._release_ready_pages()
         if self._pending_first:
             need = {i for i, snap in enumerate(w.slots)
@@ -2452,6 +2496,7 @@ class TPUEngine(AsyncEngine):
             if self.slot_req[i] is not r or r.epoch != epoch:
                 continue
             if r.ctx.is_killed:
+                self._end_decode_span(r)
                 r.push(None)
                 self._finish_slot(i, register=True)
                 continue
@@ -2506,12 +2551,8 @@ class TPUEngine(AsyncEngine):
                     self.disp_positions[i] -= delta
                     self.disp_seq_lens[i] -= delta
             self.tokens_generated_total += len(accepted)
-            if self._recorder.enabled and accepted:
-                self._recorder.add(
-                    "engine.decode", r.ctx.trace_id, r.ctx.span_id,
-                    w.t0, time.monotonic(),
-                    attrs={"tokens": len(accepted), "window": w.size,
-                           "spec": True})
+            if accepted:
+                r.decode_windows += 1
             self._emit(r, accepted, finish, None)
             if finish is not None:
                 self._finish_slot(i, register=True)
@@ -2531,13 +2572,51 @@ class TPUEngine(AsyncEngine):
     def _emit(self, r: _Request, tokens: list[int],
               finish: FinishReason | None = None,
               lp_out: tuple[list, list] | None = None) -> None:
+        if tokens and not r.decode_t0:
+            r.decode_t0 = time.monotonic()  # the first token: decode starts
         out = LLMEngineOutput(token_ids=tokens, finish_reason=finish)
         if lp_out is not None:
             out.log_probs = lp_out[0]
             out.top_log_probs = lp_out[1]
+        if finish is not None:
+            # Before the push: a caller that reads the trace when its
+            # stream ends must find the span there.
+            self._end_decode_span(r)
         r.push(out.to_wire())
 
-    def _finish_slot(self, slot: int, register: bool) -> None:
+    def _end_decode_span(self, r: _Request) -> None:
+        """The request's ONE engine.decode span (a span per row per window
+        turned the ring over in two minutes and nothing read them).
+        Recorded ahead of whatever ends the client's stream; a second call
+        is a no-op."""
+        if not r.decode_t0:
+            return
+        if self._recorder.enabled:
+            self._recorder.add(
+                "engine.decode", r.ctx.trace_id, r.ctx.span_id,
+                r.decode_t0, time.monotonic(),
+                attrs={"tokens": r.generated, "windows": r.decode_windows,
+                       "window": self.decode_window,
+                       "preemptions": r.preemptions})
+        r.decode_t0 = 0.0
+
+    def _note_ready(self, w: _Window) -> None:
+        """A window's readback is complete: its period (see _Window), and
+        the decode histogram. A window that was queued behind the previous
+        one is timed by its period, which is a window of the device;
+        dispatch -> readback would count the windows queued ahead of it
+        (pipeline_depth of them) into every sample."""
+        now = time.monotonic()
+        last = self._last_ready_t
+        if last is not None and w.t0 and w.t0 <= last:
+            w.period_s = now - last
+        w.t_ready = now
+        self._last_ready_t = now
+        if self.phase is not None and w.t0:
+            self.phase.decode.observe(w.period_s or now - w.t0)
+
+    def _finish_slot(self, slot: int, register: bool,
+                     requeue: bool = False) -> None:
         r = self.slot_req[slot]
         self.slot_req[slot] = None
         self.disp_positions[slot] = 0
@@ -2550,6 +2629,10 @@ class TPUEngine(AsyncEngine):
         self._release_adapter(r)
         r.slot = -1
         r.epoch += 1
+        if not requeue:
+            # Failure paths only: a finish that went through _emit has
+            # recorded the span already.
+            self._end_decode_span(r)
         if not register:
             # Failure path: the pages' KV contents are suspect (partial
             # prefill / failed step) — drop their prefix-cache entries so no
@@ -2565,10 +2648,12 @@ class TPUEngine(AsyncEngine):
         the re-prefill mostly hits) and requeue the request with its
         accumulated tokens."""
         r = self.slot_req[slot]
-        self._finish_slot(slot, register=True)
+        self._finish_slot(slot, register=True, requeue=True)
         if r is None:
             return
+        r.preemptions += 1
         if r.ctx.is_killed or r.ctx.is_stopped:
+            self._end_decode_span(r)
             r.push(LLMEngineOutput(
                 token_ids=[], finish_reason=FinishReason.CANCELLED).to_wire())
             return
@@ -2601,14 +2686,19 @@ class TPUEngine(AsyncEngine):
         # Plain stores; independent of the flight ring's frozen state.
         window_tokens = tokens_total - self._perf_tokens_last
         self._perf_tokens_last = tokens_total
+        rows = sum(1 for snap in w.slots if snap is not None)
         if w.t0 and w.toks is not None:
+            latency = (w.t_ready or now) - w.t0
             self._perf.note_window(
-                now - w.t0, window_tokens,
-                sum(1 for snap in w.slots if snap is not None),
-                w.size, self._step_floor_ms)
+                w.period_s or latency, window_tokens, rows,
+                w.size, self._step_floor_ms, latency_s=latency)
         fr = self._flight
         if not fr.enabled:
             return
+        clock = self.phase_clock
+        clock.sync(now)
+        wait_total, idle_total = clock.waited()
+        busy_total = clock.total() - wait_total - idle_total
         chunk_total = self.chunk_tokens_total
         accepted = fr.record(
             now, now - w.t0 if w.t0 else 0.0,
@@ -2617,14 +2707,20 @@ class TPUEngine(AsyncEngine):
             chunk_total - self._flight_chunk_last,
             len(self._chunk_inflight), self.preempt_count,
             self.brownout_level, self._flight_stall_last,
-            self.step_count, tokens_total - self._flight_tokens_last)
+            self.step_count, tokens_total - self._flight_tokens_last,
+            w.period_s, busy_total - self._flight_busy_last,
+            wait_total - self._flight_wait_last,
+            idle_total - self._flight_idle_last, rows, w.page_bucket)
         if accepted:
             # A frozen ring (bundle capture in flight) rejects the row:
-            # keep accumulating so the stall/chunk/token deltas land in
-            # the first post-thaw record instead of vanishing.
+            # keep accumulating so the stall/chunk/token/host-time deltas
+            # land in the first post-thaw record instead of vanishing.
             self._flight_chunk_last = chunk_total
             self._flight_stall_last = 0.0
             self._flight_tokens_last = tokens_total
+            self._flight_busy_last = busy_total
+            self._flight_wait_last = wait_total
+            self._flight_idle_last = idle_total
 
     def _publish(self) -> None:
         if self.kv_metrics is not None:

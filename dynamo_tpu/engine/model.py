@@ -21,6 +21,7 @@ from jax.sharding import PartitionSpec as P
 from dynamo_tpu.engine.config import ModelSpec
 from dynamo_tpu.engine.kv_quant import (gather_pages_folded, scatter_pages,
                                         scatter_tokens)
+from dynamo_tpu.engine.perf import scope
 from dynamo_tpu.engine.quant import QTensor
 
 Params = dict[str, Any]
@@ -457,8 +458,9 @@ def paged_window_attention_xla(q: jax.Array, k_cache: jax.Array,
     # Layer+head-folded gather straight into the dot's [Nkv,B,L,D]
     # operand layout (no transposed relayout of the gathered history);
     # dequantizes int8 pools inside the gather expression.
-    k_all = gather_pages_folded(k_cache, layer, page_table)
-    v_all = gather_pages_folded(v_cache, layer, page_table)
+    with scope("attn.kv_gather"):
+        k_all = gather_pages_folded(k_cache, layer, page_table)
+        v_all = gather_pages_folded(v_cache, layer, page_table)
     qg = q.reshape(b, nkv, q_per_kv, d)
     scale = 1.0 / jnp.sqrt(jnp.float32(d))
     s_hist = jnp.einsum("bngd,nbld->bngl", qg, k_all,
@@ -516,47 +518,53 @@ def prefill_forward(params: Params, spec: ModelSpec,
     b, s = tokens.shape
     d = spec.head_dim
     page = k_cache.shape[3]
-    x = embed_lookup(params["embed"], tokens)  # [B,S,H]
-    if x_embeds is not None:
-        # Multimodal spans: encoder-produced embeddings replace the token
-        # table's rows wherever the mask is set (the placeholder ids
-        # under the span never reach the model).
-        x = jnp.where(embeds_mask[..., None], x_embeds.astype(x.dtype), x)
-    if sp_shard:
-        x = jax.lax.with_sharding_constraint(x, P(None, "sp", None))
-    cos, sin = rope_tables(positions, d, spec.rope_theta)
+    with scope("embed"):
+        x = embed_lookup(params["embed"], tokens)  # [B,S,H]
+        if x_embeds is not None:
+            # Multimodal spans: encoder-produced embeddings replace the token
+            # table's rows wherever the mask is set (the placeholder ids
+            # under the span never reach the model).
+            x = jnp.where(embeds_mask[..., None], x_embeds.astype(x.dtype), x)
+        if sp_shard:
+            x = jax.lax.with_sharding_constraint(x, P(None, "sp", None))
+    with scope("attn.qkv"):
+        cos, sin = rope_tables(positions, d, spec.rope_theta)
     valid = jnp.arange(s)[None, :] < seq_lens[:, None]
 
     def layer_fn(x, scan_in):
         lp, ll = scan_in if lora is not None else (scan_in, None)
-        h = rms_norm(x, lp["input_norm"], spec.rms_norm_eps)
-        q = mm(h, lp["wq"], "bsh,hd->bsd")
-        k = mm(h, lp["wk"], "bsh,hd->bsd")
-        v = mm(h, lp["wv"], "bsh,hd->bsd")
-        if ll is not None:
-            q, k, v = qkv_lora(q, k, v, h, ll, adapter_ids)
-        if spec.qkv_bias:
-            q = q + lp["bq"]
-            k = k + lp["bk"]
-            v = v + lp["bv"]
-        q = _split_heads(q, spec.num_heads, d)
-        k = _split_heads(k, spec.num_kv_heads, d)
-        v = _split_heads(v, spec.num_kv_heads, d)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        if ring_mesh is not None:
-            attn = ring_causal_attention(ring_mesh, q, k, v, positions,
-                                         valid, spec.q_per_kv)
-        else:
-            attn = dense_causal_attention(q, k, v, positions, valid,
-                                          spec.q_per_kv)
-        attn = attn.reshape(b, s, -1)
-        proj = mm(attn, lp["wo"], "bsd,dh->bsh")
-        if ll is not None:
-            proj = proj + lora_delta(attn, ll["wo"], adapter_ids)
-        x = x + proj
-        h2 = rms_norm(x, lp["post_attn_norm"], spec.rms_norm_eps)
-        x = x + ffn_block(h2, lp, spec, ll, adapter_ids)
+        with scope("attn.qkv"):
+            h = rms_norm(x, lp["input_norm"], spec.rms_norm_eps)
+            q = mm(h, lp["wq"], "bsh,hd->bsd")
+            k = mm(h, lp["wk"], "bsh,hd->bsd")
+            v = mm(h, lp["wv"], "bsh,hd->bsd")
+            if ll is not None:
+                q, k, v = qkv_lora(q, k, v, h, ll, adapter_ids)
+            if spec.qkv_bias:
+                q = q + lp["bq"]
+                k = k + lp["bk"]
+                v = v + lp["bv"]
+            q = _split_heads(q, spec.num_heads, d)
+            k = _split_heads(k, spec.num_kv_heads, d)
+            v = _split_heads(v, spec.num_kv_heads, d)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+        with scope("attn.core"):
+            if ring_mesh is not None:
+                attn = ring_causal_attention(ring_mesh, q, k, v, positions,
+                                             valid, spec.q_per_kv)
+            else:
+                attn = dense_causal_attention(q, k, v, positions, valid,
+                                              spec.q_per_kv)
+            attn = attn.reshape(b, s, -1)
+        with scope("attn.out"):
+            proj = mm(attn, lp["wo"], "bsd,dh->bsh")
+            if ll is not None:
+                proj = proj + lora_delta(attn, ll["wo"], adapter_ids)
+            x = x + proj
+        with scope("mlp"):
+            h2 = rms_norm(x, lp["post_attn_norm"], spec.rms_norm_eps)
+            x = x + ffn_block(h2, lp, spec, ll, adapter_ids)
         return x, (k, v)
 
     # Cache writes are deferred out of the scan (ys are fresh allocations —
@@ -565,21 +573,23 @@ def prefill_forward(params: Params, spec: ModelSpec,
     x, (k_new, v_new) = jax.lax.scan(layer_fn, x, xs)
     # k_new [L,B,S,Nkv,D] -> page blocks [L,Nkv,B*S/page,page,D]; one
     # in-place scatter per cache covers every layer.
-    L = spec.num_layers
-    nkv = spec.num_kv_heads
-    k_blocks = (k_new.reshape(L, b * (s // page), page, nkv, d)
-                .transpose(0, 3, 1, 2, 4))
-    v_blocks = (v_new.reshape(L, b * (s // page), page, nkv, d)
-                .transpose(0, 3, 1, 2, 4))
-    flat_pages = page_table.reshape(-1)
-    # scatter_pages quantizes int8 pools in the same fused commit.
-    k_cache = scatter_pages(k_cache, k_blocks, flat_pages)
-    v_cache = scatter_pages(v_cache, v_blocks, flat_pages)
-    x = rms_norm(x, params["final_norm"], spec.rms_norm_eps)
-    # Last valid token per sequence.
-    last_idx = jnp.maximum(seq_lens - 1, 0)
-    x_last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
-    logits = lm_logits(x_last, params, spec)
+    with scope("kv.commit"):
+        L = spec.num_layers
+        nkv = spec.num_kv_heads
+        k_blocks = (k_new.reshape(L, b * (s // page), page, nkv, d)
+                    .transpose(0, 3, 1, 2, 4))
+        v_blocks = (v_new.reshape(L, b * (s // page), page, nkv, d)
+                    .transpose(0, 3, 1, 2, 4))
+        flat_pages = page_table.reshape(-1)
+        # scatter_pages quantizes int8 pools in the same fused commit.
+        k_cache = scatter_pages(k_cache, k_blocks, flat_pages)
+        v_cache = scatter_pages(v_cache, v_blocks, flat_pages)
+    with scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], spec.rms_norm_eps)
+        # Last valid token per sequence.
+        last_idx = jnp.maximum(seq_lens - 1, 0)
+        x_last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
+        logits = lm_logits(x_last, params, spec)
     return logits, k_cache, v_cache
 
 
@@ -846,8 +856,10 @@ def decode_window_multi_step(params: Params, spec: ModelSpec,
     page = k_cache.shape[3]
     maxp = page_table.shape[1]
     W = k_buf.shape[3]
-    x = embed_lookup(params["embed"], tokens)          # [B,S,H]
-    cos, sin = rope_tables(positions, d, spec.rope_theta)
+    with scope("embed"):
+        x = embed_lookup(params["embed"], tokens)          # [B,S,H]
+    with scope("attn.qkv"):
+        cos, sin = rope_tables(positions, d, spec.rope_theta)
     scale = 1.0 / jnp.sqrt(jnp.float32(d))
     L = spec.num_layers
 
@@ -856,69 +868,76 @@ def decode_window_multi_step(params: Params, spec: ModelSpec,
             lp, layer, kb_l, vb_l, ll = scan_in        # kb_l [Nkv,B,W,D]
         else:
             (lp, layer, kb_l, vb_l), ll = scan_in, None
-        h = rms_norm(x, lp["input_norm"], spec.rms_norm_eps)
-        q = mm(h, lp["wq"], "bsh,hd->bsd")
-        k = mm(h, lp["wk"], "bsh,hd->bsd")
-        v = mm(h, lp["wv"], "bsh,hd->bsd")
-        if ll is not None:
-            q, k, v = qkv_lora(q, k, v, h, ll, adapter_ids)
-        if spec.qkv_bias:
-            q = q + lp["bq"]
-            k = k + lp["bk"]
-            v = v + lp["bv"]
-        q = _split_heads(q, spec.num_heads, d)         # [B,S,Nh,D]
-        k = _split_heads(k, nkv, d)                    # [B,S,Nkv,D]
-        v = _split_heads(v, nkv, d)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        qg = q.reshape(b, s, nkv, spec.q_per_kv, d)
+        with scope("attn.qkv"):
+            h = rms_norm(x, lp["input_norm"], spec.rms_norm_eps)
+            q = mm(h, lp["wq"], "bsh,hd->bsd")
+            k = mm(h, lp["wk"], "bsh,hd->bsd")
+            v = mm(h, lp["wv"], "bsh,hd->bsd")
+            if ll is not None:
+                q, k, v = qkv_lora(q, k, v, h, ll, adapter_ids)
+            if spec.qkv_bias:
+                q = q + lp["bq"]
+                k = k + lp["bk"]
+                v = v + lp["bv"]
+            q = _split_heads(q, spec.num_heads, d)         # [B,S,Nh,D]
+            k = _split_heads(k, nkv, d)                    # [B,S,Nkv,D]
+            v = _split_heads(v, nkv, d)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+        with scope("attn.core"):
+            qg = q.reshape(b, s, nkv, spec.q_per_kv, d)
         # Paged history: the same layer+head-folded fused gather as the
         # single-token step — the [B,S] verify reads the bucketed page
         # table once per layer into the dot's [Nkv,B,L,D] layout, with
         # no materialized per-position (or per-head-transpose) copies.
-        k_all = gather_pages_folded(k_cache, layer, page_table)
-        v_all = gather_pages_folded(v_cache, layer, page_table)
-        s_hist = jnp.einsum("bsngd,nbld->bnsgl", qg, k_all,
-                            preferred_element_type=jnp.float32) * scale
-        lpos = jnp.arange(maxp * page)[None, :]
-        s_hist = jnp.where(
-            (lpos < hist_lens[:, None])[:, None, None, None, :],
-            s_hist, -1e30)
-        # This window's committed columns (< wlen per slot).
-        s_win = jnp.einsum("bsngd,nbjd->bnsgj", qg, kb_l,
-                           preferred_element_type=jnp.float32) * scale
-        wvalid = (jnp.arange(W)[None, :]
-                  < wlen[:, None])[:, None, None, None, :]
-        s_win = jnp.where(jnp.broadcast_to(wvalid, s_win.shape),
-                          s_win, -1e30)
-        # In-block causal among the S verify tokens.
-        s_blk = jnp.einsum("bsngd,btnd->bnsgt", qg, k,
-                           preferred_element_type=jnp.float32) * scale
-        causal = (jnp.arange(s)[:, None] >= jnp.arange(s)[None, :])
-        s_blk = jnp.where(causal[None, None, :, None, :], s_blk, -1e30)
-        full = jnp.concatenate([s_hist, s_win, s_blk], axis=-1)
-        probs = jax.nn.softmax(full, axis=-1)
-        p_hist = probs[..., :maxp * page].astype(q.dtype)
-        p_win = probs[..., maxp * page:maxp * page + W].astype(q.dtype)
-        p_blk = probs[..., maxp * page + W:].astype(q.dtype)
-        out = (jnp.einsum("bnsgl,nbld->bsngd", p_hist, v_all)
-               + jnp.einsum("bnsgj,nbjd->bsngd", p_win, vb_l)
-               + jnp.einsum("bnsgt,btnd->bsngd", p_blk, v))
-        attn = out.reshape(b, s, -1)
-        proj = mm(attn, lp["wo"], "bsd,dh->bsh")
-        if ll is not None:
-            proj = proj + lora_delta(attn, ll["wo"], adapter_ids)
-        x = x + proj
-        h2 = rms_norm(x, lp["post_attn_norm"], spec.rms_norm_eps)
-        x = x + ffn_block(h2, lp, spec, ll, adapter_ids)
+        with scope("attn.kv_gather"):
+            k_all = gather_pages_folded(k_cache, layer, page_table)
+            v_all = gather_pages_folded(v_cache, layer, page_table)
+        with scope("attn.core"):
+            s_hist = jnp.einsum("bsngd,nbld->bnsgl", qg, k_all,
+                                preferred_element_type=jnp.float32) * scale
+            lpos = jnp.arange(maxp * page)[None, :]
+            s_hist = jnp.where(
+                (lpos < hist_lens[:, None])[:, None, None, None, :],
+                s_hist, -1e30)
+            # This window's committed columns (< wlen per slot).
+            s_win = jnp.einsum("bsngd,nbjd->bnsgj", qg, kb_l,
+                               preferred_element_type=jnp.float32) * scale
+            wvalid = (jnp.arange(W)[None, :]
+                      < wlen[:, None])[:, None, None, None, :]
+            s_win = jnp.where(jnp.broadcast_to(wvalid, s_win.shape),
+                              s_win, -1e30)
+            # In-block causal among the S verify tokens.
+            s_blk = jnp.einsum("bsngd,btnd->bnsgt", qg, k,
+                               preferred_element_type=jnp.float32) * scale
+            causal = (jnp.arange(s)[:, None] >= jnp.arange(s)[None, :])
+            s_blk = jnp.where(causal[None, None, :, None, :], s_blk, -1e30)
+            full = jnp.concatenate([s_hist, s_win, s_blk], axis=-1)
+            probs = jax.nn.softmax(full, axis=-1)
+            p_hist = probs[..., :maxp * page].astype(q.dtype)
+            p_win = probs[..., maxp * page:maxp * page + W].astype(q.dtype)
+            p_blk = probs[..., maxp * page + W:].astype(q.dtype)
+            out = (jnp.einsum("bnsgl,nbld->bsngd", p_hist, v_all)
+                   + jnp.einsum("bnsgj,nbjd->bsngd", p_win, vb_l)
+                   + jnp.einsum("bnsgt,btnd->bsngd", p_blk, v))
+            attn = out.reshape(b, s, -1)
+        with scope("attn.out"):
+            proj = mm(attn, lp["wo"], "bsd,dh->bsh")
+            if ll is not None:
+                proj = proj + lora_delta(attn, ll["wo"], adapter_ids)
+            x = x + proj
+        with scope("mlp"):
+            h2 = rms_norm(x, lp["post_attn_norm"], spec.rms_norm_eps)
+            x = x + ffn_block(h2, lp, spec, ll, adapter_ids)
         return x, (k, v)
 
     xs = ((params["layers"], jnp.arange(L), k_buf, v_buf, lora)
           if lora is not None
           else (params["layers"], jnp.arange(L), k_buf, v_buf))
     x, (k_new, v_new) = jax.lax.scan(layer_fn, x, xs)
-    x = rms_norm(x, params["final_norm"], spec.rms_norm_eps)
-    logits = lm_logits(x.reshape(b * s, -1), params, spec)
+    with scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], spec.rms_norm_eps)
+        logits = lm_logits(x.reshape(b * s, -1), params, spec)
     return logits.reshape(b, s, -1), k_new, v_new
 
 
@@ -990,8 +1009,10 @@ def decode_window_step(params: Params, spec: ModelSpec,
     """
     b = tokens.shape[0]
     d = spec.head_dim
-    x = embed_lookup(params["embed"], tokens)
-    cos, sin = rope_tables(positions, d, spec.rope_theta)
+    with scope("embed"):
+        x = embed_lookup(params["embed"], tokens)
+    with scope("attn.qkv"):
+        cos, sin = rope_tables(positions, d, spec.rope_theta)
     attn_fn = attention_impl or paged_window_attention_xla
     L = spec.num_layers
 
@@ -1000,36 +1021,41 @@ def decode_window_step(params: Params, spec: ModelSpec,
             lp, layer, kb_l, vb_l, ll = scan_in
         else:
             (lp, layer, kb_l, vb_l), ll = scan_in, None
-        h = rms_norm(x, lp["input_norm"], spec.rms_norm_eps)
-        q = mm(h, lp["wq"], "bh,hd->bd")
-        k = mm(h, lp["wk"], "bh,hd->bd")
-        v = mm(h, lp["wv"], "bh,hd->bd")
-        if ll is not None:
-            q, k, v = qkv_lora(q, k, v, h, ll, adapter_ids)
-        if spec.qkv_bias:
-            q = q + lp["bq"]
-            k = k + lp["bk"]
-            v = v + lp["bv"]
-        q = _split_heads(q, spec.num_heads, d)
-        k = _split_heads(k, spec.num_kv_heads, d)
-        v = _split_heads(v, spec.num_kv_heads, d)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        attn = attn_fn(q, k_cache, v_cache, layer, page_table, hist_lens,
-                       kb_l, vb_l, m, k, v, spec.q_per_kv)
-        attn = attn.reshape(b, -1)
-        proj = mm(attn, lp["wo"], "bd,dh->bh")
-        if ll is not None:
-            proj = proj + lora_delta(attn, ll["wo"], adapter_ids)
-        x = x + proj
-        h2 = rms_norm(x, lp["post_attn_norm"], spec.rms_norm_eps)
-        x = x + ffn_block(h2, lp, spec, ll, adapter_ids)
+        with scope("attn.qkv"):
+            h = rms_norm(x, lp["input_norm"], spec.rms_norm_eps)
+            q = mm(h, lp["wq"], "bh,hd->bd")
+            k = mm(h, lp["wk"], "bh,hd->bd")
+            v = mm(h, lp["wv"], "bh,hd->bd")
+            if ll is not None:
+                q, k, v = qkv_lora(q, k, v, h, ll, adapter_ids)
+            if spec.qkv_bias:
+                q = q + lp["bq"]
+                k = k + lp["bk"]
+                v = v + lp["bv"]
+            q = _split_heads(q, spec.num_heads, d)
+            k = _split_heads(k, spec.num_kv_heads, d)
+            v = _split_heads(v, spec.num_kv_heads, d)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+        with scope("attn.core"):
+            attn = attn_fn(q, k_cache, v_cache, layer, page_table, hist_lens,
+                           kb_l, vb_l, m, k, v, spec.q_per_kv)
+            attn = attn.reshape(b, -1)
+        with scope("attn.out"):
+            proj = mm(attn, lp["wo"], "bd,dh->bh")
+            if ll is not None:
+                proj = proj + lora_delta(attn, ll["wo"], adapter_ids)
+            x = x + proj
+        with scope("mlp"):
+            h2 = rms_norm(x, lp["post_attn_norm"], spec.rms_norm_eps)
+            x = x + ffn_block(h2, lp, spec, ll, adapter_ids)
         return x, (k, v)
 
     xs = ((params["layers"], jnp.arange(L), k_buf, v_buf, lora)
           if lora is not None
           else (params["layers"], jnp.arange(L), k_buf, v_buf))
     x, (k_new, v_new) = jax.lax.scan(layer_fn, x, xs)
-    x = rms_norm(x, params["final_norm"], spec.rms_norm_eps)
-    logits = lm_logits(x, params, spec)
+    with scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], spec.rms_norm_eps)
+        logits = lm_logits(x, params, spec)
     return logits, k_new, v_new

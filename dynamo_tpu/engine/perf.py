@@ -29,11 +29,20 @@ This module makes them live series:
   don't cross-flag each other's first compiles; pre-ready compiles are
   never flagged (warmup intentionally double-compiles signatures whose
   input shardings converge after the first run).
-- ``note_window``: the engine feeds one (device-seconds, tokens,
+- ``note_window``: the engine feeds one (window-seconds, tokens,
   active-slots, steps) sample per processed decode window — plain
   float stores on the engine thread, no locks, no allocation — from
   which the registry derives EWMA step seconds, achieved tok/s, and
-  the fraction of the weight-read roofline those tokens achieved.
+  the fraction of the weight-read roofline those tokens achieved. The
+  seconds are the window's PERIOD (readback complete to readback
+  complete) while the pipe is full, so a step is a step of the device
+  and not the latency through ``pipeline_depth`` queued windows.
+- **Scopes** (``SCOPES``, :func:`scope`): the one vocabulary of
+  ``jax.named_scope`` regions inside the decode-window and prefill
+  programs, and ``CompileRegistry.ops_by_scope(program)``: each
+  instruction of the compiled executable mapped to its scope from the
+  executable's own HLO text, so a device trace's ``%fusion.296`` has a
+  name that survives a rewrite of the program.
 - ``PerfMetricsUpdater``: throttled exporter (same discipline as
   engine/kv_metrics.py KvMetricsUpdater) turning the registry's plain
   ints into ``dynamo_tpu_perf_*`` counters/gauges, plus periodic
@@ -41,8 +50,9 @@ This module makes them live series:
 
 This module also owns the persistent XLA compile cache
 (:func:`configure_compile_cache`: ``JAX_COMPILATION_CACHE_DIR`` when set,
-else a fixed ``.jax_cache`` in the checkout), because every program it
-would hold is built through :func:`instrumented_jit`.
+else a fixed ``.jax_cache`` in the checkout; inside it the sub-directory
+of the scope vocabulary's version), because every program it would hold
+is built through :func:`instrumented_jit`.
 
 Env knobs: ``DTPU_PERF_COST`` = ``lower`` (default: cheap unoptimized-
 HLO estimate) | ``compile`` (accurate, pays a second XLA compile per
@@ -52,8 +62,10 @@ program family) | ``off``.
 from __future__ import annotations
 
 import os
+import re
 import threading
 import time
+import weakref
 
 import jax
 
@@ -64,6 +76,116 @@ log = get_logger("perf")
 
 #: EWMA smoothing for the per-window series (0.2 = ~5-window memory).
 _EWMA = 0.2
+
+
+# -- the programs' regions --------------------------------------------------------
+#: Regions of the decode-window and prefill programs (engine/model.py,
+#: engine/runner.py wrap them in ``scope(name)``): the names a device trace
+#: is reduced by (docs/OBSERVABILITY.md "Program scopes"). Metadata only:
+#: a scope changes no HLO instruction and so no device time.
+SCOPES = ("embed", "attn.qkv", "attn.kv_gather", "attn.core", "attn.out",
+          "mlp", "lm_head", "sample", "kv.commit")
+#: Bump when SCOPES or where a scope is drawn changes. jax's persistent
+#: cache key leaves debug info out (jax/_src/cache_key.py strips it), so an
+#: executable cached by a tree with other scopes would be loaded with ITS
+#: names; the version is a sub-directory of the cache directory, and a
+#: change of vocabulary costs one cold start instead.
+SCOPES_VERSION = 1
+
+
+def scope(name: str):
+    """``jax.named_scope`` for one name of SCOPES."""
+    assert name in SCOPES, name
+    return jax.named_scope(name)
+
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.-]+)\s*\(.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT\s+)?%?([\w.-]+)\s*=\s.*?\s([a-z][a-z0-9-]*)\(")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLS = re.compile(r"\bcalls=%?([\w.-]+)")
+_HLO_NAME = re.compile(r"%([\w.-]+)")
+#: Layout changes and transfers the compiler puts in by itself: they carry
+#: no op_name, or the name of a parameter.
+_HLO_MOVES = ("copy", "copy-start", "copy-done")
+
+
+def _scope_of(op_name: str) -> str | None:
+    """The innermost component of an ``op_name`` path that is a scope."""
+    for part in reversed(op_name.split("/")):
+        if part in SCOPES:
+            return part
+    return None
+
+
+def _operands(line: str, opcode_end: int) -> list[str]:
+    """Names inside the parentheses that open at ``opcode_end - 1``."""
+    depth, i = 1, opcode_end
+    while i < len(line) and depth:
+        depth += {"(": 1, ")": -1}.get(line[i], 0)
+        i += 1
+    return ["%" + n for n in _HLO_NAME.findall(line[opcode_end:i])]
+
+
+def scopes_of_hlo(text: str) -> dict[str, str | None]:
+    """``{"%fusion.296": "attn.kv_gather", ...}`` from optimized HLO text:
+    an instruction takes the scope its ``metadata={op_name=...}`` names; a
+    fusion takes the scopes of the instructions of its fused computation,
+    in SCOPES order and joined with ``+`` when more than one; a copy the
+    compiler put in (no scope of its own) takes the scope of what reads
+    it, else of what it reads: the pool's layout changes around the commit
+    scatter are the commit's. None for an instruction in no scope."""
+    own: dict[str, str | None] = {}
+    calls: dict[str, str] = {}
+    members: dict[str, list[str]] = {}
+    reads: dict[str, list[str]] = {}
+    moves: list[str] = []
+    computation = None
+    for line in text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m and computation is not None:
+            name, opcode = "%" + m.group(1), m.group(2)
+            found = _HLO_OP_NAME.search(line)
+            own[name] = _scope_of(found.group(1)) if found else None
+            members[computation].append(name)
+            reads[name] = _operands(line, m.end())
+            if opcode == "fusion":
+                called = _HLO_CALLS.search(line)
+                if called:
+                    calls[name] = called.group(1)
+            elif opcode in _HLO_MOVES and own[name] is None:
+                moves.append(name)
+            continue
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            computation = m.group(1)
+            members[computation] = []
+        elif line.startswith("}"):
+            computation = None
+    out = dict(own)
+    for name, fused in calls.items():
+        inside = {own[i] for i in members.get(fused, ()) if own.get(i)}
+        if inside:
+            out[name] = "+".join(s for s in SCOPES if s in inside)
+    read_by: dict[str, list[str]] = {}
+    for name, operands in reads.items():
+        for operand in operands:
+            read_by.setdefault(operand, []).append(name)
+    for _ in range(2):  # copy -> copy-start -> copy-done chains
+        for name in moves:
+            if out.get(name):
+                continue
+            near = ({out.get(u) for u in read_by.get(name, ())} - {None}
+                    or {out.get(o) for o in reads[name]} - {None})
+            if len(near) == 1:
+                out[name] = near.pop()
+    return out
+
+
+def _abstract(x):
+    if isinstance(x, jax.Array):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
+    return x
 
 
 def _cost_mode() -> str:
@@ -142,12 +264,13 @@ jax.monitoring.register_event_listener(_on_cache_event)
 
 
 def compile_cache_dir() -> str:
-    """The persistent compile cache directory this process uses: what
-    JAX_COMPILATION_CACHE_DIR says when it is set (jax reads that variable
-    itself and no other directory is set in code), else the fixed
-    in-checkout default."""
-    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
-            or DEFAULT_COMPILE_CACHE_DIR)
+    """The persistent compile cache directory this process uses: inside
+    what JAX_COMPILATION_CACHE_DIR says when it is set, else inside the
+    fixed in-checkout default, the sub-directory of the scope vocabulary's
+    version (SCOPES_VERSION says why)."""
+    return os.path.join(os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                        or DEFAULT_COMPILE_CACHE_DIR,
+                        f"scopes-v{SCOPES_VERSION}")
 
 
 def configure_compile_cache() -> str | None:
@@ -159,12 +282,17 @@ def configure_compile_cache() -> str | None:
     (``JAX_ENABLE_COMPILATION_CACHE=false``, as tests/conftest.py does)."""
     if not jax.config.jax_enable_compilation_cache:
         return None
-    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        jax.config.update("jax_compilation_cache_dir",
-                          DEFAULT_COMPILE_CACHE_DIR)
+    path = compile_cache_dir()
+    if jax.config.jax_compilation_cache_dir != path:
+        jax.config.update("jax_compilation_cache_dir", path)
+        # jax opens its cache once, at the first compile: a process that
+        # compiled before this call (a test, a generator of weights) holds
+        # the directory above this one open.
+        from jax.experimental.compilation_cache import compilation_cache
+        compilation_cache.reset_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    return compile_cache_dir()
+    return path
 
 
 def compile_cache_status() -> dict:
@@ -187,7 +315,7 @@ class _InstrumentedJit:
     these in place of the raw jitted function."""
 
     __slots__ = ("_fn", "_registry", "_program", "_key", "_calls",
-                 "_compiles")
+                 "_compiles", "_signature", "_scopes", "__weakref__")
 
     def __init__(self, registry: "CompileRegistry", program: str,
                  fn, key):
@@ -197,10 +325,16 @@ class _InstrumentedJit:
         self._key = key
         self._calls = 0
         self._compiles = 0
+        self._signature = None  # the first call's arguments, as shapes
+        self._scopes = None     # ops_by_scope(), built on demand
 
     def __call__(self, *args, **kwargs):
         n0, s0 = _probe()
         t0 = time.monotonic()
+        if self._signature is None:
+            # Shapes, dtypes and shardings only: the arrays themselves are
+            # donated. Once per wrapper; ops_by_scope() lowers from it.
+            self._signature = jax.tree.map(_abstract, (args, kwargs))
         out = self._fn(*args, **kwargs)
         dt = time.monotonic() - t0
         self._calls += 1
@@ -231,6 +365,24 @@ class _InstrumentedJit:
     def lower(self, *args, **kwargs):
         return self._fn.lower(*args, **kwargs)
 
+    def ops_by_scope(self) -> dict | None:
+        """scopes_of_hlo() of this wrapper's executable; None before the
+        first call. The executable comes from jax's caches (this process
+        compiled or loaded it already), so its names are those of the
+        program that RUNS, also where the persistent cache handed over
+        what another tree had compiled."""
+        if self._scopes is None and self._signature is not None:
+            args, kwargs = self._signature
+            try:
+                text = self._fn.lower(*args, **kwargs).compile().as_text()
+            except Exception:  # noqa: BLE001 — the perf plane never raises
+                # (a program that needs the runner's mesh context to lower)
+                log.exception("ops_by_scope: %s %r does not lower here",
+                              self._program, self._key)
+                return None
+            self._scopes = scopes_of_hlo(text)
+        return self._scopes
+
 
 class CompileRegistry:
     """Process-wide compile observatory + per-window perf accumulator."""
@@ -238,6 +390,7 @@ class CompileRegistry:
     def __init__(self):
         self._lock = threading.Lock()  # compile bookkeeping only (rare)
         self._programs: dict[str, _Program] = {}
+        self._wrappers: dict[str, list] = {}  # program -> weakrefs
         self.warmup_complete = False
         self.warmup_complete_ts = 0.0
         # Per-window series (single engine-thread writer, lock-free).
@@ -250,9 +403,28 @@ class CompileRegistry:
 
     # -- compile observatory ---------------------------------------------------
     def wrap(self, program: str, fn, key=None) -> _InstrumentedJit:
+        wrapper = _InstrumentedJit(self, program, fn, key)
         with self._lock:
             self._programs.setdefault(program, _Program(program))
-        return _InstrumentedJit(self, program, fn, key)
+            refs = self._wrappers.setdefault(program, [])
+            refs[:] = [r for r in refs if r() is not None]
+            refs.append(weakref.ref(wrapper))
+        return wrapper
+
+    def ops_by_scope(self, program: str, key=None) -> dict | None:
+        """Each instruction name of a compiled executable of ``program``
+        (``%fusion.296``) mapped to its scope (scopes_of_hlo). ``key`` is
+        the shape-signature key the caller memoizes under; without it, the
+        wrapper of that program called most often. Built on demand (one
+        lowering, and a compile that jax's caches answer), never in
+        set-up. None when no such wrapper has run."""
+        with self._lock:
+            live = [w for w in (r() for r in self._wrappers.get(program, ()))
+                    if w is not None and w._calls
+                    and (key is None or w._key == key)]
+        if not live:
+            return None
+        return max(live, key=lambda w: w._calls).ops_by_scope()
 
     def note_compile(self, program: str, key, seconds: float,
                      unexpected: bool | None = None) -> None:
@@ -333,16 +505,20 @@ class CompileRegistry:
 
     # -- roofline-attributed window timing ------------------------------------
     def note_window(self, window_s: float, tokens: int, active: int,
-                    steps: int, step_floor_ms: float) -> None:
+                    steps: int, step_floor_ms: float,
+                    latency_s: float) -> None:
         """One processed decode window (ENGINE THREAD: plain stores
-        only). ``window_s`` is dispatch -> readback-complete device
-        time, ``tokens`` the tokens it emitted, ``active`` the
-        dispatched slot rows, ``step_floor_ms`` the shard's weight-read
-        step floor (ModelSpec.weight_read_step_ms)."""
+        only). ``window_s`` is the window's period (its readback
+        complete minus the previous window's) when it was queued behind
+        that window, else dispatch -> readback complete; ``tokens`` the
+        tokens it emitted, ``active`` the dispatched slot rows,
+        ``step_floor_ms`` the shard's weight-read step floor
+        (ModelSpec.weight_read_step_ms). ``window_seconds_total`` keeps
+        summing ``latency_s`` (dispatch -> readback complete)."""
         if window_s <= 0 or steps <= 0:
             return
         self.windows_total += 1
-        self.window_seconds_total += window_s
+        self.window_seconds_total += latency_s
         self.window_tokens_total += tokens
         step_s = window_s / steps
         tok_s = tokens / window_s
@@ -408,6 +584,7 @@ class CompileRegistry:
         """Tests only: drop every program and window sample."""
         with self._lock:
             self._programs.clear()
+            self._wrappers.clear()
         self.warmup_complete = False
         self.warmup_complete_ts = 0.0
         self.windows_total = 0
@@ -472,11 +649,16 @@ class PerfMetricsUpdater:
             "nonzero rate in steady state is a serving-path bug",
             ["program"])
         self.g_step_seconds = registry.gauge(
-            "perf_step_seconds", "EWMA seconds per decode step "
-            "(window device time / window steps)")
+            "perf_step_seconds", "EWMA seconds per decode step (a "
+            "window's period while the pipe is full, else dispatch to "
+            "readback, over its steps)")
         self.g_achieved = registry.gauge(
             "perf_achieved_tok_per_s", "EWMA decode tokens/s over "
-            "dispatched windows (device-time attributed)")
+            "dispatched windows (tokens over the window's period)")
+        self.c_phase_seconds = registry.counter(
+            "engine_phase_seconds_total", "Engine-thread self time by "
+            "loop phase (runtime/tracing.py ENGINE_PHASES); the phases "
+            "add up to the thread's wall time", ["phase"])
         self.g_roofline = registry.gauge(
             "perf_roofline_frac", "EWMA fraction of the shard's "
             "weight-read roofline achieved by decode windows")
@@ -535,6 +717,11 @@ class PerfMetricsUpdater:
                         program=name)
             self._delta(self.c_unexpected, ("u", name), unexpected,
                         program=name)
+        clock = getattr(engine, "phase_clock", None)
+        if clock is not None:
+            for name, seconds in clock.totals().items():
+                self._delta(self.c_phase_seconds, ("ph", name), seconds,
+                            phase=name)
         self.g_step_seconds.set(reg.step_seconds)
         self.g_achieved.set(reg.achieved_tok_s)
         self.g_roofline.set(reg.roofline_frac)

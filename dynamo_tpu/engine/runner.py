@@ -544,37 +544,38 @@ class ModelRunner:
                                and self.config.ring_attention else None),
                     x_embeds=emb, embeds_mask=emb_mask,
                     lora=lora, adapter_ids=adapter_ids)
-            if penalized:
-                freq = jax.lax.bitcast_convert_type(packed[:, 7],
-                                                    jnp.float32)
-                pres = jax.lax.bitcast_convert_type(packed[:, 8],
-                                                    jnp.float32)
-                cf = counts.astype(jnp.float32)
-                logits = (logits - freq[:, None] * cf
-                          - pres[:, None] * (cf > 0))
-            rng, sub = jax.random.split(rng)
-            if seeded:
-                # First generated token lands at position start + n.
-                seed_flag = packed[:, 10] > 0
-                base_keys = jax.vmap(jax.random.key)(packed[:, 9])
-                per_seed = jax.vmap(jax.random.fold_in)(base_keys, start + n)
-                shared = jax.random.split(sub, temp.shape[0])
-                row_keys = jax.random.wrap_key_data(jnp.where(
-                    seed_flag[:, None],
-                    jax.random.key_data(per_seed),
-                    jax.random.key_data(shared)))
-                sampled = sample_tokens_per_row(logits, temp, top_k, top_p,
-                                                row_keys)
-            else:
-                sampled = sample_tokens(logits, temp, top_k, top_p, sub)
-            B = sampled.shape[0]
-            lp, top_v, top_i = jax.lax.cond(
-                jnp.any(packed[:, 6] > 0),
-                lambda _: _logprobs_of(logits, sampled),
-                lambda _: (jnp.zeros((B,), jnp.float32),
-                           jnp.zeros((B, TOP_LOGPROBS), jnp.float32),
-                           jnp.zeros((B, TOP_LOGPROBS), jnp.int32)),
-                None)
+            with perf.scope("sample"):
+                if penalized:
+                    freq = jax.lax.bitcast_convert_type(packed[:, 7],
+                                                        jnp.float32)
+                    pres = jax.lax.bitcast_convert_type(packed[:, 8],
+                                                        jnp.float32)
+                    cf = counts.astype(jnp.float32)
+                    logits = (logits - freq[:, None] * cf
+                              - pres[:, None] * (cf > 0))
+                rng, sub = jax.random.split(rng)
+                if seeded:
+                    # First generated token lands at position start + n.
+                    seed_flag = packed[:, 10] > 0
+                    base_keys = jax.vmap(jax.random.key)(packed[:, 9])
+                    per_seed = jax.vmap(jax.random.fold_in)(base_keys, start + n)
+                    shared = jax.random.split(sub, temp.shape[0])
+                    row_keys = jax.random.wrap_key_data(jnp.where(
+                        seed_flag[:, None],
+                        jax.random.key_data(per_seed),
+                        jax.random.key_data(shared)))
+                    sampled = sample_tokens_per_row(logits, temp, top_k, top_p,
+                                                    row_keys)
+                else:
+                    sampled = sample_tokens(logits, temp, top_k, top_p, sub)
+                B = sampled.shape[0]
+                lp, top_v, top_i = jax.lax.cond(
+                    jnp.any(packed[:, 6] > 0),
+                    lambda _: _logprobs_of(logits, sampled),
+                    lambda _: (jnp.zeros((B,), jnp.float32),
+                               jnp.zeros((B, TOP_LOGPROBS), jnp.float32),
+                               jnp.zeros((B, TOP_LOGPROBS), jnp.int32)),
+                    None)
             return sampled, lp, top_v, top_i, logits, k_cache, v_cache, rng
 
         fn = perf.instrumented_jit("prefill", step, key=key,
@@ -646,8 +647,9 @@ class ModelRunner:
             # through scan ys/carries makes XLA copy it per step (measured:
             # 50 ms/step at a 3 GB pool, vs flat ~1.5 ms this way).
             hist_lens = jnp.maximum(seq_lens0 - 1, 0)
-            kbuf0 = jnp.zeros((L, nkv, B, window, d), k_cache.dtype)
-            vbuf0 = jnp.zeros((L, nkv, B, window, d), v_cache.dtype)
+            with perf.scope("kv.commit"):
+                kbuf0 = jnp.zeros((L, nkv, B, window, d), k_cache.dtype)
+                vbuf0 = jnp.zeros((L, nkv, B, window, d), v_cache.dtype)
 
             want_lp = jnp.any(packed[:, PK_LOGPROB] > 0)
 
@@ -663,52 +665,54 @@ class ModelRunner:
                     attention_impl=self._window_attention_impl,
                     lora=lora, adapter_ids=adapter_ids)
                 # Append this step's K/V ([L,B,Nkv,D] -> window col m).
-                kbuf = jax.lax.dynamic_update_slice(
-                    kbuf, k_new.transpose(0, 2, 1, 3)[:, :, :, None],
-                    (0, 0, 0, m, 0))
-                vbuf = jax.lax.dynamic_update_slice(
-                    vbuf, v_new.transpose(0, 2, 1, 3)[:, :, :, None],
-                    (0, 0, 0, m, 0))
-                if penalized:
-                    # OpenAI penalties over generated tokens (vLLM
-                    # semantics): subtract before temperature/top-k.
-                    cf = cnts.astype(jnp.float32)
-                    logits = (logits - freq_pen[:, None] * cf
-                              - pres_pen[:, None] * (cf > 0))
-                rng, sub = jax.random.split(rng)
-                if seeded:
-                    # The token being sampled lands at positions + 1: fold
-                    # the request seed with that absolute position, so the
-                    # draw depends only on (seed, position, logits).
-                    per_seed = jax.vmap(jax.random.fold_in)(
-                        base_keys, positions + 1)
-                    shared = jax.random.split(sub, temp.shape[0])
-                    row_keys = jax.random.wrap_key_data(jnp.where(
-                        seed_flag[:, None],
-                        jax.random.key_data(per_seed),
-                        jax.random.key_data(shared)))
-                    sampled = sample_tokens_per_row(logits, temp, top_k,
-                                                    top_p, row_keys)
-                else:
-                    sampled = sample_tokens(logits, temp, top_k, top_p, sub)
-                B = sampled.shape[0]
-                if penalized:
-                    # Saturating per-row count bump for this step's token.
-                    b_idx = jnp.arange(B)
-                    cur = cnts[b_idx, sampled]
-                    inc = (live & (cur < 255)).astype(jnp.uint8)
-                    cnts = cnts.at[b_idx, sampled].add(inc)
-                # Logprobs only when some slot asked (lax.cond executes one
-                # branch on TPU: zero cost otherwise).
-                lp, top_v, top_i = jax.lax.cond(
-                    want_lp,
-                    lambda _: _logprobs_of(logits, sampled),
-                    lambda _: (jnp.zeros((B,), jnp.float32),
-                               jnp.zeros((B, TOP_LOGPROBS), jnp.float32),
-                               jnp.zeros((B, TOP_LOGPROBS), jnp.int32)),
-                    None)
-                tokens = jnp.where(live, sampled, tokens)
-                positions = positions + live.astype(jnp.int32)
+                with perf.scope("kv.commit"):
+                    kbuf = jax.lax.dynamic_update_slice(
+                        kbuf, k_new.transpose(0, 2, 1, 3)[:, :, :, None],
+                        (0, 0, 0, m, 0))
+                    vbuf = jax.lax.dynamic_update_slice(
+                        vbuf, v_new.transpose(0, 2, 1, 3)[:, :, :, None],
+                        (0, 0, 0, m, 0))
+                with perf.scope("sample"):
+                    if penalized:
+                        # OpenAI penalties over generated tokens (vLLM
+                        # semantics): subtract before temperature/top-k.
+                        cf = cnts.astype(jnp.float32)
+                        logits = (logits - freq_pen[:, None] * cf
+                                  - pres_pen[:, None] * (cf > 0))
+                    rng, sub = jax.random.split(rng)
+                    if seeded:
+                        # The token being sampled lands at positions + 1: fold
+                        # the request seed with that absolute position, so the
+                        # draw depends only on (seed, position, logits).
+                        per_seed = jax.vmap(jax.random.fold_in)(
+                            base_keys, positions + 1)
+                        shared = jax.random.split(sub, temp.shape[0])
+                        row_keys = jax.random.wrap_key_data(jnp.where(
+                            seed_flag[:, None],
+                            jax.random.key_data(per_seed),
+                            jax.random.key_data(shared)))
+                        sampled = sample_tokens_per_row(logits, temp, top_k,
+                                                        top_p, row_keys)
+                    else:
+                        sampled = sample_tokens(logits, temp, top_k, top_p, sub)
+                    B = sampled.shape[0]
+                    if penalized:
+                        # Saturating per-row count bump for this step's token.
+                        b_idx = jnp.arange(B)
+                        cur = cnts[b_idx, sampled]
+                        inc = (live & (cur < 255)).astype(jnp.uint8)
+                        cnts = cnts.at[b_idx, sampled].add(inc)
+                    # Logprobs only when some slot asked (lax.cond executes one
+                    # branch on TPU: zero cost otherwise).
+                    lp, top_v, top_i = jax.lax.cond(
+                        want_lp,
+                        lambda _: _logprobs_of(logits, sampled),
+                        lambda _: (jnp.zeros((B,), jnp.float32),
+                                   jnp.zeros((B, TOP_LOGPROBS), jnp.float32),
+                                   jnp.zeros((B, TOP_LOGPROBS), jnp.int32)),
+                        None)
+                    tokens = jnp.where(live, sampled, tokens)
+                    positions = positions + live.astype(jnp.int32)
                 return (tokens, positions, kbuf, vbuf, rng, cnts), (
                     sampled, lp, top_v, top_i)
 
@@ -719,23 +723,24 @@ class ModelRunner:
                 jax.lax.scan(step, carry0, jnp.arange(window))
             # Commit the window: scatter every (slot, step) entry into its
             # page. Frozen/inactive entries land on the scratch page 0.
-            m_idx = jnp.arange(window)[:, None]                      # [M,1]
-            adv = jnp.clip(jnp.minimum(m_idx, cap[None, :] - positions0),
-                           0, None)
-            pos_m = positions0[None, :] + adv                        # [M,B]
-            live_m = (seq_lens0[None, :] > 0) & (pos_m < cap[None, :])
-            pidx = jnp.clip(pos_m // page, 0, page_table.shape[1] - 1)
-            dest = jnp.take_along_axis(
-                jnp.broadcast_to(page_table[None], (window, *page_table.shape)),
-                pidx[:, :, None], axis=2)[:, :, 0]                   # [M,B]
-            dest = jnp.where(live_m, dest, 0)
-            off = jnp.where(live_m, pos_m % page, 0)
-            # kbuf [L,Nkv,B,M,D] -> [L,Nkv,M,B,D] matching index arrays.
-            # scatter_tokens quantizes int8 pools inside the same commit.
-            k_cache = scatter_tokens(k_cache, kbuf.transpose(0, 1, 3, 2, 4),
-                                     dest, off)
-            v_cache = scatter_tokens(v_cache, vbuf.transpose(0, 1, 3, 2, 4),
-                                     dest, off)
+            with perf.scope("kv.commit"):
+                m_idx = jnp.arange(window)[:, None]                      # [M,1]
+                adv = jnp.clip(jnp.minimum(m_idx, cap[None, :] - positions0),
+                               0, None)
+                pos_m = positions0[None, :] + adv                        # [M,B]
+                live_m = (seq_lens0[None, :] > 0) & (pos_m < cap[None, :])
+                pidx = jnp.clip(pos_m // page, 0, page_table.shape[1] - 1)
+                dest = jnp.take_along_axis(
+                    jnp.broadcast_to(page_table[None], (window, *page_table.shape)),
+                    pidx[:, :, None], axis=2)[:, :, 0]                   # [M,B]
+                dest = jnp.where(live_m, dest, 0)
+                off = jnp.where(live_m, pos_m % page, 0)
+                # kbuf [L,Nkv,B,M,D] -> [L,Nkv,M,B,D] matching index arrays.
+                # scatter_tokens quantizes int8 pools inside the same commit.
+                k_cache = scatter_tokens(k_cache, kbuf.transpose(0, 1, 3, 2, 4),
+                                         dest, off)
+                v_cache = scatter_tokens(v_cache, vbuf.transpose(0, 1, 3, 2, 4),
+                                         dest, off)
             if penalized:
                 return (toks, lps, top_vs, top_is, tokens, k_cache,
                         v_cache, rng, counts_out)
@@ -1566,12 +1571,14 @@ def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
     nkv = spec.num_kv_heads
     page = k_cache.shape[3]
     L = spec.num_layers
-    x = embed_lookup(params["embed"], tokens)
-    if x_embeds is not None:
-        x = jnp.where(embeds_mask[..., None], x_embeds.astype(x.dtype), x)
-    if sp_shard:
-        x = jax.lax.with_sharding_constraint(x, P(None, "sp", None))
-    cos, sin = rope_tables(positions, d, spec.rope_theta)
+    with perf.scope("embed"):
+        x = embed_lookup(params["embed"], tokens)
+        if x_embeds is not None:
+            x = jnp.where(embeds_mask[..., None], x_embeds.astype(x.dtype), x)
+        if sp_shard:
+            x = jax.lax.with_sharding_constraint(x, P(None, "sp", None))
+    with perf.scope("attn.qkv"):
+        cos, sin = rope_tables(positions, d, spec.rope_theta)
     valid = jnp.arange(s)[None, :] < seq_lens[:, None]
     maxp = hist_table.shape[1]
 
@@ -1580,71 +1587,79 @@ def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
             lp, layer, ll = scan_in
         else:
             (lp, layer), ll = scan_in, None
-        h = rms_norm(x, lp["input_norm"], spec.rms_norm_eps)
-        q = mm(h, lp["wq"], "bsh,hd->bsd")
-        k = mm(h, lp["wk"], "bsh,hd->bsd")
-        v = mm(h, lp["wv"], "bsh,hd->bsd")
-        if ll is not None:
-            from dynamo_tpu.engine.model import qkv_lora
-            q, k, v = qkv_lora(q, k, v, h, ll, adapter_ids)
-        if spec.qkv_bias:
-            q = q + lp["bq"]
-            k = k + lp["bk"]
-            v = v + lp["bv"]
-        q = _split_heads(q, spec.num_heads, d)
-        k = _split_heads(k, nkv, d)
-        v = _split_heads(v, nkv, d)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        with perf.scope("attn.qkv"):
+            h = rms_norm(x, lp["input_norm"], spec.rms_norm_eps)
+            q = mm(h, lp["wq"], "bsh,hd->bsd")
+            k = mm(h, lp["wk"], "bsh,hd->bsd")
+            v = mm(h, lp["wv"], "bsh,hd->bsd")
+            if ll is not None:
+                from dynamo_tpu.engine.model import qkv_lora
+                q, k, v = qkv_lora(q, k, v, h, ll, adapter_ids)
+            if spec.qkv_bias:
+                q = q + lp["bq"]
+                k = k + lp["bk"]
+                v = v + lp["bv"]
+            q = _split_heads(q, spec.num_heads, d)
+            k = _split_heads(k, nkv, d)
+            v = _split_heads(v, nkv, d)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
         # In-chunk causal scores (grouped GQA, no repeat).
-        qg = q.reshape(b, s, nkv, spec.q_per_kv, d)
-        chunk_scores = jnp.einsum("bqngd,bknd->bngqk", qg, k,
-                                  preferred_element_type=jnp.float32)
-        causal = (positions[:, None, None, :, None]
-                  >= positions[:, None, None, None, :])
-        chunk_scores = jnp.where(causal & valid[:, None, None, None, :],
-                                 chunk_scores, -1e30)
+        with perf.scope("attn.core"):
+            qg = q.reshape(b, s, nkv, spec.q_per_kv, d)
+            chunk_scores = jnp.einsum("bqngd,bknd->bngqk", qg, k,
+                                      preferred_element_type=jnp.float32)
+            causal = (positions[:, None, None, :, None]
+                      >= positions[:, None, None, None, :])
+            chunk_scores = jnp.where(causal & valid[:, None, None, None, :],
+                                     chunk_scores, -1e30)
         # History over prior pages: layer+head-folded gather from the
         # stacked cache straight into the dot's [Nkv,B,L,D] layout
         # (hist pages are disjoint from this chunk's pages, whose
         # writes are deferred out of the scan).
         from dynamo_tpu.engine.kv_quant import gather_pages_folded
-        k_hist = gather_pages_folded(k_cache, layer, hist_table)
-        v_hist = gather_pages_folded(v_cache, layer, hist_table)
-        hist_scores = jnp.einsum("bqngd,nbld->bngql", qg, k_hist,
-                                 preferred_element_type=jnp.float32)
-        hist_valid = (jnp.arange(maxp * page)[None, :]
-                      < hist_lens[:, None])[:, None, None, None, :]
-        hist_scores = jnp.where(hist_valid, hist_scores, -1e30)
-        scores = jnp.concatenate([hist_scores, chunk_scores], axis=-1)
-        scores = scores / jnp.sqrt(jnp.float32(d))
-        probs = jax.nn.softmax(scores, axis=-1).astype(jnp.bfloat16)
-        p_hist, p_chunk = jnp.split(probs, [maxp * page], axis=-1)
-        attn = (jnp.einsum("bngql,nbld->bqngd", p_hist, v_hist)
-                + jnp.einsum("bngqk,bknd->bqngd", p_chunk, v))
-        attn = attn.reshape(b, s, -1)
-        proj = mm(attn, lp["wo"], "bsd,dh->bsh")
-        if ll is not None:
-            from dynamo_tpu.engine.model import lora_delta
-            proj = proj + lora_delta(attn, ll["wo"], adapter_ids)
-        x = x + proj
-        h2 = rms_norm(x, lp["post_attn_norm"], spec.rms_norm_eps)
-        x = x + ffn_block(h2, lp, spec, ll, adapter_ids)
+        with perf.scope("attn.kv_gather"):
+            k_hist = gather_pages_folded(k_cache, layer, hist_table)
+            v_hist = gather_pages_folded(v_cache, layer, hist_table)
+        with perf.scope("attn.core"):
+            hist_scores = jnp.einsum("bqngd,nbld->bngql", qg, k_hist,
+                                     preferred_element_type=jnp.float32)
+            hist_valid = (jnp.arange(maxp * page)[None, :]
+                          < hist_lens[:, None])[:, None, None, None, :]
+            hist_scores = jnp.where(hist_valid, hist_scores, -1e30)
+            scores = jnp.concatenate([hist_scores, chunk_scores], axis=-1)
+            scores = scores / jnp.sqrt(jnp.float32(d))
+            probs = jax.nn.softmax(scores, axis=-1).astype(jnp.bfloat16)
+            p_hist, p_chunk = jnp.split(probs, [maxp * page], axis=-1)
+            attn = (jnp.einsum("bngql,nbld->bqngd", p_hist, v_hist)
+                    + jnp.einsum("bngqk,bknd->bqngd", p_chunk, v))
+            attn = attn.reshape(b, s, -1)
+        with perf.scope("attn.out"):
+            proj = mm(attn, lp["wo"], "bsd,dh->bsh")
+            if ll is not None:
+                from dynamo_tpu.engine.model import lora_delta
+                proj = proj + lora_delta(attn, ll["wo"], adapter_ids)
+            x = x + proj
+        with perf.scope("mlp"):
+            h2 = rms_norm(x, lp["post_attn_norm"], spec.rms_norm_eps)
+            x = x + ffn_block(h2, lp, spec, ll, adapter_ids)
         return x, (k, v)
 
     xs = ((params["layers"], jnp.arange(L), lora) if lora is not None
           else (params["layers"], jnp.arange(L)))
     x, (k_new, v_new) = jax.lax.scan(layer_fn, x, xs)
-    k_blocks = (k_new.reshape(L, b * (s // page), page, nkv, d)
-                .transpose(0, 3, 1, 2, 4))
-    v_blocks = (v_new.reshape(L, b * (s // page), page, nkv, d)
-                .transpose(0, 3, 1, 2, 4))
-    flat = page_table.reshape(-1)
-    from dynamo_tpu.engine.kv_quant import scatter_pages
-    k_cache = scatter_pages(k_cache, k_blocks, flat)
-    v_cache = scatter_pages(v_cache, v_blocks, flat)
-    x = rms_norm(x, params["final_norm"], spec.rms_norm_eps)
-    last_idx = jnp.maximum(seq_lens - 1, 0)
-    x_last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
-    logits = lm_logits(x_last, params, spec)
+    with perf.scope("kv.commit"):
+        k_blocks = (k_new.reshape(L, b * (s // page), page, nkv, d)
+                    .transpose(0, 3, 1, 2, 4))
+        v_blocks = (v_new.reshape(L, b * (s // page), page, nkv, d)
+                    .transpose(0, 3, 1, 2, 4))
+        flat = page_table.reshape(-1)
+        from dynamo_tpu.engine.kv_quant import scatter_pages
+        k_cache = scatter_pages(k_cache, k_blocks, flat)
+        v_cache = scatter_pages(v_cache, v_blocks, flat)
+    with perf.scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], spec.rms_norm_eps)
+        last_idx = jnp.maximum(seq_lens - 1, 0)
+        x_last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
+        logits = lm_logits(x_last, params, spec)
     return logits, k_cache, v_cache

@@ -36,7 +36,7 @@ from dynamo_tpu.runtime.logging import (current_trace, get_logger,
                                         parse_traceparent)
 from dynamo_tpu.runtime.overload import (PRIORITY_BATCH, PRIORITY_INTERACTIVE,
                                          AdaptiveLimiter)
-from dynamo_tpu.runtime.tracing import span
+from dynamo_tpu.runtime.tracing import get_recorder, span
 
 log = get_logger("http")
 
@@ -224,13 +224,17 @@ class HttpService:
                     "(positive milliseconds)")
         return priority, deadline_ms, None
 
-    async def _admit(self, request: web.Request, route: str, acct=None):
+    async def _admit(self, request: web.Request, route: str, acct=None,
+                     ctx: Context | None = None):
         """Run the overload-defense admission for one request. Returns
         (permit_ctx, response_headers, error_response): on a shed,
         error_response is the typed 429/503 (+ Retry-After) and the
         caller returns it immediately. ``acct`` (the accounting record)
         picks up tenant/priority/deadline, the admission queue wait, and
-        — on a shed — the limiter's typed reason."""
+        — on a shed — the limiter's typed reason. With ``ctx`` the wait
+        is also the request's ``http.admit_wait`` span: in its trace,
+        beside ``http.request`` (which opens only once a permit is
+        held), so the trace of a request is as long as the request."""
         if acct is not None:
             acct["tenant"] = request.headers.get(TENANT_HEADER)
         null = contextlib.nullcontext()
@@ -247,9 +251,14 @@ class HttpService:
                             http_status=400)
             return null, {}, bad
         t0 = time.monotonic()
+        limit_at_entry = int(self.overload.limit)
+        waiting_at_entry = self.overload.waiting()
+        outcome = "cancelled"  # the caller went away while it queued
         try:
             permit = await self.overload.admit(priority, deadline_ms)
+            outcome = "granted"
         except RateLimitedError as exc:
+            outcome = "shed"
             self._m_requests.inc(route=route, status="429")
             if acct is not None:
                 acct.update(status="shed", http_status=429,
@@ -259,6 +268,7 @@ class HttpService:
                 str(exc), "rate_limited", 429,
                 retry_after_s=self._retry_after(exc))
         except OverloadedError as exc:
+            outcome = "shed"
             self._m_requests.inc(route=route, status="503")
             if acct is not None:
                 acct.update(status="shed", http_status=503,
@@ -266,6 +276,15 @@ class HttpService:
             return null, {}, _error_body(
                 str(exc), "overloaded", 503,
                 retry_after_s=self._retry_after(exc))
+        finally:
+            if ctx is not None and get_recorder().enabled:
+                get_recorder().add(
+                    "http.admit_wait", ctx.trace_id, ctx.parent_span_id,
+                    t0, time.monotonic(),
+                    status="ok" if outcome == "granted" else "error",
+                    attrs={"route": route, "priority": priority,
+                           "limit": limit_at_entry,
+                           "waiting": waiting_at_entry, "outcome": outcome})
         if acct is not None:
             acct["queue_wait_s"] = time.monotonic() - t0
         headers = {}
@@ -395,12 +414,12 @@ class HttpService:
                                    "model_not_found", 404)
             acct = make_account(route, chat_req.model)
             acct["adapter"] = _adapter_of(served)
-            permit, meta_headers, shed = await self._admit(request, route,
-                                                           acct)
-            if shed is not None:
-                return shed
             ctx = self._make_context(request)
             acct["request_id"], acct["trace_id"] = ctx.id, ctx.trace_id
+            permit, meta_headers, shed = await self._admit(request, route,
+                                                           acct, ctx)
+            if shed is not None:
+                return shed
             try:
                 with permit, span("http.request", ctx=ctx, route=route,
                                   model=chat_req.model):
@@ -493,12 +512,12 @@ class HttpService:
                                    "model_not_found", 404)
             acct = make_account(route, comp_req.model)
             acct["adapter"] = _adapter_of(served)
-            permit, meta_headers, shed = await self._admit(request, route,
-                                                           acct)
-            if shed is not None:
-                return shed
             ctx = self._make_context(request)
             acct["request_id"], acct["trace_id"] = ctx.id, ctx.trace_id
+            permit, meta_headers, shed = await self._admit(request, route,
+                                                           acct, ctx)
+            if shed is not None:
+                return shed
             try:
                 with permit, span("http.request", ctx=ctx, route=route,
                                   model=comp_req.model):
@@ -809,12 +828,12 @@ class HttpService:
                 self._m_requests.inc(route=route, status="400")
                 return _error_body(str(exc))
             acct = make_account(route, model)
-            permit, meta_headers, shed = await self._admit(request, route,
-                                                           acct)
-            if shed is not None:
-                return shed
             ctx = self._make_context(request)
             acct["request_id"], acct["trace_id"] = ctx.id, ctx.trace_id
+            permit, meta_headers, shed = await self._admit(request, route,
+                                                           acct, ctx)
+            if shed is not None:
+                return shed
             with permit, span("http.request", ctx=ctx, route=route,
                               model=model):
                 self._apply_brownout(chat_req)
@@ -836,6 +855,7 @@ class HttpService:
                     _response_object(full, model, msg.get("content")),
                     headers=meta_headers)
         except RateLimitedError as exc:
+            outcome = "shed"
             self._m_requests.inc(route=route, status="429")
             if acct is not None:
                 acct.update(status="shed", http_status=429,
@@ -844,6 +864,7 @@ class HttpService:
             return _error_body(str(exc), "rate_limited", 429,
                                retry_after_s=self._retry_after(exc))
         except OverloadedError as exc:
+            outcome = "shed"
             self._m_requests.inc(route=route, status="503")
             if acct is not None:
                 acct.update(status="shed", http_status=503,

@@ -23,7 +23,7 @@ sustained incident produces one bundle, not a disk flood. ``GET/POST
 captures.
 
 Env knobs (read once at import; ``configure()`` overrides):
-``DTPU_FLIGHT_CAPACITY`` (ring slots, default 512, 0 disables),
+``DTPU_FLIGHT_CAPACITY`` (ring slots, default 2048, 0 disables),
 ``DTPU_FLIGHT_DIR`` (bundle directory, default /tmp/dtpu-flight),
 ``DTPU_FLIGHT_STALL_S`` (decode-stall trigger threshold, default 2.0,
 0 disables), ``DTPU_FLIGHT_COOLDOWN_S`` (default 300).
@@ -46,12 +46,25 @@ from dynamo_tpu.runtime.logging import get_logger
 log = get_logger("flight")
 
 # Ring columns, in record() argument order. "tokens" (decode tokens
-# emitted by the window) rides with "dur_s" (dispatch -> readback device
-# time) so the perf plane's roofline attribution is replayable from a
-# frozen ring, not only from live gauges.
+# emitted by the window) rides with "dur_s" (dispatch -> readback: the
+# window's LATENCY THROUGH THE PIPELINE, pipeline_depth windows long when
+# the pipe is full) so the perf plane's attribution is replayable from a
+# frozen ring. The window's own clock: "period_s" (this window's readback
+# complete minus the previous one's when this window was already queued
+# behind it, i.e. a window of the device; 0 when the pipe was not full),
+# "host_s" / "wait_s" / "idle_s" (engine-thread seconds since the previous
+# row: in every phase but the two waits, in engine.readback_wait, in
+# engine.idle; they add up to the time between two rows), "rows" (slot
+# rows the window was dispatched with), "page_bucket" (the page-table
+# width of its program) and "missed" (rows refused while frozen or skipped
+# as idle since the previous row).
 FIELDS = ("t_mono", "dur_s", "active", "waiting", "free_pages",
           "chunk_tokens", "chunks_inflight", "preempts", "brownout",
-          "stall_s", "step", "tokens")
+          "stall_s", "step", "tokens", "period_s", "host_s", "wait_s",
+          "idle_s", "rows", "page_bucket", "missed")
+_INT_FIELDS = ("active", "waiting", "free_pages", "chunk_tokens",
+               "chunks_inflight", "preempts", "brownout", "step", "tokens",
+               "rows", "page_bucket", "missed")
 
 
 def _env_int(name: str, default: int) -> int:
@@ -68,7 +81,7 @@ class FlightRecorder:
     """Fixed-slot ring of per-window records (preallocated numpy
     columns; single engine-thread writer, any-thread readers)."""
 
-    def __init__(self, capacity: int = 512, enabled: bool = True):
+    def __init__(self, capacity: int = 2048, enabled: bool = True):
         self.capacity = max(1, capacity)
         self.enabled = enabled and capacity > 0
         self._cols = {name: np.zeros(self.capacity, np.float64)
@@ -79,6 +92,11 @@ class FlightRecorder:
         # path must retain no fresh objects (asserted by tracemalloc in
         # tests/test_slo.py).
         self._skipped = np.zeros(1, np.int64)
+        # Rows refused (frozen) or skipped (idle) since the last row that
+        # was stored, and the time of the last such row: between() says
+        # how many rows a span lacks.
+        self._missed = np.zeros(1, np.int64)
+        self._missed_t = np.zeros(1, np.float64)
         self.frozen = False
         self.frozen_reason = ""
         self._was_idle = False
@@ -89,18 +107,27 @@ class FlightRecorder:
     def record(self, t_mono: float, dur_s: float, active: int, waiting: int,
                free_pages: int, chunk_tokens: int, chunks_inflight: int,
                preempts: int, brownout: int, stall_s: float,
-               step: int, tokens: int = 0) -> bool:
+               step: int, tokens: int = 0, period_s: float = 0.0,
+               host_s: float = 0.0, wait_s: float = 0.0,
+               idle_s: float = 0.0, rows: int = 0,
+               page_bucket: int = 0) -> bool:
         """One engine-window row. Idle-stable windows (no active slots,
         no waiters, no chunk work — same as the previous call) are
         skipped without touching the ring. Returns False when the row
         was REJECTED (disabled / frozen mid-capture) so the caller
         keeps accumulating its deltas instead of losing them."""
-        if not self.enabled or self.frozen:
+        if not self.enabled:
+            return False
+        if self.frozen:
+            self._missed[0] += 1
+            self._missed_t[0] = t_mono
             return False
         idle = active == 0 and waiting == 0 and chunks_inflight == 0 \
             and chunk_tokens == 0
         if idle and self._was_idle:
             self._skipped[0] += 1
+            self._missed[0] += 1
+            self._missed_t[0] = t_mono
             return True
         self._was_idle = idle
         with self._lock:
@@ -118,6 +145,14 @@ class FlightRecorder:
             cols["stall_s"][i] = stall_s
             cols["step"][i] = step
             cols["tokens"][i] = tokens
+            cols["period_s"][i] = period_s
+            cols["host_s"][i] = host_s
+            cols["wait_s"][i] = wait_s
+            cols["idle_s"][i] = idle_s
+            cols["rows"][i] = rows
+            cols["page_bucket"][i] = page_bucket
+            cols["missed"][i] = self._missed[0]
+            self._missed[0] = 0
             self._idx = (i + 1) % self.capacity
             if self._count < self.capacity:
                 self._count += 1
@@ -145,6 +180,7 @@ class FlightRecorder:
             self._idx = 0
             self._count = 0
             self._skipped[0] = 0
+            self._missed[0] = 0
             self._was_idle = False
 
     def dump(self) -> list[dict]:
@@ -158,12 +194,33 @@ class FlightRecorder:
             for i in order:
                 row = {name: float(col[i])
                        for name, col in self._cols.items()}
-                for name in ("active", "waiting", "free_pages",
-                             "chunk_tokens", "chunks_inflight", "preempts",
-                             "brownout", "step", "tokens"):
+                for name in _INT_FIELDS:
                     row[name] = int(row[name])
                 rows.append(row)
             return rows
+
+    def between(self, t_lo: float, t_hi: float) -> dict:
+        """The rows with ``t_lo <= t_mono <= t_hi``, oldest first, as one
+        numpy array per column, with ``missed``: how many rows of that
+        span the ring does not hold (refused while frozen for a bundle
+        capture, skipped as idle, or overwritten because the ring turned
+        over since ``t_lo``; the last counts as at least one). A reader
+        that finds ``missed`` above zero has a part of the span: it says
+        so and returns nothing, it does not average what is left."""
+        with self._lock:
+            n = self._count
+            start = (self._idx - n) % self.capacity
+            order = (start + np.arange(n)) % self.capacity
+            t = self._cols["t_mono"][order]
+            keep = order[(t >= t_lo) & (t <= t_hi)]
+            cols = {name: col[keep].copy()
+                    for name, col in self._cols.items()}
+            missed = int(cols["missed"].sum())
+            if self._missed[0] and t_lo <= self._missed_t[0] <= t_hi:
+                missed += int(self._missed[0])
+            if n == self.capacity and n and t[0] > t_lo:
+                missed += 1  # the ring turned over inside the span
+        return {"rows": len(keep), "missed": missed, "columns": cols}
 
     @property
     def skipped_idle(self) -> int:
@@ -178,7 +235,7 @@ class FlightRecorder:
 # -- process-global recorder + anomaly capture ---------------------------------
 
 _RECORDER = FlightRecorder(
-    capacity=_env_int("DTPU_FLIGHT_CAPACITY", 512))
+    capacity=_env_int("DTPU_FLIGHT_CAPACITY", 2048))
 
 #: Decode-stall trigger threshold consulted by the engine loop (0
 #: disables the automatic trigger; the manual POST /debug/flight and

@@ -20,9 +20,15 @@ is answerable without a debugger. Pieces:
   (queue wait / prefill / decode / KV transfer) every span-producing
   site also feeds, so SLO dashboards get phase breakdowns, not just
   edge TTFT/ITL.
+- ``PhaseClock`` — the ENGINE THREAD's phases (``ENGINE_PHASES``): each
+  ``with clock.phase(name)`` is a ``jax.profiler.TraceAnnotation`` (on the
+  profiler's clock, beside the device planes, when a profiler session
+  runs; nothing otherwise) and self time in a preallocated accumulator.
+  No ``Span``, no lock, not the span ring.
 - ``capture_profile(...)`` — the on-demand ``jax.profiler`` hook behind
   ``POST /debug/profile``, degrading to a span-recorder dump when JAX
-  profiling is unavailable.
+  profiling is unavailable; its reply carries the engine phases' seconds
+  and the flight rows of the capture.
 
 Threading: spans are recorded from the event loop AND the engine thread;
 the recorder takes a lock per record (one append per span, not per
@@ -39,6 +45,7 @@ import json
 import os
 import threading
 import time
+import weakref
 
 from dynamo_tpu.runtime.logging import (current_trace, generate_span_id,
                                         generate_trace_id, get_logger)
@@ -175,7 +182,7 @@ class SpanRecorder:
         timestamps relative to the earliest span) — drop the payload in
         Perfetto or chrome://tracing."""
         spans = (self.trace(trace_id) if trace_id is not None
-                 else sorted(self._snapshot(), key=lambda s: s.start_mono))
+                 else sorted(self.snapshot()[0], key=lambda s: s.start_mono))
         events = []
         if spans:
             base = min(s.start_mono for s in spans)
@@ -204,7 +211,7 @@ class SpanRecorder:
         """OTLP/JSON-shaped dict (ExportTraceServiceRequest): importable
         by any OTLP-JSON consumer without an OTEL SDK dependency."""
         spans = (self.trace(trace_id) if trace_id is not None
-                 else sorted(self._snapshot(), key=lambda s: s.start_mono))
+                 else sorted(self.snapshot()[0], key=lambda s: s.start_mono))
         status_code = {"ok": 1, "error": 2, "cancelled": 2}
         otlp_spans = []
         for s in spans:
@@ -233,9 +240,16 @@ class SpanRecorder:
             }],
         }]}
 
-    def _snapshot(self) -> list[Span]:
+    def snapshot(self) -> tuple[list[Span], int]:
+        """The ring's spans, oldest first, and how many the ring has
+        evicted so far: a reader that finds ``dropped`` above zero is
+        looking at a part of what was recorded."""
         with self._lock:
-            return list(self._spans)
+            return list(self._spans), self.dropped
+
+    def _snapshot(self) -> list[Span]:
+        # benchmark/run.py reads the ring under this name.
+        return self.snapshot()[0]
 
 
 def _otlp_value(v) -> dict:
@@ -391,6 +405,128 @@ class span:
         return self.__exit__(exc_type, exc, tb)
 
 
+# -- the engine thread's phases -------------------------------------------------
+
+#: What the engine loop does, in loop order. One vocabulary for the trace's
+#: host line, ``engine_phase_seconds_total{phase}``, ``/debug/perf`` and the
+#: flight ring's ``host_s`` / ``wait_s`` (docs/OBSERVABILITY.md "Engine
+#: phases"). ``engine.other`` is loop time in no named phase.
+ENGINE_PHASES = (
+    "engine.jobs", "engine.resolve_first", "engine.kvbm",
+    "engine.retire_chunks", "engine.admit", "engine.dispatch_chunks",
+    "engine.dispatch_window", "engine.readback_wait",
+    "engine.process_window", "engine.publish", "engine.release_pages",
+    "engine.idle", "engine.other")
+_OTHER = ENGINE_PHASES.index("engine.other")
+#: Phases in which the engine thread waits (for the device, or for work):
+#: everything else is host time a window costs.
+WAIT_PHASES = ("engine.readback_wait", "engine.idle")
+_MAX_DEPTH = 8
+
+_CLOCKS: "weakref.WeakSet[PhaseClock]" = weakref.WeakSet()
+
+
+class _Phase:
+    """One phase of one clock as a reusable context manager (made once per
+    clock and name: entering allocates the annotation only)."""
+
+    __slots__ = ("_clock", "_index", "_name")
+
+    def __init__(self, clock: "PhaseClock", index: int):
+        self._clock = clock
+        self._index = index
+        self._name = ENGINE_PHASES[index]
+
+    def __enter__(self) -> None:
+        c = self._clock
+        ann = None
+        trace_me = c._trace_me
+        if trace_me is not None and trace_me.is_enabled():
+            ann = trace_me(self._name)
+            ann.__enter__()
+        d = c._depth
+        c._ann[d] = ann
+        c._outer[d] = c._current
+        c._depth = d + 1
+        now = time.monotonic()
+        c.seconds[c._current] += now - c._t_last
+        c._t_last = now
+        c._current = self._index
+
+    def __exit__(self, *exc) -> bool:
+        c = self._clock
+        now = time.monotonic()
+        c.seconds[c._current] += now - c._t_last
+        c._t_last = now
+        d = c._depth - 1
+        c._depth = d
+        c._current = c._outer[d]
+        ann = c._ann[d]
+        if ann is not None:
+            c._ann[d] = None
+            ann.__exit__(None, None, None)
+        return False
+
+
+class PhaseClock:
+    """Self time of the engine thread by phase. ONE writer (the engine
+    thread): plain stores into preallocated lists, no lock, nothing kept
+    per call; readers tolerate a torn read of a counter. A phase entered
+    inside another suspends the outer one, so the seconds add up to the
+    thread's wall time since ``restart()``."""
+
+    def __init__(self):
+        n = len(ENGINE_PHASES)
+        self.seconds = [0.0] * n
+        self._phases = {name: _Phase(self, i)
+                        for i, name in enumerate(ENGINE_PHASES)}
+        self._outer = [_OTHER] * _MAX_DEPTH
+        self._ann: list = [None] * _MAX_DEPTH
+        self._depth = 0
+        self._current = _OTHER
+        self._t_last = time.monotonic()
+        try:  # a no-op unless a profiler session runs
+            from jax.profiler import TraceAnnotation
+            self._trace_me = TraceAnnotation
+        except Exception:  # noqa: BLE001 — no jax here: counters only
+            self._trace_me = None
+        _CLOCKS.add(self)
+
+    def restart(self) -> None:
+        """Count from now (the engine loop calls this as it starts: what
+        the thread did before is warm-up, not a phase)."""
+        self._t_last = time.monotonic()
+
+    def phase(self, name: str) -> _Phase:
+        return self._phases[name]
+
+    def sync(self, now: float) -> None:
+        """Credit the running phase up to ``now`` (ENGINE THREAD)."""
+        self.seconds[self._current] += now - self._t_last
+        self._t_last = now
+
+    def waited(self) -> tuple[float, float]:
+        """(seconds in engine.readback_wait, seconds in engine.idle)."""
+        return (self.seconds[_WAIT_INDEX[0]], self.seconds[_WAIT_INDEX[1]])
+
+    def total(self) -> float:
+        return sum(self.seconds)
+
+    def totals(self) -> dict[str, float]:
+        return dict(zip(ENGINE_PHASES, self.seconds))
+
+
+_WAIT_INDEX = tuple(ENGINE_PHASES.index(n) for n in WAIT_PHASES)
+
+def engine_phase_totals() -> dict[str, float]:
+    """Seconds by engine phase, summed over this process's engines."""
+    out = dict.fromkeys(ENGINE_PHASES, 0.0)
+    for clock in list(_CLOCKS):
+        for name, seconds in clock.totals().items():
+            out[name] += seconds
+    return out
+
+
 # -- per-phase latency histograms ----------------------------------------------
 
 _LATENCY_BUCKETS = (.0005, .001, .0025, .005, .01, .025, .05, .1, .25, .5,
@@ -415,7 +551,9 @@ class PhaseMetrics:
             buckets=_LATENCY_BUCKETS)
         self.decode = registry.histogram(
             "decode_step_seconds",
-            "Decode window dispatch to host processing",
+            "One decode window of the device: readback complete to "
+            "readback complete while the pipe is full, dispatch to "
+            "readback otherwise",
             buckets=_LATENCY_BUCKETS)
         self.kv_transfer = registry.histogram(
             "kv_transfer_seconds",
@@ -485,17 +623,23 @@ async def capture_profile(duration_ms: int, out_dir: str,
 
     Preferred mode: a ``jax.profiler`` trace (TensorBoard/Perfetto
     loadable) covering device programs — one curl away from a TPU
-    hot-path investigation. When JAX profiling is unavailable (CPU-only
-    builds, profiler already claimed), degrades to dumping the span
-    recorder's current contents as Chrome trace JSON so the capture is
-    never empty-handed.
+    hot-path investigation. The engine thread's phases are annotations
+    on its line of that trace, on the device planes' clock
+    (``python3 -m benchmark.lib.host_phases <xplane.pb>`` reduces both).
+    When JAX profiling is unavailable (CPU-only builds, profiler already
+    claimed), degrades to dumping the span recorder's current contents
+    as Chrome trace JSON so the capture is never empty-handed. Either
+    way the reply carries the engine phases' seconds over the capture
+    and the flight rows (one per decode window) recorded during it.
     """
     duration_ms = max(1, min(int(duration_ms), 60_000))
     os.makedirs(out_dir, exist_ok=True)
     if not _profile_lock.acquire(blocking=False):
         raise RuntimeError("a profile capture is already running")
     try:
+        from dynamo_tpu.runtime import flight
         started = time.monotonic()
+        phases0 = engine_phase_totals()
         mode = "jax"
         try:
             import jax
@@ -520,9 +664,19 @@ async def capture_profile(duration_ms: int, out_dir: str,
                 json.dump(rec.export_chrome(), fh)
 
         await asyncio.to_thread(_dump)
+        ended = time.monotonic()
+        phases1 = engine_phase_totals()
+        windows = flight.get_recorder().between(started, ended)
         return {"mode": mode, "out_dir": out_dir,
                 "span_dump": span_path,
                 "duration_ms": duration_ms,
-                "wall_s": round(time.monotonic() - started, 3)}
+                "wall_s": round(ended - started, 3),
+                "engine_phase_seconds": {
+                    name: round(phases1[name] - phases0[name], 6)
+                    for name in ENGINE_PHASES},
+                "flight": {"rows": windows["rows"],
+                           "missed": windows["missed"],
+                           "columns": {k: v.tolist() for k, v
+                                       in windows["columns"].items()}}}
     finally:
         _profile_lock.release()

@@ -18,6 +18,7 @@ interleaved with decode windows:
 """
 
 import asyncio
+import time
 
 import numpy as np
 import pytest
@@ -174,6 +175,11 @@ async def test_decode_progresses_during_chunked_prefill(chunked_engine):
 
     def win(packed, window):
         events.append(("window", None))
+        # A window of a real device takes time. On the CPU the tiny model's
+        # eight windows take 0.3 ms each, so on a loaded machine the decoder
+        # could be through all 64 tokens before this test's own coroutine
+        # was scheduled to send the long prompt (seen at the parent too).
+        time.sleep(0.002)
         return orig_win(packed, window)
 
     def chunk(seq):

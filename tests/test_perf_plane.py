@@ -158,19 +158,19 @@ def test_note_window_derives_roofline_gauges():
     # 8 steps x 8 active rows in 8 ms against a 1 ms step floor:
     # achieved = 8000 tok/s, roofline = 8 / 1ms = 8000 -> frac 1.0.
     reg.note_window(window_s=0.008, tokens=64, active=8, steps=8,
-                    step_floor_ms=1.0)
+                    step_floor_ms=1.0, latency_s=0.008)
     assert reg.step_seconds == pytest.approx(0.001)
     assert reg.achieved_tok_s == pytest.approx(8000.0)
     assert reg.roofline_frac == pytest.approx(1.0)
     # Half the tokens at the same device time: frac EWMAs down.
     reg.note_window(window_s=0.008, tokens=32, active=8, steps=8,
-                    step_floor_ms=1.0)
+                    step_floor_ms=1.0, latency_s=0.008)
     assert 0.5 < reg.roofline_frac < 1.0
     w = reg.window_snapshot()
     assert w["windows_total"] == 2
     assert w["window_tokens_total"] == 96
     # Degenerate inputs never divide by zero.
-    reg.note_window(0.0, 0, 0, 0, 1.0)
+    reg.note_window(0.0, 0, 0, 0, 1.0, 0.0)
     assert reg.window_snapshot()["windows_total"] == 2
 
 
@@ -195,7 +195,7 @@ def test_perf_metrics_updater_exports_deltas_and_gauges(monkeypatch):
     up = PerfMetricsUpdater(metrics, min_interval_s=0.0)
     reg.note_compile("decode_window", (8,), 2.0)
     reg.note_compile("decode_window", (8,), 1.0)  # unexpected
-    reg.note_window(0.01, 32, 4, 8, 1.0)
+    reg.note_window(0.01, 32, 4, 8, 1.0, 0.01)
     eng = _FakeEngine({"bytes_in_use": 100, "peak_bytes_in_use": 150,
                        "bytes_limit": 200})
     up.update(eng, force=True)

@@ -58,21 +58,26 @@ def test_chip_smoke_refuses_without_a_tpu():
 
 
 def test_compile_cache_dir_resolution(monkeypatch, tmp_path):
+    """Inside the placed (or the fixed in-checkout) directory, the
+    sub-directory of the scope vocabulary's version: an executable cached
+    by a tree with other scopes would be loaded with that tree's names."""
+    sub = f"scopes-v{perf.SCOPES_VERSION}"
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
-    assert perf.compile_cache_dir() == str(tmp_path)
+    assert perf.compile_cache_dir() == str(tmp_path / sub)
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     fixed = perf.compile_cache_dir()
-    assert fixed == os.path.join(REPO, ".jax_cache")
+    assert fixed == os.path.join(REPO, ".jax_cache", sub)
     assert fixed == perf.compile_cache_dir()  # no pid, no timestamp
 
 
-def test_configure_compile_cache_keeps_the_placed_directory():
+def test_configure_compile_cache_stays_inside_the_placed_directory():
     """With the variable set (conftest sets the tests' own directory) the
-    program sets no other directory; it only drops the thresholds."""
+    cache stays inside that directory; the thresholds drop to zero."""
     import jax
     placed = os.environ["JAX_COMPILATION_CACHE_DIR"]
-    assert perf.configure_compile_cache() == placed
-    assert jax.config.jax_compilation_cache_dir == placed
+    got = perf.configure_compile_cache()
+    assert os.path.dirname(got) == placed
+    assert jax.config.jax_compilation_cache_dir == got
     assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
 
 
