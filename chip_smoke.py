@@ -15,8 +15,10 @@ program's init seed), and checks what comes out by the repo's own means:
   reference  served greedy logprobs vs the plain forward the tests use
              (prefill_forward/decode_forward), teacher-forced, same weights
   kernels    attention_backend="pallas" (bf16 and int8 KV) vs XLA on engines
-             with a small chunk size, so scheduled chunked prefill runs too;
-             ``tpu_custom_call`` must be in the compiled window program
+             with a small chunk size, so scheduled chunked prefill runs too,
+             then "auto" on a small head_dim-128 model, which must resolve
+             to the kernel on the chip; ``tpu_custom_call`` must be in the
+             compiled window program wherever the kernel runs
   disagg     prefill engine -> KV plane -> decode engine on the one chip;
              tokens must equal the aggregated engine's
 
@@ -523,30 +525,46 @@ def small_config(args, spec, **kw):
 
 
 async def phase_kernels(args, jax, rng, keep: dict):
-    """The Pallas decode kernel, since a user can select it: bf16 and int8 KV
-    against the XLA attention on the same prompts at mixed lengths. Returns
-    the bf16 XLA engine (the disagg phase's aggregated reference)."""
+    """The Pallas decode kernel: explicit "pallas" against explicit "xla"
+    with bf16 and int8 KV on the served model (head_dim 64: the packed
+    variant, which "auto" does not select), then what "auto" resolves to on
+    a small head_dim-128 model (the kernel on one TPU device, XLA on the
+    CPU rehearsal) against "xla" on the same weights. Same prompts at mixed
+    lengths per round. Returns the served model's bf16 XLA engine (the
+    disagg phase's aggregated reference)."""
     import jax.numpy as jnp
     import numpy as np
 
+    from dynamo_tpu.engine.config import ModelSpec
     from dynamo_tpu.engine.engine import TPUEngine
     from dynamo_tpu.engine.runner import PK_PREFIX
     spec, params = keep["spec"], keep["params"]
+    wide = ModelSpec(name="smoke-d128", vocab_size=2048, hidden_size=512,
+                     intermediate_size=1024, num_layers=2, num_heads=4,
+                     num_kv_heads=2)  # head_dim 128
     lengths = (20, 70, 150) if args.rehearse_cpu else (24, 200, 700)
-    prompts = [rng.integers(2, spec.vocab_size, size=n).tolist()
-               for n in lengths]
     n_out = 20
     on_tpu = jax.devices()[0].platform == "tpu"
     agg = None
-    for quant_kv in (None, "int8"):
+    for spec_r, params_r, quant_kv, backends in (
+            (spec, params, None, ("xla", "pallas")),
+            (spec, params, "int8", ("xla", "pallas")),
+            (wide, None, None, ("xla", "auto"))):
+        prompts = [rng.integers(2, spec_r.vocab_size, size=n).tolist()
+                   for n in lengths]
         runs = {}
-        for backend in ("xla", "pallas"):
-            eng = TPUEngine(small_config(args, spec, quant_kv=quant_kv,
+        for backend in backends:
+            eng = TPUEngine(small_config(args, spec_r, quant_kv=quant_kv,
                                          attention_backend=backend),
-                            params=params)
-            check(eng.runner.attention_backend == backend,
-                  f"asked for {backend}, runner resolved "
-                  f"{eng.runner.attention_backend}")
+                            params=params_r)
+            params_r = eng.runner.params  # the round's engines share weights
+            # What ModelRunner._pick_attention decides "auto" from.
+            want = (backend if backend != "auto" else "pallas"
+                    if on_tpu and spec_r.head_dim == 128 else "xla")
+            resolved = eng.runner.attention_backend
+            check(resolved == want,
+                  f"asked for {backend}, expected {want}, runner resolved "
+                  f"{resolved}")
             t0 = time.monotonic()
             runs[backend] = await asyncio.gather(
                 *[engine_generate(eng, p, n_out) for p in prompts])
@@ -556,7 +574,7 @@ async def phase_kernels(args, jax, rng, keep: dict):
             chunks = eng.chunk_tokens_total
             check(chunks > 0, "the longest prompt was not chunk-prefilled")
             custom_call = None
-            if backend == "pallas":
+            if resolved == "pallas":
                 # The window program that just served: is the kernel in it?
                 runner = eng.runner
                 key = next(k for k in runner._window_cache
@@ -575,26 +593,28 @@ async def phase_kernels(args, jax, rng, keep: dict):
                 check(custom_call == on_tpu,
                       f"tpu_custom_call in the window program: "
                       f"{custom_call} on {jax.devices()[0].platform}")
-            emit("kernels.run", quant_kv=quant_kv or "bf16",
-                 attention_backend=backend, prompt_lengths=lengths,
+            emit("kernels.run", model=spec_r.name,
+                 quant_kv=quant_kv or "bf16", attention_backend=backend,
+                 resolved=resolved, prompt_lengths=lengths,
                  chunk_tokens=chunks, seconds=round(seconds, 2),
                  tpu_custom_call=custom_call)
-            if backend == "xla" and quant_kv is None:
+            if backend == "xla" and spec_r is spec and quant_kv is None:
                 agg = eng
             else:
                 eng.stop()
         compared, worst = 0, 0.0
-        for (tx, lx), (tp, lp) in zip(runs["xla"], runs["pallas"]):
+        for (tx, lx), (tp, lp) in zip(runs["xla"], runs[backends[1]]):
             n, diff = agreeing_prefix_diff(tx, lx, tp, lp)
             compared += n
             worst = max(worst, diff)
-        emit("kernels.compare", quant_kv=quant_kv or "bf16",
+        emit("kernels.compare", model=spec_r.name,
+             quant_kv=quant_kv or "bf16", against=backends[1],
              tokens_compared=compared, of=n_out * len(prompts),
              max_abs_logprob_diff=round(worst, 5), tolerance=LOGPROB_ATOL)
         check(compared >= len(prompts) * 2, "runs parted at once")
         check(worst <= LOGPROB_ATOL,
-              f"pallas vs xla ({quant_kv or 'bf16'} KV) logprobs differ by "
-              f"{worst:.4f} nats")
+              f"{backends[1]} vs xla ({spec_r.name}, {quant_kv or 'bf16'} "
+              f"KV) logprobs differ by {worst:.4f} nats")
     return agg
 
 

@@ -101,7 +101,12 @@ def parse_args(argv=None) -> argparse.Namespace:
                              "the first long prompt never pays per-bucket "
                              "XLA compiles while decode slots wait")
     parser.add_argument("--attention-backend", default="auto",
-                        choices=["auto", "pallas", "xla"])
+                        choices=["auto", "pallas", "xla"],
+                        help="decode attention: 'auto' runs the Pallas "
+                             "paged kernel on one TPU device at head_dim "
+                             "128 and the XLA gather everywhere else (CPU, "
+                             "a mesh, smaller heads); an explicit "
+                             "'pallas' that cannot be had is an error")
     parser.add_argument("--quant", default=None, choices=["int8"],
                         help="weight-only quantization: int8 storage, "
                              "bf16 MXU compute (halves weight HBM — fits "

@@ -1,12 +1,22 @@
 """Pallas TPU paged-attention decode kernel.
 
 The hot op of the decode step (the role block_copy.cu + engine attention
-kernels play on the reference's GPUs). One grid program per (sequence,
-kv-head): it walks the sequence's page table (scalar-prefetched into SMEM),
-DMAs K/V pages HBM->VMEM in double-buffered chunks of PAGES_PER_CHUNK pages,
-and accumulates flash-style online softmax for the q_per_kv grouped query
-heads. Only live pages are read — unlike the XLA gather fallback
-(model.paged_decode_attention_xla) which touches max_len for every sequence.
+kernels play on the reference's GPUs), and what attention_backend="auto"
+runs on one TPU device at head_dim 128. One grid program per sequence: it
+walks the sequence's page table (scalar-prefetched into SMEM), DMAs the
+live K/V pages HBM->VMEM in double-buffered chunks (pages_per_chunk pages,
+one strided copy per page across all KV heads), and accumulates
+flash-style online softmax for the q_per_kv grouped query heads of every
+KV head, bf16 operands into float32 scores, statistics and accumulator. The chunk
+pipeline runs on from one sequence's program into the next, and a slot
+without history fetches nothing. Only live pages of live rows are read —
+unlike the XLA gather (model.paged_window_attention_xla), which
+materializes the page-table bucket of the longest row for every slot.
+
+Measured on one v5e (PERF.md section 6, PR 26), attention of one decode
+step of Qwen2.5-7B, 17 live rows of 32 at about 950 tokens: the gather
+21.4 ms, this kernel 3 ms (as it stood before that PR, a program per
+(row, head), 8-page chunks, float32 dots: 9.0 ms).
 
 Lane packing: Mosaic DMAs want the trailing dim = 128 lanes, but head_dim 64
 models (qwen2.5-0.5b etc.) have 64-wide K/V rows. The kernel therefore views
@@ -25,6 +35,14 @@ tpr=2 consecutive tokens — and runs the flash accumulation in packed space:
 
 For D >= 128 this degenerates (tpr=1) to the natural unpacked layout with
 the same merge doing only the final normalization.
+
+What the packing costs on the chip (PERF.md, PR 26): the [page, 64] ->
+[page/2, 128] view is free in row-major memory and NOT on a TPU, where the
+pool rests lane-padded; XLA copies the whole pool into the packed layout
+around the kernel, once per layer (qwen2.5-0.5b: 247 ms a decode step
+against 8.5 through the gather). So the packed variant is correct, is
+compiled and compared on the chip by chip_smoke.py, and is not what "auto"
+selects; it waits for a lane-dense pool (ROADMAP D3).
 """
 
 from __future__ import annotations
@@ -38,37 +56,40 @@ from jax.experimental.pallas import tpu as pltpu
 
 from dynamo_tpu.engine.kv_quant import QuantKV
 
-PAGES_PER_CHUNK = 8  # tokens per chunk = 8 * page_size (128 for 16-tok pages)
+#: K (and as much V) one chunk holds across all KV heads of its pages. The
+#: chunk is what one loop turn fetches, waits for and multiplies: large
+#: enough that the turn's fixed costs (loop, semaphore waits, a flash
+#: update per head) are paid once per few hundred tokens, small enough
+#: that two slots of K and of V stay a few MB of VMEM at any head count.
+CHUNK_BYTES = 512 * 1024
+MIN_PAGES_PER_CHUNK = 8    # 128 tokens of 16-token pages: one lane tile
+MAX_PAGES_PER_CHUNK = 64
 NEG_INF = -1e30
 
 
-class _ChunkCopy:
-    """Async copy of PAGES_PER_CHUNK K/V pages for one (layer, head, chunk)
-    into a VMEM slot (idiom after the stock multi-page copy descriptor)."""
-
-    def __init__(self, hbm_ref, buf, sem, layer, page_table_ref, b, h, chunk,
-                 max_pages):
-        self._copies = []
-        for j in range(PAGES_PER_CHUNK):
-            idx = jnp.minimum(chunk * PAGES_PER_CHUNK + j, max_pages - 1)
-            pid = page_table_ref[b, idx]
-            self._copies.append(pltpu.make_async_copy(
-                hbm_ref.at[layer].at[h].at[pid], buf.at[j], sem))
-
-    def start(self):
-        for c in self._copies:
-            c.start()
-
-    def wait(self):
-        for c in self._copies:
-            c.wait()
+def pages_per_chunk(page_size: int, nkv: int, d: int, itemsize: int) -> int:
+    """Pages one chunk holds: the power of two that fills CHUNK_BYTES with
+    the pages' K across all heads, within [MIN, MAX]_PAGES_PER_CHUNK
+    (32 at Qwen2.5-7B's 4 x 128 bf16, 16 at Llama-3-8B's 8 x 128, 64 for
+    small or int8 pages)."""
+    fit = CHUNK_BYTES // (nkv * page_size * d * itemsize)
+    ppc = MIN_PAGES_PER_CHUNK
+    while ppc * 2 <= min(fit, MAX_PAGES_PER_CHUNK):
+        ppc *= 2
+    return ppc
 
 
 def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
                    q_ref, k_hbm, v_hbm,  # q2 VMEM block; k/v packed (ANY)
                    *rest,  # [ks_ref, vs_ref if quantized], outputs, scratch
-                   page_size: int, max_pages: int, tpr: int, qpk: int,
+                   page_size: int, tpr: int, qpk: int,
                    quantized: bool = False):
+    """One grid program per batch row, all KV heads inside it. The K/V
+    fetch is ONE pipeline across the whole grid: chunk g (counted over the
+    live rows' chunks in row order) lands in slot g % 2, and while chunk g
+    is multiplied chunk g+1 is in flight, be it this row's next chunk or
+    the next live row's first. A row with no history costs an empty grid
+    step; only live pages are ever copied."""
     if quantized:
         # int8 pages; the per-token f32 scales arrive as a VMEM block
         # already laid out per chunk in score space ([chunks, tpr, rows],
@@ -76,92 +97,166 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
         # below — no bf16 copy of the history is ever materialized and
         # the kernel never reshapes a scale vector (Mosaic refuses the
         # [pages, page] -> [rows, tpr] shape cast).
-        ks_ref, vs_ref, acc_ref, m_ref, l_ref, k_buf, v_buf, sems = rest
+        (ks_ref, vs_ref, acc_ref, m_ref, l_ref,
+         k_buf, v_buf, sems, g_ref) = rest
     else:
-        acc_ref, m_ref, l_ref, k_buf, v_buf, sems = rest
+        acc_ref, m_ref, l_ref, k_buf, v_buf, sems, g_ref = rest
+    _, nkv, ppc, _, _ = k_buf.shape  # [slot, Nkv, pages, rows/page, 128]
     b = pl.program_id(0)
-    h = pl.program_id(1)
+    nb = pl.num_programs(0)
     layer = layer_ref[0]
     seq_len = seq_lens_ref[b]
-    chunk_tokens = PAGES_PER_CHUNK * page_size
+    chunk_tokens = ppc * page_size
     rows = chunk_tokens // tpr  # packed rows per chunk
-    num_chunks = jnp.maximum(1, pl.cdiv(seq_len, chunk_tokens))
-
+    num_chunks = pl.cdiv(seq_len, chunk_tokens)
     n = tpr * qpk
-    q2 = q_ref[0, 0].astype(jnp.float32)  # [n, 128]
     d = 128 // tpr
     scale = 1.0 / (d ** 0.5)
 
-    def make_copies(c, slot):
-        return [
-            _ChunkCopy(k_hbm, k_buf.at[slot], sems.at[0, slot], layer,
-                       page_table_ref, b, h, c, max_pages),
-            _ChunkCopy(v_hbm, v_buf.at[slot], sems.at[1, slot], layer,
-                       page_table_ref, b, h, c, max_pages)]
+    @pl.when(b == 0)
+    def _():
+        # Chunks fetched so far; and finite K/V under the masked columns
+        # of a chunk's unfetched tail (0 * stale NaN would poison acc).
+        g_ref[0] = 0
+        for slot in range(2):
+            for h in range(nkv):
+                k_buf[slot, h] = jnp.zeros(k_buf.shape[2:], k_buf.dtype)
+                v_buf[slot, h] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
 
-    for cp in make_copies(0, 0):
-        cp.start()
+    pools = ((k_hbm, k_buf), (v_hbm, v_buf))
+
+    def live_pages(row, chunk):
+        """How many of the chunk's ppc pages hold tokens of this row."""
+        return jnp.minimum(
+            ppc, pl.cdiv(seq_lens_ref[row], page_size) - chunk * ppc)
+
+    def start_fetch(row, chunk, slot):
+        """Per live page of the chunk ONE strided copy of its [Nkv, page] K
+        rows and one of V, all on the slot's two semaphores."""
+        def one(j, carry):
+            pid = page_table_ref[row, chunk * ppc + j]
+            for s, (hbm, buf) in enumerate(pools):
+                pltpu.make_async_copy(hbm.at[layer, :, pid],
+                                      buf.at[slot, :, j],
+                                      sems.at[s, slot]).start()
+            return carry
+
+        jax.lax.fori_loop(0, live_pages(row, chunk), one, 0)
+
+    def wait_fetch(row, chunk, slot):
+        """A DMA semaphore counts bytes, so the page copies of a slot are
+        awaited in powers of two of pages (a full chunk: one wait each
+        for K and V) instead of page by page; only a wait's shape and
+        semaphore matter, not where its descriptor points."""
+        count = live_pages(row, chunk)
+        bit = ppc
+        while bit:
+            @pl.when((count & bit) != 0)
+            def _(bit=bit):
+                for s, (hbm, buf) in enumerate(pools):
+                    pltpu.make_async_copy(hbm.at[layer, :, pl.ds(0, bit)],
+                                          buf.at[slot, :, pl.ds(0, bit)],
+                                          sems.at[s, slot]).wait()
+            bit //= 2
 
     # token index of (row-group t, packed row r) is chunk_start + r*tpr + t
     # where t = sublane // qpk.
     group = jax.lax.broadcasted_iota(jnp.int32, (n, rows), 0) // qpk
     row = jax.lax.broadcasted_iota(jnp.int32, (n, rows), 1)
 
-    def score_scales(s_ref, c):
+    def score_scales(s_ref, h, c):
         # Chunk c's scales [tpr, rows] -> [n, rows]: score row t*qpk+i,
         # column r belongs to token r*tpr+t, whose scale is s[t, r]. A
         # sublane broadcast per group; nothing crosses lanes.
-        s = s_ref[0, 0, c]
+        s = s_ref[0, h, c]
         out = jnp.broadcast_to(s[0:1, :], (n, rows))
         for t in range(1, tpr):
             out = jnp.where(group == t,
                             jnp.broadcast_to(s[t:t + 1, :], (n, rows)), out)
         return out
 
-    def body(c, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(c, 2)
+    def pages_of(buf, slot, h, dtype):
+        x = buf[slot, h]  # [ppc, rows_per_page, 128]
+        if x.dtype != dtype:
+            # int8 pages (every int8 is a bf16): through f32, the one
+            # integer conversion every TPU generation's VPU has.
+            x = x.astype(jnp.float32).astype(dtype)
+        return x.reshape(rows, 128)
 
-        @pl.when(c + 1 < num_chunks)
+    def body(c, carry, g0, nxt):
+        g = g0 + c
+        slot = jax.lax.rem(g, 2)
+        more = c + 1 < num_chunks
+
+        @pl.when(more | (nxt < nb))
         def _():
-            for cp in make_copies(c + 1, jax.lax.rem(c + 1, 2)):
-                cp.start()
+            start_fetch(jnp.where(more, b, nxt), jnp.where(more, c + 1, 0),
+                        1 - slot)
 
-        for cp in make_copies(c, slot):
-            cp.wait()
-        k2 = k_buf[slot].astype(jnp.float32).reshape(rows, 128)
-        v2 = v_buf[slot].astype(jnp.float32).reshape(rows, 128)
-        scores = jax.lax.dot_general(
-            q2, k2, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [n, rows]
-        if quantized:
-            # q . (k_int8 * s) == (q . k_int8) * s: dequantize the scores.
-            scores = scores * score_scales(ks_ref, c)
+        wait_fetch(b, c, slot)
         token_idx = c * chunk_tokens + row * tpr + group
-        scores = jnp.where(token_idx < seq_len, scores, NEG_INF)
-        # Per-row online softmax (groups merged outside the kernel).
-        m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
-        p = jnp.exp(scores - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        if quantized:
-            # p . (v_int8 * s) == (p * s) . v_int8 (l keeps the bare p).
-            p = p * score_scales(vs_ref, c)
-        acc_new = acc * alpha + jax.lax.dot_general(
-            p, v2, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l_new, acc_new
+        live = token_idx < seq_len
+        out = []
+        for h in range(nkv):
+            m, l, acc = carry[3 * h:3 * h + 3]
+            q2 = q_ref[0, h]  # [n, 128]
+            # bf16 operands, f32 accumulation: what the XLA path's einsums
+            # do, and one MXU pass where an f32 product takes several.
+            k2 = pages_of(k_buf, slot, h, q2.dtype)
+            v2 = pages_of(v_buf, slot, h, q2.dtype)
+            scores = jax.lax.dot_general(
+                q2, k2, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [n, rows]
+            if quantized:
+                # q . (k_int8 * s) == (q . k_int8) * s: dequantize the scores.
+                scores = scores * score_scales(ks_ref, h, c)
+            scores = jnp.where(live, scores, NEG_INF)
+            # Per-row online softmax (groups merged outside the kernel).
+            m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+            p = jnp.exp(scores - m_new)
+            alpha = jnp.exp(m - m_new)
+            l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            if quantized:
+                # p . (v_int8 * s) == (p * s) . v_int8 (l keeps the bare p).
+                p = p * score_scales(vs_ref, h, c)
+            acc_new = acc * alpha + jax.lax.dot_general(
+                p.astype(v2.dtype), v2, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            out += [m_new, l_new, acc_new]
+        return tuple(out)
 
-    m0 = jnp.full((n, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((n, 1), jnp.float32)
-    acc0 = jnp.zeros((n, 128), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, num_chunks, body, (m0, l0, acc0))
-    acc_ref[0, 0] = acc.astype(acc_ref.dtype)
-    m_ref[0, 0] = jnp.broadcast_to(m, (n, 128))
-    l_ref[0, 0] = jnp.broadcast_to(l, (n, 128))
+    init = (jnp.full((n, 1), NEG_INF, jnp.float32),
+            jnp.zeros((n, 1), jnp.float32),
+            jnp.zeros((n, 128), jnp.float32)) * nkv
+
+    def emit(stats):
+        for h in range(nkv):
+            m, l, acc = stats[3 * h:3 * h + 3]
+            acc_ref[0, h] = acc
+            m_ref[0, h] = jnp.broadcast_to(m, (n, 128))
+            l_ref[0, h] = jnp.broadcast_to(l, (n, 128))
+
+    @pl.when(seq_len == 0)
+    def _():
+        emit(init)  # no history: the wrapper's merge weighs it exp(-inf)
+
+    @pl.when(seq_len > 0)
+    def _():
+        g0 = g_ref[0]
+
+        @pl.when(g0 == 0)
+        def _():
+            start_fetch(b, 0, 0)  # first live row: nobody fetched for it
+
+        nxt = jax.lax.while_loop(
+            lambda i: (i < nb) & (seq_lens_ref[jnp.minimum(i, nb - 1)] == 0),
+            lambda i: i + 1, b + 1)
+        emit(jax.lax.fori_loop(
+            0, num_chunks, functools.partial(body, g0=g0, nxt=nxt), init))
+        g_ref[0] = g0 + num_chunks
 
 
-def _chunk_scales(scale, layer, page_table, tpr: int):
+def _chunk_scales(scale, layer, page_table, tpr: int, ppc: int):
     """Per-token scales of the sequences' pages, gathered in XLA and laid
     out the way the kernel multiplies them: [B, Nkv, chunks, tpr, rows]
     with [c, t, r] = the scale of token c*chunk_tokens + r*tpr + t.
@@ -170,14 +265,14 @@ def _chunk_scales(scale, layer, page_table, tpr: int):
     data; page-table padding is masked by seq_len in the kernel."""
     b, maxp = page_table.shape
     nkv, page = scale.shape[1], scale.shape[3]
-    chunks = pl.cdiv(maxp, PAGES_PER_CHUNK)
-    pt = jnp.pad(page_table, ((0, 0), (0, chunks * PAGES_PER_CHUNK - maxp)))
+    chunks = pl.cdiv(maxp, ppc)
+    pt = jnp.pad(page_table, ((0, 0), (0, chunks * ppc - maxp)))
     # Layer and head stay ADVANCED indices (kv_quant.gather_pages_folded:
     # a basic cache[layer] is a dynamic-slice copy of the pool).
     idx_l = jnp.broadcast_to(layer, (b, nkv, pt.shape[1]))
     idx_n = jnp.arange(nkv)[None, :, None]
     s = scale[idx_l, idx_n, pt[:, None, :]]     # [B, Nkv, pages, page]
-    rows = PAGES_PER_CHUNK * page // tpr
+    rows = ppc * page // tpr
     return s.reshape(b, nkv, chunks, rows, tpr).transpose(0, 1, 2, 4, 3)
 
 
@@ -189,7 +284,6 @@ def _hist_flash_pallas(q, k_cache, v_cache, layer, page_table, hist_lens,
     (the in-window buffer and/or the current token)."""
     b, nh, d = q.shape
     _, nkv, num_pages, page_size, _ = k_cache.shape
-    maxp = page_table.shape[1]
     seq_lens = hist_lens
     q_per_kv = int(q_per_kv)
     if d >= 128:
@@ -216,6 +310,7 @@ def _hist_flash_pallas(q, k_cache, v_cache, layer, page_table, hist_lens,
     v_pages = v_cache.data if quantized else v_cache
     kp = k_pages.reshape(L, nkv, num_pages, rows_per_page, 128)
     vp = v_pages.reshape(L, nkv, num_pages, rows_per_page, 128)
+    ppc = pages_per_chunk(page_size, nkv, d, kp.dtype.itemsize)
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
 
     # Expand q: group t occupies rows [t*qpk,(t+1)*qpk) and lanes
@@ -228,38 +323,37 @@ def _hist_flash_pallas(q, k_cache, v_cache, layer, page_table, hist_lens,
         for t in range(tpr):
             q2 = q2.at[:, :, t * qpk:(t + 1) * qpk, t * d:(t + 1) * d].set(qg)
 
-    blk = pl.BlockSpec((1, 1, n, tpr * d), lambda i, j, *_: (i, j, 0, 0))
+    blk = pl.BlockSpec((1, nkv, n, 128), lambda i, *_: (i, 0, 0, 0))
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [blk, any_spec, any_spec]
     operands = [q2, kp, vp]
     if quantized:
-        ks = _chunk_scales(k_cache.scale, layer, page_table, tpr)
-        vs = _chunk_scales(v_cache.scale, layer, page_table, tpr)
-        s_blk = pl.BlockSpec((1, 1, *ks.shape[2:]),
-                             lambda i, j, *_: (i, j, 0, 0, 0))
+        ks = _chunk_scales(k_cache.scale, layer, page_table, tpr, ppc)
+        vs = _chunk_scales(v_cache.scale, layer, page_table, tpr, ppc)
+        s_blk = pl.BlockSpec((1, *ks.shape[1:]),
+                             lambda i, *_: (i, 0, 0, 0, 0))
         in_specs += [s_blk, s_blk]
         operands += [ks, vs]
+    buf = pltpu.VMEM((2, nkv, ppc, rows_per_page, 128), kp.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, nkv),
+        grid=(b,),
         in_specs=in_specs,
         out_specs=(blk, blk, blk),
-        scratch_shapes=[
-            pltpu.VMEM((2, PAGES_PER_CHUNK, rows_per_page, 128), kp.dtype),
-            pltpu.VMEM((2, PAGES_PER_CHUNK, rows_per_page, 128), vp.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
+        scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.SMEM((1,), jnp.int32)],
     )
-    kernel = functools.partial(_decode_kernel, page_size=page_size,
-                               max_pages=maxp, tpr=tpr, qpk=qpk,
-                               quantized=quantized)
-    shape = jax.ShapeDtypeStruct((b, nkv, n, tpr * d), jnp.float32)
+    kernel = functools.partial(_decode_kernel, page_size=page_size, tpr=tpr,
+                               qpk=qpk, quantized=quantized)
+    shape = jax.ShapeDtypeStruct((b, nkv, n, 128), jnp.float32)
     acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=(shape, shape, shape),
+        # Sequential by construction: the fetch pipeline and its chunk
+        # counter run from one row's program into the next.
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(layer_arr, page_table, seq_lens, *operands)
     m = m[..., :1]  # broadcast lanes -> scalar stat per row

@@ -256,7 +256,13 @@ class EngineConfig:
     # at long context roughly halves. Composes with weight-only
     # ModelSpec.quant. Env DTPU_QUANT_KV overrides ("none" disables).
     quant_kv: str | None = None
-    # Attention backend: "auto" | "pallas" | "xla"
+    # Decode attention over cache-resident history: "pallas" reads each
+    # row's live pages in place (engine/attention.py), "xla" gathers the
+    # page-table bucket of every slot first. "auto" is the kernel on one
+    # TPU device at head_dim 128 and XLA everywhere else (CPU, any
+    # tp/pp/dp/sp mesh, a head_dim under 128, where the kernel's packed
+    # view of the pool is a copy of it); ModelRunner._pick_attention
+    # decides, and runner.attention_backend says what it resolved to.
     attention_backend: str = "auto"
     # KV tiering (reference KVBM G1..G3, block_manager.rs:72-82):
     # host_cache_pages > 0 enables the G2 host-DRAM block cache — pages

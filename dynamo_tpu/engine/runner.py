@@ -422,36 +422,55 @@ class ModelRunner:
                 f"cannot size the KV pool or report HBM use")
         return stats
 
+    def _pallas_refusal(self) -> str | None:
+        """Why the Pallas kernel cannot run this model on this mesh, or
+        None: what "pallas" raises with and what "auto" decides from."""
+        d = self.spec.head_dim
+        page = self.config.page_size
+        if not (d == 128 or (d < 128 and 128 % d == 0
+                             and (page * d) % 128 == 0)):
+            return (f"needs head_dim 128, or a head_dim that packs into 128 "
+                    f"lanes (128 % head_dim == 0 and page_size*head_dim % "
+                    f"128 == 0); got head_dim {d}, page_size {page}")
+        if self.mesh.size > 1:
+            return ("runs on one device: the kernel has no partitioning "
+                    "rule, so a tp/pp/dp/sp mesh would gather the whole KV "
+                    "pool around it")
+        return None
+
     def _pick_attention(self):
         """Returns (single-step impl, window impl). A requested backend is
-        what runs: "pallas" that cannot be had is an error, never XLA."""
+        what runs: "pallas" that cannot be had is an error, never XLA.
+        "auto" is decided from what the runner observes, nothing else: the
+        platform, the mesh's size and the head dimension."""
         from dynamo_tpu.engine.model import paged_window_attention_xla
         backend = self.config.attention_backend
+        refusal = self._pallas_refusal()
         if backend == "auto":
-            # The bucketed XLA gather is the default: where the two were
-            # timed (hand-run before this round, short uniform batches)
-            # the kernel did not win, so it stays opt-in (ROADMAP D4).
-            backend = "xla"
+            # Timed on one v5e (PERF.md section 6, PR 26). head_dim 128:
+            # attention of a Qwen2.5-7B decode step, 17 live rows of 32 at
+            # about 950 tokens, costs 21.4 ms gathered and 3 ms in the
+            # kernel, a row past 2048 tokens moves every slot of the
+            # gather to the next bucket and costs the kernel its own
+            # pages, and at 8- and 16-page buckets the two are level
+            # (llama-3-8b-L8: 12.4 against 12.4 and 12.9 against 12.5 ms a
+            # step). head_dim 64: the kernel's [page, D] -> [rows, 128]
+            # view of the pool is a relayout on the device, a copy of the
+            # pool per layer (qwen2.5-0.5b: 247 ms a step against 8.5), so
+            # a packed head stays on XLA until the pool is stored
+            # lane-dense (ROADMAP D3). The CPU would interpret the kernel;
+            # a mesh would gather the pool around it.
+            backend = ("pallas" if self.device.platform == "tpu"
+                       and refusal is None and self.spec.head_dim == 128
+                       else "xla")
         self.attention_backend = backend
         if backend == "xla":
             return paged_decode_attention_xla, paged_window_attention_xla
         if backend != "pallas":
             raise ValueError(f"attention_backend must be 'auto', 'xla' or "
                              f"'pallas', got {backend!r}")
-        d = self.spec.head_dim
-        page = self.config.page_size
-        if not (d == 128 or (d < 128 and 128 % d == 0
-                             and (page * d) % 128 == 0)):
-            raise ValueError(
-                f"attention_backend='pallas' needs head_dim 128, or a "
-                f"head_dim that packs into 128 lanes (128 % head_dim == 0 "
-                f"and page_size*head_dim % 128 == 0); got head_dim {d}, "
-                f"page_size {page}")
-        if self.mesh.size > 1:
-            raise ValueError(
-                "attention_backend='pallas' runs on one device: the kernel "
-                "has no partitioning rule, so a tp/pp/dp/sp mesh would "
-                "gather the whole KV pool around it")
+        if refusal is not None:
+            raise ValueError(f"attention_backend='pallas' {refusal}")
         from dynamo_tpu.engine.attention import (
             paged_decode_attention_pallas, paged_window_attention_pallas)
         # Interpret mode exists for the CPU backend only; a chip compiles

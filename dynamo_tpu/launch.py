@@ -63,7 +63,12 @@ def parse_args(argv=None) -> argparse.Namespace:
     parser.add_argument("--pp", type=int, default=1)
     parser.add_argument("--sp", type=int, default=1)
     parser.add_argument("--attention-backend", default="auto",
-                        choices=["auto", "pallas", "xla"])
+                        choices=["auto", "pallas", "xla"],
+                        help="decode attention: 'auto' runs the Pallas "
+                             "paged kernel on one TPU device at head_dim "
+                             "128 and the XLA gather everywhere else (CPU, "
+                             "a mesh, smaller heads); an explicit "
+                             "'pallas' that cannot be had is an error")
     from dynamo_tpu.backends.tpu import _chunk_arg, _window_arg
     parser.add_argument("--decode-window", default="auto", type=_window_arg,
                         help="positive int or 'auto' (size from the model's "

@@ -82,37 +82,99 @@ def test_pallas_mqa_single_group():
     np.testing.assert_allclose(out, ref, atol=0.03, rtol=0.03)
 
 
+def _window_both(d, nkv, qpk, maxp, lens, m, M=8, seed=11, L=2, page=16):
+    """Window attention, kernel against the XLA gather, on a pool that
+    holds only the live pages (a 256-page table stays a few MB)."""
+    from dynamo_tpu.engine.attention import paged_window_attention_pallas
+    from dynamo_tpu.engine.model import paged_window_attention_xla
+    rng = np.random.default_rng(seed)
+    b = len(lens)
+    need = [-(-n // page) for n in lens]
+    npages = sum(need) + 2
+    ids = rng.permutation(np.arange(1, npages))
+    pt = np.zeros((b, maxp), np.int32)
+    at = 0
+    for i, n in enumerate(need):
+        pt[i, :n] = ids[at:at + n]
+        at += n
+
+    def arr(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+
+    args = (arr(b, nkv * qpk, d), arr(L, nkv, npages, page, d),
+            arr(L, nkv, npages, page, d), jnp.asarray(1, jnp.int32),
+            jnp.asarray(pt), jnp.asarray(lens, jnp.int32),
+            arr(nkv, b, M, d), arr(nkv, b, M, d), jnp.asarray(m, jnp.int32),
+            arr(b, nkv, d), arr(b, nkv, d))
+    ref = paged_window_attention_xla(*args, qpk)
+    out = paged_window_attention_pallas(*args, qpk, interpret=True)
+    return np.asarray(ref, np.float32), np.asarray(out, np.float32)
+
+
 @pytest.mark.parametrize("m", [0, 3])
 def test_pallas_window_matches_xla(m):
     """Window variant: history kernel + in-window buffer cols (j < m) +
     self column must match the XLA window reference."""
-    from dynamo_tpu.engine.attention import paged_window_attention_pallas
-    from dynamo_tpu.engine.model import paged_window_attention_xla
-    rng = np.random.default_rng(7)
-    b, nkv, qpk, d, maxp, page, L, M = 4, 2, 2, 64, 8, 16, 2, 8
-    q = jnp.asarray(rng.standard_normal((b, nkv * qpk, d)), jnp.bfloat16)
-    npages = maxp * b + 2
-    kc = jnp.asarray(rng.standard_normal((L, nkv, npages, page, d)),
-                     jnp.bfloat16)
-    vc = jnp.asarray(rng.standard_normal((L, nkv, npages, page, d)),
-                     jnp.bfloat16)
-    kw = jnp.asarray(rng.standard_normal((nkv, b, M, d)), jnp.bfloat16)
-    vw = jnp.asarray(rng.standard_normal((nkv, b, M, d)), jnp.bfloat16)
-    ks = jnp.asarray(rng.standard_normal((b, nkv, d)), jnp.bfloat16)
-    vs = jnp.asarray(rng.standard_normal((b, nkv, d)), jnp.bfloat16)
-    pt = np.zeros((b, maxp), np.int32)
-    for i in range(b):
-        pt[i] = rng.permutation(np.arange(1, npages - 1))[:maxp]
-    pt = jnp.asarray(pt)
-    sl = jnp.asarray([0, 30, 64, 127], jnp.int32)
-    ly = jnp.asarray(1, jnp.int32)
-    mm = jnp.asarray(m, jnp.int32)
-    ref = np.asarray(paged_window_attention_xla(
-        q, kc, vc, ly, pt, sl, kw, vw, mm, ks, vs, qpk), np.float32)
-    out = np.asarray(paged_window_attention_pallas(
-        q, kc, vc, ly, pt, sl, kw, vw, mm, ks, vs, qpk, interpret=True),
-        np.float32)
+    ref, out = _window_both(d=64, nkv=2, qpk=2, maxp=8,
+                            lens=[0, 30, 64, 127], m=m, seed=7)
     np.testing.assert_allclose(out, ref, atol=0.03, rtol=0.03)
+
+
+# Qwen2.5-7B, the benchmark cell's model: head_dim 128, 4 KV heads, 7 query
+# heads per KV head (one 32-page chunk is 512 tokens at its widths).
+QWEN_7B = dict(d=128, nkv=4, qpk=7)
+
+
+def test_pallas_qwen7b_single_step():
+    """The cell's head shape through the single decode step: lengths inside
+    one chunk, at a chunk's edge and across three chunks."""
+    ref, out = _both(_case(128, b=4, nkv=4, qpk=7, maxp=80,
+                           seq_lens=[5, 512, 1100, 64], seed=8), qpk=7)
+    np.testing.assert_allclose(out, ref, atol=0.03, rtol=0.03)
+
+
+@pytest.mark.parametrize("m", [0, 3, 7])
+def test_pallas_qwen7b_window(m):
+    ref, out = _window_both(**QWEN_7B, maxp=80, lens=[17, 513, 1100, 300],
+                            m=m)
+    np.testing.assert_allclose(out, ref, atol=0.03, rtol=0.03)
+
+
+@pytest.mark.parametrize("lens", [
+    [0, 200, 0, 0, 513, 0, 31, 0],   # dead first, last and in runs
+    [300, 0, 0, 0, 0, 0, 0, 40],     # the pipeline hops six dead slots
+    [0, 0, 0, 0],                    # nobody has history: self column only
+    [0, 0, 0, 77],                   # the first live row is the last row
+], ids=["between", "hop", "all-dead", "last-only"])
+@pytest.mark.parametrize("d", [64, 128])
+def test_pallas_dead_slots_between_live_rows(d, lens):
+    """A slot with hist_lens == 0 fetches nothing and leaves the chunk
+    pipeline where it was: the next live row finds its first chunk in the
+    slot its predecessor started it in."""
+    nkv, qpk = (2, 7) if d == 64 else (4, 7)
+    ref, out = _window_both(d=d, nkv=nkv, qpk=qpk, maxp=40, lens=lens, m=2)
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out, ref, atol=0.03, rtol=0.03)
+
+
+def test_pallas_row_past_2048_tokens_beside_short_rows():
+    """A 256-page table: one row of 2100 tokens (five chunks) beside short
+    rows and a dead slot; every row reads its own pages only."""
+    ref, out = _window_both(**QWEN_7B, maxp=256, lens=[40, 2100, 0, 700],
+                            m=5)
+    np.testing.assert_allclose(out, ref, atol=0.03, rtol=0.03)
+
+
+@pytest.mark.parametrize("shape, want", [
+    ((16, 4, 128, 2), 32),    # qwen2.5-7b bf16: 512 tokens a chunk
+    ((16, 8, 128, 2), 16),    # llama-3-8b
+    ((16, 2, 64, 2), 64),     # qwen2.5-0.5b: small pages, the cap
+    ((16, 4, 128, 1), 64),    # int8 pages hold twice the tokens
+    ((128, 8, 128, 2), 8),    # large pages: the floor
+])
+def test_pages_per_chunk_follows_page_bytes(shape, want):
+    from dynamo_tpu.engine.attention import pages_per_chunk
+    assert pages_per_chunk(*shape) == want
 
 
 def test_pallas_rejects_unpackable_head_dim():

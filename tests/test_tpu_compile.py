@@ -48,8 +48,10 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-# (head_dim, kv heads, q heads per kv head, layers): qwen2.5-0.5b, llama-3-8b.
-WIDTHS = {"qwen2.5-0.5b": (64, 2, 7, 24), "llama-3-8b": (128, 8, 4, 32)}
+# (head_dim, kv heads, q heads per kv head, layers): qwen2.5-0.5b, llama-3-8b,
+# and the benchmark cell's qwen2.5-7b.
+WIDTHS = {"qwen2.5-0.5b": (64, 2, 7, 24), "llama-3-8b": (128, 8, 4, 32),
+          "qwen2.5-7b": (128, 4, 7, 28)}
 
 
 def _shapes(one, model, quantized, b=40, maxp=64):

@@ -121,6 +121,61 @@ def test_requested_pallas_is_pallas_or_an_error():
     assert runner.hbm_stats() == {}  # the CPU has no memory_stats: explicit
 
 
+def _picked(platform, mesh_size, head_dim, backend="auto", page_size=16):
+    """_pick_attention on a stubbed device and mesh: the choice reads only
+    platform, mesh size, head_dim and page size."""
+    runner = object.__new__(ModelRunner)
+    runner.config = SimpleNamespace(attention_backend=backend,
+                                    page_size=page_size)
+    runner.spec = SimpleNamespace(head_dim=head_dim)
+    runner.device = SimpleNamespace(platform=platform)
+    runner.mesh = SimpleNamespace(size=mesh_size)
+    step, window = runner._pick_attention()
+    return runner.attention_backend, step, window
+
+
+@pytest.mark.parametrize("platform, mesh_size, head_dim, want", [
+    ("tpu", 1, 128, "pallas"),   # the benchmark cell: one v5e, qwen2.5-7b
+    ("tpu", 1, 64, "xla"),       # packs, but the packed view copies the pool
+    ("tpu", 1, 48, "xla"),       # a head the kernel cannot pack
+    ("tpu", 4, 128, "xla"),      # tp / dp / pp / sp: no partitioning rule
+    ("tpu", 2, 64, "xla"),
+    ("cpu", 1, 128, "xla"),      # the CPU would interpret the kernel
+    ("cpu", 8, 64, "xla"),
+    ("gpu", 1, 128, "xla"),      # Mosaic TPU kernels compile for a TPU
+])
+def test_auto_attention_is_decided_from_platform_mesh_and_head(
+        platform, mesh_size, head_dim, want):
+    backend, step, window = _picked(platform, mesh_size, head_dim)
+    assert backend == want
+    if want == "pallas":
+        # Compiled through Mosaic on the chip, never interpreted there.
+        assert step.keywords == window.keywords == {"interpret": False}
+        assert step.func.__name__ == "paged_decode_attention_pallas"
+        assert window.func.__name__ == "paged_window_attention_pallas"
+    else:
+        assert step.__name__ == "paged_decode_attention_xla"
+        assert window.__name__ == "paged_window_attention_xla"
+
+
+@pytest.mark.parametrize("platform, mesh_size, head_dim, match", [
+    ("tpu", 4, 128, "one device"),
+    ("tpu", 1, 48, "head_dim"),
+    ("cpu", 2, 64, "one device"),
+])
+def test_requested_pallas_that_cannot_be_had_is_still_an_error(
+        platform, mesh_size, head_dim, match):
+    with pytest.raises(ValueError, match=match):
+        _picked(platform, mesh_size, head_dim, backend="pallas")
+    # ... and an explicit "xla" is XLA wherever it runs.
+    assert _picked(platform, mesh_size, head_dim, backend="xla")[0] == "xla"
+
+
+def test_auto_attention_on_a_real_cpu_runner_and_mesh_is_xla():
+    assert ModelRunner(_tiny()).attention_backend == "xla"
+    assert ModelRunner(_tiny(tp=2)).attention_backend == "xla"
+
+
 def test_warmup_failure_fails_startup(monkeypatch):
     from dynamo_tpu.engine.engine import TPUEngine
 
