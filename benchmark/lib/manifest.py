@@ -67,10 +67,27 @@ def load_module(subdir: str, name: str, root: str = ROOT):
     """``benchmark/<subdir>/<name>.py`` as a module, found by name."""
     path = os.path.join(root, "benchmark", subdir, name + ".py")
     if not os.path.exists(path):
-        raise ManifestError(f"{subdir}/{name}.py does not exist")
+        raise ManifestError(f"benchmark/{subdir}/{name}.py does not exist")
     spec = importlib.util.spec_from_file_location(
         f"benchmark_{subdir}_{name.replace('.', '_').replace('-', '_')}",
         path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+def config_module(config: dict, key: str, default, needs: tuple,
+                  root: str = ROOT):
+    """What a configuration's optional ``key`` ("reference", "roofline")
+    names: ``benchmark/<key>s/<name>.py``, which has to expose ``needs``;
+    without the key, ``default``, the module of lib/ that holds the dense
+    block's. Returns the module and where it came from."""
+    name = config.get(key)
+    if name is None:
+        return default, f"lib/{key}.py"
+    module = load_module(key + "s", name, root)
+    missing = [n for n in needs if not callable(getattr(module, n, None))]
+    if missing:
+        raise ManifestError(
+            f"benchmark/{key}s/{name}.py does not define {missing}")
+    return module, f"{key}s/{name}.py"

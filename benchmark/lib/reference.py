@@ -1,5 +1,11 @@
 """The plain reference: a decoder-only transformer forward in float32.
 
+This module is the DENSE block's reference and every configuration's default.
+A configuration of another block kind names its own (``for_config``:
+``benchmark/references/<name>.py``), which brings its layer and, if it has
+measured one, its tolerance; ``teacher_forced``, ``judge`` and ``diff_stats``
+here are the one definition for all of them.
+
 ``jax.numpy`` only, ``default_matmul_precision("highest")``, one layer at a
 time over the SAME device-resident parameters the server holds (int8 leaves
 dequantised as stored: q * s), no cache, no batching, no paging, nothing of
@@ -47,10 +53,16 @@ from __future__ import annotations
 
 import functools
 import math
+import sys
+
+from benchmark.lib import manifest
 
 MEDIAN_NATS = 0.02
 RMS_NATS = 0.06
 WORST_NATS = 0.25
+#: What a reference module may state for its own block kind (see
+#: ``for_config``); these are the three above.
+ALLOWED_NATS = {"median": MEDIAN_NATS, "rms": RMS_NATS, "worst": WORST_NATS}
 
 
 def _dims(spec) -> tuple:
@@ -59,7 +71,7 @@ def _dims(spec) -> tuple:
             bool(spec.qkv_bias))
 
 
-def _plain(leaf):
+def plain(leaf):
     """A float32 matrix from a leaf as stored: a (q, s) pair is int8 values
     and their float32 scales."""
     import jax.numpy as jnp
@@ -68,13 +80,13 @@ def _plain(leaf):
     return leaf.astype(jnp.float32)
 
 
-def _rms_norm(x, scale, eps):
+def rms_norm(x, scale, eps):
     import jax.numpy as jnp
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return x / jnp.sqrt(var + eps) * scale.astype(jnp.float32)
 
 
-def _rope(x, theta):
+def rope(x, theta):
     """x [S, heads, D]; rotate-half rotary embedding at positions 0..S-1."""
     import jax.numpy as jnp
     s, _, d = x.shape
@@ -96,16 +108,16 @@ def _layer_fn(dims: tuple):
         lp = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
             a, index, 0, keepdims=False), layers)
         s = x.shape[0]
-        h = _rms_norm(x, lp["input_norm"], eps)
-        q = h @ _plain(lp["wq"])
-        k = h @ _plain(lp["wk"])
-        v = h @ _plain(lp["wv"])
+        h = rms_norm(x, lp["input_norm"], eps)
+        q = h @ plain(lp["wq"])
+        k = h @ plain(lp["wk"])
+        v = h @ plain(lp["wv"])
         if bias:
             q = q + lp["bq"].astype(jnp.float32)
             k = k + lp["bk"].astype(jnp.float32)
             v = v + lp["bv"].astype(jnp.float32)
-        q = _rope(q.reshape(s, nh, d), theta)
-        k = _rope(k.reshape(s, nkv, d), theta)
+        q = rope(q.reshape(s, nh, d), theta)
+        k = rope(k.reshape(s, nkv, d), theta)
         v = v.reshape(s, nkv, d)
         group = nh // nkv
         k = jnp.repeat(k, group, axis=1)
@@ -115,11 +127,11 @@ def _layer_fn(dims: tuple):
         scores = jnp.where(causal[None], scores, -jnp.inf)
         probs = jax.nn.softmax(scores, axis=-1)
         attn = jnp.einsum("hqk,khd->qhd", probs, v).reshape(s, nh * d)
-        x = x + attn @ _plain(lp["wo"])
-        h2 = _rms_norm(x, lp["post_attn_norm"], eps)
-        gate = h2 @ _plain(lp["w_gate"])
-        up = h2 @ _plain(lp["w_up"])
-        return x + (jax.nn.silu(gate) * up) @ _plain(lp["w_down"])
+        x = x + attn @ plain(lp["wo"])
+        h2 = rms_norm(x, lp["post_attn_norm"], eps)
+        gate = h2 @ plain(lp["w_gate"])
+        up = h2 @ plain(lp["w_up"])
+        return x + (jax.nn.silu(gate) * up) @ plain(lp["w_down"])
 
     return jax.jit(layer)
 
@@ -130,7 +142,7 @@ def _head_fn(eps: float, tied: bool, chunks: int):
     import jax.numpy as jnp
 
     def head(x, final_norm, table):
-        h = _rms_norm(x, final_norm, eps)
+        h = rms_norm(x, final_norm, eps)
         vocab_axis = 0 if tied else 1
         width = table_shape(table)[vocab_axis] // chunks
 
@@ -140,7 +152,7 @@ def _head_fn(eps: float, tied: bool, chunks: int):
             part = jax.tree.map(lambda a: jax.lax.dynamic_slice_in_dim(
                 a, c * width, width, vocab_axis)
                 if a.shape[vocab_axis] != 1 else a, table)
-            w = _plain(part)
+            w = plain(part)
             return h @ (w.T if tied else w)
 
         parts = jax.lax.map(logits_of, jnp.arange(chunks))  # [C, S, width]
@@ -154,17 +166,15 @@ def table_shape(table) -> tuple:
     return (table.q if hasattr(table, "q") else table).shape
 
 
-def reference_logprobs(params, spec, prompt: list[int],
-                       generated: list[int], skip_layer: int | None = None
-                       ) -> list[float]:
+def teacher_forced(params, spec, prompt: list[int], generated: list[int],
+                   layer, skip_layer: int | None = None) -> list[float]:
     """Logprob of each generated token under the plain forward of
-    ``prompt + generated[:-1]``. ``skip_layer`` leaves that layer out: the
-    check uses it on itself, to show what the tolerance would catch."""
+    ``prompt + generated[:-1]``: embedding, ``layer(x, layers, index)`` for
+    each layer but ``skip_layer``, final norm and head. What every block
+    kind's reference shares; the block itself is ``layer``."""
     import jax
     import jax.numpy as jnp
     import numpy as np
-    if getattr(spec, "num_experts", 0):
-        raise NotImplementedError("the plain reference has no MoE block")
     tokens = np.asarray(list(prompt) + list(generated[:-1]), np.int32)
     n_prompt, n_gen = len(prompt), len(generated)
     with jax.default_matmul_precision("highest"):
@@ -173,7 +183,6 @@ def reference_logprobs(params, spec, prompt: list[int],
         x = rows.astype(jnp.float32)
         if hasattr(embed, "s"):
             x = x * embed.s.astype(jnp.float32)[0]
-        layer = _layer_fn(_dims(spec))
         for index in range(spec.num_layers):
             if index != skip_layer:
                 x = layer(x, params["layers"], jnp.int32(index))
@@ -189,6 +198,20 @@ def reference_logprobs(params, spec, prompt: list[int],
     return [float(v) for v in np.asarray(picked, np.float64)]
 
 
+def reference_logprobs(params, spec, prompt: list[int],
+                       generated: list[int], skip_layer: int | None = None
+                       ) -> list[float]:
+    """``teacher_forced`` through the dense block above. ``skip_layer``
+    leaves that layer out: the check uses it on itself, to show what the
+    tolerance would catch."""
+    if getattr(spec, "num_experts", 0):
+        raise NotImplementedError(
+            "the dense reference has no expert layer: the configuration "
+            "names its own (\"reference\": benchmark/references/<name>.py)")
+    return teacher_forced(params, spec, prompt, generated,
+                          _layer_fn(_dims(spec)), skip_layer)
+
+
 def diff_stats(a, b) -> dict:
     """How far two lists of logprobs are apart, token by token: root mean
     square, median, 90th percentile and largest absolute difference."""
@@ -200,15 +223,27 @@ def diff_stats(a, b) -> dict:
             "worst": d[-1]}
 
 
-def judge(served, full) -> dict:
+def judge(served, full, allowed: dict = ALLOWED_NATS) -> dict:
     """The verdict on one run's logprobs: ``served`` by the system, ``full``
-    by the plain forward."""
+    by the plain forward, ``allowed`` the largest median, root mean square
+    and worst difference that pass."""
     near = diff_stats(served, full)
     ok = (len(served) == len(full) > 0
           and all(x == x and x <= 0.0 for x in served)
-          and near["median"] <= MEDIAN_NATS and near["rms"] <= RMS_NATS
-          and near["worst"] <= WORST_NATS)
+          and all(near[k] <= allowed[k] for k in ALLOWED_NATS))
     return {"ok": ok, **{k + "_nats": v for k, v in near.items()},
-            "allowed_nats": {"median": MEDIAN_NATS, "rms": RMS_NATS,
-                             "worst": WORST_NATS},
+            "allowed_nats": {k: allowed[k] for k in ALLOWED_NATS},
             "samples": len(served)}
+
+
+def for_config(config: dict, root: str = manifest.ROOT) -> dict:
+    """The reference that judges a configuration. Its file may name one,
+    ``"reference": "<name>"`` -> ``benchmark/references/<name>.py`` with
+    ``reference_logprobs`` of the signature above and, optionally, its own
+    ``ALLOWED_NATS`` (the measurement in its docstring); without the key it
+    is this module. A name without its file is a ManifestError."""
+    module, where = manifest.config_module(
+        config, "reference", sys.modules[__name__], ("reference_logprobs",),
+        root)
+    return {"module": where, "logprobs": module.reference_logprobs,
+            "allowed": getattr(module, "ALLOWED_NATS", ALLOWED_NATS)}

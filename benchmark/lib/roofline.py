@@ -18,6 +18,10 @@ peak rate; ``decode_step_floor`` says which.
 
 from __future__ import annotations
 
+import sys
+
+from benchmark.lib import manifest
+
 
 def _head_dim(cfg: dict) -> int:
     return cfg.get("head_dim") or cfg["hidden_size"] // cfg[
@@ -96,17 +100,32 @@ def decode_step_flops(cfg: dict, tp: int, rows: float,
     return (2 * weights * rows + attn) / tp
 
 
+def counting(cfg: dict, root: str = manifest.ROOT) -> tuple:
+    """The module that counts a configuration's decode step, and where it
+    is: the one its file names (``"roofline": "<name>"`` ->
+    ``benchmark/rooflines/<name>.py`` with ``decode_step_bytes`` and
+    ``decode_step_flops`` of the signatures above, one file per block
+    kind), else this module, the dense block's. A name without its file is
+    a ManifestError."""
+    return manifest.config_module(
+        cfg, "roofline", sys.modules[__name__],
+        ("decode_step_bytes", "decode_step_flops"), root)
+
+
 def decode_step_floor(cfg: dict, quant: str | None, tp: int, rows: float,
-                      context_tokens: float, peaks: dict) -> dict:
-    """The least seconds one decode step can take on one chip, and which
-    bound gives it."""
-    t_bytes = (decode_step_bytes(cfg, quant, tp, rows, context_tokens)
+                      context_tokens: float, peaks: dict,
+                      root: str = manifest.ROOT) -> dict:
+    """The least seconds one decode step can take on one chip, which bound
+    gives it, and which module counted (``counting``)."""
+    counts, where = counting(cfg, root)
+    t_bytes = (counts.decode_step_bytes(cfg, quant, tp, rows, context_tokens)
                / (peaks["hbm_gbps"] * 1e9))
-    t_flops = (decode_step_flops(cfg, tp, rows, context_tokens)
+    t_flops = (counts.decode_step_flops(cfg, tp, rows, context_tokens)
                / (peaks["bf16_tflops"] * 1e12))
     return {"seconds": max(t_bytes, t_flops),
             "bound": "bandwidth" if t_bytes >= t_flops else "compute",
-            "bytes_seconds": t_bytes, "flops_seconds": t_flops}
+            "bytes_seconds": t_bytes, "flops_seconds": t_flops,
+            "counted_by": where}
 
 
 def peaks_of(device_kind: str, table: dict) -> dict:

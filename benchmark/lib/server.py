@@ -24,12 +24,13 @@ import asyncio
 import contextlib
 import dataclasses
 import json
+import os
 import sys
 import time
 
 import numpy as np
 
-from benchmark.lib import weights
+from benchmark.lib import manifest, weights
 
 
 class BenchError(Exception):
@@ -37,23 +38,17 @@ class BenchError(Exception):
 
 
 def model_spec(name: str, cfg: dict, quant: str | None):
-    """ModelSpec from a configuration file (Hugging Face key names)."""
+    """ModelSpec of a configuration, read by the program's own reader
+    (engine/config.py ``ModelSpec.from_hf_config``) from a copy of the
+    dictionary as it is run, written under .bench_run/: a key a later
+    architecture needs is then read by the program, not mapped here."""
     from dynamo_tpu.engine.config import ModelSpec
-    return ModelSpec(
-        name=name, vocab_size=cfg["vocab_size"],
-        hidden_size=cfg["hidden_size"],
-        intermediate_size=cfg["intermediate_size"],
-        num_layers=cfg["num_hidden_layers"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg.get("num_key_value_heads",
-                             cfg["num_attention_heads"]),
-        head_dim=cfg.get("head_dim"),
-        rope_theta=cfg.get("rope_theta", 10000.0),
-        rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
-        qkv_bias=cfg.get("qkv_bias", cfg.get("model_type") == "qwen2"),
-        tie_word_embeddings=cfg.get("tie_word_embeddings", False),
-        max_position_embeddings=cfg.get("max_position_embeddings", 8192),
-        quant=quant)
+    os.makedirs(manifest.RUN_DIR, exist_ok=True)
+    path = os.path.join(manifest.RUN_DIR, f"config-{name}.json")
+    with open(path, "w", encoding="utf-8") as fh:
+        json.dump(cfg, fh)
+    return dataclasses.replace(ModelSpec.from_hf_config(path), name=name,
+                               quant=quant)
 
 
 #: Rows of one batched prefill call. The engine has no setting for it:

@@ -13,7 +13,9 @@ its per-layer metrics, with a profiler trace of a few steady seconds.
 This file knows no cell, configuration, traffic mix or metric by name: it
 finds ``benchmark/configs/<config>.json``, ``benchmark/traffic/<mix>.json``,
 ``benchmark/cells/<cell>.json``, ``benchmark/generators/<generator>.py`` and
-``benchmark/layer_metrics/<metric>.py`` from the names in BENCHMARK.json.
+``benchmark/layer_metrics/<metric>.py`` from the names in BENCHMARK.json,
+and ``benchmark/references/<name>.py`` and ``benchmark/rooflines/<name>.py``
+where the configuration's file names them.
 
 Without a TPU (or with fewer chips than the cell asks for) it exits with
 code 2 and prints nothing, unless ``--rehearse-cpu`` is given: then the same
@@ -93,7 +95,8 @@ def rehearsal_cut(files: dict) -> dict:
         if key in params:
             params[key] = min(params[key], cap)
     config = dict(files["config"])
-    config.update(toy["model"])
+    # The configuration's own toy where its block is not the dense one.
+    config.update(config.pop("rehearsal_model", None) or toy["model"])
     launch = dict(config.get("launch", {}))
     launch.update(toy["launch_extra"])
     config["launch"] = launch
@@ -181,7 +184,7 @@ def program_spans() -> list[dict]:
     from dynamo_tpu.runtime import tracing
     rec = tracing.get_recorder()
     return [{"name": s.name, "start": s.start_mono, "end": s.end_mono}
-            for s in rec._snapshot() if s.end_mono is not None]
+            for s in rec.snapshot()[0] if s.end_mono is not None]
 
 
 # -- the profiler ---------------------------------------------------------------
@@ -228,12 +231,13 @@ async def capture_trace(trace_dir: str, start_at: float, seconds: float
 
 # -- correctness ------------------------------------------------------------------
 
-async def check_logprobs(srv, seed: int, overhead: int, vocab: int,
-                         prompts: int = 4, prompt_tokens: int = 64,
-                         n_gen: int = 16) -> dict:
+async def check_logprobs(srv, judged: dict, seed: int, overhead: int,
+                         vocab: int, prompts: int = 4,
+                         prompt_tokens: int = 64, n_gen: int = 16) -> dict:
     """Check (a): served logprobs of seeded prompts against the plain
-    float32 forward over the same device-resident parameters
-    (reference.judge). The verdict keeps what was served, for
+    float32 forward over the same device-resident parameters, by the
+    reference and the tolerance of the configuration
+    (``reference.for_config``). The verdict keeps what was served, for
     probe_faults()."""
     from benchmark.lib import reference
     runner = srv.engine.runner
@@ -248,8 +252,8 @@ async def check_logprobs(srv, seed: int, overhead: int, vocab: int,
         got = [e["logprob"] for e in
                body["choices"][0]["logprobs"]["content"]]
         t0 = time.monotonic()
-        ref = reference.reference_logprobs(runner.params, runner.spec,
-                                           tap["prompt"], tap["tokens"])
+        ref = judged["logprobs"](runner.params, runner.spec, tap["prompt"],
+                                 tap["tokens"])
         shape_ok = shape_ok and (len(got) == n_gen == len(tap["tokens"])
                                  and len(tap["prompt"]) == prompt_tokens)
         served += got
@@ -259,13 +263,13 @@ async def check_logprobs(srv, seed: int, overhead: int, vocab: int,
                      "served_first": got[:3], "reference_first": ref[:3],
                      "reference_seconds": time.monotonic() - t0,
                      "usage": body.get("usage")})
-    verdict = reference.judge(served, full)
+    verdict = reference.judge(served, full, judged["allowed"])
     verdict["ok"] = bool(verdict["ok"] and shape_ok)
-    emit("reference", **verdict, prompts=rows)
+    emit("reference", **verdict, module=judged["module"], prompts=rows)
     return {**verdict, "_served": served, "_full": full, "_taps": taps}
 
 
-def probe_faults(runner, checked: dict) -> None:
+def probe_faults(runner, judged: dict, checked: dict) -> None:
     """``--probe-faults``, after the window: how far the plain forward with
     its first or its last layer left out is from what was served, in the
     statistics the check bounds. Says what the tolerance would catch; costs
@@ -276,10 +280,11 @@ def probe_faults(runner, checked: dict) -> None:
                          ("last_layer_skipped", last)):
         faulty = []
         for prompt, tokens in checked["_taps"]:
-            faulty += reference.reference_logprobs(
+            faulty += judged["logprobs"](
                 runner.params, runner.spec, prompt, tokens, skip_layer=layer)
         emit("fault_probe", fault=label,
-             would_pass=reference.judge(checked["_served"], faulty)["ok"],
+             would_pass=reference.judge(checked["_served"], faulty,
+                                        judged["allowed"])["ok"],
              served_vs_faulty=reference.diff_stats(checked["_served"],
                                                    faulty),
              reference_vs_faulty=reference.diff_stats(checked["_full"],
@@ -313,11 +318,14 @@ def arrays_on(tree, platform: str) -> bool:
 # -- one run ------------------------------------------------------------------------
 
 async def run_cell(args, files: dict, man: dict, jax) -> dict:
-    from benchmark.lib import server
+    from benchmark.lib import reference, roofline, server
     from dynamo_tpu.engine import perf
 
     cell, config = files["cell"], files["config"]
     name = cell["config"]
+    # What the configuration names is found before anything is launched.
+    judged = reference.for_config(config)
+    counted_by = roofline.counting(config)[1]
     seconds = float(args.seconds)
     os.makedirs(manifest.RUN_DIR, exist_ok=True)
     launch = config.get("launch", {})
@@ -346,16 +354,18 @@ async def run_cell(args, files: dict, man: dict, jax) -> dict:
         emit("launch", argv=argv, cell=cell["name"], seed=args.seed,
              seconds=seconds, trace=args.trace, generator=files["generator"],
              requests_planned=len(all_reqs), shapes=vars(shapes),
+             reference=judged["module"], roofline=counted_by,
              compile_cache=perf.compile_cache_status())
         async with server.Server(argv) as srv:
             return await _serve_and_measure(args, files, man, jax, srv,
-                                            seams, plan, open_loop, spec)
+                                            seams, plan, open_loop, spec,
+                                            judged)
     finally:
         seams.restore()
 
 
 async def _serve_and_measure(args, files, man, jax, srv, seams, plan,
-                             open_loop, spec) -> dict:
+                             open_loop, spec, judged) -> dict:
     from benchmark.lib import roofline, trace_reduce
     from dynamo_tpu.engine import perf
 
@@ -387,7 +397,8 @@ async def _serve_and_measure(args, files, man, jax, srv, seams, plan,
         req["content"] = bench_tok.text_of(ids)
         prompt_keys[req["id"]] = tuple(ids[:6])
 
-    checked = await check_logprobs(srv, args.seed, overhead, spec.vocab_size)
+    checked = await check_logprobs(srv, judged, args.seed, overhead,
+                                   spec.vocab_size)
     on_device = all(arrays_on(getattr(runner, n), platform)
                     for n in ("params", "k_cache", "v_cache"))
     # The cache holds what the configuration says: an int8 cache rounds as
@@ -532,7 +543,7 @@ async def _serve_and_measure(args, files, man, jax, srv, seams, plan,
          arrays_on_device=on_device, kv_cache_types=kv_types,
          kv_cache_as_configured=kv_as_configured, platform=platform)
     if args.probe_faults:
-        probe_faults(runner, checked)
+        probe_faults(runner, judged, checked)
 
     entries = manifest.metrics_of(
         man, "per_layer" if args.trace else "end_to_end", cell["name"])

@@ -1,11 +1,16 @@
 """BENCHMARK.json against the contract's limits and against the files."""
+import inspect
 import os
 import re
 
-from benchmark.lib import manifest
+import pytest
+
+from benchmark.lib import manifest, reference, roofline
 from benchmark.lib import tokenizer as bench_tok
 
 MAN = manifest.load_manifest()
+#: A checkout in miniature whose configuration names all it may name.
+FIXTURE = os.path.join(os.path.dirname(__file__), "data", "new_block")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
@@ -64,6 +69,37 @@ def test_every_file_a_cell_names_exists():
         data = manifest.load_json(os.path.join(manifest.ROOT, cfg["file"]))
         assert data["source"] == cfg["source"]
         assert data["reduced"] == cfg["reduced"]
+
+
+@pytest.mark.parametrize("root", [manifest.ROOT, FIXTURE],
+                         ids=["repo", "fixture"])
+def test_what_a_configuration_names_exists_and_has_the_signatures(root):
+    """``reference`` / ``roofline`` / ``rehearsal_model`` are optional; a
+    name is a file with the callables of lib/'s own, never a default."""
+    def parameters(fn):
+        return list(inspect.signature(fn).parameters)
+
+    for entry in manifest.load_manifest(root)["configs"]:
+        config = manifest.load_json(os.path.join(root, entry["file"]))
+        judged = reference.for_config(config, root=root)
+        assert (judged["module"] == "lib/reference.py") == (
+            "reference" not in config)
+        assert parameters(judged["logprobs"]) == parameters(
+            reference.reference_logprobs)
+        assert set(judged["allowed"]) == {"median", "rms", "worst"}
+        assert all(0 < v < float("inf") for v in judged["allowed"].values())
+        counts, where = roofline.counting(config, root=root)
+        assert (where == "lib/roofline.py") == ("roofline" not in config)
+        for name in ("decode_step_bytes", "decode_step_flops"):
+            assert parameters(getattr(counts, name)) == parameters(
+                getattr(roofline, name))
+        toy = config.get("rehearsal_model", {})
+        assert set(toy) <= set(config), "toy sizes under the public keys"
+        for key in ("reference", "roofline"):
+            with pytest.raises(manifest.ManifestError,
+                               match=f"benchmark/{key}s/no_such.py"):
+                manifest.config_module({**config, key: "no_such"}, key,
+                                       None, (), root)
 
 
 def cells_of(metric):

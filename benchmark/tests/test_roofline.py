@@ -62,6 +62,23 @@ def test_decode_step_floor_says_which_bound():
         "bound"] == "compute"
 
 
+def test_default_floor_of_the_dense_cell_is_the_number_of_pr_26():
+    # qwen2.5-7b.reasoning in its traced seconds: about 18 rows, 19,800
+    # live tokens. What lib/roofline.py gave at commit c2678d8, before a
+    # configuration could name a roofline of its own; Q7 names none.
+    peaks = roofline.peaks_of("TPU v5 lite", manifest.load_json(
+        manifest.BENCH + "/peaks.json"))
+    data = 7_077_126_656 + 17 * 3584 + (19800 + 18) * 57_344
+    assert roofline.decode_step_bytes(Q7, "int8", 1, 18, 19800) == data
+    assert data == 8_213_630_976
+    assert roofline.decode_step_flops(Q7, 1, 18, 19800) == 262_478_168_064
+    assert roofline.decode_step_floor(Q7, "int8", 1, 18, 19800, peaks) == {
+        "seconds": 0.010028853450549451, "bound": "bandwidth",
+        "bytes_seconds": 0.010028853450549451,
+        "flops_seconds": 0.0013323764876345178,
+        "counted_by": "lib/roofline.py"}
+
+
 def test_unknown_device_kind_is_an_error():
     table = manifest.load_json(manifest.BENCH + "/peaks.json")
     assert roofline.peaks_of("TPU v5 lite", table)["hbm_gbps"] == 819.0
