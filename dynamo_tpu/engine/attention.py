@@ -80,16 +80,25 @@ def pages_per_chunk(page_size: int, nkv: int, d: int, itemsize: int) -> int:
 
 
 def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
-                   q_ref, k_hbm, v_hbm,  # q2 VMEM block; k/v packed (ANY)
-                   *rest,  # [ks_ref, vs_ref if quantized], outputs, scratch
+                   *rest,  # [lo_ref if windowed], q2 VMEM block, k/v packed
+                   # (ANY), [ks_ref, vs_ref if quantized], outputs, scratch
                    page_size: int, tpr: int, qpk: int,
-                   quantized: bool = False):
+                   quantized: bool = False, windowed: bool = False):
     """One grid program per batch row, all KV heads inside it. The K/V
     fetch is ONE pipeline across the whole grid: chunk g (counted over the
     live rows' chunks in row order) lands in slot g % 2, and while chunk g
     is multiplied chunk g+1 is in flight, be it this row's next chunk or
     the next live row's first. A row with no history costs an empty grid
-    step; only live pages are ever copied."""
+    step; only live pages are ever copied.
+
+    ``windowed`` (a model with sliding-window layers): a fourth prefetched
+    vector, lo [B], is the first token each row's query still sees in THIS
+    layer (0 in a full layer). The walk starts at the chunk that holds lo,
+    so chunks wholly before the window are never fetched, and tokens before
+    lo inside that chunk are masked."""
+    if windowed:
+        lo_ref, *rest = rest
+    q_ref, k_hbm, v_hbm, *rest = rest
     if quantized:
         # int8 pages; the per-token f32 scales arrive as a VMEM block
         # already laid out per chunk in score space ([chunks, tpr, rows],
@@ -109,6 +118,11 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
     chunk_tokens = ppc * page_size
     rows = chunk_tokens // tpr  # packed rows per chunk
     num_chunks = pl.cdiv(seq_len, chunk_tokens)
+
+    def first_chunk(r):
+        """The chunk a row's walk starts at (0 without windows)."""
+        return lo_ref[r] // chunk_tokens if windowed else 0
+
     n = tpr * qpk
     d = 128 // tpr
     scale = 1.0 / (d ** 0.5)
@@ -184,18 +198,22 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
         return x.reshape(rows, 128)
 
     def body(c, carry, g0, nxt):
-        g = g0 + c
+        g = g0 + c - first_chunk(b) if windowed else g0 + c
         slot = jax.lax.rem(g, 2)
         more = c + 1 < num_chunks
 
         @pl.when(more | (nxt < nb))
         def _():
-            start_fetch(jnp.where(more, b, nxt), jnp.where(more, c + 1, 0),
-                        1 - slot)
+            nxt_row = jnp.where(more, b, nxt)
+            start_fetch(nxt_row, jnp.where(
+                more, c + 1, first_chunk(jnp.minimum(nxt, nb - 1))
+                if windowed else 0), 1 - slot)
 
         wait_fetch(b, c, slot)
         token_idx = c * chunk_tokens + row * tpr + group
         live = token_idx < seq_len
+        if windowed:
+            live = live & (token_idx >= lo_ref[b])
         out = []
         for h in range(nkv):
             m, l, acc = carry[3 * h:3 * h + 3]
@@ -246,14 +264,17 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
 
         @pl.when(g0 == 0)
         def _():
-            start_fetch(b, 0, 0)  # first live row: nobody fetched for it
+            # first live row: nobody fetched for it
+            start_fetch(b, first_chunk(b), 0)
 
         nxt = jax.lax.while_loop(
             lambda i: (i < nb) & (seq_lens_ref[jnp.minimum(i, nb - 1)] == 0),
             lambda i: i + 1, b + 1)
         emit(jax.lax.fori_loop(
-            0, num_chunks, functools.partial(body, g0=g0, nxt=nxt), init))
-        g_ref[0] = g0 + num_chunks
+            first_chunk(b), num_chunks,
+            functools.partial(body, g0=g0, nxt=nxt), init))
+        g_ref[0] = (g0 + num_chunks - first_chunk(b) if windowed
+                    else g0 + num_chunks)
 
 
 def _chunk_scales(scale, layer, page_table, tpr: int, ppc: int):
@@ -277,11 +298,13 @@ def _chunk_scales(scale, layer, page_table, tpr: int, ppc: int):
 
 
 def _hist_flash_pallas(q, k_cache, v_cache, layer, page_table, hist_lens,
-                       q_per_kv, interpret: bool):
+                       q_per_kv, interpret: bool, lo=None):
     """Run the kernel over the cache-resident history; returns the flash
     triple (num [b,nkv,qpk,d] unnormalized, l_star [b,nkv,qpk,1],
     m_s [b,nkv,qpk,1]) for the wrapper to merge with out-of-cache columns
-    (the in-window buffer and/or the current token)."""
+    (the in-window buffer and/or the current token). ``lo`` [B] (None: a
+    model without window layers, whose kernel has no such operand): the
+    first history token each row still sees."""
     b, nh, d = q.shape
     _, nkv, num_pages, page_size, _ = k_cache.shape
     seq_lens = hist_lens
@@ -323,6 +346,14 @@ def _hist_flash_pallas(q, k_cache, v_cache, layer, page_table, hist_lens,
         for t in range(tpr):
             q2 = q2.at[:, :, t * qpk:(t + 1) * qpk, t * d:(t + 1) * d].set(qg)
 
+    windowed = lo is not None
+    # A row whose window starts past its history sees none of it.
+    prefetch = [layer_arr, page_table,
+                seq_lens if not windowed
+                else jnp.where(lo < seq_lens, seq_lens, 0)]
+    if windowed:
+        prefetch.append(jnp.minimum(lo, jnp.maximum(seq_lens - 1, 0))
+                        .astype(jnp.int32))
     blk = pl.BlockSpec((1, nkv, n, 128), lambda i, *_: (i, 0, 0, 0))
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [blk, any_spec, any_spec]
@@ -336,7 +367,7 @@ def _hist_flash_pallas(q, k_cache, v_cache, layer, page_table, hist_lens,
         operands += [ks, vs]
     buf = pltpu.VMEM((2, nkv, ppc, rows_per_page, 128), kp.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(prefetch),
         grid=(b,),
         in_specs=in_specs,
         out_specs=(blk, blk, blk),
@@ -344,7 +375,8 @@ def _hist_flash_pallas(q, k_cache, v_cache, layer, page_table, hist_lens,
                         pltpu.SMEM((1,), jnp.int32)],
     )
     kernel = functools.partial(_decode_kernel, page_size=page_size, tpr=tpr,
-                               qpk=qpk, quantized=quantized)
+                               qpk=qpk, quantized=quantized,
+                               windowed=windowed)
     shape = jax.ShapeDtypeStruct((b, nkv, n, 128), jnp.float32)
     acc, m, l = pl.pallas_call(
         kernel,
@@ -355,7 +387,7 @@ def _hist_flash_pallas(q, k_cache, v_cache, layer, page_table, hist_lens,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(layer_arr, page_table, seq_lens, *operands)
+    )(*prefetch, *operands)
     m = m[..., :1]  # broadcast lanes -> scalar stat per row
     l = l[..., :1]
     if tpr == 1:
@@ -406,8 +438,8 @@ def paged_decode_attention_pallas(q: jax.Array, k_cache: jax.Array,
                                   v_cache: jax.Array, layer: jax.Array,
                                   page_table: jax.Array, hist_lens: jax.Array,
                                   k_self: jax.Array, v_self: jax.Array,
-                                  q_per_kv: int, interpret: bool = False
-                                  ) -> jax.Array:
+                                  q_per_kv: int, interpret: bool = False,
+                                  lo: jax.Array | None = None) -> jax.Array:
     """Drop-in replacement for model.paged_decode_attention_xla.
 
     q [B,Nh,D]; k_cache/v_cache [L,Nkv,P,page,D] (the FULL stacked cache —
@@ -425,7 +457,7 @@ def paged_decode_attention_pallas(q: jax.Array, k_cache: jax.Array,
     nkv = k_cache.shape[1]
     num, l_star, m_s = _hist_flash_pallas(q, k_cache, v_cache, layer,
                                           page_table, hist_lens, q_per_kv,
-                                          interpret)
+                                          interpret, lo)
     mask = jnp.ones((b, 1, 1, 1), bool)
     return _merge_extra(q, num, l_star, m_s, k_self[:, :, None, :],
                         v_self[:, :, None, :], mask, q_per_kv)
@@ -439,20 +471,25 @@ def paged_window_attention_pallas(q: jax.Array, k_cache: jax.Array,
                                   k_win: jax.Array, v_win: jax.Array,
                                   m: jax.Array, k_self: jax.Array,
                                   v_self: jax.Array, q_per_kv: int,
-                                  interpret: bool = False) -> jax.Array:
+                                  interpret: bool = False,
+                                  lo: jax.Array | None = None) -> jax.Array:
     """Window variant (model.paged_window_attention_xla interface): kernel
     over the cache-resident history + XLA flash-merge of the in-window
-    buffer (cols j < m) and the current token. k_win/v_win [Nkv,B,M,D]."""
+    buffer (cols j < m) and the current token. k_win/v_win [Nkv,B,M,D].
+    ``lo`` [B]: the first position a row still sees (window layers)."""
     b = q.shape[0]
     M = k_win.shape[2]
     num, l_star, m_s = _hist_flash_pallas(q, k_cache, v_cache, layer,
                                           page_table, hist_lens, q_per_kv,
-                                          interpret)
+                                          interpret, lo)
     k_extra = jnp.concatenate(
         [k_win.transpose(1, 0, 2, 3), k_self[:, :, None, :]], axis=2)
     v_extra = jnp.concatenate(
         [v_win.transpose(1, 0, 2, 3), v_self[:, :, None, :]], axis=2)
     win_valid = jnp.arange(M)[None, :] < m          # [1,M] (m traced)
+    if lo is not None:  # column j stands at position hist_lens + j
+        win_valid = win_valid & (hist_lens[:, None] + jnp.arange(M)[None, :]
+                                 >= lo[:, None])
     col_mask = jnp.concatenate(
         [jnp.broadcast_to(win_valid, (b, M)),
          jnp.ones((b, 1), bool)], axis=1)[:, None, None, :]

@@ -1,8 +1,13 @@
 """Model and engine configuration.
 
-ModelSpec covers the Llama family (Llama-2/3, Qwen2/2.5 via qkv_bias, TinyLlama)
-— the architectures the reference's backends serve most (BASELINE.md config
-ladder). MoE (Mixtral/DeepSeek) lands with the expert-parallel stage.
+ModelSpec states three block kinds: the dense Llama / Qwen2 block (QKV bias
+by ``qkv_bias``), the Mixtral-style block (``num_experts`` SwiGLU experts of
+the dense width, top-k then softmax, routed on the post-attention norm), and
+the SmallThinker block (a router that reads the layer's INPUT, softmax over
+all experts then top-k renormalised, ReGLU experts of their own width, and a
+per-layer pattern of RoPE / NoPE and sliding-window / full attention).
+``from_hf_config`` reads each from its public ``config.json`` keys as they
+are spelled there.
 """
 
 from __future__ import annotations
@@ -64,6 +69,19 @@ class ModelSpec:
     # MoE (Mixtral family): num_experts == 0 means dense FFN.
     num_experts: int = 0
     num_experts_per_tok: int = 2
+    # What the dense and the Mixtral-style block have as constants and
+    # another block kind states as FIELDS of its subclass
+    # (SmallThinkerSpec): class attributes here, so a dense spec stays
+    # equal, field by field, to the one every cached program and the
+    # benchmark's tests were built from.
+    moe_intermediate_size = None        # an expert's width: intermediate_size
+    moe_router = "topk_softmax"         # the k largest logits, softmax over them
+    norm_topk_prob = True
+    moe_router_input = "post_attn_norm"  # the state the experts read
+    ffn_act = "silu"                    # SwiGLU
+    sliding_window = None               # every layer sees every earlier key
+    sliding_window_layout = None
+    rope_layout = None                  # RoPE on every layer
     # Weight-only quantization: None (bf16) or "int8" (engine/quant.py —
     # int8 storage, bf16 MXU compute; halves the weight-read roofline and
     # fits full llama-3-8b on one 16 GB v5e).
@@ -72,6 +90,28 @@ class ModelSpec:
     def __post_init__(self):
         if self.head_dim is None:
             self.head_dim = self.hidden_size // self.num_heads
+
+    @property
+    def expert_size(self) -> int:
+        """Width of one expert's gate / up / down matrices."""
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def has_layer_pattern(self) -> bool:
+        """Layers differ in kind (RoPE or not, window or full)."""
+        return bool((self.rope_layout and not all(self.rope_layout))
+                    or (self.sliding_window_layout
+                        and any(self.sliding_window_layout)))
+
+    @property
+    def block_kind(self) -> str:
+        """"dense", "mixtral" or "smallthinker": what a path that cannot
+        run every kind names when it refuses one (UnsupportedBlockError)."""
+        if (self.has_layer_pattern or self.moe_router != "topk_softmax"
+                or self.moe_router_input != "post_attn_norm"
+                or self.ffn_act != "silu"):
+            return "smallthinker"
+        return "mixtral" if self.num_experts else "dense"
 
     @property
     def q_per_kv(self) -> int:
@@ -84,7 +124,8 @@ class ModelSpec:
         attn = h * (self.num_heads * d) + 2 * h * (self.num_kv_heads * d) \
             + (self.num_heads * d) * h
         if self.num_experts:
-            mlp = self.num_experts * 3 * h * i + h * self.num_experts
+            mlp = (self.num_experts * 3 * h * self.expert_size
+                   + h * self.num_experts)
         else:
             mlp = 3 * h * i
         per_layer = attn + mlp + 2 * h
@@ -116,6 +157,8 @@ class ModelSpec:
         # dtpu: ignore[blocking-call-in-async] -- model-load startup I/O (HF config.json), never on the serving path
         with open(path) as fh:
             cfg = json.load(fh)
+        if "moe_num_primary_experts" in cfg:
+            return cls._from_smallthinker(cfg, path)
         return cls(
             name=cfg.get("_name_or_path", os.path.basename(os.path.dirname(path))),
             vocab_size=cfg["vocab_size"],
@@ -134,6 +177,90 @@ class ModelSpec:
             num_experts=cfg.get("num_local_experts", 0),
             num_experts_per_tok=cfg.get("num_experts_per_tok", 2),
         )
+
+    @classmethod
+    def _from_smallthinker(cls, cfg: dict, path: str) -> "ModelSpec":
+        """SmallThinker's keys (PowerInfer/SmallThinker-21BA3B-Instruct
+        ``config.json``): experts of ``moe_ffn_hidden_size`` and no dense
+        width, a router ahead of attention, ReGLU, the two layouts."""
+        if not cfg.get("moe_primary_router_apply_softmax", False):
+            raise UnsupportedBlockError(
+                "smallthinker", "a router without softmax "
+                "(moe_primary_router_apply_softmax false): its equations "
+                "are not written down in this repository")
+        if cfg.get("rope_scaling"):
+            raise UnsupportedBlockError("smallthinker", "rope_scaling")
+        width = cfg["moe_ffn_hidden_size"]
+        return SmallThinkerSpec(
+            name=cfg.get("_name_or_path") or cfg.get("model_name")
+            or os.path.basename(os.path.dirname(path)),
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=width,
+            num_layers=cfg["num_hidden_layers"],
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg.get("num_key_value_heads",
+                                 cfg["num_attention_heads"]),
+            head_dim=cfg.get("head_dim"),
+            rope_theta=float(cfg.get("rope_theta", 10000.0)),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            max_position_embeddings=cfg.get("max_position_embeddings", 8192),
+            num_experts=cfg["moe_num_primary_experts"],
+            num_experts_per_tok=cfg["moe_num_active_primary_experts"],
+            moe_intermediate_size=width,
+            norm_topk_prob=bool(cfg.get("norm_topk_prob", False)),
+            sliding_window=cfg.get("sliding_window_size"),
+            sliding_window_layout=cfg.get("sliding_window_layout"),
+            rope_layout=cfg.get("rope_layout"),
+        )
+
+
+@dataclasses.dataclass
+class SmallThinkerSpec(ModelSpec):
+    """The SmallThinker block (PowerInfer/SmallThinker-21BA3B-Instruct):
+    what it states beyond ModelSpec's fields."""
+    # An expert's width (``moe_ffn_hidden_size``); the block has no dense
+    # feed-forward, so intermediate_size repeats it.
+    moe_intermediate_size: int | None = None
+    # "softmax_topk": softmax over all experts in float32, the k largest,
+    # divided by their sum when norm_topk_prob.
+    moe_router: str = "softmax_topk"
+    norm_topk_prob: bool = True
+    # "layer_input": the router reads the residual stream as it enters the
+    # layer, ahead of input_norm (the pre-attention router).
+    moe_router_input: str = "layer_input"
+    ffn_act: str = "relu"               # ReGLU
+    # Layer pattern, one entry a layer. In rope_layout 1 is rotate-half
+    # RoPE and 0 none (NoPE); in sliding_window_layout 1 is a layer whose
+    # query i sees key j iff i - sliding_window < j <= i, 0 one that sees
+    # every j <= i.
+    sliding_window: int | None = None
+    sliding_window_layout: tuple | None = None
+    rope_layout: tuple | None = None
+
+    def __post_init__(self):
+        super().__post_init__()
+        for name in ("sliding_window_layout", "rope_layout"):
+            layout = getattr(self, name)
+            if layout is not None:
+                layout = tuple(int(v) for v in layout)
+                if len(layout) != self.num_layers:
+                    raise ValueError(
+                        f"{name} has {len(layout)} entries for "
+                        f"{self.num_layers} layers")
+                setattr(self, name, layout)
+        if self.sliding_window_layout and any(self.sliding_window_layout) \
+                and not self.sliding_window:
+            raise ValueError("sliding_window_layout without sliding_window")
+
+
+class UnsupportedBlockError(NotImplementedError):
+    """A path that cannot run a block kind refuses it by name, at start-up;
+    it never runs it under another kind's rules."""
+
+    def __init__(self, kind: str, what: str):
+        super().__init__(f"block kind {kind!r} is not supported by {what}")
 
 
 # Presets (shapes from the public model cards).
@@ -390,6 +517,8 @@ class EngineConfig:
             "wv": (m.hidden_size, m.num_kv_heads * d),
             "wo": (m.num_heads * d, m.hidden_size),
         }
+        if m.block_kind == "smallthinker":
+            raise UnsupportedBlockError(m.block_kind, "LoRA adapters")
         if not m.num_experts:
             shapes["w_gate"] = (m.hidden_size, m.intermediate_size)
             shapes["w_up"] = (m.hidden_size, m.intermediate_size)

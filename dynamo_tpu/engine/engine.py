@@ -128,6 +128,11 @@ class _Window:
     # queued behind it (the device ran them back to back): one window of
     # the device. 0 when the pipe was not full.
     period_s: float = 0.0
+    # A routed block's window: float32 [3] read back with the tokens
+    # (runner.decode_window): distinct experts chosen and the fullest
+    # expert's tokens over the mean, summed over steps and expert layers,
+    # and how many of those the sums hold.
+    moe: object = None
     # Speculative windows: toks = (outs [m,B,S], emits [m,B],
     # ndrafts [m,B]); slots snaps carry the ASSUMED advance so
     # processing can correct the host's upper-bound positions.
@@ -268,6 +273,9 @@ class TPUEngine(AsyncEngine):
         # decode windows where drafting was suspended by it.
         self.brownout_level = 0
         self.spec_brownout_windows = 0
+        # Expert-layer load of a routed block, summed over every decode
+        # window processed (the three entries of _Window.moe).
+        self.moe_totals = np.zeros(3, np.float64)
         # Control jobs executed on the engine thread between windows
         # (disagg prefill-extract, KV injection helpers, etc.).
         self._jobs: queue.Queue = queue.Queue()
@@ -1043,6 +1051,18 @@ class TPUEngine(AsyncEngine):
             "phases": {k: round(v, 6)
                        for k, v in self.phase_clock.totals().items()},
         }
+        if self.runner.spec.num_experts:
+            touched, load, n = self.moe_totals
+            experts = self.runner.spec.num_experts
+            status["moe"] = {
+                "experts": experts,
+                "experts_per_tok": self.runner.spec.num_experts_per_tok,
+                # (decode step, expert layer) pairs with a live row.
+                "layer_steps": int(n),
+                "experts_touched_pct": round(100.0 * touched / (n * experts),
+                                             3) if n else None,
+                "load_max_over_mean": round(load / n, 4) if n else None,
+            }
         if self.config.spec_decode:
             # Verify-of-k bandwidth: the spec program runs m_outer verify
             # steps of S = spec_k + 1 positions each, so cost-registry
@@ -1829,7 +1849,13 @@ class TPUEngine(AsyncEngine):
         # before the prefill program overwrites those pages.
         self._flush_spills()
         rest = len(prompt) - reuse_tokens
-        max_chunk = min(cfg.max_prefill_tokens, cfg.prefill_buckets[-1])
+        # A prompt goes whole only if it fits one iteration's chunk budget:
+        # past it the whole-prompt program stalls every decoder for its
+        # length and, at a 7B-class model's default pool, does not fit the
+        # chip (8192 tokens whole: 7.5 GB of float32 scores; a 5,000-token
+        # prompt was answered 500). Chunks attend over history pages.
+        max_chunk = min(cfg.max_prefill_tokens, cfg.prefill_buckets[-1],
+                        self.prefill_chunk_tokens)
         if rest > max_chunk:
             return "chunked"
         first_page = reuse_tokens // page
@@ -2380,6 +2406,11 @@ class TPUEngine(AsyncEngine):
                 lps = np.asarray(w.toks[1]) if want_lp else None
                 top_vs = np.asarray(w.toks[2]) if want_lp else None
                 top_is = np.asarray(w.toks[3]) if want_lp else None
+                if len(w.toks) > 4:
+                    # Twelve bytes of the same program's output, copied
+                    # with the tokens: no second wait for the device.
+                    w.moe = np.asarray(w.toks[4], np.float64)
+                    self.moe_totals += w.moe
             self._note_ready(w)
         else:
             toks = None
@@ -2710,7 +2741,8 @@ class TPUEngine(AsyncEngine):
             self.step_count, tokens_total - self._flight_tokens_last,
             w.period_s, busy_total - self._flight_busy_last,
             wait_total - self._flight_wait_last,
-            idle_total - self._flight_idle_last, rows, w.page_bucket)
+            idle_total - self._flight_idle_last, rows, w.page_bucket,
+            *(w.moe if w.moe is not None else ()))
         if accepted:
             # A frozen ring (bundle capture in flight) rejects the row:
             # keep accumulating so the stall/chunk/token/host-time deltas

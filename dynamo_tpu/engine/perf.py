@@ -85,6 +85,12 @@ _EWMA = 0.2
 #: a scope changes no HLO instruction and so no device time.
 SCOPES = ("embed", "attn.qkv", "attn.kv_gather", "attn.core", "attn.out",
           "mlp", "lm_head", "sample", "kv.commit")
+#: Regions INSIDE a scope, drawn only in programs of a routed block (the
+#: expert layer's router and experts, inside ``mlp``). An instruction in one
+#: keeps its scope and names the sub-scope after it (``mlp+moe.experts``), so
+#: a reader that sums ``mlp`` still counts it. Dense programs have none:
+#: their names, and so SCOPES_VERSION, stand.
+SUBSCOPES = ("moe.router", "moe.experts")
 #: Bump when SCOPES or where a scope is drawn changes. jax's persistent
 #: cache key leaves debug info out (jax/_src/cache_key.py strips it), so an
 #: executable cached by a tree with other scopes would be loaded with ITS
@@ -94,8 +100,8 @@ SCOPES_VERSION = 1
 
 
 def scope(name: str):
-    """``jax.named_scope`` for one name of SCOPES."""
-    assert name in SCOPES, name
+    """``jax.named_scope`` for one name of SCOPES or SUBSCOPES."""
+    assert name in SCOPES or name in SUBSCOPES, name
     return jax.named_scope(name)
 
 
@@ -111,11 +117,12 @@ _HLO_MOVES = ("copy", "copy-start", "copy-done")
 
 
 def _scope_of(op_name: str) -> str | None:
-    """The innermost component of an ``op_name`` path that is a scope."""
-    for part in reversed(op_name.split("/")):
-        if part in SCOPES:
-            return part
-    return None
+    """The innermost component of an ``op_name`` path that is a scope, and
+    after it (joined with ``+``) the innermost that is a sub-scope."""
+    parts = op_name.split("/")
+    found = [next((p for p in reversed(parts) if p in names), None)
+             for names in (SCOPES, SUBSCOPES)]
+    return "+".join(p for p in found if p) or None
 
 
 def _operands(line: str, opcode_end: int) -> list[str]:
@@ -164,9 +171,11 @@ def scopes_of_hlo(text: str) -> dict[str, str | None]:
             computation = None
     out = dict(own)
     for name, fused in calls.items():
-        inside = {own[i] for i in members.get(fused, ()) if own.get(i)}
+        inside = {part for i in members.get(fused, ()) if own.get(i)
+                  for part in own[i].split("+")}
         if inside:
-            out[name] = "+".join(s for s in SCOPES if s in inside)
+            out[name] = "+".join(s for s in SCOPES + SUBSCOPES
+                                 if s in inside)
     read_by: dict[str, list[str]] = {}
     for name, operands in reads.items():
         for operand in operands:
@@ -690,6 +699,20 @@ class PerfMetricsUpdater:
         self.c_spec_brownout = registry.counter(
             "perf_spec_brownout_windows_total", "Decode windows where "
             "brownout pressure suspended speculative drafting")
+        self.c_moe_layer_steps = registry.counter(
+            "moe_layer_steps_total", "Routed block: (decode step, expert "
+            "layer) pairs with a live row, the denominator of the two "
+            "series below")
+        self.c_moe_touched = registry.counter(
+            "moe_experts_touched_total", "Routed block: distinct experts "
+            "the live rows chose, summed over decode steps and expert "
+            "layers (over moe_layer_steps_total: experts a layer-step "
+            "touches)")
+        self.c_moe_load = registry.counter(
+            "moe_expert_load_max_over_mean_total", "Routed block: the "
+            "fullest expert's tokens over the mean per expert, summed over "
+            "decode steps and expert layers (over moe_layer_steps_total: "
+            "1.0 is an even load)")
         for bound in (self.g_step_seconds, self.g_achieved, self.g_roofline,
                       self.g_hbm_in_use, self.g_hbm_peak, self.g_hbm_limit):
             bound.ensure()
@@ -732,6 +755,11 @@ class PerfMetricsUpdater:
             self.g_hbm_in_use.set(hbm.get("bytes_in_use", 0))
             self.g_hbm_peak.set(hbm.get("peak_bytes_in_use", 0))
             self.g_hbm_limit.set(hbm.get("bytes_limit", 0))
+        moe = getattr(engine, "moe_totals", None)
+        if moe is not None and moe[2]:
+            self._delta(self.c_moe_touched, ("moe_t",), float(moe[0]))
+            self._delta(self.c_moe_load, ("moe_l",), float(moe[1]))
+            self._delta(self.c_moe_layer_steps, ("moe_n",), float(moe[2]))
         if getattr(engine, "spec_emit_hist", None):
             self._delta(self.c_spec_draft_tokens, ("spec_dt",),
                         engine.spec_tokens)

@@ -36,7 +36,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.engine import perf
-from dynamo_tpu.engine.config import EngineConfig
+from dynamo_tpu.engine.config import EngineConfig, UnsupportedBlockError
 from dynamo_tpu.engine.kv_quant import (KV_SCALE_BYTES, QuantKV, pack_parcel,
                                         parcel_to_bf16, quantize_np,
                                         scatter_tokens, unpack_parcel)
@@ -165,6 +165,23 @@ class ModelRunner:
         # composes back to the canonical grouping j // (H/nkv).
         self.canonical_spec = spec
         self.canonical_nkv = spec.num_kv_heads
+        if spec.block_kind == "smallthinker":
+            # Paths that hold their own copy of the dense block, or would
+            # partition what has no partitioning rule (the grouped expert
+            # product, the window layers' kernel), refuse the kind by name
+            # here, at start-up; none runs it with the dense block's rules.
+            for what, on in (
+                    ("a tp/pp/dp/sp mesh (its experts and the grouped "
+                     "product have no partitioning rule yet)",
+                     config.tp * config.pp * config.dp * config.sp > 1),
+                    ("speculative decoding (spec_decode)",
+                     bool(config.spec_decode)),
+                    ("LoRA adapters (max_adapters)", config.max_adapters > 0),
+                    ("ring attention", config.ring_attention),
+                    ("the pipelined prefill (pp_microbatch)",
+                     config.pp_microbatch)):
+                if on:
+                    raise UnsupportedBlockError(spec.block_kind, what)
         if spec.num_heads % config.tp != 0:
             raise ValueError(
                 f"num_heads={spec.num_heads} not divisible by tp={config.tp}")
@@ -210,6 +227,9 @@ class ModelRunner:
         dev_array = np.array(devices[:total]).reshape(
             config.dp, config.pp, config.sp, config.tp)
         self.mesh = Mesh(dev_array, ("dp", "pp", "sp", "tp"))
+        # A routed block's experts are whole on one device: model.ffn_block
+        # may then compute a long batch by the chosen experts only.
+        self.experts_local = self.mesh.size == 1
         # This process's first mesh device: what memory is sized from and
         # read back from, and whose platform decides everything that
         # differs between a chip and the CPU backend (never the process
@@ -548,7 +568,8 @@ class ModelRunner:
                     page_table, seq_lens, hist_table, hist_lens,
                     self._attention_impl, sp_shard=sp_shard,
                     x_embeds=emb, embeds_mask=emb_mask,
-                    lora=lora, adapter_ids=adapter_ids)
+                    lora=lora, adapter_ids=adapter_ids,
+                    experts_local=self.experts_local)
             elif pipelined:
                 from dynamo_tpu.engine.model import (
                     prefill_forward_pipelined)
@@ -562,7 +583,8 @@ class ModelRunner:
                     ring_mesh=(self.mesh if sp_shard
                                and self.config.ring_attention else None),
                     x_embeds=emb, embeds_mask=emb_mask,
-                    lora=lora, adapter_ids=adapter_ids)
+                    lora=lora, adapter_ids=adapter_ids,
+                    experts_local=self.experts_local)
             with perf.scope("sample"):
                 if penalized:
                     freq = jax.lax.bitcast_convert_type(packed[:, 7],
@@ -611,7 +633,8 @@ class ModelRunner:
                  seq_lens, temperature, top_k, top_p, rng):
             logits, k_cache, v_cache = decode_forward(
                 params, spec, k_cache, v_cache, tokens, positions,
-                page_table, seq_lens, attention_impl=self._attention_impl)
+                page_table, seq_lens, attention_impl=self._attention_impl,
+                experts_local=self.experts_local)
             rng, sub = jax.random.split(rng)
             sampled = sample_tokens(logits, temperature, top_k, top_p, sub)
             return sampled, k_cache, v_cache, rng
@@ -671,6 +694,10 @@ class ModelRunner:
                 vbuf0 = jnp.zeros((L, nkv, B, window, d), v_cache.dtype)
 
             want_lp = jnp.any(packed[:, PK_LOGPROB] > 0)
+            # A routed block's window also counts what its routing did to
+            # the live rows (model.moe_load_stats), summed over steps and
+            # layers on the device: one [3] vector more in the readback.
+            routed = bool(spec.num_experts)
 
             def step(carry, m):
                 tokens, positions, kbuf, vbuf, rng, cnts = carry
@@ -678,11 +705,13 @@ class ModelRunner:
                 # pages; at capacity it freezes in-graph (the host emits
                 # LENGTH when it sees the cap).
                 live = (seq_lens0 > 0) & (positions < cap)
-                logits, k_new, v_new = decode_window_step(
+                logits, k_new, v_new, *moe = decode_window_step(
                     params, spec, k_cache, v_cache, kbuf, vbuf, m, tokens,
                     positions, page_table, hist_lens,
                     attention_impl=self._window_attention_impl,
-                    lora=lora, adapter_ids=adapter_ids)
+                    lora=lora, adapter_ids=adapter_ids,
+                    live=live if routed else None,
+                    experts_local=self.experts_local)
                 # Append this step's K/V ([L,B,Nkv,D] -> window col m).
                 with perf.scope("kv.commit"):
                     kbuf = jax.lax.dynamic_update_slice(
@@ -733,13 +762,15 @@ class ModelRunner:
                     tokens = jnp.where(live, sampled, tokens)
                     positions = positions + live.astype(jnp.int32)
                 return (tokens, positions, kbuf, vbuf, rng, cnts), (
-                    sampled, lp, top_v, top_i)
+                    sampled, lp, top_v, top_i, *moe)
 
             carry0 = (tokens0, positions0, kbuf0, vbuf0, rng,
                       counts if penalized else jnp.zeros((), jnp.uint8))
             (tokens, _, kbuf, vbuf, rng, counts_out), \
-                (toks, lps, top_vs, top_is) = \
+                (toks, lps, top_vs, top_is, *moe) = \
                 jax.lax.scan(step, carry0, jnp.arange(window))
+            # [M, L, 3] -> [3]: layer-steps with a live row last.
+            moe = [jnp.sum(moe[0], axis=(0, 1))] if routed else []
             # Commit the window: scatter every (slot, step) entry into its
             # page. Frozen/inactive entries land on the scratch page 0.
             with perf.scope("kv.commit"):
@@ -762,8 +793,9 @@ class ModelRunner:
                                          dest, off)
             if penalized:
                 return (toks, lps, top_vs, top_is, tokens, k_cache,
-                        v_cache, rng, counts_out)
-            return toks, lps, top_vs, top_is, tokens, k_cache, v_cache, rng
+                        v_cache, rng, counts_out, *moe)
+            return (toks, lps, top_vs, top_is, tokens, k_cache, v_cache,
+                    rng, *moe)
 
         donate = (1, 2, 6) if penalized else (1, 2)
         fn = perf.instrumented_jit("decode_window", run_window, key=key,
@@ -1254,7 +1286,10 @@ class ModelRunner:
         Returns (toks [M,B], lp [M,B], top_v [M,B,K], top_i [M,B,K])
         device arrays (fetch with np.asarray when needed; start async
         copies early via .copy_to_host_async()). The logprob arrays are
-        zeros unless some slot set PK_LOGPROB.
+        zeros unless some slot set PK_LOGPROB. A routed block adds a fifth,
+        float32 [3]: over the window's steps and expert layers, the sum of
+        distinct experts the live rows chose, the sum of the fullest
+        expert's tokens over the mean, and the layer-steps counted.
         """
         bucket_pages = packed.shape[1] - PK_PREFIX
         # Specialize on whether any slot carries penalties THIS window —
@@ -1268,16 +1303,16 @@ class ModelRunner:
         with self.mesh:
             if penalized:
                 (toks, lps, top_vs, top_is, self.tokens_dev, self.k_cache,
-                 self.v_cache, self._rng, self.counts_dev) = fn(
+                 self.v_cache, self._rng, self.counts_dev, *moe) = fn(
                     self.params, self.k_cache, self.v_cache,
                     self.tokens_dev, jnp.asarray(packed), self._rng,
                     self.counts_dev, **kw)
             else:
                 (toks, lps, top_vs, top_is, self.tokens_dev, self.k_cache,
-                 self.v_cache, self._rng) = fn(
+                 self.v_cache, self._rng, *moe) = fn(
                     self.params, self.k_cache, self.v_cache,
                     self.tokens_dev, jnp.asarray(packed), self._rng, **kw)
-        return toks, lps, top_vs, top_is
+        return (toks, lps, top_vs, top_is, *moe)
 
     def embed(self, token_lists: list[list[int]],
               pooling: str = "last") -> np.ndarray:
@@ -1573,7 +1608,8 @@ def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
                           page_table, seq_lens, hist_table, hist_lens,
                           attention_impl, sp_shard: bool = False,
                           x_embeds=None, embeds_mask=None,
-                          lora=None, adapter_ids=None):
+                          lora=None, adapter_ids=None,
+                          experts_local: bool = False):
     """Chunked prefill: like prefill_forward but queries also attend to the
     sequence's earlier pages (read via the paged path). x_embeds/embeds_mask
     override token embeddings under multimodal media spans (rows are
@@ -1581,9 +1617,10 @@ def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
     first chunk — injects correctly."""
     import jax
     import jax.numpy as jnp
+    from dynamo_tpu.engine.kv_quant import gather_pages_folded
     from dynamo_tpu.engine.model import (
-        _split_heads, apply_rope, embed_lookup, ffn_block, lm_logits, mm,
-        rms_norm, rope_tables)
+        embed_lookup, layer_kind, lm_logits, rms_norm, rope_tables,
+        transformer_block, window_reach)
 
     b, s = tokens.shape
     d = spec.head_dim
@@ -1606,62 +1643,51 @@ def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
             lp, layer, ll = scan_in
         else:
             (lp, layer), ll = scan_in, None
-        with perf.scope("attn.qkv"):
-            h = rms_norm(x, lp["input_norm"], spec.rms_norm_eps)
-            q = mm(h, lp["wq"], "bsh,hd->bsd")
-            k = mm(h, lp["wk"], "bsh,hd->bsd")
-            v = mm(h, lp["wv"], "bsh,hd->bsd")
-            if ll is not None:
-                from dynamo_tpu.engine.model import qkv_lora
-                q, k, v = qkv_lora(q, k, v, h, ll, adapter_ids)
-            if spec.qkv_bias:
-                q = q + lp["bq"]
-                k = k + lp["bk"]
-                v = v + lp["bv"]
-            q = _split_heads(q, spec.num_heads, d)
-            k = _split_heads(k, nkv, d)
-            v = _split_heads(v, nkv, d)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-        # In-chunk causal scores (grouped GQA, no repeat).
-        with perf.scope("attn.core"):
-            qg = q.reshape(b, s, nkv, spec.q_per_kv, d)
-            chunk_scores = jnp.einsum("bqngd,bknd->bngqk", qg, k,
-                                      preferred_element_type=jnp.float32)
-            causal = (positions[:, None, None, :, None]
-                      >= positions[:, None, None, None, :])
-            chunk_scores = jnp.where(causal & valid[:, None, None, None, :],
-                                     chunk_scores, -1e30)
-        # History over prior pages: layer+head-folded gather from the
-        # stacked cache straight into the dot's [Nkv,B,L,D] layout
-        # (hist pages are disjoint from this chunk's pages, whose
-        # writes are deferred out of the scan).
-        from dynamo_tpu.engine.kv_quant import gather_pages_folded
-        with perf.scope("attn.kv_gather"):
-            k_hist = gather_pages_folded(k_cache, layer, hist_table)
-            v_hist = gather_pages_folded(v_cache, layer, hist_table)
-        with perf.scope("attn.core"):
-            hist_scores = jnp.einsum("bqngd,nbld->bngql", qg, k_hist,
-                                     preferred_element_type=jnp.float32)
-            hist_valid = (jnp.arange(maxp * page)[None, :]
-                          < hist_lens[:, None])[:, None, None, None, :]
-            hist_scores = jnp.where(hist_valid, hist_scores, -1e30)
-            scores = jnp.concatenate([hist_scores, chunk_scores], axis=-1)
-            scores = scores / jnp.sqrt(jnp.float32(d))
-            probs = jax.nn.softmax(scores, axis=-1).astype(jnp.bfloat16)
-            p_hist, p_chunk = jnp.split(probs, [maxp * page], axis=-1)
-            attn = (jnp.einsum("bngql,nbld->bqngd", p_hist, v_hist)
-                    + jnp.einsum("bngqk,bknd->bqngd", p_chunk, v))
-            attn = attn.reshape(b, s, -1)
-        with perf.scope("attn.out"):
-            proj = mm(attn, lp["wo"], "bsd,dh->bsh")
-            if ll is not None:
-                from dynamo_tpu.engine.model import lora_delta
-                proj = proj + lora_delta(attn, ll["wo"], adapter_ids)
-            x = x + proj
-        with perf.scope("mlp"):
-            h2 = rms_norm(x, lp["post_attn_norm"], spec.rms_norm_eps)
-            x = x + ffn_block(h2, lp, spec, ll, adapter_ids)
+
+        def attend(q, k, v, kind):
+            reach = window_reach(spec, kind)
+            # In-chunk causal scores (grouped GQA, no repeat).
+            with perf.scope("attn.core"):
+                qg = q.reshape(b, s, nkv, spec.q_per_kv, d)
+                chunk_scores = jnp.einsum("bqngd,bknd->bngqk", qg, k,
+                                          preferred_element_type=jnp.float32)
+                causal = (positions[:, None, None, :, None]
+                          >= positions[:, None, None, None, :])
+                seen = causal & valid[:, None, None, None, :]
+                if reach is not None:
+                    seen = seen & (positions[:, None, None, :, None] - reach
+                                   < positions[:, None, None, None, :])
+                chunk_scores = jnp.where(seen, chunk_scores, -1e30)
+            # History over prior pages: layer+head-folded gather from the
+            # stacked cache straight into the dot's [Nkv,B,L,D] layout
+            # (hist pages are disjoint from this chunk's pages, whose
+            # writes are deferred out of the scan).
+            with perf.scope("attn.kv_gather"):
+                k_hist = gather_pages_folded(k_cache, layer, hist_table)
+                v_hist = gather_pages_folded(v_cache, layer, hist_table)
+            with perf.scope("attn.core"):
+                hist_scores = jnp.einsum("bqngd,nbld->bngql", qg, k_hist,
+                                         preferred_element_type=jnp.float32)
+                hist_pos = jnp.arange(maxp * page)[None, :]
+                hist_valid = (hist_pos
+                              < hist_lens[:, None])[:, None, None, None, :]
+                if reach is not None:
+                    # History token l stands at position l.
+                    hist_valid = hist_valid & (
+                        positions[:, None, None, :, None] - reach
+                        < hist_pos[:, None, None, None, :])
+                hist_scores = jnp.where(hist_valid, hist_scores, -1e30)
+                scores = jnp.concatenate([hist_scores, chunk_scores], axis=-1)
+                scores = scores / jnp.sqrt(jnp.float32(d))
+                probs = jax.nn.softmax(scores, axis=-1).astype(jnp.bfloat16)
+                p_hist, p_chunk = jnp.split(probs, [maxp * page], axis=-1)
+                attn = (jnp.einsum("bngql,nbld->bqngd", p_hist, v_hist)
+                        + jnp.einsum("bngqk,bknd->bqngd", p_chunk, v))
+                return attn.reshape(b, s, -1)
+
+        x, k, v, _ = transformer_block(
+            x, lp, spec, cos, sin, attend, layer_kind(spec, layer), ll,
+            adapter_ids, experts_local=experts_local)
         return x, (k, v)
 
     xs = ((params["layers"], jnp.arange(L), lora) if lora is not None

@@ -24,6 +24,12 @@ def load_hf_weights(spec: ModelSpec, model_dir: str):
     import ml_dtypes
     from safetensors import safe_open
 
+    if spec.block_kind == "smallthinker":
+        # Its tensor names are not the Llama / Mixtral ones mapped below.
+        from dynamo_tpu.engine.config import UnsupportedBlockError
+        raise UnsupportedBlockError(spec.block_kind,
+                                    "the safetensors loader (random weights "
+                                    "only: no name map for its checkpoint)")
     files = sorted(glob.glob(os.path.join(model_dir, "*.safetensors")))
     if not files:
         raise FileNotFoundError(f"no safetensors under {model_dir}")

@@ -57,11 +57,17 @@ log = get_logger("flight")
 # engine.idle; they add up to the time between two rows), "rows" (slot
 # rows the window was dispatched with), "page_bucket" (the page-table
 # width of its program) and "missed" (rows refused while frozen or skipped
-# as idle since the previous row).
+# as idle since the previous row). A routed block's window adds what its
+# expert layers' routing did to the live rows, summed over the window's
+# steps and layers on the device: "moe_touched" (distinct experts chosen),
+# "moe_load" (the fullest expert's tokens over the mean) and
+# "moe_layer_steps" (how many (step, layer) pairs the two sums hold); 0 for
+# a dense model.
 FIELDS = ("t_mono", "dur_s", "active", "waiting", "free_pages",
           "chunk_tokens", "chunks_inflight", "preempts", "brownout",
           "stall_s", "step", "tokens", "period_s", "host_s", "wait_s",
-          "idle_s", "rows", "page_bucket", "missed")
+          "idle_s", "rows", "page_bucket", "missed", "moe_touched",
+          "moe_load", "moe_layer_steps")
 _INT_FIELDS = ("active", "waiting", "free_pages", "chunk_tokens",
                "chunks_inflight", "preempts", "brownout", "step", "tokens",
                "rows", "page_bucket", "missed")
@@ -110,7 +116,8 @@ class FlightRecorder:
                step: int, tokens: int = 0, period_s: float = 0.0,
                host_s: float = 0.0, wait_s: float = 0.0,
                idle_s: float = 0.0, rows: int = 0,
-               page_bucket: int = 0) -> bool:
+               page_bucket: int = 0, moe_touched: float = 0.0,
+               moe_load: float = 0.0, moe_layer_steps: float = 0.0) -> bool:
         """One engine-window row. Idle-stable windows (no active slots,
         no waiters, no chunk work — same as the previous call) are
         skipped without touching the ring. Returns False when the row
@@ -151,6 +158,9 @@ class FlightRecorder:
             cols["idle_s"][i] = idle_s
             cols["rows"][i] = rows
             cols["page_bucket"][i] = page_bucket
+            cols["moe_touched"][i] = moe_touched
+            cols["moe_load"][i] = moe_load
+            cols["moe_layer_steps"][i] = moe_layer_steps
             cols["missed"][i] = self._missed[0]
             self._missed[0] = 0
             self._idx = (i + 1) % self.capacity

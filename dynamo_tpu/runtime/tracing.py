@@ -247,10 +247,6 @@ class SpanRecorder:
         with self._lock:
             return list(self._spans), self.dropped
 
-    def _snapshot(self) -> list[Span]:
-        # benchmark/run.py reads the ring under this name.
-        return self.snapshot()[0]
-
 
 def _otlp_value(v) -> dict:
     if isinstance(v, bool):

@@ -356,3 +356,22 @@ def test_warmup_ladder_off_is_noop(chunked_engine):
     keys_before = set(chunked_engine.runner._prefill_cache)
     chunked_engine._warmup_prefill_ladder()
     assert set(chunked_engine.runner._prefill_cache) == keys_before
+
+
+@async_test
+async def test_a_prompt_past_the_chunk_budget_is_chunked_not_sent_whole(params):
+    """A prompt goes whole only if it fits one iteration's chunk budget,
+    whatever the largest bucket would take: on the chip a 5,000-token
+    prompt under the defaults (budget 1024, largest bucket 8192) was
+    compiled whole, did not fit, and was answered 500 (PR 28)."""
+    eng = TPUEngine(cfg(max_prefill_tokens=128, prefill_chunk_tokens=32),
+                    params=params)
+    try:
+        short, _ = await run_one(eng, _prompt(3, 30), 4)
+        assert eng.chunk_dispatch_count == 0 and len(short) == 4
+        long, _ = await run_one(eng, _prompt(4, 100), 4)  # under 128, over 32
+        assert len(long) == 4
+        assert eng.chunk_dispatch_count == 4            # 32 + 32 + 32 + 4
+        assert eng.chunk_tokens_total == 100
+    finally:
+        eng.stop()

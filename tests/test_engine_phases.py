@@ -465,4 +465,3 @@ def test_span_recorder_snapshot_is_public_and_says_what_was_dropped():
         rec.add("engine.decode", "t" * 32, None, float(i), float(i) + 1.0)
     spans, dropped = rec.snapshot()
     assert [s.start_mono for s in spans] == [1.0, 2.0] and dropped == 1
-    assert rec._snapshot() == spans  # the name benchmark/run.py reads

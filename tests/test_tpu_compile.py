@@ -52,6 +52,8 @@ def no_persistent_cache():
 # and the benchmark cell's qwen2.5-7b.
 WIDTHS = {"qwen2.5-0.5b": (64, 2, 7, 24), "llama-3-8b": (128, 8, 4, 32),
           "qwen2.5-7b": (128, 4, 7, 28)}
+# SmallThinker-21B-A3B's attention is qwen2.5-7b's geometry over 24 layers.
+WIDTHS["smallthinker-21b-a3b"] = (128, 4, 7, 24)
 
 
 def _shapes(one, model, quantized, b=40, maxp=64):
@@ -93,3 +95,55 @@ def test_window_kernel_compiles_for_v5e(v5e, quantized):
         lambda *a: paged_window_attention_pallas(*a, q_per_kv=qpk)
     ).lower(*head, win, win, step, self_kv, self_kv).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_windowed_window_kernel_compiles_for_v5e(v5e):
+    """The variant a model with sliding-window layers runs: a fourth
+    prefetched vector, the first token each row still sees, and a walk that
+    starts at its chunk."""
+    head, self_kv, qpk = _shapes(v5e, "smallthinker-21b-a3b", False)
+    b, nkv, d = self_kv.shape
+    win = jax.ShapeDtypeStruct((nkv, b, 4, d), jnp.bfloat16, sharding=v5e)
+    step = jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e)
+    lo = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=v5e)
+    compiled = jax.jit(
+        lambda *a, lo: paged_window_attention_pallas(*a, q_per_kv=qpk, lo=lo)
+    ).lower(*head, win, win, step, self_kv, self_kv, lo=lo).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows", [32, 1024, 4096],
+                         ids=["masked", "masked at the limit", "grouped"])
+def test_smallthinker_expert_layer_compiles_for_v5e(v5e, rows):
+    """The expert layer at the published widths (64 int8 experts of 2560 x
+    768) on both sides of MOE_DENSE_MAX_ROWS: the product over resident
+    experts under the gate mask, and the grouped product over tokens sorted
+    by expert, whose operations are the chosen experts' only."""
+    from dynamo_tpu.engine import model
+    from dynamo_tpu.engine.config import SmallThinkerSpec
+    from dynamo_tpu.engine.quant import QTensor
+    spec = SmallThinkerSpec(
+        hidden_size=2560, intermediate_size=768, num_layers=4, num_heads=28,
+        num_kv_heads=4, head_dim=128, num_experts=64, num_experts_per_tok=6,
+        moe_intermediate_size=768, quant="int8")
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def q(shape):
+        return QTensor(s(shape, jnp.int8),
+                       s((*shape[:-2], 1, shape[-1]), jnp.float32))
+
+    lp = {"moe_gate": s((2560, 64), jnp.bfloat16),
+          "moe_w_gate": q((64, 2560, 768)), "moe_w_up": q((64, 2560, 768)),
+          "moe_w_down": q((64, 768, 2560))}
+    x = s((rows, 2560), jnp.bfloat16)
+    compiled = jax.jit(lambda x, lp: model.ffn_block(
+        x, lp, spec, router_in=x, experts_local=True)).lower(x, lp).compile()
+    flops = compiled.cost_analysis()["flops"]
+    chosen = 2 * rows * 6 * 3 * 2560 * 768
+    if rows > model.MOE_DENSE_MAX_ROWS:
+        assert flops < 1.5 * chosen, (flops, chosen)
+    else:
+        assert flops > 64 / 6 * 0.9 * chosen, (flops, chosen)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30

@@ -39,7 +39,7 @@ def test_span_nesting_and_attrs():
         with span("child"):
             time.sleep(0.002)
         sp.set(b=2)
-    spans = rec.trace(rec._snapshot()[0].trace_id)
+    spans = rec.trace(rec.snapshot()[0][0].trace_id)
     assert [s.name for s in spans] == ["root", "child"]
     root, child = spans
     assert child.parent_span_id == root.span_id
@@ -54,7 +54,7 @@ def test_span_error_status():
     with pytest.raises(RuntimeError):
         with span("boom"):
             raise RuntimeError("nope")
-    s = rec._snapshot()[-1]
+    s = rec.snapshot()[0][-1]
     assert s.status == "error"
     assert "RuntimeError" in s.attrs["error"]
 
@@ -65,7 +65,7 @@ def test_span_adopts_request_context():
     ctx = Context()
     with span("http.request", ctx=ctx):
         pass
-    s = rec._snapshot()[-1]
+    s = rec.snapshot()[0][-1]
     assert s.span_id == ctx.span_id
     assert s.trace_id == ctx.trace_id
     # Nested ctx adoption (worker.request already holds ctx.span_id):
@@ -73,7 +73,7 @@ def test_span_adopts_request_context():
     with span("worker.request", ctx=ctx):
         with span("inner", ctx=ctx):
             pass
-    inner = rec._snapshot()[-2]
+    inner = rec.snapshot()[0][-2]
     assert inner.name == "inner"
     assert inner.span_id != ctx.span_id
     assert inner.parent_span_id == ctx.span_id
@@ -88,7 +88,7 @@ async def test_span_parenting_across_asyncio_tasks():
                 await asyncio.sleep(0.001)
 
         await asyncio.gather(worker(0), worker(1), worker(2))
-    spans = rec._snapshot()
+    spans = rec.snapshot()[0]
     outer = [s for s in spans if s.name == "outer"][0]
     inners = [s for s in spans if s.name == "inner"]
     assert len(inners) == 3
@@ -102,7 +102,7 @@ def test_ring_buffer_eviction():
     rec = SpanRecorder(capacity=8)
     for i in range(20):
         rec.add(f"s{i}", "ab" * 16, None, float(i), float(i) + 0.5)
-    spans = rec._snapshot()
+    spans = rec.snapshot()[0]
     assert len(spans) == 8
     assert rec.dropped == 12
     # Oldest evicted first.
@@ -277,7 +277,7 @@ def test_disabled_recorder_is_noop_singleton():
     with span("x") as sp:
         sp.set(a=1)  # no-op, no error
     assert rec.add("x", "ab" * 16, None, 0.0, 1.0) is None
-    assert rec._snapshot() == []
+    assert rec.snapshot()[0] == []
 
 
 def test_disabled_recorder_zero_allocations():
