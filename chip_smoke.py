@@ -565,6 +565,14 @@ async def phase_kernels(args, jax, rng, keep: dict):
             check(resolved == want,
                   f"asked for {backend}, expected {want}, runner resolved "
                   f"{resolved}")
+            # The window's commit follows its reader (_pick_kv_commit): in
+            # place beside the kernel on a plain bf16 pool at head_dim 128,
+            # so the third round compares it with the scatter's logprobs.
+            commit = eng.runner.kv_commit_backend
+            check((commit == "in_place") == (
+                resolved == "pallas" and spec_r.head_dim == 128
+                and quant_kv is None), f"{resolved} reader at head_dim "
+                f"{spec_r.head_dim}, {quant_kv or 'bf16'} KV: commit {commit}")
             t0 = time.monotonic()
             runs[backend] = await asyncio.gather(
                 *[engine_generate(eng, p, n_out) for p in prompts])
@@ -595,9 +603,9 @@ async def phase_kernels(args, jax, rng, keep: dict):
                       f"{custom_call} on {jax.devices()[0].platform}")
             emit("kernels.run", model=spec_r.name,
                  quant_kv=quant_kv or "bf16", attention_backend=backend,
-                 resolved=resolved, prompt_lengths=lengths,
-                 chunk_tokens=chunks, seconds=round(seconds, 2),
-                 tpu_custom_call=custom_call)
+                 resolved=resolved, kv_commit_backend=commit,
+                 prompt_lengths=lengths, chunk_tokens=chunks,
+                 seconds=round(seconds, 2), tpu_custom_call=custom_call)
             if backend == "xla" and spec_r is spec and quant_kv is None:
                 agg = eng
             else:

@@ -43,6 +43,16 @@ around the kernel, once per layer (qwen2.5-0.5b: 247 ms a decode step
 against 8.5 through the gather). So the packed variant is correct, is
 compiled and compared on the chip by chip_smoke.py, and is not what "auto"
 selects; it waits for a lane-dense pool (ROADMAP D3).
+
+The writer beside the reader (commit_window_pallas, PR 29): the decode
+window's commit, for the pool this kernel reads as it lies (plain bf16,
+head_dim 128, one device). XLA's scatter wants the pool in another layout
+and converted it in and out, four pool-sized copies a window; the commit
+kernel copies in, merges and copies back only the pages a live row's window
+touched, all layers and KV heads of a page in one strided copy, the pools
+aliased to its outputs. Measured on one v5e (PERF.md section 6, PR 29), 18
+live rows of 32, both pools of the Qwen2.5-7B cell (2 x 2.8 GB): the
+scatter 33.7 ms a window, this 0.30 ms.
 """
 
 from __future__ import annotations
@@ -495,3 +505,114 @@ def paged_window_attention_pallas(q: jax.Array, k_cache: jax.Array,
          jnp.ones((b, 1), bool)], axis=1)[:, None, None, :]
     return _merge_extra(q, num, l_star, m_s, k_extra, v_extra, col_mask,
                         q_per_kv)
+
+
+def _commit_kernel(pid_ref, r0_ref, m0_ref, n_ref,  # SMEM prefetch, [B*J]
+                   kwin_ref, vwin_ref,  # VMEM blocks [L, Nkv, 1, M, D] f32
+                   k_in, v_in,  # the pools (ANY), aliased to k_hbm / v_hbm
+                   k_hbm, v_hbm, k_buf, v_buf, sems):
+    """One grid program per (row, touched page): the page's K and V rows
+    of every layer and KV head come into VMEM (one strided copy each, the
+    reader's ``hbm.at[layer, :, pid]`` turned to ``hbm.at[:, :, pid]``),
+    the window's tokens that fall on the page are selected into them, and
+    the page goes back where it came from. A program whose page takes no
+    token (a dead or frozen row, a window that stayed on its first page)
+    copies nothing."""
+    del k_in, v_in  # the same buffers as the outputs
+    i = pl.program_id(0)
+    n = n_ref[i]
+
+    @pl.when(n > 0)
+    def _():
+        pid, r0, m0 = pid_ref[i], r0_ref[i], m0_ref[i]
+        pools = ((k_hbm, k_buf, kwin_ref), (v_hbm, v_buf, vwin_ref))
+
+        def page_copy(s, hbm, buf, out: bool):
+            src, dst = (buf, hbm.at[:, :, pid]) if out else \
+                (hbm.at[:, :, pid], buf)
+            return pltpu.make_async_copy(src, dst, sems.at[s])
+
+        for s, (hbm, buf, _) in enumerate(pools):
+            page_copy(s, hbm, buf, False).start()
+        # Row r of this page takes window token m0 + (r - r0), r0 <= r <
+        # r0 + n: for token m, the rows whose offset from m0 - r0 is m.
+        row = jax.lax.broadcasted_iota(jnp.int32, k_buf.shape, 2)
+        token = jnp.where((row >= r0) & (row < r0 + n), row - r0 + m0, -1)
+        for s, (hbm, buf, win_ref) in enumerate(pools):
+            page_copy(s, hbm, buf, False).wait()
+            # Through float32 (exact both ways): the one width whose rows
+            # every TPU generation's VPU selects and broadcasts singly.
+            x = buf[...].astype(jnp.float32)
+            for m in range(win_ref.shape[3]):
+                x = jnp.where(token == m, win_ref[:, :, 0, m:m + 1, :], x)
+            buf[...] = x.astype(buf.dtype)
+            page_copy(s, hbm, buf, True).start()
+        for s, (hbm, buf, _) in enumerate(pools):
+            page_copy(s, hbm, buf, True).wait()
+
+
+def window_pages(positions0, cap, seq_lens0, page_table, window: int,
+                 page_size: int):
+    """Where a window's tokens land, page by page. A row's ``window``
+    tokens from ``positions0`` on touch at most J = ceil((window - 1) /
+    page) + 1 pages; for each (row, j), flattened to [B*J]: the pool page
+    ``pid``, the in-page row ``r0`` of the first token it takes, that
+    token's index ``m0`` in the window, and how many it takes, ``n`` (0: a
+    dead row, a row at its cap, a page the window did not reach)."""
+    J = -(-(window - 1) // page_size) + 1
+    n_live = jnp.where(seq_lens0 > 0,
+                       jnp.clip(cap - positions0, 0, window), 0)     # [B]
+    pj = positions0[:, None] // page_size + jnp.arange(J)[None, :]   # [B,J]
+    first = jnp.maximum(positions0[:, None], pj * page_size)
+    last = jnp.minimum((positions0 + n_live)[:, None], (pj + 1) * page_size)
+    n = jnp.maximum(last - first, 0)
+    pid = jnp.take_along_axis(
+        page_table, jnp.clip(pj, 0, page_table.shape[1] - 1), axis=1)
+    return tuple(a.astype(jnp.int32).reshape(-1) for a in (
+        jnp.where(n > 0, pid, 0), first - pj * page_size,
+        first - positions0[:, None], n))
+
+
+def commit_window_pallas(k_cache: jax.Array, v_cache: jax.Array,
+                         k_win: jax.Array, v_win: jax.Array,
+                         positions0: jax.Array, cap: jax.Array,
+                         seq_lens0: jax.Array, page_table: jax.Array,
+                         interpret: bool = False):
+    """The decode window's commit, in place: the pools [L,Nkv,P,page,D]
+    stay where and how they lie (row-major, what the decode kernel reads)
+    and are aliased to the outputs; of each live row only the pages its
+    window touched are read, merged with the window's buffer k_win/v_win
+    [L,Nkv,B,M,D] and written back. Token m of row b lands at position
+    positions0[b] + m while that is under cap[b] (and seq_lens0[b] > 0),
+    where kv_quant.scatter_tokens puts it; a token that does not land is
+    written nowhere (the scatter sends it to scratch page 0). A written
+    page must belong to one row: the page being appended to is private
+    (kv_cache.PageAllocator shares full pages only)."""
+    L, nkv, _, page_size, d = k_cache.shape
+    b, window = k_win.shape[2], k_win.shape[3]
+    prefetch = window_pages(positions0, cap, seq_lens0, page_table, window,
+                            page_size)
+    J = prefetch[0].shape[0] // b
+    win = pl.BlockSpec((L, nkv, 1, window, d),
+                       lambda i, *_: (0, 0, i // J, 0, 0))
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    buf = pltpu.VMEM((L, nkv, page_size, d), k_cache.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(b * J,),
+        in_specs=[win, win, any_spec, any_spec],
+        out_specs=(any_spec, any_spec),
+        scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2,))],
+    )
+    n_pre = len(prefetch)
+    return pl.pallas_call(
+        _commit_kernel,
+        grid_spec=grid_spec,
+        out_shape=(jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
+                   jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)),
+        input_output_aliases={n_pre + 2: 0, n_pre + 3: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(*prefetch, k_win.astype(jnp.float32), v_win.astype(jnp.float32),
+      k_cache, v_cache)

@@ -1046,6 +1046,9 @@ class TPUEngine(AsyncEngine):
             },
             "hbm": self.runner.hbm_stats(),
             "memory": self.runner.memory_breakdown(),
+            # Static per runner: how the window program writes the pool
+            # (runner._pick_kv_commit).
+            "kv_commit_backend": self.runner.kv_commit_backend,
             # Engine-thread self time by loop phase, seconds since the
             # loop started (engine_phase_seconds_total on /metrics).
             "phases": {k: round(v, 6)

@@ -15,7 +15,9 @@ pages), dequantized in the same fused expression that reads them:
 - the XLA gather paths multiply the gathered pages by the gathered
   scales, which XLA fuses into the gather consumer;
 - quantization is fused into every KV write: the prefill page scatter
-  and the per-window decode commit scatter quantize in-graph.
+  and the per-window decode commit scatter quantize in-graph (an int8
+  pool's window program keeps that scatter; a bf16 pool under the Pallas
+  kernel commits in place, see scatter_tokens).
 
 Per-token scales (not one scale per page) are what make the decode
 commit correct: a page fills across multiple windows, and a
@@ -146,10 +148,44 @@ def scatter_pages(cache, blocks, flat_pages):
     return cache.at[:, :, flat_pages].set(blocks)
 
 
+def window_token_slots(positions0, cap, seq_lens0, page_table, window: int,
+                       page_size: int):
+    """(dest, off) [M, B] for scatter_tokens: the pool page and the in-page
+    row of token m of row b of a decode window, which lands at position
+    positions0[b] + m while that is under cap[b] and the row is live
+    (seq_lens0[b] > 0); frozen and inactive entries land on scratch page 0,
+    row 0."""
+    import jax.numpy as jnp
+
+    m_idx = jnp.arange(window)[:, None]                              # [M,1]
+    adv = jnp.clip(jnp.minimum(m_idx, cap[None, :] - positions0), 0, None)
+    pos_m = positions0[None, :] + adv                                # [M,B]
+    live_m = (seq_lens0[None, :] > 0) & (pos_m < cap[None, :])
+    pidx = jnp.clip(pos_m // page_size, 0, page_table.shape[1] - 1)
+    dest = jnp.take_along_axis(
+        jnp.broadcast_to(page_table[None], (window, *page_table.shape)),
+        pidx[:, :, None], axis=2)[:, :, 0]                           # [M,B]
+    return jnp.where(live_m, dest, 0), jnp.where(live_m, pos_m % page_size, 0)
+
+
 def scatter_tokens(cache, vals, dest, off):
-    """Per-token commit ``cache.at[:, :, dest, off].set(vals)`` (the
-    decode-window scatter) with quantization fused in. vals [L, Nkv, ...,
-    D]; dest/off broadcastable index arrays."""
+    """Per-token commit ``cache.at[:, :, dest, off].set(vals)`` with
+    quantization fused in. vals [L, Nkv, ..., D]; dest/off broadcastable
+    index arrays.
+
+    Who still calls it: the single-step ``model.decode_forward``, the
+    speculative window (``runner._get_spec_window``), and the decode window
+    program wherever ``runner.kv_commit_backend`` is "scatter": a mesh, a
+    packed head (head_dim 64), an int8 pool, the CPU under "auto". On a TPU
+    XLA's scatter wants the pool as ``{4,1,3,2,0:T(4,128)}`` and converts a
+    row-major pool in and out, two pool-sized copies per cache per call
+    (PERF.md 6, PR 29). That is paid where the reader is XLA's gather too,
+    and not where the Pallas kernel reads a plain bf16 pool at head_dim
+    128: there the window program writes its pages in place
+    (``attention.commit_window_pallas``). Each of the others waits for a
+    reader whose layout its writer can match: int8 tiles are 32 rows over
+    pages of 16 and the scales are a second array; a packed head's pool
+    rests lane-padded (ROADMAP D3); the kernel has no partitioning rule."""
     if isinstance(cache, QuantKV):
         q, s = kv_quantize(vals)
         return QuantKV(cache.data.at[:, :, dest, off].set(q),

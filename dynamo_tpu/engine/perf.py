@@ -324,11 +324,15 @@ class _InstrumentedJit:
     these in place of the raw jitted function."""
 
     __slots__ = ("_fn", "_registry", "_program", "_key", "_calls",
-                 "_compiles", "_signature", "_scopes", "__weakref__")
+                 "_compiles", "_signature", "_scopes", "_labels",
+                 "__weakref__")
 
     def __init__(self, registry: "CompileRegistry", program: str,
-                 fn, key):
+                 fn, key, labels: dict | None = None):
         self._fn = fn
+        # What the builder chose statically for this program (the window
+        # program's kv_commit_backend): a name, not a count.
+        self._labels = dict(labels or {})
         self._registry = registry
         self._program = program
         self._key = key
@@ -411,8 +415,9 @@ class CompileRegistry:
         self.roofline_frac = 0.0       # EWMA achieved / weight-read roofline
 
     # -- compile observatory ---------------------------------------------------
-    def wrap(self, program: str, fn, key=None) -> _InstrumentedJit:
-        wrapper = _InstrumentedJit(self, program, fn, key)
+    def wrap(self, program: str, fn, key=None,
+             labels: dict | None = None) -> _InstrumentedJit:
+        wrapper = _InstrumentedJit(self, program, fn, key, labels)
         with self._lock:
             self._programs.setdefault(program, _Program(program))
             refs = self._wrappers.setdefault(program, [])
@@ -565,6 +570,7 @@ class CompileRegistry:
                     "unexpected_recompiles": p.unexpected,
                     "cost": p.cost,
                     "last_compile_ts": p.last_compile_ts,
+                    "labels": self._labels_of(name),
                 }
                 for name, p in sorted(self._programs.items())
             }
@@ -577,6 +583,16 @@ class CompileRegistry:
                 v["unexpected_recompiles"] for v in programs.values()),
             "warmup_complete": self.warmup_complete,
         }
+
+    def _labels_of(self, program: str) -> dict:
+        """Each label of the family's live programs with the distinct
+        values they carry (one, unless two runners in a process differ)."""
+        out: dict[str, list] = {}
+        for ref in self._wrappers.get(program, ()):
+            for k, v in getattr(ref(), "_labels", {}).items():
+                if v not in out.setdefault(k, []):
+                    out[k].append(v)
+        return out
 
     def window_snapshot(self) -> dict:
         """The /debug/perf "window" body (EWMA-smoothed live series)."""
@@ -612,16 +628,19 @@ def get_registry() -> CompileRegistry:
 
 
 def instrumented_jit(program: str, fun, *, key=None, registry=None,
-                     **jit_kwargs):
+                     labels: dict | None = None, **jit_kwargs):
     """The ONE sanctioned way to build a serving-path jit program:
     ``jax.jit`` + compile observatory in a drop-in wrapper. ``program``
     is the family label (``prefill``, ``decode_window``, ...); ``key``
     the shape-signature cache key the caller memoizes under (the
     recompile detector treats a second compile of the same key as
-    unexpected). Extra kwargs go straight to ``jax.jit``."""
+    unexpected); ``labels`` what the caller chose statically for the
+    family (the snapshot's ``labels``). Extra kwargs go straight to
+    ``jax.jit``."""
     reg = registry if registry is not None else _REGISTRY
     # dtpu: ignore[jit-recompile-hazard] until=2027-08-01 -- this IS the caching chokepoint: every caller memoizes the returned wrapper by its shape key
-    return reg.wrap(program, jax.jit(fun, **jit_kwargs), key=key)
+    return reg.wrap(program, jax.jit(fun, **jit_kwargs), key=key,
+                    labels=labels)
 
 
 def process_perf_status() -> dict:
@@ -680,6 +699,13 @@ class PerfMetricsUpdater:
         self.g_hbm_limit = registry.gauge(
             "perf_hbm_limit_bytes", "device.memory_stats bytes_limit on "
             "this worker's first addressable device")
+        self.g_kv_commit = registry.gauge(
+            "perf_kv_commit_info", "1 under the label of how this worker's "
+            "decode window program commits its tokens to the KV pool "
+            "(runner.kv_commit_backend): in_place (the touched pages are "
+            "rewritten where they lie, beside the Pallas reader) or "
+            "scatter (XLA's scatter; pool-sized layout copies on a TPU)",
+            ["backend"])
         self.c_spec_draft_tokens = registry.counter(
             "perf_spec_draft_tokens_total", "Speculative draft tokens "
             "proposed by the on-device n-gram drafter")
@@ -751,6 +777,9 @@ class PerfMetricsUpdater:
         runner = getattr(engine, "runner", None)
         hbm = runner.hbm_stats() if runner is not None and hasattr(
             runner, "hbm_stats") else {}
+        backend = getattr(runner, "kv_commit_backend", None)
+        if backend:
+            self.g_kv_commit.set(1, backend=backend)
         if hbm:
             self.g_hbm_in_use.set(hbm.get("bytes_in_use", 0))
             self.g_hbm_peak.set(hbm.get("peak_bytes_in_use", 0))

@@ -39,7 +39,8 @@ from dynamo_tpu.engine import perf
 from dynamo_tpu.engine.config import EngineConfig, UnsupportedBlockError
 from dynamo_tpu.engine.kv_quant import (KV_SCALE_BYTES, QuantKV, pack_parcel,
                                         parcel_to_bf16, quantize_np,
-                                        scatter_tokens, unpack_parcel)
+                                        scatter_tokens, unpack_parcel,
+                                        window_token_slots)
 from dynamo_tpu.engine.model import (
     dense_causal_attention,
     init_params,
@@ -242,6 +243,7 @@ class ModelRunner:
         # the start-up in milliseconds.
         self._attention_impl, self._window_attention_impl = \
             self._pick_attention()
+        self.kv_commit_backend = self._pick_kv_commit()
         self._sized_pages(self.device)
 
         # Shard or init parameters.
@@ -501,6 +503,21 @@ class ModelRunner:
                 functools.partial(paged_window_attention_pallas,
                                   interpret=interpret))
 
+    def _pick_kv_commit(self) -> str:
+        """How the decode window program writes its tokens into the pool,
+        decided by the observation that chose the reader: the writer must
+        leave the pool in the layout the reader reads. "in_place": the
+        Pallas decode kernel reads a plain bf16 pool row-major at head_dim
+        128, so attention.commit_window_pallas rewrites the touched pages
+        where they lie. "scatter" (kv_quant.scatter_tokens) everywhere
+        else: a mesh and the CPU under "auto" (the XLA reader), a packed
+        head (head_dim 64), int8 pages (QuantKV: tiles of 32 rows over
+        pages of 16, and the scales are a second array)."""
+        in_place = (self.attention_backend == "pallas"
+                    and self.mesh.size == 1 and self.spec.head_dim == 128
+                    and self.quant_kv is None)
+        return "in_place" if in_place else "scatter"
+
     # -- compiled steps -------------------------------------------------------
     def _get_prefill(self, bucket: int, batch: int, with_history: bool,
                      penalized: bool = False, seeded: bool = False,
@@ -684,10 +701,10 @@ class ModelRunner:
             d = spec.head_dim
             # Cache-resident history length is FIXED across the window: the
             # window's own tokens live in a small in-window buffer and are
-            # committed to the pool by ONE scatter at the end. The caches
-            # are read-only inside the scan — carrying a multi-GB pool
-            # through scan ys/carries makes XLA copy it per step (measured:
-            # 50 ms/step at a 3 GB pool, vs flat ~1.5 ms this way).
+            # committed to the pool ONCE, at the end. The caches are
+            # read-only inside the scan — carrying a multi-GB pool through
+            # scan ys/carries makes XLA copy it per step (measured: 50
+            # ms/step at a 3 GB pool, vs flat ~1.5 ms this way).
             hist_lens = jnp.maximum(seq_lens0 - 1, 0)
             with perf.scope("kv.commit"):
                 kbuf0 = jnp.zeros((L, nkv, B, window, d), k_cache.dtype)
@@ -771,26 +788,30 @@ class ModelRunner:
                 jax.lax.scan(step, carry0, jnp.arange(window))
             # [M, L, 3] -> [3]: layer-steps with a live row last.
             moe = [jnp.sum(moe[0], axis=(0, 1))] if routed else []
-            # Commit the window: scatter every (slot, step) entry into its
-            # page. Frozen/inactive entries land on the scratch page 0.
+
+            # Commit the window: every (slot, step) entry goes to its page.
             with perf.scope("kv.commit"):
-                m_idx = jnp.arange(window)[:, None]                      # [M,1]
-                adv = jnp.clip(jnp.minimum(m_idx, cap[None, :] - positions0),
-                               0, None)
-                pos_m = positions0[None, :] + adv                        # [M,B]
-                live_m = (seq_lens0[None, :] > 0) & (pos_m < cap[None, :])
-                pidx = jnp.clip(pos_m // page, 0, page_table.shape[1] - 1)
-                dest = jnp.take_along_axis(
-                    jnp.broadcast_to(page_table[None], (window, *page_table.shape)),
-                    pidx[:, :, None], axis=2)[:, :, 0]                   # [M,B]
-                dest = jnp.where(live_m, dest, 0)
-                off = jnp.where(live_m, pos_m % page, 0)
-                # kbuf [L,Nkv,B,M,D] -> [L,Nkv,M,B,D] matching index arrays.
-                # scatter_tokens quantizes int8 pools inside the same commit.
-                k_cache = scatter_tokens(k_cache, kbuf.transpose(0, 1, 3, 2, 4),
-                                         dest, off)
-                v_cache = scatter_tokens(v_cache, vbuf.transpose(0, 1, 3, 2, 4),
-                                         dest, off)
+                if self.kv_commit_backend == "in_place":
+                    # Only the pages a live row's window touched are
+                    # rewritten, where and how they lie: XLA's scatter
+                    # converts the whole pool to its own layout and back,
+                    # four pool-sized copies a window (PERF.md 6, PR 29).
+                    from dynamo_tpu.engine.attention import (
+                        commit_window_pallas)
+                    k_cache, v_cache = commit_window_pallas(
+                        k_cache, v_cache, kbuf, vbuf, positions0, cap,
+                        seq_lens0, page_table,
+                        interpret=self.device.platform == "cpu")
+                else:
+                    dest, off = window_token_slots(
+                        positions0, cap, seq_lens0, page_table, window, page)
+                    # kbuf [L,Nkv,B,M,D] -> [L,Nkv,M,B,D] matching the index
+                    # arrays; scatter_tokens quantizes int8 pools in the
+                    # same commit.
+                    k_cache = scatter_tokens(
+                        k_cache, kbuf.transpose(0, 1, 3, 2, 4), dest, off)
+                    v_cache = scatter_tokens(
+                        v_cache, vbuf.transpose(0, 1, 3, 2, 4), dest, off)
             if penalized:
                 return (toks, lps, top_vs, top_is, tokens, k_cache,
                         v_cache, rng, counts_out, *moe)
@@ -798,8 +819,9 @@ class ModelRunner:
                     rng, *moe)
 
         donate = (1, 2, 6) if penalized else (1, 2)
-        fn = perf.instrumented_jit("decode_window", run_window, key=key,
-                                   donate_argnums=donate)
+        fn = perf.instrumented_jit(
+            "decode_window", run_window, key=key, donate_argnums=donate,
+            labels={"kv_commit_backend": self.kv_commit_backend})
         self._window_cache[key] = fn
         return fn
 

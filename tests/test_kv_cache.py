@@ -177,3 +177,32 @@ def test_reuse_counters_track_hits_and_lookups():
     stats = a.stats()
     assert stats["reuse_hit_blocks"] == 2
     assert stats["reuse_lookup_blocks"] == 3
+
+
+def test_page_being_appended_to_belongs_to_one_sequence():
+    """What the in-place window commit rests on
+    (attention.commit_window_pallas reads, merges and rewrites whole
+    pages): a page a sequence still appends to is its own. Only a FULL
+    block has a hash to register (TokenBlockSequence hashes complete
+    blocks), so a prefix hit pins full pages and the open page is found
+    by no lookup until its owner fills it."""
+    from dynamo_tpu.llm.tokens import TokenBlockSequence
+    a = PageAllocator(num_pages=6, page_size=4)
+    owner = TokenBlockSequence(4, [1, 2, 3, 4, 5, 6])  # one full block + 2
+    pages = a.allocate(2)
+    assert owner.num_complete_blocks == 1
+    for page, h in zip(pages, owner.block_hashes):
+        a.register(page, h)
+    full, open_page = pages
+    # The same prompt again: the full page is shared, the open one is not.
+    twin = TokenBlockSequence(4, [1, 2, 3, 4, 5, 6])
+    assert a.acquire_cached(twin.block_hashes) == [full]
+    assert a.refs[full] == 2 and a.refs[open_page] == 1
+    assert open_page not in a.cached_by_page
+    fresh = a.allocate(1)  # the twin's own open page
+    assert fresh and fresh[0] not in (full, open_page)
+    # The owner fills its page: only now can it be registered and shared,
+    # and from now on the owner writes the NEXT page.
+    assert owner.append(7) is None
+    a.register(open_page, owner.append(8))
+    assert a.lookup(owner.block_hashes) == [full, open_page]

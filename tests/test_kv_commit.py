@@ -1,0 +1,206 @@
+"""The decode window's commit in place == kv_quant.scatter_tokens.
+
+attention.commit_window_pallas rewrites, of each live row, only the pages
+its window touched, where they lie in the pool; the scatter it stands in
+for writes the same bf16 values to the same (page, offset) and sends what
+does not land to scratch page 0. Interpret mode on the CPU checks results,
+bit for bit on every page but page 0; tests/test_tpu_compile.py compiles
+the kernel for a described v5e and reads the window program's text for
+pool-sized operations. Which program takes which commit is the runner's
+observation (ModelRunner._pick_kv_commit), checked here too.
+"""
+
+from types import SimpleNamespace
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dynamo_tpu.engine.attention import commit_window_pallas, window_pages
+from dynamo_tpu.engine.config import PRESETS, EngineConfig, ModelSpec
+from dynamo_tpu.engine.kv_quant import scatter_tokens, window_token_slots
+from dynamo_tpu.engine.runner import (PK_CAP, PK_OVERRIDE, PK_POS, PK_PREFIX,
+                                      PK_SEQLEN, PK_TOKEN, ModelRunner)
+
+PAGE = 16
+D = 128
+#: (layers, kv heads) of the two benchmark cells' pools.
+CELLS = {"qwen2.5-7b": (28, 4), "smallthinker-21b-a3b": (24, 4)}
+
+
+def scatter_commit(k_cache, kbuf, positions0, cap, seq_lens0, page_table):
+    """What ModelRunner's window program does where it scatters."""
+    dest, off = window_token_slots(positions0, cap, seq_lens0, page_table,
+                                   kbuf.shape[3], PAGE)
+    return scatter_tokens(k_cache, kbuf.transpose(0, 1, 3, 2, 4), dest, off)
+
+
+#: position, pages held (cap = pages x 16; 0: a dead slot) of each row:
+#: dead slots between live rows, a window that starts at offset 15, a row
+#: at its cap, one a token under it, one that fills its last page exactly.
+ROWS = [(15, 4), (0, 0), (37, 4), (0, 0), (64, 4), (63, 4), (50, 4), (0, 0),
+        (3, 2)]
+
+
+def _case(layers, nkv, window, seed=0):
+    rng = np.random.default_rng(seed)
+    b, maxp = len(ROWS), 4
+    pages = 1 + b * maxp
+    pos = np.array([p for p, _ in ROWS], np.int32)
+    held = np.array([n for _, n in ROWS], np.int32)
+    table = (1 + rng.permutation(pages - 1)).reshape(b, maxp).astype(np.int32)
+    table[held == 0] = 0
+    pool = (layers, nkv, pages, PAGE, D)
+    args = [jnp.asarray(rng.standard_normal(s), jnp.bfloat16)
+            for s in (pool, pool, (layers, nkv, b, window, D),
+                      (layers, nkv, b, window, D))]
+    return (*args, jnp.asarray(pos), jnp.asarray(held * PAGE),
+            jnp.asarray(np.where(held > 0, pos + 1, 0).astype(np.int32)),
+            jnp.asarray(table))
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+@pytest.mark.parametrize("window", [1, 4, 8, 20],
+                         ids=["window 1", "window 4", "window 8",
+                              "window 20 (three pages)"])
+def test_in_place_commit_equals_the_scatter_bit_for_bit(window, cell):
+    kc, vc, kb, vb, pos, cap, seq, table = _case(*CELLS[cell], window)
+    k_new, v_new = commit_window_pallas(kc, vc, kb, vb, pos, cap, seq, table,
+                                        interpret=True)
+    k_ref = scatter_commit(kc, kb, pos, cap, seq, table)
+    v_ref = scatter_commit(vc, vb, pos, cap, seq, table)
+    for new, ref, old in ((k_new, k_ref, kc), (v_new, v_ref, vc)):
+        np.testing.assert_array_equal(
+            np.asarray(new[:, :, 1:]).view(np.uint16),
+            np.asarray(ref[:, :, 1:]).view(np.uint16))
+        # What does not land is written nowhere: page 0 as it was.
+        np.testing.assert_array_equal(
+            np.asarray(new[:, :, 0]).view(np.uint16),
+            np.asarray(old[:, :, 0]).view(np.uint16))
+    changed = np.flatnonzero(
+        (np.asarray(k_new) != np.asarray(kc)).any(axis=(0, 1, 3, 4)))
+    assert len(changed) and set(changed) <= set(np.asarray(table).ravel())
+
+
+def test_window_pages_names_every_landing_token_once():
+    """The kernel's schedule against the scatter's index arrays: each
+    (row, page) entry's run [r0, r0 + n) of tokens [m0, m0 + n) is exactly
+    the live tokens the scatter sends to that page."""
+    window = 20
+    *_, pos, cap, seq, table = _case(1, 1, window)
+    pid, r0, m0, n = (np.asarray(a) for a in window_pages(
+        pos, cap, seq, table, window, PAGE))
+    j = -(-(window - 1) // PAGE) + 1
+    assert pid.shape == (len(ROWS) * j,)
+    landed = {}
+    for i in np.flatnonzero(n):
+        for t in range(n[i]):
+            landed[(i // j, m0[i] + t)] = (pid[i], r0[i] + t)
+    want = {}
+    for b, (p, held) in enumerate(ROWS):
+        for m in range(window):
+            if held and p + m < held * PAGE:
+                want[(b, m)] = (int(table[b, (p + m) // PAGE]),
+                                (p + m) % PAGE)
+    assert landed == want
+    assert (pid[n == 0] == 0).all()
+
+
+# -- the whole window program -------------------------------------------------
+
+SPEC128 = ModelSpec(name="tiny-128", vocab_size=256, hidden_size=256,
+                    intermediate_size=256, num_layers=2, num_heads=2,
+                    num_kv_heads=1, max_position_embeddings=2048)
+
+
+def _runner(**kw) -> ModelRunner:
+    defaults = dict(model=SPEC128, page_size=PAGE, num_pages=40,
+                    max_pages_per_seq=8, max_num_seqs=4,
+                    prefill_buckets=(32,), max_prefill_tokens=32)
+    defaults.update(kw)
+    return ModelRunner(EngineConfig(**defaults), seed=3)
+
+
+def _window_of(runner, window):
+    """One decode window over a pool of noise: rows at 15 (the window
+    crosses a page edge), dead, at 40, and one token under its cap."""
+    rng = np.random.default_rng(5)
+    noise = jnp.asarray(rng.standard_normal(runner.k_cache.shape),
+                        jnp.bfloat16)
+    runner.k_cache = jax.device_put(noise, runner.kv_sharding)
+    runner.v_cache = jax.device_put(-noise, runner.kv_sharding)
+    packed = np.zeros((4, PK_PREFIX + 8), np.int32)
+    for slot, (pos, pages) in enumerate([(15, 2), (0, 0), (40, 4), (47, 3)]):
+        if not pages:
+            continue
+        packed[slot, [PK_OVERRIDE, PK_TOKEN, PK_POS, PK_SEQLEN, PK_CAP]] = (
+            1, 7 + slot, pos, pos + 1, pages * PAGE)
+        packed[slot, PK_PREFIX:PK_PREFIX + pages] = 1 + 8 * slot + np.arange(
+            pages)
+    toks, *_ = runner.decode_window(packed, window)
+    return (np.asarray(toks), np.asarray(runner.k_cache).view(np.uint16),
+            np.asarray(runner.v_cache).view(np.uint16))
+
+
+@pytest.mark.parametrize("window", [4, 8])
+def test_run_window_in_place_gives_the_scatter_s_tokens_and_pool(window):
+    in_place = _runner(attention_backend="pallas")
+    assert in_place.kv_commit_backend == "in_place"
+    scatter = _runner(attention_backend="pallas")
+    scatter.kv_commit_backend = "scatter"  # steer the twin: same reader
+    toks_a, k_a, v_a = _window_of(in_place, window)
+    toks_b, k_b, v_b = _window_of(scatter, window)
+    np.testing.assert_array_equal(toks_a, toks_b)
+    np.testing.assert_array_equal(k_a[:, :, 1:], k_b[:, :, 1:])
+    np.testing.assert_array_equal(v_a[:, :, 1:], v_b[:, :, 1:])
+    text = in_place._get_window(window, 8).lower(
+        *_window_args(in_place)).as_text()
+    assert "stablehlo.scatter" not in text
+
+
+def _window_args(runner):
+    return (runner.params, runner.k_cache, runner.v_cache, runner.tokens_dev,
+            jnp.zeros((runner.config.max_num_seqs, PK_PREFIX + 8), jnp.int32),
+            runner._rng)
+
+
+# -- which program takes which commit ----------------------------------------
+
+def _picked(attention_backend, mesh_size, head_dim, quant_kv):
+    runner = object.__new__(ModelRunner)
+    runner.attention_backend = attention_backend
+    runner.mesh = SimpleNamespace(size=mesh_size)
+    runner.spec = SimpleNamespace(head_dim=head_dim)
+    runner.quant_kv = quant_kv
+    return runner._pick_kv_commit()
+
+
+@pytest.mark.parametrize("attention_backend, mesh_size, head_dim, quant_kv, "
+                         "want", [
+    ("pallas", 1, 128, None, "in_place"),   # both benchmark cells
+    ("xla", 1, 128, None, "scatter"),       # the CPU under "auto"; a request
+    ("xla", 4, 128, None, "scatter"),       # a mesh (its reader is XLA's)
+    ("pallas", 4, 128, None, "scatter"),    # never built; the mesh alone says
+    ("pallas", 1, 64, None, "scatter"),     # a packed head
+    ("pallas", 1, 128, "int8", "scatter"),  # QuantKV: two arrays, 32-row tiles
+])
+def test_commit_is_decided_from_reader_mesh_head_and_pool(
+        attention_backend, mesh_size, head_dim, quant_kv, want):
+    assert _picked(attention_backend, mesh_size, head_dim, quant_kv) == want
+
+
+@pytest.mark.parametrize("kw", [
+    dict(tp=2), dict(model=PRESETS["tiny-test"], attention_backend="pallas"),
+    dict(attention_backend="pallas", quant_kv="int8"), dict()],
+    ids=["a mesh", "head_dim 32 (packed)", "QuantKV", "the CPU under auto"])
+def test_programs_off_the_predicate_keep_the_scatter(kw):
+    """Built for real: the runner's label says scatter, the program's
+    lowered text holds the scatter of both pools and no commit kernel."""
+    runner = _runner(**kw)
+    assert runner.kv_commit_backend == "scatter"
+    with runner.mesh:
+        text = runner._get_window(4, 8).lower(*_window_args(runner)).as_text()
+    pools = 4 if kw.get("quant_kv") else 2  # values and scales
+    assert text.count("stablehlo.scatter") >= pools
+    assert "_commit_kernel" not in text

@@ -175,6 +175,8 @@ def test_note_window_derives_roofline_gauges():
 
 
 class _FakeRunner:
+    kv_commit_backend = "in_place"
+
     def __init__(self, hbm):
         self._hbm = hbm
 
@@ -206,6 +208,9 @@ def test_perf_metrics_updater_exports_deltas_and_gauges(monkeypatch):
     assert up.g_roofline.get() == pytest.approx(reg.roofline_frac)
     assert up.g_hbm_in_use.get() == 100
     assert up.g_hbm_limit.get() == 200
+    # How the window program commits: an info series, 1 under its label.
+    assert up.g_kv_commit.get(backend="in_place") == 1
+    assert 'backend="in_place"' in metrics.expose().decode()
     # Deltas: a second update with no new compiles adds nothing.
     up.update(eng, force=True)
     assert up.c_compiles.get(program="decode_window") == 2.0
@@ -459,8 +464,21 @@ async def test_perf_smoke_engine_zero_recompiles_and_pane(tmp_path):
         assert 0 <= status["roofline"]["frac"] <= 1
         assert status["memory"]["params_bytes"] > 0
         assert status["memory"]["kv_pool_bytes"] > 0
+        # How the window program writes the pool, as the runner decided
+        # (the CPU engine: XLA's gather, so its scatter): on the pane, in
+        # the registry's record of the window programs, and as an info
+        # series.
+        assert status["kv_commit_backend"] == \
+            engine.runner.kv_commit_backend == "scatter"
+        assert "scatter" in snap1["programs"]["decode_window"]["labels"][
+            "kv_commit_backend"]
+        assert snap1["programs"]["prefill"]["labels"] == {}
         engine.perf_metrics.update(engine, force=True)
-        assert metrics.expose().decode().count("dynamo_tpu_perf_") > 0
+        text = metrics.expose().decode()
+        assert text.count("dynamo_tpu_perf_") > 0
+        assert [line for line in text.splitlines()
+                if line.startswith("dynamo_tpu_perf_kv_commit_info{")
+                and 'backend="scatter"' in line and line.endswith(" 1.0")]
 
         # The pane: worker status server (explicit provider) + frontend
         # (process-global fallback + in-process engine discovery off).
@@ -482,6 +500,7 @@ async def test_perf_smoke_engine_zero_recompiles_and_pane(tmp_path):
                     == snap1["unexpected_recompiles_total"]
                 assert "decode_window" in body["compiles"]["programs"]
                 assert "roofline_frac" in body["window"]
+                assert body["kv_commit_backend"] == "scatter"
             async with session.get(
                     f"http://127.0.0.1:{frontend.port}/debug/perf") as resp:
                 assert resp.status == 200

@@ -147,3 +147,97 @@ def test_smallthinker_expert_layer_compiles_for_v5e(v5e, rows):
     else:
         assert flops > 64 / 6 * 0.9 * chosen, (flops, chosen)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+# -- the decode window program: what it does to the KV pool --------------------
+
+#: Result kinds that move no pool: the entry's arguments, the loop that
+#: carries the pool through its steps untouched, and tuple plumbing.
+_PLUMBING = {"parameter", "get-tuple-element", "tuple", "bitcast", "while"}
+
+
+def pool_sized_ops(text: str, pool: tuple) -> list[tuple[str, str]]:
+    """(name, kind) of every instruction of an optimised HLO module whose
+    result holds an array of the pool's shape and that is neither plumbing
+    nor a kernel call aliased to its operand (written in place)."""
+    import re
+    shape = "[" + ",".join(map(str, pool)) + "]"
+    out = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%?[\w.\-]+) = (.*?) ([\w\-]+)\(", line)
+        if not m or shape not in m.group(2) or m.group(3) in _PLUMBING:
+            continue
+        if m.group(3) == "custom-call" and "output_to_operand_aliasing" in line:
+            continue
+        out.append((m.group(1), m.group(3)))
+    return out
+
+
+def _window_program(one, model, commit, pages=1500, rows=32, window=8,
+                    table=128):
+    """The runner's own decode window program, lowered for the described
+    chip: a ModelRunner that places nothing (no device to hold an array),
+    with the cell's attention geometry and depth and a narrow MLP and
+    vocabulary (they never touch the pool, and keep the compile short)."""
+    from types import SimpleNamespace
+
+    from dynamo_tpu.engine.config import EngineConfig, ModelSpec
+    from dynamo_tpu.engine.model import param_shapes
+    from dynamo_tpu.engine.runner import PK_PREFIX, ModelRunner
+    d, nkv, qpk, layers = WIDTHS[model]
+    spec = ModelSpec(name=model, vocab_size=1024, hidden_size=nkv * qpk * d,
+                     intermediate_size=1024, num_layers=layers,
+                     num_heads=nkv * qpk, num_kv_heads=nkv)
+    runner = object.__new__(ModelRunner)
+    runner.spec = spec
+    runner.config = EngineConfig(model=spec, page_size=PAGE, num_pages=pages,
+                                 max_num_seqs=rows)
+    runner.device = SimpleNamespace(platform="tpu")
+    runner.mesh = SimpleNamespace(size=1)
+    runner.quant_kv = runner.lora = None
+    runner.experts_local = True
+    runner._window_cache = {}
+    runner._attention_impl, runner._window_attention_impl = \
+        runner._pick_attention()
+    assert runner.attention_backend == "pallas"
+    assert runner._pick_kv_commit() == "in_place"
+    runner.kv_commit_backend = commit
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = jax.tree.map(lambda shape: s(shape, jnp.bfloat16),
+                          param_shapes(spec),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    pool = (layers, nkv, pages, PAGE, d)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    compiled = runner._get_window(window, table).lower(
+        params, s(pool, jnp.bfloat16), s(pool, jnp.bfloat16),
+        s((rows,), jnp.int32), s((rows, PK_PREFIX + table), jnp.int32),
+        s(key.shape, key.dtype)).compile()
+    return compiled, pool
+
+
+@pytest.mark.parametrize("model, window", [("qwen2.5-7b", 8),
+                                           ("smallthinker-21b-a3b", 4)])
+def test_window_program_commits_in_place_for_v5e(v5e, model, window):
+    """The window program of both benchmark cells' geometry, pools donated:
+    a loop of steps that read the pool through the attention kernel, then
+    the commit. Nothing in the optimised program has the pool's shape but
+    the arguments, the loop's carry and the commit kernel aliased to them:
+    no copy (not the scatter's four, not a defensive one before the aliased
+    call), no transpose, no scatter."""
+    compiled, pool = _window_program(v5e, model, "in_place", window=window)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2  # the reader and the commit
+    assert "output_to_operand_aliasing" in text
+    assert pool_sized_ops(text, pool) == []
+
+
+def test_the_pool_guard_sees_the_scatter_s_copies(v5e):
+    """The same check on the same program with kv_quant.scatter_tokens as
+    its commit FAILS: XLA's scatter wants the pool in another layout than
+    the kernel reads, and converts both pools in and out."""
+    compiled, pool = _window_program(v5e, "qwen2.5-7b", "scatter")
+    found = pool_sized_ops(compiled.as_text(), pool)
+    assert [kind for _, kind in found].count("copy") >= 4, found
