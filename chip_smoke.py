@@ -48,6 +48,13 @@ import sys
 import threading
 import time
 
+# What the engine emits for each request, recorded between the Backend and
+# the engine: the OpenAI response carries token TEXT, and with a preset and
+# no checkpoint the server detokenizes a 151,936-wide model with the
+# 512-entry test tokenizer, so ids are only visible there. The benchmark's
+# tap (it also stamps each emission); imports nothing of jax.
+from benchmark.lib.server import EngineTap
+
 # Served vs reference logprob agreement, in nats. bf16 keeps 8 mantissa bits:
 # two correct programs that order a model's sums differently measured 0.001
 # to 0.003 apart on the v5e at qwen2.5-0.5b (PR 21) and up to 0.03 on the
@@ -87,31 +94,6 @@ def check(cond, msg: str) -> None:
 # --------------------------------------------------------------------------
 # Small helpers shared by the phases
 # --------------------------------------------------------------------------
-
-class EngineTap:
-    """Sits between the detokenizing Backend and the engine and records what
-    the engine emits for each request: the OpenAI response carries token
-    TEXT, and with a preset and no checkpoint the server detokenizes a
-    151,936-wide model with the 512-entry test tokenizer, so ids are only
-    visible here. Everything else forwards to the engine (the HTTP service
-    finds kv_status/perf_status through this object)."""
-
-    def __init__(self, engine):
-        self._engine = engine
-        self.calls: list[dict] = []
-
-    def __getattr__(self, name):
-        return getattr(self._engine, name)
-
-    async def generate(self, request, context):
-        rec = {"prompt": list(request.token_ids), "tokens": [],
-               "logprobs": []}
-        self.calls.append(rec)
-        async for out in self._engine.generate(request, context):
-            rec["tokens"].extend(out.get("token_ids", []))
-            rec["logprobs"].extend(out.get("log_probs") or [])
-            yield out
-
 
 async def engine_generate(engine, prompt, max_tokens, **sampling):
     """Drive ``engine.generate`` directly; returns (tokens, logprobs)."""

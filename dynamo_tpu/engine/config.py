@@ -104,16 +104,6 @@ class ModelSpec:
                         and any(self.sliding_window_layout)))
 
     @property
-    def block_kind(self) -> str:
-        """"dense", "mixtral" or "smallthinker": what a path that cannot
-        run every kind names when it refuses one (UnsupportedBlockError)."""
-        if (self.has_layer_pattern or self.moe_router != "topk_softmax"
-                or self.moe_router_input != "post_attn_norm"
-                or self.ffn_act != "silu"):
-            return "smallthinker"
-        return "mixtral" if self.num_experts else "dense"
-
-    @property
     def q_per_kv(self) -> int:
         return self.num_heads // self.num_kv_heads
 
@@ -185,11 +175,13 @@ class ModelSpec:
         width, a router ahead of attention, ReGLU, the two layouts."""
         if not cfg.get("moe_primary_router_apply_softmax", False):
             raise UnsupportedBlockError(
-                "smallthinker", "a router without softmax "
+                "the config reader", "a SmallThinker router without softmax "
                 "(moe_primary_router_apply_softmax false): its equations "
                 "are not written down in this repository")
         if cfg.get("rope_scaling"):
-            raise UnsupportedBlockError("smallthinker", "rope_scaling")
+            raise UnsupportedBlockError(
+                "the config reader", "SmallThinker with rope_scaling: no "
+                "path scales its rotation")
         width = cfg["moe_ffn_hidden_size"]
         return SmallThinkerSpec(
             name=cfg.get("_name_or_path") or cfg.get("model_name")
@@ -256,11 +248,66 @@ class SmallThinkerSpec(ModelSpec):
 
 
 class UnsupportedBlockError(NotImplementedError):
-    """A path that cannot run a block kind refuses it by name, at start-up;
-    it never runs it under another kind's rules."""
+    """A path that lacks a mechanism a model's block needs refuses the
+    model at start-up and names what it lacks; it never runs the block
+    under another block's rules."""
 
-    def __init__(self, kind: str, what: str):
-        super().__init__(f"block kind {kind!r} is not supported by {what}")
+    def __init__(self, what: str, why: str):
+        super().__init__(f"{what} cannot take this model: {why}")
+
+
+def block_refusals(spec: ModelSpec, config: "EngineConfig | None" = None,
+                   checkpoint: bool = False) -> list[UnsupportedBlockError]:
+    """Every reason the block of ``spec`` cannot run the way ``config``
+    asks (None: nothing is asked of an engine) or, with ``checkpoint``,
+    take its weights from safetensors. ModelRunner raises the first at
+    start-up and the loader before it opens a file; the forward functions
+    hold no refusal, and nothing else in the package asks what kind of
+    block a model has.
+
+    Where a path lacks a mechanism, the test is on the field that carries
+    it. Where a combination was only never compared with its reference,
+    the test is ``other``: a block that is not the Llama / Qwen2 / Mixtral
+    one (a router of another kind or ahead of attention, ReGLU, layers
+    that differ in kind)."""
+    windowed = bool(spec.sliding_window_layout
+                    and any(spec.sliding_window_layout))
+    other = (spec.has_layer_pattern or spec.moe_router != "topk_softmax"
+             or spec.moe_router_input != "post_attn_norm"
+             or spec.ffn_act != "silu")
+    unlike = "a block other than Llama's, Qwen2's or Mixtral's"
+    out = []
+    if checkpoint and other:
+        out.append(UnsupportedBlockError(
+            "the safetensors loader", "it has a tensor-name map for the "
+            f"Llama, Qwen2 and Mixtral checkpoints only, and this is {unlike} "
+            "(random weights only)"))
+    if config is None:
+        return out
+    if config.spec_decode and windowed:
+        out.append(UnsupportedBlockError(
+            "speculative decoding (spec_decode)", "the verify step's scores "
+            "have no window mask, and sliding_window_layout has a window "
+            "layer"))
+    if config.ring_attention and windowed:
+        out.append(UnsupportedBlockError(
+            "ring attention", "its blockwise scores have no window mask, "
+            "and sliding_window_layout has a window layer"))
+    if config.pp_microbatch and spec.has_layer_pattern:
+        out.append(UnsupportedBlockError(
+            "the pipelined prefill (pp_microbatch)", "a stage's scan has no "
+            "global layer index, and rope_layout / sliding_window_layout "
+            "give layers that differ in kind"))
+    if config.max_adapters > 0 and other:
+        out.append(UnsupportedBlockError(
+            "LoRA adapters (max_adapters)", f"LoRA on the attention of "
+            f"{unlike} was never compared with its reference"))
+    if config.tp * config.pp * config.dp * config.sp > 1 and other:
+        out.append(UnsupportedBlockError(
+            "a tp/pp/dp/sp mesh", f"{unlike} was never compared with its "
+            "reference on more than one device (its grouped expert product "
+            "has no partitioning rule)"))
+    return out
 
 
 # Presets (shapes from the public model cards).
@@ -312,7 +359,8 @@ class EngineConfig:
     # window PERIOD (M x step) lands near DTPU_WINDOW_TARGET_MS (default
     # 75 ms — keeps prefill admission gaps SLA-friendly): a 0.5B model
     # resolves to M=32, an unsharded 8B to M=4, an 8B shard at tp=4 to
-    # M=12 (docs/PERF_NOTES.md sweep is where the target comes from).
+    # M=12. The target was swept on other hardware: not measured on this
+    # chip (ROADMAP D6; both cells' periods are in PERF.md section 5).
     decode_window: int | str = 8
     # Microbatched pipeline-parallel PREFILL (model.prefill_forward_
     # pipelined): with pp > 1, whole-prompt prefill batches split into pp
@@ -349,7 +397,9 @@ class EngineConfig:
     # the whole prompt. "auto" derives the budget from the same
     # DTPU_WINDOW_TARGET_MS model as decode_window="auto" (one chunk ~
     # one window period). Env DTPU_PREFILL_CHUNK_TOKENS overrides either
-    # form (docs/PERF_NOTES.md "Stall-free prefill").
+    # form. On this chip: prompts of 2,049 to 8,192 tokens in chunks cost
+    # the Qwen configuration 6 % of time to first token and took a third
+    # off the decoders' gap p95 (PERF.md section 6, PR 28 f).
     prefill_chunk_tokens: int | str = "auto"
     # Windows in flight before the host blocks on the oldest readback.
     # Each dispatch/readback pays a host<->device round trip (about 0.45 ms
@@ -517,8 +567,6 @@ class EngineConfig:
             "wv": (m.hidden_size, m.num_kv_heads * d),
             "wo": (m.num_heads * d, m.hidden_size),
         }
-        if m.block_kind == "smallthinker":
-            raise UnsupportedBlockError(m.block_kind, "LoRA adapters")
         if not m.num_experts:
             shapes["w_gate"] = (m.hidden_size, m.intermediate_size)
             shapes["w_up"] = (m.hidden_size, m.intermediate_size)
@@ -549,7 +597,8 @@ class EngineConfig:
         ~constant. Pick M so the window period M x (step estimate) hits
         DTPU_WINDOW_TARGET_MS — long enough to amortize dispatch, short
         enough that prefill admission between windows keeps p99 TTFT
-        inside the SLA (bench sweep in docs/PERF_NOTES.md)."""
+        inside the SLA (the target: not measured on this chip, ROADMAP
+        D6)."""
         if isinstance(self.decode_window, int):
             if self.decode_window < 1:
                 raise ValueError(

@@ -716,7 +716,8 @@ class TPUEngine(AsyncEngine):
         completes, with one page group per prefill chunk gated on that
         chunk's extract — the decode worker pulls KV while later chunks
         are still computing, hiding the per-prompt transfer tax
-        (PERF_NOTES' 15-20 ms projection) behind prefill compute."""
+        (15-20 ms projected before the chip; not measured on this chip,
+        ROADMAP D6) behind prefill compute."""
         spec = self.runner.spec
         page = self.config.page_size
         n = -(-len(req.token_ids) // page)
@@ -2224,8 +2225,8 @@ class TPUEngine(AsyncEngine):
         # shrink-while-waiting policy was tried and reverted — the only
         # states where requests persist in the queue are slot/KV
         # saturation, where short windows just multiply dispatch overhead
-        # without admitting anyone (docs/PERF_NOTES.md, round-3 negative
-        # results).
+        # without admitting anyone (tried before the chip; not measured on
+        # this chip, ROADMAP D6).
         M = self.decode_window
         b = cfg.max_num_seqs
         frozen: dict[int, tuple] = {}

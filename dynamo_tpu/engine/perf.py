@@ -4,9 +4,9 @@ timing, and HBM telemetry (docs/OBSERVABILITY.md "Engine perf plane").
 The device/compiler layer was the last dark subsystem: tracing covers
 requests, the flight recorder covers engine-loop state, the KV pane
 covers the cache — but nothing measured *compiles*, per-window device
-time, or HBM occupancy, so docs/PERF_NOTES.md's "~34% of roofline" and
-"first-long-prompt compile stall" findings were hand-run archaeology.
-This module makes them live series:
+time, or HBM occupancy, so a roofline share or a compile stall was
+found by hand. This module makes them live series (what they read on
+this chip: PERF.md section 5 and PERF_LEDGER.jsonl):
 
 - ``CompileRegistry``: every ``jax.jit`` program in the serving path is
   built through :func:`instrumented_jit` (enforced by the

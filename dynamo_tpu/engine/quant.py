@@ -1,7 +1,8 @@
 """Weight-only int8 quantization with bf16 compute.
 
 The decode hot path is HBM-bandwidth-bound (one full weight read per
-step — docs/PERF_NOTES.md roofline), so halving weight bytes both
+step: 76 % of the Qwen cell's step and 82 % of SmallThinker's, PERF.md
+section 5, my chip run, PR 29), so halving weight bytes both
 doubles the decode ceiling and is what fits full Llama-3-8B (16 GB bf16)
 on a single 16 GB v5e chip beside its KV cache (round-3 VERDICT missing
 #7; the reference ecosystem's own baseline workload is a quantized 70B,

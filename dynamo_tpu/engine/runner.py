@@ -36,7 +36,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.engine import perf
-from dynamo_tpu.engine.config import EngineConfig, UnsupportedBlockError
+from dynamo_tpu.engine.config import EngineConfig, block_refusals
 from dynamo_tpu.engine.kv_quant import (KV_SCALE_BYTES, QuantKV, pack_parcel,
                                         parcel_to_bf16, quantize_np,
                                         scatter_tokens, unpack_parcel,
@@ -166,23 +166,8 @@ class ModelRunner:
         # composes back to the canonical grouping j // (H/nkv).
         self.canonical_spec = spec
         self.canonical_nkv = spec.num_kv_heads
-        if spec.block_kind == "smallthinker":
-            # Paths that hold their own copy of the dense block, or would
-            # partition what has no partitioning rule (the grouped expert
-            # product, the window layers' kernel), refuse the kind by name
-            # here, at start-up; none runs it with the dense block's rules.
-            for what, on in (
-                    ("a tp/pp/dp/sp mesh (its experts and the grouped "
-                     "product have no partitioning rule yet)",
-                     config.tp * config.pp * config.dp * config.sp > 1),
-                    ("speculative decoding (spec_decode)",
-                     bool(config.spec_decode)),
-                    ("LoRA adapters (max_adapters)", config.max_adapters > 0),
-                    ("ring attention", config.ring_attention),
-                    ("the pipelined prefill (pp_microbatch)",
-                     config.pp_microbatch)):
-                if on:
-                    raise UnsupportedBlockError(spec.block_kind, what)
+        for refusal in block_refusals(spec, config):
+            raise refusal
         if spec.num_heads % config.tp != 0:
             raise ValueError(
                 f"num_heads={spec.num_heads} not divisible by tp={config.tp}")

@@ -12,7 +12,7 @@ import os
 
 import numpy as np
 
-from dynamo_tpu.engine.config import ModelSpec
+from dynamo_tpu.engine.config import ModelSpec, block_refusals
 from dynamo_tpu.runtime.logging import get_logger
 
 log = get_logger("weights")
@@ -24,12 +24,8 @@ def load_hf_weights(spec: ModelSpec, model_dir: str):
     import ml_dtypes
     from safetensors import safe_open
 
-    if spec.block_kind == "smallthinker":
-        # Its tensor names are not the Llama / Mixtral ones mapped below.
-        from dynamo_tpu.engine.config import UnsupportedBlockError
-        raise UnsupportedBlockError(spec.block_kind,
-                                    "the safetensors loader (random weights "
-                                    "only: no name map for its checkpoint)")
+    for refusal in block_refusals(spec, checkpoint=True):
+        raise refusal
     files = sorted(glob.glob(os.path.join(model_dir, "*.safetensors")))
     if not files:
         raise FileNotFoundError(f"no safetensors under {model_dir}")

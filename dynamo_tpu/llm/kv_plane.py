@@ -227,7 +227,7 @@ class KvPlaneServer:
         self._running = False
         self._use_jax = (jax_transfer_usable() if use_jax_path is None
                          else use_jax_path)
-        # Telemetry (tests + PERF_NOTES measurements).
+        # Telemetry (stats(); engine/kv_metrics.py exports it).
         self.transfers = 0
         self.bytes_out = 0
         self.block_requests = 0

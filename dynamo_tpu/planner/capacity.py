@@ -9,7 +9,8 @@ LIVE from data the fleet already publishes, and closes the loop:
   count. Demand = active + waiting slots across the pool (the
   ForwardPassMetrics stream the planner already aggregates) plus the
   shared prefill-queue backlog; per-worker capacity = the admission-cap
-  style concurrency limit (PERF_NOTES' "bs<=18 at SLO" measurements)
+  style concurrency limit (a batch of 18 at the SLO before the chip; not
+  measured on this chip, ROADMAP D6)
   times a utilization headroom, derated by the live roofline fraction
   from the perf plane when a worker is measurably slower than the
   model expects (``/debug/perf`` ``perf_roofline_frac``). SLO pressure
@@ -71,8 +72,9 @@ class CapacityConfig:
     component: str = "tpu"
     min_workers: int = 1
     max_workers: int = 8
-    # Admission-cap style per-worker concurrency at SLO (PERF_NOTES
-    # measures bs<=18 on llama-3-8b int8; mockers are configured).
+    # Admission-cap style per-worker concurrency at SLO (bs<=18 on
+    # llama-3-8b int8 on other hardware; not measured on this chip,
+    # ROADMAP D6; mockers are configured).
     slots_per_worker: int = 16
     # Headroom: plan to this fraction of the cap, not to saturation.
     target_utilization: float = 0.75

@@ -135,6 +135,9 @@ async def test_frames_unchanged_when_chaos_disabled():
     async def on_conn(reader, writer):
         server_got.append(await read_frame(reader, chaos_site="service"))
         await write_frame(writer, {"pong": 1}, chaos_site="service")
+        # Since Python 3.12 Server.wait_closed() waits for every
+        # connection the server accepted: one left open hangs it.
+        writer.close()
 
     server = await asyncio.start_server(on_conn, "127.0.0.1", 0)
     port = server.sockets[0].getsockname()[1]

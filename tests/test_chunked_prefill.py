@@ -5,9 +5,10 @@ blocking loop inside the engine thread into scheduled chunk work
 interleaved with decode windows:
 
 - exact token parity between the chunked and whole-prompt paths (greedy,
-  seeded sampling, penalties, prefix-cache reuse, multimodal spans) —
-  everything in the chunked token path is deterministic, so equality is
-  asserted exactly;
+  penalties, prefix-cache reuse, multimodal spans): everything in the
+  chunked token path is deterministic, so equality is asserted exactly;
+  seeded sampling makes the same draws on both (held to the common prefix:
+  the two programs' logits differ in the last bits);
 - decode windows keep dispatching BETWEEN chunk dispatches (no
   full-prompt stall) while a long prompt prefills;
 - intermediate chunks perform no blocking host readback
@@ -90,23 +91,36 @@ async def run_one(engine, prompt, max_tokens, mm=None, **sampling):
 @async_test
 async def test_chunked_whole_prompt_parity_greedy_and_seeded(
         chunked_engine, whole_engine):
-    """The same prompt produces IDENTICAL tokens through the chunked and
-    whole-prompt paths — greedy, and seeded stochastic sampling with
-    logprobs (seeded draws fold (seed, position), so the path split
-    cannot perturb them)."""
+    """The same prompt produces IDENTICAL greedy tokens through the chunked
+    and whole-prompt paths, and seeded stochastic sampling makes the same
+    draws on both: a draw folds (seed, position), so the path split cannot
+    perturb it.
+
+    What the split does perturb is the logits, by the two prefill
+    programs' orders of summation (up to 0.03 nat here), and over this
+    model's nearly flat distribution a draw at temperature 0.9 then lands
+    on the other side of a boundary about once in thirty tokens (seeds 11,
+    15 and 23 of 11 to 30 part at tokens 7, 4 and 6: ROADMAP D1). So a
+    seeded pair is held to its common prefix: keys that differed would
+    part at the first token of every seed, rounding parts few and late."""
     p_greedy = _prompt(5, 150)
     a, _ = await run_one(chunked_engine, p_greedy, 8)
     b, _ = await run_one(whole_engine, p_greedy, 8)
     assert a == b
     p_seeded = _prompt(6, 150)
-    kw = dict(temperature=0.9, top_p=0.95, seed=11, logprobs=2)
-    a, lp_a = await run_one(chunked_engine, p_seeded, 8, **kw)
-    b, lp_b = await run_one(whole_engine, p_seeded, 8, **kw)
-    assert a == b
-    assert len(lp_a) == len(lp_b) == 8
-    # Chosen-token logprobs agree within bf16 path tolerance (the two
-    # prefill programs reduce in different orders).
-    np.testing.assert_allclose(lp_a, lp_b, atol=0.05)
+    agreed = 0
+    seeds = range(11, 17)
+    for seed in seeds:
+        kw = dict(temperature=0.9, top_p=0.95, seed=seed, logprobs=2)
+        a, lp_a = await run_one(chunked_engine, p_seeded, 8, **kw)
+        b, lp_b = await run_one(whole_engine, p_seeded, 8, **kw)
+        assert len(lp_a) == len(lp_b) == len(a) == len(b) == 8
+        n = next((i for i in range(8) if a[i] != b[i]), 8)
+        agreed += n
+        # Chosen-token logprobs agree within bf16 path tolerance (the two
+        # prefill programs reduce in different orders).
+        np.testing.assert_allclose(lp_a[:n], lp_b[:n], atol=0.05)
+    assert agreed >= 0.75 * 8 * len(seeds), agreed
     # And none of the chunked serving above performed a blocking prefill
     # readback: intermediate chunks chain KV on device; the final
     # chunk's token resolves asynchronously.

@@ -276,7 +276,7 @@ def test_sampler_greedy_and_topk():
 def test_auto_decode_window_sizing(monkeypatch):
     """decode_window='auto' targets DTPU_WINDOW_TARGET_MS from the shard's
     weight-read step estimate: small models get long windows, big shards
-    short ones (docs/PERF_NOTES.md sweep)."""
+    short ones."""
     import pytest
     from dynamo_tpu.engine.config import (DEVICE_PEAKS, EngineConfig,
                                           PRESETS)

@@ -262,19 +262,38 @@ async def test_decode_span_is_recorded_before_the_finish_frame(n_windows):
 
 @async_test(timeout=240)
 async def test_phases_are_on_the_profiler_trace_and_in_the_capture_reply(
-        tmp_path):
+        tmp_path, monkeypatch):
     """Under a profiler session every phase is a TraceAnnotation on the
     engine thread's line, which benchmark.lib.host_phases reduces; the
-    /debug/profile capture's reply carries the same split and the rows."""
+    /debug/profile capture's reply carries the same split and the rows.
+
+    The capture ends on its rows, not on the clock: its one sleep returns
+    when the ring holds three rows younger than the capture (a fixed 1.5 s
+    was too short for three windows on a loaded machine)."""
+    import types
+
     from benchmark.lib import host_phases, trace_reduce
-    flight.get_recorder().thaw()
+    ring = flight.get_recorder()
+    ring.thaw()
+    begun, rows_in = asyncio.Event(), asyncio.Event()
+
+    async def until_rows(_seconds):
+        begun.set()
+        await rows_in.wait()
+
+    monkeypatch.setattr(tracing, "asyncio", types.SimpleNamespace(
+        **{**vars(asyncio), "sleep": until_rows}))
     engine = _tiny_engine()
     try:
         await _generate(engine, 4)  # compile outside the capture
+        t0 = time.monotonic()
         task = asyncio.ensure_future(
-            tracing.capture_profile(1500, str(tmp_path)))
-        await asyncio.sleep(0.3)
+            tracing.capture_profile(60_000, str(tmp_path)))
+        await begun.wait()
         await _generate(engine, 3 * engine.decode_window + 1)
+        while ring.between(t0, time.monotonic())["rows"] < 3:
+            await asyncio.sleep(0.01)
+        rows_in.set()
         reply = await task
     finally:
         engine.stop()

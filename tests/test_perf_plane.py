@@ -1,18 +1,15 @@
 """Engine perf plane (docs/OBSERVABILITY.md "Engine perf plane"):
 compile observatory units, the unexpected-recompile detector, the
 cost-analysis fallback on CPU, the flight ring's tokens column staying
-allocation-free, the fleet-pane perf merge, perf_gate diff logic, and a
-tiny-CPU-engine smoke asserting zero unexpected recompiles across
-consecutive decode windows with /debug/perf served on both the worker
-status server and the frontend.
+allocation-free, the fleet-pane perf merge, and a tiny-CPU-engine smoke
+asserting zero unexpected recompiles across consecutive decode windows
+with /debug/perf served on both the worker status server and the
+frontend.
 
-All near-free on the 1-core box: fake data or one tiny engine; nothing
-here runs a real bench (that path is exercised by scripts/perf_gate.py
-against bench.py output on hardware).
+All near-free: fake data or one tiny engine. Speed is measured by
+benchmark/ on the chip and recorded in PERF_LEDGER.jsonl, not here.
 """
 
-import pathlib
-import sys
 import tracemalloc
 
 import aiohttp
@@ -25,11 +22,6 @@ from dynamo_tpu.engine.perf import (CompileRegistry, PerfMetricsUpdater,
 from dynamo_tpu.runtime import flight
 from dynamo_tpu.runtime.config import RuntimeConfig
 from dynamo_tpu.runtime.metrics import MetricsRegistry
-
-REPO = pathlib.Path(__file__).resolve().parent.parent
-sys.path.insert(0, str(REPO / "scripts"))
-
-import perf_gate  # noqa: E402  (scripts/perf_gate.py)
 
 
 # -- CompileRegistry units ----------------------------------------------------
@@ -273,118 +265,6 @@ def test_fleet_aggregate_sums_perf_views():
     assert agg["workers_ok"] == 3 and agg["workers_down"] == 1
     assert agg["compiles_total"] == 10
     assert agg["unexpected_recompiles"] == 2
-
-
-# -- perf_gate diff logic -----------------------------------------------------
-
-
-def _run_json(platform="cpu", value=100.0, frac=0.3, unexpected=0,
-              compiles=3):
-    return {
-        "metric": "decode_tok_s", "value": value, "unit": "tok/s",
-        "vs_baseline": frac,
-        "detail": {
-            "platform": platform,
-            "perf": {
-                "compiles": {
-                    "programs": {"decode_window": {
-                        "compiles": compiles, "compile_seconds": 2.0,
-                        "unexpected_recompiles": unexpected}},
-                    "compiles_total": compiles,
-                    "unexpected_recompiles_total": unexpected,
-                },
-                "window": {"roofline_frac": frac},
-            },
-        },
-    }
-
-
-def test_perf_gate_passes_like_for_like():
-    fails, notes = perf_gate.gate(_run_json(), _run_json())
-    assert fails == []
-    assert any("ok" in n for n in notes)
-
-
-def test_perf_gate_fails_on_unexpected_recompiles():
-    fails, _ = perf_gate.gate(_run_json(unexpected=1), _run_json())
-    assert any("unexpected_recompiles_total" in f for f in fails)
-
-
-def test_perf_gate_fails_on_throughput_and_roofline_regression():
-    fails, _ = perf_gate.gate(_run_json(value=70.0, frac=0.2),
-                              _run_json(value=100.0, frac=0.3),
-                              tolerance=0.15)
-    assert any("throughput regressed" in f for f in fails)
-    assert any("roofline fraction regressed" in f for f in fails)
-    # Within tolerance: clean.
-    fails, _ = perf_gate.gate(_run_json(value=90.0, frac=0.27),
-                              _run_json(value=100.0, frac=0.3),
-                              tolerance=0.15)
-    assert fails == []
-
-
-def test_perf_gate_compile_budget():
-    fails, _ = perf_gate.gate(_run_json(compiles=9), _run_json(compiles=3),
-                              compile_slack=2)
-    assert any("shape bucketing regressed" in f for f in fails)
-
-
-def test_perf_gate_platform_mismatch_gates_structure_only():
-    """A CPU smoke against the committed TPU baseline: value checks are
-    skipped, structural checks (incl. zero unexpected recompiles) still
-    gate."""
-    fails, notes = perf_gate.gate(_run_json(platform="cpu", value=1.0),
-                                  _run_json(platform="tpu", value=22000.0))
-    assert fails == []
-    assert any("platform mismatch" in n for n in notes)
-    fails, _ = perf_gate.gate(
-        _run_json(platform="cpu", unexpected=2),
-        _run_json(platform="tpu"))
-    assert fails
-
-
-def test_perf_gate_structural_failures():
-    run = _run_json()
-    del run["detail"]["perf"]
-    fails, _ = perf_gate.gate(run, None)
-    assert any("detail.perf" in f for f in fails)
-
-
-def test_perf_gate_record_and_main_roundtrip(tmp_path):
-    """The CLI records a fresh baseline from a structurally sound run,
-    then passes against it — the check.sh perf smoke's gate machinery."""
-    import json
-    run_path = tmp_path / "run.json"
-    base_path = tmp_path / "baseline.json"
-    run_path.write_text(json.dumps(_run_json()))
-    assert perf_gate.main(["--run", str(run_path), "--baseline",
-                           str(base_path), "--record"]) == 0
-    assert base_path.exists()
-    assert perf_gate.main(["--run", str(run_path), "--baseline",
-                           str(base_path)]) == 0
-    # A regressed run against the recorded baseline fails.
-    run_path.write_text(json.dumps(_run_json(value=10.0)))
-    assert perf_gate.main(["--run", str(run_path), "--baseline",
-                           str(base_path)]) == 1
-    # Refuses to record a structurally broken baseline.
-    run_path.write_text(json.dumps(_run_json(unexpected=3)))
-    assert perf_gate.main(["--run", str(run_path), "--baseline",
-                           str(base_path), "--record"]) == 1
-
-
-def test_perf_gate_committed_baseline_is_loadable():
-    base = perf_gate.load_run(str(REPO / "deploy" / "perf-baseline.json"))
-    # No absolute value is committed until one is measured on the local
-    # chip (PR 21); the structure is what gates.
-    assert base["value"] is None and base["metric"]
-    assert (base.get("detail") or {}).get("platform") == "tpu"
-    # A TPU run gates against it too: null values skip, nothing fails.
-    fails, _ = perf_gate.gate(_run_json(platform="tpu"), base)
-    assert fails == []
-    # A CPU run gates structurally against it (platform mismatch note).
-    fails, notes = perf_gate.gate(_run_json(platform="cpu"), base)
-    assert fails == []
-    assert any("platform mismatch" in n for n in notes)
 
 
 # -- tiny-engine smoke: zero unexpected recompiles + the pane -----------------
