@@ -501,9 +501,12 @@ def small_config(args, spec, **kw):
     else:
         sizes = dict(prefill_buckets=(128, 256, 512, 1024),
                      max_prefill_tokens=256, num_pages=1024)
-    return EngineConfig(model=spec, page_size=16, max_pages_per_seq=64,
-                        max_num_seqs=8, decode_window=8, pipeline_depth=2,
-                        **sizes, **kw)
+    cfg = EngineConfig(model=spec, max_num_seqs=8, decode_window=8,
+                       pipeline_depth=2, **sizes, **kw)
+    # The page is the launcher's ("auto": derived where the kernel reads
+    # the pool on a TPU, 16 elsewhere); the context stays 1,024 tokens.
+    cfg.max_pages_per_seq = 1024 // cfg.page_size
+    return cfg
 
 
 async def phase_kernels(args, jax, rng, keep: dict):
@@ -521,9 +524,11 @@ async def phase_kernels(args, jax, rng, keep: dict):
     from dynamo_tpu.engine.engine import TPUEngine
     from dynamo_tpu.engine.runner import PK_PREFIX
     spec, params = keep["spec"], keep["params"]
-    wide = ModelSpec(name="smoke-d128", vocab_size=2048, hidden_size=512,
-                     intermediate_size=1024, num_layers=2, num_heads=4,
-                     num_kv_heads=2)  # head_dim 128
+    # head_dim 128 over 4 KV heads: the benchmark cells' page (64 tokens
+    # where "auto" derives it, EngineConfig.resolve_page_size).
+    wide = ModelSpec(name="smoke-d128", vocab_size=2048, hidden_size=1024,
+                     intermediate_size=1024, num_layers=2, num_heads=8,
+                     num_kv_heads=4)
     lengths = (20, 70, 150) if args.rehearse_cpu else (24, 200, 700)
     n_out = 20
     on_tpu = jax.devices()[0].platform == "tpu"
@@ -535,9 +540,16 @@ async def phase_kernels(args, jax, rng, keep: dict):
         prompts = [rng.integers(2, spec_r.vocab_size, size=n).tolist()
                    for n in lengths]
         runs = {}
+        # Both engines of a round at the page the round's second backend
+        # resolves: the kernel is compared with XLA at the derived page.
+        page = small_config(args, spec_r, quant_kv=quant_kv,
+                            attention_backend=backends[1]).page_size
+        check(page == (64 if on_tpu and spec_r is wide else 16),
+              f"{spec_r.name} under {backends[1]}: page of {page} tokens")
         for backend in backends:
             eng = TPUEngine(small_config(args, spec_r, quant_kv=quant_kv,
-                                         attention_backend=backend),
+                                         attention_backend=backend,
+                                         page_size=page),
                             params=params_r)
             params_r = eng.runner.params  # the round's engines share weights
             # What ModelRunner._pick_attention decides "auto" from.
@@ -586,6 +598,7 @@ async def phase_kernels(args, jax, rng, keep: dict):
             emit("kernels.run", model=spec_r.name,
                  quant_kv=quant_kv or "bf16", attention_backend=backend,
                  resolved=resolved, kv_commit_backend=commit,
+                 page_size=eng.runner.page_size,
                  prompt_lengths=lengths, chunk_tokens=chunks,
                  seconds=round(seconds, 2), tpu_custom_call=custom_call)
             if backend == "xla" and spec_r is spec and quant_kv is None:

@@ -59,10 +59,18 @@ def parse_args(argv=None) -> argparse.Namespace:
     parser.add_argument("--component", default="tpu")
     parser.add_argument("--endpoint", default="generate")
     parser.add_argument("--tokenizer", default=None)
-    parser.add_argument("--page-size", type=int, default=16)
+    parser.add_argument("--page-size", default="auto",
+                        type=_auto_or_positive,
+                        help="tokens per KV page (= kv_cache_block_size): "
+                             "a positive int, or 'auto': 16, and where the "
+                             "Pallas kernel reads the pool on one TPU "
+                             "device the page whose one copy moves 64 KB "
+                             "(64 tokens at 4 KV heads of 128)")
     parser.add_argument("--num-pages", type=int, default=None)
     parser.add_argument("--max-num-seqs", type=int, default=32)
-    parser.add_argument("--max-pages-per-seq", type=int, default=512)
+    parser.add_argument("--max-pages-per-seq", type=int, default=None,
+                        help="page-table width of a sequence (default: "
+                             "what holds 8192 tokens at the page size)")
     parser.add_argument("--tp", type=int, default=1)
     parser.add_argument("--dp", type=int, default=1)
     parser.add_argument("--pp", type=int, default=1,
@@ -79,7 +87,7 @@ def parse_args(argv=None) -> argparse.Namespace:
                              "instead of all-gathering the full K/V — "
                              "peak K/V memory is one block per device")
     parser.add_argument("--decode-window", default="auto",
-                        type=_window_arg,
+                        type=_auto_or_positive,
                         help="decode steps per dispatched window: a "
                              "positive int, or 'auto' to size from the "
                              "model's weight-read step estimate "
@@ -88,7 +96,7 @@ def parse_args(argv=None) -> argparse.Namespace:
                         help="decode windows in flight before the host "
                              "blocks on the oldest readback")
     parser.add_argument("--prefill-chunk-tokens", default="auto",
-                        type=_chunk_arg,
+                        type=_auto_or_positive,
                         help="stall-free chunked prefill: prompt tokens "
                              "dispatched as prefill chunks per engine-loop "
                              "iteration before the next decode window; "
@@ -254,9 +262,10 @@ def build_engine_config(args) -> EngineConfig:
         pp_microbatch=getattr(args, "pp_microbatch", False),
         ring_attention=getattr(args, "ring_attention", False),
         attention_backend=args.attention_backend,
-        decode_window=_window_arg(getattr(args, "decode_window", "auto")),
+        decode_window=_auto_or_positive(
+            getattr(args, "decode_window", "auto")),
         pipeline_depth=getattr(args, "pipeline_depth", 4),
-        prefill_chunk_tokens=_chunk_arg(
+        prefill_chunk_tokens=_auto_or_positive(
             getattr(args, "prefill_chunk_tokens", "auto")),
         warmup_windows=True,
         warmup_prefill_ladder=getattr(args, "warmup_prefill_ladder", False),
@@ -306,24 +315,15 @@ def _watermark_arg(value) -> tuple[float, float]:
     return low, high
 
 
-def _window_arg(value) -> int | str:
-    """argparse type for --decode-window: positive int or 'auto'.
-    ValueError -> argparse's clean 'invalid value' error at parse time."""
+def _auto_or_positive(value) -> int | str:
+    """argparse type of an option that is a positive int or 'auto'
+    (--decode-window, --prefill-chunk-tokens, --page-size). ValueError ->
+    argparse's clean 'invalid value' error at parse time."""
     if value == "auto":
         return value
     n = int(value)
     if n < 1:
-        raise ValueError(f"decode window must be >= 1, got {n}")
-    return n
-
-
-def _chunk_arg(value) -> int | str:
-    """argparse type for --prefill-chunk-tokens: positive int or 'auto'."""
-    if value == "auto":
-        return value
-    n = int(value)
-    if n < 1:
-        raise ValueError(f"prefill chunk tokens must be >= 1, got {n}")
+        raise ValueError(f"must be >= 1 or 'auto', got {n}")
     return n
 
 

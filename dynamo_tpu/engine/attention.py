@@ -12,6 +12,11 @@ pipeline runs on from one sequence's program into the next, and a slot
 without history fetches nothing. Only live pages of live rows are read —
 unlike the XLA gather (model.paged_window_attention_xla), which
 materializes the page-table bucket of the longest row for every slot.
+Each page copy is issued from a scalar loop, and a chunk turn waits for the
+issue of the next chunk's copies, so where this kernel reads the pool the
+page is as large as makes one copy worth its descriptor
+(config.resolve_page_size: 64 tokens at 4 KV heads of 128; PERF.md section
+6, PR 31: 2.77 ms at pages of 16, 2.00 at 64, of which the dots are 1.35).
 
 Measured on one v5e (PERF.md section 6, PR 26), attention of one decode
 step of Qwen2.5-7B, 17 live rows of 32 at about 950 tokens: the gather
@@ -72,18 +77,23 @@ from dynamo_tpu.engine.kv_quant import QuantKV
 #: update per head) are paid once per few hundred tokens, small enough
 #: that two slots of K and of V stay a few MB of VMEM at any head count.
 CHUNK_BYTES = 512 * 1024
-MIN_PAGES_PER_CHUNK = 8    # 128 tokens of 16-token pages: one lane tile
+MIN_CHUNK_TOKENS = 128     # a chunk's tokens are the scores' lanes: one tile
 MAX_PAGES_PER_CHUNK = 64
 NEG_INF = -1e30
+#: Token rows one entry of the window's commit moves: a bfloat16 tile's
+#: sublanes, and the page the commit was measured at (PERF.md, PR 29).
+COMMIT_TILE = 16
 
 
 def pages_per_chunk(page_size: int, nkv: int, d: int, itemsize: int) -> int:
     """Pages one chunk holds: the power of two that fills CHUNK_BYTES with
-    the pages' K across all heads, within [MIN, MAX]_PAGES_PER_CHUNK
-    (32 at Qwen2.5-7B's 4 x 128 bf16, 16 at Llama-3-8B's 8 x 128, 64 for
-    small or int8 pages)."""
+    the pages' K across all heads, from MIN_CHUNK_TOKENS of pages up to
+    MAX_PAGES_PER_CHUNK. At Qwen2.5-7B's 4 x 128 bf16 a chunk is 512
+    tokens whatever the page (32 pages of 16, 8 of 64); at Llama-3-8B's
+    8 x 128 it is 256 tokens; small or int8 pages of 16 tokens come 64 to
+    a chunk."""
     fit = CHUNK_BYTES // (nkv * page_size * d * itemsize)
-    ppc = MIN_PAGES_PER_CHUNK
+    ppc = max(1, MIN_CHUNK_TOKENS // page_size)
     while ppc * 2 <= min(fit, MAX_PAGES_PER_CHUNK):
         ppc *= 2
     return ppc
@@ -508,16 +518,26 @@ def paged_window_attention_pallas(q: jax.Array, k_cache: jax.Array,
 
 
 def _commit_kernel(pid_ref, r0_ref, m0_ref, n_ref,  # SMEM prefetch, [B*J]
-                   kwin_ref, vwin_ref,  # VMEM blocks [L, Nkv, 1, M, D] f32
-                   k_in, v_in,  # the pools (ANY), aliased to k_hbm / v_hbm
-                   k_hbm, v_hbm, k_buf, v_buf, sems):
-    """One grid program per (row, touched page): the page's K and V rows
-    of every layer and KV head come into VMEM (one strided copy each, the
-    reader's ``hbm.at[layer, :, pid]`` turned to ``hbm.at[:, :, pid]``),
-    the window's tokens that fall on the page are selected into them, and
-    the page goes back where it came from. A program whose page takes no
-    token (a dead or frozen row, a window that stayed on its first page)
-    copies nothing."""
+                   *rest,  # [t0_ref if tiled], then
+                   # kwin_ref, vwin_ref: VMEM blocks [L, Nkv, 1, M, D] f32
+                   # k_in, v_in: the pools (ANY), aliased to k_hbm / v_hbm
+                   # k_hbm, v_hbm, k_buf, v_buf, sems
+                   tiled: bool = False):
+    """One grid program per (row, touched tile of a page): the tile's K and
+    V rows of every layer and KV head come into VMEM (one strided copy
+    each, the reader's ``hbm.at[layer, :, pid]`` turned to
+    ``hbm.at[:, :, pid]``), the window's tokens that fall on the tile are
+    selected into them, and the tile goes back where it came from. A
+    program whose tile takes no token (a dead or frozen row, a window that
+    stayed on its first tile) copies nothing.
+
+    ``tiled`` (a page of several COMMIT_TILEs): a fifth prefetched vector,
+    t0, is the tile's first row in its page, and the copies move those
+    rows alone, so the commit costs what a 16-token page costs whatever
+    the page. Where the page is one tile the whole page moves."""
+    if tiled:
+        t0_ref, *rest = rest
+    kwin_ref, vwin_ref, k_in, v_in, k_hbm, v_hbm, k_buf, v_buf, sems = rest
     del k_in, v_in  # the same buffers as the outputs
     i = pl.program_id(0)
     n = n_ref[i]
@@ -526,15 +546,19 @@ def _commit_kernel(pid_ref, r0_ref, m0_ref, n_ref,  # SMEM prefetch, [B*J]
     def _():
         pid, r0, m0 = pid_ref[i], r0_ref[i], m0_ref[i]
         pools = ((k_hbm, k_buf, kwin_ref), (v_hbm, v_buf, vwin_ref))
+        tile = k_buf.shape[2]
 
         def page_copy(s, hbm, buf, out: bool):
-            src, dst = (buf, hbm.at[:, :, pid]) if out else \
-                (hbm.at[:, :, pid], buf)
+            rows = hbm.at[:, :, pid]
+            if tiled:
+                rows = rows.at[:, :, pl.ds(
+                    pl.multiple_of(t0_ref[i], tile), tile)]
+            src, dst = (buf, rows) if out else (rows, buf)
             return pltpu.make_async_copy(src, dst, sems.at[s])
 
         for s, (hbm, buf, _) in enumerate(pools):
             page_copy(s, hbm, buf, False).start()
-        # Row r of this page takes window token m0 + (r - r0), r0 <= r <
+        # Row r of this tile takes window token m0 + (r - r0), r0 <= r <
         # r0 + n: for token m, the rows whose offset from m0 - r0 is m.
         row = jax.lax.broadcasted_iota(jnp.int32, k_buf.shape, 2)
         token = jnp.where((row >= r0) & (row < r0 + n), row - r0 + m0, -1)
@@ -552,25 +576,32 @@ def _commit_kernel(pid_ref, r0_ref, m0_ref, n_ref,  # SMEM prefetch, [B*J]
 
 
 def window_pages(positions0, cap, seq_lens0, page_table, window: int,
-                 page_size: int):
-    """Where a window's tokens land, page by page. A row's ``window``
-    tokens from ``positions0`` on touch at most J = ceil((window - 1) /
-    page) + 1 pages; for each (row, j), flattened to [B*J]: the pool page
-    ``pid``, the in-page row ``r0`` of the first token it takes, that
-    token's index ``m0`` in the window, and how many it takes, ``n`` (0: a
-    dead row, a row at its cap, a page the window did not reach)."""
-    J = -(-(window - 1) // page_size) + 1
+                 page_size: int, tile: int | None = None):
+    """Where a window's tokens land, tile by tile (``tile`` rows of a
+    page; None: the page whole). A row's ``window`` tokens from
+    ``positions0`` on touch at most J = ceil((window - 1) / tile) + 1
+    tiles; for each (row, j), flattened to [B*J]: the pool page ``pid``,
+    the in-tile row ``r0`` of the first token it takes, that token's index
+    ``m0`` in the window, and how many it takes, ``n`` (0: a dead row, a
+    row at its cap, a tile the window did not reach); where a page holds
+    several tiles also ``t0``, the tile's first row in its page."""
+    tile = tile or page_size
+    per_page = page_size // tile
+    J = -(-(window - 1) // tile) + 1
     n_live = jnp.where(seq_lens0 > 0,
                        jnp.clip(cap - positions0, 0, window), 0)     # [B]
-    pj = positions0[:, None] // page_size + jnp.arange(J)[None, :]   # [B,J]
-    first = jnp.maximum(positions0[:, None], pj * page_size)
-    last = jnp.minimum((positions0 + n_live)[:, None], (pj + 1) * page_size)
+    tj = positions0[:, None] // tile + jnp.arange(J)[None, :]        # [B,J]
+    first = jnp.maximum(positions0[:, None], tj * tile)
+    last = jnp.minimum((positions0 + n_live)[:, None], (tj + 1) * tile)
     n = jnp.maximum(last - first, 0)
+    pj = tj if per_page == 1 else tj // per_page
     pid = jnp.take_along_axis(
         page_table, jnp.clip(pj, 0, page_table.shape[1] - 1), axis=1)
-    return tuple(a.astype(jnp.int32).reshape(-1) for a in (
-        jnp.where(n > 0, pid, 0), first - pj * page_size,
-        first - positions0[:, None], n))
+    out = (jnp.where(n > 0, pid, 0), first - tj * tile,
+           first - positions0[:, None], n)
+    if per_page > 1:
+        out += ((tj % per_page) * tile,)
+    return tuple(a.astype(jnp.int32).reshape(-1) for a in out)
 
 
 def commit_window_pallas(k_cache: jax.Array, v_cache: jax.Array,
@@ -587,16 +618,21 @@ def commit_window_pallas(k_cache: jax.Array, v_cache: jax.Array,
     where kv_quant.scatter_tokens puts it; a token that does not land is
     written nowhere (the scatter sends it to scratch page 0). A written
     page must belong to one row: the page being appended to is private
-    (kv_cache.PageAllocator shares full pages only)."""
+    (kv_cache.PageAllocator shares full pages only). What moves is the
+    COMMIT_TILE rows of a page that the tokens fall on, so a larger page
+    costs the commit nothing (a page that is no whole number of tiles
+    moves whole)."""
     L, nkv, _, page_size, d = k_cache.shape
     b, window = k_win.shape[2], k_win.shape[3]
+    tile = COMMIT_TILE if page_size % COMMIT_TILE == 0 else page_size
+    tiled = tile < page_size
     prefetch = window_pages(positions0, cap, seq_lens0, page_table, window,
-                            page_size)
+                            page_size, tile)
     J = prefetch[0].shape[0] // b
     win = pl.BlockSpec((L, nkv, 1, window, d),
                        lambda i, *_: (0, 0, i // J, 0, 0))
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
-    buf = pltpu.VMEM((L, nkv, page_size, d), k_cache.dtype)
+    buf = pltpu.VMEM((L, nkv, tile, d), k_cache.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(b * J,),
@@ -606,7 +642,8 @@ def commit_window_pallas(k_cache: jax.Array, v_cache: jax.Array,
     )
     n_pre = len(prefetch)
     return pl.pallas_call(
-        _commit_kernel,
+        functools.partial(_commit_kernel, tiled=True) if tiled
+        else _commit_kernel,
         grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
                    jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)),

@@ -338,15 +338,73 @@ PRESETS: dict[str, ModelSpec] = {
 }
 
 
+#: The page where nothing on the device chose one: the reference's GPU
+#: engines' block, and the floor of a derived page.
+DEFAULT_PAGE_SIZE = 16
+MAX_PAGE_SIZE = 128
+#: What one page copy of the Pallas decode kernel should move, K or V across
+#: the KV heads of one page of one layer: measured on one v5e (PERF.md
+#: section 6, PR 31).
+PAGE_COPY_BYTES = 64 * 1024
+#: The context limit in tokens where max_pages_per_seq is not given.
+DEFAULT_MAX_MODEL_LEN = 8192
+
+
+def pool_access(attention_backend: str, platform: str, mesh_size: int,
+                head_dim: int, quant_kv: str | None) -> tuple[str, str]:
+    """(attention backend, KV commit): who reads the KV pool in decode and
+    how the decode window writes it, from what a runner observes and
+    nothing else. The ONE statement of that choice: ModelRunner's
+    _pick_attention and _pick_kv_commit ask it with their device's
+    platform and their mesh, EngineConfig.resolve_page_size with the
+    configuration's.
+
+    Reader, under "auto": the Pallas kernel on one TPU device at head_dim
+    128, the XLA gather everywhere else. Timed on one v5e (PERF.md section
+    6, PR 26). head_dim 128: attention of a Qwen2.5-7B decode step, 17
+    live rows of 32 at about 950 tokens, costs 21.4 ms gathered and 3 ms
+    in the kernel, a row past 2048 tokens moves every slot of the gather
+    to the next bucket and costs the kernel its own pages, and at 8- and
+    16-page buckets the two are level (llama-3-8b-L8: 12.4 against 12.4
+    and 12.9 against 12.5 ms a step). head_dim 64: the kernel's [page, D]
+    -> [rows, 128] view of the pool is a relayout on the device, a copy of
+    the pool per layer (qwen2.5-0.5b: 247 ms a step against 8.5), so a
+    packed head stays on XLA until the pool is stored lane-dense (ROADMAP
+    D3). The CPU would interpret the kernel; a mesh would gather the pool
+    around it. A requested backend is returned as asked: whether it can be
+    had is the runner's to refuse.
+
+    Writer: "in_place" (attention.commit_window_pallas) where that kernel
+    reads a plain bf16 pool row-major at head_dim 128 on one device, so
+    the touched rows are rewritten where they lie; "scatter"
+    (kv_quant.scatter_tokens) everywhere else: the XLA reader (a mesh and
+    the CPU under "auto"), a packed head (head_dim 64), int8 pages
+    (QuantKV: tiles of 32 rows, and the scales are a second array)."""
+    plain = mesh_size == 1 and head_dim == 128
+    reader = attention_backend
+    if reader == "auto":
+        reader = "pallas" if platform == "tpu" and plain else "xla"
+    in_place = reader == "pallas" and plain and quant_kv is None
+    return reader, "in_place" if in_place else "scatter"
+
+
 @dataclasses.dataclass
 class EngineConfig:
     model: ModelSpec = dataclasses.field(
         default_factory=lambda: PRESETS["tiny-test"])
-    # KV paging
-    page_size: int = 16  # tokens per page (= kv_cache_block_size)
+    # KV paging. page_size: tokens per page (= kv_cache_block_size: the
+    # allocator's unit, the prefix cache's hash block, the model card's
+    # and the KV router's block, a KV parcel's page). "auto" is resolved
+    # to an integer as this object is built (resolve_page_size, from the
+    # platform of the process's first device), so every reader sees a
+    # number; an explicit integer is kept.
+    page_size: int | str = "auto"
     num_pages: int | None = None  # None => size from HBM budget
     hbm_kv_budget_frac: float = 0.6  # fraction of free HBM for KV after params
-    max_pages_per_seq: int = 512
+    # Page-table width of a sequence. None: what holds DEFAULT_MAX_MODEL_LEN
+    # tokens at the resolved page, so the context limit (max_model_len)
+    # stays in tokens whatever the page (512 pages of 16, 128 of 64).
+    max_pages_per_seq: int | None = None
     # Batching
     max_num_seqs: int = 32
     max_prefill_tokens: int = 8192
@@ -516,6 +574,52 @@ class EngineConfig:
     # below it. None (default) disables the comparison; env
     # DTPU_EXPECTED_ROOFLINE_FRAC overrides at serving time.
     expected_roofline_frac: float | None = None
+
+    def __post_init__(self):
+        if isinstance(self.page_size, str):
+            if self.page_size != "auto":
+                raise ValueError(f"page_size must be an int or 'auto', "
+                                 f"got {self.page_size!r}")
+            self.page_size = self.resolve_page_size()
+        if self.page_size < 1:
+            raise ValueError(f"page_size must be >= 1, got {self.page_size}")
+        if self.max_pages_per_seq is None:
+            self.max_pages_per_seq = max(
+                1, DEFAULT_MAX_MODEL_LEN // self.page_size)
+
+    @property
+    def mesh_size(self) -> int:
+        return self.tp * self.pp * self.dp * self.sp
+
+    def resolve_page_size(self, platform: str | None = None) -> int:
+        """``page_size="auto"`` as a number of tokens. Where on a TPU the
+        Pallas kernel reads the pool and the window commits in place
+        (pool_access), a page is the smallest power of two of tokens whose
+        ONE strided copy across the KV heads moves PAGE_COPY_BYTES, within
+        [DEFAULT_PAGE_SIZE, MAX_PAGE_SIZE]: 64 tokens at 4 KV heads of 128
+        in bfloat16 (Qwen2.5-7B, SmallThinker), 32 at 8 (Llama-3-8B). The
+        kernel issues a copy a live page a layer from a scalar loop, and a
+        chunk turn waits for the issue of the next chunk's copies (PERF.md
+        section 6, PR 31). Everywhere else DEFAULT_PAGE_SIZE: the XLA
+        gather pads every slot to the longest row's page bucket of at
+        least 8 pages, so a larger page there is a cost nobody has
+        measured. ``platform`` None: that of the process's first device,
+        asked only where a TPU would change the answer."""
+        m = self.model
+        _, writer = pool_access(self.attention_backend, "tpu", self.mesh_size,
+                                m.head_dim, self.resolve_quant_kv())
+        if writer != "in_place":
+            return DEFAULT_PAGE_SIZE
+        if platform is None:
+            import jax
+            platform = jax.devices()[0].platform
+        if platform != "tpu":
+            return DEFAULT_PAGE_SIZE
+        copy_bytes = m.num_kv_heads * m.head_dim * 2  # a token row, bf16
+        page = DEFAULT_PAGE_SIZE
+        while page < MAX_PAGE_SIZE and page * copy_bytes < PAGE_COPY_BYTES:
+            page *= 2
+        return page
 
     def resolve_quant_kv(self) -> str | None:
         """The effective KV-pool quantization mode, with the DTPU_QUANT_KV

@@ -1050,6 +1050,9 @@ class TPUEngine(AsyncEngine):
             # Static per runner: how the window program writes the pool
             # (runner._pick_kv_commit).
             "kv_commit_backend": self.runner.kv_commit_backend,
+            # Tokens a KV page holds (config.resolve_page_size): over 16
+            # where the page was derived for the Pallas reader.
+            "page_size": self.runner.page_size,
             # Engine-thread self time by loop phase, seconds since the
             # loop started (engine_phase_seconds_total on /metrics).
             "phases": {k: round(v, 6)
@@ -1758,6 +1761,10 @@ class TPUEngine(AsyncEngine):
         r.blocks = TokenBlockSequence(
             page, prompt, salt=chain_salt(getattr(r.req, "adapter", None)))
         total_pages = -(-len(prompt) // page)
+        from dynamo_tpu.llm.kv_transfer import foreign_pages
+        refusal = foreign_pages(kv.shape, page)
+        if refusal:
+            raise ValueError(refusal)
         if kv.shape[3] != total_pages:
             raise ValueError(
                 f"transferred KV has {kv.shape[3]} pages, prompt needs "

@@ -183,8 +183,10 @@ def scatter_tokens(cache, vals, dest, off):
     and not where the Pallas kernel reads a plain bf16 pool at head_dim
     128: there the window program writes its pages in place
     (``attention.commit_window_pallas``). Each of the others waits for a
-    reader whose layout its writer can match: int8 tiles are 32 rows over
-    pages of 16 and the scales are a second array; a packed head's pool
+    reader whose layout its writer can match: int8 tiles are 32 rows (a
+    page of 32 or more tokens holds them, an int8 pool still resolves a
+    page of 16: config.resolve_page_size) and the scales are a second
+    array; a packed head's pool
     rests lane-padded (ROADMAP D3); the kernel has no partitioning rule."""
     if isinstance(cache, QuantKV):
         q, s = kv_quantize(vals)

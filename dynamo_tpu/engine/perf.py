@@ -706,6 +706,11 @@ class PerfMetricsUpdater:
             "rewritten where they lie, beside the Pallas reader) or "
             "scatter (XLA's scatter; pool-sized layout copies on a TPU)",
             ["backend"])
+        self.g_kv_page = registry.gauge(
+            "perf_kv_page_info", "1 under the label of how many tokens a "
+            "KV page of this worker holds (runner.page_size): 16, or the "
+            "page derived for the Pallas reader on one TPU device",
+            ["tokens"])
         self.c_spec_draft_tokens = registry.counter(
             "perf_spec_draft_tokens_total", "Speculative draft tokens "
             "proposed by the on-device n-gram drafter")
@@ -780,6 +785,9 @@ class PerfMetricsUpdater:
         backend = getattr(runner, "kv_commit_backend", None)
         if backend:
             self.g_kv_commit.set(1, backend=backend)
+        page = getattr(runner, "page_size", None)
+        if page:
+            self.g_kv_page.set(1, tokens=str(page))
         if hbm:
             self.g_hbm_in_use.set(hbm.get("bytes_in_use", 0))
             self.g_hbm_peak.set(hbm.get("peak_bytes_in_use", 0))

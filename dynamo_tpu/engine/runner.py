@@ -36,7 +36,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.engine import perf
-from dynamo_tpu.engine.config import EngineConfig, block_refusals
+from dynamo_tpu.engine.config import (EngineConfig, block_refusals,
+                                      pool_access)
 from dynamo_tpu.engine.kv_quant import (KV_SCALE_BYTES, QuantKV, pack_parcel,
                                         parcel_to_bf16, quantize_np,
                                         scatter_tokens, unpack_parcel,
@@ -229,6 +230,7 @@ class ModelRunner:
         self._attention_impl, self._window_attention_impl = \
             self._pick_attention()
         self.kv_commit_backend = self._pick_kv_commit()
+        self.page_size = config.page_size
         self._sized_pages(self.device)
 
         # Shard or init parameters.
@@ -449,33 +451,19 @@ class ModelRunner:
         """Returns (single-step impl, window impl). A requested backend is
         what runs: "pallas" that cannot be had is an error, never XLA.
         "auto" is decided from what the runner observes, nothing else: the
-        platform, the mesh's size and the head dimension."""
+        platform, the mesh's size and the head dimension
+        (config.pool_access, which has the measurements)."""
         from dynamo_tpu.engine.model import paged_window_attention_xla
-        backend = self.config.attention_backend
-        refusal = self._pallas_refusal()
-        if backend == "auto":
-            # Timed on one v5e (PERF.md section 6, PR 26). head_dim 128:
-            # attention of a Qwen2.5-7B decode step, 17 live rows of 32 at
-            # about 950 tokens, costs 21.4 ms gathered and 3 ms in the
-            # kernel, a row past 2048 tokens moves every slot of the
-            # gather to the next bucket and costs the kernel its own
-            # pages, and at 8- and 16-page buckets the two are level
-            # (llama-3-8b-L8: 12.4 against 12.4 and 12.9 against 12.5 ms a
-            # step). head_dim 64: the kernel's [page, D] -> [rows, 128]
-            # view of the pool is a relayout on the device, a copy of the
-            # pool per layer (qwen2.5-0.5b: 247 ms a step against 8.5), so
-            # a packed head stays on XLA until the pool is stored
-            # lane-dense (ROADMAP D3). The CPU would interpret the kernel;
-            # a mesh would gather the pool around it.
-            backend = ("pallas" if self.device.platform == "tpu"
-                       and refusal is None and self.spec.head_dim == 128
-                       else "xla")
+        backend, _ = pool_access(
+            self.config.attention_backend, self.device.platform,
+            self.mesh.size, self.spec.head_dim, self.quant_kv)
         self.attention_backend = backend
         if backend == "xla":
             return paged_decode_attention_xla, paged_window_attention_xla
         if backend != "pallas":
             raise ValueError(f"attention_backend must be 'auto', 'xla' or "
                              f"'pallas', got {backend!r}")
+        refusal = self._pallas_refusal()
         if refusal is not None:
             raise ValueError(f"attention_backend='pallas' {refusal}")
         from dynamo_tpu.engine.attention import (
@@ -489,19 +477,13 @@ class ModelRunner:
                                   interpret=interpret))
 
     def _pick_kv_commit(self) -> str:
-        """How the decode window program writes its tokens into the pool,
-        decided by the observation that chose the reader: the writer must
-        leave the pool in the layout the reader reads. "in_place": the
-        Pallas decode kernel reads a plain bf16 pool row-major at head_dim
-        128, so attention.commit_window_pallas rewrites the touched pages
-        where they lie. "scatter" (kv_quant.scatter_tokens) everywhere
-        else: a mesh and the CPU under "auto" (the XLA reader), a packed
-        head (head_dim 64), int8 pages (QuantKV: tiles of 32 rows over
-        pages of 16, and the scales are a second array)."""
-        in_place = (self.attention_backend == "pallas"
-                    and self.mesh.size == 1 and self.spec.head_dim == 128
-                    and self.quant_kv is None)
-        return "in_place" if in_place else "scatter"
+        """How the decode window program writes its tokens into the pool
+        ("in_place" or "scatter"), decided by the observation that chose
+        the reader (config.pool_access): the writer must leave the pool in
+        the layout the reader reads."""
+        return pool_access(self.attention_backend, self.device.platform,
+                           self.mesh.size, self.spec.head_dim,
+                           self.quant_kv)[1]
 
     # -- compiled steps -------------------------------------------------------
     def _get_prefill(self, bucket: int, batch: int, with_history: bool,
@@ -806,7 +788,8 @@ class ModelRunner:
         donate = (1, 2, 6) if penalized else (1, 2)
         fn = perf.instrumented_jit(
             "decode_window", run_window, key=key, donate_argnums=donate,
-            labels={"kv_commit_backend": self.kv_commit_backend})
+            labels={"kv_commit_backend": self.kv_commit_backend,
+                    "page_size": self.config.page_size})
         self._window_cache[key] = fn
         return fn
 
@@ -1518,7 +1501,12 @@ class ModelRunner:
         either form converts to this runner's pool dtype on upload, so
         mixed bf16/int8 fleets interoperate. The mesh re-shards on
         upload, so TP-mismatched prefill->decode transfers work without
-        a transpose kernel (the role of block_copy.cu)."""
+        a transpose kernel (the role of block_copy.cu). Pages of another
+        page size are refused (kv_transfer.foreign_pages)."""
+        from dynamo_tpu.llm.kv_transfer import foreign_pages
+        refusal = foreign_pages(kv.shape, self.config.page_size)
+        if refusal:
+            raise ValueError(refusal)
         n = len(pages)
         assert kv.shape[3] == n, (kv.shape, n)
         if kv.shape[2] == self.canonical_nkv and self.kv_rep > 1:

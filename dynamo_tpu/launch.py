@@ -56,8 +56,16 @@ def parse_args(argv=None) -> argparse.Namespace:
     parser.add_argument("--max-num-seqs", type=int, default=32)
     parser.add_argument("--context-length", type=int, default=8192)
     # Engine knobs shared with the worker (backends.tpu.build_engine_config).
-    parser.add_argument("--page-size", type=int, default=16)
-    parser.add_argument("--max-pages-per-seq", type=int, default=512)
+    from dynamo_tpu.backends.tpu import _auto_or_positive
+    parser.add_argument("--page-size", default="auto",
+                        type=_auto_or_positive,
+                        help="tokens per KV page: a positive int or 'auto' "
+                             "(16; where the Pallas kernel reads the pool "
+                             "on one TPU device, the page whose one copy "
+                             "moves 64 KB)")
+    parser.add_argument("--max-pages-per-seq", type=int, default=None,
+                        help="default: what holds 8192 tokens at the page "
+                             "size")
     parser.add_argument("--tp", type=int, default=1)
     parser.add_argument("--dp", type=int, default=1)
     parser.add_argument("--pp", type=int, default=1)
@@ -69,13 +77,13 @@ def parse_args(argv=None) -> argparse.Namespace:
                              "128 and the XLA gather everywhere else (CPU, "
                              "a mesh, smaller heads); an explicit "
                              "'pallas' that cannot be had is an error")
-    from dynamo_tpu.backends.tpu import _chunk_arg, _window_arg
-    parser.add_argument("--decode-window", default="auto", type=_window_arg,
+    parser.add_argument("--decode-window", default="auto",
+                        type=_auto_or_positive,
                         help="positive int or 'auto' (size from the model's "
                              "weight-read step estimate)")
     parser.add_argument("--pipeline-depth", type=int, default=4)
     parser.add_argument("--prefill-chunk-tokens", default="auto",
-                        type=_chunk_arg,
+                        type=_auto_or_positive,
                         help="stall-free chunked prefill budget per "
                              "engine-loop iteration (int or 'auto')")
     parser.add_argument("--warmup-prefill-ladder", action="store_true",

@@ -321,7 +321,8 @@ class DisaggDecodeHandler:
                 req.to_wire(), context=context)
             return await collect_prefill_response(
                 stream, plane_client=self.plane_client,
-                metrics=getattr(self.engine, "phase", None))
+                metrics=getattr(self.engine, "phase", None),
+                page_size=self.engine.config.page_size)
         except (NoInstancesError, StreamIncompleteError, EngineError,
                 ConnectionError, OSError, RuntimeError) as exc:
             self.remote_failures += 1

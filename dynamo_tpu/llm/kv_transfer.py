@@ -33,6 +33,20 @@ def _bf16():
     return ml_dtypes.bfloat16
 
 
+def foreign_pages(kv_shape, page_size: int) -> str | None:
+    """Why a parcel [2, L, Nkv, n, page, D] cannot enter a pool of
+    ``page_size``-token pages, or None. Pages of another size are refused
+    and never reshaped: a page is the allocator's unit, the prefix cache's
+    hash block and the router's block, so workers that exchange pages
+    resolve the same page (they do when they share a configuration and a
+    platform: EngineConfig.resolve_page_size)."""
+    if kv_shape[4] == page_size:
+        return None
+    return (f"KV parcel holds pages of {kv_shape[4]} tokens and this "
+            f"worker's pool pages of {page_size}: refused, not reshaped "
+            f"(give both workers the same --page-size)")
+
+
 def kv_to_chunks(kv: np.ndarray) -> tuple[dict, list[bytes]]:
     """Serialize a KV parcel: returns (meta, chunk list)."""
     raw = np.ascontiguousarray(kv).tobytes()
@@ -54,7 +68,8 @@ def kv_from_chunks(meta: dict, chunks: list[bytes]) -> np.ndarray:
 
 async def collect_prefill_response(stream: AsyncIterator[dict],
                                    plane_client=None,
-                                   metrics=None) -> tuple[int, np.ndarray]:
+                                   metrics=None, page_size: int | None = None
+                                   ) -> tuple[int, np.ndarray]:
     """Assemble a prefill worker's response into (first_token, kv parcel).
 
     Two wire forms: a transfer TICKET (the worker staged the parcel on
@@ -62,7 +77,8 @@ async def collect_prefill_response(stream: AsyncIterator[dict],
     there), or inline chunks (the v0 host-staged path, still emitted by
     plane-less workers). ``metrics`` (a tracing.PhaseMetrics) feeds the
     kv_transfer_seconds/bytes histograms; the recv span records either
-    way."""
+    way. ``page_size`` (the receiving pool's): a parcel of another page
+    size is refused here, by name (foreign_pages)."""
     import asyncio
 
     t0 = time.monotonic()
@@ -116,6 +132,9 @@ async def collect_prefill_response(stream: AsyncIterator[dict],
             kv = kv_from_chunks(meta, chunks)
             sp.set(path="inline", nbytes=int(kv.nbytes),
                    chunks=len(chunks))
+    refusal = page_size and foreign_pages(kv.shape, page_size)
+    if refusal:
+        raise RuntimeError(refusal)
     if metrics is not None:
         metrics.kv_transfer.observe(time.monotonic() - t0,
                                     direction="recv")

@@ -125,18 +125,26 @@ def test_pallas_window_matches_xla(m):
 QWEN_7B = dict(d=128, nkv=4, qpk=7)
 
 
-def test_pallas_qwen7b_single_step():
+#: 16, and the pages a derived page_size can be (config.resolve_page_size):
+#: a 512-token chunk is 32, 16, 8 or 4 of them.
+PAGES = [16, 32, 64, 128]
+
+
+@pytest.mark.parametrize("page", PAGES)
+def test_pallas_qwen7b_single_step(page):
     """The cell's head shape through the single decode step: lengths inside
     one chunk, at a chunk's edge and across three chunks."""
-    ref, out = _both(_case(128, b=4, nkv=4, qpk=7, maxp=80,
-                           seq_lens=[5, 512, 1100, 64], seed=8), qpk=7)
+    ref, out = _both(_case(128, b=4, nkv=4, qpk=7, maxp=1280 // page,
+                           seq_lens=[5, 512, 1100, 64], seed=8, page=page),
+                     qpk=7)
     np.testing.assert_allclose(out, ref, atol=0.03, rtol=0.03)
 
 
+@pytest.mark.parametrize("page", PAGES)
 @pytest.mark.parametrize("m", [0, 3, 7])
-def test_pallas_qwen7b_window(m):
-    ref, out = _window_both(**QWEN_7B, maxp=80, lens=[17, 513, 1100, 300],
-                            m=m)
+def test_pallas_qwen7b_window(m, page):
+    ref, out = _window_both(**QWEN_7B, maxp=1280 // page,
+                            lens=[17, 513, 1100, 300], m=m, page=page)
     np.testing.assert_allclose(out, ref, atol=0.03, rtol=0.03)
 
 
@@ -157,12 +165,62 @@ def test_pallas_dead_slots_between_live_rows(d, lens):
     np.testing.assert_allclose(out, ref, atol=0.03, rtol=0.03)
 
 
-def test_pallas_row_past_2048_tokens_beside_short_rows():
-    """A 256-page table: one row of 2100 tokens (five chunks) beside short
-    rows and a dead slot; every row reads its own pages only."""
-    ref, out = _window_both(**QWEN_7B, maxp=256, lens=[40, 2100, 0, 700],
-                            m=5)
+@pytest.mark.parametrize("page", PAGES[1:])
+@pytest.mark.parametrize("lens", [
+    [0, 200, 0, 0, 513, 0, 31, 0], [300, 0, 0, 0, 0, 0, 0, 40], [0, 0, 0, 77],
+], ids=["between", "hop", "last-only"])
+def test_pallas_dead_slots_at_larger_pages(page, lens):
+    """The same pipeline at pages of 32, 64 and 128 tokens: dead slots
+    before, between and after live rows, rows that end inside a page."""
+    ref, out = _window_both(**QWEN_7B, maxp=640 // page, lens=lens, m=2,
+                            page=page)
+    assert np.isfinite(out).all()
     np.testing.assert_allclose(out, ref, atol=0.03, rtol=0.03)
+
+
+@pytest.mark.parametrize("page", PAGES)
+def test_pallas_row_past_2048_tokens_beside_short_rows(page):
+    """A table of 4,096 tokens: one row of 2100 tokens (five chunks) beside
+    short rows and a dead slot; every row reads its own pages only."""
+    ref, out = _window_both(**QWEN_7B, maxp=4096 // page,
+                            lens=[40, 2100, 0, 700], m=5, page=page)
+    np.testing.assert_allclose(out, ref, atol=0.03, rtol=0.03)
+
+
+@pytest.mark.parametrize("lo", [[70, 0, 0], [128, 0, 600], [699, 0, 1030]],
+                         ids=["inside a page", "on a page's edge, across "
+                              "a chunk", "one token of history left"])
+@pytest.mark.parametrize("page", [16, 64])
+def test_pallas_window_layer_lo_inside_and_across_a_page(page, lo):
+    """A window layer's first visible token (SmallThinker's window layers)
+    inside a page, on a page's edge and in a later chunk, at 16-token pages
+    and at the derived 64: against the gather with the same ``lo``."""
+    from dynamo_tpu.engine.attention import paged_window_attention_pallas
+    from dynamo_tpu.engine.model import paged_window_attention_xla
+    rng = np.random.default_rng(13)
+    d, nkv, qpk, M = 128, 4, 7, 4
+    hist = [700, 0, 1100]
+    b, maxp = len(hist), 1280 // page
+
+    def arr(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+
+    table = 1 + rng.permutation(b * maxp).reshape(b, maxp).astype(np.int32)
+    pool = (2, nkv, b * maxp + 1, page, d)
+    args = (arr(b, nkv * qpk, d), arr(*pool), arr(*pool),
+            jnp.asarray(1, jnp.int32), jnp.asarray(table),
+            jnp.asarray(hist, jnp.int32), arr(nkv, b, M, d),
+            arr(nkv, b, M, d), jnp.asarray(2, jnp.int32), arr(b, nkv, d),
+            arr(b, nkv, d))
+    lo = jnp.asarray(lo, jnp.int32)
+    want = paged_window_attention_xla(*args, qpk, lo=lo)
+    got = paged_window_attention_pallas(*args, qpk, interpret=True, lo=lo)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=0.03,
+                               rtol=0.03)
+    full = paged_window_attention_xla(*args, qpk)
+    assert np.max(np.abs(np.asarray(full, np.float32)
+                         - np.asarray(want, np.float32))) > 0.02
 
 
 @pytest.mark.parametrize("shape, want", [
@@ -170,7 +228,12 @@ def test_pallas_row_past_2048_tokens_beside_short_rows():
     ((16, 8, 128, 2), 16),    # llama-3-8b
     ((16, 2, 64, 2), 64),     # qwen2.5-0.5b: small pages, the cap
     ((16, 4, 128, 1), 64),    # int8 pages hold twice the tokens
-    ((128, 8, 128, 2), 8),    # large pages: the floor
+    ((32, 4, 128, 2), 16),    # the same 512 tokens at a page of 32,
+    ((64, 4, 128, 2), 8),     # ... of 64 (what "auto" derives at 4 x 128)
+    ((128, 4, 128, 2), 4),    # ... and of 128
+    ((32, 8, 128, 2), 8),     # llama-3-8b's derived page: 256 tokens
+    ((128, 8, 128, 2), 2),    # large pages: what CHUNK_BYTES holds
+    ((128, 32, 128, 2), 1),   # the floor: one lane tile of tokens
 ])
 def test_pages_per_chunk_follows_page_bytes(shape, want):
     from dynamo_tpu.engine.attention import pages_per_chunk

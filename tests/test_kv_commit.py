@@ -32,7 +32,7 @@ CELLS = {"qwen2.5-7b": (28, 4), "smallthinker-21b-a3b": (24, 4)}
 def scatter_commit(k_cache, kbuf, positions0, cap, seq_lens0, page_table):
     """What ModelRunner's window program does where it scatters."""
     dest, off = window_token_slots(positions0, cap, seq_lens0, page_table,
-                                   kbuf.shape[3], PAGE)
+                                   kbuf.shape[3], k_cache.shape[3])
     return scatter_tokens(k_cache, kbuf.transpose(0, 1, 3, 2, 4), dest, off)
 
 
@@ -43,19 +43,28 @@ ROWS = [(15, 4), (0, 0), (37, 4), (0, 0), (64, 4), (63, 4), (50, 4), (0, 0),
         (3, 2)]
 
 
-def _case(layers, nkv, window, seed=0):
+#: The same at a page of several 16-row tiles (position, pages held): a
+#: window that crosses a page, one that crosses a tile inside a page, one
+#: that starts on a page's last row, a row at its cap, one a token under it.
+def rows_at(page):
+    return [(page - 3, 4), (0, 0), (page + 13, 4), (0, 0), (4 * page, 4),
+            (4 * page - 1, 4), (2 * page + 16, 4), (0, 0), (3, 2),
+            (page - 1, 3)]
+
+
+def _case(layers, nkv, window, seed=0, page=PAGE, rows=ROWS):
     rng = np.random.default_rng(seed)
-    b, maxp = len(ROWS), 4
+    b, maxp = len(rows), 4
     pages = 1 + b * maxp
-    pos = np.array([p for p, _ in ROWS], np.int32)
-    held = np.array([n for _, n in ROWS], np.int32)
+    pos = np.array([p for p, _ in rows], np.int32)
+    held = np.array([n for _, n in rows], np.int32)
     table = (1 + rng.permutation(pages - 1)).reshape(b, maxp).astype(np.int32)
     table[held == 0] = 0
-    pool = (layers, nkv, pages, PAGE, D)
+    pool = (layers, nkv, pages, page, D)
     args = [jnp.asarray(rng.standard_normal(s), jnp.bfloat16)
             for s in (pool, pool, (layers, nkv, b, window, D),
                       (layers, nkv, b, window, D))]
-    return (*args, jnp.asarray(pos), jnp.asarray(held * PAGE),
+    return (*args, jnp.asarray(pos), jnp.asarray(held * page),
             jnp.asarray(np.where(held > 0, pos + 1, 0).astype(np.int32)),
             jnp.asarray(table))
 
@@ -65,7 +74,22 @@ def _case(layers, nkv, window, seed=0):
                          ids=["window 1", "window 4", "window 8",
                               "window 20 (three pages)"])
 def test_in_place_commit_equals_the_scatter_bit_for_bit(window, cell):
-    kc, vc, kb, vb, pos, cap, seq, table = _case(*CELLS[cell], window)
+    _commit_equals_scatter(_case(*CELLS[cell], window))
+
+
+@pytest.mark.parametrize("page", [32, 64, 128])
+@pytest.mark.parametrize("window", [4, 8, 20],
+                         ids=["window 4", "window 8",
+                              "window 20 (three tiles)"])
+def test_in_place_commit_at_larger_pages_equals_the_scatter(window, page):
+    """A page of several tiles: the commit moves the 16-row tiles the
+    tokens fall on, and the pool comes out as the scatter leaves it."""
+    _commit_equals_scatter(_case(3, 2, window, page=page,
+                                 rows=rows_at(page)))
+
+
+def _commit_equals_scatter(case):
+    kc, vc, kb, vb, pos, cap, seq, table = case
     k_new, v_new = commit_window_pallas(kc, vc, kb, vb, pos, cap, seq, table,
                                         interpret=True)
     k_ref = scatter_commit(kc, kb, pos, cap, seq, table)
@@ -83,26 +107,35 @@ def test_in_place_commit_equals_the_scatter_bit_for_bit(window, cell):
     assert len(changed) and set(changed) <= set(np.asarray(table).ravel())
 
 
-def test_window_pages_names_every_landing_token_once():
+@pytest.mark.parametrize("page, tile", [(16, None), (64, 16), (128, 16),
+                                        (64, None)])
+def test_window_pages_names_every_landing_token_once(page, tile):
     """The kernel's schedule against the scatter's index arrays: each
-    (row, page) entry's run [r0, r0 + n) of tokens [m0, m0 + n) is exactly
-    the live tokens the scatter sends to that page."""
+    (row, tile) entry's run [r0, r0 + n) of tokens [m0, m0 + n), at the
+    tile's first row t0 of its page, is exactly the live tokens the
+    scatter sends to that page."""
     window = 20
-    *_, pos, cap, seq, table = _case(1, 1, window)
-    pid, r0, m0, n = (np.asarray(a) for a in window_pages(
-        pos, cap, seq, table, window, PAGE))
-    j = -(-(window - 1) // PAGE) + 1
-    assert pid.shape == (len(ROWS) * j,)
+    rows = ROWS if page == PAGE else rows_at(page)
+    *_, pos, cap, seq, table = _case(1, 1, window, page=page, rows=rows)
+    sched = [np.asarray(a) for a in window_pages(
+        pos, cap, seq, table, window, page, tile)]
+    pid, r0, m0, n = sched[:4]
+    # A page of one tile has no t0: the program the parent lowered.
+    assert len(sched) == (4 if (tile or page) == page else 5)
+    t0 = sched[4] if len(sched) == 5 else np.zeros_like(pid)
+    j = -(-(window - 1) // (tile or page)) + 1
+    assert pid.shape == (len(rows) * j,)
+    assert (t0 % (tile or page) == 0).all()
     landed = {}
     for i in np.flatnonzero(n):
         for t in range(n[i]):
-            landed[(i // j, m0[i] + t)] = (pid[i], r0[i] + t)
+            landed[(i // j, m0[i] + t)] = (pid[i], t0[i] + r0[i] + t)
     want = {}
-    for b, (p, held) in enumerate(ROWS):
+    for b, (p, held) in enumerate(rows):
         for m in range(window):
-            if held and p + m < held * PAGE:
-                want[(b, m)] = (int(table[b, (p + m) // PAGE]),
-                                (p + m) % PAGE)
+            if held and p + m < held * page:
+                want[(b, m)] = (int(table[b, (p + m) // page]),
+                                (p + m) % page)
     assert landed == want
     assert (pid[n == 0] == 0).all()
 
@@ -123,19 +156,23 @@ def _runner(**kw) -> ModelRunner:
 
 
 def _window_of(runner, window):
-    """One decode window over a pool of noise: rows at 15 (the window
-    crosses a page edge), dead, at 40, and one token under its cap."""
+    """One decode window over a pool of noise: rows at a page's last token
+    (the window crosses a page edge), dead, inside a page (at a page of 64
+    its window crosses a 16-row tile), and one token under its cap."""
+    page = runner.config.page_size
     rng = np.random.default_rng(5)
     noise = jnp.asarray(rng.standard_normal(runner.k_cache.shape),
                         jnp.bfloat16)
     runner.k_cache = jax.device_put(noise, runner.kv_sharding)
     runner.v_cache = jax.device_put(-noise, runner.kv_sharding)
     packed = np.zeros((4, PK_PREFIX + 8), np.int32)
-    for slot, (pos, pages) in enumerate([(15, 2), (0, 0), (40, 4), (47, 3)]):
+    for slot, (pos, pages) in enumerate([(page - 1, 2), (0, 0),
+                                         (2 * page + 8, 4),
+                                         (3 * page - 1, 3)]):
         if not pages:
             continue
         packed[slot, [PK_OVERRIDE, PK_TOKEN, PK_POS, PK_SEQLEN, PK_CAP]] = (
-            1, 7 + slot, pos, pos + 1, pages * PAGE)
+            1, 7 + slot, pos, pos + 1, pages * page)
         packed[slot, PK_PREFIX:PK_PREFIX + pages] = 1 + 8 * slot + np.arange(
             pages)
     toks, *_ = runner.decode_window(packed, window)
@@ -143,11 +180,13 @@ def _window_of(runner, window):
             np.asarray(runner.v_cache).view(np.uint16))
 
 
+@pytest.mark.parametrize("page", [PAGE, 64])
 @pytest.mark.parametrize("window", [4, 8])
-def test_run_window_in_place_gives_the_scatter_s_tokens_and_pool(window):
-    in_place = _runner(attention_backend="pallas")
+def test_run_window_in_place_gives_the_scatter_s_tokens_and_pool(window,
+                                                                  page):
+    in_place = _runner(attention_backend="pallas", page_size=page)
     assert in_place.kv_commit_backend == "in_place"
-    scatter = _runner(attention_backend="pallas")
+    scatter = _runner(attention_backend="pallas", page_size=page)
     scatter.kv_commit_backend = "scatter"  # steer the twin: same reader
     toks_a, k_a, v_a = _window_of(in_place, window)
     toks_b, k_b, v_b = _window_of(scatter, window)
@@ -170,6 +209,7 @@ def _window_args(runner):
 def _picked(attention_backend, mesh_size, head_dim, quant_kv):
     runner = object.__new__(ModelRunner)
     runner.attention_backend = attention_backend
+    runner.device = SimpleNamespace(platform="tpu")
     runner.mesh = SimpleNamespace(size=mesh_size)
     runner.spec = SimpleNamespace(head_dim=head_dim)
     runner.quant_kv = quant_kv

@@ -329,8 +329,9 @@ def test_disk_tier_stores_packed_parcels(tmp_path):
 # pallas kernel: fused in-register dequant (interpret mode on CPU)
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("page", [16, 64])
 @pytest.mark.parametrize("d", [64, 128])
-def test_pallas_fused_dequant_matches_xla_quant_path(d):
+def test_pallas_fused_dequant_matches_xla_quant_path(d, page):
     import jax.numpy as jnp
     import ml_dtypes
 
@@ -338,7 +339,9 @@ def test_pallas_fused_dequant_matches_xla_quant_path(d):
     from dynamo_tpu.engine.model import paged_decode_attention_xla
 
     rng = np.random.default_rng(0)
-    page = 16  # d=64 packs tpr=2 tokens per 128-lane row; d=128 is natural
+    # d=64 packs tpr=2 tokens per 128-lane row; d=128 is natural. A page
+    # of 64 (an explicit --page-size: an int8 pool resolves 16 itself) lays
+    # the scales out by the same chunk (attention._chunk_scales).
     L, nkv, P, B, qpk = 2, 2, 12, 3, 4
     k = rng.standard_normal((L, nkv, P, page, d)).astype(ml_dtypes.bfloat16)
     v = rng.standard_normal((L, nkv, P, page, d)).astype(ml_dtypes.bfloat16)
@@ -349,7 +352,7 @@ def test_pallas_fused_dequant_matches_xla_quant_path(d):
     q = jnp.asarray(
         rng.standard_normal((B, nkv * qpk, d)).astype(ml_dtypes.bfloat16))
     pt = jnp.asarray(rng.integers(0, P, size=(B, 8)).astype(np.int32))
-    hist = jnp.asarray(np.array([5, 37, 100], np.int32))
+    hist = jnp.asarray(np.array([5, 37, 100 * page // 16], np.int32))
     k_self = jnp.asarray(
         rng.standard_normal((B, nkv, d)).astype(ml_dtypes.bfloat16))
     v_self = jnp.asarray(

@@ -168,6 +168,7 @@ def test_note_window_derives_roofline_gauges():
 
 class _FakeRunner:
     kv_commit_backend = "in_place"
+    page_size = 64
 
     def __init__(self, hbm):
         self._hbm = hbm
@@ -352,6 +353,12 @@ async def test_perf_smoke_engine_zero_recompiles_and_pane(tmp_path):
             engine.runner.kv_commit_backend == "scatter"
         assert "scatter" in snap1["programs"]["decode_window"]["labels"][
             "kv_commit_backend"]
+        # ... and beside it how many tokens a page holds (a CPU engine:
+        # 16, nothing derived).
+        assert status["page_size"] == engine.runner.page_size == \
+            engine.config.page_size == 16
+        assert snap1["programs"]["decode_window"]["labels"][
+            "page_size"] == [16]
         assert snap1["programs"]["prefill"]["labels"] == {}
         engine.perf_metrics.update(engine, force=True)
         text = metrics.expose().decode()
@@ -359,6 +366,9 @@ async def test_perf_smoke_engine_zero_recompiles_and_pane(tmp_path):
         assert [line for line in text.splitlines()
                 if line.startswith("dynamo_tpu_perf_kv_commit_info{")
                 and 'backend="scatter"' in line and line.endswith(" 1.0")]
+        assert [line for line in text.splitlines()
+                if line.startswith("dynamo_tpu_perf_kv_page_info{")
+                and 'tokens="16"' in line and line.endswith(" 1.0")]
 
         # The pane: worker status server (explicit provider) + frontend
         # (process-global fallback + in-process engine discovery off).
@@ -381,6 +391,7 @@ async def test_perf_smoke_engine_zero_recompiles_and_pane(tmp_path):
                 assert "decode_window" in body["compiles"]["programs"]
                 assert "roofline_frac" in body["window"]
                 assert body["kv_commit_backend"] == "scatter"
+                assert body["page_size"] == 16
             async with session.get(
                     f"http://127.0.0.1:{frontend.port}/debug/perf") as resp:
                 assert resp.status == 200

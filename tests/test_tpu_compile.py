@@ -56,9 +56,21 @@ WIDTHS = {"qwen2.5-0.5b": (64, 2, 7, 24), "llama-3-8b": (128, 8, 4, 32),
 WIDTHS["smallthinker-21b-a3b"] = (128, 4, 7, 24)
 
 
-def _shapes(one, model, quantized, b=40, maxp=64):
+def derived_page(model) -> int:
+    """What page_size="auto" resolves to for the model's KV geometry where
+    the kernel reads the pool on one TPU device."""
+    from dynamo_tpu.engine.config import EngineConfig, ModelSpec
     d, nkv, qpk, layers = WIDTHS[model]
-    pool = (layers, nkv, b * maxp + 16, PAGE, d)
+    return EngineConfig(
+        model=ModelSpec(hidden_size=nkv * qpk * d, num_heads=nkv * qpk,
+                        num_kv_heads=nkv, num_layers=layers),
+        page_size=PAGE).resolve_page_size("tpu")
+
+
+def _shapes(one, model, quantized, b=40, maxp=64, page=PAGE):
+    d, nkv, qpk, layers = WIDTHS[model]
+    maxp = maxp * PAGE // page
+    pool = (layers, nkv, b * maxp + 16, page, d)
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
@@ -83,6 +95,21 @@ def test_decode_kernel_compiles_for_v5e(v5e, model, quantized):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("model, page", [
+    ("qwen2.5-7b", 64), ("smallthinker-21b-a3b", 64), ("llama-3-8b", 32),
+    ("qwen2.5-7b", 128)])
+def test_decode_kernel_compiles_at_the_derived_page(v5e, model, page):
+    """The page "auto" derives where the kernel reads the pool (64 tokens
+    at 4 KV heads of 128, 32 at 8), and the largest a page may be."""
+    if page != 128:
+        assert derived_page(model) == page
+    head, self_kv, qpk = _shapes(v5e, model, False, page=page)
+    compiled = jax.jit(
+        lambda *a: paged_decode_attention_pallas(*a, q_per_kv=qpk)
+    ).lower(*head, self_kv, self_kv).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8kv"])
 def test_window_kernel_compiles_for_v5e(v5e, quantized):
     """The variant the serving window program calls: kernel over the
@@ -97,11 +124,13 @@ def test_window_kernel_compiles_for_v5e(v5e, quantized):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_windowed_window_kernel_compiles_for_v5e(v5e):
+@pytest.mark.parametrize("page", [PAGE, 64])
+def test_windowed_window_kernel_compiles_for_v5e(v5e, page):
     """The variant a model with sliding-window layers runs: a fourth
     prefetched vector, the first token each row still sees, and a walk that
     starts at its chunk."""
-    head, self_kv, qpk = _shapes(v5e, "smallthinker-21b-a3b", False)
+    head, self_kv, qpk = _shapes(v5e, "smallthinker-21b-a3b", False,
+                                 page=page)
     b, nkv, d = self_kv.shape
     win = jax.ShapeDtypeStruct((nkv, b, 4, d), jnp.bfloat16, sharding=v5e)
     step = jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e)
@@ -173,12 +202,13 @@ def pool_sized_ops(text: str, pool: tuple) -> list[tuple[str, str]]:
     return out
 
 
-def _window_program(one, model, commit, pages=1500, rows=32, window=8,
-                    table=128):
+def _window_program(one, model, commit, pool_tokens=24000, rows=32, window=8,
+                    page=PAGE):
     """The runner's own decode window program, lowered for the described
     chip: a ModelRunner that places nothing (no device to hold an array),
     with the cell's attention geometry and depth and a narrow MLP and
-    vocabulary (they never touch the pool, and keep the compile short)."""
+    vocabulary (they never touch the pool, and keep the compile short).
+    Returns (lowered, pool shape)."""
     from types import SimpleNamespace
 
     from dynamo_tpu.engine.config import EngineConfig, ModelSpec
@@ -190,8 +220,11 @@ def _window_program(one, model, commit, pages=1500, rows=32, window=8,
                      num_heads=nkv * qpk, num_kv_heads=nkv)
     runner = object.__new__(ModelRunner)
     runner.spec = spec
-    runner.config = EngineConfig(model=spec, page_size=PAGE, num_pages=pages,
+    pages = pool_tokens // page
+    runner.config = EngineConfig(model=spec, page_size=page, num_pages=pages,
                                  max_num_seqs=rows)
+    assert runner.config.max_model_len == 8192
+    table = runner.config.max_pages_per_seq // 4
     runner.device = SimpleNamespace(platform="tpu")
     runner.mesh = SimpleNamespace(size=1)
     runner.quant_kv = runner.lora = None
@@ -209,35 +242,84 @@ def _window_program(one, model, commit, pages=1500, rows=32, window=8,
     params = jax.tree.map(lambda shape: s(shape, jnp.bfloat16),
                           param_shapes(spec),
                           is_leaf=lambda x: isinstance(x, tuple))
-    pool = (layers, nkv, pages, PAGE, d)
+    pool = (layers, nkv, pages, page, d)
     key = jax.eval_shape(lambda: jax.random.key(0))
-    compiled = runner._get_window(window, table).lower(
+    lowered = runner._get_window(window, table).lower(
         params, s(pool, jnp.bfloat16), s(pool, jnp.bfloat16),
         s((rows,), jnp.int32), s((rows, PK_PREFIX + table), jnp.int32),
-        s(key.shape, key.dtype)).compile()
-    return compiled, pool
+        s(key.shape, key.dtype))
+    return lowered, pool
 
 
-@pytest.mark.parametrize("model, window", [("qwen2.5-7b", 8),
-                                           ("smallthinker-21b-a3b", 4)])
-def test_window_program_commits_in_place_for_v5e(v5e, model, window):
-    """The window program of both benchmark cells' geometry, pools donated:
-    a loop of steps that read the pool through the attention kernel, then
-    the commit. Nothing in the optimised program has the pool's shape but
-    the arguments, the loop's carry and the commit kernel aliased to them:
-    no copy (not the scatter's four, not a defensive one before the aliased
+CELLS = [("qwen2.5-7b", 8), ("smallthinker-21b-a3b", 4)]
+
+
+@pytest.mark.parametrize("page", [PAGE, "derived"])
+@pytest.mark.parametrize("model, window", CELLS)
+def test_window_program_commits_in_place_for_v5e(v5e, model, window, page):
+    """The window program of both benchmark cells' geometry, pools donated,
+    at the page "auto" derives for them (64 tokens) and at 16: a loop of
+    steps that read the pool through the attention kernel, then the
+    commit. Nothing in the optimised program has the pool's shape but the
+    arguments, the loop's carry and the commit kernel aliased to them: no
+    copy (not the scatter's four, not a defensive one before the aliased
     call), no transpose, no scatter."""
-    compiled, pool = _window_program(v5e, model, "in_place", window=window)
-    text = compiled.as_text()
+    if page == "derived":
+        page = derived_page(model)
+        assert page == 64
+    lowered, pool = _window_program(v5e, model, "in_place", window=window,
+                                    page=page)
+    text = lowered.compile().as_text()
     assert text.count("tpu_custom_call") >= 2  # the reader and the commit
     assert "output_to_operand_aliasing" in text
     assert pool_sized_ops(text, pool) == []
+
+
+def lowered_text(lowered) -> str:
+    """The lowered module with each Mosaic kernel's body (MLIR bytecode in
+    the custom call's backend_config, which carries the checkout's path and
+    source lines) printed without positions."""
+    import base64
+    import re
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    def body(match):
+        ctx = mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(match.group(1)))
+            return module.operation.get_asm(enable_debug_info=False)
+
+    return re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', body,
+                  lowered.as_text())
+
+
+#: sha256 of lowered_text of the window programs below as the PARENT of PR
+#: 31 lowered them (16-token pages were all there was). A PR that changes
+#: the window program on purpose lowers them again and replaces these.
+PARENT_AT_16 = {"qwen2.5-7b": "988aad9f87818035",
+                "smallthinker-21b-a3b": "2a965d7c6ecbfe81"}
+
+
+@pytest.mark.parametrize("model, window", CELLS)
+def test_window_program_at_an_explicit_page_of_16_is_the_parent_s(
+        v5e, model, window):
+    """A derived page changes what is chosen, not the program at 16: with
+    page_size=16 given, the reader's chunks, the commit's schedule (four
+    prefetched vectors, whole pages) and everything around them lower to
+    the text they lowered to before a page could be anything else."""
+    import hashlib
+    lowered, _ = _window_program(v5e, model, "in_place", window=window)
+    digest = hashlib.sha256(lowered_text(lowered).encode()).hexdigest()
+    assert digest[:16] == PARENT_AT_16[model]
 
 
 def test_the_pool_guard_sees_the_scatter_s_copies(v5e):
     """The same check on the same program with kv_quant.scatter_tokens as
     its commit FAILS: XLA's scatter wants the pool in another layout than
     the kernel reads, and converts both pools in and out."""
-    compiled, pool = _window_program(v5e, "qwen2.5-7b", "scatter")
-    found = pool_sized_ops(compiled.as_text(), pool)
+    lowered, pool = _window_program(v5e, "qwen2.5-7b", "scatter")
+    found = pool_sized_ops(lowered.compile().as_text(), pool)
     assert [kind for _, kind in found].count("copy") >= 4, found

@@ -130,6 +130,7 @@ def _picked(platform, mesh_size, head_dim, backend="auto", page_size=16):
     runner.spec = SimpleNamespace(head_dim=head_dim)
     runner.device = SimpleNamespace(platform=platform)
     runner.mesh = SimpleNamespace(size=mesh_size)
+    runner.quant_kv = None  # the writer's half of config.pool_access
     step, window = runner._pick_attention()
     return runner.attention_backend, step, window
 
