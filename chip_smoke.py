@@ -17,8 +17,11 @@ program's init seed), and checks what comes out by the repo's own means:
   kernels    attention_backend="pallas" (bf16 and int8 KV) vs XLA on engines
              with a small chunk size, so scheduled chunked prefill runs too,
              then "auto" on a small head_dim-128 model, which must resolve
-             to the kernel on the chip; ``tpu_custom_call`` must be in the
-             compiled window program wherever the kernel runs
+             to the kernel on the chip, and on the Cohere2-MoE block at toy
+             depth and Command A+'s head geometry (128 query heads over 8
+             KV heads of 128, a share of the experts, a window the longest
+             prompt passes: a page of 32 there); ``tpu_custom_call`` must be
+             in the compiled window program wherever the kernel runs
   disagg     prefill engine -> KV plane -> decode engine on the one chip;
              tokens must equal the aggregated engine's
 
@@ -514,7 +517,8 @@ async def phase_kernels(args, jax, rng, keep: dict):
     with bf16 and int8 KV on the served model (head_dim 64: the packed
     variant, which "auto" does not select), then what "auto" resolves to on
     a small head_dim-128 model (the kernel on one TPU device, XLA on the
-    CPU rehearsal) against "xla" on the same weights. Same prompts at mixed
+    CPU rehearsal) against "xla" on the same weights, at 4 KV heads and, on
+    the Cohere2-MoE block, at 8 KV heads under 16 query rows each. Same prompts at mixed
     lengths per round. Returns the served model's bf16 XLA engine (the
     disagg phase's aggregated reference)."""
     import jax.numpy as jnp
@@ -529,6 +533,20 @@ async def phase_kernels(args, jax, rng, keep: dict):
     wide = ModelSpec(name="smoke-d128", vocab_size=2048, hidden_size=1024,
                      intermediate_size=1024, num_layers=2, num_heads=8,
                      num_kv_heads=4)
+    # The Cohere2-MoE block at toy depth and Command A+'s head geometry: 128
+    # query heads over 8 KV heads of 128 (16 query rows a KV head; a page
+    # of 32 tokens by the same rule), one period of window and full layers
+    # with a window the longest prompt passes, a share of the experts.
+    from dynamo_tpu.engine.config import Cohere2MoeSpec
+    share = Cohere2MoeSpec(
+        name="smoke-ep-share", vocab_size=2048, hidden_size=512,
+        intermediate_size=256, num_layers=4, num_heads=128, num_kv_heads=8,
+        head_dim=128, tie_word_embeddings=True, num_experts=4,
+        num_experts_per_tok=3, moe_intermediate_size=256,
+        num_routed_experts=8, first_expert=4, num_shared_experts=2,
+        sliding_window=64 if args.rehearse_cpu else 256,
+        sliding_window_layout=(1, 1, 1, 0), rope_layout=(1, 1, 1, 0))
+    pages = {wide.name: 64, share.name: 32}      # where "auto" derives one
     lengths = (20, 70, 150) if args.rehearse_cpu else (24, 200, 700)
     n_out = 20
     on_tpu = jax.devices()[0].platform == "tpu"
@@ -536,7 +554,8 @@ async def phase_kernels(args, jax, rng, keep: dict):
     for spec_r, params_r, quant_kv, backends in (
             (spec, params, None, ("xla", "pallas")),
             (spec, params, "int8", ("xla", "pallas")),
-            (wide, None, None, ("xla", "auto"))):
+            (wide, None, None, ("xla", "auto")),
+            (share, None, None, ("xla", "auto"))):
         prompts = [rng.integers(2, spec_r.vocab_size, size=n).tolist()
                    for n in lengths]
         runs = {}
@@ -544,7 +563,7 @@ async def phase_kernels(args, jax, rng, keep: dict):
         # resolves: the kernel is compared with XLA at the derived page.
         page = small_config(args, spec_r, quant_kv=quant_kv,
                             attention_backend=backends[1]).page_size
-        check(page == (64 if on_tpu and spec_r is wide else 16),
+        check(page == (pages.get(spec_r.name, 16) if on_tpu else 16),
               f"{spec_r.name} under {backends[1]}: page of {page} tokens")
         for backend in backends:
             eng = TPUEngine(small_config(args, spec_r, quant_kv=quant_kv,

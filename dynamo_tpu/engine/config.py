@@ -1,13 +1,18 @@
 """Model and engine configuration.
 
-ModelSpec states three block kinds: the dense Llama / Qwen2 block (QKV bias
+ModelSpec states four block kinds: the dense Llama / Qwen2 block (QKV bias
 by ``qkv_bias``), the Mixtral-style block (``num_experts`` SwiGLU experts of
-the dense width, top-k then softmax, routed on the post-attention norm), and
-the SmallThinker block (a router that reads the layer's INPUT, softmax over
+the dense width, top-k then softmax, routed on the post-attention norm), the
+SmallThinker block (a router that reads the layer's INPUT, softmax over
 all experts then top-k renormalised, ReGLU experts of their own width, and a
-per-layer pattern of RoPE / NoPE and sliding-window / full attention).
-``from_hf_config`` reads each from its public ``config.json`` keys as they
-are spelled there.
+per-layer pattern of RoPE / NoPE and sliding-window / full attention), and
+the Cohere2-MoE block (Command A+: attention and feed-forward read ONE
+mean-centred LayerNorm of the layer's input and both add to the residual, a
+sigmoid router whose width is the deployment's experts while this device
+holds ``num_experts`` of them from ``first_expert`` on, shared experts
+averaged, interleaved RoPE on window layers and none on full ones, a tied
+head). ``from_hf_config`` reads each from its public ``config.json`` keys as
+they are spelled there.
 """
 
 from __future__ import annotations
@@ -82,6 +87,15 @@ class ModelSpec:
     sliding_window = None               # every layer sees every earlier key
     sliding_window_layout = None
     rope_layout = None                  # RoPE on every layer
+    rope_interleaved = False            # rotate-half pairs (i, i + half)
+    norm_kind = "rms"                   # RMSNorm; rms_norm_eps is its epsilon
+    parallel_block = False              # attention, then feed-forward
+    # An expert layer that is told its share (Cohere2MoeSpec): the router's
+    # width where it is not num_experts, the first expert held, and the
+    # experts every token passes through.
+    num_routed_experts = None
+    first_expert = 0
+    num_shared_experts = 0
     # Weight-only quantization: None (bf16) or "int8" (engine/quant.py —
     # int8 storage, bf16 MXU compute; halves the weight-read roofline and
     # fits full llama-3-8b on one 16 GB v5e).
@@ -97,6 +111,17 @@ class ModelSpec:
         return self.moe_intermediate_size or self.intermediate_size
 
     @property
+    def router_width(self) -> int:
+        """Outputs of the router: every expert of the deployment, of which
+        this device holds ``num_experts``."""
+        return self.num_routed_experts or self.num_experts
+
+    @property
+    def holds_share(self) -> bool:
+        """Some of the router's experts are held elsewhere."""
+        return self.router_width != self.num_experts
+
+    @property
     def has_layer_pattern(self) -> bool:
         """Layers differ in kind (RoPE or not, window or full)."""
         return bool((self.rope_layout and not all(self.rope_layout))
@@ -108,17 +133,21 @@ class ModelSpec:
         return self.num_heads // self.num_kv_heads
 
     def num_params(self) -> int:
-        """Approximate parameter count."""
+        """Parameters resident here: the sum of model.param_shapes (the
+        experts HELD, shared experts, QKV biases, one norm a layer in a
+        parallel block)."""
         h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
         d = self.head_dim
         attn = h * (self.num_heads * d) + 2 * h * (self.num_kv_heads * d) \
             + (self.num_heads * d) * h
+        if self.qkv_bias:
+            attn += (self.num_heads + 2 * self.num_kv_heads) * d
         if self.num_experts:
-            mlp = (self.num_experts * 3 * h * self.expert_size
-                   + h * self.num_experts)
+            mlp = ((self.num_experts + self.num_shared_experts)
+                   * 3 * h * self.expert_size + h * self.router_width)
         else:
             mlp = 3 * h * i
-        per_layer = attn + mlp + 2 * h
+        per_layer = attn + mlp + (1 if self.parallel_block else 2) * h
         embed = v * h * (1 if self.tie_word_embeddings else 2)
         return self.num_layers * per_layer + embed + h
 
@@ -149,6 +178,8 @@ class ModelSpec:
             cfg = json.load(fh)
         if "moe_num_primary_experts" in cfg:
             return cls._from_smallthinker(cfg, path)
+        if cfg.get("model_type") == "cohere2_moe":
+            return cls._from_cohere2_moe(cfg, path)
         return cls(
             name=cfg.get("_name_or_path", os.path.basename(os.path.dirname(path))),
             vocab_size=cfg["vocab_size"],
@@ -208,6 +239,85 @@ class ModelSpec:
         )
 
 
+    @classmethod
+    def _from_cohere2_moe(cls, cfg: dict, path: str) -> "ModelSpec":
+        """Command A+'s keys (CohereLabs/command-a-plus-05-2026
+        ``config.json``). ``num_experts`` counts the experts HELD here; a
+        file that cuts a deployment's share states the router's width and
+        the share's first expert under ``expert_parallel``
+        (``{"routed_experts": 128, "first_expert": 0}``), the public file
+        has neither and holds them all."""
+        reader = "the config reader"
+        for key, want, why in (
+                ("use_qk_norm", False, "no path normalises q and k"),
+                ("attention_bias", False, "its projections have no bias "
+                 "leaves under a LayerNorm block"),
+                ("use_parallel_block", True, "the sequential Cohere block "
+                 "is not written down in this repository"),
+                ("use_gated_activation", True, "an ungated expert is not "
+                 "written down in this repository"),
+                ("hidden_act", "silu", "the experts are SwiGLU"),
+                ("expert_selection_fn", "sigmoid", "the router kinds are "
+                 "sigmoid_topk, softmax_topk and topk_softmax"),
+                ("shared_expert_combination_strategy", "average", "shared "
+                 "experts are averaged and added to the routed sum"),
+                ("position_embedding_type", "rope_gptj", "this block's "
+                 "rotation is the interleaved one"),
+                ("rotary_pct", 1, "no path rotates part of a head"),
+                ("logit_scale", 1, "no path scales the logits")):
+            got = cfg.get(key, want)
+            if got != want:
+                raise UnsupportedBlockError(
+                    reader, f"cohere2_moe with {key} {got!r}: {why}")
+        if cfg.get("first_k_dense_replace", 0) > 0:
+            raise UnsupportedBlockError(
+                reader, "cohere2_moe with first_k_dense_replace > 0: no "
+                "path mixes dense and routed layers in one scan")
+        scaling = (cfg.get("rope_parameters") or {}).get("rope_type",
+                                                         "default")
+        if cfg.get("rope_scaling") or scaling != "default":
+            raise UnsupportedBlockError(
+                reader, "cohere2_moe with scaled RoPE: no path scales its "
+                "rotation")
+        kinds = {"sliding_attention": 1, "full_attention": 0}
+        try:
+            windowed = tuple(kinds[t] for t in cfg["layer_types"])
+        except KeyError as exc:
+            raise UnsupportedBlockError(
+                reader, f"cohere2_moe layer type {exc.args[0]!r}: the layer "
+                "kinds are sliding_attention and full_attention") from None
+        share = cfg.get("expert_parallel") or {}
+        width = cfg["intermediate_size"]
+        return Cohere2MoeSpec(
+            name=cfg.get("_name_or_path")
+            or os.path.basename(os.path.dirname(path)),
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=width,
+            num_layers=cfg["num_hidden_layers"],
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg.get("num_key_value_heads",
+                                 cfg["num_attention_heads"]),
+            head_dim=cfg.get("head_dim"),
+            rope_theta=float(cfg.get("rope_theta", 10000.0)),
+            rms_norm_eps=cfg.get("layer_norm_eps", 1e-5),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", True),
+            max_position_embeddings=cfg.get("max_position_embeddings", 8192),
+            num_experts=cfg["num_experts"],
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            moe_intermediate_size=width,
+            norm_topk_prob=bool(cfg.get("norm_topk_prob", False)),
+            sliding_window=cfg.get("sliding_window"),
+            sliding_window_layout=windowed,
+            # Window layers rotate, full layers carry no position.
+            rope_layout=windowed,
+            num_routed_experts=share.get("routed_experts",
+                                         cfg["num_experts"]),
+            first_expert=share.get("first_expert", 0),
+            num_shared_experts=cfg.get("num_shared_experts", 0),
+        )
+
+
 @dataclasses.dataclass
 class SmallThinkerSpec(ModelSpec):
     """The SmallThinker block (PowerInfer/SmallThinker-21BA3B-Instruct):
@@ -247,6 +357,47 @@ class SmallThinkerSpec(ModelSpec):
             raise ValueError("sliding_window_layout without sliding_window")
 
 
+@dataclasses.dataclass
+class Cohere2MoeSpec(SmallThinkerSpec):
+    """The Cohere2-MoE block (CohereLabs/command-a-plus-05-2026): the layer
+    pattern, expert width and router fields SmallThinkerSpec states, at
+    this block's values, and what it states beyond them."""
+    # "sigmoid_topk": sigmoid of every logit in float32, the k largest,
+    # divided by their sum when norm_topk_prob (over all k chosen, held
+    # here or not).
+    moe_router: str = "sigmoid_topk"
+    # The router reads what the experts read: the layer's one norm.
+    moe_router_input: str = "post_attn_norm"
+    ffn_act: str = "silu"               # SwiGLU
+    # RoPE in interleaved pairs (2i, 2i + 1) ("rope_gptj"), where
+    # rope_layout has a 1.
+    rope_interleaved: bool = True
+    # "layer": (x - mean) / sqrt(var + rms_norm_eps) * weight, no bias.
+    norm_kind: str = "layer"
+    # x + attention(norm(x)) + feed_forward(norm(x)): one norm a layer.
+    parallel_block: bool = True
+    # The router's width; this device holds experts first_expert to
+    # first_expert + num_experts - 1 and computes their part of the routed
+    # sum. What the others would add is left out: no exchange.
+    num_routed_experts: int | None = None
+    first_expert: int = 0
+    # Experts every token passes through, of the routed experts' width;
+    # the mean of their outputs is added to the routed sum.
+    num_shared_experts: int = 0
+
+    def __post_init__(self):
+        super().__post_init__()
+        if self.num_routed_experts is None:
+            self.num_routed_experts = self.num_experts
+        if not (0 <= self.first_expert
+                and self.first_expert + self.num_experts
+                <= self.num_routed_experts):
+            raise ValueError(
+                f"experts {self.first_expert} to "
+                f"{self.first_expert + self.num_experts - 1} are not among "
+                f"the router's {self.num_routed_experts}")
+
+
 class UnsupportedBlockError(NotImplementedError):
     """A path that lacks a mechanism a model's block needs refuses the
     model at start-up and names what it lacks; it never runs the block
@@ -257,24 +408,30 @@ class UnsupportedBlockError(NotImplementedError):
 
 
 def block_refusals(spec: ModelSpec, config: "EngineConfig | None" = None,
-                   checkpoint: bool = False) -> list[UnsupportedBlockError]:
+                   checkpoint: bool = False, embeddings: bool = False
+                   ) -> list[UnsupportedBlockError]:
     """Every reason the block of ``spec`` cannot run the way ``config``
-    asks (None: nothing is asked of an engine) or, with ``checkpoint``,
-    take its weights from safetensors. ModelRunner raises the first at
-    start-up and the loader before it opens a file; the forward functions
-    hold no refusal, and nothing else in the package asks what kind of
-    block a model has.
+    asks (None: nothing is asked of an engine), with ``checkpoint`` take
+    its weights from safetensors, or with ``embeddings`` take an encoder's
+    embeddings in place of token rows. ModelRunner raises the first at
+    start-up, the loader before it opens a file and the engine as it
+    validates such a request; the forward functions hold no refusal, and
+    nothing else in the package asks what kind of block a model has.
 
     Where a path lacks a mechanism, the test is on the field that carries
     it. Where a combination was only never compared with its reference,
     the test is ``other``: a block that is not the Llama / Qwen2 / Mixtral
     one (a router of another kind or ahead of attention, ReGLU, layers
-    that differ in kind)."""
+    that differ in kind, one norm for both branches, shared experts, a
+    share of the experts)."""
     windowed = bool(spec.sliding_window_layout
                     and any(spec.sliding_window_layout))
+    share = spec.holds_share
     other = (spec.has_layer_pattern or spec.moe_router != "topk_softmax"
              or spec.moe_router_input != "post_attn_norm"
-             or spec.ffn_act != "silu")
+             or spec.ffn_act != "silu" or spec.parallel_block
+             or spec.norm_kind != "rms" or spec.num_shared_experts > 0
+             or share)
     unlike = "a block other than Llama's, Qwen2's or Mixtral's"
     out = []
     if checkpoint and other:
@@ -282,8 +439,21 @@ def block_refusals(spec: ModelSpec, config: "EngineConfig | None" = None,
             "the safetensors loader", "it has a tensor-name map for the "
             f"Llama, Qwen2 and Mixtral checkpoints only, and this is {unlike} "
             "(random weights only)"))
+    if embeddings and (spec.num_shared_experts or share):
+        out.append(UnsupportedBlockError(
+            "encoder embeddings in a prompt (mm_embeds)", "no vision or "
+            "audio tower is written down for a block with shared experts "
+            "or a share of its routed experts, and another encoder's rows "
+            "under it were never compared with its reference"))
     if config is None:
         return out
+    if share and config.tp * config.pp * config.dp * config.sp > 1:
+        out.append(UnsupportedBlockError(
+            "a tp/pp/dp/sp mesh", f"the expert layer is told ONE share "
+            f"(experts {spec.first_expert} to "
+            f"{spec.first_expert + spec.num_experts - 1} of "
+            f"{spec.router_width}) and there is no exchange of rows "
+            "between devices that hold different experts"))
     if config.spec_decode and windowed:
         out.append(UnsupportedBlockError(
             "speculative decoding (spec_decode)", "the verify step's scores "

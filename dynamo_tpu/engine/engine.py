@@ -30,7 +30,8 @@ from typing import AsyncIterator
 
 import numpy as np
 
-from dynamo_tpu.engine.config import EngineConfig, device_peaks
+from dynamo_tpu.engine.config import (EngineConfig, block_refusals,
+                                      device_peaks)
 from dynamo_tpu.engine.kv_cache import PageAllocator
 from dynamo_tpu.engine.runner import (
     ModelRunner, PrefillSeq, PK_OVERRIDE, PK_TOKEN, PK_POS, PK_SEQLEN,
@@ -131,7 +132,8 @@ class _Window:
     # A routed block's window: float32 [3] read back with the tokens
     # (runner.decode_window): distinct experts chosen and the fullest
     # expert's tokens over the mean, summed over steps and expert layers,
-    # and how many of those the sums hold.
+    # and how many of those the sums hold; [5] where the expert layer is
+    # told its share: picks on held experts and all picks follow.
     moe: object = None
     # Speculative windows: toks = (outs [m,B,S], emits [m,B],
     # ndrafts [m,B]); slots snaps carry the ASSUMED advance so
@@ -274,8 +276,10 @@ class TPUEngine(AsyncEngine):
         self.brownout_level = 0
         self.spec_brownout_windows = 0
         # Expert-layer load of a routed block, summed over every decode
-        # window processed (the three entries of _Window.moe).
-        self.moe_totals = np.zeros(3, np.float64)
+        # window processed (the entries of _Window.moe).
+        self.moe_totals = np.zeros(
+            5 if config.model.num_routed_experts is not None else 3,
+            np.float64)
         # Control jobs executed on the engine thread between windows
         # (disagg prefill-extract, KV injection helpers, etc.).
         self._jobs: queue.Queue = queue.Queue()
@@ -401,6 +405,9 @@ class TPUEngine(AsyncEngine):
     def _validate(self, req: PreprocessedRequest) -> None:
         if not req.token_ids:
             raise ValueError("empty token_ids")
+        if getattr(req, "mm_embeds", None):
+            for refusal in block_refusals(self.config.model, embeddings=True):
+                raise refusal
         if self.config.spec_decode:
             # Spec decode serves the full sampling surface on-device
             # (temperature/top-k/top-p/seed as data in the verify
@@ -1059,17 +1066,27 @@ class TPUEngine(AsyncEngine):
                        for k, v in self.phase_clock.totals().items()},
         }
         if self.runner.spec.num_experts:
-            touched, load, n = self.moe_totals
-            experts = self.runner.spec.num_experts
+            touched, load, n = self.moe_totals[:3]
+            spec = self.runner.spec
+            experts = spec.num_experts
             status["moe"] = {
+                # Experts this device holds; touched and load count them.
                 "experts": experts,
-                "experts_per_tok": self.runner.spec.num_experts_per_tok,
+                "experts_per_tok": spec.num_experts_per_tok,
                 # (decode step, expert layer) pairs with a live row.
                 "layer_steps": int(n),
                 "experts_touched_pct": round(100.0 * touched / (n * experts),
                                              3) if n else None,
                 "load_max_over_mean": round(load / n, 4) if n else None,
             }
+            if spec.num_routed_experts is not None:
+                local, picks = self.moe_totals[3:]
+                status["moe"].update(
+                    experts_routed=spec.router_width,
+                    first_expert=spec.first_expert,
+                    experts_shared=spec.num_shared_experts,
+                    local_picks_pct=round(100.0 * local / picks, 3)
+                    if picks else None)
         if self.config.spec_decode:
             # Verify-of-k bandwidth: the spec program runs m_outer verify
             # steps of S = spec_k + 1 positions each, so cost-registry
@@ -2418,7 +2435,7 @@ class TPUEngine(AsyncEngine):
                 top_vs = np.asarray(w.toks[2]) if want_lp else None
                 top_is = np.asarray(w.toks[3]) if want_lp else None
                 if len(w.toks) > 4:
-                    # Twelve bytes of the same program's output, copied
+                    # A few bytes of the same program's output, copied
                     # with the tokens: no second wait for the device.
                     w.moe = np.asarray(w.toks[4], np.float64)
                     self.moe_totals += w.moe

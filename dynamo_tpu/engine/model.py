@@ -1,11 +1,13 @@
-"""Functional JAX transformer (Llama/Qwen2 family) with a paged KV cache.
+"""Functional JAX transformer (the block kinds engine/config.py states) with
+a paged KV cache.
 
 Pure-functional, scan-over-layers (O(1) compile time in depth), bfloat16 on
 the MXU with fp32 softmax/norm accumulations. Parameters and the KV cache are
 sharded over a ("dp", "tp") mesh with XLA inserting the collectives
 (all-reduce after attention-out and MLP-down projections) — the tpu-idiomatic
 replacement for the reference engines' NCCL tensor parallelism (SURVEY.md
-§2.7). RoPE uses HF's rotate-half convention so HF safetensors load directly.
+§2.7). RoPE uses HF's rotate-half convention so HF safetensors load directly
+(interleaved pairs where a spec says so).
 """
 
 from __future__ import annotations
@@ -122,12 +124,20 @@ def param_shapes(spec: ModelSpec) -> dict:
         "wv": (L, h, nkv * d),
         "wo": (L, nh * d, h),
     }
+    if spec.parallel_block:             # one norm feeds both branches
+        del layers["post_attn_norm"]
     if spec.num_experts:
+        # The router over every expert of the deployment, the experts HELD.
         E, ie = spec.num_experts, spec.expert_size
-        layers["moe_gate"] = (L, h, E)
+        layers["moe_gate"] = (L, h, spec.router_width)
         layers["moe_w_gate"] = (L, E, h, ie)
         layers["moe_w_up"] = (L, E, h, ie)
         layers["moe_w_down"] = (L, E, ie, h)
+        if spec.num_shared_experts:
+            S = spec.num_shared_experts
+            layers["shared_w_gate"] = (L, S, h, ie)
+            layers["shared_w_up"] = (L, S, h, ie)
+            layers["shared_w_down"] = (L, S, ie, h)
     else:
         layers["w_gate"] = (L, h, i)
         layers["w_up"] = (L, h, i)
@@ -161,11 +171,18 @@ def param_specs(spec: ModelSpec) -> dict:
         "wv": P("pp", None, "tp"),
         "wo": P("pp", "tp", None),
     }
+    if spec.parallel_block:
+        del layers["post_attn_norm"]
     if spec.num_experts:
         layers["moe_gate"] = P("pp", None, None)
         layers["moe_w_gate"] = P("pp", "tp", None, None)
         layers["moe_w_up"] = P("pp", "tp", None, None)
         layers["moe_w_down"] = P("pp", "tp", None, None)
+        if spec.num_shared_experts:
+            # Every device computes every shared expert (such a block is
+            # served on one device: config.block_refusals).
+            for key in ("shared_w_gate", "shared_w_up", "shared_w_down"):
+                layers[key] = P("pp", None, None, None)
     else:
         layers["w_gate"] = P("pp", None, "tp")
         layers["w_up"] = P("pp", None, "tp")
@@ -224,10 +241,14 @@ def moe_route(router: jax.Array, spec: ModelSpec
     """Router logits [T, E] float32 -> (gates [T, k] float32, experts
     [T, k]). "topk_softmax" (Mixtral): the k largest logits, softmax over
     those. "softmax_topk" (SmallThinker): softmax over all E, the k largest
-    probabilities, divided by their sum when norm_topk_prob."""
-    if spec.moe_router == "softmax_topk":
-        top_v, top_i = jax.lax.top_k(jax.nn.softmax(router, axis=-1),
-                                     spec.num_experts_per_tok)
+    probabilities, divided by their sum when norm_topk_prob.
+    "sigmoid_topk" (Cohere2-MoE): the same with a sigmoid of each logit in
+    place of the softmax. E is the router's width: the sum runs over all k
+    chosen, whichever device holds them."""
+    if spec.moe_router in ("softmax_topk", "sigmoid_topk"):
+        score = (jax.nn.sigmoid(router) if spec.moe_router == "sigmoid_topk"
+                 else jax.nn.softmax(router, axis=-1))
+        top_v, top_i = jax.lax.top_k(score, spec.num_experts_per_tok)
         if spec.norm_topk_prob:
             top_v = top_v / jnp.sum(top_v, axis=-1, keepdims=True)
         return top_v, top_i
@@ -248,17 +269,23 @@ def _gate_act(gate: jax.Array, spec: ModelSpec) -> jax.Array:
 def moe_load_stats(one_hot: jax.Array, live: jax.Array, spec: ModelSpec
                    ) -> jax.Array:
     """What one expert layer's routing did to the rows that are ``live``
-    [T] (bool): float32 [3] = (distinct experts chosen, the fullest
-    expert's tokens over the mean, 1 if any row was live else 0).
-    one_hot [T, k, E]."""
+    [T] (bool), counted over the experts HELD: float32 [3] = (distinct
+    held experts chosen, the fullest held expert's tokens over the mean an
+    expert of the router's width would get, 1 if any row was live else 0).
+    one_hot [T, k, E] over the held experts (a choice that fell on an
+    expert held elsewhere is a row of zeros). A layer that is told its
+    share (num_routed_experts) adds two: the (row, choice) pairs that fell
+    on held experts, and all pairs."""
     load = jnp.einsum("tke,t->e", one_hot, live.astype(jnp.float32))
     rows = jnp.sum(live.astype(jnp.float32))
-    mean = rows * spec.num_experts_per_tok / spec.num_experts
+    mean = rows * spec.num_experts_per_tok / spec.router_width
     some = rows > 0
-    return jnp.stack([jnp.sum(load > 0).astype(jnp.float32),
-                      jnp.where(some, jnp.max(load)
-                                / jnp.maximum(mean, 1e-9), 0.0),
-                      some.astype(jnp.float32)])
+    stats = [jnp.sum(load > 0).astype(jnp.float32),
+             jnp.where(some, jnp.max(load) / jnp.maximum(mean, 1e-9), 0.0),
+             some.astype(jnp.float32)]
+    if spec.num_routed_experts is not None:
+        stats += [jnp.sum(load), rows * spec.num_experts_per_tok]
+    return jnp.stack(stats)
 
 
 def _grouped_experts(x: jax.Array, gates: jax.Array, top_i: jax.Array,
@@ -306,7 +333,15 @@ def ffn_block(h2: jax.Array, lp: dict, spec: ModelSpec, ll: dict | None = None,
     Above it, where the caller says the experts are whole on one device
     (``experts_local``: the runner's mesh has one device), either routed
     kind computes the chosen experts only (``_grouped_experts``; the grouped
-    product has no partitioning rule yet).
+    product has no partitioning rule yet); a layer that holds a SHARE of a
+    wider router's experts keeps the masked product, a block of rows at a
+    time.
+
+    The router is as wide as the deployment has experts and its gates are
+    normalised over all the chosen; this device multiplies the experts it
+    HOLDS (``first_expert`` on, ``num_experts`` of them) and what the others
+    would add is left out: no exchange, nothing in its place. Shared
+    experts (``num_shared_experts``) take every row and their mean is added.
 
     With ``live`` ([T] bool, routed blocks only) returns (out, stats) with
     ``moe_load_stats`` of the live rows; else out."""
@@ -332,28 +367,61 @@ def ffn_block(h2: jax.Array, lp: dict, spec: ModelSpec, ll: dict | None = None,
         router = jnp.einsum("th,he->te", rin, lp["moe_gate"],
                             preferred_element_type=jnp.float32)
         gates, top_i = moe_route(router, spec)
+        if spec.holds_share:
+            # Counted from the first expert held: a choice that fell on
+            # an expert held elsewhere has no column in one_hot.
+            top_i = top_i - spec.first_expert
         one_hot = jax.nn.one_hot(top_i, spec.num_experts, dtype=jnp.float32)
         stats = (None if live is None
                  else moe_load_stats(one_hot, live.reshape(-1), spec))
     with scope("moe.experts"):
-        if experts_local and x.shape[0] > MOE_DENSE_MAX_ROWS:
+        share, rows = spec.holds_share, x.shape[0]
+        if experts_local and rows > MOE_DENSE_MAX_ROWS and not share:
             out = _grouped_experts(x, gates, top_i, lp, spec)
         else:
             w_te = jnp.einsum("tk,tke->te", gates, one_hot)  # [T, E] sparse-ish
-            gate = mm(x, lp["moe_w_gate"], "th,ehi->eti")
-            up = mm(x, lp["moe_w_up"], "th,ehi->eti")
-            ff = _gate_act(gate, spec) * up
-            wd = lp["moe_w_down"]
-            if isinstance(wd, QTensor):
-                down = (jnp.einsum("eti,eih->eth", ff,
-                                   wd.q.astype(jnp.bfloat16),
-                                   preferred_element_type=jnp.float32) * wd.s)
+
+            def masked(x, w_te):
+                down = _every_expert(x, lp["moe_w_gate"], lp["moe_w_up"],
+                                     lp["moe_w_down"], spec)
+                return jnp.einsum("eth,te->th", down, w_te)
+
+            if share and rows > MOE_DENSE_MAX_ROWS:
+                # A share's long batch: the masked product a block of rows
+                # at a time. Sorted by expert, 7 of 8 (row, choice) pairs
+                # would belong to experts held elsewhere, and the grouped
+                # product's gathers are sized for all of them.
+                pad = -rows % MOE_DENSE_MAX_ROWS
+                blocks = jax.lax.map(lambda xw: masked(*xw), tuple(
+                    jnp.pad(a, ((0, pad), (0, 0))).reshape(
+                        -1, MOE_DENSE_MAX_ROWS, a.shape[-1])
+                    for a in (x, w_te)))
+                out = blocks.reshape(rows + pad, -1)[:rows]
             else:
-                down = jnp.einsum("eti,eih->eth", ff, wd,
-                                  preferred_element_type=jnp.float32)
-            out = jnp.einsum("eth,te->th", down, w_te)
+                out = masked(x, w_te)
+    if spec.num_shared_experts:
+        with scope("moe.shared"):
+            # Every row through every shared expert; their mean joins the
+            # routed sum ("shared_expert_combination_strategy": "average").
+            shared = _every_expert(x, lp["shared_w_gate"], lp["shared_w_up"],
+                                   lp["shared_w_down"], spec)
+            out = out + jnp.mean(shared, axis=0)
     out = out.astype(jnp.bfloat16).reshape(orig)
     return out if live is None else (out, stats)
+
+
+def _every_expert(x: jax.Array, w_gate, w_up, w_down, spec: ModelSpec
+                  ) -> jax.Array:
+    """Every expert of a stack [E, ...] on every row: x [T, H] bf16 ->
+    [E, T, H] float32, SwiGLU / ReGLU by ``spec.ffn_act``."""
+    gate = mm(x, w_gate, "th,ehi->eti")
+    up = mm(x, w_up, "th,ehi->eti")
+    ff = _gate_act(gate, spec) * up
+    if isinstance(w_down, QTensor):
+        return (jnp.einsum("eti,eih->eth", ff, w_down.q.astype(jnp.bfloat16),
+                           preferred_element_type=jnp.float32) * w_down.s)
+    return jnp.einsum("eti,eih->eth", ff, w_down,
+                      preferred_element_type=jnp.float32)
 
 
 def init_params(spec: ModelSpec, key: jax.Array, dtype=jnp.bfloat16) -> Params:
@@ -374,10 +442,9 @@ def init_params(spec: ModelSpec, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     params = jax.tree.unflatten(treedef, inited)
     # Norm scales must be ones.
     params["final_norm"] = jnp.ones(shapes["final_norm"], dtype)
-    params["layers"]["input_norm"] = jnp.ones(
-        shapes["layers"]["input_norm"], dtype)
-    params["layers"]["post_attn_norm"] = jnp.ones(
-        shapes["layers"]["post_attn_norm"], dtype)
+    for name, shape in shapes["layers"].items():
+        if name.endswith("_norm"):
+            params["layers"][name] = jnp.ones(shape, dtype)
     return params
 
 
@@ -391,6 +458,21 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 
+def layer_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """Mean-centred LayerNorm without bias, in float32 inside."""
+    xf = x.astype(jnp.float32)
+    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
+
+
+def norm(x: jax.Array, scale: jax.Array, spec: ModelSpec) -> jax.Array:
+    """The model's norm: RMSNorm or, by ``spec.norm_kind``, LayerNorm."""
+    if spec.norm_kind == "layer":
+        return layer_norm(x, scale, spec.rms_norm_eps)
+    return rms_norm(x, scale, spec.rms_norm_eps)
+
+
 def rope_tables(positions: jax.Array, head_dim: int, theta: float
                 ) -> tuple[jax.Array, jax.Array]:
     """cos/sin tables for HF rotate-half RoPE; positions [...]."""
@@ -402,9 +484,18 @@ def rope_tables(positions: jax.Array, head_dim: int, theta: float
     return cos, sin
 
 
-def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """x [..., heads, head_dim]; cos/sin [..., half] (broadcast over heads)."""
+def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
+               interleaved: bool = False) -> jax.Array:
+    """x [..., heads, head_dim]; cos/sin [..., half] (broadcast over heads).
+    Frequency i turns the pair (i, i + half) (rotate-half) or, with
+    ``interleaved`` (GPT-J), the pair (2i, 2i + 1)."""
     half = x.shape[-1] // 2
+    if interleaved:
+        pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], half, 2)
+        xf1, xf2 = pairs[..., 0], pairs[..., 1]
+        cos, sin = cos[..., None, :], sin[..., None, :]
+        out = jnp.stack([xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin], -1)
+        return out.reshape(x.shape).astype(x.dtype)
     x1, x2 = x[..., :half], x[..., half:]
     cos = cos[..., None, :]
     sin = sin[..., None, :]
@@ -666,7 +757,7 @@ def transformer_block(x: jax.Array, lp: dict, spec: ModelSpec,
     sc = scope if scoped else (lambda _name: contextlib.nullcontext())
     d = spec.head_dim
     with sc("attn.qkv"):
-        h = rms_norm(x, lp["input_norm"], spec.rms_norm_eps)
+        h = norm(x, lp["input_norm"], spec)
         q = mm(h, lp["wq"], "...h,hd->...d")
         k = mm(h, lp["wk"], "...h,hd->...d")
         v = mm(h, lp["wv"], "...h,hd->...d")
@@ -683,8 +774,8 @@ def transformer_block(x: jax.Array, lp: dict, spec: ModelSpec,
             # NoPE layers: the identity rotation (x*1 - y*0 is exact).
             cos = jnp.where(kind[0], cos, 1.0)
             sin = jnp.where(kind[0], sin, 0.0)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        q = apply_rope(q, cos, sin, spec.rope_interleaved)
+        k = apply_rope(k, cos, sin, spec.rope_interleaved)
     attn = attend(q, k, v, kind)
     with sc("attn.out"):
         proj = mm(attn, lp["wo"], "...d,dh->...h")
@@ -692,7 +783,9 @@ def transformer_block(x: jax.Array, lp: dict, spec: ModelSpec,
             proj = proj + lora_delta(attn, ll["wo"], ids)
         x_in, x = x, x + proj
     with sc("mlp"):
-        h2 = rms_norm(x, lp["post_attn_norm"], spec.rms_norm_eps)
+        # A parallel block's feed-forward reads the norm attention read,
+        # and its output joins the same residual sum.
+        h2 = h if spec.parallel_block else norm(x, lp["post_attn_norm"], spec)
         router_in = x_in if spec.moe_router_input == "layer_input" else None
         out = ffn_block(h2, lp, spec, ll, ids, router_in=router_in,
                         live=live if spec.num_experts else None,
@@ -789,7 +882,7 @@ def prefill_forward(params: Params, spec: ModelSpec,
         k_cache = scatter_pages(k_cache, k_blocks, flat_pages)
         v_cache = scatter_pages(v_cache, v_blocks, flat_pages)
     with scope("lm_head"):
-        x = rms_norm(x, params["final_norm"], spec.rms_norm_eps)
+        x = norm(x, params["final_norm"], spec)
         # Last valid token per sequence.
         last_idx = jnp.maximum(seq_lens - 1, 0)
         x_last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
@@ -934,7 +1027,7 @@ def prefill_forward_pipelined(params: Params, spec: ModelSpec,
     v_cache = scatter_pages(v_cache, v_blocks, flat_pages)
 
     x = xout[:G].reshape(B, s, -1)
-    x = rms_norm(x, params["final_norm"], spec.rms_norm_eps)
+    x = norm(x, params["final_norm"], spec)
     last_idx = jnp.maximum(seq_lens - 1, 0)
     x_last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
     logits = lm_logits(x_last, params, spec)
@@ -1006,7 +1099,7 @@ def decode_forward(params: Params, spec: ModelSpec,
                              dest_page, page_off)
     v_cache = scatter_tokens(v_cache, v_new.transpose(0, 2, 1, 3),
                              dest_page, page_off)
-    x = rms_norm(x, params["final_norm"], spec.rms_norm_eps)
+    x = norm(x, params["final_norm"], spec)
     logits = lm_logits(x, params, spec)
     return logits, k_cache, v_cache
 
@@ -1106,7 +1199,7 @@ def decode_window_multi_step(params: Params, spec: ModelSpec,
           else (params["layers"], jnp.arange(L), k_buf, v_buf))
     x, (k_new, v_new) = jax.lax.scan(layer_fn, x, xs)
     with scope("lm_head"):
-        x = rms_norm(x, params["final_norm"], spec.rms_norm_eps)
+        x = norm(x, params["final_norm"], spec)
         logits = lm_logits(x.reshape(b * s, -1), params, spec)
     return logits.reshape(b, s, -1), k_new, v_new
 
@@ -1143,7 +1236,7 @@ def embed_forward(params: Params, spec: ModelSpec, tokens: jax.Array,
     xs = ((params["layers"], jnp.arange(spec.num_layers)) if patterned
           else params["layers"])
     x, _ = jax.lax.scan(layer_fn, x, xs)
-    x = rms_norm(x, params["final_norm"], spec.rms_norm_eps).astype(
+    x = norm(x, params["final_norm"], spec).astype(
         jnp.float32)
     if pooling == "mean":
         m = valid[..., None].astype(jnp.float32)
@@ -1206,7 +1299,7 @@ def decode_window_step(params: Params, spec: ModelSpec,
           else (params["layers"], jnp.arange(L), k_buf, v_buf))
     x, ys = jax.lax.scan(layer_fn, x, xs)
     with scope("lm_head"):
-        x = rms_norm(x, params["final_norm"], spec.rms_norm_eps)
+        x = norm(x, params["final_norm"], spec)
         logits = lm_logits(x, params, spec)
     # A routed block asked for its load (``live``) adds [L, 3] stats.
     return (logits, *ys)

@@ -86,11 +86,12 @@ _EWMA = 0.2
 SCOPES = ("embed", "attn.qkv", "attn.kv_gather", "attn.core", "attn.out",
           "mlp", "lm_head", "sample", "kv.commit")
 #: Regions INSIDE a scope, drawn only in programs of a routed block (the
-#: expert layer's router and experts, inside ``mlp``). An instruction in one
+#: expert layer's router and experts and, where the block has them, its
+#: shared experts, inside ``mlp``). An instruction in one
 #: keeps its scope and names the sub-scope after it (``mlp+moe.experts``), so
 #: a reader that sums ``mlp`` still counts it. Dense programs have none:
 #: their names, and so SCOPES_VERSION, stand.
-SUBSCOPES = ("moe.router", "moe.experts")
+SUBSCOPES = ("moe.router", "moe.experts", "moe.shared")
 #: Bump when SCOPES or where a scope is drawn changes. jax's persistent
 #: cache key leaves debug info out (jax/_src/cache_key.py strips it), so an
 #: executable cached by a tree with other scopes would be loaded with ITS
@@ -744,6 +745,16 @@ class PerfMetricsUpdater:
             "fullest expert's tokens over the mean per expert, summed over "
             "decode steps and expert layers (over moe_layer_steps_total: "
             "1.0 is an even load)")
+        self.c_moe_local_picks = registry.counter(
+            "moe_local_picks_total", "Expert layer told its share: (row, "
+            "choice) pairs of live rows that fell on experts held here "
+            "(over moe_picks_total: an even router gives held / routed)")
+        self.c_moe_picks = registry.counter(
+            "moe_picks_total", "Expert layer told its share: all (row, "
+            "choice) pairs of live rows, wherever the expert is held")
+        self.g_moe_experts = registry.gauge(
+            "moe_experts_info", "Expert layer told its share: experts the "
+            "router chooses among, held here and shared", ["kind"])
         for bound in (self.g_step_seconds, self.g_achieved, self.g_roofline,
                       self.g_hbm_in_use, self.g_hbm_peak, self.g_hbm_limit):
             bound.ensure()
@@ -797,6 +808,15 @@ class PerfMetricsUpdater:
             self._delta(self.c_moe_touched, ("moe_t",), float(moe[0]))
             self._delta(self.c_moe_load, ("moe_l",), float(moe[1]))
             self._delta(self.c_moe_layer_steps, ("moe_n",), float(moe[2]))
+            if len(moe) > 3:
+                self._delta(self.c_moe_local_picks, ("moe_p",),
+                            float(moe[3]))
+                self._delta(self.c_moe_picks, ("moe_a",), float(moe[4]))
+                spec = engine.runner.spec
+                for kind, n in (("routed", spec.router_width),
+                                ("held", spec.num_experts),
+                                ("shared", spec.num_shared_experts)):
+                    self.g_moe_experts.set(n, kind=kind)
         if getattr(engine, "spec_emit_hist", None):
             self._delta(self.c_spec_draft_tokens, ("spec_dt",),
                         engine.spec_tokens)

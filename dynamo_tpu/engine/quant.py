@@ -40,7 +40,8 @@ class QTensor(NamedTuple):
 
 # Layer leaves that quantize (the big matmuls); everything else stays bf16.
 QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
-                    "moe_w_gate", "moe_w_up", "moe_w_down")
+                    "moe_w_gate", "moe_w_up", "moe_w_down",
+                    "shared_w_gate", "shared_w_up", "shared_w_down")
 
 
 def _safe_scale(amax: np.ndarray) -> np.ndarray:

@@ -753,7 +753,7 @@ class ModelRunner:
             (tokens, _, kbuf, vbuf, rng, counts_out), \
                 (toks, lps, top_vs, top_is, *moe) = \
                 jax.lax.scan(step, carry0, jnp.arange(window))
-            # [M, L, 3] -> [3]: layer-steps with a live row last.
+            # [M, L, n] -> [n] (model.moe_load_stats: 3 sums, 5 for a told share).
             moe = [jnp.sum(moe[0], axis=(0, 1))] if routed else []
 
             # Commit the window: every (slot, step) entry goes to its page.
@@ -1599,6 +1599,15 @@ def _replicate_kv_heads(params, spec, rep: int):
     return out
 
 
+#: Float32 attention scores of one with-history prefill call (rows x heads x
+#: chunk x (history + chunk)) up to which every KV head's are computed at
+#: once; above it a KV head at a time. At 128 query heads a chunk of 1,024
+#: tokens over 4,096 of history is 2.7 GB of scores, more than a v5e has
+#: left beside 9.3 GB of weights and the pool (compiled for a described
+#: v5e, PR 32); 28 heads at the same shape are 0.6 GB.
+HISTORY_SCORE_BYTES = 1 << 30
+
+
 def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
                           page_table, seq_lens, hist_table, hist_lens,
                           attention_impl, sp_shard: bool = False,
@@ -1614,7 +1623,7 @@ def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
     import jax.numpy as jnp
     from dynamo_tpu.engine.kv_quant import gather_pages_folded
     from dynamo_tpu.engine.model import (
-        embed_lookup, layer_kind, lm_logits, rms_norm, rope_tables,
+        embed_lookup, layer_kind, lm_logits, norm, rope_tables,
         transformer_block, window_reach)
 
     b, s = tokens.shape
@@ -1641,9 +1650,18 @@ def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
 
         def attend(q, k, v, kind):
             reach = window_reach(spec, kind)
-            # In-chunk causal scores (grouped GQA, no repeat).
-            with perf.scope("attn.core"):
-                qg = q.reshape(b, s, nkv, spec.q_per_kv, d)
+            # History over prior pages: layer+head-folded gather from the
+            # stacked cache straight into the dot's [Nkv,B,L,D] layout
+            # (hist pages are disjoint from this chunk's pages, whose
+            # writes are deferred out of the scan).
+            with perf.scope("attn.kv_gather"):
+                k_hist = gather_pages_folded(k_cache, layer, hist_table)
+                v_hist = gather_pages_folded(v_cache, layer, hist_table)
+
+            def heads(qg, k, v, k_hist, v_hist):
+                """qg [b,s,n,g,d], k/v [b,s,n,d], k_hist/v_hist [n,b,l,d]
+                for n of the KV heads -> [b,s,n,g,d]."""
+                # In-chunk causal scores (grouped GQA, no repeat).
                 chunk_scores = jnp.einsum("bqngd,bknd->bngqk", qg, k,
                                           preferred_element_type=jnp.float32)
                 causal = (positions[:, None, None, :, None]
@@ -1653,14 +1671,6 @@ def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
                     seen = seen & (positions[:, None, None, :, None] - reach
                                    < positions[:, None, None, None, :])
                 chunk_scores = jnp.where(seen, chunk_scores, -1e30)
-            # History over prior pages: layer+head-folded gather from the
-            # stacked cache straight into the dot's [Nkv,B,L,D] layout
-            # (hist pages are disjoint from this chunk's pages, whose
-            # writes are deferred out of the scan).
-            with perf.scope("attn.kv_gather"):
-                k_hist = gather_pages_folded(k_cache, layer, hist_table)
-                v_hist = gather_pages_folded(v_cache, layer, hist_table)
-            with perf.scope("attn.core"):
                 hist_scores = jnp.einsum("bqngd,nbld->bngql", qg, k_hist,
                                          preferred_element_type=jnp.float32)
                 hist_pos = jnp.arange(maxp * page)[None, :]
@@ -1676,8 +1686,23 @@ def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
                 scores = scores / jnp.sqrt(jnp.float32(d))
                 probs = jax.nn.softmax(scores, axis=-1).astype(jnp.bfloat16)
                 p_hist, p_chunk = jnp.split(probs, [maxp * page], axis=-1)
-                attn = (jnp.einsum("bngql,nbld->bqngd", p_hist, v_hist)
+                return (jnp.einsum("bngql,nbld->bqngd", p_hist, v_hist)
                         + jnp.einsum("bngqk,bknd->bqngd", p_chunk, v))
+
+            with perf.scope("attn.core"):
+                qg = q.reshape(b, s, nkv, spec.q_per_kv, d)
+                score_bytes = 4 * b * spec.num_heads * s * (maxp * page + s)
+                if score_bytes <= HISTORY_SCORE_BYTES or nkv == 1:
+                    attn = heads(qg, k, v, k_hist, v_hist)
+                else:
+                    # A KV head at a time: every head's scores at once
+                    # would not fit beside the weights and the pool.
+                    one = jax.lax.map(
+                        lambda a: heads(*(x[:, :, None] for x in a[:3]),
+                                        *(x[None] for x in a[3:])),
+                        (jnp.moveaxis(qg, 2, 0), jnp.moveaxis(k, 2, 0),
+                         jnp.moveaxis(v, 2, 0), k_hist, v_hist))
+                    attn = jnp.moveaxis(one[:, :, :, 0], 0, 2)
                 return attn.reshape(b, s, -1)
 
         x, k, v, _ = transformer_block(
@@ -1698,7 +1723,7 @@ def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
         k_cache = scatter_pages(k_cache, k_blocks, flat)
         v_cache = scatter_pages(v_cache, v_blocks, flat)
     with perf.scope("lm_head"):
-        x = rms_norm(x, params["final_norm"], spec.rms_norm_eps)
+        x = norm(x, params["final_norm"], spec)
         last_idx = jnp.maximum(seq_lens - 1, 0)
         x_last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
         logits = lm_logits(x_last, params, spec)

@@ -61,13 +61,16 @@ log = get_logger("flight")
 # expert layers' routing did to the live rows, summed over the window's
 # steps and layers on the device: "moe_touched" (distinct experts chosen),
 # "moe_load" (the fullest expert's tokens over the mean) and
-# "moe_layer_steps" (how many (step, layer) pairs the two sums hold); 0 for
-# a dense model.
+# "moe_layer_steps" (how many (step, layer) pairs the two sums hold), all
+# three over the experts the device HOLDS; 0 for a dense model. An expert
+# layer that is told its share of a wider router adds "moe_local_picks"
+# ((row, choice) pairs that fell on held experts) and "moe_picks" (all
+# pairs); 0 for every other block.
 FIELDS = ("t_mono", "dur_s", "active", "waiting", "free_pages",
           "chunk_tokens", "chunks_inflight", "preempts", "brownout",
           "stall_s", "step", "tokens", "period_s", "host_s", "wait_s",
           "idle_s", "rows", "page_bucket", "missed", "moe_touched",
-          "moe_load", "moe_layer_steps")
+          "moe_load", "moe_layer_steps", "moe_local_picks", "moe_picks")
 _INT_FIELDS = ("active", "waiting", "free_pages", "chunk_tokens",
                "chunks_inflight", "preempts", "brownout", "step", "tokens",
                "rows", "page_bucket", "missed")
@@ -117,7 +120,8 @@ class FlightRecorder:
                host_s: float = 0.0, wait_s: float = 0.0,
                idle_s: float = 0.0, rows: int = 0,
                page_bucket: int = 0, moe_touched: float = 0.0,
-               moe_load: float = 0.0, moe_layer_steps: float = 0.0) -> bool:
+               moe_load: float = 0.0, moe_layer_steps: float = 0.0,
+               moe_local_picks: float = 0.0, moe_picks: float = 0.0) -> bool:
         """One engine-window row. Idle-stable windows (no active slots,
         no waiters, no chunk work — same as the previous call) are
         skipped without touching the ring. Returns False when the row
@@ -161,6 +165,8 @@ class FlightRecorder:
             cols["moe_touched"][i] = moe_touched
             cols["moe_load"][i] = moe_load
             cols["moe_layer_steps"][i] = moe_layer_steps
+            cols["moe_local_picks"][i] = moe_local_picks
+            cols["moe_picks"][i] = moe_picks
             cols["missed"][i] = self._missed[0]
             self._missed[0] = 0
             self._idx = (i + 1) % self.capacity

@@ -54,6 +54,13 @@ WIDTHS = {"qwen2.5-0.5b": (64, 2, 7, 24), "llama-3-8b": (128, 8, 4, 32),
           "qwen2.5-7b": (128, 4, 7, 28)}
 # SmallThinker-21B-A3B's attention is qwen2.5-7b's geometry over 24 layers.
 WIDTHS["smallthinker-21b-a3b"] = (128, 4, 7, 24)
+# Command A+'s share: 128 query heads over 8 KV heads (16 query rows a KV
+# head), 8 layers, over a stream of 4096 (not heads x head_dim).
+WIDTHS["command-a-plus"] = (128, 8, 16, 8)
+HIDDEN = {"command-a-plus": 4096}
+#: The page "auto" derives where the kernel reads the pool: 64 KB a copy.
+DERIVED = {"qwen2.5-7b": 64, "smallthinker-21b-a3b": 64, "llama-3-8b": 32,
+           "command-a-plus": 32}
 
 
 def derived_page(model) -> int:
@@ -97,7 +104,7 @@ def test_decode_kernel_compiles_for_v5e(v5e, model, quantized):
 
 @pytest.mark.parametrize("model, page", [
     ("qwen2.5-7b", 64), ("smallthinker-21b-a3b", 64), ("llama-3-8b", 32),
-    ("qwen2.5-7b", 128)])
+    ("command-a-plus", 32), ("qwen2.5-7b", 128)])
 def test_decode_kernel_compiles_at_the_derived_page(v5e, model, page):
     """The page "auto" derives where the kernel reads the pool (64 tokens
     at 4 KV heads of 128, 32 at 8), and the largest a page may be."""
@@ -178,6 +185,47 @@ def test_smallthinker_expert_layer_compiles_for_v5e(v5e, rows):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
 
 
+@pytest.mark.parametrize("rows", [32, 4096], ids=["a decode step",
+                                                  "a prefill group"])
+def test_cohere2_moe_expert_layer_compiles_for_v5e(v5e, rows):
+    """Command A+'s expert layer at the published widths as one chip holds
+    it (a router over 128, 16 int8 experts of 4096 x 4096 held, 4 shared):
+    the masked product over the held experts, in blocks of
+    MOE_DENSE_MAX_ROWS rows above that many (a share never takes the
+    grouped product: 7 of 8 of its sorted pairs belong elsewhere), and the
+    shared experts' mean; the temporaries stay under a gigabyte."""
+    from dynamo_tpu.engine import model
+    from dynamo_tpu.engine.config import Cohere2MoeSpec
+    from dynamo_tpu.engine.quant import QTensor
+    spec = Cohere2MoeSpec(
+        hidden_size=4096, intermediate_size=4096, num_layers=4,
+        num_heads=128, num_kv_heads=8, head_dim=128, num_experts=16,
+        num_experts_per_tok=8, moe_intermediate_size=4096,
+        num_routed_experts=128, num_shared_experts=4, quant="int8")
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def q(shape):
+        return QTensor(s(shape, jnp.int8),
+                       s((*shape[:-2], 1, shape[-1]), jnp.float32))
+
+    wide = (4096, 4096)
+    lp = {"moe_gate": s((4096, 128), jnp.bfloat16),
+          **{f"moe_w_{k}": q((16, *wide)) for k in ("gate", "up", "down")},
+          **{f"shared_w_{k}": q((4, *wide)) for k in ("gate", "up", "down")}}
+    x = s((rows, 4096), jnp.bfloat16)
+    compiled = jax.jit(lambda x, lp: model.ffn_block(
+        x, lp, spec, experts_local=True)).lower(x, lp).compile()
+    flops = compiled.cost_analysis()["flops"]
+    # 4 shared experts over every row and 16 held over a block of rows (the
+    # analysis counts the loop over blocks once).
+    every = 2 * 3 * 4096 * 4096 * (4 * rows + 16 * min(
+        rows, model.MOE_DENSE_MAX_ROWS))
+    assert 0.9 * every < flops < 1.2 * every, (flops, every)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 # -- the decode window program: what it does to the KV pool --------------------
 
 #: Result kinds that move no pool: the entry's arguments, the loop that
@@ -215,9 +263,10 @@ def _window_program(one, model, commit, pool_tokens=24000, rows=32, window=8,
     from dynamo_tpu.engine.model import param_shapes
     from dynamo_tpu.engine.runner import PK_PREFIX, ModelRunner
     d, nkv, qpk, layers = WIDTHS[model]
-    spec = ModelSpec(name=model, vocab_size=1024, hidden_size=nkv * qpk * d,
+    spec = ModelSpec(name=model, vocab_size=1024,
+                     hidden_size=HIDDEN.get(model, nkv * qpk * d),
                      intermediate_size=1024, num_layers=layers,
-                     num_heads=nkv * qpk, num_kv_heads=nkv)
+                     num_heads=nkv * qpk, num_kv_heads=nkv, head_dim=d)
     runner = object.__new__(ModelRunner)
     runner.spec = spec
     pages = pool_tokens // page
@@ -255,10 +304,11 @@ CELLS = [("qwen2.5-7b", 8), ("smallthinker-21b-a3b", 4)]
 
 
 @pytest.mark.parametrize("page", [PAGE, "derived"])
-@pytest.mark.parametrize("model, window", CELLS)
+@pytest.mark.parametrize("model, window", CELLS + [("command-a-plus", 8)])
 def test_window_program_commits_in_place_for_v5e(v5e, model, window, page):
-    """The window program of both benchmark cells' geometry, pools donated,
-    at the page "auto" derives for them (64 tokens) and at 16: a loop of
+    """The window program of the benchmark cells' geometry, pools donated,
+    at the page "auto" derives for them (64 tokens at 4 KV heads, 32 at 8:
+    the first cell on that side of the rule) and at 16: a loop of
     steps that read the pool through the attention kernel, then the
     commit. Nothing in the optimised program has the pool's shape but the
     arguments, the loop's carry and the commit kernel aliased to them: no
@@ -266,7 +316,7 @@ def test_window_program_commits_in_place_for_v5e(v5e, model, window, page):
     call), no transpose, no scatter."""
     if page == "derived":
         page = derived_page(model)
-        assert page == 64
+        assert page == DERIVED[model]
     lowered, pool = _window_program(v5e, model, "in_place", window=window,
                                     page=page)
     text = lowered.compile().as_text()
