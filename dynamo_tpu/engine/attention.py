@@ -4,7 +4,7 @@ The hot op of the decode step (the role block_copy.cu + engine attention
 kernels play on the reference's GPUs), and what attention_backend="auto"
 runs on one TPU device at head_dim 128. One grid program per sequence: it
 walks the sequence's page table (scalar-prefetched into SMEM), DMAs the
-live K/V pages HBM->VMEM in double-buffered chunks (pages_per_chunk pages,
+live K/V pages HBM->VMEM in chunks, three buffers deep (pages_per_chunk pages,
 one strided copy per page across all KV heads), and accumulates
 flash-style online softmax for the q_per_kv grouped query heads of every
 KV head, bf16 operands into float32 scores, statistics and accumulator. The chunk
@@ -12,8 +12,10 @@ pipeline runs on from one sequence's program into the next, and a slot
 without history fetches nothing. Only live pages of live rows are read —
 unlike the XLA gather (model.paged_window_attention_xla), which
 materializes the page-table bucket of the longest row for every slot.
-Each page copy is issued from a scalar loop, and a chunk turn waits for the
-issue of the next chunk's copies, so where this kernel reads the pool the
+Each page copy is issued from a scalar loop after the turn that frees its
+buffer (a fetch cursor walks the live rows' chunks SLOTS turns ahead of the
+multiplication, so copies are in flight through a row's end and the grid's
+step: PERF.md section 6, PR 33), so where this kernel reads the pool the
 page is as large as makes one copy worth its descriptor
 (config.resolve_page_size: 64 tokens at 4 KV heads of 128; PERF.md section
 6, PR 31: 2.77 ms at pages of 16, 2.00 at 64, of which the dots are 1.35).
@@ -75,11 +77,17 @@ from dynamo_tpu.engine.kv_quant import QuantKV
 #: chunk is what one loop turn fetches, waits for and multiplies: large
 #: enough that the turn's fixed costs (loop, semaphore waits, a flash
 #: update per head) are paid once per few hundred tokens, small enough
-#: that two slots of K and of V stay a few MB of VMEM at any head count.
+#: that SLOTS buffers of K and of V stay a few MB of VMEM at any head count.
 CHUNK_BYTES = 512 * 1024
 MIN_CHUNK_TOKENS = 128     # a chunk's tokens are the scores' lanes: one tile
 MAX_PAGES_PER_CHUNK = 64
 NEG_INF = -1e30
+#: Chunk buffers of the K/V pipeline: one being multiplied and two in
+#: flight. With two, a chunk's copies could start only when the turn before
+#: it had finished with the buffer, and the copy engine stood through every
+#: row's end; the third keeps it fed (PERF.md section 6, PR 33: attention
+#: of a Qwen2.5-7B step 3.20 -> 2.78 ms; a fourth adds nothing).
+SLOTS = 3
 #: Token rows one entry of the window's commit moves: a bfloat16 tile's
 #: sublanes, and the page the commit was measured at (PERF.md, PR 29).
 COMMIT_TILE = 16
@@ -106,10 +114,13 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
                    quantized: bool = False, windowed: bool = False):
     """One grid program per batch row, all KV heads inside it. The K/V
     fetch is ONE pipeline across the whole grid: chunk g (counted over the
-    live rows' chunks in row order) lands in slot g % 2, and while chunk g
-    is multiplied chunk g+1 is in flight, be it this row's next chunk or
-    the next live row's first. A row with no history costs an empty grid
-    step; only live pages are ever copied.
+    live rows' chunks in row order) lands in slot g % SLOTS, and while
+    chunk g is multiplied the chunks after it are in flight, be they this
+    row's next chunks or the next live rows' first: a fetch cursor in SMEM
+    walks the live rows' chunks SLOTS turns ahead of the multiplication, and
+    a turn that frees its buffer issues the cursor's chunk into it. A row
+    with no history costs an empty grid step; only live pages are ever
+    copied.
 
     ``windowed`` (a model with sliding-window layers): a fourth prefetched
     vector, lo [B], is the first token each row's query still sees in THIS
@@ -126,10 +137,8 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
         # below — no bf16 copy of the history is ever materialized and
         # the kernel never reshapes a scale vector (Mosaic refuses the
         # [pages, page] -> [rows, tpr] shape cast).
-        (ks_ref, vs_ref, acc_ref, m_ref, l_ref,
-         k_buf, v_buf, sems, g_ref) = rest
-    else:
-        acc_ref, m_ref, l_ref, k_buf, v_buf, sems, g_ref = rest
+        ks_ref, vs_ref, *rest = rest
+    acc_ref, m_ref, l_ref, k_buf, v_buf, sems, g_ref, cur_ref = rest
     _, nkv, ppc, _, _ = k_buf.shape  # [slot, Nkv, pages, rows/page, 128]
     b = pl.program_id(0)
     nb = pl.num_programs(0)
@@ -140,8 +149,10 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
     num_chunks = pl.cdiv(seq_len, chunk_tokens)
 
     def first_chunk(r):
-        """The chunk a row's walk starts at (0 without windows)."""
+        """The chunk a LIVE row's walk starts at (0 without windows)."""
         return lo_ref[r] // chunk_tokens if windowed else 0
+
+    chunk0 = jnp.where(seq_len > 0, first_chunk(b), 0)  # a dead row: 0..0
 
     n = tpr * qpk
     d = 128 // tpr
@@ -149,10 +160,11 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
 
     @pl.when(b == 0)
     def _():
-        # Chunks fetched so far; and finite K/V under the masked columns
-        # of a chunk's unfetched tail (0 * stale NaN would poison acc).
+        # Chunk turns computed so far; and finite K/V under the masked
+        # columns of a chunk's unfetched tail (0 * stale NaN would poison
+        # acc).
         g_ref[0] = 0
-        for slot in range(2):
+        for slot in range(SLOTS):
             for h in range(nkv):
                 k_buf[slot, h] = jnp.zeros(k_buf.shape[2:], k_buf.dtype)
                 v_buf[slot, h] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
@@ -217,18 +229,39 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
             x = x.astype(jnp.float32).astype(dtype)
         return x.reshape(rows, 128)
 
-    def body(c, carry, g0, nxt):
-        g = g0 + c - first_chunk(b) if windowed else g0 + c
-        slot = jax.lax.rem(g, 2)
-        more = c + 1 < num_chunks
+    def next_live(after, searching=True):
+        """The first row past ``after`` with history, or nb."""
+        return jax.lax.while_loop(
+            lambda i: (i < nb) & (seq_lens_ref[jnp.minimum(i, nb - 1)] == 0),
+            lambda i: i + 1, jnp.where(searching, after + 1, nb))
 
-        @pl.when(more | (nxt < nb))
+    def issue_fetch():
+        """Start the copies of the fetch cursor's chunk (turn k of the
+        grid's walk, into slot k % SLOTS) and move the cursor to the chunk
+        after it: this row's next, or the next live row's first."""
+        row, chunk, k = cur_ref[0], cur_ref[1], cur_ref[2]
+
+        @pl.when(row < nb)
         def _():
-            nxt_row = jnp.where(more, b, nxt)
-            start_fetch(nxt_row, jnp.where(
-                more, c + 1, first_chunk(jnp.minimum(nxt, nb - 1))
-                if windowed else 0), 1 - slot)
+            start_fetch(row, chunk, jax.lax.rem(k, SLOTS))
+            more = chunk + 1 < pl.cdiv(seq_lens_ref[row], chunk_tokens)
+            nxt = next_live(row, ~more)
+            cur_ref[0] = jnp.where(more, row, nxt)
+            cur_ref[1] = jnp.where(
+                more, chunk + 1, first_chunk(jnp.minimum(nxt, nb - 1)))
+            cur_ref[2] = k + 1
 
+    @pl.when(b == 0)
+    def _():
+        # The cursor at the first live row's first chunk; every slot primed.
+        first = next_live(-1)
+        cur_ref[0] = first
+        cur_ref[1] = first_chunk(jnp.minimum(first, nb - 1))
+        cur_ref[2] = 0
+        jax.lax.fori_loop(0, SLOTS, lambda _, c: (issue_fetch(), c)[1], 0)
+
+    def body(c, carry, g0):
+        slot = jax.lax.rem(g0 + c - chunk0, SLOTS)
         wait_fetch(b, c, slot)
         token_idx = c * chunk_tokens + row * tpr + group
         live = token_idx < seq_len
@@ -261,40 +294,26 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
                 p.astype(v2.dtype), v2, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             out += [m_new, l_new, acc_new]
+        # The slot is free: the chunk SLOTS turns on takes it, and flies
+        # under the next turns, a row's end and the grid's step.
+        issue_fetch()
         return tuple(out)
 
     init = (jnp.full((n, 1), NEG_INF, jnp.float32),
             jnp.zeros((n, 1), jnp.float32),
             jnp.zeros((n, 128), jnp.float32)) * nkv
 
-    def emit(stats):
-        for h in range(nkv):
-            m, l, acc = stats[3 * h:3 * h + 3]
-            acc_ref[0, h] = acc
-            m_ref[0, h] = jnp.broadcast_to(m, (n, 128))
-            l_ref[0, h] = jnp.broadcast_to(l, (n, 128))
-
-    @pl.when(seq_len == 0)
-    def _():
-        emit(init)  # no history: the wrapper's merge weighs it exp(-inf)
-
-    @pl.when(seq_len > 0)
-    def _():
-        g0 = g_ref[0]
-
-        @pl.when(g0 == 0)
-        def _():
-            # first live row: nobody fetched for it
-            start_fetch(b, first_chunk(b), 0)
-
-        nxt = jax.lax.while_loop(
-            lambda i: (i < nb) & (seq_lens_ref[jnp.minimum(i, nb - 1)] == 0),
-            lambda i: i + 1, b + 1)
-        emit(jax.lax.fori_loop(
-            first_chunk(b), num_chunks,
-            functools.partial(body, g0=g0, nxt=nxt), init))
-        g_ref[0] = (g0 + num_chunks - first_chunk(b) if windowed
-                    else g0 + num_chunks)
+    # A row without history walks no chunk and emits the neutral triple,
+    # which the wrapper's merge weighs exp(-inf).
+    g0 = g_ref[0]
+    stats = jax.lax.fori_loop(chunk0, num_chunks,
+                              functools.partial(body, g0=g0), init)
+    g_ref[0] = g0 + num_chunks - chunk0
+    for h in range(nkv):
+        m, l, acc = stats[3 * h:3 * h + 3]
+        acc_ref[0, h] = acc
+        m_ref[0, h] = jnp.broadcast_to(m, (n, 128))
+        l_ref[0, h] = jnp.broadcast_to(l, (n, 128))
 
 
 def _chunk_scales(scale, layer, page_table, tpr: int, ppc: int):
@@ -385,14 +404,15 @@ def _hist_flash_pallas(q, k_cache, v_cache, layer, page_table, hist_lens,
                              lambda i, *_: (i, 0, 0, 0, 0))
         in_specs += [s_blk, s_blk]
         operands += [ks, vs]
-    buf = pltpu.VMEM((2, nkv, ppc, rows_per_page, 128), kp.dtype)
+    buf = pltpu.VMEM((SLOTS, nkv, ppc, rows_per_page, 128), kp.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(b,),
         in_specs=in_specs,
         out_specs=(blk, blk, blk),
-        scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2)),
-                        pltpu.SMEM((1,), jnp.int32)],
+        scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, SLOTS)),
+                        pltpu.SMEM((1,), jnp.int32),
+                        pltpu.SMEM((3,), jnp.int32)],
     )
     kernel = functools.partial(_decode_kernel, page_size=page_size, tpr=tpr,
                                qpk=qpk, quantized=quantized,

@@ -223,6 +223,154 @@ def test_pallas_window_layer_lo_inside_and_across_a_page(page, lo):
                          - np.asarray(want, np.float32))) > 0.02
 
 
+# -- the cells' geometry (head_dim 128): the window's own columns and the
+# -- current token beside the kernel's walk, and its fetch cursor ---------------
+
+#: The cells' query geometry: 7 rows a KV head at 4 KV heads and a page of
+#: 64 (Qwen2.5-7B, SmallThinker), 16 at 8 and a page of 32 (Command A+).
+CELL_SHAPES = [dict(nkv=4, qpk=7, page=64), dict(nkv=8, qpk=16, page=32)]
+SHAPE_IDS = ["7x4 page 64", "16x8 page 32"]
+
+
+def _columns_case(nkv, qpk, page, hist, M, m, lo=None, seed=21, single=False):
+    """(reference, kernel) attention [B, Nh, 128] of window step ``m`` of
+    ``M`` (``single``: the decode step, no buffer) over rows of ``hist``
+    cache-resident tokens."""
+    from dynamo_tpu.engine.attention import paged_window_attention_pallas
+    from dynamo_tpu.engine.model import paged_window_attention_xla
+    rng = np.random.default_rng(seed)
+    d, b = 128, len(hist)
+    maxp = max(2, -(-max(hist) // page))
+    table = 1 + rng.permutation(b * maxp).reshape(b, maxp).astype(np.int32)
+
+    def arr(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+
+    pool = (2, nkv, b * maxp + 1, page, d)
+    head = (arr(b, nkv * qpk, d), arr(*pool), arr(*pool),
+            jnp.asarray(1, jnp.int32), jnp.asarray(table),
+            jnp.asarray(hist, jnp.int32))
+    own = (arr(b, nkv, d), arr(b, nkv, d))
+    kw = {} if lo is None else {"lo": jnp.asarray(lo, jnp.int32)}
+    if single:
+        want = paged_decode_attention_xla(*head, *own, qpk, **kw)
+        got = paged_decode_attention_pallas(*head, *own, qpk, interpret=True,
+                                            **kw)
+    else:
+        args = (*head, arr(nkv, b, M, d), arr(nkv, b, M, d),
+                jnp.asarray(m, jnp.int32), *own)
+        want = paged_window_attention_xla(*args, qpk, **kw)
+        got = paged_window_attention_pallas(*args, qpk, interpret=True, **kw)
+    assert got.dtype == jnp.bfloat16 and got.shape == (b, nkv * qpk, d)
+    return np.asarray(want, np.float32), np.asarray(got, np.float32)
+
+
+@pytest.mark.parametrize("shape", CELL_SHAPES, ids=SHAPE_IDS)
+@pytest.mark.parametrize("M, m", [(4, 0), (4, 1), (4, 3), (8, 0), (8, 1),
+                                  (8, 7)])
+def test_extra_columns_at_the_first_the_second_and_the_last_step(shape, M, m):
+    """No column of the buffer written yet, one, and all but the last: the
+    current token stands in column m of the tile and nothing after it is
+    seen, whatever the buffer holds there."""
+    want, got = _columns_case(**shape, hist=[70, 700, 0, 33], M=M, m=m)
+    np.testing.assert_allclose(got, want, atol=0.03, rtol=0.03)
+
+
+@pytest.mark.parametrize("shape", CELL_SHAPES, ids=SHAPE_IDS)
+@pytest.mark.parametrize("m", [0, 2])
+def test_extra_columns_of_rows_with_no_history_at_all(shape, m):
+    """Fresh rows and dead slots only: no page is fetched, the attention is
+    the softmax over the window's columns and the current token (at step 0
+    the current token's V itself)."""
+    want, got = _columns_case(**shape, hist=[0, 0, 0], M=4, m=m)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=0.03, rtol=0.03)
+
+
+@pytest.mark.parametrize("shape", CELL_SHAPES, ids=SHAPE_IDS)
+@pytest.mark.parametrize("M, m", [(4, 3), (8, 5)])
+def test_extra_columns_of_a_dead_slot_between_live_rows(shape, M, m):
+    """A slot without history between rows with some: it emits the neutral
+    triple while the pipeline holds the next live rows' first chunks."""
+    want, got = _columns_case(**shape, hist=[130, 0, 0, 600, 0, 64], M=M,
+                               m=m)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=0.03, rtol=0.03)
+
+
+@pytest.mark.parametrize("shape", CELL_SHAPES, ids=SHAPE_IDS)
+@pytest.mark.parametrize("lo", [[301, 0, 0, 45], [303, 0, 2, 41],
+                                [300, 0, 0, 39]],
+                         ids=["one column hidden", "all but the newest hidden",
+                              "the window starts at the first column"])
+def test_extra_columns_window_layer_lo_inside_the_extra_columns(shape, lo):
+    """A window layer whose first visible position lies past the history
+    (lo > hist_lens): no page is read for that row and the columns of the
+    buffer before lo are masked too; column j stands at hist_lens + j.
+    Beside it a row that sees everything and a dead slot."""
+    hist = [300, 500, 0, 40]
+    want, got = _columns_case(**shape, hist=hist, M=8, m=4, lo=lo)
+    np.testing.assert_allclose(got, want, atol=0.03, rtol=0.03)
+    full, _ = _columns_case(**shape, hist=hist, M=8, m=4)
+    assert np.max(np.abs(full[0] - want[0])) > 0.02   # the mask bites
+    np.testing.assert_allclose(full[1], want[1], atol=1e-6)
+
+
+@pytest.mark.parametrize("shape", CELL_SHAPES, ids=SHAPE_IDS)
+@pytest.mark.parametrize("lo", [None, [0, 190, 0, 64]],
+                         ids=["full layers", "a window layer"])
+def test_extra_columns_of_the_single_step(shape, lo):
+    """The decode step outside a window: the current token is the only
+    column out of the pool (the same kernel, no buffer operand)."""
+    want, got = _columns_case(**shape, hist=[5, 200, 0, 64], M=0, m=0,
+                               lo=lo, single=True)
+    np.testing.assert_allclose(got, want, atol=0.03, rtol=0.03)
+
+
+@pytest.mark.parametrize("lens", [
+    [1500, 0, 513, 512, 0, 1, 1100],   # five turns, then a row of three
+    [40, 30, 20, 10, 5, 600, 1],       # one turn a row: the cursor hops rows
+    [2100],                            # one row alone, more turns than slots
+], ids=["long and short", "a turn a row", "alone"])
+@pytest.mark.parametrize("shape", CELL_SHAPES, ids=SHAPE_IDS)
+def test_fetch_cursor_runs_ahead_across_rows(shape, lens):
+    """The fetch cursor walks the live rows' chunks SLOTS turns ahead of
+    the multiplication: rows with more turns than buffers, rows of one turn
+    (the cursor is two rows on), dead slots between them."""
+    want, got = _columns_case(**shape, hist=lens, M=4, m=2)
+    np.testing.assert_allclose(got, want, atol=0.03, rtol=0.03)
+
+
+def test_extra_columns_probabilities_stay_float32():
+    """Against a float32 softmax over bfloat16 values: rows whose weight
+    lies in the window's columns (no history) come out to the rounding of
+    the OUTPUT alone, which probabilities rounded to bfloat16 would not."""
+    nkv, qpk, M, m = 4, 7, 8, 7
+    want, got = _columns_case(nkv, qpk, 64, hist=[0, 0], M=M, m=m, seed=5)
+    rng = np.random.default_rng(5)          # the case's own draws, again
+    b, d = 2, 128
+    rng.permutation(b * 2)
+
+    def arr(*shape):
+        return np.asarray(jnp.asarray(rng.standard_normal(shape),
+                                      jnp.bfloat16), np.float32)
+
+    q = arr(b, nkv * qpk, d).reshape(b, nkv, qpk, d)
+    arr(2, nkv, b * 2 + 1, 64, d), arr(2, nkv, b * 2 + 1, 64, d)
+    k_own, v_own = arr(b, nkv, d), arr(b, nkv, d)
+    k_win, v_win = arr(nkv, b, M, d), arr(nkv, b, M, d)
+    k = np.concatenate([k_win.transpose(1, 0, 2, 3)[:, :, :m],
+                        k_own[:, :, None]], axis=2)
+    v = np.concatenate([v_win.transpose(1, 0, 2, 3)[:, :, :m],
+                        v_own[:, :, None]], axis=2)
+    s = np.einsum("bngd,bnjd->bngj", q, k) / np.sqrt(d)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    exact = np.einsum("bngj,bnjd->bngd", p / p.sum(-1, keepdims=True), v)
+    exact = exact.reshape(b, nkv * qpk, d)
+    # bfloat16 keeps 8 bits: the output's rounding is under 2**-8 of a value.
+    np.testing.assert_allclose(got, exact, rtol=2 ** -8, atol=2 ** -9)
+
+
 @pytest.mark.parametrize("shape, want", [
     ((16, 4, 128, 2), 32),    # qwen2.5-7b bf16: 512 tokens a chunk
     ((16, 8, 128, 2), 16),    # llama-3-8b

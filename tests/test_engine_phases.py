@@ -5,6 +5,7 @@ compile registry's ``ops_by_scope`` (docs/OBSERVABILITY.md "Engine phases",
 "Program scopes")."""
 
 import asyncio
+import gc
 import time
 import tracemalloc
 
@@ -286,6 +287,9 @@ async def test_phases_are_on_the_profiler_trace_and_in_the_capture_reply(
     engine = _tiny_engine()
     try:
         await _generate(engine, 4)  # compile outside the capture
+        # The capture's split is a difference of the process's phase totals:
+        # an earlier test's stopped engine must not leave them in between.
+        gc.collect()
         t0 = time.monotonic()
         task = asyncio.ensure_future(
             tracing.capture_profile(60_000, str(tmp_path)))

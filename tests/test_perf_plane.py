@@ -357,8 +357,11 @@ async def test_perf_smoke_engine_zero_recompiles_and_pane(tmp_path):
         # 16, nothing derived).
         assert status["page_size"] == engine.runner.page_size == \
             engine.config.page_size == 16
-        assert snap1["programs"]["decode_window"]["labels"][
-            "page_size"] == [16]
+        # (the registry is the process's: this engine's own programs)
+        assert sorted({fn._labels["page_size"] for fn in
+                       engine.runner._window_cache.values()}) == [16]
+        assert 16 in snap1["programs"]["decode_window"]["labels"][
+            "page_size"]
         assert snap1["programs"]["prefill"]["labels"] == {}
         engine.perf_metrics.update(engine, force=True)
         text = metrics.expose().decode()

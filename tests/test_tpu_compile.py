@@ -118,12 +118,18 @@ def test_decode_kernel_compiles_at_the_derived_page(v5e, model, page):
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8kv"])
-def test_window_kernel_compiles_for_v5e(v5e, quantized):
+@pytest.mark.parametrize("model, steps", [
+    ("qwen2.5-0.5b", 32), ("qwen2.5-7b", 8), ("command-a-plus", 8),
+    ("llama-3-8b", 32)])
+def test_window_kernel_compiles_for_v5e(v5e, model, steps, quantized):
     """The variant the serving window program calls: kernel over the
-    cache-resident history, in-window buffer merged in XLA."""
-    head, self_kv, qpk = _shapes(v5e, "qwen2.5-0.5b", quantized)
+    cache-resident history, three chunk buffers deep (at 4 and 8 KV heads of
+    128 and packed, over bf16 and int8 pages); the in-window buffer merged
+    in XLA."""
+    head, self_kv, qpk = _shapes(v5e, model, quantized)
     b, nkv, d = self_kv.shape
-    win = jax.ShapeDtypeStruct((nkv, b, 32, d), jnp.bfloat16, sharding=v5e)
+    win = jax.ShapeDtypeStruct((nkv, b, steps, d), jnp.bfloat16,
+                               sharding=v5e)
     step = jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e)
     compiled = jax.jit(
         lambda *a: paged_window_attention_pallas(*a, q_per_kv=qpk)
@@ -251,11 +257,13 @@ def pool_sized_ops(text: str, pool: tuple) -> list[tuple[str, str]]:
 
 
 def _window_program(one, model, commit, pool_tokens=24000, rows=32, window=8,
-                    page=PAGE):
+                    page=PAGE, platform="tpu", mesh_size=1, quant_kv=None):
     """The runner's own decode window program, lowered for the described
     chip: a ModelRunner that places nothing (no device to hold an array),
     with the cell's attention geometry and depth and a narrow MLP and
     vocabulary (they never touch the pool, and keep the compile short).
+    ``commit`` None: what the runner picks (the XLA side of
+    config.pool_access: another platform, a mesh, a packed head).
     Returns (lowered, pool shape)."""
     from types import SimpleNamespace
 
@@ -274,15 +282,20 @@ def _window_program(one, model, commit, pool_tokens=24000, rows=32, window=8,
                                  max_num_seqs=rows)
     assert runner.config.max_model_len == 8192
     table = runner.config.max_pages_per_seq // 4
-    runner.device = SimpleNamespace(platform="tpu")
-    runner.mesh = SimpleNamespace(size=1)
-    runner.quant_kv = runner.lora = None
+    runner.device = SimpleNamespace(platform=platform)
+    runner.mesh = SimpleNamespace(size=mesh_size)
+    runner.quant_kv, runner.lora = quant_kv, None
     runner.experts_local = True
     runner._window_cache = {}
     runner._attention_impl, runner._window_attention_impl = \
         runner._pick_attention()
-    assert runner.attention_backend == "pallas"
-    assert runner._pick_kv_commit() == "in_place"
+    if commit is None:
+        assert runner.attention_backend == "xla"
+        commit = runner._pick_kv_commit()
+        assert commit == "scatter"
+    else:
+        assert runner.attention_backend == "pallas"
+        assert runner._pick_kv_commit() == "in_place"
     runner.kv_commit_backend = commit
 
     def s(shape, dtype):
@@ -292,9 +305,11 @@ def _window_program(one, model, commit, pool_tokens=24000, rows=32, window=8,
                           param_shapes(spec),
                           is_leaf=lambda x: isinstance(x, tuple))
     pool = (layers, nkv, pages, page, d)
+    cache = (QuantKV(s(pool, jnp.int8), s(pool[:-1], jnp.float32))
+             if quant_kv else s(pool, jnp.bfloat16))
     key = jax.eval_shape(lambda: jax.random.key(0))
     lowered = runner._get_window(window, table).lower(
-        params, s(pool, jnp.bfloat16), s(pool, jnp.bfloat16),
+        params, cache, cache,
         s((rows,), jnp.int32), s((rows, PK_PREFIX + table), jnp.int32),
         s(key.shape, key.dtype))
     return lowered, pool
@@ -346,11 +361,18 @@ def lowered_text(lowered) -> str:
                   lowered.as_text())
 
 
-#: sha256 of lowered_text of the window programs below as the PARENT of PR
-#: 31 lowered them (16-token pages were all there was). A PR that changes
-#: the window program on purpose lowers them again and replaces these.
-PARENT_AT_16 = {"qwen2.5-7b": "988aad9f87818035",
-                "smallthinker-21b-a3b": "2a965d7c6ecbfe81"}
+def digest(lowered) -> str:
+    import hashlib
+    return hashlib.sha256(lowered_text(lowered).encode()).hexdigest()[:16]
+
+
+#: sha256 of lowered_text of the window programs below, kernel side, as PR 33
+#: lowers them (the reader's copies run three buffers deep from a fetch
+#: cursor; until then 988aad9f87818035 and 2a965d7c6ecbfe81, PR 31's
+#: parent's). A PR that changes the window program on purpose lowers them
+#: again and replaces these.
+KERNEL_SIDE_AT_16 = {"qwen2.5-7b": "76873333d324d04b",
+                     "smallthinker-21b-a3b": "5059697b68ad77c8"}
 
 
 @pytest.mark.parametrize("model, window", CELLS)
@@ -360,10 +382,31 @@ def test_window_program_at_an_explicit_page_of_16_is_the_parent_s(
     page_size=16 given, the reader's chunks, the commit's schedule (four
     prefetched vectors, whole pages) and everything around them lower to
     the text they lowered to before a page could be anything else."""
-    import hashlib
     lowered, _ = _window_program(v5e, model, "in_place", window=window)
-    digest = hashlib.sha256(lowered_text(lowered).encode()).hexdigest()
-    assert digest[:16] == PARENT_AT_16[model]
+    assert digest(lowered) == KERNEL_SIDE_AT_16[model]
+
+
+#: The same of the window programs on the XLA side of config.pool_access, as
+#: the PARENT of PR 33 lowered them: nothing that reads the pool through the
+#: gather may move when the kernel's side does.
+XLA_SIDE = {
+    "the CPU under auto": (dict(platform="cpu"), "adf2608560ac8010"),
+    "a mesh of four": (dict(mesh_size=4), "adf2608560ac8010"),
+    "head_dim 64": (dict(model="qwen2.5-0.5b"), "7467e907cda3f83e"),
+    "an int8 pool on a mesh": (dict(mesh_size=4, quant_kv="int8"),
+                               "964e27d79521bfe5"),
+}
+
+
+@pytest.mark.parametrize("case", list(XLA_SIDE))
+def test_window_program_on_the_xla_side_is_the_parent_s(v5e, case):
+    """Where the gather reads the pool (another platform, any mesh, a
+    packed head, and an int8 pool there) the window program lowers to the
+    parent's text: the pipeline's depth is the kernel's alone."""
+    kwargs, want = XLA_SIDE[case]
+    kwargs = {"model": "qwen2.5-7b", **kwargs}
+    lowered, _ = _window_program(v5e, commit=None, **kwargs)
+    assert digest(lowered) == want
 
 
 def test_the_pool_guard_sees_the_scatter_s_copies(v5e):
