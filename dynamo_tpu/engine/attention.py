@@ -580,13 +580,18 @@ def _commit_kernel(pid_ref, r0_ref, m0_ref, n_ref,  # SMEM prefetch, [B*J]
             page_copy(s, hbm, buf, False).start()
         # Row r of this tile takes window token m0 + (r - r0), r0 <= r <
         # r0 + n: for token m, the rows whose offset from m0 - r0 is m.
-        row = jax.lax.broadcasted_iota(jnp.int32, k_buf.shape, 2)
-        token = jnp.where((row >= r0) & (row < r0 + n), row - r0 + m0, -1)
+        def tokens_of(shape):
+            row = jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+            return jnp.where((row >= r0) & (row < r0 + n), row - r0 + m0, -1)
+
+        token = tokens_of(k_buf.shape)
         for s, (hbm, buf, win_ref) in enumerate(pools):
             page_copy(s, hbm, buf, False).wait()
             # Through float32 (exact both ways): the one width whose rows
             # every TPU generation's VPU selects and broadcasts singly.
             x = buf[...].astype(jnp.float32)
+            if buf.shape != k_buf.shape:    # a latent pool's narrower rows
+                token = tokens_of(buf.shape)
             for m in range(win_ref.shape[3]):
                 x = jnp.where(token == m, win_ref[:, :, 0, m:m + 1, :], x)
             buf[...] = x.astype(buf.dtype)
@@ -642,23 +647,27 @@ def commit_window_pallas(k_cache: jax.Array, v_cache: jax.Array,
     COMMIT_TILE rows of a page that the tokens fall on, so a larger page
     costs the commit nothing (a page that is no whole number of tiles
     moves whole)."""
-    L, nkv, _, page_size, d = k_cache.shape
+    L, nkv, _, page_size, _ = k_cache.shape
     b, window = k_win.shape[2], k_win.shape[3]
     tile = COMMIT_TILE if page_size % COMMIT_TILE == 0 else page_size
     tiled = tile < page_size
     prefetch = window_pages(positions0, cap, seq_lens0, page_table, window,
                             page_size, tile)
     J = prefetch[0].shape[0] // b
-    win = pl.BlockSpec((L, nkv, 1, window, d),
-                       lambda i, *_: (0, 0, i // J, 0, 0))
+    # The two pools share everything but their rows' width (K and V of
+    # one head_dim; a latent pool's entry and index key).
+    wins = [pl.BlockSpec((L, nkv, 1, window, cache.shape[4]),
+                         lambda i, *_: (0, 0, i // J, 0, 0))
+            for cache in (k_cache, v_cache)]
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
-    buf = pltpu.VMEM((L, nkv, tile, d), k_cache.dtype)
+    bufs = [pltpu.VMEM((L, nkv, tile, cache.shape[4]), cache.dtype)
+            for cache in (k_cache, v_cache)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(b * J,),
-        in_specs=[win, win, any_spec, any_spec],
+        in_specs=[*wins, any_spec, any_spec],
         out_specs=(any_spec, any_spec),
-        scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2,))],
+        scratch_shapes=[*bufs, pltpu.SemaphoreType.DMA((2,))],
     )
     n_pre = len(prefetch)
     return pl.pallas_call(

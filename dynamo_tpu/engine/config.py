@@ -1,6 +1,6 @@
 """Model and engine configuration.
 
-ModelSpec states four block kinds: the dense Llama / Qwen2 block (QKV bias
+ModelSpec states five block kinds: the dense Llama / Qwen2 block (QKV bias
 by ``qkv_bias``), the Mixtral-style block (``num_experts`` SwiGLU experts of
 the dense width, top-k then softmax, routed on the post-attention norm), the
 SmallThinker block (a router that reads the layer's INPUT, softmax over
@@ -11,15 +11,26 @@ mean-centred LayerNorm of the layer's input and both add to the residual, a
 sigmoid router whose width is the deployment's experts while this device
 holds ``num_experts`` of them from ``first_expert`` on, shared experts
 averaged, interleaved RoPE on window layers and none on full ones, a tied
-head). ``from_hf_config`` reads each from its public ``config.json`` keys as
-they are spelled there.
+head), and the DeepSeek-V3.2 block (``deepseek_v32``: latent attention whose
+cache holds ONE latent entry and one index key a token, a learned indexer
+that keeps ``index_topk`` keys a query, leading dense layers ahead of the
+expert layers, a grouped sigmoid router with a selection bias and a scaling
+factor, YaRN frequencies on part of a head). ``from_hf_config`` reads each
+from its public ``config.json`` keys as they are spelled there.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import json
+import math
 import os
+
+
+#: What a leading dense layer's leaves are called in ``params["layers"]``
+#: (``ModelSpec.first_k_dense``): the layer's own names behind this prefix,
+#: stacked over the dense layers alone (model.scan_layers).
+DENSE_PREFIX = "dense_"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -96,6 +107,22 @@ class ModelSpec:
     num_routed_experts = None
     first_expert = 0
     num_shared_experts = 0
+    # What the DeepSeek-V3.2 block states (DeepseekV32Spec). kv_lora_rank 0:
+    # a token leaves K and V of num_kv_heads x head_dim in the cache.
+    kv_lora_rank = 0
+    q_lora_rank = 0
+    qk_nope_head_dim = 0
+    qk_rope_head_dim = 0
+    v_head_dim = 0
+    index_n_heads = 0
+    index_head_dim = 0
+    index_topk = 0
+    first_k_dense = 0                   # every layer has the same feed-forward
+    n_group = 1                         # the router chooses among all experts
+    topk_group = 1
+    routed_scaling_factor = 1.0
+    moe_select_bias = False
+    rope_yarn = None                    # plain frequencies theta ** (-2i / d)
     # Weight-only quantization: None (bf16) or "int8" (engine/quant.py —
     # int8 storage, bf16 MXU compute; halves the weight-read roofline and
     # fits full llama-3-8b on one 16 GB v5e).
@@ -132,31 +159,69 @@ class ModelSpec:
     def q_per_kv(self) -> int:
         return self.num_heads // self.num_kv_heads
 
+    @property
+    def latent(self) -> bool:
+        """A token leaves a latent entry and an index key in the cache, not
+        K and V (kv_lora_rank > 0)."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def kv_entry(self) -> tuple[int, tuple[int, int]]:
+        """(heads, (width of the first pool's row, of the second's)): what
+        ONE token leaves in ONE layer of the two pool arrays
+        [L, heads, P, page, width] that share a page table. K and V of
+        num_kv_heads x head_dim; or (latent) one row of the latent
+        kv_lora_rank, then the shared rope key, then zeros up to the next
+        multiple of 128 lanes (512 + 64 + 64 = 640: a last dimension that
+        is no multiple of 128 costs a relayout of the pool a layer, PERF.md
+        section 6, PR 26), and one row of the index key."""
+        if not self.latent:
+            return self.num_kv_heads, (self.head_dim, self.head_dim)
+        used = self.kv_lora_rank + self.qk_rope_head_dim
+        return 1, (-(-used // 128) * 128, self.index_head_dim)
+
     def num_params(self) -> int:
         """Parameters resident here: the sum of model.param_shapes (the
         experts HELD, shared experts, QKV biases, one norm a layer in a
-        parallel block)."""
+        parallel block; the latent projections, the indexer, the selection
+        bias and the leading dense layers of the DeepSeek-V3.2 block)."""
         h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
         d = self.head_dim
-        attn = h * (self.num_heads * d) + 2 * h * (self.num_kv_heads * d) \
-            + (self.num_heads * d) * h
+        if self.latent:
+            nh, r, qr = self.num_heads, self.kv_lora_rank, self.q_lora_rank
+            rope, nope = self.qk_rope_head_dim, self.qk_nope_head_dim
+            attn = (h * qr + qr + qr * nh * (nope + rope) + h * (r + rope) + r
+                    + r * nh * (nope + self.v_head_dim)
+                    + nh * self.v_head_dim * h
+                    # the indexer: query, key, its LayerNorm, head weights
+                    + qr * self.index_n_heads * self.index_head_dim
+                    + h * self.index_head_dim + 2 * self.index_head_dim
+                    + h * self.index_n_heads)
+        else:
+            attn = h * (self.num_heads * d) + 2 * h * (self.num_kv_heads * d) \
+                + (self.num_heads * d) * h
         if self.qkv_bias:
             attn += (self.num_heads + 2 * self.num_kv_heads) * d
         if self.num_experts:
             mlp = ((self.num_experts + self.num_shared_experts)
                    * 3 * h * self.expert_size + h * self.router_width)
+            if self.moe_select_bias:
+                mlp += self.router_width
         else:
             mlp = 3 * h * i
-        per_layer = attn + mlp + (1 if self.parallel_block else 2) * h
+        norms = (1 if self.parallel_block else 2) * h
         embed = v * h * (1 if self.tie_word_embeddings else 2)
-        return self.num_layers * per_layer + embed + h
+        dense = self.first_k_dense
+        return ((self.num_layers - dense) * (attn + mlp + norms)
+                + dense * (attn + 3 * h * i + norms) + embed + h)
 
     def kv_bytes_per_token(self, dtype_bytes: int = 2) -> int:
-        """bf16-pool bytes per token (k+v, all layers/heads). Quantized
-        KV pools add per-token scales — use EngineConfig.kv_token_bytes()
-        for pool sizing so the int8 accounting stays honest."""
-        return (2 * self.num_layers * self.num_kv_heads * self.head_dim
-                * dtype_bytes)
+        """bf16-pool bytes per token (both pool arrays, all layers/heads).
+        Quantized KV pools add per-token scales — use
+        EngineConfig.kv_token_bytes() for pool sizing so the int8
+        accounting stays honest."""
+        heads, widths = self.kv_entry
+        return self.num_layers * heads * sum(widths) * dtype_bytes
 
     def weight_read_step_ms(self, hbm_gbps: float, tp: int = 1,
                             pp: int = 1) -> float:
@@ -180,6 +245,8 @@ class ModelSpec:
             return cls._from_smallthinker(cfg, path)
         if cfg.get("model_type") == "cohere2_moe":
             return cls._from_cohere2_moe(cfg, path)
+        if cfg.get("model_type") == "deepseek_v32":
+            return cls._from_deepseek_v32(cfg, path)
         return cls(
             name=cfg.get("_name_or_path", os.path.basename(os.path.dirname(path))),
             vocab_size=cfg["vocab_size"],
@@ -270,9 +337,22 @@ class ModelSpec:
                 raise UnsupportedBlockError(
                     reader, f"cohere2_moe with {key} {got!r}: {why}")
         if cfg.get("first_k_dense_replace", 0) > 0:
+            # A leading dense layer is served (first_k_dense, the
+            # DeepSeek-V3.2 block); this family's has a width and a window
+            # pattern of its own.
             raise UnsupportedBlockError(
-                reader, "cohere2_moe with first_k_dense_replace > 0: no "
-                "path mixes dense and routed layers in one scan")
+                reader, "cohere2_moe with first_k_dense_replace > 0: its "
+                "leading layers take prefix_dense_intermediate_size and "
+                "prefix_dense_sliding_window_pattern, whose equations are "
+                "not written down in this repository")
+        for key in ("n_group", "topk_group", "routed_scaling_factor",
+                    "e_score_correction_bias", "topk_method"):
+            if key in cfg:
+                raise UnsupportedBlockError(
+                    reader, f"cohere2_moe with {key}: the router serves "
+                    "groups, a selection bias and a scaling factor "
+                    "(moe_route), but how this family would state and "
+                    "combine them is not written down in this repository")
         scaling = (cfg.get("rope_parameters") or {}).get("rope_type",
                                                          "default")
         if cfg.get("rope_scaling") or scaling != "default":
@@ -315,6 +395,95 @@ class ModelSpec:
                                          cfg["num_experts"]),
             first_expert=share.get("first_expert", 0),
             num_shared_experts=cfg.get("num_shared_experts", 0),
+        )
+
+    @classmethod
+    def _from_deepseek_v32(cls, cfg: dict, path: str) -> "ModelSpec":
+        """DeepSeek-V3.2-Exp's keys (deepseek-ai/DeepSeek-V3.2-Exp
+        ``config.json``). ``n_routed_experts`` counts the experts HELD
+        here; a file that cuts a deployment's share states the router's
+        width and the share's first expert under ``expert_parallel`` as
+        Command A+'s does, the public file has neither and holds them
+        all."""
+        reader = "the config reader"
+        for key, want, why in (
+                ("attention_bias", False, "the latent projections have no "
+                 "bias leaves"),
+                ("hidden_act", "silu", "the feed-forward is SwiGLU"),
+                ("scoring_func", "sigmoid", "the grouped router scores "
+                 "with a sigmoid"),
+                ("topk_method", "noaux_tc", "the router's choice is the "
+                 "grouped one with a selection bias"),
+                ("moe_layer_freq", 1, "every layer after the leading "
+                 "dense ones is an expert layer"),
+                ("num_nextn_predict_layers", 0, "a multi-token-prediction "
+                 "module is a draft that is a module of the model, and no "
+                 "path runs one (ROADMAP R10)"),
+                ("num_key_value_heads", cfg["num_attention_heads"],
+                 "latent attention expands one latent to every head"),
+                ("n_shared_experts", 1, "the shared experts' outputs are "
+                 "averaged (ffn_block), which is this block's sum only "
+                 "for one")):
+            got = cfg.get(key, want)
+            if got != want:
+                raise UnsupportedBlockError(
+                    reader, f"deepseek_v32 with {key} {got!r}: {why}")
+        if not cfg.get("q_lora_rank"):
+            raise UnsupportedBlockError(
+                reader, "deepseek_v32 without q_lora_rank: the indexer "
+                "reads the low-rank query")
+        scaling = cfg.get("rope_scaling")
+        yarn = None
+        if scaling:
+            if scaling.get("type", scaling.get("rope_type")) != "yarn":
+                raise UnsupportedBlockError(
+                    reader, f"deepseek_v32 with rope_scaling {scaling!r}: "
+                    "the scaled rotation written down is YaRN")
+            if scaling.get("mscale", 1) != scaling.get("mscale_all_dim", 1):
+                raise UnsupportedBlockError(
+                    reader, "deepseek_v32 with mscale != mscale_all_dim: "
+                    "no path scales cos and sin")
+            yarn = (float(scaling["factor"]),
+                    int(scaling["original_max_position_embeddings"]),
+                    float(scaling.get("beta_fast", 32)),
+                    float(scaling.get("beta_slow", 1)),
+                    float(scaling.get("mscale_all_dim", 0)))
+        share = cfg.get("expert_parallel") or {}
+        return DeepseekV32Spec(
+            name=cfg.get("_name_or_path")
+            or os.path.basename(os.path.dirname(path)),
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=cfg["num_hidden_layers"],
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg["num_attention_heads"],
+            head_dim=cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"],
+            rope_theta=float(cfg.get("rope_theta", 10000.0)),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-6),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            max_position_embeddings=cfg.get("max_position_embeddings", 8192),
+            num_experts=cfg["n_routed_experts"],
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            moe_intermediate_size=cfg["moe_intermediate_size"],
+            norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
+            num_routed_experts=share.get("routed_experts",
+                                         cfg["n_routed_experts"]),
+            first_expert=share.get("first_expert", 0),
+            num_shared_experts=cfg.get("n_shared_experts", 0),
+            kv_lora_rank=cfg["kv_lora_rank"],
+            q_lora_rank=cfg["q_lora_rank"],
+            qk_nope_head_dim=cfg["qk_nope_head_dim"],
+            qk_rope_head_dim=cfg["qk_rope_head_dim"],
+            v_head_dim=cfg["v_head_dim"],
+            index_n_heads=cfg["index_n_heads"],
+            index_head_dim=cfg["index_head_dim"],
+            index_topk=cfg["index_topk"],
+            first_k_dense=cfg.get("first_k_dense_replace", 0),
+            n_group=cfg.get("n_group", 1),
+            topk_group=cfg.get("topk_group", 1),
+            routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+            rope_yarn=yarn,
         )
 
 
@@ -398,6 +567,73 @@ class Cohere2MoeSpec(SmallThinkerSpec):
                 f"the router's {self.num_routed_experts}")
 
 
+@dataclasses.dataclass
+class DeepseekV32Spec(Cohere2MoeSpec):
+    """The DeepSeek-V3.2 block (deepseek-ai/DeepSeek-V3.2-Exp,
+    ``deepseek_v32``): the expert width, the sigmoid router, the share of a
+    wider router and the shared experts that Cohere2MoeSpec states, at this
+    block's values, and what it states beyond them. ``head_dim`` is a
+    query head's width, qk_nope_head_dim + qk_rope_head_dim;
+    ``num_kv_heads`` equals ``num_heads`` (the latent expands to every
+    head) and sizes no pool: ``kv_entry`` does."""
+    norm_kind: str = "rms"
+    parallel_block: bool = False        # attention, then feed-forward
+    # The rope part of q and the shared rope key turn in interleaved pairs
+    # (2i, 2i + 1); the indexer's query and key in rotate-half pairs.
+    rope_interleaved: bool = True
+    # Latent attention: c = RMS(h Wkv_a[:kv_lora_rank]) and ONE rope key
+    # of qk_rope_head_dim a token; a head's key is (c Wkv_b[K] | rope key),
+    # its value c Wkv_b[V] of v_head_dim; q = RMS(h Wq_a) Wq_b, a head
+    # (nope | rope).
+    kv_lora_rank: int = 512
+    q_lora_rank: int = 1536
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    # The indexer: I[t,s] = sum_j w[t,j] relu(qI[t,j] . kI[s]) over
+    # index_n_heads heads of index_head_dim; query t attends the
+    # index_topk keys s <= t of largest I (all of them up to that many).
+    index_n_heads: int = 64
+    index_head_dim: int = 128
+    index_topk: int = 2048
+    # Layers 0 to first_k_dense - 1 have a dense feed-forward of
+    # intermediate_size in place of the expert layer.
+    first_k_dense: int = 0
+    # The router's choice: z = sigmoid + bias; n_group groups of equal
+    # size, a group's score the sum of its 2 largest z, the topk_group best
+    # groups kept, the k largest z among their experts; gates are the
+    # chosen sigmoids (no bias) over their sum, times
+    # routed_scaling_factor.
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    moe_select_bias: bool = True
+    # YaRN: (factor, original_max_position_embeddings, beta_fast,
+    # beta_slow, mscale_all_dim); None: plain frequencies. The softmax
+    # scale is head_dim ** -0.5 times (0.1 mscale_all_dim ln(factor) + 1)
+    # squared.
+    rope_yarn: tuple | None = None
+
+    def __post_init__(self):
+        super().__post_init__()
+        if self.router_width % self.n_group:
+            raise ValueError(f"{self.router_width} experts do not divide "
+                             f"into {self.n_group} groups")
+        if not 0 <= self.first_k_dense < self.num_layers:
+            raise ValueError(f"first_k_dense {self.first_k_dense} of "
+                             f"{self.num_layers} layers")
+
+    @property
+    def attn_scale(self) -> float:
+        """What multiplies q . k ahead of the softmax."""
+        scale = self.head_dim ** -0.5
+        if self.rope_yarn is not None:
+            factor, _, _, _, mscale_all = self.rope_yarn
+            if factor > 1:
+                scale *= (0.1 * mscale_all * math.log(factor) + 1.0) ** 2
+        return scale
+
+
 class UnsupportedBlockError(NotImplementedError):
     """A path that lacks a mechanism a model's block needs refuses the
     model at start-up and names what it lacks; it never runs the block
@@ -408,12 +644,15 @@ class UnsupportedBlockError(NotImplementedError):
 
 
 def block_refusals(spec: ModelSpec, config: "EngineConfig | None" = None,
-                   checkpoint: bool = False, embeddings: bool = False
+                   checkpoint: bool = False, embeddings: bool = False,
+                   kv_transfer: bool = False
                    ) -> list[UnsupportedBlockError]:
     """Every reason the block of ``spec`` cannot run the way ``config``
     asks (None: nothing is asked of an engine), with ``checkpoint`` take
-    its weights from safetensors, or with ``embeddings`` take an encoder's
-    embeddings in place of token rows. ModelRunner raises the first at
+    its weights from safetensors, with ``embeddings`` take an encoder's
+    embeddings in place of token rows, or with ``kv_transfer`` hand pages
+    of its pool to another holder (a KV-plane parcel, a disaggregated
+    insert, a host tier). ModelRunner raises the first at
     start-up, the loader before it opens a file and the engine as it
     validates such a request; the forward functions hold no refusal, and
     nothing else in the package asks what kind of block a model has.
@@ -445,8 +684,17 @@ def block_refusals(spec: ModelSpec, config: "EngineConfig | None" = None,
             "audio tower is written down for a block with shared experts "
             "or a share of its routed experts, and another encoder's rows "
             "under it were never compared with its reference"))
+    latent = spec.latent
+    if kv_transfer and latent:
+        out.append(UnsupportedBlockError(
+            "a KV parcel (KV-plane tickets, disaggregated insert, host and "
+            "disk tiers)", "a parcel is K and V pages of one shape stacked, "
+            "and a latent pool's page is a latent entry and an index key "
+            "of different widths"))
     if config is None:
         return out
+    if latent:
+        out += _latent_refusals(spec, config)
     if share and config.tp * config.pp * config.dp * config.sp > 1:
         out.append(UnsupportedBlockError(
             "a tp/pp/dp/sp mesh", f"the expert layer is told ONE share "
@@ -477,6 +725,44 @@ def block_refusals(spec: ModelSpec, config: "EngineConfig | None" = None,
             "a tp/pp/dp/sp mesh", f"{unlike} was never compared with its "
             "reference on more than one device (its grouped expert product "
             "has no partitioning rule)"))
+    return out
+
+
+def _latent_refusals(spec: ModelSpec, config: "EngineConfig"
+                     ) -> list[UnsupportedBlockError]:
+    """block_refusals' part for a latent pool (``spec.latent``): every
+    engine path that still assumes a K and V pair, by what it lacks."""
+    out = []
+    if config.resolve_quant_kv() is not None:
+        out.append(UnsupportedBlockError(
+            "int8 KV pages (quant_kv)", "QuantKV scales a K or V row a "
+            "head; a latent entry's latent and rope key differ in range "
+            "and the index key decides a choice: no scale is written down "
+            "for either"))
+    if config.host_cache_pages > 0 or config.kv_disk_cache_dir:
+        out.append(UnsupportedBlockError(
+            "the host and disk KV tiers (kvbm)", "they move parcels of K "
+            "and V pages of one shape, and a latent pool's two arrays "
+            "differ in width"))
+    if config.spec_decode:
+        out.append(UnsupportedBlockError(
+            "speculative decoding (spec_decode)", "the verify step scores "
+            "K and V heads and has neither the absorbed latent product nor "
+            "the indexer's selection"))
+    if config.ring_attention or config.sp > 1:
+        out.append(UnsupportedBlockError(
+            "ring and sequence-parallel prefill", "their blockwise scores "
+            "rotate K and V blocks and have no index scores to select by"))
+    if config.pp_microbatch or config.pp > 1:
+        out.append(UnsupportedBlockError(
+            "a pipeline of layer stages (pp)", "a stage's scan takes one "
+            "stack of layers, and the leading dense layers are a stack of "
+            "their own"))
+    if config.max_adapters > 0:
+        out.append(UnsupportedBlockError(
+            "LoRA adapters (max_adapters)", "the adapter targets are wq, "
+            "wk, wv and wo, and the latent block's queries and keys come "
+            "from low-rank pairs"))
     return out
 
 
@@ -521,7 +807,8 @@ DEFAULT_MAX_MODEL_LEN = 8192
 
 
 def pool_access(attention_backend: str, platform: str, mesh_size: int,
-                head_dim: int, quant_kv: str | None) -> tuple[str, str]:
+                head_dim: int, quant_kv: str | None, latent: bool = False
+                ) -> tuple[str, str]:
     """(attention backend, KV commit): who reads the KV pool in decode and
     how the decode window writes it, from what a runner observes and
     nothing else. The ONE statement of that choice: ModelRunner's
@@ -549,13 +836,60 @@ def pool_access(attention_backend: str, platform: str, mesh_size: int,
     the touched rows are rewritten where they lie; "scatter"
     (kv_quant.scatter_tokens) everywhere else: the XLA reader (a mesh and
     the CPU under "auto"), a packed head (head_dim 64), int8 pages
-    (QuantKV: tiles of 32 rows, and the scales are a second array)."""
+    (QuantKV: tiles of 32 rows, and the scales are a second array).
+
+    A ``latent`` pool (ModelSpec.kv_entry: one latent entry of 640 lanes
+    and one index key of 128 a token a layer, under one page table): the
+    reader is XLA's (model.latent_window_attention gathers a row's pages
+    of index keys, scores them, and reads the latent entries it chose); no
+    Pallas kernel walks such a pool yet, so "auto" never resolves to one
+    and a requested one is the runner's to refuse. The writer on one TPU
+    device is still "in_place": XLA's gather takes whole [page, width]
+    blocks by their leading indices from a row-major pool as it lies, so
+    nothing converts the pool, and the commit kernel moves rows of any
+    lane-dense width."""
+    if latent:
+        reader = "xla" if attention_backend == "auto" else attention_backend
+        in_place = (reader == "xla" and platform == "tpu" and mesh_size == 1
+                    and quant_kv is None)
+        return reader, "in_place" if in_place else "scatter"
     plain = mesh_size == 1 and head_dim == 128
     reader = attention_backend
     if reader == "auto":
         reader = "pallas" if platform == "tpu" and plain else "xla"
     in_place = reader == "pallas" and plain and quant_kv is None
     return reader, "in_place" if in_place else "scatter"
+
+
+#: Tokens by which the XLA reader's page-table bucket grows past its first
+#: two steps (window_page_bucket).
+XLA_BUCKET_TOKENS = 1024
+
+
+def window_page_bucket(needed: int, reader: str, page_size: int,
+                       max_pages: int) -> int:
+    """Page-table width of the decode window whose longest row holds
+    ``needed`` pages, by who reads the pool (``pool_access``'s reader): a
+    power of two from 8 up to ``max_pages``. The XLA reader gathers the
+    bucket of EVERY slot whatever the rows hold, so its time follows the
+    bucket and not the rows: past two steps of XLA_BUCKET_TOKENS its
+    buckets are multiples of that step, and a step's time follows the
+    longest row within 1,024 tokens. The Pallas kernel walks a row's live
+    pages alone and pays nothing for a wide table: its buckets stay powers
+    of two (fewer programs). Measured where the XLA reader serves a cell, a
+    latent pool on one v5e over six seeds each (PERF.md section 6, PR 34):
+    at powers of two a step was a third longer from the moment one row
+    passed 4,096 tokens (`out_tok_s` 652); steps of 1,024 tokens 739, of
+    512 tokens 757 for six more window programs to compile and no steadier
+    a median time per token. Not measured over K and V pages (no cell is
+    on the XLA side there): the gather's cost by bucket is PR 26's."""
+    b = 8
+    while b < needed and b < max_pages:
+        b *= 2
+    step = max(8, XLA_BUCKET_TOKENS // page_size)
+    if reader == "xla" and b > 2 * step:
+        b = -(-needed // step) * step
+    return min(b, max_pages)
 
 
 @dataclasses.dataclass
@@ -767,7 +1101,8 @@ class EngineConfig:
         (pool_access), a page is the smallest power of two of tokens whose
         ONE strided copy across the KV heads moves PAGE_COPY_BYTES, within
         [DEFAULT_PAGE_SIZE, MAX_PAGE_SIZE]: 64 tokens at 4 KV heads of 128
-        in bfloat16 (Qwen2.5-7B, SmallThinker), 32 at 8 (Llama-3-8B). The
+        in bfloat16 (Qwen2.5-7B, SmallThinker), 32 at 8 (Llama-3-8B), 64 at
+        a latent entry of 640 (whose XLA gather takes a page a slice). The
         kernel issues a copy a live page a layer from a scalar loop, and a
         chunk turn waits for the issue of the next chunk's copies (PERF.md
         section 6, PR 31). Everywhere else DEFAULT_PAGE_SIZE: the XLA
@@ -777,7 +1112,8 @@ class EngineConfig:
         asked only where a TPU would change the answer."""
         m = self.model
         _, writer = pool_access(self.attention_backend, "tpu", self.mesh_size,
-                                m.head_dim, self.resolve_quant_kv())
+                                m.head_dim, self.resolve_quant_kv(),
+                                m.latent)
         if writer != "in_place":
             return DEFAULT_PAGE_SIZE
         if platform is None:
@@ -785,7 +1121,8 @@ class EngineConfig:
             platform = jax.devices()[0].platform
         if platform != "tpu":
             return DEFAULT_PAGE_SIZE
-        copy_bytes = m.num_kv_heads * m.head_dim * 2  # a token row, bf16
+        heads, widths = m.kv_entry
+        copy_bytes = heads * widths[0] * 2  # a token row, bf16
         page = DEFAULT_PAGE_SIZE
         while page < MAX_PAGE_SIZE and page * copy_bytes < PAGE_COPY_BYTES:
             page *= 2
@@ -815,16 +1152,19 @@ class EngineConfig:
         return KvbmPolicy(low_watermark=low, high_watermark=high)
 
     def kv_token_bytes(self) -> int:
-        """Per-token bytes in the device KV pool (k+v, all layers/heads):
-        bf16 = 2 bytes/value; int8 = 1 byte/value + a 4-byte f32 scale
-        per (layer, head, token). The single source for pool sizing and
-        the perf plane's HBM KV ledger."""
+        """Per-token bytes in the device KV pool (both arrays, all
+        layers/heads, a latent entry's lane padding included: 768 values a
+        layer for the 704 it uses): bf16 = 2 bytes/value; int8 = 1
+        byte/value + a 4-byte f32 scale per (layer, head, token). The
+        single source for pool sizing and the perf plane's HBM KV
+        ledger."""
         m = self.model
+        heads, widths = m.kv_entry
         if self.resolve_quant_kv() == "int8":
-            per_head = m.head_dim + 4  # KV_SCALE_BYTES
+            per_head = sum(w + 4 for w in widths)  # KV_SCALE_BYTES
         else:
-            per_head = 2 * m.head_dim
-        return 2 * m.num_layers * m.num_kv_heads * per_head
+            per_head = 2 * sum(widths)
+        return m.num_layers * heads * per_head
 
     def lora_target_shapes(self) -> dict[str, tuple[int, int]]:
         """(d_in, d_out) per LoRA target projection for this model —

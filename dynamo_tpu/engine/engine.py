@@ -135,6 +135,10 @@ class _Window:
     # and how many of those the sums hold; [5] where the expert layer is
     # told its share: picks on held experts and all picks follow.
     moe: object = None
+    # A latent block's window: float32 [2] read back beside it: keys the
+    # live rows attended and keys they had in context, over steps and
+    # layers.
+    attn: object = None
     # Speculative windows: toks = (outs [m,B,S], emits [m,B],
     # ndrafts [m,B]); slots snaps carry the ASSUMED advance so
     # processing can correct the host's upper-bound positions.
@@ -280,6 +284,9 @@ class TPUEngine(AsyncEngine):
         self.moe_totals = np.zeros(
             5 if config.model.num_routed_experts is not None else 3,
             np.float64)
+        # A latent block's windows: the keys its live rows attended and
+        # the keys they had in context (the entries of _Window.attn).
+        self.attn_totals = np.zeros(2, np.float64)
         # Control jobs executed on the engine thread between windows
         # (disagg prefill-extract, KV injection helpers, etc.).
         self._jobs: queue.Queue = queue.Queue()
@@ -1087,6 +1094,20 @@ class TPUEngine(AsyncEngine):
                     experts_shared=spec.num_shared_experts,
                     local_picks_pct=round(100.0 * local / picks, 3)
                     if picks else None)
+        if self.runner.spec.latent:
+            spec = self.runner.spec
+            selected, context = self.attn_totals
+            status["attn"] = {
+                # Keys a query attends at most (the indexer's choice).
+                "index_topk": spec.index_topk,
+                # What a token holds in the pool, every layer, lane padding
+                # included (config.kv_token_bytes).
+                "kv_entry_bytes": self.config.kv_token_bytes(),
+                # Keys attended over keys in context, live rows, every
+                # layer and decode step so far.
+                "selected_pct": round(100.0 * selected / context, 3)
+                if context else None,
+            }
         if self.config.spec_decode:
             # Verify-of-k bandwidth: the spec program runs m_outer verify
             # steps of S = spec_k + 1 positions each, so cost-registry
@@ -2434,11 +2455,16 @@ class TPUEngine(AsyncEngine):
                 lps = np.asarray(w.toks[1]) if want_lp else None
                 top_vs = np.asarray(w.toks[2]) if want_lp else None
                 top_is = np.asarray(w.toks[3]) if want_lp else None
-                if len(w.toks) > 4:
-                    # A few bytes of the same program's output, copied
-                    # with the tokens: no second wait for the device.
-                    w.moe = np.asarray(w.toks[4], np.float64)
+                # What the block counted: a few bytes of the same
+                # program's output, copied with the tokens: no second wait
+                # for the device.
+                counted = w.toks[4]
+                if "moe" in counted:
+                    w.moe = np.asarray(counted["moe"], np.float64)
                     self.moe_totals += w.moe
+                if "attn" in counted:
+                    w.attn = np.asarray(counted["attn"], np.float64)
+                    self.attn_totals += w.attn
             self._note_ready(w)
         else:
             toks = None
@@ -2770,7 +2796,9 @@ class TPUEngine(AsyncEngine):
             w.period_s, busy_total - self._flight_busy_last,
             wait_total - self._flight_wait_last,
             idle_total - self._flight_idle_last, rows, w.page_bucket,
-            *(w.moe if w.moe is not None else ()))
+            *(w.moe if w.moe is not None else ()),
+            **({} if w.attn is None else {
+                "attn_selected": w.attn[0], "attn_context": w.attn[1]}))
         if accepted:
             # A frozen ring (bundle capture in flight) rejects the row:
             # keep accumulating so the stall/chunk/token/host-time deltas

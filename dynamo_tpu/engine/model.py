@@ -15,13 +15,13 @@ from __future__ import annotations
 import contextlib
 import functools
 import math
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from dynamo_tpu.engine.config import ModelSpec
+from dynamo_tpu.engine.config import DENSE_PREFIX, ModelSpec
 from dynamo_tpu.engine.kv_quant import (gather_pages_folded, scatter_pages,
                                         scatter_tokens)
 from dynamo_tpu.engine.perf import scope
@@ -112,18 +112,51 @@ def lm_logits(x: jax.Array, params: Params, spec: ModelSpec) -> jax.Array:
 # Parameter init + sharding specs
 # ---------------------------------------------------------------------------
 
-def param_shapes(spec: ModelSpec) -> dict:
-    h, d = spec.hidden_size, spec.head_dim
-    nh, nkv, L = spec.num_heads, spec.num_kv_heads, spec.num_layers
-    i = spec.intermediate_size
-    layers: dict = {
+def _attention_shapes(spec: ModelSpec, L: int) -> dict:
+    """The norms and attention leaves of L layers: q, k, v and o, or the
+    DeepSeek-V3.2 block's latent projections and indexer. Its selection
+    bias and the indexer's LayerNorm bias end in a 1 so that a generator
+    drawing normal / sqrt(shape[-2]) draws them small beside what they are
+    added to."""
+    h, nh = spec.hidden_size, spec.num_heads
+    if not spec.latent:
+        d, nkv = spec.head_dim, spec.num_kv_heads
+        return {
+            "input_norm": (L, h),
+            "post_attn_norm": (L, h),
+            "wq": (L, h, nh * d),
+            "wk": (L, h, nkv * d),
+            "wv": (L, h, nkv * d),
+            "wo": (L, nh * d, h),
+        }
+    r, qr = spec.kv_lora_rank, spec.q_lora_rank
+    return {
         "input_norm": (L, h),
         "post_attn_norm": (L, h),
-        "wq": (L, h, nh * d),
-        "wk": (L, h, nkv * d),
-        "wv": (L, h, nkv * d),
-        "wo": (L, nh * d, h),
+        "wq_a": (L, h, qr),
+        "q_a_norm": (L, qr),
+        "wq_b": (L, qr, nh * spec.head_dim),
+        "wkv_a": (L, h, r + spec.qk_rope_head_dim),
+        "kv_a_norm": (L, r),
+        # c Wkv_b, a head's key part and its value part as two leaves.
+        "wk_b": (L, r, nh * spec.qk_nope_head_dim),
+        "wv_b": (L, r, nh * spec.v_head_dim),
+        "wo": (L, nh * spec.v_head_dim, h),
+        "index_wq_b": (L, qr, spec.index_n_heads * spec.index_head_dim),
+        "index_wk": (L, h, spec.index_head_dim),
+        "index_k_norm": (L, spec.index_head_dim),
+        "index_k_bias": (L, spec.index_head_dim, 1),
+        "index_w": (L, h, spec.index_n_heads),
     }
+
+
+def param_shapes(spec: ModelSpec) -> dict:
+    h, d = spec.hidden_size, spec.head_dim
+    nh, nkv = spec.num_heads, spec.num_kv_heads
+    # Leading dense layers are leaves of their own (DENSE_PREFIX).
+    L = spec.num_layers - spec.first_k_dense
+    i = spec.intermediate_size
+    layers = _attention_shapes(spec, L)
     if spec.parallel_block:             # one norm feeds both branches
         del layers["post_attn_norm"]
     if spec.num_experts:
@@ -138,10 +171,17 @@ def param_shapes(spec: ModelSpec) -> dict:
             layers["shared_w_gate"] = (L, S, h, ie)
             layers["shared_w_up"] = (L, S, h, ie)
             layers["shared_w_down"] = (L, S, ie, h)
+        if spec.moe_select_bias:
+            layers["moe_bias"] = (L, spec.router_width, 1)
     else:
         layers["w_gate"] = (L, h, i)
         layers["w_up"] = (L, h, i)
         layers["w_down"] = (L, i, h)
+    if spec.first_k_dense:
+        K = spec.first_k_dense
+        dense = {**_attention_shapes(spec, K), "w_gate": (K, h, i),
+                 "w_up": (K, h, i), "w_down": (K, i, h)}
+        layers.update({DENSE_PREFIX + k: v for k, v in dense.items()})
     shapes = {
         "embed": (spec.vocab_size, h),
         "final_norm": (h,),
@@ -171,6 +211,10 @@ def param_specs(spec: ModelSpec) -> dict:
         "wv": P("pp", None, "tp"),
         "wo": P("pp", "tp", None),
     }
+    if spec.latent:
+        # Served on one device (config.block_refusals): nothing is split.
+        layers = {k: P("pp", *([None] * (len(v) - 1)))
+                  for k, v in _attention_shapes(spec, 1).items()}
     if spec.parallel_block:
         del layers["post_attn_norm"]
     if spec.num_experts:
@@ -183,10 +227,18 @@ def param_specs(spec: ModelSpec) -> dict:
             # served on one device: config.block_refusals).
             for key in ("shared_w_gate", "shared_w_up", "shared_w_down"):
                 layers[key] = P("pp", None, None, None)
+        if spec.moe_select_bias:
+            layers["moe_bias"] = P("pp", None, None)
     else:
         layers["w_gate"] = P("pp", None, "tp")
         layers["w_up"] = P("pp", None, "tp")
         layers["w_down"] = P("pp", "tp", None)
+    if spec.first_k_dense:
+        dense = {**{k: v for k, v in layers.items()
+                    if k in _attention_shapes(spec, 1)},
+                 "w_gate": P("pp", None, "tp"), "w_up": P("pp", None, "tp"),
+                 "w_down": P("pp", "tp", None)}
+        layers.update({DENSE_PREFIX + k: v for k, v in dense.items()})
     specs = {
         "embed": P(None, "tp"),
         "final_norm": P(None),
@@ -236,21 +288,44 @@ def param_specs(spec: ModelSpec) -> dict:
 MOE_DENSE_MAX_ROWS = 1024
 
 
-def moe_route(router: jax.Array, spec: ModelSpec
-              ) -> tuple[jax.Array, jax.Array]:
+def moe_route(router: jax.Array, spec: ModelSpec,
+              bias: jax.Array | None = None) -> tuple[jax.Array, jax.Array]:
     """Router logits [T, E] float32 -> (gates [T, k] float32, experts
     [T, k]). "topk_softmax" (Mixtral): the k largest logits, softmax over
     those. "softmax_topk" (SmallThinker): softmax over all E, the k largest
     probabilities, divided by their sum when norm_topk_prob.
     "sigmoid_topk" (Cohere2-MoE): the same with a sigmoid of each logit in
     place of the softmax. E is the router's width: the sum runs over all k
-    chosen, whichever device holds them."""
+    chosen, whichever device holds them.
+
+    DeepSeek-V3's grouped choice on top of "sigmoid_topk": ``bias`` [E]
+    (float32; ``moe_select_bias``) is added to the scores for the CHOICE
+    alone; with ``n_group`` > 1 the E experts are n_group runs of equal
+    length, a group's score is the sum of its 2 largest biased scores, and
+    the k are taken among the experts of the ``topk_group`` best groups;
+    the gates are the chosen experts' unbiased scores, normalised as
+    above, times ``routed_scaling_factor``."""
     if spec.moe_router in ("softmax_topk", "sigmoid_topk"):
         score = (jax.nn.sigmoid(router) if spec.moe_router == "sigmoid_topk"
                  else jax.nn.softmax(router, axis=-1))
-        top_v, top_i = jax.lax.top_k(score, spec.num_experts_per_tok)
+        if bias is None and spec.n_group == 1:
+            top_v, top_i = jax.lax.top_k(score, spec.num_experts_per_tok)
+        else:
+            choice = score if bias is None else score + bias
+            if spec.n_group > 1:
+                groups = choice.reshape(*choice.shape[:-1], spec.n_group, -1)
+                best = jnp.sum(jax.lax.top_k(groups, 2)[0], axis=-1)
+                _, keep = jax.lax.top_k(best, spec.topk_group)
+                kept = jnp.any(jax.nn.one_hot(keep, spec.n_group,
+                                              dtype=jnp.bool_), axis=-2)
+                choice = jnp.where(kept[..., None], groups,
+                                   -jnp.inf).reshape(choice.shape)
+            _, top_i = jax.lax.top_k(choice, spec.num_experts_per_tok)
+            top_v = jnp.take_along_axis(score, top_i, axis=-1)
         if spec.norm_topk_prob:
             top_v = top_v / jnp.sum(top_v, axis=-1, keepdims=True)
+        if spec.routed_scaling_factor != 1.0:
+            top_v = top_v * spec.routed_scaling_factor
         return top_v, top_i
     top_v, top_i = jax.lax.top_k(router, spec.num_experts_per_tok)
     return jax.nn.softmax(top_v, axis=-1), top_i           # over top-k
@@ -344,8 +419,9 @@ def ffn_block(h2: jax.Array, lp: dict, spec: ModelSpec, ll: dict | None = None,
     experts (``num_shared_experts``) take every row and their mean is added.
 
     With ``live`` ([T] bool, routed blocks only) returns (out, stats) with
-    ``moe_load_stats`` of the live rows; else out."""
-    if not spec.num_experts:
+    ``moe_load_stats`` of the live rows; else out. A leading dense layer of
+    a routed model (``lp`` holds no router) takes the dense branch."""
+    if not spec.num_experts or "moe_gate" not in lp:
         # Dense-MLP LoRA targets (gathered per-row deltas; MoE expert
         # weights are not adapter targets — attention-only there, so the
         # stacks simply lack the MLP keys).
@@ -366,7 +442,9 @@ def ffn_block(h2: jax.Array, lp: dict, spec: ModelSpec, ll: dict | None = None,
         rin = x if router_in is None else router_in.reshape(-1, orig[-1])
         router = jnp.einsum("th,he->te", rin, lp["moe_gate"],
                             preferred_element_type=jnp.float32)
-        gates, top_i = moe_route(router, spec)
+        bias = (lp["moe_bias"][:, 0].astype(jnp.float32)
+                if spec.moe_select_bias else None)
+        gates, top_i = moe_route(router, spec, bias)
         if spec.holds_share:
             # Counted from the first expert held: a choice that fell on
             # an expert held elsewhere has no column in one_hot.
@@ -445,6 +523,10 @@ def init_params(spec: ModelSpec, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     for name, shape in shapes["layers"].items():
         if name.endswith("_norm"):
             params["layers"][name] = jnp.ones(shape, dtype)
+        elif name.endswith("_bias"):    # [..., n, 1]: small beside a score
+            params["layers"][name] = (
+                jax.random.normal(jax.random.fold_in(key, len(name)), shape,
+                                  dtype) * (shape[-2] ** -0.5))
     return params
 
 
@@ -473,11 +555,48 @@ def norm(x: jax.Array, scale: jax.Array, spec: ModelSpec) -> jax.Array:
     return rms_norm(x, scale, spec.rms_norm_eps)
 
 
-def rope_tables(positions: jax.Array, head_dim: int, theta: float
-                ) -> tuple[jax.Array, jax.Array]:
+def yarn_frequencies(dim: int, theta: float, yarn: tuple) -> jax.Array:
+    """YaRN's dim // 2 frequencies: f_i = theta ** (-2i / dim) where a
+    rotation is fast (more than beta_fast turns over the original
+    context), f_i / factor where it is slow (fewer than beta_slow), a
+    linear ramp over the frequency index between: d(r) = dim ln(original /
+    (2 pi r)) / (2 ln theta), lo = floor(d(beta_fast)), hi =
+    ceil(d(beta_slow)), ramp_i = clip((i - lo) / (hi - lo), 0, 1), f'_i =
+    f_i (1 - ramp_i) + f_i / factor * ramp_i. cos and sin are not scaled
+    (mscale equals mscale_all_dim): the attention's scale carries it
+    (DeepseekV32Spec.attn_scale)."""
+    factor, original, beta_fast, beta_slow = yarn[:4]
+    half = dim // 2
+
+    def turns_dim(r):
+        return dim * math.log(original / (2 * math.pi * r)) \
+            / (2 * math.log(theta))
+
+    lo = max(math.floor(turns_dim(beta_fast)), 0)
+    hi = min(math.ceil(turns_dim(beta_slow)), dim - 1)
+    i = jnp.arange(half, dtype=jnp.float32)
+    ramp = jnp.clip((i - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    freqs = 1.0 / (theta ** (i / half))
+    return freqs * (1.0 - ramp) + freqs / factor * ramp
+
+
+def spec_rope_tables(spec: ModelSpec, positions: jax.Array):
+    """``rope_tables`` of what ``spec`` rotates: a whole head at plain
+    frequencies, or the latent block's rope part at YaRN's."""
+    if spec.latent:
+        return rope_tables(positions, spec.qk_rope_head_dim, spec.rope_theta,
+                           spec.rope_yarn)
+    return rope_tables(positions, spec.head_dim, spec.rope_theta)
+
+
+def rope_tables(positions: jax.Array, head_dim: int, theta: float,
+                yarn: tuple | None = None) -> tuple[jax.Array, jax.Array]:
     """cos/sin tables for HF rotate-half RoPE; positions [...]."""
     half = head_dim // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    if yarn is not None:
+        freqs = yarn_frequencies(head_dim, theta, yarn)
+    else:
+        freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     angles = positions.astype(jnp.float32)[..., None] * freqs  # [..., half]
     cos = jnp.cos(angles)
     sin = jnp.sin(angles)
@@ -703,6 +822,294 @@ def paged_window_attention_xla(q: jax.Array, k_cache: jax.Array,
 def _split_heads(x, n, d):
     return x.reshape(*x.shape[:-1], n, d)
 
+# ---------------------------------------------------------------------------
+# Latent attention with a learned selection of keys (DeepSeek-V3.2)
+# ---------------------------------------------------------------------------
+
+class LatentQuery(NamedTuple):
+    """What ``attend`` gets as ``q`` in the latent block: the heads' nope
+    and rope parts, the indexer's query heads and head weights, and the two
+    halves of Wkv_b, which prefill multiplies the latent by (expanded form)
+    and decode folds into the query and the output (absorbed form)."""
+    nope: jax.Array         # [..., Nh, qk_nope_head_dim]
+    rope: jax.Array         # [..., Nh, qk_rope_head_dim], rotated
+    iq: jax.Array           # [..., index_n_heads, index_head_dim]
+    iw: jax.Array           # [..., index_n_heads] float32
+    wk_b: Any               # [kv_lora_rank, Nh * qk_nope_head_dim]
+    wv_b: Any               # [kv_lora_rank, Nh * v_head_dim]
+
+
+def latent_qkv(h: jax.Array, lp: dict, spec: ModelSpec, cos: jax.Array,
+               sin: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array,
+                                         jax.Array]:
+    """The latent projections of normalized states h [..., H]: (cq
+    [..., q_lora_rank], q nope [..., Nh, nope], q rope [..., Nh, rope]
+    rotated, entry [..., 1, width]). The entry is what the token leaves in
+    the first pool: the normalized latent, the ONE rope key every head
+    shares (rotated), zeros up to ``spec.kv_entry``'s width."""
+    eps = spec.rms_norm_eps
+    cq = rms_norm(mm(h, lp["wq_a"], "...h,hr->...r"), lp["q_a_norm"], eps)
+    q = _split_heads(mm(cq, lp["wq_b"], "...r,rd->...d"), spec.num_heads,
+                     spec.head_dim)
+    nope = q[..., :spec.qk_nope_head_dim]
+    rope = apply_rope(q[..., spec.qk_nope_head_dim:], cos, sin,
+                      spec.rope_interleaved)
+    ckv = mm(h, lp["wkv_a"], "...h,hr->...r")
+    c = rms_norm(ckv[..., :spec.kv_lora_rank], lp["kv_a_norm"], eps)
+    kr = apply_rope(ckv[..., None, spec.kv_lora_rank:], cos, sin,
+                    spec.rope_interleaved)[..., 0, :]
+    width = spec.kv_entry[1][0]
+    pad = jnp.zeros((*c.shape[:-1], width - c.shape[-1] - kr.shape[-1]),
+                    c.dtype)
+    return cq, nope, rope, jnp.concatenate([c, kr, pad], -1)[..., None, :]
+
+
+def index_qk(h: jax.Array, cq: jax.Array, lp: dict, spec: ModelSpec,
+             cos: jax.Array, sin: jax.Array):
+    """The indexer's projections: (query heads [..., J, Di], head weights
+    [..., J] float32, key [..., 1, Di]). The first qk_rope_head_dim dims of
+    each query head and of the key turn in rotate-half pairs at the
+    attention's own frequencies; the key is a LayerNorm WITH bias (eps
+    1e-6) of h WIk; the weights carry J ** -0.5 * Di ** -0.5."""
+    J, di, r = spec.index_n_heads, spec.index_head_dim, spec.qk_rope_head_dim
+
+    def turned(x):       # [..., heads, Di]
+        return jnp.concatenate(
+            [apply_rope(x[..., :r], cos, sin), x[..., r:]], axis=-1)
+
+    iq = turned(_split_heads(mm(cq, lp["index_wq_b"], "...r,rd->...d"),
+                             J, di))
+    k = mm(h, lp["index_wk"], "...h,hd->...d")
+    k = layer_norm(k, lp["index_k_norm"], 1e-6) + lp["index_k_bias"][:, 0]
+    ik = turned(k[..., None, :])
+    iw = jnp.einsum("...h,hj->...j", h, lp["index_w"],
+                    preferred_element_type=jnp.float32) * (J * di) ** -0.5
+    return iq, iw, ik
+
+
+#: Float32 bytes up to which the indexer's per-head scores, and a prefill
+#: call's attention scores, are one product. A quarter of the K-and-V
+#: path's runner.HISTORY_SCORE_BYTES, and not that bound: at exactly 1 GiB
+#: (8 rows x 128 heads x 512 x 512) a whole-prompt group's scores and as
+#: much again of exponentials are 3.3 GB of temporaries beside 13.5 GB of
+#: weights and pool, 1.4 GB in blocks of this size (compiled for a
+#: described v5e, PR 34).
+LATENT_SCORE_BYTES = 256 << 20
+
+
+def index_scores(iq: jax.Array, iw: jax.Array, ik: jax.Array) -> jax.Array:
+    """I[b, t, s] = sum_j iw[b, t, j] relu(iq[b, t, j] . ik[b, s]), float32.
+    iq [B, T, J, Di], iw [B, T, J], ik [B, S, Di]. A head at a time where
+    [B, T, J, S] float32 would pass LATENT_SCORE_BYTES."""
+    b, t, j, _ = iq.shape
+    s = ik.shape[1]
+
+    def of(iq, iw):                       # [B, T, j', Di], [B, T, j']
+        dots = jnp.einsum("btjd,bsd->btjs", iq, ik,
+                          preferred_element_type=jnp.float32)
+        return jnp.einsum("btjs,btj->bts", jnp.maximum(dots, 0.0), iw)
+
+    if 4 * b * t * j * s <= LATENT_SCORE_BYTES:
+        return of(iq, iw)
+    total, _ = jax.lax.scan(
+        lambda acc, x: (acc + of(x[0][:, :, None], x[1][:, :, None]), None),
+        jnp.zeros((b, t, s), jnp.float32),
+        (jnp.moveaxis(iq, 2, 0), jnp.moveaxis(iw, 2, 0)))
+    return total
+
+
+def select_topk(scores: jax.Array, valid: jax.Array, k: int) -> jax.Array:
+    """Bool mask [..., S] of the k valid entries of largest ``scores``
+    (float32) along the last axis, every valid one where there are fewer:
+    the SET ``jax.lax.top_k`` returns, without its sort. The k-th largest
+    value is found by bisection on the scores' bit patterns (an
+    order-preserving int32 of a float32), 32 counts over the row; a sort at
+    k = 2,048 of 8,192 costs more than the rest of the indexer (PERF.md
+    section 6, PR 34). Entries that TIE with the k-th value are all kept
+    (``top_k`` keeps the lower indices): more than k only where float32
+    scores of distinct keys are equal across rank k (none at 64 index heads;
+    ranking them took a cumulative sum over the row, as much as ten of the
+    counts)."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    key = bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+    bottom = jnp.int32(-2 ** 31)
+    key = jnp.where(valid, jnp.maximum(key, bottom + 1), bottom)
+
+    def halve(_, lohi):
+        lo, hi = lohi       # count(key >= lo) >= k, or lo is the bottom
+        mid = (lo >> 1) + (hi >> 1) + (((lo & 1) + (hi & 1) + 1) >> 1)
+        enough = jnp.sum(key >= mid[..., None], axis=-1) >= k
+        return jnp.where(enough, mid, lo), jnp.where(enough, hi, mid - 1)
+
+    shape = scores.shape[:-1]
+    kth, _ = jax.lax.fori_loop(
+        0, 32, halve, (jnp.full(shape, bottom), jnp.full(shape, 2 ** 31 - 1,
+                                                         jnp.int32)))
+    return (key >= kth[..., None]) & valid
+
+
+def _weight(w, heads: int):
+    """A [rank, heads * d] leaf as (matrix [rank, heads, d] in bfloat16 as
+    stored, float32 scale [heads, d] or None)."""
+    if isinstance(w, QTensor):
+        return (w.q.reshape(w.q.shape[0], heads, -1).astype(jnp.bfloat16),
+                w.s.reshape(heads, -1))
+    return w.reshape(w.shape[0], heads, -1), None
+
+
+def latent_prefill_attention(q: LatentQuery, entry: jax.Array,
+                             ik: jax.Array, positions: jax.Array,
+                             valid: jax.Array, spec: ModelSpec,
+                             hist: tuple | None = None) -> jax.Array:
+    """Prefill attention of the latent block in the EXPANDED form: every
+    head's key (c Wk_b | the shared rope key) and value c Wv_b are made
+    from the entries, over the chunk's own tokens and, with ``hist``
+    (entries [B, Lh, width], index keys [B, Lh, Di], hist_lens [B]: the
+    row's earlier pages, token l at position l), its history. q's leaves
+    [B, S, ...]; entry [B, S, 1, width], ik [B, S, 1, Di]; positions,
+    valid [B, S]. Where a query can have more than ``index_topk`` keys
+    (statically: history + chunk), it attends the index_topk of largest
+    index score among those it may see. Returns [B, S, Nh * v_head_dim]."""
+    b, s = positions.shape
+    nh, r = spec.num_heads, spec.kv_lora_rank
+    rope = spec.qk_rope_head_dim
+    e, ki = entry[:, :, 0], ik[:, :, 0]
+    seen = (positions[:, :, None] >= positions[:, None, :]) \
+        & valid[:, None, :]                                  # [B, S, S]
+    if hist is not None:
+        e_hist, ki_hist, hist_lens = hist
+        lh = e_hist.shape[1]
+        e = jnp.concatenate([e_hist, e], axis=1)
+        ki = jnp.concatenate([ki_hist, ki], axis=1)
+        old = jnp.arange(lh)[None, None, :] < hist_lens[:, None, None]
+        seen = jnp.concatenate(
+            [jnp.broadcast_to(old, (b, s, lh)), seen], axis=-1)
+    keys = e.shape[1]
+    if keys > spec.index_topk:
+        with scope("attn.index"):
+            seen = select_topk(index_scores(q.iq, q.iw, ki), seen,
+                               spec.index_topk)
+    with scope("attn.core"):
+        c, kr = e[..., :r], e[..., r:r + rope]
+        kn = _split_heads(mm(c, q.wk_b, "bkr,rd->bkd"), nh,
+                          spec.qk_nope_head_dim)
+        v = _split_heads(mm(c, q.wv_b, "bkr,rd->bkd"), nh, spec.v_head_dim)
+
+        def heads(x):
+            """A block of heads: nope, rope [B,S,n,.], kn, v [B,K,n,.]."""
+            qn, qr, kn, v = x
+            scores = (jnp.einsum("bqnd,bknd->bnqk", qn, kn,
+                                 preferred_element_type=jnp.float32)
+                      + jnp.einsum("bqnd,bkd->bnqk", qr, kr,
+                                   preferred_element_type=jnp.float32))
+            scores = jnp.where(seen[:, None], scores * spec.attn_scale,
+                               -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+            return jnp.einsum("bnqk,bknd->bqnd", probs, v)
+
+        # Heads in blocks whose float32 scores stay under the limit: all
+        # 128 heads of 8 rows of 512 tokens are 1.07 GB of scores and as
+        # much again of exponentials, beside 8.4 GB of weights and the pool
+        # (compiled for a described v5e, PR 34: 3.3 GB of temporaries
+        # whole, 1.4 GB in blocks).
+        block = nh
+        while block > 1 and 4 * b * block * s * keys > LATENT_SCORE_BYTES:
+            block //= 2
+        if block == nh:
+            out = heads((q.nope, q.rope, kn, v))
+        else:
+            split = lambda a: jnp.moveaxis(  # noqa: E731
+                a.reshape(*a.shape[:2], nh // block, block, a.shape[-1]),
+                2, 0)
+            out = jax.lax.map(heads, tuple(
+                split(a) for a in (q.nope, q.rope, kn, v)))
+            out = jnp.moveaxis(out, 0, 2)
+        return out.reshape(b, s, nh * spec.v_head_dim)
+
+
+def latent_window_attention(q: LatentQuery, e_cache: jax.Array,
+                            i_cache: jax.Array, layer: jax.Array,
+                            page_table: jax.Array, hist_lens: jax.Array,
+                            e_win: jax.Array, i_win: jax.Array, m: jax.Array,
+                            e_self: jax.Array, i_self: jax.Array,
+                            spec: ModelSpec, live: jax.Array | None = None):
+    """Decode attention of the latent block for step ``m`` of a window, in
+    the ABSORBED form: a head's query is folded through Wk_b into the
+    latent's space (qa_h = q_nope_h Wk_b[h]^T), scores and the weighted
+    sum run over the ENTRIES (the one key and value every head shares),
+    and Wv_b expands the result. The same numbers as the expanded form.
+
+    q's leaves [B, ...]; e_cache [L, 1, P, page, width] latent entries and
+    i_cache [L, 1, P, page, Di] index keys under one page table; e_win /
+    i_win [1, B, M, .] the window's earlier steps (columns < m), e_self /
+    i_self [B, 1, .] this token. Keys come from the three places
+    ``paged_window_attention_xla`` reads. Where the page-table bucket can
+    hold more than ``index_topk`` keys the indexer scores every key in
+    context and the row attends the index_topk of largest score. Returns
+    (attention [B, Nh * v_head_dim], float32 [2]: keys attended and keys
+    in context, summed over the ``live`` rows)."""
+    b = hist_lens.shape[0]
+    nh, r = spec.num_heads, spec.kv_lora_rank
+    page = e_cache.shape[3]
+    maxp = page_table.shape[1]
+    M = e_win.shape[2]
+    hist = maxp * page
+    pos = jnp.arange(hist)[None, :]
+    seen = jnp.concatenate(
+        [pos < hist_lens[:, None],
+         jnp.broadcast_to(jnp.arange(M)[None, :] < m, (b, M)),
+         jnp.ones((b, 1), bool)], axis=1)                    # [B, K]
+    chosen = seen
+    if hist + M + 1 > spec.index_topk:
+        with scope("attn.index"):
+            ki = gather_pages_folded(i_cache, layer, page_table)[0]
+            score = jnp.concatenate(
+                [index_scores(q.iq[:, None], q.iw[:, None], keys)[:, 0]
+                 for keys in (ki, i_win[0], i_self)], axis=-1)
+            chosen = select_topk(score, seen, spec.index_topk)
+    with scope("attn.kv_gather"):
+        e_all = gather_pages_folded(e_cache, layer, page_table)[0]
+    with scope("attn.core"):
+        wk, sk = _weight(q.wk_b, nh)
+        wv, sv = _weight(q.wv_b, nh)
+        nope = q.nope if sk is None else (
+            q.nope.astype(jnp.float32) * sk).astype(q.nope.dtype)
+        qa = jnp.einsum("bhd,rhd->bhr", nope, wk,
+                        preferred_element_type=jnp.bfloat16)
+        width = e_all.shape[-1]
+        qe = jnp.concatenate(
+            [qa, q.rope, jnp.zeros((b, nh, width - r - q.rope.shape[-1]),
+                                   qa.dtype)], axis=-1)      # [B, Nh, width]
+        # The three places' scores are never joined: a softmax over their
+        # concatenation copies [B, Nh, bucket] float32 scores twice more
+        # than the running maximum and sum below (PERF.md section 6, PR 34).
+        parts = (e_all, e_win[0], e_self)
+        kept = jnp.split(chosen, [hist, hist + M], axis=-1)
+        scores = [jnp.where(keep[:, None, :], jnp.einsum(
+            "bhe,bke->bhk", qe, e, preferred_element_type=jnp.float32)
+            * spec.attn_scale, -1e30) for keep, e in zip(kept, parts)]
+        top = functools.reduce(jnp.maximum,
+                               [jnp.max(sc, axis=-1, initial=-1e30)
+                                for sc in scores])
+        weights = [jnp.exp(sc - top[..., None]) for sc in scores]
+        total = sum(jnp.sum(w, axis=-1) for w in weights)
+        # Over the whole entry, the latent cut out of the SUM: a slice of
+        # the gathered entries would be a copy of them.
+        ctx = sum(jnp.einsum("bhk,bke->bhe", w.astype(qe.dtype), e,
+                             preferred_element_type=jnp.float32)
+                  for w, e in zip(weights, parts))[..., :r]
+        ctx = ctx / total[..., None]
+        out = jnp.einsum("bhr,rhd->bhd", ctx.astype(qe.dtype), wv,
+                         preferred_element_type=jnp.float32)
+        if sv is not None:
+            out = out * sv
+        out = out.astype(qe.dtype).reshape(b, -1)
+    rows = (jnp.ones((b,), jnp.float32) if live is None
+            else live.astype(jnp.float32))
+    counts = jnp.stack([jnp.sum(jnp.sum(chosen, -1) * rows),
+                        jnp.sum(jnp.sum(seen, -1) * rows)])
+    return out, counts
+
 
 # ---------------------------------------------------------------------------
 # The block, once
@@ -753,30 +1160,48 @@ def transformer_block(x: jax.Array, lp: dict, spec: ModelSpec,
     ``kind`` is ``layer_kind`` of this layer. Returns (x, k, v, stats):
     k/v the layer's fresh keys and values, stats ``moe_load_stats`` of the
     ``live`` rows where asked for (routed blocks), else None.
-    ``experts_local``: see ``ffn_block``."""
+    ``experts_local``: see ``ffn_block``.
+
+    The latent block (``spec.latent``) differs in the projections ahead of
+    ``attend`` alone: q is a ``LatentQuery``, k the token's latent entry
+    and v its index key (what the two pools hold); where ``attend``
+    returns (attention, counts), stats is (counts,) or (counts, the
+    expert layer's stats)."""
     sc = scope if scoped else (lambda _name: contextlib.nullcontext())
     d = spec.head_dim
     with sc("attn.qkv"):
         h = norm(x, lp["input_norm"], spec)
-        q = mm(h, lp["wq"], "...h,hd->...d")
-        k = mm(h, lp["wk"], "...h,hd->...d")
-        v = mm(h, lp["wv"], "...h,hd->...d")
-        if ll is not None:
-            q, k, v = qkv_lora(q, k, v, h, ll, ids)
-        if spec.qkv_bias:
-            q = q + lp["bq"]
-            k = k + lp["bk"]
-            v = v + lp["bv"]
-        q = _split_heads(q, spec.num_heads, d)
-        k = _split_heads(k, spec.num_kv_heads, d)
-        v = _split_heads(v, spec.num_kv_heads, d)
-        if kind is not None:
-            # NoPE layers: the identity rotation (x*1 - y*0 is exact).
-            cos = jnp.where(kind[0], cos, 1.0)
-            sin = jnp.where(kind[0], sin, 0.0)
-        q = apply_rope(q, cos, sin, spec.rope_interleaved)
-        k = apply_rope(k, cos, sin, spec.rope_interleaved)
+        if spec.latent:
+            cq, nope, rope, k = latent_qkv(h, lp, spec, cos, sin)
+        else:
+            q = mm(h, lp["wq"], "...h,hd->...d")
+            k = mm(h, lp["wk"], "...h,hd->...d")
+            v = mm(h, lp["wv"], "...h,hd->...d")
+            if ll is not None:
+                q, k, v = qkv_lora(q, k, v, h, ll, ids)
+            if spec.qkv_bias:
+                q = q + lp["bq"]
+                k = k + lp["bk"]
+                v = v + lp["bv"]
+            q = _split_heads(q, spec.num_heads, d)
+            k = _split_heads(k, spec.num_kv_heads, d)
+            v = _split_heads(v, spec.num_kv_heads, d)
+            if kind is not None:
+                # NoPE layers: the identity rotation (x*1 - y*0 is exact).
+                cos = jnp.where(kind[0], cos, 1.0)
+                sin = jnp.where(kind[0], sin, 0.0)
+            q = apply_rope(q, cos, sin, spec.rope_interleaved)
+            k = apply_rope(k, cos, sin, spec.rope_interleaved)
+    if spec.latent:
+        # What the token leaves in the cache is the entry (k) and the
+        # index key (v); the query carries what attend selects by.
+        with sc("attn.index"):
+            iq, iw, v = index_qk(h, cq, lp, spec, cos, sin)
+        q = LatentQuery(nope, rope, iq, iw, lp["wk_b"], lp["wv_b"])
     attn = attend(q, k, v, kind)
+    counts = None
+    if isinstance(attn, tuple):     # the latent window step counts its keys
+        attn, counts = attn
     with sc("attn.out"):
         proj = mm(attn, lp["wo"], "...d,dh->...h")
         if ll is not None:
@@ -792,7 +1217,35 @@ def transformer_block(x: jax.Array, lp: dict, spec: ModelSpec,
                         experts_local=experts_local)
         out, stats = out if isinstance(out, tuple) else (out, None)
         x = x + out
+    if counts is not None:
+        stats = (counts,) if stats is None else (counts, stats)
     return x, k, v, stats
+
+
+def scan_layers(layer_fn, x: jax.Array, xs, spec: ModelSpec):
+    """``jax.lax.scan(layer_fn, x, xs)`` over the layers, where ``xs`` is
+    ``params["layers"]`` or a tuple that starts with it and goes on with
+    arrays stacked over ALL layers (the layer index, a window's buffers).
+    A model with leading dense layers (``first_k_dense``) is two scans: the
+    dense layers' leaves (``DENSE_PREFIX``, under the names the layer
+    reads) with the first rows of the other arrays, then the rest; what
+    both scans give a layer (k, v, counts) is joined along the layer axis,
+    what only the expert layers give (their load) follows."""
+    dense = spec.first_k_dense
+    if not dense:
+        return jax.lax.scan(layer_fn, x, xs)
+    layers, others = (xs, None) if isinstance(xs, dict) else (xs[0], xs[1:])
+    first = {k[len(DENSE_PREFIX):]: v for k, v in layers.items()
+             if k.startswith(DENSE_PREFIX)}
+    rest = {k: v for k, v in layers.items() if not k.startswith(DENSE_PREFIX)}
+    if others is not None:
+        first = (first, *(a[:dense] for a in others))
+        rest = (rest, *(a[dense:] for a in others))
+    x, ys_first = jax.lax.scan(layer_fn, x, first)
+    x, ys_rest = jax.lax.scan(layer_fn, x, rest)
+    joined = tuple(jnp.concatenate([a, b])
+                   for a, b in zip(ys_first, ys_rest))
+    return x, joined + tuple(ys_rest[len(ys_first):])
 
 
 # ---------------------------------------------------------------------------
@@ -836,12 +1289,14 @@ def prefill_forward(params: Params, spec: ModelSpec,
         if sp_shard:
             x = jax.lax.with_sharding_constraint(x, P(None, "sp", None))
     with scope("attn.qkv"):
-        cos, sin = rope_tables(positions, d, spec.rope_theta)
+        cos, sin = spec_rope_tables(spec, positions)
     valid = jnp.arange(s)[None, :] < seq_lens[:, None]
 
     patterned = spec.has_layer_pattern
 
     def attend(q, k, v, kind):
+        if spec.latent:     # owns its scopes: attn.index, attn.core
+            return latent_prefill_attention(q, k, v, positions, valid, spec)
         with scope("attn.core"):
             if ring_mesh is not None:
                 attn = ring_causal_attention(ring_mesh, q, k, v, positions,
@@ -867,15 +1322,15 @@ def prefill_forward(params: Params, spec: ModelSpec,
     xs = (params["layers"], lora) if lora is not None else params["layers"]
     if patterned:
         xs = (xs, jnp.arange(spec.num_layers))
-    x, (k_new, v_new) = jax.lax.scan(layer_fn, x, xs)
+    x, (k_new, v_new) = scan_layers(layer_fn, x, xs, spec)
     # k_new [L,B,S,Nkv,D] -> page blocks [L,Nkv,B*S/page,page,D]; one
     # in-place scatter per cache covers every layer.
     with scope("kv.commit"):
         L = spec.num_layers
-        nkv = spec.num_kv_heads
-        k_blocks = (k_new.reshape(L, b * (s // page), page, nkv, d)
+        nkv, (dk, dv) = spec.kv_entry
+        k_blocks = (k_new.reshape(L, b * (s // page), page, nkv, dk)
                     .transpose(0, 3, 1, 2, 4))
-        v_blocks = (v_new.reshape(L, b * (s // page), page, nkv, d)
+        v_blocks = (v_new.reshape(L, b * (s // page), page, nkv, dv)
                     .transpose(0, 3, 1, 2, 4))
         flat_pages = page_table.reshape(-1)
         # scatter_pages quantizes int8 pools in the same fused commit.
@@ -1056,7 +1511,7 @@ def decode_forward(params: Params, spec: ModelSpec,
     d = spec.head_dim
     page = k_cache.shape[3]
     x = embed_lookup(params["embed"], tokens)  # [B,H]
-    cos, sin = rope_tables(positions, d, spec.rope_theta)
+    cos, sin = spec_rope_tables(spec, positions)
     # Target page slot for the new token.
     page_idx = positions // page
     page_off = positions % page
@@ -1081,6 +1536,11 @@ def decode_forward(params: Params, spec: ModelSpec,
             (lp, layer), ll = scan_in, None
 
         def attend(q, k, v, kind):
+            if spec.latent:     # the window's attention, no window columns
+                return latent_window_attention(
+                    q, k_cache, v_cache, layer, page_table, hist_lens,
+                    k[None, :, :0], v[None, :, :0], jnp.asarray(0, jnp.int32),
+                    k, v, spec)[0]
             attn = attn_fn(q, k_cache, v_cache, layer, page_table, hist_lens,
                            k, v, spec.q_per_kv,
                            lo=window_lo(spec, kind, positions))  # [B,Nh,D]
@@ -1093,7 +1553,7 @@ def decode_forward(params: Params, spec: ModelSpec,
 
     xs = ((params["layers"], jnp.arange(L), lora) if lora is not None
           else (params["layers"], jnp.arange(L)))
-    x, (k_new, v_new) = jax.lax.scan(layer_fn, x, xs)
+    x, (k_new, v_new) = scan_layers(layer_fn, x, xs, spec)
     # One in-place scatter: [L,Nkv,B,D] at (dest_page[b], page_off[b]).
     k_cache = scatter_tokens(k_cache, k_new.transpose(0, 2, 1, 3),
                              dest_page, page_off)
@@ -1133,7 +1593,7 @@ def decode_window_multi_step(params: Params, spec: ModelSpec,
     with scope("embed"):
         x = embed_lookup(params["embed"], tokens)          # [B,S,H]
     with scope("attn.qkv"):
-        cos, sin = rope_tables(positions, d, spec.rope_theta)
+        cos, sin = spec_rope_tables(spec, positions)
     scale = 1.0 / jnp.sqrt(jnp.float32(d))
     L = spec.num_layers
 
@@ -1217,7 +1677,7 @@ def embed_forward(params: Params, spec: ModelSpec, tokens: jax.Array,
     d = spec.head_dim
     x = embed_lookup(params["embed"], tokens)
     positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
-    cos, sin = rope_tables(positions, d, spec.rope_theta)
+    cos, sin = spec_rope_tables(spec, positions)
     valid = jnp.arange(s)[None, :] < seq_lens[:, None]
     patterned = spec.has_layer_pattern
 
@@ -1272,7 +1732,7 @@ def decode_window_step(params: Params, spec: ModelSpec,
     with scope("embed"):
         x = embed_lookup(params["embed"], tokens)
     with scope("attn.qkv"):
-        cos, sin = rope_tables(positions, d, spec.rope_theta)
+        cos, sin = spec_rope_tables(spec, positions)
     attn_fn = attention_impl or paged_window_attention_xla
     L = spec.num_layers
 
@@ -1283,6 +1743,10 @@ def decode_window_step(params: Params, spec: ModelSpec,
             (lp, layer, kb_l, vb_l), ll = scan_in, None
 
         def attend(q, k, v, kind):
+            if spec.latent:     # owns its scopes; counts the live rows' keys
+                return latent_window_attention(
+                    q, k_cache, v_cache, layer, page_table, hist_lens, kb_l,
+                    vb_l, m, k, v, spec, live)
             with scope("attn.core"):
                 attn = attn_fn(q, k_cache, v_cache, layer, page_table,
                                hist_lens, kb_l, vb_l, m, k, v, spec.q_per_kv,
@@ -1292,14 +1756,18 @@ def decode_window_step(params: Params, spec: ModelSpec,
         x, k, v, stats = transformer_block(
             x, lp, spec, cos, sin, attend, layer_kind(spec, layer), ll,
             adapter_ids, live=live, experts_local=experts_local)
-        return x, ((k, v) if stats is None else (k, v, stats))
+        if stats is None:
+            return x, (k, v)
+        return x, ((k, v, *stats) if isinstance(stats, tuple)
+                   else (k, v, stats))
 
     xs = ((params["layers"], jnp.arange(L), k_buf, v_buf, lora)
           if lora is not None
           else (params["layers"], jnp.arange(L), k_buf, v_buf))
-    x, ys = jax.lax.scan(layer_fn, x, xs)
+    x, ys = scan_layers(layer_fn, x, xs, spec)
     with scope("lm_head"):
         x = norm(x, params["final_norm"], spec)
         logits = lm_logits(x, params, spec)
-    # A routed block asked for its load (``live``) adds [L, 3] stats.
+    # A routed block asked for its load (``live``) adds [L, 3] stats; the
+    # latent block its key counts [L, 2] ahead of them.
     return (logits, *ys)

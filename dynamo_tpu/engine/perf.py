@@ -85,6 +85,11 @@ _EWMA = 0.2
 #: a scope changes no HLO instruction and so no device time.
 SCOPES = ("embed", "attn.qkv", "attn.kv_gather", "attn.core", "attn.out",
           "mlp", "lm_head", "sample", "kv.commit")
+#: Scopes BESIDE those, drawn only in programs of one block kind: the latent
+#: block's indexer (its projections, its read and scores of the index keys
+#: in context, the choice). The other blocks' programs have none, so their
+#: names, and SCOPES_VERSION, stand.
+BLOCK_SCOPES = ("attn.index",)
 #: Regions INSIDE a scope, drawn only in programs of a routed block (the
 #: expert layer's router and experts and, where the block has them, its
 #: shared experts, inside ``mlp``). An instruction in one
@@ -101,8 +106,9 @@ SCOPES_VERSION = 1
 
 
 def scope(name: str):
-    """``jax.named_scope`` for one name of SCOPES or SUBSCOPES."""
-    assert name in SCOPES or name in SUBSCOPES, name
+    """``jax.named_scope`` for one name of SCOPES, BLOCK_SCOPES or
+    SUBSCOPES."""
+    assert name in SCOPES + BLOCK_SCOPES + SUBSCOPES, name
     return jax.named_scope(name)
 
 
@@ -122,7 +128,7 @@ def _scope_of(op_name: str) -> str | None:
     after it (joined with ``+``) the innermost that is a sub-scope."""
     parts = op_name.split("/")
     found = [next((p for p in reversed(parts) if p in names), None)
-             for names in (SCOPES, SUBSCOPES)]
+             for names in (SCOPES + BLOCK_SCOPES, SUBSCOPES)]
     return "+".join(p for p in found if p) or None
 
 
@@ -175,7 +181,7 @@ def scopes_of_hlo(text: str) -> dict[str, str | None]:
         inside = {part for i in members.get(fused, ()) if own.get(i)
                   for part in own[i].split("+")}
         if inside:
-            out[name] = "+".join(s for s in SCOPES + SUBSCOPES
+            out[name] = "+".join(s for s in SCOPES + BLOCK_SCOPES + SUBSCOPES
                                  if s in inside)
     read_by: dict[str, list[str]] = {}
     for name, operands in reads.items():
@@ -755,6 +761,20 @@ class PerfMetricsUpdater:
         self.g_moe_experts = registry.gauge(
             "moe_experts_info", "Expert layer told its share: experts the "
             "router chooses among, held here and shared", ["kind"])
+        self.c_attn_selected = registry.counter(
+            "attn_selected_total", "Latent block: keys the live rows "
+            "attended (the indexer's choice, at most index_topk a row), "
+            "summed over rows, layers and decode steps")
+        self.c_attn_context = registry.counter(
+            "attn_context_total", "Latent block: keys the live rows had in "
+            "context, summed over rows, layers and decode steps (every one "
+            "is scored by the indexer)")
+        self.g_kv_entry = registry.gauge(
+            "perf_kv_entry_info", "1 under the labels of what a token "
+            "holds in this worker's KV pool over all layers: kind "
+            "(kv: K and V heads; latent: a latent entry and an index key) "
+            "and bytes (config.kv_token_bytes, lane padding included)",
+            ["kind", "bytes"])
         for bound in (self.g_step_seconds, self.g_achieved, self.g_roofline,
                       self.g_hbm_in_use, self.g_hbm_peak, self.g_hbm_limit):
             bound.ensure()
@@ -799,10 +819,19 @@ class PerfMetricsUpdater:
         page = getattr(runner, "page_size", None)
         if page:
             self.g_kv_page.set(1, tokens=str(page))
+        config = getattr(engine, "config", None)
+        if config is not None and hasattr(config, "kv_token_bytes"):
+            latent = config.model.latent
+            self.g_kv_entry.set(1, kind="latent" if latent else "kv",
+                                bytes=str(config.kv_token_bytes()))
         if hbm:
             self.g_hbm_in_use.set(hbm.get("bytes_in_use", 0))
             self.g_hbm_peak.set(hbm.get("peak_bytes_in_use", 0))
             self.g_hbm_limit.set(hbm.get("bytes_limit", 0))
+        attn = getattr(engine, "attn_totals", None)
+        if attn is not None and attn[1]:
+            self._delta(self.c_attn_selected, ("attn_s",), float(attn[0]))
+            self._delta(self.c_attn_context, ("attn_c",), float(attn[1]))
         moe = getattr(engine, "moe_totals", None)
         if moe is not None and moe[2]:
             self._delta(self.c_moe_touched, ("moe_t",), float(moe[0]))

@@ -31,6 +31,8 @@ from typing import Any, NamedTuple
 
 import numpy as np
 
+from dynamo_tpu.engine.config import DENSE_PREFIX
+
 
 class QTensor(NamedTuple):
     """int8 weight + broadcastable scale; a pytree of two leaves."""
@@ -39,9 +41,17 @@ class QTensor(NamedTuple):
 
 
 # Layer leaves that quantize (the big matmuls); everything else stays bf16.
-QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
-                    "moe_w_gate", "moe_w_up", "moe_w_down",
-                    "shared_w_gate", "shared_w_up", "shared_w_down")
+_BLOCK_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+               "moe_w_gate", "moe_w_up", "moe_w_down",
+               "shared_w_gate", "shared_w_up", "shared_w_down")
+# The DeepSeek-V3.2 block's latent projections and its indexer's (the
+# indexer's head weights index_w stay bf16 beside the router), and a leading
+# dense layer's leaves under their own names (config.DENSE_PREFIX).
+_LATENT_KEYS = ("wq_a", "wq_b", "wkv_a", "wk_b", "wv_b", "index_wq_b",
+                "index_wk")
+QUANT_LAYER_KEYS = _BLOCK_KEYS + _LATENT_KEYS + tuple(
+    DENSE_PREFIX + key for key in ("wo", "w_gate", "w_up", "w_down")
+    + _LATENT_KEYS)
 
 
 def _safe_scale(amax: np.ndarray) -> np.ndarray:

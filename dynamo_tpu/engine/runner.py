@@ -37,7 +37,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.engine import perf
 from dynamo_tpu.engine.config import (EngineConfig, block_refusals,
-                                      pool_access)
+                                      pool_access, window_page_bucket)
 from dynamo_tpu.engine.kv_quant import (KV_SCALE_BYTES, QuantKV, pack_parcel,
                                         parcel_to_bf16, quantize_np,
                                         scatter_tokens, unpack_parcel,
@@ -266,10 +266,14 @@ class ModelRunner:
         # KV cache arrays [L, Nkv, P, page, D]: layers sharded over pp
         # (pages live with their layer's stage), kv heads over tp, and
         # [page, D] contiguous per (head, page) for clean Pallas DMAs.
+        # A latent pool (spec.kv_entry) is the same two arrays under the
+        # same page table, allocator and prefix hashes: k_cache holds a
+        # token's latent entry, v_cache its index key, one "head" each.
         kv_spec = P("pp", "tp", None, None, None)
         self.kv_sharding = NamedSharding(self.mesh, kv_spec)
-        kv_shape = (spec.num_layers, spec.num_kv_heads, self.num_pages,
-                    config.page_size, spec.head_dim)
+        kv_heads, (k_width, v_width) = spec.kv_entry
+        kv_shape = (spec.num_layers, kv_heads, self.num_pages,
+                    config.page_size, k_width)
         if self.quant_kv == "int8":
             # int8 pages + per-token-per-head f32 scales (zero-init: an
             # unwritten page dequantizes to 0, same as the bf16 pool;
@@ -286,7 +290,7 @@ class ModelRunner:
         else:
             self.k_cache = _mh_zeros(kv_shape, jnp.bfloat16,
                                      self.kv_sharding)
-            self.v_cache = _mh_zeros(kv_shape, jnp.bfloat16,
+            self.v_cache = _mh_zeros((*kv_shape[:-1], v_width), jnp.bfloat16,
                                      self.kv_sharding)
         # Byte ledgers for the perf plane's HBM breakdown (/debug/perf):
         # this process's per-device share of params and the KV pool —
@@ -300,7 +304,7 @@ class ModelRunner:
         self.kv_pool_bytes = (
             2 * self.num_pages * config.page_size
             * self._kv_token_head_bytes() * spec.num_layers
-            * spec.num_kv_heads) // shard
+            * kv_heads) // shard
 
         self._prefill_cache: dict = {}
         self._decode_fn = None
@@ -378,9 +382,10 @@ class ModelRunner:
 
     # -- setup ---------------------------------------------------------------
     def _kv_token_head_bytes(self) -> int:
-        """Pool bytes per (layer, kv-head, token): bf16 values, or int8
-        values + the f32 scale (engine/kv_quant.py)."""
-        d = self.spec.head_dim
+        """Pool bytes per (layer, kv-head, token) of ONE of the two pool
+        arrays (their mean, where a latent pool's differ in width): bf16
+        values, or int8 values + the f32 scale (engine/kv_quant.py)."""
+        d = sum(self.spec.kv_entry[1]) // 2
         return (d + KV_SCALE_BYTES) if self.quant_kv == "int8" else 2 * d
 
     def _sized_pages(self, device) -> None:
@@ -413,7 +418,7 @@ class ModelRunner:
         # The cache shards over tp (heads) AND pp (layers). int8 pages
         # (+ scales) cost ~half the bf16 bytes, so the same budget holds
         # ~2x pages — directly more resident sequences per chip.
-        token_bytes = (2 * self.spec.num_layers * self.spec.num_kv_heads
+        token_bytes = (2 * self.spec.num_layers * self.spec.kv_entry[0]
                        * self._kv_token_head_bytes())
         page_bytes = token_bytes * cfg.page_size // max(1, cfg.tp * cfg.pp)
         self.num_pages = max(16, budget // max(1, page_bytes))
@@ -436,6 +441,10 @@ class ModelRunner:
         None: what "pallas" raises with and what "auto" decides from."""
         d = self.spec.head_dim
         page = self.config.page_size
+        if self.spec.latent:
+            return ("walks K and V pages of one head_dim; no kernel reads a "
+                    "latent pool (an entry of 640 lanes chosen by index "
+                    "scores over keys of 128)")
         if not (d == 128 or (d < 128 and 128 % d == 0
                              and (page * d) % 128 == 0)):
             return (f"needs head_dim 128, or a head_dim that packs into 128 "
@@ -456,7 +465,8 @@ class ModelRunner:
         from dynamo_tpu.engine.model import paged_window_attention_xla
         backend, _ = pool_access(
             self.config.attention_backend, self.device.platform,
-            self.mesh.size, self.spec.head_dim, self.quant_kv)
+            self.mesh.size, self.spec.head_dim, self.quant_kv,
+            self.spec.latent)
         self.attention_backend = backend
         if backend == "xla":
             return paged_decode_attention_xla, paged_window_attention_xla
@@ -483,7 +493,7 @@ class ModelRunner:
         the layout the reader reads."""
         return pool_access(self.attention_backend, self.device.platform,
                            self.mesh.size, self.spec.head_dim,
-                           self.quant_kv)[1]
+                           self.quant_kv, self.spec.latent)[1]
 
     # -- compiled steps -------------------------------------------------------
     def _get_prefill(self, bucket: int, batch: int, with_history: bool,
@@ -664,8 +674,8 @@ class ModelRunner:
                 base_keys = jax.vmap(jax.random.key)(packed[:, PK_SEED])
             page_table = packed[:, PK_PREFIX:]
             B = tokens0.shape[0]
-            L, nkv = spec.num_layers, spec.num_kv_heads
-            d = spec.head_dim
+            L = spec.num_layers
+            nkv, (dk, dv) = spec.kv_entry
             # Cache-resident history length is FIXED across the window: the
             # window's own tokens live in a small in-window buffer and are
             # committed to the pool ONCE, at the end. The caches are
@@ -674,13 +684,16 @@ class ModelRunner:
             # ms/step at a 3 GB pool, vs flat ~1.5 ms this way).
             hist_lens = jnp.maximum(seq_lens0 - 1, 0)
             with perf.scope("kv.commit"):
-                kbuf0 = jnp.zeros((L, nkv, B, window, d), k_cache.dtype)
-                vbuf0 = jnp.zeros((L, nkv, B, window, d), v_cache.dtype)
+                kbuf0 = jnp.zeros((L, nkv, B, window, dk), k_cache.dtype)
+                vbuf0 = jnp.zeros((L, nkv, B, window, dv), v_cache.dtype)
 
             want_lp = jnp.any(packed[:, PK_LOGPROB] > 0)
             # A routed block's window also counts what its routing did to
             # the live rows (model.moe_load_stats), summed over steps and
-            # layers on the device: one [3] vector more in the readback.
+            # layers on the device: one [3] vector more in the readback
+            # ("moe"). A latent block's counts the keys its rows attended
+            # and had in context (model.latent_window_attention): a [2]
+            # vector of its own ("attn").
             routed = bool(spec.num_experts)
 
             def step(carry, m):
@@ -689,7 +702,7 @@ class ModelRunner:
                 # pages; at capacity it freezes in-graph (the host emits
                 # LENGTH when it sees the cap).
                 live = (seq_lens0 > 0) & (positions < cap)
-                logits, k_new, v_new, *moe = decode_window_step(
+                logits, k_new, v_new, *stats = decode_window_step(
                     params, spec, k_cache, v_cache, kbuf, vbuf, m, tokens,
                     positions, page_table, hist_lens,
                     attention_impl=self._window_attention_impl,
@@ -746,15 +759,22 @@ class ModelRunner:
                     tokens = jnp.where(live, sampled, tokens)
                     positions = positions + live.astype(jnp.int32)
                 return (tokens, positions, kbuf, vbuf, rng, cnts), (
-                    sampled, lp, top_v, top_i, *moe)
+                    sampled, lp, top_v, top_i, *stats)
 
             carry0 = (tokens0, positions0, kbuf0, vbuf0, rng,
                       counts if penalized else jnp.zeros((), jnp.uint8))
             (tokens, _, kbuf, vbuf, rng, counts_out), \
-                (toks, lps, top_vs, top_is, *moe) = \
+                (toks, lps, top_vs, top_is, *stats) = \
                 jax.lax.scan(step, carry0, jnp.arange(window))
-            # [M, L, n] -> [n] (model.moe_load_stats: 3 sums, 5 for a told share).
-            moe = [jnp.sum(moe[0], axis=(0, 1))] if routed else []
+            # [M, L, n] -> [n] under the name of what was counted, in the
+            # order decode_window_step returns them: a latent block's keys
+            # (2 sums), a routed block's load (model.moe_load_stats: 3
+            # sums, 5 for a told share). A block that counts nothing adds
+            # nothing to the program's outputs.
+            names = (["attn"] if spec.latent else []) + (
+                ["moe"] if routed else [])
+            stats = {name: jnp.sum(a, axis=(0, 1))
+                     for name, a in zip(names, stats, strict=True)}
 
             # Commit the window: every (slot, step) entry goes to its page.
             with perf.scope("kv.commit"):
@@ -781,9 +801,9 @@ class ModelRunner:
                         v_cache, vbuf.transpose(0, 1, 3, 2, 4), dest, off)
             if penalized:
                 return (toks, lps, top_vs, top_is, tokens, k_cache,
-                        v_cache, rng, counts_out, *moe)
+                        v_cache, rng, counts_out, stats)
             return (toks, lps, top_vs, top_is, tokens, k_cache, v_cache,
-                    rng, *moe)
+                    rng, stats)
 
         donate = (1, 2, 6) if penalized else (1, 2)
         fn = perf.instrumented_jit(
@@ -1261,13 +1281,11 @@ class ModelRunner:
                 jnp.asarray(rows, jnp.uint8))
 
     def bucket_pages_for(self, needed: int) -> int:
-        """Page-table width bucket (power of two, >= 8) for the decode
-        window."""
-        b = 8
-        maxp = self.config.max_pages_per_seq
-        while b < needed and b < maxp:
-            b *= 2
-        return min(b, maxp)
+        """Page-table width bucket for the decode window
+        (config.window_page_bucket, by the reader this runner resolved)."""
+        return window_page_bucket(needed, self.attention_backend,
+                                  self.config.page_size,
+                                  self.config.max_pages_per_seq)
 
     def decode_window(self, packed: np.ndarray, window: int):
         """Dispatch one M-step decode window.
@@ -1276,10 +1294,14 @@ class ModelRunner:
         Returns (toks [M,B], lp [M,B], top_v [M,B,K], top_i [M,B,K])
         device arrays (fetch with np.asarray when needed; start async
         copies early via .copy_to_host_async()). The logprob arrays are
-        zeros unless some slot set PK_LOGPROB. A routed block adds a fifth,
-        float32 [3]: over the window's steps and expert layers, the sum of
-        distinct experts the live rows chose, the sum of the fullest
-        expert's tokens over the mean, and the layer-steps counted.
+        zeros unless some slot set PK_LOGPROB. The fifth is a dict of what
+        the block counted on the device, empty for most: "moe" (a routed
+        block) float32 [3], over the window's steps and expert layers the
+        sum of distinct experts the live rows chose, the sum of the fullest
+        expert's tokens over the mean, and the layer-steps counted ([5] for
+        a told share: picks on held experts, all picks); "attn" (a latent
+        block) float32 [2], keys the live rows attended and keys they had
+        in context, over steps and layers.
         """
         bucket_pages = packed.shape[1] - PK_PREFIX
         # Specialize on whether any slot carries penalties THIS window —
@@ -1293,16 +1315,16 @@ class ModelRunner:
         with self.mesh:
             if penalized:
                 (toks, lps, top_vs, top_is, self.tokens_dev, self.k_cache,
-                 self.v_cache, self._rng, self.counts_dev, *moe) = fn(
+                 self.v_cache, self._rng, self.counts_dev, stats) = fn(
                     self.params, self.k_cache, self.v_cache,
                     self.tokens_dev, jnp.asarray(packed), self._rng,
                     self.counts_dev, **kw)
             else:
                 (toks, lps, top_vs, top_is, self.tokens_dev, self.k_cache,
-                 self.v_cache, self._rng, *moe) = fn(
+                 self.v_cache, self._rng, stats) = fn(
                     self.params, self.k_cache, self.v_cache,
                     self.tokens_dev, jnp.asarray(packed), self._rng, **kw)
-        return (toks, lps, top_vs, top_is, *moe)
+        return (toks, lps, top_vs, top_is, stats)
 
     def embed(self, token_lists: list[list[int]],
               pooling: str = "last") -> np.ndarray:
@@ -1448,6 +1470,8 @@ class ModelRunner:
         blocking (offload path: the extract is stream-ordered before any
         later program that reuses the pages, and the host fetch overlaps
         subsequent windows). Finalize with ``finalize_extract``."""
+        for refusal in block_refusals(self.spec, kv_transfer=True):
+            raise refusal
         n = len(pages)
         nb = self._page_bucket(n)
         idx = np.zeros(nb, np.int32)
@@ -1504,6 +1528,8 @@ class ModelRunner:
         a transpose kernel (the role of block_copy.cu). Pages of another
         page size are refused (kv_transfer.foreign_pages)."""
         from dynamo_tpu.llm.kv_transfer import foreign_pages
+        for refusal in block_refusals(self.spec, kv_transfer=True):
+            raise refusal
         refusal = foreign_pages(kv.shape, self.config.page_size)
         if refusal:
             raise ValueError(refusal)
@@ -1623,8 +1649,8 @@ def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
     import jax.numpy as jnp
     from dynamo_tpu.engine.kv_quant import gather_pages_folded
     from dynamo_tpu.engine.model import (
-        embed_lookup, layer_kind, lm_logits, norm, rope_tables,
-        transformer_block, window_reach)
+        embed_lookup, latent_prefill_attention, layer_kind, lm_logits, norm,
+        scan_layers, spec_rope_tables, transformer_block, window_reach)
 
     b, s = tokens.shape
     d = spec.head_dim
@@ -1638,7 +1664,7 @@ def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
         if sp_shard:
             x = jax.lax.with_sharding_constraint(x, P(None, "sp", None))
     with perf.scope("attn.qkv"):
-        cos, sin = rope_tables(positions, d, spec.rope_theta)
+        cos, sin = spec_rope_tables(spec, positions)
     valid = jnp.arange(s)[None, :] < seq_lens[:, None]
     maxp = hist_table.shape[1]
 
@@ -1657,6 +1683,12 @@ def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
             with perf.scope("attn.kv_gather"):
                 k_hist = gather_pages_folded(k_cache, layer, hist_table)
                 v_hist = gather_pages_folded(v_cache, layer, hist_table)
+            if spec.latent:
+                # Entries and index keys of the earlier pages beside the
+                # chunk's own: the indexer chooses among both.
+                return latent_prefill_attention(
+                    q, k, v, positions, valid, spec,
+                    hist=(k_hist[0], v_hist[0], hist_lens))
 
             def heads(qg, k, v, k_hist, v_hist):
                 """qg [b,s,n,g,d], k/v [b,s,n,d], k_hist/v_hist [n,b,l,d]
@@ -1712,11 +1744,12 @@ def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
 
     xs = ((params["layers"], jnp.arange(L), lora) if lora is not None
           else (params["layers"], jnp.arange(L)))
-    x, (k_new, v_new) = jax.lax.scan(layer_fn, x, xs)
+    x, (k_new, v_new) = scan_layers(layer_fn, x, xs, spec)
     with perf.scope("kv.commit"):
-        k_blocks = (k_new.reshape(L, b * (s // page), page, nkv, d)
+        heads, (dk, dv) = spec.kv_entry
+        k_blocks = (k_new.reshape(L, b * (s // page), page, heads, dk)
                     .transpose(0, 3, 1, 2, 4))
-        v_blocks = (v_new.reshape(L, b * (s // page), page, nkv, d)
+        v_blocks = (v_new.reshape(L, b * (s // page), page, heads, dv)
                     .transpose(0, 3, 1, 2, 4))
         flat = page_table.reshape(-1)
         from dynamo_tpu.engine.kv_quant import scatter_pages

@@ -65,12 +65,16 @@ log = get_logger("flight")
 # three over the experts the device HOLDS; 0 for a dense model. An expert
 # layer that is told its share of a wider router adds "moe_local_picks"
 # ((row, choice) pairs that fell on held experts) and "moe_picks" (all
-# pairs); 0 for every other block.
+# pairs); 0 for every other block. A latent block's window adds
+# "attn_selected" (keys its live rows attended, summed over rows, layers
+# and steps on the device) and "attn_context" (keys they had in context);
+# 0 for every other block.
 FIELDS = ("t_mono", "dur_s", "active", "waiting", "free_pages",
           "chunk_tokens", "chunks_inflight", "preempts", "brownout",
           "stall_s", "step", "tokens", "period_s", "host_s", "wait_s",
           "idle_s", "rows", "page_bucket", "missed", "moe_touched",
-          "moe_load", "moe_layer_steps", "moe_local_picks", "moe_picks")
+          "moe_load", "moe_layer_steps", "moe_local_picks", "moe_picks",
+          "attn_selected", "attn_context")
 _INT_FIELDS = ("active", "waiting", "free_pages", "chunk_tokens",
                "chunks_inflight", "preempts", "brownout", "step", "tokens",
                "rows", "page_bucket", "missed")
@@ -121,7 +125,9 @@ class FlightRecorder:
                idle_s: float = 0.0, rows: int = 0,
                page_bucket: int = 0, moe_touched: float = 0.0,
                moe_load: float = 0.0, moe_layer_steps: float = 0.0,
-               moe_local_picks: float = 0.0, moe_picks: float = 0.0) -> bool:
+               moe_local_picks: float = 0.0, moe_picks: float = 0.0,
+               attn_selected: float = 0.0, attn_context: float = 0.0
+               ) -> bool:
         """One engine-window row. Idle-stable windows (no active slots,
         no waiters, no chunk work — same as the previous call) are
         skipped without touching the ring. Returns False when the row
@@ -167,6 +173,8 @@ class FlightRecorder:
             cols["moe_layer_steps"][i] = moe_layer_steps
             cols["moe_local_picks"][i] = moe_local_picks
             cols["moe_picks"][i] = moe_picks
+            cols["attn_selected"][i] = attn_selected
+            cols["attn_context"][i] = attn_context
             cols["missed"][i] = self._missed[0]
             self._missed[0] = 0
             self._idx = (i + 1) % self.capacity

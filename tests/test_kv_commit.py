@@ -52,7 +52,7 @@ def rows_at(page):
             (page - 1, 3)]
 
 
-def _case(layers, nkv, window, seed=0, page=PAGE, rows=ROWS):
+def _case(layers, nkv, window, seed=0, page=PAGE, rows=ROWS, widths=(D, D)):
     rng = np.random.default_rng(seed)
     b, maxp = len(rows), 4
     pages = 1 + b * maxp
@@ -60,10 +60,11 @@ def _case(layers, nkv, window, seed=0, page=PAGE, rows=ROWS):
     held = np.array([n for _, n in rows], np.int32)
     table = (1 + rng.permutation(pages - 1)).reshape(b, maxp).astype(np.int32)
     table[held == 0] = 0
-    pool = (layers, nkv, pages, page, D)
+    pool = (layers, nkv, pages, page)
+    buf = (layers, nkv, b, window)
     args = [jnp.asarray(rng.standard_normal(s), jnp.bfloat16)
-            for s in (pool, pool, (layers, nkv, b, window, D),
-                      (layers, nkv, b, window, D))]
+            for s in ((*pool, widths[0]), (*pool, widths[1]),
+                      (*buf, widths[0]), (*buf, widths[1]))]
     return (*args, jnp.asarray(pos), jnp.asarray(held * page),
             jnp.asarray(np.where(held > 0, pos + 1, 0).astype(np.int32)),
             jnp.asarray(table))
@@ -86,6 +87,17 @@ def test_in_place_commit_at_larger_pages_equals_the_scatter(window, page):
     tokens fall on, and the pool comes out as the scatter leaves it."""
     _commit_equals_scatter(_case(3, 2, window, page=page,
                                  rows=rows_at(page)))
+
+
+@pytest.mark.parametrize("page", [16, 64])
+@pytest.mark.parametrize("window", [4, 8])
+def test_in_place_commit_of_a_latent_pool_s_two_widths(window, page):
+    """A latent pool (ModelSpec.kv_entry): one "head", latent entries of
+    640 lanes in the first array and index keys of 128 in the second, one
+    page table: both come out as the scatter leaves them."""
+    _commit_equals_scatter(_case(
+        9, 1, window, page=page, widths=(640, 128),
+        rows=ROWS if page == PAGE else rows_at(page)))
 
 
 def _commit_equals_scatter(case):
@@ -211,7 +223,7 @@ def _picked(attention_backend, mesh_size, head_dim, quant_kv):
     runner.attention_backend = attention_backend
     runner.device = SimpleNamespace(platform="tpu")
     runner.mesh = SimpleNamespace(size=mesh_size)
-    runner.spec = SimpleNamespace(head_dim=head_dim)
+    runner.spec = SimpleNamespace(head_dim=head_dim, latent=False)
     runner.quant_kv = quant_kv
     return runner._pick_kv_commit()
 

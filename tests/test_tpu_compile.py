@@ -416,3 +416,66 @@ def test_the_pool_guard_sees_the_scatter_s_copies(v5e):
     lowered, pool = _window_program(v5e, "qwen2.5-7b", "scatter")
     found = pool_sized_ops(lowered.compile().as_text(), pool)
     assert [kind for _, kind in found].count("copy") >= 4, found
+
+
+def test_latent_window_program_commits_in_place_for_v5e(v5e):
+    """The window program of a latent pool at the DeepSeek-V3.2 cell's
+    widths (entries of 640 lanes, index keys of 128, one page table; 128
+    heads, 64 index heads, 2,048 keys kept; a leading dense layer and two
+    expert layers, narrow feed-forwards), pools donated, at the page "auto"
+    derives (64) and a table of 4,096 tokens: XLA's gather reads whole pages
+    from the row-major pools as they lie and the commit kernel rewrites the
+    touched rows of both widths, so nothing in the optimised program has
+    either pool's shape but the arguments and the commit aliased to them."""
+    from types import SimpleNamespace
+
+    from dynamo_tpu.engine.config import DeepseekV32Spec, EngineConfig
+    from dynamo_tpu.engine.model import param_shapes
+    from dynamo_tpu.engine.runner import PK_PREFIX, ModelRunner
+    spec = DeepseekV32Spec(
+        name="latent", vocab_size=1024, hidden_size=7168,
+        intermediate_size=512, num_layers=3, num_heads=128, num_kv_heads=128,
+        head_dim=192, rms_norm_eps=1e-6, num_experts=4,
+        num_experts_per_tok=8, moe_intermediate_size=256,
+        num_routed_experts=64, num_shared_experts=1, first_k_dense=1,
+        n_group=8, topk_group=4, routed_scaling_factor=2.5,
+        rope_yarn=(40.0, 4096, 32.0, 1.0, 1.0))
+    assert spec.kv_entry == (1, (640, 128))
+    runner = object.__new__(ModelRunner)
+    runner.spec = spec
+    rows, window, pages = 32, 8, 1500
+    runner.config = EngineConfig(model=spec, num_pages=pages,
+                                 max_num_seqs=rows)
+    page = runner.config.resolve_page_size("tpu")
+    assert page == 64
+    runner.config = EngineConfig(model=spec, page_size=page, num_pages=pages,
+                                 max_num_seqs=rows)
+    table = runner.config.max_pages_per_seq // 2
+    runner.device = SimpleNamespace(platform="tpu")
+    runner.mesh = SimpleNamespace(size=1)
+    runner.quant_kv, runner.lora = None, None
+    runner.experts_local = True
+    runner._window_cache = {}
+    runner._attention_impl, runner._window_attention_impl = \
+        runner._pick_attention()
+    runner.kv_commit_backend = runner._pick_kv_commit()
+    assert (runner.attention_backend, runner.kv_commit_backend) == (
+        "xla", "in_place")
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.tree.map(lambda shape: s(shape, jnp.bfloat16),
+                          param_shapes(spec),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    pools = [(3, 1, pages, page, width) for width in (640, 128)]
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    lowered = runner._get_window(window, table).lower(
+        params, *(s(pool, jnp.bfloat16) for pool in pools),
+        s((rows,), jnp.int32), s((rows, PK_PREFIX + table), jnp.int32),
+        s(key.shape, key.dtype))
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") == 1       # the commit alone
+    assert "output_to_operand_aliasing" in text
+    for pool in pools:
+        assert pool_sized_ops(text, pool) == []

@@ -127,7 +127,7 @@ def _picked(platform, mesh_size, head_dim, backend="auto", page_size=16):
     runner = object.__new__(ModelRunner)
     runner.config = SimpleNamespace(attention_backend=backend,
                                     page_size=page_size)
-    runner.spec = SimpleNamespace(head_dim=head_dim)
+    runner.spec = SimpleNamespace(head_dim=head_dim, latent=False)
     runner.device = SimpleNamespace(platform=platform)
     runner.mesh = SimpleNamespace(size=mesh_size)
     runner.quant_kv = None  # the writer's half of config.pool_access
