@@ -20,8 +20,10 @@ program's init seed), and checks what comes out by the repo's own means:
              to the kernel on the chip, and on the Cohere2-MoE block at toy
              depth and Command A+'s head geometry (128 query heads over 8
              KV heads of 128, a share of the experts, a window the longest
-             prompt passes: a page of 32 there); ``tpu_custom_call`` must be
-             in the compiled window program wherever the kernel runs
+             prompt passes: a page of 32 there), and on the DeepSeek-V3.2
+             block at toy depth over a pool of latent entries (640 | 128
+             lanes, a page of 64: the latent reader); ``tpu_custom_call``
+             must be in the compiled window program wherever a kernel runs
   disagg     prefill engine -> KV plane -> decode engine on the one chip;
              tokens must equal the aggregated engine's
 
@@ -517,8 +519,9 @@ async def phase_kernels(args, jax, rng, keep: dict):
     with bf16 and int8 KV on the served model (head_dim 64: the packed
     variant, which "auto" does not select), then what "auto" resolves to on
     a small head_dim-128 model (the kernel on one TPU device, XLA on the
-    CPU rehearsal) against "xla" on the same weights, at 4 KV heads and, on
-    the Cohere2-MoE block, at 8 KV heads under 16 query rows each. Same prompts at mixed
+    CPU rehearsal) against "xla" on the same weights, at 4 KV heads, on
+    the Cohere2-MoE block at 8 KV heads under 16 query rows each, and on the
+    DeepSeek-V3.2 block over a pool of latent entries. Same prompts at mixed
     lengths per round. Returns the served model's bf16 XLA engine (the
     disagg phase's aggregated reference)."""
     import jax.numpy as jnp
@@ -546,7 +549,23 @@ async def phase_kernels(args, jax, rng, keep: dict):
         num_routed_experts=8, first_expert=4, num_shared_experts=2,
         sliding_window=64 if args.rehearse_cpu else 256,
         sliding_window_layout=(1, 1, 1, 0), rope_layout=(1, 1, 1, 0))
-    pages = {wide.name: 64, share.name: 32}      # where "auto" derives one
+    # The DeepSeek-V3.2 block at toy depth with its pool's real widths (an
+    # entry of 512 + 64 + 64 lanes, an index key of 128; a page of 64 by the
+    # same rule), 16 heads, every expert chosen and the published 2,048
+    # keys kept, so every key in context is attended: a key that swaps
+    # sides at the indexer's rank moved these logprobs by 0.14 to 0.16 nats
+    # between two correct readers (128 keys kept; PERF.md section 6, PR 35).
+    # benchmark/selection_check.py holds the choice to its reference.
+    from dynamo_tpu.engine.config import DeepseekV32Spec
+    latent = DeepseekV32Spec(
+        name="smoke-latent", vocab_size=2048, hidden_size=512,
+        intermediate_size=256, num_layers=3, num_heads=16, num_kv_heads=16,
+        head_dim=192, rms_norm_eps=1e-6, q_lora_rank=256, index_n_heads=8,
+        num_experts=2, num_experts_per_tok=2,
+        moe_intermediate_size=128, num_routed_experts=2,
+        num_shared_experts=1, first_k_dense=1,
+        rope_yarn=(40.0, 4096, 32.0, 1.0, 1.0))
+    pages = {wide.name: 64, share.name: 32, latent.name: 64}  # derived
     lengths = (20, 70, 150) if args.rehearse_cpu else (24, 200, 700)
     n_out = 20
     on_tpu = jax.devices()[0].platform == "tpu"
@@ -555,7 +574,10 @@ async def phase_kernels(args, jax, rng, keep: dict):
             (spec, params, None, ("xla", "pallas")),
             (spec, params, "int8", ("xla", "pallas")),
             (wide, None, None, ("xla", "auto")),
-            (share, None, None, ("xla", "auto"))):
+            (share, None, None, ("xla", "auto")),
+            # The rehearsal interprets the kernel: "auto" is XLA's there.
+            (latent, None, None,
+             ("xla", "pallas" if args.rehearse_cpu else "auto"))):
         prompts = [rng.integers(2, spec_r.vocab_size, size=n).tolist()
                    for n in lengths]
         runs = {}
@@ -571,18 +593,22 @@ async def phase_kernels(args, jax, rng, keep: dict):
                                          page_size=page),
                             params=params_r)
             params_r = eng.runner.params  # the round's engines share weights
-            # What ModelRunner._pick_attention decides "auto" from.
+            # What ModelRunner._pick_attention decides "auto" from: K and V
+            # heads of 128, or a pool of latent entries.
             want = (backend if backend != "auto" else "pallas"
-                    if on_tpu and spec_r.head_dim == 128 else "xla")
+                    if on_tpu and (spec_r.head_dim == 128 or spec_r.latent)
+                    else "xla")
             resolved = eng.runner.attention_backend
             check(resolved == want,
                   f"asked for {backend}, expected {want}, runner resolved "
                   f"{resolved}")
             # The window's commit follows its reader (_pick_kv_commit): in
             # place beside the kernel on a plain bf16 pool at head_dim 128,
-            # so the third round compares it with the scatter's logprobs.
+            # so the third round compares it with the scatter's logprobs;
+            # a latent pool's is in place on a TPU under either reader.
             commit = eng.runner.kv_commit_backend
             check((commit == "in_place") == (
+                on_tpu if spec_r.latent else
                 resolved == "pallas" and spec_r.head_dim == 128
                 and quant_kv is None), f"{resolved} reader at head_dim "
                 f"{spec_r.head_dim}, {quant_kv or 'bf16'} KV: commit {commit}")

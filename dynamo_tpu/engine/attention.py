@@ -1,8 +1,11 @@
-"""Pallas TPU paged-attention decode kernel.
+"""Pallas TPU paged-attention decode kernels.
 
 The hot op of the decode step (the role block_copy.cu + engine attention
 kernels play on the reference's GPUs), and what attention_backend="auto"
-runs on one TPU device at head_dim 128. One grid program per sequence: it
+runs on one TPU device: over K and V pages at head_dim 128 (_decode_kernel,
+below), over a pool of latent entries (_latent_kernel, further down: the
+same walk and the same fetch pipeline over ONE array whose rows are key and
+value, under the indexer's choice as a mask). One grid program per sequence: it
 walks the sequence's page table (scalar-prefetched into SMEM), DMAs the
 live K/V pages HBM->VMEM in chunks, three buffers deep (pages_per_chunk pages,
 one strided copy per page across all KV heads), and accumulates
@@ -60,6 +63,19 @@ touched, all layers and KV heads of a page in one strided copy, the pools
 aliased to its outputs. Measured on one v5e (PERF.md section 6, PR 29), 18
 live rows of 32, both pools of the Qwen2.5-7B cell (2 x 2.8 GB): the
 scatter 33.7 ms a window, this 0.30 ms.
+
+The reader of a latent pool (latent_history_pallas, PR 35): the DeepSeek-V3.2
+block's decode attention in the absorbed form. What a token leaves in a
+layer is ONE entry of 640 lanes (the latent 512, the rope key 64, zeros)
+that every one of the 128 heads reads as its key, whole, and as its value,
+the first 512 lanes: the queries are the dot's 128 sublanes, a page is one
+copy of 80 KB, a chunk 8 pages. The indexer's choice of 2,048 keys a row
+(model.select_topk, XLA's) comes in as an additive bias a token, so the
+kernel walks every live page and masks; it gathers no chosen row (26 ns a
+gathered row, PERF.md section 6, PR 34). Measured on one v5e (PERF.md
+section 6, PR 35): 17 live rows of 32 slots at 3,000 to 5,000 tokens, nine
+layers: XLA's walk (the bucket of all slots gathered, read twice more)
+11.3 ms a step, this 1.7.
 """
 
 from __future__ import annotations
@@ -107,20 +123,98 @@ def pages_per_chunk(page_size: int, nkv: int, d: int, itemsize: int) -> int:
     return ppc
 
 
+def _fetch_pipeline(page_table_ref, seq_lens_ref, cur_ref, pools, sems,
+                    layer, nb, page_size: int, first_chunk):
+    """The page fetch both readers run, ONE pipeline across the whole grid:
+    chunk g (counted over the live rows' chunks in row order) lands in slot
+    g % SLOTS of every pool's buffer, and a fetch cursor in SMEM (``cur_ref``:
+    row, chunk, turn) walks the live rows' chunks SLOTS turns ahead of the
+    multiplication. ``pools``: (array in HBM [L, heads, P, ...], its buffer
+    [slot, heads, pages, ...]) pairs that share a page table, pool s on the
+    semaphores ``sems[s]``; ``first_chunk(row)``: where a live row's walk
+    starts. Returns (issue_fetch, wait_fetch, prime)."""
+    ppc = pools[0][1].shape[2]
+    chunk_tokens = ppc * page_size
+
+    def live_pages(row, chunk):
+        """How many of the chunk's ppc pages hold tokens of this row."""
+        return jnp.minimum(
+            ppc, pl.cdiv(seq_lens_ref[row], page_size) - chunk * ppc)
+
+    def start_fetch(row, chunk, slot):
+        """Per live page of the chunk ONE strided copy of its [heads, page]
+        rows from each pool, all on the slot's semaphores."""
+        def one(j, carry):
+            pid = page_table_ref[row, chunk * ppc + j]
+            for s, (hbm, buf) in enumerate(pools):
+                pltpu.make_async_copy(hbm.at[layer, :, pid],
+                                      buf.at[slot, :, j],
+                                      sems.at[s, slot]).start()
+            return carry
+
+        jax.lax.fori_loop(0, live_pages(row, chunk), one, 0)
+
+    def wait_fetch(row, chunk, slot):
+        """A DMA semaphore counts bytes, so the page copies of a slot are
+        awaited in powers of two of pages (a full chunk: one wait a pool)
+        instead of page by page; only a wait's shape and semaphore matter,
+        not where its descriptor points."""
+        count = live_pages(row, chunk)
+        bit = ppc
+        while bit:
+            @pl.when((count & bit) != 0)
+            def _(bit=bit):
+                for s, (hbm, buf) in enumerate(pools):
+                    pltpu.make_async_copy(hbm.at[layer, :, pl.ds(0, bit)],
+                                          buf.at[slot, :, pl.ds(0, bit)],
+                                          sems.at[s, slot]).wait()
+            bit //= 2
+
+    def next_live(after, searching=True):
+        """The first row past ``after`` with history, or nb."""
+        return jax.lax.while_loop(
+            lambda i: (i < nb) & (seq_lens_ref[jnp.minimum(i, nb - 1)] == 0),
+            lambda i: i + 1, jnp.where(searching, after + 1, nb))
+
+    def issue_fetch():
+        """Start the copies of the fetch cursor's chunk (turn k of the
+        grid's walk, into slot k % SLOTS) and move the cursor to the chunk
+        after it: this row's next, or the next live row's first."""
+        row, chunk, k = cur_ref[0], cur_ref[1], cur_ref[2]
+
+        @pl.when(row < nb)
+        def _():
+            start_fetch(row, chunk, jax.lax.rem(k, SLOTS))
+            more = chunk + 1 < pl.cdiv(seq_lens_ref[row], chunk_tokens)
+            nxt = next_live(row, ~more)
+            cur_ref[0] = jnp.where(more, row, nxt)
+            cur_ref[1] = jnp.where(
+                more, chunk + 1, first_chunk(jnp.minimum(nxt, nb - 1)))
+            cur_ref[2] = k + 1
+
+    def prime():
+        """The cursor at the first live row's first chunk; every slot
+        primed."""
+        first = next_live(-1)
+        cur_ref[0] = first
+        cur_ref[1] = first_chunk(jnp.minimum(first, nb - 1))
+        cur_ref[2] = 0
+        jax.lax.fori_loop(0, SLOTS, lambda _, c: (issue_fetch(), c)[1], 0)
+
+    return issue_fetch, wait_fetch, prime
+
+
 def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
                    *rest,  # [lo_ref if windowed], q2 VMEM block, k/v packed
                    # (ANY), [ks_ref, vs_ref if quantized], outputs, scratch
                    page_size: int, tpr: int, qpk: int,
                    quantized: bool = False, windowed: bool = False):
     """One grid program per batch row, all KV heads inside it. The K/V
-    fetch is ONE pipeline across the whole grid: chunk g (counted over the
-    live rows' chunks in row order) lands in slot g % SLOTS, and while
+    fetch is ONE pipeline across the whole grid (_fetch_pipeline): while
     chunk g is multiplied the chunks after it are in flight, be they this
-    row's next chunks or the next live rows' first: a fetch cursor in SMEM
-    walks the live rows' chunks SLOTS turns ahead of the multiplication, and
-    a turn that frees its buffer issues the cursor's chunk into it. A row
-    with no history costs an empty grid step; only live pages are ever
-    copied.
+    row's next chunks or the next live rows' first, and a turn that frees
+    its buffer issues the cursor's chunk into it. A row with no history
+    costs an empty grid step; only live pages are ever copied.
 
     ``windowed`` (a model with sliding-window layers): a fourth prefetched
     vector, lo [B], is the first token each row's query still sees in THIS
@@ -169,41 +263,10 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
                 k_buf[slot, h] = jnp.zeros(k_buf.shape[2:], k_buf.dtype)
                 v_buf[slot, h] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
 
-    pools = ((k_hbm, k_buf), (v_hbm, v_buf))
-
-    def live_pages(row, chunk):
-        """How many of the chunk's ppc pages hold tokens of this row."""
-        return jnp.minimum(
-            ppc, pl.cdiv(seq_lens_ref[row], page_size) - chunk * ppc)
-
-    def start_fetch(row, chunk, slot):
-        """Per live page of the chunk ONE strided copy of its [Nkv, page] K
-        rows and one of V, all on the slot's two semaphores."""
-        def one(j, carry):
-            pid = page_table_ref[row, chunk * ppc + j]
-            for s, (hbm, buf) in enumerate(pools):
-                pltpu.make_async_copy(hbm.at[layer, :, pid],
-                                      buf.at[slot, :, j],
-                                      sems.at[s, slot]).start()
-            return carry
-
-        jax.lax.fori_loop(0, live_pages(row, chunk), one, 0)
-
-    def wait_fetch(row, chunk, slot):
-        """A DMA semaphore counts bytes, so the page copies of a slot are
-        awaited in powers of two of pages (a full chunk: one wait each
-        for K and V) instead of page by page; only a wait's shape and
-        semaphore matter, not where its descriptor points."""
-        count = live_pages(row, chunk)
-        bit = ppc
-        while bit:
-            @pl.when((count & bit) != 0)
-            def _(bit=bit):
-                for s, (hbm, buf) in enumerate(pools):
-                    pltpu.make_async_copy(hbm.at[layer, :, pl.ds(0, bit)],
-                                          buf.at[slot, :, pl.ds(0, bit)],
-                                          sems.at[s, slot]).wait()
-            bit //= 2
+    issue_fetch, wait_fetch, prime = _fetch_pipeline(
+        page_table_ref, seq_lens_ref, cur_ref,
+        ((k_hbm, k_buf), (v_hbm, v_buf)), sems, layer, nb, page_size,
+        first_chunk)
 
     # token index of (row-group t, packed row r) is chunk_start + r*tpr + t
     # where t = sublane // qpk.
@@ -229,36 +292,7 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
             x = x.astype(jnp.float32).astype(dtype)
         return x.reshape(rows, 128)
 
-    def next_live(after, searching=True):
-        """The first row past ``after`` with history, or nb."""
-        return jax.lax.while_loop(
-            lambda i: (i < nb) & (seq_lens_ref[jnp.minimum(i, nb - 1)] == 0),
-            lambda i: i + 1, jnp.where(searching, after + 1, nb))
-
-    def issue_fetch():
-        """Start the copies of the fetch cursor's chunk (turn k of the
-        grid's walk, into slot k % SLOTS) and move the cursor to the chunk
-        after it: this row's next, or the next live row's first."""
-        row, chunk, k = cur_ref[0], cur_ref[1], cur_ref[2]
-
-        @pl.when(row < nb)
-        def _():
-            start_fetch(row, chunk, jax.lax.rem(k, SLOTS))
-            more = chunk + 1 < pl.cdiv(seq_lens_ref[row], chunk_tokens)
-            nxt = next_live(row, ~more)
-            cur_ref[0] = jnp.where(more, row, nxt)
-            cur_ref[1] = jnp.where(
-                more, chunk + 1, first_chunk(jnp.minimum(nxt, nb - 1)))
-            cur_ref[2] = k + 1
-
-    @pl.when(b == 0)
-    def _():
-        # The cursor at the first live row's first chunk; every slot primed.
-        first = next_live(-1)
-        cur_ref[0] = first
-        cur_ref[1] = first_chunk(jnp.minimum(first, nb - 1))
-        cur_ref[2] = 0
-        jax.lax.fori_loop(0, SLOTS, lambda _, c: (issue_fetch(), c)[1], 0)
+    pl.when(b == 0)(prime)
 
     def body(c, carry, g0):
         slot = jax.lax.rem(g0 + c - chunk0, SLOTS)
@@ -535,6 +569,157 @@ def paged_window_attention_pallas(q: jax.Array, k_cache: jax.Array,
          jnp.ones((b, 1), bool)], axis=1)[:, None, None, :]
     return _merge_extra(q, num, l_star, m_s, k_extra, v_extra, col_mask,
                         q_per_kv)
+
+
+def _latent_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
+                   q_ref, bias_ref, e_hbm,     # VMEM blocks; the pool (ANY)
+                   acc_ref, m_ref, l_ref,      # outputs
+                   e_buf, sems, g_ref, cur_ref,  # scratch
+                   *, page_size: int, scale: float):
+    """The reader of a LATENT pool, one grid program per batch row: every
+    head's absorbed query (q_ref [1, Nh, width]: the dot's left side, Nh
+    sublanes) against the row's live pages of latent entries, which are key
+    AND value: ONE copy a page into e_buf [slot, 1, pages, page, width]
+    through _fetch_pipeline, the score over the whole row (the query's
+    padding lanes are zeros), the value the row's first lanes, as many as
+    acc_ref [1, Nh, value lanes] is wide: a slice of the buffer at a lane
+    tile's edge. Which keys a row attends comes in as bias_ref [1, chunks,
+    1, chunk tokens] float32, 0 at an attended key and NEG_INF at every other
+    (the indexer's choice, or every key in context; nothing past the row's
+    length is ever attended), so the kernel walks every LIVE page and masks:
+    it compares no token index and gathers no chosen row. Out come the
+    unnormalised sum and the running maximum and sum, for XLA to merge with
+    the window's own columns and the self token."""
+    ppc = e_buf.shape[2]
+    width, value = e_buf.shape[4], acc_ref.shape[2]
+    b = pl.program_id(0)
+    nb = pl.num_programs(0)
+    chunk_tokens = ppc * page_size
+    num_chunks = pl.cdiv(seq_lens_ref[b], chunk_tokens)
+    issue_fetch, wait_fetch, prime = _fetch_pipeline(
+        page_table_ref, seq_lens_ref, cur_ref, ((e_hbm, e_buf),), sems,
+        layer_ref[0], nb, page_size, lambda row: 0)
+
+    @pl.when(b == 0)
+    def _():
+        # Finite entries under the masked columns of a chunk's unfetched
+        # tail, as _decode_kernel has them.
+        g_ref[0] = 0
+        for slot in range(SLOTS):
+            e_buf[slot] = jnp.zeros(e_buf.shape[1:], e_buf.dtype)
+        prime()
+
+    nh = q_ref.shape[1]
+    q = q_ref[0]
+    acc_ref[0] = jnp.zeros((nh, value), jnp.float32)
+
+    def body(c, carry, g0):
+        m, l = carry
+        slot = jax.lax.rem(g0 + c, SLOTS)
+        wait_fetch(b, c, slot)
+        e = e_buf[slot, 0].reshape(chunk_tokens, width)
+        scores = jax.lax.dot_general(
+            q, e, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale + bias_ref[0, c]
+        m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+        p = jnp.exp(scores - m_new)
+        alpha = jnp.exp(m - m_new)
+        v = e_buf[slot, 0, :, :, :value].reshape(chunk_tokens, value)
+        acc_ref[0] = acc_ref[0] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        issue_fetch()
+        return m_new, l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+
+    g0 = g_ref[0]
+    m, l = jax.lax.fori_loop(
+        0, num_chunks, functools.partial(body, g0=g0),
+        (jnp.full((nh, 1), NEG_INF, jnp.float32),
+         jnp.zeros((nh, 1), jnp.float32)))
+    g_ref[0] = g0 + num_chunks
+    m_ref[0] = jnp.broadcast_to(m, m_ref.shape[1:])
+    l_ref[0] = jnp.broadcast_to(l, l_ref.shape[1:])
+
+
+# dtpu: ignore[unregistered-jit] -- inner kernel: only ever traced INSIDE registered runner programs (inlined), never dispatched standalone from the serving loop
+@functools.partial(jax.jit, static_argnames=("scale", "rank", "interpret"))
+def _latent_flash(qe, e_cache, layer, page_table, hist_lens, bias,
+                  scale: float, rank: int, interpret: bool):
+    """_latent_kernel over bias [B, chunks, 1, chunk tokens]. Its own jit:
+    callers whose operands have one shape share ONE trace of the kernel
+    (latent_history_pallas)."""
+    b, nh, width = qe.shape
+    page_size = e_cache.shape[3]
+    _, chunks, _, tokens = bias.shape
+    value = pl.cdiv(rank, 128) * 128    # whole lane tiles of the entry
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, nh, width), lambda i, *_: (i, 0, 0)),
+                  pl.BlockSpec((1, chunks, 1, tokens),
+                               lambda i, *_: (i, 0, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=(pl.BlockSpec((1, nh, value), lambda i, *_: (i, 0, 0)),)
+        + (pl.BlockSpec((1, nh, 128), lambda i, *_: (i, 0, 0)),) * 2,
+        scratch_shapes=[
+            pltpu.VMEM((SLOTS, 1, tokens // page_size, page_size, width),
+                       e_cache.dtype),
+            pltpu.SemaphoreType.DMA((1, SLOTS)),
+            pltpu.SMEM((1,), jnp.int32), pltpu.SMEM((3,), jnp.int32)],
+    )
+    stat = jax.ShapeDtypeStruct((b, nh, 128), jnp.float32)
+    acc, m, l = pl.pallas_call(
+        functools.partial(_latent_kernel, page_size=page_size, scale=scale),
+        grid_spec=grid_spec,
+        out_shape=(jax.ShapeDtypeStruct((b, nh, value), jnp.float32),
+                   stat, stat),
+        # Sequential: the fetch pipeline runs from one row into the next.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), page_table, hist_lens,
+      qe, bias, e_cache)
+    return acc[..., :rank], m[..., 0], l[..., 0]
+
+
+def latent_history_pallas(qe: jax.Array, e_cache: jax.Array,
+                          layer: jax.Array, page_table: jax.Array,
+                          hist_lens: jax.Array, keep: jax.Array,
+                          scale: float, rank: int, interpret: bool = False,
+                          table: int | None = None):
+    """Flash attention of the absorbed queries qe [B, Nh, width] over the
+    cache-resident history of a latent pool e_cache [L, 1, P, page, width]
+    (the FULL stacked pool: the kernel copies pages of ``layer``), keys
+    where ``keep`` [B, maxP * page] (bool; nothing at or past hist_lens [B])
+    says so, scores times ``scale``, the value an entry's first ``rank``
+    lanes. Returns (unnormalised sum [B, Nh, rank], maximum [B, Nh], sum
+    [B, Nh]), float32: what model.latent_window_attention merges with the
+    columns that are not in the pool yet. ``interpret`` as in
+    paged_decode_attention_pallas.
+
+    ``table`` (pages): the widest page table its caller ever passes. The
+    table and the mask are padded to it, so the window programs of every
+    page-table bucket and both of a program's layer scans call ONE kernel
+    and trace it once a process: a trace of the kernel took 0.3 s on the
+    benchmark's host, twice a program, thirteen programs a start-up
+    (PERF.md section 6, PR 35). The kernel walks live pages alone, so the
+    width costs it a mask block of 4 B a token of the table a row."""
+    b = qe.shape[0]
+    page_size, width = e_cache.shape[3], e_cache.shape[4]
+    # An entry is key and value in one: a chunk holds the bytes of a K chunk
+    # and a V chunk together (8 pages of 64 x 640: 512 tokens; on one v5e
+    # the walk took 2.52 ms a step at 4 pages, 1.94 at 8 and 1.84 at 16).
+    ppc = pages_per_chunk(page_size, 1, width // 2, e_cache.dtype.itemsize)
+    tokens = ppc * page_size
+    maxp = page_table.shape[1]
+    pages = max(table or 0, maxp)
+    chunks = pl.cdiv(pages * page_size, tokens)
+    bias = jnp.where(keep, 0.0, NEG_INF).astype(jnp.float32)
+    bias = jnp.pad(bias, ((0, 0), (0, chunks * tokens - keep.shape[1])),
+                   constant_values=NEG_INF).reshape(b, chunks, 1, tokens)
+    page_table = jnp.pad(page_table, ((0, 0), (0, pages - maxp)))
+    return _latent_flash(qe, e_cache, layer, page_table, hist_lens, bias,
+                         scale=scale, rank=rank, interpret=interpret)
 
 
 def _commit_kernel(pid_ref, r0_ref, m0_ref, n_ref,  # SMEM prefetch, [B*J]

@@ -839,55 +839,66 @@ def pool_access(attention_backend: str, platform: str, mesh_size: int,
     (QuantKV: tiles of 32 rows, and the scales are a second array).
 
     A ``latent`` pool (ModelSpec.kv_entry: one latent entry of 640 lanes
-    and one index key of 128 a token a layer, under one page table): the
-    reader is XLA's (model.latent_window_attention gathers a row's pages
-    of index keys, scores them, and reads the latent entries it chose); no
-    Pallas kernel walks such a pool yet, so "auto" never resolves to one
-    and a requested one is the runner's to refuse. The writer on one TPU
-    device is still "in_place": XLA's gather takes whole [page, width]
-    blocks by their leading indices from a row-major pool as it lies, so
-    nothing converts the pool, and the commit kernel moves rows of any
-    lane-dense width."""
-    if latent:
-        reader = "xla" if attention_backend == "auto" else attention_backend
-        in_place = (reader == "xla" and platform == "tpu" and mesh_size == 1
-                    and quant_kv is None)
-        return reader, "in_place" if in_place else "scatter"
-    plain = mesh_size == 1 and head_dim == 128
+    and one index key of 128 a token a layer, under one page table), under
+    "auto": the Pallas kernel (attention.latent_history_pallas) on one TPU
+    device over bfloat16 entries, XLA's walk everywhere else (the CPU, any
+    mesh). XLA's walk gathers the page-table bucket of the LONGEST row for
+    all slots (32 x 5,120 tokens x 1,280 B a layer) and reads the copy
+    twice more: 8.0 ms of a 21.6 ms decode step on one v5e at 17 live rows
+    of 32 (PERF.md section 5, PR 34); the kernel reads a row's live pages
+    once, one copy a page, with the indexer's choice as its mask (PERF.md
+    section 6, PR 35). The indexer (a row's index keys gathered over the
+    bucket, scored, the choice) is XLA's under either reader. A requested
+    backend is returned as asked. The writer on one TPU device is
+    "in_place" under either reader: both read the row-major pool as it
+    lies (XLA's gather takes whole [page, width] blocks by their leading
+    indices), so nothing converts the pool, and the commit kernel moves
+    rows of any lane-dense width."""
+    # What the kernel of this pool can walk: bfloat16 entries, or K and V
+    # heads of 128; on one device either way.
+    plain = mesh_size == 1 and (quant_kv is None if latent
+                                else head_dim == 128)
     reader = attention_backend
     if reader == "auto":
         reader = "pallas" if platform == "tpu" and plain else "xla"
-    in_place = reader == "pallas" and plain and quant_kv is None
+    if latent:
+        in_place = platform == "tpu" and plain
+    else:
+        in_place = reader == "pallas" and plain and quant_kv is None
     return reader, "in_place" if in_place else "scatter"
 
 
-#: Tokens by which the XLA reader's page-table bucket grows past its first
-#: two steps (window_page_bucket).
+#: Tokens by which a page-table bucket that XLA gathers whole grows past its
+#: first two steps (window_page_bucket).
 XLA_BUCKET_TOKENS = 1024
 
 
 def window_page_bucket(needed: int, reader: str, page_size: int,
-                       max_pages: int) -> int:
+                       max_pages: int, latent: bool = False) -> int:
     """Page-table width of the decode window whose longest row holds
-    ``needed`` pages, by who reads the pool (``pool_access``'s reader): a
-    power of two from 8 up to ``max_pages``. The XLA reader gathers the
-    bucket of EVERY slot whatever the rows hold, so its time follows the
-    bucket and not the rows: past two steps of XLA_BUCKET_TOKENS its
-    buckets are multiples of that step, and a step's time follows the
-    longest row within 1,024 tokens. The Pallas kernel walks a row's live
-    pages alone and pays nothing for a wide table: its buckets stay powers
-    of two (fewer programs). Measured where the XLA reader serves a cell, a
-    latent pool on one v5e over six seeds each (PERF.md section 6, PR 34):
-    at powers of two a step was a third longer from the moment one row
-    passed 4,096 tokens (`out_tok_s` 652); steps of 1,024 tokens 739, of
-    512 tokens 757 for six more window programs to compile and no steadier
-    a median time per token. Not measured over K and V pages (no cell is
-    on the XLA side there): the gather's cost by bucket is PR 26's."""
+    ``needed`` pages, by who still pays for the bucket: a power of two from
+    8 up to ``max_pages``. An XLA gather reads the bucket of EVERY slot
+    whatever the rows hold, so its time follows the bucket and not the
+    rows: past two steps of XLA_BUCKET_TOKENS such a bucket is a multiple
+    of that step, and a step's time follows the longest row within 1,024
+    tokens. That is the XLA ``reader`` (``pool_access``'s) of any pool, and
+    a ``latent`` pool under EITHER reader while its indexer is XLA's: the
+    Pallas reader walks a row's live entries alone, but the index keys are
+    still gathered over the bucket (32 slots x bucket x 256 B a layer) and
+    ``select_topk`` counts over it 32 times. The Pallas kernel over K and V
+    pages pays nothing for a wide table: its buckets stay powers of two
+    (fewer programs). Measured where XLA's walk served a latent pool on one
+    v5e over six seeds each (PERF.md section 6, PR 34): at powers of two a
+    step was a third longer from the moment one row passed 4,096 tokens
+    (`out_tok_s` 652); steps of 1,024 tokens 739, of 512 tokens 757 for six
+    more window programs to compile and no steadier a median time per
+    token. Not measured over K and V pages (no cell is on the XLA side
+    there): the gather's cost by bucket is PR 26's."""
     b = 8
     while b < needed and b < max_pages:
         b *= 2
     step = max(8, XLA_BUCKET_TOKENS // page_size)
-    if reader == "xla" and b > 2 * step:
+    if (reader == "xla" or latent) and b > 2 * step:
         b = -(-needed // step) * step
     return min(b, max_pages)
 
@@ -1102,7 +1113,7 @@ class EngineConfig:
         ONE strided copy across the KV heads moves PAGE_COPY_BYTES, within
         [DEFAULT_PAGE_SIZE, MAX_PAGE_SIZE]: 64 tokens at 4 KV heads of 128
         in bfloat16 (Qwen2.5-7B, SmallThinker), 32 at 8 (Llama-3-8B), 64 at
-        a latent entry of 640 (whose XLA gather takes a page a slice). The
+        a latent entry of 640 (one copy of 80 KB a page for its reader). The
         kernel issues a copy a live page a layer from a scalar loop, and a
         chunk turn waits for the issue of the next chunk's copies (PERF.md
         section 6, PR 31). Everywhere else DEFAULT_PAGE_SIZE: the XLA

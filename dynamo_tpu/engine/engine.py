@@ -1061,8 +1061,10 @@ class TPUEngine(AsyncEngine):
             },
             "hbm": self.runner.hbm_stats(),
             "memory": self.runner.memory_breakdown(),
-            # Static per runner: how the window program writes the pool
-            # (runner._pick_kv_commit).
+            # Static per runner: who reads the pool in decode and how the
+            # window program writes it (runner._pick_attention,
+            # _pick_kv_commit: config.pool_access).
+            "attention_backend": self.runner.attention_backend,
             "kv_commit_backend": self.runner.kv_commit_backend,
             # Tokens a KV page holds (config.resolve_page_size): over 16
             # where the page was derived for the Pallas reader.

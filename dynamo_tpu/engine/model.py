@@ -1032,7 +1032,8 @@ def latent_window_attention(q: LatentQuery, e_cache: jax.Array,
                             page_table: jax.Array, hist_lens: jax.Array,
                             e_win: jax.Array, i_win: jax.Array, m: jax.Array,
                             e_self: jax.Array, i_self: jax.Array,
-                            spec: ModelSpec, live: jax.Array | None = None):
+                            spec: ModelSpec, live: jax.Array | None = None,
+                            reader=None):
     """Decode attention of the latent block for step ``m`` of a window, in
     the ABSORBED form: a head's query is folded through Wk_b into the
     latent's space (qa_h = q_nope_h Wk_b[h]^T), scores and the weighted
@@ -1045,9 +1046,17 @@ def latent_window_attention(q: LatentQuery, e_cache: jax.Array,
     i_self [B, 1, .] this token. Keys come from the three places
     ``paged_window_attention_xla`` reads. Where the page-table bucket can
     hold more than ``index_topk`` keys the indexer scores every key in
-    context and the row attends the index_topk of largest score. Returns
-    (attention [B, Nh * v_head_dim], float32 [2]: keys attended and keys
-    in context, summed over the ``live`` rows)."""
+    context and the row attends the index_topk of largest score.
+
+    Who reads the pool's entries (config.pool_access): ``reader``
+    (attention.latent_history_pallas, on one TPU device) walks each row's
+    live pages once with the choice as its mask, and hands back a running
+    maximum, sum and weighted sum that are merged here with the window's
+    columns and the self token; None (the CPU, any mesh) is XLA's walk,
+    which gathers the whole bucket of every slot and reads the copy twice
+    more. The indexer is XLA's under either. Returns (attention
+    [B, Nh * v_head_dim], float32 [2]: keys attended and keys in context,
+    summed over the ``live`` rows)."""
     b = hist_lens.shape[0]
     nh, r = spec.num_heads, spec.kv_lora_rank
     page = e_cache.shape[3]
@@ -1067,8 +1076,14 @@ def latent_window_attention(q: LatentQuery, e_cache: jax.Array,
                 [index_scores(q.iq[:, None], q.iw[:, None], keys)[:, 0]
                  for keys in (ki, i_win[0], i_self)], axis=-1)
             chosen = select_topk(score, seen, spec.index_topk)
-    with scope("attn.kv_gather"):
-        e_all = gather_pages_folded(e_cache, layer, page_table)[0]
+    # The places XLA scores itself: the window's columns and the self token,
+    # and without a reader the gathered history ahead of them.
+    parts = (e_win[0], e_self)
+    if reader is None:
+        with scope("attn.kv_gather"):
+            parts = (gather_pages_folded(e_cache, layer, page_table)[0],
+                     *parts)
+    kept = jnp.split(chosen, [hist, hist + M], axis=-1)[-len(parts):]
     with scope("attn.core"):
         wk, sk = _weight(q.wk_b, nh)
         wv, sv = _weight(q.wv_b, nh)
@@ -1076,21 +1091,23 @@ def latent_window_attention(q: LatentQuery, e_cache: jax.Array,
             q.nope.astype(jnp.float32) * sk).astype(q.nope.dtype)
         qa = jnp.einsum("bhd,rhd->bhr", nope, wk,
                         preferred_element_type=jnp.bfloat16)
-        width = e_all.shape[-1]
+        width = e_cache.shape[-1]
         qe = jnp.concatenate(
             [qa, q.rope, jnp.zeros((b, nh, width - r - q.rope.shape[-1]),
                                    qa.dtype)], axis=-1)      # [B, Nh, width]
-        # The three places' scores are never joined: a softmax over their
+        # The places' scores are never joined: a softmax over their
         # concatenation copies [B, Nh, bucket] float32 scores twice more
         # than the running maximum and sum below (PERF.md section 6, PR 34).
-        parts = (e_all, e_win[0], e_self)
-        kept = jnp.split(chosen, [hist, hist + M], axis=-1)
         scores = [jnp.where(keep[:, None, :], jnp.einsum(
             "bhe,bke->bhk", qe, e, preferred_element_type=jnp.float32)
             * spec.attn_scale, -1e30) for keep, e in zip(kept, parts)]
-        top = functools.reduce(jnp.maximum,
-                               [jnp.max(sc, axis=-1, initial=-1e30)
-                                for sc in scores])
+        tops = [jnp.max(sc, axis=-1, initial=-1e30) for sc in scores]
+        if reader is not None:
+            ctx_h, top_h, total_h = reader(
+                qe, e_cache, layer, page_table, hist_lens,
+                chosen[:, :hist], spec.attn_scale, r)
+            tops.append(top_h)
+        top = functools.reduce(jnp.maximum, tops)
         weights = [jnp.exp(sc - top[..., None]) for sc in scores]
         total = sum(jnp.sum(w, axis=-1) for w in weights)
         # Over the whole entry, the latent cut out of the SUM: a slice of
@@ -1098,6 +1115,10 @@ def latent_window_attention(q: LatentQuery, e_cache: jax.Array,
         ctx = sum(jnp.einsum("bhk,bke->bhe", w.astype(qe.dtype), e,
                              preferred_element_type=jnp.float32)
                   for w, e in zip(weights, parts))[..., :r]
+        if reader is not None:
+            w_h = jnp.exp(top_h - top)
+            total = total + total_h * w_h
+            ctx = ctx + ctx_h * w_h[..., None]
         ctx = ctx / total[..., None]
         out = jnp.einsum("bhr,rhd->bhd", ctx.astype(qe.dtype), wv,
                          preferred_element_type=jnp.float32)
@@ -1540,7 +1561,7 @@ def decode_forward(params: Params, spec: ModelSpec,
                 return latent_window_attention(
                     q, k_cache, v_cache, layer, page_table, hist_lens,
                     k[None, :, :0], v[None, :, :0], jnp.asarray(0, jnp.int32),
-                    k, v, spec)[0]
+                    k, v, spec, reader=attention_impl)[0]
             attn = attn_fn(q, k_cache, v_cache, layer, page_table, hist_lens,
                            k, v, spec.q_per_kv,
                            lo=window_lo(spec, kind, positions))  # [B,Nh,D]
@@ -1746,7 +1767,7 @@ def decode_window_step(params: Params, spec: ModelSpec,
             if spec.latent:     # owns its scopes; counts the live rows' keys
                 return latent_window_attention(
                     q, k_cache, v_cache, layer, page_table, hist_lens, kb_l,
-                    vb_l, m, k, v, spec, live)
+                    vb_l, m, k, v, spec, live, reader=attention_impl)
             with scope("attn.core"):
                 attn = attn_fn(q, k_cache, v_cache, layer, page_table,
                                hist_lens, kb_l, vb_l, m, k, v, spec.q_per_kv,

@@ -713,6 +713,12 @@ class PerfMetricsUpdater:
             "rewritten where they lie, beside the Pallas reader) or "
             "scatter (XLA's scatter; pool-sized layout copies on a TPU)",
             ["backend"])
+        self.g_attention = registry.gauge(
+            "perf_attention_info", "1 under the label of who reads this "
+            "worker's KV pool in decode (runner.attention_backend, "
+            "config.pool_access): pallas (a kernel walks a row's live "
+            "pages: K and V heads, or latent entries) or xla (the gather "
+            "of every slot's page-table bucket)", ["backend"])
         self.g_kv_page = registry.gauge(
             "perf_kv_page_info", "1 under the label of how many tokens a "
             "KV page of this worker holds (runner.page_size): 16, or the "
@@ -816,6 +822,9 @@ class PerfMetricsUpdater:
         backend = getattr(runner, "kv_commit_backend", None)
         if backend:
             self.g_kv_commit.set(1, backend=backend)
+        reader = getattr(runner, "attention_backend", None)
+        if reader:
+            self.g_attention.set(1, backend=reader)
         page = getattr(runner, "page_size", None)
         if page:
             self.g_kv_page.set(1, tokens=str(page))

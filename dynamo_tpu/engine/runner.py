@@ -437,16 +437,19 @@ class ModelRunner:
         return stats
 
     def _pallas_refusal(self) -> str | None:
-        """Why the Pallas kernel cannot run this model on this mesh, or
+        """Why no Pallas kernel can read this model's pool on this mesh, or
         None: what "pallas" raises with and what "auto" decides from."""
         d = self.spec.head_dim
         page = self.config.page_size
         if self.spec.latent:
-            return ("walks K and V pages of one head_dim; no kernel reads a "
-                    "latent pool (an entry of 640 lanes chosen by index "
-                    "scores over keys of 128)")
-        if not (d == 128 or (d < 128 and 128 % d == 0
-                             and (page * d) % 128 == 0)):
+            # attention.latent_history_pallas: an entry is key and value,
+            # whatever the heads' own widths.
+            if self.quant_kv is not None:
+                return ("walks a latent pool of bfloat16 entries; no kernel "
+                        "reads int8 latent pages (an entry's scales would "
+                        "be a third array under the page table)")
+        elif not (d == 128 or (d < 128 and 128 % d == 0
+                               and (page * d) % 128 == 0)):
             return (f"needs head_dim 128, or a head_dim that packs into 128 "
                     f"lanes (128 % head_dim == 0 and page_size*head_dim % "
                     f"128 == 0); got head_dim {d}, page_size {page}")
@@ -460,8 +463,11 @@ class ModelRunner:
         """Returns (single-step impl, window impl). A requested backend is
         what runs: "pallas" that cannot be had is an error, never XLA.
         "auto" is decided from what the runner observes, nothing else: the
-        platform, the mesh's size and the head dimension
-        (config.pool_access, which has the measurements)."""
+        platform, the mesh's size, the head dimension and what a token
+        leaves in the pool (config.pool_access, which has the
+        measurements). A latent pool has ONE reader for the step and the
+        window (model.latent_window_attention's ``reader``): the kernel,
+        or None for XLA's walk."""
         from dynamo_tpu.engine.model import paged_window_attention_xla
         backend, _ = pool_access(
             self.config.attention_backend, self.device.platform,
@@ -469,6 +475,8 @@ class ModelRunner:
             self.spec.latent)
         self.attention_backend = backend
         if backend == "xla":
+            if self.spec.latent:
+                return None, None
             return paged_decode_attention_xla, paged_window_attention_xla
         if backend != "pallas":
             raise ValueError(f"attention_backend must be 'auto', 'xla' or "
@@ -477,10 +485,15 @@ class ModelRunner:
         if refusal is not None:
             raise ValueError(f"attention_backend='pallas' {refusal}")
         from dynamo_tpu.engine.attention import (
-            paged_decode_attention_pallas, paged_window_attention_pallas)
+            latent_history_pallas, paged_decode_attention_pallas,
+            paged_window_attention_pallas)
         # Interpret mode exists for the CPU backend only; a chip compiles
         # the kernel through Mosaic or fails.
         interpret = self.device.platform == "cpu"
+        if self.spec.latent:
+            return (functools.partial(
+                latent_history_pallas, interpret=interpret,
+                table=self.config.max_pages_per_seq),) * 2
         return (functools.partial(paged_decode_attention_pallas,
                                   interpret=interpret),
                 functools.partial(paged_window_attention_pallas,
@@ -808,7 +821,8 @@ class ModelRunner:
         donate = (1, 2, 6) if penalized else (1, 2)
         fn = perf.instrumented_jit(
             "decode_window", run_window, key=key, donate_argnums=donate,
-            labels={"kv_commit_backend": self.kv_commit_backend,
+            labels={"attention_backend": self.attention_backend,
+                    "kv_commit_backend": self.kv_commit_backend,
                     "page_size": self.config.page_size})
         self._window_cache[key] = fn
         return fn
@@ -1282,10 +1296,12 @@ class ModelRunner:
 
     def bucket_pages_for(self, needed: int) -> int:
         """Page-table width bucket for the decode window
-        (config.window_page_bucket, by the reader this runner resolved)."""
+        (config.window_page_bucket, by the reader this runner resolved and
+        by what its pool holds)."""
         return window_page_bucket(needed, self.attention_backend,
                                   self.config.page_size,
-                                  self.config.max_pages_per_seq)
+                                  self.config.max_pages_per_seq,
+                                  latent=self.spec.latent)
 
     def decode_window(self, packed: np.ndarray, window: int):
         """Dispatch one M-step decode window.

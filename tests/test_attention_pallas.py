@@ -391,3 +391,94 @@ def test_pages_per_chunk_follows_page_bytes(shape, want):
 def test_pallas_rejects_unpackable_head_dim():
     with pytest.raises(AssertionError):
         _both(_case(48, b=2, nkv=1, qpk=2, maxp=4, seq_lens=[8, 8]), qpk=2)
+
+
+# -- the reader of a latent pool ------------------------------------------------
+
+#: The DeepSeek-V3.2 cell's widths (128 heads, entries of 640 lanes of which
+#: 512 are the value, index keys of 128 scored by 64 heads, 2,048 keys kept,
+#: a page of 64), cut in batch and table only: four rows, a table of 512
+#: tokens (under index_topk: every key in context is attended) or of 2,560
+#: (over it: the indexer chooses).
+LATENT_PAGE, LATENT_WINDOW = 64, 8
+LATENT_CASES = [
+    (table, form, m, weights)
+    for table in (8, 40) for weights in ("bf16", "int8")
+    for form, m in (("window", 0), ("window", LATENT_WINDOW - 1),
+                    ("decode_forward", 0))]
+
+
+@pytest.mark.parametrize("table, form, m, weights", LATENT_CASES)
+def test_latent_reader_matches_the_xla_walk(table, form, m, weights):
+    """attention.latent_history_pallas (interpreted) as
+    model.latent_window_attention's ``reader`` against XLA's walk (reader
+    None), as the window step calls it (eight window columns of which
+    ``m`` are written) and as decode_forward does (no window columns): rows
+    without history, with one token, at a page's edge and at the bucket's
+    end in one batch, the table and the mask padded to the widest table the
+    caller has; the attention and the counts [attended, in context]."""
+    import functools
+
+    import jax
+
+    from dynamo_tpu.engine import model
+    from dynamo_tpu.engine.attention import latent_history_pallas
+    from dynamo_tpu.engine.config import DeepseekV32Spec
+    from dynamo_tpu.engine.quant import quantize_weight
+    spec = DeepseekV32Spec(
+        name="latent", vocab_size=64, hidden_size=64, intermediate_size=64,
+        num_layers=2, num_heads=128, num_kv_heads=128, head_dim=192,
+        num_experts=4, num_experts_per_tok=2, moe_intermediate_size=64,
+        num_routed_experts=4, num_shared_experts=1,
+        rope_yarn=(40.0, 4096, 32.0, 1.0, 1.0))
+    assert spec.kv_entry == (1, (640, 128)) and spec.index_topk == 2048
+    page, M = LATENT_PAGE, LATENT_WINDOW if form == "window" else 0
+    selecting = table * page + M + 1 > spec.index_topk
+    assert selecting == (table == 40)
+    hist_lens = [0, 1, 3 * page, table * page]
+    b, nh, L = len(hist_lens), spec.num_heads, 2
+    rng = np.random.default_rng(table + m)
+    pages = b * table + 2
+
+    def normal(*shape, scale=1.0, dtype=jnp.bfloat16):
+        return jnp.asarray(rng.standard_normal(shape) * scale, dtype)
+
+    e_cache = normal(L, 1, pages, page, 640, scale=0.3)
+    e_cache = e_cache.at[..., 576:].set(0)      # an entry's padding lanes
+    i_cache = normal(L, 1, pages, page, 128)
+    pt = np.stack([rng.permutation(np.arange(1, pages - 1))[:table]
+                   for _ in range(b)]).astype(np.int32)
+    matrices = [rng.standard_normal((512, nh * 128)).astype(np.float32) * 0.05
+                for _ in range(2)]
+    if weights == "int8":
+        wk_b, wv_b = (jax.tree.map(jnp.asarray, quantize_weight(w))
+                      for w in matrices)
+    else:
+        wk_b, wv_b = (jnp.asarray(w, jnp.bfloat16) for w in matrices)
+    q = model.LatentQuery(
+        nope=normal(b, nh, 128), rope=normal(b, nh, 64),
+        iq=normal(b, 64, 128), iw=normal(b, 64, dtype=jnp.float32),
+        wk_b=wk_b, wv_b=wv_b)
+    args = (q, e_cache, i_cache, jnp.asarray(1, jnp.int32), jnp.asarray(pt),
+            jnp.asarray(hist_lens, jnp.int32),
+            normal(1, b, M, 640, scale=0.3), normal(1, b, M, 128),
+            jnp.asarray(m, jnp.int32), normal(b, 1, 640, scale=0.3),
+            normal(b, 1, 128))
+    live = jnp.asarray([False, True, True, True])
+
+    def run(reader):
+        return jax.jit(lambda *a: model.latent_window_attention(
+            *a, spec, live, reader=reader))(*args)
+
+    want, want_counts = run(None)
+    # As the runner binds it: one kernel for every table up to 48 pages.
+    got, got_counts = run(functools.partial(latent_history_pallas,
+                                            interpret=True, table=48))
+    np.testing.assert_array_equal(np.asarray(got_counts),
+                                  np.asarray(want_counts))
+    context = sum(hist_lens[1:]) + 3 * (m + 1)
+    assert float(want_counts[1]) == context
+    assert (float(want_counts[0]) < context) == selecting
+    want, got = (np.asarray(x, np.float32) for x in (want, got))
+    assert np.abs(want).max() > 0.05
+    np.testing.assert_allclose(got, want, atol=0.01, rtol=0.03)

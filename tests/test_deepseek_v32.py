@@ -161,7 +161,7 @@ def test_the_cut_holds_16_of_256_experts_and_8_44_gb():
     assert config.kv_token_bytes() == 9 * 768 * 2 == 13824
     assert config.resolve_page_size("tpu") == 64
     assert config.resolve_page_size("cpu") == 16
-    assert pool_access("auto", "tpu", 1, 192, None, True) == ("xla",
+    assert pool_access("auto", "tpu", 1, 192, None, True) == ("pallas",
                                                               "in_place")
     assert pool_access("auto", "cpu", 1, 192, None, True) == ("xla",
                                                               "scatter")
@@ -255,32 +255,103 @@ def test_parcels_checkpoints_and_embeddings_are_refused():
         runner.extract_pages([1])
     with pytest.raises(UnsupportedBlockError, match="KV parcel"):
         runner.insert_pages(np.zeros((2, 3, 1, 1, 4, 16), np.float32), [1])
-    with pytest.raises(ValueError, match="no kernel reads a latent pool"):
-        ModelRunner(EngineConfig(model=spec, page_size=4, num_pages=16,
-                                 attention_backend="pallas"))
 
 
-def test_the_xla_reader_s_page_table_buckets_grow_by_1024_tokens():
-    """XLA's walk gathers the whole bucket of every slot, so past 2,048
-    tokens its buckets are multiples of 1,024 tokens where the kernel's,
-    which walks live pages alone, stay powers of two; a latent pool's reader
-    is XLA's (config.pool_access), so the rule is its by the reader."""
+@pytest.mark.parametrize("backend, platform, mesh, quant_kv, want", [
+    ("auto", "tpu", 1, None, ("pallas", "in_place")),
+    ("auto", "cpu", 1, None, ("xla", "scatter")),
+    ("auto", "tpu", 4, None, ("xla", "scatter")),
+    ("auto", "tpu", 1, "int8", ("xla", "scatter")),
+    ("xla", "tpu", 1, None, ("xla", "in_place")),
+    ("pallas", "tpu", 1, None, ("pallas", "in_place")),
+    ("pallas", "cpu", 1, None, ("pallas", "scatter")),    # interpreted
+    ("pallas", "tpu", 4, None, ("pallas", "scatter"))])   # the runner's to refuse
+def test_who_reads_and_writes_a_latent_pool(backend, platform, mesh,
+                                            quant_kv, want):
+    """config.pool_access for a latent pool: under "auto" the kernel on one
+    TPU device over bfloat16 entries and XLA's walk everywhere else; the
+    window commits in place on one TPU device under EITHER reader (both read
+    the row-major pool as it lies); a requested reader comes back as asked.
+    The choice reads what a token leaves in the pool and the device, never
+    the heads' own width."""
+    for head_dim in (192, 128):
+        assert pool_access(backend, platform, mesh, head_dim, quant_kv,
+                           latent=True) == want
+
+
+def _bare_runner(spec, mesh=1, quant_kv=None, platform="cpu", **config):
     from types import SimpleNamespace
+    runner = object.__new__(ModelRunner)
+    runner.spec = spec
+    runner.config = SimpleNamespace(page_size=4, max_pages_per_seq=128,
+                                    attention_backend="auto", **config)
+    runner.mesh = SimpleNamespace(size=mesh)
+    runner.device = SimpleNamespace(platform=platform)
+    runner.quant_kv = quant_kv
+    return runner
 
-    from dynamo_tpu.engine.config import pool_access, window_page_bucket
+
+def test_the_runner_takes_the_kernel_for_a_latent_pool_where_it_serves():
+    """_pallas_refusal has no sentence left for a latent pool on one device
+    (whatever the heads' width: 24 at the toy, 192 as published), and keeps
+    one each for a mesh and for int8 pages; a requested "pallas" runs
+    interpreted on the CPU, one reader for the decode step and the window;
+    "auto" on a TPU is the kernel and on the CPU XLA's walk (no reader)."""
+    from dynamo_tpu.engine.attention import latent_history_pallas
+    spec = read_spec(TOY)
+    assert _bare_runner(spec)._pallas_refusal() is None
+    assert "one device" in _bare_runner(spec, mesh=2)._pallas_refusal()
+    assert "int8 latent pages" in _bare_runner(
+        spec, quant_kv="int8")._pallas_refusal()
+    # A K-and-V pool of the same head width is still refused by its width.
+    kv = ModelSpec(head_dim=spec.head_dim, num_heads=4, num_kv_heads=4,
+                   hidden_size=96)
+    assert spec.head_dim == 24
+    assert "head_dim" in _bare_runner(kv)._pallas_refusal()
+    runner = ModelRunner(EngineConfig(model=spec, page_size=4, num_pages=16,
+                                      attention_backend="pallas"))
+    assert runner.attention_backend == "pallas"
+    assert runner.kv_commit_backend == "scatter"    # the CPU
+    assert runner._attention_impl is runner._window_attention_impl
+    assert runner._attention_impl.func is latent_history_pallas
+    # ... and one kernel for every page-table bucket: the table's limit.
+    assert runner._attention_impl.keywords == {
+        "interpret": True, "table": runner.config.max_pages_per_seq}
+    on_tpu = _bare_runner(spec, platform="tpu")
+    assert on_tpu._pick_attention()[0].keywords == {"interpret": False,
+                                                    "table": 128}
+    assert (on_tpu.attention_backend, on_tpu._pick_kv_commit()) == (
+        "pallas", "in_place")
+    on_cpu = _bare_runner(spec)
+    assert on_cpu._pick_attention() == (None, None)
+    assert (on_cpu.attention_backend, on_cpu._pick_kv_commit()) == (
+        "xla", "scatter")
+
+
+def test_a_bucket_that_xla_gathers_whole_grows_by_1024_tokens():
+    """An XLA gather reads the whole bucket of every slot, so past 2,048
+    tokens such a bucket is a multiple of 1,024 tokens where the K-and-V
+    kernel's, which walks live pages alone, stays a power of two. A latent
+    pool keeps the steps under EITHER reader: its entries are walked by the
+    kernel, its index keys still gathered over the bucket by XLA."""
+    from dynamo_tpu.engine.config import window_page_bucket
     needs = (1, 9, 17, 33, 49, 65, 81, 100, 121, 500)
-    assert [window_page_bucket(n, "xla", 64, 128) for n in needs] == [
-        8, 16, 32, 48, 64, 80, 96, 112, 128, 128]
+    steps = [8, 16, 32, 48, 64, 80, 96, 112, 128, 128]
+    assert [window_page_bucket(n, "xla", 64, 128) for n in needs] == steps
     assert [window_page_bucket(n, "pallas", 64, 128) for n in needs] == [
         8, 16, 32, 64, 64, 128, 128, 128, 128, 128]
+    for reader in ("xla", "pallas"):
+        assert [window_page_bucket(n, reader, 64, 128, latent=True)
+                for n in needs] == steps
     # A page of 16: steps of 64 pages past 128.
     assert [window_page_bucket(n, "xla", 16, 512)
             for n in (100, 129, 193, 400)] == [128, 192, 256, 448]
-    runner = object.__new__(ModelRunner)
-    runner.config = SimpleNamespace(max_pages_per_seq=128, page_size=64)
-    runner.attention_backend = pool_access("auto", "tpu", 1, 192, None,
-                                           latent=True)[0]
-    assert runner.bucket_pages_for(65) == 80
+    spec = read_spec(TOY)
+    for platform in ("tpu", "cpu"):
+        runner = _bare_runner(spec, platform=platform)
+        runner.config.page_size = 64
+        runner._pick_attention()
+        assert runner.bucket_pages_for(65) == 80
 
 
 # -- the pieces ----------------------------------------------------------------
@@ -416,12 +487,14 @@ def pools(spec, pages: int):
             jnp.zeros((*shape, dv), jnp.bfloat16))
 
 
-def served_logits(spec, params, tokens) -> tuple[np.ndarray, np.ndarray]:
+def served_logits(spec, params, tokens, reader=None
+                  ) -> tuple[np.ndarray, np.ndarray]:
     """Logits the program gives after positions FIRST-1 (whole-prompt
     prefill), FIRST+CHUNK-1 (chunk prefill over cached history), then one
     row a decoded position: WINDOW steps of the window program with its
     commit, the rest by the single decode step; [B, rows, V]. And the
-    window's counts [L, 2] of its last step."""
+    window's counts [L, 2] of its last step. ``reader``: who reads the
+    entries in the window and the step (None: XLA's walk)."""
     b = tokens.shape[0]
     pages = SEQ // PAGE
     k, v = pools(spec, b * pages + 1)
@@ -453,7 +526,7 @@ def served_logits(spec, params, tokens) -> tuple[np.ndarray, np.ndarray]:
             logits, k_new, v_new, counts, stats = model.decode_window_step(
                 p, spec, k, v, kbuf, vbuf, jnp.int32(m),
                 tokens[:, done + m], hist + m, table, hist,
-                live=jnp.ones((b,), bool))
+                attention_impl=reader, live=jnp.ones((b,), bool))
             kbuf = kbuf.at[:, :, :, m].set(k_new.transpose(0, 2, 1, 3))
             vbuf = vbuf.at[:, :, :, m].set(v_new.transpose(0, 2, 1, 3))
             out.append(logits)
@@ -471,7 +544,7 @@ def served_logits(spec, params, tokens) -> tuple[np.ndarray, np.ndarray]:
     assert stats.shape == (spec.num_layers - spec.first_k_dense, 5)
     assert (np.asarray(stats)[:, 4] == b * spec.num_experts_per_tok).all()
     decode = jax.jit(lambda p, k, v, t, at: model.decode_forward(
-        p, spec, k, v, t, at, table, at + 1))
+        p, spec, k, v, t, at, table, at + 1, attention_impl=reader))
     while done < SEQ:
         logits, k, v = decode(params, k, v, tokens[:, done],
                               np.full((b,), done, np.int32))
@@ -510,13 +583,19 @@ CONTROLS = {"every key attended": {"select": False},
 TOLERANCE = 0.15
 
 
+@pytest.mark.parametrize("reader", ["xla", "pallas"])
 @pytest.mark.parametrize("quant", [None, "int8"])
 def test_prefill_then_decode_agrees_with_the_reference_with_selection_in_force(
-        quant):
+        quant, reader):
+    """Under either reader of the entries (config.pool_access): XLA's walk,
+    and the Pallas kernel (interpreted here) with the choice as its mask."""
+    from dynamo_tpu.engine.attention import latent_history_pallas
     spec, params, tokens = toy(quant)
     assert FIRST < spec.index_topk == 40 < FIRST + CHUNK
     assert spec.first_k_dense == 1
-    served, counts = served_logits(spec, params, tokens)
+    served, counts = served_logits(
+        spec, params, tokens, None if reader == "xla" else functools.partial(
+            latent_history_pallas, interpret=True))
     full = reference_logits(spec, params, tokens)
     assert served.shape == full.shape == (2, 2 + SEQ - FIRST - CHUNK,
                                           spec.vocab_size)

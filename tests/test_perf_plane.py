@@ -167,6 +167,7 @@ def test_note_window_derives_roofline_gauges():
 
 
 class _FakeRunner:
+    attention_backend = "pallas"
     kv_commit_backend = "in_place"
     page_size = 64
 
@@ -203,6 +204,7 @@ def test_perf_metrics_updater_exports_deltas_and_gauges(monkeypatch):
     assert up.g_hbm_limit.get() == 200
     # How the window program commits: an info series, 1 under its label.
     assert up.g_kv_commit.get(backend="in_place") == 1
+    assert up.g_attention.get(backend="pallas") == 1
     assert 'backend="in_place"' in metrics.expose().decode()
     # Deltas: a second update with no new compiles adds nothing.
     up.update(eng, force=True)
@@ -353,6 +355,11 @@ async def test_perf_smoke_engine_zero_recompiles_and_pane(tmp_path):
             engine.runner.kv_commit_backend == "scatter"
         assert "scatter" in snap1["programs"]["decode_window"]["labels"][
             "kv_commit_backend"]
+        # ... and who reads it (this engine asked for the gather).
+        assert status["attention_backend"] == \
+            engine.runner.attention_backend == "xla"
+        assert "xla" in snap1["programs"]["decode_window"]["labels"][
+            "attention_backend"]
         # ... and beside it how many tokens a page holds (a CPU engine:
         # 16, nothing derived).
         assert status["page_size"] == engine.runner.page_size == \
@@ -369,6 +376,9 @@ async def test_perf_smoke_engine_zero_recompiles_and_pane(tmp_path):
         assert [line for line in text.splitlines()
                 if line.startswith("dynamo_tpu_perf_kv_commit_info{")
                 and 'backend="scatter"' in line and line.endswith(" 1.0")]
+        assert [line for line in text.splitlines()
+                if line.startswith("dynamo_tpu_perf_attention_info{")
+                and 'backend="xla"' in line and line.endswith(" 1.0")]
         assert [line for line in text.splitlines()
                 if line.startswith("dynamo_tpu_perf_kv_page_info{")
                 and 'tokens="16"' in line and line.endswith(" 1.0")]
@@ -394,6 +404,7 @@ async def test_perf_smoke_engine_zero_recompiles_and_pane(tmp_path):
                 assert "decode_window" in body["compiles"]["programs"]
                 assert "roofline_frac" in body["window"]
                 assert body["kv_commit_backend"] == "scatter"
+                assert body["attention_backend"] == "xla"
                 assert body["page_size"] == 16
             async with session.get(
                     f"http://127.0.0.1:{frontend.port}/debug/perf") as resp:

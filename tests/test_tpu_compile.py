@@ -154,6 +154,31 @@ def test_windowed_window_kernel_compiles_for_v5e(v5e, page):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("table", [512, 8192])
+def test_latent_kernel_compiles_for_v5e(v5e, table):
+    """The reader of a latent pool at the DeepSeek-V3.2 cell's widths: 128
+    absorbed query heads against entries of 640 lanes of which 512 are the
+    value, a page of 64 (one copy of 80 KB a page, eight pages a chunk), 32
+    rows, nine layers, at the table the short check runs (512 tokens) and
+    the launcher's limit (8,192): the mask block of the whole table, three
+    chunk buffers and the [128, 512] accumulator fit the kernel's VMEM."""
+    from dynamo_tpu.engine.attention import (latent_history_pallas,
+                                             pages_per_chunk)
+    assert pages_per_chunk(64, 1, 640 // 2, 2) == 8
+    b, page = 32, 64
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    compiled = jax.jit(lambda *a: latent_history_pallas(
+        *a, scale=0.135, rank=512)).lower(
+        s((b, 128, 640), jnp.bfloat16),
+        s((9, 1, 5742, page, 640), jnp.bfloat16), s((), jnp.int32),
+        s((b, table // page), jnp.int32), s((b,), jnp.int32),
+        s((b, table), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 @pytest.mark.parametrize("rows", [32, 1024, 4096],
                          ids=["masked", "masked at the limit", "grouped"])
 def test_smallthinker_expert_layer_compiles_for_v5e(v5e, rows):
@@ -423,10 +448,11 @@ def test_latent_window_program_commits_in_place_for_v5e(v5e):
     widths (entries of 640 lanes, index keys of 128, one page table; 128
     heads, 64 index heads, 2,048 keys kept; a leading dense layer and two
     expert layers, narrow feed-forwards), pools donated, at the page "auto"
-    derives (64) and a table of 4,096 tokens: XLA's gather reads whole pages
-    from the row-major pools as they lie and the commit kernel rewrites the
-    touched rows of both widths, so nothing in the optimised program has
-    either pool's shape but the arguments and the commit aliased to them."""
+    derives (64) and a table of 4,096 tokens: the kernel walks the entries'
+    live pages, XLA's gather reads whole pages of index keys from the
+    row-major pool as it lies and the commit kernel rewrites the touched
+    rows of both widths, so nothing in the optimised program has either
+    pool's shape but the arguments and the commit aliased to them."""
     from types import SimpleNamespace
 
     from dynamo_tpu.engine.config import DeepseekV32Spec, EngineConfig
@@ -460,7 +486,7 @@ def test_latent_window_program_commits_in_place_for_v5e(v5e):
         runner._pick_attention()
     runner.kv_commit_backend = runner._pick_kv_commit()
     assert (runner.attention_backend, runner.kv_commit_backend) == (
-        "xla", "in_place")
+        "pallas", "in_place")
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
@@ -474,8 +500,10 @@ def test_latent_window_program_commits_in_place_for_v5e(v5e):
         params, *(s(pool, jnp.bfloat16) for pool in pools),
         s((rows,), jnp.int32), s((rows, PK_PREFIX + table), jnp.int32),
         s(key.shape, key.dtype))
+    # ONE kernel for both layer scans, and for every table (the widest).
+    assert lowered.as_text().count("func.func private @_latent_flash") == 1
     text = lowered.compile().as_text()
-    assert text.count("tpu_custom_call") == 1       # the commit alone
+    assert text.count("tpu_custom_call") >= 2  # the reader and the commit
     assert "output_to_operand_aliasing" in text
     for pool in pools:
         assert pool_sized_ops(text, pool) == []
