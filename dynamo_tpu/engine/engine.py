@@ -124,6 +124,11 @@ class _Window:
     serial: int = 0  # dispatch order (pipelined deferred-release fencing)
     t0: float = 0.0  # dispatch time (latency through the pipeline)
     page_bucket: int = 0   # page-table width of the program dispatched
+    # Slots a request held WITHOUT a row in this window, at the instant
+    # ``slots`` was taken: in chunked prefill, stalled for pages, frozen
+    # for a preemption, or owed only its first token's readback. With the
+    # rows and the empty slots it adds up to max_num_seqs.
+    prefilling: int = 0
     t_ready: float = 0.0   # readback complete (set when processed)
     # t_ready minus the previous window's, when this window was already
     # queued behind it (the device ran them back to back): one window of
@@ -253,7 +258,12 @@ class TPUEngine(AsyncEngine):
         self.prefill_rate_tok_s: float | None = None
         self._cold_inflight = 0   # admitted; first token not yet resolved
         self._waiting_cold = 0    # queued; not yet admitted
-        self.admission_deferred = 0  # gate held the queue head back
+        # _admit calls that ended with requests still queued, by cause
+        # (flight.ADMIT_STOPS): no free slot, no KV room for the head, or
+        # the SLA gate holding the head back. The HTTP limiter admits ahead
+        # of this queue, so 0 everywhere says every empty slot is its.
+        self.admit_stops = dict.fromkeys(flight.ADMIT_STOPS, 0)
+        self.m_admit_stops = None
         # Deferred queue HEAD: the SLA gate parks the over-budget head
         # here instead of re-queueing at the tail — strict FIFO, so a
         # large prompt can't be starved by a stream of later small ones
@@ -329,6 +339,13 @@ class TPUEngine(AsyncEngine):
             for bound in (self.m_chunk_tokens, self.m_chunks_inflight,
                           self.m_decode_stall):
                 bound.ensure()
+            self.m_admit_stops = metrics_registry.counter(
+                "engine_admit_stops_total",
+                "Engine admission passes that ended with requests still "
+                "queued, by cause (no_slot, no_pages, ttft_budget)",
+                ["cause"])
+            for cause in flight.ADMIT_STOPS:
+                self.m_admit_stops.ensure(cause=cause)
         # Flight recorder (runtime/flight.py): one compact row per
         # processed decode window into the process-global ring; the
         # deltas below turn cumulative counters into per-window values.
@@ -336,6 +353,7 @@ class TPUEngine(AsyncEngine):
         self._flight_chunk_last = 0
         self._flight_stall_last = 0.0
         self._flight_tokens_last = 0
+        self._flight_admit_stop = 0   # ADMIT_STOPS bits since the last row
         # The engine thread's phases (runtime/tracing.py ENGINE_PHASES) and
         # what of them the last flight row already carries.
         self.phase_clock = tracing.PhaseClock()
@@ -1649,6 +1667,14 @@ class TPUEngine(AsyncEngine):
                                2 if ratio < 2.5 else 3)
 
     # -- admission / prefill --------------------------------------------------
+    def _note_admit_stop(self, cause: str) -> None:
+        """This _admit call ends with requests still queued (ENGINE
+        THREAD): once a call, on the counter and in the next flight row."""
+        self.admit_stops[cause] += 1
+        self._flight_admit_stop |= flight.ADMIT_STOPS[cause]
+        if self.m_admit_stops is not None:
+            self.m_admit_stops.inc(cause=cause)
+
     def _admit(self) -> bool:
         self._update_brownout()
         free_slots = [i for i, r in enumerate(self.slot_req) if r is None]
@@ -1703,7 +1729,7 @@ class TPUEngine(AsyncEngine):
                         self._waiting_cold += r.queued_cold
                         self.num_waiting += 1
                     self._deferred_head = r
-                    self.admission_deferred += 1
+                    self._note_admit_stop("ttft_budget")
                     break
             self._note_queue_wait(r)
             try:
@@ -1718,6 +1744,7 @@ class TPUEngine(AsyncEngine):
                 # adapter ref while queued so it can't pin the slot).
                 self._release_adapter(r)
                 self._queue_put(r)
+                self._note_admit_stop("no_pages")
                 break
             slot = free_slots.pop(0)
             if plan == "chunked":
@@ -1741,6 +1768,12 @@ class TPUEngine(AsyncEngine):
             r.cold_tokens = len(r.tokens_all) - r.reuse_tokens
             self._cold_inflight += r.cold_tokens
             staged.append((r, slot, plan))
+        else:
+            # Every slot is taken (the loop did not break on an empty
+            # queue, no KV room or the SLA gate): who still queues waits
+            # for a slot.
+            if self.num_waiting:
+                self._note_admit_stop("no_slot")
         if not staged:
             return False
         # Batch the staged whole-prompt rows (split by history-ness; the
@@ -2378,10 +2411,13 @@ class TPUEngine(AsyncEngine):
             for i in (*active_rows, *stalled, *satisfied):
                 w.frozen.pop(i, None)
         self._dispatch_serial += 1
+        held_without_row = (sum(1 for r in self.slot_req if r is not None)
+                            - len(active_rows))
         if not active_rows:
             return _Window(toks=None, slots=[None] * b, frozen=frozen,
                            size=M, serial=self._dispatch_serial,
-                           t0=time.monotonic())
+                           t0=time.monotonic(),
+                           prefilling=held_without_row)
         bucket = self.runner.bucket_pages_for(needed_max)
         packed = np.zeros((b, PK_PREFIX + bucket), np.int32)
         slots: list = [None] * b
@@ -2438,7 +2474,8 @@ class TPUEngine(AsyncEngine):
                        serial=self._dispatch_serial,
                        spec=use_spec,
                        t0=time.monotonic(),
-                       page_bucket=packed.shape[1] - PK_PREFIX)
+                       page_bucket=packed.shape[1] - PK_PREFIX,
+                       prefilling=held_without_row)
 
     def _process_window(self, w: _Window) -> None:
         if w.spec and w.toks is not None:
@@ -2800,7 +2837,8 @@ class TPUEngine(AsyncEngine):
             idle_total - self._flight_idle_last, rows, w.page_bucket,
             *(w.moe if w.moe is not None else ()),
             **({} if w.attn is None else {
-                "attn_selected": w.attn[0], "attn_context": w.attn[1]}))
+                "attn_selected": w.attn[0], "attn_context": w.attn[1]}),
+            prefilling=w.prefilling, admit_stop=self._flight_admit_stop)
         if accepted:
             # A frozen ring (bundle capture in flight) rejects the row:
             # keep accumulating so the stall/chunk/token/host-time deltas
@@ -2808,6 +2846,7 @@ class TPUEngine(AsyncEngine):
             self._flight_chunk_last = chunk_total
             self._flight_stall_last = 0.0
             self._flight_tokens_last = tokens_total
+            self._flight_admit_stop = 0
             self._flight_busy_last = busy_total
             self._flight_wait_last = wait_total
             self._flight_idle_last = idle_total

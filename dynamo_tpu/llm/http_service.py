@@ -36,7 +36,7 @@ from dynamo_tpu.runtime.logging import (current_trace, get_logger,
                                         parse_traceparent)
 from dynamo_tpu.runtime.overload import (PRIORITY_BATCH, PRIORITY_INTERACTIVE,
                                          AdaptiveLimiter)
-from dynamo_tpu.runtime.tracing import get_recorder, span
+from dynamo_tpu.runtime.tracing import NULL_SPAN, get_recorder, span
 
 log = get_logger("http")
 
@@ -308,13 +308,14 @@ class HttpService:
             req.max_tokens = clamped
 
     async def _timed_first(self, chunks: AsyncIterator[dict], permit,
-                           started: float, acct: dict | None = None
-                           ) -> AsyncIterator[dict]:
+                           started: float, acct: dict | None = None,
+                           req_span=NULL_SPAN) -> AsyncIterator[dict]:
         """Report time-to-first-chunk (the per-phase latency AIMD adapts
         against) into the admission permit — and, from the SAME timing
         point, feed the SLO plane's TTFT/ITL SLIs and the accounting
         record (TTFT, inter-chunk gaps, the usage block's token
-        counts)."""
+        counts). What the limiter will judge rides on ``req_span``, the
+        request's open ``http.request`` span, as ``permit_to_first_ms``."""
         plane = slo_mod.get_plane()
         last_t = None
         async for chunk in chunks:
@@ -323,6 +324,7 @@ class HttpService:
                 ttft = now - started
                 if permit is not None and hasattr(permit, "note_latency"):
                     permit.note_latency(ttft)
+                    req_span.set(permit_to_first_ms=ttft * 1e3)
                 plane.observe_ttft(ttft)
                 if acct is not None:
                     acct["ttft_s"] = ttft
@@ -422,11 +424,11 @@ class HttpService:
                 return shed
             try:
                 with permit, span("http.request", ctx=ctx, route=route,
-                                  model=chat_req.model):
+                                  model=chat_req.model) as req_span:
                     self._apply_brownout(chat_req)
                     chunks = self._timed_first(
                         served.preprocessor.generate(chat_req, ctx),
-                        permit, time.monotonic(), acct)
+                        permit, time.monotonic(), acct, req_span)
                     if chat_req.stream:
                         resp = await self._sse_stream(request, chunks, ctx,
                                                       chat_req.model,
@@ -520,7 +522,7 @@ class HttpService:
                 return shed
             try:
                 with permit, span("http.request", ctx=ctx, route=route,
-                                  model=comp_req.model):
+                                  model=comp_req.model) as req_span:
                     self._apply_brownout(comp_req)
                     if not comp_req.stream:
                         # Force the usage chunk so the folded response
@@ -529,7 +531,7 @@ class HttpService:
                     chunks = self._timed_first(
                         served.preprocessor.generate_completion(
                             comp_req, ctx),
-                        permit, time.monotonic(), acct)
+                        permit, time.monotonic(), acct, req_span)
                     if comp_req.stream:
                         resp = await self._sse_stream(request, chunks, ctx,
                                                       comp_req.model,
@@ -835,11 +837,11 @@ class HttpService:
             if shed is not None:
                 return shed
             with permit, span("http.request", ctx=ctx, route=route,
-                              model=model):
+                              model=model) as req_span:
                 self._apply_brownout(chat_req)
                 chunks = self._timed_first(
                     served.preprocessor.generate(chat_req, ctx),
-                    permit, time.monotonic(), acct)
+                    permit, time.monotonic(), acct, req_span)
                 if body.get("stream"):
                     resp = await self._responses_sse(request, chunks, ctx,
                                                      model)

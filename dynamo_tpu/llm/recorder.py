@@ -333,7 +333,7 @@ def finish_account(acct: dict, status: str, reason: str | None = None,
         values = getattr(ctx, "values", {})
         for key in ("worker_id", "migrations", "migration_reason",
                     "reuse_tokens", "kv_hit_ratio", "kv_tiers",
-                    "queue_wait_s", "adapter"):
+                    "adapter"):
             if values.get(key) is not None:
                 acct[key] = values[key]
     (ledger or get_ledger()).record(acct)

@@ -14,8 +14,9 @@ stores (asserted allocation-free in tests/test_slo.py in the style of
 
 Anomaly capture: an SLO fast-burn page (runtime/slo.py ``on_page``) or
 a decode-stall tail spike (engine/engine.py consults
-``stall_threshold_s``) calls ``trigger(reason)`` — the ring freezes,
-and a background thread writes a **diagnostic bundle** (flight ring +
+``stall_threshold_s``) calls ``trigger(reason)`` — the ring freezes
+for a copy of itself, and a background thread writes a **diagnostic
+bundle** (the copy +
 recent spans + metrics snapshot + config fingerprint) as one JSON file
 under ``bundle_dir``. Captures are throttled by ``cooldown_s`` so a
 sustained incident produces one bundle, not a disk flood. ``GET/POST
@@ -23,7 +24,8 @@ sustained incident produces one bundle, not a disk flood. ``GET/POST
 captures.
 
 Env knobs (read once at import; ``configure()`` overrides):
-``DTPU_FLIGHT_CAPACITY`` (ring slots, default 2048, 0 disables),
+``DTPU_FLIGHT_CAPACITY`` (ring slots, default 8192: 300 s of windows of
+40 ms, 1.8 MB; 0 disables),
 ``DTPU_FLIGHT_DIR`` (bundle directory, default /tmp/dtpu-flight),
 ``DTPU_FLIGHT_STALL_S`` (decode-stall trigger threshold, default 2.0,
 0 disables), ``DTPU_FLIGHT_COOLDOWN_S`` (default 300).
@@ -68,16 +70,28 @@ log = get_logger("flight")
 # pairs); 0 for every other block. A latent block's window adds
 # "attn_selected" (keys its live rows attended, summed over rows, layers
 # and steps on the device) and "attn_context" (keys they had in context);
-# 0 for every other block.
+# 0 for every other block. Beside "rows", taken at the same instant (the
+# window's DISPATCH): "prefilling", the slots a request held without a row
+# in this window (in chunked prefill, or stalled for pages, frozen for a
+# preemption, or owed nothing but its first token's readback), so that
+# rows + prefilling + empty slots = max_num_seqs in every row of the ring
+# ("active" is taken at PROCESSING, pipeline_depth windows later, and cannot
+# be subtracted from "rows"). "admit_stop": why the engine's own admission
+# left requests queued since the previous row, a bit set of ADMIT_STOPS
+# (0: it turned nobody away).
 FIELDS = ("t_mono", "dur_s", "active", "waiting", "free_pages",
           "chunk_tokens", "chunks_inflight", "preempts", "brownout",
           "stall_s", "step", "tokens", "period_s", "host_s", "wait_s",
           "idle_s", "rows", "page_bucket", "missed", "moe_touched",
           "moe_load", "moe_layer_steps", "moe_local_picks", "moe_picks",
-          "attn_selected", "attn_context")
+          "attn_selected", "attn_context", "prefilling", "admit_stop")
 _INT_FIELDS = ("active", "waiting", "free_pages", "chunk_tokens",
                "chunks_inflight", "preempts", "brownout", "step", "tokens",
-               "rows", "page_bucket", "missed")
+               "rows", "page_bucket", "missed", "prefilling", "admit_stop")
+#: Why TPUEngine._admit ended with requests still queued, and the bit each
+#: cause sets in "admit_stop" (the label values of
+#: ``engine_admit_stops_total{cause}``).
+ADMIT_STOPS = {"no_slot": 1, "no_pages": 2, "ttft_budget": 4}
 
 
 def _env_int(name: str, default: int) -> int:
@@ -94,7 +108,7 @@ class FlightRecorder:
     """Fixed-slot ring of per-window records (preallocated numpy
     columns; single engine-thread writer, any-thread readers)."""
 
-    def __init__(self, capacity: int = 2048, enabled: bool = True):
+    def __init__(self, capacity: int = 8192, enabled: bool = True):
         self.capacity = max(1, capacity)
         self.enabled = enabled and capacity > 0
         self._cols = {name: np.zeros(self.capacity, np.float64)
@@ -126,8 +140,8 @@ class FlightRecorder:
                page_bucket: int = 0, moe_touched: float = 0.0,
                moe_load: float = 0.0, moe_layer_steps: float = 0.0,
                moe_local_picks: float = 0.0, moe_picks: float = 0.0,
-               attn_selected: float = 0.0, attn_context: float = 0.0
-               ) -> bool:
+               attn_selected: float = 0.0, attn_context: float = 0.0,
+               prefilling: int = 0, admit_stop: int = 0) -> bool:
         """One engine-window row. Idle-stable windows (no active slots,
         no waiters, no chunk work — same as the previous call) are
         skipped without touching the ring. Returns False when the row
@@ -175,6 +189,8 @@ class FlightRecorder:
             cols["moe_picks"][i] = moe_picks
             cols["attn_selected"][i] = attn_selected
             cols["attn_context"][i] = attn_context
+            cols["prefilling"][i] = prefilling
+            cols["admit_stop"][i] = admit_stop
             cols["missed"][i] = self._missed[0]
             self._missed[0] = 0
             self._idx = (i + 1) % self.capacity
@@ -207,21 +223,19 @@ class FlightRecorder:
             self._missed[0] = 0
             self._was_idle = False
 
-    def dump(self) -> list[dict]:
-        """Ring contents oldest-first as dicts (the /debug/flight and
-        bundle payload)."""
+    def columns(self) -> dict:
+        """A copy of the ring's columns, oldest row first. The lock is
+        held for the copy alone (record() waits on it)."""
         with self._lock:
             n = self._count
             start = (self._idx - n) % self.capacity
-            order = [(start + k) % self.capacity for k in range(n)]
-            rows = []
-            for i in order:
-                row = {name: float(col[i])
-                       for name, col in self._cols.items()}
-                for name in _INT_FIELDS:
-                    row[name] = int(row[name])
-                rows.append(row)
-            return rows
+            order = (start + np.arange(n)) % self.capacity
+            return {name: col[order] for name, col in self._cols.items()}
+
+    def dump(self) -> list[dict]:
+        """Ring contents oldest-first as dicts (the /debug/flight and
+        bundle payload)."""
+        return rows_of(self.columns())
 
     def between(self, t_lo: float, t_hi: float) -> dict:
         """The rows with ``t_lo <= t_mono <= t_hi``, oldest first, as one
@@ -256,10 +270,17 @@ class FlightRecorder:
                 "frozen": self.frozen, "frozen_reason": self.frozen_reason}
 
 
+def rows_of(columns: dict) -> list[dict]:
+    """``FlightRecorder.columns()`` as one dict a row."""
+    lists = {name: (col.astype(np.int64) if name in _INT_FIELDS
+                    else col).tolist() for name, col in columns.items()}
+    return [dict(zip(lists, values)) for values in zip(*lists.values())]
+
+
 # -- process-global recorder + anomaly capture ---------------------------------
 
 _RECORDER = FlightRecorder(
-    capacity=_env_int("DTPU_FLIGHT_CAPACITY", 2048))
+    capacity=_env_int("DTPU_FLIGHT_CAPACITY", 8192))
 
 #: Decode-stall trigger threshold consulted by the engine loop (0
 #: disables the automatic trigger; the manual POST /debug/flight and
@@ -305,9 +326,11 @@ def _fingerprint_payload() -> dict:
             "sha256": hashlib.sha256(body.encode()).hexdigest()}
 
 
-def capture_bundle(reason: str, out_dir: str | None = None) -> str:
+def capture_bundle(reason: str, out_dir: str | None = None,
+                   ring: dict | None = None) -> str:
     """Write one diagnostic bundle NOW (blocking; call off the loop).
-    Returns the bundle path."""
+    ``ring``: the flight part as ``trigger`` copied it at the anomaly
+    (default: the ring as it stands). Returns the bundle path."""
     from dynamo_tpu.runtime import tracing
 
     out_dir = out_dir or _bundle_dir
@@ -321,7 +344,7 @@ def capture_bundle(reason: str, out_dir: str | None = None) -> str:
     bundle = {
         "reason": reason,
         "ts": ts,
-        "flight": {"meta": rec.meta(), "windows": rec.dump()},
+        "flight": ring or {"meta": rec.meta(), "windows": rec.dump()},
         "spans": span_rec.export_chrome(),
         "metrics": (_metrics_registry.expose().decode()
                     if _metrics_registry is not None else None),
@@ -332,8 +355,11 @@ def capture_bundle(reason: str, out_dir: str | None = None) -> str:
         "journal": journal_mod.get_journal().snapshot(limit=256),
         "config_fingerprint": _fingerprint_payload(),
     }
-    with open(path, "w") as fh:
+    # Whole or absent: whoever watches the directory (an operator's
+    # script, a test) never reads half a bundle.
+    with open(path + ".tmp", "w") as fh:
         json.dump(bundle, fh)
+    os.replace(path + ".tmp", path)
     log.warning("flight bundle written: %s (%d windows, reason=%s)",
                 path, len(bundle["flight"]["windows"]), reason)
     return path
@@ -350,7 +376,16 @@ def trigger(reason: str, clock=time.monotonic) -> bool:
             return False
         _last_trigger_t = now
         triggers_total += 1
+    # Frozen for the COPY alone, not while the bundle is serialised and
+    # written (0.1 to 0.3 s, which cost three rows a capture): a row the
+    # engine hands in meanwhile is kept, so a reader of the window that
+    # holds the anomaly (``between``) still finds every row of it. A
+    # trigger from the engine thread itself (the decode stall) loses none.
     _RECORDER.freeze(reason)
+    try:
+        meta, columns = _RECORDER.meta(), _RECORDER.columns()
+    finally:
+        _RECORDER.thaw()
     # Decision plane: an anomaly capture is itself a fleet decision.
     # Cause: the SLO page that pulled the trigger, else (decode-stall
     # path) the chaos injection that froze the engine, when either is
@@ -364,11 +399,10 @@ def trigger(reason: str, clock=time.monotonic) -> bool:
 
     def _write() -> None:
         try:
-            capture_bundle(reason)
+            capture_bundle(reason, ring={"meta": meta,
+                                         "windows": rows_of(columns)})
         except Exception:  # noqa: BLE001 — diagnostics must never crash serving
             log.exception("flight bundle capture failed")
-        finally:
-            _RECORDER.thaw()
 
     threading.Thread(target=_write, name="flight-bundle",
                      daemon=True).start()

@@ -58,6 +58,9 @@ class EventKind:
     BREAKER_TRANSITION = "breaker_transition"
     SHED = "shed"
     BROWNOUT_CHANGE = "brownout_change"
+    # The AIMD limiter (runtime/overload.py) lowered its concurrency
+    # limit: a first token took longer than target_latency_ms.
+    LIMIT_DECREASE = "limit_decrease"
     PREEMPT = "preempt"
     MIGRATION = "migration"
     ROLE_FLIP_REQUESTED = "role_flip_requested"

@@ -50,10 +50,10 @@ import random
 import time
 from typing import Callable, Iterable
 
-from dynamo_tpu.runtime import journal
+from dynamo_tpu.runtime import chaos, journal, tracing
 from dynamo_tpu.runtime.errors import OverloadedError, RateLimitedError
 from dynamo_tpu.runtime.journal import EventKind
-from dynamo_tpu.runtime.logging import get_logger
+from dynamo_tpu.runtime.logging import generate_trace_id, get_logger
 
 log = get_logger("overload")
 
@@ -62,6 +62,12 @@ log = get_logger("overload")
 #: (reason, priority) per interval with a suppressed count, not all of
 #: them (the shed_total counter keeps the exact tally).
 _SHED_JOURNAL_INTERVAL_S = 1.0
+
+#: Buckets of ``overload_judged_latency_seconds``: dense around the default
+#: ``target_latency_ms`` (5 s), so the distance of what the limiter judges
+#: from the target that would lower the limit is readable off /metrics.
+_JUDGED_BUCKETS = (.05, .1, .25, .5, 1.0, 1.5, 2.0, 3.0, 4.0, 5.0, 7.5,
+                   10.0, 15.0, 30.0, 60.0)
 
 PRIORITY_INTERACTIVE = "interactive"
 PRIORITY_BATCH = "batch"
@@ -207,11 +213,19 @@ class AdaptiveLimiter:
         # Local mirrors of the metrics (always available to tests).
         self.admitted_total = collections.Counter()   # priority -> n
         self.shed_counts = collections.Counter()      # (reason, priority)
+        # What _observe decided, one count a judged completion:
+        # "increase", "decrease", or "held" (over the target, but inside
+        # decrease_cooldown_s of the last decrease: the limit stays).
+        self.limit_changes = collections.Counter()    # direction -> n
+        # The limiter's own trace: its ``overload.limit`` events share
+        # one trace id, so /debug/traces/<id> is the limit's history.
+        self._trace_id = generate_trace_id()
         # Journal state: shed-event throttle + last brownout level.
         self._shed_journal: dict[tuple[str, str], list] = {}
         self._journal_level = 0
         self._m_shed = self._m_admitted = None
         self._m_limit = self._m_queue = self._m_level = None
+        self._m_changes = self._m_judged = None
         if metrics is not None:
             m = metrics.namespace("overload")
             self._m_shed = m.counter(
@@ -227,6 +241,19 @@ class AdaptiveLimiter:
             self._m_level = m.gauge(
                 "brownout_level", "Current brownout pressure level")
             self._m_limit.set(self.limit)
+            self._m_changes = m.counter(
+                "overload_limit_changes_total",
+                "AIMD decisions by direction: increase, decrease, or held "
+                "(over the target inside the decrease cooldown)",
+                ["direction"])
+            self._m_judged = m.histogram(
+                "overload_judged_latency_seconds",
+                "Permit-to-first-token latency the limiter judged "
+                "against target_latency_ms",
+                buckets=_JUDGED_BUCKETS)
+            for direction in ("increase", "decrease", "held"):
+                self._m_changes.ensure(direction=direction)
+            self._m_judged.ensure()
 
     # -- pressure / projections -----------------------------------------------
     def waiting(self) -> int:
@@ -394,6 +421,7 @@ class AdaptiveLimiter:
 
     def _observe(self, latency_s: float) -> None:
         cfg = self.cfg
+        before = self.limit
         self.avg_service_s = (
             latency_s if self.avg_service_s is None
             else 0.8 * self.avg_service_s + 0.2 * latency_s)
@@ -403,12 +431,52 @@ class AdaptiveLimiter:
                 self._last_decrease_t = now
                 self.limit = max(float(cfg.min_concurrency),
                                  self.limit * cfg.multiplicative_decrease)
+                direction = "decrease"
+            else:
+                direction = "held"
         else:
             self.limit = min(float(cfg.max_concurrency),
                              self.limit + cfg.additive_increase
                              / max(1.0, self.limit))
+            direction = "increase"
+        self.limit_changes[direction] += 1
         if self._m_limit is not None:
             self._m_limit.set(self.limit)
+            self._m_changes.inc(direction=direction)
+            self._m_judged.observe(latency_s)
+        if direction == "decrease" or int(self.limit) != int(before):
+            self._note_limit_change(before, direction, latency_s)
+
+    def _note_limit_change(self, before: float, direction: str,
+                           latency_s: float) -> None:
+        """A change of the limit that changes who gets in, as an event
+        with a time: a zero-length ``overload.limit`` span, and for a
+        decrease a journal event. Rare by construction at any request
+        rate: ``int(limit)`` rises once in about ``limit`` completions
+        under the target (each adds 1/limit), and a decrease happens at
+        most once a ``decrease_cooldown_s``, so the events cannot flood
+        the span ring (tests/test_overload.py holds 1,000 completions at
+        limit 64 to at most 16 of them)."""
+        rec = tracing.get_recorder()
+        if rec.enabled:
+            now = self._clock()
+            rec.add("overload.limit", self._trace_id, None, now, now,
+                    attrs={"before": before, "after": self.limit,
+                           "direction": direction,
+                           "judged_ms": latency_s * 1e3,
+                           "inflight": self.inflight,
+                           "waiting": self.waiting()})
+        if direction == "decrease":
+            # Decision plane: lowering the limit turns callers away for
+            # as long as it takes to climb back. Cause: the chaos
+            # injection that slowed the first token, when one is active.
+            journal.emit(EventKind.LIMIT_DECREASE,
+                         cause=(journal.recent_ref(EventKind.CHAOS_INJECT)
+                                if chaos.ACTIVE else None),
+                         before=round(before, 3), after=round(self.limit, 3),
+                         judged_ms=round(latency_s * 1e3, 1),
+                         target_ms=self.cfg.target_latency_ms,
+                         inflight=self.inflight, waiting=self.waiting())
 
     def _wake_waiters(self) -> None:
         """Hand freed slots to waiters — interactive strictly first, so

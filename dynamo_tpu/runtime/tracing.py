@@ -514,10 +514,13 @@ class PhaseClock:
 
 _WAIT_INDEX = tuple(ENGINE_PHASES.index(n) for n in WAIT_PHASES)
 
-def engine_phase_totals() -> dict[str, float]:
-    """Seconds by engine phase, summed over this process's engines."""
+def engine_phase_totals(clocks=None) -> dict[str, float]:
+    """Seconds by engine phase, summed over this process's engines (or
+    over ``clocks``: a caller that differences two readings holds the
+    clocks in between, so that an engine collected meanwhile does not
+    read as negative seconds)."""
     out = dict.fromkeys(ENGINE_PHASES, 0.0)
-    for clock in list(_CLOCKS):
+    for clock in (list(_CLOCKS) if clocks is None else clocks):
         for name, seconds in clock.totals().items():
             out[name] += seconds
     return out
@@ -635,7 +638,8 @@ async def capture_profile(duration_ms: int, out_dir: str,
     try:
         from dynamo_tpu.runtime import flight
         started = time.monotonic()
-        phases0 = engine_phase_totals()
+        clocks = list(_CLOCKS)  # strong references for the capture
+        phases0 = engine_phase_totals(clocks)
         mode = "jax"
         try:
             import jax
@@ -661,7 +665,7 @@ async def capture_profile(duration_ms: int, out_dir: str,
 
         await asyncio.to_thread(_dump)
         ended = time.monotonic()
-        phases1 = engine_phase_totals()
+        phases1 = engine_phase_totals(clocks)
         windows = flight.get_recorder().between(started, ended)
         return {"mode": mode, "out_dir": out_dir,
                 "span_dump": span_path,

@@ -418,7 +418,7 @@ async def test_sla_admission_defers_over_budget():
         results = await asyncio.gather(*[one() for _ in range(6)])
         for got, finish in results:
             assert finish == "length" and len(got) == 2
-        assert eng.admission_deferred > 0, (
+        assert eng.admit_stops["ttft_budget"] > 0, (
             "the SLA gate never deferred a request under a 1 ms budget")
         assert eng._cold_inflight == 0 and eng._waiting_cold == 0
     finally:
@@ -428,11 +428,11 @@ async def test_sla_admission_defers_over_budget():
 @async_test
 async def test_sla_admission_disabled_never_defers(engine):
     rng = np.random.default_rng(22)
-    before = engine.admission_deferred
+    before = engine.admit_stops["ttft_budget"]
     prompts = [rng.integers(0, SPEC.vocab_size, size=24).tolist()
                for _ in range(4)]
     await asyncio.gather(*[collect(engine, p, 2) for p in prompts])
-    assert engine.admission_deferred == before
+    assert engine.admit_stops["ttft_budget"] == before
 
 
 @async_test

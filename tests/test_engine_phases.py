@@ -136,10 +136,17 @@ def test_flight_between_returns_columns_and_counts_what_it_lacks():
 
 def test_default_ring_outlasts_a_benchmark_read_at_the_bandwidth_floor():
     """The benchmark's readers run after the window AND the drain (51 s +
-    30 s + the client's wait); the ring must still hold the window's first
-    row then, also once a window is as short as the chip's bandwidth
-    allows (8 steps of 9.7 ms for the 7B cell, PERF.md 5.1)."""
-    assert flight.FlightRecorder().capacity * 8 * 0.0097 >= 120.0
+    30 s + the client's wait, about 100 s after the ramp's first row); the
+    ring must still hold the window's first row then, also once a window
+    is as short as the chip's bandwidth allows (8 steps of 9.7 ms for the
+    7B cell, PERF.md 5.1) AND at the next halving of the shortest window a
+    cell has today (SmallThinker's 4 steps, 62.5 ms, so 40 ms and under):
+    300 s of them, in 28 columns of 8 bytes (under 2 MB)."""
+    ring = flight.FlightRecorder()
+    assert ring.capacity * 8 * 0.0097 >= 120.0
+    assert ring.capacity * 0.040 >= 300.0
+    assert len(flight.FIELDS) == 28
+    assert sum(c.nbytes for c in ring._cols.values()) <= 2_000_000
 
 
 def test_flight_record_with_the_new_columns_retains_nothing():
@@ -148,9 +155,12 @@ def test_flight_record_with_the_new_columns_retains_nothing():
     def hot(n):
         for _ in range(n):
             rec.record(1.5, 0.01, 4, 1, 100, 32, 1, 0, 0, 0.0, 7, 64,
-                       0.29, 0.02, 0.25, 0.0, 17, 128)
+                       0.29, 0.02, 0.25, 0.0, 17, 128,
+                       prefilling=2, admit_stop=5)
 
     assert _retained_in("flight.py", hot) <= 0
+    last = rec.dump()[-1]
+    assert last["prefilling"] == 2 and last["admit_stop"] == 5
     rec.freeze("x")
     assert _retained_in("flight.py", hot) <= 0  # the refusing path too
 
@@ -287,9 +297,6 @@ async def test_phases_are_on_the_profiler_trace_and_in_the_capture_reply(
     engine = _tiny_engine()
     try:
         await _generate(engine, 4)  # compile outside the capture
-        # The capture's split is a difference of the process's phase totals:
-        # an earlier test's stopped engine must not leave them in between.
-        gc.collect()
         t0 = time.monotonic()
         task = asyncio.ensure_future(
             tracing.capture_profile(60_000, str(tmp_path)))
@@ -317,6 +324,129 @@ async def test_phases_are_on_the_profiler_trace_and_in_the_capture_reply(
     # Self time: the intervals do not overlap.
     ivs = host_phases.phase_intervals(trace)
     assert all(a[1] <= b[0] + 1 for a, b in zip(ivs, ivs[1:]))
+
+
+@async_test(timeout=60)
+async def test_capture_holds_the_clocks_it_differences(tmp_path, monkeypatch):
+    """The capture's split is a difference of the process's phase totals
+    at its two ends: it holds the clocks meanwhile, so an engine that is
+    collected during the capture cannot read as negative seconds."""
+    import types
+    begun, go_on = asyncio.Event(), asyncio.Event()
+
+    async def until_told(_seconds):
+        begun.set()
+        await go_on.wait()
+
+    monkeypatch.setattr(tracing, "asyncio", types.SimpleNamespace(
+        **{**vars(asyncio), "sleep": until_told}))
+    doomed = PhaseClock()
+    with doomed.phase("engine.admit"):
+        time.sleep(0.02)
+    task = asyncio.ensure_future(
+        tracing.capture_profile(60_000, str(tmp_path)))
+    await begun.wait()
+    with doomed.phase("engine.publish"):
+        time.sleep(0.01)
+    del doomed          # the engine stops and is collected mid-capture
+    gc.collect()
+    go_on.set()
+    reply = await task
+    seconds = reply["engine_phase_seconds"]
+    assert min(seconds.values()) >= 0.0, seconds
+    assert seconds["engine.publish"] >= 0.009
+
+
+# -- every slot has a state in every window; the engine says why it stops ----------
+
+@async_test(timeout=240)
+async def test_rows_and_prefilling_are_one_instant_and_never_pass_the_slots():
+    """``rows`` and ``prefilling`` are both taken as the window is
+    dispatched: with the empty slots they add up to max_num_seqs in every
+    row, and a long prompt in chunked prefill beside two live decoders
+    reads prefilling 1, rows 2."""
+    ring = flight.get_recorder()
+    ring.thaw()
+    ring.clear()
+    engine = _tiny_engine(prefill_chunk_tokens=32)
+    slots = engine.config.max_num_seqs
+    try:
+        window = engine.decode_window
+        t_lo = time.monotonic()
+        # 28 windows each (the toy's context holds no more): the long
+        # prompt arrives with up to pipeline_depth of them dispatched.
+        decoders = [asyncio.ensure_future(_generate(engine, 28 * window + 1))
+                    for _ in range(2)]
+        while sum(r is not None and not r.prefilling
+                  for r in engine.slot_req) < 2:
+            await asyncio.sleep(0.005)
+        await _generate(engine, window + 1, prompt=150)   # five chunks
+        await asyncio.gather(*decoders)
+        cols = ring.between(t_lo, time.monotonic())["columns"]
+        rows = cols["rows"].astype(int)
+        prefilling = cols["prefilling"].astype(int)
+        assert len(rows) >= 12
+        assert ((rows + prefilling) <= slots).all()
+        assert (prefilling >= 0).all()
+        # From the first window that holds both decoders and nothing else
+        # (before it, one of them may hold its slot and await its first
+        # token): only the long prompt is ever without a row.
+        steady = np.flatnonzero((rows == 2) & (prefilling == 0))[0]
+        rows, prefilling = rows[steady:], prefilling[steady:]
+        assert prefilling.max() == 1
+        assert set(rows[prefilling == 1]) == {2}
+        assert (prefilling == 1).sum() >= 2      # a chunk a window
+        assert rows.max() == 3                   # then it decodes beside them
+        assert (cols["admit_stop"] == 0).all()   # nobody was turned away
+        assert engine.admit_stops == {"no_slot": 0, "no_pages": 0,
+                                      "ttft_budget": 0}
+    finally:
+        engine.stop()
+
+
+@pytest.mark.parametrize("cause,config", [
+    ("no_slot", dict(max_num_seqs=2)),
+    ("no_pages", dict(num_pages=9, max_num_seqs=4)),
+    ("ttft_budget", dict(ttft_budget_ms=1.0, max_num_seqs=4)),
+])
+@async_test(timeout=240)
+async def test_each_admit_stop_sets_its_bit_and_its_counter(cause, config):
+    """A full batch, a pool too small for the queue's head, and the TTFT
+    budget each leave requests queued: the pass that ends so counts it
+    under its cause, and the next flight row carries the cause's bit."""
+    from dynamo_tpu.engine.engine import TPUEngine
+    from dynamo_tpu.runtime.metrics import MetricsRegistry
+    from test_engine import tiny_config
+    ring = flight.get_recorder()
+    ring.thaw()
+    ring.clear()
+    reg = MetricsRegistry()
+    engine = TPUEngine(tiny_config(**config), metrics_registry=reg)
+    if cause == "ttft_budget":
+        engine.prefill_rate_tok_s = 1.0   # the gate needs a measured rate
+    try:
+        window = engine.decode_window
+        t_lo = time.monotonic()
+        # Four callers of 40-token prompts (3 pages each, 4 with their
+        # output): two slots, or eight usable pages, hold two of them.
+        await asyncio.gather(*(
+            _generate(engine, 3 * window + 1, prompt=40) for _ in range(4)))
+        others = set(flight.ADMIT_STOPS) - {cause}
+        assert engine.admit_stops[cause] >= 1, engine.admit_stops
+        assert all(engine.admit_stops[c] == 0 for c in others), \
+            engine.admit_stops
+        cols = ring.between(t_lo, time.monotonic())["columns"]
+        bits = set(cols["admit_stop"].astype(int)) - {0}
+        assert bits == {flight.ADMIT_STOPS[cause]}, bits
+        expo = reg.expose().decode()
+        for name in flight.ADMIT_STOPS:
+            (line,) = [ln for ln in expo.splitlines() if ln.startswith(
+                "dynamo_tpu_engine_admit_stops_total{")
+                and f'cause="{name}"' in ln]
+            assert float(line.rsplit(" ", 1)[1]) \
+                == engine.admit_stops[name], line
+    finally:
+        engine.stop()
 
 
 # -- scopes ------------------------------------------------------------------------

@@ -17,6 +17,7 @@ The e2e scenarios run a mocker fleet behind the real HTTP frontend at
 """
 
 import asyncio
+import os
 import time
 
 import aiohttp
@@ -285,6 +286,273 @@ async def test_limiter_unit_zero_silent_drops_accounting():
     assert sum(lim.shed_counts.values()) == outcomes["shed"]
     for p in permits:
         p.release()
+
+
+# -- the limiter's ledger of its own decisions (PR 36) --------------------------
+
+async def _complete(lim, latency_s: float) -> None:
+    p = await lim.admit()
+    p.note_latency(latency_s)
+    p.release()
+
+
+def _limit_events(rec):
+    return [s for s in rec.snapshot()[0] if s.name == "overload.limit"]
+
+
+@pytest.fixture
+def span_ring(monkeypatch):
+    """A span ring of the test's own in place of the process's."""
+    from dynamo_tpu.runtime import tracing
+    rec = tracing.SpanRecorder(capacity=4096)
+    monkeypatch.setattr(tracing, "_RECORDER", rec)
+    return rec
+
+
+def test_limiter_unit_counts_each_decision_once(span_ring):
+    """increase / decrease / held are counted at the completion that
+    made them, on the object and on /metrics, with the latency judged;
+    an ``overload.limit`` event exactly when ``int(limit)`` changes and
+    on every decrease, and no other."""
+    from dynamo_tpu.runtime.metrics import MetricsRegistry
+
+    async def run():
+        clk = FakeClock(100.0)
+        reg = MetricsRegistry()
+        lim = AdaptiveLimiter(OverloadConfig(
+            initial_concurrency=4, min_concurrency=1, max_concurrency=8,
+            target_latency_ms=100, decrease_cooldown_s=1.0), metrics=reg,
+            clock=clk)
+        # Three completions under the target: 4 -> 4.25 -> 4.485 -> 4.708,
+        # int(limit) stays 4: counted, no event.
+        for _ in range(3):
+            await _complete(lim, 0.01)
+        assert lim.limit_changes == {"increase": 3}
+        assert _limit_events(span_ring) == []
+        # The fifth crosses 5: one event, with what it judged.
+        await _complete(lim, 0.01)
+        await _complete(lim, 0.02)
+        assert int(lim.limit) == 5 and lim.limit_changes["increase"] == 5
+        (up,) = _limit_events(span_ring)
+        assert up.attrs["direction"] == "increase"
+        assert int(up.attrs["before"]) == 4 and int(up.attrs["after"]) == 5
+        assert up.attrs["judged_ms"] == pytest.approx(20.0)
+        assert up.start_mono == up.end_mono == 100.0  # the limiter's clock
+        # Over the target: a decrease, then two held inside the cooldown,
+        # then a second decrease that does NOT change int(limit) at the
+        # floor's neighbourhood still is an event.
+        clk.advance(5.0)
+        await _complete(lim, 1.0)
+        await _complete(lim, 1.0)
+        await _complete(lim, 1.0)
+        assert lim.limit_changes == {"increase": 5, "decrease": 1, "held": 2}
+        events = _limit_events(span_ring)
+        assert [e.attrs["direction"] for e in events] == ["increase",
+                                                          "decrease"]
+        down = events[-1]
+        assert down.attrs["after"] == pytest.approx(
+            down.attrs["before"] * 0.7)
+        assert down.attrs["judged_ms"] == pytest.approx(1000.0)
+        assert down.attrs["inflight"] == 0 and down.attrs["waiting"] == 0
+        assert down.start_mono == 105.0
+        lim.limit = 1.2
+        clk.advance(2.0)
+        await _complete(lim, 1.0)     # 1.2 -> 1.0 (the floor): int stays 1
+        assert lim.limit == 1.0 and len(_limit_events(span_ring)) == 3
+        clk.advance(2.0)
+        await _complete(lim, 1.0)     # at the floor: decided, and an event
+        assert lim.limit_changes["decrease"] == 3
+        assert len(_limit_events(span_ring)) == 4
+        # One trace for the limiter's life.
+        assert len({e.trace_id for e in _limit_events(span_ring)}) == 1
+        expo = reg.expose().decode()
+        for direction, n in (("increase", 5), ("decrease", 3), ("held", 2)):
+            (line,) = [ln for ln in expo.splitlines()
+                       if ln.startswith(
+                           "dynamo_tpu_overload_limit_changes_total{")
+                       and f'direction="{direction}"' in ln]
+            assert float(line.rsplit(" ", 1)[1]) == n, line
+        (count,) = [ln for ln in expo.splitlines() if ln.startswith(
+            "dynamo_tpu_overload_judged_latency_seconds_count")]
+        assert float(count.rsplit(" ", 1)[1]) == 10
+        # The 5 s target of the default configuration is a bucket edge.
+        assert 'overload_judged_latency_seconds_bucket{' in expo \
+            and 'le="5.0"' in expo
+
+    asyncio.run(run())
+
+
+def test_limiter_unit_limit_events_cannot_flood_the_ring(span_ring):
+    """The integer of the limit rises once in about ``limit`` completions:
+    1,000 completions under the target at limit 64 leave at most 16
+    events, whatever the request rate."""
+    async def run():
+        lim = AdaptiveLimiter(OverloadConfig(
+            initial_concurrency=64, max_concurrency=512), clock=FakeClock())
+        for _ in range(1000):
+            await _complete(lim, 0.5)
+        assert lim.limit_changes == {"increase": 1000}
+        events = _limit_events(span_ring)
+        assert 1 <= len(events) <= 16, len(events)
+        assert len(events) == int(lim.limit) - 64
+        # The step function a reader rebuilds is unbroken.
+        for a, b in zip(events, events[1:]):
+            assert int(a.attrs["after"]) == int(b.attrs["before"])
+
+    asyncio.run(run())
+
+
+def test_limiter_unit_disabled_recorder_records_and_allocates_no_event(
+        monkeypatch):
+    import tracemalloc
+
+    from dynamo_tpu.runtime import overload as overload_mod
+    from dynamo_tpu.runtime import tracing
+    rec = tracing.SpanRecorder(capacity=64, enabled=False)
+    monkeypatch.setattr(tracing, "_RECORDER", rec)
+    clk = FakeClock(10.0)
+    lim = AdaptiveLimiter(OverloadConfig(
+        initial_concurrency=2, target_latency_ms=100,
+        decrease_cooldown_s=0.0), clock=clk)
+    journal_off = overload_mod.journal.get_journal()
+    monkeypatch.setattr(journal_off, "enabled", False)
+
+    def churn(n):
+        for _ in range(n):
+            lim._observe(0.01)   # crosses an integer every few calls
+            lim._observe(1.0)    # a decrease every time (no cooldown)
+
+    churn(50)  # warm the counters' keys
+    tracemalloc.start()
+    try:
+        before = tracemalloc.take_snapshot()
+        churn(500)
+        after = tracemalloc.take_snapshot()
+    finally:
+        tracemalloc.stop()
+    grown = sum(st.size_diff for st in after.compare_to(before, "filename")
+                if st.traceback[0].filename.endswith(
+                    ("overload.py", "tracing.py")) and st.size_diff > 0)
+    assert grown < 2048, grown
+    assert rec.snapshot() == ([], 0)
+    assert lim.limit_changes["decrease"] == 550
+
+
+def test_limiter_unit_decrease_is_a_journal_event_with_its_cause(span_ring):
+    from dynamo_tpu.runtime import journal
+    from dynamo_tpu.runtime.journal import EventKind
+
+    async def run():
+        clk = FakeClock(50.0)
+        lim = AdaptiveLimiter(OverloadConfig(
+            initial_concurrency=10, target_latency_ms=5000), clock=clk)
+        j = journal.get_journal()
+        seq0 = j.snapshot(limit=1)["seq"]
+        await _complete(lim, 1.2)   # an increase is no decision to journal
+        await _complete(lim, 7.5)
+        mine = [e for e in j.since(seq0)[0]
+                if e["kind"] == EventKind.LIMIT_DECREASE]
+        assert len(mine) == 1
+        ev = mine[0]
+        assert ev["cause"] is None
+        attrs = ev["attrs"]
+        assert attrs["judged_ms"] == 7500.0 and attrs["target_ms"] == 5000
+        assert attrs["after"] == pytest.approx(attrs["before"] * 0.7,
+                                               abs=2e-3)
+        # With a chaos injection active, the decrease names it.
+        with chaos.active("seed=1;engine.stall_ms=x1"):
+            assert chaos.fire("engine.stall_ms", "engine")
+            injected = journal.recent_ref(EventKind.CHAOS_INJECT)
+            assert injected is not None
+            clk.advance(2.0)
+            await _complete(lim, 7.5)
+        caused = [e for e in j.since(seq0)[0]
+                  if e["kind"] == EventKind.LIMIT_DECREASE][-1]
+        assert caused["cause"] == injected
+
+    asyncio.run(run())
+
+
+REPLAY = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
+                      "limiter_replay.json")
+
+
+async def replay_limiter(make_limiter, config: dict, ops: list) -> list:
+    """Drive one limiter through ``ops`` on a fake clock and return, per
+    op, what an observer of admission can see: the grants and sheds the
+    op caused (in order), then ``limit`` (as float.hex: bit for bit),
+    ``inflight`` and the waiting count."""
+    clk = FakeClock(1000.0)
+    lim = make_limiter(OverloadConfig(**config), clk)
+    events: list = []
+    held: list = []        # (id, permit), in grant order
+    pending: dict = {}     # id -> task still inside admit()
+
+    async def arrive(rid, priority, deadline_ms):
+        try:
+            permit = await lim.admit(priority, deadline_ms)
+        except (OverloadedError, RateLimitedError) as exc:
+            events.append(["shed", rid, type(exc).__name__, exc.shed_reason,
+                           float(exc.retry_after_s).hex()])
+        except asyncio.CancelledError:
+            events.append(["left", rid])
+        else:
+            held.append((rid, permit))
+            events.append(["grant", rid])
+        finally:
+            pending.pop(rid, None)
+
+    trace = []
+    for op in ops:
+        kind = op["op"]
+        if kind == "arrive":
+            pending[op["id"]] = asyncio.ensure_future(
+                arrive(op["id"], op["priority"], op["deadline_ms"]))
+        elif kind == "complete" and held:
+            rid, permit = held.pop(op["k"] % len(held))
+            if op["latency_s"] is not None:
+                permit.note_latency(op["latency_s"])
+            permit.release()
+        elif kind == "leave" and pending:
+            pending[sorted(pending)[op["k"] % len(pending)]].cancel()
+        elif kind == "advance":
+            clk.advance(op["dt"])
+        for _ in range(4):
+            await asyncio.sleep(0)
+        trace.append({"events": events[:], "limit": lim.limit.hex(),
+                      "inflight": lim.inflight, "waiting": lim.waiting()})
+        events.clear()
+    for task in pending.values():
+        task.cancel()
+    return trace
+
+
+def test_limiter_unit_admits_exactly_as_the_parent_of_pr_36_did():
+    """Counting its decisions changed nothing the limiter decides: one
+    recorded sequence of arrivals, completions, latencies and departures
+    replayed against today's limiter gives the trace the limiter of the
+    parent commit gave (tests/data/limiter_replay.json, written by
+    running this same ``replay_limiter`` over the parent's
+    ``runtime/overload.py``): every grant and shed in order, ``limit``
+    bit for bit, ``inflight`` and the queue after each step."""
+    import json
+    with open(REPLAY, encoding="utf-8") as fh:
+        pinned = json.load(fh)
+    got = asyncio.run(replay_limiter(
+        lambda cfg, clk: AdaptiveLimiter(cfg, clock=clk),
+        pinned["config"], pinned["ops"]))
+    assert len(got) == len(pinned["trace"]) >= 600
+    for i, (mine, theirs) in enumerate(zip(got, pinned["trace"])):
+        assert mine == theirs, (i, pinned["ops"][i], mine, theirs)
+    # The sequence is worth replaying: it reaches every kind of decision.
+    kinds = {(e[0], *e[2:4]) for step in got for e in step["events"]}
+    assert {("grant",), ("left",),
+            ("shed", "OverloadedError", "queue_full"),
+            ("shed", "RateLimitedError", "deadline"),
+            ("shed", "RateLimitedError", "priority")} <= kinds, kinds
+    limits = [float.fromhex(step["limit"]) for step in got]
+    falls = sum(b < a for a, b in zip(limits, limits[1:]))
+    assert falls >= 4 and max(limits) == 12.0 and min(limits) == 4.0
 
 
 def test_config_unit_overload_env_and_toml_layering(tmp_path, monkeypatch):

@@ -237,8 +237,7 @@ def test_ledger_unit_ctx_attribution_and_slo_feed():
         id = "r1"
         trace_id = "t" * 32
         values = {"worker_id": "3f2a", "migrations": 2,
-                  "reuse_tokens": 128, "kv_hit_ratio": 0.5,
-                  "queue_wait_s": 0.25}
+                  "reuse_tokens": 128, "kv_hit_ratio": 0.5}
 
     clk = FakeClock()
     plane = make_plane(clk, ttft_p99_ms=0.0, goodput=0.9, min_events=1)
@@ -248,7 +247,7 @@ def test_ledger_unit_ctx_attribution_and_slo_feed():
                    ledger=ledger, slo_plane=plane)
     rec = ledger.recent(1)[0]
     assert rec["worker_id"] == "3f2a" and rec["migrations"] == 2
-    assert rec["reuse_tokens"] == 128 and rec["queue_wait_s"] == 0.25
+    assert rec["reuse_tokens"] == 128 and rec["queue_wait_s"] is None
     assert rec["reason"] == "deadline" and rec["status"] == "shed"
     good, total = plane._series["goodput"].window(300)
     assert (good, total) == (0, 1)  # shed = bad for goodput
@@ -371,6 +370,34 @@ def test_flight_steady_state_zero_allocations():
             if grown <= 0:
                 break
         assert results[-1][0] <= 0, (name, results)
+
+
+def test_flight_trigger_freezes_for_the_copy_and_loses_no_row(
+        tmp_path, monkeypatch):
+    """The ring is frozen while ``trigger`` copies it, not while the
+    bundle is written: the row the engine hands in next is kept (a
+    reader of that window finds ``missed`` 0), and the bundle holds the
+    rows before the anomaly under the reason that froze them."""
+    monkeypatch.setattr(flight, "_bundle_dir", str(tmp_path))
+    monkeypatch.setattr(flight, "_last_trigger_t", -1e18)
+    rec = flight.get_recorder()
+    rec.thaw()
+    rec.clear()
+    for i in range(3):
+        assert rec.record(10.0 + i, 0.01, 2, 0, 10, 0, 0, 0, 1, 0.0, i)
+    assert flight.trigger("unit_copy") is True
+    assert rec.frozen is False
+    assert rec.record(13.0, 0.01, 2, 0, 10, 0, 0, 0, 1, 0.0, 3) is True
+    got = rec.between(9.0, 14.0)
+    assert (got["rows"], got["missed"]) == (4, 0)
+    for _ in range(100):   # the writer renames a finished file into place
+        bundles = list(tmp_path.glob("flight-*unit_copy*.json"))
+        if bundles:
+            break
+        time.sleep(0.02)
+    bundle = json.loads(bundles[0].read_text())
+    assert [w["step"] for w in bundle["flight"]["windows"]] == [0, 1, 2]
+    assert bundle["flight"]["meta"]["frozen_reason"] == "unit_copy"
 
 
 def test_flight_trigger_throttles_and_writes_bundle(tmp_path):

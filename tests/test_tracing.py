@@ -520,3 +520,83 @@ async def test_profile_endpoint(tmp_path):
     finally:
         await server.stop()
         await runtime.close()
+
+
+# -- the permit on the request's own span (PR 36) -------------------------------
+
+@async_test(timeout=120)
+async def test_http_request_span_is_the_permit_and_carries_what_was_judged():
+    """``http.request`` is held exactly as long as the permit, so the spans
+    that cover an instant are the permits held at it, and each carries the
+    permit-to-first-token time the limiter judged as ``permit_to_first_ms``."""
+    from test_overload import start_frontend, start_mocker, wait_model
+    from dynamo_tpu.runtime.coordinator import Coordinator
+    from dynamo_tpu.runtime.overload import OverloadConfig
+
+    coord = Coordinator()
+    await coord.start()
+    mocker = await start_mocker(coord, max_num_seqs=8, decode_step_s=0.004)
+    rt, manager, watcher, service = await start_frontend(
+        coord, overload=OverloadConfig(
+            seed=3, initial_concurrency=3, max_concurrency=3,
+            min_concurrency=3, queue_depth=16, default_deadline_ms=60_000,
+            target_latency_ms=60_000))
+    rec = get_recorder()
+    limiter = service.overload
+    try:
+        await wait_model(manager)
+
+        async def post(session, i):
+            async with session.post(
+                    f"http://127.0.0.1:{service.port}/v1/chat/completions",
+                    json={"model": "mock-model", "max_tokens": 100 + 30 * i,
+                          "messages": [{"role": "user",
+                                        "content": f"hold {i}"}]}) as resp:
+                assert resp.status == 200
+                await resp.json()
+
+        seen = []   # (instant, permits held at it, callers queued at it)
+
+        async def watch(done):
+            while not done.is_set():
+                seen.append((time.monotonic(), limiter.inflight,
+                             limiter.waiting()))
+                await asyncio.sleep(0.01)
+
+        done = asyncio.Event()
+        watcher_task = asyncio.ensure_future(watch(done))
+        async with aiohttp.ClientSession() as session:
+            await asyncio.gather(*(post(session, i) for i in range(7)))
+        done.set()
+        await watcher_task
+        spans = rec.snapshot()[0]
+        reqs = [s for s in spans if s.name == "http.request"]
+        waits = [s for s in spans if s.name == "http.admit_wait"]
+        assert len(reqs) == 7 == len(waits)
+        for s in reqs:
+            first = s.attrs["permit_to_first_ms"]
+            assert 0 < first <= s.duration_s * 1e3
+        assert limiter.limit_changes["increase"] == 7  # each one judged
+        # Away from a span's own edges (a sample may fall between the
+        # grant and the span's first instruction), the cover IS the count.
+        edges = [t for s in reqs + waits for t in (s.start_mono, s.end_mono)]
+        compared = 0
+        for t, inflight, waiting in seen:
+            if any(abs(t - e) < 0.015 for e in edges):
+                continue
+            compared += 1
+            assert sum(s.start_mono <= t <= s.end_mono for s in reqs) \
+                == inflight, t
+            assert sum(s.start_mono <= t <= s.end_mono for s in waits) \
+                == waiting, t
+        assert compared >= 10 and max(n for _, n, _ in seen) == 3
+        assert max(w for _, _, w in seen) >= 3
+    finally:
+        await service.stop()
+        await watcher.stop()
+        mrt, engine, server = mocker
+        await engine.stop()
+        await server.shutdown()
+        await mrt.close()
+        await rt.close()
+        await coord.stop()
