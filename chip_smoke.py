@@ -22,8 +22,13 @@ program's init seed), and checks what comes out by the repo's own means:
              KV heads of 128, a share of the experts, a window the longest
              prompt passes: a page of 32 there), and on the DeepSeek-V3.2
              block at toy depth over a pool of latent entries (640 | 128
-             lanes, a page of 64: the latent reader); ``tpu_custom_call``
-             must be in the compiled window program wherever a kernel runs
+             lanes, a page of 64: the latent reader), then the same block
+             with the published 64 index heads over prompts past the 2,048
+             keys it keeps (the indexer's kernel: a row's live pages of
+             index keys walked and scored, against XLA's gather of the
+             bucket; select_topk chooses over either's scores);
+             ``tpu_custom_call`` must be in the compiled window program
+             wherever a kernel runs
   disagg     prefill engine -> KV plane -> decode engine on the one chip;
              tokens must equal the aggregated engine's
 
@@ -496,9 +501,10 @@ async def phase_server(args, jax, rng) -> dict:
     return keep
 
 
-def small_config(args, spec, **kw):
+def small_config(args, spec, context: int = 1024, **kw):
     """Engines of the later phases: small pool, small chunk size (so a
-    600-token prompt is chunked), short windows."""
+    600-token prompt is chunked), short windows, ``context`` tokens a
+    sequence."""
     from dynamo_tpu.engine.config import EngineConfig
     if args.rehearse_cpu:
         sizes = dict(prefill_buckets=(32, 64, 128), max_prefill_tokens=64,
@@ -509,8 +515,8 @@ def small_config(args, spec, **kw):
     cfg = EngineConfig(model=spec, max_num_seqs=8, decode_window=8,
                        pipeline_depth=2, **sizes, **kw)
     # The page is the launcher's ("auto": derived where the kernel reads
-    # the pool on a TPU, 16 elsewhere); the context stays 1,024 tokens.
-    cfg.max_pages_per_seq = 1024 // cfg.page_size
+    # the pool on a TPU, 16 elsewhere); the context stays in tokens.
+    cfg.max_pages_per_seq = context // cfg.page_size
     return cfg
 
 
@@ -520,10 +526,12 @@ async def phase_kernels(args, jax, rng, keep: dict):
     variant, which "auto" does not select), then what "auto" resolves to on
     a small head_dim-128 model (the kernel on one TPU device, XLA on the
     CPU rehearsal) against "xla" on the same weights, at 4 KV heads, on
-    the Cohere2-MoE block at 8 KV heads under 16 query rows each, and on the
-    DeepSeek-V3.2 block over a pool of latent entries. Same prompts at mixed
-    lengths per round. Returns the served model's bf16 XLA engine (the
-    disagg phase's aggregated reference)."""
+    the Cohere2-MoE block at 8 KV heads under 16 query rows each, on the
+    DeepSeek-V3.2 block over a pool of latent entries (every key attended:
+    the reader), and on that block past 2,048 tokens of context (the
+    indexer chooses: its kernel). Same prompts at mixed lengths per round.
+    Returns the served model's bf16 XLA engine (the disagg phase's
+    aggregated reference)."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -565,30 +573,45 @@ async def phase_kernels(args, jax, rng, keep: dict):
         moe_intermediate_size=128, num_routed_experts=2,
         num_shared_experts=1, first_k_dense=1,
         rope_yarn=(40.0, 4096, 32.0, 1.0, 1.0))
-    pages = {wide.name: 64, share.name: 32, latent.name: 64}  # derived
-    lengths = (20, 70, 150) if args.rehearse_cpu else (24, 200, 700)
+    # The indexer's round: the same block with the published 64 index heads
+    # of 128 (the kernel's dot: [64, 128] against a chunk's keys) and
+    # prompts PAST the 2,048 keys a query keeps, so the indexer chooses in
+    # every layer of every decode step (a fifth of the longest prompt's
+    # keys dropped; a key that swaps sides at rank 2,048 carries a
+    # two-thousandth of a query). On the chip the kernel walks a row's
+    # live pages of index keys and scores them; XLA gathers every slot's
+    # bucket and scores the copy; select_topk chooses over either's.
+    indexed = dataclasses.replace(latent, name="smoke-latent-index",
+                                  index_n_heads=64)
+    pages = {wide.name: 64, share.name: 32, latent.name: 64,
+             indexed.name: 64}  # derived
+    short = (20, 70, 150) if args.rehearse_cpu else (24, 200, 700)
+    past_topk = (20, 2100) if args.rehearse_cpu else (24, 2200, 2600)
+    assert max(short) + 64 < indexed.index_topk < min(past_topk[1:])
     n_out = 20
     on_tpu = jax.devices()[0].platform == "tpu"
+    # The rehearsal interprets the kernels: "auto" is XLA's there.
+    kernels = "pallas" if args.rehearse_cpu else "auto"
     agg = None
-    for spec_r, params_r, quant_kv, backends in (
-            (spec, params, None, ("xla", "pallas")),
-            (spec, params, "int8", ("xla", "pallas")),
-            (wide, None, None, ("xla", "auto")),
-            (share, None, None, ("xla", "auto")),
-            # The rehearsal interprets the kernel: "auto" is XLA's there.
-            (latent, None, None,
-             ("xla", "pallas" if args.rehearse_cpu else "auto"))):
+    for spec_r, params_r, quant_kv, backends, lengths, context in (
+            (spec, params, None, ("xla", "pallas"), short, 1024),
+            (spec, params, "int8", ("xla", "pallas"), short, 1024),
+            (wide, None, None, ("xla", "auto"), short, 1024),
+            (share, None, None, ("xla", "auto"), short, 1024),
+            (latent, None, None, ("xla", kernels), short, 1024),
+            (indexed, None, None, ("xla", kernels), past_topk, 4096)):
         prompts = [rng.integers(2, spec_r.vocab_size, size=n).tolist()
                    for n in lengths]
         runs = {}
         # Both engines of a round at the page the round's second backend
         # resolves: the kernel is compared with XLA at the derived page.
-        page = small_config(args, spec_r, quant_kv=quant_kv,
+        page = small_config(args, spec_r, context, quant_kv=quant_kv,
                             attention_backend=backends[1]).page_size
         check(page == (pages.get(spec_r.name, 16) if on_tpu else 16),
               f"{spec_r.name} under {backends[1]}: page of {page} tokens")
         for backend in backends:
-            eng = TPUEngine(small_config(args, spec_r, quant_kv=quant_kv,
+            eng = TPUEngine(small_config(args, spec_r, context,
+                                         quant_kv=quant_kv,
                                          attention_backend=backend,
                                          page_size=page),
                             params=params_r)
@@ -606,6 +629,10 @@ async def phase_kernels(args, jax, rng, keep: dict):
             # place beside the kernel on a plain bf16 pool at head_dim 128,
             # so the third round compares it with the scatter's logprobs;
             # a latent pool's is in place on a TPU under either reader.
+            # Whoever walks a latent pool's entries walks its index keys.
+            index = eng.runner.index_backend
+            check(index == (resolved if spec_r.latent else None),
+                  f"{resolved} reader of {spec_r.name}: indexer {index}")
             commit = eng.runner.kv_commit_backend
             check((commit == "in_place") == (
                 on_tpu if spec_r.latent else
@@ -620,6 +647,13 @@ async def phase_kernels(args, jax, rng, keep: dict):
                 check(len(toks) == n_out == len(lps), "short output")
             chunks = eng.chunk_tokens_total
             check(chunks > 0, "the longest prompt was not chunk-prefilled")
+            selected = None
+            if spec_r.latent:
+                # Did the indexer choose? Keys attended of keys in context.
+                selected = eng.perf_status()["attn"]["selected_pct"]
+                check((selected < 100) == (lengths is past_topk),
+                      f"{spec_r.name} at {lengths}: {selected} % of the "
+                      f"keys in context attended")
             custom_call = None
             if resolved == "pallas":
                 # The window program that just served: is the kernel in it?
@@ -643,6 +677,7 @@ async def phase_kernels(args, jax, rng, keep: dict):
             emit("kernels.run", model=spec_r.name,
                  quant_kv=quant_kv or "bf16", attention_backend=backend,
                  resolved=resolved, kv_commit_backend=commit,
+                 index_backend=index, attn_selected_pct=selected,
                  page_size=eng.runner.page_size,
                  prompt_lengths=lengths, chunk_tokens=chunks,
                  seconds=round(seconds, 2), tpu_custom_call=custom_call)

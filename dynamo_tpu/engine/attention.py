@@ -76,6 +76,16 @@ gathered row, PERF.md section 6, PR 34). Measured on one v5e (PERF.md
 section 6, PR 35): 17 live rows of 32 slots at 3,000 to 5,000 tokens, nine
 layers: XLA's walk (the bucket of all slots gathered, read twice more)
 11.3 ms a step, this 1.7.
+
+The indexer's scores over a latent pool (latent_index_pallas, PR 37): the
+same walk over the pool's other array. A row's live pages of index keys (16
+KB a copy, 32 pages a turn) meet its 64 index queries in one product a 512
+tokens, then the ReLU and the sum over the heads under the float32 head
+weights: one float32 score a key, model.index_scores' value. The choice
+over them stays model.select_topk's, in XLA. XLA's indexer gathers every
+slot's whole bucket and scores the copy: alone on one v5e, nine layers, 32
+slots, a bucket of 5,120 tokens, 1.45 ms a step at 17 live rows and 1.46 at
+32, this 0.73 and 1.18 (PERF.md section 6, PR 37, call 3).
 """
 
 from __future__ import annotations
@@ -720,6 +730,142 @@ def latent_history_pallas(qe: jax.Array, e_cache: jax.Array,
     page_table = jnp.pad(page_table, ((0, 0), (0, pages - maxp)))
     return _latent_flash(qe, e_cache, layer, page_table, hist_lens, bias,
                          scale=scale, rank=rank, interpret=interpret)
+
+
+#: Tokens of index keys one turn of the indexer's kernel fetches and waits
+#: for: four of the reader's chunks. An index-key page is a fifth of an
+#: entry's bytes and a turn's arithmetic a tenth of the reader's, so the
+#: turn's fixed costs (cursor, waits) show sooner: on one v5e, nine layers,
+#: 17 live rows of 32 at 3,000 to 5,020 tokens, the indexer with its choice
+#: took 0.93 ms a step at 1,024 tokens a turn, 0.92 at 2,048 and 0.92 at
+#: 4,096 (PERF.md section 6, PR 37, call 3).
+INDEX_CHUNK_TOKENS = 2048
+#: Tokens one product of that kernel scores: [64 heads, 512] float32 is half
+#: the vector registers.
+INDEX_SCORE_TOKENS = 512
+
+
+def _index_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
+                  iq_ref, iw_ref, k_hbm,    # VMEM blocks; the pool (ANY)
+                  out_ref,                  # output
+                  k_buf, sems, g_ref, cur_ref,  # scratch
+                  *, page_size: int):
+    """The indexer's scores over a latent pool, one grid program per batch
+    row: the row's index query (iq_ref [1, J, Di] bfloat16, the dot's J
+    sublanes) against its LIVE pages of index keys, ONE copy a page into
+    k_buf [slot, 1, pages, page, Di] through _fetch_pipeline. A chunk is
+    scored out_ref's last axis of tokens at a time: the products in
+    bfloat16 into float32, the ReLU, and the sum over the heads under the
+    row's float32 head weights (iw_ref [J, rows on the lanes], every row's,
+    whole in VMEM: a [J, 1] block a row would reach the kernel padded to
+    128 lanes, a copy of 1 MB a layer): model.index_scores. Out go the
+    scores of what the row walked, out_ref [1, table tokens / tokens,
+    tokens] float32; a part of a chunk past the row's length is not scored
+    and what lies there is its caller's to mask."""
+    ppc, di = k_buf.shape[2], k_buf.shape[4]
+    tokens = out_ref.shape[2]
+    b = pl.program_id(0)
+    nb = pl.num_programs(0)
+    chunk_tokens = ppc * page_size
+    parts, part_pages = chunk_tokens // tokens, tokens // page_size
+    seq_len = seq_lens_ref[b]
+    num_chunks = pl.cdiv(seq_len, chunk_tokens)
+    issue_fetch, wait_fetch, prime = _fetch_pipeline(
+        page_table_ref, seq_lens_ref, cur_ref, ((k_hbm, k_buf),), sems,
+        layer_ref[0], nb, page_size, lambda row: 0)
+
+    @pl.when(b == 0)
+    def _():
+        g_ref[0] = 0
+        prime()
+
+    iq = iq_ref[0]
+    weights = iw_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, weights.shape, 1)
+    iw = jnp.sum(jnp.where(lane == b, weights, 0.0), axis=1, keepdims=True)
+
+    def body(c, carry, g0):
+        slot = jax.lax.rem(g0 + c, SLOTS)
+        wait_fetch(b, c, slot)
+        for j in range(parts):
+            @pl.when(c * chunk_tokens + j * tokens < seq_len)
+            def _(j=j):
+                keys = k_buf[slot, 0, j * part_pages:(j + 1) * part_pages]
+                dots = jax.lax.dot_general(
+                    iq, keys.reshape(tokens, di), (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)       # [J, tokens]
+                out_ref[0, pl.ds(c * parts + j, 1), :] = jnp.sum(
+                    jnp.maximum(dots, 0.0) * iw, axis=0, keepdims=True)
+        issue_fetch()
+        return carry
+
+    g0 = g_ref[0]
+    jax.lax.fori_loop(0, num_chunks, functools.partial(body, g0=g0), 0)
+    g_ref[0] = g0 + num_chunks
+
+
+# dtpu: ignore[unregistered-jit] -- inner kernel: only ever traced INSIDE registered runner programs (inlined), never dispatched standalone from the serving loop
+@functools.partial(jax.jit, static_argnames=("tokens", "chunk_tokens",
+                                             "interpret"))
+def _latent_index(iq, iw, i_cache, layer, page_table, hist_lens,
+                  tokens: int, chunk_tokens: int, interpret: bool):
+    """_index_kernel over a page table of whole chunks, iw [J, lanes]. Its
+    own jit, as _latent_flash."""
+    b, heads, di = iq.shape
+    page_size = i_cache.shape[3]
+    parts = page_table.shape[1] * page_size // tokens
+    return pl.pallas_call(
+        functools.partial(_index_kernel, page_size=page_size),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b,),
+            in_specs=[pl.BlockSpec((1, heads, di), lambda i, *_: (i, 0, 0)),
+                      pl.BlockSpec(iw.shape, lambda i, *_: (0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, parts, tokens),
+                                   lambda i, *_: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((SLOTS, 1, chunk_tokens // page_size, page_size,
+                            di), i_cache.dtype),
+                pltpu.SemaphoreType.DMA((1, SLOTS)),
+                pltpu.SMEM((1,), jnp.int32), pltpu.SMEM((3,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((b, parts, tokens), jnp.float32),
+        # Sequential: the fetch pipeline runs from one row into the next.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), page_table, hist_lens,
+      iq, iw, i_cache)
+
+
+def latent_index_pallas(iq: jax.Array, iw: jax.Array, i_cache: jax.Array,
+                        layer: jax.Array, page_table: jax.Array,
+                        hist_lens: jax.Array, interpret: bool = False,
+                        table: int | None = None) -> jax.Array:
+    """The decode indexer's scores of the cache-resident index keys of a
+    latent pool: index queries iq [B, J, Di] under head weights iw [B, J]
+    float32 against i_cache [L, 1, P, page, Di] of ``layer`` (the FULL
+    stacked pool: the kernel copies pages), a row's first hist_lens [B]
+    tokens under ``page_table`` [B, maxP]. Returns [B, maxP * page]
+    float32, model.index_scores' value (bfloat16 products, float32 sums;
+    the heads summed in another order) at every token a row holds and
+    UNDEFINED past it: model.select_topk takes them under its ``valid``.
+    XLA's indexer gathers every slot's whole bucket and scores the copy;
+    this reads a row's live pages once (PERF.md section 6, PR 37).
+    ``table``, ``interpret``: as latent_history_pallas (one trace of the
+    kernel for every page-table bucket)."""
+    b, heads = iw.shape
+    page_size = i_cache.shape[3]
+    tokens = max(INDEX_SCORE_TOKENS, page_size)
+    chunk_tokens = max(INDEX_CHUNK_TOKENS, tokens)
+    maxp = page_table.shape[1]
+    pages = pl.cdiv(max(table or 0, maxp) * page_size, chunk_tokens) \
+        * chunk_tokens // page_size
+    page_table = jnp.pad(page_table, ((0, 0), (0, pages - maxp)))
+    iw = jnp.pad(iw.T, ((0, 0), (0, pl.cdiv(b, 128) * 128 - b)))
+    scores = _latent_index(iq, iw, i_cache, layer, page_table, hist_lens,
+                           tokens=tokens, chunk_tokens=chunk_tokens,
+                           interpret=interpret)
+    return scores.reshape(b, -1)[:, :maxp * page_size]
 
 
 def _commit_kernel(pid_ref, r0_ref, m0_ref, n_ref,  # SMEM prefetch, [B*J]

@@ -847,8 +847,15 @@ def pool_access(attention_backend: str, platform: str, mesh_size: int,
     twice more: 8.0 ms of a 21.6 ms decode step on one v5e at 17 live rows
     of 32 (PERF.md section 5, PR 34); the kernel reads a row's live pages
     once, one copy a page, with the indexer's choice as its mask (PERF.md
-    section 6, PR 35). The indexer (a row's index keys gathered over the
-    bucket, scored, the choice) is XLA's under either reader. A requested
+    section 6, PR 35). The indexer's scores follow the reader: whoever
+    walks a row's entries walks its index keys under the same page table
+    (runner.index_backend is the reader's name for a latent pool, None for
+    a block without an indexer). XLA's gathers the index keys of every
+    slot's bucket and scores the copy; attention.latent_index_pallas reads
+    a row's live pages of index keys once and returns a float32 score a
+    key: the indexer of a decode step 1.36 -> 0.52 ms on one v5e at 17 live
+    rows of 32 (PERF.md section 6, PR 37). The choice over the scores
+    (model.select_topk) is XLA's under either. A requested
     backend is returned as asked. The writer on one TPU device is
     "in_place" under either reader: both read the row-major pool as it
     lies (XLA's gather takes whole [page, width] blocks by their leading
@@ -874,31 +881,32 @@ XLA_BUCKET_TOKENS = 1024
 
 
 def window_page_bucket(needed: int, reader: str, page_size: int,
-                       max_pages: int, latent: bool = False) -> int:
+                       max_pages: int) -> int:
     """Page-table width of the decode window whose longest row holds
     ``needed`` pages, by who still pays for the bucket: a power of two from
     8 up to ``max_pages``. An XLA gather reads the bucket of EVERY slot
     whatever the rows hold, so its time follows the bucket and not the
     rows: past two steps of XLA_BUCKET_TOKENS such a bucket is a multiple
     of that step, and a step's time follows the longest row within 1,024
-    tokens. That is the XLA ``reader`` (``pool_access``'s) of any pool, and
-    a ``latent`` pool under EITHER reader while its indexer is XLA's: the
-    Pallas reader walks a row's live entries alone, but the index keys are
-    still gathered over the bucket (32 slots x bucket x 256 B a layer) and
-    ``select_topk`` counts over it 32 times. The Pallas kernel over K and V
-    pages pays nothing for a wide table: its buckets stay powers of two
-    (fewer programs). Measured where XLA's walk served a latent pool on one
-    v5e over six seeds each (PERF.md section 6, PR 34): at powers of two a
-    step was a third longer from the moment one row passed 4,096 tokens
-    (`out_tok_s` 652); steps of 1,024 tokens 739, of 512 tokens 757 for six
-    more window programs to compile and no steadier a median time per
-    token. Not measured over K and V pages (no cell is on the XLA side
-    there): the gather's cost by bucket is PR 26's."""
+    tokens. That is the XLA ``reader`` (``pool_access``'s) of any pool, K
+    and V pages or latent entries and their index keys. A Pallas reader
+    walks a row's live pages alone and pays nothing for a wide table: its
+    buckets stay powers of two (fewer programs). For a latent pool that
+    holds since its indexer's scores are the kernel's too: what still
+    follows the bucket there is XLA's ``select_topk`` (32 counts over
+    [slots, bucket]) and the reader's mask, 0.1 ms of a 14.4 ms step at
+    5,120 tokens on one v5e, and ``tpot_p50_ms`` of the latent cell read
+    within 0.11 % at steps of 1,024 tokens and at powers of two, with four
+    window programs fewer to compile (PERF.md section 6, PR 37 (5)). Where
+    XLA's walk served that pool a step was a third longer at powers of two
+    from the moment one row passed 4,096 tokens (PERF.md section 6, PR 34).
+    Not measured over K and V pages (no cell is on the XLA side there): the
+    gather's cost by bucket is PR 26's."""
     b = 8
     while b < needed and b < max_pages:
         b *= 2
     step = max(8, XLA_BUCKET_TOKENS // page_size)
-    if (reader == "xla" or latent) and b > 2 * step:
+    if reader == "xla" and b > 2 * step:
         b = -(-needed // step) * step
     return min(b, max_pages)
 

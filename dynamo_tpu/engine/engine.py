@@ -1084,6 +1084,9 @@ class TPUEngine(AsyncEngine):
             # _pick_kv_commit: config.pool_access).
             "attention_backend": self.runner.attention_backend,
             "kv_commit_backend": self.runner.kv_commit_backend,
+            # Who scores a latent pool's index keys in decode; None for a
+            # block without an indexer.
+            "index_backend": self.runner.index_backend,
             # Tokens a KV page holds (config.resolve_page_size): over 16
             # where the page was derived for the Pallas reader.
             "page_size": self.runner.page_size,

@@ -1033,7 +1033,7 @@ def latent_window_attention(q: LatentQuery, e_cache: jax.Array,
                             e_win: jax.Array, i_win: jax.Array, m: jax.Array,
                             e_self: jax.Array, i_self: jax.Array,
                             spec: ModelSpec, live: jax.Array | None = None,
-                            reader=None):
+                            kernels=None):
     """Decode attention of the latent block for step ``m`` of a window, in
     the ABSORBED form: a head's query is folded through Wk_b into the
     latent's space (qa_h = q_nope_h Wk_b[h]^T), scores and the weighted
@@ -1048,15 +1048,20 @@ def latent_window_attention(q: LatentQuery, e_cache: jax.Array,
     hold more than ``index_topk`` keys the indexer scores every key in
     context and the row attends the index_topk of largest score.
 
-    Who reads the pool's entries (config.pool_access): ``reader``
-    (attention.latent_history_pallas, on one TPU device) walks each row's
-    live pages once with the choice as its mask, and hands back a running
-    maximum, sum and weighted sum that are merged here with the window's
-    columns and the self token; None (the CPU, any mesh) is XLA's walk,
-    which gathers the whole bucket of every slot and reads the copy twice
-    more. The indexer is XLA's under either. Returns (attention
-    [B, Nh * v_head_dim], float32 [2]: keys attended and keys in context,
-    summed over the ``live`` rows)."""
+    Who reads the pool (config.pool_access): ``kernels``, on one TPU
+    device, is the pair a runner binds (attention.latent_history_pallas,
+    attention.latent_index_pallas). The indexer's kernel walks each row's
+    live pages of index keys once and returns a float32 score a key; the
+    reader's walks the row's live entries once with the choice as its mask,
+    and hands back a running maximum, sum and weighted sum that are merged
+    here with the window's columns and the self token. None (the CPU, any
+    mesh) is XLA's walk, which gathers the whole bucket of every slot from
+    both arrays, scores the index keys' copy and reads the entries' twice
+    more. Either way XLA scores the keys that are not in the pool yet (the
+    window's columns, the self token) and the choice over all of them stays
+    ``select_topk``'s: PERF.md section 6, PR 37 has the measurements.
+    Returns (attention [B, Nh * v_head_dim], float32 [2]: keys attended and
+    keys in context, summed over the ``live`` rows)."""
     b = hist_lens.shape[0]
     nh, r = spec.num_heads, spec.kv_lora_rank
     page = e_cache.shape[3]
@@ -1069,12 +1074,20 @@ def latent_window_attention(q: LatentQuery, e_cache: jax.Array,
          jnp.broadcast_to(jnp.arange(M)[None, :] < m, (b, M)),
          jnp.ones((b, 1), bool)], axis=1)                    # [B, K]
     chosen = seen
+    reader, indexer = kernels or (None, None)
     if hist + M + 1 > spec.index_topk:
         with scope("attn.index"):
-            ki = gather_pages_folded(i_cache, layer, page_table)[0]
-            score = jnp.concatenate(
-                [index_scores(q.iq[:, None], q.iw[:, None], keys)[:, 0]
-                 for keys in (ki, i_win[0], i_self)], axis=-1)
+            def scores(keys):
+                return index_scores(q.iq[:, None], q.iw[:, None], keys)[:, 0]
+
+            if indexer is None:
+                old = scores(gather_pages_folded(i_cache, layer,
+                                                 page_table)[0])
+            else:
+                old = indexer(q.iq, q.iw, i_cache, layer, page_table,
+                              hist_lens)
+            score = jnp.concatenate([old, scores(i_win[0]), scores(i_self)],
+                                    axis=-1)
             chosen = select_topk(score, seen, spec.index_topk)
     # The places XLA scores itself: the window's columns and the self token,
     # and without a reader the gathered history ahead of them.
@@ -1561,7 +1574,7 @@ def decode_forward(params: Params, spec: ModelSpec,
                 return latent_window_attention(
                     q, k_cache, v_cache, layer, page_table, hist_lens,
                     k[None, :, :0], v[None, :, :0], jnp.asarray(0, jnp.int32),
-                    k, v, spec, reader=attention_impl)[0]
+                    k, v, spec, kernels=attention_impl)[0]
             attn = attn_fn(q, k_cache, v_cache, layer, page_table, hist_lens,
                            k, v, spec.q_per_kv,
                            lo=window_lo(spec, kind, positions))  # [B,Nh,D]
@@ -1767,7 +1780,7 @@ def decode_window_step(params: Params, spec: ModelSpec,
             if spec.latent:     # owns its scopes; counts the live rows' keys
                 return latent_window_attention(
                     q, k_cache, v_cache, layer, page_table, hist_lens, kb_l,
-                    vb_l, m, k, v, spec, live, reader=attention_impl)
+                    vb_l, m, k, v, spec, live, kernels=attention_impl)
             with scope("attn.core"):
                 attn = attn_fn(q, k_cache, v_cache, layer, page_table,
                                hist_lens, kb_l, vb_l, m, k, v, spec.q_per_kv,

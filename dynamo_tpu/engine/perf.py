@@ -719,6 +719,14 @@ class PerfMetricsUpdater:
             "config.pool_access): pallas (a kernel walks a row's live "
             "pages: K and V heads, or latent entries) or xla (the gather "
             "of every slot's page-table bucket)", ["backend"])
+        self.g_index = registry.gauge(
+            "perf_index_info", "1 under the label of who runs the decode "
+            "indexer of this worker's latent pool (runner.index_backend, "
+            "config.pool_access): pallas (a kernel walks a row's live pages "
+            "of index keys and scores them) or xla (the gather of every "
+            "slot's page-table bucket, scored); the choice over the scores "
+            "is XLA's under either; no sample for a block without an indexer",
+            ["backend"])
         self.g_kv_page = registry.gauge(
             "perf_kv_page_info", "1 under the label of how many tokens a "
             "KV page of this worker holds (runner.page_size): 16, or the "
@@ -825,6 +833,9 @@ class PerfMetricsUpdater:
         reader = getattr(runner, "attention_backend", None)
         if reader:
             self.g_attention.set(1, backend=reader)
+        indexer = getattr(runner, "index_backend", None)
+        if indexer:
+            self.g_index.set(1, backend=indexer)
         page = getattr(runner, "page_size", None)
         if page:
             self.g_kv_page.set(1, tokens=str(page))

@@ -466,14 +466,18 @@ class ModelRunner:
         platform, the mesh's size, the head dimension and what a token
         leaves in the pool (config.pool_access, which has the
         measurements). A latent pool has ONE reader for the step and the
-        window (model.latent_window_attention's ``reader``): the kernel,
-        or None for XLA's walk."""
+        window, and with it the indexer's scores
+        (model.latent_window_attention's ``kernels``): the bound pair, or
+        None for XLA's walk; ``index_backend`` says which."""
         from dynamo_tpu.engine.model import paged_window_attention_xla
         backend, _ = pool_access(
             self.config.attention_backend, self.device.platform,
             self.mesh.size, self.spec.head_dim, self.quant_kv,
             self.spec.latent)
         self.attention_backend = backend
+        # Whoever reads a latent pool reads BOTH its arrays: the entries and
+        # the index keys the indexer scores (None: a block without one).
+        self.index_backend = backend if self.spec.latent else None
         if backend == "xla":
             if self.spec.latent:
                 return None, None
@@ -485,15 +489,16 @@ class ModelRunner:
         if refusal is not None:
             raise ValueError(f"attention_backend='pallas' {refusal}")
         from dynamo_tpu.engine.attention import (
-            latent_history_pallas, paged_decode_attention_pallas,
-            paged_window_attention_pallas)
+            latent_history_pallas, latent_index_pallas,
+            paged_decode_attention_pallas, paged_window_attention_pallas)
         # Interpret mode exists for the CPU backend only; a chip compiles
         # the kernel through Mosaic or fails.
         interpret = self.device.platform == "cpu"
         if self.spec.latent:
-            return (functools.partial(
-                latent_history_pallas, interpret=interpret,
-                table=self.config.max_pages_per_seq),) * 2
+            bound = dict(interpret=interpret,
+                         table=self.config.max_pages_per_seq)
+            return ((functools.partial(latent_history_pallas, **bound),
+                     functools.partial(latent_index_pallas, **bound)),) * 2
         return (functools.partial(paged_decode_attention_pallas,
                                   interpret=interpret),
                 functools.partial(paged_window_attention_pallas,
@@ -823,7 +828,9 @@ class ModelRunner:
             "decode_window", run_window, key=key, donate_argnums=donate,
             labels={"attention_backend": self.attention_backend,
                     "kv_commit_backend": self.kv_commit_backend,
-                    "page_size": self.config.page_size})
+                    "page_size": self.config.page_size,
+                    **({"index_backend": self.index_backend}
+                       if self.index_backend else {})})
         self._window_cache[key] = fn
         return fn
 
@@ -1296,12 +1303,10 @@ class ModelRunner:
 
     def bucket_pages_for(self, needed: int) -> int:
         """Page-table width bucket for the decode window
-        (config.window_page_bucket, by the reader this runner resolved and
-        by what its pool holds)."""
+        (config.window_page_bucket, by the reader this runner resolved)."""
         return window_page_bucket(needed, self.attention_backend,
                                   self.config.page_size,
-                                  self.config.max_pages_per_seq,
-                                  latent=self.spec.latent)
+                                  self.config.max_pages_per_seq)
 
     def decode_window(self, packed: np.ndarray, window: int):
         """Dispatch one M-step decode window.

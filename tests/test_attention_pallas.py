@@ -410,19 +410,22 @@ LATENT_CASES = [
 
 @pytest.mark.parametrize("table, form, m, weights", LATENT_CASES)
 def test_latent_reader_matches_the_xla_walk(table, form, m, weights):
-    """attention.latent_history_pallas (interpreted) as
-    model.latent_window_attention's ``reader`` against XLA's walk (reader
-    None), as the window step calls it (eight window columns of which
+    """attention.latent_history_pallas and latent_index_pallas (interpreted)
+    as model.latent_window_attention's ``kernels`` against XLA's walk
+    (None), as the window step calls it (eight window columns of which
     ``m`` are written) and as decode_forward does (no window columns): rows
     without history, with one token, at a page's edge and at the bucket's
     end in one batch, the table and the mask padded to the widest table the
-    caller has; the attention and the counts [attended, in context]."""
+    caller has; the attention and the counts [attended, in context]. The
+    indexer's kernel scores the pool's index keys where the table can hold
+    more than index_topk keys; under it no indexer runs."""
     import functools
 
     import jax
 
     from dynamo_tpu.engine import model
-    from dynamo_tpu.engine.attention import latent_history_pallas
+    from dynamo_tpu.engine.attention import (latent_history_pallas,
+                                             latent_index_pallas)
     from dynamo_tpu.engine.config import DeepseekV32Spec
     from dynamo_tpu.engine.quant import quantize_weight
     spec = DeepseekV32Spec(
@@ -466,14 +469,15 @@ def test_latent_reader_matches_the_xla_walk(table, form, m, weights):
             normal(b, 1, 128))
     live = jnp.asarray([False, True, True, True])
 
-    def run(reader):
+    def run(kernels):
         return jax.jit(lambda *a: model.latent_window_attention(
-            *a, spec, live, reader=reader))(*args)
+            *a, spec, live, kernels=kernels))(*args)
 
     want, want_counts = run(None)
-    # As the runner binds it: one kernel for every table up to 48 pages.
-    got, got_counts = run(functools.partial(latent_history_pallas,
-                                            interpret=True, table=48))
+    # As the runner binds them: one kernel for every table up to 48 pages.
+    bound = dict(interpret=True, table=48)
+    got, got_counts = run((functools.partial(latent_history_pallas, **bound),
+                           functools.partial(latent_index_pallas, **bound)))
     np.testing.assert_array_equal(np.asarray(got_counts),
                                   np.asarray(want_counts))
     context = sum(hist_lens[1:]) + 3 * (m + 1)
@@ -482,3 +486,128 @@ def test_latent_reader_matches_the_xla_walk(table, form, m, weights):
     want, got = (np.asarray(x, np.float32) for x in (want, got))
     assert np.abs(want).max() > 0.05
     np.testing.assert_allclose(got, want, atol=0.01, rtol=0.03)
+
+
+# -- the indexer of a latent pool -----------------------------------------------
+
+#: Rows of one batch, tokens of cache-resident history, at the cell's widths
+#: (64 index heads of 128, a page of 64, 2,048 keys kept, eight window
+#: columns), a page table of 40 pages: a kernel turn is 2,048 tokens, scored
+#: 512 at a time.
+INDEX_ROWS = {
+    "a dead row, one token, inside a page, the table's limit":
+        [0, 1, 3 * LATENT_PAGE + 5, 40 * LATENT_PAGE],
+    "shorter than a chunk, a chunk and a token, a page's edge, over k by one":
+        [1500, 2049, 33 * LATENT_PAGE, 2040],
+}
+
+
+def _index_case(lens, seed, ties=False):
+    """(iq, iw, i_cache, layer, page table, lengths) and XLA's scores of the
+    gathered bucket. ``ties``: every key of the longest row is ONE key."""
+    from dynamo_tpu.engine import model
+    from dynamo_tpu.engine.kv_quant import gather_pages_folded
+    page, table = LATENT_PAGE, 40
+    b = len(lens)
+    rng = np.random.default_rng(seed)
+    pages = b * table + 2
+    i_cache = jnp.asarray(rng.standard_normal((2, 1, pages, page, 128)),
+                          jnp.bfloat16)
+    pt = np.stack([rng.permutation(np.arange(1, pages - 1))[:table]
+                   for _ in range(b)]).astype(np.int32)
+    if ties:
+        i_cache = i_cache.at[:, :, pt[int(np.argmax(lens))]].set(
+            i_cache[:, :, :1, :1, :])
+    iq = jnp.asarray(rng.standard_normal((b, 64, 128)), jnp.bfloat16)
+    iw = jnp.asarray(rng.standard_normal((b, 64)), jnp.float32)
+    layer = jnp.asarray(1, jnp.int32)
+    args = (iq, iw, i_cache, layer, jnp.asarray(pt),
+            jnp.asarray(lens, jnp.int32))
+    want = np.asarray(model.index_scores(
+        iq[:, None], iw[:, None],
+        gather_pages_folded(i_cache, layer, jnp.asarray(pt))[0])[:, 0])
+    return args, want
+
+
+@pytest.mark.parametrize("table", [None, 48, 128],
+                         ids=["its own table", "bound to 48 pages",
+                              "bound to the launcher's limit"])
+@pytest.mark.parametrize("lens", list(INDEX_ROWS.values()),
+                         ids=list(INDEX_ROWS))
+def test_latent_indexer_scores_what_xla_scores(lens, table):
+    """attention.latent_index_pallas (interpreted) against model.index_scores
+    over the gathered bucket: the same float32 score at every token a row
+    holds (bfloat16 products, float32 sums: only the order of the 64 heads'
+    sum differs), whatever table the kernel is bound to (a runner binds its
+    widest, so every bucket's program shares one trace); what lies past a
+    row's length is undefined and select_topk never reads it."""
+    import jax
+
+    from dynamo_tpu.engine.attention import latent_index_pallas
+    args, want = _index_case(lens, seed=len(lens) + (table or 0))
+    got = np.asarray(jax.jit(lambda *a: latent_index_pallas(
+        *a, interpret=True, table=table))(*args))
+    assert got.shape == want.shape == (len(lens), 40 * LATENT_PAGE)
+    assert got.dtype == np.float32
+    for r, n in enumerate(lens):
+        np.testing.assert_allclose(got[r, :n], want[r, :n], rtol=2e-5,
+                                   atol=2e-5 * np.abs(want[r]).max())
+    assert np.abs(want).max() > 10
+
+
+@pytest.mark.parametrize("m", [0, LATENT_WINDOW - 1])
+@pytest.mark.parametrize("ties", [False, True], ids=["distinct", "ties"])
+@pytest.mark.parametrize("lens", list(INDEX_ROWS.values()),
+                         ids=list(INDEX_ROWS))
+def test_the_kernel_s_scores_serve_select_topk_s_set(lens, ties, m):
+    """model.select_topk over the kernel's scores and the nine keys that are
+    not in the pool yet (as model.latent_window_attention joins them)
+    against the same over XLA's scores: the sets are equal wherever the
+    margin exceeds the gap (a key may swap sides only within 1e-5 of the
+    k-th score's scale), exactly k keys a row, every valid key where there
+    are fewer, nothing past a row's length; with ``ties`` every key of the
+    longest row is ONE key, every score ties at the k-th and all are
+    kept."""
+    import jax
+
+    from dynamo_tpu.engine import model
+    from dynamo_tpu.engine.attention import latent_index_pallas
+    M, k = LATENT_WINDOW, 2048
+    b, hist = len(lens), 40 * LATENT_PAGE
+    args, old = _index_case(lens, seed=len(lens) + m, ties=ties)
+    iq, iw = args[:2]
+    rng = np.random.default_rng(m)
+    new = np.concatenate(
+        [np.asarray(model.index_scores(
+            iq[:, None], iw[:, None],
+            jnp.asarray(rng.standard_normal((b, n, 128)), jnp.bfloat16))[:, 0])
+         for n in (M, 1)], axis=-1)
+    valid = np.concatenate(
+        [np.arange(hist)[None] < np.asarray(lens)[:, None],
+         np.broadcast_to(np.concatenate([np.arange(M) < m, [True]]),
+                         (b, M + 1))], axis=-1)
+    got_old = np.asarray(jax.jit(lambda *a: latent_index_pallas(
+        *a, interpret=True, table=48))(*args))
+    # What a row does not hold is undefined: poison it.
+    got_old = np.where(valid[:, :hist], got_old, np.nan).astype(np.float32)
+
+    def choice(old):
+        return np.asarray(model.select_topk(
+            jnp.asarray(np.concatenate([old, new], axis=-1)),
+            jnp.asarray(valid), k))
+
+    want, got = choice(old), choice(got_old)
+    assert (got & ~valid).sum() == 0
+    longest = int(np.argmax(lens))
+    if ties:
+        assert got[longest, :lens[longest]].all()
+        assert got[longest].sum() >= lens[longest] > k
+    else:
+        assert (got.sum(-1) == np.minimum(valid.sum(-1), k)).all()
+    scores = np.concatenate([old, new], axis=-1)
+    for r in range(b):
+        differ = np.flatnonzero(got[r] != want[r])
+        if differ.size:     # only AT the boundary
+            kth = np.sort(scores[r][valid[r]])[-k]
+            assert np.abs(scores[r][differ] - kth).max() \
+                < 1e-5 * np.abs(scores[r][valid[r]]).max()

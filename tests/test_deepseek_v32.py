@@ -297,7 +297,8 @@ def test_the_runner_takes_the_kernel_for_a_latent_pool_where_it_serves():
     one each for a mesh and for int8 pages; a requested "pallas" runs
     interpreted on the CPU, one reader for the decode step and the window;
     "auto" on a TPU is the kernel and on the CPU XLA's walk (no reader)."""
-    from dynamo_tpu.engine.attention import latent_history_pallas
+    from dynamo_tpu.engine.attention import (latent_history_pallas,
+                                             latent_index_pallas)
     spec = read_spec(TOY)
     assert _bare_runner(spec)._pallas_refusal() is None
     assert "one device" in _bare_runner(spec, mesh=2)._pallas_refusal()
@@ -313,45 +314,56 @@ def test_the_runner_takes_the_kernel_for_a_latent_pool_where_it_serves():
     assert runner.attention_backend == "pallas"
     assert runner.kv_commit_backend == "scatter"    # the CPU
     assert runner._attention_impl is runner._window_attention_impl
-    assert runner._attention_impl.func is latent_history_pallas
-    # ... and one kernel for every page-table bucket: the table's limit.
-    assert runner._attention_impl.keywords == {
+    # The reader of the entries and the indexer over the index keys come
+    # together: whoever walks the one walks the other.
+    reader, indexer = runner._attention_impl
+    assert reader.func is latent_history_pallas
+    assert indexer.func is latent_index_pallas
+    assert runner.index_backend == "pallas"
+    # ... and one kernel each for every page-table bucket: the table's limit.
+    assert reader.keywords == indexer.keywords == {
         "interpret": True, "table": runner.config.max_pages_per_seq}
     on_tpu = _bare_runner(spec, platform="tpu")
-    assert on_tpu._pick_attention()[0].keywords == {"interpret": False,
-                                                    "table": 128}
-    assert (on_tpu.attention_backend, on_tpu._pick_kv_commit()) == (
-        "pallas", "in_place")
-    on_cpu = _bare_runner(spec)
-    assert on_cpu._pick_attention() == (None, None)
-    assert (on_cpu.attention_backend, on_cpu._pick_kv_commit()) == (
-        "xla", "scatter")
+    for bound in on_tpu._pick_attention()[0]:
+        assert bound.keywords == {"interpret": False, "table": 128}
+    assert (on_tpu.attention_backend, on_tpu.index_backend,
+            on_tpu._pick_kv_commit()) == ("pallas", "pallas", "in_place")
+    for elsewhere in (_bare_runner(spec), _bare_runner(spec, mesh=4,
+                                                       platform="tpu")):
+        assert elsewhere._pick_attention() == (None, None)
+        assert (elsewhere.attention_backend, elsewhere.index_backend,
+                elsewhere._pick_kv_commit()) == ("xla", "xla", "scatter")
+    # A block without an indexer has no such label.
+    kv_runner = _bare_runner(ModelSpec(head_dim=128, num_heads=4,
+                                       num_kv_heads=4, hidden_size=512),
+                             platform="tpu")
+    kv_runner._pick_attention()
+    assert (kv_runner.attention_backend, kv_runner.index_backend) == (
+        "pallas", None)
 
 
 def test_a_bucket_that_xla_gathers_whole_grows_by_1024_tokens():
     """An XLA gather reads the whole bucket of every slot, so past 2,048
-    tokens such a bucket is a multiple of 1,024 tokens where the K-and-V
-    kernel's, which walks live pages alone, stays a power of two. A latent
-    pool keeps the steps under EITHER reader: its entries are walked by the
-    kernel, its index keys still gathered over the bucket by XLA."""
+    tokens such a bucket is a multiple of 1,024 tokens where a kernel's,
+    which walks live pages alone, stays a power of two. A latent pool
+    follows its reader like any other since the indexer's scores are the
+    kernel's too (PR 37; it kept the steps under either reader while XLA
+    gathered its index keys over the bucket)."""
     from dynamo_tpu.engine.config import window_page_bucket
     needs = (1, 9, 17, 33, 49, 65, 81, 100, 121, 500)
     steps = [8, 16, 32, 48, 64, 80, 96, 112, 128, 128]
+    powers = [8, 16, 32, 64, 64, 128, 128, 128, 128, 128]
     assert [window_page_bucket(n, "xla", 64, 128) for n in needs] == steps
-    assert [window_page_bucket(n, "pallas", 64, 128) for n in needs] == [
-        8, 16, 32, 64, 64, 128, 128, 128, 128, 128]
-    for reader in ("xla", "pallas"):
-        assert [window_page_bucket(n, reader, 64, 128, latent=True)
-                for n in needs] == steps
+    assert [window_page_bucket(n, "pallas", 64, 128) for n in needs] == powers
     # A page of 16: steps of 64 pages past 128.
     assert [window_page_bucket(n, "xla", 16, 512)
             for n in (100, 129, 193, 400)] == [128, 192, 256, 448]
     spec = read_spec(TOY)
-    for platform in ("tpu", "cpu"):
+    for platform, want in (("tpu", powers), ("cpu", steps)):
         runner = _bare_runner(spec, platform=platform)
         runner.config.page_size = 64
         runner._pick_attention()
-        assert runner.bucket_pages_for(65) == 80
+        assert [runner.bucket_pages_for(n) for n in needs] == want
 
 
 # -- the pieces ----------------------------------------------------------------
@@ -587,15 +599,19 @@ TOLERANCE = 0.15
 @pytest.mark.parametrize("quant", [None, "int8"])
 def test_prefill_then_decode_agrees_with_the_reference_with_selection_in_force(
         quant, reader):
-    """Under either reader of the entries (config.pool_access): XLA's walk,
-    and the Pallas kernel (interpreted here) with the choice as its mask."""
-    from dynamo_tpu.engine.attention import latent_history_pallas
+    """Under either side of config.pool_access: XLA's walk and XLA's
+    indexer; the Pallas kernels (interpreted here), the indexer's scores
+    under the same choice."""
+    from dynamo_tpu.engine.attention import (latent_history_pallas,
+                                             latent_index_pallas)
     spec, params, tokens = toy(quant)
     assert FIRST < spec.index_topk == 40 < FIRST + CHUNK
     assert spec.first_k_dense == 1
-    served, counts = served_logits(
-        spec, params, tokens, None if reader == "xla" else functools.partial(
-            latent_history_pallas, interpret=True))
+    impl = None
+    if reader != "xla":
+        impl = (functools.partial(latent_history_pallas, interpret=True),
+                functools.partial(latent_index_pallas, interpret=True))
+    served, counts = served_logits(spec, params, tokens, impl)
     full = reference_logits(spec, params, tokens)
     assert served.shape == full.shape == (2, 2 + SEQ - FIRST - CHUNK,
                                           spec.vocab_size)
@@ -897,6 +913,14 @@ async def test_the_engine_serves_it_counts_its_keys_and_reuses_a_prefix():
             "decode_window").values() if v for part in v.split("+")}
         assert "attn.index" in scopes and "moe.router" in scopes
         status = engine.perf_status()
+        # Who runs the indexer (the CPU under "auto": XLA's), on the pane,
+        # among the window programs' labels and as an info series.
+        assert status["index_backend"] == engine.runner.index_backend \
+            == status["attention_backend"] == "xla"
+        assert "xla" in perf.get_registry().snapshot()["programs"][
+            "decode_window"]["labels"]["index_backend"]
+        assert {fn._labels["index_backend"] for fn in
+                engine.runner._window_cache.values()} == {"xla"}
         attn = status["attn"]
         assert attn["index_topk"] == 40
         assert attn["kv_entry_bytes"] == 3 * (128 + 16) * 2

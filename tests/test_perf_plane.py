@@ -214,6 +214,30 @@ def test_perf_metrics_updater_exports_deltas_and_gauges(monkeypatch):
     assert up.g_hbm_limit.get() == 200
 
 
+@pytest.mark.parametrize("backend", ["pallas", "xla", None])
+def test_the_indexer_s_backend_is_an_info_series(monkeypatch, backend):
+    """dynamo_tpu_perf_index_info{backend}: 1 under the label of who runs
+    the decode indexer of a latent pool (runner.index_backend); no sample
+    at all from a worker whose blocks have no indexer (None, or a runner
+    that predates the attribute)."""
+    from dynamo_tpu.engine import perf as perf_mod
+    monkeypatch.setattr(perf_mod, "_REGISTRY", CompileRegistry())
+    metrics = MetricsRegistry()
+    up = PerfMetricsUpdater(metrics, min_interval_s=0.0)
+    eng = _FakeEngine({})
+    if backend is not None:
+        eng.runner.index_backend = backend
+    up.update(eng, force=True)
+    samples = [line for line in metrics.expose().decode().splitlines()
+               if line.startswith("dynamo_tpu_perf_index_info{")]
+    if backend is None:
+        assert samples == []
+    else:
+        assert len(samples) == 1 and f'backend="{backend}"' in samples[0]
+        assert samples[0].endswith(" 1.0")
+        assert up.g_index.get(backend=backend) == 1
+
+
 # -- flight ring: tokens column stays allocation-free -------------------------
 
 

@@ -179,6 +179,28 @@ def test_latent_kernel_compiles_for_v5e(v5e, table):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("table", [512, 8192])
+def test_latent_index_kernel_compiles_for_v5e(v5e, table):
+    """The indexer of a latent pool at the DeepSeek-V3.2 cell's widths: 64
+    index heads of 128 against index keys of 128 lanes, a page of 64 (one
+    copy of 16 KB a page, 32 pages a turn), 32 rows, nine layers, bound to
+    the launcher's limit as a runner binds it: three chunk buffers of 512
+    KB, every row's head weights and a row's scores of the whole table fit
+    the kernel's VMEM."""
+    from dynamo_tpu.engine.attention import latent_index_pallas
+    b, page = 32, 64
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    lowered = jax.jit(lambda *a: latent_index_pallas(*a, table=128)).lower(
+        s((b, 64, 128), jnp.bfloat16), s((b, 64), jnp.float32),
+        s((9, 1, 5742, page, 128), jnp.bfloat16), s((), jnp.int32),
+        s((b, table // page), jnp.int32), s((b,), jnp.int32))
+    assert lowered.out_info.shape == (b, table)
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
 @pytest.mark.parametrize("rows", [32, 1024, 4096],
                          ids=["masked", "masked at the limit", "grouped"])
 def test_smallthinker_expert_layer_compiles_for_v5e(v5e, rows):
