@@ -158,14 +158,18 @@ def parse_args(argv=None) -> argparse.Namespace:
                         help="adapter ranks pad to this fixed max so "
                              "stacks keep static shapes (checkpoints "
                              "with a larger rank are rejected)")
-    parser.add_argument("--spec-decode", default=None, choices=["ngram"],
+    parser.add_argument("--spec-decode", default=None,
+                        choices=["ngram", "mtp"],
                         help="speculative decoding: 'ngram' = prompt-"
                              "lookup self-drafting verified in-window; "
-                             "serves greedy and temperature/top-k/top-p/"
-                             "seeded sampling (on-device rejection "
-                             "sampling keeps the exact output "
-                             "distribution); logprobs and penalties "
-                             "are not supported under spec decode")
+                             "'mtp' = the model's own prediction module "
+                             "(num_nextn_predict_layers) drafts inside the "
+                             "window program, --spec-k 1. Both serve "
+                             "greedy and temperature/top-k/top-p/seeded "
+                             "sampling (on-device rejection sampling keeps "
+                             "the exact output distribution); penalties "
+                             "are not supported under either, logprobs "
+                             "under 'mtp' only")
     parser.add_argument("--spec-k", type=int, default=3,
                         help="drafts verified per speculative step")
     parser.add_argument("--ttft-budget-ms", type=float, default=None,

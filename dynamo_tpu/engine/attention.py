@@ -582,10 +582,10 @@ def paged_window_attention_pallas(q: jax.Array, k_cache: jax.Array,
 
 
 def _latent_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
-                   q_ref, bias_ref, e_hbm,     # VMEM blocks; the pool (ANY)
-                   acc_ref, m_ref, l_ref,      # outputs
-                   e_buf, sems, g_ref, cur_ref,  # scratch
-                   *, page_size: int, scale: float):
+                   q_ref, *rest,               # [bias_ref if masked], then
+                   # e_hbm: the pool (ANY); acc_ref, m_ref, l_ref: outputs;
+                   # e_buf, sems, g_ref, cur_ref: scratch
+                   page_size: int, scale: float, masked: bool = True):
     """The reader of a LATENT pool, one grid program per batch row: every
     head's absorbed query (q_ref [1, Nh, width]: the dot's left side, Nh
     sublanes) against the row's live pages of latent entries, which are key
@@ -599,7 +599,15 @@ def _latent_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
     length is ever attended), so the kernel walks every LIVE page and masks:
     it compares no token index and gathers no chosen row. Out come the
     unnormalised sum and the running maximum and sum, for XLA to merge with
-    the window's own columns and the self token."""
+    the window's own columns and the self token.
+
+    Not ``masked`` (a block without an indexer: latent_block_pallas): no
+    bias operand; a key is attended iff its index in the row lies in
+    [layer_ref[1], seq_lens_ref[row]), compared against an iota of the
+    chunk's lanes, and q_ref's rows are every query position's heads."""
+    if masked:
+        bias_ref, *rest = rest
+    e_hbm, acc_ref, m_ref, l_ref, e_buf, sems, g_ref, cur_ref = rest
     ppc = e_buf.shape[2]
     width, value = e_buf.shape[4], acc_ref.shape[2]
     b = pl.program_id(0)
@@ -630,7 +638,15 @@ def _latent_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
         e = e_buf[slot, 0].reshape(chunk_tokens, width)
         scores = jax.lax.dot_general(
             q, e, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale + bias_ref[0, c]
+            preferred_element_type=jnp.float32) * scale
+        if masked:
+            scores = scores + bias_ref[0, c]
+        else:
+            key = c * chunk_tokens + jax.lax.broadcasted_iota(
+                jnp.int32, (1, chunk_tokens), 1)
+            scores = jnp.where(
+                (key >= layer_ref[1]) & (key < seq_lens_ref[b]), scores,
+                NEG_INF)
         m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
         p = jnp.exp(scores - m_new)
         alpha = jnp.exp(m - m_new)
@@ -730,6 +746,82 @@ def latent_history_pallas(qe: jax.Array, e_cache: jax.Array,
     page_table = jnp.pad(page_table, ((0, 0), (0, pages - maxp)))
     return _latent_flash(qe, e_cache, layer, page_table, hist_lens, bias,
                          scale=scale, rank=rank, interpret=interpret)
+
+
+# dtpu: ignore[unregistered-jit] -- inner kernel: only ever traced INSIDE registered runner programs (inlined), never dispatched standalone from the serving loop
+@functools.partial(jax.jit, static_argnames=("scale", "rank", "tokens",
+                                             "interpret"))
+def _latent_block_flash(qe, e_cache, layer_lo, page_table, hist_lens,
+                        scale: float, rank: int, tokens: int,
+                        interpret: bool):
+    """_latent_kernel without its mask over qe [B, rows, width]; layer_lo
+    int32 [2] (the pool's layer, the first slot attended). Its own jit, as
+    _latent_flash."""
+    b, rows, width = qe.shape
+    page_size = e_cache.shape[3]
+    value = pl.cdiv(rank, 128) * 128
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, rows, width), lambda i, *_: (i, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=(pl.BlockSpec((1, rows, value), lambda i, *_: (i, 0, 0)),)
+        + (pl.BlockSpec((1, rows, 128), lambda i, *_: (i, 0, 0)),) * 2,
+        scratch_shapes=[
+            pltpu.VMEM((SLOTS, 1, tokens // page_size, page_size, width),
+                       e_cache.dtype),
+            pltpu.SemaphoreType.DMA((1, SLOTS)),
+            pltpu.SMEM((1,), jnp.int32), pltpu.SMEM((3,), jnp.int32)],
+    )
+    stat = jax.ShapeDtypeStruct((b, rows, 128), jnp.float32)
+    acc, m, l = pl.pallas_call(
+        functools.partial(_latent_kernel, page_size=page_size, scale=scale,
+                          masked=False),
+        grid_spec=grid_spec,
+        out_shape=(jax.ShapeDtypeStruct((b, rows, value), jnp.float32),
+                   stat, stat),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(layer_lo, page_table, hist_lens, qe, e_cache)
+    return acc[..., :rank], m[..., 0], l[..., 0]
+
+
+#: Query rows of a slot are padded to a multiple of this for the block
+#: reader: a bfloat16 tile's sublanes.
+BLOCK_ROW_TILE = 16
+
+
+def latent_block_pallas(qe: jax.Array, e_cache: jax.Array, layer: jax.Array,
+                        page_table: jax.Array, hist_lens: jax.Array,
+                        scale: float, rank: int, lo: int = 0,
+                        interpret: bool = False, table: int | None = None):
+    """``latent_history_pallas`` for a latent block WITHOUT an indexer and
+    a block of query positions a slot: qe [B, S * Nh, width] (every
+    position's absorbed heads: a row's live pages are walked ONCE for all
+    of them), every key of slots ``lo`` to hist_lens - 1 attended, so no
+    mask operand: the kernel compares a key's index with the row's bounds.
+    The rows are padded with zeros to a multiple of BLOCK_ROW_TILE (40
+    rows of 2 x 20 heads to 48: a fifth more of the kernel's arithmetic,
+    which rides under the page copies, and 16 KB more of accumulator a
+    slot) and cut off again. ``table`` as latent_history_pallas: the page
+    table is padded to it so that every bucket's window program and both
+    layer scans call one kernel. Returns (unnormalised sum [B, S * Nh,
+    rank], maximum, sum [B, S * Nh]), float32."""
+    b, rows, width = qe.shape
+    page_size = e_cache.shape[3]
+    ppc = pages_per_chunk(page_size, 1, width // 2, e_cache.dtype.itemsize)
+    maxp = page_table.shape[1]
+    page_table = jnp.pad(page_table,
+                         ((0, 0), (0, max(table or 0, maxp) - maxp)))
+    pad = -rows % BLOCK_ROW_TILE
+    qe = jnp.pad(qe, ((0, 0), (0, pad), (0, 0)))
+    layer_lo = jnp.stack([jnp.asarray(layer, jnp.int32),
+                          jnp.asarray(lo, jnp.int32)])
+    acc, m, l = _latent_block_flash(
+        qe, e_cache, layer_lo, page_table, hist_lens, scale=scale,
+        rank=rank, tokens=ppc * page_size, interpret=interpret)
+    return acc[:, :rows], m[:, :rows], l[:, :rows]
 
 
 #: Tokens of index keys one turn of the indexer's kernel fetches and waits
@@ -873,7 +965,7 @@ def _commit_kernel(pid_ref, r0_ref, m0_ref, n_ref,  # SMEM prefetch, [B*J]
                    # kwin_ref, vwin_ref: VMEM blocks [L, Nkv, 1, M, D] f32
                    # k_in, v_in: the pools (ANY), aliased to k_hbm / v_hbm
                    # k_hbm, v_hbm, k_buf, v_buf, sems
-                   tiled: bool = False):
+                   tiled: bool = False, layers: tuple | None = None):
     """One grid program per (row, touched tile of a page): the tile's K and
     V rows of every layer and KV head come into VMEM (one strided copy
     each, the reader's ``hbm.at[layer, :, pid]`` turned to
@@ -885,22 +977,31 @@ def _commit_kernel(pid_ref, r0_ref, m0_ref, n_ref,  # SMEM prefetch, [B*J]
     ``tiled`` (a page of several COMMIT_TILEs): a fifth prefetched vector,
     t0, is the tile's first row in its page, and the copies move those
     rows alone, so the commit costs what a 16-token page costs whatever
-    the page. Where the page is one tile the whole page moves."""
+    the page. Where the page is one tile the whole page moves.
+    ``layers`` (first, count): the pools' layers the window holds, where
+    that is not all of them."""
     if tiled:
         t0_ref, *rest = rest
-    kwin_ref, vwin_ref, k_in, v_in, k_hbm, v_hbm, k_buf, v_buf, sems = rest
-    del k_in, v_in  # the same buffers as the outputs
+    # ``pools`` arrays (two; one where the second pool has no width): their
+    # windows, the pools in (the same buffers as the outputs), the pools
+    # out, their tile buffers, then the semaphores.
+    n_pools = (len(rest) - 1) // 4
+    wins, hbms, bufs = (rest[:n_pools], rest[2 * n_pools:3 * n_pools],
+                        rest[3 * n_pools:4 * n_pools])
+    sems = rest[-1]
+    k_buf = bufs[0]
     i = pl.program_id(0)
     n = n_ref[i]
 
     @pl.when(n > 0)
     def _():
         pid, r0, m0 = pid_ref[i], r0_ref[i], m0_ref[i]
-        pools = ((k_hbm, k_buf, kwin_ref), (v_hbm, v_buf, vwin_ref))
+        pools = tuple(zip(hbms, bufs, wins))
         tile = k_buf.shape[2]
 
         def page_copy(s, hbm, buf, out: bool):
-            rows = hbm.at[:, :, pid]
+            rows = hbm.at[:, :, pid] if layers is None else hbm.at[
+                pl.ds(layers[0], layers[1]), :, pid]
             if tiled:
                 rows = rows.at[:, :, pl.ds(
                     pl.multiple_of(t0_ref[i], tile), tile)]
@@ -964,7 +1065,8 @@ def commit_window_pallas(k_cache: jax.Array, v_cache: jax.Array,
                          k_win: jax.Array, v_win: jax.Array,
                          positions0: jax.Array, cap: jax.Array,
                          seq_lens0: jax.Array, page_table: jax.Array,
-                         interpret: bool = False):
+                         interpret: bool = False,
+                         layers: tuple | None = None):
     """The decode window's commit, in place: the pools [L,Nkv,P,page,D]
     stay where and how they lie (row-major, what the decode kernel reads)
     and are aliased to the outputs; of each live row only the pages its
@@ -977,8 +1079,13 @@ def commit_window_pallas(k_cache: jax.Array, v_cache: jax.Array,
     (kv_cache.PageAllocator shares full pages only). What moves is the
     COMMIT_TILE rows of a page that the tokens fall on, so a larger page
     costs the commit nothing (a page that is no whole number of tiles
-    moves whole)."""
+    moves whole). A pool array of no width (a latent block without an
+    indexer) is handed back as it came. ``layers`` (first, count): the
+    windows hold those layers of the pools alone (a prediction module's
+    layer, whose tokens land one slot on)."""
     L, nkv, _, page_size, _ = k_cache.shape
+    if layers is not None:
+        L = layers[1]
     b, window = k_win.shape[2], k_win.shape[3]
     tile = COMMIT_TILE if page_size % COMMIT_TILE == 0 else page_size
     tiled = tile < page_size
@@ -987,29 +1094,36 @@ def commit_window_pallas(k_cache: jax.Array, v_cache: jax.Array,
     J = prefetch[0].shape[0] // b
     # The two pools share everything but their rows' width (K and V of
     # one head_dim; a latent pool's entry and index key).
+    caches = [(c, w) for c, w in ((k_cache, k_win), (v_cache, v_win))
+              if c.shape[4]]
+    n = len(caches)
     wins = [pl.BlockSpec((L, nkv, 1, window, cache.shape[4]),
                          lambda i, *_: (0, 0, i // J, 0, 0))
-            for cache in (k_cache, v_cache)]
+            for cache, _ in caches]
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     bufs = [pltpu.VMEM((L, nkv, tile, cache.shape[4]), cache.dtype)
-            for cache in (k_cache, v_cache)]
+            for cache, _ in caches]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(b * J,),
-        in_specs=[*wins, any_spec, any_spec],
-        out_specs=(any_spec, any_spec),
-        scratch_shapes=[*bufs, pltpu.SemaphoreType.DMA((2,))],
+        in_specs=[*wins, *[any_spec] * n],
+        out_specs=(any_spec,) * n,
+        scratch_shapes=[*bufs, pltpu.SemaphoreType.DMA((n,))],
     )
     n_pre = len(prefetch)
-    return pl.pallas_call(
-        functools.partial(_commit_kernel, tiled=True) if tiled
-        else _commit_kernel,
+    kernel = _commit_kernel
+    if tiled or layers is not None:
+        kernel = functools.partial(_commit_kernel, tiled=tiled,
+                                   layers=layers)
+    out = pl.pallas_call(
+        kernel,
         grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
-                   jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)),
-        input_output_aliases={n_pre + 2: 0, n_pre + 3: 1},
+        out_shape=tuple(jax.ShapeDtypeStruct(c.shape, c.dtype)
+                        for c, _ in caches),
+        input_output_aliases={n_pre + n + j: j for j in range(n)},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(*prefetch, k_win.astype(jnp.float32), v_win.astype(jnp.float32),
-      k_cache, v_cache)
+    )(*prefetch, *(w.astype(jnp.float32) for _, w in caches),
+      *(c for c, _ in caches))
+    return (out[0], out[1]) if n == 2 else (out[0], v_cache)

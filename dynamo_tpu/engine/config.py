@@ -1,6 +1,6 @@
 """Model and engine configuration.
 
-ModelSpec states five block kinds: the dense Llama / Qwen2 block (QKV bias
+ModelSpec states six block kinds: the dense Llama / Qwen2 block (QKV bias
 by ``qkv_bias``), the Mixtral-style block (``num_experts`` SwiGLU experts of
 the dense width, top-k then softmax, routed on the post-attention norm), the
 SmallThinker block (a router that reads the layer's INPUT, softmax over
@@ -15,8 +15,13 @@ head), and the DeepSeek-V3.2 block (``deepseek_v32``: latent attention whose
 cache holds ONE latent entry and one index key a token, a learned indexer
 that keeps ``index_topk`` keys a query, leading dense layers ahead of the
 expert layers, a grouped sigmoid router with a selection bias and a scaling
-factor, YaRN frequencies on part of a head). ``from_hf_config`` reads each
-from its public ``config.json`` keys as they are spelled there.
+factor, YaRN frequencies on part of a head), and the GLM-4.7-Flash block
+(``glm4_moe_lite``: the same latent block with NO indexer, every query
+attends every key and the cache holds one array, plus ``mtp_layers``
+prediction modules: a whole block of the same kind behind a projection of
+[embedding ; hidden], which drafts the token after next).
+``from_hf_config`` reads each from its public ``config.json`` keys as they
+are spelled there.
 """
 
 from __future__ import annotations
@@ -31,6 +36,11 @@ import os
 #: (``ModelSpec.first_k_dense``): the layer's own names behind this prefix,
 #: stacked over the dense layers alone (model.scan_layers).
 DENSE_PREFIX = "dense_"
+#: What a prediction module's leaves are called there (``ModelSpec.
+#: mtp_layers``): its block's own names behind this prefix, a stack of one,
+#: and ``w_eh`` (the projection of [embedding ; hidden]), ``e_norm``,
+#: ``h_norm`` and ``head_norm`` (model.mtp_leaves).
+MTP_PREFIX = "mtp_"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -123,6 +133,7 @@ class ModelSpec:
     routed_scaling_factor = 1.0
     moe_select_bias = False
     rope_yarn = None                    # plain frequencies theta ** (-2i / d)
+    mtp_layers = 0                      # no prediction module to draft with
     # Weight-only quantization: None (bf16) or "int8" (engine/quant.py —
     # int8 storage, bf16 MXU compute; halves the weight-read roofline and
     # fits full llama-3-8b on one 16 GB v5e).
@@ -164,6 +175,13 @@ class ModelSpec:
         """A token leaves a latent entry and an index key in the cache, not
         K and V (kv_lora_rank > 0)."""
         return self.kv_lora_rank > 0
+
+    @property
+    def pool_layers(self) -> int:
+        """Layers of the two pool arrays: the model's, then one a prediction
+        module (``mtp_layers``), whose block leaves entries of the same
+        width under the same page table."""
+        return self.num_layers + self.mtp_layers
 
     @property
     def kv_entry(self) -> tuple[int, tuple[int, int]]:
@@ -212,8 +230,12 @@ class ModelSpec:
         norms = (1 if self.parallel_block else 2) * h
         embed = v * h * (1 if self.tie_word_embeddings else 2)
         dense = self.first_k_dense
+        # A prediction module: a whole expert layer of the block's kind,
+        # the projection of [embedding ; hidden] and three norms; the
+        # embedding and the head are the model's own.
+        module = self.mtp_layers * (attn + mlp + norms + 2 * h * h + 3 * h)
         return ((self.num_layers - dense) * (attn + mlp + norms)
-                + dense * (attn + 3 * h * i + norms) + embed + h)
+                + dense * (attn + 3 * h * i + norms) + embed + h + module)
 
     def kv_bytes_per_token(self, dtype_bytes: int = 2) -> int:
         """bf16-pool bytes per token (both pool arrays, all layers/heads).
@@ -221,7 +243,7 @@ class ModelSpec:
         EngineConfig.kv_token_bytes() for pool sizing so the int8
         accounting stays honest."""
         heads, widths = self.kv_entry
-        return self.num_layers * heads * sum(widths) * dtype_bytes
+        return self.pool_layers * heads * sum(widths) * dtype_bytes
 
     def weight_read_step_ms(self, hbm_gbps: float, tp: int = 1,
                             pp: int = 1) -> float:
@@ -247,6 +269,8 @@ class ModelSpec:
             return cls._from_cohere2_moe(cfg, path)
         if cfg.get("model_type") == "deepseek_v32":
             return cls._from_deepseek_v32(cfg, path)
+        if cfg.get("model_type") == "glm4_moe_lite":
+            return cls._from_glm4_moe_lite(cfg, path)
         return cls(
             name=cfg.get("_name_or_path", os.path.basename(os.path.dirname(path))),
             vocab_size=cfg["vocab_size"],
@@ -416,9 +440,10 @@ class ModelSpec:
                  "grouped one with a selection bias"),
                 ("moe_layer_freq", 1, "every layer after the leading "
                  "dense ones is an expert layer"),
-                ("num_nextn_predict_layers", 0, "a multi-token-prediction "
-                 "module is a draft that is a module of the model, and no "
-                 "path runs one (ROADMAP R10)"),
+                ("num_nextn_predict_layers", 0, "a prediction module drafts "
+                 "inside the window program (spec_decode mtp), whose verify "
+                 "step attends every key and has no indexer's selection "
+                 "(ROADMAP R10)"),
                 ("num_key_value_heads", cfg["num_attention_heads"],
                  "latent attention expands one latent to every head"),
                 ("n_shared_experts", 1, "the shared experts' outputs are "
@@ -448,8 +473,22 @@ class ModelSpec:
                     float(scaling.get("beta_fast", 32)),
                     float(scaling.get("beta_slow", 1)),
                     float(scaling.get("mscale_all_dim", 0)))
-        share = cfg.get("expert_parallel") or {}
         return DeepseekV32Spec(
+            **cls._latent_fields(cfg, path),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-6),
+            index_n_heads=cfg["index_n_heads"],
+            index_head_dim=cfg["index_head_dim"],
+            index_topk=cfg["index_topk"],
+            rope_yarn=yarn,
+        )
+
+    @staticmethod
+    def _latent_fields(cfg: dict, path: str) -> dict:
+        """What the two latent families' files state under the same keys
+        (``_from_deepseek_v32``, ``_from_glm4_moe_lite``): the widths, the
+        low ranks, a head's parts, the router and the share."""
+        share = cfg.get("expert_parallel") or {}
+        return dict(
             name=cfg.get("_name_or_path")
             or os.path.basename(os.path.dirname(path)),
             vocab_size=cfg["vocab_size"],
@@ -460,7 +499,6 @@ class ModelSpec:
             num_kv_heads=cfg["num_attention_heads"],
             head_dim=cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"],
             rope_theta=float(cfg.get("rope_theta", 10000.0)),
-            rms_norm_eps=cfg.get("rms_norm_eps", 1e-6),
             tie_word_embeddings=cfg.get("tie_word_embeddings", False),
             max_position_embeddings=cfg.get("max_position_embeddings", 8192),
             num_experts=cfg["n_routed_experts"],
@@ -476,14 +514,69 @@ class ModelSpec:
             qk_nope_head_dim=cfg["qk_nope_head_dim"],
             qk_rope_head_dim=cfg["qk_rope_head_dim"],
             v_head_dim=cfg["v_head_dim"],
-            index_n_heads=cfg["index_n_heads"],
-            index_head_dim=cfg["index_head_dim"],
-            index_topk=cfg["index_topk"],
             first_k_dense=cfg.get("first_k_dense_replace", 0),
             n_group=cfg.get("n_group", 1),
             topk_group=cfg.get("topk_group", 1),
             routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
-            rope_yarn=yarn,
+        )
+
+    @classmethod
+    def _from_glm4_moe_lite(cls, cfg: dict, path: str) -> "ModelSpec":
+        """GLM-4.7-Flash's keys (zai-org/GLM-4.7-Flash ``config.json``,
+        ``glm4_moe_lite``): the DeepSeek-V3.2 block's latent attention and
+        router WITHOUT an indexer (no ``index_*`` key: every query attends
+        every key, the pool is one array), plain rope frequencies, and
+        ``num_nextn_predict_layers`` prediction modules that the window
+        program drafts with (``spec_decode="mtp"``). ``n_routed_experts``
+        and ``expert_parallel`` as ``_from_deepseek_v32`` reads them."""
+        reader = "the config reader"
+        for key, want, why in (
+                ("attention_bias", False, "the latent projections have no "
+                 "bias leaves"),
+                ("hidden_act", "silu", "the feed-forward is SwiGLU"),
+                ("scoring_func", "sigmoid", "the router scores with a "
+                 "sigmoid"),
+                ("topk_method", "noaux_tc", "the router's choice is the "
+                 "one with a selection bias"),
+                ("moe_layer_freq", 1, "every layer after the leading "
+                 "dense ones is an expert layer"),
+                ("partial_rotary_factor", 1, "the rope part of a head is "
+                 "qk_rope_head_dim, all of it rotated"),
+                ("num_key_value_heads", cfg["num_attention_heads"],
+                 "latent attention expands one latent to every head"),
+                ("n_shared_experts", 1, "the shared experts' outputs are "
+                 "averaged (ffn_block), which is this block's sum only "
+                 "for one")):
+            got = cfg.get(key, want)
+            if got != want:
+                raise UnsupportedBlockError(
+                    reader, f"glm4_moe_lite with {key} {got!r}: {why}")
+        if not cfg.get("q_lora_rank"):
+            raise UnsupportedBlockError(
+                reader, "glm4_moe_lite without q_lora_rank: the query is "
+                "written down as the low-rank pair alone")
+        if cfg.get("rope_scaling"):
+            raise UnsupportedBlockError(
+                reader, f"glm4_moe_lite with rope_scaling "
+                f"{cfg['rope_scaling']!r}: this family's scaled rotation is "
+                "not written down in this repository")
+        for key in ("index_topk", "index_n_heads", "index_head_dim"):
+            if cfg.get(key):
+                raise UnsupportedBlockError(
+                    reader, f"glm4_moe_lite with {key}: the block with an "
+                    "indexer is read as deepseek_v32")
+        modules = int(cfg.get("num_nextn_predict_layers", 0))
+        if modules > 1:
+            raise UnsupportedBlockError(
+                reader, f"glm4_moe_lite with num_nextn_predict_layers "
+                f"{modules}: the window program chains ONE prediction "
+                "module (a second would draft from the first's output, "
+                "which no path carries)")
+        return DeepseekV32Spec(
+            **cls._latent_fields(cfg, path),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+            index_n_heads=0, index_head_dim=0, index_topk=0,
+            mtp_layers=modules,
         )
 
 
@@ -613,6 +706,13 @@ class DeepseekV32Spec(Cohere2MoeSpec):
     # scale is head_dim ** -0.5 times (0.1 mscale_all_dim ln(factor) + 1)
     # squared.
     rope_yarn: tuple | None = None
+    # Prediction modules (``num_nextn_predict_layers``): each a whole block
+    # of this kind over x_i = [RMS(Emb(t_{i+1})) ; RMS(h_i)] W_eh, h_i the
+    # model's output at position i, whose output through the model's own
+    # head drafts t_{i+2}; its entries are one more layer of the pool.
+    # index_topk 0 (with index_n_heads and index_head_dim 0) is this block
+    # WITHOUT the indexer: every query attends every key.
+    mtp_layers: int = 0
 
     def __post_init__(self):
         super().__post_init__()
@@ -707,6 +807,32 @@ def block_refusals(spec: ModelSpec, config: "EngineConfig | None" = None,
             "speculative decoding (spec_decode)", "the verify step's scores "
             "have no window mask, and sliding_window_layout has a window "
             "layer"))
+    if config.spec_decode == "mtp":
+        if not spec.mtp_layers:
+            out.append(UnsupportedBlockError(
+                "drafting with the model's own prediction module "
+                "(spec_decode mtp)", "the model has no such module "
+                "(num_nextn_predict_layers 0: no mtp_ leaves to draft "
+                "with)"))
+        elif config.spec_k > spec.mtp_layers:
+            out.append(UnsupportedBlockError(
+                "drafting with the model's own prediction module "
+                "(spec_decode mtp)", f"spec_k {config.spec_k} asks for more "
+                f"drafts a step than the model has modules "
+                f"({spec.mtp_layers}): a module drafts one token and "
+                "nothing chains a module on itself"))
+        if config.tp * config.pp * config.dp * config.sp > 1:
+            out.append(UnsupportedBlockError(
+                "drafting with the model's own prediction module "
+                "(spec_decode mtp)", "the drafting window carries a row's "
+                "position and draft on ONE device and was never compared "
+                "with its reference on a mesh"))
+    elif config.spec_decode and spec.mtp_layers:
+        out.append(UnsupportedBlockError(
+            "speculative decoding by n-gram drafting (spec_decode ngram)",
+            "the model's pool has a "
+            "layer of its prediction module's entries, which the n-gram "
+            "program's verify step neither reads nor writes"))
     if config.ring_attention and windowed:
         out.append(UnsupportedBlockError(
             "ring attention", "its blockwise scores have no window mask, "
@@ -744,11 +870,18 @@ def _latent_refusals(spec: ModelSpec, config: "EngineConfig"
             "the host and disk KV tiers (kvbm)", "they move parcels of K "
             "and V pages of one shape, and a latent pool's two arrays "
             "differ in width"))
-    if config.spec_decode:
+    if config.spec_decode == "mtp" and spec.index_topk:
         out.append(UnsupportedBlockError(
-            "speculative decoding (spec_decode)", "the verify step scores "
-            "K and V heads and has neither the absorbed latent product nor "
-            "the indexer's selection"))
+            "drafting with the model's own prediction module (spec_decode "
+            "mtp)", "the drafting window's verify step attends every key "
+            "in context and has no indexer's selection over two query "
+            "positions (index_topk > 0)"))
+    elif config.spec_decode and config.spec_decode != "mtp":
+        out.append(UnsupportedBlockError(
+            "speculative decoding by n-gram drafting (spec_decode ngram)",
+            "its verify step "
+            "(model.decode_window_multi_step) scores K and V heads and has "
+            "no absorbed latent product over a pool of latent entries"))
     if config.ring_attention or config.sp > 1:
         out.append(UnsupportedBlockError(
             "ring and sequence-parallel prefill", "their blockwise scores "
@@ -1183,7 +1316,7 @@ class EngineConfig:
             per_head = sum(w + 4 for w in widths)  # KV_SCALE_BYTES
         else:
             per_head = 2 * sum(widths)
-        return m.num_layers * heads * per_head
+        return m.pool_layers * heads * per_head
 
     def lora_target_shapes(self) -> dict[str, tuple[int, int]]:
         """(d_in, d_out) per LoRA target projection for this model —

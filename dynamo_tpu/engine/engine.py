@@ -145,9 +145,15 @@ class _Window:
     # layers.
     attn: object = None
     # Speculative windows: toks = (outs [m,B,S], emits [m,B],
-    # ndrafts [m,B]); slots snaps carry the ASSUMED advance so
+    # ndrafts [m,B]), or under "mtp" the plain window's five with an axis
+    # of spec_k + 1 positions behind the rows' and "emit" / "drafted"
+    # [m,B] in the fifth; slots snaps carry the ASSUMED advance so
     # processing can correct the host's upper-bound positions.
     spec: bool = False
+    # What the window's verify steps did, summed over its rows when it is
+    # processed: (draft tokens taken in, accepted, live row-steps). The
+    # flight ring's spec_* columns.
+    drafted: tuple = (0, 0, 0)
 
 
 class TPUEngine(AsyncEngine):
@@ -276,6 +282,16 @@ class TPUEngine(AsyncEngine):
         self.spec_m_outer = (max(1, self.decode_window
                                  // (config.spec_k + 1))
                              if config.spec_decode else 0)
+        # The model's own prediction module drafts INSIDE the plain window
+        # program (runner._get_mtp_window): every one of decode_window
+        # scan steps is a draft and a verify of spec_k + 1 positions.
+        self.mtp = config.spec_decode == "mtp"
+        if self.mtp:
+            self.spec_m_outer = self.decode_window
+        # Set to a dict by a check (benchmark/draft_check.py): request id ->
+        # [(index of the token drafted, the draft)] of every draft a drafting
+        # window verified, kept as its readback is walked.
+        self.draft_log: dict | None = None
         self.spec_drafts = 0        # verify steps that had drafts
         self.spec_tokens = 0        # draft tokens proposed
         self.spec_accepted = 0      # draft tokens accepted
@@ -437,13 +453,16 @@ class TPUEngine(AsyncEngine):
             # Spec decode serves the full sampling surface on-device
             # (temperature/top-k/top-p/seed as data in the verify
             # program; every emitted token is exactly target-distributed
-            # via rejection sampling). Still outside it: logprobs (the
-            # verify program has no per-step logprob taps) and OpenAI
-            # penalties (the [B,V] count state doesn't thread through
-            # the spec scan).
+            # via rejection sampling). Still outside it: OpenAI penalties
+            # (the [B,V] count state threads through neither drafting
+            # scan) and, under the n-gram drafter, logprobs (its program,
+            # spec_window, has no per-step taps). Under "mtp" the window
+            # program itself drafts and verifies, the verify has the
+            # target's logits at every emitted position, and logprobs are
+            # served from them.
             s = req.sampling_options
             unsupported = []
-            if s.logprobs is not None:
+            if s.logprobs is not None and not self.mtp:
                 unsupported.append("logprobs")
             if getattr(s, "frequency_penalty", None) or \
                     getattr(s, "presence_penalty", None):
@@ -453,7 +472,8 @@ class TPUEngine(AsyncEngine):
                     f"speculative decoding ({self.config.spec_decode}) "
                     f"does not support: {', '.join(unsupported)}. "
                     f"Disable spec_decode or drop these options "
-                    f"(temperature/top_k/top_p/seed are supported)")
+                    f"(temperature/top_k/top_p/seed are supported"
+                    f"{', logprobs too' if self.mtp else ''})")
         if len(req.token_ids) >= self.config.max_model_len:
             raise ValueError(
                 f"prompt length {len(req.token_ids)} exceeds max model len "
@@ -1087,6 +1107,9 @@ class TPUEngine(AsyncEngine):
             # Who scores a latent pool's index keys in decode; None for a
             # block without an indexer.
             "index_backend": self.runner.index_backend,
+            # Who drafts inside the window program's steps: "mtp" (the
+            # model's own prediction module, spec_decode mtp) or "none".
+            "draft": "mtp" if self.mtp else "none",
             # Tokens a KV page holds (config.resolve_page_size): over 16
             # where the page was derived for the Pallas reader.
             "page_size": self.runner.page_size,
@@ -1137,11 +1160,13 @@ class TPUEngine(AsyncEngine):
             # bytes over m_outer * S is HBM bytes per VERIFIED position —
             # the number the fused multi-token verify keeps near the
             # single-token step's (one weight read covers S positions).
-            cost = (compiles["programs"].get("spec_window") or {}).get(
+            cost = (compiles["programs"].get(
+                "decode_window" if self.mtp else "spec_window") or {}).get(
                 "cost") or {}
             positions = self.spec_m_outer * (self.config.spec_k + 1)
             vb = cost.get("bytes_accessed")
             status["spec"] = {
+                "draft": self.config.spec_decode,
                 "k": self.config.spec_k,
                 "m_outer": self.spec_m_outer,
                 "drafts": self.spec_drafts,
@@ -1190,7 +1215,7 @@ class TPUEngine(AsyncEngine):
         bucket_pages = self.runner.bucket_pages_for(1)
         packed = np.zeros((self.config.max_num_seqs,
                            PK_PREFIX + bucket_pages), np.int32)
-        if self.config.spec_decode:
+        if self.config.spec_decode == "ngram":
             # ONE spec program covers greedy, sampled and seeded verify:
             # temperature/top-k/top-p/seed are data (packed columns),
             # not trace-time specializations, so warming it once also
@@ -1218,26 +1243,25 @@ class TPUEngine(AsyncEngine):
         np.asarray(outs[0])  # force compile + execute
         # The penalized variant too: a first penalized request must not
         # stall every in-flight stream on its compile. One inactive row
-        # with penalty bits set selects it; inactive rows do no work.
-        packed_pen = packed.copy()
-        packed_pen[0, PK_FREQPEN] = np.float32(1.0).view(np.int32)
+        # with penalty bits set selects it; inactive rows do no work. (The
+        # drafting window has none: penalties are refused at validation.)
         # TWICE: under tp > 1, GSPMD re-shards counts_dev in the first
         # penalized program's output (replicated P() in, vocab-sharded
         # out), so the SECOND call traces a new input signature — warm
         # both here or the first real penalized request still pays that
         # second compile (found by the perf plane's recompile detector).
-        for _ in range(2):
-            outs = self.runner.decode_window(packed_pen, self.decode_window)
-            np.asarray(outs[0])
-        packed_seed = packed.copy()
-        packed_seed[0, PK_SEEDED] = 1
-        outs = self.runner.decode_window(packed_seed, self.decode_window)
-        np.asarray(outs[0])
-        packed_both = packed_seed.copy()
-        packed_both[0, PK_FREQPEN] = np.float32(1.0).view(np.int32)
-        for _ in range(2):
-            outs = self.runner.decode_window(packed_both, self.decode_window)
-            np.asarray(outs[0])
+        variants = [({PK_SEEDED: 1}, 1)]
+        if not self.mtp:
+            pen = {PK_FREQPEN: np.float32(1.0).view(np.int32)}
+            variants = [(pen, 2), *variants, ({PK_SEEDED: 1, **pen}, 2)]
+        for columns, times in variants:
+            packed_var = packed.copy()
+            for column, value in columns.items():
+                packed_var[0, column] = value
+            for _ in range(times):
+                outs = self.runner.decode_window(packed_var,
+                                                 self.decode_window)
+                np.asarray(outs[0])
         log.info("warmed window programs M=%d in %.1fs", self.decode_window,
                  time.monotonic() - t0)
         t0 = time.monotonic()
@@ -1925,7 +1949,9 @@ class TPUEngine(AsyncEngine):
             "hbm": hbm_tokens,
             "host": extra_tokens - peer_tokens,
             "peer": peer_tokens}
-        total_prompt_pages = -(-len(prompt) // page)
+        # (A drafting module's entry of the last prompt token lies at the
+        # slot after it: the page of that slot is allocated with the rest.)
+        total_prompt_pages = -(-(len(prompt) + int(self.mtp)) // page)
         need = total_prompt_pages - len(cached_pages)
         new_pages = self.allocator.allocate(need)
         if new_pages is None:
@@ -1946,7 +1972,8 @@ class TPUEngine(AsyncEngine):
         if rest > max_chunk:
             return "chunked"
         first_page = reuse_tokens // page
-        chunk_pages = np.asarray(r.pages[first_page:], np.int32)
+        chunk_pages = np.asarray(
+            r.pages[first_page:-(-len(prompt) // page)], np.int32)
         hist = (np.asarray(r.pages[:first_page], np.int32)
                 if first_page else None)
         return PrefillSeq(
@@ -1955,7 +1982,15 @@ class TPUEngine(AsyncEngine):
             hist_pages=hist, sampling=self._sampling_of(r),
             logprobs=r.req.sampling_options.logprobs is not None,
             penalties=self._penalties_of(r), seed=self._seed_of(r),
-            adapter_id=r.adapter_slot)
+            adapter_id=r.adapter_slot,
+            next_page=self._page_of_slot(r, len(prompt)))
+
+    def _page_of_slot(self, r: _Request, slot: int) -> int:
+        """The page of ``r`` that holds position ``slot``, for a drafting
+        module's entry of the token before it (PrefillSeq.next_page); 0,
+        the scratch page, where nothing drafts or no such page is held."""
+        index = slot // self.config.page_size
+        return int(r.pages[index]) if self.mtp and index < len(r.pages) else 0
 
     def _plan_prefill_multimodal(self, r: _Request, mm: list[dict]):
         """Plan a prompt with encoder-embedding spans (reference
@@ -2023,19 +2058,22 @@ class TPUEngine(AsyncEngine):
             if sl.any():
                 emb, emb_mask = full_emb[start:start + n], sl
         tokens = np.asarray(r.tokens_all[start:start + n], np.int32)
+        next_page = self._page_of_slot(r, start + n)
         if not final:
             return PrefillSeq(
                 tokens=tokens, start_pos=start, chunk_pages=chunk_pages,
                 hist_pages=hist if len(hist) else None,
                 sampling=(0.0, 0, 1.0), embeds=emb, embeds_mask=emb_mask,
-                adapter_id=r.adapter_slot)
+                adapter_id=r.adapter_slot,
+                next_token=int(r.tokens_all[start + n]), next_page=next_page)
         return PrefillSeq(
             tokens=tokens, start_pos=start, chunk_pages=chunk_pages,
             hist_pages=hist if len(hist) else None,
             sampling=self._sampling_of(r),
             logprobs=r.req.sampling_options.logprobs is not None,
             penalties=self._penalties_of(r), seed=self._seed_of(r),
-            embeds=emb, embeds_mask=emb_mask, adapter_id=r.adapter_slot)
+            embeds=emb, embeds_mask=emb_mask, adapter_id=r.adapter_slot,
+            next_page=next_page)
 
     def _dispatch_prefill_chunks(self) -> bool:
         """One scheduling pass over the prefilling requests: dispatch at
@@ -2311,6 +2349,11 @@ class TPUEngine(AsyncEngine):
         # without admitting anyone (tried before the chip; not measured on
         # this chip, ROADMAP D6).
         M = self.decode_window
+        if self.mtp:
+            # The most a row advances: every step's drafts accepted. The
+            # pages a row needs are taken for that case; processing takes
+            # back what the window did not use (_process_spec_window).
+            M *= cfg.spec_k + 1
         b = cfg.max_num_seqs
         frozen: dict[int, tuple] = {}
         stalled: set[int] = set()
@@ -2338,7 +2381,8 @@ class TPUEngine(AsyncEngine):
                 # readback, and a dispatched window would delay it.
                 satisfied.add(i)
                 continue
-            last_pos = int(self.disp_positions[i]) + M - 1
+            # (A prediction module's entry of a position lies one slot on.)
+            last_pos = int(self.disp_positions[i]) + M - 1 + int(self.mtp)
             # Clamp to the model-length cap AND the request's own length
             # cap: the slot decodes up to its allocated capacity within the
             # window and freezes in-graph (the host emits LENGTH when
@@ -2457,7 +2501,7 @@ class TPUEngine(AsyncEngine):
         # Brownout degradation hook: drop back to plain decode windows
         # while the engine-local pressure level is at/above the
         # configured threshold (0 in config disables the hook).
-        use_spec = bool(self.config.spec_decode)
+        use_spec = self.config.spec_decode == "ngram"
         if (use_spec and self.config.brownout_spec_disable_level
                 and self.brownout_level
                 >= self.config.brownout_spec_disable_level):
@@ -2467,15 +2511,16 @@ class TPUEngine(AsyncEngine):
             outs = self.runner.decode_spec_window(
                 packed, self.spec_m_outer, self.config.spec_k)
         else:
-            outs = self.runner.decode_window(packed, M)
-        for arr in outs:
+            outs = self.runner.decode_window(packed, self.decode_window)
+        # (The drafting window's fifth output holds what its steps emitted.)
+        for arr in (*outs, *(outs[4].values() if self.mtp else ())):
             try:
                 arr.copy_to_host_async()
             except Exception:  # noqa: BLE001 — not all backends support it
                 pass
         return _Window(toks=outs, slots=slots, frozen=frozen, size=M,
                        serial=self._dispatch_serial,
-                       spec=use_spec,
+                       spec=use_spec or self.mtp,
                        t0=time.monotonic(),
                        page_bucket=packed.shape[1] - PK_PREFIX,
                        prefilling=held_without_row)
@@ -2592,10 +2637,29 @@ class TPUEngine(AsyncEngine):
         and CORRECTS its dispatch-time position upper bound down to the
         actual advance (pipelined dispatches assumed the worst case)."""
         page = self.config.page_size
+        lps = top_vs = top_is = None
         with self.phase_clock.phase("engine.readback_wait"):
             outs = np.asarray(w.toks[0])     # [m, B, S]
-            emits = np.asarray(w.toks[1])    # [m, B]
-            ndrafts = np.asarray(w.toks[2])  # [m, B]
+            if self.mtp:
+                # The drafting window (runner._get_mtp_window): the plain
+                # window's outputs with the positions' axis, and in the
+                # same readback what its steps emitted and counted.
+                counted = w.toks[4]
+                emits = np.asarray(counted["emit"])
+                proposed = np.asarray(counted["draft"])     # -1: none
+                ndrafts = (proposed >= 0).astype(np.int64)
+                if any(snap is not None and
+                       snap[0].req.sampling_options.logprobs is not None
+                       for snap in w.slots):
+                    lps, top_vs, top_is = (np.asarray(a)
+                                           for a in w.toks[1:4])
+                w.moe = np.asarray(counted["moe"], np.float64)
+                self.moe_totals += w.moe
+                w.attn = np.asarray(counted["attn"], np.float64)
+                self.attn_totals += w.attn
+            else:
+                emits = np.asarray(w.toks[1])    # [m, B]
+                ndrafts = np.asarray(w.toks[2])  # [m, B]
         self._note_ready(w)
         self._release_ready_pages()
         if self._pending_first:
@@ -2616,6 +2680,13 @@ class TPUEngine(AsyncEngine):
             else:
                 self._requeue_slot(i)
         steps = outs.shape[0]
+        live_rows = [i for i, snap in enumerate(w.slots) if snap is not None]
+        if live_rows:
+            # What the window's verify steps did, for the flight ring.
+            e_live, d_live = emits[:, live_rows], ndrafts[:, live_rows]
+            w.drafted = (int(d_live.sum()),
+                         int(np.maximum(e_live - 1, 0).sum()),
+                         int((e_live > 0).sum()))
         for i, snap in enumerate(w.slots):
             if snap is None:
                 continue
@@ -2628,6 +2699,8 @@ class TPUEngine(AsyncEngine):
                 self._finish_slot(i, register=True)
                 continue
             accepted: list[int] = []
+            lp_out = (([], []) if lps is not None and
+                      r.req.sampling_options.logprobs is not None else None)
             finish = None
             inp = r.last_token
             pos = start
@@ -2639,6 +2712,10 @@ class TPUEngine(AsyncEngine):
                         finish = FinishReason.LENGTH
                     break
                 nd = int(ndrafts[m, i])
+                if nd and self.mtp and self.draft_log is not None:
+                    # The draft of the token after the chained one.
+                    self.draft_log.setdefault(r.ctx.id, []).append(
+                        (len(r.tokens_all), int(proposed[m, i])))
                 if nd:
                     self.spec_drafts += 1
                     self.spec_tokens += nd
@@ -2652,6 +2729,13 @@ class TPUEngine(AsyncEngine):
                         self.allocator.register(r.pages[page_idx],
                                                 new_block)
                     accepted.append(token)
+                    if lp_out is not None:
+                        k = r.req.sampling_options.logprobs or 0
+                        lp_out[0].append(float(lps[m, i, j]))
+                        lp_out[1].append(
+                            [{"token_id": int(top_is[m, i, j, n]),
+                              "logprob": float(top_vs[m, i, j, n])}
+                             for n in range(k)])
                     r.tokens_all.append(token)
                     inp = token
                     finish = self._check_finish(r, token)
@@ -2680,7 +2764,7 @@ class TPUEngine(AsyncEngine):
             self.tokens_generated_total += len(accepted)
             if accepted:
                 r.decode_windows += 1
-            self._emit(r, accepted, finish, None)
+            self._emit(r, accepted, finish, lp_out)
             if finish is not None:
                 self._finish_slot(i, register=True)
 
@@ -2841,7 +2925,9 @@ class TPUEngine(AsyncEngine):
             *(w.moe if w.moe is not None else ()),
             **({} if w.attn is None else {
                 "attn_selected": w.attn[0], "attn_context": w.attn[1]}),
-            prefilling=w.prefilling, admit_stop=self._flight_admit_stop)
+            prefilling=w.prefilling, admit_stop=self._flight_admit_stop,
+            **dict(zip(("spec_drafted", "spec_accepted", "spec_row_steps"),
+                       w.drafted)))
         if accepted:
             # A frozen ring (bundle capture in flight) rejects the row:
             # keep accumulating so the stall/chunk/token/host-time deltas

@@ -145,6 +145,10 @@ def scatter_pages(cache, blocks, flat_pages):
         q, s = kv_quantize(blocks)
         return QuantKV(cache.data.at[:, :, flat_pages].set(q),
                        cache.scale.at[:, :, flat_pages].set(s))
+    if blocks.shape[0] != cache.shape[0]:
+        # The model's layers of a pool that holds a prediction module's
+        # layer behind them (ModelSpec.pool_layers).
+        return cache.at[:blocks.shape[0], :, flat_pages].set(blocks)
     return cache.at[:, :, flat_pages].set(blocks)
 
 
@@ -192,6 +196,8 @@ def scatter_tokens(cache, vals, dest, off):
         q, s = kv_quantize(vals)
         return QuantKV(cache.data.at[:, :, dest, off].set(q),
                        cache.scale.at[:, :, dest, off].set(s))
+    if vals.shape[0] != cache.shape[0]:     # as scatter_pages
+        return cache.at[:vals.shape[0], :, dest, off].set(vals)
     return cache.at[:, :, dest, off].set(vals)
 
 

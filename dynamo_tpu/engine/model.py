@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from dynamo_tpu.engine.config import DENSE_PREFIX, ModelSpec
+from dynamo_tpu.engine.config import DENSE_PREFIX, MTP_PREFIX, ModelSpec
 from dynamo_tpu.engine.kv_quant import (gather_pages_folded, scatter_pages,
                                         scatter_tokens)
 from dynamo_tpu.engine.perf import scope
@@ -130,7 +130,7 @@ def _attention_shapes(spec: ModelSpec, L: int) -> dict:
             "wo": (L, nh * d, h),
         }
     r, qr = spec.kv_lora_rank, spec.q_lora_rank
-    return {
+    shapes = {
         "input_norm": (L, h),
         "post_attn_norm": (L, h),
         "wq_a": (L, h, qr),
@@ -142,12 +142,36 @@ def _attention_shapes(spec: ModelSpec, L: int) -> dict:
         "wk_b": (L, r, nh * spec.qk_nope_head_dim),
         "wv_b": (L, r, nh * spec.v_head_dim),
         "wo": (L, nh * spec.v_head_dim, h),
-        "index_wq_b": (L, qr, spec.index_n_heads * spec.index_head_dim),
-        "index_wk": (L, h, spec.index_head_dim),
-        "index_k_norm": (L, spec.index_head_dim),
-        "index_k_bias": (L, spec.index_head_dim, 1),
-        "index_w": (L, h, spec.index_n_heads),
     }
+    if spec.index_topk:     # the block WITH an indexer
+        shapes.update({
+            "index_wq_b": (L, qr, spec.index_n_heads * spec.index_head_dim),
+            "index_wk": (L, h, spec.index_head_dim),
+            "index_k_norm": (L, spec.index_head_dim),
+            "index_k_bias": (L, spec.index_head_dim, 1),
+            "index_w": (L, h, spec.index_n_heads),
+        })
+    return shapes
+
+
+def _expert_shapes(spec: ModelSpec, L: int) -> dict:
+    """The feed-forward leaves of L expert layers: the router over every
+    expert of the deployment, the experts HELD, shared experts, the
+    selection bias."""
+    h = spec.hidden_size
+    E, ie = spec.num_experts, spec.expert_size
+    shapes = {"moe_gate": (L, h, spec.router_width),
+              "moe_w_gate": (L, E, h, ie),
+              "moe_w_up": (L, E, h, ie),
+              "moe_w_down": (L, E, ie, h)}
+    if spec.num_shared_experts:
+        S = spec.num_shared_experts
+        shapes.update({"shared_w_gate": (L, S, h, ie),
+                       "shared_w_up": (L, S, h, ie),
+                       "shared_w_down": (L, S, ie, h)})
+    if spec.moe_select_bias:
+        shapes["moe_bias"] = (L, spec.router_width, 1)
+    return shapes
 
 
 def param_shapes(spec: ModelSpec) -> dict:
@@ -160,19 +184,7 @@ def param_shapes(spec: ModelSpec) -> dict:
     if spec.parallel_block:             # one norm feeds both branches
         del layers["post_attn_norm"]
     if spec.num_experts:
-        # The router over every expert of the deployment, the experts HELD.
-        E, ie = spec.num_experts, spec.expert_size
-        layers["moe_gate"] = (L, h, spec.router_width)
-        layers["moe_w_gate"] = (L, E, h, ie)
-        layers["moe_w_up"] = (L, E, h, ie)
-        layers["moe_w_down"] = (L, E, ie, h)
-        if spec.num_shared_experts:
-            S = spec.num_shared_experts
-            layers["shared_w_gate"] = (L, S, h, ie)
-            layers["shared_w_up"] = (L, S, h, ie)
-            layers["shared_w_down"] = (L, S, ie, h)
-        if spec.moe_select_bias:
-            layers["moe_bias"] = (L, spec.router_width, 1)
+        layers.update(_expert_shapes(spec, L))
     else:
         layers["w_gate"] = (L, h, i)
         layers["w_up"] = (L, h, i)
@@ -182,6 +194,16 @@ def param_shapes(spec: ModelSpec) -> dict:
         dense = {**_attention_shapes(spec, K), "w_gate": (K, h, i),
                  "w_up": (K, h, i), "w_down": (K, i, h)}
         layers.update({DENSE_PREFIX + k: v for k, v in dense.items()})
+    if spec.mtp_layers:
+        # The prediction module: one expert layer of the block's kind (a
+        # stack of one), the projection of [embedding ; hidden] and the
+        # norms of its two inputs and of its output ahead of the model's
+        # own head (MTP_PREFIX).
+        M = spec.mtp_layers
+        module = {**_attention_shapes(spec, M), **_expert_shapes(spec, M),
+                  "w_eh": (M, 2 * h, h), "e_norm": (M, h), "h_norm": (M, h),
+                  "head_norm": (M, h)}
+        layers.update({MTP_PREFIX + k: v for k, v in module.items()})
     shapes = {
         "embed": (spec.vocab_size, h),
         "final_norm": (h,),
@@ -239,6 +261,11 @@ def param_specs(spec: ModelSpec) -> dict:
                  "w_gate": P("pp", None, "tp"), "w_up": P("pp", None, "tp"),
                  "w_down": P("pp", "tp", None)}
         layers.update({DENSE_PREFIX + k: v for k, v in dense.items()})
+    if spec.mtp_layers:
+        # Served on one device (config.block_refusals): nothing is split.
+        layers.update({k: P("pp", *([None] * (len(v) - 1)))
+                       for k, v in param_shapes(spec)["layers"].items()
+                       if k.startswith(MTP_PREFIX)})
     specs = {
         "embed": P(None, "tp"),
         "final_norm": P(None),
@@ -833,8 +860,8 @@ class LatentQuery(NamedTuple):
     and decode folds into the query and the output (absorbed form)."""
     nope: jax.Array         # [..., Nh, qk_nope_head_dim]
     rope: jax.Array         # [..., Nh, qk_rope_head_dim], rotated
-    iq: jax.Array           # [..., index_n_heads, index_head_dim]
-    iw: jax.Array           # [..., index_n_heads] float32
+    iq: Any                 # [..., index_n_heads, index_head_dim], or None
+    iw: Any                 # [..., index_n_heads] float32 (no indexer: None)
     wk_b: Any               # [kv_lora_rank, Nh * qk_nope_head_dim]
     wv_b: Any               # [kv_lora_rank, Nh * v_head_dim]
 
@@ -985,7 +1012,7 @@ def latent_prefill_attention(q: LatentQuery, entry: jax.Array,
         seen = jnp.concatenate(
             [jnp.broadcast_to(old, (b, s, lh)), seen], axis=-1)
     keys = e.shape[1]
-    if keys > spec.index_topk:
+    if spec.index_topk and keys > spec.index_topk:
         with scope("attn.index"):
             seen = select_topk(index_scores(q.iq, q.iw, ki), seen,
                                spec.index_topk)
@@ -1075,7 +1102,7 @@ def latent_window_attention(q: LatentQuery, e_cache: jax.Array,
          jnp.ones((b, 1), bool)], axis=1)                    # [B, K]
     chosen = seen
     reader, indexer = kernels or (None, None)
-    if hist + M + 1 > spec.index_topk:
+    if spec.index_topk and hist + M + 1 > spec.index_topk:
         with scope("attn.index"):
             def scores(keys):
                 return index_scores(q.iq[:, None], q.iw[:, None], keys)[:, 0]
@@ -1143,6 +1170,242 @@ def latent_window_attention(q: LatentQuery, e_cache: jax.Array,
     counts = jnp.stack([jnp.sum(jnp.sum(chosen, -1) * rows),
                         jnp.sum(jnp.sum(seen, -1) * rows)])
     return out, counts
+
+
+def latent_block_attention(q: LatentQuery, e_cache: jax.Array,
+                           layer: jax.Array, page_table: jax.Array,
+                           hist_lens: jax.Array, e_win: jax.Array,
+                           win_keep: jax.Array, e_blk: jax.Array,
+                           spec: ModelSpec, live: jax.Array | None = None,
+                           reader=None, lo: int = 0, scoped: bool = True):
+    """Attention of a latent block WITHOUT an indexer for a block of S
+    query positions a row (a verify step's chained token and its drafts; a
+    prediction module's inputs), in the absorbed form of
+    ``latent_window_attention``: every query attends every key.
+
+    q's leaves [B, S, ...]; e_cache [L, 1, P, page, width]; the row's keys
+    are the pool's slots ``lo`` to hist_lens - 1 of ``layer`` (``lo`` 1 for
+    a prediction module's layer, whose slot j holds the entry of position
+    j - 1 and whose slot 0 holds nothing), the window's committed columns
+    e_win [B, W, width] where win_keep [B, W] says so (a rejected draft's
+    column is not one), and the block's own entries e_blk [B, S, width],
+    causal. ``reader``: attention.latent_block_pallas bound
+    by a runner (one walk of the row's live pages for all S x Nh query
+    rows, no mask operand), or None for XLA's gather of the whole bucket.
+    ``scoped`` False draws no scope (a module's attention stays in
+    ``mtp``). Returns (attention [B, S, Nh * v_head_dim], float32 [2]: the
+    ``live`` rows' keys in context, counted ONCE a step whatever S, twice
+    over: attended and in context are the same number here)."""
+    b, s = q.nope.shape[:2]
+    nh, r = spec.num_heads, spec.kv_lora_rank
+    page, width = e_cache.shape[3], e_cache.shape[-1]
+    W = e_win.shape[1]
+    sc = scope if scoped else (lambda _name: contextlib.nullcontext())
+    parts = [e_win, e_blk]
+    kept = [jnp.broadcast_to(win_keep[:, None, :], (b, s * nh, W)),
+            jnp.broadcast_to((jnp.arange(s * nh)[:, None] // nh
+                              >= jnp.arange(s)[None, :])[None],
+                             (b, s * nh, s))]
+    if reader is None:
+        with sc("attn.kv_gather"):
+            parts.insert(0, gather_pages_folded(e_cache, layer,
+                                                page_table)[0])
+        pos = jnp.arange(page_table.shape[1] * page)[None, :]
+        old = (pos >= lo) & (pos < hist_lens[:, None])
+        kept.insert(0, jnp.broadcast_to(old[:, None, :],
+                                        (b, s * nh, old.shape[1])))
+    with sc("attn.core"):
+        wk, sk = _weight(q.wk_b, nh)
+        wv, sv = _weight(q.wv_b, nh)
+        nope = q.nope if sk is None else (
+            q.nope.astype(jnp.float32) * sk).astype(q.nope.dtype)
+        qa = jnp.einsum("bshd,rhd->bshr", nope, wk,
+                        preferred_element_type=jnp.bfloat16)
+        qe = jnp.concatenate(
+            [qa, q.rope, jnp.zeros((b, s, nh, width - r - q.rope.shape[-1]),
+                                   qa.dtype)], axis=-1)
+        qe = qe.reshape(b, s * nh, width)
+        scores = [jnp.where(keep, jnp.einsum(
+            "bhe,bke->bhk", qe, e, preferred_element_type=jnp.float32)
+            * spec.attn_scale, -1e30) for keep, e in zip(kept, parts)]
+        tops = [jnp.max(sc_, axis=-1, initial=-1e30) for sc_ in scores]
+        if reader is not None:
+            ctx_h, top_h, total_h = reader(
+                qe, e_cache, layer, page_table, hist_lens,
+                spec.attn_scale, r, lo)
+            tops.append(top_h)
+        top = functools.reduce(jnp.maximum, tops)
+        weights = [jnp.exp(sc_ - top[..., None]) for sc_ in scores]
+        total = sum(jnp.sum(w, axis=-1) for w in weights)
+        ctx = sum(jnp.einsum("bhk,bke->bhe", w.astype(qe.dtype), e,
+                             preferred_element_type=jnp.float32)
+                  for w, e in zip(weights, parts))[..., :r]
+        if reader is not None:
+            w_h = jnp.exp(top_h - top)
+            total = total + total_h * w_h
+            ctx = ctx + ctx_h * w_h[..., None]
+        ctx = (ctx / total[..., None]).reshape(b, s, nh, r)
+        out = jnp.einsum("bshr,rhd->bshd", ctx.astype(qe.dtype), wv,
+                         preferred_element_type=jnp.float32)
+        if sv is not None:
+            out = out * sv
+        out = out.astype(qe.dtype).reshape(b, s, -1)
+    rows = (jnp.ones((b,), jnp.float32) if live is None
+            else live.astype(jnp.float32))
+    keys = jnp.sum((jnp.maximum(hist_lens - lo, 0)
+                    + jnp.sum(win_keep, axis=-1) + 1) * rows)
+    return out, jnp.stack([keys, keys])
+
+
+def mtp_leaves(layers: dict, module: int = 0) -> dict:
+    """The leaves of prediction module ``module`` out of
+    ``params["layers"]`` (``MTP_PREFIX``), under the names its block and
+    ``mtp_block`` read."""
+    return {k[len(MTP_PREFIX):]: jax.tree.map(lambda a: a[module], v)
+            for k, v in layers.items() if k.startswith(MTP_PREFIX)}
+
+
+def mtp_block(params: Params, spec: ModelSpec, hidden: jax.Array,
+              next_tokens: jax.Array, cos: jax.Array, sin: jax.Array,
+              attend, live: jax.Array | None = None,
+              experts_local: bool = False):
+    """The prediction module over positions whose NEXT token is known:
+    x_i = [RMS_e(Emb(t_{i+1})) ; RMS_h(h_i)] W_eh, then one whole block of
+    the model's kind (its own latent entries; ``attend`` reads them), at
+    the rope position of h_i. ``hidden`` [..., H]: the model's output
+    after its final norm; ``next_tokens`` [...]. Returns (y [..., H], the
+    block's fresh entries, its expert layer's stats or None): the draft of
+    t_{i+2} is ``mtp_logits(y_i)``. No scope inside but the expert layer's
+    sub-scopes: the caller draws ``mtp`` around it."""
+    lp = mtp_leaves(params["layers"])
+    eps = spec.rms_norm_eps
+    emb = rms_norm(embed_lookup(params["embed"], next_tokens), lp["e_norm"],
+                   eps)
+    x = mm(jnp.concatenate([emb, rms_norm(hidden, lp["h_norm"], eps)], -1),
+           lp["w_eh"], "...i,ih->...h")
+    y, k, _, stats = transformer_block(
+        x, lp, spec, cos, sin, attend, scoped=False, live=live,
+        experts_local=experts_local)
+    return y, k, stats
+
+
+def mtp_logits(params: Params, spec: ModelSpec, y: jax.Array) -> jax.Array:
+    """Draft logits [..., V] of the module's output y [..., H]: its own
+    norm, the model's own head."""
+    lp = mtp_leaves(params["layers"])
+    x = rms_norm(y, lp["head_norm"], spec.rms_norm_eps)
+    return lm_logits(x.reshape(-1, x.shape[-1]), params, spec).reshape(
+        *y.shape[:-1], -1)
+
+
+def mtp_prefill(params: Params, spec: ModelSpec, k_cache: jax.Array,
+                hidden: jax.Array, tokens: jax.Array, positions: jax.Array,
+                seq_lens: jax.Array, next_token: jax.Array,
+                hist: tuple | None = None, experts_local: bool = False):
+    """The prediction module over a prefill chunk: fills its entries for
+    every position of the chunk (the next token is the chunk's own next,
+    and ``next_token`` [B] after the last valid one: the prompt's next
+    token, or the token just sampled) and drafts after the last.
+
+    The entry of position i is kept at slot i + 1 of the module's layer
+    (pool layer ``spec.num_layers``), the slot of t_{i+1}, so that a
+    page's content is decided by the tokens its hash covers (a cached
+    prefix drafts as cold). hidden [B, S, H] the model's normed output.
+    ``hist`` (hist_table [B, Ph], hist_lens [B], h_prev [B, H]): the row's
+    earlier pages and the model's normed output at the position before the
+    chunk (what the page before it left in the runner's ``mtp_hidden``):
+    that position's entry, which belongs at the chunk's first slot and
+    needs the chunk's first token, is made here with the chunk's own.
+    Returns (blocks [1, 1, B * S / page, page, width] for the chunk's
+    slots; the last valid position's entry [B, width], which belongs at
+    chunk slot seq_lens; draft tokens [B])."""
+    b, s = tokens.shape
+    page, width = k_cache.shape[3], k_cache.shape[4]
+    layer = jnp.asarray(spec.num_layers, jnp.int32)
+    last = jnp.maximum(seq_lens - 1, 0)
+    nxt = jnp.where(jnp.arange(s)[None, :] == last[:, None],
+                    next_token[:, None],
+                    jnp.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1))
+    valid = jnp.arange(s)[None, :] < seq_lens[:, None]
+    mod_hist = None
+    if hist is not None:
+        hist_table, hist_lens, h_prev = hist
+        # One position more, ahead of the chunk's: start - 1, whose next
+        # token is the chunk's first.
+        hidden = jnp.concatenate([h_prev[:, None], hidden], axis=1)
+        nxt = jnp.concatenate([tokens[:, :1], nxt], axis=1)
+        positions = jnp.concatenate([positions[:, :1] - 1, positions], axis=1)
+        valid = jnp.concatenate([jnp.ones((b, 1), bool), valid], axis=1)
+        # Slots 1 .. start - 1 hold the entries of positions 0 .. start - 2.
+        old = gather_pages_folded(k_cache, layer, hist_table)[0][:, 1:]
+        mod_hist = (old, old[..., :0], hist_lens - 1)
+    cos, sin = spec_rope_tables(spec, positions)
+
+    def attend(q, k, v, kind):
+        return latent_prefill_attention(q, k, v, positions, valid, spec,
+                                        hist=mod_hist)
+
+    y, k_new, _ = mtp_block(params, spec, hidden, nxt, cos, sin, attend,
+                            experts_local=experts_local)
+    e = k_new[:, :, 0]                                   # [B, S (+ 1), w]
+    if hist is None:
+        # Slot 0 holds no entry (no position before the first).
+        e = jnp.concatenate([jnp.zeros_like(e[:, :1]), e], axis=1)
+        y = jnp.concatenate([y[:, :1], y], axis=1)
+    # Now e[:, j] belongs at chunk slot j: the entry of position j - 1.
+    blocks = e[:, :s].reshape(1, 1, b * (s // page), page, width)
+    e_last = jnp.take_along_axis(e, last[:, None, None] + 1, axis=1)[:, 0]
+    y_last = jnp.take_along_axis(y, last[:, None, None] + 1, axis=1)[:, 0]
+    draft = jnp.argmax(mtp_logits(params, spec, y_last), axis=-1)
+    return blocks, e_last, draft.astype(jnp.int32)
+
+
+def decode_verify_step(params: Params, spec: ModelSpec, k_cache: jax.Array,
+                       k_buf: jax.Array, win_keep: jax.Array,
+                       tokens: jax.Array,
+                       positions: jax.Array, page_table: jax.Array,
+                       hist_lens: jax.Array, live: jax.Array,
+                       reader=None, experts_local: bool = False):
+    """One verify step INSIDE a drafting window: S = k + 1 tokens a slot
+    (the chained token and its drafts) through the model at once, one read
+    of the weights for S positions. A latent block without an indexer.
+
+    tokens / positions / live [B, S] (``live``: the positions that count);
+    k_buf [W, L', B, width] the window's columns, COLUMN-major so that a
+    step's fresh columns are one contiguous block of it (the model's
+    layers first), win_keep [B, W] which of them hold a committed token;
+    hist_lens [B] cache-resident tokens.
+    Returns (hidden [B, S, H] after the final norm, logits [B, S, V],
+    entries [L, B, S, 1, width], key counts [L, 2], the expert layers'
+    ``moe_load_stats``)."""
+    b, s = tokens.shape
+    with scope("embed"):
+        x = embed_lookup(params["embed"], tokens)
+    with scope("attn.qkv"):
+        cos, sin = spec_rope_tables(spec, positions)
+    L = spec.num_layers
+
+    def layer_fn(x, scan_in):
+        lp, layer = scan_in
+
+        def attend(q, k, v, kind):
+            e_win = jax.lax.dynamic_index_in_dim(k_buf, layer, axis=1,
+                                                 keepdims=False)
+            return latent_block_attention(
+                q, k_cache, layer, page_table, hist_lens,
+                jnp.swapaxes(e_win, 0, 1), win_keep, k[:, :, 0], spec,
+                live[:, 0], reader)
+
+        x, k, _, stats = transformer_block(
+            x, lp, spec, cos, sin, attend, live=live,
+            experts_local=experts_local)
+        return x, (k, *stats)
+
+    x, ys = scan_layers(layer_fn, x, (params["layers"], jnp.arange(L)), spec)
+    with scope("lm_head"):
+        hidden = norm(x, params["final_norm"], spec)
+        logits = lm_logits(hidden.reshape(b * s, -1), params, spec)
+    return (hidden, logits.reshape(b, s, -1), *ys)
 
 
 # ---------------------------------------------------------------------------
@@ -1229,8 +1492,12 @@ def transformer_block(x: jax.Array, lp: dict, spec: ModelSpec,
     if spec.latent:
         # What the token leaves in the cache is the entry (k) and the
         # index key (v); the query carries what attend selects by.
-        with sc("attn.index"):
-            iq, iw, v = index_qk(h, cq, lp, spec, cos, sin)
+        if spec.index_topk:
+            with sc("attn.index"):
+                iq, iw, v = index_qk(h, cq, lp, spec, cos, sin)
+        else:               # no indexer: the second pool has no width
+            iq = iw = None
+            v = k[..., :0]
         q = LatentQuery(nope, rope, iq, iw, lp["wk_b"], lp["wv_b"])
     attn = attend(q, k, v, kind)
     counts = None
@@ -1266,6 +1533,12 @@ def scan_layers(layer_fn, x: jax.Array, xs, spec: ModelSpec):
     both scans give a layer (k, v, counts) is joined along the layer axis,
     what only the expert layers give (their load) follows."""
     dense = spec.first_k_dense
+    if spec.mtp_layers:
+        # A prediction module's leaves (a stack of its own, MTP_PREFIX) are
+        # no layer of the model: mtp_leaves hands them to mtp_block.
+        strip = lambda d: {k: v for k, v in d.items()  # noqa: E731
+                           if not k.startswith(MTP_PREFIX)}
+        xs = strip(xs) if isinstance(xs, dict) else (strip(xs[0]), *xs[1:])
     if not dense:
         return jax.lax.scan(layer_fn, x, xs)
     layers, others = (xs, None) if isinstance(xs, dict) else (xs[0], xs[1:])
@@ -1296,9 +1569,14 @@ def prefill_forward(params: Params, spec: ModelSpec,
                     embeds_mask: jax.Array | None = None,
                     lora: dict | None = None,
                     adapter_ids: jax.Array | None = None,
-                    experts_local: bool = False,
+                    experts_local: bool = False, defer: bool = False,
                     ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Process prompt chunks and write K/V into pages.
+
+    ``defer`` (a model with a prediction module, whose entries join the
+    commit once the next token is sampled): the caches come back as they
+    were and a fourth value holds what the caller commits, (the normed
+    hidden states [B,S,H], k_blocks, v_blocks, flat_pages).
 
     tokens/positions [B,S] (S = bucket, multiple of page_size), page_table
     [B, S//page_size] (pages covering THIS chunk), seq_lens [B] (valid token
@@ -1367,15 +1645,18 @@ def prefill_forward(params: Params, spec: ModelSpec,
         v_blocks = (v_new.reshape(L, b * (s // page), page, nkv, dv)
                     .transpose(0, 3, 1, 2, 4))
         flat_pages = page_table.reshape(-1)
-        # scatter_pages quantizes int8 pools in the same fused commit.
-        k_cache = scatter_pages(k_cache, k_blocks, flat_pages)
-        v_cache = scatter_pages(v_cache, v_blocks, flat_pages)
+        if not defer:
+            # scatter_pages quantizes int8 pools in the same fused commit.
+            k_cache = scatter_pages(k_cache, k_blocks, flat_pages)
+            v_cache = scatter_pages(v_cache, v_blocks, flat_pages)
     with scope("lm_head"):
         x = norm(x, params["final_norm"], spec)
         # Last valid token per sequence.
         last_idx = jnp.maximum(seq_lens - 1, 0)
         x_last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
         logits = lm_logits(x_last, params, spec)
+    if defer:
+        return logits, k_cache, v_cache, (x, k_blocks, v_blocks, flat_pages)
     return logits, k_cache, v_cache
 
 

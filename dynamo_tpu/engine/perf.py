@@ -87,9 +87,12 @@ SCOPES = ("embed", "attn.qkv", "attn.kv_gather", "attn.core", "attn.out",
           "mlp", "lm_head", "sample", "kv.commit")
 #: Scopes BESIDE those, drawn only in programs of one block kind: the latent
 #: block's indexer (its projections, its read and scores of the index keys
-#: in context, the choice). The other blocks' programs have none, so their
-#: names, and SCOPES_VERSION, stand.
-BLOCK_SCOPES = ("attn.index",)
+#: in context, the choice); a drafting window's prediction module (``mtp``:
+#: its projection of [embedding ; hidden], its block with that block's
+#: attention, its norm and its read of the head; the expert layer inside
+#: keeps its sub-scopes: ``mtp+moe.experts``). The other blocks' programs
+#: have none, so their names, and SCOPES_VERSION, stand.
+BLOCK_SCOPES = ("attn.index", "mtp")
 #: Regions INSIDE a scope, drawn only in programs of a routed block (the
 #: expert layer's router and experts and, where the block has them, its
 #: shared experts, inside ``mlp``). An instruction in one
@@ -734,23 +737,35 @@ class PerfMetricsUpdater:
             ["tokens"])
         self.c_spec_draft_tokens = registry.counter(
             "perf_spec_draft_tokens_total", "Speculative draft tokens "
-            "proposed by the on-device n-gram drafter")
+            "proposed on the device: by the n-gram drafter (spec_window) "
+            "or by the model's own prediction module inside the window "
+            "program (spec_decode mtp: perf_draft_info)")
         self.c_spec_accepted_tokens = registry.counter(
             "perf_spec_accepted_tokens_total", "Speculative draft tokens "
-            "accepted by the fused verify (rejection-sampled for "
-            "temperature > 0; exact-match under greedy)")
+            "accepted by the fused verify of either drafter "
+            "(rejection-sampled for temperature > 0; exact-match under "
+            "greedy)")
         self.c_spec_verify_steps = registry.counter(
             "perf_spec_verify_steps_total", "Speculative verify steps by "
             "tokens emitted — the per-window emitted-token histogram "
             "(emitted=1 means no draft accepted; emitted=spec_k+1 means "
-            "the whole draft block landed; emitted=0 a frozen slot)",
+            "the whole draft block landed; emitted=0 a frozen slot); under "
+            "spec_decode mtp a verify step is one scan step of the window "
+            "program, and logprobs are served from its logits",
             ["emitted"])
         self.g_spec_acceptance = registry.gauge(
             "perf_spec_acceptance_rate", "Lifetime accepted/proposed "
-            "draft-token ratio of the speculative verify")
+            "draft-token ratio of the speculative verify (either drafter)")
         self.c_spec_brownout = registry.counter(
             "perf_spec_brownout_windows_total", "Decode windows where "
-            "brownout pressure suspended speculative drafting")
+            "brownout pressure suspended n-gram drafting (a prediction "
+            "module drafts inside the one window program and is not "
+            "suspended)")
+        self.g_draft = registry.gauge(
+            "perf_draft_info", "1 under the label of who drafts inside the "
+            "decode window program's steps: mtp (the model's own "
+            "prediction module, verified in the same step) or none",
+            ["kind"])
         self.c_moe_layer_steps = registry.counter(
             "moe_layer_steps_total", "Routed block: (decode step, expert "
             "layer) pairs with a live row, the denominator of the two "
@@ -840,6 +855,9 @@ class PerfMetricsUpdater:
         if page:
             self.g_kv_page.set(1, tokens=str(page))
         config = getattr(engine, "config", None)
+        if config is not None and hasattr(config, "spec_decode"):
+            self.g_draft.set(1, kind="mtp" if config.spec_decode == "mtp"
+                             else "none")
         if config is not None and hasattr(config, "kv_token_bytes"):
             latent = config.model.latent
             self.g_kv_entry.set(1, kind="latent" if latent else "kv",

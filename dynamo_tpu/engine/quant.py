@@ -31,7 +31,7 @@ from typing import Any, NamedTuple
 
 import numpy as np
 
-from dynamo_tpu.engine.config import DENSE_PREFIX
+from dynamo_tpu.engine.config import DENSE_PREFIX, MTP_PREFIX
 
 
 class QTensor(NamedTuple):
@@ -51,7 +51,11 @@ _LATENT_KEYS = ("wq_a", "wq_b", "wkv_a", "wk_b", "wv_b", "index_wq_b",
                 "index_wk")
 QUANT_LAYER_KEYS = _BLOCK_KEYS + _LATENT_KEYS + tuple(
     DENSE_PREFIX + key for key in ("wo", "w_gate", "w_up", "w_down")
-    + _LATENT_KEYS)
+    + _LATENT_KEYS) + tuple(
+    # A prediction module's block (an expert layer of the latent kind) and
+    # its projection of [embedding ; hidden] (config.MTP_PREFIX).
+    MTP_PREFIX + key for key in ("wo", "w_eh") + _BLOCK_KEYS[7:]
+    + _LATENT_KEYS[:5])
 
 
 def _safe_scale(amax: np.ndarray) -> np.ndarray:

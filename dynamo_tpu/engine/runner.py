@@ -120,6 +120,13 @@ class PrefillSeq:
     embeds_mask: np.ndarray | None = None
     # Resident LoRA adapter slot (0 = base model; engine/lora.py).
     adapter_id: int = 0
+    # A model with a prediction module (ModelSpec.mtp_layers): the prompt's
+    # token after this chunk (-1: the chunk is the last, and the token the
+    # program samples is the next), and the page that holds the slot after
+    # the chunk's last token, where the module's entry of that token is
+    # kept (0, the scratch page: no such page is allocated).
+    next_token: int = -1
+    next_page: int = 0
 
 
 def _mh_put(value, sharding):
@@ -272,7 +279,7 @@ class ModelRunner:
         kv_spec = P("pp", "tp", None, None, None)
         self.kv_sharding = NamedSharding(self.mesh, kv_spec)
         kv_heads, (k_width, v_width) = spec.kv_entry
-        kv_shape = (spec.num_layers, kv_heads, self.num_pages,
+        kv_shape = (spec.pool_layers, kv_heads, self.num_pages,
                     config.page_size, k_width)
         if self.quant_kv == "int8":
             # int8 pages + per-token-per-head f32 scales (zero-init: an
@@ -303,7 +310,7 @@ class ModelRunner:
         self.param_bytes = spec.num_params() * per_weight // shard
         self.kv_pool_bytes = (
             2 * self.num_pages * config.page_size
-            * self._kv_token_head_bytes() * spec.num_layers
+            * self._kv_token_head_bytes() * spec.pool_layers
             * kv_heads) // shard
 
         self._prefill_cache: dict = {}
@@ -332,11 +339,30 @@ class ModelRunner:
         # correct source). Allocated lazily: plain serving never pays.
         self.hist_dev = None
         self.positions_dev = None
+        # Drafting with the model's own prediction module ("mtp"): the
+        # position chains on the device as under "ngram", and with it the
+        # draft of the token after the chained one (-1: none).
+        self.draft_dev = None
+        self.mtp_hidden = None
         if config.spec_decode:
             hist_w = config.max_pages_per_seq * config.page_size
-            self.hist_dev = _mh_zeros(
-                (config.max_num_seqs, hist_w), jnp.int32,
-                NamedSharding(self.mesh, P()))
+            if config.spec_decode == "mtp":
+                self.draft_dev = _mh_put(
+                    np.full((config.max_num_seqs,), -1, np.int32),
+                    NamedSharding(self.mesh, P()))
+                # The model's normed output at the LAST position of every
+                # page, by page id: what the module's entry of that
+                # position needs once the next page's first token is known,
+                # where the page came from the prefix cache and nothing
+                # recomputes it (model.mtp_prefill). Decided by the tokens
+                # the page's hash covers, as the page is.
+                self.mtp_hidden = _mh_zeros(
+                    (self.num_pages, spec.hidden_size), jnp.bfloat16,
+                    NamedSharding(self.mesh, P()))
+            else:
+                self.hist_dev = _mh_zeros(
+                    (config.max_num_seqs, hist_w), jnp.int32,
+                    NamedSharding(self.mesh, P()))
             self.positions_dev = _mh_zeros(
                 (config.max_num_seqs,), jnp.int32,
                 NamedSharding(self.mesh, P()))
@@ -418,7 +444,7 @@ class ModelRunner:
         # The cache shards over tp (heads) AND pp (layers). int8 pages
         # (+ scales) cost ~half the bf16 bytes, so the same budget holds
         # ~2x pages — directly more resident sequences per chip.
-        token_bytes = (2 * self.spec.num_layers * self.spec.kv_entry[0]
+        token_bytes = (2 * self.spec.pool_layers * self.spec.kv_entry[0]
                        * self._kv_token_head_bytes())
         page_bytes = token_bytes * cfg.page_size // max(1, cfg.tp * cfg.pp)
         self.num_pages = max(16, budget // max(1, page_bytes))
@@ -477,7 +503,12 @@ class ModelRunner:
         self.attention_backend = backend
         # Whoever reads a latent pool reads BOTH its arrays: the entries and
         # the index keys the indexer scores (None: a block without one).
-        self.index_backend = backend if self.spec.latent else None
+        self.index_backend = (backend if self.spec.latent
+                              and self.spec.index_topk else None)
+        # A latent block without an indexer, S query positions a slot (the
+        # drafting window's verify step and its module): the reader
+        # without a mask operand, or None for XLA's gather.
+        self._block_reader = None
         if backend == "xla":
             if self.spec.latent:
                 return None, None
@@ -497,6 +528,10 @@ class ModelRunner:
         if self.spec.latent:
             bound = dict(interpret=interpret,
                          table=self.config.max_pages_per_seq)
+            if not self.spec.index_topk:
+                from dynamo_tpu.engine.attention import latent_block_pallas
+                self._block_reader = functools.partial(latent_block_pallas,
+                                                       **bound)
             return ((functools.partial(latent_history_pallas, **bound),
                      functools.partial(latent_index_pallas, **bound)),) * 2
         return (functools.partial(paged_decode_attention_pallas,
@@ -549,8 +584,12 @@ class ModelRunner:
         # re-sampled token respects the penalties. The embeds variant
         # (multimodal prompts) takes encoder embeddings + a mask that
         # override the token table under media spans.
+        # A model whose prediction module drafts: the chunk's commit waits
+        # for the module's entries, which wait for the sampled token.
+        mtp = self.config.spec_decode == "mtp" and bool(spec.mtp_layers)
+
         def step(params, k_cache, v_cache, packed, rng, counts=None,
-                 emb=None, emb_mask=None, lora=None):
+                 emb=None, emb_mask=None, lora=None, page_ends=None):
             start = packed[:, 0]
             n = packed[:, 1]
             hist_lens = packed[:, 2]
@@ -562,6 +601,9 @@ class ModelRunner:
             page_table = packed[:, _PF_HDR + bucket:
                                 _PF_HDR + bucket + bucket_pages]
             hist_table = packed[:, _PF_HDR + bucket + bucket_pages:]
+            if mtp:     # two columns behind the tables (PrefillSeq)
+                hist_table, next_tok, next_page = (
+                    hist_table[:, :-2], packed[:, -2], packed[:, -1])
             # positions: start..start+n-1, pads clamped to the last valid.
             positions = start[:, None] + jnp.minimum(
                 jnp.arange(bucket)[None, :],
@@ -575,13 +617,13 @@ class ModelRunner:
                          and batch % cfg_pp == 0
                          and spec.num_layers % cfg_pp == 0)
             if with_history:
-                logits, k_cache, v_cache = _prefill_with_history(
+                logits, k_cache, v_cache, *deferred = _prefill_with_history(
                     params, spec, k_cache, v_cache, tokens, positions,
                     page_table, seq_lens, hist_table, hist_lens,
                     self._attention_impl, sp_shard=sp_shard,
                     x_embeds=emb, embeds_mask=emb_mask,
                     lora=lora, adapter_ids=adapter_ids,
-                    experts_local=self.experts_local)
+                    experts_local=self.experts_local, defer=mtp)
             elif pipelined:
                 from dynamo_tpu.engine.model import (
                     prefill_forward_pipelined)
@@ -589,14 +631,14 @@ class ModelRunner:
                     params, spec, k_cache, v_cache, tokens, positions,
                     page_table, seq_lens, n_stages=cfg_pp)
             else:
-                logits, k_cache, v_cache = prefill_forward(
+                logits, k_cache, v_cache, *deferred = prefill_forward(
                     params, spec, k_cache, v_cache, tokens, positions,
                     page_table, seq_lens, sp_shard=sp_shard,
                     ring_mesh=(self.mesh if sp_shard
                                and self.config.ring_attention else None),
                     x_embeds=emb, embeds_mask=emb_mask,
                     lora=lora, adapter_ids=adapter_ids,
-                    experts_local=self.experts_local)
+                    experts_local=self.experts_local, defer=mtp)
             with perf.scope("sample"):
                 if penalized:
                     freq = jax.lax.bitcast_convert_type(packed[:, 7],
@@ -629,12 +671,273 @@ class ModelRunner:
                                jnp.zeros((B, TOP_LOGPROBS), jnp.float32),
                                jnp.zeros((B, TOP_LOGPROBS), jnp.int32)),
                     None)
+            if mtp:
+                k_cache, page_ends, draft = self._mtp_prefill_commit(
+                    params, k_cache, v_cache, page_ends, deferred[0], tokens,
+                    positions, seq_lens,
+                    jnp.where(next_tok >= 0, next_tok, sampled),
+                    page_table, next_page,
+                    (hist_table, hist_lens) if with_history else None)
+                return (sampled, lp, top_v, top_i, logits, k_cache, v_cache,
+                        rng, draft, page_ends)
             return sampled, lp, top_v, top_i, logits, k_cache, v_cache, rng
 
         fn = perf.instrumented_jit("prefill", step, key=key,
                                    donate_argnums=(1, 2))
         self._prefill_cache[key] = fn
         return fn
+
+    def _mtp_prefill_commit(self, params, k_cache, v_cache, page_ends,
+                            deferred, tokens, positions, seq_lens,
+                            next_token, page_table, next_page, hist):
+        """Traced inside a prefill program of a model whose prediction
+        module drafts: the module over the chunk (model.mtp_prefill), then
+        ONE page-block commit of the model's layers and the module's, the
+        module's entry of the last valid token into the slot after it (the
+        chunk's pages, or ``next_page``), and the normed output at each
+        full page's last position into ``page_ends`` (mtp_hidden). Returns
+        (k_cache, page_ends, the draft of the token after next [B])."""
+        from dynamo_tpu.engine.kv_quant import scatter_pages
+        from dynamo_tpu.engine.model import mtp_prefill
+        spec = self.spec
+        L, page = spec.num_layers, self.config.page_size
+        hidden, k_blocks, _v_blocks, flat = deferred
+        b, s = tokens.shape
+        if hist is not None:
+            hist_table, hist_lens = hist
+            before = jnp.take_along_axis(
+                hist_table, jnp.maximum(hist_lens // page - 1, 0)[:, None],
+                axis=1)[:, 0]
+            hist = (hist_table, hist_lens, page_ends[before])
+        with perf.scope("mtp"):
+            blocks, e_last, draft = mtp_prefill(
+                params, spec, k_cache, hidden, tokens, positions, seq_lens,
+                next_token, hist, experts_local=self.experts_local)
+        with perf.scope("kv.commit"):
+            k_cache = scatter_pages(
+                k_cache, jnp.concatenate([k_blocks, blocks], axis=0), flat)
+            full = ((jnp.arange(s // page)[None, :] + 1) * page
+                    <= seq_lens[:, None])
+            page_ends = page_ends.at[jnp.where(full, page_table, 0)].set(
+                hidden[:, page - 1::page])
+            table = jnp.concatenate([page_table, next_page[:, None]], axis=1)
+            valid = seq_lens > 0
+            if self.kv_commit_backend == "in_place":
+                from dynamo_tpu.engine.attention import commit_window_pallas
+                k_cache, _ = commit_window_pallas(
+                    k_cache, v_cache, e_last[None, None, :, None, :],
+                    jnp.zeros((1, 1, b, 1, 0), v_cache.dtype),
+                    seq_lens, jnp.where(valid, seq_lens + 1, 0),
+                    valid.astype(jnp.int32), table,
+                    interpret=self.device.platform == "cpu", layers=(L, 1))
+            else:
+                dest = jnp.take_along_axis(
+                    table, (seq_lens // page)[:, None], axis=1)[:, 0]
+                k_cache = k_cache.at[
+                    L, 0, jnp.where(valid, dest, 0),
+                    jnp.where(valid, seq_lens % page, 0)].set(e_last)
+        return k_cache, page_ends, draft
+
+    def _get_mtp_window(self, window: int, bucket_pages: int, seeded: bool):
+        """The window program of a model whose own prediction module drafts
+        (``spec_decode="mtp"``; a latent block without an indexer): each of
+        ``window`` scan steps is ONE verify of spec_k + 1 positions a row
+        (the chained token and its draft, one read of the weights) and ONE
+        run of the module over the positions emitted, which drafts for the
+        next step. The accept rule is _get_spec_window's for a point-mass
+        drafter: each position draws from the target; the draft is accepted
+        iff the first draw equals it; every emitted token is
+        target-distributed and greedy rows are token-identical to the plain
+        window's. A row's position and draft chain on the device
+        (positions_dev, draft_dev) as the advance is data-dependent.
+
+        The window's columns are static (step m writes columns m * S to
+        m * S + S - 1 of the buffer of all pool layers, which is
+        column-major [W, layers, B, width] so that they are one contiguous
+        block of it) and a mask says which hold a committed token; once, after the scan, the committed
+        columns are moved to the front and go into the pool through the
+        in-place writer (attention.commit_window_pallas; a scatter where
+        that cannot be had), the module's layer one slot on: its entry of
+        position i lies at slot i + 1 (model.mtp_prefill)."""
+        from dynamo_tpu.engine.model import (decode_verify_step,
+                                             latent_block_attention,
+                                             mtp_block, mtp_logits,
+                                             spec_rope_tables)
+        spec = self.spec
+        page = self.config.page_size
+        S = self.config.spec_k + 1
+        W = window * S
+        L, LP = spec.num_layers, spec.pool_layers
+        reader = self._block_reader
+
+        def run_window(params, k_cache, v_cache, tokens_dev, positions_dev,
+                       draft_dev, page_ends, packed, rng):
+            override = packed[:, PK_OVERRIDE] > 0
+            tokens0 = jnp.where(override, packed[:, PK_TOKEN], tokens_dev)
+            pos0 = jnp.where(override, packed[:, PK_POS], positions_dev)
+            # A token the host put in has no draft behind it.
+            draft0 = jnp.where(override, -1, draft_dev)
+            active = packed[:, PK_SEQLEN] > 0
+            cap = packed[:, PK_CAP]
+            top_k = packed[:, PK_TOPK]
+            temp = jax.lax.bitcast_convert_type(packed[:, PK_TEMP],
+                                                jnp.float32)
+            top_p = jax.lax.bitcast_convert_type(packed[:, PK_TOPP],
+                                                 jnp.float32)
+            page_table = packed[:, PK_PREFIX:]
+            B = tokens0.shape[0]
+            b_idx = jnp.arange(B)
+            width = spec.kv_entry[1][0]
+            # Cache-resident before the window: the model's layers hold
+            # positions below pos0, the module's layer slots 1 to pos0.
+            hist_lens = jnp.where(active, pos0, 0)
+            mod_lens = jnp.where(active, pos0 + 1, 0)
+            with perf.scope("kv.commit"):
+                kbuf0 = jnp.zeros((W, LP, B, width), k_cache.dtype)
+            want_lp = jnp.any(packed[:, PK_LOGPROB] > 0)
+            temp_s, top_k_s, top_p_s = (jnp.repeat(a, S)
+                                        for a in (temp, top_k, top_p))
+            if seeded:
+                seed_s = jnp.repeat(packed[:, PK_SEEDED] > 0, S)
+                base_s = jax.random.wrap_key_data(jnp.repeat(
+                    jax.random.key_data(jax.vmap(jax.random.key)(
+                        packed[:, PK_SEED])), S, axis=0))
+
+            def step(carry, m):
+                tokens, pos, draft, keep, kbuf, hbuf, rng = carry
+                live = active & (pos < cap)
+                # The draft's own position has to be under the row's cap.
+                drafted = live & (draft >= 0) & (pos + 1 < cap)
+                tok_blk = jnp.stack(
+                    [tokens, jnp.where(drafted, draft, 0)], axis=1)
+                pos_blk = pos[:, None] + jnp.arange(S)[None, :]
+                hidden, logits, k_new, counts, load = decode_verify_step(
+                    params, spec, k_cache, kbuf, keep, tok_blk, pos_blk,
+                    page_table, hist_lens, jnp.stack([live, drafted], axis=1),
+                    reader, experts_local=self.experts_local)
+                with perf.scope("sample"):
+                    flat = logits.reshape(B * S, -1)
+                    rng, sub = jax.random.split(rng)
+                    if seeded:
+                        # Column j's token lands at pos + 1 + j: the plain
+                        # seeded window's convention.
+                        per_seed = jax.vmap(jax.random.fold_in)(
+                            base_s, (pos_blk + 1).reshape(-1))
+                        shared = jax.random.split(sub, B * S)
+                        row_keys = jax.random.wrap_key_data(jnp.where(
+                            seed_s[:, None], jax.random.key_data(per_seed),
+                            jax.random.key_data(shared)))
+                        out = sample_tokens_per_row(flat, temp_s, top_k_s,
+                                                    top_p_s, row_keys)
+                    else:
+                        out = sample_tokens(flat, temp_s, top_k_s, top_p_s,
+                                            sub)
+                    lp, top_v, top_i = jax.lax.cond(
+                        want_lp, lambda _: _logprobs_of(flat, out),
+                        lambda _: (
+                            jnp.zeros((B * S,), jnp.float32),
+                            jnp.zeros((B * S, TOP_LOGPROBS), jnp.float32),
+                            jnp.zeros((B * S, TOP_LOGPROBS), jnp.int32)),
+                        None)
+                    out = out.reshape(B, S)
+                    accepted = drafted & (out[:, 0] == draft)
+                    emitted = jnp.where(live, 1 + accepted, 0)
+                with perf.scope("mtp"):
+                    # The module over the positions emitted: their next
+                    # tokens are the draws; the draft after the last.
+                    cos, sin = spec_rope_tables(spec, pos_blk)
+                    layer = jnp.asarray(L, jnp.int32)
+
+                    def attend(q, k, v, kind):
+                        return latent_block_attention(
+                            q, k_cache, layer, page_table, mod_lens,
+                            jnp.swapaxes(kbuf[:, L], 0, 1), keep,
+                            k[:, :, 0], spec, live, reader, lo=1,
+                            scoped=False)
+
+                    y, k_mod, mstats = mtp_block(
+                        params, spec, hidden, out, cos, sin, attend,
+                        live=jnp.stack([live, accepted], axis=1),
+                        experts_local=self.experts_local)
+                    last = accepted.astype(jnp.int32)
+                    nxt = jnp.argmax(mtp_logits(
+                        params, spec, y[b_idx, last]), axis=-1)
+                    draft = jnp.where(live, nxt.astype(jnp.int32), draft)
+                with perf.scope("kv.commit"):
+                    fresh = jnp.concatenate([k_new, k_mod[None]], axis=0)
+                    kbuf = jax.lax.dynamic_update_slice(
+                        kbuf, fresh[:, :, :, 0].transpose(2, 0, 1, 3),
+                        (m * S, 0, 0, 0))
+                    keep = jax.lax.dynamic_update_slice(
+                        keep, jnp.arange(S)[None, :] < emitted[:, None],
+                        (0, m * S))
+                    hbuf = jax.lax.dynamic_update_slice(hbuf, hidden,
+                                                        (0, m * S, 0))
+                tokens = jnp.where(live, out[b_idx, last], tokens)
+                pos = pos + emitted
+                counted = (counts.sum(0) + mstats[0], load.sum(0) + mstats[1])
+                return (tokens, pos, draft, keep, kbuf, hbuf, rng), (
+                    out, lp.reshape(B, S), top_v.reshape(B, S, -1),
+                    top_i.reshape(B, S, -1), emitted.astype(jnp.int32),
+                    jnp.where(drafted, tok_blk[:, 1], -1), *counted)
+
+            carry0 = (tokens0, pos0, draft0, jnp.zeros((B, W), bool), kbuf0,
+                      jnp.zeros((B, W, spec.hidden_size), jnp.bfloat16), rng)
+            (tokens, pos, draft, keep, kbuf, hbuf, rng), \
+                (toks, lps, top_vs, top_is, emits, drafts, attn, moe) = \
+                jax.lax.scan(step, carry0, jnp.arange(window))
+            # "emit" [M, B]: tokens a row's step emitted (0: not live);
+            # "draft": the draft it verified (-1: none).
+            stats = {"attn": attn.sum(0), "moe": moe.sum(0), "emit": emits,
+                     "draft": drafts}
+            with perf.scope("kv.commit"):
+                # The committed columns to the front, in order: column c of
+                # the result is the token at position pos0 + c.
+                wlen = jnp.sum(keep, axis=-1)
+                cols = jnp.arange(W)[None, :]
+                order = jnp.argsort(jnp.where(keep, cols, W + cols), axis=-1)
+                kbuf = jnp.take_along_axis(
+                    kbuf, order.T[:, None, :, None], axis=0)
+                # As the commit takes it: [layers, 1, B, W, width].
+                kbuf = kbuf.transpose(1, 2, 0, 3)[:, None]
+                hbuf = jnp.take_along_axis(hbuf, order[:, :, None], axis=1)
+                # The model's output at each page's last position that the
+                # window committed, by page id (mtp_hidden).
+                for j in range(-(-W // page)):
+                    col = page - 1 - pos0 % page + j * page
+                    at = jnp.take_along_axis(page_table, jnp.clip(
+                        (pos0 + col) // page, 0,
+                        page_table.shape[1] - 1)[:, None], axis=1)[:, 0]
+                    done = active & (col < wlen) & (pos0 + col < cap)
+                    page_ends = page_ends.at[jnp.where(done, at, 0)].set(
+                        hbuf[b_idx, jnp.clip(col, 0, W - 1)])
+                seq = active.astype(jnp.int32)
+                ends = [jnp.minimum(cap, first + wlen)
+                        for first in (pos0, pos0 + 1)]
+                if self.kv_commit_backend == "in_place":
+                    from dynamo_tpu.engine.attention import (
+                        commit_window_pallas)
+                    cpu = self.device.platform == "cpu"
+                    vwin = jnp.zeros((LP, 1, B, W, 0), v_cache.dtype)
+                    k_cache, _ = commit_window_pallas(
+                        k_cache, v_cache, kbuf[:L], vwin[:L], pos0, ends[0],
+                        seq, page_table, interpret=cpu, layers=(0, L))
+                    k_cache, _ = commit_window_pallas(
+                        k_cache, v_cache, kbuf[L:], vwin[L:], pos0 + 1,
+                        ends[1], seq, page_table, interpret=cpu,
+                        layers=(L, LP - L))
+                else:
+                    for part, first, end in (
+                            (slice(0, L), pos0, ends[0]),
+                            (slice(L, LP), pos0 + 1, ends[1])):
+                        dest, off = window_token_slots(
+                            first, end, seq, page_table, W, page)
+                        k_cache = k_cache.at[part, :, dest, off].set(
+                            kbuf[part].transpose(0, 1, 3, 2, 4))
+            return (toks, lps, top_vs, top_is, tokens, pos, draft, page_ends,
+                    k_cache, v_cache, rng, stats)
+
+        return run_window
 
     def _get_decode(self):
         if self._decode_fn is not None:
@@ -669,6 +972,23 @@ class ModelRunner:
             return fn
         spec = self.spec
         page = self.config.page_size
+        drafting = self.config.spec_decode == "mtp"
+        labels = {"attention_backend": self.attention_backend,
+                  "kv_commit_backend": self.kv_commit_backend,
+                  "page_size": self.config.page_size,
+                  **({"index_backend": self.index_backend}
+                     if self.index_backend else {}),
+                  # Who drafts inside this program's steps.
+                  "draft": "mtp" if drafting else "none"}
+        if drafting:
+            # The same program under the same name and key: each of its
+            # ``window`` steps is a draft and a verify (_get_mtp_window).
+            fn = perf.instrumented_jit(
+                "decode_window",
+                self._get_mtp_window(window, bucket_pages, seeded), key=key,
+                donate_argnums=(1, 2), labels=labels)
+            self._window_cache[key] = fn
+            return fn
 
         def run_window(params, k_cache, v_cache, tokens_dev, packed, rng,
                        counts=None, lora=None):
@@ -806,7 +1126,10 @@ class ModelRunner:
                     k_cache, v_cache = commit_window_pallas(
                         k_cache, v_cache, kbuf, vbuf, positions0, cap,
                         seq_lens0, page_table,
-                        interpret=self.device.platform == "cpu")
+                        interpret=self.device.platform == "cpu",
+                        # A pool with a prediction module's layer behind
+                        # the model's, which this window leaves alone.
+                        layers=(0, L) if spec.mtp_layers else None)
                 else:
                     dest, off = window_token_slots(
                         positions0, cap, seq_lens0, page_table, window, page)
@@ -826,11 +1149,7 @@ class ModelRunner:
         donate = (1, 2, 6) if penalized else (1, 2)
         fn = perf.instrumented_jit(
             "decode_window", run_window, key=key, donate_argnums=donate,
-            labels={"attention_backend": self.attention_backend,
-                    "kv_commit_backend": self.kv_commit_backend,
-                    "page_size": self.config.page_size,
-                    **({"index_backend": self.index_backend}
-                       if self.index_backend else {})})
+            labels=labels)
         self._window_cache[key] = fn
         return fn
 
@@ -1158,7 +1477,9 @@ class ModelRunner:
         while bp < len(seqs):
             bp *= 2
         maxp = cfg.max_pages_per_seq
-        width = _PF_HDR + bucket + bucket_pages + (maxp if with_history else 0)
+        mtp = cfg.spec_decode == "mtp" and bool(self.spec.mtp_layers)
+        width = (_PF_HDR + bucket + bucket_pages
+                 + (maxp if with_history else 0) + (2 if mtp else 0))
         packed = np.zeros((bp, width), np.int32)
         for i, s in enumerate(seqs):
             n = len(s.tokens)
@@ -1176,6 +1497,8 @@ class ModelRunner:
                 packed[i, 9] = mask_seed(s.seed)
                 packed[i, 10] = 1
             packed[i, 11] = s.adapter_id
+            if mtp:
+                packed[i, -2:] = (s.next_token, s.next_page)
             packed[i, _PF_HDR:_PF_HDR + n] = s.tokens
             # Pad page-table rows stay 0 = the allocator's RESERVED scratch
             # page, so padded block scatters land there — padding with a
@@ -1207,6 +1530,8 @@ class ModelRunner:
             # Adapter stacks ride every prefill when LoRA serving is on:
             # row ids are data (col 11), so one program covers every mix.
             kw["lora"] = self.lora
+        if mtp:
+            kw["page_ends"] = self.mtp_hidden
         fn = self._get_prefill(bucket, bp, with_history, penalized, seeded,
                                with_embeds)
         with self.mesh:
@@ -1222,9 +1547,11 @@ class ModelRunner:
                     jnp.asarray(packed), self._rng, jnp.asarray(rows), **kw)
             else:
                 (sampled, lp, top_v, top_i, logits, self.k_cache,
-                 self.v_cache, self._rng) = fn(
+                 self.v_cache, self._rng, *draft) = fn(
                     self.params, self.k_cache, self.v_cache,
                     jnp.asarray(packed), self._rng, **kw)
+                if mtp:
+                    self.mtp_hidden = draft[1]
         # Device handle (no transfer unless a caller converts it).
         self.last_prefill_logits = logits
         if slots is not None:
@@ -1232,6 +1559,16 @@ class ModelRunner:
             with self.mesh:
                 self.tokens_dev = self.tokens_dev.at[idx].set(
                     sampled[:len(seqs)])
+                if mtp:
+                    # The drafting window chains from these as from the
+                    # token: where the row stands, and the module's draft
+                    # of the token after it.
+                    self.positions_dev = self.positions_dev.at[idx].set(
+                        jnp.asarray(np.asarray(
+                            [s.start_pos + len(s.tokens) for s in seqs],
+                            np.int32)))
+                    self.draft_dev = self.draft_dev.at[idx].set(
+                        draft[0][:len(seqs)])
                 if count_rows is not None:
                     # Penalty state for these slots: prior generated-token
                     # counts (zeros for fresh requests; rebuilt rows after
@@ -1334,7 +1671,16 @@ class ModelRunner:
         fn = self._get_window(window, bucket_pages, penalized, seeded)
         kw = {} if self.lora is None else {"lora": self.lora}
         with self.mesh:
-            if penalized:
+            if self.draft_dev is not None:
+                # The drafting window (_get_mtp_window): toks, lps and tops
+                # have an axis of spec_k + 1 positions behind the rows'.
+                (toks, lps, top_vs, top_is, self.tokens_dev,
+                 self.positions_dev, self.draft_dev, self.mtp_hidden,
+                 self.k_cache, self.v_cache, self._rng, stats) = fn(
+                    self.params, self.k_cache, self.v_cache,
+                    self.tokens_dev, self.positions_dev, self.draft_dev,
+                    self.mtp_hidden, jnp.asarray(packed), self._rng)
+            elif penalized:
                 (toks, lps, top_vs, top_is, self.tokens_dev, self.k_cache,
                  self.v_cache, self._rng, self.counts_dev, stats) = fn(
                     self.params, self.k_cache, self.v_cache,
@@ -1660,7 +2006,7 @@ def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
                           attention_impl, sp_shard: bool = False,
                           x_embeds=None, embeds_mask=None,
                           lora=None, adapter_ids=None,
-                          experts_local: bool = False):
+                          experts_local: bool = False, defer: bool = False):
     """Chunked prefill: like prefill_forward but queries also attend to the
     sequence's earlier pages (read via the paged path). x_embeds/embeds_mask
     override token embeddings under multimodal media spans (rows are
@@ -1774,11 +2120,14 @@ def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
                     .transpose(0, 3, 1, 2, 4))
         flat = page_table.reshape(-1)
         from dynamo_tpu.engine.kv_quant import scatter_pages
-        k_cache = scatter_pages(k_cache, k_blocks, flat)
-        v_cache = scatter_pages(v_cache, v_blocks, flat)
+        if not defer:
+            k_cache = scatter_pages(k_cache, k_blocks, flat)
+            v_cache = scatter_pages(v_cache, v_blocks, flat)
     with perf.scope("lm_head"):
         x = norm(x, params["final_norm"], spec)
         last_idx = jnp.maximum(seq_lens - 1, 0)
         x_last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
         logits = lm_logits(x_last, params, spec)
+    if defer:   # prefill_forward's: the caller commits with the module's
+        return logits, k_cache, v_cache, (x, k_blocks, v_blocks, flat)
     return logits, k_cache, v_cache

@@ -98,6 +98,15 @@ def parse_args(argv=None) -> argparse.Namespace:
                              "with --quant (DTPU_QUANT_KV overrides)")
     parser.add_argument("--host-cache-pages", type=int, default=0)
     parser.add_argument("--kv-disk-cache-dir", default=None)
+    # The worker's own two options (backends/tpu.py), handed through.
+    parser.add_argument("--spec-decode", default=None,
+                        choices=["ngram", "mtp"],
+                        help="out=tpu: speculative decoding, as the "
+                             "worker's --spec-decode ('mtp': the model's "
+                             "own prediction module drafts inside the "
+                             "window program)")
+    parser.add_argument("--spec-k", type=int, default=3,
+                        help="out=tpu: drafts verified per step")
     parser.add_argument("--lora", action="append", default=[],
                         metavar="NAME=PATH",
                         help="out=tpu: serve a LoRA adapter as its own "

@@ -529,3 +529,84 @@ def test_latent_window_program_commits_in_place_for_v5e(v5e):
     assert "output_to_operand_aliasing" in text
     for pool in pools:
         assert pool_sized_ops(text, pool) == []
+
+
+def _glm_runner(v5e, spec_decode, rows=32, pages=1200):
+    """A ModelRunner that places nothing, at GLM-4.7-Flash's attention
+    geometry (20 heads of 192 | 64, v 256, q rank 768; entries of 640 lanes
+    and NO second array) with a dense layer, two expert layers, the
+    prediction module, narrow feed-forwards and a small vocabulary."""
+    from types import SimpleNamespace
+
+    from dynamo_tpu.engine.config import DeepseekV32Spec, EngineConfig
+    from dynamo_tpu.engine.runner import ModelRunner
+    spec = DeepseekV32Spec(
+        name="glm", vocab_size=1024, hidden_size=2048,
+        intermediate_size=512, num_layers=3, num_heads=20, num_kv_heads=20,
+        head_dim=256, rms_norm_eps=1e-5, rope_theta=1e6, num_experts=4,
+        num_experts_per_tok=4, moe_intermediate_size=256,
+        num_routed_experts=16, num_shared_experts=1, first_k_dense=1,
+        routed_scaling_factor=1.8, kv_lora_rank=512, q_lora_rank=768,
+        qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+        index_n_heads=0, index_head_dim=0, index_topk=0, mtp_layers=1)
+    assert spec.kv_entry == (1, (640, 0)) and spec.pool_layers == 4
+    runner = object.__new__(ModelRunner)
+    runner.spec = spec
+    runner.config = EngineConfig(model=spec, num_pages=pages,
+                                 max_num_seqs=rows)
+    page = runner.config.resolve_page_size("tpu")
+    assert page == 64
+    runner.config = EngineConfig(model=spec, page_size=page, num_pages=pages,
+                                 max_num_seqs=rows, spec_decode=spec_decode,
+                                 spec_k=1)
+    runner.device = SimpleNamespace(platform="tpu")
+    runner.mesh = SimpleNamespace(size=1)
+    runner.quant_kv, runner.lora = None, None
+    runner.experts_local = True
+    runner._window_cache, runner._prefill_cache = {}, {}
+    runner._attention_impl, runner._window_attention_impl = \
+        runner._pick_attention()
+    runner.kv_commit_backend = runner._pick_kv_commit()
+    assert (runner.attention_backend, runner.kv_commit_backend,
+            runner.index_backend) == ("pallas", "in_place", None)
+    return runner, spec, page, pages
+
+
+@pytest.mark.parametrize("spec_decode", ["mtp", None])
+def test_glm_window_program_commits_in_place_for_v5e(v5e, spec_decode):
+    """The window program of a latent pool WITHOUT an indexer at
+    GLM-4.7-Flash's widths, drafting with the model's own module (two query
+    positions a row: 40 query rows a slot padded to 48, the reader without
+    a mask operand; two commits, the module's layer one slot on) and plain:
+    the pool of ONE array is donated, walked by the kernel and rewritten in
+    place, and nothing in the optimised program has its shape but the
+    arguments and the commits aliased to them."""
+    from dynamo_tpu.engine.model import param_shapes
+    from dynamo_tpu.engine.runner import PK_PREFIX
+    runner, spec, page, pages = _glm_runner(v5e, spec_decode)
+    rows, window = 32, 8
+    table = runner.config.max_pages_per_seq // 2
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.tree.map(lambda shape: s(shape, jnp.bfloat16),
+                          param_shapes(spec),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    pool = (4, 1, pages, page, 640)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    state = [s((rows,), jnp.int32)] * (3 if spec_decode else 1)
+    if spec_decode:     # mtp_hidden: a page's last position's output
+        state.append(s((pages, spec.hidden_size), jnp.bfloat16))
+    lowered = runner._get_window(window, table).lower(
+        params, s(pool, jnp.bfloat16), s((*pool[:-1], 0), jnp.bfloat16),
+        *state, s((rows, PK_PREFIX + table), jnp.int32),
+        s(key.shape, key.dtype))
+    if spec_decode:
+        # ONE reader for both layer scans and the module's layer.
+        assert lowered.as_text().count(
+            "func.func private @_latent_block_flash") == 1
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") >= (3 if spec_decode else 2)
+    assert "output_to_operand_aliasing" in text
+    assert pool_sized_ops(text, pool) == []
