@@ -674,10 +674,26 @@ async def phase_kernels(args, jax, rng, keep: dict):
                 check(custom_call == on_tpu,
                       f"tpu_custom_call in the window program: "
                       f"{custom_call} on {jax.devices()[0].platform}")
+            # A routed block's prefill chunks (256 rows on the chip) take
+            # the grouped expert product, its window's rows the masked one
+            # (model.MOE_DENSE_MAX_ROWS; the rehearsal's 64-row chunks stay
+            # masked): the programs' own label.
+            products = {
+                family: sorted({fn._labels["expert_product"]
+                                for fn in cache.values()})
+                for family, cache in (("prefill", eng.runner._prefill_cache),
+                                      ("decode_window",
+                                       eng.runner._window_cache))
+            } if spec_r.num_experts else None
+            check(products is None or (
+                ("grouped" in products["prefill"]) != args.rehearse_cpu
+                and products["decode_window"] == ["masked"]),
+                f"expert products of {spec_r.name}: {products}")
             emit("kernels.run", model=spec_r.name,
                  quant_kv=quant_kv or "bf16", attention_backend=backend,
                  resolved=resolved, kv_commit_backend=commit,
                  index_backend=index, attn_selected_pct=selected,
+                 expert_product=products,
                  page_size=eng.runner.page_size,
                  prompt_lengths=lengths, chunk_tokens=chunks,
                  seconds=round(seconds, 2), tpu_custom_call=custom_call)

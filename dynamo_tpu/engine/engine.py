@@ -1131,6 +1131,11 @@ class TPUEngine(AsyncEngine):
                 "experts_touched_pct": round(100.0 * touched / (n * experts),
                                              3) if n else None,
                 "load_max_over_mean": round(load / n, 4) if n else None,
+                # The product each program family's expert layers take
+                # (static by a program's rows: model.MOE_DENSE_MAX_ROWS),
+                # and the pairs a layer the grouped prefill calls sorted.
+                "expert_product": self._perf.label_values("expert_product"),
+                "grouped_pairs": self.runner.moe_grouped_pairs,
             }
             if spec.num_routed_experts is not None:
                 local, picks = self.moe_totals[3:]

@@ -300,19 +300,58 @@ def param_specs(spec: ModelSpec) -> dict:
 
 #: Rows up to which a routed block's expert layer multiplies every row by
 #: every resident expert under the gate mask; above it, where the experts
-#: are whole on one device, tokens are sorted by expert and multiplied by
-#: their own experts only (jax.lax.ragged_dot). Measured on one v5e, one
-#: layer of 64 int8 experts of 2560 x 768 with 6 a row (PERF.md section 6,
-#: PR 28, call 8; ms, masked | grouped): 8 to 96 rows 0.52 to 0.54 | 2.6 to
-#: 3.6; 256 rows 1.17 | 4.80; 512 rows 2.65 | 5.07; 1,024 rows 5.34 | 6.06;
-#: 2,048 rows (10.7 by its operations) | 8.40. The masked product streams
-#: the layer's 377 MB in 0.52 ms and its 10.7-fold work rides under that
-#: read up to about 100 rows; the grouped product pays some 2.5 ms a layer
-#: before its first row (it writes a bf16 copy of every int8 expert, 1.9 GB
-#: of traffic, for ragged_dot to read), so the two cross past 1,024 rows,
-#: where the masked product's [64, rows, 2560] float32 intermediate is also
-#: 0.67 GB and doubling.
-MOE_DENSE_MAX_ROWS = 1024
+#: are whole on one device, the (row, choice) pairs are sorted by held expert
+#: and multiplied by their own experts only (engine/experts.py: a kernel
+#: that reads each int8 expert once, as stored). One layer alone on one v5e,
+#: int8 leaves (PERF.md section 6, PR 40, call 1, scripts/expert_layer_bench.py;
+#: ms, masked | kernel under the routing of random weights [a balanced one];
+#: weight tiles of 1 MiB: the 2 MiB of experts.TILE_ELEMS are 2 to 5 % faster):
+#:  rows  64 of 2,560 x 768, 6 a row   16 held of 64 of 2,048 x     16 held of 128 of 4,096 x
+#:                                     1,536, 4 a row               4,096, 8 a row
+#:    32  0.52 | 0.73 [0.52 | 0.76]    0.22 | 0.29 [0.23 | 0.33]    1.11 | 1.29 [1.11 | 1.58]
+#:    64  0.53 | 0.79 [0.52 | 0.79]    0.22 | 0.33 [0.22 | 0.33]    1.14 | 1.51 [1.15 | 1.59]
+#:   128  0.70 | 0.84 [0.64 | 0.82]    0.25 | 0.37 [0.25 | 0.35]    1.40 | 1.71 [1.40 | 1.63]
+#:   256  1.17 | 0.94 [1.08 | 0.91]    0.47 | 0.40 [0.47 | 0.38]    2.49 | 1.78 [2.48 | 1.69]
+#:   512  2.65 | 1.14 [2.40 | 1.06]    1.03 | 0.53 [1.02 | 0.48]    5.31 | 2.21 [5.31 | 1.98]
+#: 1,024  5.34 | 1.64 [4.97 | 1.49]    2.05 | 0.69 [2.04 | 0.55]    10.8 | 3.25 [10.8 | 2.67]
+#: 2,048  11.2 | 3.23 [9.93 | 3.00]    4.47 | 0.90 [4.45 | 0.68]    21.7 | 4.85 [21.6 | 3.80]
+#: 4,096  22.4 | 5.50 [19.6 | 5.19]    8.36 | 2.24 [8.92 | 2.08]    43.3 | 8.06 [43.2 | 7.52]
+#: The masked product streams the layer (377, 151 and 805 MB in 0.52, 0.22
+#: and 1.11 ms) and its work rides under that read to about 100 rows, then
+#: grows with the rows (10.7 times the chosen work where all 64 are held,
+#: 16 times in the two shares, whose rows pick ONE held expert each on
+#: average). The kernel costs a (group, row tile) visit
+#: whatever the group holds: rows / 128 + experts - 1 of them at most, each
+#: a weight tile's copy, conversion and product for 128 rows (12 us a visit
+#: of 2,560 x 768 x 3: 62 visits at 32 rows 0.75 ms, 87 at 512 rows 1.05),
+#: plus XLA's sort and two gathers (0.1 ms at 512 rows). The two cross
+#: between 128 and 256 rows in all three geometries under either routing,
+#: so the constant is 128, the largest power of two at which the masked
+#: product is still no slower in all three. It stays at 64 or above whatever
+#: a later table says: a decode step's rows (32) and a verify step's (64)
+#: keep the masked product, whose every-resident-expert read is what
+#: decode_window_roofline's floor counts (ROADMAP S9).
+MOE_DENSE_MAX_ROWS = 128
+
+
+class LayerOf(NamedTuple):
+    """A leaf of ``params["layers"]`` handed to a layer WHOLE: the stack
+    over all layers and the layer's index (scan_layers ``whole_experts``)."""
+    stack: Any   # [L, ...], or a QTensor of such
+    layer: Any   # int32 scalar
+
+
+#: The leaves a grouped expert layer reads through the kernel.
+EXPERT_LEAVES = ("moe_w_gate", "moe_w_up", "moe_w_down")
+
+
+def expert_product(rows: int, experts_local) -> str:
+    """The product an expert layer of ``rows`` rows takes, a static fact of
+    its program (the runner's label ``expert_product``): "grouped" above
+    MOE_DENSE_MAX_ROWS where the caller says the experts are whole on one
+    device, else "masked"."""
+    return ("grouped" if experts_local and rows > MOE_DENSE_MAX_ROWS
+            else "masked")
 
 
 def moe_route(router: jax.Array, spec: ModelSpec,
@@ -391,37 +430,52 @@ def moe_load_stats(one_hot: jax.Array, live: jax.Array, spec: ModelSpec
 
 
 def _grouped_experts(x: jax.Array, gates: jax.Array, top_i: jax.Array,
-                     lp: dict, spec: ModelSpec) -> jax.Array:
+                     lp: dict, spec: ModelSpec, interpret: bool = False
+                     ) -> jax.Array:
     """The chosen experts' outputs summed under their gates, computing only
-    what was chosen: each (token, expert) pair is a row, rows sorted by
-    expert, one ragged product per matrix over the groups. x [T, H] bf16;
-    returns [T, H] float32."""
+    what was chosen of the experts HELD: each (token, choice) pair is a row,
+    rows sorted by held expert, and experts.pairs_product multiplies each
+    group by its own expert as stored (gate and up in one call, down in a
+    second). top_i counts from the first expert held: a choice outside
+    [0, num_experts) fell on an expert held elsewhere, sorts behind the last
+    group and adds nothing. x [T, H] bf16; returns [T, H] float32."""
+    from dynamo_tpu.engine.experts import ROW_TILE, pairs_product, visits
     t, k = top_i.shape
-    flat_e = top_i.reshape(-1)                               # [T*k]
+    held = (top_i >= 0) & (top_i < spec.num_experts)
+    flat_e = jnp.where(held, top_i, spec.num_experts).reshape(-1)  # [T*k]
     order = jnp.argsort(flat_e)                              # stable
-    sorted_e = flat_e[order]
-    sizes = jnp.bincount(flat_e, length=spec.num_experts).astype(jnp.int32)
+    sizes = jnp.sum(flat_e[:, None] == jnp.arange(spec.num_experts)[None, :],
+                    axis=0, dtype=jnp.int32)
+    rows = jnp.pad(x[order // k], ((0, -(t * k) % ROW_TILE), (0, 0)))
+    walk = visits(sizes, rows.shape[0])
 
-    def rd(a, w):
-        if isinstance(w, QTensor):
-            y = jax.lax.ragged_dot(a, w.q.astype(jnp.bfloat16), sizes,
-                                   preferred_element_type=jnp.float32)
-            return y * w.s[:, 0, :][sorted_e]
-        return jax.lax.ragged_dot(a, w, sizes,
-                                  preferred_element_type=jnp.float32)
+    def leaves(*keys):
+        """(stacks over all layers, their scales or None, the layer): as
+        scan_layers hands them whole, else this layer's as a stack of one."""
+        ws, layer = [lp[key] for key in keys], 0
+        if isinstance(ws[0], LayerOf):
+            ws, layer = [w.stack for w in ws], ws[0].layer
+        else:
+            ws = [jax.tree.map(lambda a: a[None], w) for w in ws]
+        if isinstance(ws[0], QTensor):
+            return tuple(w.q for w in ws), tuple(w.s for w in ws), layer
+        return tuple(ws), None, layer
 
-    rows = x[order // k]                                     # [T*k, H]
-    ff = _gate_act(rd(rows, lp["moe_w_gate"]), spec) \
-        * rd(rows, lp["moe_w_up"]).astype(jnp.bfloat16)
-    down = rd(ff, lp["moe_w_down"])                          # [T*k, H] f32
-    # Back to (token, choice) order by a gather, then the gated sum over k.
+    ff = pairs_product(rows, *leaves("moe_w_gate", "moe_w_up"), walk,
+                       act=spec.ffn_act, interpret=interpret)
+    down = pairs_product(ff, *leaves("moe_w_down"), walk,
+                         interpret=interpret)                # [T*k+, H] f32
+    # Back to (token, choice) order by a gather, then the gated sum over k;
+    # a pair of no group reads whatever the kernel's buffers held: zeros.
     down = down[jnp.argsort(order)].reshape(t, k, -1)
-    return jnp.einsum("tkh,tk->th", down, gates)
+    return jnp.einsum("tkh,tk->th", jnp.where(held[..., None], down, 0.0),
+                      gates)
 
 
 def ffn_block(h2: jax.Array, lp: dict, spec: ModelSpec, ll: dict | None = None,
               ids: jax.Array | None = None, router_in: jax.Array | None = None,
-              live: jax.Array | None = None, experts_local: bool = False):
+              live: jax.Array | None = None,
+              experts_local: bool | str = False):
     """Feed-forward over normalized hidden states [..., H]: dense SwiGLU /
     ReGLU, or a routed expert layer when spec.num_experts > 0.
 
@@ -433,11 +487,12 @@ def ffn_block(h2: jax.Array, lp: dict, spec: ModelSpec, ll: dict | None = None,
     with experts sharded over "tp" each device runs E/tp experts and XLA
     inserts the psum, i.e. expert parallelism without a dynamic all-to-all.
     Above it, where the caller says the experts are whole on one device
-    (``experts_local``: the runner's mesh has one device), either routed
-    kind computes the chosen experts only (``_grouped_experts``; the grouped
-    product has no partitioning rule yet); a layer that holds a SHARE of a
-    wider router's experts keeps the masked product, a block of rows at a
-    time.
+    (``experts_local``: the runner's mesh has one device; "interpret" says
+    the same of the CPU, which interprets the kernel), every routed kind
+    multiplies the (row, choice) pairs by their own experts only
+    (``_grouped_experts``: a Pallas kernel, which GSPMD cannot partition);
+    a layer that holds a SHARE of a wider router's experts does the same
+    with the pairs that fell on the experts it holds.
 
     The router is as wide as the deployment has experts and its gates are
     normalised over all the chosen; this device multiplies the experts it
@@ -480,30 +535,14 @@ def ffn_block(h2: jax.Array, lp: dict, spec: ModelSpec, ll: dict | None = None,
         stats = (None if live is None
                  else moe_load_stats(one_hot, live.reshape(-1), spec))
     with scope("moe.experts"):
-        share, rows = spec.holds_share, x.shape[0]
-        if experts_local and rows > MOE_DENSE_MAX_ROWS and not share:
-            out = _grouped_experts(x, gates, top_i, lp, spec)
+        if expert_product(x.shape[0], experts_local) == "grouped":
+            out = _grouped_experts(x, gates, top_i, lp, spec,
+                                   interpret=experts_local == "interpret")
         else:
             w_te = jnp.einsum("tk,tke->te", gates, one_hot)  # [T, E] sparse-ish
-
-            def masked(x, w_te):
-                down = _every_expert(x, lp["moe_w_gate"], lp["moe_w_up"],
-                                     lp["moe_w_down"], spec)
-                return jnp.einsum("eth,te->th", down, w_te)
-
-            if share and rows > MOE_DENSE_MAX_ROWS:
-                # A share's long batch: the masked product a block of rows
-                # at a time. Sorted by expert, 7 of 8 (row, choice) pairs
-                # would belong to experts held elsewhere, and the grouped
-                # product's gathers are sized for all of them.
-                pad = -rows % MOE_DENSE_MAX_ROWS
-                blocks = jax.lax.map(lambda xw: masked(*xw), tuple(
-                    jnp.pad(a, ((0, pad), (0, 0))).reshape(
-                        -1, MOE_DENSE_MAX_ROWS, a.shape[-1])
-                    for a in (x, w_te)))
-                out = blocks.reshape(rows + pad, -1)[:rows]
-            else:
-                out = masked(x, w_te)
+            down = _every_expert(x, lp["moe_w_gate"], lp["moe_w_up"],
+                                 lp["moe_w_down"], spec)
+            out = jnp.einsum("eth,te->th", down, w_te)
     if spec.num_shared_experts:
         with scope("moe.shared"):
             # Every row through every shared expert; their mean joins the
@@ -1523,7 +1562,8 @@ def transformer_block(x: jax.Array, lp: dict, spec: ModelSpec,
     return x, k, v, stats
 
 
-def scan_layers(layer_fn, x: jax.Array, xs, spec: ModelSpec):
+def scan_layers(layer_fn, x: jax.Array, xs, spec: ModelSpec,
+                whole_experts: bool = False):
     """``jax.lax.scan(layer_fn, x, xs)`` over the layers, where ``xs`` is
     ``params["layers"]`` or a tuple that starts with it and goes on with
     arrays stacked over ALL layers (the layer index, a window's buffers).
@@ -1531,7 +1571,33 @@ def scan_layers(layer_fn, x: jax.Array, xs, spec: ModelSpec):
     dense layers' leaves (``DENSE_PREFIX``, under the names the layer
     reads) with the first rows of the other arrays, then the rest; what
     both scans give a layer (k, v, counts) is joined along the layer axis,
-    what only the expert layers give (their load) follows."""
+    what only the expert layers give (their load) follows.
+
+    ``whole_experts`` (the caller's layers take the grouped expert product:
+    ``expert_product``): the EXPERT_LEAVES are not sliced a layer; the
+    layer reads them as ``LayerOf`` (the whole stack and its index), which
+    the kernel's index maps follow. Sliced ahead of a custom call a layer's
+    experts are COPIED (126 MB a matrix of 64 x 2,560 x 768; XLA fuses such
+    a slice into its own products alone)."""
+    def scan(fn, x, xs):
+        layers = xs if isinstance(xs, dict) else xs[0]
+        if not (whole_experts and isinstance(layers, dict)
+                and EXPERT_LEAVES[0] in layers):
+            return jax.lax.scan(fn, x, xs)
+        stacks = {k: layers[k] for k in EXPERT_LEAVES}
+        sliced = {k: v for k, v in layers.items() if k not in stacks}
+
+        def body(x, scan_in):
+            rest, i = scan_in
+            lp = rest if isinstance(xs, dict) else rest[0]
+            lp = {**lp, **{k: LayerOf(v, i) for k, v in stacks.items()}}
+            return fn(x, lp if isinstance(xs, dict) else (lp, *rest[1:]))
+
+        n = jax.tree.leaves(stacks)[0].shape[0]
+        return jax.lax.scan(
+            body, x, (sliced if isinstance(xs, dict) else (sliced, *xs[1:]),
+                      jnp.arange(n)))
+
     dense = spec.first_k_dense
     if spec.mtp_layers:
         # A prediction module's leaves (a stack of its own, MTP_PREFIX) are
@@ -1540,7 +1606,7 @@ def scan_layers(layer_fn, x: jax.Array, xs, spec: ModelSpec):
                            if not k.startswith(MTP_PREFIX)}
         xs = strip(xs) if isinstance(xs, dict) else (strip(xs[0]), *xs[1:])
     if not dense:
-        return jax.lax.scan(layer_fn, x, xs)
+        return scan(layer_fn, x, xs)
     layers, others = (xs, None) if isinstance(xs, dict) else (xs[0], xs[1:])
     first = {k[len(DENSE_PREFIX):]: v for k, v in layers.items()
              if k.startswith(DENSE_PREFIX)}
@@ -1549,7 +1615,7 @@ def scan_layers(layer_fn, x: jax.Array, xs, spec: ModelSpec):
         first = (first, *(a[:dense] for a in others))
         rest = (rest, *(a[dense:] for a in others))
     x, ys_first = jax.lax.scan(layer_fn, x, first)
-    x, ys_rest = jax.lax.scan(layer_fn, x, rest)
+    x, ys_rest = scan(layer_fn, x, rest)
     joined = tuple(jnp.concatenate([a, b])
                    for a, b in zip(ys_first, ys_rest))
     return x, joined + tuple(ys_rest[len(ys_first):])
@@ -1634,7 +1700,9 @@ def prefill_forward(params: Params, spec: ModelSpec,
     xs = (params["layers"], lora) if lora is not None else params["layers"]
     if patterned:
         xs = (xs, jnp.arange(spec.num_layers))
-    x, (k_new, v_new) = scan_layers(layer_fn, x, xs, spec)
+    x, (k_new, v_new) = scan_layers(
+        layer_fn, x, xs, spec,
+        whole_experts=expert_product(b * s, experts_local) == "grouped")
     # k_new [L,B,S,Nkv,D] -> page blocks [L,Nkv,B*S/page,page,D]; one
     # in-place scatter per cache covers every layer.
     with scope("kv.commit"):

@@ -594,6 +594,14 @@ class CompileRegistry:
             "warmup_complete": self.warmup_complete,
         }
 
+    def label_values(self, label: str) -> dict:
+        """{program family: the distinct values its live programs carry
+        under ``label``}; a family without the label is left out."""
+        with self._lock:
+            families = sorted(self._wrappers)
+        return {name: values for name in families
+                if (values := self._labels_of(name).get(label))}
+
     def _labels_of(self, program: str) -> dict:
         """Each label of the family's live programs with the distinct
         values they carry (one, unless two runners in a process differ)."""
@@ -787,6 +795,18 @@ class PerfMetricsUpdater:
         self.c_moe_picks = registry.counter(
             "moe_picks_total", "Expert layer told its share: all (row, "
             "choice) pairs of live rows, wherever the expert is held")
+        self.c_moe_grouped_pairs = registry.counter(
+            "moe_grouped_pairs_total", "Routed block: (row, choice) pairs a "
+            "layer of the prefill calls whose expert layers took the "
+            "grouped product (the kernel of engine/experts.py: each pair "
+            "by its own expert), counted on the host from the rows sent")
+        self.g_expert_product = registry.gauge(
+            "perf_expert_product_info", "1 under the labels of a program "
+            "family of a routed block (prefill, decode_window) and the "
+            "product its expert layers take, static by the program's rows "
+            "(model.MOE_DENSE_MAX_ROWS): grouped (sorted pairs, each by its "
+            "own expert) or masked (every row by every resident expert)",
+            ["program", "kind"])
         self.g_moe_experts = registry.gauge(
             "moe_experts_info", "Expert layer told its share: experts the "
             "router chooses among, held here and shared", ["kind"])
@@ -870,6 +890,11 @@ class PerfMetricsUpdater:
         if attn is not None and attn[1]:
             self._delta(self.c_attn_selected, ("attn_s",), float(attn[0]))
             self._delta(self.c_attn_context, ("attn_c",), float(attn[1]))
+        for program, kinds in reg.label_values("expert_product").items():
+            for kind in kinds:
+                self.g_expert_product.set(1, program=program, kind=kind)
+        self._delta(self.c_moe_grouped_pairs, ("moe_g",),
+                    float(getattr(runner, "moe_grouped_pairs", 0)))
         moe = getattr(engine, "moe_totals", None)
         if moe is not None and moe[2]:
             self._delta(self.c_moe_touched, ("moe_t",), float(moe[0]))

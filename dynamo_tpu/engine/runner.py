@@ -44,6 +44,7 @@ from dynamo_tpu.engine.kv_quant import (KV_SCALE_BYTES, QuantKV, pack_parcel,
                                         window_token_slots)
 from dynamo_tpu.engine.model import (
     dense_causal_attention,
+    expert_product,
     init_params,
     paged_decode_attention_xla,
     param_specs,
@@ -221,9 +222,6 @@ class ModelRunner:
         dev_array = np.array(devices[:total]).reshape(
             config.dp, config.pp, config.sp, config.tp)
         self.mesh = Mesh(dev_array, ("dp", "pp", "sp", "tp"))
-        # A routed block's experts are whole on one device: model.ffn_block
-        # may then compute a long batch by the chosen experts only.
-        self.experts_local = self.mesh.size == 1
         # This process's first mesh device: what memory is sized from and
         # read back from, and whose platform decides everything that
         # differs between a chip and the CPU backend (never the process
@@ -232,6 +230,16 @@ class ModelRunner:
         local = [d for d in devices[:total]
                  if d.process_index == jax.process_index()]
         self.device = local[0] if local else devices[0]
+        # A routed block's experts are whole on one device: model.ffn_block
+        # then multiplies a long batch's rows by their own experts only
+        # (the kernel of engine/experts.py; the CPU interprets it, as it
+        # does the attention kernels). On any mesh: the masked product,
+        # which GSPMD can partition.
+        self.experts_local = self.mesh.size == 1 and (
+            "interpret" if self.device.platform == "cpu" else True)
+        # (row, choice) pairs the prefill calls sent through that kernel, a
+        # layer: counted on the host from the rows of each call.
+        self.moe_grouped_pairs = 0
         # Before any weight is loaded: a backend that cannot be had fails
         # the start-up in milliseconds.
         self._attention_impl, self._window_attention_impl = \
@@ -549,6 +557,15 @@ class ModelRunner:
                            self.quant_kv, self.spec.latent)[1]
 
     # -- compiled steps -------------------------------------------------------
+    def _expert_product(self, rows: int) -> dict:
+        """The label of a program whose expert layers multiply ``rows`` rows
+        at once: ``expert_product`` "grouped" | "masked", what
+        model.ffn_block decides from the same two facts; none for a dense
+        block."""
+        if not self.spec.num_experts:
+            return {}
+        return {"expert_product": expert_product(rows, self.experts_local)}
+
     def _get_prefill(self, bucket: int, batch: int, with_history: bool,
                      penalized: bool = False, seeded: bool = False,
                      with_embeds: bool = False):
@@ -683,7 +700,8 @@ class ModelRunner:
             return sampled, lp, top_v, top_i, logits, k_cache, v_cache, rng
 
         fn = perf.instrumented_jit("prefill", step, key=key,
-                                   donate_argnums=(1, 2))
+                                   donate_argnums=(1, 2),
+                                   labels=self._expert_product(bucket * batch))
         self._prefill_cache[key] = fn
         return fn
 
@@ -979,7 +997,10 @@ class ModelRunner:
                   **({"index_backend": self.index_backend}
                      if self.index_backend else {}),
                   # Who drafts inside this program's steps.
-                  "draft": "mtp" if drafting else "none"}
+                  "draft": "mtp" if drafting else "none",
+                  # A step's rows: every slot, and each verified position.
+                  **self._expert_product(self.config.max_num_seqs * (
+                      self.config.spec_k + 1 if drafting else 1))}
         if drafting:
             # The same program under the same name and key: each of its
             # ``window`` steps is a draft and a verify (_get_mtp_window).
@@ -1534,6 +1555,10 @@ class ModelRunner:
             kw["page_ends"] = self.mtp_hidden
         fn = self._get_prefill(bucket, bp, with_history, penalized, seeded,
                                with_embeds)
+        if self._expert_product(bucket * bp).get(
+                "expert_product") == "grouped":
+            self.moe_grouped_pairs += (bucket * bp
+                                       * self.spec.num_experts_per_tok)
         with self.mesh:
             if penalized:
                 rows = np.asarray(count_rows, np.uint8)
@@ -2111,7 +2136,9 @@ def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
 
     xs = ((params["layers"], jnp.arange(L), lora) if lora is not None
           else (params["layers"], jnp.arange(L)))
-    x, (k_new, v_new) = scan_layers(layer_fn, x, xs, spec)
+    x, (k_new, v_new) = scan_layers(
+        layer_fn, x, xs, spec,
+        whole_experts=expert_product(b * s, experts_local) == "grouped")
     with perf.scope("kv.commit"):
         heads, (dk, dv) = spec.kv_entry
         k_blocks = (k_new.reshape(L, b * (s // page), page, heads, dk)

@@ -1,0 +1,235 @@
+#!/usr/bin/env python3
+"""One expert layer alone on the device this process holds: the masked
+product (every row by every resident expert) against the grouped one (the
+kernel of dynamo_tpu/engine/experts.py), the table at
+``model.MOE_DENSE_MAX_ROWS``.
+
+    chiprun -- python3 scripts/expert_layer_bench.py [--rows 32,64,...]
+        [--geometry smallthinker,glm,command] [--tiles 128x1048576,...]
+
+Three geometries, as the benchmark's routed cells hold them (int8 leaves):
+``smallthinker`` 64 experts of 2,560 x 768 all held, 6 a row, ReGLU;
+``glm`` 16 held of 64 of 2,048 x 1,536, 4 a row; ``command`` 16 held of 128
+of 4,096 x 4,096, 8 a row. Two routings: ``random`` (the router of random
+weights over random rows, what the benchmark's cells route by) and
+``balanced`` (row t takes experts t, t + R/k, ... mod R: every expert the
+same load). One JSON line a (geometry, routing, rows) with the milliseconds
+of ``model.ffn_block`` under either product, the largest difference of
+their outputs, and the kernel's two calls alone; ``--tiles`` times the
+grouped product under other (ROW_TILE, TILE_ELEMS); ``--layers`` adds the
+time a layer of a scan over stacked layers, as a served program runs them. A time is the least of
+three loops' mean, each loop ending in ``block_until_ready``."""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax                                                    # noqa: E402
+import jax.numpy as jnp                                       # noqa: E402
+import numpy as np                                            # noqa: E402
+
+from dynamo_tpu.engine import experts, model                  # noqa: E402
+from dynamo_tpu.engine.config import (                        # noqa: E402
+    Cohere2MoeSpec, SmallThinkerSpec)
+from dynamo_tpu.engine.quant import QTensor                   # noqa: E402
+
+_ATTN = dict(num_layers=1, num_heads=2, num_kv_heads=1, head_dim=128,
+             quant="int8")
+GEOMETRIES = {
+    "smallthinker": SmallThinkerSpec(
+        hidden_size=2560, intermediate_size=768, moe_intermediate_size=768,
+        num_experts=64, num_experts_per_tok=6, **_ATTN),
+    "glm": Cohere2MoeSpec(
+        hidden_size=2048, intermediate_size=1536, moe_intermediate_size=1536,
+        num_experts=16, num_experts_per_tok=4, num_routed_experts=64,
+        first_expert=16, **_ATTN),
+    "command": Cohere2MoeSpec(
+        hidden_size=4096, intermediate_size=4096, moe_intermediate_size=4096,
+        num_experts=16, num_experts_per_tok=8, num_routed_experts=128,
+        **_ATTN),
+}
+ROWS = (32, 64, 128, 256, 512, 1024, 2048, 4096)
+
+
+def layer(spec, key):
+    """One layer's router and int8 expert stacks, made on the device."""
+    h, i, e = spec.hidden_size, spec.moe_intermediate_size, spec.num_experts
+    ks = jax.random.split(key, 7)
+
+    def q(k, shape):
+        return QTensor(
+            jax.random.randint(k, shape, -127, 128, jnp.int8),
+            jnp.full((e, 1, shape[-1]), shape[-2] ** -0.5 / 64, jnp.float32))
+
+    return {"moe_gate": jax.random.normal(ks[0], (h, spec.router_width),
+                                          jnp.bfloat16) * h ** -0.5,
+            "moe_w_gate": q(ks[1], (e, h, i)), "moe_w_up": q(ks[2], (e, h, i)),
+            "moe_w_down": q(ks[3], (e, i, h))}
+
+
+def balanced(spec):
+    r, k = spec.router_width, spec.num_experts_per_tok
+
+    def route(router, spec, bias=None):
+        t = jnp.arange(router.shape[0])[:, None]
+        top_i = (t + jnp.arange(k)[None, :] * (r // k)) % r
+        return jnp.full(top_i.shape, 1.0 / k, jnp.float32), top_i
+    return route
+
+
+def timed(fn, *args, loops=3):
+    out = fn(*args)
+    jax.block_until_ready(out)
+    n = 5
+    best = float("inf")
+    for _ in range(loops):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        dt = (time.perf_counter() - t0) / n
+        best = min(best, dt)
+        n = max(5, min(50, int(0.2 / max(dt, 1e-5))))
+    return best * 1e3, out
+
+
+def products(spec, lp, x, local):
+    f = jax.jit(lambda x, lp: model.ffn_block(x, lp, spec, router_in=x,
+                                              experts_local=local))
+    try:
+        return timed(f, x, lp)
+    except Exception as e:  # noqa: BLE001 -- out of memory at a size: a hole in the table
+        print(f"# {type(e).__name__}: {str(e)[:200]}", file=sys.stderr)
+        return None, None
+
+
+def scanned(spec, lps, x, local, whole):
+    """Milliseconds a layer of ``model.scan_layers`` over the stacked
+    layers ``lps`` (as a served program runs them), the experts sliced a
+    layer or handed whole."""
+    n = lps["moe_gate"].shape[0]
+
+    def body(x, lp):
+        return x + model.ffn_block(x, lp, spec, router_in=x,
+                                   experts_local=local), None
+
+    f = jax.jit(lambda x, lps: model.scan_layers(
+        body, x, lps, spec, whole_experts=whole)[0])
+    try:
+        return round(timed(f, x, lps)[0] / n, 4)
+    except Exception as e:  # noqa: BLE001 -- out of memory at a size: a hole in the table
+        print(f"# {type(e).__name__}: {str(e)[:200]}", file=sys.stderr)
+        return None
+
+
+def kernel_calls(spec, lp, x, interpret):
+    """The kernel's two calls alone, on the batch's own sorted pairs."""
+    router = jnp.einsum("th,he->te", x, lp["moe_gate"],
+                        preferred_element_type=jnp.float32)
+    _, top_i = model.moe_route(router, spec)
+    top_i = top_i - spec.first_expert
+    e, k = spec.num_experts, spec.num_experts_per_tok
+    flat = jnp.where((top_i >= 0) & (top_i < e), top_i, e).reshape(-1)
+    order = jnp.argsort(flat)
+    sizes = jnp.sum(flat[:, None] == jnp.arange(e)[None, :], axis=0,
+                    dtype=jnp.int32)
+    rows = jnp.pad(x[order // k], ((0, -flat.shape[0] % experts.ROW_TILE),
+                                   (0, 0)))
+    walk = experts.visits(sizes, rows.shape[0])
+    gu = (lp["moe_w_gate"], lp["moe_w_up"])
+    # Stacks of one layer, made once: a [None] inside the timed call would
+    # copy the experts each time.
+    (gq, gs), (uq, us), (dq, ds) = (
+        (w.q[None], w.s[None]) for w in (*gu, lp["moe_w_down"]))
+    up_ms, ff = timed(lambda: experts.pairs_product(
+        rows, (gq, uq), (gs, us), 0, walk, act=spec.ffn_act,
+        interpret=interpret))
+    down_ms, _ = timed(lambda: experts.pairs_product(
+        ff, (dq,), (ds,), 0, walk, interpret=interpret))
+    return {"gate_up_ms": round(up_ms, 4), "down_ms": round(down_ms, 4),
+            "held_pairs": int(jnp.sum(sizes)), "pairs": int(flat.shape[0]),
+            "groups": int(jnp.sum(sizes > 0)), "visits": int(walk[3])}
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--rows", default=",".join(map(str, ROWS)))
+    ap.add_argument("--geometry", default=",".join(GEOMETRIES))
+    ap.add_argument("--routing", default="random,balanced")
+    ap.add_argument("--tiles", default="",
+                    help="ROW_TILExTILE_ELEMS variants of the grouped product")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="also scan this many stacked layers: ms a layer "
+                    "masked, grouped over sliced experts, grouped over whole")
+    ap.add_argument("--out", default="chiprun_out/expert_layer.jsonl")
+    args = ap.parse_args()
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind}
+    os.makedirs(os.path.dirname(args.out), exist_ok=True)
+    real_route = model.moe_route
+    # The threshold is what is being measured: either product at every size.
+    model.MOE_DENSE_MAX_ROWS = 0
+    local = "interpret" if dev.platform == "cpu" else True
+    with open(args.out, "a") as sink:
+        def say(line):
+            text = json.dumps({**line, "device": device})
+            print(text, flush=True)
+            sink.write(text + "\n")
+            sink.flush()
+
+        for name in args.geometry.split(","):
+            spec = GEOMETRIES[name]
+            lp = layer(spec, jax.random.key(40))
+            lps = args.layers and jax.tree.map(
+                lambda *a: jnp.stack(a), *(layer(spec, jax.random.key(i))
+                                           for i in range(args.layers)))
+            for routing in args.routing.split(","):
+                model.moe_route = (balanced(spec) if routing == "balanced"
+                                   else real_route)
+                for rows in map(int, args.rows.split(",")):
+                    x = jax.random.normal(jax.random.key(rows),
+                                          (rows, spec.hidden_size),
+                                          jnp.bfloat16)
+                    line = {"geometry": name, "routing": routing,
+                            "rows": rows}
+                    masked_ms, a = products(spec, lp, x, False)
+                    grouped_ms, b = products(spec, lp, x, local)
+                    line["masked_ms"] = masked_ms and round(masked_ms, 4)
+                    line["grouped_ms"] = grouped_ms and round(grouped_ms, 4)
+                    if a is not None and b is not None:
+                        a, b = (np.asarray(v, np.float32) for v in (a, b))
+                        line["mean_abs"] = float(np.abs(a).mean())
+                        line["max_diff"] = float(np.abs(a - b).max())
+                    if routing == "random" and grouped_ms is not None:
+                        line.update(kernel_calls(spec, lp, x,
+                                                 local == "interpret"))
+                    if args.layers:
+                        line["scan_masked_ms"] = scanned(spec, lps, x, False,
+                                                         False)
+                        line["scan_sliced_ms"] = scanned(spec, lps, x, local,
+                                                         False)
+                        line["scan_whole_ms"] = scanned(spec, lps, x, local,
+                                                        True)
+                    for variant in filter(None, args.tiles.split(",")):
+                        tm, elems = map(int, variant.split("x"))
+                        keep = experts.ROW_TILE, experts.TILE_ELEMS
+                        experts.ROW_TILE, experts.TILE_ELEMS = tm, elems
+                        jax.clear_caches()
+                        ms, _ = products(spec, lp, x, local)
+                        line[f"grouped_ms@{variant}"] = ms and round(ms, 4)
+                        experts.ROW_TILE, experts.TILE_ELEMS = keep
+                        jax.clear_caches()
+                    say(line)
+            model.moe_route = real_route
+            del lp
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

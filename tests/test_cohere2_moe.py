@@ -447,16 +447,25 @@ def test_load_stats_count_the_held_experts():
                                 mixtral).shape == (3,)
 
 
-def test_a_long_batch_of_a_share_is_the_masked_product_in_blocks(monkeypatch):
+def test_a_long_batch_of_a_share_takes_the_kernel_by_its_own_pairs(
+        monkeypatch):
+    """Above MOE_DENSE_MAX_ROWS a share sorts its pairs by the expert HELD
+    and multiplies each group by its own expert; a pair whose expert is held
+    elsewhere sorts behind the last group and adds nothing, as under the
+    masked product."""
     spec, params, _ = toy("int8")
     lp = jax.tree.map(lambda a: a[2], params["layers"])
     x = jax.random.normal(jax.random.key(1), (100, 64), jnp.bfloat16)
-    monkeypatch.setattr(model, "_grouped_experts", None)   # never taken
+    calls = []
+    real = model._grouped_experts
+    monkeypatch.setattr(model, "_grouped_experts",
+                        lambda *a, **kw: calls.append(1) or real(*a, **kw))
     outs = []
     for limit in (32, 10 ** 9):
         monkeypatch.setattr(model, "MOE_DENSE_MAX_ROWS", limit)
         outs.append(np.asarray(jax.jit(lambda x: model.ffn_block(
-            x, lp, spec, experts_local=True))(x), np.float32))
+            x, lp, spec, experts_local="interpret"))(x), np.float32))
+        assert len(calls) == 1          # taken above the threshold alone
     assert np.abs(outs[1]).mean() > 0.1
     np.testing.assert_allclose(outs[0], outs[1], atol=0.02)
 

@@ -238,6 +238,42 @@ def test_the_indexer_s_backend_is_an_info_series(monkeypatch, backend):
         assert up.g_index.get(backend=backend) == 1
 
 
+def test_the_expert_product_is_an_info_series_and_a_counter(monkeypatch):
+    """dynamo_tpu_perf_expert_product_info{program,kind}: 1 under each
+    (program family, product) the registry's live programs carry as the
+    label ``expert_product``; dynamo_tpu_moe_grouped_pairs_total follows
+    the runner's host-side count; a dense worker exposes neither sample."""
+    from dynamo_tpu.engine import perf as perf_mod
+    registry = CompileRegistry()
+    monkeypatch.setattr(perf_mod, "_REGISTRY", registry)
+    metrics = MetricsRegistry()
+    up = PerfMetricsUpdater(metrics, min_interval_s=0.0)
+    eng = _FakeEngine({})
+    up.update(eng, force=True)
+
+    def samples(prefix):
+        return [line for line in metrics.expose().decode().splitlines()
+                if line.startswith(prefix)]
+
+    assert samples("dynamo_tpu_perf_expert_product_info{") == []
+    kept = [registry.wrap("prefill", lambda x: x, key=(rows,),
+                          labels={"expert_product": kind})
+            for rows, kind in ((512, "grouped"), (128, "masked"))]
+    kept.append(registry.wrap("decode_window", lambda x: x, key=(8,),
+                              labels={"expert_product": "masked"}))
+    eng.runner.moe_grouped_pairs = 3072
+    up.update(eng, force=True)
+    info = samples("dynamo_tpu_perf_expert_product_info{")
+    assert len(info) == 3 and all(line.endswith(" 1.0") for line in info)
+    assert up.g_expert_product.get(program="prefill", kind="grouped") == 1
+    assert up.g_expert_product.get(program="decode_window",
+                                   kind="masked") == 1
+    assert up.c_moe_grouped_pairs.get() == 3072
+    eng.runner.moe_grouped_pairs += 1536
+    up.update(eng, force=True)
+    assert up.c_moe_grouped_pairs.get() == 4608
+
+
 # -- flight ring: tokens column stays allocation-free -------------------------
 
 
@@ -393,7 +429,11 @@ async def test_perf_smoke_engine_zero_recompiles_and_pane(tmp_path):
                        engine.runner._window_cache.values()}) == [16]
         assert 16 in snap1["programs"]["decode_window"]["labels"][
             "page_size"]
-        assert snap1["programs"]["prefill"]["labels"] == {}
+        # A dense block's prefill programs carry no label (the registry is
+        # the process's: a routed runner of an earlier test may still have
+        # ``expert_product`` on its own).
+        assert engine.runner._prefill_cache and all(
+            fn._labels == {} for fn in engine.runner._prefill_cache.values())
         engine.perf_metrics.update(engine, force=True)
         text = metrics.expose().decode()
         assert text.count("dynamo_tpu_perf_") > 0

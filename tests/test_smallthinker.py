@@ -418,10 +418,11 @@ def test_embeddings_agree_with_the_references_pooled_hidden_state(pooling):
 
 @pytest.mark.parametrize("quant", [None, "int8"])
 def test_grouped_product_matches_the_masked_product(quant, monkeypatch):
-    """Above MOE_DENSE_MAX_ROWS tokens are sorted by expert and multiplied
-    by their own experts only (jax.lax.ragged_dot); the result is the
-    masked product's, to bfloat16's rounding, with skewed routing and
-    experts nobody chose (empty groups)."""
+    """Above MOE_DENSE_MAX_ROWS the (token, choice) pairs are sorted by
+    expert and multiplied by their own experts only (the kernel of
+    engine/experts.py, interpreted here); the result is the masked
+    product's, to bfloat16's rounding, with skewed routing and experts
+    nobody chose (empty groups)."""
     spec, params, _ = toy(quant)
     lp = jax.tree.map(lambda a: a[2], params["layers"])
     x = jax.random.normal(jax.random.key(1), (96, 64), jnp.bfloat16)
@@ -435,7 +436,7 @@ def test_grouped_product_matches_the_masked_product(quant, monkeypatch):
     for limit in (64, 10 ** 9):
         monkeypatch.setattr(model, "MOE_DENSE_MAX_ROWS", limit)
         outs.append(np.asarray(jax.jit(lambda x, rin: model.ffn_block(
-            x, lp, spec, router_in=rin, experts_local=True))(x, rin),
+            x, lp, spec, router_in=rin, experts_local="interpret"))(x, rin),
             np.float32))
     assert np.abs(outs[1]).mean() > 0.2
     np.testing.assert_allclose(outs[0], outs[1], atol=0.05)
@@ -444,15 +445,18 @@ def test_grouped_product_matches_the_masked_product(quant, monkeypatch):
 @pytest.mark.parametrize("local", [True, False])
 def test_the_product_is_chosen_by_rows_and_by_where_the_experts_are(
         local, monkeypatch):
-    """One rule for both routed kinds: the grouped product above
+    """One rule for every routed kind: the grouped product above
     MOE_DENSE_MAX_ROWS rows where the caller says the experts are whole on
     one device (the runner: a mesh of one), the masked product below it and
     wherever the expert axis may be partitioned. A Mixtral-style spec takes
-    the same fork; no model's name decides."""
+    the same fork; no model's name decides. The constant stays at 64 or
+    above: a decode step's rows (32) and a verify step's (64) keep the
+    masked product (ROADMAP S9)."""
+    assert model.MOE_DENSE_MAX_ROWS >= 64
     calls = []
     real = model._grouped_experts
     monkeypatch.setattr(model, "_grouped_experts",
-                        lambda *a: calls.append(1) or real(*a))
+                        lambda *a, **kw: calls.append(1) or real(*a, **kw))
     mixtral = ModelSpec(vocab_size=64, hidden_size=32, intermediate_size=16,
                         num_layers=1, num_heads=2, num_kv_heads=1,
                         num_experts=4, num_experts_per_tok=2)
@@ -462,8 +466,12 @@ def test_the_product_is_chosen_by_rows_and_by_where_the_experts_are(
     for rows in (model.MOE_DENSE_MAX_ROWS, model.MOE_DENSE_MAX_ROWS + 8):
         x = jax.random.normal(jax.random.key(rows), (rows, 32), jnp.bfloat16)
         calls.clear()
-        out[rows] = model.ffn_block(x, lp, mixtral, experts_local=local)
-        assert bool(calls) == (local and rows > model.MOE_DENSE_MAX_ROWS)
+        out[rows] = model.ffn_block(x, lp, mixtral,
+                                    experts_local=local and "interpret")
+        grouped = local and rows > model.MOE_DENSE_MAX_ROWS
+        assert bool(calls) == grouped
+        assert model.expert_product(rows, local) == (
+            "grouped" if grouped else "masked")
         masked = model.ffn_block(x, lp, mixtral)
         np.testing.assert_allclose(
             np.asarray(out[rows], np.float32),
@@ -472,7 +480,9 @@ def test_the_product_is_chosen_by_rows_and_by_where_the_experts_are(
                 max_pages_per_seq=16, max_num_seqs=2,
                 prefill_buckets=(16, 32), attention_backend="xla")
     params = model.init_params(mixtral, jax.random.key(0))
-    assert ModelRunner(EngineConfig(**base), params=params).experts_local
+    # The CPU interprets the kernel, as it does the attention kernels.
+    assert ModelRunner(EngineConfig(**base),
+                       params=params).experts_local == "interpret"
     assert not ModelRunner(EngineConfig(**base, tp=2),
                            params=params).experts_local
 
