@@ -26,7 +26,13 @@ program's init seed), and checks what comes out by the repo's own means:
              with the published 64 index heads over prompts past the 2,048
              keys it keeps (the indexer's kernel: a row's live pages of
              index keys walked and scored, against XLA's gather of the
-             bucket; select_topk chooses over either's scores);
+             bucket; select_topk chooses over either's scores), and on the
+             Nemotron-H block at toy depth and its attention geometry (32
+             query heads over 2 KV heads of 128, a page of 128; a pool of
+             its ONE attention layer; Mamba-2 mixers whose float32 state
+             lies by slot beside the pool, on the device, and is carried
+             through chunked prefill and the windows; two-matrix relu2
+             experts, a share of them, under the masked product alone);
              ``tpu_custom_call`` must be in the compiled window program
              wherever a kernel runs
   disagg     prefill engine -> KV plane -> decode engine on the one chip;
@@ -583,8 +589,24 @@ async def phase_kernels(args, jax, rng, keep: dict):
     # bucket and scores the copy; select_topk chooses over either's.
     indexed = dataclasses.replace(latent, name="smoke-latent-index",
                                   index_n_heads=64)
+    # The Nemotron-H block at toy depth: all three kinds of layer, a *
+    # between an M and an E, Nemotron-3-Nano's attention geometry (32 query
+    # heads over 2 KV heads of 128: a page of 128 by the same rule) and its
+    # mixer's head and state sizes, a share of two-matrix relu2 experts and
+    # a shared expert of its own width. The longest prompt is prefilled in
+    # chunks: the state is carried from chunk to chunk in its slot.
+    from dynamo_tpu.engine.config import NemotronHSpec
+    hybrid = NemotronHSpec(
+        name="smoke-hybrid", vocab_size=2048, hidden_size=512,
+        intermediate_size=256, num_layers=7, num_heads=32, num_kv_heads=2,
+        head_dim=128, rms_norm_eps=1e-5, num_experts=4,
+        num_experts_per_tok=2, moe_intermediate_size=256,
+        num_routed_experts=8, first_expert=4, num_shared_experts=1,
+        shared_intermediate_size=384, routed_scaling_factor=2.5,
+        layer_pattern="MEM*EME", ssm_heads=8, ssm_head_dim=64, ssm_groups=2,
+        ssm_state=128, ssm_conv=4, ssm_chunk=128)
     pages = {wide.name: 64, share.name: 32, latent.name: 64,
-             indexed.name: 64}  # derived
+             indexed.name: 64, hybrid.name: 128}  # derived
     short = (20, 70, 150) if args.rehearse_cpu else (24, 200, 700)
     past_topk = (20, 2100) if args.rehearse_cpu else (24, 2200, 2600)
     assert max(short) + 64 < indexed.index_topk < min(past_topk[1:])
@@ -599,7 +621,8 @@ async def phase_kernels(args, jax, rng, keep: dict):
             (wide, None, None, ("xla", "auto"), short, 1024),
             (share, None, None, ("xla", "auto"), short, 1024),
             (latent, None, None, ("xla", kernels), short, 1024),
-            (indexed, None, None, ("xla", kernels), past_topk, 4096)):
+            (indexed, None, None, ("xla", kernels), past_topk, 4096),
+            (hybrid, None, None, ("xla", "auto"), short, 1024)):
         prompts = [rng.integers(2, spec_r.vocab_size, size=n).tolist()
                    for n in lengths]
         runs = {}
@@ -662,14 +685,18 @@ async def phase_kernels(args, jax, rng, keep: dict):
                            if isinstance(k[0], int))
                 packed = np.zeros((runner.config.max_num_seqs,
                                    PK_PREFIX + key[1]), np.int32)
-                shapes = jax.tree.map(  # shapes: the engine owns the arrays
+                # (a block with recurrent layers: its two state arrays too)
+                state = (runner.ssm_state, runner.conv_state)
+                *shapes, state = jax.tree.map(  # the engine owns the arrays
                     lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                                    sharding=a.sharding),
                     (runner.params, runner.k_cache, runner.v_cache,
-                     runner.tokens_dev, jnp.asarray(packed), runner._rng))
+                     runner.tokens_dev, jnp.asarray(packed), runner._rng,
+                     state if spec_r.recurrent else ()))
                 with runner.mesh:
                     text = runner._window_cache[key].lower(
-                        *shapes).compile().as_text()
+                        *shapes, **({"state": state} if state else {})
+                    ).compile().as_text()
                 custom_call = "tpu_custom_call" in text
                 check(custom_call == on_tpu,
                       f"tpu_custom_call in the window program: "
@@ -685,15 +712,27 @@ async def phase_kernels(args, jax, rng, keep: dict):
                                       ("decode_window",
                                        eng.runner._window_cache))
             } if spec_r.num_experts else None
+            # (two-matrix experts take the masked product at every size).
+            grouped = not args.rehearse_cpu and spec_r.ffn_act != "relu2"
             check(products is None or (
-                ("grouped" in products["prefill"]) != args.rehearse_cpu
+                ("grouped" in products["prefill"]) == grouped
                 and products["decode_window"] == ["masked"]),
                 f"expert products of {spec_r.name}: {products}")
+            state_on = None
+            if spec_r.recurrent:
+                # The recurrent state beside the pool: where the arrays the
+                # programs handed back lie, and that steps were counted.
+                state_on = sorted({d.platform for a in (
+                    eng.runner.ssm_state, eng.runner.conv_state)
+                    for d in a.devices()})
+                check(state_on == [jax.devices()[0].platform]
+                      and eng.perf_status()["ssm"]["row_steps"] > 0,
+                      f"recurrent state of {spec_r.name} on {state_on}")
             emit("kernels.run", model=spec_r.name,
                  quant_kv=quant_kv or "bf16", attention_backend=backend,
                  resolved=resolved, kv_commit_backend=commit,
                  index_backend=index, attn_selected_pct=selected,
-                 expert_product=products,
+                 expert_product=products, ssm_state_on=state_on,
                  page_size=eng.runner.page_size,
                  prompt_lengths=lengths, chunk_tokens=chunks,
                  seconds=round(seconds, 2), tpu_custom_call=custom_call)

@@ -30,6 +30,7 @@ import dataclasses
 import json
 import math
 import os
+import re
 
 
 #: What a leading dense layer's leaves are called in ``params["layers"]``
@@ -134,6 +135,16 @@ class ModelSpec:
     moe_select_bias = False
     rope_yarn = None                    # plain frequencies theta ** (-2i / d)
     mtp_layers = 0                      # no prediction module to draft with
+    # What the Nemotron-H block states (NemotronHSpec). layer_pattern None:
+    # every layer is attention, then a feed-forward.
+    layer_pattern = None
+    ssm_heads = 0
+    ssm_head_dim = 0
+    ssm_groups = 0
+    ssm_state = 0
+    ssm_conv = 0
+    ssm_chunk = 0
+    shared_intermediate_size = None     # a shared expert is expert_size wide
     # Weight-only quantization: None (bf16) or "int8" (engine/quant.py —
     # int8 storage, bf16 MXU compute; halves the weight-read roofline and
     # fits full llama-3-8b on one 16 GB v5e).
@@ -177,10 +188,50 @@ class ModelSpec:
         return self.kv_lora_rank > 0
 
     @property
+    def recurrent(self) -> bool:
+        """Some layers keep a state a ROW (``ssm_layers`` of them), beside
+        what the attention layers leave a TOKEN in the pool."""
+        return self.ssm_layers > 0
+
+    @property
+    def ssm_layers(self) -> int:
+        return (self.layer_pattern or "").count("M")
+
+    @property
+    def expert_layers(self) -> int:
+        """Layers with a routed feed-forward: every one, or a pattern's E."""
+        if self.layer_pattern:
+            return self.layer_pattern.count("E")
+        return self.num_layers - self.first_k_dense if self.num_experts else 0
+
+    @property
+    def ssm_channels(self) -> int:
+        """Inputs of the recurrent layer's convolution: x | B | C."""
+        return (self.ssm_heads * self.ssm_head_dim
+                + 2 * self.ssm_groups * self.ssm_state)
+
+    @property
+    def ssm_state_shapes(self) -> tuple[tuple, tuple]:
+        """What ONE row keeps in ONE recurrent layer: the state S [heads,
+        head_dim, state] (float32) and the convolution's last inputs
+        [ssm_conv - 1, channels] (bfloat16)."""
+        return ((self.ssm_heads, self.ssm_head_dim, self.ssm_state),
+                (self.ssm_conv - 1, self.ssm_channels))
+
+    @property
+    def ssm_state_bytes_per_row(self) -> int:
+        """Bytes of recurrent state a row (a slot) holds over all layers."""
+        s, c = self.ssm_state_shapes
+        return self.ssm_layers * (4 * math.prod(s) + 2 * math.prod(c))
+
+    @property
     def pool_layers(self) -> int:
         """Layers of the two pool arrays: the model's, then one a prediction
         module (``mtp_layers``), whose block leaves entries of the same
-        width under the same page table."""
+        width under the same page table; under a ``layer_pattern`` the
+        attention layers alone (the others leave nothing a token)."""
+        if self.layer_pattern:
+            return self.layer_pattern.count("*")
         return self.num_layers + self.mtp_layers
 
     @property
@@ -203,6 +254,11 @@ class ModelSpec:
         experts HELD, shared experts, QKV biases, one norm a layer in a
         parallel block; the latent projections, the indexer, the selection
         bias and the leading dense layers of the DeepSeek-V3.2 block)."""
+        if self.layer_pattern:
+            from dynamo_tpu.engine.model import param_shapes
+            shapes = param_shapes(self)
+            return sum(math.prod(s) for s in (
+                *shapes.pop("layers").values(), *shapes.values()))
         h, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
         d = self.head_dim
         if self.latent:
@@ -271,6 +327,8 @@ class ModelSpec:
             return cls._from_deepseek_v32(cfg, path)
         if cfg.get("model_type") == "glm4_moe_lite":
             return cls._from_glm4_moe_lite(cfg, path)
+        if cfg.get("model_type") == "nemotron_h":
+            return cls._from_nemotron_h(cfg, path)
         return cls(
             name=cfg.get("_name_or_path", os.path.basename(os.path.dirname(path))),
             vocab_size=cfg["vocab_size"],
@@ -579,6 +637,91 @@ class ModelSpec:
             mtp_layers=modules,
         )
 
+    @classmethod
+    def _from_nemotron_h(cls, cfg: dict, path: str) -> "ModelSpec":
+        """Nemotron-3-Nano's keys (nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16
+        ``config.json``, ``nemotron_h``): ``hybrid_override_pattern`` gives
+        every layer ONE mixer (M a Mamba-2 layer, E an expert layer, * an
+        attention layer); ``n_routed_experts`` and ``expert_parallel`` as
+        ``_from_deepseek_v32`` reads them. ``expand`` is not read (the inner
+        width is mamba_num_heads x mamba_head_dim), nor the rope keys (the
+        attention layers rotate nothing) nor the ``time_step_*`` keys (they
+        shape the initialisation of dt_bias; the row has no
+        ``time_step_limit``, so dt is not clamped)."""
+        reader = "the config reader"
+        for key, want, why in (
+                ("attention_bias", False, "the attention layers have no "
+                 "bias leaves"),
+                ("mamba_proj_bias", False, "the recurrent layer's "
+                 "projections have no bias leaves"),
+                ("mlp_bias", False, "the experts have no bias leaves"),
+                ("use_bias", False, "no projection of this block has a bias "
+                 "leaf"),
+                ("use_conv_bias", True, "the convolution is written down "
+                 "with its bias"),
+                ("mamba_hidden_act", "silu", "the convolution's activation "
+                 "and the gate are SiLU"),
+                ("mlp_hidden_act", "relu2", "the experts are two matrices "
+                 "around a squared ReLU (relu2)"),
+                ("scoring_func", "sigmoid", "the router scores with a "
+                 "sigmoid"),
+                ("n_shared_experts", 1, "ONE shared expert of its own width "
+                 "is added to the routed sum"),
+                ("sliding_window", None, "the attention layers see every "
+                 "earlier key"),
+                ("residual_in_fp32", False, "the residual stream is "
+                 "bfloat16")):
+            got = cfg.get(key, want)
+            if got != want:
+                raise UnsupportedBlockError(
+                    reader, f"nemotron_h with {key} {got!r}: {why}")
+        if cfg.get("time_step_limit"):
+            raise UnsupportedBlockError(
+                reader, "nemotron_h with time_step_limit: the recurrent "
+                "layer is written down without a clamp on dt")
+        pattern = cfg["hybrid_override_pattern"]
+        if len(pattern) != cfg["num_hidden_layers"]:
+            raise UnsupportedBlockError(
+                reader, f"nemotron_h whose hybrid_override_pattern has "
+                f"{len(pattern)} layers for num_hidden_layers "
+                f"{cfg['num_hidden_layers']}")
+        share = cfg.get("expert_parallel") or {}
+        return NemotronHSpec(
+            name=cfg.get("_name_or_path")
+            or os.path.basename(os.path.dirname(path)),
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=cfg["num_hidden_layers"],
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg["num_key_value_heads"],
+            head_dim=cfg.get("head_dim"),
+            rms_norm_eps=cfg.get("layer_norm_epsilon",
+                                 cfg.get("norm_eps", 1e-5)),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            max_position_embeddings=cfg.get("max_position_embeddings", 8192),
+            num_experts=cfg["n_routed_experts"],
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            moe_intermediate_size=cfg["moe_intermediate_size"],
+            norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
+            num_routed_experts=share.get("routed_experts",
+                                         cfg["n_routed_experts"]),
+            first_expert=share.get("first_expert", 0),
+            num_shared_experts=1,
+            n_group=cfg.get("n_group", 1),
+            topk_group=cfg.get("topk_group", 1),
+            routed_scaling_factor=float(cfg.get("routed_scaling_factor", 1.0)),
+            layer_pattern=pattern,
+            ssm_heads=cfg["mamba_num_heads"],
+            ssm_head_dim=cfg["mamba_head_dim"],
+            ssm_groups=cfg["n_groups"],
+            ssm_state=cfg["ssm_state_size"],
+            ssm_conv=cfg["conv_kernel"],
+            ssm_chunk=cfg.get("chunk_size", 128),
+            shared_intermediate_size=cfg[
+                "moe_shared_expert_intermediate_size"],
+        )
+
 
 @dataclasses.dataclass
 class SmallThinkerSpec(ModelSpec):
@@ -733,6 +876,59 @@ class DeepseekV32Spec(Cohere2MoeSpec):
                 scale *= (0.1 * mscale_all * math.log(factor) + 1.0) ** 2
         return scale
 
+@dataclasses.dataclass
+class NemotronHSpec(Cohere2MoeSpec):
+    """The Nemotron-H block (nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16,
+    ``nemotron_h``): every layer is h + Mixer(RMS(h)) with ONE mixer, its
+    kind by ``layer_pattern``; the sigmoid router, the share of a wider
+    router and the shared expert that Cohere2MoeSpec states, at this
+    block's values, and what it states beyond them. The programs are
+    engine/hybrid.py's."""
+    norm_kind: str = "rms"
+    parallel_block: bool = False
+    rope_interleaved: bool = False      # nothing rotates: no rope at all
+    # "relu2": an expert is TWO matrices, down(relu(up x) ** 2): no gate
+    # leaf. The shared expert is one of the same form, of its own width,
+    # added unscaled.
+    ffn_act: str = "relu2"
+    shared_intermediate_size: int | None = None
+    # The router's choice as DeepseekV32Spec states it.
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    moe_select_bias: bool = True
+    # One letter a layer: M a Mamba-2 mixer, E an expert layer, * an
+    # attention layer (no rotary embedding, every earlier key). The pattern
+    # is pairs of M and E, a pair at a time, some with a * between the two:
+    # the programs scan the stacked pairs (hybrid.pairs_of).
+    layer_pattern: str | None = None
+    # The Mamba-2 mixer: ssm_heads heads of ssm_head_dim, B and C in
+    # ssm_groups groups of ssm_state, a causal depthwise convolution of
+    # ssm_conv taps over x | B | C; prefill computes the recurrence in
+    # chunks of ssm_chunk tokens. A row keeps the state S [heads, head_dim,
+    # state] in float32 and the convolution's last ssm_conv - 1 inputs.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 0
+    ssm_chunk: int = 128
+
+    def __post_init__(self):
+        super().__post_init__()
+        pattern = self.layer_pattern or ""
+        if len(pattern) != self.num_layers or set(pattern) - set("ME*"):
+            raise ValueError(f"layer_pattern {pattern!r} does not give "
+                             f"{self.num_layers} layers of M, E and *")
+        if not re.fullmatch(r"(M\*?E)+", pattern):
+            raise UnsupportedBlockError(
+                "the layer scan", f"layer_pattern {pattern!r} is not pairs "
+                "of M and E with at most one * between the two: the scan "
+                "over stacked layers is written for that form alone")
+        if self.ssm_heads % self.ssm_groups:
+            raise ValueError(f"{self.ssm_heads} heads do not divide into "
+                             f"{self.ssm_groups} groups")
+
 
 class UnsupportedBlockError(NotImplementedError):
     """A path that lacks a mechanism a model's block needs refuses the
@@ -791,10 +987,28 @@ def block_refusals(spec: ModelSpec, config: "EngineConfig | None" = None,
             "disk tiers)", "a parcel is K and V pages of one shape stacked, "
             "and a latent pool's page is a latent entry and an index key "
             "of different widths"))
+    recurrent = spec.recurrent
+    if kv_transfer and recurrent:
+        out.append(UnsupportedBlockError(
+            "a KV parcel (KV-plane tickets, disaggregated insert, host and "
+            "disk tiers)", "a parcel is pages, and a row of this model also "
+            "holds a recurrent state a layer, which has no parcel: pages "
+            "without the state at their border continue nothing"))
+    if checkpoint and recurrent:
+        out.append(UnsupportedBlockError(
+            "the safetensors loader", "it has no tensor-name map for the "
+            "recurrent layer's leaves (random weights only)"))
+    if embeddings and recurrent:
+        out.append(UnsupportedBlockError(
+            "encoder embeddings in a prompt (mm_embeds)", "the programs of "
+            "a block with recurrent layers (engine/hybrid.py) take token "
+            "rows alone"))
     if config is None:
         return out
     if latent:
         out += _latent_refusals(spec, config)
+    if recurrent:
+        out += _recurrent_refusals(spec, config)
     if share and config.tp * config.pp * config.dp * config.sp > 1:
         out.append(UnsupportedBlockError(
             "a tp/pp/dp/sp mesh", f"the expert layer is told ONE share "
@@ -851,6 +1065,52 @@ def block_refusals(spec: ModelSpec, config: "EngineConfig | None" = None,
             "a tp/pp/dp/sp mesh", f"{unlike} was never compared with its "
             "reference on more than one device (its grouped expert product "
             "has no partitioning rule)"))
+    return out
+
+
+def _recurrent_refusals(spec: ModelSpec, config: "EngineConfig"
+                        ) -> list[UnsupportedBlockError]:
+    """block_refusals' part for a block with recurrent layers
+    (``spec.recurrent``): every engine path that assumes a row's whole
+    state is pages, by what it lacks."""
+    out = []
+    if config.spec_decode:
+        out.append(UnsupportedBlockError(
+            f"speculative decoding (spec_decode {config.spec_decode})",
+            "a rejected draft has to be undone, and the recurrent state a "
+            "verify step leaves is the state AFTER every drafted token: "
+            "there is no copy of the state before them to go back to"))
+    if config.host_cache_pages > 0 or config.kv_disk_cache_dir:
+        out.append(UnsupportedBlockError(
+            "the host and disk KV tiers (kvbm)", "they move parcels of "
+            "pages, and pages onboarded without the recurrent state at "
+            "their border continue nothing"))
+    if config.tp * config.pp * config.dp * config.sp > 1:
+        out.append(UnsupportedBlockError(
+            "a tp/pp/dp/sp mesh", "the recurrent state arrays and the "
+            "programs that carry them (engine/hybrid.py) have no "
+            "partitioning rule: they were written and compared with their "
+            "reference on one device"))
+    if config.ring_attention:
+        out.append(UnsupportedBlockError(
+            "ring attention", "its blocks of a prompt rotate K and V "
+            "between devices, and the recurrence over the prompt has no "
+            "hand-over of its state from one block's device to the next"))
+    if config.pp_microbatch:
+        out.append(UnsupportedBlockError(
+            "the pipelined prefill (pp_microbatch)", "a stage's scan takes "
+            "one stack of alike layers and carries no recurrent state from "
+            "stage to stage"))
+    if config.max_adapters > 0:
+        out.append(UnsupportedBlockError(
+            "LoRA adapters (max_adapters)", "the adapter targets are wq, "
+            "wk, wv and wo of every layer, and most of this block's layers "
+            "(the recurrent and the expert layers) have none of them"))
+    if config.resolve_quant_kv() is not None:
+        out.append(UnsupportedBlockError(
+            "int8 KV pages (quant_kv)", "the programs of a block with "
+            "recurrent layers (engine/hybrid.py) were compared with their "
+            "reference over a bfloat16 pool alone"))
     return out
 
 

@@ -36,7 +36,8 @@ from dynamo_tpu.engine.kv_cache import PageAllocator
 from dynamo_tpu.engine.runner import (
     ModelRunner, PrefillSeq, PK_OVERRIDE, PK_TOKEN, PK_POS, PK_SEQLEN,
     PK_TOPK, PK_TEMP, PK_TOPP, PK_CAP, PK_LOGPROB, PK_FREQPEN, PK_PRESPEN,
-    PK_SEED, PK_SEEDED, PK_ADAPTER, PK_PREFIX, TOP_LOGPROBS)
+    PK_SEED, PK_SEEDED, PK_ADAPTER, PK_PREFIX, PREFIX_REUSE_OFF,
+    SSM_STATE_DTYPE, TOP_LOGPROBS)
 from dynamo_tpu.engine.sampler import MAX_TOPK
 from dynamo_tpu.llm.kv_router.protocols import (ForwardPassMetrics, KvStats,
                                                 SpecDecodeStats, WorkerStats)
@@ -144,6 +145,9 @@ class _Window:
     # live rows attended and keys they had in context, over steps and
     # layers.
     attn: object = None
+    # A block with recurrent layers: the live rows summed over the window's
+    # steps, read back beside them (the flight ring's ssm_row_steps).
+    ssm: float = 0.0
     # Speculative windows: toks = (outs [m,B,S], emits [m,B],
     # ndrafts [m,B]), or under "mtp" the plain window's five with an axis
     # of spec_k + 1 positions behind the rows' and "emit" / "drafted"
@@ -313,6 +317,9 @@ class TPUEngine(AsyncEngine):
         # A latent block's windows: the keys its live rows attended and
         # the keys they had in context (the entries of _Window.attn).
         self.attn_totals = np.zeros(2, np.float64)
+        # A block with recurrent layers: (decode step, live row) pairs of
+        # every window processed: the rows whose state a step had to touch.
+        self.ssm_row_steps = 0.0
         # Control jobs executed on the engine thread between windows
         # (disagg prefill-extract, KV injection helpers, etc.).
         self._jobs: queue.Queue = queue.Queue()
@@ -1159,6 +1166,19 @@ class TPUEngine(AsyncEngine):
                 "selected_pct": round(100.0 * selected / context, 3)
                 if context else None,
             }
+        if self.runner.spec.recurrent:
+            spec = self.runner.spec
+            status["ssm"] = {
+                # Recurrent layers, and what a row (a slot) keeps over them
+                # beside its pages (float32 S, the convolution's inputs).
+                "layers": spec.ssm_layers,
+                "state_bytes_per_row": spec.ssm_state_bytes_per_row,
+                "state_dtype": SSM_STATE_DTYPE,
+                # (decode step, live row) pairs so far.
+                "row_steps": int(self.ssm_row_steps),
+                # Static: a page's border has no state to continue from.
+                "prefix_reuse": PREFIX_REUSE_OFF,
+            }
         if self.config.spec_decode:
             # Verify-of-k bandwidth: the spec program runs m_outer verify
             # steps of S = spec_k + 1 positions each, so cost-registry
@@ -1924,6 +1944,15 @@ class TPUEngine(AsyncEngine):
         canonical = (getattr(s, "seed", None) is not None
                      and (s.temperature or 0.0) > 0.0
                      and r.generated == 0)
+        # A block with recurrent layers takes no cached page and registers
+        # no hash (no_cache: nothing is published to the router either): a
+        # page's border has no recurrent state to continue from until
+        # snapshots exist, so every prompt is computed from its first token
+        # (/debug/perf ``ssm.prefix_reuse``).
+        recurrent = self.runner.spec.recurrent
+        if recurrent:
+            r.no_cache = True
+        canonical = canonical or recurrent
         cached_pages = ([] if canonical
                         else self.allocator.acquire_cached(hashes))
         reuse_tokens = len(cached_pages) * page
@@ -2070,7 +2099,8 @@ class TPUEngine(AsyncEngine):
                 hist_pages=hist if len(hist) else None,
                 sampling=(0.0, 0, 1.0), embeds=emb, embeds_mask=emb_mask,
                 adapter_id=r.adapter_slot,
-                next_token=int(r.tokens_all[start + n]), next_page=next_page)
+                next_token=int(r.tokens_all[start + n]), next_page=next_page,
+                slot=r.slot)
         return PrefillSeq(
             tokens=tokens, start_pos=start, chunk_pages=chunk_pages,
             hist_pages=hist if len(hist) else None,
@@ -2078,7 +2108,7 @@ class TPUEngine(AsyncEngine):
             logprobs=r.req.sampling_options.logprobs is not None,
             penalties=self._penalties_of(r), seed=self._seed_of(r),
             embeds=emb, embeds_mask=emb_mask, adapter_id=r.adapter_slot,
-            next_page=next_page)
+            next_page=next_page, slot=r.slot)
 
     def _dispatch_prefill_chunks(self) -> bool:
         """One scheduling pass over the prefilling requests: dispatch at
@@ -2557,6 +2587,9 @@ class TPUEngine(AsyncEngine):
                 if "attn" in counted:
                     w.attn = np.asarray(counted["attn"], np.float64)
                     self.attn_totals += w.attn
+                if "ssm" in counted:
+                    w.ssm = float(np.asarray(counted["ssm"])[0])
+                    self.ssm_row_steps += w.ssm
             self._note_ready(w)
         else:
             toks = None
@@ -2862,7 +2895,10 @@ class TPUEngine(AsyncEngine):
     def _requeue_slot(self, slot: int) -> None:
         """Preempt: free this slot's pages (prefix-cache entries survive so
         the re-prefill mostly hits) and requeue the request with its
-        accumulated tokens."""
+        accumulated tokens. A block with recurrent layers registered no
+        entry and has no snapshot of its state: the re-prefill recomputes
+        the row from its first token, and starts (at position 0) from a
+        zero state whatever the slot it lands in held."""
         r = self.slot_req[slot]
         self._finish_slot(slot, register=True, requeue=True)
         if r is None:
@@ -2932,7 +2968,7 @@ class TPUEngine(AsyncEngine):
                 "attn_selected": w.attn[0], "attn_context": w.attn[1]}),
             prefilling=w.prefilling, admit_stop=self._flight_admit_stop,
             **dict(zip(("spec_drafted", "spec_accepted", "spec_row_steps"),
-                       w.drafted)))
+                       w.drafted)), ssm_row_steps=w.ssm)
         if accepted:
             # A frozen ring (bundle capture in flight) rejects the row:
             # keep accumulating so the stall/chunk/token/host-time deltas

@@ -14,6 +14,14 @@ or more live sequences; may also be registered for sharing), or INACTIVE
 (registered, refcount 0 — reusable on a prefix hit, evictable LRU).
 Only INACTIVE pages may be evicted: evicting a page a live sequence still
 writes to would silently corrupt its KV.
+
+A block with recurrent layers (``ModelSpec.recurrent``) keeps a second kind
+of per-request state that is NOT here: arrays of the runner indexed by SLOT
+(``ModelRunner.ssm_state`` / ``conv_state``), sized ahead of this pool. Its
+pages (the attention layers' K and V alone) are allocated, shared by
+refcount and released as any other, but none is registered under a hash and
+none is taken on a prefix hit: the state at a page's border is kept nowhere,
+so a prefix of pages continues nothing (engine.TPUEngine._plan_prefill).
 """
 
 from __future__ import annotations

@@ -166,12 +166,52 @@ def _expert_shapes(spec: ModelSpec, L: int) -> dict:
               "moe_w_down": (L, E, ie, h)}
     if spec.num_shared_experts:
         S = spec.num_shared_experts
-        shapes.update({"shared_w_gate": (L, S, h, ie),
-                       "shared_w_up": (L, S, h, ie),
-                       "shared_w_down": (L, S, ie, h)})
+        si = spec.shared_intermediate_size or ie
+        shapes.update({"shared_w_gate": (L, S, h, si),
+                       "shared_w_up": (L, S, h, si),
+                       "shared_w_down": (L, S, si, h)})
+    if spec.ffn_act == "relu2":     # two matrices an expert: no gate leaf
+        shapes = {k: v for k, v in shapes.items() if "_w_gate" not in k}
     if spec.moe_select_bias:
         shapes["moe_bias"] = (L, spec.router_width, 1)
     return shapes
+
+
+def _recurrent_shapes(spec: ModelSpec, L: int) -> dict:
+    """The leaves of L Mamba-2 mixers (engine/hybrid.py has the equations).
+    The in-projection W_in (z | xBC | dt) is stored as two leaves, the same
+    numbers: z | xBC (a whole number of 128-lane tiles wide) and dt (one
+    column a head); in one leaf of 10,304 columns the TPU's compiler copies
+    the whole int8 stack into a padded layout every window (0.64 GB, 2.5
+    ms: my chip run, PR 41, call 1).
+    The convolution's taps lie [tap, channel] and the vectors of a head end
+    in a 1, so that a generator drawing normal / sqrt(shape[-2]) draws taps
+    of 1/2 (the convolution's output is of its input's size and the
+    recurrence's term S C is a first-order part of y, beside D x) and small
+    biases; the gated norm's weight is a ``_norm`` leaf (ones)."""
+    h, nh = spec.hidden_size, spec.ssm_heads
+    inner, chan = nh * spec.ssm_head_dim, spec.ssm_channels
+    return {"ssm_w_in": (L, h, inner + chan),          # z | xBC
+            "ssm_w_dt": (L, h, nh),
+            "ssm_conv_w": (L, spec.ssm_conv, chan),
+            "ssm_conv_bias": (L, chan, 1),
+            "ssm_dt_bias": (L, nh, 1),
+            "ssm_a_log": (L, nh, 1),
+            "ssm_d": (L, nh, 1),
+            "ssm_gate_norm": (L, inner),
+            "ssm_w_out": (L, inner, h)}
+
+
+def _pattern_shapes(spec: ModelSpec) -> dict:
+    """``params["layers"]`` of a block whose layers are ONE mixer each
+    (``spec.layer_pattern``): three stacks, each over the layers of its own
+    kind in the model's order, and the norm ahead of every layer's mixer,
+    stacked over all of them."""
+    attn = {k: v for k, v in _attention_shapes(spec, spec.pool_layers).items()
+            if not k.endswith("_norm")}
+    return {"mixer_norm": (spec.num_layers, spec.hidden_size),
+            **_recurrent_shapes(spec, spec.ssm_layers),
+            **_expert_shapes(spec, spec.expert_layers), **attn}
 
 
 def param_shapes(spec: ModelSpec) -> dict:
@@ -183,7 +223,9 @@ def param_shapes(spec: ModelSpec) -> dict:
     layers = _attention_shapes(spec, L)
     if spec.parallel_block:             # one norm feeds both branches
         del layers["post_attn_norm"]
-    if spec.num_experts:
+    if spec.layer_pattern:
+        layers = _pattern_shapes(spec)
+    elif spec.num_experts:
         layers.update(_expert_shapes(spec, L))
     else:
         layers["w_gate"] = (L, h, i)
@@ -266,6 +308,10 @@ def param_specs(spec: ModelSpec) -> dict:
         layers.update({k: P("pp", *([None] * (len(v) - 1)))
                        for k, v in param_shapes(spec)["layers"].items()
                        if k.startswith(MTP_PREFIX)})
+    if spec.layer_pattern:
+        # Served on one device (config.block_refusals): nothing is split.
+        layers = {k: P(*([None] * len(v)))
+                  for k, v in _pattern_shapes(spec).items()}
     specs = {
         "embed": P(None, "tp"),
         "final_norm": P(None),
@@ -398,11 +444,14 @@ def moe_route(router: jax.Array, spec: ModelSpec,
 
 
 def _gate_act(gate: jax.Array, spec: ModelSpec) -> jax.Array:
-    """SwiGLU's SiLU or ReGLU's ReLU on the gate projection, in float32.
+    """SwiGLU's SiLU or ReGLU's ReLU on the gate projection, in float32;
+    "relu2": the squared ReLU of a two-matrix expert's one projection.
     (``jnp.maximum``, not ``jax.nn.relu``: behind the latter XLA's CPU
     backend folds the converts away and is left with a bf16 x bf16 -> f32
     batched dot it cannot execute; the tests run there.)"""
     g = gate.astype(jnp.float32)
+    if spec.ffn_act == "relu2":
+        return jnp.square(jnp.maximum(g, 0.0)).astype(jnp.bfloat16)
     act = jnp.maximum(g, 0.0) if spec.ffn_act == "relu" else jax.nn.silu(g)
     return act.astype(jnp.bfloat16)
 
@@ -540,15 +589,16 @@ def ffn_block(h2: jax.Array, lp: dict, spec: ModelSpec, ll: dict | None = None,
                                    interpret=experts_local == "interpret")
         else:
             w_te = jnp.einsum("tk,tke->te", gates, one_hot)  # [T, E] sparse-ish
-            down = _every_expert(x, lp["moe_w_gate"], lp["moe_w_up"],
+            down = _every_expert(x, lp.get("moe_w_gate"), lp["moe_w_up"],
                                  lp["moe_w_down"], spec)
             out = jnp.einsum("eth,te->th", down, w_te)
     if spec.num_shared_experts:
         with scope("moe.shared"):
             # Every row through every shared expert; their mean joins the
             # routed sum ("shared_expert_combination_strategy": "average").
-            shared = _every_expert(x, lp["shared_w_gate"], lp["shared_w_up"],
-                                   lp["shared_w_down"], spec)
+            shared = _every_expert(x, lp.get("shared_w_gate"),
+                                   lp["shared_w_up"], lp["shared_w_down"],
+                                   spec)
             out = out + jnp.mean(shared, axis=0)
     out = out.astype(jnp.bfloat16).reshape(orig)
     return out if live is None else (out, stats)
@@ -557,10 +607,13 @@ def ffn_block(h2: jax.Array, lp: dict, spec: ModelSpec, ll: dict | None = None,
 def _every_expert(x: jax.Array, w_gate, w_up, w_down, spec: ModelSpec
                   ) -> jax.Array:
     """Every expert of a stack [E, ...] on every row: x [T, H] bf16 ->
-    [E, T, H] float32, SwiGLU / ReGLU by ``spec.ffn_act``."""
-    gate = mm(x, w_gate, "th,ehi->eti")
+    [E, T, H] float32, SwiGLU / ReGLU by ``spec.ffn_act``; without a gate
+    matrix (``w_gate`` None, "relu2") down(relu(up x) ** 2)."""
     up = mm(x, w_up, "th,ehi->eti")
-    ff = _gate_act(gate, spec) * up
+    if w_gate is None:
+        ff = _gate_act(up, spec)
+    else:
+        ff = _gate_act(mm(x, w_gate, "th,ehi->eti"), spec) * up
     if isinstance(w_down, QTensor):
         return (jnp.einsum("eti,eih->eth", ff, w_down.q.astype(jnp.bfloat16),
                            preferred_element_type=jnp.float32) * w_down.s)
@@ -798,6 +851,78 @@ def dense_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bngqk,bknd->bqngd", probs, v)
     return out.reshape(b, s, nh, d)
+
+
+#: Float32 attention scores of one with-history prefill call (rows x heads x
+#: chunk x (history + chunk)) up to which every KV head's are computed at
+#: once; above it a KV head at a time. At 128 query heads a chunk of 1,024
+#: tokens over 4,096 of history is 2.7 GB of scores, more than a v5e has
+#: left beside 9.3 GB of weights and the pool (compiled for a described
+#: v5e, PR 32); 28 heads at the same shape are 0.6 GB.
+HISTORY_SCORE_BYTES = 1 << 30
+
+
+def history_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                      k_hist: jax.Array, v_hist: jax.Array,
+                      positions: jax.Array, valid: jax.Array,
+                      hist_lens: jax.Array, spec: ModelSpec,
+                      reach=None, limit: int = HISTORY_SCORE_BYTES
+                      ) -> jax.Array:
+    """A prefill chunk's attention over itself and the row's earlier pages:
+    q [B,S,Nh,D], k/v [B,S,Nkv,D] the chunk's own, k_hist/v_hist
+    [Nkv,B,L,D] the gathered pages (history token l stands at position l,
+    ``hist_lens`` [B] of them are real), ``reach`` ``window_reach`` of the
+    layer, ``limit`` the score bytes up to which the KV heads go at once.
+    Returns [B,S,Nh*D]."""
+    b, s = positions.shape
+    d, nkv = spec.head_dim, spec.num_kv_heads
+    hist_len = k_hist.shape[2]
+
+    def heads(qg, k, v, k_hist, v_hist):
+        """qg [b,s,n,g,d], k/v [b,s,n,d], k_hist/v_hist [n,b,l,d]
+        for n of the KV heads -> [b,s,n,g,d]."""
+        # In-chunk causal scores (grouped GQA, no repeat).
+        chunk_scores = jnp.einsum("bqngd,bknd->bngqk", qg, k,
+                                  preferred_element_type=jnp.float32)
+        causal = (positions[:, None, None, :, None]
+                  >= positions[:, None, None, None, :])
+        seen = causal & valid[:, None, None, None, :]
+        if reach is not None:
+            seen = seen & (positions[:, None, None, :, None] - reach
+                           < positions[:, None, None, None, :])
+        chunk_scores = jnp.where(seen, chunk_scores, -1e30)
+        hist_scores = jnp.einsum("bqngd,nbld->bngql", qg, k_hist,
+                                 preferred_element_type=jnp.float32)
+        hist_pos = jnp.arange(hist_len)[None, :]
+        hist_valid = (hist_pos
+                      < hist_lens[:, None])[:, None, None, None, :]
+        if reach is not None:
+            # History token l stands at position l.
+            hist_valid = hist_valid & (
+                positions[:, None, None, :, None] - reach
+                < hist_pos[:, None, None, None, :])
+        hist_scores = jnp.where(hist_valid, hist_scores, -1e30)
+        scores = jnp.concatenate([hist_scores, chunk_scores], axis=-1)
+        scores = scores / jnp.sqrt(jnp.float32(d))
+        probs = jax.nn.softmax(scores, axis=-1).astype(jnp.bfloat16)
+        p_hist, p_chunk = jnp.split(probs, [hist_len], axis=-1)
+        return (jnp.einsum("bngql,nbld->bqngd", p_hist, v_hist)
+                + jnp.einsum("bngqk,bknd->bqngd", p_chunk, v))
+
+    qg = q.reshape(b, s, nkv, spec.q_per_kv, d)
+    score_bytes = 4 * b * spec.num_heads * s * (hist_len + s)
+    if score_bytes <= limit or nkv == 1:
+        attn = heads(qg, k, v, k_hist, v_hist)
+    else:
+        # A KV head at a time: every head's scores at once would not fit
+        # beside the weights and the pool.
+        one = jax.lax.map(
+            lambda a: heads(*(x[:, :, None] for x in a[:3]),
+                            *(x[None] for x in a[3:])),
+            (jnp.moveaxis(qg, 2, 0), jnp.moveaxis(k, 2, 0),
+             jnp.moveaxis(v, 2, 0), k_hist, v_hist))
+        attn = jnp.moveaxis(one[:, :, :, 0], 0, 2)
+    return attn.reshape(b, s, -1)
 
 
 def paged_decode_attention_xla(q: jax.Array, k_cache: jax.Array,

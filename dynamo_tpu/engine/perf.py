@@ -90,9 +90,13 @@ SCOPES = ("embed", "attn.qkv", "attn.kv_gather", "attn.core", "attn.out",
 #: in context, the choice); a drafting window's prediction module (``mtp``:
 #: its projection of [embedding ; hidden], its block with that block's
 #: attention, its norm and its read of the head; the expert layer inside
-#: keeps its sub-scopes: ``mtp+moe.experts``). The other blocks' programs
-#: have none, so their names, and SCOPES_VERSION, stand.
-BLOCK_SCOPES = ("attn.index", "mtp")
+#: keeps its sub-scopes: ``mtp+moe.experts``); a recurrent layer (``ssm``:
+#: the Nemotron-H block's Mamba-2 mixer, engine/hybrid.py: its norm, its
+#: in-projection, the convolution, the recurrence over the state, the gated
+#: norm and the out-projection, and in prefill the rows' state gathered from
+#: and written back to their slots). The other blocks' programs
+#: have none, so their names stand.
+BLOCK_SCOPES = ("attn.index", "mtp", "ssm")
 #: Regions INSIDE a scope, drawn only in programs of a routed block (the
 #: expert layer's router and experts and, where the block has them, its
 #: shared experts, inside ``mlp``). An instruction in one
@@ -104,8 +108,8 @@ SUBSCOPES = ("moe.router", "moe.experts", "moe.shared")
 #: cache key leaves debug info out (jax/_src/cache_key.py strips it), so an
 #: executable cached by a tree with other scopes would be loaded with ITS
 #: names; the version is a sub-directory of the cache directory, and a
-#: change of vocabulary costs one cold start instead.
-SCOPES_VERSION = 1
+#: change of vocabulary costs one cold start instead. 2: ``ssm`` (PR 41).
+SCOPES_VERSION = 2
 
 
 def scope(name: str):
@@ -818,6 +822,17 @@ class PerfMetricsUpdater:
             "attn_context_total", "Latent block: keys the live rows had in "
             "context, summed over rows, layers and decode steps (every one "
             "is scored by the indexer)")
+        self.c_ssm_row_steps = registry.counter(
+            "ssm_row_steps_total", "Block with recurrent layers: (decode "
+            "step, live row) pairs, summed on the device: the rows whose "
+            "recurrent state a step had to read and write (over steps x "
+            "max_num_seqs: the share of the state arrays in use)")
+        self.g_ssm_state = registry.gauge(
+            "perf_ssm_state_info", "1 under the labels of what a row (a "
+            "slot) of this worker keeps beside its pages over all recurrent "
+            "layers: bytes_per_row (the state and the convolution's last "
+            "inputs) and dtype (the state's); no sample for a block whose "
+            "whole per-request state is pages", ["bytes_per_row", "dtype"])
         self.g_kv_entry = registry.gauge(
             "perf_kv_entry_info", "1 under the labels of what a token "
             "holds in this worker's KV pool over all layers: kind "
@@ -886,6 +901,13 @@ class PerfMetricsUpdater:
             self.g_hbm_in_use.set(hbm.get("bytes_in_use", 0))
             self.g_hbm_peak.set(hbm.get("peak_bytes_in_use", 0))
             self.g_hbm_limit.set(hbm.get("bytes_limit", 0))
+        recurrent = getattr(getattr(runner, "spec", None), "recurrent", False)
+        if recurrent:
+            self.g_ssm_state.set(
+                1, bytes_per_row=str(runner.spec.ssm_state_bytes_per_row),
+                dtype=str(runner.ssm_state.dtype))
+            self._delta(self.c_ssm_row_steps, ("ssm_rs",),
+                        float(getattr(engine, "ssm_row_steps", 0.0)))
         attn = getattr(engine, "attn_totals", None)
         if attn is not None and attn[1]:
             self._delta(self.c_attn_selected, ("attn_s",), float(attn[0]))

@@ -36,13 +36,15 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.engine import perf
-from dynamo_tpu.engine.config import (EngineConfig, block_refusals,
+from dynamo_tpu.engine.config import (EngineConfig, UnsupportedBlockError,
+                                      block_refusals,
                                       pool_access, window_page_bucket)
 from dynamo_tpu.engine.kv_quant import (KV_SCALE_BYTES, QuantKV, pack_parcel,
                                         parcel_to_bf16, quantize_np,
                                         scatter_tokens, unpack_parcel,
                                         window_token_slots)
 from dynamo_tpu.engine.model import (
+    HISTORY_SCORE_BYTES,  # read here by _prefill_with_history at call time
     dense_causal_attention,
     expert_product,
     init_params,
@@ -128,6 +130,19 @@ class PrefillSeq:
     # kept (0, the scratch page: no such page is allocated).
     next_token: int = -1
     next_page: int = 0
+    # A block with recurrent layers (ModelSpec.recurrent): the slot whose
+    # state the chunk continues (from zeros at position 0) and leaves its
+    # own in; -1: none, the state goes nowhere (a warm-up's inert row).
+    # prefill_batch's ``slots`` say the same where they are given.
+    slot: int = -1
+
+
+#: What /debug/perf and the programs' labels say of prefix reuse for a block
+#: with recurrent layers (engine.TPUEngine._plan_prefill takes no cached page).
+PREFIX_REUSE_OFF = "off (recurrent state has no snapshot)"
+#: What the recurrent state S is kept in: the configuration's choice (the
+#: model card asks engines for a float32 state cache), not an option.
+SSM_STATE_DTYPE = "float32"
 
 
 def _mh_put(value, sharding):
@@ -237,6 +252,11 @@ class ModelRunner:
         # which GSPMD can partition.
         self.experts_local = self.mesh.size == 1 and (
             "interpret" if self.device.platform == "cpu" else True)
+        if spec.ffn_act == "relu2":
+            # A two-matrix expert takes the masked product at every row
+            # count: the kernel multiplies gated pairs of matrices, and an
+            # expert width of 1,856 is no whole number of its lane tiles.
+            self.experts_local = False
         # (row, choice) pairs the prefill calls sent through that kernel, a
         # layer: counted on the host from the rows of each call.
         self.moe_grouped_pairs = 0
@@ -307,6 +327,20 @@ class ModelRunner:
                                      self.kv_sharding)
             self.v_cache = _mh_zeros((*kv_shape[:-1], v_width), jnp.bfloat16,
                                      self.kv_sharding)
+        # Recurrent state beside the pages (a block with ModelSpec.recurrent
+        # layers): what a ROW holds, by slot, a layer: the state S in
+        # float32 and the convolution's last inputs. Donated to and
+        # returned by every program that writes them (prefill at a row's
+        # last real token, the window once a step), never copied whole.
+        self.ssm_state = self.conv_state = None
+        if spec.recurrent:
+            s_shape, c_shape = spec.ssm_state_shapes
+            rows = (spec.ssm_layers, config.max_num_seqs)
+            whole = NamedSharding(self.mesh, P())
+            self.ssm_state = _mh_zeros((*rows, *s_shape),
+                                       jnp.dtype(SSM_STATE_DTYPE), whole)
+            self.conv_state = _mh_zeros((*rows, *c_shape), jnp.bfloat16,
+                                        whole)
         # Byte ledgers for the perf plane's HBM breakdown (/debug/perf):
         # this process's per-device share of params and the KV pool —
         # workspace is whatever memory_stats says is in use beyond them.
@@ -422,6 +456,13 @@ class ModelRunner:
         d = sum(self.spec.kv_entry[1]) // 2
         return (d + KV_SCALE_BYTES) if self.quant_kv == "int8" else 2 * d
 
+    @property
+    def ssm_state_bytes(self) -> int:
+        """Bytes of the recurrent state arrays (every slot, every recurrent
+        layer); 0 for a block whose whole per-request state is pages."""
+        return (self.config.max_num_seqs
+                * self.spec.ssm_state_bytes_per_row)
+
     def _sized_pages(self, device) -> None:
         cfg = self.config
         if cfg.num_pages is not None:
@@ -438,7 +479,12 @@ class ModelRunner:
         per_weight = 1 if self.spec.quant == "int8" else 2
         param_bytes = (self.spec.num_params() * per_weight
                        // max(1, cfg.tp * cfg.pp))
-        budget = max(64 << 20, int((free - param_bytes) * cfg.hbm_kv_budget_frac))
+        # The recurrent state arrays come out of what is free ahead of the
+        # pool, as the parameters do (neither is on the device yet when a
+        # pool is sized on the still-empty chip).
+        state_bytes = cfg.max_num_seqs * self.spec.ssm_state_bytes_per_row
+        budget = max(64 << 20, int((free - param_bytes - state_bytes)
+                                   * cfg.hbm_kv_budget_frac))
         if device.platform != "cpu" and self.spec.head_dim < 128:
             # Under 128 lanes of head_dim the pool rests in a compact device
             # layout, and every step program copies both caches into a
@@ -566,6 +612,16 @@ class ModelRunner:
             return {}
         return {"expert_product": expert_product(rows, self.experts_local)}
 
+    def _recurrent_labels(self) -> dict:
+        """The labels of a program that carries recurrent state: what the
+        state is kept in, and that a prompt's pages are never reused (a
+        page's border has no state to continue from); none for a block
+        whose whole per-request state is pages."""
+        if not self.spec.recurrent:
+            return {}
+        return {"ssm_state": SSM_STATE_DTYPE,
+                "prefix_reuse": PREFIX_REUSE_OFF}
+
     def _get_prefill(self, bucket: int, batch: int, with_history: bool,
                      penalized: bool = False, seeded: bool = False,
                      with_embeds: bool = False):
@@ -604,9 +660,14 @@ class ModelRunner:
         # A model whose prediction module drafts: the chunk's commit waits
         # for the module's entries, which wait for the sampled token.
         mtp = self.config.spec_decode == "mtp" and bool(spec.mtp_layers)
+        # A block with recurrent layers: the rows' slots ride one column
+        # behind the tables, the two state arrays are donated and returned
+        # behind the rng.
+        recurrent = spec.recurrent
 
         def step(params, k_cache, v_cache, packed, rng, counts=None,
-                 emb=None, emb_mask=None, lora=None, page_ends=None):
+                 emb=None, emb_mask=None, lora=None, page_ends=None,
+                 state=None):
             start = packed[:, 0]
             n = packed[:, 1]
             hist_lens = packed[:, 2]
@@ -621,6 +682,8 @@ class ModelRunner:
             if mtp:     # two columns behind the tables (PrefillSeq)
                 hist_table, next_tok, next_page = (
                     hist_table[:, :-2], packed[:, -2], packed[:, -1])
+            if recurrent:
+                hist_table, slots = hist_table[:, :-1], packed[:, -1]
             # positions: start..start+n-1, pads clamped to the last valid.
             positions = start[:, None] + jnp.minimum(
                 jnp.arange(bucket)[None, :],
@@ -633,7 +696,13 @@ class ModelRunner:
                          and not with_embeds and lora is None
                          and batch % cfg_pp == 0
                          and spec.num_layers % cfg_pp == 0)
-            if with_history:
+            if recurrent:
+                from dynamo_tpu.engine import hybrid
+                logits, k_cache, v_cache, state = hybrid.prefill(
+                    params, spec, k_cache, v_cache, state, tokens, positions,
+                    page_table, seq_lens, slots,
+                    hist=(hist_table, hist_lens) if with_history else None)
+            elif with_history:
                 logits, k_cache, v_cache, *deferred = _prefill_with_history(
                     params, spec, k_cache, v_cache, tokens, positions,
                     page_table, seq_lens, hist_table, hist_lens,
@@ -697,11 +766,18 @@ class ModelRunner:
                     (hist_table, hist_lens) if with_history else None)
                 return (sampled, lp, top_v, top_i, logits, k_cache, v_cache,
                         rng, draft, page_ends)
+            if recurrent:
+                return (sampled, lp, top_v, top_i, logits, k_cache, v_cache,
+                        rng, *state)
             return sampled, lp, top_v, top_i, logits, k_cache, v_cache, rng
 
         fn = perf.instrumented_jit("prefill", step, key=key,
                                    donate_argnums=(1, 2),
-                                   labels=self._expert_product(bucket * batch))
+                                   **({"donate_argnames": ("state",)}
+                                      if recurrent else {}),
+                                   labels={
+                                       **self._expert_product(bucket * batch),
+                                       **self._recurrent_labels()})
         self._prefill_cache[key] = fn
         return fn
 
@@ -961,6 +1037,11 @@ class ModelRunner:
         if self._decode_fn is not None:
             return self._decode_fn
         spec = self.spec
+        if spec.recurrent:
+            raise UnsupportedBlockError(
+                "the single decode step (runner.decode)", "it carries no "
+                "recurrent state; a block with recurrent layers decodes "
+                "through the window program")
 
         def step(params, k_cache, v_cache, tokens, positions, page_table,
                  seq_lens, temperature, top_k, top_p, rng):
@@ -1000,7 +1081,8 @@ class ModelRunner:
                   "draft": "mtp" if drafting else "none",
                   # A step's rows: every slot, and each verified position.
                   **self._expert_product(self.config.max_num_seqs * (
-                      self.config.spec_k + 1 if drafting else 1))}
+                      self.config.spec_k + 1 if drafting else 1)),
+                  **self._recurrent_labels()}
         if drafting:
             # The same program under the same name and key: each of its
             # ``window`` steps is a draft and a verify (_get_mtp_window).
@@ -1011,8 +1093,12 @@ class ModelRunner:
             self._window_cache[key] = fn
             return fn
 
+        # A block with recurrent layers: its two state arrays ride the
+        # steps' carry behind the counts and are returned behind the rest.
+        recurrent = spec.recurrent
+
         def run_window(params, k_cache, v_cache, tokens_dev, packed, rng,
-                       counts=None, lora=None):
+                       counts=None, lora=None, state=()):
             adapter_ids = packed[:, PK_ADAPTER]
             mask = packed[:, PK_OVERRIDE] > 0
             tokens0 = jnp.where(mask, packed[:, PK_TOKEN], tokens_dev)
@@ -1033,7 +1119,8 @@ class ModelRunner:
                 base_keys = jax.vmap(jax.random.key)(packed[:, PK_SEED])
             page_table = packed[:, PK_PREFIX:]
             B = tokens0.shape[0]
-            L = spec.num_layers
+            # The window's buffers hold the layers that leave K and V.
+            L = spec.pool_layers if recurrent else spec.num_layers
             nkv, (dk, dv) = spec.kv_entry
             # Cache-resident history length is FIXED across the window: the
             # window's own tokens live in a small in-window buffer and are
@@ -1056,18 +1143,25 @@ class ModelRunner:
             routed = bool(spec.num_experts)
 
             def step(carry, m):
-                tokens, positions, kbuf, vbuf, rng, cnts = carry
+                tokens, positions, kbuf, vbuf, rng, cnts, *state = carry
                 # A slot advances only while live AND within its allocated
                 # pages; at capacity it freezes in-graph (the host emits
                 # LENGTH when it sees the cap).
                 live = (seq_lens0 > 0) & (positions < cap)
-                logits, k_new, v_new, *stats = decode_window_step(
-                    params, spec, k_cache, v_cache, kbuf, vbuf, m, tokens,
-                    positions, page_table, hist_lens,
-                    attention_impl=self._window_attention_impl,
-                    lora=lora, adapter_ids=adapter_ids,
-                    live=live if routed else None,
-                    experts_local=self.experts_local)
+                if recurrent:
+                    from dynamo_tpu.engine import hybrid
+                    logits, k_new, v_new, state, *stats = hybrid.window_step(
+                        params, spec, k_cache, v_cache, kbuf, vbuf, m, tokens,
+                        page_table, hist_lens, tuple(state), live,
+                        attention_impl=self._window_attention_impl)
+                else:
+                    logits, k_new, v_new, *stats = decode_window_step(
+                        params, spec, k_cache, v_cache, kbuf, vbuf, m, tokens,
+                        positions, page_table, hist_lens,
+                        attention_impl=self._window_attention_impl,
+                        lora=lora, adapter_ids=adapter_ids,
+                        live=live if routed else None,
+                        experts_local=self.experts_local)
                 # Append this step's K/V ([L,B,Nkv,D] -> window col m).
                 with perf.scope("kv.commit"):
                     kbuf = jax.lax.dynamic_update_slice(
@@ -1117,12 +1211,13 @@ class ModelRunner:
                         None)
                     tokens = jnp.where(live, sampled, tokens)
                     positions = positions + live.astype(jnp.int32)
-                return (tokens, positions, kbuf, vbuf, rng, cnts), (
+                return (tokens, positions, kbuf, vbuf, rng, cnts, *state), (
                     sampled, lp, top_v, top_i, *stats)
 
             carry0 = (tokens0, positions0, kbuf0, vbuf0, rng,
-                      counts if penalized else jnp.zeros((), jnp.uint8))
-            (tokens, _, kbuf, vbuf, rng, counts_out), \
+                      counts if penalized else jnp.zeros((), jnp.uint8),
+                      *state)
+            (tokens, _, kbuf, vbuf, rng, counts_out, *state), \
                 (toks, lps, top_vs, top_is, *stats) = \
                 jax.lax.scan(step, carry0, jnp.arange(window))
             # [M, L, n] -> [n] under the name of what was counted, in the
@@ -1130,8 +1225,10 @@ class ModelRunner:
             # (2 sums), a routed block's load (model.moe_load_stats: 3
             # sums, 5 for a told share). A block that counts nothing adds
             # nothing to the program's outputs.
+            # A block with recurrent layers adds the live rows of every
+            # step ("ssm": 1 sum, the rows whose state a step had to touch).
             names = (["attn"] if spec.latent else []) + (
-                ["moe"] if routed else [])
+                ["moe"] if routed else []) + (["ssm"] if recurrent else [])
             stats = {name: jnp.sum(a, axis=(0, 1))
                      for name, a in zip(names, stats, strict=True)}
 
@@ -1163,14 +1260,15 @@ class ModelRunner:
                         v_cache, vbuf.transpose(0, 1, 3, 2, 4), dest, off)
             if penalized:
                 return (toks, lps, top_vs, top_is, tokens, k_cache,
-                        v_cache, rng, counts_out, stats)
+                        v_cache, rng, counts_out, stats, *state)
             return (toks, lps, top_vs, top_is, tokens, k_cache, v_cache,
-                    rng, stats)
+                    rng, stats, *state)
 
         donate = (1, 2, 6) if penalized else (1, 2)
         fn = perf.instrumented_jit(
             "decode_window", run_window, key=key, donate_argnums=donate,
-            labels=labels)
+            labels=labels, **({"donate_argnames": ("state",)}
+                              if recurrent else {}))
         self._window_cache[key] = fn
         return fn
 
@@ -1499,11 +1597,17 @@ class ModelRunner:
             bp *= 2
         maxp = cfg.max_pages_per_seq
         mtp = cfg.spec_decode == "mtp" and bool(self.spec.mtp_layers)
+        recurrent = self.spec.recurrent
         width = (_PF_HDR + bucket + bucket_pages
-                 + (maxp if with_history else 0) + (2 if mtp else 0))
+                 + (maxp if with_history else 0) + (2 if mtp else 0)
+                 + int(recurrent))
         packed = np.zeros((bp, width), np.int32)
+        if recurrent:
+            packed[:, -1] = -1      # a padding row's state goes nowhere
         for i, s in enumerate(seqs):
             n = len(s.tokens)
+            if recurrent:
+                packed[i, -1] = s.slot if slots is None else slots[i]
             packed[i, 0] = s.start_pos
             packed[i, 1] = n
             temp, top_k, top_p = s.sampling
@@ -1553,12 +1657,16 @@ class ModelRunner:
             kw["lora"] = self.lora
         if mtp:
             kw["page_ends"] = self.mtp_hidden
+        if recurrent:
+            kw["state"] = (self.ssm_state, self.conv_state)
         fn = self._get_prefill(bucket, bp, with_history, penalized, seeded,
                                with_embeds)
         if self._expert_product(bucket * bp).get(
                 "expert_product") == "grouped":
             self.moe_grouped_pairs += (bucket * bp
                                        * self.spec.num_experts_per_tok)
+        # rest: a prediction module's (draft, page ends), or the two state
+        # arrays of a block with recurrent layers.
         with self.mesh:
             if penalized:
                 rows = np.asarray(count_rows, np.uint8)
@@ -1567,16 +1675,18 @@ class ModelRunner:
                         [rows, np.zeros((bp - rows.shape[0], rows.shape[1]),
                                         np.uint8)])
                 (sampled, lp, top_v, top_i, logits, self.k_cache,
-                 self.v_cache, self._rng) = fn(
+                 self.v_cache, self._rng, *rest) = fn(
                     self.params, self.k_cache, self.v_cache,
                     jnp.asarray(packed), self._rng, jnp.asarray(rows), **kw)
             else:
                 (sampled, lp, top_v, top_i, logits, self.k_cache,
-                 self.v_cache, self._rng, *draft) = fn(
+                 self.v_cache, self._rng, *rest) = fn(
                     self.params, self.k_cache, self.v_cache,
                     jnp.asarray(packed), self._rng, **kw)
                 if mtp:
-                    self.mtp_hidden = draft[1]
+                    self.mtp_hidden = rest[1]
+            if recurrent:
+                self.ssm_state, self.conv_state = rest
         # Device handle (no transfer unless a caller converts it).
         self.last_prefill_logits = logits
         if slots is not None:
@@ -1593,7 +1703,7 @@ class ModelRunner:
                             [s.start_pos + len(s.tokens) for s in seqs],
                             np.int32)))
                     self.draft_dev = self.draft_dev.at[idx].set(
-                        draft[0][:len(seqs)])
+                        rest[0][:len(seqs)])
                 if count_rows is not None:
                     # Penalty state for these slots: prior generated-token
                     # counts (zeros for fresh requests; rebuilt rows after
@@ -1684,7 +1794,8 @@ class ModelRunner:
         expert's tokens over the mean, and the layer-steps counted ([5] for
         a told share: picks on held experts, all picks); "attn" (a latent
         block) float32 [2], keys the live rows attended and keys they had
-        in context, over steps and layers.
+        in context, over steps and layers; "ssm" (a block with recurrent
+        layers) float32 [1], the live rows summed over the window's steps.
         """
         bucket_pages = packed.shape[1] - PK_PREFIX
         # Specialize on whether any slot carries penalties THIS window —
@@ -1695,6 +1806,8 @@ class ModelRunner:
         seeded = bool(packed[:, PK_SEEDED].any())
         fn = self._get_window(window, bucket_pages, penalized, seeded)
         kw = {} if self.lora is None else {"lora": self.lora}
+        if self.spec.recurrent:
+            kw["state"] = (self.ssm_state, self.conv_state)
         with self.mesh:
             if self.draft_dev is not None:
                 # The drafting window (_get_mtp_window): toks, lps and tops
@@ -1707,15 +1820,18 @@ class ModelRunner:
                     self.mtp_hidden, jnp.asarray(packed), self._rng)
             elif penalized:
                 (toks, lps, top_vs, top_is, self.tokens_dev, self.k_cache,
-                 self.v_cache, self._rng, self.counts_dev, stats) = fn(
+                 self.v_cache, self._rng, self.counts_dev, stats,
+                 *state) = fn(
                     self.params, self.k_cache, self.v_cache,
                     self.tokens_dev, jnp.asarray(packed), self._rng,
                     self.counts_dev, **kw)
             else:
                 (toks, lps, top_vs, top_is, self.tokens_dev, self.k_cache,
-                 self.v_cache, self._rng, stats) = fn(
+                 self.v_cache, self._rng, stats, *state) = fn(
                     self.params, self.k_cache, self.v_cache,
                     self.tokens_dev, jnp.asarray(packed), self._rng, **kw)
+            if self.spec.recurrent:
+                self.ssm_state, self.conv_state = state
         return (toks, lps, top_vs, top_is, stats)
 
     def embed(self, token_lists: list[list[int]],
@@ -1725,6 +1841,11 @@ class ModelRunner:
         from dynamo_tpu.engine.model import embed_forward
         cfg = self.config
         spec = self.spec
+        if spec.recurrent:
+            raise UnsupportedBlockError(
+                "pooled embeddings (runner.embed)", "embed_forward scans "
+                "one stack of attention and feed-forward layers, and this "
+                "block's layers are one mixer each")
         if not token_lists or any(not t for t in token_lists):
             raise ValueError("embeddings need at least one non-empty input")
         n_max = max(len(t) for t in token_lists)
@@ -1827,15 +1948,19 @@ class ModelRunner:
     def memory_breakdown(self) -> dict:
         """Params / KV-pool / workspace attribution of device memory from
         this runner's own ledgers (the breakdown memory_stats can't
-        give): workspace = measured in-use minus the two known pools,
-        None when the backend has no memory_stats."""
+        give): workspace = measured in-use minus the known pools (the
+        parameters, the KV pool, the recurrent state), None when the
+        backend has no memory_stats."""
         hbm = self.hbm_stats()
         in_use = hbm.get("bytes_in_use")
         return {
             "params_bytes": self.param_bytes,
             "kv_pool_bytes": self.kv_pool_bytes,
+            # The recurrent state arrays (0 without recurrent layers).
+            "ssm_state_bytes": self.ssm_state_bytes,
             "workspace_bytes": (max(0, in_use - self.param_bytes
-                                    - self.kv_pool_bytes)
+                                    - self.kv_pool_bytes
+                                    - self.ssm_state_bytes)
                                 if in_use is not None else None),
         }
 
@@ -2017,15 +2142,6 @@ def _replicate_kv_heads(params, spec, rep: int):
     return out
 
 
-#: Float32 attention scores of one with-history prefill call (rows x heads x
-#: chunk x (history + chunk)) up to which every KV head's are computed at
-#: once; above it a KV head at a time. At 128 query heads a chunk of 1,024
-#: tokens over 4,096 of history is 2.7 GB of scores, more than a v5e has
-#: left beside 9.3 GB of weights and the pool (compiled for a described
-#: v5e, PR 32); 28 heads at the same shape are 0.6 GB.
-HISTORY_SCORE_BYTES = 1 << 30
-
-
 def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
                           page_table, seq_lens, hist_table, hist_lens,
                           attention_impl, sp_shard: bool = False,
@@ -2041,8 +2157,9 @@ def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
     import jax.numpy as jnp
     from dynamo_tpu.engine.kv_quant import gather_pages_folded
     from dynamo_tpu.engine.model import (
-        embed_lookup, latent_prefill_attention, layer_kind, lm_logits, norm,
-        scan_layers, spec_rope_tables, transformer_block, window_reach)
+        embed_lookup, history_attention, latent_prefill_attention, layer_kind,
+        lm_logits, norm, scan_layers, spec_rope_tables, transformer_block,
+        window_reach)
 
     b, s = tokens.shape
     d = spec.head_dim
@@ -2082,52 +2199,10 @@ def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
                     q, k, v, positions, valid, spec,
                     hist=(k_hist[0], v_hist[0], hist_lens))
 
-            def heads(qg, k, v, k_hist, v_hist):
-                """qg [b,s,n,g,d], k/v [b,s,n,d], k_hist/v_hist [n,b,l,d]
-                for n of the KV heads -> [b,s,n,g,d]."""
-                # In-chunk causal scores (grouped GQA, no repeat).
-                chunk_scores = jnp.einsum("bqngd,bknd->bngqk", qg, k,
-                                          preferred_element_type=jnp.float32)
-                causal = (positions[:, None, None, :, None]
-                          >= positions[:, None, None, None, :])
-                seen = causal & valid[:, None, None, None, :]
-                if reach is not None:
-                    seen = seen & (positions[:, None, None, :, None] - reach
-                                   < positions[:, None, None, None, :])
-                chunk_scores = jnp.where(seen, chunk_scores, -1e30)
-                hist_scores = jnp.einsum("bqngd,nbld->bngql", qg, k_hist,
-                                         preferred_element_type=jnp.float32)
-                hist_pos = jnp.arange(maxp * page)[None, :]
-                hist_valid = (hist_pos
-                              < hist_lens[:, None])[:, None, None, None, :]
-                if reach is not None:
-                    # History token l stands at position l.
-                    hist_valid = hist_valid & (
-                        positions[:, None, None, :, None] - reach
-                        < hist_pos[:, None, None, None, :])
-                hist_scores = jnp.where(hist_valid, hist_scores, -1e30)
-                scores = jnp.concatenate([hist_scores, chunk_scores], axis=-1)
-                scores = scores / jnp.sqrt(jnp.float32(d))
-                probs = jax.nn.softmax(scores, axis=-1).astype(jnp.bfloat16)
-                p_hist, p_chunk = jnp.split(probs, [maxp * page], axis=-1)
-                return (jnp.einsum("bngql,nbld->bqngd", p_hist, v_hist)
-                        + jnp.einsum("bngqk,bknd->bqngd", p_chunk, v))
-
             with perf.scope("attn.core"):
-                qg = q.reshape(b, s, nkv, spec.q_per_kv, d)
-                score_bytes = 4 * b * spec.num_heads * s * (maxp * page + s)
-                if score_bytes <= HISTORY_SCORE_BYTES or nkv == 1:
-                    attn = heads(qg, k, v, k_hist, v_hist)
-                else:
-                    # A KV head at a time: every head's scores at once
-                    # would not fit beside the weights and the pool.
-                    one = jax.lax.map(
-                        lambda a: heads(*(x[:, :, None] for x in a[:3]),
-                                        *(x[None] for x in a[3:])),
-                        (jnp.moveaxis(qg, 2, 0), jnp.moveaxis(k, 2, 0),
-                         jnp.moveaxis(v, 2, 0), k_hist, v_hist))
-                    attn = jnp.moveaxis(one[:, :, :, 0], 0, 2)
-                return attn.reshape(b, s, -1)
+                return history_attention(q, k, v, k_hist, v_hist, positions,
+                                         valid, hist_lens, spec, reach,
+                                         limit=HISTORY_SCORE_BYTES)
 
         x, k, v, _ = transformer_block(
             x, lp, spec, cos, sin, attend, layer_kind(spec, layer), ll,

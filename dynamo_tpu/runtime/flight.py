@@ -75,7 +75,10 @@ log = get_logger("flight")
 # (those the target's own draws confirmed) and "spec_row_steps" (verify
 # steps of live rows: each emits one token and its accepted drafts, so the
 # tokens emitted are spec_row_steps + spec_accepted), read back with the
-# window's tokens; 0 without drafting. Beside "rows", taken at the same instant (the
+# window's tokens; 0 without drafting. A block with recurrent layers adds
+# "ssm_row_steps" (live rows summed over the window's steps, on the device:
+# the rows whose recurrent state a step had to touch); 0 for every other
+# block. Beside "rows", taken at the same instant (the
 # window's DISPATCH): "prefilling", the slots a request held without a row
 # in this window (in chunked prefill, or stalled for pages, frozen for a
 # preemption, or owed nothing but its first token's readback), so that
@@ -90,7 +93,8 @@ FIELDS = ("t_mono", "dur_s", "active", "waiting", "free_pages",
           "idle_s", "rows", "page_bucket", "missed", "moe_touched",
           "moe_load", "moe_layer_steps", "moe_local_picks", "moe_picks",
           "attn_selected", "attn_context", "prefilling", "admit_stop",
-          "spec_drafted", "spec_accepted", "spec_row_steps")
+          "spec_drafted", "spec_accepted", "spec_row_steps",
+          "ssm_row_steps")
 _INT_FIELDS = ("active", "waiting", "free_pages", "chunk_tokens",
                "chunks_inflight", "preempts", "brownout", "step", "tokens",
                "rows", "page_bucket", "missed", "prefilling", "admit_stop")
@@ -149,7 +153,7 @@ class FlightRecorder:
                attn_selected: float = 0.0, attn_context: float = 0.0,
                prefilling: int = 0, admit_stop: int = 0,
                spec_drafted: int = 0, spec_accepted: int = 0,
-               spec_row_steps: int = 0) -> bool:
+               spec_row_steps: int = 0, ssm_row_steps: float = 0.0) -> bool:
         """One engine-window row. Idle-stable windows (no active slots,
         no waiters, no chunk work — same as the previous call) are
         skipped without touching the ring. Returns False when the row
@@ -202,6 +206,7 @@ class FlightRecorder:
             cols["spec_drafted"][i] = spec_drafted
             cols["spec_accepted"][i] = spec_accepted
             cols["spec_row_steps"][i] = spec_row_steps
+            cols["ssm_row_steps"][i] = ssm_row_steps
             cols["missed"][i] = self._missed[0]
             self._missed[0] = 0
             self._idx = (i + 1) % self.capacity

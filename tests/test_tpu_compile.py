@@ -656,3 +656,94 @@ def test_glm_window_program_commits_in_place_for_v5e(v5e, spec_decode):
     assert text.count("tpu_custom_call") >= (3 if spec_decode else 2)
     assert "output_to_operand_aliasing" in text
     assert pool_sized_ops(text, pool) == []
+
+
+def test_hybrid_window_program_keeps_the_state_where_it_lies_for_v5e(v5e):
+    """The window program of a block with recurrent layers at
+    Nemotron-3-Nano's widths (Mamba-2 mixers of 64 heads of 64 over a state
+    of 128, 8 groups; 32 query heads over 2 KV heads of 128, a page of 128;
+    a pool of the ONE attention layer; two-matrix relu2 experts; pattern
+    MEM*EME, a narrow vocabulary), int8 weights, pool and state donated:
+    the attention layer reads the pool through the kernel and commits in
+    place with ITS index in the pool, and the float32 state (32 slots x 3
+    layers x 2 MB) rides the steps' carry and is rewritten a layer at a
+    time by a ``dynamic-update-slice`` where it lies: nothing else in the
+    optimised program has its shape (no copy: at the cell's depth one is
+    1.5 GB, 3.8 ms a step)."""
+    from types import SimpleNamespace
+
+    from dynamo_tpu.engine.config import EngineConfig, NemotronHSpec
+    from dynamo_tpu.engine.model import param_shapes
+    from dynamo_tpu.engine.quant import QUANT_LAYER_KEYS, QTensor
+    from dynamo_tpu.engine.runner import PK_PREFIX, ModelRunner
+    spec = NemotronHSpec(
+        name="hybrid", vocab_size=1024, hidden_size=2688,
+        intermediate_size=1856, num_layers=7, num_heads=32, num_kv_heads=2,
+        head_dim=128, rms_norm_eps=1e-5, num_experts=4,
+        num_experts_per_tok=6, moe_intermediate_size=1856,
+        num_routed_experts=128, num_shared_experts=1,
+        shared_intermediate_size=3712, routed_scaling_factor=2.5,
+        layer_pattern="MEM*EME", ssm_heads=64, ssm_head_dim=64, ssm_groups=8,
+        ssm_state=128, ssm_conv=4, ssm_chunk=128, quant="int8")
+    assert (spec.pool_layers, spec.ssm_layers, spec.kv_entry) == (
+        1, 3, (2, (128, 128)))
+    # A pool past the chip's 128 MiB of VMEM, as the cell's is: one of 600
+    # pages (39 MB) the compiler prefetches whole into VMEM, a copy-start of
+    # the pool's shape that says nothing of the program at the cell's size.
+    rows, window, pages = 32, 8, 3000
+    runner = object.__new__(ModelRunner)
+    runner.spec = spec
+    runner.config = EngineConfig(model=spec, num_pages=pages,
+                                 max_num_seqs=rows)
+    page = runner.config.resolve_page_size("tpu")
+    assert page == 128
+    runner.config = EngineConfig(model=spec, page_size=page, num_pages=pages,
+                                 max_num_seqs=rows)
+    table = runner.config.max_pages_per_seq // 2
+    runner.device = SimpleNamespace(platform="tpu")
+    runner.mesh = SimpleNamespace(size=1)
+    runner.quant_kv, runner.lora, runner.draft_dev = None, None, None
+    runner.experts_local = False
+    runner._window_cache = {}
+    runner._attention_impl, runner._window_attention_impl = \
+        runner._pick_attention()
+    runner.kv_commit_backend = runner._pick_kv_commit()
+    assert (runner.attention_backend, runner.kv_commit_backend) == (
+        "pallas", "in_place")
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def q(shape):
+        return QTensor(s(shape, jnp.int8),
+                       s((*shape[:-2], 1, shape[-1]), jnp.float32))
+
+    shapes = param_shapes(spec)
+    params = {"layers": {k: q(v) if k in QUANT_LAYER_KEYS
+                         else s(v, jnp.bfloat16)
+                         for k, v in shapes["layers"].items()},
+              "embed": QTensor(s(shapes["embed"], jnp.int8),
+                               s((1, shapes["embed"][1]), jnp.float32)),
+              "final_norm": s(shapes["final_norm"], jnp.bfloat16),
+              "lm_head": q(shapes["lm_head"])}
+    pool = (1, 2, pages, page, 128)
+    s_shape, c_shape = spec.ssm_state_shapes
+    state = (3, rows, *s_shape)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    fn = runner._get_window(window, table)
+    assert fn._labels["prefix_reuse"].startswith("off")
+    assert fn._labels["expert_product"] == "masked"
+    lowered = fn.lower(
+        params, s(pool, jnp.bfloat16), s(pool, jnp.bfloat16),
+        s((rows,), jnp.int32), s((rows, PK_PREFIX + table), jnp.int32),
+        s(key.shape, key.dtype),
+        state=(s(state, jnp.float32), s((3, rows, *c_shape), jnp.bfloat16)))
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") >= 2  # the reader and the commit
+    assert "output_to_operand_aliasing" in text
+    assert pool_sized_ops(text, pool) == []
+    moved = pool_sized_ops(text, state)
+    assert moved and {kind for _, kind in moved} <= {
+        "dynamic-update-slice", "fusion"}, moved
+    # The fusion IS the update in place (its root), not a copy beside it.
+    assert len(moved) <= 2, moved
