@@ -718,7 +718,7 @@ async def phase_kernels(args, jax, rng, keep: dict):
                 ("grouped" in products["prefill"]) == grouped
                 and products["decode_window"] == ["masked"]),
                 f"expert products of {spec_r.name}: {products}")
-            state_on = None
+            state_on = ssm = None
             if spec_r.recurrent:
                 # The recurrent state beside the pool: where the arrays the
                 # programs handed back lie, and that steps were counted.
@@ -728,11 +728,19 @@ async def phase_kernels(args, jax, rng, keep: dict):
                 check(state_on == [jax.devices()[0].platform]
                       and eng.perf_status()["ssm"]["row_steps"] > 0,
                       f"recurrent state of {spec_r.name} on {state_on}")
+                # Who updates it in a decode step follows the reader: the
+                # kernel beside the Pallas reader on the chip, XLA beside
+                # XLA's (the round's comparison is kernel against XLA).
+                ssm = eng.perf_status()["ssm"]["backend"]
+                check(ssm == ("kernel" if on_tpu and resolved == "pallas"
+                              else "xla") == eng.runner.ssm_backend,
+                      f"{resolved} reader of {spec_r.name}: state by {ssm}")
             emit("kernels.run", model=spec_r.name,
                  quant_kv=quant_kv or "bf16", attention_backend=backend,
                  resolved=resolved, kv_commit_backend=commit,
                  index_backend=index, attn_selected_pct=selected,
                  expert_product=products, ssm_state_on=state_on,
+                 ssm_backend=ssm,
                  page_size=eng.runner.page_size,
                  prompt_lengths=lengths, chunk_tokens=chunks,
                  seconds=round(seconds, 2), tpu_custom_call=custom_call)

@@ -1174,7 +1174,11 @@ class TPUEngine(AsyncEngine):
                 "layers": spec.ssm_layers,
                 "state_bytes_per_row": spec.ssm_state_bytes_per_row,
                 "state_dtype": SSM_STATE_DTYPE,
-                # (decode step, live row) pairs so far.
+                # Who updates S in a decode step: "kernel" (the live slots,
+                # in place: engine/recurrence.py) | "xla" (every slot).
+                "backend": self.runner.ssm_backend,
+                # (decode step, live row) pairs so far: the rows the kernel
+                # visited, a layer.
                 "row_steps": int(self.ssm_row_steps),
                 # Static: a page's border has no state to continue from.
                 "prefix_reuse": PREFIX_REUSE_OFF,

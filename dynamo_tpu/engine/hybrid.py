@@ -29,6 +29,17 @@ matrix times dt x, between chunks the state at each border); decode takes
 one token (``ssm_step``). Past a row's last real token dt is 0 (the state
 stands: exp(0) S + 0) and the convolution keeps the last real inputs, so a
 padded batch, a dead slot and a frozen row leave a state exactly as it was.
+
+Who updates the float32 state in a decode step (``runner.ssm_backend``,
+decided by the runner as the pool's reader is, and beside it): where the
+Pallas reader runs on one TPU device the kernel of engine/recurrence.py,
+which is handed the stack over all layers where it lies and visits the
+live slots of one layer (``ssm_step_live``: a dead slot is neither read nor
+written, and the new state is read by C in the pass that writes it);
+everywhere else (the CPU backend, any mesh, a runner asked for the XLA
+reader) XLA through ``ssm_step``, over every slot of the layer's slice.
+``ssm_step`` is the definition the kernel is held to. Prefill is XLA's
+under either, and so is the convolution's state.
 """
 
 from __future__ import annotations
@@ -46,6 +57,7 @@ from dynamo_tpu.engine.model import (Params, _split_heads,
                                      ffn_block, history_attention, lm_logits,
                                      mm, paged_window_attention_xla, rms_norm)
 from dynamo_tpu.engine.perf import scope
+from dynamo_tpu.engine.recurrence import state_step
 
 ATTN_LEAVES = ("wq", "wk", "wv", "wo")
 
@@ -118,29 +130,68 @@ def _gated_out(y: jax.Array, z: jax.Array, lp: dict, spec: ModelSpec):
     return mm(y, lp["ssm_w_out"], "...d,dh->...h")
 
 
-def ssm_step(h: jax.Array, lp: dict, spec: ModelSpec, state: jax.Array,
-             conv: jax.Array, live: jax.Array):
-    """One token a row. h [B, hidden] (normed), state [B, heads, head_dim,
-    state] float32, conv [B, K - 1, channels], live [B]. Returns (out [B,
-    hidden], state, conv); a row that is not live keeps both."""
-    b = h.shape[0]
+def _token(h: jax.Array, lp: dict, spec: ModelSpec, conv: jax.Array,
+           live: jax.Array):
+    """What one token a row hands the recurrence: (z, x [B,G,Hg,P], B and C
+    [B,G,N], dt and dt A [B, heads], all float32 but z; conv with the token
+    behind its last inputs where the row is ``live``)."""
     z, xbc, dt_raw = _project(h, lp, spec)
     full = jnp.concatenate([conv, xbc[:, None].astype(conv.dtype)], axis=1)
     taps = lp["ssm_conv_w"].astype(jnp.float32)                # [K, C]
     xbc = jax.nn.silu(jnp.sum(full.astype(jnp.float32) * taps, axis=1)
                       + lp["ssm_conv_bias"][:, 0].astype(jnp.float32))
     conv = jnp.where(live[:, None, None], full[:, 1:], conv)
-    x, bb, cc = _split_xbc(xbc, spec)          # [B,G,Hg,P], [B,G,N] float32
+    return (z, *_split_xbc(xbc, spec), *_steps(dt_raw, lp, live), conv)
+
+
+def _skip(lp: dict, x: jax.Array):
+    """D x: what a token gives its own output past the state."""
     g, hg = x.shape[1], x.shape[2]
-    dt, da = _steps(dt_raw, lp, live)                          # [B, heads]
+    return lp["ssm_d"][:, 0].astype(jnp.float32).reshape(g, hg, 1) * x
+
+
+def ssm_step(h: jax.Array, lp: dict, spec: ModelSpec, state: jax.Array,
+             conv: jax.Array, live: jax.Array):
+    """One token a row. h [B, hidden] (normed), state [B, heads, head_dim,
+    state] float32, conv [B, K - 1, channels], live [B]. Returns (out [B,
+    hidden], state, conv); a row that is not live keeps both."""
+    b = h.shape[0]
+    z, x, bb, cc, dt, da, conv = _token(h, lp, spec, conv, live)
+    g, hg = x.shape[1], x.shape[2]
     decay = jnp.exp(da).reshape(b, g, hg, 1, 1)
     dx = dt.reshape(b, g, hg, 1) * x                           # [B,G,Hg,P]
     grouped = state.reshape(b, g, hg, *state.shape[2:])
     grouped = decay * grouped + dx[..., None] * bb[:, :, None, None, :]
     y = jnp.sum(grouped * cc[:, :, None, None, :], axis=-1)    # [B,G,Hg,P]
-    y = y + lp["ssm_d"][:, 0].astype(jnp.float32).reshape(g, hg, 1) * x
+    y = y + _skip(lp, x)
     return (_gated_out(y.reshape(b, -1), z, lp, spec),
             grouped.reshape(state.shape), conv)
+
+
+def live_walk(live: jax.Array) -> tuple:
+    """(slots [B] int32, count int32) of ``live`` [B]: the live slots in
+    order, then entries nobody reads. The ONE count of a step's live rows:
+    what the kernel visits and what the window reports."""
+    return (jnp.nonzero(live, size=live.shape[0], fill_value=0)[0].astype(
+        jnp.int32), jnp.sum(live.astype(jnp.int32)))
+
+
+def ssm_step_live(h: jax.Array, lp: dict, spec: ModelSpec, states: jax.Array,
+                  layer: jax.Array, conv: jax.Array, live: jax.Array,
+                  walk: tuple, interpret: bool = False):
+    """``ssm_step`` for the rows that are live, in place: ``states`` is the
+    stack [M, B, heads, head_dim, state] over every recurrent layer, of
+    which the kernel of engine/recurrence.py visits layer ``layer``'s slots
+    ``walk`` (``live_walk``), the live ones. Returns (out, states,
+    conv); a dead row's ``out`` is what a mixer makes of y = D x."""
+    b = h.shape[0]
+    z, x, bb, cc, dt, da, conv = _token(h, lp, spec, conv, live)
+    dx = dt.reshape(*x.shape[:3], 1) * x
+    states, y = state_step(states, layer, *walk, jnp.exp(da),
+                           dx.reshape(b, dt.shape[1], -1), bb, cc,
+                           interpret=interpret)
+    y = y.reshape(x.shape) + _skip(lp, x)
+    return _gated_out(y.reshape(b, -1), z, lp, spec), states, conv
 
 
 def ssm_chunked(h: jax.Array, lp: dict, spec: ModelSpec, state: jax.Array,
@@ -227,11 +278,23 @@ def _index(tree, i):
         a, i, 0, keepdims=False), tree)
 
 
+def in_layer(step):
+    """``step(h, lp, S, conv) -> (out, S, conv)`` over ONE layer's rows as
+    scan_pairs takes it: layer p's rows sliced out of the two stacks and
+    put back where they lay."""
+    def ssm_fn(h, lp, s_all, c_all, p):
+        out, s_new, c_new = step(h, lp, _index(s_all, p), _index(c_all, p))
+        return (out, jax.lax.dynamic_update_index_in_dim(s_all, s_new, p, 0),
+                jax.lax.dynamic_update_index_in_dim(c_all, c_new, p, 0))
+    return ssm_fn
+
+
 def scan_pairs(layers: dict, spec: ModelSpec, x: jax.Array, state: tuple,
                ssm_fn, attn_fn, kv_like: tuple, live=None):
     """x through every layer. ``state`` (S [M, rows, ...], conv [M, rows,
-    ...]) rides the carry and layer p rewrites its own rows in place;
-    ``ssm_fn(h, lp, S, conv) -> (out, S, conv)``; ``attn_fn(h, ap, a) ->
+    ...]) rides the carry and layer p rewrites its own rows in place:
+    ``ssm_fn(h, lp, S, conv, p) -> (out, S, conv)`` over the whole stacks
+    (``in_layer`` for a step over one layer's rows); ``attn_fn(h, ap, a) ->
     (out, k, v)`` for attention layer a of its stack (k and v shaped as
     ``kv_like``); ``live`` as model.ffn_block takes it. Returns (x, state,
     k [A, ...], v [A, ...], the expert layers' load [E, n] or None)."""
@@ -249,10 +312,8 @@ def scan_pairs(layers: dict, spec: ModelSpec, x: jax.Array, state: tuple,
         x, s_all, c_all = carry
         lp_m, lp_e, norm_m, norm_a, norm_e, star, a, p = xs
         with scope("ssm"):
-            out, s_new, c_new = ssm_fn(rms_norm(x, norm_m, eps), lp_m,
-                                       _index(s_all, p), _index(c_all, p))
-            s_all = jax.lax.dynamic_update_index_in_dim(s_all, s_new, p, 0)
-            c_all = jax.lax.dynamic_update_index_in_dim(c_all, c_new, p, 0)
+            out, s_all, c_all = ssm_fn(rms_norm(x, norm_m, eps), lp_m, s_all,
+                                       c_all, p)
             x = x + out
 
         def attend(x):
@@ -320,6 +381,7 @@ def prefill(params: Params, spec: ModelSpec, k_cache: jax.Array,
             fresh.reshape(1, b, *(1,) * (a.ndim - 2)), 0, a[:, rows])
             for a in state)
 
+    @in_layer
     def ssm_fn(h, lp, s_rows, c_rows):
         return ssm_chunked(h, lp, spec, s_rows, c_rows, valid, seq_lens)
 
@@ -374,21 +436,34 @@ def window_step(params: Params, spec: ModelSpec, k_cache: jax.Array,
                 v_cache: jax.Array, k_buf: jax.Array, v_buf: jax.Array,
                 m: jax.Array, tokens: jax.Array, page_table: jax.Array,
                 hist_lens: jax.Array, state: tuple, live: jax.Array,
-                attention_impl=None):
+                attention_impl=None, ssm_kernel=False):
     """model.decode_window_step for this block: one token a slot. The pool
     (its layers are the attention layers') is read-only and this window's
     earlier tokens come from k_buf / v_buf [A, Nkv, B, M, D]; ``state`` is
     the runner's two arrays over all slots, carried through the window's
-    steps: a row that is not ``live`` keeps its own. Returns (logits, k_new
-    and v_new [A, B, Nkv, D], state, the expert layers' load [E, 5], the
-    live rows [1, 1])."""
+    steps: a row that is not ``live`` keeps its own. ``ssm_kernel`` (True,
+    or "interpret"): the kernel of engine/recurrence.py updates the live
+    slots' S where the stack lies; else XLA every slot's (``ssm_step``).
+    Returns (logits, k_new and v_new [A, B, Nkv, D], state, the expert
+    layers' load [E, 5], the live rows [1, 1])."""
     b = tokens.shape[0]
     with scope("embed"):
         x = embed_lookup(params["embed"], tokens)
     attend = attention_impl or paged_window_attention_xla
+    with scope("ssm"):
+        walk = live_walk(live)      # (XLA's path takes the count alone)
 
-    def ssm_fn(h, lp, s_rows, c_rows):
-        return ssm_step(h, lp, spec, s_rows, c_rows, live)
+    if ssm_kernel:
+        def ssm_fn(h, lp, s_all, c_all, p):
+            out, s_all, c_new = ssm_step_live(
+                h, lp, spec, s_all, p, _index(c_all, p), live, walk,
+                interpret=ssm_kernel == "interpret")
+            return out, s_all, jax.lax.dynamic_update_index_in_dim(
+                c_all, c_new, p, 0)
+    else:
+        @in_layer
+        def ssm_fn(h, lp, s_rows, c_rows):
+            return ssm_step(h, lp, spec, s_rows, c_rows, live)
 
     def attn_fn(h, ap, a):
         q, k, v = _qkv(h, ap, spec)
@@ -405,6 +480,5 @@ def window_step(params: Params, spec: ModelSpec, k_cache: jax.Array,
     with scope("lm_head"):
         x = rms_norm(x, params["final_norm"], spec.rms_norm_eps)
         logits = lm_logits(x, params, spec)
-    with scope("ssm"):
-        rows = jnp.sum(live.astype(jnp.float32)).reshape(1, 1)
-    return logits, k_new, v_new, state, load, rows
+    return (logits, k_new, v_new, state, load,
+            walk[1].astype(jnp.float32).reshape(1, 1))

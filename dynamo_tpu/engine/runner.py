@@ -265,6 +265,7 @@ class ModelRunner:
         self._attention_impl, self._window_attention_impl = \
             self._pick_attention()
         self.kv_commit_backend = self._pick_kv_commit()
+        self.ssm_backend = self._pick_ssm()
         self.page_size = config.page_size
         self._sized_pages(self.device)
 
@@ -601,6 +602,23 @@ class ModelRunner:
         return pool_access(self.attention_backend, self.device.platform,
                            self.mesh.size, self.spec.head_dim,
                            self.quant_kv, self.spec.latent)[1]
+
+    def _pick_ssm(self) -> str | None:
+        """Who updates a recurrent layer's float32 state in a decode step
+        ("kernel" | "xla"; None: a block without such layers), decided as
+        the pool's reader is and beside it: the kernel of
+        engine/recurrence.py where the Pallas reader runs on one TPU device
+        (it visits the live slots where the stack lies and reads a state
+        once), XLA through hybrid.ssm_step everywhere else: the CPU
+        backend, which would interpret the kernel; a mesh, which never has
+        the Pallas reader (``_pallas_refusal``) and is refused for such a
+        block (config.block_refusals); and a runner asked for the XLA
+        reader, which is XLA's throughout (chip_smoke.py compares the two
+        on the chip)."""
+        if not self.spec.recurrent:
+            return None
+        return ("kernel" if self.attention_backend == "pallas"
+                and self.device.platform == "tpu" else "xla")
 
     # -- compiled steps -------------------------------------------------------
     def _expert_product(self, rows: int) -> dict:
@@ -1082,7 +1100,10 @@ class ModelRunner:
                   # A step's rows: every slot, and each verified position.
                   **self._expert_product(self.config.max_num_seqs * (
                       self.config.spec_k + 1 if drafting else 1)),
-                  **self._recurrent_labels()}
+                  **self._recurrent_labels(),
+                  # Who updates a recurrent layer's state in a step.
+                  **({"ssm_backend": self.ssm_backend}
+                     if spec.recurrent else {})}
         if drafting:
             # The same program under the same name and key: each of its
             # ``window`` steps is a draft and a verify (_get_mtp_window).
@@ -1153,7 +1174,8 @@ class ModelRunner:
                     logits, k_new, v_new, state, *stats = hybrid.window_step(
                         params, spec, k_cache, v_cache, kbuf, vbuf, m, tokens,
                         page_table, hist_lens, tuple(state), live,
-                        attention_impl=self._window_attention_impl)
+                        attention_impl=self._window_attention_impl,
+                        ssm_kernel=self.ssm_backend == "kernel")
                 else:
                     logits, k_new, v_new, *stats = decode_window_step(
                         params, spec, k_cache, v_cache, kbuf, vbuf, m, tokens,

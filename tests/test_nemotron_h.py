@@ -30,7 +30,7 @@ from conftest import async_test
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark.lib import manifest  # noqa: E402
-from dynamo_tpu.engine import hybrid, model, perf  # noqa: E402
+from dynamo_tpu.engine import hybrid, model, perf, recurrence  # noqa: E402
 from dynamo_tpu.engine.config import (EngineConfig, ModelSpec,  # noqa: E402
                                       NemotronHSpec, UnsupportedBlockError,
                                       block_refusals)
@@ -384,6 +384,113 @@ def test_the_recurrence_is_a_first_order_part_of_the_output():
         assert float(jnp.abs(wrong - full).mean()) > 0.05, switch
 
 
+# -- the kernel of the decode step ------------------------------------------------
+
+#: seq_lens0, positions0 and cap of six slots over a four-step window: which
+#: rows a step finds live is (seq_lens0 > 0) & (positions < cap), as the
+#: window program computes it, and a live row's position moves a step on.
+WALKS = {
+    "every row live": ([9] * 6, [8] * 6, [16] * 6),
+    "no row live": ([0] * 6, [0] * 6, [16] * 6),
+    "live rows scattered, the last slot among them":
+        ([0, 9, 0, 0, 9, 9], [8] * 6, [16] * 6),
+    "one live row": ([0, 0, 9, 0, 0, 0], [8] * 6, [16] * 6),
+    "a row freezes at its cap in the second step":
+        ([9, 0, 15, 9, 0, 0], [8, 0, 15, 8, 0, 0], [32, 16, 16, 32, 16, 16]),
+}
+
+
+def _bits(a) -> np.ndarray:
+    return np.asarray(a).view(np.int32)
+
+
+@pytest.mark.parametrize("walk", list(WALKS))
+def test_the_kernel_updates_the_live_rows_and_touches_no_other(walk):
+    """engine/recurrence.py through the Pallas interpreter against
+    ``hybrid.ssm_step`` at the toy's widths, four steps of one layer of a
+    stack of three: a live row's output and new state are the
+    definition's to float32 rounding; a slot that is dead (NaN in its state
+    from the start: whoever reads or writes it shows), a row from the step
+    it freezes at on, and the other layers keep their state BITWISE."""
+    seq_lens0, positions, cap = (np.asarray(a) for a in WALKS[walk])
+    rows, layer = len(cap), 1
+    lp, h, _, conv = _mixer_inputs(rows, 4, 7)
+    s_shape, _ = SPEC.ssm_state_shapes
+    states = 0.5 * jax.random.normal(jax.random.key(8),
+                                     (3, rows, *s_shape), jnp.float32)
+    ever = seq_lens0 > 0
+    states = jnp.where(ever[None, :, None, None, None], states, jnp.nan)
+    want, conv_want = states[layer], conv
+    step = jax.jit(lambda *a: hybrid.ssm_step_live(
+        *a[:2], SPEC, *a[2:], interpret=True))
+    define = jax.jit(lambda *a: hybrid.ssm_step(*a[:2], SPEC, *a[2:]))
+    token = jax.jit(lambda *a: hybrid._token(*a[:2], SPEC, *a[2:]))
+    for t in range(4):
+        live = jnp.asarray(ever & (positions < cap))
+        on, held = np.asarray(live), states
+        walked = hybrid.live_walk(live)
+        # The kernel alone: y = S_t C_t of the definition's new state.
+        _, x, bb, cc, dt, da, _ = token(h[:, t], lp, conv, live)
+        _, y = recurrence.state_step(
+            states, layer, *walked, jnp.exp(da),
+            (dt.reshape(*x.shape[:3], 1) * x).reshape(rows, dt.shape[1], -1),
+            bb, cc, interpret=True)
+        out_want, want, conv_want = define(h[:, t], lp, want, conv_want,
+                                           live)
+        y_want = jnp.sum(want.reshape(*x.shape, -1)
+                         * cc[:, :, None, None, :], axis=-1).reshape(y.shape)
+        np.testing.assert_allclose(np.asarray(y)[on], np.asarray(y_want)[on],
+                                   rtol=1e-5, atol=1e-5)
+        assert not np.asarray(y)[~on].any()
+        # The mixer around it: out (bfloat16), the state and the inputs.
+        out, states, conv = step(h[:, t], lp, states, jnp.int32(layer),
+                                 conv, live, walked)
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32)[on],
+            np.asarray(out_want, np.float32)[on],
+            atol=0.01 * float(jnp.abs(out_want.astype(jnp.float32)[on]).max()
+                              if on.any() else 1.0))
+        np.testing.assert_allclose(np.asarray(states[layer])[on],
+                                   np.asarray(want)[on], rtol=2e-6, atol=1e-6)
+        np.testing.assert_array_equal(_bits(states[layer])[~on],
+                                      _bits(held[layer])[~on])
+        np.testing.assert_array_equal(_bits(states[::2]), _bits(held[::2]))
+        np.testing.assert_array_equal(np.asarray(conv, np.float32),
+                                      np.asarray(conv_want, np.float32))
+        positions = positions + on
+    if "freezes" in walk:
+        assert not on[2] and np.isfinite(np.asarray(states[layer, 2])).all()
+
+
+def test_the_window_step_walks_the_rows_it_counts():
+    """hybrid.window_step with the kernel (interpreted) against XLA's
+    ``ssm_step``: the live rows' logits, both state arrays and the count
+    of live rows agree, and the count is the one the kernel walked to."""
+    rows, window, pages = 4, 4, 8
+    nkv, d = SPEC.num_kv_heads, SPEC.head_dim
+    pool = jnp.zeros((SPEC.pool_layers, nkv, pages, PAGE, d), jnp.bfloat16)
+    buf = jnp.zeros((SPEC.pool_layers, nkv, rows, window, d), jnp.bfloat16)
+    s_shape, c_shape = SPEC.ssm_state_shapes
+    state = (jax.random.normal(jax.random.key(1),
+                               (SPEC.ssm_layers, rows, *s_shape)),
+             jnp.zeros((SPEC.ssm_layers, rows, *c_shape), jnp.bfloat16))
+    live = jnp.asarray([True, False, True, True])
+    args = (PARAMS, SPEC, pool, pool, buf, buf, jnp.int32(0),
+            jnp.asarray([3, 0, 5, 7]), jnp.zeros((rows, 2), jnp.int32),
+            jnp.zeros(rows, jnp.int32), state, live)
+    want = hybrid.window_step(*args)
+    got = hybrid.window_step(*args, ssm_kernel="interpret")
+    on = np.asarray(live)
+    np.testing.assert_allclose(np.asarray(got[0], np.float32)[on],
+                               np.asarray(want[0], np.float32)[on],
+                               atol=0.02 * float(jnp.abs(want[0]).max()))
+    for a, b in zip(got[3], want[3]):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), rtol=2e-6,
+                                   atol=1e-6)
+    assert float(got[5][0, 0]) == float(want[5][0, 0]) == 3.0
+
+
 # -- the runner ------------------------------------------------------------------
 
 def _window(runner, rows: dict, steps: int):
@@ -492,7 +599,8 @@ async def test_the_engine_serves_what_the_reference_computes():
         status = engine.perf_status()
         assert status["ssm"] == {
             "layers": 3, "state_bytes_per_row": SPEC.ssm_state_bytes_per_row,
-            "state_dtype": "float32", "row_steps": status["ssm"]["row_steps"],
+            "state_dtype": "float32", "backend": "xla",
+            "row_steps": status["ssm"]["row_steps"],
             "prefix_reuse": "off (recurrent state has no snapshot)"}
         assert status["ssm"]["row_steps"] >= 21 + 14 + 18 + 12 - 4
         assert status["memory"]["ssm_state_bytes"] \
@@ -502,6 +610,10 @@ async def test_the_engine_serves_what_the_reference_computes():
         assert "off (recurrent state has no snapshot)" in np.atleast_1d(
             labels["prefix_reuse"])
         assert "float32" in np.atleast_1d(labels["ssm_state"])
+        # The CPU backend: XLA's ssm_step, and a label of the window alone.
+        assert "xla" in np.atleast_1d(labels["ssm_backend"])
+        assert "ssm_backend" not in status["compiles"]["programs"][
+            "prefill"]["labels"]
         assert status["moe"]["experts"] == 4
         # The flight ring's column, the series on /metrics, the scope.
         await asyncio.sleep(0.05)

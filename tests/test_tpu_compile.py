@@ -666,10 +666,14 @@ def test_hybrid_window_program_keeps_the_state_where_it_lies_for_v5e(v5e):
     MEM*EME, a narrow vocabulary), int8 weights, pool and state donated:
     the attention layer reads the pool through the kernel and commits in
     place with ITS index in the pool, and the float32 state (32 slots x 3
-    layers x 2 MB) rides the steps' carry and is rewritten a layer at a
-    time by a ``dynamic-update-slice`` where it lies: nothing else in the
-    optimised program has its shape (no copy: at the cell's depth one is
-    1.5 GB, 3.8 ms a step)."""
+    layers x 2 MB) rides the pairs' carry and the steps' carry into ONE
+    more kernel (``ssm_backend`` ``kernel``: engine/recurrence.py compiles
+    for Mosaic at these widths, three row buffers of 2 MB in VMEM), which
+    is handed the whole stack aliased to its output and rewrites the live
+    slots of one layer where they lie: NOTHING else in the optimised
+    program has the state's shape (no copy: at the cell's depth one is 1.5
+    GB, 3.8 ms a step; no ``dynamic-update-slice`` of a layer's slice; no
+    fusion that reads it a second time)."""
     from types import SimpleNamespace
 
     from dynamo_tpu.engine.config import EngineConfig, NemotronHSpec
@@ -708,8 +712,9 @@ def test_hybrid_window_program_keeps_the_state_where_it_lies_for_v5e(v5e):
     runner._attention_impl, runner._window_attention_impl = \
         runner._pick_attention()
     runner.kv_commit_backend = runner._pick_kv_commit()
-    assert (runner.attention_backend, runner.kv_commit_backend) == (
-        "pallas", "in_place")
+    runner.ssm_backend = runner._pick_ssm()
+    assert (runner.attention_backend, runner.kv_commit_backend,
+            runner.ssm_backend) == ("pallas", "in_place", "kernel")
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
@@ -733,17 +738,21 @@ def test_hybrid_window_program_keeps_the_state_where_it_lies_for_v5e(v5e):
     fn = runner._get_window(window, table)
     assert fn._labels["prefix_reuse"].startswith("off")
     assert fn._labels["expert_product"] == "masked"
+    assert fn._labels["ssm_backend"] == "kernel"
     lowered = fn.lower(
         params, s(pool, jnp.bfloat16), s(pool, jnp.bfloat16),
         s((rows,), jnp.int32), s((rows, PK_PREFIX + table), jnp.int32),
         s(key.shape, key.dtype),
         state=(s(state, jnp.float32), s((3, rows, *c_shape), jnp.bfloat16)))
+    # ONE kernel for the mixers of every pair and step.
+    assert lowered.as_text().count("func.func private @state_step") == 1
     text = lowered.compile().as_text()
-    assert text.count("tpu_custom_call") >= 2  # the reader and the commit
-    assert "output_to_operand_aliasing" in text
+    # The reader, the commit and the recurrence.
+    assert text.count("tpu_custom_call") >= 3
     assert pool_sized_ops(text, pool) == []
-    moved = pool_sized_ops(text, state)
-    assert moved and {kind for _, kind in moved} <= {
-        "dynamic-update-slice", "fusion"}, moved
-    # The fusion IS the update in place (its root), not a copy beside it.
-    assert len(moved) <= 2, moved
+    shape = "f32[" + ",".join(map(str, state)) + "]"
+    aliased = [line for line in text.splitlines()
+               if "ssm_state_step" in line and "custom-call(" in line]
+    assert len(aliased) == 1 and shape in aliased[0] \
+        and "output_to_operand_aliasing" in aliased[0], aliased
+    assert pool_sized_ops(text, state) == []
