@@ -32,7 +32,8 @@ program's init seed), and checks what comes out by the repo's own means:
              its ONE attention layer; Mamba-2 mixers whose float32 state
              lies by slot beside the pool, on the device, and is carried
              through chunked prefill and the windows; two-matrix relu2
-             experts, a share of them, under the masked product alone);
+             experts, a share of them: the grouped product in a 256-row
+             chunk, the masked one in the window);
              ``tpu_custom_call`` must be in the compiled window program
              wherever a kernel runs
   disagg     prefill engine -> KV plane -> decode engine on the one chip;
@@ -712,8 +713,7 @@ async def phase_kernels(args, jax, rng, keep: dict):
                                       ("decode_window",
                                        eng.runner._window_cache))
             } if spec_r.num_experts else None
-            # (two-matrix experts take the masked product at every size).
-            grouped = not args.rehearse_cpu and spec_r.ffn_act != "relu2"
+            grouped = not args.rehearse_cpu
             check(products is None or (
                 ("grouped" in products["prefill"]) == grouped
                 and products["decode_window"] == ["masked"]),

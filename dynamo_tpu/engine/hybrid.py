@@ -12,7 +12,10 @@
   each group, times a weight; ``out = y W_out``. A row keeps S and the last
   K - 1 inputs of the convolution, a layer (``spec.ssm_state_shapes``).
 - **E, an expert layer:** model.ffn_block (sigmoid router with a selection
-  bias, two-matrix relu2 experts, one shared expert of its own width).
+  bias, two-matrix relu2 experts, one shared expert of its own width); a
+  prefill over model.MOE_DENSE_MAX_ROWS rows sends each row to its own
+  experts (``scan_pairs`` hands the kernel of engine/experts.py the expert
+  stacks whole), a window step multiplies every held expert.
 - **\\*, attention:** grouped-query, causal, NO rotary embedding; K and V go
   to the pool, whose layers are these alone.
 
@@ -44,6 +47,7 @@ under either, and so is the convolution's state.
 
 from __future__ import annotations
 
+import math
 import re
 from typing import NamedTuple
 
@@ -54,8 +58,10 @@ from dynamo_tpu.engine.config import ModelSpec
 from dynamo_tpu.engine.kv_quant import gather_pages_folded, scatter_pages
 from dynamo_tpu.engine.model import (Params, _split_heads,
                                      dense_causal_attention, embed_lookup,
-                                     ffn_block, history_attention, lm_logits,
-                                     mm, paged_window_attention_xla, rms_norm)
+                                     expert_product, ffn_block,
+                                     history_attention, layer_of, lm_logits,
+                                     mm, paged_window_attention_xla, rms_norm,
+                                     whole_expert_leaves)
 from dynamo_tpu.engine.perf import scope
 from dynamo_tpu.engine.recurrence import state_step
 
@@ -290,20 +296,28 @@ def in_layer(step):
 
 
 def scan_pairs(layers: dict, spec: ModelSpec, x: jax.Array, state: tuple,
-               ssm_fn, attn_fn, kv_like: tuple, live=None):
+               ssm_fn, attn_fn, kv_like: tuple, live=None,
+               experts_local: bool | str = False):
     """x through every layer. ``state`` (S [M, rows, ...], conv [M, rows,
     ...]) rides the carry and layer p rewrites its own rows in place:
     ``ssm_fn(h, lp, S, conv, p) -> (out, S, conv)`` over the whole stacks
     (``in_layer`` for a step over one layer's rows); ``attn_fn(h, ap, a) ->
     (out, k, v)`` for attention layer a of its stack (k and v shaped as
-    ``kv_like``); ``live`` as model.ffn_block takes it. Returns (x, state,
-    k [A, ...], v [A, ...], the expert layers' load [E, n] or None)."""
+    ``kv_like``); ``live`` and ``experts_local`` as model.ffn_block takes
+    them: where x's rows take the grouped product the expert stacks are not
+    sliced a pair but handed whole with the pair's index, as
+    model.scan_layers hands them (sliced ahead of a custom call a layer's
+    experts are COPIED: 160 MB a matrix of 32 x 2,688 x 1,856). Returns (x,
+    state, k [A, ...], v [A, ...], the expert layers' load [E, n] or None)."""
     pairs = pairs_of(spec)
     eps = spec.rms_norm_eps
     norms = layers["mixer_norm"]
     ssm = {k: v for k, v in layers.items() if k.startswith("ssm_")}
     moe = {k: v for k, v in layers.items()
            if k.startswith(("moe_", "shared_"))}
+    whole = {}
+    if expert_product(math.prod(x.shape[:-1]), experts_local) == "grouped":
+        moe, whole = whole_expert_leaves(moe)
     attn = {k: layers[k] for k in ATTN_LEAVES}
     starred = jnp.asarray([a >= 0 for a in pairs.attn_index])
     at = lambda idx: norms[jnp.asarray([max(i, 0) for i in idx])]  # noqa: E731
@@ -327,7 +341,9 @@ def scan_pairs(layers: dict, spec: ModelSpec, x: jax.Array, state: tuple,
 
         x, k, v = jax.lax.cond(star, attend, skip, x)
         with scope("mlp"):
-            out = ffn_block(rms_norm(x, norm_e, eps), lp_e, spec, live=live)
+            out = ffn_block(rms_norm(x, norm_e, eps),
+                            {**lp_e, **layer_of(whole, p)}, spec, live=live,
+                            experts_local=experts_local)
             out, load = out if isinstance(out, tuple) else (out, None)
             x = x + out
         return (x, s_all, c_all), ((k, v) if load is None else (k, v, load))
@@ -360,7 +376,8 @@ def _qkv(h: jax.Array, ap: dict, spec: ModelSpec):
 def prefill(params: Params, spec: ModelSpec, k_cache: jax.Array,
             v_cache: jax.Array, state: tuple, tokens: jax.Array,
             positions: jax.Array, page_table: jax.Array, seq_lens: jax.Array,
-            slots: jax.Array, hist: tuple | None = None):
+            slots: jax.Array, hist: tuple | None = None,
+            experts_local: bool | str = False):
     """model.prefill_forward for this block: a chunk of each row's prompt,
     whole (``hist`` None) or after earlier chunks (``hist`` (hist_table,
     hist_lens): the attention layers also read the row's earlier pages).
@@ -368,6 +385,8 @@ def prefill(params: Params, spec: ModelSpec, k_cache: jax.Array,
     the slot of each row (-1: none, the row's state goes nowhere): a row
     whose chunk starts at position 0 starts from zeros, any other from its
     slot's state, and each leaves there the state at its last real token.
+    ``experts_local``: see model.ffn_block (a window step's rows never take
+    the grouped product: ``window_step`` has no such argument).
     Returns (last-token logits, k_cache, v_cache, state)."""
     b, s = tokens.shape
     page = k_cache.shape[3]
@@ -404,7 +423,7 @@ def prefill(params: Params, spec: ModelSpec, k_cache: jax.Array,
     nkv, d = spec.num_kv_heads, spec.head_dim
     x, held, k_new, v_new, _ = scan_pairs(
         params["layers"], spec, x, held, ssm_fn, attn_fn,
-        ((b, s, nkv, d),) * 2)
+        ((b, s, nkv, d),) * 2, experts_local=experts_local)
     with scope("kv.commit"):
         n_attn = spec.pool_layers
         blocks = lambda a: (a.reshape(n_attn, b * (s // page), page, nkv, d)  # noqa: E731

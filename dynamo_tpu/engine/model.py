@@ -362,6 +362,13 @@ def param_specs(spec: ModelSpec) -> dict:
 #: 1,024  5.34 | 1.64 [4.97 | 1.49]    2.05 | 0.69 [2.04 | 0.55]    10.8 | 3.25 [10.8 | 2.67]
 #: 2,048  11.2 | 3.23 [9.93 | 3.00]    4.47 | 0.90 [4.45 | 0.68]    21.7 | 4.85 [21.6 | 3.80]
 #: 4,096  22.4 | 5.50 [19.6 | 5.19]    8.36 | 2.24 [8.92 | 2.08]    43.3 | 8.06 [43.2 | 7.52]
+#: A fourth shape, 32 held of 128 two-matrix relu2 experts of 2,688 x 1,856,
+#: 6 a row (PERF.md section 6, PR 43, call 1; the kernel reads the up stack
+#: as the chip holds it, experts.lies_turned): 32 rows 0.44 | 0.56 [0.44 |
+#: 0.66], 64 0.44 | 0.58 [0.44 | 0.67], 128 0.59 | 0.70 [0.59 | 0.70], 256
+#: 0.97 | 0.78 [0.97 | 0.76], 512 1.98 | 0.92 [1.97 | 0.88], 1,024 4.77 |
+#: 1.28 [4.75 | 1.22], 2,048 8.97 | 2.58 [8.94 | 2.40], 4,096 17.8 | 4.50
+#: [17.7 | 4.31]: the same crossing.
 #: The masked product streams the layer (377, 151 and 805 MB in 0.52, 0.22
 #: and 1.11 ms) and its work rides under that read to about 100 rows, then
 #: grows with the rows (10.7 times the chosen work where all 64 are held,
@@ -371,9 +378,9 @@ def param_specs(spec: ModelSpec) -> dict:
 #: a weight tile's copy, conversion and product for 128 rows (12 us a visit
 #: of 2,560 x 768 x 3: 62 visits at 32 rows 0.75 ms, 87 at 512 rows 1.05),
 #: plus XLA's sort and two gathers (0.1 ms at 512 rows). The two cross
-#: between 128 and 256 rows in all three geometries under either routing,
+#: between 128 and 256 rows in all four geometries under either routing,
 #: so the constant is 128, the largest power of two at which the masked
-#: product is still no slower in all three. It stays at 64 or above whatever
+#: product is still no slower in all four. It stays at 64 or above whatever
 #: a later table says: a decode step's rows (32) and a verify step's (64)
 #: keep the masked product, whose every-resident-expert read is what
 #: decode_window_roofline's floor counts (ROADMAP S9).
@@ -387,8 +394,21 @@ class LayerOf(NamedTuple):
     layer: Any   # int32 scalar
 
 
-#: The leaves a grouped expert layer reads through the kernel.
+#: The leaves a grouped expert layer reads through the kernel (a two-matrix
+#: expert has no gate leaf: ``whole_expert_leaves`` takes those that are there).
 EXPERT_LEAVES = ("moe_w_gate", "moe_w_up", "moe_w_down")
+
+
+def whole_expert_leaves(layers: dict) -> tuple[dict, dict]:
+    """``layers`` as (what a layer scan slices a layer, the EXPERT_LEAVES it
+    has: stacks a scan closes over and hands each layer as ``LayerOf``)."""
+    stacks = {k: layers[k] for k in EXPERT_LEAVES if k in layers}
+    return {k: v for k, v in layers.items() if k not in stacks}, stacks
+
+
+def layer_of(stacks: dict, layer) -> dict:
+    """Those stacks as layer ``layer`` (int32 scalar) reads them."""
+    return {k: LayerOf(v, layer) for k, v in stacks.items()}
 
 
 def expert_product(rows: int, experts_local) -> str:
@@ -484,10 +504,11 @@ def _grouped_experts(x: jax.Array, gates: jax.Array, top_i: jax.Array,
     """The chosen experts' outputs summed under their gates, computing only
     what was chosen of the experts HELD: each (token, choice) pair is a row,
     rows sorted by held expert, and experts.pairs_product multiplies each
-    group by its own expert as stored (gate and up in one call, down in a
-    second). top_i counts from the first expert held: a choice outside
-    [0, num_experts) fell on an expert held elsewhere, sorts behind the last
-    group and adds nothing. x [T, H] bf16; returns [T, H] float32."""
+    group by its own expert as stored (gate and up in one call, or a
+    two-matrix expert's up with its activation; down in a second). top_i
+    counts from the first expert held: a choice outside [0, num_experts)
+    fell on an expert held elsewhere, sorts behind the last group and adds
+    nothing. x [T, H] bf16; returns [T, H] float32."""
     from dynamo_tpu.engine.experts import ROW_TILE, pairs_product, visits
     t, k = top_i.shape
     held = (top_i >= 0) & (top_i < spec.num_experts)
@@ -510,8 +531,9 @@ def _grouped_experts(x: jax.Array, gates: jax.Array, top_i: jax.Array,
             return tuple(w.q for w in ws), tuple(w.s for w in ws), layer
         return tuple(ws), None, layer
 
-    ff = pairs_product(rows, *leaves("moe_w_gate", "moe_w_up"), walk,
-                       act=spec.ffn_act, interpret=interpret)
+    ff = pairs_product(
+        rows, *leaves(*(k for k in EXPERT_LEAVES[:2] if k in lp)), walk,
+        act=spec.ffn_act, interpret=interpret)
     down = pairs_product(ff, *leaves("moe_w_down"), walk,
                          interpret=interpret)                # [T*k+, H] f32
     # Back to (token, choice) order by a gather, then the gated sum over k;
@@ -537,7 +559,8 @@ def ffn_block(h2: jax.Array, lp: dict, spec: ModelSpec, ll: dict | None = None,
     inserts the psum, i.e. expert parallelism without a dynamic all-to-all.
     Above it, where the caller says the experts are whole on one device
     (``experts_local``: the runner's mesh has one device; "interpret" says
-    the same of the CPU, which interprets the kernel), every routed kind
+    the same of the CPU, which interprets the kernel), every routed kind,
+    gated experts and two-matrix ones ("relu2": no gate leaf) alike,
     multiplies the (row, choice) pairs by their own experts only
     (``_grouped_experts``: a Pallas kernel, which GSPMD cannot partition);
     a layer that holds a SHARE of a wider router's experts does the same
@@ -1706,16 +1729,15 @@ def scan_layers(layer_fn, x: jax.Array, xs, spec: ModelSpec,
     a slice into its own products alone)."""
     def scan(fn, x, xs):
         layers = xs if isinstance(xs, dict) else xs[0]
-        if not (whole_experts and isinstance(layers, dict)
-                and EXPERT_LEAVES[0] in layers):
+        sliced, stacks = (whole_expert_leaves(layers) if whole_experts
+                          and isinstance(layers, dict) else (layers, {}))
+        if not stacks:
             return jax.lax.scan(fn, x, xs)
-        stacks = {k: layers[k] for k in EXPERT_LEAVES}
-        sliced = {k: v for k, v in layers.items() if k not in stacks}
 
         def body(x, scan_in):
             rest, i = scan_in
             lp = rest if isinstance(xs, dict) else rest[0]
-            lp = {**lp, **{k: LayerOf(v, i) for k, v in stacks.items()}}
+            lp = {**lp, **layer_of(stacks, i)}
             return fn(x, lp if isinstance(xs, dict) else (lp, *rest[1:]))
 
         n = jax.tree.leaves(stacks)[0].shape[0]

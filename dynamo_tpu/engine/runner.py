@@ -247,16 +247,12 @@ class ModelRunner:
         self.device = local[0] if local else devices[0]
         # A routed block's experts are whole on one device: model.ffn_block
         # then multiplies a long batch's rows by their own experts only
-        # (the kernel of engine/experts.py; the CPU interprets it, as it
-        # does the attention kernels). On any mesh: the masked product,
-        # which GSPMD can partition.
+        # (the kernel of engine/experts.py, for gated and two-matrix
+        # experts alike; the CPU interprets it, as it does the attention
+        # kernels). On any mesh: the masked product, which GSPMD can
+        # partition.
         self.experts_local = self.mesh.size == 1 and (
             "interpret" if self.device.platform == "cpu" else True)
-        if spec.ffn_act == "relu2":
-            # A two-matrix expert takes the masked product at every row
-            # count: the kernel multiplies gated pairs of matrices, and an
-            # expert width of 1,856 is no whole number of its lane tiles.
-            self.experts_local = False
         # (row, choice) pairs the prefill calls sent through that kernel, a
         # layer: counted on the host from the rows of each call.
         self.moe_grouped_pairs = 0
@@ -719,7 +715,8 @@ class ModelRunner:
                 logits, k_cache, v_cache, state = hybrid.prefill(
                     params, spec, k_cache, v_cache, state, tokens, positions,
                     page_table, seq_lens, slots,
-                    hist=(hist_table, hist_lens) if with_history else None)
+                    hist=(hist_table, hist_lens) if with_history else None,
+                    experts_local=self.experts_local)
             elif with_history:
                 logits, k_cache, v_cache, *deferred = _prefill_with_history(
                     params, spec, k_cache, v_cache, tokens, positions,

@@ -5,18 +5,23 @@ kernel of dynamo_tpu/engine/experts.py), the table at
 ``model.MOE_DENSE_MAX_ROWS``.
 
     chiprun -- python3 scripts/expert_layer_bench.py [--rows 32,64,...]
-        [--geometry smallthinker,glm,command] [--tiles 128x1048576,...]
+        [--geometry smallthinker,glm,command,nemotron]
+        [--tiles 128x1048576,...] [--out-tiles 640,...]
 
-Three geometries, as the benchmark's routed cells hold them (int8 leaves):
+Four geometries, as the benchmark's routed cells hold them (int8 leaves):
 ``smallthinker`` 64 experts of 2,560 x 768 all held, 6 a row, ReGLU;
 ``glm`` 16 held of 64 of 2,048 x 1,536, 4 a row; ``command`` 16 held of 128
-of 4,096 x 4,096, 8 a row. Two routings: ``random`` (the router of random
+of 4,096 x 4,096, 8 a row; ``nemotron`` 32 held of 128 two-matrix relu2
+experts of 2,688 x 1,856 (no whole number of lane tiles), 6 a row. Two
+routings: ``random`` (the router of random
 weights over random rows, what the benchmark's cells route by) and
 ``balanced`` (row t takes experts t, t + R/k, ... mod R: every expert the
 same load). One JSON line a (geometry, routing, rows) with the milliseconds
 of ``model.ffn_block`` under either product, the largest difference of
 their outputs, and the kernel's two calls alone; ``--tiles`` times the
-grouped product under other (ROW_TILE, TILE_ELEMS); ``--layers`` adds the
+grouped product under other (ROW_TILE, TILE_ELEMS) and ``--out-tiles``
+under other output tiles of a width that is no whole number of lane tiles
+(a ragged last tile in place of the whole width); ``--layers`` adds the
 time a layer of a scan over stacked layers, as a served program runs them. A time is the least of
 three loops' mean, each loop ending in ``block_until_ready``."""
 
@@ -53,6 +58,10 @@ GEOMETRIES = {
         hidden_size=4096, intermediate_size=4096, moe_intermediate_size=4096,
         num_experts=16, num_experts_per_tok=8, num_routed_experts=128,
         **_ATTN),
+    "nemotron": Cohere2MoeSpec(
+        hidden_size=2688, intermediate_size=1856, moe_intermediate_size=1856,
+        num_experts=32, num_experts_per_tok=6, num_routed_experts=128,
+        ffn_act="relu2", **_ATTN),
 }
 ROWS = (32, 64, 128, 256, 512, 1024, 2048, 4096)
 
@@ -67,10 +76,13 @@ def layer(spec, key):
             jax.random.randint(k, shape, -127, 128, jnp.int8),
             jnp.full((e, 1, shape[-1]), shape[-2] ** -0.5 / 64, jnp.float32))
 
-    return {"moe_gate": jax.random.normal(ks[0], (h, spec.router_width),
-                                          jnp.bfloat16) * h ** -0.5,
-            "moe_w_gate": q(ks[1], (e, h, i)), "moe_w_up": q(ks[2], (e, h, i)),
-            "moe_w_down": q(ks[3], (e, i, h))}
+    lp = {"moe_gate": jax.random.normal(ks[0], (h, spec.router_width),
+                                        jnp.bfloat16) * h ** -0.5,
+          "moe_w_gate": q(ks[1], (e, h, i)), "moe_w_up": q(ks[2], (e, h, i)),
+          "moe_w_down": q(ks[3], (e, i, h))}
+    if spec.ffn_act == "relu2":     # two matrices an expert
+        del lp["moe_w_gate"]
+    return lp
 
 
 def balanced(spec):
@@ -142,14 +154,12 @@ def kernel_calls(spec, lp, x, interpret):
     rows = jnp.pad(x[order // k], ((0, -flat.shape[0] % experts.ROW_TILE),
                                    (0, 0)))
     walk = experts.visits(sizes, rows.shape[0])
-    gu = (lp["moe_w_gate"], lp["moe_w_up"])
     # Stacks of one layer, made once: a [None] inside the timed call would
     # copy the experts each time.
-    (gq, gs), (uq, us), (dq, ds) = (
-        (w.q[None], w.s[None]) for w in (*gu, lp["moe_w_down"]))
+    *gu, (dq, ds) = ((lp[k].q[None], lp[k].s[None])
+                     for k in model.EXPERT_LEAVES if k in lp)
     up_ms, ff = timed(lambda: experts.pairs_product(
-        rows, (gq, uq), (gs, us), 0, walk, act=spec.ffn_act,
-        interpret=interpret))
+        rows, *zip(*gu), 0, walk, act=spec.ffn_act, interpret=interpret))
     down_ms, _ = timed(lambda: experts.pairs_product(
         ff, (dq,), (ds,), 0, walk, interpret=interpret))
     return {"gate_up_ms": round(up_ms, 4), "down_ms": round(down_ms, 4),
@@ -164,6 +174,9 @@ def main() -> int:
     ap.add_argument("--routing", default="random,balanced")
     ap.add_argument("--tiles", default="",
                     help="ROW_TILExTILE_ELEMS variants of the grouped product")
+    ap.add_argument("--out-tiles", default="",
+                    help="output tiles of a width that is no whole number "
+                    "of lane tiles, in place of the whole width")
     ap.add_argument("--layers", type=int, default=0,
                     help="also scan this many stacked layers: ms a layer "
                     "masked, grouped over sliced experts, grouped over whole")
@@ -224,6 +237,20 @@ def main() -> int:
                         ms, _ = products(spec, lp, x, local)
                         line[f"grouped_ms@{variant}"] = ms and round(ms, 4)
                         experts.ROW_TILE, experts.TILE_ELEMS = keep
+                        jax.clear_caches()
+                    for tn in map(int, filter(None,
+                                              args.out_tiles.split(","))):
+                        keep = experts.out_tile
+                        experts.out_tile = lambda k, n, tn=tn, keep=keep: (
+                            tn if n % 128 else keep(k, n))
+                        jax.clear_caches()
+                        ms, _ = products(spec, lp, x, local)
+                        line[f"grouped_ms@tn{tn}"] = ms and round(ms, 4)
+                        if ms is not None and routing == "random":
+                            line[f"gate_up_ms@tn{tn}"] = kernel_calls(
+                                spec, lp, x, local == "interpret")[
+                                    "gate_up_ms"]
+                        experts.out_tile = keep
                         jax.clear_caches()
                     say(line)
             model.moe_route = real_route

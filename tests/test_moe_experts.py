@@ -24,11 +24,15 @@ _ATTN = dict(hidden_size=H, intermediate_size=I, moe_intermediate_size=I,
 HELD = {"all held": dict(num_experts=16, num_experts_per_tok=4),
         "a share": dict(num_experts=16, num_experts_per_tok=4,
                         num_routed_experts=64, first_expert=16)}
-ACTS = ("relu", "silu")                    # ReGLU, SwiGLU
+#: ReGLU, SwiGLU, and a two-matrix expert (no gate leaf) whose width is
+#: one lane tile and three quarters.
+ACTS = ("relu", "silu", "relu2")
+WIDTH = {"relu2": 224}
 
 
 def layer(spec: ModelSpec, quant: bool, seed: int = 0) -> dict:
     e, ks = spec.num_experts, jax.random.split(jax.random.key(seed), 4)
+    I = spec.expert_size  # noqa: E741,N806
 
     def w(k, shape):
         a = jax.random.normal(k, shape, jnp.float32) * shape[-2] ** -0.5
@@ -37,9 +41,12 @@ def layer(spec: ModelSpec, quant: bool, seed: int = 0) -> dict:
         s = jnp.max(jnp.abs(a), axis=-2, keepdims=True) / 127
         return QTensor(jnp.round(a / s).astype(jnp.int8), s)
 
-    return {"moe_gate": jnp.zeros((H, spec.router_width), jnp.bfloat16),
-            "moe_w_gate": w(ks[0], (e, H, I)), "moe_w_up": w(ks[1], (e, H, I)),
-            "moe_w_down": w(ks[2], (e, I, H))}
+    lp = {"moe_gate": jnp.zeros((H, spec.router_width), jnp.bfloat16),
+          "moe_w_gate": w(ks[0], (e, H, I)), "moe_w_up": w(ks[1], (e, H, I)),
+          "moe_w_down": w(ks[2], (e, I, H))}
+    if spec.ffn_act == "relu2":
+        del lp["moe_w_gate"]
+    return lp
 
 
 def skewed(spec: ModelSpec, rows: int):
@@ -67,7 +74,8 @@ def skewed(spec: ModelSpec, rows: int):
 @pytest.mark.parametrize("held", list(HELD))
 def test_the_kernel_gives_the_masked_product(held, quant, act, rows,
                                              monkeypatch):
-    spec = Cohere2MoeSpec(**_ATTN, **HELD[held], ffn_act=act)
+    spec = Cohere2MoeSpec(**{**_ATTN, "moe_intermediate_size": WIDTH.get(
+        act, I)}, **HELD[held], ffn_act=act)
     assert spec.holds_share == (held == "a share")
     lp = layer(spec, quant)
     route, top_i = skewed(spec, rows)
@@ -134,6 +142,19 @@ def rehearsal(name: str | None):
                          num_layers=2, num_heads=2, num_kv_heads=1,
                          num_experts=4, num_experts_per_tok=2)
         return spec, decided(model.init_params(spec, jax.random.key(0)))
+    if name == "a share of two-matrix experts":
+        # relu2 experts 4 to 7 of a router over 16, 40 wide (no gate leaf,
+        # no whole number of lane tiles), int8, beside a shared expert.
+        spec = Cohere2MoeSpec(
+            vocab_size=64, hidden_size=128, intermediate_size=40,
+            moe_intermediate_size=40, num_layers=3, num_heads=2,
+            num_kv_heads=1, head_dim=64, num_experts=4,
+            num_experts_per_tok=3, num_routed_experts=16, first_expert=4,
+            num_shared_experts=1, ffn_act="relu2", quant="int8")
+        params = decided(model.init_params(spec, jax.random.key(5)))
+        assert "moe_w_gate" not in params["layers"]
+        return spec, jax.tree.map(jnp.asarray, quantize_params(
+            jax.tree.map(np.asarray, params)))
     import tempfile
     with open(os.path.join(CONFIGS, name + ".json"), encoding="utf-8") as fh:
         config = json.load(fh)
@@ -151,7 +172,8 @@ def rehearsal(name: str | None):
 
 @pytest.mark.parametrize("name", [
     "smallthinker-21b-a3b-int8", "command-a-plus-ep8-int8",
-    "deepseek-v3.2-exp-ep16-int8", "glm-4.7-flash-ep4-int8", None])
+    "deepseek-v3.2-exp-ep16-int8", "glm-4.7-flash-ep4-int8", None,
+    "a share of two-matrix experts"])
 def test_prefill_gives_the_masked_products_logits(name, monkeypatch):
     """``prefill_forward`` of each routed rehearsal model with the threshold
     on either side of the prompt's rows: the same logits."""

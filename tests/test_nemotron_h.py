@@ -552,6 +552,40 @@ def test_a_padded_batch_of_unequal_prompts_and_its_windows():
         == runner.ssm_state.nbytes + runner.conv_state.nbytes
 
 
+def test_a_long_batch_s_rows_go_to_their_own_experts():
+    """Three prompts in a bucket of 64 are 256 rows a batch of four, over
+    model.MOE_DENSE_MAX_ROWS: the prefill program is labelled ``grouped``
+    (the kernel of engine/experts.py, interpreted here, over two-matrix
+    relu2 experts 32 wide, experts 4 to 7 of a router over 16), its logits
+    are the masked product's, both state arrays lie as the masked run
+    leaves them, the pairs are counted, and the window stays ``masked``."""
+    assert SPEC.expert_size % 128 and SPEC.holds_share
+    prompts = [prompt_of(n, 70 + n) for n in (40, 57, 64)]
+    slots, pages = [0, 2, 3], [[1, 2, 3, 4], [5, 6, 7, 8], [9, 10, 11, 12]]
+    seqs = [PrefillSeq(tokens=np.asarray(p, np.int32), start_pos=0,
+                       chunk_pages=np.asarray(pg, np.int32),
+                       hist_pages=None, sampling=(0.0, 0, 1.0))
+            for p, pg in zip(prompts, pages)]
+    got = {}
+    for product in ("grouped", "masked"):
+        runner = ModelRunner(config(), params=PARAMS)
+        assert runner.experts_local == "interpret"
+        if product == "masked":
+            runner.experts_local = False
+        runner.prefill_batch(seqs, slots=slots)
+        fn, = runner._prefill_cache.values()
+        assert fn._labels["expert_product"] == product
+        assert runner.moe_grouped_pairs == (
+            256 * SPEC.num_experts_per_tok if product == "grouped" else 0)
+        assert runner._get_window(4, 4)._labels["expert_product"] == "masked"
+        got[product] = [np.asarray(a, np.float32) for a in (
+            runner.last_prefill_logits, runner.ssm_state, runner.conv_state)]
+    for name, a, b in zip(("logits", "state", "conv"), *got.values()):
+        assert np.abs(b).max() > 0.1, name
+        assert np.abs(a - b).max() < 0.03 * np.abs(b).max(), name
+    np.testing.assert_array_equal(got["grouped"][1][:, 1], 0.0)
+
+
 def test_an_inert_row_writes_no_state():
     """A warm-up's row (no slot) and a padding row leave every slot as it
     was; the pool is sized after the state arrays."""

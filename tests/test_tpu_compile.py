@@ -203,7 +203,7 @@ def test_latent_index_kernel_compiles_for_v5e(v5e, table):
 
 def _expert_layer(v5e, spec, h, i, held, routed, shared=0):
     """(x maker, leaves) of one expert layer of int8 stacks for the
-    described chip."""
+    described chip; a two-matrix expert ("relu2") has no gate leaf."""
     from dynamo_tpu.engine.quant import QTensor
 
     def s(shape, dtype):
@@ -219,6 +219,8 @@ def _expert_layer(v5e, spec, h, i, held, routed, shared=0):
           **({f"shared_w_{k}": q((shared, *d)) for k, d in (
               ("gate", (h, i)), ("up", (h, i)), ("down", (i, h)))}
              if shared else {})}
+    if spec.ffn_act == "relu2":
+        lp = {k: v for k, v in lp.items() if "_w_gate" not in k}
     return (lambda rows: s((rows, h), jnp.bfloat16)), lp
 
 
@@ -323,6 +325,42 @@ def test_a_shares_expert_layer_compiles_for_v5e(v5e, rows, widths):
     every = 2 * 3 * h * i * rows * (shared + (0 if grouped else 16))
     assert 0.9 * every < flops < 1.2 * every, (flops, every)
     assert compiled.memory_analysis().temp_size_in_bytes < 5 << 28
+
+
+@pytest.mark.parametrize("rows", [256, 4096],
+                         ids=["a prompt", "a prefill group"])
+def test_a_two_matrix_expert_layer_reads_its_stacks_as_the_chip_holds_them(
+        v5e, rows):
+    """A share of two-matrix relu2 experts at Nemotron-3-Nano's widths (32
+    int8 experts held of a router over 128; up [2,688, 1,856], 14.5 lane
+    tiles wide; down [1,856, 2,688]) above MOE_DENSE_MAX_ROWS: two custom
+    calls that Mosaic compiles within experts.VMEM_LIMIT_BYTES (up: ONE
+    stack with its activation, whole-width tiles of W^T; down: tiles of
+    896), and nothing in the optimised program has a stack's shape but the
+    arguments and their bitcasts. The chip holds ``up`` with 2,688 minor
+    (the TPU's default layout of such a shape): handed to the kernel as
+    [.., K, N] it is copied whole first (experts.lies_turned)."""
+    from dynamo_tpu.engine import experts, model
+    from dynamo_tpu.engine.config import Cohere2MoeSpec
+    h, i, held = 2688, 1856, 32
+    spec = Cohere2MoeSpec(
+        hidden_size=h, intermediate_size=i, num_layers=4, num_heads=32,
+        num_kv_heads=2, head_dim=128, num_experts=held,
+        num_experts_per_tok=6, moe_intermediate_size=i,
+        num_routed_experts=128, ffn_act="relu2", quant="int8")
+    x, lp = _expert_layer(v5e, spec, h, i, held, 128)
+    assert experts.lies_turned(h, i) and not experts.lies_turned(i, h)
+    assert model.expert_product(rows, True) == "grouped"
+    compiled = jax.jit(lambda x, lp: model.ffn_block(
+        x, lp, spec, experts_local=True)).lower(x(rows), lp).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    for stack in ((h, i), (i, h)):
+        for shape in ((held, *stack), (1, held, *stack)):
+            assert pool_sized_ops(text, shape) == [], shape
+    # The pairs' rows gathered, their unit, their outputs twice.
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        rows * 6 * (h * 2 + i * 2 + 2 * h * 4) * 1.2 + (1 << 20))
 
 
 # -- the decode window program: what it does to the KV pool --------------------
@@ -658,28 +696,19 @@ def test_glm_window_program_commits_in_place_for_v5e(v5e, spec_decode):
     assert pool_sized_ops(text, pool) == []
 
 
-def test_hybrid_window_program_keeps_the_state_where_it_lies_for_v5e(v5e):
-    """The window program of a block with recurrent layers at
-    Nemotron-3-Nano's widths (Mamba-2 mixers of 64 heads of 64 over a state
-    of 128, 8 groups; 32 query heads over 2 KV heads of 128, a page of 128;
-    a pool of the ONE attention layer; two-matrix relu2 experts; pattern
-    MEM*EME, a narrow vocabulary), int8 weights, pool and state donated:
-    the attention layer reads the pool through the kernel and commits in
-    place with ITS index in the pool, and the float32 state (32 slots x 3
-    layers x 2 MB) rides the pairs' carry and the steps' carry into ONE
-    more kernel (``ssm_backend`` ``kernel``: engine/recurrence.py compiles
-    for Mosaic at these widths, three row buffers of 2 MB in VMEM), which
-    is handed the whole stack aliased to its output and rewrites the live
-    slots of one layer where they lie: NOTHING else in the optimised
-    program has the state's shape (no copy: at the cell's depth one is 1.5
-    GB, 3.8 ms a step; no ``dynamic-update-slice`` of a layer's slice; no
-    fusion that reads it a second time)."""
+def _hybrid_runner(v5e, rows=32, pages=3000):
+    """A ModelRunner that places nothing, for a block with recurrent layers
+    at Nemotron-3-Nano's widths (Mamba-2 mixers of 64 heads of 64 over a
+    state of 128, 8 groups; 32 query heads over 2 KV heads of 128, a page
+    of 128; a pool of the ONE attention layer; 4 two-matrix relu2 experts
+    held of a router over 128; pattern MEM*EME, a narrow vocabulary), int8
+    weights. Returns (runner, spec, params as shapes, s)."""
     from types import SimpleNamespace
 
     from dynamo_tpu.engine.config import EngineConfig, NemotronHSpec
     from dynamo_tpu.engine.model import param_shapes
     from dynamo_tpu.engine.quant import QUANT_LAYER_KEYS, QTensor
-    from dynamo_tpu.engine.runner import PK_PREFIX, ModelRunner
+    from dynamo_tpu.engine.runner import ModelRunner
     spec = NemotronHSpec(
         name="hybrid", vocab_size=1024, hidden_size=2688,
         intermediate_size=1856, num_layers=7, num_heads=32, num_kv_heads=2,
@@ -691,10 +720,6 @@ def test_hybrid_window_program_keeps_the_state_where_it_lies_for_v5e(v5e):
         ssm_state=128, ssm_conv=4, ssm_chunk=128, quant="int8")
     assert (spec.pool_layers, spec.ssm_layers, spec.kv_entry) == (
         1, 3, (2, (128, 128)))
-    # A pool past the chip's 128 MiB of VMEM, as the cell's is: one of 600
-    # pages (39 MB) the compiler prefetches whole into VMEM, a copy-start of
-    # the pool's shape that says nothing of the program at the cell's size.
-    rows, window, pages = 32, 8, 3000
     runner = object.__new__(ModelRunner)
     runner.spec = spec
     runner.config = EngineConfig(model=spec, num_pages=pages,
@@ -703,12 +728,11 @@ def test_hybrid_window_program_keeps_the_state_where_it_lies_for_v5e(v5e):
     assert page == 128
     runner.config = EngineConfig(model=spec, page_size=page, num_pages=pages,
                                  max_num_seqs=rows)
-    table = runner.config.max_pages_per_seq // 2
     runner.device = SimpleNamespace(platform="tpu")
     runner.mesh = SimpleNamespace(size=1)
     runner.quant_kv, runner.lora, runner.draft_dev = None, None, None
-    runner.experts_local = False
-    runner._window_cache = {}
+    runner.experts_local = True
+    runner._window_cache, runner._prefill_cache = {}, {}
     runner._attention_impl, runner._window_attention_impl = \
         runner._pick_attention()
     runner.kv_commit_backend = runner._pick_kv_commit()
@@ -731,6 +755,33 @@ def test_hybrid_window_program_keeps_the_state_where_it_lies_for_v5e(v5e):
                                s((1, shapes["embed"][1]), jnp.float32)),
               "final_norm": s(shapes["final_norm"], jnp.bfloat16),
               "lm_head": q(shapes["lm_head"])}
+    return runner, spec, params, s
+
+
+def test_hybrid_window_program_keeps_the_state_where_it_lies_for_v5e(v5e):
+    """The window program of a block with recurrent layers at
+    Nemotron-3-Nano's widths (Mamba-2 mixers of 64 heads of 64 over a state
+    of 128, 8 groups; 32 query heads over 2 KV heads of 128, a page of 128;
+    a pool of the ONE attention layer; two-matrix relu2 experts; pattern
+    MEM*EME, a narrow vocabulary), int8 weights, pool and state donated:
+    the attention layer reads the pool through the kernel and commits in
+    place with ITS index in the pool, and the float32 state (32 slots x 3
+    layers x 2 MB) rides the pairs' carry and the steps' carry into ONE
+    more kernel (``ssm_backend`` ``kernel``: engine/recurrence.py compiles
+    for Mosaic at these widths, three row buffers of 2 MB in VMEM), which
+    is handed the whole stack aliased to its output and rewrites the live
+    slots of one layer where they lie: NOTHING else in the optimised
+    program has the state's shape (no copy: at the cell's depth one is 1.5
+    GB, 3.8 ms a step; no ``dynamic-update-slice`` of a layer's slice; no
+    fusion that reads it a second time)."""
+    from dynamo_tpu.engine.runner import PK_PREFIX
+    # A pool past the chip's 128 MiB of VMEM, as the cell's is: one of 600
+    # pages (39 MB) the compiler prefetches whole into VMEM, a copy-start of
+    # the pool's shape that says nothing of the program at the cell's size.
+    rows, window, pages = 32, 8, 3000
+    runner, spec, params, s = _hybrid_runner(v5e, rows, pages)
+    page = runner.config.page_size
+    table = runner.config.max_pages_per_seq // 2
     pool = (1, 2, pages, page, 128)
     s_shape, c_shape = spec.ssm_state_shapes
     state = (3, rows, *s_shape)
@@ -756,3 +807,40 @@ def test_hybrid_window_program_keeps_the_state_where_it_lies_for_v5e(v5e):
     assert len(aliased) == 1 and shape in aliased[0] \
         and "output_to_operand_aliasing" in aliased[0], aliased
     assert pool_sized_ops(text, state) == []
+
+
+def test_hybrid_prefill_program_reads_the_expert_stacks_where_they_lie(v5e):
+    """The prefill program of the same block for two prompts of 256 tokens
+    (512 rows: over MOE_DENSE_MAX_ROWS, labelled ``grouped``): the scan over
+    pairs hands the kernel of engine/experts.py the expert stacks over ALL
+    expert layers and the pair's index (hybrid.scan_pairs, as
+    model.scan_layers hands them), so NOTHING in the optimised program has
+    the shape of a stack or of a layer's slice of one but the arguments and
+    their bitcasts (sliced a pair ahead of a custom call a layer's experts
+    are copied; handed as [.., K, N] the whole ``up`` stack is: it lies
+    with K minor); the program at 128 rows takes the masked product."""
+    from dynamo_tpu.engine.runner import _PF_HDR
+    runner, spec, params, s = _hybrid_runner(v5e)
+    page, bucket, batch = runner.config.page_size, 256, 2
+    pool = (1, 2, 3000, page, 128)
+    s_shape, c_shape = spec.ssm_state_shapes
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    assert runner._get_prefill(128, 1, False)._labels[
+        "expert_product"] == "masked"
+    fn = runner._get_prefill(bucket, batch, False)
+    assert fn._labels["expert_product"] == "grouped"
+    lowered = fn.lower(
+        params, s(pool, jnp.bfloat16), s(pool, jnp.bfloat16),
+        s((batch, _PF_HDR + bucket + bucket // page + 1), jnp.int32),
+        s(key.shape, key.dtype),
+        state=(s((3, 32, *s_shape), jnp.float32),
+               s((3, 32, *c_shape), jnp.bfloat16)))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    # Two calls a pair, traced once each inside the scan's body.
+    assert text.count("tpu_custom_call") == 2
+    up, down = (2688, 1856), (1856, 2688)
+    for stack in (up, down):
+        for lead in ((3, 4), (1, 4), (4,)):
+            assert pool_sized_ops(text, (*lead, *stack)) == [], (lead, stack)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 4 * 2688 * 1856
