@@ -640,8 +640,9 @@ async def phase_kernels(args, jax, rng, keep: dict):
                                          page_size=page),
                             params=params_r)
             params_r = eng.runner.params  # the round's engines share weights
-            # What ModelRunner._pick_attention decides "auto" from: K and V
-            # heads of 128, or a pool of latent entries.
+            # What backends.choose decides "auto" from: K and V heads of
+            # 128, or a pool of latent entries. The runner's record
+            # (engine/backends.py) holds every choice compared below.
             want = (backend if backend != "auto" else "pallas"
                     if on_tpu and (spec_r.head_dim == 128 or spec_r.latent)
                     else "xla")
@@ -649,15 +650,16 @@ async def phase_kernels(args, jax, rng, keep: dict):
             check(resolved == want,
                   f"asked for {backend}, expected {want}, runner resolved "
                   f"{resolved}")
-            # The window's commit follows its reader (_pick_kv_commit): in
+            record = eng.runner.backends
+            # The window's commit follows its reader (config.pool_access): in
             # place beside the kernel on a plain bf16 pool at head_dim 128,
             # so the third round compares it with the scatter's logprobs;
             # a latent pool's is in place on a TPU under either reader.
             # Whoever walks a latent pool's entries walks its index keys.
-            index = eng.runner.index_backend
+            index = record.index
             check(index == (resolved if spec_r.latent else None),
                   f"{resolved} reader of {spec_r.name}: indexer {index}")
-            commit = eng.runner.kv_commit_backend
+            commit = record.kv_commit
             check((commit == "in_place") == (
                 on_tpu if spec_r.latent else
                 resolved == "pallas" and spec_r.head_dim == 128
@@ -733,7 +735,7 @@ async def phase_kernels(args, jax, rng, keep: dict):
                 # XLA's (the round's comparison is kernel against XLA).
                 ssm = eng.perf_status()["ssm"]["backend"]
                 check(ssm == ("kernel" if on_tpu and resolved == "pallas"
-                              else "xla") == eng.runner.ssm_backend,
+                              else "xla") == record.ssm,
                       f"{resolved} reader of {spec_r.name}: state by {ssm}")
             emit("kernels.run", model=spec_r.name,
                  quant_kv=quant_kv or "bf16", attention_backend=backend,

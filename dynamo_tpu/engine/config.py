@@ -1204,10 +1204,10 @@ def pool_access(attention_backend: str, platform: str, mesh_size: int,
                 ) -> tuple[str, str]:
     """(attention backend, KV commit): who reads the KV pool in decode and
     how the decode window writes it, from what a runner observes and
-    nothing else. The ONE statement of that choice: ModelRunner's
-    _pick_attention and _pick_kv_commit ask it with their device's
-    platform and their mesh, EngineConfig.resolve_page_size with the
-    configuration's.
+    nothing else. The ONE statement of that choice, the inner rule of
+    backends.choose: that asks it with the runner's device's platform and
+    its mesh (and decides beside it what follows the reader),
+    EngineConfig.resolve_page_size with the configuration's.
 
     Reader, under "auto": the Pallas kernel on one TPU device at head_dim
     128, the XLA gather everywhere else. Timed on one v5e (PERF.md section
@@ -1242,7 +1242,7 @@ def pool_access(attention_backend: str, platform: str, mesh_size: int,
     once, one copy a page, with the indexer's choice as its mask (PERF.md
     section 6, PR 35). The indexer's scores follow the reader: whoever
     walks a row's entries walks its index keys under the same page table
-    (runner.index_backend is the reader's name for a latent pool, None for
+    (Backends.index is the reader's name for a latent pool, None for
     a block without an indexer). XLA's gathers the index keys of every
     slot's bucket and scores the copy; attention.latent_index_pallas reads
     a row's live pages of index keys once and returns a float32 score a
@@ -1412,8 +1412,8 @@ class EngineConfig:
     # page-table bucket of every slot first. "auto" is the kernel on one
     # TPU device at head_dim 128 and XLA everywhere else (CPU, any
     # tp/pp/dp/sp mesh, a head_dim under 128, where the kernel's packed
-    # view of the pool is a copy of it); ModelRunner._pick_attention
-    # decides, and runner.attention_backend says what it resolved to.
+    # view of the pool is a copy of it); backends.choose decides, and
+    # runner.attention_backend says what it resolved to.
     attention_backend: str = "auto"
     # KV tiering (reference KVBM G1..G3, block_manager.rs:72-82):
     # host_cache_pages > 0 enables the G2 host-DRAM block cache — pages
@@ -1440,12 +1440,16 @@ class EngineConfig:
     # the previous occurrence, and VERIFIES them in one multi-token
     # forward — one weight read covers up to spec_k+1 positions, which
     # on an HBM-bound decode is up to a (spec_k+1)x ITL win on
-    # repetitive text (summaries, code edits, RAG). GREEDY ONLY:
-    # requests with temperature/logprobs/penalties/seeds are rejected
-    # while this is enabled (rejection sampling for stochastic
-    # equivalence is a later step). Off by default; plain serving is
-    # untouched.
-    spec_decode: str | None = None  # None | "ngram"
+    # repetitive text (summaries, code edits, RAG). "mtp" = the model's
+    # own prediction module drafts inside the window program's steps (a
+    # model with mtp_layers; runner._get_mtp_window). Either way the
+    # verify is rejection sampling on the device for a point-mass
+    # drafter: every emitted token is target-distributed, temperature,
+    # top-k, top-p and seeds ride in as data, and greedy rows are
+    # token-identical to plain decode (runner._get_spec_window). Still
+    # refused, by name (engine.generate): penalties under either drafter,
+    # logprobs under "ngram". Off by default; plain serving is untouched.
+    spec_decode: str | None = None  # None | "ngram" | "mtp"
     spec_k: int = 3                 # drafts verified per step
     # SLA-aware admission (reference pre_deployment_profiling.md:36-38
     # role): with a TTFT budget set, admission projects the time to

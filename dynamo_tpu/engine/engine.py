@@ -36,8 +36,7 @@ from dynamo_tpu.engine.kv_cache import PageAllocator
 from dynamo_tpu.engine.runner import (
     ModelRunner, PrefillSeq, PK_OVERRIDE, PK_TOKEN, PK_POS, PK_SEQLEN,
     PK_TOPK, PK_TEMP, PK_TOPP, PK_CAP, PK_LOGPROB, PK_FREQPEN, PK_PRESPEN,
-    PK_SEED, PK_SEEDED, PK_ADAPTER, PK_PREFIX, PREFIX_REUSE_OFF,
-    SSM_STATE_DTYPE, TOP_LOGPROBS)
+    PK_SEED, PK_SEEDED, PK_ADAPTER, PK_PREFIX, TOP_LOGPROBS)
 from dynamo_tpu.engine.sampler import MAX_TOPK
 from dynamo_tpu.llm.kv_router.protocols import (ForwardPassMetrics, KvStats,
                                                 SpecDecodeStats, WorkerStats)
@@ -135,29 +134,18 @@ class _Window:
     # queued behind it (the device ran them back to back): one window of
     # the device. 0 when the pipe was not full.
     period_s: float = 0.0
-    # A routed block's window: float32 [3] read back with the tokens
-    # (runner.decode_window): distinct experts chosen and the fullest
-    # expert's tokens over the mean, summed over steps and expert layers,
-    # and how many of those the sums hold; [5] where the expert layer is
-    # told its share: picks on held experts and all picks follow.
-    moe: object = None
-    # A latent block's window: float32 [2] read back beside it: keys the
-    # live rows attended and keys they had in context, over steps and
-    # layers.
-    attn: object = None
-    # A block with recurrent layers: the live rows summed over the window's
-    # steps, read back beside them (the flight ring's ssm_row_steps).
-    ssm: float = 0.0
+    # What the window counted, by flight-ring column (runtime/flight.py
+    # COUNTS has what each is): the vectors its program read back with the
+    # tokens (a routed block's load, a latent block's keys, the live rows
+    # of a block with recurrent layers) and, of a drafting window, what
+    # its verify steps did, summed over its rows when it is processed.
+    counts: dict = dataclasses.field(default_factory=dict)
     # Speculative windows: toks = (outs [m,B,S], emits [m,B],
     # ndrafts [m,B]), or under "mtp" the plain window's five with an axis
     # of spec_k + 1 positions behind the rows' and "emit" / "drafted"
     # [m,B] in the fifth; slots snaps carry the ASSUMED advance so
     # processing can correct the host's upper-bound positions.
     spec: bool = False
-    # What the window's verify steps did, summed over its rows when it is
-    # processed: (draft tokens taken in, accepted, live row-steps). The
-    # flight ring's spec_* columns.
-    drafted: tuple = (0, 0, 0)
 
 
 class TPUEngine(AsyncEngine):
@@ -309,17 +297,9 @@ class TPUEngine(AsyncEngine):
         # decode windows where drafting was suspended by it.
         self.brownout_level = 0
         self.spec_brownout_windows = 0
-        # Expert-layer load of a routed block, summed over every decode
-        # window processed (the entries of _Window.moe).
-        self.moe_totals = np.zeros(
-            5 if config.model.num_routed_experts is not None else 3,
-            np.float64)
-        # A latent block's windows: the keys its live rows attended and
-        # the keys they had in context (the entries of _Window.attn).
-        self.attn_totals = np.zeros(2, np.float64)
-        # A block with recurrent layers: (decode step, live row) pairs of
-        # every window processed: the rows whose state a step had to touch.
-        self.ssm_row_steps = 0.0
+        # What every decode window processed so far counted
+        # (_Window.counts), summed by flight-ring column.
+        self.counts_total = dict.fromkeys(flight.COUNT_COLUMNS, 0.0)
         # Control jobs executed on the engine thread between windows
         # (disagg prefill-extract, KV injection helpers, etc.).
         self._jobs: queue.Queue = queue.Queue()
@@ -1094,6 +1074,8 @@ class TPUEngine(AsyncEngine):
         if raw:
             expected = float(raw)
         compiles = self._perf.snapshot()
+        window = self.runner.backends.labels("decode_window")
+        total = self.counts_total
         status = {
             "role": "engine",
             "compiles": compiles,
@@ -1106,27 +1088,25 @@ class TPUEngine(AsyncEngine):
             },
             "hbm": self.runner.hbm_stats(),
             "memory": self.runner.memory_breakdown(),
-            # Static per runner: who reads the pool in decode and how the
-            # window program writes it (runner._pick_attention,
-            # _pick_kv_commit: config.pool_access).
-            "attention_backend": self.runner.attention_backend,
-            "kv_commit_backend": self.runner.kv_commit_backend,
-            # Who scores a latent pool's index keys in decode; None for a
-            # block without an indexer.
-            "index_backend": self.runner.index_backend,
-            # Who drafts inside the window program's steps: "mtp" (the
-            # model's own prediction module, spec_decode mtp) or "none".
-            "draft": "mtp" if self.mtp else "none",
-            # Tokens a KV page holds (config.resolve_page_size): over 16
+            # Static per runner, the window program's labels (the record
+            # of engine/backends.py): who reads the pool in decode and how
+            # the window program writes it; who scores a latent pool's
+            # index keys in decode (None for a block without an indexer);
+            # who drafts inside the window program's steps: "mtp" (the
+            # model's own prediction module, spec_decode mtp) or "none";
+            # tokens a KV page holds (config.resolve_page_size): over 16
             # where the page was derived for the Pallas reader.
-            "page_size": self.runner.page_size,
+            **{key: window.get(key) for key in (
+                "attention_backend", "kv_commit_backend", "index_backend",
+                "draft", "page_size")},
             # Engine-thread self time by loop phase, seconds since the
             # loop started (engine_phase_seconds_total on /metrics).
             "phases": {k: round(v, 6)
                        for k, v in self.phase_clock.totals().items()},
         }
         if self.runner.spec.num_experts:
-            touched, load, n = self.moe_totals[:3]
+            touched, load, n = (total[c] for c in (
+                "moe_touched", "moe_load", "moe_layer_steps"))
             spec = self.runner.spec
             experts = spec.num_experts
             status["moe"] = {
@@ -1145,7 +1125,7 @@ class TPUEngine(AsyncEngine):
                 "grouped_pairs": self.runner.moe_grouped_pairs,
             }
             if spec.num_routed_experts is not None:
-                local, picks = self.moe_totals[3:]
+                local, picks = total["moe_local_picks"], total["moe_picks"]
                 status["moe"].update(
                     experts_routed=spec.router_width,
                     first_expert=spec.first_expert,
@@ -1154,7 +1134,7 @@ class TPUEngine(AsyncEngine):
                     if picks else None)
         if self.runner.spec.latent:
             spec = self.runner.spec
-            selected, context = self.attn_totals
+            selected, context = total["attn_selected"], total["attn_context"]
             status["attn"] = {
                 # Keys a query attends at most (the indexer's choice).
                 "index_topk": spec.index_topk,
@@ -1173,15 +1153,15 @@ class TPUEngine(AsyncEngine):
                 # beside its pages (float32 S, the convolution's inputs).
                 "layers": spec.ssm_layers,
                 "state_bytes_per_row": spec.ssm_state_bytes_per_row,
-                "state_dtype": SSM_STATE_DTYPE,
+                "state_dtype": window["ssm_state"],
                 # Who updates S in a decode step: "kernel" (the live slots,
                 # in place: engine/recurrence.py) | "xla" (every slot).
-                "backend": self.runner.ssm_backend,
+                "backend": window["ssm_backend"],
                 # (decode step, live row) pairs so far: the rows the kernel
                 # visited, a layer.
-                "row_steps": int(self.ssm_row_steps),
+                "row_steps": int(total["ssm_row_steps"]),
                 # Static: a page's border has no state to continue from.
-                "prefix_reuse": PREFIX_REUSE_OFF,
+                "prefix_reuse": window["prefix_reuse"],
             }
         if self.config.spec_decode:
             # Verify-of-k bandwidth: the spec program runs m_outer verify
@@ -2564,6 +2544,17 @@ class TPUEngine(AsyncEngine):
                        page_bucket=packed.shape[1] - PK_PREFIX,
                        prefilling=held_without_row)
 
+    def _count(self, w: _Window, counted: dict) -> None:
+        """What a window counted, into its own counts and this engine's
+        totals by flight-ring column: ``counted`` holds a vector under each
+        key of flight.COUNTS that the window has (a program's readback may
+        hold other keys beside them: a drafting window's "emit" and
+        "draft")."""
+        for key in counted.keys() & flight.COUNTS.keys():
+            for column, value in flight.columns_of(key, counted[key]).items():
+                w.counts[column] = value
+                self.counts_total[column] += value
+
     def _process_window(self, w: _Window) -> None:
         if w.spec and w.toks is not None:
             self._process_spec_window(w)
@@ -2584,16 +2575,7 @@ class TPUEngine(AsyncEngine):
                 # What the block counted: a few bytes of the same
                 # program's output, copied with the tokens: no second wait
                 # for the device.
-                counted = w.toks[4]
-                if "moe" in counted:
-                    w.moe = np.asarray(counted["moe"], np.float64)
-                    self.moe_totals += w.moe
-                if "attn" in counted:
-                    w.attn = np.asarray(counted["attn"], np.float64)
-                    self.attn_totals += w.attn
-                if "ssm" in counted:
-                    w.ssm = float(np.asarray(counted["ssm"])[0])
-                    self.ssm_row_steps += w.ssm
+                self._count(w, w.toks[4])
             self._note_ready(w)
         else:
             toks = None
@@ -2695,10 +2677,7 @@ class TPUEngine(AsyncEngine):
                        for snap in w.slots):
                     lps, top_vs, top_is = (np.asarray(a)
                                            for a in w.toks[1:4])
-                w.moe = np.asarray(counted["moe"], np.float64)
-                self.moe_totals += w.moe
-                w.attn = np.asarray(counted["attn"], np.float64)
-                self.attn_totals += w.attn
+                self._count(w, counted)
             else:
                 emits = np.asarray(w.toks[1])    # [m, B]
                 ndrafts = np.asarray(w.toks[2])  # [m, B]
@@ -2726,9 +2705,9 @@ class TPUEngine(AsyncEngine):
         if live_rows:
             # What the window's verify steps did, for the flight ring.
             e_live, d_live = emits[:, live_rows], ndrafts[:, live_rows]
-            w.drafted = (int(d_live.sum()),
-                         int(np.maximum(e_live - 1, 0).sum()),
-                         int((e_live > 0).sum()))
+            self._count(w, {"spec": (d_live.sum(),
+                                     np.maximum(e_live - 1, 0).sum(),
+                                     (e_live > 0).sum())})
         for i, snap in enumerate(w.slots):
             if snap is None:
                 continue
@@ -2967,12 +2946,8 @@ class TPUEngine(AsyncEngine):
             w.period_s, busy_total - self._flight_busy_last,
             wait_total - self._flight_wait_last,
             idle_total - self._flight_idle_last, rows, w.page_bucket,
-            *(w.moe if w.moe is not None else ()),
-            **({} if w.attn is None else {
-                "attn_selected": w.attn[0], "attn_context": w.attn[1]}),
             prefilling=w.prefilling, admit_stop=self._flight_admit_stop,
-            **dict(zip(("spec_drafted", "spec_accepted", "spec_row_steps"),
-                       w.drafted)), ssm_row_steps=w.ssm)
+            counts=w.counts)
         if accepted:
             # A frozen ring (bundle capture in flight) rejects the row:
             # keep accumulating so the stall/chunk/token/host-time deltas

@@ -33,8 +33,8 @@ one token (``ssm_step``). Past a row's last real token dt is 0 (the state
 stands: exp(0) S + 0) and the convolution keeps the last real inputs, so a
 padded batch, a dead slot and a frozen row leave a state exactly as it was.
 
-Who updates the float32 state in a decode step (``runner.ssm_backend``,
-decided by the runner as the pool's reader is, and beside it): where the
+Who updates the float32 state in a decode step (``Backends.ssm``, decided
+by backends.choose as the pool's reader is, and beside it): where the
 Pallas reader runs on one TPU device the kernel of engine/recurrence.py,
 which is handed the stack over all layers where it lies and visits the
 live slots of one layer (``ssm_step_live``: a dead slot is neither read nor
@@ -54,13 +54,14 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from dynamo_tpu.engine.backends import XLA, Backends
 from dynamo_tpu.engine.config import ModelSpec
 from dynamo_tpu.engine.kv_quant import gather_pages_folded, scatter_pages
 from dynamo_tpu.engine.model import (Params, _split_heads,
                                      dense_causal_attention, embed_lookup,
                                      expert_product, ffn_block,
-                                     history_attention, layer_of, lm_logits,
-                                     mm, paged_window_attention_xla, rms_norm,
+                                     history_attention, kv_attention,
+                                     layer_of, lm_logits, mm, rms_norm,
                                      whole_expert_leaves)
 from dynamo_tpu.engine.perf import scope
 from dynamo_tpu.engine.recurrence import state_step
@@ -297,13 +298,13 @@ def in_layer(step):
 
 def scan_pairs(layers: dict, spec: ModelSpec, x: jax.Array, state: tuple,
                ssm_fn, attn_fn, kv_like: tuple, live=None,
-               experts_local: bool | str = False):
+               backends: Backends = XLA):
     """x through every layer. ``state`` (S [M, rows, ...], conv [M, rows,
     ...]) rides the carry and layer p rewrites its own rows in place:
     ``ssm_fn(h, lp, S, conv, p) -> (out, S, conv)`` over the whole stacks
     (``in_layer`` for a step over one layer's rows); ``attn_fn(h, ap, a) ->
     (out, k, v)`` for attention layer a of its stack (k and v shaped as
-    ``kv_like``); ``live`` and ``experts_local`` as model.ffn_block takes
+    ``kv_like``); ``live`` and ``backends`` as model.ffn_block takes
     them: where x's rows take the grouped product the expert stacks are not
     sliced a pair but handed whole with the pair's index, as
     model.scan_layers hands them (sliced ahead of a custom call a layer's
@@ -316,7 +317,7 @@ def scan_pairs(layers: dict, spec: ModelSpec, x: jax.Array, state: tuple,
     moe = {k: v for k, v in layers.items()
            if k.startswith(("moe_", "shared_"))}
     whole = {}
-    if expert_product(math.prod(x.shape[:-1]), experts_local) == "grouped":
+    if expert_product(math.prod(x.shape[:-1]), backends) == "grouped":
         moe, whole = whole_expert_leaves(moe)
     attn = {k: layers[k] for k in ATTN_LEAVES}
     starred = jnp.asarray([a >= 0 for a in pairs.attn_index])
@@ -343,7 +344,7 @@ def scan_pairs(layers: dict, spec: ModelSpec, x: jax.Array, state: tuple,
         with scope("mlp"):
             out = ffn_block(rms_norm(x, norm_e, eps),
                             {**lp_e, **layer_of(whole, p)}, spec, live=live,
-                            experts_local=experts_local)
+                            backends=backends)
             out, load = out if isinstance(out, tuple) else (out, None)
             x = x + out
         return (x, s_all, c_all), ((k, v) if load is None else (k, v, load))
@@ -377,7 +378,7 @@ def prefill(params: Params, spec: ModelSpec, k_cache: jax.Array,
             v_cache: jax.Array, state: tuple, tokens: jax.Array,
             positions: jax.Array, page_table: jax.Array, seq_lens: jax.Array,
             slots: jax.Array, hist: tuple | None = None,
-            experts_local: bool | str = False):
+            backends: Backends = XLA):
     """model.prefill_forward for this block: a chunk of each row's prompt,
     whole (``hist`` None) or after earlier chunks (``hist`` (hist_table,
     hist_lens): the attention layers also read the row's earlier pages).
@@ -385,8 +386,8 @@ def prefill(params: Params, spec: ModelSpec, k_cache: jax.Array,
     the slot of each row (-1: none, the row's state goes nowhere): a row
     whose chunk starts at position 0 starts from zeros, any other from its
     slot's state, and each leaves there the state at its last real token.
-    ``experts_local``: see model.ffn_block (a window step's rows never take
-    the grouped product: ``window_step`` has no such argument).
+    ``backends``: see model.ffn_block (a window step's rows never take the
+    grouped product: ``window_step`` hands its expert layers no record).
     Returns (last-token logits, k_cache, v_cache, state)."""
     b, s = tokens.shape
     page = k_cache.shape[3]
@@ -423,7 +424,7 @@ def prefill(params: Params, spec: ModelSpec, k_cache: jax.Array,
     nkv, d = spec.num_kv_heads, spec.head_dim
     x, held, k_new, v_new, _ = scan_pairs(
         params["layers"], spec, x, held, ssm_fn, attn_fn,
-        ((b, s, nkv, d),) * 2, experts_local=experts_local)
+        ((b, s, nkv, d),) * 2, backends=backends)
     with scope("kv.commit"):
         n_attn = spec.pool_layers
         blocks = lambda a: (a.reshape(n_attn, b * (s // page), page, nkv, d)  # noqa: E731
@@ -455,28 +456,29 @@ def window_step(params: Params, spec: ModelSpec, k_cache: jax.Array,
                 v_cache: jax.Array, k_buf: jax.Array, v_buf: jax.Array,
                 m: jax.Array, tokens: jax.Array, page_table: jax.Array,
                 hist_lens: jax.Array, state: tuple, live: jax.Array,
-                attention_impl=None, ssm_kernel=False):
+                backends: Backends = XLA):
     """model.decode_window_step for this block: one token a slot. The pool
     (its layers are the attention layers') is read-only and this window's
     earlier tokens come from k_buf / v_buf [A, Nkv, B, M, D]; ``state`` is
     the runner's two arrays over all slots, carried through the window's
-    steps: a row that is not ``live`` keeps its own. ``ssm_kernel`` (True,
-    or "interpret"): the kernel of engine/recurrence.py updates the live
-    slots' S where the stack lies; else XLA every slot's (``ssm_step``).
-    Returns (logits, k_new and v_new [A, B, Nkv, D], state, the expert
-    layers' load [E, 5], the live rows [1, 1])."""
+    steps: a row that is not ``live`` keeps its own. ``backends.ssm``
+    "kernel": the kernel of engine/recurrence.py updates the live slots' S
+    where the stack lies; else XLA every slot's (``ssm_step``).
+    Returns (logits, k_new and v_new [A, B, Nkv, D], state, counts: "moe"
+    the expert layers' load [E, 5], "ssm" the live rows [1, 1]; the keys
+    the host's table knows, runtime/flight.py COUNTS)."""
     b = tokens.shape[0]
     with scope("embed"):
         x = embed_lookup(params["embed"], tokens)
-    attend = attention_impl or paged_window_attention_xla
+    attend = kv_attention(backends, window=True)
     with scope("ssm"):
         walk = live_walk(live)      # (XLA's path takes the count alone)
 
-    if ssm_kernel:
+    if backends.ssm == "kernel":
         def ssm_fn(h, lp, s_all, c_all, p):
             out, s_all, c_new = ssm_step_live(
                 h, lp, spec, s_all, p, _index(c_all, p), live, walk,
-                interpret=ssm_kernel == "interpret")
+                interpret=backends.interpret)
             return out, s_all, jax.lax.dynamic_update_index_in_dim(
                 c_all, c_new, p, 0)
     else:
@@ -499,5 +501,5 @@ def window_step(params: Params, spec: ModelSpec, k_cache: jax.Array,
     with scope("lm_head"):
         x = rms_norm(x, params["final_norm"], spec.rms_norm_eps)
         logits = lm_logits(x, params, spec)
-    return (logits, k_new, v_new, state, load,
-            walk[1].astype(jnp.float32).reshape(1, 1))
+    return (logits, k_new, v_new, state,
+            {"moe": load, "ssm": walk[1].astype(jnp.float32).reshape(1, 1)})

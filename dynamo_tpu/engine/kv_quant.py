@@ -179,7 +179,7 @@ def scatter_tokens(cache, vals, dest, off):
 
     Who still calls it: the single-step ``model.decode_forward``, the
     speculative window (``runner._get_spec_window``), and the decode window
-    program wherever ``runner.kv_commit_backend`` is "scatter": a mesh, a
+    program wherever ``Backends.kv_commit`` is "scatter": a mesh, a
     packed head (head_dim 64), an int8 pool, the CPU under "auto". On a TPU
     XLA's scatter wants the pool as ``{4,1,3,2,0:T(4,128)}`` and converts a
     row-major pool in and out, two pool-sized copies per cache per call

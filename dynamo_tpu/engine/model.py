@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from dynamo_tpu.engine.backends import XLA, Backends
 from dynamo_tpu.engine.config import DENSE_PREFIX, MTP_PREFIX, ModelSpec
 from dynamo_tpu.engine.kv_quant import (gather_pages_folded, scatter_pages,
                                         scatter_tokens)
@@ -411,12 +412,12 @@ def layer_of(stacks: dict, layer) -> dict:
     return {k: LayerOf(v, layer) for k, v in stacks.items()}
 
 
-def expert_product(rows: int, experts_local) -> str:
+def expert_product(rows: int, backends: Backends) -> str:
     """The product an expert layer of ``rows`` rows takes, a static fact of
-    its program (the runner's label ``expert_product``): "grouped" above
-    MOE_DENSE_MAX_ROWS where the caller says the experts are whole on one
-    device, else "masked"."""
-    return ("grouped" if experts_local and rows > MOE_DENSE_MAX_ROWS
+    its program (the label ``expert_product``): "grouped" above
+    MOE_DENSE_MAX_ROWS where the runner's record says the experts are whole
+    on one device, else "masked"."""
+    return ("grouped" if backends.experts_whole and rows > MOE_DENSE_MAX_ROWS
             else "masked")
 
 
@@ -545,8 +546,7 @@ def _grouped_experts(x: jax.Array, gates: jax.Array, top_i: jax.Array,
 
 def ffn_block(h2: jax.Array, lp: dict, spec: ModelSpec, ll: dict | None = None,
               ids: jax.Array | None = None, router_in: jax.Array | None = None,
-              live: jax.Array | None = None,
-              experts_local: bool | str = False):
+              live: jax.Array | None = None, backends: Backends = XLA):
     """Feed-forward over normalized hidden states [..., H]: dense SwiGLU /
     ReGLU, or a routed expert layer when spec.num_experts > 0.
 
@@ -557,9 +557,10 @@ def ffn_block(h2: jax.Array, lp: dict, spec: ModelSpec, ll: dict | None = None,
     and the combine contracts over the expert axis under the gate mask:
     with experts sharded over "tp" each device runs E/tp experts and XLA
     inserts the psum, i.e. expert parallelism without a dynamic all-to-all.
-    Above it, where the caller says the experts are whole on one device
-    (``experts_local``: the runner's mesh has one device; "interpret" says
-    the same of the CPU, which interprets the kernel), every routed kind,
+    Above it, where the runner's record says the experts are whole on one
+    device (``backends.experts_whole``: its mesh has one device;
+    ``backends.interpret``: the CPU, which interprets the kernel), every
+    routed kind,
     gated experts and two-matrix ones ("relu2": no gate leaf) alike,
     multiplies the (row, choice) pairs by their own experts only
     (``_grouped_experts``: a Pallas kernel, which GSPMD cannot partition);
@@ -607,9 +608,9 @@ def ffn_block(h2: jax.Array, lp: dict, spec: ModelSpec, ll: dict | None = None,
         stats = (None if live is None
                  else moe_load_stats(one_hot, live.reshape(-1), spec))
     with scope("moe.experts"):
-        if expert_product(x.shape[0], experts_local) == "grouped":
+        if expert_product(x.shape[0], backends) == "grouped":
             out = _grouped_experts(x, gates, top_i, lp, spec,
-                                   interpret=experts_local == "interpret")
+                                   interpret=backends.interpret)
         else:
             w_te = jnp.einsum("tk,tke->te", gates, one_hot)  # [T, E] sparse-ish
             down = _every_expert(x, lp.get("moe_w_gate"), lp["moe_w_up"],
@@ -1033,6 +1034,15 @@ def paged_window_attention_xla(q: jax.Array, k_cache: jax.Array,
     return out.reshape(b, nh, d)
 
 
+def kv_attention(backends: Backends, window: bool):
+    """Who attends a pool of K and V pages, in a window's step
+    (``paged_window_attention_xla``'s signature) or in the single decode
+    step (``paged_decode_attention_xla``'s): the kernel the runner's record
+    binds, else XLA's gather. The one place a forward program asks."""
+    return backends.kv_reader(window) or (
+        paged_window_attention_xla if window else paged_decode_attention_xla)
+
+
 def _split_heads(x, n, d):
     return x.reshape(*x.shape[:-1], n, d)
 
@@ -1247,7 +1257,7 @@ def latent_window_attention(q: LatentQuery, e_cache: jax.Array,
                             e_win: jax.Array, i_win: jax.Array, m: jax.Array,
                             e_self: jax.Array, i_self: jax.Array,
                             spec: ModelSpec, live: jax.Array | None = None,
-                            kernels=None):
+                            backends: Backends = XLA):
     """Decode attention of the latent block for step ``m`` of a window, in
     the ABSORBED form: a head's query is folded through Wk_b into the
     latent's space (qa_h = q_nope_h Wk_b[h]^T), scores and the weighted
@@ -1262,14 +1272,14 @@ def latent_window_attention(q: LatentQuery, e_cache: jax.Array,
     hold more than ``index_topk`` keys the indexer scores every key in
     context and the row attends the index_topk of largest score.
 
-    Who reads the pool (config.pool_access): ``kernels``, on one TPU
-    device, is the pair a runner binds (attention.latent_history_pallas,
+    Who reads the pool (``backends.latent_readers``): on one TPU device
+    the pair the runner's record binds (attention.latent_history_pallas,
     attention.latent_index_pallas). The indexer's kernel walks each row's
     live pages of index keys once and returns a float32 score a key; the
     reader's walks the row's live entries once with the choice as its mask,
     and hands back a running maximum, sum and weighted sum that are merged
-    here with the window's columns and the self token. None (the CPU, any
-    mesh) is XLA's walk, which gathers the whole bucket of every slot from
+    here with the window's columns and the self token. (None, None) (the
+    CPU, any mesh) is XLA's walk, which gathers the whole bucket of every slot from
     both arrays, scores the index keys' copy and reads the entries' twice
     more. Either way XLA scores the keys that are not in the pool yet (the
     window's columns, the self token) and the choice over all of them stays
@@ -1288,7 +1298,7 @@ def latent_window_attention(q: LatentQuery, e_cache: jax.Array,
          jnp.broadcast_to(jnp.arange(M)[None, :] < m, (b, M)),
          jnp.ones((b, 1), bool)], axis=1)                    # [B, K]
     chosen = seen
-    reader, indexer = kernels or (None, None)
+    reader, indexer = backends.latent_readers()
     if spec.index_topk and hist + M + 1 > spec.index_topk:
         with scope("attn.index"):
             def scores(keys):
@@ -1364,7 +1374,8 @@ def latent_block_attention(q: LatentQuery, e_cache: jax.Array,
                            hist_lens: jax.Array, e_win: jax.Array,
                            win_keep: jax.Array, e_blk: jax.Array,
                            spec: ModelSpec, live: jax.Array | None = None,
-                           reader=None, lo: int = 0, scoped: bool = True):
+                           backends: Backends = XLA, lo: int = 0,
+                           scoped: bool = True):
     """Attention of a latent block WITHOUT an indexer for a block of S
     query positions a row (a verify step's chained token and its drafts; a
     prediction module's inputs), in the absorbed form of
@@ -1376,9 +1387,10 @@ def latent_block_attention(q: LatentQuery, e_cache: jax.Array,
     j - 1 and whose slot 0 holds nothing), the window's committed columns
     e_win [B, W, width] where win_keep [B, W] says so (a rejected draft's
     column is not one), and the block's own entries e_blk [B, S, width],
-    causal. ``reader``: attention.latent_block_pallas bound
-    by a runner (one walk of the row's live pages for all S x Nh query
-    rows, no mask operand), or None for XLA's gather of the whole bucket.
+    causal. Who reads the pool (``backends.block_reader``):
+    attention.latent_block_pallas bound by the runner's record (one walk of
+    the row's live pages for all S x Nh query rows, no mask operand), or
+    None for XLA's gather of the whole bucket.
     ``scoped`` False draws no scope (a module's attention stays in
     ``mtp``). Returns (attention [B, S, Nh * v_head_dim], float32 [2]: the
     ``live`` rows' keys in context, counted ONCE a step whatever S, twice
@@ -1388,6 +1400,7 @@ def latent_block_attention(q: LatentQuery, e_cache: jax.Array,
     page, width = e_cache.shape[3], e_cache.shape[-1]
     W = e_win.shape[1]
     sc = scope if scoped else (lambda _name: contextlib.nullcontext())
+    reader = backends.block_reader()
     parts = [e_win, e_blk]
     kept = [jnp.broadcast_to(win_keep[:, None, :], (b, s * nh, W)),
             jnp.broadcast_to((jnp.arange(s * nh)[:, None] // nh
@@ -1455,13 +1468,14 @@ def mtp_leaves(layers: dict, module: int = 0) -> dict:
 def mtp_block(params: Params, spec: ModelSpec, hidden: jax.Array,
               next_tokens: jax.Array, cos: jax.Array, sin: jax.Array,
               attend, live: jax.Array | None = None,
-              experts_local: bool = False):
+              backends: Backends = XLA):
     """The prediction module over positions whose NEXT token is known:
     x_i = [RMS_e(Emb(t_{i+1})) ; RMS_h(h_i)] W_eh, then one whole block of
     the model's kind (its own latent entries; ``attend`` reads them), at
     the rope position of h_i. ``hidden`` [..., H]: the model's output
     after its final norm; ``next_tokens`` [...]. Returns (y [..., H], the
-    block's fresh entries, its expert layer's stats or None): the draft of
+    block's fresh entries, what the block counted: transformer_block's
+    dict): the draft of
     t_{i+2} is ``mtp_logits(y_i)``. No scope inside but the expert layer's
     sub-scopes: the caller draws ``mtp`` around it."""
     lp = mtp_leaves(params["layers"])
@@ -1470,10 +1484,10 @@ def mtp_block(params: Params, spec: ModelSpec, hidden: jax.Array,
                    eps)
     x = mm(jnp.concatenate([emb, rms_norm(hidden, lp["h_norm"], eps)], -1),
            lp["w_eh"], "...i,ih->...h")
-    y, k, _, stats = transformer_block(
+    y, k, _, counts = transformer_block(
         x, lp, spec, cos, sin, attend, scoped=False, live=live,
-        experts_local=experts_local)
-    return y, k, stats
+        backends=backends)
+    return y, k, counts
 
 
 def mtp_logits(params: Params, spec: ModelSpec, y: jax.Array) -> jax.Array:
@@ -1488,7 +1502,7 @@ def mtp_logits(params: Params, spec: ModelSpec, y: jax.Array) -> jax.Array:
 def mtp_prefill(params: Params, spec: ModelSpec, k_cache: jax.Array,
                 hidden: jax.Array, tokens: jax.Array, positions: jax.Array,
                 seq_lens: jax.Array, next_token: jax.Array,
-                hist: tuple | None = None, experts_local: bool = False):
+                hist: tuple | None = None, backends: Backends = XLA):
     """The prediction module over a prefill chunk: fills its entries for
     every position of the chunk (the next token is the chunk's own next,
     and ``next_token`` [B] after the last valid one: the prompt's next
@@ -1533,7 +1547,7 @@ def mtp_prefill(params: Params, spec: ModelSpec, k_cache: jax.Array,
                                         hist=mod_hist)
 
     y, k_new, _ = mtp_block(params, spec, hidden, nxt, cos, sin, attend,
-                            experts_local=experts_local)
+                            backends=backends)
     e = k_new[:, :, 0]                                   # [B, S (+ 1), w]
     if hist is None:
         # Slot 0 holds no entry (no position before the first).
@@ -1552,7 +1566,7 @@ def decode_verify_step(params: Params, spec: ModelSpec, k_cache: jax.Array,
                        tokens: jax.Array,
                        positions: jax.Array, page_table: jax.Array,
                        hist_lens: jax.Array, live: jax.Array,
-                       reader=None, experts_local: bool = False):
+                       backends: Backends = XLA):
     """One verify step INSIDE a drafting window: S = k + 1 tokens a slot
     (the chained token and its drafts) through the model at once, one read
     of the weights for S positions. A latent block without an indexer.
@@ -1563,8 +1577,8 @@ def decode_verify_step(params: Params, spec: ModelSpec, k_cache: jax.Array,
     layers first), win_keep [B, W] which of them hold a committed token;
     hist_lens [B] cache-resident tokens.
     Returns (hidden [B, S, H] after the final norm, logits [B, S, V],
-    entries [L, B, S, 1, width], key counts [L, 2], the expert layers'
-    ``moe_load_stats``)."""
+    entries [L, B, S, 1, width], counts: "attn" the key counts [L, 2],
+    "moe" the expert layers' ``moe_load_stats``)."""
     b, s = tokens.shape
     with scope("embed"):
         x = embed_lookup(params["embed"], tokens)
@@ -1581,12 +1595,11 @@ def decode_verify_step(params: Params, spec: ModelSpec, k_cache: jax.Array,
             return latent_block_attention(
                 q, k_cache, layer, page_table, hist_lens,
                 jnp.swapaxes(e_win, 0, 1), win_keep, k[:, :, 0], spec,
-                live[:, 0], reader)
+                live[:, 0], backends)
 
-        x, k, _, stats = transformer_block(
-            x, lp, spec, cos, sin, attend, live=live,
-            experts_local=experts_local)
-        return x, (k, *stats)
+        x, k, _, counts = transformer_block(
+            x, lp, spec, cos, sin, attend, live=live, backends=backends)
+        return x, (k, counts)
 
     x, ys = scan_layers(layer_fn, x, (params["layers"], jnp.arange(L)), spec)
     with scope("lm_head"):
@@ -1634,23 +1647,24 @@ def transformer_block(x: jax.Array, lp: dict, spec: ModelSpec,
                       kind: tuple | None = None, ll: dict | None = None,
                       ids: jax.Array | None = None, scoped: bool = True,
                       live: jax.Array | None = None,
-                      experts_local: bool = False):
+                      backends: Backends = XLA):
     """One layer, for every forward program: whole-prompt prefill,
     with-history prefill, the single decode step and the decode window
     under either attention backend, the n-gram verify step, embeddings
     and the pipelined prefill's stage. x [B,H] or [B,S,H] is the residual
     stream as it enters the layer; ``attend(q, k, v, kind)`` is the path's
     attention over split heads (it owns its scopes and returns [..., Nh*D]);
-    ``kind`` is ``layer_kind`` of this layer. Returns (x, k, v, stats):
-    k/v the layer's fresh keys and values, stats ``moe_load_stats`` of the
-    ``live`` rows where asked for (routed blocks), else None.
-    ``experts_local``: see ``ffn_block``.
+    ``kind`` is ``layer_kind`` of this layer. Returns (x, k, v, counts):
+    k/v the layer's fresh keys and values, counts what the layer counted
+    under the keys the host's table knows (runtime/flight.py COUNTS):
+    "moe", ``moe_load_stats`` of the ``live`` rows where asked for (routed
+    blocks), "attn", the keys where ``attend`` returns (attention, key
+    counts); {} for a layer that counts nothing.
+    ``backends``: see ``ffn_block``.
 
     The latent block (``spec.latent``) differs in the projections ahead of
     ``attend`` alone: q is a ``LatentQuery``, k the token's latent entry
-    and v its index key (what the two pools hold); where ``attend``
-    returns (attention, counts), stats is (counts,) or (counts, the
-    expert layer's stats)."""
+    and v its index key (what the two pools hold)."""
     sc = scope if scoped else (lambda _name: contextlib.nullcontext())
     d = spec.head_dim
     with sc("attn.qkv"):
@@ -1687,9 +1701,9 @@ def transformer_block(x: jax.Array, lp: dict, spec: ModelSpec,
             v = k[..., :0]
         q = LatentQuery(nope, rope, iq, iw, lp["wk_b"], lp["wv_b"])
     attn = attend(q, k, v, kind)
-    counts = None
+    counts = {}
     if isinstance(attn, tuple):     # the latent window step counts its keys
-        attn, counts = attn
+        attn, counts["attn"] = attn
     with sc("attn.out"):
         proj = mm(attn, lp["wo"], "...d,dh->...h")
         if ll is not None:
@@ -1702,12 +1716,11 @@ def transformer_block(x: jax.Array, lp: dict, spec: ModelSpec,
         router_in = x_in if spec.moe_router_input == "layer_input" else None
         out = ffn_block(h2, lp, spec, ll, ids, router_in=router_in,
                         live=live if spec.num_experts else None,
-                        experts_local=experts_local)
-        out, stats = out if isinstance(out, tuple) else (out, None)
+                        backends=backends)
+        if isinstance(out, tuple):
+            out, counts["moe"] = out
         x = x + out
-    if counts is not None:
-        stats = (counts,) if stats is None else (counts, stats)
-    return x, k, v, stats
+    return x, k, v, counts
 
 
 def scan_layers(layer_fn, x: jax.Array, xs, spec: ModelSpec,
@@ -1718,8 +1731,9 @@ def scan_layers(layer_fn, x: jax.Array, xs, spec: ModelSpec,
     A model with leading dense layers (``first_k_dense``) is two scans: the
     dense layers' leaves (``DENSE_PREFIX``, under the names the layer
     reads) with the first rows of the other arrays, then the rest; what
-    both scans give a layer (k, v, counts) is joined along the layer axis,
-    what only the expert layers give (their load) follows.
+    both scans give a layer (k, v, and in a dict of counts the keys) is
+    joined along the layer axis, what only the expert layers give (their
+    load) follows.
 
     ``whole_experts`` (the caller's layers take the grouped expert product:
     ``expert_product``): the EXPERT_LEAVES are not sliced a layer; the
@@ -1763,9 +1777,13 @@ def scan_layers(layer_fn, x: jax.Array, xs, spec: ModelSpec,
         rest = (rest, *(a[dense:] for a in others))
     x, ys_first = jax.lax.scan(layer_fn, x, first)
     x, ys_rest = scan(layer_fn, x, rest)
-    joined = tuple(jnp.concatenate([a, b])
-                   for a, b in zip(ys_first, ys_rest))
-    return x, joined + tuple(ys_rest[len(ys_first):])
+    def join(a, b):
+        if isinstance(b, dict):
+            return {k: jnp.concatenate([a[k], v]) if k in a else v
+                    for k, v in b.items()}
+        return jnp.concatenate([a, b])
+
+    return x, tuple(join(a, b) for a, b in zip(ys_first, ys_rest))
 
 
 # ---------------------------------------------------------------------------
@@ -1782,7 +1800,7 @@ def prefill_forward(params: Params, spec: ModelSpec,
                     embeds_mask: jax.Array | None = None,
                     lora: dict | None = None,
                     adapter_ids: jax.Array | None = None,
-                    experts_local: bool = False, defer: bool = False,
+                    backends: Backends = XLA, defer: bool = False,
                     ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Process prompt chunks and write K/V into pages.
 
@@ -1839,7 +1857,7 @@ def prefill_forward(params: Params, spec: ModelSpec,
         lp, ll = scan_in if lora is not None else (scan_in, None)
         x, k, v, _ = transformer_block(
             x, lp, spec, cos, sin, attend, layer_kind(spec, layer), ll,
-            adapter_ids, experts_local=experts_local)
+            adapter_ids, backends=backends)
         return x, (k, v)
 
     # Cache writes are deferred out of the scan (ys are fresh allocations —
@@ -1849,7 +1867,7 @@ def prefill_forward(params: Params, spec: ModelSpec,
         xs = (xs, jnp.arange(spec.num_layers))
     x, (k_new, v_new) = scan_layers(
         layer_fn, x, xs, spec,
-        whole_experts=expert_product(b * s, experts_local) == "grouped")
+        whole_experts=expert_product(b * s, backends) == "grouped")
     # k_new [L,B,S,Nkv,D] -> page blocks [L,Nkv,B*S/page,page,D]; one
     # in-place scatter per cache covers every layer.
     with scope("kv.commit"):
@@ -2023,10 +2041,10 @@ def decode_forward(params: Params, spec: ModelSpec,
                    k_cache: jax.Array, v_cache: jax.Array,
                    tokens: jax.Array, positions: jax.Array,
                    page_table: jax.Array, seq_lens: jax.Array,
-                   attention_impl=None, write_mask: jax.Array | None = None,
+                   backends: Backends = XLA,
+                   write_mask: jax.Array | None = None,
                    lora: dict | None = None,
                    adapter_ids: jax.Array | None = None,
-                   experts_local: bool = False,
                    ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One decode step for the whole slot batch.
 
@@ -2049,7 +2067,7 @@ def decode_forward(params: Params, spec: ModelSpec,
     if write_mask is not None:
         dest_page = jnp.where(write_mask, dest_page, 0)
         page_off = jnp.where(write_mask, page_off, 0)
-    attn_fn = attention_impl or paged_decode_attention_xla
+    attn_fn = kv_attention(backends, window=False)
     # The new token's K/V is NOT written inside the layer loop: attention
     # takes it as an explicit self column (hist_lens = cache-resident
     # length) and one batched scatter below writes all layers at once. The
@@ -2070,7 +2088,7 @@ def decode_forward(params: Params, spec: ModelSpec,
                 return latent_window_attention(
                     q, k_cache, v_cache, layer, page_table, hist_lens,
                     k[None, :, :0], v[None, :, :0], jnp.asarray(0, jnp.int32),
-                    k, v, spec, kernels=attention_impl)[0]
+                    k, v, spec, backends=backends)[0]
             attn = attn_fn(q, k_cache, v_cache, layer, page_table, hist_lens,
                            k, v, spec.q_per_kv,
                            lo=window_lo(spec, kind, positions))  # [B,Nh,D]
@@ -2078,7 +2096,7 @@ def decode_forward(params: Params, spec: ModelSpec,
 
         x, k, v, _ = transformer_block(
             x, lp, spec, cos, sin, attend, layer_kind(spec, layer), ll,
-            adapter_ids, scoped=False, experts_local=experts_local)
+            adapter_ids, scoped=False, backends=backends)
         return x, (k, v)
 
     xs = ((params["layers"], jnp.arange(L), lora) if lora is not None
@@ -2243,19 +2261,20 @@ def decode_window_step(params: Params, spec: ModelSpec,
                        k_buf: jax.Array, v_buf: jax.Array, m: jax.Array,
                        tokens: jax.Array, positions: jax.Array,
                        page_table: jax.Array, hist_lens: jax.Array,
-                       attention_impl=None, lora: dict | None = None,
+                       backends: Backends = XLA, lora: dict | None = None,
                        adapter_ids: jax.Array | None = None,
-                       live: jax.Array | None = None,
-                       experts_local: bool = False) -> tuple:
+                       live: jax.Array | None = None) -> tuple:
     """One decode step INSIDE an M-step window: the caches are read-only
     (gathered), this window's earlier tokens come from k_buf/v_buf
     [L,Nkv,B,M,D], and the step's fresh K/V is returned ([L,B,Nkv,D]) for
     the caller to append to the buffer — no cache writes here at all.
 
     hist_lens [B]: tokens cache-resident BEFORE the window (fixed across
-    the window). Returns (logits [B,V], k_new, v_new), and with ``live``
-    [B] (bool, a routed block's rows that count) a fourth: the expert
-    layers' ``moe_load_stats`` [L, 3].
+    the window). Returns (logits [B,V], k_new, v_new, counts): what the
+    layers counted, a layer a row, under transformer_block's keys: with
+    ``live`` [B] (bool, a routed block's rows that count) "moe", the
+    expert layers' ``moe_load_stats`` [L, 3]; a latent block's "attn", its
+    key counts [L, 2]; {} for a block that counts nothing.
     """
     b = tokens.shape[0]
     d = spec.head_dim
@@ -2263,7 +2282,7 @@ def decode_window_step(params: Params, spec: ModelSpec,
         x = embed_lookup(params["embed"], tokens)
     with scope("attn.qkv"):
         cos, sin = spec_rope_tables(spec, positions)
-    attn_fn = attention_impl or paged_window_attention_xla
+    attn_fn = kv_attention(backends, window=True)
     L = spec.num_layers
 
     def layer_fn(x, scan_in):
@@ -2276,20 +2295,17 @@ def decode_window_step(params: Params, spec: ModelSpec,
             if spec.latent:     # owns its scopes; counts the live rows' keys
                 return latent_window_attention(
                     q, k_cache, v_cache, layer, page_table, hist_lens, kb_l,
-                    vb_l, m, k, v, spec, live, kernels=attention_impl)
+                    vb_l, m, k, v, spec, live, backends=backends)
             with scope("attn.core"):
                 attn = attn_fn(q, k_cache, v_cache, layer, page_table,
                                hist_lens, kb_l, vb_l, m, k, v, spec.q_per_kv,
                                lo=window_lo(spec, kind, positions))
                 return attn.reshape(b, -1)
 
-        x, k, v, stats = transformer_block(
+        x, k, v, counts = transformer_block(
             x, lp, spec, cos, sin, attend, layer_kind(spec, layer), ll,
-            adapter_ids, live=live, experts_local=experts_local)
-        if stats is None:
-            return x, (k, v)
-        return x, ((k, v, *stats) if isinstance(stats, tuple)
-                   else (k, v, stats))
+            adapter_ids, live=live, backends=backends)
+        return x, (k, v, counts)
 
     xs = ((params["layers"], jnp.arange(L), k_buf, v_buf, lora)
           if lora is not None
@@ -2298,6 +2314,4 @@ def decode_window_step(params: Params, spec: ModelSpec,
     with scope("lm_head"):
         x = norm(x, params["final_norm"], spec)
         logits = lm_logits(x, params, spec)
-    # A routed block asked for its load (``live``) adds [L, 3] stats; the
-    # latent block its key counts [L, 2] ahead of them.
     return (logits, *ys)

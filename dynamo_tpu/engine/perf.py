@@ -69,6 +69,7 @@ import weakref
 
 import jax
 
+from dynamo_tpu.runtime import flight
 from dynamo_tpu.runtime.logging import (generate_span_id, generate_trace_id,
                                         get_logger)
 
@@ -345,7 +346,7 @@ class _InstrumentedJit:
                  fn, key, labels: dict | None = None):
         self._fn = fn
         # What the builder chose statically for this program (the window
-        # program's kv_commit_backend): a name, not a count.
+        # program's kv_commit_backend: Backends.labels): a name, not a count.
         self._labels = dict(labels or {})
         self._registry = registry
         self._program = program
@@ -724,20 +725,20 @@ class PerfMetricsUpdater:
         self.g_kv_commit = registry.gauge(
             "perf_kv_commit_info", "1 under the label of how this worker's "
             "decode window program commits its tokens to the KV pool "
-            "(runner.kv_commit_backend): in_place (the touched pages are "
-            "rewritten where they lie, beside the Pallas reader) or "
+            "(engine/backends.py Backends.kv_commit): in_place (the touched "
+            "pages are rewritten where they lie, beside the Pallas reader) or "
             "scatter (XLA's scatter; pool-sized layout copies on a TPU)",
             ["backend"])
         self.g_attention = registry.gauge(
             "perf_attention_info", "1 under the label of who reads this "
-            "worker's KV pool in decode (runner.attention_backend, "
-            "config.pool_access): pallas (a kernel walks a row's live "
+            "worker's KV pool in decode (engine/backends.py "
+            "Backends.attention): pallas (a kernel walks a row's live "
             "pages: K and V heads, or latent entries) or xla (the gather "
             "of every slot's page-table bucket)", ["backend"])
         self.g_index = registry.gauge(
             "perf_index_info", "1 under the label of who runs the decode "
-            "indexer of this worker's latent pool (runner.index_backend, "
-            "config.pool_access): pallas (a kernel walks a row's live pages "
+            "indexer of this worker's latent pool (engine/backends.py "
+            "Backends.index): pallas (a kernel walks a row's live pages "
             "of index keys and scores them) or xla (the gather of every "
             "slot's page-table bucket, scored); the choice over the scores "
             "is XLA's under either; no sample for a block without an indexer",
@@ -778,25 +779,28 @@ class PerfMetricsUpdater:
             "decode window program's steps: mtp (the model's own "
             "prediction module, verified in the same step) or none",
             ["kind"])
-        self.c_moe_layer_steps = registry.counter(
+        # What decode windows counted, by flight-ring column: the table
+        # flight.COUNTS names each column's counter, and update() walks it.
+        self.c_counts: dict = {}
+        self.c_counts["moe_layer_steps"] = registry.counter(
             "moe_layer_steps_total", "Routed block: (decode step, expert "
             "layer) pairs with a live row, the denominator of the two "
             "series below")
-        self.c_moe_touched = registry.counter(
+        self.c_counts["moe_touched"] = registry.counter(
             "moe_experts_touched_total", "Routed block: distinct experts "
             "the live rows chose, summed over decode steps and expert "
             "layers (over moe_layer_steps_total: experts a layer-step "
             "touches)")
-        self.c_moe_load = registry.counter(
+        self.c_counts["moe_load"] = registry.counter(
             "moe_expert_load_max_over_mean_total", "Routed block: the "
             "fullest expert's tokens over the mean per expert, summed over "
             "decode steps and expert layers (over moe_layer_steps_total: "
             "1.0 is an even load)")
-        self.c_moe_local_picks = registry.counter(
+        self.c_counts["moe_local_picks"] = registry.counter(
             "moe_local_picks_total", "Expert layer told its share: (row, "
             "choice) pairs of live rows that fell on experts held here "
             "(over moe_picks_total: an even router gives held / routed)")
-        self.c_moe_picks = registry.counter(
+        self.c_counts["moe_picks"] = registry.counter(
             "moe_picks_total", "Expert layer told its share: all (row, "
             "choice) pairs of live rows, wherever the expert is held")
         self.c_moe_grouped_pairs = registry.counter(
@@ -814,15 +818,15 @@ class PerfMetricsUpdater:
         self.g_moe_experts = registry.gauge(
             "moe_experts_info", "Expert layer told its share: experts the "
             "router chooses among, held here and shared", ["kind"])
-        self.c_attn_selected = registry.counter(
+        self.c_counts["attn_selected"] = registry.counter(
             "attn_selected_total", "Latent block: keys the live rows "
             "attended (the indexer's choice, at most index_topk a row), "
             "summed over rows, layers and decode steps")
-        self.c_attn_context = registry.counter(
+        self.c_counts["attn_context"] = registry.counter(
             "attn_context_total", "Latent block: keys the live rows had in "
             "context, summed over rows, layers and decode steps (every one "
             "is scored by the indexer)")
-        self.c_ssm_row_steps = registry.counter(
+        self.c_counts["ssm_row_steps"] = registry.counter(
             "ssm_row_steps_total", "Block with recurrent layers: (decode "
             "step, live row) pairs, summed on the device: the rows whose "
             "recurrent state a step had to read and write (over steps x "
@@ -877,22 +881,19 @@ class PerfMetricsUpdater:
         runner = getattr(engine, "runner", None)
         hbm = runner.hbm_stats() if runner is not None and hasattr(
             runner, "hbm_stats") else {}
-        backend = getattr(runner, "kv_commit_backend", None)
-        if backend:
-            self.g_kv_commit.set(1, backend=backend)
-        reader = getattr(runner, "attention_backend", None)
-        if reader:
-            self.g_attention.set(1, backend=reader)
-        indexer = getattr(runner, "index_backend", None)
-        if indexer:
-            self.g_index.set(1, backend=indexer)
-        page = getattr(runner, "page_size", None)
-        if page:
-            self.g_kv_page.set(1, tokens=str(page))
+        # The window program's labels (engine/backends.py), one info
+        # series each.
+        backends = getattr(runner, "backends", None)
+        window = backends.labels("decode_window") if backends else {}
+        for gauge, key, label in (
+                (self.g_kv_commit, "kv_commit_backend", "backend"),
+                (self.g_attention, "attention_backend", "backend"),
+                (self.g_index, "index_backend", "backend"),
+                (self.g_kv_page, "page_size", "tokens"),
+                (self.g_draft, "draft", "kind")):
+            if window.get(key):
+                gauge.set(1, **{label: str(window[key])})
         config = getattr(engine, "config", None)
-        if config is not None and hasattr(config, "spec_decode"):
-            self.g_draft.set(1, kind="mtp" if config.spec_decode == "mtp"
-                             else "none")
         if config is not None and hasattr(config, "kv_token_bytes"):
             latent = config.model.latent
             self.g_kv_entry.set(1, kind="latent" if latent else "kv",
@@ -906,31 +907,23 @@ class PerfMetricsUpdater:
             self.g_ssm_state.set(
                 1, bytes_per_row=str(runner.spec.ssm_state_bytes_per_row),
                 dtype=str(runner.ssm_state.dtype))
-            self._delta(self.c_ssm_row_steps, ("ssm_rs",),
-                        float(getattr(engine, "ssm_row_steps", 0.0)))
-        attn = getattr(engine, "attn_totals", None)
-        if attn is not None and attn[1]:
-            self._delta(self.c_attn_selected, ("attn_s",), float(attn[0]))
-            self._delta(self.c_attn_context, ("attn_c",), float(attn[1]))
+        totals = getattr(engine, "counts_total", None) or {}
+        for columns in flight.COUNTS.values():
+            for column, metric in columns:
+                if metric:
+                    self._delta(self.c_counts[column], (column,),
+                                totals.get(column, 0.0))
         for program, kinds in reg.label_values("expert_product").items():
             for kind in kinds:
                 self.g_expert_product.set(1, program=program, kind=kind)
         self._delta(self.c_moe_grouped_pairs, ("moe_g",),
                     float(getattr(runner, "moe_grouped_pairs", 0)))
-        moe = getattr(engine, "moe_totals", None)
-        if moe is not None and moe[2]:
-            self._delta(self.c_moe_touched, ("moe_t",), float(moe[0]))
-            self._delta(self.c_moe_load, ("moe_l",), float(moe[1]))
-            self._delta(self.c_moe_layer_steps, ("moe_n",), float(moe[2]))
-            if len(moe) > 3:
-                self._delta(self.c_moe_local_picks, ("moe_p",),
-                            float(moe[3]))
-                self._delta(self.c_moe_picks, ("moe_a",), float(moe[4]))
-                spec = engine.runner.spec
-                for kind, n in (("routed", spec.router_width),
-                                ("held", spec.num_experts),
-                                ("shared", spec.num_shared_experts)):
-                    self.g_moe_experts.set(n, kind=kind)
+        if totals.get("moe_picks"):     # an expert layer told its share
+            spec = engine.runner.spec
+            for kind, n in (("routed", spec.router_width),
+                            ("held", spec.num_experts),
+                            ("shared", spec.num_shared_experts)):
+                self.g_moe_experts.set(n, kind=kind)
         if getattr(engine, "spec_emit_hist", None):
             self._delta(self.c_spec_draft_tokens, ("spec_dt",),
                         engine.spec_tokens)

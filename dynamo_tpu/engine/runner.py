@@ -27,7 +27,6 @@ KV arrays are donated through every call so XLA updates them in place.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import time
 
 import jax
@@ -36,9 +35,9 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dynamo_tpu.engine import perf
+from dynamo_tpu.engine.backends import SSM_STATE_DTYPE, choose
 from dynamo_tpu.engine.config import (EngineConfig, UnsupportedBlockError,
-                                      block_refusals,
-                                      pool_access, window_page_bucket)
+                                      block_refusals, window_page_bucket)
 from dynamo_tpu.engine.kv_quant import (KV_SCALE_BYTES, QuantKV, pack_parcel,
                                         parcel_to_bf16, quantize_np,
                                         scatter_tokens, unpack_parcel,
@@ -48,7 +47,6 @@ from dynamo_tpu.engine.model import (
     dense_causal_attention,
     expert_product,
     init_params,
-    paged_decode_attention_xla,
     param_specs,
     prefill_forward,
     decode_forward,
@@ -135,14 +133,6 @@ class PrefillSeq:
     # own in; -1: none, the state goes nowhere (a warm-up's inert row).
     # prefill_batch's ``slots`` say the same where they are given.
     slot: int = -1
-
-
-#: What /debug/perf and the programs' labels say of prefix reuse for a block
-#: with recurrent layers (engine.TPUEngine._plan_prefill takes no cached page).
-PREFIX_REUSE_OFF = "off (recurrent state has no snapshot)"
-#: What the recurrent state S is kept in: the configuration's choice (the
-#: model card asks engines for a float32 state cache), not an option.
-SSM_STATE_DTYPE = "float32"
 
 
 def _mh_put(value, sharding):
@@ -245,23 +235,17 @@ class ModelRunner:
         local = [d for d in devices[:total]
                  if d.process_index == jax.process_index()]
         self.device = local[0] if local else devices[0]
-        # A routed block's experts are whole on one device: model.ffn_block
-        # then multiplies a long batch's rows by their own experts only
-        # (the kernel of engine/experts.py, for gated and two-matrix
-        # experts alike; the CPU interprets it, as it does the attention
-        # kernels). On any mesh: the masked product, which GSPMD can
-        # partition.
-        self.experts_local = self.mesh.size == 1 and (
-            "interpret" if self.device.platform == "cpu" else True)
-        # (row, choice) pairs the prefill calls sent through that kernel, a
-        # layer: counted on the host from the rows of each call.
+        # Who runs what, decided once (engine/backends.py) and handed to
+        # every forward program whole. Before any weight is loaded: a
+        # backend that cannot be had fails the start-up in milliseconds.
+        self.backends = choose(config, spec, self.device.platform,
+                               self.mesh.size, self.quant_kv)
+        # Who reads the pool in decode, under the name the benchmark reads.
+        self.attention_backend = self.backends.attention
+        # (row, choice) pairs the prefill calls sent through the grouped
+        # product's kernel, a layer: counted on the host from the rows of
+        # each call.
         self.moe_grouped_pairs = 0
-        # Before any weight is loaded: a backend that cannot be had fails
-        # the start-up in milliseconds.
-        self._attention_impl, self._window_attention_impl = \
-            self._pick_attention()
-        self.kv_commit_backend = self._pick_kv_commit()
-        self.ssm_backend = self._pick_ssm()
         self.page_size = config.page_size
         self._sized_pages(self.device)
 
@@ -513,129 +497,7 @@ class ModelRunner:
                 f"cannot size the KV pool or report HBM use")
         return stats
 
-    def _pallas_refusal(self) -> str | None:
-        """Why no Pallas kernel can read this model's pool on this mesh, or
-        None: what "pallas" raises with and what "auto" decides from."""
-        d = self.spec.head_dim
-        page = self.config.page_size
-        if self.spec.latent:
-            # attention.latent_history_pallas: an entry is key and value,
-            # whatever the heads' own widths.
-            if self.quant_kv is not None:
-                return ("walks a latent pool of bfloat16 entries; no kernel "
-                        "reads int8 latent pages (an entry's scales would "
-                        "be a third array under the page table)")
-        elif not (d == 128 or (d < 128 and 128 % d == 0
-                               and (page * d) % 128 == 0)):
-            return (f"needs head_dim 128, or a head_dim that packs into 128 "
-                    f"lanes (128 % head_dim == 0 and page_size*head_dim % "
-                    f"128 == 0); got head_dim {d}, page_size {page}")
-        if self.mesh.size > 1:
-            return ("runs on one device: the kernel has no partitioning "
-                    "rule, so a tp/pp/dp/sp mesh would gather the whole KV "
-                    "pool around it")
-        return None
-
-    def _pick_attention(self):
-        """Returns (single-step impl, window impl). A requested backend is
-        what runs: "pallas" that cannot be had is an error, never XLA.
-        "auto" is decided from what the runner observes, nothing else: the
-        platform, the mesh's size, the head dimension and what a token
-        leaves in the pool (config.pool_access, which has the
-        measurements). A latent pool has ONE reader for the step and the
-        window, and with it the indexer's scores
-        (model.latent_window_attention's ``kernels``): the bound pair, or
-        None for XLA's walk; ``index_backend`` says which."""
-        from dynamo_tpu.engine.model import paged_window_attention_xla
-        backend, _ = pool_access(
-            self.config.attention_backend, self.device.platform,
-            self.mesh.size, self.spec.head_dim, self.quant_kv,
-            self.spec.latent)
-        self.attention_backend = backend
-        # Whoever reads a latent pool reads BOTH its arrays: the entries and
-        # the index keys the indexer scores (None: a block without one).
-        self.index_backend = (backend if self.spec.latent
-                              and self.spec.index_topk else None)
-        # A latent block without an indexer, S query positions a slot (the
-        # drafting window's verify step and its module): the reader
-        # without a mask operand, or None for XLA's gather.
-        self._block_reader = None
-        if backend == "xla":
-            if self.spec.latent:
-                return None, None
-            return paged_decode_attention_xla, paged_window_attention_xla
-        if backend != "pallas":
-            raise ValueError(f"attention_backend must be 'auto', 'xla' or "
-                             f"'pallas', got {backend!r}")
-        refusal = self._pallas_refusal()
-        if refusal is not None:
-            raise ValueError(f"attention_backend='pallas' {refusal}")
-        from dynamo_tpu.engine.attention import (
-            latent_history_pallas, latent_index_pallas,
-            paged_decode_attention_pallas, paged_window_attention_pallas)
-        # Interpret mode exists for the CPU backend only; a chip compiles
-        # the kernel through Mosaic or fails.
-        interpret = self.device.platform == "cpu"
-        if self.spec.latent:
-            bound = dict(interpret=interpret,
-                         table=self.config.max_pages_per_seq)
-            if not self.spec.index_topk:
-                from dynamo_tpu.engine.attention import latent_block_pallas
-                self._block_reader = functools.partial(latent_block_pallas,
-                                                       **bound)
-            return ((functools.partial(latent_history_pallas, **bound),
-                     functools.partial(latent_index_pallas, **bound)),) * 2
-        return (functools.partial(paged_decode_attention_pallas,
-                                  interpret=interpret),
-                functools.partial(paged_window_attention_pallas,
-                                  interpret=interpret))
-
-    def _pick_kv_commit(self) -> str:
-        """How the decode window program writes its tokens into the pool
-        ("in_place" or "scatter"), decided by the observation that chose
-        the reader (config.pool_access): the writer must leave the pool in
-        the layout the reader reads."""
-        return pool_access(self.attention_backend, self.device.platform,
-                           self.mesh.size, self.spec.head_dim,
-                           self.quant_kv, self.spec.latent)[1]
-
-    def _pick_ssm(self) -> str | None:
-        """Who updates a recurrent layer's float32 state in a decode step
-        ("kernel" | "xla"; None: a block without such layers), decided as
-        the pool's reader is and beside it: the kernel of
-        engine/recurrence.py where the Pallas reader runs on one TPU device
-        (it visits the live slots where the stack lies and reads a state
-        once), XLA through hybrid.ssm_step everywhere else: the CPU
-        backend, which would interpret the kernel; a mesh, which never has
-        the Pallas reader (``_pallas_refusal``) and is refused for such a
-        block (config.block_refusals); and a runner asked for the XLA
-        reader, which is XLA's throughout (chip_smoke.py compares the two
-        on the chip)."""
-        if not self.spec.recurrent:
-            return None
-        return ("kernel" if self.attention_backend == "pallas"
-                and self.device.platform == "tpu" else "xla")
-
     # -- compiled steps -------------------------------------------------------
-    def _expert_product(self, rows: int) -> dict:
-        """The label of a program whose expert layers multiply ``rows`` rows
-        at once: ``expert_product`` "grouped" | "masked", what
-        model.ffn_block decides from the same two facts; none for a dense
-        block."""
-        if not self.spec.num_experts:
-            return {}
-        return {"expert_product": expert_product(rows, self.experts_local)}
-
-    def _recurrent_labels(self) -> dict:
-        """The labels of a program that carries recurrent state: what the
-        state is kept in, and that a prompt's pages are never reused (a
-        page's border has no state to continue from); none for a block
-        whose whole per-request state is pages."""
-        if not self.spec.recurrent:
-            return {}
-        return {"ssm_state": SSM_STATE_DTYPE,
-                "prefix_reuse": PREFIX_REUSE_OFF}
-
     def _get_prefill(self, bucket: int, batch: int, with_history: bool,
                      penalized: bool = False, seeded: bool = False,
                      with_embeds: bool = False):
@@ -716,15 +578,14 @@ class ModelRunner:
                     params, spec, k_cache, v_cache, state, tokens, positions,
                     page_table, seq_lens, slots,
                     hist=(hist_table, hist_lens) if with_history else None,
-                    experts_local=self.experts_local)
+                    backends=self.backends)
             elif with_history:
                 logits, k_cache, v_cache, *deferred = _prefill_with_history(
                     params, spec, k_cache, v_cache, tokens, positions,
                     page_table, seq_lens, hist_table, hist_lens,
-                    self._attention_impl, sp_shard=sp_shard,
+                    self.backends, sp_shard=sp_shard,
                     x_embeds=emb, embeds_mask=emb_mask,
-                    lora=lora, adapter_ids=adapter_ids,
-                    experts_local=self.experts_local, defer=mtp)
+                    lora=lora, adapter_ids=adapter_ids, defer=mtp)
             elif pipelined:
                 from dynamo_tpu.engine.model import (
                     prefill_forward_pipelined)
@@ -739,7 +600,7 @@ class ModelRunner:
                                and self.config.ring_attention else None),
                     x_embeds=emb, embeds_mask=emb_mask,
                     lora=lora, adapter_ids=adapter_ids,
-                    experts_local=self.experts_local, defer=mtp)
+                    backends=self.backends, defer=mtp)
             with perf.scope("sample"):
                 if penalized:
                     freq = jax.lax.bitcast_convert_type(packed[:, 7],
@@ -790,9 +651,9 @@ class ModelRunner:
                                    donate_argnums=(1, 2),
                                    **({"donate_argnames": ("state",)}
                                       if recurrent else {}),
-                                   labels={
-                                       **self._expert_product(bucket * batch),
-                                       **self._recurrent_labels()})
+                                   labels=self.backends.labels(
+                                       "prefill", expert_product(
+                                           bucket * batch, self.backends)))
         self._prefill_cache[key] = fn
         return fn
 
@@ -821,7 +682,7 @@ class ModelRunner:
         with perf.scope("mtp"):
             blocks, e_last, draft = mtp_prefill(
                 params, spec, k_cache, hidden, tokens, positions, seq_lens,
-                next_token, hist, experts_local=self.experts_local)
+                next_token, hist, backends=self.backends)
         with perf.scope("kv.commit"):
             k_cache = scatter_pages(
                 k_cache, jnp.concatenate([k_blocks, blocks], axis=0), flat)
@@ -831,14 +692,14 @@ class ModelRunner:
                 hidden[:, page - 1::page])
             table = jnp.concatenate([page_table, next_page[:, None]], axis=1)
             valid = seq_lens > 0
-            if self.kv_commit_backend == "in_place":
+            if self.backends.kv_commit == "in_place":
                 from dynamo_tpu.engine.attention import commit_window_pallas
                 k_cache, _ = commit_window_pallas(
                     k_cache, v_cache, e_last[None, None, :, None, :],
                     jnp.zeros((1, 1, b, 1, 0), v_cache.dtype),
                     seq_lens, jnp.where(valid, seq_lens + 1, 0),
                     valid.astype(jnp.int32), table,
-                    interpret=self.device.platform == "cpu", layers=(L, 1))
+                    interpret=self.backends.interpret, layers=(L, 1))
             else:
                 dest = jnp.take_along_axis(
                     table, (seq_lens // page)[:, None], axis=1)[:, 0]
@@ -877,7 +738,6 @@ class ModelRunner:
         S = self.config.spec_k + 1
         W = window * S
         L, LP = spec.num_layers, spec.pool_layers
-        reader = self._block_reader
 
         def run_window(params, k_cache, v_cache, tokens_dev, positions_dev,
                        draft_dev, page_ends, packed, rng):
@@ -920,10 +780,10 @@ class ModelRunner:
                 tok_blk = jnp.stack(
                     [tokens, jnp.where(drafted, draft, 0)], axis=1)
                 pos_blk = pos[:, None] + jnp.arange(S)[None, :]
-                hidden, logits, k_new, counts, load = decode_verify_step(
+                hidden, logits, k_new, counts = decode_verify_step(
                     params, spec, k_cache, kbuf, keep, tok_blk, pos_blk,
                     page_table, hist_lens, jnp.stack([live, drafted], axis=1),
-                    reader, experts_local=self.experts_local)
+                    self.backends)
                 with perf.scope("sample"):
                     flat = logits.reshape(B * S, -1)
                     rng, sub = jax.random.split(rng)
@@ -961,13 +821,13 @@ class ModelRunner:
                         return latent_block_attention(
                             q, k_cache, layer, page_table, mod_lens,
                             jnp.swapaxes(kbuf[:, L], 0, 1), keep,
-                            k[:, :, 0], spec, live, reader, lo=1,
+                            k[:, :, 0], spec, live, self.backends, lo=1,
                             scoped=False)
 
-                    y, k_mod, mstats = mtp_block(
+                    y, k_mod, mcounts = mtp_block(
                         params, spec, hidden, out, cos, sin, attend,
                         live=jnp.stack([live, accepted], axis=1),
-                        experts_local=self.experts_local)
+                        backends=self.backends)
                     last = accepted.astype(jnp.int32)
                     nxt = jnp.argmax(mtp_logits(
                         params, spec, y[b_idx, last]), axis=-1)
@@ -984,21 +844,23 @@ class ModelRunner:
                                                         (0, m * S, 0))
                 tokens = jnp.where(live, out[b_idx, last], tokens)
                 pos = pos + emitted
-                counted = (counts.sum(0) + mstats[0], load.sum(0) + mstats[1])
+                # What the model's layers and the module's counted, by key.
+                counted = {key: a.sum(0) + mcounts[key]
+                           for key, a in counts.items()}
                 return (tokens, pos, draft, keep, kbuf, hbuf, rng), (
                     out, lp.reshape(B, S), top_v.reshape(B, S, -1),
                     top_i.reshape(B, S, -1), emitted.astype(jnp.int32),
-                    jnp.where(drafted, tok_blk[:, 1], -1), *counted)
+                    jnp.where(drafted, tok_blk[:, 1], -1), counted)
 
             carry0 = (tokens0, pos0, draft0, jnp.zeros((B, W), bool), kbuf0,
                       jnp.zeros((B, W, spec.hidden_size), jnp.bfloat16), rng)
             (tokens, pos, draft, keep, kbuf, hbuf, rng), \
-                (toks, lps, top_vs, top_is, emits, drafts, attn, moe) = \
+                (toks, lps, top_vs, top_is, emits, drafts, counted) = \
                 jax.lax.scan(step, carry0, jnp.arange(window))
             # "emit" [M, B]: tokens a row's step emitted (0: not live);
             # "draft": the draft it verified (-1: none).
-            stats = {"attn": attn.sum(0), "moe": moe.sum(0), "emit": emits,
-                     "draft": drafts}
+            stats = {**{key: a.sum(0) for key, a in counted.items()},
+                     "emit": emits, "draft": drafts}
             with perf.scope("kv.commit"):
                 # The committed columns to the front, in order: column c of
                 # the result is the token at position pos0 + c.
@@ -1023,17 +885,18 @@ class ModelRunner:
                 seq = active.astype(jnp.int32)
                 ends = [jnp.minimum(cap, first + wlen)
                         for first in (pos0, pos0 + 1)]
-                if self.kv_commit_backend == "in_place":
+                if self.backends.kv_commit == "in_place":
                     from dynamo_tpu.engine.attention import (
                         commit_window_pallas)
-                    cpu = self.device.platform == "cpu"
+                    interpret = self.backends.interpret
                     vwin = jnp.zeros((LP, 1, B, W, 0), v_cache.dtype)
                     k_cache, _ = commit_window_pallas(
                         k_cache, v_cache, kbuf[:L], vwin[:L], pos0, ends[0],
-                        seq, page_table, interpret=cpu, layers=(0, L))
+                        seq, page_table, interpret=interpret,
+                        layers=(0, L))
                     k_cache, _ = commit_window_pallas(
                         k_cache, v_cache, kbuf[L:], vwin[L:], pos0 + 1,
-                        ends[1], seq, page_table, interpret=cpu,
+                        ends[1], seq, page_table, interpret=interpret,
                         layers=(L, LP - L))
                 else:
                     for part, first, end in (
@@ -1062,8 +925,7 @@ class ModelRunner:
                  seq_lens, temperature, top_k, top_p, rng):
             logits, k_cache, v_cache = decode_forward(
                 params, spec, k_cache, v_cache, tokens, positions,
-                page_table, seq_lens, attention_impl=self._attention_impl,
-                experts_local=self.experts_local)
+                page_table, seq_lens, backends=self.backends)
             rng, sub = jax.random.split(rng)
             sampled = sample_tokens(logits, temperature, top_k, top_p, sub)
             return sampled, k_cache, v_cache, rng
@@ -1087,20 +949,10 @@ class ModelRunner:
         spec = self.spec
         page = self.config.page_size
         drafting = self.config.spec_decode == "mtp"
-        labels = {"attention_backend": self.attention_backend,
-                  "kv_commit_backend": self.kv_commit_backend,
-                  "page_size": self.config.page_size,
-                  **({"index_backend": self.index_backend}
-                     if self.index_backend else {}),
-                  # Who drafts inside this program's steps.
-                  "draft": "mtp" if drafting else "none",
-                  # A step's rows: every slot, and each verified position.
-                  **self._expert_product(self.config.max_num_seqs * (
-                      self.config.spec_k + 1 if drafting else 1)),
-                  **self._recurrent_labels(),
-                  # Who updates a recurrent layer's state in a step.
-                  **({"ssm_backend": self.ssm_backend}
-                     if spec.recurrent else {})}
+        # A step's rows: every slot, and each verified position.
+        labels = self.backends.labels("decode_window", expert_product(
+            self.config.max_num_seqs * (
+                self.config.spec_k + 1 if drafting else 1), self.backends))
         if drafting:
             # The same program under the same name and key: each of its
             # ``window`` steps is a draft and a verify (_get_mtp_window).
@@ -1168,19 +1020,17 @@ class ModelRunner:
                 live = (seq_lens0 > 0) & (positions < cap)
                 if recurrent:
                     from dynamo_tpu.engine import hybrid
-                    logits, k_new, v_new, state, *stats = hybrid.window_step(
+                    logits, k_new, v_new, state, counted = hybrid.window_step(
                         params, spec, k_cache, v_cache, kbuf, vbuf, m, tokens,
                         page_table, hist_lens, tuple(state), live,
-                        attention_impl=self._window_attention_impl,
-                        ssm_kernel=self.ssm_backend == "kernel")
+                        backends=self.backends)
                 else:
-                    logits, k_new, v_new, *stats = decode_window_step(
+                    logits, k_new, v_new, counted = decode_window_step(
                         params, spec, k_cache, v_cache, kbuf, vbuf, m, tokens,
                         positions, page_table, hist_lens,
-                        attention_impl=self._window_attention_impl,
-                        lora=lora, adapter_ids=adapter_ids,
-                        live=live if routed else None,
-                        experts_local=self.experts_local)
+                        backends=self.backends, lora=lora,
+                        adapter_ids=adapter_ids,
+                        live=live if routed else None)
                 # Append this step's K/V ([L,B,Nkv,D] -> window col m).
                 with perf.scope("kv.commit"):
                     kbuf = jax.lax.dynamic_update_slice(
@@ -1231,29 +1081,27 @@ class ModelRunner:
                     tokens = jnp.where(live, sampled, tokens)
                     positions = positions + live.astype(jnp.int32)
                 return (tokens, positions, kbuf, vbuf, rng, cnts, *state), (
-                    sampled, lp, top_v, top_i, *stats)
+                    sampled, lp, top_v, top_i, counted)
 
             carry0 = (tokens0, positions0, kbuf0, vbuf0, rng,
                       counts if penalized else jnp.zeros((), jnp.uint8),
                       *state)
             (tokens, _, kbuf, vbuf, rng, counts_out, *state), \
-                (toks, lps, top_vs, top_is, *stats) = \
+                (toks, lps, top_vs, top_is, counted) = \
                 jax.lax.scan(step, carry0, jnp.arange(window))
-            # [M, L, n] -> [n] under the name of what was counted, in the
-            # order decode_window_step returns them: a latent block's keys
-            # (2 sums), a routed block's load (model.moe_load_stats: 3
-            # sums, 5 for a told share). A block that counts nothing adds
-            # nothing to the program's outputs.
-            # A block with recurrent layers adds the live rows of every
-            # step ("ssm": 1 sum, the rows whose state a step had to touch).
-            names = (["attn"] if spec.latent else []) + (
-                ["moe"] if routed else []) + (["ssm"] if recurrent else [])
-            stats = {name: jnp.sum(a, axis=(0, 1))
-                     for name, a in zip(names, stats, strict=True)}
+            # [M, L, n] -> [n] under the key the step function counted it
+            # by (runtime/flight.py COUNTS has the columns): a latent
+            # block's keys ("attn": 2 sums), a routed block's load ("moe",
+            # model.moe_load_stats: 3 sums, 5 for a told share), the live
+            # rows of every step of a block with recurrent layers ("ssm": 1
+            # sum, the rows whose state a step had to touch). A block that
+            # counts nothing adds nothing to the program's outputs.
+            stats = {key: jnp.sum(a, axis=(0, 1))
+                     for key, a in counted.items()}
 
             # Commit the window: every (slot, step) entry goes to its page.
             with perf.scope("kv.commit"):
-                if self.kv_commit_backend == "in_place":
+                if self.backends.kv_commit == "in_place":
                     # Only the pages a live row's window touched are
                     # rewritten, where and how they lie: XLA's scatter
                     # converts the whole pool to its own layout and back,
@@ -1263,7 +1111,7 @@ class ModelRunner:
                     k_cache, v_cache = commit_window_pallas(
                         k_cache, v_cache, kbuf, vbuf, positions0, cap,
                         seq_lens0, page_table,
-                        interpret=self.device.platform == "cpu",
+                        interpret=self.backends.interpret,
                         # A pool with a prediction module's layer behind
                         # the model's, which this window leaves alone.
                         layers=(0, L) if spec.mtp_layers else None)
@@ -1680,8 +1528,8 @@ class ModelRunner:
             kw["state"] = (self.ssm_state, self.conv_state)
         fn = self._get_prefill(bucket, bp, with_history, penalized, seeded,
                                with_embeds)
-        if self._expert_product(bucket * bp).get(
-                "expert_product") == "grouped":
+        if self.spec.num_experts and expert_product(
+                bucket * bp, self.backends) == "grouped":
             self.moe_grouped_pairs += (bucket * bp
                                        * self.spec.num_experts_per_tok)
         # rest: a prediction module's (draft, page ends), or the two state
@@ -2163,10 +2011,9 @@ def _replicate_kv_heads(params, spec, rep: int):
 
 def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
                           page_table, seq_lens, hist_table, hist_lens,
-                          attention_impl, sp_shard: bool = False,
+                          backends, sp_shard: bool = False,
                           x_embeds=None, embeds_mask=None,
-                          lora=None, adapter_ids=None,
-                          experts_local: bool = False, defer: bool = False):
+                          lora=None, adapter_ids=None, defer: bool = False):
     """Chunked prefill: like prefill_forward but queries also attend to the
     sequence's earlier pages (read via the paged path). x_embeds/embeds_mask
     override token embeddings under multimodal media spans (rows are
@@ -2225,14 +2072,14 @@ def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
 
         x, k, v, _ = transformer_block(
             x, lp, spec, cos, sin, attend, layer_kind(spec, layer), ll,
-            adapter_ids, experts_local=experts_local)
+            adapter_ids, backends=backends)
         return x, (k, v)
 
     xs = ((params["layers"], jnp.arange(L), lora) if lora is not None
           else (params["layers"], jnp.arange(L)))
     x, (k_new, v_new) = scan_layers(
         layer_fn, x, xs, spec,
-        whole_experts=expert_product(b * s, experts_local) == "grouped")
+        whole_experts=expert_product(b * s, backends) == "grouped")
     with perf.scope("kv.commit"):
         heads, (dk, dv) = spec.kv_entry
         k_blocks = (k_new.reshape(L, b * (s // page), page, heads, dk)

@@ -98,6 +98,44 @@ FIELDS = ("t_mono", "dur_s", "active", "waiting", "free_pages",
 _INT_FIELDS = ("active", "waiting", "free_pages", "chunk_tokens",
                "chunks_inflight", "preempts", "brownout", "step", "tokens",
                "rows", "page_bucket", "missed", "prefilling", "admit_stop")
+#: What a window counted: the key its program returns a vector under (the
+#: step functions of engine/model.py and engine/hybrid.py name them; "spec"
+#: is the engine's own sums over a drafting window's rows) -> the ring's
+#: columns its entries are, in order, each with the /metrics counter that
+#: sums it (engine/perf.py registers them under these names) or None. The
+#: ONE statement of where a count lands: the engine fills a window's counts
+#: and its totals through ``columns_of``, ``record`` stores by column name,
+#: perf's exporter walks the table. A vector may be shorter than its
+#: columns ("moe" is [3] where the expert layer is not told its share).
+COUNTS = {
+    "moe": (("moe_touched", "moe_experts_touched_total"),
+            ("moe_load", "moe_expert_load_max_over_mean_total"),
+            ("moe_layer_steps", "moe_layer_steps_total"),
+            ("moe_local_picks", "moe_local_picks_total"),
+            ("moe_picks", "moe_picks_total")),
+    "attn": (("attn_selected", "attn_selected_total"),
+             ("attn_context", "attn_context_total")),
+    "ssm": (("ssm_row_steps", "ssm_row_steps_total"),),
+    "spec": (("spec_drafted", None), ("spec_accepted", None),
+             ("spec_row_steps", None)),
+}
+COUNT_COLUMNS = tuple(column for columns in COUNTS.values()
+                      for column, _ in columns)
+_COUNT_SET = frozenset(COUNT_COLUMNS)
+
+
+def columns_of(key: str, values) -> dict[str, float]:
+    """A window's vector under ``key`` as {column: value}, in the table's
+    order. Raises on a key the table lacks (KeyError) and on more entries
+    than the key has columns."""
+    columns = COUNTS[key]
+    values = np.asarray(values, np.float64).reshape(-1)
+    if len(values) > len(columns):
+        raise ValueError(f"{key!r} counts {len(values)} values, the table "
+                         f"has {len(columns)} columns for it")
+    return {column: float(v) for (column, _), v in zip(columns, values)}
+
+
 #: Why TPUEngine._admit ended with requests still queued, and the bit each
 #: cause sets in "admit_stop" (the label values of
 #: ``engine_admit_stops_total{cause}``).
@@ -147,18 +185,19 @@ class FlightRecorder:
                step: int, tokens: int = 0, period_s: float = 0.0,
                host_s: float = 0.0, wait_s: float = 0.0,
                idle_s: float = 0.0, rows: int = 0,
-               page_bucket: int = 0, moe_touched: float = 0.0,
-               moe_load: float = 0.0, moe_layer_steps: float = 0.0,
-               moe_local_picks: float = 0.0, moe_picks: float = 0.0,
-               attn_selected: float = 0.0, attn_context: float = 0.0,
-               prefilling: int = 0, admit_stop: int = 0,
-               spec_drafted: int = 0, spec_accepted: int = 0,
-               spec_row_steps: int = 0, ssm_row_steps: float = 0.0) -> bool:
-        """One engine-window row. Idle-stable windows (no active slots,
+               page_bucket: int = 0, prefilling: int = 0,
+               admit_stop: int = 0, counts: dict | None = None) -> bool:
+        """One engine-window row. ``counts``: what the window counted, by
+        column name (COUNT_COLUMNS; ``columns_of`` makes them of a
+        program's vectors); a column it does not name is 0, a name that is
+        no such column is an error. Idle-stable windows (no active slots,
         no waiters, no chunk work — same as the previous call) are
         skipped without touching the ring. Returns False when the row
         was REJECTED (disabled / frozen mid-capture) so the caller
         keeps accumulating its deltas instead of losing them."""
+        if counts and not counts.keys() <= _COUNT_SET:
+            raise KeyError(f"no count column named "
+                           f"{sorted(counts.keys() - _COUNT_SET)}")
         if not self.enabled:
             return False
         if self.frozen:
@@ -194,19 +233,13 @@ class FlightRecorder:
             cols["idle_s"][i] = idle_s
             cols["rows"][i] = rows
             cols["page_bucket"][i] = page_bucket
-            cols["moe_touched"][i] = moe_touched
-            cols["moe_load"][i] = moe_load
-            cols["moe_layer_steps"][i] = moe_layer_steps
-            cols["moe_local_picks"][i] = moe_local_picks
-            cols["moe_picks"][i] = moe_picks
-            cols["attn_selected"][i] = attn_selected
-            cols["attn_context"][i] = attn_context
             cols["prefilling"][i] = prefilling
             cols["admit_stop"][i] = admit_stop
-            cols["spec_drafted"][i] = spec_drafted
-            cols["spec_accepted"][i] = spec_accepted
-            cols["spec_row_steps"][i] = spec_row_steps
-            cols["ssm_row_steps"][i] = ssm_row_steps
+            for name in COUNT_COLUMNS:
+                cols[name][i] = 0.0
+            if counts:
+                for name, value in counts.items():
+                    cols[name][i] = value
             cols["missed"][i] = self._missed[0]
             self._missed[0] = 0
             self._idx = (i + 1) % self.capacity

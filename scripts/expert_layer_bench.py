@@ -40,6 +40,7 @@ import jax.numpy as jnp                                       # noqa: E402
 import numpy as np                                            # noqa: E402
 
 from dynamo_tpu.engine import experts, model                  # noqa: E402
+from dynamo_tpu.engine.backends import XLA, Backends          # noqa: E402
 from dynamo_tpu.engine.config import (                        # noqa: E402
     Cohere2MoeSpec, SmallThinkerSpec)
 from dynamo_tpu.engine.quant import QTensor                   # noqa: E402
@@ -111,9 +112,9 @@ def timed(fn, *args, loops=3):
     return best * 1e3, out
 
 
-def products(spec, lp, x, local):
+def products(spec, lp, x, backends):
     f = jax.jit(lambda x, lp: model.ffn_block(x, lp, spec, router_in=x,
-                                              experts_local=local))
+                                              backends=backends))
     try:
         return timed(f, x, lp)
     except Exception as e:  # noqa: BLE001 -- out of memory at a size: a hole in the table
@@ -121,7 +122,7 @@ def products(spec, lp, x, local):
         return None, None
 
 
-def scanned(spec, lps, x, local, whole):
+def scanned(spec, lps, x, backends, whole):
     """Milliseconds a layer of ``model.scan_layers`` over the stacked
     layers ``lps`` (as a served program runs them), the experts sliced a
     layer or handed whole."""
@@ -129,7 +130,7 @@ def scanned(spec, lps, x, local, whole):
 
     def body(x, lp):
         return x + model.ffn_block(x, lp, spec, router_in=x,
-                                   experts_local=local), None
+                                   backends=backends), None
 
     f = jax.jit(lambda x, lps: model.scan_layers(
         body, x, lps, spec, whole_experts=whole)[0])
@@ -188,7 +189,9 @@ def main() -> int:
     real_route = model.moe_route
     # The threshold is what is being measured: either product at every size.
     model.MOE_DENSE_MAX_ROWS = 0
-    local = "interpret" if dev.platform == "cpu" else True
+    # The record of a runner on one device: its experts are whole (the
+    # CPU interprets the kernel); XLA: the masked product at every size.
+    local = Backends(experts_whole=True, interpret=dev.platform == "cpu")
     with open(args.out, "a") as sink:
         def say(line):
             text = json.dumps({**line, "device": device})
@@ -211,7 +214,7 @@ def main() -> int:
                                           jnp.bfloat16)
                     line = {"geometry": name, "routing": routing,
                             "rows": rows}
-                    masked_ms, a = products(spec, lp, x, False)
+                    masked_ms, a = products(spec, lp, x, XLA)
                     grouped_ms, b = products(spec, lp, x, local)
                     line["masked_ms"] = masked_ms and round(masked_ms, 4)
                     line["grouped_ms"] = grouped_ms and round(grouped_ms, 4)
@@ -221,9 +224,9 @@ def main() -> int:
                         line["max_diff"] = float(np.abs(a - b).max())
                     if routing == "random" and grouped_ms is not None:
                         line.update(kernel_calls(spec, lp, x,
-                                                 local == "interpret"))
+                                                 local.interpret))
                     if args.layers:
-                        line["scan_masked_ms"] = scanned(spec, lps, x, False,
+                        line["scan_masked_ms"] = scanned(spec, lps, x, XLA,
                                                          False)
                         line["scan_sliced_ms"] = scanned(spec, lps, x, local,
                                                          False)
@@ -248,7 +251,7 @@ def main() -> int:
                         line[f"grouped_ms@tn{tn}"] = ms and round(ms, 4)
                         if ms is not None and routing == "random":
                             line[f"gate_up_ms@tn{tn}"] = kernel_calls(
-                                spec, lp, x, local == "interpret")[
+                                spec, lp, x, local.interpret)[
                                     "gate_up_ms"]
                         experts.out_tile = keep
                         jax.clear_caches()

@@ -419,13 +419,12 @@ def test_latent_reader_matches_the_xla_walk(table, form, m, weights):
     caller has; the attention and the counts [attended, in context]. The
     indexer's kernel scores the pool's index keys where the table can hold
     more than index_topk keys; under it no indexer runs."""
-    import functools
-
     import jax
 
     from dynamo_tpu.engine import model
     from dynamo_tpu.engine.attention import (latent_history_pallas,
                                              latent_index_pallas)
+    from dynamo_tpu.engine.backends import XLA, Backends
     from dynamo_tpu.engine.config import DeepseekV32Spec
     from dynamo_tpu.engine.quant import quantize_weight
     spec = DeepseekV32Spec(
@@ -469,15 +468,18 @@ def test_latent_reader_matches_the_xla_walk(table, form, m, weights):
             normal(b, 1, 128))
     live = jnp.asarray([False, True, True, True])
 
-    def run(kernels):
+    def run(record):
         return jax.jit(lambda *a: model.latent_window_attention(
-            *a, spec, live, kernels=kernels))(*args)
+            *a, spec, live, backends=record))(*args)
 
-    want, want_counts = run(None)
-    # As the runner binds them: one kernel for every table up to 48 pages.
-    bound = dict(interpret=True, table=48)
-    got, got_counts = run((functools.partial(latent_history_pallas, **bound),
-                           functools.partial(latent_index_pallas, **bound)))
+    want, want_counts = run(XLA)
+    # As the runner's record binds them: one kernel for every table up to
+    # 48 pages.
+    record = Backends(attention="pallas", interpret=True, table=48)
+    reader, indexer = record.latent_readers()
+    assert (reader.func, indexer.func) == (latent_history_pallas,
+                                           latent_index_pallas)
+    got, got_counts = run(record)
     np.testing.assert_array_equal(np.asarray(got_counts),
                                   np.asarray(want_counts))
     context = sum(hist_lens[1:]) + 3 * (m + 1)

@@ -100,7 +100,7 @@ async def test_chunked_whole_prompt_parity_greedy_and_seeded(
     programs' orders of summation (up to 0.03 nat here), and over this
     model's nearly flat distribution a draw at temperature 0.9 then lands
     on the other side of a boundary about once in thirty tokens (seeds 11,
-    15 and 23 of 11 to 30 part at tokens 7, 4 and 6: ROADMAP D1). So a
+    15 and 23 of 11 to 30 part at tokens 7, 4 and 6). So a
     seeded pair is held to its common prefix: keys that differed would
     part at the first token of every seed, rounding parts few and late."""
     p_greedy = _prompt(5, 150)

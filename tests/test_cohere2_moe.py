@@ -28,8 +28,7 @@ from test_smallthinker import distance
 
 from benchmark.references import cohere2_moe as ref
 from dynamo_tpu.engine import model, runner as runner_mod
-from dynamo_tpu.engine.attention import (paged_decode_attention_pallas,
-                                         paged_window_attention_pallas)
+from dynamo_tpu.engine.backends import XLA, Backends
 from dynamo_tpu.engine.config import (PRESETS, Cohere2MoeSpec, EngineConfig,
                                       ModelSpec, UnsupportedBlockError,
                                       block_refusals)
@@ -192,13 +191,10 @@ def served_logits(spec, params, tokens, backend: str) -> np.ndarray:
     kv = jnp.zeros((spec.num_layers, spec.num_kv_heads, b * pages + 1, PAGE,
                     spec.head_dim), jnp.bfloat16)
     table = (1 + np.arange(b * pages, dtype=np.int32)).reshape(b, pages)
-    if backend == "xla":
-        step_attn, window_attn = None, None
-    else:
-        step_attn = functools.partial(paged_decode_attention_pallas,
-                                      interpret=True)
-        window_attn = functools.partial(paged_window_attention_pallas,
-                                        interpret=True)
+    # The record a runner would hand the programs: XLA's, or the kernels
+    # interpreted.
+    record = XLA if backend == "xla" else Backends(attention="pallas",
+                                                   interpret=True)
     rows = []
     pos = np.broadcast_to(np.arange(FIRST, dtype=np.int32), (b, FIRST))
     lens = np.full((b,), FIRST, np.int32)
@@ -211,7 +207,7 @@ def served_logits(spec, params, tokens, backend: str) -> np.ndarray:
         p, spec, k, v, tokens[:, done:done + CHUNK], pos + done,
         table[:, done // PAGE:(done + CHUNK) // PAGE],
         np.full((b,), CHUNK, np.int32), table[:, :done // PAGE],
-        np.full((b,), done, np.int32), step_attn))(params, k, v)
+        np.full((b,), done, np.int32), record))(params, k, v)
     rows.append(logits)
     done += CHUNK
 
@@ -225,7 +221,7 @@ def served_logits(spec, params, tokens, backend: str) -> np.ndarray:
             logits, k_new, v_new, stats = model.decode_window_step(
                 p, spec, k, v, kbuf, vbuf, jnp.int32(m),
                 tokens[:, done + m], hist + m, table, hist,
-                attention_impl=window_attn, live=jnp.ones((b,), bool))
+                backends=record, live=jnp.ones((b,), bool))
             kbuf = kbuf.at[:, :, :, m].set(k_new.transpose(0, 2, 1, 3))
             vbuf = vbuf.at[:, :, :, m].set(v_new.transpose(0, 2, 1, 3))
             out.append(logits)
@@ -239,7 +235,7 @@ def served_logits(spec, params, tokens, backend: str) -> np.ndarray:
     logits, k, v, stats = jax.jit(window)(params, k, v)
     rows += list(logits)
     done += WINDOW
-    stats = np.asarray(stats)                               # [L, 5]
+    stats = np.asarray(stats["moe"])                        # [L, 5]
     k_tok = spec.num_experts_per_tok
     assert stats.shape == (spec.num_layers, 5) and (stats[:, 2] == 1).all()
     # Touched and the picks are counted over the experts HELD.
@@ -248,7 +244,7 @@ def served_logits(spec, params, tokens, backend: str) -> np.ndarray:
     assert (stats[:, 3] <= stats[:, 4]).all() and stats[:, 3].sum() > 0
     assert (stats[:, 0] <= stats[:, 3]).all()
     decode = jax.jit(lambda p, k, v, t, at: model.decode_forward(
-        p, spec, k, v, t, at, table, at + 1, attention_impl=step_attn))
+        p, spec, k, v, t, at, table, at + 1, backends=record))
     while done < SEQ:
         logits, k, v = decode(params, k, v, tokens[:, done],
                               np.full((b,), done, np.int32))
@@ -464,7 +460,8 @@ def test_a_long_batch_of_a_share_takes_the_kernel_by_its_own_pairs(
     for limit in (32, 10 ** 9):
         monkeypatch.setattr(model, "MOE_DENSE_MAX_ROWS", limit)
         outs.append(np.asarray(jax.jit(lambda x: model.ffn_block(
-            x, lp, spec, experts_local="interpret"))(x), np.float32))
+            x, lp, spec, backends=Backends(
+                experts_whole=True, interpret=True)))(x), np.float32))
         assert len(calls) == 1          # taken above the threshold alone
     assert np.abs(outs[1]).mean() > 0.1
     np.testing.assert_allclose(outs[0], outs[1], atol=0.02)
@@ -490,7 +487,7 @@ def test_history_attention_a_kv_head_at_a_time_is_the_same(monkeypatch):
             p, spec, k, v, tokens[:, FIRST:FIRST + CHUNK], pos + FIRST,
             table[:, FIRST // PAGE:(FIRST + CHUNK) // PAGE],
             np.full((b,), CHUNK, np.int32), table[:, :FIRST // PAGE],
-            np.full((b,), FIRST, np.int32), None))(params, k, v)
+            np.full((b,), FIRST, np.int32), XLA))(params, k, v)
         got.append(np.asarray(logits, np.float32))
     np.testing.assert_allclose(got[0], got[1], atol=2e-2)
     assert np.abs(got[0]).mean() > 0.1
@@ -572,7 +569,8 @@ async def test_the_engine_serves_the_share_and_counts_its_picks():
         a, b = await asyncio.gather(generate(20, 21), generate(12, 13))
         assert (len(a), len(b)) == (21, 13)
         await asyncio.sleep(0.05)
-        touched, load, n, local, picks = engine.moe_totals
+        touched, load, n, local, picks = (
+            engine.counts_total[c] for c, _ in flight.COUNTS["moe"])
         assert n > 0 and n % spec.num_layers == 0
         # 1 or 2 live rows of 3 choices among 8, of which 4 are held.
         assert 3 * n <= picks <= 6 * n and 0 < local < picks
@@ -616,7 +614,7 @@ def test_no_other_block_carries_the_share_s_counters_or_scope():
             live=jnp.ones((b,), bool))).lower(params, kv, kv)
         assert jax.eval_shape(lambda p, k, v: model.decode_window_step(
             p, spec, k, v, kbuf, kbuf, jnp.int32(0), at, at, table, at,
-            live=jnp.ones((b,), bool)), params, kv, kv)[3].shape == (
+            live=jnp.ones((b,), bool)), params, kv, kv)[3]["moe"].shape == (
             spec.num_layers, width)
         text = lowered.as_text(debug_info=True)
         assert ("moe.shared" in text) == shared

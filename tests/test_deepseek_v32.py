@@ -31,6 +31,7 @@ from test_smallthinker import distance
 
 from benchmark.references import deepseek_v32 as ref
 from dynamo_tpu.engine import model
+from dynamo_tpu.engine.backends import XLA, Backends, choose, pallas_refusal
 from dynamo_tpu.engine.config import (DeepseekV32Spec, EngineConfig,
                                       ModelSpec, UnsupportedBlockError,
                                       block_refusals, pool_access)
@@ -279,20 +280,20 @@ def test_who_reads_and_writes_a_latent_pool(backend, platform, mesh,
                            latent=True) == want
 
 
-def _bare_runner(spec, mesh=1, quant_kv=None, platform="cpu", **config):
+def _bare_config(**config):
     from types import SimpleNamespace
-    runner = object.__new__(ModelRunner)
-    runner.spec = spec
-    runner.config = SimpleNamespace(page_size=4, max_pages_per_seq=128,
-                                    attention_backend="auto", **config)
-    runner.mesh = SimpleNamespace(size=mesh)
-    runner.device = SimpleNamespace(platform=platform)
-    runner.quant_kv = quant_kv
-    return runner
+    return SimpleNamespace(**{**dict(
+        page_size=4, max_pages_per_seq=128, attention_backend="auto",
+        spec_decode=None), **config})
+
+
+def _chosen(spec, mesh=1, quant_kv=None, platform="cpu", **config):
+    """backends.choose for a runner of ``spec`` that observes this."""
+    return choose(_bare_config(**config), spec, platform, mesh, quant_kv)
 
 
 def test_the_runner_takes_the_kernel_for_a_latent_pool_where_it_serves():
-    """_pallas_refusal has no sentence left for a latent pool on one device
+    """pallas_refusal has no sentence left for a latent pool on one device
     (whatever the heads' width: 24 at the toy, 192 as published), and keeps
     one each for a mesh and for int8 pages; a requested "pallas" runs
     interpreted on the CPU, one reader for the decode step and the window;
@@ -300,46 +301,41 @@ def test_the_runner_takes_the_kernel_for_a_latent_pool_where_it_serves():
     from dynamo_tpu.engine.attention import (latent_history_pallas,
                                              latent_index_pallas)
     spec = read_spec(TOY)
-    assert _bare_runner(spec)._pallas_refusal() is None
-    assert "one device" in _bare_runner(spec, mesh=2)._pallas_refusal()
-    assert "int8 latent pages" in _bare_runner(
-        spec, quant_kv="int8")._pallas_refusal()
+    assert pallas_refusal(spec, 4, 1, None) is None
+    assert "one device" in pallas_refusal(spec, 4, 2, None)
+    assert "int8 latent pages" in pallas_refusal(spec, 4, 1, "int8")
     # A K-and-V pool of the same head width is still refused by its width.
     kv = ModelSpec(head_dim=spec.head_dim, num_heads=4, num_kv_heads=4,
                    hidden_size=96)
     assert spec.head_dim == 24
-    assert "head_dim" in _bare_runner(kv)._pallas_refusal()
+    assert "head_dim" in pallas_refusal(kv, 4, 1, None)
     runner = ModelRunner(EngineConfig(model=spec, page_size=4, num_pages=16,
                                       attention_backend="pallas"))
     assert runner.attention_backend == "pallas"
-    assert runner.kv_commit_backend == "scatter"    # the CPU
-    assert runner._attention_impl is runner._window_attention_impl
+    assert runner.backends.kv_commit == "scatter"    # the CPU
     # The reader of the entries and the indexer over the index keys come
-    # together: whoever walks the one walks the other.
-    reader, indexer = runner._attention_impl
+    # together, one pair for the decode step and the window: whoever walks
+    # the one walks the other.
+    reader, indexer = runner.backends.latent_readers()
     assert reader.func is latent_history_pallas
     assert indexer.func is latent_index_pallas
-    assert runner.index_backend == "pallas"
+    assert runner.backends.index == "pallas"
     # ... and one kernel each for every page-table bucket: the table's limit.
     assert reader.keywords == indexer.keywords == {
         "interpret": True, "table": runner.config.max_pages_per_seq}
-    on_tpu = _bare_runner(spec, platform="tpu")
-    for bound in on_tpu._pick_attention()[0]:
+    on_tpu = _chosen(spec, platform="tpu")
+    for bound in on_tpu.latent_readers():
         assert bound.keywords == {"interpret": False, "table": 128}
-    assert (on_tpu.attention_backend, on_tpu.index_backend,
-            on_tpu._pick_kv_commit()) == ("pallas", "pallas", "in_place")
-    for elsewhere in (_bare_runner(spec), _bare_runner(spec, mesh=4,
-                                                       platform="tpu")):
-        assert elsewhere._pick_attention() == (None, None)
-        assert (elsewhere.attention_backend, elsewhere.index_backend,
-                elsewhere._pick_kv_commit()) == ("xla", "xla", "scatter")
+    assert (on_tpu.attention, on_tpu.index, on_tpu.kv_commit) == (
+        "pallas", "pallas", "in_place")
+    for elsewhere in (_chosen(spec), _chosen(spec, mesh=4, platform="tpu")):
+        assert elsewhere.latent_readers() == (None, None)
+        assert (elsewhere.attention, elsewhere.index,
+                elsewhere.kv_commit) == ("xla", "xla", "scatter")
     # A block without an indexer has no such label.
-    kv_runner = _bare_runner(ModelSpec(head_dim=128, num_heads=4,
-                                       num_kv_heads=4, hidden_size=512),
-                             platform="tpu")
-    kv_runner._pick_attention()
-    assert (kv_runner.attention_backend, kv_runner.index_backend) == (
-        "pallas", None)
+    kv = _chosen(ModelSpec(head_dim=128, num_heads=4, num_kv_heads=4,
+                           hidden_size=512), platform="tpu")
+    assert (kv.attention, kv.index) == ("pallas", None)
 
 
 def test_a_bucket_that_xla_gathers_whole_grows_by_1024_tokens():
@@ -360,9 +356,10 @@ def test_a_bucket_that_xla_gathers_whole_grows_by_1024_tokens():
             for n in (100, 129, 193, 400)] == [128, 192, 256, 448]
     spec = read_spec(TOY)
     for platform, want in (("tpu", powers), ("cpu", steps)):
-        runner = _bare_runner(spec, platform=platform)
-        runner.config.page_size = 64
-        runner._pick_attention()
+        runner = object.__new__(ModelRunner)
+        runner.config = _bare_config(page_size=64)
+        runner.attention_backend = choose(runner.config, spec, platform, 1,
+                                          None).attention
         assert [runner.bucket_pages_for(n) for n in needs] == want
 
 
@@ -499,14 +496,14 @@ def pools(spec, pages: int):
             jnp.zeros((*shape, dv), jnp.bfloat16))
 
 
-def served_logits(spec, params, tokens, reader=None
+def served_logits(spec, params, tokens, record=XLA
                   ) -> tuple[np.ndarray, np.ndarray]:
     """Logits the program gives after positions FIRST-1 (whole-prompt
     prefill), FIRST+CHUNK-1 (chunk prefill over cached history), then one
     row a decoded position: WINDOW steps of the window program with its
     commit, the rest by the single decode step; [B, rows, V]. And the
-    window's counts [L, 2] of its last step. ``reader``: who reads the
-    entries in the window and the step (None: XLA's walk)."""
+    window's counts [L, 2] of its last step. ``record``: who reads the
+    entries in the window and the step (XLA: XLA's walk)."""
     b = tokens.shape[0]
     pages = SEQ // PAGE
     k, v = pools(spec, b * pages + 1)
@@ -523,7 +520,7 @@ def served_logits(spec, params, tokens, reader=None
         p, spec, k, v, tokens[:, done:done + CHUNK], pos + done,
         table[:, done // PAGE:(done + CHUNK) // PAGE],
         np.full((b,), CHUNK, np.int32), table[:, :done // PAGE],
-        np.full((b,), done, np.int32), None))(params, k, v)
+        np.full((b,), done, np.int32), record))(params, k, v)
     rows.append(logits)
     done += CHUNK
 
@@ -535,10 +532,11 @@ def served_logits(spec, params, tokens, reader=None
         hist = jnp.full((b,), done, jnp.int32)
         out = []
         for m in range(WINDOW):
-            logits, k_new, v_new, counts, stats = model.decode_window_step(
+            logits, k_new, v_new, counted = model.decode_window_step(
                 p, spec, k, v, kbuf, vbuf, jnp.int32(m),
                 tokens[:, done + m], hist + m, table, hist,
-                attention_impl=reader, live=jnp.ones((b,), bool))
+                backends=record, live=jnp.ones((b,), bool))
+            counts, stats = counted["attn"], counted["moe"]
             kbuf = kbuf.at[:, :, :, m].set(k_new.transpose(0, 2, 1, 3))
             vbuf = vbuf.at[:, :, :, m].set(v_new.transpose(0, 2, 1, 3))
             out.append(logits)
@@ -556,7 +554,7 @@ def served_logits(spec, params, tokens, reader=None
     assert stats.shape == (spec.num_layers - spec.first_k_dense, 5)
     assert (np.asarray(stats)[:, 4] == b * spec.num_experts_per_tok).all()
     decode = jax.jit(lambda p, k, v, t, at: model.decode_forward(
-        p, spec, k, v, t, at, table, at + 1, attention_impl=reader))
+        p, spec, k, v, t, at, table, at + 1, backends=record))
     while done < SEQ:
         logits, k, v = decode(params, k, v, tokens[:, done],
                               np.full((b,), done, np.int32))
@@ -602,16 +600,12 @@ def test_prefill_then_decode_agrees_with_the_reference_with_selection_in_force(
     """Under either side of config.pool_access: XLA's walk and XLA's
     indexer; the Pallas kernels (interpreted here), the indexer's scores
     under the same choice."""
-    from dynamo_tpu.engine.attention import (latent_history_pallas,
-                                             latent_index_pallas)
     spec, params, tokens = toy(quant)
     assert FIRST < spec.index_topk == 40 < FIRST + CHUNK
     assert spec.first_k_dense == 1
-    impl = None
-    if reader != "xla":
-        impl = (functools.partial(latent_history_pallas, interpret=True),
-                functools.partial(latent_index_pallas, interpret=True))
-    served, counts = served_logits(spec, params, tokens, impl)
+    record = XLA if reader == "xla" else Backends(attention="pallas",
+                                                  interpret=True)
+    served, counts = served_logits(spec, params, tokens, record)
     full = reference_logits(spec, params, tokens)
     assert served.shape == full.shape == (2, 2 + SEQ - FIRST - CHUNK,
                                           spec.vocab_size)
@@ -915,7 +909,7 @@ async def test_the_engine_serves_it_counts_its_keys_and_reuses_a_prefix():
         status = engine.perf_status()
         # Who runs the indexer (the CPU under "auto": XLA's), on the pane,
         # among the window programs' labels and as an info series.
-        assert status["index_backend"] == engine.runner.index_backend \
+        assert status["index_backend"] == engine.runner.backends.index \
             == status["attention_backend"] == "xla"
         assert "xla" in perf.get_registry().snapshot()["programs"][
             "decode_window"]["labels"]["index_backend"]

@@ -23,7 +23,7 @@ from dynamo_tpu.engine.model import (
     prefill_forward,
 )
 from dynamo_tpu.engine.runner import _prefill_with_history
-from dynamo_tpu.engine.model import paged_decode_attention_xla
+from dynamo_tpu.engine.backends import XLA
 from dynamo_tpu.engine.sampler import sample_tokens
 from dynamo_tpu.llm.protocols import PreprocessedRequest
 from dynamo_tpu.runtime.context import Context
@@ -35,7 +35,7 @@ PAGE = 16
 _prefill_jit = jax.jit(lambda p, k, v, t, pos, pt, sl: prefill_forward(
     p, SPEC, k, v, t, pos, pt, sl))
 _decode_jit = jax.jit(lambda p, k, v, t, pos, pt, sl: decode_forward(
-    p, SPEC, k, v, t, pos, pt, sl, attention_impl=paged_decode_attention_xla))
+    p, SPEC, k, v, t, pos, pt, sl, backends=XLA))
 
 
 def tiny_config(**kw) -> EngineConfig:
@@ -138,8 +138,7 @@ def test_chunked_prefill_with_history_matches_dense(params):
     logits, k, v = _prefill_with_history(
         params, SPEC, k, v, jnp.asarray(tok2), jnp.asarray(pos2),
         jnp.asarray([[2]], np.int32), jnp.asarray([16], np.int32),
-        jnp.asarray(htab), jnp.asarray([32], np.int32),
-        paged_decode_attention_xla)
+        jnp.asarray(htab), jnp.asarray([32], np.int32), XLA)
     ref = dense_logits(params, prompt)
     np.testing.assert_allclose(np.asarray(logits[0]), ref, atol=0.15, rtol=0.05)
 

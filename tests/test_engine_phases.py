@@ -8,6 +8,7 @@ import asyncio
 import gc
 import time
 import tracemalloc
+from types import SimpleNamespace
 
 import aiohttp
 import numpy as np
@@ -164,6 +165,77 @@ def test_flight_record_with_the_new_columns_retains_nothing():
     assert last["prefilling"] == 2 and last["admit_stop"] == 5
     rec.freeze("x")
     assert _retained_in("flight.py", hot) <= 0  # the refusing path too
+
+
+# -- what a window counted: one table, stored by name -----------------------------
+
+@pytest.mark.parametrize("key, values, want", [
+    # A told share's five land in the table's order, a plain router's three
+    # in the first three; every other count column stays 0.
+    ("moe", [11.0, 2.5, 6.0, 40.0, 72.0], {
+        "moe_touched": 11.0, "moe_load": 2.5, "moe_layer_steps": 6.0,
+        "moe_local_picks": 40.0, "moe_picks": 72.0}),
+    ("moe", [11.0, 2.5, 6.0], {
+        "moe_touched": 11.0, "moe_load": 2.5, "moe_layer_steps": 6.0}),
+    ("attn", [80.0, 104.0], {"attn_selected": 80.0, "attn_context": 104.0}),
+    ("ssm", [[3.0]], {"ssm_row_steps": 3.0}),
+    ("spec", (5, 2, 7), {"spec_drafted": 5.0, "spec_accepted": 2.0,
+                         "spec_row_steps": 7.0}),
+    ("moe", [1.0] * 6, ValueError),     # more values than columns
+    ("emit", [1.0], KeyError),          # no such key in the table
+])
+def test_a_window_s_counts_land_in_the_table_s_columns(key, values, want):
+    rec = flight.FlightRecorder(capacity=4)
+    if isinstance(want, type):
+        with pytest.raises(want):
+            flight.columns_of(key, values)
+        return
+    counts = flight.columns_of(key, values)
+    assert counts == want and list(counts) == [
+        column for column, _ in flight.COUNTS[key]][:len(counts)]
+    assert _row(rec, 1.0, counts=counts) and _row(rec, 2.0)
+    first, second = rec.dump()
+    for column in flight.COUNT_COLUMNS:
+        assert first[column] == want.get(column, 0.0), column
+        assert second[column] == 0.0        # a row that names none
+    # Columns outside the table are not counts: record takes them itself.
+    assert not {"rows", "prefilling", "admit_stop"} & set(
+        flight.COUNT_COLUMNS)
+    with pytest.raises(KeyError, match="no count column named"):
+        _row(rec, 3.0, counts={**counts, "moe_touchd": 1.0})
+    with pytest.raises(TypeError):
+        _row(rec, 3.0, moe_touched=1.0)     # the twelve keywords are gone
+    assert len(rec.dump()) == 2             # a refused row stores nothing
+
+
+def test_the_table_s_counters_are_the_exporter_s():
+    """Every column the table gives a /metrics counter is exported under
+    that name with that column's total; the engine's own ``spec`` sums have
+    none (perf_spec_* follow the engine's accounting)."""
+    from dynamo_tpu.runtime.metrics import MetricsRegistry
+    registry = MetricsRegistry()
+    updater = perf.PerfMetricsUpdater(registry, min_interval_s=0.0)
+    totals = {column: float(i + 1)
+              for i, column in enumerate(flight.COUNT_COLUMNS)}
+    spec = SimpleNamespace(router_width=16, num_experts=4,
+                           num_shared_experts=1)
+    updater.update(SimpleNamespace(counts_total=totals,
+                                   runner=SimpleNamespace(spec=spec)),
+                   force=True)
+    assert updater.g_moe_experts.get(kind="held") == 4  # picks were counted
+    text = registry.expose().decode()
+    named = [(column, metric) for columns in flight.COUNTS.values()
+             for column, metric in columns]
+    assert [c for c, _ in named] == list(flight.COUNT_COLUMNS)
+    assert set(updater.c_counts) == {c for c, m in named if m}
+    assert {c for c, m in named if not m} == {
+        "spec_drafted", "spec_accepted", "spec_row_steps"}
+    for column, metric in named:
+        if metric:
+            line, = [ln for ln in text.splitlines()
+                     if ln.startswith(f"dynamo_tpu_{metric}" + "{")
+                     or ln.startswith(f"dynamo_tpu_{metric} ")]
+            assert float(line.rsplit(" ", 1)[1]) == totals[column], line
 
 
 # -- the engine: rows, spans, phases on the profiler's clock ---------------------------

@@ -19,8 +19,8 @@ import pytest
 
 from dynamo_tpu.engine.config import EngineConfig, ModelSpec
 from dynamo_tpu.engine.engine import TPUEngine
-from dynamo_tpu.engine.model import (
-    decode_forward, prefill_forward, paged_decode_attention_xla)
+from dynamo_tpu.engine.backends import XLA
+from dynamo_tpu.engine.model import decode_forward, prefill_forward
 from dynamo_tpu.engine.weights import load_hf_weights
 from dynamo_tpu.llm.protocols import PreprocessedRequest
 from dynamo_tpu.runtime.context import Context
@@ -83,8 +83,7 @@ def _our_stepwise_logits(spec, params, tokens):
                                                           np.int32))
     out = [np.asarray(logits[0], np.float32)]
     decode = jax.jit(lambda p, k, v, t, po, pt, sl: decode_forward(
-        p, spec, k, v, t, po, pt, sl,
-        attention_impl=paged_decode_attention_xla))
+        p, spec, k, v, t, po, pt, sl, backends=XLA))
     page_table = np.zeros((1, 8), np.int32)
     page_table[0, :4] = [1, 2, 3, 4]
     for i in range(n_prefill, len(tokens)):

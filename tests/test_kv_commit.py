@@ -7,9 +7,10 @@ does not land to scratch page 0. Interpret mode on the CPU checks results,
 bit for bit on every page but page 0; tests/test_tpu_compile.py compiles
 the kernel for a described v5e and reads the window program's text for
 pool-sized operations. Which program takes which commit is the runner's
-observation (ModelRunner._pick_kv_commit), checked here too.
+observation (backends.choose), checked here too.
 """
 
+import dataclasses
 from types import SimpleNamespace
 
 import jax
@@ -18,7 +19,9 @@ import numpy as np
 import pytest
 
 from dynamo_tpu.engine.attention import commit_window_pallas, window_pages
-from dynamo_tpu.engine.config import PRESETS, EngineConfig, ModelSpec
+from dynamo_tpu.engine.backends import choose
+from dynamo_tpu.engine.config import (PRESETS, EngineConfig, ModelSpec,
+                                      pool_access)
 from dynamo_tpu.engine.kv_quant import scatter_tokens, window_token_slots
 from dynamo_tpu.engine.runner import (PK_CAP, PK_OVERRIDE, PK_POS, PK_PREFIX,
                                       PK_SEQLEN, PK_TOKEN, ModelRunner)
@@ -197,9 +200,11 @@ def _window_of(runner, window):
 def test_run_window_in_place_gives_the_scatter_s_tokens_and_pool(window,
                                                                   page):
     in_place = _runner(attention_backend="pallas", page_size=page)
-    assert in_place.kv_commit_backend == "in_place"
+    assert in_place.backends.kv_commit == "in_place"
     scatter = _runner(attention_backend="pallas", page_size=page)
-    scatter.kv_commit_backend = "scatter"  # steer the twin: same reader
+    # Steer the twin: same reader.
+    scatter.backends = dataclasses.replace(scatter.backends,
+                                           kv_commit="scatter")
     toks_a, k_a, v_a = _window_of(in_place, window)
     toks_b, k_b, v_b = _window_of(scatter, window)
     np.testing.assert_array_equal(toks_a, toks_b)
@@ -219,13 +224,12 @@ def _window_args(runner):
 # -- which program takes which commit ----------------------------------------
 
 def _picked(attention_backend, mesh_size, head_dim, quant_kv):
-    runner = object.__new__(ModelRunner)
-    runner.attention_backend = attention_backend
-    runner.device = SimpleNamespace(platform="tpu")
-    runner.mesh = SimpleNamespace(size=mesh_size)
-    runner.spec = SimpleNamespace(head_dim=head_dim, latent=False)
-    runner.quant_kv = quant_kv
-    return runner._pick_kv_commit()
+    config = SimpleNamespace(attention_backend=attention_backend,
+                             page_size=PAGE, max_pages_per_seq=8,
+                             spec_decode=None)
+    spec = SimpleNamespace(head_dim=head_dim, latent=False, recurrent=False,
+                           index_topk=0, num_experts=0)
+    return choose(config, spec, "tpu", mesh_size, quant_kv).kv_commit
 
 
 @pytest.mark.parametrize("attention_backend, mesh_size, head_dim, quant_kv, "
@@ -239,6 +243,13 @@ def _picked(attention_backend, mesh_size, head_dim, quant_kv):
 ])
 def test_commit_is_decided_from_reader_mesh_head_and_pool(
         attention_backend, mesh_size, head_dim, quant_kv, want):
+    if attention_backend == "pallas" and mesh_size > 1:
+        # Refused before a writer is asked for; the inner rule still says.
+        with pytest.raises(ValueError, match="runs on one device"):
+            _picked(attention_backend, mesh_size, head_dim, quant_kv)
+        assert pool_access(attention_backend, "tpu", mesh_size, head_dim,
+                           quant_kv)[1] == want
+        return
     assert _picked(attention_backend, mesh_size, head_dim, quant_kv) == want
 
 
@@ -250,7 +261,7 @@ def test_programs_off_the_predicate_keep_the_scatter(kw):
     """Built for real: the runner's label says scatter, the program's
     lowered text holds the scatter of both pools and no commit kernel."""
     runner = _runner(**kw)
-    assert runner.kv_commit_backend == "scatter"
+    assert runner.backends.kv_commit == "scatter"
     with runner.mesh:
         text = runner._get_window(4, 8).lower(*_window_args(runner)).as_text()
     pools = 4 if kw.get("quant_kv") else 2  # values and scales

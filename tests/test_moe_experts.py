@@ -13,8 +13,12 @@ import numpy as np
 import pytest
 
 from dynamo_tpu.engine import experts, model
+from dynamo_tpu.engine.backends import Backends
 from dynamo_tpu.engine.config import Cohere2MoeSpec, EngineConfig, ModelSpec
 from dynamo_tpu.engine.quant import QTensor, quantize_params
+
+#: A runner's record on one CPU device, as the expert layer reads it.
+WHOLE = Backends(experts_whole=True, interpret=True)
 
 CONFIGS = os.path.join(os.path.dirname(__file__), "..", "benchmark", "configs")
 H, I = 128, 256
@@ -87,9 +91,9 @@ def test_the_kernel_gives_the_masked_product(held, quant, act, rows,
     outs = {}
     for product, limit in (("grouped", 64), ("masked", 10 ** 9)):
         monkeypatch.setattr(model, "MOE_DENSE_MAX_ROWS", limit)
-        assert model.expert_product(rows, "interpret") == product
+        assert model.expert_product(rows, WHOLE) == product
         outs[product] = np.asarray(jax.jit(lambda x: model.ffn_block(
-            x, lp, spec, router_in=x, experts_local="interpret"))(x),
+            x, lp, spec, router_in=x, backends=WHOLE))(x),
             np.float32)
     assert np.abs(outs["masked"]).mean() > 0.05
     np.testing.assert_allclose(outs["grouped"], outs["masked"], atol=0.05)
@@ -105,7 +109,7 @@ def test_a_share_whose_every_pair_falls_elsewhere_gives_zeros(monkeypatch):
     monkeypatch.setattr(model, "MOE_DENSE_MAX_ROWS", 64)
     x = jax.random.normal(jax.random.key(1), (rows, H), jnp.bfloat16)
     out = jax.jit(lambda x: model.ffn_block(
-        x, lp, spec, experts_local="interpret"))(x)
+        x, lp, spec, backends=WHOLE))(x)
     assert out.shape == x.shape and not np.asarray(out, np.float32).any()
 
 
@@ -190,11 +194,11 @@ def test_prefill_gives_the_masked_products_logits(name, monkeypatch):
     logits = {}
     for product, limit in (("grouped", b * s - 1), ("masked", b * s)):
         monkeypatch.setattr(model, "MOE_DENSE_MAX_ROWS", limit)
-        assert model.expert_product(b * s, "interpret") == product
+        assert model.expert_product(b * s, WHOLE) == product
         logits[product] = np.asarray(jax.jit(
             lambda p, k, v: model.prefill_forward(
                 p, spec, k, v, tokens, pos, table, np.full((b,), s, np.int32),
-                experts_local="interpret")[0])(params, *pools), np.float32)
+                backends=WHOLE)[0])(params, *pools), np.float32)
     assert np.abs(logits["masked"]).mean() > 0.05
     # Eight layers deep, two roundings of each layer's gate and up apart
     # (the masked product rounds a product to bfloat16 before its scale).
@@ -253,7 +257,7 @@ def test_the_label_says_which_product_a_program_takes(monkeypatch):
     perf.get_registry().reset()
     spec, params = rehearsal(None)
     runner = _runner(spec, params)
-    assert runner.experts_local == "interpret"
+    assert runner.backends.experts_whole and runner.backends.interpret
     seqs = [PrefillSeq(tokens=np.arange(1, 1 + n, dtype=np.int32),
                        start_pos=0, hist_pages=None, sampling=(0.0, 0, 1.0),
                        chunk_pages=np.arange(1 + 8 * i, 9 + 8 * i))
@@ -276,7 +280,7 @@ def test_the_label_says_which_product_a_program_takes(monkeypatch):
     assert perf.get_registry().label_values("expert_product") == {
         "prefill": ["grouped", "masked"], "decode_window": ["masked"]}
     # Any mesh keeps the masked product at every size.
-    assert not _runner(spec, params, tp=2).experts_local
+    assert not _runner(spec, params, tp=2).backends.experts_whole
     # A dense block has no such label.
     perf.get_registry().reset()
     dense = ModelSpec(vocab_size=64, hidden_size=32, intermediate_size=16,

@@ -31,6 +31,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark.lib import manifest  # noqa: E402
 from dynamo_tpu.engine import hybrid, model, perf, recurrence  # noqa: E402
+from dynamo_tpu.engine.backends import Backends  # noqa: E402
 from dynamo_tpu.engine.config import (EngineConfig, ModelSpec,  # noqa: E402
                                       NemotronHSpec, UnsupportedBlockError,
                                       block_refusals)
@@ -479,7 +480,8 @@ def test_the_window_step_walks_the_rows_it_counts():
             jnp.asarray([3, 0, 5, 7]), jnp.zeros((rows, 2), jnp.int32),
             jnp.zeros(rows, jnp.int32), state, live)
     want = hybrid.window_step(*args)
-    got = hybrid.window_step(*args, ssm_kernel="interpret")
+    got = hybrid.window_step(*args, backends=Backends(
+        ssm="kernel", interpret=True))
     on = np.asarray(live)
     np.testing.assert_allclose(np.asarray(got[0], np.float32)[on],
                                np.asarray(want[0], np.float32)[on],
@@ -488,7 +490,7 @@ def test_the_window_step_walks_the_rows_it_counts():
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32), rtol=2e-6,
                                    atol=1e-6)
-    assert float(got[5][0, 0]) == float(want[5][0, 0]) == 3.0
+    assert float(got[4]["ssm"][0, 0]) == float(want[4]["ssm"][0, 0]) == 3.0
 
 
 # -- the runner ------------------------------------------------------------------
@@ -569,9 +571,10 @@ def test_a_long_batch_s_rows_go_to_their_own_experts():
     got = {}
     for product in ("grouped", "masked"):
         runner = ModelRunner(config(), params=PARAMS)
-        assert runner.experts_local == "interpret"
+        assert runner.backends.experts_whole and runner.backends.interpret
         if product == "masked":
-            runner.experts_local = False
+            runner.backends = dataclasses.replace(runner.backends,
+                                                  experts_whole=False)
         runner.prefill_batch(seqs, slots=slots)
         fn, = runner._prefill_cache.values()
         assert fn._labels["expert_product"] == product

@@ -11,6 +11,7 @@ import pytest
 from jax.sharding import Mesh
 
 from dynamo_tpu.engine import model
+from dynamo_tpu.engine.backends import XLA
 from dynamo_tpu.engine.config import ModelSpec
 from dynamo_tpu.engine.runner import _prefill_with_history
 
@@ -46,7 +47,7 @@ def _programs():
             params, SPEC, kv, kv, tok, pos, chunk, lens),
         "with history": lambda: _prefill_with_history(
             params, SPEC, kv, kv, tok, pos + S, chunk, lens, table, lens,
-            None),
+            XLA),
         "decode step": lambda: model.decode_forward(
             params, SPEC, kv, kv, tok[:, 0], lens, table, lens + 1),
         "decode window": lambda: model.decode_window_step(

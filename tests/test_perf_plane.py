@@ -10,6 +10,7 @@ All near-free: fake data or one tiny engine. Speed is measured by
 benchmark/ on the chip and recorded in PERF_LEDGER.jsonl, not here.
 """
 
+import dataclasses
 import tracemalloc
 
 import aiohttp
@@ -17,6 +18,7 @@ import numpy as np
 import pytest
 from conftest import async_test
 
+from dynamo_tpu.engine.backends import Backends
 from dynamo_tpu.engine.perf import (CompileRegistry, PerfMetricsUpdater,
                                     instrumented_jit)
 from dynamo_tpu.runtime import flight
@@ -167,9 +169,8 @@ def test_note_window_derives_roofline_gauges():
 
 
 class _FakeRunner:
-    attention_backend = "pallas"
-    kv_commit_backend = "in_place"
-    page_size = 64
+    backends = Backends(attention="pallas", kv_commit="in_place",
+                        page_size=64)
 
     def __init__(self, hbm):
         self._hbm = hbm
@@ -217,16 +218,15 @@ def test_perf_metrics_updater_exports_deltas_and_gauges(monkeypatch):
 @pytest.mark.parametrize("backend", ["pallas", "xla", None])
 def test_the_indexer_s_backend_is_an_info_series(monkeypatch, backend):
     """dynamo_tpu_perf_index_info{backend}: 1 under the label of who runs
-    the decode indexer of a latent pool (runner.index_backend); no sample
-    at all from a worker whose blocks have no indexer (None, or a runner
-    that predates the attribute)."""
+    the decode indexer of a latent pool (Backends.index); no sample at all
+    from a worker whose blocks have no indexer (None)."""
     from dynamo_tpu.engine import perf as perf_mod
     monkeypatch.setattr(perf_mod, "_REGISTRY", CompileRegistry())
     metrics = MetricsRegistry()
     up = PerfMetricsUpdater(metrics, min_interval_s=0.0)
     eng = _FakeEngine({})
-    if backend is not None:
-        eng.runner.index_backend = backend
+    eng.runner.backends = dataclasses.replace(eng.runner.backends,
+                                              index=backend)
     up.update(eng, force=True)
     samples = [line for line in metrics.expose().decode().splitlines()
                if line.startswith("dynamo_tpu_perf_index_info{")]
@@ -412,7 +412,7 @@ async def test_perf_smoke_engine_zero_recompiles_and_pane(tmp_path):
         # the registry's record of the window programs, and as an info
         # series.
         assert status["kv_commit_backend"] == \
-            engine.runner.kv_commit_backend == "scatter"
+            engine.runner.backends.kv_commit == "scatter"
         assert "scatter" in snap1["programs"]["decode_window"]["labels"][
             "kv_commit_backend"]
         # ... and who reads it (this engine asked for the gather).

@@ -24,13 +24,16 @@ from conftest import async_test
 from benchmark.lib import reference as plainref
 from benchmark.references import smallthinker as ref
 from dynamo_tpu.engine import model
-from dynamo_tpu.engine.attention import (paged_decode_attention_pallas,
-                                         paged_window_attention_pallas)
+from dynamo_tpu.engine.backends import XLA, Backends
+from dynamo_tpu.engine.attention import paged_decode_attention_pallas
 from dynamo_tpu.engine.config import (PRESETS, EngineConfig, ModelSpec,
                                       UnsupportedBlockError, block_refusals)
 from dynamo_tpu.engine.kv_quant import scatter_tokens
 from dynamo_tpu.engine.quant import quantize_params
 from dynamo_tpu.engine.runner import ModelRunner, _prefill_with_history
+
+#: A runner's record on one CPU device, as the expert layer reads it.
+WHOLE = Backends(experts_whole=True, interpret=True)
 
 # The catalog row's ``config`` (model-configs guide, architectures.jsonl:
 # SmallThinker-21BA3B-Instruct), layouts written as their period of four.
@@ -163,13 +166,10 @@ def served_logits(spec, params, tokens, backend: str) -> np.ndarray:
     kv = jnp.zeros((spec.num_layers, spec.num_kv_heads, b * pages + 1, PAGE,
                     spec.head_dim), jnp.bfloat16)
     table = (1 + np.arange(b * pages, dtype=np.int32)).reshape(b, pages)
-    if backend == "xla":
-        step_attn, window_attn = None, None
-    else:
-        step_attn = functools.partial(paged_decode_attention_pallas,
-                                      interpret=True)
-        window_attn = functools.partial(paged_window_attention_pallas,
-                                        interpret=True)
+    # The record a runner would hand the programs: XLA's, or the kernels
+    # interpreted.
+    record = XLA if backend == "xla" else Backends(attention="pallas",
+                                                   interpret=True)
     rows = []
     pos = np.broadcast_to(np.arange(FIRST, dtype=np.int32), (b, FIRST))
     lens = np.full((b,), FIRST, np.int32)
@@ -182,7 +182,7 @@ def served_logits(spec, params, tokens, backend: str) -> np.ndarray:
         p, spec, k, v, tokens[:, done:done + CHUNK], pos + done,
         table[:, done // PAGE:(done + CHUNK) // PAGE],
         np.full((b,), CHUNK, np.int32), table[:, :done // PAGE],
-        np.full((b,), done, np.int32), step_attn))(params, k, v)
+        np.full((b,), done, np.int32), record))(params, k, v)
     rows.append(logits)
     done += CHUNK
 
@@ -196,7 +196,7 @@ def served_logits(spec, params, tokens, backend: str) -> np.ndarray:
             logits, k_new, v_new, stats = model.decode_window_step(
                 p, spec, k, v, kbuf, vbuf, jnp.int32(m),
                 tokens[:, done + m], hist + m, table, hist,
-                attention_impl=window_attn, live=jnp.ones((b,), bool))
+                backends=record, live=jnp.ones((b,), bool))
             kbuf = kbuf.at[:, :, :, m].set(k_new.transpose(0, 2, 1, 3))
             vbuf = vbuf.at[:, :, :, m].set(v_new.transpose(0, 2, 1, 3))
             out.append(logits)
@@ -210,13 +210,13 @@ def served_logits(spec, params, tokens, backend: str) -> np.ndarray:
     logits, k, v, stats = jax.jit(window)(params, k, v)
     rows += list(logits)
     done += WINDOW
-    stats = np.asarray(stats)                               # [L, 3]
+    stats = np.asarray(stats["moe"])                        # [L, 3]
     assert stats.shape == (spec.num_layers, 3) and (stats[:, 2] == 1).all()
     assert (1 <= stats[:, 0]).all() and (stats[:, 0] <= min(
         spec.num_experts, b * spec.num_experts_per_tok)).all()
     assert (stats[:, 1] >= 1.0 - 1e-6).all()
     decode = jax.jit(lambda p, k, v, t, at: model.decode_forward(
-        p, spec, k, v, t, at, table, at + 1, attention_impl=step_attn))
+        p, spec, k, v, t, at, table, at + 1, backends=record))
     while done < SEQ:
         logits, k, v = decode(params, k, v, tokens[:, done],
                               np.full((b,), done, np.int32))
@@ -369,7 +369,7 @@ def test_a_refusal_tests_the_field_that_carries_the_mechanism():
     assert sum("no global layer index" in m for m in said) == 1
     assert block_refusals(spec, EngineConfig(model=spec, **BASE)) == []
     assert ModelRunner(EngineConfig(model=spec, **BASE),
-                       params=params).experts_local
+                       params=params).backends.experts_whole
     assert "w_gate" not in EngineConfig(model=spec).lora_target_shapes()
 
 
@@ -436,7 +436,7 @@ def test_grouped_product_matches_the_masked_product(quant, monkeypatch):
     for limit in (64, 10 ** 9):
         monkeypatch.setattr(model, "MOE_DENSE_MAX_ROWS", limit)
         outs.append(np.asarray(jax.jit(lambda x, rin: model.ffn_block(
-            x, lp, spec, router_in=rin, experts_local="interpret"))(x, rin),
+            x, lp, spec, router_in=rin, backends=WHOLE))(x, rin),
             np.float32))
     assert np.abs(outs[1]).mean() > 0.2
     np.testing.assert_allclose(outs[0], outs[1], atol=0.05)
@@ -466,11 +466,11 @@ def test_the_product_is_chosen_by_rows_and_by_where_the_experts_are(
     for rows in (model.MOE_DENSE_MAX_ROWS, model.MOE_DENSE_MAX_ROWS + 8):
         x = jax.random.normal(jax.random.key(rows), (rows, 32), jnp.bfloat16)
         calls.clear()
-        out[rows] = model.ffn_block(x, lp, mixtral,
-                                    experts_local=local and "interpret")
+        record = WHOLE if local else XLA
+        out[rows] = model.ffn_block(x, lp, mixtral, backends=record)
         grouped = local and rows > model.MOE_DENSE_MAX_ROWS
         assert bool(calls) == grouped
-        assert model.expert_product(rows, local) == (
+        assert model.expert_product(rows, record) == (
             "grouped" if grouped else "masked")
         masked = model.ffn_block(x, lp, mixtral)
         np.testing.assert_allclose(
@@ -481,10 +481,10 @@ def test_the_product_is_chosen_by_rows_and_by_where_the_experts_are(
                 prefill_buckets=(16, 32), attention_backend="xla")
     params = model.init_params(mixtral, jax.random.key(0))
     # The CPU interprets the kernel, as it does the attention kernels.
-    assert ModelRunner(EngineConfig(**base),
-                       params=params).experts_local == "interpret"
+    on_cpu = ModelRunner(EngineConfig(**base), params=params).backends
+    assert on_cpu.experts_whole and on_cpu.interpret
     assert not ModelRunner(EngineConfig(**base, tp=2),
-                           params=params).experts_local
+                           params=params).backends.experts_whole
 
 
 # -- the kernel's window: chunks wholly before it are not walked -------------------
@@ -571,7 +571,8 @@ async def test_the_engine_serves_the_block_and_counts_its_expert_load():
         a, b = await asyncio.gather(generate(20, 21), generate(12, 13))
         assert (len(a), len(b)) == (21, 13)
         await asyncio.sleep(0.05)
-        touched, load, n = engine.moe_totals
+        touched, load, n = (engine.counts_total[c] for c in (
+            "moe_touched", "moe_load", "moe_layer_steps"))
         # Every counted (step, layer) pair had 1 or 2 live rows of 3
         # experts each: 3 to 6 distinct, the fullest holding 1 or 2 tokens
         # of a mean of rows * 3 / 8.

@@ -10,6 +10,8 @@ serves, guard every later PR at no chip time. Nothing runs here, so nothing
 is said about results or speed.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -18,7 +20,11 @@ from jax.sharding import SingleDeviceSharding
 
 from dynamo_tpu.engine.attention import (paged_decode_attention_pallas,
                                          paged_window_attention_pallas)
+from dynamo_tpu.engine.backends import Backends, choose
 from dynamo_tpu.engine.kv_quant import QuantKV
+
+#: A runner's record on one TPU device, as the expert layer reads it.
+WHOLE = Backends(experts_whole=True)
 
 PAGE = 16
 
@@ -244,13 +250,13 @@ def test_smallthinker_expert_layer_compiles_for_v5e(v5e, rows):
         moe_intermediate_size=768, quant="int8")
     x, lp = _expert_layer(v5e, spec, 2560, 768, 64, 64)
     compiled = jax.jit(lambda x, lp: model.ffn_block(
-        x, lp, spec, router_in=x, experts_local=True)).lower(
+        x, lp, spec, router_in=x, backends=WHOLE)).lower(
             x(rows), lp).compile()
     flops = compiled.cost_analysis()["flops"]
     chosen = 2 * rows * 6 * 3 * 2560 * 768
     kernels = compiled.as_text().count("tpu_custom_call")
     if rows > model.MOE_DENSE_MAX_ROWS:
-        assert model.expert_product(rows, True) == "grouped"
+        assert model.expert_product(rows, WHOLE) == "grouped"
         assert kernels == 2 and flops < 0.1 * chosen, (kernels, flops)
         # The pairs' rows gathered, their gated unit, their outputs twice.
         assert compiled.memory_analysis().temp_size_in_bytes < (
@@ -281,7 +287,7 @@ def test_a_scanned_expert_layer_reads_the_stack_where_it_lies(v5e, whole):
 
     def body(x, lp):
         return x + model.ffn_block(x, lp, spec, router_in=x,
-                                   experts_local=True), None
+                                   backends=WHOLE), None
 
     compiled = jax.jit(lambda x, lps: model.scan_layers(
         body, x, lps, spec, whole_experts=whole)[0]).lower(
@@ -316,7 +322,7 @@ def test_a_shares_expert_layer_compiles_for_v5e(v5e, rows, widths):
         first_expert=first, num_shared_experts=shared, quant="int8")
     x, lp = _expert_layer(v5e, spec, h, i, 16, routed, shared)
     compiled = jax.jit(lambda x, lp: model.ffn_block(
-        x, lp, spec, experts_local=True)).lower(x(rows), lp).compile()
+        x, lp, spec, backends=WHOLE)).lower(x(rows), lp).compile()
     flops = compiled.cost_analysis()["flops"]
     grouped = rows > model.MOE_DENSE_MAX_ROWS
     assert compiled.as_text().count("tpu_custom_call") == 2 * grouped
@@ -350,9 +356,9 @@ def test_a_two_matrix_expert_layer_reads_its_stacks_as_the_chip_holds_them(
         num_routed_experts=128, ffn_act="relu2", quant="int8")
     x, lp = _expert_layer(v5e, spec, h, i, held, 128)
     assert experts.lies_turned(h, i) and not experts.lies_turned(i, h)
-    assert model.expert_product(rows, True) == "grouped"
+    assert model.expert_product(rows, WHOLE) == "grouped"
     compiled = jax.jit(lambda x, lp: model.ffn_block(
-        x, lp, spec, experts_local=True)).lower(x(rows), lp).compile()
+        x, lp, spec, backends=WHOLE)).lower(x(rows), lp).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 2
     for stack in ((h, i), (i, h)):
@@ -393,11 +399,9 @@ def _window_program(one, model, commit, pool_tokens=24000, rows=32, window=8,
     chip: a ModelRunner that places nothing (no device to hold an array),
     with the cell's attention geometry and depth and a narrow MLP and
     vocabulary (they never touch the pool, and keep the compile short).
-    ``commit`` None: what the runner picks (the XLA side of
+    ``commit`` None: what backends.choose picks (the XLA side of
     config.pool_access: another platform, a mesh, a packed head).
     Returns (lowered, pool shape)."""
-    from types import SimpleNamespace
-
     from dynamo_tpu.engine.config import EngineConfig, ModelSpec
     from dynamo_tpu.engine.model import param_shapes
     from dynamo_tpu.engine.runner import PK_PREFIX, ModelRunner
@@ -413,21 +417,15 @@ def _window_program(one, model, commit, pool_tokens=24000, rows=32, window=8,
                                  max_num_seqs=rows)
     assert runner.config.max_model_len == 8192
     table = runner.config.max_pages_per_seq // 4
-    runner.device = SimpleNamespace(platform=platform)
-    runner.mesh = SimpleNamespace(size=mesh_size)
     runner.quant_kv, runner.lora = quant_kv, None
-    runner.experts_local = True
     runner._window_cache = {}
-    runner._attention_impl, runner._window_attention_impl = \
-        runner._pick_attention()
-    if commit is None:
-        assert runner.attention_backend == "xla"
-        commit = runner._pick_kv_commit()
-        assert commit == "scatter"
-    else:
-        assert runner.attention_backend == "pallas"
-        assert runner._pick_kv_commit() == "in_place"
-    runner.kv_commit_backend = commit
+    runner.backends = choose(runner.config, spec, platform, mesh_size,
+                             quant_kv)
+    assert (runner.backends.attention, runner.backends.kv_commit) == (
+        ("xla", "scatter") if commit is None else ("pallas", "in_place"))
+    if commit is not None:      # "scatter": steer the twin, same reader
+        runner.backends = dataclasses.replace(runner.backends,
+                                              kv_commit=commit)
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
@@ -559,8 +557,6 @@ def test_latent_window_program_commits_in_place_for_v5e(v5e):
     row-major pool as it lies and the commit kernel rewrites the touched
     rows of both widths, so nothing in the optimised program has either
     pool's shape but the arguments and the commit aliased to them."""
-    from types import SimpleNamespace
-
     from dynamo_tpu.engine.config import DeepseekV32Spec, EngineConfig
     from dynamo_tpu.engine.model import param_shapes
     from dynamo_tpu.engine.runner import PK_PREFIX, ModelRunner
@@ -583,15 +579,10 @@ def test_latent_window_program_commits_in_place_for_v5e(v5e):
     runner.config = EngineConfig(model=spec, page_size=page, num_pages=pages,
                                  max_num_seqs=rows)
     table = runner.config.max_pages_per_seq // 2
-    runner.device = SimpleNamespace(platform="tpu")
-    runner.mesh = SimpleNamespace(size=1)
     runner.quant_kv, runner.lora = None, None
-    runner.experts_local = True
     runner._window_cache = {}
-    runner._attention_impl, runner._window_attention_impl = \
-        runner._pick_attention()
-    runner.kv_commit_backend = runner._pick_kv_commit()
-    assert (runner.attention_backend, runner.kv_commit_backend) == (
+    runner.backends = choose(runner.config, spec, "tpu", 1, None)
+    assert (runner.backends.attention, runner.backends.kv_commit) == (
         "pallas", "in_place")
 
     def s(shape, dtype):
@@ -620,8 +611,6 @@ def _glm_runner(v5e, spec_decode, rows=32, pages=1200):
     geometry (20 heads of 192 | 64, v 256, q rank 768; entries of 640 lanes
     and NO second array) with a dense layer, two expert layers, the
     prediction module, narrow feed-forwards and a small vocabulary."""
-    from types import SimpleNamespace
-
     from dynamo_tpu.engine.config import DeepseekV32Spec, EngineConfig
     from dynamo_tpu.engine.runner import ModelRunner
     spec = DeepseekV32Spec(
@@ -643,16 +632,11 @@ def _glm_runner(v5e, spec_decode, rows=32, pages=1200):
     runner.config = EngineConfig(model=spec, page_size=page, num_pages=pages,
                                  max_num_seqs=rows, spec_decode=spec_decode,
                                  spec_k=1)
-    runner.device = SimpleNamespace(platform="tpu")
-    runner.mesh = SimpleNamespace(size=1)
     runner.quant_kv, runner.lora = None, None
-    runner.experts_local = True
     runner._window_cache, runner._prefill_cache = {}, {}
-    runner._attention_impl, runner._window_attention_impl = \
-        runner._pick_attention()
-    runner.kv_commit_backend = runner._pick_kv_commit()
-    assert (runner.attention_backend, runner.kv_commit_backend,
-            runner.index_backend) == ("pallas", "in_place", None)
+    runner.backends = choose(runner.config, spec, "tpu", 1, None)
+    assert (runner.backends.attention, runner.backends.kv_commit,
+            runner.backends.index) == ("pallas", "in_place", None)
     return runner, spec, page, pages
 
 
@@ -703,8 +687,6 @@ def _hybrid_runner(v5e, rows=32, pages=3000):
     of 128; a pool of the ONE attention layer; 4 two-matrix relu2 experts
     held of a router over 128; pattern MEM*EME, a narrow vocabulary), int8
     weights. Returns (runner, spec, params as shapes, s)."""
-    from types import SimpleNamespace
-
     from dynamo_tpu.engine.config import EngineConfig, NemotronHSpec
     from dynamo_tpu.engine.model import param_shapes
     from dynamo_tpu.engine.quant import QUANT_LAYER_KEYS, QTensor
@@ -728,17 +710,11 @@ def _hybrid_runner(v5e, rows=32, pages=3000):
     assert page == 128
     runner.config = EngineConfig(model=spec, page_size=page, num_pages=pages,
                                  max_num_seqs=rows)
-    runner.device = SimpleNamespace(platform="tpu")
-    runner.mesh = SimpleNamespace(size=1)
     runner.quant_kv, runner.lora, runner.draft_dev = None, None, None
-    runner.experts_local = True
     runner._window_cache, runner._prefill_cache = {}, {}
-    runner._attention_impl, runner._window_attention_impl = \
-        runner._pick_attention()
-    runner.kv_commit_backend = runner._pick_kv_commit()
-    runner.ssm_backend = runner._pick_ssm()
-    assert (runner.attention_backend, runner.kv_commit_backend,
-            runner.ssm_backend) == ("pallas", "in_place", "kernel")
+    runner.backends = choose(runner.config, spec, "tpu", 1, None)
+    assert (runner.backends.attention, runner.backends.kv_commit,
+            runner.backends.ssm) == ("pallas", "in_place", "kernel")
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)

@@ -18,7 +18,8 @@ from types import SimpleNamespace
 
 import pytest
 
-from dynamo_tpu.engine import perf
+from dynamo_tpu.engine import model, perf
+from dynamo_tpu.engine.backends import choose
 from dynamo_tpu.engine.config import (DEVICE_PEAKS, EngineConfig, ModelSpec,
                                       PRESETS, device_peaks)
 from dynamo_tpu.engine.runner import ModelRunner
@@ -116,23 +117,23 @@ def test_requested_pallas_is_pallas_or_an_error():
         ModelRunner(_tiny(attention_backend="flash"))
     runner = ModelRunner(_tiny(attention_backend="pallas"))
     assert runner.attention_backend == "pallas"
-    assert runner._attention_impl.keywords == {"interpret": True}
+    assert runner.backends.kv_reader(window=False).keywords == {
+        "interpret": True}
     assert ModelRunner(_tiny()).attention_backend == "xla"  # "auto"
     assert runner.hbm_stats() == {}  # the CPU has no memory_stats: explicit
 
 
 def _picked(platform, mesh_size, head_dim, backend="auto", page_size=16):
-    """_pick_attention on a stubbed device and mesh: the choice reads only
-    platform, mesh size, head_dim and page size."""
-    runner = object.__new__(ModelRunner)
-    runner.config = SimpleNamespace(attention_backend=backend,
-                                    page_size=page_size)
-    runner.spec = SimpleNamespace(head_dim=head_dim, latent=False)
-    runner.device = SimpleNamespace(platform=platform)
-    runner.mesh = SimpleNamespace(size=mesh_size)
-    runner.quant_kv = None  # the writer's half of config.pool_access
-    step, window = runner._pick_attention()
-    return runner.attention_backend, step, window
+    """backends.choose on a stubbed platform and mesh: the choice reads only
+    platform, mesh size, head_dim and page size. Returns (the reader's
+    name, who attends in the single step, who attends in a window's)."""
+    config = SimpleNamespace(attention_backend=backend, page_size=page_size,
+                             max_pages_per_seq=8, spec_decode=None)
+    spec = SimpleNamespace(head_dim=head_dim, latent=False, recurrent=False,
+                           index_topk=0, num_experts=0)
+    record = choose(config, spec, platform, mesh_size, None)
+    return (record.attention, model.kv_attention(record, window=False),
+            model.kv_attention(record, window=True))
 
 
 @pytest.mark.parametrize("platform, mesh_size, head_dim, want", [
