@@ -514,8 +514,11 @@ def small_config(args, spec, context: int = 1024, **kw):
     sequence."""
     from dynamo_tpu.engine.config import EngineConfig
     if args.rehearse_cpu:
-        sizes = dict(prefill_buckets=(32, 64, 128), max_prefill_tokens=64,
-                     num_pages=256)
+        # (A bucket is whole pages, and a page whole blocks of a block
+        # whose attention reads chosen blocks: 64 tokens off the chip.)
+        sizes = dict(prefill_buckets=tuple(
+            b for b in (32, 64, 128) if b % max(16, spec.sparse_block) == 0),
+            max_prefill_tokens=64, num_pages=256)
     else:
         sizes = dict(prefill_buckets=(128, 256, 512, 1024),
                      max_prefill_tokens=256, num_pages=1024)
@@ -606,8 +609,26 @@ async def phase_kernels(args, jax, rng, keep: dict):
         shared_intermediate_size=384, routed_scaling_factor=2.5,
         layer_pattern="MEM*EME", ssm_heads=8, ssm_head_dim=64, ssm_groups=2,
         ssm_state=128, ssm_conv=4, ssm_chunk=128)
+    # The MiniCPM-SALA block at toy depth: both mixers (S L L S L L S), its
+    # attention geometry (32 query heads over 2 KV heads of 128: a page of
+    # 128, two blocks of 64) and its lightning heads' state (128 x 128), a
+    # query keeping 4 blocks of 64 of which the window's are 2, so the two
+    # longer prompts choose in every attention layer of every step: on the
+    # chip the recurrence's kernel at a group a head and the pool's reader
+    # over the chosen blocks' table, against XLA's of both; the longest
+    # prompt is prefilled in chunks over their history.
+    from dynamo_tpu.engine.config import MiniCPMSALASpec
+    sala = MiniCPMSALASpec(
+        name="smoke-sala", vocab_size=2048, hidden_size=512,
+        intermediate_size=1024, num_layers=7, num_heads=32, num_kv_heads=2,
+        head_dim=128, rms_norm_eps=1e-6, layer_pattern="SDLDLDSDLDLDSD",
+        ssm_heads=8, ssm_head_dim=128, ssm_groups=8, ssm_state=128,
+        ssm_chunk=128, scale_emb=12.0, residual_scale=1.4 / 7 ** 0.5,
+        logit_divisor=2.0, sparse_kernel=32, sparse_stride=16,
+        sparse_block=64, sparse_topk=4, sparse_init_blocks=1,
+        sparse_window=128)
     pages = {wide.name: 64, share.name: 32, latent.name: 64,
-             indexed.name: 64, hybrid.name: 128}  # derived
+             indexed.name: 64, hybrid.name: 128, sala.name: 128}  # derived
     short = (20, 70, 150) if args.rehearse_cpu else (24, 200, 700)
     past_topk = (20, 2100) if args.rehearse_cpu else (24, 2200, 2600)
     assert max(short) + 64 < indexed.index_topk < min(past_topk[1:])
@@ -623,7 +644,8 @@ async def phase_kernels(args, jax, rng, keep: dict):
             (share, None, None, ("xla", "auto"), short, 1024),
             (latent, None, None, ("xla", kernels), short, 1024),
             (indexed, None, None, ("xla", kernels), past_topk, 4096),
-            (hybrid, None, None, ("xla", "auto"), short, 1024)):
+            (hybrid, None, None, ("xla", "auto"), short, 1024),
+            (sala, None, None, ("xla", "auto"), short, 1024)):
         prompts = [rng.integers(2, spec_r.vocab_size, size=n).tolist()
                    for n in lengths]
         runs = {}
@@ -631,7 +653,10 @@ async def phase_kernels(args, jax, rng, keep: dict):
         # resolves: the kernel is compared with XLA at the derived page.
         page = small_config(args, spec_r, context, quant_kv=quant_kv,
                             attention_backend=backends[1]).page_size
-        check(page == (pages.get(spec_r.name, 16) if on_tpu else 16),
+        # (Off the chip a page is 16 tokens, or one block of a block whose
+        # attention reads chosen blocks.)
+        check(page == (pages.get(spec_r.name, 16) if on_tpu
+                       else max(16, spec_r.sparse_block)),
               f"{spec_r.name} under {backends[1]}: page of {page} tokens")
         for backend in backends:
             eng = TPUEngine(small_config(args, spec_r, context,
@@ -674,10 +699,14 @@ async def phase_kernels(args, jax, rng, keep: dict):
             chunks = eng.chunk_tokens_total
             check(chunks > 0, "the longest prompt was not chunk-prefilled")
             selected = None
-            if spec_r.latent:
-                # Did the indexer choose? Keys attended of keys in context.
+            if spec_r.latent or spec_r.compressed_keys:
+                # Did the indexer choose (or the scores over compressed
+                # keys)? Keys attended of keys in context.
                 selected = eng.perf_status()["attn"]["selected_pct"]
-                check((selected < 100) == (lengths is past_topk),
+                chooses = lengths is past_topk or (
+                    spec_r.compressed_keys and max(lengths)
+                    > spec_r.sparse_topk * spec_r.sparse_block)
+                check((selected < 100) == chooses,
                       f"{spec_r.name} at {lengths}: {selected} % of the "
                       f"keys in context attended")
             custom_call = None
@@ -688,8 +717,8 @@ async def phase_kernels(args, jax, rng, keep: dict):
                            if isinstance(k[0], int))
                 packed = np.zeros((runner.config.max_num_seqs,
                                    PK_PREFIX + key[1]), np.int32)
-                # (a block with recurrent layers: its two state arrays too)
-                state = (runner.ssm_state, runner.conv_state)
+                # (a block with recurrent layers: its state arrays too)
+                state = runner.state_arrays
                 *shapes, state = jax.tree.map(  # the engine owns the arrays
                     lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                                    sharding=a.sharding),
@@ -724,9 +753,9 @@ async def phase_kernels(args, jax, rng, keep: dict):
             if spec_r.recurrent:
                 # The recurrent state beside the pool: where the arrays the
                 # programs handed back lie, and that steps were counted.
-                state_on = sorted({d.platform for a in (
-                    eng.runner.ssm_state, eng.runner.conv_state)
-                    for d in a.devices()})
+                state_on = sorted({d.platform
+                                   for a in eng.runner.state_arrays
+                                   for d in a.devices()})
                 check(state_on == [jax.devices()[0].platform]
                       and eng.perf_status()["ssm"]["row_steps"] > 0,
                       f"recurrent state of {spec_r.name} on {state_on}")

@@ -145,6 +145,21 @@ class ModelSpec:
     ssm_conv = 0
     ssm_chunk = 0
     shared_intermediate_size = None     # a shared expert is expert_size wide
+    # What the MiniCPM-SALA block states (MiniCPMSALASpec): the muP scalars
+    # (the embedding's factor, what a sublayer's output is multiplied by
+    # ahead of the residual, what divides the final norm's output ahead of
+    # the head) and the constants of attention over chosen BLOCKS of keys
+    # (sparse_block 0: every layer attends every earlier key).
+    scale_emb = 1.0
+    residual_scale = 1.0
+    logit_divisor = 1.0
+    qk_norm = False
+    sparse_kernel = 0
+    sparse_stride = 0
+    sparse_block = 0
+    sparse_topk = 0
+    sparse_init_blocks = 0
+    sparse_window = 0
     # Weight-only quantization: None (bf16) or "int8" (engine/quant.py —
     # int8 storage, bf16 MXU compute; halves the weight-read roofline and
     # fits full llama-3-8b on one 16 GB v5e).
@@ -195,7 +210,17 @@ class ModelSpec:
 
     @property
     def ssm_layers(self) -> int:
-        return (self.layer_pattern or "").count("M")
+        """Layers that keep a state a row: a pattern's M (Mamba-2) and L
+        (lightning linear attention)."""
+        pattern = self.layer_pattern or ""
+        return pattern.count("M") + pattern.count("L")
+
+    @property
+    def compressed_keys(self) -> bool:
+        """The attention layers choose BLOCKS of keys by scores over
+        mean-pooled keys, which the pool holds in a third array beside K
+        and V under the same page table (``comp_key_shape``)."""
+        return self.sparse_block > 0
 
     @property
     def expert_layers(self) -> int:
@@ -211,18 +236,43 @@ class ModelSpec:
                 + 2 * self.ssm_groups * self.ssm_state)
 
     @property
-    def ssm_state_shapes(self) -> tuple[tuple, tuple]:
+    def ssm_state_shapes(self) -> tuple[tuple, tuple | None]:
         """What ONE row keeps in ONE recurrent layer: the state S [heads,
         head_dim, state] (float32) and the convolution's last inputs
-        [ssm_conv - 1, channels] (bfloat16)."""
+        [ssm_conv - 1, channels] (bfloat16; None for a mixer without a
+        convolution, ``ssm_conv`` 0: there is no second array)."""
         return ((self.ssm_heads, self.ssm_head_dim, self.ssm_state),
-                (self.ssm_conv - 1, self.ssm_channels))
+                (self.ssm_conv - 1, self.ssm_channels) if self.ssm_conv
+                else None)
 
     @property
     def ssm_state_bytes_per_row(self) -> int:
         """Bytes of recurrent state a row (a slot) holds over all layers."""
         s, c = self.ssm_state_shapes
-        return self.ssm_layers * (4 * math.prod(s) + 2 * math.prod(c))
+        return self.ssm_layers * (4 * math.prod(s)
+                                  + (2 * math.prod(c) if c else 0))
+
+    @property
+    def comp_key_bytes_per_token(self) -> int:
+        """What a token adds to the compressed-key array over all attention
+        layers (bfloat16: a stripe's share a KV head); 0 without one."""
+        if not self.compressed_keys:
+            return 0
+        return (self.pool_layers * self.num_kv_heads
+                * 2 * self.head_dim // self.sparse_stride)
+
+    def comp_key_shape(self, num_pages: int, page_size: int) -> tuple:
+        """The compressed-key array of a pool of ``num_pages`` pages:
+        [attention layers, KV heads, pages, stripes a page x head_dim]
+        bfloat16. A STRIPE is the mean of ``sparse_stride`` keys in a row
+        of the page; a compressed key (the mean of ``sparse_kernel`` = two
+        strides of keys) is the mean of two stripes that follow each other,
+        taken where the scores are (hybrid.stripe_scores), so that a page's
+        stripes are the page's own keys' and nothing is written across a
+        page's border. The last axis is lane-dense: [stripes, head_dim]
+        behind a page would rest padded to 16 sublanes."""
+        return (self.pool_layers, self.num_kv_heads, num_pages,
+                page_size // self.sparse_stride * self.head_dim)
 
     @property
     def pool_layers(self) -> int:
@@ -231,7 +281,8 @@ class ModelSpec:
         width under the same page table; under a ``layer_pattern`` the
         attention layers alone (the others leave nothing a token)."""
         if self.layer_pattern:
-            return self.layer_pattern.count("*")
+            return (self.layer_pattern.count("*")
+                    + self.layer_pattern.count("S"))
         return self.num_layers + self.mtp_layers
 
     @property
@@ -299,7 +350,8 @@ class ModelSpec:
         EngineConfig.kv_token_bytes() for pool sizing so the int8
         accounting stays honest."""
         heads, widths = self.kv_entry
-        return self.pool_layers * heads * sum(widths) * dtype_bytes
+        return (self.pool_layers * heads * sum(widths) * dtype_bytes
+                + self.comp_key_bytes_per_token)
 
     def weight_read_step_ms(self, hbm_gbps: float, tp: int = 1,
                             pp: int = 1) -> float:
@@ -329,6 +381,8 @@ class ModelSpec:
             return cls._from_glm4_moe_lite(cfg, path)
         if cfg.get("model_type") == "nemotron_h":
             return cls._from_nemotron_h(cfg, path)
+        if cfg.get("model_type") == "minicpm_sala":
+            return cls._from_minicpm_sala(cfg, path)
         return cls(
             name=cfg.get("_name_or_path", os.path.basename(os.path.dirname(path))),
             vocab_size=cfg["vocab_size"],
@@ -722,6 +776,89 @@ class ModelSpec:
                 "moe_shared_expert_intermediate_size"],
         )
 
+    @classmethod
+    def _from_minicpm_sala(cls, cfg: dict, path: str) -> "ModelSpec":
+        """MiniCPM-SALA's keys (openbmb/MiniCPM-SALA ``config.json``,
+        ``minicpm_sala``): ``mixer_types`` gives every layer its mixer
+        (``lightning-attn`` linear attention, ``minicpm4`` attention over
+        chosen blocks of keys), every layer a dense SwiGLU feed-forward
+        behind it. The sparse layer's six constants come from
+        ``sparse_config`` where the file has it, else the family's
+        (MiniCPM4.1's released ``sparse_config``). ``mup_denominator`` shapes
+        training and ``lightning_scale`` ("1/sqrt(d)") is the only scale
+        written down; neither is read."""
+        reader = "the config reader"
+        for key, want, why in (
+                ("attention_bias", False, "no projection has a bias leaf"),
+                ("hidden_act", "silu", "the feed-forward is SwiGLU"),
+                ("attn_use_rope", False, "the attention layers over chosen "
+                 "blocks rotate nothing (compressed keys are means of "
+                 "unrotated keys)"),
+                ("lightning_use_rope", True, "the linear-attention layers "
+                 "rotate q and k"),
+                ("qk_norm", True, "q and k are RMS-normalised a head"),
+                ("use_output_norm", True, "the linear-attention output is "
+                 "RMS-normalised a head ahead of its gate"),
+                ("use_output_gate", True, "the linear-attention output is "
+                 "gated by sigmoid(u W_z)"),
+                ("attn_use_output_gate", True, "the attention output is "
+                 "gated by sigmoid(u W_z)"),
+                ("lightning_scale", "1/sqrt(d)", "the state is read by "
+                 "q / sqrt(head_dim)")):
+            got = cfg.get(key, want)
+            if got != want:
+                raise UnsupportedBlockError(
+                    reader, f"minicpm_sala with {key} {got!r}: {why}")
+        kinds = {"lightning-attn": "L", "minicpm4": "S"}
+        mixers = cfg["mixer_types"]
+        unknown = sorted(set(mixers) - set(kinds))
+        if unknown or len(mixers) != cfg["num_hidden_layers"]:
+            raise UnsupportedBlockError(
+                reader, f"minicpm_sala whose mixer_types names {unknown} or "
+                f"has {len(mixers)} entries for num_hidden_layers "
+                f"{cfg['num_hidden_layers']}")
+        if (cfg["lightning_nkv"] != cfg["lightning_nh"]
+                or cfg["lightning_head_dim"] != cfg["head_dim"]):
+            raise UnsupportedBlockError(
+                reader, "minicpm_sala whose linear-attention layers share "
+                "keys between heads or have another head width than the "
+                "attention layers: one state a head of head_dim x head_dim "
+                "is what is written down")
+        sparse = cfg.get("sparse_config") or {}
+        layers = cfg["num_hidden_layers"]
+        return MiniCPMSALASpec(
+            name=cfg.get("_name_or_path")
+            or os.path.basename(os.path.dirname(path)),
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=layers,
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg["num_key_value_heads"],
+            head_dim=cfg.get("head_dim"),
+            rope_theta=cfg.get("rope_theta", 10000.0),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-6),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            max_position_embeddings=cfg.get("max_position_embeddings", 8192),
+            layer_pattern="".join(kinds[m] + "D" for m in mixers),
+            ssm_heads=cfg["lightning_nh"],
+            ssm_head_dim=cfg["lightning_head_dim"],
+            ssm_groups=cfg["lightning_nh"],
+            ssm_state=cfg["lightning_head_dim"],
+            ssm_chunk=cfg.get("chunk_size", 128),
+            scale_emb=float(cfg.get("scale_emb", 1.0)),
+            residual_scale=float(cfg.get("scale_depth", 1.0))
+            / math.sqrt(layers) if "scale_depth" in cfg else 1.0,
+            logit_divisor=cfg["hidden_size"] / cfg["dim_model_base"]
+            if "dim_model_base" in cfg else 1.0,
+            sparse_kernel=sparse.get("kernel_size", 32),
+            sparse_stride=sparse.get("kernel_stride", 16),
+            sparse_block=sparse.get("block_size", 64),
+            sparse_topk=sparse.get("topk", 64),
+            sparse_init_blocks=sparse.get("init_blocks", 1),
+            sparse_window=sparse.get("window_size", 2048),
+        )
+
 
 @dataclasses.dataclass
 class SmallThinkerSpec(ModelSpec):
@@ -920,14 +1057,92 @@ class NemotronHSpec(Cohere2MoeSpec):
         if len(pattern) != self.num_layers or set(pattern) - set("ME*"):
             raise ValueError(f"layer_pattern {pattern!r} does not give "
                              f"{self.num_layers} layers of M, E and *")
-        if not re.fullmatch(r"(M\*?E)+", pattern):
-            raise UnsupportedBlockError(
-                "the layer scan", f"layer_pattern {pattern!r} is not pairs "
-                "of M and E with at most one * between the two: the scan "
-                "over stacked layers is written for that form alone")
+        _check_groups(pattern)
         if self.ssm_heads % self.ssm_groups:
             raise ValueError(f"{self.ssm_heads} heads do not divide into "
                              f"{self.ssm_groups} groups")
+
+
+#: A GROUP of a ``layer_pattern``: at most one recurrent mixer (M Mamba-2, L
+#: lightning linear attention), at most one attention layer behind it (* over
+#: every earlier key, S over chosen blocks of keys), then ONE feed-forward (E
+#: an expert layer, D a dense one). The programs scan the stacked groups
+#: (hybrid.groups_of): Nemotron-H's pairs of M and E with a * between some
+#: are one instance, a layer of a mixer and its feed-forward another.
+GROUP = r"[ML]?[*S]?[ED]"
+
+
+def _check_groups(pattern: str) -> None:
+    if not re.fullmatch(f"({GROUP})+", pattern):
+        raise UnsupportedBlockError(
+            "the layer scan", f"layer_pattern {pattern!r} is not groups of "
+            "at most one recurrent mixer, at most one attention layer and "
+            "one feed-forward (Nemotron-H's pairs of M and E with a * "
+            "between some are such groups): the scan over stacked groups is "
+            "written for that form alone")
+
+
+@dataclasses.dataclass
+class MiniCPMSALASpec(ModelSpec):
+    """The MiniCPM-SALA block (openbmb/MiniCPM-SALA, ``minicpm_sala``):
+    every layer is ``h + a Mixer(RMS(h))`` then ``h + a MLP(RMS(h))`` with
+    ``a = residual_scale``, the mixer one of two kinds by the layer; what it
+    states beyond ModelSpec's fields. The programs are engine/hybrid.py's,
+    which has the equations."""
+    # Two letters a layer, one a SUBLAYER: L a lightning linear-attention
+    # mixer, S attention over chosen blocks of keys, each followed by D, the
+    # dense SwiGLU feed-forward (config.GROUP).
+    layer_pattern: str | None = None
+    # The lightning mixer: ssm_heads heads, a state S [ssm_head_dim (v),
+    # ssm_state (k)] float32 a head and NO convolution (ssm_conv 0: a row
+    # keeps the state alone); a head's keys are its own (ssm_groups =
+    # ssm_heads). Prefill computes the recurrence in chunks of ssm_chunk.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 0
+    ssm_state: int = 0
+    ssm_chunk: int = 128
+    # q and k are RMS-normalised a head, times a weight [head_dim].
+    qk_norm: bool = True
+    # muP: x0 = scale_emb * E[token]; every sublayer's output times
+    # residual_scale (scale_depth / sqrt(num_hidden_layers)); the final
+    # norm's output over logit_divisor (hidden_size / dim_model_base).
+    scale_emb: float = 1.0
+    residual_scale: float = 1.0
+    logit_divisor: float = 1.0
+    # Attention over chosen blocks (InfLLM-V2): compressed keys are means of
+    # sparse_kernel keys every sparse_stride; a query keeps sparse_topk
+    # blocks of sparse_block keys a KV group, among them always the first
+    # sparse_init_blocks and those of the last sparse_window keys.
+    sparse_kernel: int = 32
+    sparse_stride: int = 16
+    sparse_block: int = 64
+    sparse_topk: int = 64
+    sparse_init_blocks: int = 1
+    sparse_window: int = 2048
+
+    def __post_init__(self):
+        super().__post_init__()
+        pattern = self.layer_pattern or ""
+        if len(pattern) != 2 * self.num_layers or set(pattern) - set("LSD"):
+            raise ValueError(f"layer_pattern {pattern!r} does not give "
+                             f"{self.num_layers} layers of L or S, then D")
+        _check_groups(pattern)
+        if self.sparse_kernel != 2 * self.sparse_stride:
+            raise UnsupportedBlockError(
+                "the compressed-key array", f"a compressed key of "
+                f"{self.sparse_kernel} keys every {self.sparse_stride} is "
+                "not two strides: the array holds a mean a stride and a "
+                "compressed key is the mean of two of them")
+        if (self.sparse_block % self.sparse_stride
+                or self.sparse_window < 2 * self.sparse_block):
+            raise UnsupportedBlockError(
+                "attention over chosen blocks", f"blocks of "
+                f"{self.sparse_block} keys are not whole strides of "
+                f"{self.sparse_stride}, or the window of "
+                f"{self.sparse_window} keys every query keeps is under two "
+                "blocks (the block a window's tokens are written in is kept "
+                "by the window alone)")
 
 
 class UnsupportedBlockError(NotImplementedError):
@@ -994,6 +1209,12 @@ def block_refusals(spec: ModelSpec, config: "EngineConfig | None" = None,
             "disk tiers)", "a parcel is pages, and a row of this model also "
             "holds a recurrent state a layer, which has no parcel: pages "
             "without the state at their border continue nothing"))
+    if kv_transfer and spec.compressed_keys:
+        out.append(UnsupportedBlockError(
+            "a KV parcel (KV-plane tickets, disaggregated insert, host and "
+            "disk tiers)", "a parcel is K and V pages, and a page of this "
+            "model also has a row of the compressed-key array, which no "
+            "parcel carries: pages inserted without it are never chosen"))
     if checkpoint and recurrent:
         out.append(UnsupportedBlockError(
             "the safetensors loader", "it has no tensor-name map for the "
@@ -1009,6 +1230,8 @@ def block_refusals(spec: ModelSpec, config: "EngineConfig | None" = None,
         out += _latent_refusals(spec, config)
     if recurrent:
         out += _recurrent_refusals(spec, config)
+    if spec.compressed_keys:
+        out += _compressed_refusals(spec, config)
     if share and config.tp * config.pp * config.dp * config.sp > 1:
         out.append(UnsupportedBlockError(
             "a tp/pp/dp/sp mesh", f"the expert layer is told ONE share "
@@ -1111,6 +1334,42 @@ def _recurrent_refusals(spec: ModelSpec, config: "EngineConfig"
             "int8 KV pages (quant_kv)", "the programs of a block with "
             "recurrent layers (engine/hybrid.py) were compared with their "
             "reference over a bfloat16 pool alone"))
+    return out
+
+
+def _compressed_refusals(spec: ModelSpec, config: "EngineConfig"
+                         ) -> list[UnsupportedBlockError]:
+    """block_refusals' part for attention over chosen blocks of keys
+    (``spec.compressed_keys``): every engine path that knows two pool
+    arrays and a row's whole context, by what it lacks."""
+    out = []
+    if config.page_size % spec.sparse_block:
+        out.append(UnsupportedBlockError(
+            f"a pool of pages of {config.page_size} tokens", "the chosen "
+            f"blocks of {spec.sparse_block} keys are read as parts of a "
+            "page and a page's stripes are the page's own keys': a page "
+            "has to be whole blocks (page_size \"auto\" resolves to one)"))
+    if config.spec_decode:
+        out.append(UnsupportedBlockError(
+            f"speculative decoding (spec_decode {config.spec_decode})",
+            "the verify step scores every key in context for several "
+            "query positions and has no choice of blocks a position, nor "
+            "the compressed keys of the drafted tokens"))
+    if config.host_cache_pages > 0 or config.kv_disk_cache_dir:
+        out.append(UnsupportedBlockError(
+            "the host and disk KV tiers (kvbm)", "they move parcels of K "
+            "and V pages, and a page of this model also has a row of the "
+            "compressed-key array, which no tier holds"))
+    if config.tp * config.pp * config.dp * config.sp > 1:
+        out.append(UnsupportedBlockError(
+            "a tp/pp/dp/sp mesh", "the chosen blocks are read through a "
+            "table over the pool seen as blocks of ONE KV head, and the "
+            "compressed-key array has no partitioning rule"))
+    if config.resolve_quant_kv() is not None:
+        out.append(UnsupportedBlockError(
+            "int8 KV pages (quant_kv)", "the compressed keys are means of "
+            "bfloat16 keys read back from the pool's last rows, and no "
+            "scale is written down for a mean of int8 rows"))
     return out
 
 
@@ -1530,16 +1789,19 @@ class EngineConfig:
         _, writer = pool_access(self.attention_backend, "tpu", self.mesh_size,
                                 m.head_dim, self.resolve_quant_kv(),
                                 m.latent)
+        # Attention over chosen blocks reads a block as part of a page: a
+        # page is at least one (ModelSpec.sparse_block; 0 elsewhere).
+        least = max(DEFAULT_PAGE_SIZE, m.sparse_block)
         if writer != "in_place":
-            return DEFAULT_PAGE_SIZE
+            return least
         if platform is None:
             import jax
             platform = jax.devices()[0].platform
         if platform != "tpu":
-            return DEFAULT_PAGE_SIZE
+            return least
         heads, widths = m.kv_entry
         copy_bytes = heads * widths[0] * 2  # a token row, bf16
-        page = DEFAULT_PAGE_SIZE
+        page = least
         while page < MAX_PAGE_SIZE and page * copy_bytes < PAGE_COPY_BYTES:
             page *= 2
         return page
@@ -1580,7 +1842,8 @@ class EngineConfig:
             per_head = sum(w + 4 for w in widths)  # KV_SCALE_BYTES
         else:
             per_head = 2 * sum(widths)
-        return m.pool_layers * heads * per_head
+        # (and the third array's share, where the block has one)
+        return m.pool_layers * heads * per_head + m.comp_key_bytes_per_token
 
     def lora_target_shapes(self) -> dict[str, tuple[int, int]]:
         """(d_in, d_out) per LoRA target projection for this model —

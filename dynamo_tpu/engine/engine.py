@@ -1146,6 +1146,21 @@ class TPUEngine(AsyncEngine):
                 "selected_pct": round(100.0 * selected / context, 3)
                 if context else None,
             }
+        if self.runner.spec.compressed_keys:
+            spec = self.runner.spec
+            selected, context = total["attn_selected"], total["attn_context"]
+            status["attn"] = {
+                # Blocks of keys a query attends at most, a KV group.
+                "topk_blocks": spec.sparse_topk,
+                "block": spec.sparse_block,
+                # K, V and the compressed-key array's share, every
+                # attention layer (config.kv_token_bytes).
+                "kv_entry_bytes": self.config.kv_token_bytes(),
+                # Keys of the kept blocks over keys in context, live rows,
+                # every attention layer and decode step so far.
+                "selected_pct": round(100.0 * selected / context, 3)
+                if context else None,
+            }
         if self.runner.spec.recurrent:
             spec = self.runner.spec
             status["ssm"] = {

@@ -1,7 +1,9 @@
-"""The Nemotron-H block's programs: layers of ONE mixer each, three kinds.
+"""The programs of a block whose layers are GROUPS of sublayers of ONE mixer
+or feed-forward each, every sublayer ``h <- h + a f(RMS(h; w, eps))`` with
+``a = spec.residual_scale``.
 
-``spec.layer_pattern`` gives every layer its kind, and a layer is
-``h <- h + Mixer(RMS(h; w, eps))``:
+``spec.layer_pattern`` gives every sublayer its kind, a letter each
+(config.GROUP):
 
 - **M, Mamba-2.** ``[z | xBC | dt] = u W_in``; ``xBC_t = silu(b_c + sum_j
   w_c[j] xBC_{t-K+1+j})`` (causal, depthwise, K taps, zeros before the
@@ -11,27 +13,64 @@
   ``y_t = S_t C_t[g] + D[h] x_t``; ``y = y silu(z)``, RMS-normalised within
   each group, times a weight; ``out = y W_out``. A row keeps S and the last
   K - 1 inputs of the convolution, a layer (``spec.ssm_state_shapes``).
+- **L, lightning linear attention.** ``[q | k | v | z] = u W_in``, heads of
+  ``ssm_head_dim``; q and k RMS-normalised a head (times a weight) and
+  rotated (rotate-half, every lane); a head's constant decay ``lambda_h``
+  (``lightning_decay``); state S [head_dim (v), head_dim (k)] float32 a
+  head: ``S_t = lambda_h S_{t-1} + v_t (x) k_t``, ``o_t = S_t q_t /
+  sqrt(head_dim)``: the recurrence above with ``dx = v``, ``B = k``,
+  ``C = q / sqrt(d)``, a group a head, no convolution and no D. ``o`` is
+  RMS-normalised a head (times a weight), times ``sigmoid(z)``; ``out = o
+  W_out``. The SAME state arrays, chunked prefill and decode kernel as M.
 - **E, an expert layer:** model.ffn_block (sigmoid router with a selection
   bias, two-matrix relu2 experts, one shared expert of its own width); a
   prefill over model.MOE_DENSE_MAX_ROWS rows sends each row to its own
-  experts (``scan_pairs`` hands the kernel of engine/experts.py the expert
-  stacks whole), a window step multiplies every held expert.
+  experts (``scan_groups`` hands the kernel of engine/experts.py the expert
+  stacks whole), a window step multiplies every held expert. **D, a dense
+  feed-forward:** the same function's dense branch (SwiGLU).
 - **\\*, attention:** grouped-query, causal, NO rotary embedding; K and V go
-  to the pool, whose layers are these alone.
+  to the pool, whose layers are the attention layers alone.
+- **S, attention over chosen BLOCKS of keys** (InfLLM-V2): as \\*, q and k
+  RMS-normalised a head, and a query attends ``sparse_topk`` blocks of
+  ``sparse_block`` keys a KV group: a compressed key is the mean of
+  ``sparse_kernel`` keys every ``sparse_stride``; ``p_h = softmax_j(q_h .
+  kc_j / sqrt(d))`` over the compressed keys whose keys are all at or
+  before the query; a group's score of j is the sum of p_h over its heads,
+  a block's the largest over the compressed keys that overlap it; the
+  first ``sparse_init_blocks`` and the ``sparse_window / sparse_block``
+  blocks that end with the query's own always stay; ties to the lower
+  block. The output is gated by ``sigmoid(u W_z)``. What a token leaves
+  beside K and V is its share of a STRIPE (the mean of ``sparse_stride``
+  keys), in a third array under the same page table
+  (``spec.comp_key_shape``): a compressed key is the mean of two stripes.
+  Decode scores the stripes, chooses, and reads the chosen blocks ALONE:
+  the pool seen as blocks of one KV head each is walked by the reader that
+  walks a page table (model.kv_attention: the Pallas kernel or XLA's
+  gather), over a table [rows x KV heads, sparse_topk] of block ids in
+  rising order, so that the one partly filled block is the last and the
+  reader's length masks it. Prefill masks dense scores by blocks, a chunk
+  of queries at a time.
 
-The pattern is pairs (M, E), some with a * between the two (``pairs_of``),
-and every program here is ONE scan over the stacked pairs with the
-attention layer under a ``lax.cond`` that indexes its own stack: 52 layers
-unrolled, eight steps a window, do not compile in a set-up anyone waits
-for. The recurrent state rides the scan's carry and each layer updates its
-own rows in place; the pool is read-only inside a scan as in model.py.
+A group is at most one recurrent mixer, at most one attention layer, then a
+feed-forward (``groups_of``): Nemotron-H's pairs of M and E with a * between
+some, MiniCPM-SALA's layers of L or S and D. Every program here is ONE scan
+over the stacked groups in which a sublayer that only some groups have lies
+under a ``lax.cond`` that indexes its own stack: 52 layers unrolled, eight
+steps a window, do not compile in a set-up anyone waits for. The recurrent
+state rides the scan's carry and each layer updates its own rows in place;
+it never rides a conditional (a branch that hands a 1.2 GB carry through
+may copy it): where only some groups have a recurrent mixer its
+projections lie under the ``lax.cond`` and the update runs in every group,
+over no row where the group has none (``Mixer``). The pool is read-only
+inside a scan as in model.py.
 
 Prefill computes the recurrence in chunks of ``spec.ssm_chunk`` tokens as
-matrix products (``ssm_chunked``: within a chunk the decayed (C_l . B_s)
-matrix times dt x, between chunks the state at each border); decode takes
-one token (``ssm_step``). Past a row's last real token dt is 0 (the state
-stands: exp(0) S + 0) and the convolution keeps the last real inputs, so a
-padded batch, a dead slot and a frozen row leave a state exactly as it was.
+matrix products (``chunked_recurrence``: within a chunk the decayed (C_l .
+B_s) matrix times dt x, between chunks the state at each border); decode
+takes one token (``ssm_step``, ``lightning_step``). Past a row's last real
+token dt is 0 (the state stands: exp(0) S + 0) and the convolution keeps the
+last real inputs, so a padded batch, a dead slot and a frozen row leave a
+state exactly as it was.
 
 Who updates the float32 state in a decode step (``Backends.ssm``, decided
 by backends.choose as the pool's reader is, and beside it): where the
@@ -40,8 +79,8 @@ which is handed the stack over all layers where it lies and visits the
 live slots of one layer (``ssm_step_live``: a dead slot is neither read nor
 written, and the new state is read by C in the pass that writes it);
 everywhere else (the CPU backend, any mesh, a runner asked for the XLA
-reader) XLA through ``ssm_step``, over every slot of the layer's slice.
-``ssm_step`` is the definition the kernel is held to. Prefill is XLA's
+reader) XLA through ``ssm_step`` / ``lightning_step``, over every slot of
+the layer's slice: the definitions the kernel is held to. Prefill is XLA's
 under either, and so is the convolution's state.
 """
 
@@ -49,48 +88,53 @@ from __future__ import annotations
 
 import math
 import re
-from typing import NamedTuple
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from dynamo_tpu.engine.backends import XLA, Backends
-from dynamo_tpu.engine.config import ModelSpec
+from dynamo_tpu.engine.config import GROUP, ModelSpec
 from dynamo_tpu.engine.kv_quant import gather_pages_folded, scatter_pages
-from dynamo_tpu.engine.model import (Params, _split_heads,
-                                     dense_causal_attention, embed_lookup,
-                                     expert_product, ffn_block,
+from dynamo_tpu.engine.model import (LATENT_SCORE_BYTES, Params, _split_heads,
+                                     apply_rope, dense_causal_attention,
+                                     embed_lookup, expert_product, ffn_block,
                                      history_attention, kv_attention,
                                      layer_of, lm_logits, mm, rms_norm,
-                                     whole_expert_leaves)
+                                     rope_tables, whole_expert_leaves)
 from dynamo_tpu.engine.perf import scope
 from dynamo_tpu.engine.recurrence import state_step
 
-ATTN_LEAVES = ("wq", "wk", "wv", "wo")
+ATTN_LEAVES = ("wq", "wk", "wv", "wo", "wz", "q_norm", "k_norm")
+FFN_LEAVES = ("w_gate", "w_up", "w_down")
 
 
-class Pairs(NamedTuple):
-    """``spec.layer_pattern`` as its (M, E) pairs: for pair p the index
-    among ALL layers of its M, of its E and of the * between them (-1:
-    none), and that attention layer's index in its own stack."""
-    mamba: tuple
-    expert: tuple
+class Groups(NamedTuple):
+    """``spec.layer_pattern`` as its groups (config.GROUP): for group p the
+    index among ALL sublayers of its recurrent mixer, of the attention
+    layer behind it (-1: none) and of its feed-forward, and the mixer's and
+    the attention layer's index in the stack of their kind (-1: none)."""
+    mixer_layer: tuple
+    mixer_index: tuple
     attn_layer: tuple
     attn_index: tuple
+    ffn_layer: tuple
 
 
-def pairs_of(spec: ModelSpec) -> Pairs:
-    mamba, expert, attn_layer, attn_index = [], [], [], []
-    seen = 0
-    for found in re.finditer(r"M(\*?)E", spec.layer_pattern):
-        star = bool(found.group(1))
-        mamba.append(found.start())
-        expert.append(found.end() - 1)
-        attn_layer.append(found.start() + 1 if star else -1)
-        attn_index.append(seen if star else -1)
-        seen += star
-    return Pairs(tuple(mamba), tuple(expert), tuple(attn_layer),
-                 tuple(attn_index))
+def groups_of(spec: ModelSpec) -> Groups:
+    out = Groups([], [], [], [], [])
+    mixers = attns = 0
+    for found in re.finditer(GROUP, spec.layer_pattern):
+        at, kinds = found.start(), found.group()
+        mixer, attn = kinds[0] in "ML", any(c in "*S" for c in kinds)
+        out.mixer_layer.append(at if mixer else -1)
+        out.mixer_index.append(mixers if mixer else -1)
+        out.attn_layer.append(at + mixer if attn else -1)
+        out.attn_index.append(attns if attn else -1)
+        out.ffn_layer.append(found.end() - 1)
+        mixers += mixer
+        attns += attn
+    return Groups(*(tuple(a) for a in out))
 
 
 # ---------------------------------------------------------------------------
@@ -126,35 +170,64 @@ def _split_xbc(xbc: jax.Array, spec: ModelSpec):
             b.reshape(*lead, g, n), c.reshape(*lead, g, n))
 
 
+def _rms_within(y: jax.Array, parts: int, eps: float) -> jax.Array:
+    """y [..., inner] float32 RMS-normalised within each of ``parts`` equal
+    parts of its last axis (a group, a head), bfloat16."""
+    split = y.reshape(*y.shape[:-1], parts, -1)
+    var = jnp.mean(split * split, axis=-1, keepdims=True)
+    return (split * jax.lax.rsqrt(var + eps)).reshape(y.shape).astype(
+        jnp.bfloat16)
+
+
 def _gated_out(y: jax.Array, z: jax.Array, lp: dict, spec: ModelSpec):
     """y silu(z), RMS-normalised within each group, times the weight,
     through W_out. y [..., inner] float32."""
     y = y * jax.nn.silu(z.astype(jnp.float32))
-    grouped = y.reshape(*y.shape[:-1], spec.ssm_groups, -1)
-    var = jnp.mean(grouped * grouped, axis=-1, keepdims=True)
-    grouped = grouped * jax.lax.rsqrt(var + spec.rms_norm_eps)
-    y = grouped.reshape(y.shape).astype(jnp.bfloat16) * lp["ssm_gate_norm"]
+    y = (_rms_within(y, spec.ssm_groups, spec.rms_norm_eps)
+         * lp["ssm_gate_norm"])
     return mm(y, lp["ssm_w_out"], "...d,dh->...h")
 
 
 def _token(h: jax.Array, lp: dict, spec: ModelSpec, conv: jax.Array,
            live: jax.Array):
-    """What one token a row hands the recurrence: (z, x [B,G,Hg,P], B and C
-    [B,G,N], dt and dt A [B, heads], all float32 but z; conv with the token
-    behind its last inputs where the row is ``live``)."""
-    z, xbc, dt_raw = _project(h, lp, spec)
+    """``_token_of`` of h's projections, behind their z."""
+    parts = _project(h, lp, spec)
+    return (parts[0], *_token_of(parts, lp, spec, conv, live))
+
+
+def _token_of(parts: tuple, lp: dict, spec: ModelSpec, conv: jax.Array,
+              live: jax.Array):
+    """What one token a row hands the recurrence: (x [B,G,Hg,P], B and C
+    [B,G,N], dt and dt A [B, heads], all float32; conv with the token
+    behind its last inputs where the row is ``live``) of its projections
+    ``parts`` (``_project``)."""
+    _, xbc, dt_raw = parts
     full = jnp.concatenate([conv, xbc[:, None].astype(conv.dtype)], axis=1)
     taps = lp["ssm_conv_w"].astype(jnp.float32)                # [K, C]
     xbc = jax.nn.silu(jnp.sum(full.astype(jnp.float32) * taps, axis=1)
                       + lp["ssm_conv_bias"][:, 0].astype(jnp.float32))
     conv = jnp.where(live[:, None, None], full[:, 1:], conv)
-    return (z, *_split_xbc(xbc, spec), *_steps(dt_raw, lp, live), conv)
+    return (*_split_xbc(xbc, spec), *_steps(dt_raw, lp, live), conv)
 
 
 def _skip(lp: dict, x: jax.Array):
     """D x: what a token gives its own output past the state."""
     g, hg = x.shape[1], x.shape[2]
     return lp["ssm_d"][:, 0].astype(jnp.float32).reshape(g, hg, 1) * x
+
+
+def state_update(state: jax.Array, decay: jax.Array, dx: jax.Array,
+                 bb: jax.Array, cc: jax.Array):
+    """One token of the recurrence over every row, XLA's: state [B, heads,
+    head_dim, N] float32, decay [B, heads], dx [B, G, Hg, P], bb and cc
+    [B, G, N]: ``S <- decay S + dx (x) B``; returns (``S C`` [B, G, Hg, P],
+    S). What the kernel of engine/recurrence.py is held to."""
+    b, g, hg, _ = dx.shape
+    grouped = state.reshape(b, g, hg, *state.shape[2:])
+    grouped = (decay.reshape(b, g, hg, 1, 1) * grouped
+               + dx[..., None] * bb[:, :, None, None, :])
+    y = jnp.sum(grouped * cc[:, :, None, None, :], axis=-1)
+    return y, grouped.reshape(state.shape)
 
 
 def ssm_step(h: jax.Array, lp: dict, spec: ModelSpec, state: jax.Array,
@@ -164,15 +237,10 @@ def ssm_step(h: jax.Array, lp: dict, spec: ModelSpec, state: jax.Array,
     hidden], state, conv); a row that is not live keeps both."""
     b = h.shape[0]
     z, x, bb, cc, dt, da, conv = _token(h, lp, spec, conv, live)
-    g, hg = x.shape[1], x.shape[2]
-    decay = jnp.exp(da).reshape(b, g, hg, 1, 1)
-    dx = dt.reshape(b, g, hg, 1) * x                           # [B,G,Hg,P]
-    grouped = state.reshape(b, g, hg, *state.shape[2:])
-    grouped = decay * grouped + dx[..., None] * bb[:, :, None, None, :]
-    y = jnp.sum(grouped * cc[:, :, None, None, :], axis=-1)    # [B,G,Hg,P]
+    dx = dt.reshape(*x.shape[:3], 1) * x                       # [B,G,Hg,P]
+    y, state = state_update(state, jnp.exp(da), dx, bb, cc)
     y = y + _skip(lp, x)
-    return (_gated_out(y.reshape(b, -1), z, lp, spec),
-            grouped.reshape(state.shape), conv)
+    return _gated_out(y.reshape(b, -1), z, lp, spec), state, conv
 
 
 def live_walk(live: jax.Array) -> tuple:
@@ -201,28 +269,18 @@ def ssm_step_live(h: jax.Array, lp: dict, spec: ModelSpec, states: jax.Array,
     return _gated_out(y.reshape(b, -1), z, lp, spec), states, conv
 
 
-def ssm_chunked(h: jax.Array, lp: dict, spec: ModelSpec, state: jax.Array,
-                conv: jax.Array, valid: jax.Array, seq_lens: jax.Array):
-    """A chunk of a prompt a row. h [B, S, hidden] (normed), ``state`` and
-    ``conv`` what the rows hold as the chunk starts (zeros at position 0),
-    valid [B, S], seq_lens [B] the real tokens. Returns (out [B, S,
-    hidden], state and conv at each row's LAST REAL token)."""
-    b, s, _ = h.shape
-    taps_n = spec.ssm_conv
-    z, xbc, dt_raw = _project(h, lp, spec)
-    full = jnp.concatenate([conv, xbc.astype(conv.dtype)], axis=1)
-    taps = lp["ssm_conv_w"].astype(jnp.float32)
-    acc = lp["ssm_conv_bias"][:, 0].astype(jnp.float32)
-    for j in range(taps_n):
-        acc = acc + taps[j] * full[:, j:j + s].astype(jnp.float32)
-    xbc = jax.nn.silu(acc)
-    # The last K - 1 real inputs: input t lies at t + K - 1 of ``full``.
-    last = seq_lens[:, None] + jnp.arange(taps_n - 1)[None, :]
-    conv = jnp.take_along_axis(full, last[:, :, None], axis=1)
-    x, bb, cc = _split_xbc(xbc, spec)   # [B,S,G,Hg,P], [B,S,G,N] float32
-    g, hg = x.shape[2], x.shape[3]
-    dt, da = _steps(dt_raw, lp, valid)                      # [B, S, heads]
-    q = min(spec.ssm_chunk, s)
+def chunked_recurrence(x: jax.Array, bb: jax.Array, cc: jax.Array,
+                       dt: jax.Array, da: jax.Array, state: jax.Array,
+                       q: int, skip: jax.Array | None = None):
+    """The recurrence over a chunk of a prompt a row, in chunks of ``q``
+    tokens as matrix products. x [B,S,G,Hg,P], bb and cc [B,S,G,N], dt and
+    da [B,S,heads] (the step and its log-decay, both 0 past a row's last
+    real token), all float32; ``state`` [B, heads, P, N] what the rows hold
+    as the chunk starts; ``skip`` [G,Hg,1] (None: none) what a token gives
+    its own output past the state. Returns (y [B, S, heads * P] float32,
+    the state at each row's LAST REAL token)."""
+    b, s, g, hg, _ = x.shape
+    q = min(q, s)
     pad = -s % q
     if pad:     # dt 0 and x 0 past the end: the state stands
         x, bb, cc, dt, da = (jnp.pad(a, ((0, 0), (0, pad))
@@ -271,13 +329,432 @@ def ssm_chunked(h: jax.Array, lp: dict, spec: ModelSpec, state: jax.Array,
         border, state.reshape(b, g, hg, *state.shape[2:]),
         (lead(added), lead(through), lead(cc16), lead(cum)))
     y = y + jnp.moveaxis(y_in, 0, 1)
-    y = y + lp["ssm_d"][:, 0].astype(jnp.float32).reshape(g, hg, 1) * x
-    y = y.reshape(b, nc * q, -1)[:, :s]
-    return (_gated_out(y, z, lp, spec), grouped.reshape(state.shape), conv)
+    if skip is not None:
+        y = y + skip * x
+    return y.reshape(b, nc * q, -1)[:, :s], grouped.reshape(state.shape)
+
+
+def ssm_chunked(h: jax.Array, lp: dict, spec: ModelSpec, state: jax.Array,
+                conv: jax.Array, valid: jax.Array, seq_lens: jax.Array):
+    """A chunk of a prompt a row. h [B, S, hidden] (normed), ``state`` and
+    ``conv`` what the rows hold as the chunk starts (zeros at position 0),
+    valid [B, S], seq_lens [B] the real tokens. Returns (out [B, S,
+    hidden], state and conv at each row's LAST REAL token)."""
+    parts = _project(h, lp, spec)
+    y, state, conv = _ssm_chunk(parts, lp, spec, state, conv, valid,
+                                seq_lens)
+    return _gated_out(y, parts[0], lp, spec), state, conv
+
+
+def _ssm_chunk(parts: tuple, lp: dict, spec: ModelSpec, state: jax.Array,
+               conv: jax.Array, valid: jax.Array, seq_lens: jax.Array):
+    """``ssm_chunked`` between its projections: (y [B, S, inner] float32,
+    state, conv)."""
+    _, xbc, dt_raw = parts
+    s = xbc.shape[1]
+    taps_n = spec.ssm_conv
+    full = jnp.concatenate([conv, xbc.astype(conv.dtype)], axis=1)
+    taps = lp["ssm_conv_w"].astype(jnp.float32)
+    acc = lp["ssm_conv_bias"][:, 0].astype(jnp.float32)
+    for j in range(taps_n):
+        acc = acc + taps[j] * full[:, j:j + s].astype(jnp.float32)
+    xbc = jax.nn.silu(acc)
+    # The last K - 1 real inputs: input t lies at t + K - 1 of ``full``.
+    last = seq_lens[:, None] + jnp.arange(taps_n - 1)[None, :]
+    conv = jnp.take_along_axis(full, last[:, :, None], axis=1)
+    x, bb, cc = _split_xbc(xbc, spec)   # [B,S,G,Hg,P], [B,S,G,N] float32
+    g, hg = x.shape[2], x.shape[3]
+    dt, da = _steps(dt_raw, lp, valid)                      # [B, S, heads]
+    y, state = chunked_recurrence(
+        x, bb, cc, dt, da, state, spec.ssm_chunk,
+        lp["ssm_d"][:, 0].astype(jnp.float32).reshape(g, hg, 1))
+    return y, state, conv
 
 
 # ---------------------------------------------------------------------------
-# The scan over pairs
+# The lightning linear-attention mixer
+# ---------------------------------------------------------------------------
+
+def lightning_decay(spec: ModelSpec) -> jax.Array:
+    """[heads] float32: head h's constant decay exp(-2^(-8 h / heads)), h
+    from 1 (the ALiBi slopes of Lightning Attention-2; the configuration
+    states no scaling by layer, so there is none: another law is another
+    table here and no program)."""
+    n = spec.ssm_heads
+    return jnp.exp(-(2.0 ** (-8.0 * jnp.arange(1, n + 1, dtype=jnp.float32)
+                             / n)))
+
+
+def _lightning_project(h: jax.Array, lp: dict, spec: ModelSpec,
+                       positions: jax.Array):
+    """(q, k, v [..., heads, d], z [..., inner]) of normed h [..., hidden]
+    at ``positions`` [...]: q and k normalised a head and rotated."""
+    n, d = spec.ssm_heads, spec.ssm_head_dim
+    q, k, v, z = jnp.split(mm(h, lp["ssm_w_in"], "...h,hd->...d"), 4,
+                           axis=-1)
+    cos, sin = rope_tables(positions, d, spec.rope_theta)
+    q = apply_rope(rms_norm(_split_heads(q, n, d), lp["ssm_q_norm"],
+                            spec.rms_norm_eps), cos, sin)
+    k = apply_rope(rms_norm(_split_heads(k, n, d), lp["ssm_k_norm"],
+                            spec.rms_norm_eps), cos, sin)
+    return q, k, _split_heads(v, n, d), z
+
+
+def _lightning_terms(parts: tuple, spec: ModelSpec, live: jax.Array):
+    """What the recurrence takes of (q, k, v, z), float32: dx = v (0 where
+    ``live`` [...] is not), B = k, C = q / sqrt(d), the step (1 or 0) and
+    its log-decay [..., heads]: the state of a row that is not live
+    stands."""
+    q, k, v, _ = parts
+    on = live[..., None].astype(jnp.float32)
+    step = jnp.broadcast_to(on, (*live.shape, spec.ssm_heads))
+    return (v.astype(jnp.float32)[..., None, :] * on[..., None, None],
+            k.astype(jnp.float32),
+            q.astype(jnp.float32) * spec.ssm_head_dim ** -0.5,
+            step, step * jnp.log(lightning_decay(spec)))
+
+
+def _lightning_out(y: jax.Array, parts: tuple, lp: dict, spec: ModelSpec):
+    """y [..., inner] float32 RMS-normalised a head, times the weight,
+    times sigmoid(z), through W_out."""
+    y = _rms_within(y, spec.ssm_heads, spec.rms_norm_eps) * lp["ssm_out_norm"]
+    y = y * jax.nn.sigmoid(parts[3].astype(jnp.float32)).astype(jnp.bfloat16)
+    return mm(y, lp["ssm_w_out"], "...d,dh->...h")
+
+
+def lightning_step(h: jax.Array, lp: dict, spec: ModelSpec, state: jax.Array,
+                   positions: jax.Array, live: jax.Array):
+    """One token a row, XLA's. h [B, hidden] (normed), state [B, heads,
+    head_dim, head_dim] float32, positions and live [B]. Returns (out [B,
+    hidden], state); a row that is not live keeps its own."""
+    parts = _lightning_project(h, lp, spec, positions)
+    dx, bb, cc, _, da = _lightning_terms(parts, spec, live)
+    y, state = state_update(state, jnp.exp(da), dx, bb, cc)
+    return _lightning_out(y.reshape(h.shape[0], -1), parts, lp, spec), state
+
+
+#: Tokens of a prompt chunk that the lightning recurrence computes at once:
+#: its float32 terms (v, k, q, the decayed products of a chunk, what each
+#: chunk adds to the state) are 16 KB a token and 8,192 tokens of them 1.3
+#: GB of temporaries, where a v5e has 2 GB beside this model's weights, state
+#: and pool (compiled for a described v5e, PR 45: 8 x 1,024 rows 2.4 GB
+#: whole). Rows are independent and go a group at a time; one row's tokens
+#: go a block at a time, the state carried from block to block.
+LIGHTNING_BLOCK_TOKENS = 2048
+
+
+def lightning_recurrence(parts: tuple, spec: ModelSpec, state: jax.Array,
+                         valid: jax.Array,
+                         limit: int = LIGHTNING_BLOCK_TOKENS):
+    """The recurrence of (q, k, v [B, S, heads, d], z) over a chunk of a
+    prompt a row, ``limit`` tokens at a time: (y [B, S, inner] float32, the
+    state at each row's LAST REAL token). ``valid`` [B, S]."""
+    q, k, v, _ = parts
+    b, s = valid.shape
+
+    def some(q, k, v, valid, state):
+        return chunked_recurrence(
+            *_lightning_terms((q, k, v, None), spec, valid), state,
+            spec.ssm_chunk)
+
+    if b * s <= limit:
+        return some(q, k, v, valid, state)
+    if b > 1:
+        rows = max(1, limit // s)
+        while b % rows:
+            rows -= 1
+        groups = lambda a: a.reshape(b // rows, rows, *a.shape[1:])  # noqa: E731
+        y, state = jax.lax.map(
+            lambda x: lightning_recurrence((*x[:3], None), spec, x[4], x[3],
+                                           limit),
+            tuple(groups(a) for a in (q, k, v, valid, state)))
+        return y.reshape(b, s, -1), state.reshape(b, *state.shape[2:])
+    # One row's tokens a block at a time, the state carried.
+    size = limit
+    while s % size:
+        size //= 2
+    blocks = lambda a: jnp.moveaxis(  # noqa: E731
+        a.reshape(b, s // size, size, *a.shape[2:]), 1, 0)
+
+    def block(state, x):
+        y, state = some(*x, state)
+        return state, y
+
+    state, y = jax.lax.scan(block, state, tuple(
+        blocks(a) for a in (q, k, v, valid)))
+    return jnp.moveaxis(y, 0, 1).reshape(b, s, -1), state
+
+
+# ---------------------------------------------------------------------------
+# Attention over chosen blocks of keys
+# ---------------------------------------------------------------------------
+
+def stripe_means(k: jax.Array, stride: int) -> jax.Array:
+    """k [B, S, Nkv, D] -> [B, S / stride, Nkv, D]: the mean of every
+    ``stride`` keys in a row, as the compressed-key array holds it."""
+    b, s, n, d = k.shape
+    return jnp.mean(k.astype(jnp.float32).reshape(b, s // stride, stride, n,
+                                                  d), axis=2).astype(k.dtype)
+
+
+def choose_blocks(dots: jax.Array, n_keys: jax.Array, spec: ModelSpec):
+    """The blocks a query keeps. dots [..., G, Hg, NS] float32: a head's
+    query times stripe i of its KV group (unscaled; an entry is read only
+    where the stripe's keys are all among the query's ``n_keys`` [...]: the
+    keys at or before the query, itself among them). Returns (blocks
+    [..., G, K] int32, kept [..., G, K] bool): the ``sparse_topk`` (K: all
+    of them where the dots cover fewer) blocks of highest score, a block
+    that does not exist yet not ``kept``."""
+    st, bk = spec.sparse_stride, spec.sparse_block
+    per = bk // st
+    ns = dots.shape[-1]
+    nb = ns // per
+    n = n_keys[..., None, None]
+    # Compressed key j is the mean of stripes j and j + 1.
+    score = 0.5 * (dots[..., :-1] + dots[..., 1:]) * spec.head_dim ** -0.5
+    whole = (jnp.arange(ns - 1) + 2) * st <= n[..., None]
+    top = jnp.max(jnp.where(whole, score, -jnp.inf), axis=-1, keepdims=True)
+    e = jnp.where(whole, jnp.exp(score - jnp.where(jnp.isfinite(top), top,
+                                                   0.0)), 0.0)
+    p = e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
+    group = jnp.where(whole[..., 0, :], jnp.sum(p, axis=-2), -jnp.inf)
+    # A block's score: the largest over the compressed keys that overlap
+    # it, j = per * b - 1 to per * b + per - 1.
+    lead = group.shape[:-1]
+    edge = jnp.full((*lead, 1), -jnp.inf)
+    padded = jnp.concatenate([edge, group, edge], axis=-1)
+    block = jnp.maximum(
+        jnp.max(padded[..., 1:].reshape(*lead, nb, per), axis=-1),
+        padded[..., 0:nb * per:per])
+    ids = jnp.arange(nb)
+    own = (n - 1) // bk                                        # [..., 1, 1]
+    exists = ids <= own
+    forced = ((ids < spec.sparse_init_blocks)
+              | (own - ids < spec.sparse_window // bk))
+    block = jnp.where(forced, jnp.inf, block)
+    block = jnp.where(exists, block, -jnp.inf)
+    # Equal scores keep the lower block: top_k's order; a block that does
+    # not exist ranks behind every one that does.
+    _, blocks = jax.lax.top_k(
+        jnp.where(exists, jnp.maximum(block, -1e30), -jnp.inf),
+        min(spec.sparse_topk, nb))
+    kept = jnp.take_along_axis(jnp.broadcast_to(exists, block.shape), blocks,
+                               axis=-1)
+    return blocks.astype(jnp.int32), kept
+
+
+def sparse_prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                             positions: jax.Array, valid: jax.Array,
+                             spec: ModelSpec, hist: tuple | None = None,
+                             limit: int = LATENT_SCORE_BYTES):
+    """A prefill chunk's attention over chosen blocks: dense scores under a
+    block mask a query, ``limit`` bytes of float32 scores at a time (a
+    chunk of queries). q [B,S,Nh,D], k and v [B,S,Nkv,D] the chunk's own,
+    positions and valid [B,S]; ``hist`` None (the chunk starts at 0) or
+    (k_hist, v_hist [Nkv,B,L,D], stripes [Nkv,B,L/stride,D], hist_lens
+    [B]): the row's earlier pages and their stripes, history token l at
+    position l. Returns (attn [B,S,Nh*D], the chunk's stripes [B,
+    S/stride, Nkv, D] for the compressed-key array)."""
+    b, s, nh, d = q.shape
+    nkv = k.shape[2]
+    st, bk = spec.sparse_stride, spec.sparse_block
+    own = stripe_means(k, st)
+    key_pos = positions[:, :1] + jnp.arange(s)[None, :]
+    key_ok, keys, vals, stripes = valid, k, v, own
+    if hist is not None:
+        k_hist, v_hist, s_hist, hist_lens = hist
+        old = k_hist.shape[2]
+        keys = jnp.concatenate([jnp.moveaxis(k_hist, 0, 2), k], axis=1)
+        vals = jnp.concatenate([jnp.moveaxis(v_hist, 0, 2), v], axis=1)
+        key_pos = jnp.concatenate(
+            [jnp.broadcast_to(jnp.arange(old)[None, :], (b, old)), key_pos],
+            axis=1)
+        key_ok = jnp.concatenate(
+            [jnp.arange(old)[None, :] < hist_lens[:, None], valid], axis=1)
+        # Stripe i of the row stands at i: the history's up to where the
+        # chunk starts, the chunk's own behind them.
+        both = jnp.concatenate([jnp.moveaxis(s_hist, 0, 2), own], axis=1)
+        first = positions[:, :1] // st
+        at = jnp.arange(both.shape[1])[None, :]
+        source = jnp.where(at < first, at,
+                           jnp.minimum(old // st + at - first,
+                                       both.shape[1] - 1))
+        stripes = jnp.take_along_axis(both, source[:, :, None, None], axis=1)
+    total = keys.shape[1]
+    nb = total // bk
+    # Key k of the row lies in block ``block_of[k // bk]``: whole blocks of
+    # the history's bucket, then of the chunk from where it starts (a page's
+    # border, so a block's). A query's mask over blocks is gathered a BLOCK
+    # and repeated over its keys: gathered a key it is a billion gathered
+    # elements a prompt of 8,192 (2 s of a v5e: my chip run, PR 45, call 1).
+    block_of = key_pos[:, ::bk] // bk                          # [B, nb]
+    per = s
+    while per > 16 and per % 2 == 0 and 4 * b * nh * per * total > limit:
+        per //= 2
+    qg = q.reshape(b, s // per, per, nkv, nh // nkv, d)
+
+    def some(x):
+        qc, qpos = x                     # [B, per, Nkv, G, D], [B, per]
+        with scope("attn.index"):
+            dots = jnp.einsum("bqngd,bind->bqngi", qc, stripes,
+                              preferred_element_type=jnp.float32)
+            blocks, kept = choose_blocks(dots, qpos + 1, spec)
+            chosen = jnp.any((blocks[..., None] == jnp.arange(nb))
+                             & kept[..., None], axis=-2)       # [B,per,Nkv,nb]
+        with scope("attn.core"):
+            seen = jnp.repeat(jnp.take_along_axis(
+                chosen, jnp.broadcast_to(block_of[:, None, None, :],
+                                         (b, per, nkv, nb)), axis=-1),
+                bk, axis=-1)
+            seen = (seen & key_ok[:, None, None, :]
+                    & (key_pos[:, None, None, :] <= qpos[:, :, None, None]))
+            scores = jnp.einsum("bqngd,bknd->bqngk", qc, keys,
+                                preferred_element_type=jnp.float32) * d ** -0.5
+            scores = jnp.where(seen[:, :, :, None, :], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+            return jnp.einsum("bqngk,bknd->bqngd", probs, vals)
+
+    pos = positions.reshape(b, s // per, per)
+    out = jax.lax.map(some, (jnp.moveaxis(qg, 1, 0), jnp.moveaxis(pos, 1, 0)))
+    return jnp.moveaxis(out, 0, 1).reshape(b, s, nh * d), own
+
+
+def pool_stripes(comp: jax.Array, layer: jax.Array, page_table: jax.Array,
+                 spec: ModelSpec) -> jax.Array:
+    """A row's stripes out of the compressed-key array (``spec.
+    comp_key_shape``) by its page table [B, maxP]: [Nkv, B, maxP * stripes
+    a page, D]; stripe i of the row stands at i."""
+    nkv, b = comp.shape[1], page_table.shape[0]
+    index = (jnp.broadcast_to(layer, (nkv, *page_table.shape)),
+             jnp.arange(nkv)[:, None, None],
+             jnp.broadcast_to(page_table[None], (nkv, *page_table.shape)))
+    return comp[index].reshape(nkv, b, -1, spec.head_dim)
+
+
+def window_stripes(k_cache: jax.Array, layer: jax.Array,
+                   page_table: jax.Array, hist_lens: jax.Array,
+                   k_win: jax.Array, m: jax.Array,
+                   k_self: jax.Array | None, spec: ModelSpec):
+    """The stripes that a window's own tokens complete, which the
+    compressed-key array does not hold until the window commits: (means
+    [B, W, Nkv, D] bfloat16, first [B] int32): candidate c is stripe
+    ``first + c`` of the row, the mean of its keys among the pool's last
+    (positions from first * stride up to ``hist_lens``), the window's
+    earlier tokens k_win [Nkv, B, M, D] (columns under ``m``, a scalar or
+    [B]; column j at position hist_lens + j) and the step's own key k_self
+    [B, Nkv, D] (at hist_lens + m; None: none). A candidate is whole once
+    (first + c + 1) * stride keys are among the query's; until then its
+    mean is of the keys so far and nobody reads it."""
+    st = spec.sparse_stride
+    page = k_cache.shape[3]
+    nkv, b, M, _ = k_win.shape
+    m = jnp.broadcast_to(m, (b,))
+    first = hist_lens // st
+    # The pool's share of stripe ``first``: its page's rows from the
+    # stripe's start.
+    at = first * st
+    pages = jnp.take_along_axis(
+        page_table, jnp.clip(at // page, 0, page_table.shape[1] - 1)[:, None],
+        axis=1)                                                # [B, 1]
+    rows = (at % page)[:, None] + jnp.arange(st)[None, :]      # [B, st]
+    tail = k_cache[jnp.broadcast_to(layer, (b, st, nkv)),
+                   jnp.arange(nkv)[None, None, :],
+                   pages[:, :, None], rows[:, :, None]]        # [B,st,Nkv,D]
+    recent = [tail, jnp.moveaxis(k_win, 0, 2)]
+    position = [at[:, None] + jnp.arange(st)[None, :],
+                hist_lens[:, None] + jnp.arange(M)[None, :]]
+    there = [position[0] < hist_lens[:, None],
+             jnp.arange(M)[None, :] < m[:, None]]
+    if k_self is not None:
+        recent.append(k_self[:, None])
+        position.append((hist_lens + m)[:, None])
+        there.append(jnp.ones((b, 1), bool))
+    recent, position, there = (jnp.concatenate(a, axis=1)
+                               for a in (recent, position, there))
+    cands = (M + st - 1) // st + 1
+    member = (there[:, None, :]
+              & (position[:, None, :] // st
+                 == first[:, None, None] + jnp.arange(cands)[None, :, None]))
+    means = jnp.einsum("bcr,brnd->bcnd", member.astype(jnp.float32),
+                       recent.astype(jnp.float32)) / st
+    return means.astype(k_win.dtype), first
+
+
+def sparse_window_attention(q: jax.Array, k_cache: jax.Array,
+                            v_cache: jax.Array, comp: jax.Array,
+                            layer: jax.Array, page_table: jax.Array,
+                            hist_lens: jax.Array, k_win: jax.Array,
+                            v_win: jax.Array, m: jax.Array,
+                            k_self: jax.Array, v_self: jax.Array,
+                            spec: ModelSpec, live: jax.Array,
+                            backends: Backends = XLA):
+    """Decode attention over chosen blocks for step ``m`` of a window (the
+    operands of model.paged_window_attention_xla, and ``comp`` the
+    compressed-key array): scores over the row's stripes, the choice, and
+    the pool's reader over the chosen blocks alone. The window's own
+    tokens and the step's lie in the blocks the query's window keeps.
+    Returns (out [B, Nh, D], counts [2] float32: the keys the live rows
+    attended, a KV group's mean, and the keys they had in context)."""
+    b, nh, d = q.shape
+    nkv, pages, page = k_cache.shape[1], k_cache.shape[2], k_cache.shape[3]
+    st, bk = spec.sparse_stride, spec.sparse_block
+    group = nh // nkv
+    M = k_win.shape[2]
+    n_keys = hist_lens + m + 1
+    qg = q.reshape(b, nkv, group, d)
+    with scope("attn.index"):
+        old = jnp.einsum("bngd,nbid->bngi", qg,
+                         pool_stripes(comp, layer, page_table, spec),
+                         preferred_element_type=jnp.float32)
+        new, first = window_stripes(k_cache, layer, page_table, hist_lens,
+                                    k_win, m, k_self, spec)
+        new = jnp.einsum("bngd,bcnd->bngc", qg, new,
+                         preferred_element_type=jnp.float32)
+        at = jnp.arange(old.shape[-1])[None, :]                # [1, NS]
+        dots = jnp.where((at < first[:, None])[:, None, None, :], old, 0.0)
+        for c in range(new.shape[-1]):
+            dots = dots + jnp.where(
+                (at == first[:, None] + c)[:, None, None, :],
+                new[..., c:c + 1], 0.0)
+        blocks, kept = choose_blocks(dots, n_keys, spec)       # [B, Nkv, K]
+        nb = dots.shape[-1] * st // bk
+        blocks = jnp.sort(jnp.where(kept, blocks, nb), axis=-1)
+        # The pool holds positions under hist_lens: whole blocks up to the
+        # one of its last token, which the query's window keeps, and which
+        # is the last of the table that the reader's length lets it read.
+        last = jnp.maximum(hist_lens - 1, 0) // bk
+        whole = jnp.sum(blocks < last[:, None, None], axis=-1)  # [B, Nkv]
+        length = jnp.where(hist_lens[:, None] > 0,
+                           whole * bk + (hist_lens - last * bk)[:, None], 0)
+        # The pool as blocks of ONE KV head: block x of page p of head n
+        # is block (n * pages + p) * blocks-a-page + x.
+        per = page // bk
+        of_page = jnp.take_along_axis(
+            jnp.broadcast_to(page_table[:, None, :],
+                             (b, nkv, page_table.shape[1])),
+            jnp.clip(blocks // per, 0, page_table.shape[1] - 1), axis=-1)
+        table = ((jnp.arange(nkv)[None, :, None] * pages + of_page) * per
+                 + blocks % per)
+    as_blocks = lambda c: c.reshape(c.shape[0], 1, nkv * pages * per,  # noqa: E731
+                                    bk, d)
+    rows = lambda a: a.reshape(b * nkv, *a.shape[2:])  # noqa: E731
+    heads_first = lambda w: jnp.moveaxis(w, 0, 1).reshape(  # noqa: E731
+        1, b * nkv, M, d)
+    with scope("attn.core"):
+        out = kv_attention(backends, window=True)(
+            rows(qg), as_blocks(k_cache), as_blocks(v_cache), layer,
+            rows(table).astype(jnp.int32), rows(length).astype(jnp.int32),
+            heads_first(k_win), heads_first(v_win), m,
+            rows(k_self)[:, None], rows(v_self)[:, None], group)
+    attended = jnp.mean((length + m + 1).astype(jnp.float32), axis=-1)
+    on = live.astype(jnp.float32)
+    counts = jnp.stack([jnp.sum(attended * on),
+                        jnp.sum(n_keys.astype(jnp.float32) * on)])
+    return out.reshape(b, nh, d), counts
+
+
+# ---------------------------------------------------------------------------
+# The scan over groups
 # ---------------------------------------------------------------------------
 
 def _index(tree, i):
@@ -285,89 +762,163 @@ def _index(tree, i):
         a, i, 0, keepdims=False), tree)
 
 
+class Mixer(NamedTuple):
+    """A recurrent mixer as ``scan_groups`` takes it, in three parts so
+    that the state never rides a conditional: ``project(h, lp) -> parts``
+    (the in-projections: what reads the weights), ``update(parts, lp,
+    *state, p, on) -> (y, *state)`` (layer p of the state stacks updated
+    in place; ``on`` a bool scalar or None: False in a group without a
+    mixer, whose rows' state then stands), ``output(y, parts, lp) -> out``
+    (gate, norm and out-projection)."""
+    project: Callable
+    update: Callable
+    output: Callable
+
+
 def in_layer(step):
-    """``step(h, lp, S, conv) -> (out, S, conv)`` over ONE layer's rows as
-    scan_pairs takes it: layer p's rows sliced out of the two stacks and
-    put back where they lay."""
-    def ssm_fn(h, lp, s_all, c_all, p):
-        out, s_new, c_new = step(h, lp, _index(s_all, p), _index(c_all, p))
-        return (out, jax.lax.dynamic_update_index_in_dim(s_all, s_new, p, 0),
-                jax.lax.dynamic_update_index_in_dim(c_all, c_new, p, 0))
-    return ssm_fn
+    """``step(*rows) -> (y, *rows)`` over ONE layer's rows of the state
+    stacks as ``Mixer.update`` takes them whole: layer p's rows sliced out
+    and put back where they lay."""
+    def update(*state_p):
+        *state, p = state_p
+        y, *new = step(*(_index(a, p) for a in state))
+        return (y, *(jax.lax.dynamic_update_index_in_dim(a, n, p, 0)
+                     for a, n in zip(state, new)))
+    return update
 
 
-def scan_pairs(layers: dict, spec: ModelSpec, x: jax.Array, state: tuple,
-               ssm_fn, attn_fn, kv_like: tuple, live=None,
-               backends: Backends = XLA):
-    """x through every layer. ``state`` (S [M, rows, ...], conv [M, rows,
-    ...]) rides the carry and layer p rewrites its own rows in place:
-    ``ssm_fn(h, lp, S, conv, p) -> (out, S, conv)`` over the whole stacks
-    (``in_layer`` for a step over one layer's rows); ``attn_fn(h, ap, a) ->
-    (out, k, v)`` for attention layer a of its stack (k and v shaped as
-    ``kv_like``); ``live`` and ``backends`` as model.ffn_block takes
-    them: where x's rows take the grouped product the expert stacks are not
-    sliced a pair but handed whole with the pair's index, as
+def scan_groups(layers: dict, spec: ModelSpec, x: jax.Array, state: tuple,
+                mixer: Mixer, attn_fn, kv_like: tuple, live=None,
+                backends: Backends = XLA):
+    """x through every group. ``state`` (the recurrent layers' stacks [M,
+    rows, ...]) rides the carry and layer p rewrites its own rows in place
+    (``mixer``); ``attn_fn(h, ap, a) -> (out, kv)`` for attention layer a
+    of its stack (kv a tuple of arrays like ``kv_like``, ``_like``'s: what
+    the layer leaves a token and what it counted); ``live`` and
+    ``backends`` as model.ffn_block takes them: where x's rows take the
+    grouped product the expert stacks
+    are not sliced a group but handed whole with the group's index, as
     model.scan_layers hands them (sliced ahead of a custom call a layer's
     experts are COPIED: 160 MB a matrix of 32 x 2,688 x 1,856). Returns (x,
-    state, k [A, ...], v [A, ...], the expert layers' load [E, n] or None)."""
-    pairs = pairs_of(spec)
-    eps = spec.rms_norm_eps
+    state, kv (each [A, ...]), the expert layers' load [E, n] or None)."""
+    groups = groups_of(spec)
+    eps, scale = spec.rms_norm_eps, spec.residual_scale
     norms = layers["mixer_norm"]
     ssm = {k: v for k, v in layers.items() if k.startswith("ssm_")}
-    moe = {k: v for k, v in layers.items()
-           if k.startswith(("moe_", "shared_"))}
+    vectors = {k: v for k, v in ssm.items() if not k.startswith("ssm_w_")}
+    ffn = {k: v for k, v in layers.items()
+           if k.startswith(("moe_", "shared_")) or k in FFN_LEAVES}
     whole = {}
     if expert_product(math.prod(x.shape[:-1]), backends) == "grouped":
-        moe, whole = whole_expert_leaves(moe)
-    attn = {k: layers[k] for k in ATTN_LEAVES}
-    starred = jnp.asarray([a >= 0 for a in pairs.attn_index])
-    at = lambda idx: norms[jnp.asarray([max(i, 0) for i in idx])]  # noqa: E731
+        ffn, whole = whole_expert_leaves(ffn)
+    attn = {k: layers[k] for k in ATTN_LEAVES if k in layers}
+    # A stack with a sublayer a group is sliced by the scan; one that only
+    # some groups have is indexed under their conditional.
+    every = all(i >= 0 for i in groups.mixer_index)
+    mixed = jnp.asarray([i >= 0 for i in groups.mixer_index])
+    starred = jnp.asarray([a >= 0 for a in groups.attn_index])
+    nth = lambda idx: jnp.asarray([max(i, 0) for i in idx])  # noqa: E731
+    at = lambda idx: norms[nth(idx)]  # noqa: E731
+    add = (lambda x, out: x + out) if scale == 1.0 else (
+        lambda x, out: x + out * scale)
 
-    def pair(carry, xs):
-        x, s_all, c_all = carry
-        lp_m, lp_e, norm_m, norm_a, norm_e, star, a, p = xs
+    def group(carry, xs):
+        x, *state = carry
+        lp_m, lp_f, norm_m, norm_a, norm_f, has_m, star, a, p = xs
         with scope("ssm"):
-            out, s_all, c_all = ssm_fn(rms_norm(x, norm_m, eps), lp_m, s_all,
-                                       c_all, p)
-            x = x + out
+            if every:
+                h = rms_norm(x, norm_m, eps)
+                parts = mixer.project(h, lp_m)
+                y, *state = mixer.update(parts, lp_m, *state, p, None)
+                x = add(x, mixer.output(y, parts, lp_m))
+            elif ssm:
+                # The matrices are indexed where they are multiplied; the
+                # update takes the layer's vectors alone.
+                project = lambda x: mixer.project(  # noqa: E731
+                    rms_norm(x, norm_m, eps), _index(ssm, p))
+                parts = jax.lax.cond(
+                    has_m, project, lambda x: jax.tree.map(
+                        lambda a: jnp.zeros(a.shape, a.dtype),
+                        jax.eval_shape(project, x)), x)
+                y, *state = mixer.update(parts, _index(vectors, p), *state,
+                                         p, has_m)
+                x = jax.lax.cond(
+                    has_m, lambda x: add(x, mixer.output(
+                        y, parts, _index(ssm, p))), lambda x: x, x)
 
         def attend(x):
             with scope("attn.qkv"):
                 h = rms_norm(x, norm_a, eps)
-            out, k, v = attn_fn(h, _index(attn, a), a)
-            return x + out, k, v
+            out, kv = attn_fn(h, _index(attn, a), a)
+            return add(x, out), tuple(kv)
 
         def skip(x):
-            return x, *(jnp.zeros(shape, jnp.bfloat16) for shape in kv_like)
+            return x, tuple(jnp.zeros(a.shape, a.dtype) for a in kv_like)
 
-        x, k, v = jax.lax.cond(star, attend, skip, x)
+        x, kv = jax.lax.cond(star, attend, skip, x)
         with scope("mlp"):
-            out = ffn_block(rms_norm(x, norm_e, eps),
-                            {**lp_e, **layer_of(whole, p)}, spec, live=live,
+            out = ffn_block(rms_norm(x, norm_f, eps),
+                            {**lp_f, **layer_of(whole, p)}, spec, live=live,
                             backends=backends)
             out, load = out if isinstance(out, tuple) else (out, None)
-            x = x + out
-        return (x, s_all, c_all), ((k, v) if load is None else (k, v, load))
+            x = add(x, out)
+        return (x, *state), (kv if load is None else (kv, load))
 
-    n = len(pairs.mamba)
-    (x, *state), (k, v, *load) = jax.lax.scan(
-        pair, (x, *state),
-        (ssm, moe, at(pairs.mamba), at(pairs.attn_layer), at(pairs.expert),
-         starred, jnp.asarray([max(a, 0) for a in pairs.attn_index]),
-         jnp.arange(n)))
-    held = jnp.asarray([p for p, a in enumerate(pairs.attn_index) if a >= 0])
-    return x, tuple(state), k[held], v[held], (load[0] if load else None)
+    n = len(groups.ffn_layer)
+    (x, *state), out = jax.lax.scan(
+        group, (x, *state),
+        (ssm if every else None, ffn, at(groups.mixer_layer),
+         at(groups.attn_layer), at(groups.ffn_layer), mixed, starred,
+         nth(groups.attn_index),
+         jnp.arange(n) if every else nth(groups.mixer_index)))
+    kv, load = out if isinstance(out[0], tuple) else (out, None)
+    held = jnp.asarray([p for p, a in enumerate(groups.attn_index) if a >= 0])
+    return x, tuple(state), tuple(a[held] for a in kv), load
+
+
+def _like(*shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype)
 
 
 def _qkv(h: jax.Array, ap: dict, spec: ModelSpec):
+    """q [..., Nh, D], k and v [..., Nkv, D] of normed h; q and k
+    RMS-normalised a head where the layer has the weights."""
     with scope("attn.qkv"):
         d = spec.head_dim
-        return (_split_heads(mm(h, ap["wq"], "...h,hd->...d"),
-                             spec.num_heads, d),
-                _split_heads(mm(h, ap["wk"], "...h,hd->...d"),
-                             spec.num_kv_heads, d),
-                _split_heads(mm(h, ap["wv"], "...h,hd->...d"),
-                             spec.num_kv_heads, d))
+        q = _split_heads(mm(h, ap["wq"], "...h,hd->...d"), spec.num_heads, d)
+        k = _split_heads(mm(h, ap["wk"], "...h,hd->...d"),
+                         spec.num_kv_heads, d)
+        if "q_norm" in ap:
+            q = rms_norm(q, ap["q_norm"], spec.rms_norm_eps)
+            k = rms_norm(k, ap["k_norm"], spec.rms_norm_eps)
+        return q, k, _split_heads(mm(h, ap["wv"], "...h,hd->...d"),
+                                  spec.num_kv_heads, d)
+
+
+def _attn_out(attn: jax.Array, h: jax.Array, ap: dict):
+    """attn [..., Nh * D] through W_o, gated by sigmoid(h W_z) where the
+    layer has the gate."""
+    with scope("attn.out"):
+        if "wz" in ap:
+            gate = jax.nn.sigmoid(mm(h, ap["wz"], "...h,hd->...d")
+                                  .astype(jnp.float32))
+            attn = attn * gate.astype(attn.dtype)
+        return mm(attn, ap["wo"], "...d,dh->...h")
+
+
+def _embed(params: Params, spec: ModelSpec, tokens: jax.Array):
+    with scope("embed"):
+        x = embed_lookup(params["embed"], tokens)
+        return x if spec.scale_emb == 1.0 else x * spec.scale_emb
+
+
+def split_state(spec: ModelSpec, state: tuple) -> tuple:
+    """The runner's state arrays as (what a scan carries: the recurrent
+    layers' stacks by slot, the compressed-key array or None: the pool's
+    third array, read where it lies and written at a commit)."""
+    if spec.compressed_keys:
+        return tuple(state[:-1]), state[-1]
+    return tuple(state), None
 
 
 # ---------------------------------------------------------------------------
@@ -382,49 +933,102 @@ def prefill(params: Params, spec: ModelSpec, k_cache: jax.Array,
     """model.prefill_forward for this block: a chunk of each row's prompt,
     whole (``hist`` None) or after earlier chunks (``hist`` (hist_table,
     hist_lens): the attention layers also read the row's earlier pages).
-    ``state`` is the runner's two arrays over ALL slots and ``slots`` [B]
-    the slot of each row (-1: none, the row's state goes nowhere): a row
-    whose chunk starts at position 0 starts from zeros, any other from its
-    slot's state, and each leaves there the state at its last real token.
+    ``state`` is the runner's state arrays (``split_state``), the
+    recurrent layers' over ALL slots, carried through the scan and updated
+    where they lie, and ``slots`` [B] the slot of each row (-1: none, the
+    row's state goes nowhere): a row whose chunk starts at position 0
+    starts from zeros, any other from its slot's state, and each leaves
+    there the state at its last real token.
     ``backends``: see model.ffn_block (a window step's rows never take the
     grouped product: ``window_step`` hands its expert layers no record).
     Returns (last-token logits, k_cache, v_cache, state)."""
     b, s = tokens.shape
     page = k_cache.shape[3]
-    with scope("embed"):
-        x = embed_lookup(params["embed"], tokens)
+    x = _embed(params, spec, tokens)
+    state, comp = split_state(spec, state)
     valid = jnp.arange(s)[None, :] < seq_lens[:, None]
     rows = jnp.clip(slots, 0, state[0].shape[1] - 1)
     fresh = positions[:, 0] == 0
-    with scope("ssm"):
-        held = tuple(jnp.where(
-            fresh.reshape(1, b, *(1,) * (a.ndim - 2)), 0, a[:, rows])
-            for a in state)
+    keep = (slots >= 0) & (seq_lens > 0)
 
-    @in_layer
-    def ssm_fn(h, lp, s_rows, c_rows):
-        return ssm_chunked(h, lp, spec, s_rows, c_rows, valid, seq_lens)
+    def live(on):
+        return valid if on is None else valid & on
+
+    def of_rows(step, on):
+        """``step(*rows) -> (y, *rows)`` over the chunk's rows of ONE layer
+        as ``Mixer.update`` takes the state stacks whole: each row's state
+        read out of its slot (zeros where its chunk starts at position 0),
+        and what it leaves written back there, a row a copy in place (a
+        gather of every layer's rows ahead of the scan is 50 MB a row kept
+        through it; a scatter of the batch may copy the arrays); nothing is
+        written where ``on`` is False (a group without a mixer)."""
+        kept = keep if on is None else keep & on
+        def update(*state_p):
+            *stacks, p = state_p
+            y, *new = step(*(jnp.where(
+                fresh.reshape(b, *(1,) * (a.ndim - 2)), 0, a[p, rows])
+                for a in stacks))
+            out = []
+            for whole, rows_new in zip(stacks, new):
+                for i in range(b):
+                    at = (p, rows[i]) + (0,) * (whole.ndim - 2)
+                    old = jax.lax.dynamic_slice(
+                        whole, at, (1, 1, *whole.shape[2:]))
+                    whole = jax.lax.dynamic_update_slice(
+                        whole, jnp.where(kept[i], rows_new[i][None, None],
+                                         old), at)
+                out.append(whole)
+            return (y, *out)
+        return update
+
+    if spec.ssm_conv:
+        mixer = Mixer(
+            lambda h, lp: _project(h, lp, spec),
+            lambda parts, lp, s_all, c_all, p, on: of_rows(
+                lambda s_rows, c_rows: _ssm_chunk(
+                    parts, lp, spec, s_rows, c_rows, live(on),
+                    seq_lens if on is None else jnp.where(on, seq_lens, 0)),
+                on)(s_all, c_all, p),
+            lambda y, parts, lp: _gated_out(y, parts[0], lp, spec))
+    else:
+        mixer = Mixer(
+            lambda h, lp: _lightning_project(h, lp, spec, positions),
+            lambda parts, lp, s_all, p, on: of_rows(
+                lambda s_rows: lightning_recurrence(
+                    parts, spec, s_rows, live(on)), on)(s_all, p),
+            lambda y, parts, lp: _lightning_out(y, parts, lp, spec))
 
     def attn_fn(h, ap, a):
         q, k, v = _qkv(h, ap, spec)
-        if hist is None:
-            with scope("attn.core"):
+        old = ()
+        if hist is not None:
+            with scope("attn.kv_gather"):
+                old = (gather_pages_folded(k_cache, a, hist[0]),
+                       gather_pages_folded(v_cache, a, hist[0]))
+        if comp is not None:
+            if hist is not None:
+                with scope("attn.index"):
+                    old = (*old, pool_stripes(comp, a, hist[0], spec),
+                           hist[1])
+            attn, stripes = sparse_prefill_attention(
+                q, k, v, positions, valid, spec, old or None)
+            return _attn_out(attn, h, ap), (k, v, stripes)
+        with scope("attn.core"):
+            if hist is None:
                 attn = dense_causal_attention(
                     q, k, v, positions, valid, spec.q_per_kv).reshape(b, s, -1)
-        else:
-            with scope("attn.kv_gather"):
-                k_hist = gather_pages_folded(k_cache, a, hist[0])
-                v_hist = gather_pages_folded(v_cache, a, hist[0])
-            with scope("attn.core"):
-                attn = history_attention(q, k, v, k_hist, v_hist, positions,
-                                         valid, hist[1], spec)
-        with scope("attn.out"):
-            return mm(attn, ap["wo"], "...d,dh->...h"), k, v
+            else:
+                attn = history_attention(q, k, v, *old, positions, valid,
+                                         hist[1], spec)
+        return _attn_out(attn, h, ap), (k, v)
 
     nkv, d = spec.num_kv_heads, spec.head_dim
-    x, held, k_new, v_new, _ = scan_pairs(
-        params["layers"], spec, x, held, ssm_fn, attn_fn,
-        ((b, s, nkv, d),) * 2, backends=backends)
+    kv_like = (_like(b, s, nkv, d),) * 2
+    if comp is not None:
+        kv_like += (_like(b, s // spec.sparse_stride, nkv, d),)
+    x, state, (k_new, v_new, *stripes), _ = scan_groups(
+        params["layers"], spec, x, state, mixer, attn_fn, kv_like,
+        backends=backends)
     with scope("kv.commit"):
         n_attn = spec.pool_layers
         blocks = lambda a: (a.reshape(n_attn, b * (s // page), page, nkv, d)  # noqa: E731
@@ -432,74 +1036,150 @@ def prefill(params: Params, spec: ModelSpec, k_cache: jax.Array,
         flat = page_table.reshape(-1)
         k_cache = scatter_pages(k_cache, blocks(k_new), flat)
         v_cache = scatter_pages(v_cache, blocks(v_new), flat)
-    with scope("ssm"):
-        # Each row's state into its slot, where the arrays lie (a scatter
-        # of the whole batch may copy them: 49 MB a slot).
-        keep = (slots >= 0) & (seq_lens > 0)
-        out = []
-        for whole, new in zip(state, held):
-            for i in range(b):
-                old = jax.lax.dynamic_slice_in_dim(whole, rows[i], 1, axis=1)
-                whole = jax.lax.dynamic_update_slice_in_dim(
-                    whole, jnp.where(keep[i], new[:, i:i + 1], old), rows[i],
-                    axis=1)
-            out.append(whole)
+    if comp is not None:
+        with scope("attn.compress"):
+            # A page's stripes are one row of the array: [stripes, D] flat.
+            per = page // spec.sparse_stride
+            rows_of = (stripes[0].reshape(n_attn, b * (s // page), per, nkv, d)
+                       .transpose(0, 3, 1, 2, 4)
+                       .reshape(n_attn, nkv, -1, per * d))
+            comp = comp.at[:, :, flat].set(rows_of)
     with scope("lm_head"):
         x = rms_norm(x, params["final_norm"], spec.rms_norm_eps)
         last = jnp.maximum(seq_lens - 1, 0)
         x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
         logits = lm_logits(x_last, params, spec)
-    return logits, k_cache, v_cache, tuple(out)
+    if comp is not None:
+        state += (comp,)
+    return logits, k_cache, v_cache, state
 
 
 def window_step(params: Params, spec: ModelSpec, k_cache: jax.Array,
                 v_cache: jax.Array, k_buf: jax.Array, v_buf: jax.Array,
                 m: jax.Array, tokens: jax.Array, page_table: jax.Array,
                 hist_lens: jax.Array, state: tuple, live: jax.Array,
-                backends: Backends = XLA):
-    """model.decode_window_step for this block: one token a slot. The pool
-    (its layers are the attention layers') is read-only and this window's
+                positions: jax.Array | None = None,
+                comp: jax.Array | None = None, backends: Backends = XLA):
+    """model.decode_window_step for this block: one token a slot, at
+    ``positions`` [B] (a mixer that rotates reads them). The pool (its
+    layers are the attention layers') and its compressed-key array
+    ``comp`` are read-only and this window's
     earlier tokens come from k_buf / v_buf [A, Nkv, B, M, D]; ``state`` is
-    the runner's two arrays over all slots, carried through the window's
-    steps: a row that is not ``live`` keeps its own. ``backends.ssm``
-    "kernel": the kernel of engine/recurrence.py updates the live slots' S
-    where the stack lies; else XLA every slot's (``ssm_step``).
-    Returns (logits, k_new and v_new [A, B, Nkv, D], state, counts: "moe"
-    the expert layers' load [E, 5], "ssm" the live rows [1, 1]; the keys
+    the recurrent layers' arrays over all slots, carried through the
+    window's steps: a row that is not ``live`` keeps its own.
+    ``backends.ssm`` "kernel": the kernel of engine/recurrence.py updates
+    the live slots' S where the stack lies; else XLA every slot's
+    (``state_update``). Returns (logits, k_new and v_new [A, B, Nkv, D],
+    state, counts: "moe" the expert layers' load [E, 5], "ssm" the live
+    rows [1, 1], "attn" the keys attended and in context [A, 2]; the keys
     the host's table knows, runtime/flight.py COUNTS)."""
     b = tokens.shape[0]
-    with scope("embed"):
-        x = embed_lookup(params["embed"], tokens)
+    x = _embed(params, spec, tokens)
     attend = kv_attention(backends, window=True)
     with scope("ssm"):
         walk = live_walk(live)      # (XLA's path takes the count alone)
+    kernel = backends.ssm == "kernel"
 
-    if backends.ssm == "kernel":
-        def ssm_fn(h, lp, s_all, c_all, p):
-            out, s_all, c_new = ssm_step_live(
-                h, lp, spec, s_all, p, _index(c_all, p), live, walk,
+    def update(terms, s_all, p, on):
+        """One token of layer p's rows: ``terms`` (dx [B,G,Hg,P], B and C
+        [B,G,N], the decay [B, heads]) -> (y [B, inner], s_all). The live
+        rows alone under the kernel, none where ``on`` is False."""
+        dx, bb, cc, decay = terms
+        if kernel:
+            count = walk[1] if on is None else jnp.where(on, walk[1], 0)
+            s_all, y = state_step(
+                s_all, p, walk[0], count, decay,
+                dx.reshape(b, decay.shape[1], -1), bb, cc,
                 interpret=backends.interpret)
-            return out, s_all, jax.lax.dynamic_update_index_in_dim(
-                c_all, c_new, p, 0)
+            return y.reshape(b, -1), s_all
+        y, s_all = in_layer(lambda s_rows: state_update(
+            s_rows, decay, dx, bb, cc))(s_all, p)
+        return y.reshape(b, -1), s_all
+
+    def on_rows(on):
+        return live if on is None else live & on
+
+    if spec.ssm_conv:
+        def mamba(parts, lp, s_all, c_all, p, on):
+            x, bb, cc, dt, da, conv = _token_of(
+                parts, lp, spec, _index(c_all, p), on_rows(on))
+            y, s_all = update((dt.reshape(*x.shape[:3], 1) * x, bb, cc,
+                               jnp.exp(da)), s_all, p, on)
+            y = y.reshape(x.shape) + _skip(lp, x)
+            return (y.reshape(b, -1), s_all,
+                    jax.lax.dynamic_update_index_in_dim(c_all, conv, p, 0))
+
+        mixer = Mixer(lambda h, lp: _project(h, lp, spec), mamba,
+                      lambda y, parts, lp: _gated_out(y, parts[0], lp, spec))
     else:
-        @in_layer
-        def ssm_fn(h, lp, s_rows, c_rows):
-            return ssm_step(h, lp, spec, s_rows, c_rows, live)
+        def lightning(parts, lp, s_all, p, on):
+            dx, bb, cc, _, da = _lightning_terms(parts, spec, on_rows(on))
+            return update((dx, bb, cc, jnp.exp(da)), s_all, p, on)
+
+        mixer = Mixer(
+            lambda h, lp: _lightning_project(h, lp, spec, positions),
+            lightning,
+            lambda y, parts, lp: _lightning_out(y, parts, lp, spec))
 
     def attn_fn(h, ap, a):
         q, k, v = _qkv(h, ap, spec)
+        at = (k_cache, v_cache, a, page_table, hist_lens, _index(k_buf, a),
+              _index(v_buf, a), m, k, v)
+        if comp is not None:
+            attn, counts = sparse_window_attention(
+                q, k_cache, v_cache, comp, *at[2:], spec, live, backends)
+            return _attn_out(attn.reshape(b, -1), h, ap), (k, v, counts)
         with scope("attn.core"):
-            attn = attend(q, k_cache, v_cache, a, page_table, hist_lens,
-                          _index(k_buf, a), _index(v_buf, a), m, k, v,
-                          spec.q_per_kv).reshape(b, -1)
-        with scope("attn.out"):
-            return mm(attn, ap["wo"], "...d,dh->...h"), k, v
+            attn = attend(q, *at, spec.q_per_kv).reshape(b, -1)
+        return _attn_out(attn, h, ap), (k, v)
 
-    x, state, k_new, v_new, load = scan_pairs(
-        params["layers"], spec, x, state, ssm_fn, attn_fn,
-        ((b, spec.num_kv_heads, spec.head_dim),) * 2, live=live)
+    kv_like = (_like(b, spec.num_kv_heads, spec.head_dim),) * 2
+    if comp is not None:
+        kv_like += (_like(2, dtype=jnp.float32),)
+    x, state, (k_new, v_new, *counts), load = scan_groups(
+        params["layers"], spec, x, state, mixer, attn_fn, kv_like, live=live)
     with scope("lm_head"):
         x = rms_norm(x, params["final_norm"], spec.rms_norm_eps)
         logits = lm_logits(x, params, spec)
-    return (logits, k_new, v_new, state,
-            {"moe": load, "ssm": walk[1].astype(jnp.float32).reshape(1, 1)})
+    counted = {"ssm": walk[1].astype(jnp.float32).reshape(1, 1)}
+    if load is not None:
+        counted["moe"] = load
+    if counts:
+        counted["attn"] = counts[0].astype(jnp.float32)
+    return logits, k_new, v_new, state, counted
+
+
+def commit_stripes(comp: jax.Array, k_cache: jax.Array, k_buf: jax.Array,
+                   page_table: jax.Array, hist_lens: jax.Array,
+                   ends: jax.Array, spec: ModelSpec) -> jax.Array:
+    """The compressed-key array after a window: every stripe that the
+    window's tokens completed (``window_stripes`` over the pool as it was
+    BEFORE the window's commit and the window's buffer k_buf [A, Nkv, B,
+    M, D]) written where its page lies, a copy in place a row and
+    candidate. ``hist_lens`` [B] the tokens the pool held as the window
+    started, ``ends`` [B] those it holds after (a row that took no step:
+    the same)."""
+    st, page, d = spec.sparse_stride, k_cache.shape[3], spec.head_dim
+    n_attn, nkv, b, _, _ = k_buf.shape
+    steps = ends - hist_lens                                   # [B]
+    means = jax.lax.map(
+        lambda a: window_stripes(k_cache, a, page_table, hist_lens, k_buf[a],
+                                 steps, None, spec)[0],
+        jnp.arange(n_attn))                                    # [A,B,W,Nkv,D]
+    first = hist_lens // st
+    cands = means.shape[2]
+
+    def put(i, comp):
+        row, c = i // cands, i % cands
+        stripe = first[row] + c
+        done = (steps[row] > 0) & ((stripe + 1) * st <= ends[row])
+        of_page = page_table[row, jnp.clip(stripe * st // page, 0,
+                                           page_table.shape[1] - 1)]
+        where = (0, 0, of_page, (stripe * st % page) // st * d)
+        new = jax.lax.dynamic_slice(
+            means, (0, row, c, 0, 0), (n_attn, 1, 1, nkv, d))[:, 0]
+        old = jax.lax.dynamic_slice(comp, where, (n_attn, nkv, 1, d))
+        return jax.lax.dynamic_update_slice(
+            comp, jnp.where(done, jnp.swapaxes(new, 1, 2), old), where)
+
+    return jax.lax.fori_loop(0, b * cands, put, comp)

@@ -92,7 +92,10 @@ def embed_lookup(embed, tokens: jax.Array) -> jax.Array:
 def lm_logits(x: jax.Array, params: Params, spec: ModelSpec) -> jax.Array:
     """Final-hidden -> vocab logits (f32). Tied int8 embeddings contract
     over H, whose scale therefore folds into the activations; untied int8
-    heads scale the output columns."""
+    heads scale the output columns. ``spec.logit_divisor`` (muP) divides
+    the hidden state first."""
+    if spec.logit_divisor != 1.0:
+        x = (x.astype(jnp.float32) / spec.logit_divisor).astype(x.dtype)
     if spec.tie_word_embeddings:
         w = params["embed"]
         if isinstance(w, QTensor):
@@ -203,16 +206,47 @@ def _recurrent_shapes(spec: ModelSpec, L: int) -> dict:
             "ssm_w_out": (L, inner, h)}
 
 
+def _lightning_shapes(spec: ModelSpec, L: int) -> dict:
+    """The leaves of L lightning linear-attention mixers (engine/hybrid.py
+    has the equations): ONE in-projection q | k | v | z (four times the
+    inner width: whole lane tiles), the weights of the norms of a head's q
+    and k and of the output (``_norm`` leaves: ones), the out-projection.
+    The decay is no leaf: a head's constant by ``hybrid.lightning_decay``."""
+    h, d = spec.hidden_size, spec.ssm_head_dim
+    inner = spec.ssm_heads * d
+    return {"ssm_w_in": (L, h, 4 * inner),
+            "ssm_q_norm": (L, d), "ssm_k_norm": (L, d),
+            "ssm_out_norm": (L, inner),
+            "ssm_w_out": (L, inner, h)}
+
+
 def _pattern_shapes(spec: ModelSpec) -> dict:
-    """``params["layers"]`` of a block whose layers are ONE mixer each
-    (``spec.layer_pattern``): three stacks, each over the layers of its own
-    kind in the model's order, and the norm ahead of every layer's mixer,
-    stacked over all of them."""
+    """``params["layers"]`` of a block whose sublayers are ONE mixer or
+    feed-forward each (``spec.layer_pattern``, config.GROUP): a stack a
+    kind, each over the sublayers of its kind in the model's order, and the
+    norm ahead of every sublayer, stacked over all of them. An attention
+    layer over chosen blocks (S) also has the norms of a head's q and k and
+    its output gate ``wz``."""
+    pattern = spec.layer_pattern
     attn = {k: v for k, v in _attention_shapes(spec, spec.pool_layers).items()
             if not k.endswith("_norm")}
-    return {"mixer_norm": (spec.num_layers, spec.hidden_size),
-            **_recurrent_shapes(spec, spec.ssm_layers),
-            **_expert_shapes(spec, spec.expert_layers), **attn}
+    shapes = {"mixer_norm": (len(pattern), spec.hidden_size)}
+    if "M" in pattern:
+        shapes.update(_recurrent_shapes(spec, pattern.count("M")))
+    if "L" in pattern:
+        shapes.update(_lightning_shapes(spec, pattern.count("L")))
+    if "E" in pattern:
+        shapes.update(_expert_shapes(spec, pattern.count("E")))
+    if "D" in pattern:
+        n, h, i = pattern.count("D"), spec.hidden_size, spec.intermediate_size
+        shapes.update({"w_gate": (n, h, i), "w_up": (n, h, i),
+                       "w_down": (n, i, h)})
+    shapes.update(attn)
+    if "S" in pattern:
+        n, d = pattern.count("S"), spec.head_dim
+        shapes.update({"q_norm": (n, d), "k_norm": (n, d),
+                       "wz": (n, spec.hidden_size, spec.num_heads * d)})
+    return shapes
 
 
 def param_shapes(spec: ModelSpec) -> dict:

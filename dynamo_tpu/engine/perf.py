@@ -95,9 +95,14 @@ SCOPES = ("embed", "attn.qkv", "attn.kv_gather", "attn.core", "attn.out",
 #: the Nemotron-H block's Mamba-2 mixer, engine/hybrid.py: its norm, its
 #: in-projection, the convolution, the recurrence over the state, the gated
 #: norm and the out-projection, and in prefill the rows' state gathered from
-#: and written back to their slots). The other blocks' programs
-#: have none, so their names stand.
-BLOCK_SCOPES = ("attn.index", "mtp", "ssm")
+#: and written back to their slots; the MiniCPM-SALA block's
+#: lightning mixer likewise: norm, projections, rotation, the recurrence,
+#: output norm, gate, out-projection); the writes of a compressed-key array
+#: (``attn.compress``: a prefill chunk's stripes into their pages, a
+#: window's completed stripes at its commit; the choice of blocks over them
+#: is ``attn.index``). The other blocks' programs
+#: have none, so their names, and SCOPES_VERSION, stand.
+BLOCK_SCOPES = ("attn.index", "mtp", "ssm", "attn.compress")
 #: Regions INSIDE a scope, drawn only in programs of a routed block (the
 #: expert layer's router and experts and, where the block has them, its
 #: shared experts, inside ``mlp``). An instruction in one

@@ -52,7 +52,10 @@ _LATENT_KEYS = ("wq_a", "wq_b", "wkv_a", "wk_b", "wv_b", "index_wq_b",
 # The Nemotron-H block's recurrent layer: its two projections (the
 # convolution, A, D, dt's bias and the gated norm stay bf16).
 _RECURRENT_KEYS = ("ssm_w_in", "ssm_w_dt", "ssm_w_out")
-QUANT_LAYER_KEYS = _BLOCK_KEYS + _LATENT_KEYS + _RECURRENT_KEYS + tuple(
+# The output gate of an attention layer over chosen blocks (MiniCPM-SALA).
+_GATE_KEYS = ("wz",)
+QUANT_LAYER_KEYS = (_BLOCK_KEYS + _LATENT_KEYS + _RECURRENT_KEYS
+                    + _GATE_KEYS) + tuple(
     DENSE_PREFIX + key for key in ("wo", "w_gate", "w_up", "w_down")
     + _LATENT_KEYS) + tuple(
     # A prediction module's block (an expert layer of the latent kind) and

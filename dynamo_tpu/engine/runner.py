@@ -313,15 +313,23 @@ class ModelRunner:
         # float32 and the convolution's last inputs. Donated to and
         # returned by every program that writes them (prefill at a row's
         # last real token, the window once a step), never copied whole.
-        self.ssm_state = self.conv_state = None
+        # A mixer without a convolution keeps S alone. The pool's third
+        # array (ModelSpec.compressed_keys: means of keys under the same
+        # page table, hybrid.py) rides the same programs behind them.
+        self.ssm_state = self.conv_state = self.comp_keys = None
         if spec.recurrent:
             s_shape, c_shape = spec.ssm_state_shapes
             rows = (spec.ssm_layers, config.max_num_seqs)
             whole = NamedSharding(self.mesh, P())
             self.ssm_state = _mh_zeros((*rows, *s_shape),
                                        jnp.dtype(SSM_STATE_DTYPE), whole)
-            self.conv_state = _mh_zeros((*rows, *c_shape), jnp.bfloat16,
-                                        whole)
+            if c_shape is not None:
+                self.conv_state = _mh_zeros((*rows, *c_shape), jnp.bfloat16,
+                                            whole)
+            if spec.compressed_keys:
+                self.comp_keys = _mh_zeros(
+                    spec.comp_key_shape(self.num_pages, config.page_size),
+                    jnp.bfloat16, whole)
         # Byte ledgers for the perf plane's HBM breakdown (/debug/perf):
         # this process's per-device share of params and the KV pool —
         # workspace is whatever memory_stats says is in use beyond them.
@@ -335,6 +343,8 @@ class ModelRunner:
             2 * self.num_pages * config.page_size
             * self._kv_token_head_bytes() * spec.pool_layers
             * kv_heads) // shard
+        if self.comp_keys is not None:
+            self.kv_pool_bytes += self.comp_keys.nbytes
 
         self._prefill_cache: dict = {}
         self._decode_fn = None
@@ -444,6 +454,22 @@ class ModelRunner:
         return (self.config.max_num_seqs
                 * self.spec.ssm_state_bytes_per_row)
 
+    _STATE_ARRAYS = ("ssm_state", "conv_state", "comp_keys")
+
+    @property
+    def state_arrays(self) -> tuple:
+        """What a block with recurrent layers hands its programs as
+        ``state`` (hybrid.split_state): the arrays that exist, in order."""
+        return tuple(a for a in (getattr(self, name)
+                                 for name in self._STATE_ARRAYS)
+                     if a is not None)
+
+    def _keep_state(self, arrays) -> None:
+        names = [name for name in self._STATE_ARRAYS
+                 if getattr(self, name) is not None]
+        for name, array in zip(names, arrays, strict=True):
+            setattr(self, name, array)
+
     def _sized_pages(self, device) -> None:
         cfg = self.config
         if cfg.num_pages is not None:
@@ -481,6 +507,7 @@ class ModelRunner:
         # ~2x pages — directly more resident sequences per chip.
         token_bytes = (2 * self.spec.pool_layers * self.spec.kv_entry[0]
                        * self._kv_token_head_bytes())
+        token_bytes += self.spec.comp_key_bytes_per_token  # the third array
         page_bytes = token_bytes * cfg.page_size // max(1, cfg.tp * cfg.pp)
         self.num_pages = max(16, budget // max(1, page_bytes))
         log.info("KV pool: %d pages of %d tokens (%.1f GiB)", self.num_pages,
@@ -1012,6 +1039,13 @@ class ModelRunner:
             # vector of its own ("attn").
             routed = bool(spec.num_experts)
 
+            # The compressed-key array is read where it lies, as the pool
+            # is, and written at the window's commit.
+            comp = None
+            if recurrent:
+                from dynamo_tpu.engine import hybrid
+                state, comp = hybrid.split_state(spec, state)
+
             def step(carry, m):
                 tokens, positions, kbuf, vbuf, rng, cnts, *state = carry
                 # A slot advances only while live AND within its allocated
@@ -1019,10 +1053,10 @@ class ModelRunner:
                 # LENGTH when it sees the cap).
                 live = (seq_lens0 > 0) & (positions < cap)
                 if recurrent:
-                    from dynamo_tpu.engine import hybrid
                     logits, k_new, v_new, state, counted = hybrid.window_step(
                         params, spec, k_cache, v_cache, kbuf, vbuf, m, tokens,
                         page_table, hist_lens, tuple(state), live,
+                        positions=positions, comp=comp,
                         backends=self.backends)
                 else:
                     logits, k_new, v_new, counted = decode_window_step(
@@ -1086,9 +1120,14 @@ class ModelRunner:
             carry0 = (tokens0, positions0, kbuf0, vbuf0, rng,
                       counts if penalized else jnp.zeros((), jnp.uint8),
                       *state)
-            (tokens, _, kbuf, vbuf, rng, counts_out, *state), \
+            (tokens, positions_end, kbuf, vbuf, rng, counts_out, *state), \
                 (toks, lps, top_vs, top_is, counted) = \
                 jax.lax.scan(step, carry0, jnp.arange(window))
+            if comp is not None:
+                with perf.scope("attn.compress"):
+                    state.append(hybrid.commit_stripes(
+                        comp, k_cache, kbuf, page_table, hist_lens,
+                        hist_lens + positions_end - positions0, spec))
             # [M, L, n] -> [n] under the key the step function counted it
             # by (runtime/flight.py COUNTS has the columns): a latent
             # block's keys ("attn": 2 sums), a routed block's load ("moe",
@@ -1457,6 +1496,14 @@ class ModelRunner:
         n_max = max(len(s.tokens) for s in seqs)
         bucket = cfg.bucket_for(n_max)
         bucket_pages = bucket // page
+        # One program holds at most max_prefill_tokens rows x bucket: a
+        # group over that (the engine's groups are 8 prompts of ANY length:
+        # 8 x 8,192 rows are 4.3 GB of a 16,384-wide feed-forward's
+        # intermediates alone) runs in parts, in turn, each a program that
+        # a smaller group of the same bucket also draws.
+        rows = max(1, cfg.max_prefill_tokens // bucket)
+        if len(seqs) > rows:
+            return self._prefill_parts(seqs, slots, count_rows, fetch, rows)
         with_history = any(s.hist_pages is not None and len(s.hist_pages)
                            for s in seqs)
         bp = 1
@@ -1525,7 +1572,7 @@ class ModelRunner:
         if mtp:
             kw["page_ends"] = self.mtp_hidden
         if recurrent:
-            kw["state"] = (self.ssm_state, self.conv_state)
+            kw["state"] = self.state_arrays
         fn = self._get_prefill(bucket, bp, with_history, penalized, seeded,
                                with_embeds)
         if self.spec.num_experts and expert_product(
@@ -1553,7 +1600,7 @@ class ModelRunner:
                 if mtp:
                     self.mtp_hidden = rest[1]
             if recurrent:
-                self.ssm_state, self.conv_state = rest
+                self._keep_state(rest)
         # Device handle (no transfer unless a caller converts it).
         self.last_prefill_logits = logits
         if slots is not None:
@@ -1598,6 +1645,26 @@ class ModelRunner:
         self.sync_prefill_fetches += 1
         # dtpu: ignore[host-sync-in-hot-path] -- fetch=True branch only: prefill_chunk_async passes fetch=False and returns at the dispatch-only branch above (runtime twin: sync_prefill_fetches counter)
         return np.asarray(jax.device_get(sampled))[:len(seqs)]
+
+    def _prefill_parts(self, seqs, slots, count_rows, fetch: bool, rows: int):
+        """``prefill_batch`` of a group over the bound, ``rows`` prompts at
+        a time: what it returns for the whole group, joined."""
+        cuts = [slice(at, at + rows) for at in range(0, len(seqs), rows)]
+        parts, logits = [], []
+        for cut in cuts:
+            parts.append(self.prefill_batch(
+                seqs[cut], None if slots is None else slots[cut],
+                None if count_rows is None else count_rows[cut], fetch))
+            logits.append(self.last_prefill_logits[:len(seqs[cut])])
+        self.last_prefill_logits = jnp.concatenate(logits)
+        if slots is not None:
+            return {key: jnp.concatenate(
+                [part[key][:len(seqs[cut])]
+                 for part, cut in zip(parts, cuts)])
+                for key in parts[0]}
+        if not fetch:
+            return parts[-1]     # a completion handle: the last dispatched
+        return np.concatenate(parts)
 
     # dtpu: hotpath -- PR 5 zero-readback invariant, now static: no device->host fetch anywhere below this entry
     def prefill_chunk_async(self, seq: PrefillSeq):
@@ -1674,7 +1741,7 @@ class ModelRunner:
         fn = self._get_window(window, bucket_pages, penalized, seeded)
         kw = {} if self.lora is None else {"lora": self.lora}
         if self.spec.recurrent:
-            kw["state"] = (self.ssm_state, self.conv_state)
+            kw["state"] = self.state_arrays
         with self.mesh:
             if self.draft_dev is not None:
                 # The drafting window (_get_mtp_window): toks, lps and tops
@@ -1698,7 +1765,7 @@ class ModelRunner:
                     self.params, self.k_cache, self.v_cache,
                     self.tokens_dev, jnp.asarray(packed), self._rng, **kw)
             if self.spec.recurrent:
-                self.ssm_state, self.conv_state = state
+                self._keep_state(state)
         return (toks, lps, top_vs, top_is, stats)
 
     def embed(self, token_lists: list[list[int]],

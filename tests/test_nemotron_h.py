@@ -229,7 +229,7 @@ def catalog_row() -> dict:
 def test_the_reader_makes_the_catalog_row_s_spec():
     spec = read_spec(catalog_row()["config"])
     assert isinstance(spec, NemotronHSpec)
-    pairs = hybrid.pairs_of(spec)
+    pairs = hybrid.groups_of(spec)
     assert (spec.num_layers, spec.ssm_layers, spec.expert_layers,
             spec.pool_layers) == (52, 23, 23, 6)
     assert [a for a in pairs.attn_layer if a >= 0] == [5, 12, 19, 26, 33, 42]
@@ -570,7 +570,8 @@ def test_a_long_batch_s_rows_go_to_their_own_experts():
             for p, pg in zip(prompts, pages)]
     got = {}
     for product in ("grouped", "masked"):
-        runner = ModelRunner(config(), params=PARAMS)
+        # (One program holds the whole group: 4 x 64 rows.)
+        runner = ModelRunner(config(max_prefill_tokens=256), params=PARAMS)
         assert runner.backends.experts_whole and runner.backends.interpret
         if product == "masked":
             runner.backends = dataclasses.replace(runner.backends,

@@ -64,8 +64,10 @@ def test_pp2_microbatched_matches_plain_pp2_bitexact():
     """Same mesh, same shardings, same per-row math: the pipelined
     schedule must not change RESULTS at all vs the layer-sharded pp=2
     path (bit-exact greedy tokens + KV)."""
-    a = ModelRunner(cfg(pp=2, pp_microbatch=True))
-    b = ModelRunner(cfg(pp=2))
+    # (4 rows x 32 tokens in ONE program: a group over max_prefill_tokens
+    # runs in parts since PR 45.)
+    a = ModelRunner(cfg(pp=2, pp_microbatch=True, max_prefill_tokens=128))
+    b = ModelRunner(cfg(pp=2, max_prefill_tokens=128))
     seqs = _seqs(4)
     ta = a.prefill_batch([dataclasses.replace(s) for s in seqs])
     tb = b.prefill_batch([dataclasses.replace(s) for s in seqs])

@@ -680,7 +680,7 @@ def test_glm_window_program_commits_in_place_for_v5e(v5e, spec_decode):
     assert pool_sized_ops(text, pool) == []
 
 
-def _hybrid_runner(v5e, rows=32, pages=3000):
+def _hybrid_runner(v5e, rows=32, pages=3000, experts=4):
     """A ModelRunner that places nothing, for a block with recurrent layers
     at Nemotron-3-Nano's widths (Mamba-2 mixers of 64 heads of 64 over a
     state of 128, 8 groups; 32 query heads over 2 KV heads of 128, a page
@@ -694,7 +694,7 @@ def _hybrid_runner(v5e, rows=32, pages=3000):
     spec = NemotronHSpec(
         name="hybrid", vocab_size=1024, hidden_size=2688,
         intermediate_size=1856, num_layers=7, num_heads=32, num_kv_heads=2,
-        head_dim=128, rms_norm_eps=1e-5, num_experts=4,
+        head_dim=128, rms_norm_eps=1e-5, num_experts=experts,
         num_experts_per_tok=6, moe_intermediate_size=1856,
         num_routed_experts=128, num_shared_experts=1,
         shared_intermediate_size=3712, routed_scaling_factor=2.5,
@@ -785,7 +785,9 @@ def test_hybrid_window_program_keeps_the_state_where_it_lies_for_v5e(v5e):
     assert pool_sized_ops(text, state) == []
 
 
-def test_hybrid_prefill_program_reads_the_expert_stacks_where_they_lie(v5e):
+@pytest.mark.parametrize("experts", [16, 4])
+def test_hybrid_prefill_program_reads_the_expert_stacks_where_they_lie(
+        v5e, experts):
     """The prefill program of the same block for two prompts of 256 tokens
     (512 rows: over MOE_DENSE_MAX_ROWS, labelled ``grouped``): the scan over
     pairs hands the kernel of engine/experts.py the expert stacks over ALL
@@ -794,9 +796,16 @@ def test_hybrid_prefill_program_reads_the_expert_stacks_where_they_lie(v5e):
     the shape of a stack or of a layer's slice of one but the arguments and
     their bitcasts (sliced a pair ahead of a custom call a layer's experts
     are copied; handed as [.., K, N] the whole ``up`` stack is: it lies
-    with K minor); the program at 128 rows takes the masked product."""
+    with K minor); the program at 128 rows takes the masked product. At 16
+    experts held the stack is past the chip's 128 MiB of VMEM, as the
+    cell's is, and the rule holds to the letter. At 4 (60 MB a stack) the
+    compiler MAY place the ``up`` stack in VMEM ahead of the scan: since
+    PR 45's one scan over groups it does (three asynchronous slices of a
+    layer's experts and the bitcast that joins them, 33 MB of temporaries
+    where the scan over pairs had 22: PERF.md section 6), where the scan
+    over pairs did not; nothing else has a stack's shape there either."""
     from dynamo_tpu.engine.runner import _PF_HDR
-    runner, spec, params, s = _hybrid_runner(v5e)
+    runner, spec, params, s = _hybrid_runner(v5e, experts=experts)
     page, bucket, batch = runner.config.page_size, 256, 2
     pool = (1, 2, 3000, page, 128)
     s_shape, c_shape = spec.ssm_state_shapes
@@ -816,7 +825,115 @@ def test_hybrid_prefill_program_reads_the_expert_stacks_where_they_lie(v5e):
     # Two calls a pair, traced once each inside the scan's body.
     assert text.count("tpu_custom_call") == 2
     up, down = (2688, 1856), (1856, 2688)
+    # A stack that fits VMEM may be fetched there whole: asynchronous
+    # slices and the bitcast that joins them, no copy inside the scan.
+    vmem = {"slice-start", "slice-done", "custom-call"} \
+        if 3 * experts * 2688 * 1856 < 128 << 20 else set()
     for stack in (up, down):
-        for lead in ((3, 4), (1, 4), (4,)):
-            assert pool_sized_ops(text, (*lead, *stack)) == [], (lead, stack)
+        for lead in ((3, experts), (1, experts), (experts,)):
+            found = pool_sized_ops(text, (*lead, *stack))
+            assert {kind for _, kind in found} <= vmem, (lead, stack, found)
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 4 * 2688 * 1856
+
+
+def _sala_runner(v5e, rows=24, pages=3000):
+    """A ModelRunner that places nothing, for the MiniCPM-SALA block at its
+    published widths (lightning mixers of 32 heads over a state of 128 x
+    128 a head; 32 query heads over 2 KV heads of 128, a page of 128 that
+    is two blocks of 64, 64 of them kept; a dense feed-forward of 16,384)
+    and 7 of its layers (S L L S L L S: a pool and a compressed-key array
+    of three attention layers, a state of four mixers), a narrow
+    vocabulary, int8 weights. Returns (runner, spec, params as shapes,
+    s)."""
+    from dynamo_tpu.engine.backends import choose
+    from dynamo_tpu.engine.config import EngineConfig, MiniCPMSALASpec
+    from dynamo_tpu.engine.model import param_shapes
+    from dynamo_tpu.engine.quant import QUANT_LAYER_KEYS, QTensor
+    from dynamo_tpu.engine.runner import ModelRunner
+    spec = MiniCPMSALASpec(
+        name="sala", vocab_size=1024, hidden_size=4096,
+        intermediate_size=16384, num_layers=7, num_heads=32, num_kv_heads=2,
+        head_dim=128, rms_norm_eps=1e-6, layer_pattern="SDLDLDSDLDLDSD",
+        ssm_heads=32, ssm_head_dim=128, ssm_groups=32, ssm_state=128,
+        scale_emb=12.0, residual_scale=1.4 / 32 ** 0.5, logit_divisor=16.0,
+        quant="int8")
+    assert (spec.pool_layers, spec.ssm_layers, spec.kv_entry) == (
+        3, 4, (2, (128, 128)))
+    runner = object.__new__(ModelRunner)
+    runner.spec = spec
+    runner.config = EngineConfig(model=spec, num_pages=pages,
+                                 max_num_seqs=rows)
+    page = runner.config.resolve_page_size("tpu")
+    assert page == 128
+    runner.config = EngineConfig(model=spec, page_size=page, num_pages=pages,
+                                 max_num_seqs=rows, max_pages_per_seq=128)
+    runner.quant_kv, runner.lora, runner.draft_dev = None, None, None
+    runner._window_cache, runner._prefill_cache = {}, {}
+    runner.backends = choose(runner.config, spec, "tpu", 1, None)
+    assert (runner.backends.attention, runner.backends.kv_commit,
+            runner.backends.ssm) == ("pallas", "in_place", "kernel")
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def q(shape):
+        return QTensor(s(shape, jnp.int8),
+                       s((*shape[:-2], 1, shape[-1]), jnp.float32))
+
+    shapes = param_shapes(spec)
+    params = {"layers": {k: q(v) if k in QUANT_LAYER_KEYS
+                         else s(v, jnp.bfloat16)
+                         for k, v in shapes["layers"].items()},
+              "embed": QTensor(s(shapes["embed"], jnp.int8),
+                               s((1, shapes["embed"][1]), jnp.float32)),
+              "final_norm": s(shapes["final_norm"], jnp.bfloat16),
+              "lm_head": q(shapes["lm_head"])}
+    return runner, spec, params, s
+
+
+def test_sala_programs_compile_for_v5e_with_the_state_where_it_lies(v5e):
+    """The window program of the MiniCPM-SALA block at its widths: THREE
+    kernels (the recurrence at a group a head, three row buffers of 2 MB
+    in VMEM; the pool's reader over the chosen blocks' table, the pool
+    seen as blocks of ONE KV head; the window's commit in place), the
+    float32 state (24 slots x 4 layers x 2 MB) aliased to the recurrence's
+    output and nothing else of its shape (it never rides a conditional: a
+    group without a mixer visits no row), nothing of the pool's shape but
+    arguments and what is written in place. And the prefill program of one
+    prompt of 8,192 tokens, whole: it fits beside the weights (its
+    temporaries under 2 GB: the state is carried through the scan where it
+    lies, the lightning recurrence goes 2,048 tokens at a time, the
+    attention's scores a chunk of queries at a time)."""
+    from dynamo_tpu.engine.runner import _PF_HDR, PK_PREFIX
+    rows, window, pages = 24, 8, 3000
+    runner, spec, params, s = _sala_runner(v5e, rows, pages)
+    page, table = runner.config.page_size, 128
+    pool = (3, 2, pages, page, 128)
+    states = (4, rows, *spec.ssm_state_shapes[0])
+    state = (s(states, jnp.float32),
+             s(spec.comp_key_shape(pages, page), jnp.bfloat16))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    fn = runner._get_window(window, table)
+    assert fn._labels["ssm_backend"] == "kernel"
+    assert fn._labels["prefix_reuse"].startswith("off")
+    lowered = fn.lower(
+        params, s(pool, jnp.bfloat16), s(pool, jnp.bfloat16),
+        s((rows,), jnp.int32), s((rows, PK_PREFIX + table), jnp.int32),
+        s(key.shape, key.dtype), state=state)
+    assert lowered.as_text().count("func.func private @state_step") == 1
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert pool_sized_ops(text, pool) == []
+    shape = "f32[" + ",".join(map(str, states)) + "]"
+    aliased = [line for line in text.splitlines()
+               if "ssm_state_step" in line and "custom-call(" in line]
+    assert len(aliased) == 1 and shape in aliased[0] \
+        and "output_to_operand_aliasing" in aliased[0], aliased
+    assert pool_sized_ops(text, states) == []
+    bucket = 8192
+    fn = runner._get_prefill(bucket, 1, False)
+    compiled = fn.lower(
+        params, s(pool, jnp.bfloat16), s(pool, jnp.bfloat16),
+        s((1, _PF_HDR + bucket + bucket // page + 1), jnp.int32),
+        s(key.shape, key.dtype), state=state).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
