@@ -680,9 +680,12 @@ async def phase_kernels(args, jax, rng, keep: dict):
             # place beside the kernel on a plain bf16 pool at head_dim 128,
             # so the third round compares it with the scatter's logprobs;
             # a latent pool's is in place on a TPU under either reader.
-            # Whoever walks a latent pool's entries walks its index keys.
+            # Whoever walks a latent pool's entries walks its index keys,
+            # and whoever walks K and V pages walks a compressed-key
+            # array's stripes.
             index = record.index
-            check(index == (resolved if spec_r.latent else None),
+            check(index == (resolved if spec_r.latent
+                            or spec_r.compressed_keys else None),
                   f"{resolved} reader of {spec_r.name}: indexer {index}")
             commit = record.kv_commit
             check((commit == "in_place") == (

@@ -86,6 +86,16 @@ over them stays model.select_topk's, in XLA. XLA's indexer gathers every
 slot's whole bucket and scores the copy: alone on one v5e, nine layers, 32
 slots, a bucket of 5,120 tokens, 1.45 ms a step at 17 live rows and 1.46 at
 32, this 0.73 and 1.18 (PERF.md section 6, PR 37, call 3).
+
+The scores of a row's stripes (stripe_scores_pallas, PR 46): the same walk
+over the compressed-key array of a block that attends chosen BLOCKS of keys
+(ModelSpec.comp_key_shape: a page's stripes, the means of every
+sparse_stride keys, are [8, 128] bfloat16 a KV head, whole tiles of the
+array as the chip holds it). A live row's pages come in one copy of both
+heads' stripes each, 32 pages a turn, and a KV group's 16 query heads meet
+a chunk's 256 stripes in one product, bfloat16 into float32, written where
+hybrid.choose_blocks reads them; the choice over them stays XLA's. XLA's
+path gathers every slot's whole bucket of stripes and scores the copy.
 """
 
 from __future__ import annotations
@@ -958,6 +968,104 @@ def latent_index_pallas(iq: jax.Array, iw: jax.Array, i_cache: jax.Array,
                            tokens=tokens, chunk_tokens=chunk_tokens,
                            interpret=interpret)
     return scores.reshape(b, -1)[:, :maxp * page_size]
+
+
+#: Tokens whose stripes one turn of the stripes' kernel fetches, waits for
+#: and scores: 32 pages of 128 at a stride of 16 are 256 scores a head, two
+#: lane tiles of the output, and a copy of 128 KB.
+STRIPE_CHUNK_TOKENS = 4096
+
+
+def _stripe_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
+                   q_ref, c_hbm,            # VMEM block; the array (ANY)
+                   out_ref,                 # output
+                   c_buf, sems, g_ref, cur_ref,  # scratch
+                   *, page_size: int):
+    """The scores of a row's stripes (the compressed-key array of a block
+    that attends chosen blocks of keys: ModelSpec.comp_key_shape), one grid
+    program per batch row: every KV group's query heads (q_ref [1, Nkv, Hg,
+    D] bfloat16, a group's heads the dot's sublanes) against the row's LIVE
+    pages of stripes, ONE strided copy a page of both heads' [stripes a
+    page, D] into c_buf [slot, Nkv, pages, stripes, D] through
+    _fetch_pipeline; a chunk's stripes are one product a KV head, bfloat16
+    into float32, and stripe i of the row lands at out_ref[0, n, :, i]: what
+    hybrid.choose_blocks takes. A row without tokens walks nothing, and what
+    lies past a row's pages (a chunk's tail, a dead slot's whole block) is
+    whatever the buffers held: its caller's to mask."""
+    nkv, ppc, per, d = c_buf.shape[1:]
+    b = pl.program_id(0)
+    nb = pl.num_programs(0)
+    chunk_tokens = ppc * page_size
+    lanes = ppc * per
+    num_chunks = pl.cdiv(seq_lens_ref[b], chunk_tokens)
+    issue_fetch, wait_fetch, prime = _fetch_pipeline(
+        page_table_ref, seq_lens_ref, cur_ref, ((c_hbm, c_buf),), sems,
+        layer_ref[0], nb, page_size, lambda row: 0)
+
+    @pl.when(b == 0)
+    def _():
+        g_ref[0] = 0
+        prime()
+
+    def body(c, carry, g0):
+        slot = jax.lax.rem(g0 + c, SLOTS)
+        wait_fetch(b, c, slot)
+        for n in range(nkv):
+            out_ref[0, n, :, pl.ds(pl.multiple_of(c * lanes, lanes), lanes)] \
+                = jax.lax.dot_general(
+                    q_ref[0, n], c_buf[slot, n].reshape(lanes, d),
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)       # [Hg, lanes]
+        issue_fetch()
+        return carry
+
+    g0 = g_ref[0]
+    jax.lax.fori_loop(0, num_chunks, functools.partial(body, g0=g0), 0)
+    g_ref[0] = g0 + num_chunks
+
+
+def stripe_scores_pallas(qg: jax.Array, comp: jax.Array, layer: jax.Array,
+                         page_table: jax.Array, lens: jax.Array,
+                         page_size: int, interpret: bool = False
+                         ) -> jax.Array:
+    """The decode step's scores of the stripes a row holds in the
+    compressed-key array ``comp`` [A, Nkv, P, stripes a page, D] of
+    ``layer`` (the FULL array: the kernel copies pages): the queries qg [B,
+    Nkv, Hg, D] against a row's first lens [B] tokens' stripes (lens a
+    whole number of stripes; 0: the row walks nothing) under ``page_table``
+    [B, maxP], ``page_size`` tokens a page. Returns [B, Nkv, Hg, maxP x
+    stripes a page] float32: hybrid.pool_stripes and its product (bfloat16
+    into float32) at every stripe under lens and UNDEFINED past it, which
+    may be a NaN: read it under a ``where``. XLA's path gathers every
+    slot's whole bucket and scores the copy (24 slots x 128 pages x 2 heads
+    x 2 KB = 12.6 MB a layer where 9 live rows of 6,000 tokens hold under 2);
+    this reads a row's live pages once (PERF.md section 6, PR 46). The
+    page table is padded to whole chunks of STRIPE_CHUNK_TOKENS."""
+    b, nkv, group, d = qg.shape
+    maxp, per = page_table.shape[1], comp.shape[3]
+    ppc = max(1, STRIPE_CHUNK_TOKENS // page_size)
+    page_table = jnp.pad(page_table, ((0, 0), (0, -maxp % ppc)))
+    lanes = page_table.shape[1] * per
+    dots = pl.pallas_call(
+        functools.partial(_stripe_kernel, page_size=page_size),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b,),
+            in_specs=[pl.BlockSpec((1, nkv, group, d),
+                                   lambda i, *_: (i, 0, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, nkv, group, lanes),
+                                   lambda i, *_: (i, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((SLOTS, nkv, ppc, per, d), comp.dtype),
+                pltpu.SemaphoreType.DMA((1, SLOTS)),
+                pltpu.SMEM((1,), jnp.int32), pltpu.SMEM((3,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((b, nkv, group, lanes), jnp.float32),
+        # Sequential: the fetch pipeline runs from one row into the next.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), page_table, lens, qg, comp)
+    return dots[..., :maxp * per]
 
 
 def _commit_kernel(pid_ref, r0_ref, m0_ref, n_ref,  # SMEM prefetch, [B*J]

@@ -2,15 +2,16 @@
 
 A forward program (engine/model.py, engine/hybrid.py) differs by runner in a
 handful of facts: who reads the pool in decode, who scores a latent pool's
-index keys, how the window writes the pool, who updates a recurrent state,
-whether the experts are whole on one device and whether a kernel is
-interpreted. ``choose`` decides them once a runner, from what the runner
-observes and nothing else; the programs take the record as ONE parameter
-(``backends``), ask it for the callables it binds and for nothing else; and
-``labels`` is the one place the names a program is published under
-(perf.instrumented_jit's ``labels``, /debug/perf, the ``*_info`` gauges) are
-assembled. The imports run config <- backends <- model / hybrid <- runner;
-the Pallas kernels (engine/attention.py) are imported where they are bound.
+index keys or a compressed-key array's stripes, how the window writes the
+pool, who updates a recurrent state, whether the experts are whole on one
+device and whether a kernel is interpreted. ``choose`` decides them once a
+runner, from what the runner observes and nothing else; the programs take
+the record as ONE parameter (``backends``), ask it for the callables it
+binds and for nothing else; and ``labels`` is the one place the names a
+program is published under (perf.instrumented_jit's ``labels``,
+/debug/perf, the ``*_info`` gauges) are assembled. The imports run config
+<- backends <- model / hybrid <- runner; the Pallas kernels
+(engine/attention.py) are imported where they are bound.
 """
 
 from __future__ import annotations
@@ -36,9 +37,11 @@ class Backends:
     references, a test of the plain forward)."""
     #: Who reads the pool in decode: "pallas" | "xla".
     attention: str = "xla"
-    #: Who scores a latent pool's index keys in decode (the reader's name:
-    #: whoever walks a row's entries walks its index keys under the same
-    #: page table); None: a block without an indexer.
+    #: Who scores, in decode, what a query chooses its keys by: a latent
+    #: pool's index keys, or the compressed-key array of a block that
+    #: attends chosen blocks (the reader's name: whoever walks a row's
+    #: pages walks these under the same page table); None: a block whose
+    #: queries choose nothing.
     index: str | None = None
     #: How the decode window writes its tokens into the pool: "in_place"
     #: (attention.commit_window_pallas) | "scatter".
@@ -88,6 +91,15 @@ class Backends:
             return None, None
         return (self._bound("latent_history_pallas", latent=True),
                 self._bound("latent_index_pallas", latent=True))
+
+    def stripe_scorer(self):
+        """Who scores the stripes a row holds in the compressed-key array
+        in a window's step (hybrid.sparse_window_attention): the kernel
+        that walks a live row's pages of the array, or None for XLA's
+        gather of every slot's bucket (hybrid.pool_stripes)."""
+        if self.attention != "pallas":
+            return None
+        return self._bound("stripe_scores_pallas")
 
     def block_reader(self):
         """A latent block without an indexer, S query positions a slot (the
@@ -167,7 +179,8 @@ def choose(config: EngineConfig, spec: ModelSpec, platform: str,
     configuration's platform).
 
     A requested backend is what runs: "pallas" that cannot be had is an
-    error, never XLA. Beside the reader: the indexer's scores follow it;
+    error, never XLA. Beside the reader: the indexer's scores follow it,
+    and so do the scores over a compressed-key array;
     the recurrence's kernel runs where the Pallas reader runs on one TPU
     device (it visits the live slots where the stack lies and reads a state
     once) and XLA's ``hybrid.ssm_step`` everywhere else: the CPU backend,
@@ -191,7 +204,8 @@ def choose(config: EngineConfig, spec: ModelSpec, platform: str,
         ssm = "kernel" if reader == "pallas" and platform == "tpu" else "xla"
     return Backends(
         attention=reader,
-        index=reader if spec.latent and spec.index_topk else None,
+        index=reader if (spec.latent and spec.index_topk
+                         or spec.compressed_keys) else None,
         kv_commit=writer, ssm=ssm, experts_whole=mesh_size == 1,
         interpret=platform == "cpu",
         table=config.max_pages_per_seq if spec.latent else None,

@@ -263,16 +263,25 @@ class ModelSpec:
 
     def comp_key_shape(self, num_pages: int, page_size: int) -> tuple:
         """The compressed-key array of a pool of ``num_pages`` pages:
-        [attention layers, KV heads, pages, stripes a page x head_dim]
+        [attention layers, KV heads, pages, stripes a page, head_dim]
         bfloat16. A STRIPE is the mean of ``sparse_stride`` keys in a row
         of the page; a compressed key (the mean of ``sparse_kernel`` = two
         strides of keys) is the mean of two stripes that follow each other,
-        taken where the scores are (hybrid.stripe_scores), so that a page's
+        taken where the scores are (hybrid.choose_blocks), so that a page's
         stripes are the page's own keys' and nothing is written across a
-        page's border. The last axis is lane-dense: [stripes, head_dim]
-        behind a page would rest padded to 16 sublanes."""
+        page's border. A page's stripes are whole tiles of the array as the
+        chip holds it (8 x 128 bfloat16 under T(8,128)(2,1): 2 KB a page and
+        head, no padding; compiled for a described v5e, PR 46), so that the
+        kernel that scores a row's stripes in decode
+        (attention.stripe_scores_pallas) copies a page's, both heads in one
+        strided copy: behind one flat axis [pages, stripes x head_dim] a
+        page is one ROW of a tile of 8 pages, and Mosaic slices no tile.
+        Written by prefill (hybrid.prefill) and the window's commit
+        (hybrid.commit_stripes); read by that kernel where the Pallas
+        reader runs and by XLA's gather (hybrid.pool_stripes) everywhere
+        else and in a prefill chunk over its history."""
         return (self.pool_layers, self.num_kv_heads, num_pages,
-                page_size // self.sparse_stride * self.head_dim)
+                page_size // self.sparse_stride, self.head_dim)
 
     @property
     def pool_layers(self) -> int:

@@ -1091,7 +1091,8 @@ class TPUEngine(AsyncEngine):
             # Static per runner, the window program's labels (the record
             # of engine/backends.py): who reads the pool in decode and how
             # the window program writes it; who scores a latent pool's
-            # index keys in decode (None for a block without an indexer);
+            # index keys or a compressed-key array's stripes in decode
+            # (None for a block whose queries choose nothing);
             # who drafts inside the window program's steps: "mtp" (the
             # model's own prediction module, spec_decode mtp) or "none";
             # tokens a KV page holds (config.resolve_page_size): over 16
@@ -1159,6 +1160,12 @@ class TPUEngine(AsyncEngine):
                 # Keys of the kept blocks over keys in context, live rows,
                 # every attention layer and decode step so far.
                 "selected_pct": round(100.0 * selected / context, 3)
+                if context else None,
+                # Keys whose stripes the choice read over keys in context:
+                # 100 where the kernel walks the live rows' pages, slots x
+                # bucket over the live rows' keys under XLA's gather.
+                "index_read_pct": round(
+                    100.0 * total["attn_index_read"] / context, 3)
                 if context else None,
             }
         if self.runner.spec.recurrent:

@@ -43,13 +43,22 @@ or feed-forward each, every sublayer ``h <- h + a f(RMS(h; w, eps))`` with
   beside K and V is its share of a STRIPE (the mean of ``sparse_stride``
   keys), in a third array under the same page table
   (``spec.comp_key_shape``): a compressed key is the mean of two stripes.
-  Decode scores the stripes, chooses, and reads the chosen blocks ALONE:
-  the pool seen as blocks of one KV head each is walked by the reader that
-  walks a page table (model.kv_attention: the Pallas kernel or XLA's
-  gather), over a table [rows x KV heads, sparse_topk] of block ids in
-  rising order, so that the one partly filled block is the last and the
-  reader's length masks it. Prefill masks dense scores by blocks, a chunk
-  of queries at a time.
+  Decode scores the stripes, chooses, and reads the chosen blocks ALONE.
+  Who scores the stripes the pool holds follows the pool's reader
+  (``Backends.index``, the label ``index_backend``): beside the Pallas
+  reader the kernel attention.stripe_scores_pallas walks a LIVE row's
+  pages of the array once, a page's stripes of both heads one copy (PR
+  46); everywhere else XLA gathers every slot's whole bucket
+  (``pool_stripes``) and scores the copy. The set is found by counts, not
+  by a sort (``choose_blocks``: block i stays when fewer than K beat it),
+  and comes out in rising order: the pool seen as blocks of one KV head
+  each is walked by the reader that walks a page table
+  (model.kv_attention: the Pallas kernel or XLA's gather), over a table
+  [rows x KV heads, sparse_topk] of those block ids, so that the one
+  partly filled block is the last and the reader's length masks it.
+  Prefill masks dense scores by blocks, a chunk of queries at a time; it
+  writes the array, and so does the window's commit
+  (``commit_stripes``).
 
 A group is at most one recurrent mixer, at most one attention layer, then a
 feed-forward (``groups_of``): Nemotron-H's pairs of M and E with a * between
@@ -504,7 +513,8 @@ def choose_blocks(dots: jax.Array, n_keys: jax.Array, spec: ModelSpec):
     keys at or before the query, itself among them). Returns (blocks
     [..., G, K] int32, kept [..., G, K] bool): the ``sparse_topk`` (K: all
     of them where the dots cover fewer) blocks of highest score, a block
-    that does not exist yet not ``kept``."""
+    that does not exist yet not ``kept``; in rising order, the ones that do
+    not exist last."""
     st, bk = spec.sparse_stride, spec.sparse_block
     per = bk // st
     ns = dots.shape[-1]
@@ -533,14 +543,22 @@ def choose_blocks(dots: jax.Array, n_keys: jax.Array, spec: ModelSpec):
               | (own - ids < spec.sparse_window // bk))
     block = jnp.where(forced, jnp.inf, block)
     block = jnp.where(exists, block, -jnp.inf)
-    # Equal scores keep the lower block: top_k's order; a block that does
-    # not exist ranks behind every one that does.
-    _, blocks = jax.lax.top_k(
-        jnp.where(exists, jnp.maximum(block, -1e30), -jnp.inf),
-        min(spec.sparse_topk, nb))
-    kept = jnp.take_along_axis(jnp.broadcast_to(exists, block.shape), blocks,
-                               axis=-1)
-    return blocks.astype(jnp.int32), kept
+    # The set lax.top_k keeps, by counts and no sort: block i stays when
+    # fewer than K blocks beat it, where j beats i by a higher score, or by
+    # an equal one and a lower index (top_k's order: equal scores keep the
+    # lower block); a block that does not exist (-inf, and past every one
+    # that does) so ranks behind them all, whatever they score. The ranks
+    # are a permutation, so K stay, never more; they come out in RISING
+    # order: place k holds the block that k kept ones lie below, which is
+    # the count of blocks with at most k kept up to them.
+    keep = min(spec.sparse_topk, nb)
+    mine, other = block[..., :, None], block[..., None, :]
+    beats = (other > mine) | ((other == mine) & (ids[None, :] < ids[:, None]))
+    stays = jnp.sum(beats, axis=-1, dtype=jnp.int32) < keep     # [..., G, nb]
+    upto = jnp.cumsum(stays, axis=-1, dtype=jnp.int32)
+    blocks = jnp.sum(upto[..., None, :] <= jnp.arange(keep)[:, None],
+                     axis=-1, dtype=jnp.int32)                  # [..., G, K]
+    return blocks, blocks <= own
 
 
 def sparse_prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -623,7 +641,9 @@ def pool_stripes(comp: jax.Array, layer: jax.Array, page_table: jax.Array,
                  spec: ModelSpec) -> jax.Array:
     """A row's stripes out of the compressed-key array (``spec.
     comp_key_shape``) by its page table [B, maxP]: [Nkv, B, maxP * stripes
-    a page, D]; stripe i of the row stands at i."""
+    a page, D]; stripe i of the row stands at i. XLA's gather of every
+    slot's whole bucket: a prefill chunk's history, and the decode step's
+    wherever attention.stripe_scores_pallas does not run."""
     nkv, b = comp.shape[1], page_table.shape[0]
     index = (jnp.broadcast_to(layer, (nkv, *page_table.shape)),
              jnp.arange(nkv)[:, None, None],
@@ -693,8 +713,16 @@ def sparse_window_attention(q: jax.Array, k_cache: jax.Array,
     compressed-key array): scores over the row's stripes, the choice, and
     the pool's reader over the chosen blocks alone. The window's own
     tokens and the step's lie in the blocks the query's window keeps.
-    Returns (out [B, Nh, D], counts [2] float32: the keys the live rows
-    attended, a KV group's mean, and the keys they had in context)."""
+    Who scores the stripes the pool holds (``backends.stripe_scorer``):
+    where the Pallas reader runs, the kernel that walks a LIVE row's pages
+    of the array once; else XLA's gather of every slot's bucket
+    (``pool_stripes``), scored. Under either a stripe is read only where it
+    is whole in the pool of a live row, and under a ``where``: what the
+    kernel did not write is undefined.
+    Returns (out [B, Nh, D], counts [3] float32: the keys the live rows
+    attended, a KV group's mean, the keys they had in context, and the keys
+    whose stripes the choice READ: the live rows' own under the kernel,
+    every slot's bucket under the gather)."""
     b, nh, d = q.shape
     nkv, pages, page = k_cache.shape[1], k_cache.shape[2], k_cache.shape[3]
     st, bk = spec.sparse_stride, spec.sparse_block
@@ -702,23 +730,29 @@ def sparse_window_attention(q: jax.Array, k_cache: jax.Array,
     M = k_win.shape[2]
     n_keys = hist_lens + m + 1
     qg = q.reshape(b, nkv, group, d)
+    scorer = backends.stripe_scorer()
     with scope("attn.index"):
-        old = jnp.einsum("bngd,nbid->bngi", qg,
-                         pool_stripes(comp, layer, page_table, spec),
-                         preferred_element_type=jnp.float32)
+        # Stripe i of a live row is the pool's while i < pooled.
+        pooled = jnp.where(live, hist_lens // st, 0)
+        if scorer is None:
+            old = jnp.einsum("bngd,nbid->bngi", qg,
+                             pool_stripes(comp, layer, page_table, spec),
+                             preferred_element_type=jnp.float32)
+        else:
+            old = scorer(qg, comp, layer, page_table, pooled * st, page)
         new, first = window_stripes(k_cache, layer, page_table, hist_lens,
                                     k_win, m, k_self, spec)
         new = jnp.einsum("bngd,bcnd->bngc", qg, new,
                          preferred_element_type=jnp.float32)
         at = jnp.arange(old.shape[-1])[None, :]                # [1, NS]
-        dots = jnp.where((at < first[:, None])[:, None, None, :], old, 0.0)
+        dots = jnp.where((at < pooled[:, None])[:, None, None, :], old, 0.0)
         for c in range(new.shape[-1]):
             dots = dots + jnp.where(
                 (at == first[:, None] + c)[:, None, None, :],
                 new[..., c:c + 1], 0.0)
-        blocks, kept = choose_blocks(dots, n_keys, spec)       # [B, Nkv, K]
-        nb = dots.shape[-1] * st // bk
-        blocks = jnp.sort(jnp.where(kept, blocks, nb), axis=-1)
+        # [B, Nkv, K], rising; a block that does not exist past the pool's.
+        blocks, kept = choose_blocks(dots, n_keys, spec)
+        blocks = jnp.where(kept, blocks, dots.shape[-1] * st // bk)
         # The pool holds positions under hist_lens: whole blocks up to the
         # one of its last token, which the query's window keeps, and which
         # is the last of the table that the reader's length lets it read.
@@ -748,9 +782,11 @@ def sparse_window_attention(q: jax.Array, k_cache: jax.Array,
             rows(k_self)[:, None], rows(v_self)[:, None], group)
     attended = jnp.mean((length + m + 1).astype(jnp.float32), axis=-1)
     on = live.astype(jnp.float32)
-    counts = jnp.stack([jnp.sum(attended * on),
-                        jnp.sum(n_keys.astype(jnp.float32) * on)])
-    return out.reshape(b, nh, d), counts
+    context = jnp.sum(n_keys.astype(jnp.float32) * on)
+    read = context if scorer is not None else jnp.float32(
+        b * page_table.shape[1] * page)
+    return out.reshape(b, nh, d), jnp.stack(
+        [jnp.sum(attended * on), context, read])
 
 
 # ---------------------------------------------------------------------------
@@ -1038,12 +1074,10 @@ def prefill(params: Params, spec: ModelSpec, k_cache: jax.Array,
         v_cache = scatter_pages(v_cache, blocks(v_new), flat)
     if comp is not None:
         with scope("attn.compress"):
-            # A page's stripes are one row of the array: [stripes, D] flat.
             per = page // spec.sparse_stride
-            rows_of = (stripes[0].reshape(n_attn, b * (s // page), per, nkv, d)
-                       .transpose(0, 3, 1, 2, 4)
-                       .reshape(n_attn, nkv, -1, per * d))
-            comp = comp.at[:, :, flat].set(rows_of)
+            comp = comp.at[:, :, flat].set(
+                stripes[0].reshape(n_attn, b * (s // page), per, nkv, d)
+                .transpose(0, 3, 1, 2, 4))
     with scope("lm_head"):
         x = rms_norm(x, params["final_norm"], spec.rms_norm_eps)
         last = jnp.maximum(seq_lens - 1, 0)
@@ -1071,8 +1105,9 @@ def window_step(params: Params, spec: ModelSpec, k_cache: jax.Array,
     the live slots' S where the stack lies; else XLA every slot's
     (``state_update``). Returns (logits, k_new and v_new [A, B, Nkv, D],
     state, counts: "moe" the expert layers' load [E, 5], "ssm" the live
-    rows [1, 1], "attn" the keys attended and in context [A, 2]; the keys
-    the host's table knows, runtime/flight.py COUNTS)."""
+    rows [1, 1], "attn" the keys attended, in context and read by the
+    choice [A, 3]; the keys the host's table knows, runtime/flight.py
+    COUNTS)."""
     b = tokens.shape[0]
     x = _embed(params, spec, tokens)
     attend = kv_attention(backends, window=True)
@@ -1135,7 +1170,7 @@ def window_step(params: Params, spec: ModelSpec, k_cache: jax.Array,
 
     kv_like = (_like(b, spec.num_kv_heads, spec.head_dim),) * 2
     if comp is not None:
-        kv_like += (_like(2, dtype=jnp.float32),)
+        kv_like += (_like(3, dtype=jnp.float32),)
     x, state, (k_new, v_new, *counts), load = scan_groups(
         params["layers"], spec, x, state, mixer, attn_fn, kv_like, live=live)
     with scope("lm_head"):
@@ -1160,6 +1195,7 @@ def commit_stripes(comp: jax.Array, k_cache: jax.Array, k_buf: jax.Array,
     started, ``ends`` [B] those it holds after (a row that took no step:
     the same)."""
     st, page, d = spec.sparse_stride, k_cache.shape[3], spec.head_dim
+    per = page // st
     n_attn, nkv, b, _, _ = k_buf.shape
     steps = ends - hist_lens                                   # [B]
     means = jax.lax.map(
@@ -1175,11 +1211,17 @@ def commit_stripes(comp: jax.Array, k_cache: jax.Array, k_buf: jax.Array,
         done = (steps[row] > 0) & ((stripe + 1) * st <= ends[row])
         of_page = page_table[row, jnp.clip(stripe * st // page, 0,
                                            page_table.shape[1] - 1)]
-        where = (0, 0, of_page, (stripe * st % page) // st * d)
+        # The page's stripes whole, [per, D] a layer and head: the array's
+        # tiles as it lies (one stripe alone, and XLA turns the whole array
+        # to put the layers minor, a copy in and one out a window: compiled
+        # for a described v5e, PR 46).
+        where = (0, 0, of_page, 0, 0)
+        here = done & (jnp.arange(per) == (stripe * st % page) // st)
         new = jax.lax.dynamic_slice(
-            means, (0, row, c, 0, 0), (n_attn, 1, 1, nkv, d))[:, 0]
-        old = jax.lax.dynamic_slice(comp, where, (n_attn, nkv, 1, d))
+            means, (0, row, c, 0, 0), (n_attn, 1, 1, nkv, d))
+        old = jax.lax.dynamic_slice(comp, where, (n_attn, nkv, 1, per, d))
         return jax.lax.dynamic_update_slice(
-            comp, jnp.where(done, jnp.swapaxes(new, 1, 2), old), where)
+            comp, jnp.where(here[:, None], jnp.moveaxis(new, 3, 1), old),
+            where)
 
     return jax.lax.fori_loop(0, b * cands, put, comp)

@@ -744,9 +744,10 @@ class PerfMetricsUpdater:
             "perf_index_info", "1 under the label of who runs the decode "
             "indexer of this worker's latent pool (engine/backends.py "
             "Backends.index): pallas (a kernel walks a row's live pages "
-            "of index keys and scores them) or xla (the gather of every "
-            "slot's page-table bucket, scored); the choice over the scores "
-            "is XLA's under either; no sample for a block without an indexer",
+            "of index keys, or of a compressed-key array's stripes, and "
+            "scores them) or xla (the gather of every slot's page-table "
+            "bucket, scored); the choice over the scores is XLA's under "
+            "either; no sample for a block whose queries choose nothing",
             ["backend"])
         self.g_kv_page = registry.gauge(
             "perf_kv_page_info", "1 under the label of how many tokens a "
@@ -831,6 +832,13 @@ class PerfMetricsUpdater:
             "attn_context_total", "Latent block: keys the live rows had in "
             "context, summed over rows, layers and decode steps (every one "
             "is scored by the indexer)")
+        self.c_counts["attn_index_read"] = registry.counter(
+            "attn_index_read_total", "Block that attends chosen blocks of "
+            "keys: keys whose stripes the choice of blocks READ, summed "
+            "over layers and decode steps: attn_context_total where a "
+            "kernel walks the live rows' pages of the compressed-key "
+            "array (index_backend pallas), slots x page-table bucket a "
+            "layer and step under XLA's gather")
         self.c_counts["ssm_row_steps"] = registry.counter(
             "ssm_row_steps_total", "Block with recurrent layers: (decode "
             "step, live row) pairs, summed on the device: the rows whose "

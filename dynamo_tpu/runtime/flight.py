@@ -70,12 +70,16 @@ log = get_logger("flight")
 # pairs); 0 for every other block. A latent block's window adds
 # "attn_selected" (keys its live rows attended, summed over rows, layers
 # and steps on the device) and "attn_context" (keys they had in context);
-# 0 for every other block. A drafting window (spec_decode) adds
-# "spec_drafted" (draft tokens its verify steps took in), "spec_accepted"
-# (those the target's own draws confirmed) and "spec_row_steps" (verify
-# steps of live rows: each emits one token and its accepted drafts, so the
-# tokens emitted are spec_row_steps + spec_accepted), read back with the
-# window's tokens; 0 without drafting. A block with recurrent layers adds
+# 0 for every other block. A block that attends chosen blocks of keys adds
+# "attn_index_read" (keys whose stripes its choice read: attn_context where
+# a kernel walks the live rows' pages, slots x page-table bucket a layer
+# and step under XLA's gather); 0 for every other block. A drafting window
+# (spec_decode) adds "spec_drafted" (draft tokens its verify steps took
+# in), "spec_accepted" (those the target's own draws confirmed) and
+# "spec_row_steps" (verify steps of live rows: each emits one token and its
+# accepted drafts, so the tokens emitted are spec_row_steps +
+# spec_accepted), read back with the window's tokens; 0 without drafting.
+# A block with recurrent layers adds
 # "ssm_row_steps" (live rows summed over the window's steps, on the device:
 # the rows whose recurrent state a step had to touch); 0 for every other
 # block. Beside "rows", taken at the same instant (the
@@ -94,7 +98,7 @@ FIELDS = ("t_mono", "dur_s", "active", "waiting", "free_pages",
           "moe_load", "moe_layer_steps", "moe_local_picks", "moe_picks",
           "attn_selected", "attn_context", "prefilling", "admit_stop",
           "spec_drafted", "spec_accepted", "spec_row_steps",
-          "ssm_row_steps")
+          "ssm_row_steps", "attn_index_read")
 _INT_FIELDS = ("active", "waiting", "free_pages", "chunk_tokens",
                "chunks_inflight", "preempts", "brownout", "step", "tokens",
                "rows", "page_bucket", "missed", "prefilling", "admit_stop")
@@ -106,7 +110,8 @@ _INT_FIELDS = ("active", "waiting", "free_pages", "chunk_tokens",
 #: ONE statement of where a count lands: the engine fills a window's counts
 #: and its totals through ``columns_of``, ``record`` stores by column name,
 #: perf's exporter walks the table. A vector may be shorter than its
-#: columns ("moe" is [3] where the expert layer is not told its share).
+#: columns ("moe" is [3] where the expert layer is not told its share,
+#: "attn" [2] for a latent block).
 COUNTS = {
     "moe": (("moe_touched", "moe_experts_touched_total"),
             ("moe_load", "moe_expert_load_max_over_mean_total"),
@@ -114,7 +119,8 @@ COUNTS = {
             ("moe_local_picks", "moe_local_picks_total"),
             ("moe_picks", "moe_picks_total")),
     "attn": (("attn_selected", "attn_selected_total"),
-             ("attn_context", "attn_context_total")),
+             ("attn_context", "attn_context_total"),
+             ("attn_index_read", "attn_index_read_total")),
     "ssm": (("ssm_row_steps", "ssm_row_steps_total"),),
     "spec": (("spec_drafted", None), ("spec_accepted", None),
              ("spec_row_steps", None)),

@@ -2,7 +2,7 @@
 program's labels are assembled.
 
 (a) the choice as a table: what a runner observes -> the record, or the
-refusal's words; (b) for a tiny model of each of the six block kinds the
+refusal's words; (b) for a tiny model of each of the seven block kinds the
 benchmark serves, the names its programs are published under (the record's
 labels, the compile registry's, /debug/perf's) against ONE literal each, so
 the names the benchmark prints are pinned where a PR can see them.
@@ -31,6 +31,8 @@ POOLS = {
     "indexed": dict(latent=True, index_topk=2048, recurrent=False),
     "latent": dict(latent=True, index_topk=0, recurrent=False),
     "recurrent": dict(latent=False, index_topk=0, recurrent=True),
+    "blocks": dict(latent=False, index_topk=0, recurrent=True,
+                   compressed_keys=True),
 }
 TABLE = 64      # max_pages_per_seq of the stub: the latent readers' table
 
@@ -98,12 +100,25 @@ def on(platform, mesh=1, **kw):
         ("cpu", 1, 128, None, "recurrent", "auto", on("cpu", ssm="xla")),
         ("cpu", 1, 128, None, "recurrent", "pallas",
          on("cpu", attention="pallas", kv_commit="in_place", ssm="xla")),
+        # ... whose attention reads chosen blocks of keys: who scores the
+        # compressed-key array's stripes follows the reader too.
+        ("tpu", 1, 128, None, "blocks", "auto",
+         on("tpu", attention="pallas", index="pallas", kv_commit="in_place",
+            ssm="kernel")),
+        ("tpu", 1, 128, None, "blocks", "xla",
+         on("tpu", index="xla", ssm="xla")),
+        ("cpu", 1, 128, None, "blocks", "auto",
+         on("cpu", index="xla", ssm="xla")),
+        ("cpu", 1, 128, None, "blocks", "pallas",
+         on("cpu", attention="pallas", index="pallas", kv_commit="in_place",
+            ssm="xla")),
     ])
 def test_the_record_is_decided_from_what_a_runner_observes(
         platform, mesh, head_dim, quant_kv, pool, asked, want):
     config = SimpleNamespace(attention_backend=asked, page_size=16,
                              max_pages_per_seq=TABLE, spec_decode=None)
-    spec = SimpleNamespace(head_dim=head_dim, num_experts=0, **POOLS[pool])
+    spec = SimpleNamespace(**{**dict(head_dim=head_dim, num_experts=0,
+                                     compressed_keys=False), **POOLS[pool]})
     if isinstance(want, str):
         with pytest.raises(ValueError) as refused:
             choose(config, spec, platform, mesh, quant_kv)
@@ -117,7 +132,7 @@ def test_the_record_is_decided_from_what_a_runner_observes(
     assert (model.kv_attention(got, window=True)
             is model.paged_window_attention_xla) == (not kernel)
     for bound in (got.kv_reader(False), got.block_reader(),
-                  *got.latent_readers()):
+                  got.stripe_scorer(), *got.latent_readers()):
         assert (bound is None) == (not kernel)
         if kernel:
             assert bound.keywords["interpret"] == (platform == "cpu")
@@ -183,6 +198,13 @@ NAMES = {
                      "prefix_reuse": OFF},
                     {"expert_product": "grouped", "ssm_state": "float32",
                      "prefix_reuse": OFF})},
+    "minicpm-sala-9b-int8": {
+        "decode_window": {"attention_backend": "xla",
+                          "kv_commit_backend": "scatter", "page_size": 16,
+                          "index_backend": "xla", "draft": "none",
+                          "ssm_state": "float32", "prefix_reuse": OFF,
+                          "ssm_backend": "xla"},
+        "prefill": ({"ssm_state": "float32", "prefix_reuse": OFF},) * 2},
 }
 #: ... and the record's for the configuration AS PUBLISHED on its cell's
 #: chip (one v5e under "auto", at the page "auto" derives there): what the
@@ -207,6 +229,10 @@ ON_THE_CHIP = {
                                          "kv_commit_backend": "in_place",
                                          "page_size": 128,
                                          "ssm_backend": "kernel"},
+    "minicpm-sala-9b-int8": {"attention_backend": "pallas",
+                             "kv_commit_backend": "in_place",
+                             "page_size": 128, "index_backend": "pallas",
+                             "ssm_backend": "kernel"},
 }
 
 
@@ -223,7 +249,10 @@ def cell_config(name: str, rehearsal: bool, **kw) -> EngineConfig:
     cut = config.pop("rehearsal_model", None) or toy
     if rehearsal:
         config.update(cut)
-    launch = {k: v for k, v in config["launch"].items() if k != "quant"}
+    # The launcher's own words (the cell's sizes are the chip's: a
+    # rehearsal builds at the sizes below).
+    launch = {k: v for k, v in config["launch"].items()
+              if k in ("spec_decode", "spec_k")}
     with tempfile.TemporaryDirectory() as tmp:
         path = os.path.join(tmp, "config.json")
         with open(path, "w", encoding="utf-8") as fh:

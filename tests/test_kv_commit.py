@@ -228,6 +228,7 @@ def _picked(attention_backend, mesh_size, head_dim, quant_kv):
                              page_size=PAGE, max_pages_per_seq=8,
                              spec_decode=None)
     spec = SimpleNamespace(head_dim=head_dim, latent=False, recurrent=False,
+                           compressed_keys=False,
                            index_topk=0, num_experts=0)
     return choose(config, spec, "tpu", mesh_size, quant_kv).kv_commit
 

@@ -140,7 +140,7 @@ def test_the_reader_makes_the_catalog_row_s_spec():
     assert spec.kv_bytes_per_token() == 8 * (1024 + 32) == 8448
     assert spec.ssm_state_shapes == ((32, 128, 128), None)
     assert spec.ssm_state_bytes_per_row == 24 * 32 * 128 * 128 * 4
-    assert spec.comp_key_shape(100, 128) == (8, 2, 100, 8 * 128)
+    assert spec.comp_key_shape(100, 128) == (8, 2, 100, 8, 128)
     assert (spec.sparse_kernel, spec.sparse_stride, spec.sparse_block,
             spec.sparse_topk, spec.sparse_init_blocks, spec.sparse_window) \
         == (32, 16, 64, 64, 1, 2048)
@@ -404,6 +404,106 @@ def test_equal_scores_keep_the_lower_block():
     assert np.flatnonzero(want[-1, 0]).tolist() == [0, 1, 2, 3, 10, 11]
 
 
+def _blocks_by_sort(dots, n_keys, spec):
+    """The choice as PR 45 made it: the same block scores, then
+    ``lax.top_k`` (a sort): the set ``choose_blocks`` is held to."""
+    st, bk = spec.sparse_stride, spec.sparse_block
+    per = bk // st
+    ns = dots.shape[-1]
+    nb = ns // per
+    n = n_keys[..., None, None]
+    score = 0.5 * (dots[..., :-1] + dots[..., 1:]) * spec.head_dim ** -0.5
+    whole = (jnp.arange(ns - 1) + 2) * st <= n[..., None]
+    top = jnp.max(jnp.where(whole, score, -jnp.inf), axis=-1, keepdims=True)
+    e = jnp.where(whole, jnp.exp(score - jnp.where(jnp.isfinite(top), top,
+                                                   0.0)), 0.0)
+    p = e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
+    group = jnp.where(whole[..., 0, :], jnp.sum(p, axis=-2), -jnp.inf)
+    lead = group.shape[:-1]
+    edge = jnp.full((*lead, 1), -jnp.inf)
+    padded = jnp.concatenate([edge, group, edge], axis=-1)
+    block = jnp.maximum(
+        jnp.max(padded[..., 1:].reshape(*lead, nb, per), axis=-1),
+        padded[..., 0:nb * per:per])
+    ids = jnp.arange(nb)
+    own = (n - 1) // bk
+    exists = ids <= own
+    forced = ((ids < spec.sparse_init_blocks)
+              | (own - ids < spec.sparse_window // bk))
+    block = jnp.where(forced, jnp.inf, block)
+    block = jnp.where(exists, block, -jnp.inf)
+    _, blocks = jax.lax.top_k(
+        jnp.where(exists, jnp.maximum(block, -1e30), -jnp.inf),
+        min(spec.sparse_topk, nb))
+    kept = jnp.take_along_axis(jnp.broadcast_to(exists, block.shape), blocks,
+                               axis=-1)
+    return blocks, kept
+
+
+#: The choice at the published sizes: 64 of 256 blocks of 64 keys.
+PUBLISHED = dataclasses.replace(
+    SPEC, sparse_kernel=32, sparse_stride=16, sparse_block=64,
+    sparse_topk=64, sparse_window=2048)
+
+
+def _scores(case: str, spec, rows: int, seed: int):
+    """(dots [rows, 2, 2, NS], n_keys [rows]) of a case of the rank."""
+    rng = np.random.default_rng(seed)
+    per = spec.sparse_block // spec.sparse_stride
+    nb = 4 * spec.sparse_topk
+    ns = nb * per
+    dots = rng.normal(size=(rows, 2, 2, ns)) * 8
+    n_keys = rng.integers(nb * spec.sparse_block // 2,
+                          nb * spec.sparse_block, size=rows) + 1
+    if case == "ties across the rank":
+        # Half as many blocks as are kept stand out, every other block
+        # scores the same: rank K falls among equals.
+        high = rng.permuted(np.broadcast_to(
+            np.arange(nb) < spec.sparse_topk // 2, (rows, 2, 1, nb)), axis=-1)
+        dots = np.repeat(np.where(high, 40.0, 0.0), per, axis=-1) \
+            + np.zeros_like(dots)
+    elif case == "all equal":
+        dots = np.zeros_like(dots)
+    elif case == "fewer blocks than are kept":
+        n_keys = rng.integers(1, spec.sparse_topk * spec.sparse_block // 2,
+                              size=rows)
+    elif case == "blocks that do not exist":
+        # From a query's first key to a row that fills the table.
+        n_keys = np.linspace(1, nb * spec.sparse_block, rows).astype(int)
+    elif case == "a table narrower than the kept":
+        dots = dots[..., :spec.sparse_topk // 2 * per]
+        n_keys = rng.integers(1, spec.sparse_topk // 2 * spec.sparse_block,
+                              size=rows)
+    return jnp.asarray(dots, jnp.float32), jnp.asarray(n_keys, jnp.int32)
+
+
+@pytest.mark.parametrize("spec", [SPEC, PUBLISHED],
+                         ids=["6 of 24 blocks", "64 of 256 blocks"])
+@pytest.mark.parametrize("case", [
+    "random", "ties across the rank", "all equal",
+    "fewer blocks than are kept", "blocks that do not exist",
+    "a table narrower than the kept"])
+def test_the_rank_by_counts_keeps_top_k_s_set(case, spec):
+    """``choose_blocks`` finds its set by counts (block i stays when fewer
+    than K beat it): ``lax.top_k``'s set exactly, its tie rule with it,
+    never more than K, in rising order with the blocks that do not exist
+    last and not ``kept``."""
+    dots, n_keys = _scores(case, spec, rows=12, seed=len(case))
+    blocks, kept = (np.asarray(a) for a in
+                    jax.jit(lambda d, n: hybrid.choose_blocks(d, n, spec))(
+                        dots, n_keys))
+    want, want_kept = (np.asarray(a) for a in
+                       _blocks_by_sort(dots, n_keys, spec))
+    nb = dots.shape[-1] * spec.sparse_stride // spec.sparse_block
+    assert blocks.shape == want.shape == (12, 2, min(spec.sparse_topk, nb))
+    assert blocks.dtype == np.int32 and kept.dtype == bool
+    assert (np.diff(blocks, axis=-1) > 0).all()          # rising, no repeat
+    np.testing.assert_array_equal(np.sort(want, axis=-1), blocks)
+    own = (np.asarray(n_keys) - 1) // spec.sparse_block
+    np.testing.assert_array_equal(kept, blocks <= own[:, None, None])
+    assert (kept.sum(-1) == want_kept.sum(-1)).all()
+
+
 # -- the runner ------------------------------------------------------------------
 
 def _window(runner, rows: dict, steps: int):
@@ -471,8 +571,11 @@ def test_a_padded_batch_its_windows_and_the_compressed_keys():
         toks.append(t)
         lps.append(lp)
         assert float(np.asarray(counted["ssm"])[0]) == 12.0
-        attended, context = np.asarray(counted["attn"])
+        attended, context, read = np.asarray(counted["attn"])
         assert 0 < attended <= context
+        # XLA's gather reads every slot's bucket (the widest row's 5 pages),
+        # a layer and step.
+        assert read == 4 * 3 * 4 * 5 * PAGE
     # The third window's keys in context over the three attention layers;
     # the rows past 48 keys (6 blocks of 8) attend fewer.
     assert context == 3 * sum(
@@ -562,13 +665,13 @@ def test_a_group_over_the_bound_runs_in_parts():
     np.testing.assert_array_equal(fetched, got[256][0].astype(np.int64))
 
 
-def test_the_window_step_with_both_kernels_interpreted():
-    """hybrid.window_step with the recurrence's kernel and the pool's
-    reader (over the chosen blocks' table) interpreted, against XLA's: the
-    live rows' logits, the state and the counts agree."""
-    rows, window, pages = 2, 4, 40
+def _kernel_window_case():
+    """The operands of hybrid.window_step over four slots of a pool of 60
+    pages of 32 (whole lane tiles of a packed head: 32 x 16 / 128): rows
+    300 and 77 tokens deep, a row at its cap (it holds 128 tokens and is
+    not live) and a slot that holds nothing."""
+    rows, window, pages, page = 4, 4, 60, 32
     nkv, d = SPEC.num_kv_heads, SPEC.head_dim
-    page = 32       # whole lane tiles of a packed head: 32 x 16 / 128
     key = jax.random.key(3)
     pool = (SPEC.pool_layers, nkv, pages, page, d)
     k_cache = jax.random.normal(key, pool, jnp.bfloat16)
@@ -577,34 +680,154 @@ def test_the_window_step_with_both_kernels_interpreted():
     comp = hybrid.stripe_means(
         jnp.moveaxis(k_cache, 1, 3).reshape(-1, pages * page, nkv, d),
         SPEC.sparse_stride).reshape(SPEC.pool_layers, pages, -1, nkv, d)
-    comp = jnp.moveaxis(comp, 3, 1).reshape(
-        SPEC.comp_key_shape(pages, page))
+    comp = jnp.moveaxis(comp, 3, 1)
+    assert comp.shape == SPEC.comp_key_shape(pages, page)
     buf = jnp.zeros((SPEC.pool_layers, nkv, rows, window, d), jnp.bfloat16)
     s_shape, _ = SPEC.ssm_state_shapes
     state = (jax.random.normal(jax.random.fold_in(key, 2),
                                (SPEC.ssm_layers, rows, *s_shape)),)
-    hist = jnp.asarray([300, 77])
-    table = jnp.asarray([np.arange(1, 11), np.arange(11, 21)], jnp.int32)
+    hist = jnp.asarray([300, 77, 128, 0])
+    table = jnp.asarray(np.arange(1, 41).reshape(4, 10), jnp.int32)
+    live = jnp.asarray([True, True, False, False])
     args = (PARAMS, SPEC, k_cache, v_cache, buf, buf, jnp.int32(0),
-            jnp.asarray([3, 5]), table, hist, state,
-            jnp.asarray([True, True]))
-    want = hybrid.window_step(*args, positions=hist, comp=comp)
-    got = hybrid.window_step(*args, positions=hist, comp=comp,
+            jnp.asarray([3, 5, 7, 0]), table, hist, state, live)
+    return args, dict(positions=hist, comp=comp)
+
+
+def test_the_window_step_with_both_kernels_interpreted():
+    """hybrid.window_step with the recurrence's kernel, the stripes' kernel
+    and the pool's reader (over the chosen blocks' table) interpreted,
+    against XLA's: the live rows' logits, the state and the counts agree."""
+    args, kw = _kernel_window_case()
+    live = np.asarray(args[-1])
+    want = hybrid.window_step(*args, **kw)
+    got = hybrid.window_step(*args, **kw,
                              backends=Backends(attention="pallas",
                                                ssm="kernel", interpret=True))
-    np.testing.assert_allclose(np.asarray(got[0], np.float32),
-                               np.asarray(want[0], np.float32),
+    np.testing.assert_allclose(np.asarray(got[0], np.float32)[live],
+                               np.asarray(want[0], np.float32)[live],
                                atol=0.03 * float(jnp.abs(want[0]).max()))
     # (The readers round their sums apart, and the mixers behind the first
     # attention layer read what it gave.)
     np.testing.assert_allclose(np.asarray(got[3][0]), np.asarray(want[3][0]),
                                atol=0.03 * float(jnp.abs(want[3][0]).max()))
-    np.testing.assert_array_equal(np.asarray(got[4]["attn"]),
-                                  np.asarray(want[4]["attn"]))
-    attended, context = np.asarray(want[4]["attn"]).sum(axis=0)
+    np.testing.assert_array_equal(np.asarray(got[4]["attn"])[:, :2],
+                                  np.asarray(want[4]["attn"])[:, :2])
+    attended, context, gathered = np.asarray(want[4]["attn"]).sum(axis=0)
     # 38 and 10 blocks: 5 whole ones and the query's own partly filled one.
     assert context == 3 * (301 + 78)
     assert attended == 3 * (5 * 8 + 301 % 8 + 5 * 8 + 78 % 8)
+    # The choice read every slot's bucket under the gather, the live rows'
+    # keys under the kernel.
+    assert gathered == 3 * 4 * 10 * 32
+    assert np.asarray(got[4]["attn"])[:, 2].sum() == context
+
+
+# -- the stripes' kernel ---------------------------------------------------------
+
+@pytest.mark.parametrize("maxp", [6, 20], ids=["a bucket of 6 pages",
+                                               "a bucket of 20 pages"])
+def test_the_kernel_s_scores_are_the_gather_s(maxp, monkeypatch):
+    """attention.stripe_scores_pallas, interpreted, against
+    ``pool_stripes`` and its product at every stripe a row holds: rows of
+    unequal depth in two page-table buckets (a table of one and a half
+    chunks, padded, and one of five), a row that fills its table, rows of
+    no tokens (a dead slot's length is handed in as 0) first, between and
+    last, a row that ends on a chunk's border, on a page's and mid-page."""
+    from dynamo_tpu.engine import attention
+    monkeypatch.setattr(attention, "STRIPE_CHUNK_TOKENS", 4 * PAGE)
+    st, d, nkv = SPEC.sparse_stride, SPEC.head_dim, SPEC.num_kv_heads
+    lens = np.asarray([0, maxp * PAGE, 0, 4 * PAGE, 3 * PAGE, 38, 2, 0])
+    rows = len(lens)
+    key = jax.random.key(maxp)
+    comp = jax.random.normal(key, SPEC.comp_key_shape(200, PAGE),
+                             jnp.bfloat16)
+    qg = jax.random.normal(jax.random.fold_in(key, 1),
+                           (rows, nkv, SPEC.q_per_kv, d), jnp.bfloat16)
+    table = jnp.asarray(np.random.default_rng(maxp).permutation(
+        np.arange(1, 200))[:rows * maxp].reshape(rows, maxp), jnp.int32)
+    for layer in (0, 2):
+        want = np.asarray(jnp.einsum(
+            "bngd,nbid->bngi", qg,
+            hybrid.pool_stripes(comp, jnp.int32(layer), table, SPEC),
+            preferred_element_type=jnp.float32))
+        got = np.asarray(attention.stripe_scores_pallas(
+            qg, comp, jnp.int32(layer), table, jnp.asarray(lens, jnp.int32),
+            PAGE, interpret=True))
+        assert got.shape == want.shape == (
+            rows, nkv, SPEC.q_per_kv, maxp * PAGE // st)
+        for row, n in enumerate(lens // st):
+            np.testing.assert_allclose(got[row, :, :, :n],
+                                       want[row, :, :, :n], rtol=1e-5,
+                                       atol=1e-5 * np.abs(want).max())
+
+
+def test_what_the_kernel_did_not_write_reaches_no_live_row(monkeypatch):
+    """The scores' block of a dead slot, of a row at its cap and past a
+    live row's last whole stripe is undefined: filled with NaN behind the
+    kernel, the live rows' logits, their state and the counts are what they
+    were, bit for bit (every reader of the scores selects, none
+    multiplies)."""
+    from dynamo_tpu.engine import attention
+    args, kw = _kernel_window_case()
+    record = Backends(attention="pallas", interpret=True)
+    want = hybrid.window_step(*args, **kw, backends=record)
+    scores = attention.stripe_scores_pallas
+    poisoned = []
+
+    def poison(qg, comp, layer, page_table, lens, page, **how):
+        dots = scores(qg, comp, layer, page_table, lens, page, **how)
+        at = jnp.arange(dots.shape[-1])[None, :]
+        held = (at < lens[:, None] // SPEC.sparse_stride)[:, None, None, :]
+        poisoned.append(dots.shape)
+        return jnp.where(held, dots, jnp.nan)
+
+    monkeypatch.setattr(attention, "stripe_scores_pallas", poison)
+    got = hybrid.window_step(*args, **kw, backends=record)
+    assert poisoned and set(poisoned) == {(4, 2, 2, 160)}
+    live = np.asarray(args[-1])
+    assert np.isfinite(np.asarray(got[0], np.float32)[live]).all()
+    np.testing.assert_array_equal(_bits(got[0].astype(jnp.float32))[live],
+                                  _bits(want[0].astype(jnp.float32))[live])
+    np.testing.assert_array_equal(_bits(got[3][0])[:, live],
+                                  _bits(want[3][0])[:, live])
+    np.testing.assert_array_equal(np.asarray(got[4]["attn"]),
+                                  np.asarray(want[4]["attn"]))
+
+
+def test_a_window_served_with_the_kernel_is_the_gather_s():
+    """A runner whose record names the Pallas reader (interpreted here: the
+    stripes' kernel and the pool's reader) against XLA's: two prompts, a
+    dead slot between them, three windows: the same tokens, the same keys
+    attended and in context, and the choice READ the live rows' keys where
+    the gather read every slot's bucket."""
+    prompts = {0: prompt_of(41, 71), 2: prompt_of(60, 101)}
+    pages = {0: [1, 2, 3, 4], 2: [7, 8, 9, 10, 11]}
+    served = {}
+    for backend in ("xla", "pallas"):
+        runner = ModelRunner(config(attention_backend=backend),
+                             params=PARAMS)
+        assert runner.backends.index == backend
+        assert runner._get_window(4, 8)._labels["index_backend"] == backend
+        seqs = [PrefillSeq(tokens=np.asarray(prompts[s], np.int32),
+                           start_pos=0,
+                           chunk_pages=np.asarray(pages[s][:4], np.int32),
+                           hist_pages=None, sampling=(0.0, 0, 1.0))
+                for s in prompts]
+        runner.prefill_batch(seqs, slots=list(prompts))
+        toks, counts = [], []
+        for w in range(3):
+            t, _, counted = _window(
+                runner, {s: (len(prompts[s]) + 4 * w, pages[s])
+                         for s in prompts}, 4)
+            toks.append(t)
+            counts.append(np.asarray(counted["attn"]))
+        served[backend] = (np.concatenate(toks), np.stack(counts))
+    (want, gathered), (got, walked) = served["xla"], served["pallas"]
+    np.testing.assert_array_equal(got[:, [0, 2]], want[:, [0, 2]])
+    np.testing.assert_array_equal(walked[:, :2], gathered[:, :2])
+    assert (walked[:, 2] == walked[:, 1]).all()
+    assert (gathered[:, 2] == 4 * 3 * 4 * 5 * PAGE).all()
 
 
 # -- the engine ------------------------------------------------------------------

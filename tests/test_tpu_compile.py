@@ -892,9 +892,12 @@ def _sala_runner(v5e, rows=24, pages=3000):
 
 
 def test_sala_programs_compile_for_v5e_with_the_state_where_it_lies(v5e):
-    """The window program of the MiniCPM-SALA block at its widths: THREE
+    """The window program of the MiniCPM-SALA block at its widths: FOUR
     kernels (the recurrence at a group a head, three row buffers of 2 MB
-    in VMEM; the pool's reader over the chosen blocks' table, the pool
+    in VMEM; the scores of a row's stripes, a page's 8 x 128 of both heads
+    one copy out of the compressed-key array where it lies: nothing of its
+    shape but the argument and the commit's copy in place; the pool's
+    reader over the chosen blocks' table, the pool
     seen as blocks of ONE KV head; the window's commit in place), the
     float32 state (24 slots x 4 layers x 2 MB) aliased to the recurrence's
     output and nothing else of its shape (it never rides a conditional: a
@@ -905,7 +908,11 @@ def test_sala_programs_compile_for_v5e_with_the_state_where_it_lies(v5e):
     lies, the lightning recurrence goes 2,048 tokens at a time, the
     attention's scores a chunk of queries at a time)."""
     from dynamo_tpu.engine.runner import _PF_HDR, PK_PREFIX
-    rows, window, pages = 24, 8, 3000
+    # The compressed-key array at the cell's bytes (3,453 pages over 8
+    # layers are 113 MB; three layers here): under some 100 MB the compiler
+    # copies a conditional's operand whole into VMEM ahead of the branch,
+    # a ``copy-start`` of the array's shape that the cell never sees.
+    rows, window, pages = 24, 8, 9300
     runner, spec, params, s = _sala_runner(v5e, rows, pages)
     page, table = runner.config.page_size, 128
     pool = (3, 2, pages, page, 128)
@@ -915,6 +922,7 @@ def test_sala_programs_compile_for_v5e_with_the_state_where_it_lies(v5e):
     key = jax.eval_shape(lambda: jax.random.key(0))
     fn = runner._get_window(window, table)
     assert fn._labels["ssm_backend"] == "kernel"
+    assert fn._labels["index_backend"] == "pallas"
     assert fn._labels["prefix_reuse"].startswith("off")
     lowered = fn.lower(
         params, s(pool, jnp.bfloat16), s(pool, jnp.bfloat16),
@@ -922,8 +930,13 @@ def test_sala_programs_compile_for_v5e_with_the_state_where_it_lies(v5e):
         s(key.shape, key.dtype), state=state)
     assert lowered.as_text().count("func.func private @state_step") == 1
     text = lowered.compile().as_text()
-    assert text.count("tpu_custom_call") == 3
+    assert text.count("tpu_custom_call") == 4
     assert pool_sized_ops(text, pool) == []
+    # The compressed-key array: the commit's update in place (the update
+    # and the fusion it is the root of), no copy.
+    assert [kind for _, kind in pool_sized_ops(
+        text, spec.comp_key_shape(pages, page))] == [
+            "dynamic-update-slice", "fusion"]
     shape = "f32[" + ",".join(map(str, states)) + "]"
     aliased = [line for line in text.splitlines()
                if "ssm_state_step" in line and "custom-call(" in line]
