@@ -1235,3 +1235,74 @@ def commit_window_pallas(k_cache: jax.Array, v_cache: jax.Array,
     )(*prefetch, *(w.astype(jnp.float32) for _, w in caches),
       *(c for c, _ in caches))
     return (out[0], out[1]) if n == 2 else (out[0], v_cache)
+
+
+#: Rows of a window buffer one block of write_window_rows_pallas holds: the
+#: rows of one bfloat16 tile as the chip lays the buffer out, T(8,128)(2,1),
+#: the least a copy may take of it (Mosaic: "Slice shape along dimension 2
+#: must be aligned to tiling (8)").
+ROWS_TILE = 8
+#: Bytes of the buffer one grid program of that kernel brings in.
+ROWS_BLOCK_BYTES = 1 << 20
+
+
+def _rows_kernel(start_ref,  # SMEM prefetch, [1]
+                 new_ref, buf_ref, out_ref):
+    """One grid program per block of layers: the tile of rows that holds
+    rows start .. start + S - 1 of every (layer, slot) of the block is in
+    VMEM (BlockSpec's own pipeline), takes the S fresh rows and goes back
+    where it came from."""
+    rows = buf_ref.shape[2]
+    r0 = start_ref[0] % rows
+    # Through float32 (exact both ways), as _commit_kernel selects.
+    x = buf_ref[...].astype(jnp.float32)
+    new = new_ref[...].astype(jnp.float32)
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 2)
+    for j in range(new.shape[2]):
+        x = jnp.where(row == r0 + j, new[:, :, j:j + 1, :], x)
+    out_ref[...] = x.astype(out_ref.dtype)
+
+
+def write_window_rows_pallas(buf: jax.Array, new: jax.Array,
+                             start: jax.Array,
+                             interpret: bool = False) -> jax.Array:
+    """A window buffer's step write, in place: ``jax.lax.
+    dynamic_update_slice(buf, new, (0, 0, start, 0))`` for buf
+    [L, B, W, width] (a drafting window's columns of every pool layer, as
+    the chip holds them: a (layer, slot)'s W rows are whole tiles of
+    ROWS_TILE) and new [L, B, S, width], S dividing ROWS_TILE and
+    ``start`` a multiple of S, so that the S rows fall in ONE tile.
+
+    XLA's own update writes S rows into each of L x B x width / 128 tiles
+    at an offset it sees as dynamic, a sublane at a time (47 ns a tile:
+    PERF.md section 6, PR 47); a copy may not take less than a tile of the
+    buffer either. So the tile of rows the step falls in is what moves,
+    of every layer and slot: ROWS_TILE / W of the buffer through VMEM and
+    back, ROWS_BLOCK_BYTES of it a grid program, the buffer aliased to the
+    result. A buffer of fewer rows than a tile, or an S that does not
+    divide one, moves whole."""
+    L, B, W, width = buf.shape
+    S = new.shape[2]
+    rows = ROWS_TILE if W % ROWS_TILE == 0 and ROWS_TILE % S == 0 else W
+    per_layer = B * rows * width * buf.dtype.itemsize
+    # Layers a block: the most that divide L and fit the block's bytes.
+    lc = max(d for d in range(1, max(1, ROWS_BLOCK_BYTES // per_layer) + 1)
+             if L % d == 0)
+    tile = pl.BlockSpec((lc, B, rows, width),
+                        lambda i, s: (i, 0, s[0] // rows, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(L // lc,),
+        in_specs=[pl.BlockSpec((lc, B, S, width), lambda i, s: (i, 0, 0, 0)),
+                  tile],
+        out_specs=tile,
+    )
+    return pl.pallas_call(
+        _rows_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(buf.shape, buf.dtype),
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(jnp.asarray(start, jnp.int32).reshape(1), new, buf)

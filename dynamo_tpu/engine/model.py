@@ -1606,9 +1606,10 @@ def decode_verify_step(params: Params, spec: ModelSpec, k_cache: jax.Array,
     of the weights for S positions. A latent block without an indexer.
 
     tokens / positions / live [B, S] (``live``: the positions that count);
-    k_buf [W, L', B, width] the window's columns, COLUMN-major so that a
-    step's fresh columns are one contiguous block of it (the model's
-    layers first), win_keep [B, W] which of them hold a committed token;
+    k_buf [L', B, W, width] the window's columns (the model's layers
+    first), a layer's slab [B, W, width] read as it lies: the order the
+    chip keeps the buffer in whatever order the program states (PERF.md
+    section 6, PR 47), win_keep [B, W] which of them hold a committed token;
     hist_lens [B] cache-resident tokens.
     Returns (hidden [B, S, H] after the final norm, logits [B, S, V],
     entries [L, B, S, 1, width], counts: "attn" the key counts [L, 2],
@@ -1624,12 +1625,11 @@ def decode_verify_step(params: Params, spec: ModelSpec, k_cache: jax.Array,
         lp, layer = scan_in
 
         def attend(q, k, v, kind):
-            e_win = jax.lax.dynamic_index_in_dim(k_buf, layer, axis=1,
+            e_win = jax.lax.dynamic_index_in_dim(k_buf, layer, axis=0,
                                                  keepdims=False)
             return latent_block_attention(
-                q, k_cache, layer, page_table, hist_lens,
-                jnp.swapaxes(e_win, 0, 1), win_keep, k[:, :, 0], spec,
-                live[:, 0], backends)
+                q, k_cache, layer, page_table, hist_lens, e_win, win_keep,
+                k[:, :, 0], spec, live[:, 0], backends)
 
         x, k, _, counts = transformer_block(
             x, lp, spec, cos, sin, attend, live=live, backends=backends)

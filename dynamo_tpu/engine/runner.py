@@ -749,13 +749,17 @@ class ModelRunner:
         (positions_dev, draft_dev) as the advance is data-dependent.
 
         The window's columns are static (step m writes columns m * S to
-        m * S + S - 1 of the buffer of all pool layers, which is
-        column-major [W, layers, B, width] so that they are one contiguous
-        block of it) and a mask says which hold a committed token; once, after the scan, the committed
-        columns are moved to the front and go into the pool through the
-        in-place writer (attention.commit_window_pallas; a scatter where
-        that cannot be had), the module's layer one slot on: its entry of
-        position i lies at slot i + 1 (model.mtp_prefill)."""
+        m * S + S - 1 of the buffer of all pool layers, [layers, B, W,
+        width]: the order the chip holds it in whatever the program says,
+        a (layer, slot)'s W rows two tiles of 8, which a layer's reader
+        takes as they lie; beside the in-place commit the step's write is
+        attention.write_window_rows_pallas, the tile of rows it falls in
+        through VMEM, where XLA's update went a sublane at a time) and a
+        mask says which hold a committed token; once, after the scan, the
+        committed columns are moved to the front and go into the pool
+        through the in-place writer (attention.commit_window_pallas; a
+        scatter where that cannot be had), the module's layer one slot on:
+        its entry of position i lies at slot i + 1 (model.mtp_prefill)."""
         from dynamo_tpu.engine.model import (decode_verify_step,
                                              latent_block_attention,
                                              mtp_block, mtp_logits,
@@ -765,6 +769,15 @@ class ModelRunner:
         S = self.config.spec_k + 1
         W = window * S
         L, LP = spec.num_layers, spec.pool_layers
+
+        def write_rows(buf, new, start):
+            # A step's rows into a buffer [layers, B, W, width].
+            if self.backends.kv_commit == "in_place":
+                from dynamo_tpu.engine.attention import (
+                    write_window_rows_pallas)
+                return write_window_rows_pallas(
+                    buf, new, start, interpret=self.backends.interpret)
+            return jax.lax.dynamic_update_slice(buf, new, (0, 0, start, 0))
 
         def run_window(params, k_cache, v_cache, tokens_dev, positions_dev,
                        draft_dev, page_ends, packed, rng):
@@ -789,7 +802,7 @@ class ModelRunner:
             hist_lens = jnp.where(active, pos0, 0)
             mod_lens = jnp.where(active, pos0 + 1, 0)
             with perf.scope("kv.commit"):
-                kbuf0 = jnp.zeros((W, LP, B, width), k_cache.dtype)
+                kbuf0 = jnp.zeros((LP, B, W, width), k_cache.dtype)
             want_lp = jnp.any(packed[:, PK_LOGPROB] > 0)
             temp_s, top_k_s, top_p_s = (jnp.repeat(a, S)
                                         for a in (temp, top_k, top_p))
@@ -847,9 +860,8 @@ class ModelRunner:
                     def attend(q, k, v, kind):
                         return latent_block_attention(
                             q, k_cache, layer, page_table, mod_lens,
-                            jnp.swapaxes(kbuf[:, L], 0, 1), keep,
-                            k[:, :, 0], spec, live, self.backends, lo=1,
-                            scoped=False)
+                            kbuf[L], keep, k[:, :, 0], spec, live,
+                            self.backends, lo=1, scoped=False)
 
                     y, k_mod, mcounts = mtp_block(
                         params, spec, hidden, out, cos, sin, attend,
@@ -861,14 +873,11 @@ class ModelRunner:
                     draft = jnp.where(live, nxt.astype(jnp.int32), draft)
                 with perf.scope("kv.commit"):
                     fresh = jnp.concatenate([k_new, k_mod[None]], axis=0)
-                    kbuf = jax.lax.dynamic_update_slice(
-                        kbuf, fresh[:, :, :, 0].transpose(2, 0, 1, 3),
-                        (m * S, 0, 0, 0))
+                    kbuf = write_rows(kbuf, fresh[:, :, :, 0], m * S)
                     keep = jax.lax.dynamic_update_slice(
                         keep, jnp.arange(S)[None, :] < emitted[:, None],
                         (0, m * S))
-                    hbuf = jax.lax.dynamic_update_slice(hbuf, hidden,
-                                                        (0, m * S, 0))
+                    hbuf = write_rows(hbuf[None], hidden[None], m * S)[0]
                 tokens = jnp.where(live, out[b_idx, last], tokens)
                 pos = pos + emitted
                 # What the model's layers and the module's counted, by key.
@@ -894,10 +903,9 @@ class ModelRunner:
                 wlen = jnp.sum(keep, axis=-1)
                 cols = jnp.arange(W)[None, :]
                 order = jnp.argsort(jnp.where(keep, cols, W + cols), axis=-1)
-                kbuf = jnp.take_along_axis(
-                    kbuf, order.T[:, None, :, None], axis=0)
                 # As the commit takes it: [layers, 1, B, W, width].
-                kbuf = kbuf.transpose(1, 2, 0, 3)[:, None]
+                kbuf = jnp.take_along_axis(
+                    kbuf, order[None, :, :, None], axis=2)[:, None]
                 hbuf = jnp.take_along_axis(hbuf, order[:, :, None], axis=1)
                 # The model's output at each page's last position that the
                 # window committed, by page id (mtp_hidden).

@@ -336,6 +336,155 @@ def test_a_cached_prefix_leaves_the_module_as_cold():
     assert int(np.asarray(warm.draft_dev)[0]) == cold_drafts[0][1]
 
 
+# -- the step's write into the window's buffer ----------------------------------
+
+def bits(a) -> np.ndarray:
+    """A bfloat16 array's bits: equality that knows no tolerance."""
+    return np.asarray(a).view(np.uint16)
+
+
+def parent_s_write(buf, new, start, interpret=False):
+    """What a step's write was before it was a kernel's."""
+    return jax.lax.dynamic_update_slice(buf, new, (0, 0, start, 0))
+
+
+@pytest.mark.parametrize("shape, s", [
+    ((4, 3, 16, 128), 2),       # the window's columns: two tiles of 8 rows
+    ((1, 4, 16, 256), 2),       # the hidden states beside them, one "layer"
+    ((6, 2, 4, 128), 2),        # a window shorter than a tile moves whole
+    ((2, 2, 12, 128), 3),       # and so does one whose rows cross tiles
+    ((5, 2, 8, 128), 1),        # layers that no block size divides: 5 x 1
+])
+def test_the_step_s_write_is_dynamic_update_slice_at_every_step(shape, s):
+    """attention.write_window_rows_pallas, interpreted, against
+    ``dynamic_update_slice`` at every step of a window, bit for bit, the
+    buffer carried from step to step as the scan carries it."""
+    from dynamo_tpu.engine.attention import write_window_rows_pallas
+    buf = want = jax.random.normal(jax.random.key(1), shape, jnp.bfloat16)
+    for m in range(shape[2] // s):
+        new = jax.random.normal(jax.random.key(2 + m),
+                                (*shape[:2], s, shape[3]), jnp.bfloat16)
+        buf = write_window_rows_pallas(buf, new, jnp.int32(m * s),
+                                       interpret=True)
+        want = parent_s_write(want, new, m * s)
+        assert (bits(buf) == bits(want)).all(), m
+
+
+def _decided(head_of) -> dict:
+    """PARAMS with a head that decides every draft's fate: ``head_of``
+    gives the head's columns for tokens 1 and 2 from a fixed vector."""
+    v = jax.random.normal(jax.random.key(3), PARAMS["lm_head"].shape[:1],
+                          jnp.bfloat16)
+    head = jnp.zeros_like(PARAMS["lm_head"])
+    head = head.at[:, 1].set(head_of(v)).at[:, 2].set(-head_of(v))
+    return {**PARAMS, "lm_head": head}
+
+
+#: Every logit 0: the model draws token 0 and the module drafts it.
+ACCEPT = _decided(lambda v: 0 * v)
+#: The model's logits are (0, a, -a, 0, ...): it draws 1 or 2; the module's
+#: head norm is 0, so its logits are 0 and its draft is token 0.
+REJECT = _decided(lambda v: v)
+REJECT["layers"] = {**PARAMS["layers"], "mtp_head_norm": jnp.zeros_like(
+    PARAMS["layers"]["mtp_head_norm"])}
+_ROW = (prompt_of(21, 5), np.arange(1, 9, dtype=np.int32), None)
+#: name -> (params, {slot: (prompt, pages, cap)}, what the steps' emits of
+#: the three windows [12, B] must look like for the scene to be the scene).
+SCENES = {
+    "every draft rejected": (REJECT, {0: _ROW},
+                             lambda e: (e[:, 0] == 1).all()),
+    "every draft accepted": (ACCEPT, {0: _ROW},
+                             lambda e: (e[:, 0] == 2).all()),
+    # Its third step's pair lies at positions 31 and 32.
+    "a page's border inside a step of the first window": (
+        ACCEPT, {0: (prompt_of(27, 6), _ROW[1], None)},
+        lambda e: (e[:, 0] == 2).all()),
+    "a row at its cap inside the first window": (
+        PARAMS, {0: (_ROW[0], _ROW[1], 24)},
+        lambda e: e[:4, 0].sum() == 3 and e[4:].sum() == 0),
+    "a dead slot between two live ones": (
+        PARAMS, {0: _ROW, 2: (prompt_of(19, 9),
+                              np.arange(9, 17, dtype=np.int32), None)},
+        lambda e: (e[:, 1] == 0).all() and (e[:, [0, 2]] > 0).all()),
+}
+
+
+def drafted(scene: str, windows: int = 3, window: int = 4) -> list:
+    """The scene's rows prefilled into their slots, then ``windows``
+    drafting windows over them beside the Pallas reader and the in-place
+    commit, interpreted. After prefill and after each window: (tokens
+    [M, B, S], emit [M, B], the pool, mtp_hidden), the last two as bits."""
+    params, rows, _ = SCENES[scene]
+    runner = ModelRunner(drafting(attention_backend="pallas"), params=params)
+    assert runner.backends.interpret
+    # As backends.choose decides on one TPU device, before a program is built.
+    runner.backends = dataclasses.replace(runner.backends,
+                                          kv_commit="in_place")
+    pos = {}
+    for slot, (prompt, pages, _cap) in rows.items():
+        n = len(prompt)
+        runner.prefill_batch([PrefillSeq(
+            tokens=np.asarray(prompt, np.int32), start_pos=0,
+            chunk_pages=pages[:-(-n // PAGE)], hist_pages=None,
+            sampling=(0.0, 0, 1.0), next_page=int(pages[n // PAGE]))],
+            slots=[slot])
+        pos[slot] = n
+    # What prefill left, then what each window leaves.
+    seen = [(None, None, bits(runner.k_cache), bits(runner.mtp_hidden))]
+    for _ in range(windows):
+        packed = np.zeros((4, PK_PREFIX + 8), np.int32)
+        for slot, (_prompt, pages, cap) in rows.items():
+            packed[slot, PK_POS] = pos[slot]
+            packed[slot, PK_SEQLEN] = pos[slot] + 1
+            packed[slot, PK_CAP] = len(pages) * PAGE if cap is None else cap
+            packed[slot, PK_PREFIX:PK_PREFIX + len(pages)] = pages
+        out = runner.decode_window(packed, window)
+        emit = np.asarray(out[4]["emit"])
+        for slot in rows:
+            pos[slot] += int(emit[:, slot].sum())
+        seen.append((np.asarray(out[0]), emit, bits(runner.k_cache),
+                     bits(runner.mtp_hidden)))
+    return seen
+
+
+@pytest.fixture(scope="module")
+def both_writes():
+    """scene -> (the kernel's three windows, the parent's write's), each
+    served once a module."""
+    from dynamo_tpu.engine import attention
+    cache = {}
+
+    def get(scene):
+        if scene not in cache:
+            ours = drafted(scene)
+            with pytest.MonkeyPatch.context() as patch:
+                patch.setattr(attention, "write_window_rows_pallas",
+                              parent_s_write)
+                cache[scene] = (ours, drafted(scene))
+        return cache[scene]
+    return get
+
+
+@pytest.mark.parametrize("windows", [1, 3])
+@pytest.mark.parametrize("scene", list(SCENES))
+def test_a_drafting_window_leaves_what_the_parent_s_write_left(
+        both_writes, scene, windows):
+    """After one window and after three, the pool's entries of the model's
+    layers and of the module's layer, ``mtp_hidden`` and the tokens are bit
+    for bit what the same program leaves with ``dynamic_update_slice`` as
+    its step write: nothing but who writes differs between the two."""
+    ours, parents = both_writes(scene)
+    emits = np.concatenate([w[1] for w in ours[1:]])
+    assert SCENES[scene][2](emits), emits.T
+    for (toks, emit, pool, hidden), (toks_p, emit_p, pool_p, hidden_p) in zip(
+            ours[1:1 + windows], parents[1:]):
+        assert (emit == emit_p).all() and (toks == toks_p).all()
+        assert (pool == pool_p).all() and (hidden == hidden_p).all()
+    # The windows wrote: the model's layers and the module's both moved.
+    moved = ours[windows][2] != ours[0][2]
+    assert moved[:SPEC.num_layers].any() and moved[SPEC.num_layers].any()
+
+
 # -- through the engine -----------------------------------------------------------
 
 @async_test

@@ -606,23 +606,23 @@ def test_latent_window_program_commits_in_place_for_v5e(v5e):
         assert pool_sized_ops(text, pool) == []
 
 
-def _glm_runner(v5e, spec_decode, rows=32, pages=1200):
+def _glm_runner(v5e, spec_decode, rows=32, pages=1200, layers=3):
     """A ModelRunner that places nothing, at GLM-4.7-Flash's attention
     geometry (20 heads of 192 | 64, v 256, q rank 768; entries of 640 lanes
-    and NO second array) with a dense layer, two expert layers, the
-    prediction module, narrow feed-forwards and a small vocabulary."""
+    and NO second array) with a dense layer, ``layers`` - 1 expert layers,
+    the prediction module, narrow feed-forwards and a small vocabulary."""
     from dynamo_tpu.engine.config import DeepseekV32Spec, EngineConfig
     from dynamo_tpu.engine.runner import ModelRunner
     spec = DeepseekV32Spec(
         name="glm", vocab_size=1024, hidden_size=2048,
-        intermediate_size=512, num_layers=3, num_heads=20, num_kv_heads=20,
-        head_dim=256, rms_norm_eps=1e-5, rope_theta=1e6, num_experts=4,
-        num_experts_per_tok=4, moe_intermediate_size=256,
+        intermediate_size=512, num_layers=layers, num_heads=20,
+        num_kv_heads=20, head_dim=256, rms_norm_eps=1e-5, rope_theta=1e6,
+        num_experts=4, num_experts_per_tok=4, moe_intermediate_size=256,
         num_routed_experts=16, num_shared_experts=1, first_k_dense=1,
         routed_scaling_factor=1.8, kv_lora_rank=512, q_lora_rank=768,
         qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
         index_n_heads=0, index_head_dim=0, index_topk=0, mtp_layers=1)
-    assert spec.kv_entry == (1, (640, 0)) and spec.pool_layers == 4
+    assert spec.kv_entry == (1, (640, 0)) and spec.pool_layers == layers + 1
     runner = object.__new__(ModelRunner)
     runner.spec = spec
     runner.config = EngineConfig(model=spec, num_pages=pages,
@@ -640,18 +640,26 @@ def _glm_runner(v5e, spec_decode, rows=32, pages=1200):
     return runner, spec, page, pages
 
 
-@pytest.mark.parametrize("spec_decode", ["mtp", None])
-def test_glm_window_program_commits_in_place_for_v5e(v5e, spec_decode):
+@pytest.mark.parametrize("spec_decode, layers", [("mtp", 3), (None, 3),
+                                                 ("mtp", 47)])
+def test_glm_window_program_commits_in_place_for_v5e(v5e, spec_decode,
+                                                     layers):
     """The window program of a latent pool WITHOUT an indexer at
     GLM-4.7-Flash's widths, drafting with the model's own module (two query
     positions a row: 40 query rows a slot padded to 48, the reader without
     a mask operand; two commits, the module's layer one slot on) and plain:
     the pool of ONE array is donated, walked by the kernel and rewritten in
     place, and nothing in the optimised program has its shape but the
-    arguments and the commits aliased to them."""
+    arguments and the commits aliased to them. At the cell's depth (47
+    layers and the module's, 32 slots, 8 steps of 2 rows) also what the
+    step's write relies on: the window's buffer [48, 32, 16, 640] is
+    carried through the steps as the write's kernel takes it, aliased
+    through it, and no ``dynamic-update-slice`` of its shape stands alone
+    in the program (XLA's went a sublane at a time, 0.36 ms a step)."""
+    import re
     from dynamo_tpu.engine.model import param_shapes
     from dynamo_tpu.engine.runner import PK_PREFIX
-    runner, spec, page, pages = _glm_runner(v5e, spec_decode)
+    runner, spec, page, pages = _glm_runner(v5e, spec_decode, layers=layers)
     rows, window = 32, 8
     table = runner.config.max_pages_per_seq // 2
 
@@ -661,7 +669,7 @@ def test_glm_window_program_commits_in_place_for_v5e(v5e, spec_decode):
     params = jax.tree.map(lambda shape: s(shape, jnp.bfloat16),
                           param_shapes(spec),
                           is_leaf=lambda x: isinstance(x, tuple))
-    pool = (4, 1, pages, page, 640)
+    pool = (layers + 1, 1, pages, page, 640)
     key = jax.eval_shape(lambda: jax.random.key(0))
     state = [s((rows,), jnp.int32)] * (3 if spec_decode else 1)
     if spec_decode:     # mtp_hidden: a page's last position's output
@@ -678,6 +686,16 @@ def test_glm_window_program_commits_in_place_for_v5e(v5e, spec_decode):
     assert text.count("tpu_custom_call") >= (3 if spec_decode else 2)
     assert "output_to_operand_aliasing" in text
     assert pool_sized_ops(text, pool) == []
+    if layers == 47:
+        buf = r"bf16\[48,32,16,640\]\{3,2,1,0:"
+        writes = [line for line in text.splitlines() if re.search(
+            rf"= {buf}\S* custom-call\(", line)]
+        assert len(writes) == 1 and "while/body" in writes[0]
+        assert "output_to_operand_aliasing={{}: (2, {})}" in writes[0]
+        # In no order of its dimensions.
+        assert not re.search(
+            r"= bf16\[(48,32,16|16,48,32),640\]\S* dynamic-update-slice\(",
+            text)
 
 
 def _hybrid_runner(v5e, rows=32, pages=3000, experts=4):
