@@ -538,8 +538,10 @@ async def phase_kernels(args, jax, rng, keep: dict):
     CPU rehearsal) against "xla" on the same weights, at 4 KV heads, on
     the Cohere2-MoE block at 8 KV heads under 16 query rows each, on the
     DeepSeek-V3.2 block over a pool of latent entries (every key attended:
-    the reader), and on that block past 2,048 tokens of context (the
-    indexer chooses: its kernel). Same prompts at mixed lengths per round.
+    the reader), on that block past 2,048 tokens of context (the
+    indexer chooses: its kernel), on the two blocks with recurrent layers
+    and on a looped stack at one query row a KV head. Same prompts at mixed
+    lengths per round.
     Returns the served model's bf16 XLA engine (the disagg phase's
     aggregated reference)."""
     import jax.numpy as jnp
@@ -627,8 +629,20 @@ async def phase_kernels(args, jax, rng, keep: dict):
         logit_divisor=2.0, sparse_kernel=32, sparse_stride=16,
         sparse_block=64, sparse_topk=4, sparse_init_blocks=1,
         sparse_window=128)
+    # A looped stack at toy depth and Ouro-2.6B's attention geometry: 16
+    # query heads over 16 KV heads of 128 (ONE query row a KV head; a page
+    # of 16 by the same rule: one copy across the heads is 64 KB), 3 layers
+    # run 3 times, so the pool has 9 layers and a pass reads its own; the
+    # sandwich norms and the norm between passes. The window counts the
+    # passes its live rows took.
+    from dynamo_tpu.engine.config import OuroSpec
+    looped = OuroSpec(
+        name="smoke-looped", vocab_size=2048, hidden_size=512,
+        intermediate_size=1024, num_layers=3, num_heads=16, num_kv_heads=16,
+        head_dim=128, rope_theta=1e6, rms_norm_eps=1e-6, loop_passes=3)
     pages = {wide.name: 64, share.name: 32, latent.name: 64,
-             indexed.name: 64, hybrid.name: 128, sala.name: 128}  # derived
+             indexed.name: 64, hybrid.name: 128, sala.name: 128,
+             looped.name: 16}  # derived
     short = (20, 70, 150) if args.rehearse_cpu else (24, 200, 700)
     past_topk = (20, 2100) if args.rehearse_cpu else (24, 2200, 2600)
     assert max(short) + 64 < indexed.index_topk < min(past_topk[1:])
@@ -645,7 +659,8 @@ async def phase_kernels(args, jax, rng, keep: dict):
             (latent, None, None, ("xla", kernels), short, 1024),
             (indexed, None, None, ("xla", kernels), past_topk, 4096),
             (hybrid, None, None, ("xla", "auto"), short, 1024),
-            (sala, None, None, ("xla", "auto"), short, 1024)):
+            (sala, None, None, ("xla", "auto"), short, 1024),
+            (looped, None, None, ("xla", "auto"), short, 1024)):
         prompts = [rng.integers(2, spec_r.vocab_size, size=n).tolist()
                    for n in lengths]
         runs = {}
@@ -769,12 +784,21 @@ async def phase_kernels(args, jax, rng, keep: dict):
                 check(ssm == ("kernel" if on_tpu and resolved == "pallas"
                               else "xla") == record.ssm,
                       f"{resolved} reader of {spec_r.name}: state by {ssm}")
+            passes = None
+            if spec_r.loop_passes > 1:
+                # Counted in the window program, where the passes run.
+                passes = eng.perf_status()["loop"]["passes_per_token"]
+                check(passes == spec_r.loop_passes
+                      and eng.runner.k_cache.shape[0] == spec_r.pool_layers
+                      == spec_r.loop_passes * spec_r.num_layers,
+                      f"{spec_r.name}: {passes} passes a token, a pool of "
+                      f"{eng.runner.k_cache.shape[0]} layers")
             emit("kernels.run", model=spec_r.name,
                  quant_kv=quant_kv or "bf16", attention_backend=backend,
                  resolved=resolved, kv_commit_backend=commit,
                  index_backend=index, attn_selected_pct=selected,
                  expert_product=products, ssm_state_on=state_on,
-                 ssm_backend=ssm,
+                 ssm_backend=ssm, loop_passes_per_token=passes,
                  page_size=eng.runner.page_size,
                  prompt_lengths=lengths, chunk_tokens=chunks,
                  seconds=round(seconds, 2), tpu_custom_call=custom_call)

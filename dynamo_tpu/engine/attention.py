@@ -127,6 +127,11 @@ SLOTS = 3
 #: Token rows one entry of the window's commit moves: a bfloat16 tile's
 #: sublanes, and the page the commit was measured at (PERF.md, PR 29).
 COMMIT_TILE = 16
+#: What one call of the window's commit may hold in VMEM (its tiles of both
+#: pools and its blocks of both windows, every layer it moves): half of a
+#: v5e's 16 MiB scoped default. Over it commit_window_pallas commits by
+#: layer ranges.
+COMMIT_VMEM_BYTES = 8 << 20
 
 
 def pages_per_chunk(page_size: int, nkv: int, d: int, itemsize: int) -> int:
@@ -1190,13 +1195,33 @@ def commit_window_pallas(k_cache: jax.Array, v_cache: jax.Array,
     moves whole). A pool array of no width (a latent block without an
     indexer) is handed back as it came. ``layers`` (first, count): the
     windows hold those layers of the pools alone (a prediction module's
-    layer, whose tokens land one slot on)."""
+    layer, whose tokens land one slot on). A commit whose tiles and window
+    blocks of all its layers would pass COMMIT_VMEM_BYTES runs as several
+    calls, each a range of layers, over the same aliased pools."""
     L, nkv, _, page_size, _ = k_cache.shape
     if layers is not None:
         L = layers[1]
     b, window = k_win.shape[2], k_win.shape[3]
     tile = COMMIT_TILE if page_size % COMMIT_TILE == 0 else page_size
     tiled = tile < page_size
+    # What a program holds in VMEM a layer: a tile's rows of each pool and
+    # its block of each window in float32, twice (the pipeline's buffers).
+    per_layer = sum(nkv * c.shape[4] * (tile * c.dtype.itemsize
+                                        + 2 * window * 4)
+                    for c in (k_cache, v_cache))
+    span = next(n for n in range(L, 0, -1) if L % n == 0
+                and n * per_layer <= max(per_layer, COMMIT_VMEM_BYTES))
+    if span < L:
+        # Layer ranges that fit, one call each over the same aliased pools
+        # (a looped stack's 192 (pass, layer) pairs of 16 heads are 50 MB
+        # of tiles and windows at once).
+        first = layers[0] if layers is not None else 0
+        for lo in range(0, L, span):
+            k_cache, v_cache = commit_window_pallas(
+                k_cache, v_cache, k_win[lo:lo + span], v_win[lo:lo + span],
+                positions0, cap, seq_lens0, page_table, interpret=interpret,
+                layers=(first + lo, span))
+        return k_cache, v_cache
     prefetch = window_pages(positions0, cap, seq_lens0, page_table, window,
                             page_size, tile)
     J = prefetch[0].shape[0] // b

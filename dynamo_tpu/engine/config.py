@@ -160,6 +160,13 @@ class ModelSpec:
     sparse_topk = 0
     sparse_init_blocks = 0
     sparse_window = 0
+    # What a looped stack states (OuroSpec): how many times a token passes
+    # the SAME layers, each pass leaving its own K and V; whether a
+    # sublayer's OUTPUT is normed ahead of the residual sum; the cumulative
+    # exit probability at which a token would leave the loop.
+    loop_passes = 1
+    sandwich_norm = False
+    early_exit_threshold = 1.0
     # Weight-only quantization: None (bf16) or "int8" (engine/quant.py —
     # int8 storage, bf16 MXU compute; halves the weight-read roofline and
     # fits full llama-3-8b on one 16 GB v5e).
@@ -285,14 +292,22 @@ class ModelSpec:
 
     @property
     def pool_layers(self) -> int:
-        """Layers of the two pool arrays: the model's, then one a prediction
-        module (``mtp_layers``), whose block leaves entries of the same
-        width under the same page table; under a ``layer_pattern`` the
+        """Layers of the two pool arrays: the model's, once a pass of a looped
+        stack (``layer_visits``), then one a prediction module
+        (``mtp_layers``), whose block leaves entries of the same width
+        under the same page table; under a ``layer_pattern`` the
         attention layers alone (the others leave nothing a token)."""
         if self.layer_pattern:
             return (self.layer_pattern.count("*")
                     + self.layer_pattern.count("S"))
-        return self.num_layers + self.mtp_layers
+        return self.layer_visits + self.mtp_layers
+
+    @property
+    def layer_visits(self) -> int:
+        """(pass, layer) pairs a token goes through, each leaving K and V
+        of its own: pool layer ``t * num_layers + l`` is pass t of layer l
+        (``loop_passes`` 1: the layers)."""
+        return self.loop_passes * self.num_layers
 
     @property
     def kv_entry(self) -> tuple[int, tuple[int, int]]:
@@ -310,8 +325,8 @@ class ModelSpec:
         return 1, (-(-used // 128) * 128, self.index_head_dim)
 
     def num_params(self) -> int:
-        """Parameters resident here: the sum of model.param_shapes (the
-        experts HELD, shared experts, QKV biases, one norm a layer in a
+        """Parameters resident here, a looped stack's layers ONCE: the sum
+        of model.param_shapes (the experts HELD, shared experts, QKV biases, one norm a layer in a
         parallel block; the latent projections, the indexer, the selection
         bias and the leading dense layers of the DeepSeek-V3.2 block)."""
         if self.layer_pattern:
@@ -344,6 +359,8 @@ class ModelSpec:
         else:
             mlp = 3 * h * i
         norms = (1 if self.parallel_block else 2) * h
+        if self.sandwich_norm:      # a norm of each sublayer's output too
+            norms += 2 * h
         embed = v * h * (1 if self.tie_word_embeddings else 2)
         dense = self.first_k_dense
         # A prediction module: a whole expert layer of the block's kind,
@@ -362,14 +379,34 @@ class ModelSpec:
         return (self.pool_layers * heads * sum(widths) * dtype_bytes
                 + self.comp_key_bytes_per_token)
 
+    def step_read_params(self) -> int:
+        """Parameters a decode step reads: the resident ones, and the
+        layers once more for every further PASS of a looped stack (the
+        passes are sequential: pass t + 1 of the first layer needs pass t
+        of the last, and no layer stays on the chip between them). The ONE
+        place a block that re-reads or skips weights states it:
+        ``weight_read_step_ms`` and through it the "auto" window, the
+        prefill chunk and the perf plane's roofline fraction. (The
+        embedding table counts whole, as it always has, though a step
+        gathers a row a sequence: an estimate's term, under a tenth of any
+        preset.)"""
+        resident = self.num_params()
+        if self.loop_passes == 1:
+            return resident
+        tables = (self.vocab_size * self.hidden_size
+                  * (1 if self.tie_word_embeddings else 2))
+        layers = resident - tables - self.hidden_size   # less the final norm
+        return resident + (self.loop_passes - 1) * layers
+
     def weight_read_step_ms(self, hbm_gbps: float, tp: int = 1,
                             pp: int = 1) -> float:
-        """Lower bound on a decode step for this spec's shard: one full
-        read of the shard's weights from HBM at ``hbm_gbps`` — the
+        """Lower bound on a decode step for this spec's shard: the bytes a
+        step reads of the shard's weights (``step_read_params``, as
+        stored) from HBM at ``hbm_gbps`` — the
         serving device's DevicePeaks.hbm_gbps (bench roofline, auto window
         sizing, profiling all pass the same table row)."""
         per_weight = 1.0 if self.quant == "int8" else 2.0
-        shard_bytes = self.num_params() * per_weight / max(1, tp * pp)
+        shard_bytes = self.step_read_params() * per_weight / max(1, tp * pp)
         return shard_bytes / (hbm_gbps * 1e9) * 1e3
 
     @classmethod
@@ -392,6 +429,8 @@ class ModelSpec:
             return cls._from_nemotron_h(cfg, path)
         if cfg.get("model_type") == "minicpm_sala":
             return cls._from_minicpm_sala(cfg, path)
+        if cfg.get("model_type") == "ouro":
+            return cls._from_ouro(cfg, path)
         return cls(
             name=cfg.get("_name_or_path", os.path.basename(os.path.dirname(path))),
             vocab_size=cfg["vocab_size"],
@@ -868,6 +907,49 @@ class ModelSpec:
             sparse_window=sparse.get("window_size", 2048),
         )
 
+    @classmethod
+    def _from_ouro(cls, cfg: dict, path: str) -> "ModelSpec":
+        """Ouro's keys (ByteDance/Ouro-2.6B ``config.json``, ``ouro``): the
+        dense block's, ``total_ut_steps`` passes over the same layers and
+        ``early_exit_threshold`` (kept as stated: config.block_refusals
+        refuses one under 1). The sandwich norms are the model type's, no
+        key's."""
+        reader = "the config reader"
+        for key, want, why in (
+                ("hidden_act", "silu", "the feed-forward is SwiGLU"),
+                ("attention_bias", False, "no projection has a bias leaf"),
+                ("rope_scaling", None, "plain frequencies theta ** (-2i / "
+                 "d) are what is written down"),
+                ("use_sliding_window", False, "every layer of every pass "
+                 "attends every earlier key")):
+            got = cfg.get(key, want)
+            if got != want:
+                raise UnsupportedBlockError(
+                    reader, f"ouro with {key} {got!r}: {why}")
+        kinds = set(cfg.get("layer_types") or ["full_attention"])
+        if kinds != {"full_attention"}:
+            raise UnsupportedBlockError(
+                reader, f"ouro whose layer_types names {sorted(kinds)}: "
+                "every layer of every pass attends every earlier key")
+        return OuroSpec(
+            name=cfg.get("_name_or_path")
+            or os.path.basename(os.path.dirname(path)),
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=cfg["num_hidden_layers"],
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg.get("num_key_value_heads",
+                                 cfg["num_attention_heads"]),
+            head_dim=cfg.get("head_dim"),
+            rope_theta=cfg.get("rope_theta", 10000.0),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-6),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            max_position_embeddings=cfg.get("max_position_embeddings", 8192),
+            loop_passes=cfg["total_ut_steps"],
+            early_exit_threshold=float(cfg.get("early_exit_threshold", 1.0)),
+        )
+
 
 @dataclasses.dataclass
 class SmallThinkerSpec(ModelSpec):
@@ -1154,6 +1236,33 @@ class MiniCPMSALASpec(ModelSpec):
                 "by the window alone)")
 
 
+@dataclasses.dataclass
+class OuroSpec(ModelSpec):
+    """A looped stack (ByteDance/Ouro-2.6B, ``ouro``; arXiv:2510.25741):
+    the dense block's layers run ``loop_passes`` times a token, the final
+    norm after every pass, its output the next pass's input and the last
+    one's the head's. A pass attends the K and V of its OWN earlier visits,
+    so a token leaves K and V ``layer_visits`` times (ModelSpec.pool_layers:
+    pool layer ``t * num_layers + l``). What it states beyond ModelSpec's
+    fields; the programs are engine/model.py's (``scan_passes`` around the
+    one ``transformer_block``)."""
+    loop_passes: int = 4
+    # x + RMS(Sublayer(RMS(x))): a norm of each sublayer's OUTPUT, with a
+    # gain of its own (``attn_out_gain``, ``mlp_out_gain``), beside the
+    # one of its input.
+    sandwich_norm: bool = True
+    # The cumulative exit probability at which a token would leave the
+    # loop. At 1 only the last pass reaches it: every token takes every
+    # pass and the gate is not evaluated (it has no leaf here).
+    early_exit_threshold: float = 1.0
+
+    def __post_init__(self):
+        super().__post_init__()
+        if self.loop_passes < 1:
+            raise ValueError(f"loop_passes must be >= 1, got "
+                             f"{self.loop_passes}")
+
+
 class UnsupportedBlockError(NotImplementedError):
     """A path that lacks a mechanism a model's block needs refuses the
     model at start-up and names what it lacks; it never runs the block
@@ -1233,8 +1342,36 @@ def block_refusals(spec: ModelSpec, config: "EngineConfig | None" = None,
             "encoder embeddings in a prompt (mm_embeds)", "the programs of "
             "a block with recurrent layers (engine/hybrid.py) take token "
             "rows alone"))
+    looped = spec.loop_passes > 1
+    if spec.early_exit_threshold < 1.0:
+        out.append(UnsupportedBlockError(
+            "every engine path", f"an exit threshold of "
+            f"{spec.early_exit_threshold} lets rows of one batch leave the "
+            "loop at different passes: the scheduler and the window program "
+            "run every row of a step through every pass, the exit gate has "
+            "no leaf and a row that left would have no K and V in the "
+            "passes it skipped"))
+    if checkpoint and (looped or spec.sandwich_norm):
+        out.append(UnsupportedBlockError(
+            "the safetensors loader", "it has no tensor-name map for the "
+            "norms of a sublayer's output nor for a looped stack's "
+            "checkpoint (random weights only)"))
+    if embeddings and looped:
+        out.append(UnsupportedBlockError(
+            "encoder embeddings in a prompt (mm_embeds)", "another "
+            "encoder's rows entering the first of several passes were "
+            "never compared with a reference"))
+    if kv_transfer and looped:
+        out.append(UnsupportedBlockError(
+            "a KV parcel (KV-plane tickets, disaggregated insert, host and "
+            "disk tiers)", f"a page holds K and V of {spec.layer_visits} "
+            "(pass, layer) pairs, and a parcel of such pages was never "
+            "moved nor compared with its source (its holders size their "
+            "buffers by the model's layers)"))
     if config is None:
         return out
+    if looped:
+        out += _looped_refusals(spec, config)
     if latent:
         out += _latent_refusals(spec, config)
     if recurrent:
@@ -1297,6 +1434,48 @@ def block_refusals(spec: ModelSpec, config: "EngineConfig | None" = None,
             "a tp/pp/dp/sp mesh", f"{unlike} was never compared with its "
             "reference on more than one device (its grouped expert product "
             "has no partitioning rule)"))
+    return out
+
+
+def _looped_refusals(spec: ModelSpec, config: "EngineConfig"
+                     ) -> list[UnsupportedBlockError]:
+    """block_refusals' part for a looped stack (``spec.loop_passes`` > 1):
+    every engine path whose layer axis is the model's layers and not the
+    (pass, layer) pairs the pool holds, by what it lacks."""
+    out = []
+    if config.spec_decode:
+        out.append(UnsupportedBlockError(
+            f"speculative decoding (spec_decode {config.spec_decode})",
+            "the verify step (model.decode_window_multi_step) scans the "
+            "layers once over a window buffer of one entry a layer: it has "
+            "no loop over passes and no buffer a (pass, layer) pair"))
+    if config.max_adapters > 0:
+        out.append(UnsupportedBlockError(
+            "LoRA adapters (max_adapters)", "the adapter stacks ride the "
+            "layer scan as one entry a layer, and nothing says whether an "
+            "adapter is the same in every pass"))
+    if config.pp_microbatch or config.ring_attention:
+        out.append(UnsupportedBlockError(
+            "the pipelined and the ring prefill (pp_microbatch, "
+            "ring_attention)", "a stage's scan and the ring's blocks run "
+            "the layers once and have no loop over passes"))
+    if config.resolve_quant_kv() is not None:
+        out.append(UnsupportedBlockError(
+            "int8 KV pages (quant_kv)", "the programs of a looped stack "
+            "were compared with their reference over a bfloat16 pool alone "
+            f"(the rounding of {spec.layer_visits} quantised layer visits "
+            "was never measured)"))
+    if config.host_cache_pages > 0 or config.kv_disk_cache_dir:
+        out.append(UnsupportedBlockError(
+            "the host and disk KV tiers (kvbm)", "they move parcels of "
+            f"pages, and a page of {spec.layer_visits} (pass, layer) pairs "
+            "was never moved nor compared with its source"))
+    if config.tp * config.pp * config.dp * config.sp > 1:
+        out.append(UnsupportedBlockError(
+            "a tp/pp/dp/sp mesh", "the pool's layer axis is (pass, layer) "
+            "pairs and the parameters' is layers: a pp stage would hold "
+            "other pool layers than its own, and no mesh was compared with "
+            "the reference"))
     return out
 
 
@@ -1882,7 +2061,8 @@ class EngineConfig:
         return self.prefill_buckets[-1]
 
     def weight_read_ms(self, peaks: DevicePeaks | None) -> float:
-        """The shard's weight-read step estimate on the serving device;
+        """The time to read the bytes a step reads of the shard's weights
+        (ModelSpec.weight_read_step_ms) on the serving device;
         0 where the device has no published peak (the CPU backend), so the
         "auto" sizings below fall back to their host-overhead terms."""
         if peaks is None:
@@ -1894,8 +2074,9 @@ class EngineConfig:
         """Resolve ``decode_window="auto"`` to a concrete M for the device
         whose ``peaks`` (device_peaks(); None on the CPU backend) are given.
 
-        TPU-first sizing: a decode step is bounded below by reading this
-        shard's weights once from HBM; the per-dispatch host overhead is
+        TPU-first sizing: a decode step is bounded below by reading from
+        HBM the bytes a step reads of this shard's weights
+        (ModelSpec.step_read_params); the per-dispatch host overhead is
         ~constant. Pick M so the window period M x (step estimate) hits
         DTPU_WINDOW_TARGET_MS — long enough to amortize dispatch, short
         enough that prefill admission between windows keeps p99 TTFT

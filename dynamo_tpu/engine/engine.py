@@ -1185,6 +1185,20 @@ class TPUEngine(AsyncEngine):
                 # Static: a page's border has no state to continue from.
                 "prefix_reuse": window["prefix_reuse"],
             }
+        if self.runner.spec.loop_passes > 1:
+            spec = self.runner.spec
+            steps = total["loop_row_steps"]
+            status["loop"] = {
+                # Passes a token takes over the same layers, the (pass,
+                # layer) pairs it leaves K and V in, and their bytes.
+                "passes": spec.loop_passes,
+                "pool_layers": spec.pool_layers,
+                "kv_token_bytes": self.config.kv_token_bytes(),
+                # Counted in the window program: passes the live rows
+                # took over their decode steps.
+                "passes_per_token": round(total["loop_passes"] / steps, 4)
+                if steps else None,
+            }
         if self.config.spec_decode:
             # Verify-of-k bandwidth: the spec program runs m_outer verify
             # steps of S = spec_k + 1 positions each, so cost-registry

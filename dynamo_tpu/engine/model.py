@@ -258,6 +258,18 @@ def param_shapes(spec: ModelSpec) -> dict:
     layers = _attention_shapes(spec, L)
     if spec.parallel_block:             # one norm feeds both branches
         del layers["post_attn_norm"]
+    if spec.sandwich_norm:
+        # The gain of the norm of each sublayer's OUTPUT, a column: a
+        # generator drawing normal / sqrt(shape[-2]) draws it small (a
+        # 45th at 2,048 wide). Drawn as ones, as an INPUT norm's weight
+        # is, every sublayer adds a vector of unit rms whatever it
+        # computed, a stream of rms sqrt(k) after k of them grows by k to
+        # the power of a half of the sublayer's gain squared in every pass
+        # alike, and over 384 normed sublayers a rounding of 2 ** -9
+        # parts the bfloat16 program from the float32 one by whole nats
+        # (one seed in six on one v5e: PERF.md section 6, PR 48).
+        layers["attn_out_gain"] = (L, h, 1)
+        layers["mlp_out_gain"] = (L, h, 1)
     if spec.layer_pattern:
         layers = _pattern_shapes(spec)
     elif spec.num_experts:
@@ -316,6 +328,9 @@ def param_specs(spec: ModelSpec) -> dict:
                   for k, v in _attention_shapes(spec, 1).items()}
     if spec.parallel_block:
         del layers["post_attn_norm"]
+    if spec.sandwich_norm:
+        layers["attn_out_gain"] = P("pp", None, None)
+        layers["mlp_out_gain"] = P("pp", None, None)
     if spec.num_experts:
         layers["moe_gate"] = P("pp", None, None)
         layers["moe_w_gate"] = P("pp", "tp", None, None)
@@ -700,7 +715,8 @@ def init_params(spec: ModelSpec, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     for name, shape in shapes["layers"].items():
         if name.endswith("_norm"):
             params["layers"][name] = jnp.ones(shape, dtype)
-        elif name.endswith("_bias"):    # [..., n, 1]: small beside a score
+        elif name.endswith(("_bias", "_gain")):
+            # [..., n, 1]: small beside a score, or beside the stream
             params["layers"][name] = (
                 jax.random.normal(jax.random.fold_in(key, len(name)), shape,
                                   dtype) * (shape[-2] ** -0.5))
@@ -1703,6 +1719,8 @@ def transformer_block(x: jax.Array, lp: dict, spec: ModelSpec,
     d = spec.head_dim
     with sc("attn.qkv"):
         h = norm(x, lp["input_norm"], spec)
+        if x.dtype != jnp.bfloat16:     # a float32 stream (scan_passes)
+            h = h.astype(jnp.bfloat16)
         if spec.latent:
             cq, nope, rope, k = latent_qkv(h, lp, spec, cos, sin)
         else:
@@ -1742,17 +1760,23 @@ def transformer_block(x: jax.Array, lp: dict, spec: ModelSpec,
         proj = mm(attn, lp["wo"], "...d,dh->...h")
         if ll is not None:
             proj = proj + lora_delta(attn, ll["wo"], ids)
+        if spec.sandwich_norm:
+            proj = norm(proj, lp["attn_out_gain"][..., 0], spec)
         x_in, x = x, x + proj
     with sc("mlp"):
         # A parallel block's feed-forward reads the norm attention read,
         # and its output joins the same residual sum.
         h2 = h if spec.parallel_block else norm(x, lp["post_attn_norm"], spec)
+        if h2.dtype != jnp.bfloat16:
+            h2 = h2.astype(jnp.bfloat16)
         router_in = x_in if spec.moe_router_input == "layer_input" else None
         out = ffn_block(h2, lp, spec, ll, ids, router_in=router_in,
                         live=live if spec.num_experts else None,
                         backends=backends)
         if isinstance(out, tuple):
             out, counts["moe"] = out
+        if spec.sandwich_norm:
+            out = norm(out, lp["mlp_out_gain"][..., 0], spec)
         x = x + out
     return x, k, v, counts
 
@@ -1818,6 +1842,61 @@ def scan_layers(layer_fn, x: jax.Array, xs, spec: ModelSpec,
         return jnp.concatenate([a, b])
 
     return x, tuple(join(a, b) for a, b in zip(ys_first, ys_rest))
+
+
+def scan_passes(layer_fn, x: jax.Array, xs, spec: ModelSpec,
+                final_norm: jax.Array, live: jax.Array | None = None,
+                whole_experts: bool = False):
+    """``scan_layers`` once a PASS of a looped stack (``spec.loop_passes``;
+    with one pass exactly ``scan_layers``: such a model's programs carry no
+    trace of the loop). Every pass scans the SAME ``params["layers"]``; the
+    arrays that ``xs`` stacks behind them (the layer index, a window's
+    buffers) are stacked over the (pass, layer) pairs, pool layer ``t *
+    num_layers + l``, and a pass takes its own rows of them, so a pass
+    reads the K and V of its own earlier visits alone. Between two passes
+    the model's final norm (scope ``loop.norm``): its output enters the
+    next pass, and the last pass's goes to the caller, whose own final norm
+    ahead of the head is the one after the last pass. What the layers give
+    (k, v and their counts) comes back stacked over the pairs, in the
+    pool's order. ``live`` [B] (a window's step): the counts gain "loop",
+    a pass a row: (passes the live rows took, the live rows themselves:
+    runtime/flight.py COUNTS), counted where the passes run."""
+    T = spec.loop_passes
+    if T == 1:
+        return scan_layers(layer_fn, x, xs, spec,
+                           whole_experts=whole_experts)
+    L = spec.num_layers
+    # The residual stream rides the passes in float32: under sandwich norms
+    # every sublayer adds a vector of unit rms to a stream whose rms grows
+    # to ten within a pass, and a bfloat16 stream drops three bits of each
+    # of 2 x 192 additions (what the layers read of it is bfloat16).
+    stream, x = x.dtype, x.astype(jnp.float32)
+    layers, others = (xs, None) if isinstance(xs, dict) else (xs[0], xs[1:])
+
+    def one_pass(x, scan_in):
+        t, rows = scan_in
+        with scope("loop.norm"):
+            # (The norm ahead of the first pass is computed and dropped:
+            # the embedding enters it as it is.)
+            x = jnp.where(t > 0, norm(x, final_norm, spec).astype(x.dtype),
+                          x)
+        x, ys = scan_layers(layer_fn, x,
+                            layers if others is None else (layers, *rows),
+                            spec, whole_experts=whole_experts)
+        if live is not None:
+            n = jnp.sum(live, dtype=jnp.float32)
+            ys = (*ys[:-1], {**ys[-1], "loop": jnp.stack(
+                [n, jnp.where(t == 0, n, 0.0)])})
+        return x, ys
+
+    rows = () if others is None else tuple(
+        a.reshape(T, L, *a.shape[1:]) for a in others)
+    x, ys = jax.lax.scan(one_pass, x, (jnp.arange(T), rows))
+    # [T, L, ...] -> [T * L, ...]; a pass's own count stays a row a pass.
+    flat = lambda a: a.reshape(T * L, *a.shape[2:])  # noqa: E731
+    return x.astype(stream), tuple(
+        {k: v if k == "loop" else flat(v) for k, v in y.items()}
+        if isinstance(y, dict) else flat(y) for y in ys)
 
 
 # ---------------------------------------------------------------------------
@@ -1899,13 +1978,13 @@ def prefill_forward(params: Params, spec: ModelSpec,
     xs = (params["layers"], lora) if lora is not None else params["layers"]
     if patterned:
         xs = (xs, jnp.arange(spec.num_layers))
-    x, (k_new, v_new) = scan_layers(
-        layer_fn, x, xs, spec,
+    x, (k_new, v_new) = scan_passes(
+        layer_fn, x, xs, spec, params["final_norm"],
         whole_experts=expert_product(b * s, backends) == "grouped")
     # k_new [L,B,S,Nkv,D] -> page blocks [L,Nkv,B*S/page,page,D]; one
-    # in-place scatter per cache covers every layer.
+    # in-place scatter per cache covers every layer (of every pass).
     with scope("kv.commit"):
-        L = spec.num_layers
+        L = spec.layer_visits
         nkv, (dk, dv) = spec.kv_entry
         k_blocks = (k_new.reshape(L, b * (s // page), page, nkv, dk)
                     .transpose(0, 3, 1, 2, 4))
@@ -2109,7 +2188,7 @@ def decode_forward(params: Params, spec: ModelSpec,
     # freshly allocated each call, which silently rewrote the ENTIRE pool
     # per decode step (50 ms/step at a 3 GB pool vs ~1.5 ms now).
     hist_lens = jnp.maximum(seq_lens - 1, 0)
-    L = spec.num_layers
+    L = spec.layer_visits
 
     def layer_fn(x, scan_in):
         if lora is not None:
@@ -2135,7 +2214,8 @@ def decode_forward(params: Params, spec: ModelSpec,
 
     xs = ((params["layers"], jnp.arange(L), lora) if lora is not None
           else (params["layers"], jnp.arange(L)))
-    x, (k_new, v_new) = scan_layers(layer_fn, x, xs, spec)
+    x, (k_new, v_new) = scan_passes(layer_fn, x, xs, spec,
+                                    params["final_norm"])
     # One in-place scatter: [L,Nkv,B,D] at (dest_page[b], page_off[b]).
     k_cache = scatter_tokens(k_cache, k_new.transpose(0, 2, 1, 3),
                              dest_page, page_off)
@@ -2308,7 +2388,8 @@ def decode_window_step(params: Params, spec: ModelSpec,
     layers counted, a layer a row, under transformer_block's keys: with
     ``live`` [B] (bool, a routed block's rows that count) "moe", the
     expert layers' ``moe_load_stats`` [L, 3]; a latent block's "attn", its
-    key counts [L, 2]; {} for a block that counts nothing.
+    key counts [L, 2]; a looped stack's "loop", a pass a row
+    (``scan_passes``: [passes, 2]); {} for a block that counts nothing.
     """
     b = tokens.shape[0]
     d = spec.head_dim
@@ -2317,7 +2398,7 @@ def decode_window_step(params: Params, spec: ModelSpec,
     with scope("attn.qkv"):
         cos, sin = spec_rope_tables(spec, positions)
     attn_fn = kv_attention(backends, window=True)
-    L = spec.num_layers
+    L = spec.layer_visits
 
     def layer_fn(x, scan_in):
         if lora is not None:
@@ -2344,7 +2425,8 @@ def decode_window_step(params: Params, spec: ModelSpec,
     xs = ((params["layers"], jnp.arange(L), k_buf, v_buf, lora)
           if lora is not None
           else (params["layers"], jnp.arange(L), k_buf, v_buf))
-    x, ys = scan_layers(layer_fn, x, xs, spec)
+    x, ys = scan_passes(layer_fn, x, xs, spec, params["final_norm"],
+                        live=live if spec.loop_passes > 1 else None)
     with scope("lm_head"):
         x = norm(x, params["final_norm"], spec)
         logits = lm_logits(x, params, spec)

@@ -100,9 +100,10 @@ SCOPES = ("embed", "attn.qkv", "attn.kv_gather", "attn.core", "attn.out",
 #: output norm, gate, out-projection); the writes of a compressed-key array
 #: (``attn.compress``: a prefill chunk's stripes into their pages, a
 #: window's completed stripes at its commit; the choice of blocks over them
-#: is ``attn.index``). The other blocks' programs
+#: is ``attn.index``); the norm between two passes of a looped stack
+#: (``loop.norm``, model.scan_passes). The other blocks' programs
 #: have none, so their names, and SCOPES_VERSION, stand.
-BLOCK_SCOPES = ("attn.index", "mtp", "ssm", "attn.compress")
+BLOCK_SCOPES = ("attn.index", "mtp", "ssm", "attn.compress", "loop.norm")
 #: Regions INSIDE a scope, drawn only in programs of a routed block (the
 #: expert layer's router and experts and, where the block has them, its
 #: shared experts, inside ``mlp``). An instruction in one
@@ -546,8 +547,9 @@ class CompileRegistry:
         complete minus the previous window's) when it was queued behind
         that window, else dispatch -> readback complete; ``tokens`` the
         tokens it emitted, ``active`` the dispatched slot rows,
-        ``step_floor_ms`` the shard's weight-read step floor
-        (ModelSpec.weight_read_step_ms). ``window_seconds_total`` keeps
+        ``step_floor_ms`` the time to read the bytes a step reads of the
+        shard's weights (ModelSpec.weight_read_step_ms: a looped stack's
+        layers once a pass). ``window_seconds_total`` keeps
         summing ``latency_s`` (dispatch -> readback complete)."""
         if window_s <= 0 or steps <= 0:
             return
@@ -844,6 +846,20 @@ class PerfMetricsUpdater:
             "step, live row) pairs, summed on the device: the rows whose "
             "recurrent state a step had to read and write (over steps x "
             "max_num_seqs: the share of the state arrays in use)")
+        self.c_counts["loop_passes"] = registry.counter(
+            "loop_passes_total", "Looped stack: passes over the layers "
+            "that live rows took in decode steps, counted in the window "
+            "program where the passes run (over loop_row_steps_total: the "
+            "passes a token took)")
+        self.c_counts["loop_row_steps"] = registry.counter(
+            "loop_row_steps_total", "Looped stack: (decode step, live "
+            "row) pairs, summed on the device")
+        self.g_loop = registry.gauge(
+            "perf_loop_info", "1 under the labels of a looped stack: "
+            "passes a token takes over the layers, pool_layers (the (pass, "
+            "layer) pairs a token leaves K and V in) and kv_token_bytes; "
+            "no sample for a block whose layers run once",
+            ["passes", "pool_layers", "kv_token_bytes"])
         self.g_ssm_state = registry.gauge(
             "perf_ssm_state_info", "1 under the labels of what a row (a "
             "slot) of this worker keeps beside its pages over all recurrent "
@@ -920,6 +936,11 @@ class PerfMetricsUpdater:
             self.g_ssm_state.set(
                 1, bytes_per_row=str(runner.spec.ssm_state_bytes_per_row),
                 dtype=str(runner.ssm_state.dtype))
+        spec = getattr(runner, "spec", None)
+        if getattr(spec, "loop_passes", 1) > 1 and config is not None:
+            self.g_loop.set(1, passes=str(spec.loop_passes),
+                            pool_layers=str(spec.pool_layers),
+                            kv_token_bytes=str(config.kv_token_bytes()))
         totals = getattr(engine, "counts_total", None) or {}
         for columns in flight.COUNTS.values():
             for column, metric in columns:

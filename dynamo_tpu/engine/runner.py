@@ -161,6 +161,11 @@ def _mh_zeros(shape, dtype, sharding):
     return jnp.zeros(shape, dtype, device=sharding)
 
 
+#: Fresh K and V one prefill program may hold for all its rows ahead of
+#: its commit (prefill_batch runs a larger group in parts).
+PREFILL_FRESH_KV_BYTES = 1 << 30
+
+
 class ModelRunner:
     def __init__(self, config: EngineConfig, params=None,
                  devices: list | None = None, seed: int = 0):
@@ -248,6 +253,11 @@ class ModelRunner:
         self.moe_grouped_pairs = 0
         self.page_size = config.page_size
         self._sized_pages(self.device)
+        if spec.loop_passes > 1:
+            log.info("looped stack: %d passes over %d layers, %d pool "
+                     "layers, %d B of K and V a token", spec.loop_passes,
+                     spec.num_layers, spec.pool_layers,
+                     config.kv_token_bytes())
 
         # Shard or init parameters.
         pspecs = param_specs(spec)
@@ -1025,7 +1035,7 @@ class ModelRunner:
             page_table = packed[:, PK_PREFIX:]
             B = tokens0.shape[0]
             # The window's buffers hold the layers that leave K and V.
-            L = spec.pool_layers if recurrent else spec.num_layers
+            L = spec.pool_layers - spec.mtp_layers
             nkv, (dk, dv) = spec.kv_entry
             # Cache-resident history length is FIXED across the window: the
             # window's own tokens live in a small in-window buffer and are
@@ -1046,6 +1056,9 @@ class ModelRunner:
             # and had in context (model.latent_window_attention): a [2]
             # vector of its own ("attn").
             routed = bool(spec.num_experts)
+            # A looped stack's counts the passes its live rows took
+            # (model.scan_passes): a [2] vector ("loop").
+            looped = spec.loop_passes > 1
 
             # The compressed-key array is read where it lies, as the pool
             # is, and written at the window's commit.
@@ -1072,7 +1085,7 @@ class ModelRunner:
                         positions, page_table, hist_lens,
                         backends=self.backends, lora=lora,
                         adapter_ids=adapter_ids,
-                        live=live if routed else None)
+                        live=live if routed or looped else None)
                 # Append this step's K/V ([L,B,Nkv,D] -> window col m).
                 with perf.scope("kv.commit"):
                     kbuf = jax.lax.dynamic_update_slice(
@@ -1510,6 +1523,15 @@ class ModelRunner:
         # intermediates alone) runs in parts, in turn, each a program that
         # a smaller group of the same bucket also draws.
         rows = max(1, cfg.max_prefill_tokens // bucket)
+        # And at most PREFILL_FRESH_KV_BYTES of fresh K and V, which a
+        # program holds for all its rows ahead of the commit (a looped
+        # stack's token leaves K and V once a PASS and layer: 1.5 MiB a
+        # token at 192 pairs of 16 heads, 6 GiB for 8 rows x 512): the
+        # largest power of two of rows under it, since a batch is padded
+        # to one. No preset's bytes reach the bound at max_prefill_tokens.
+        fresh = bucket * cfg.kv_token_bytes() // max(1, cfg.tp * cfg.pp)
+        while rows > 1 and rows * fresh > PREFILL_FRESH_KV_BYTES:
+            rows = 1 << ((rows - 1).bit_length() - 1)
         if len(seqs) > rows:
             return self._prefill_parts(seqs, slots, count_rows, fetch, rows)
         with_history = any(s.hist_pages is not None and len(s.hist_pages)
@@ -1788,6 +1810,10 @@ class ModelRunner:
                 "pooled embeddings (runner.embed)", "embed_forward scans "
                 "one stack of attention and feed-forward layers, and this "
                 "block's layers are one mixer each")
+        if spec.loop_passes > 1:
+            raise UnsupportedBlockError(
+                "pooled embeddings (runner.embed)", "embed_forward scans "
+                "the layers once and has no loop over passes")
         if not token_lists or any(not t for t in token_lists):
             raise ValueError("embeddings need at least one non-empty input")
         n_max = max(len(t) for t in token_lists)
@@ -2099,14 +2125,14 @@ def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
     from dynamo_tpu.engine.kv_quant import gather_pages_folded
     from dynamo_tpu.engine.model import (
         embed_lookup, history_attention, latent_prefill_attention, layer_kind,
-        lm_logits, norm, scan_layers, spec_rope_tables, transformer_block,
+        lm_logits, norm, scan_passes, spec_rope_tables, transformer_block,
         window_reach)
 
     b, s = tokens.shape
     d = spec.head_dim
     nkv = spec.num_kv_heads
     page = k_cache.shape[3]
-    L = spec.num_layers
+    L = spec.layer_visits
     with perf.scope("embed"):
         x = embed_lookup(params["embed"], tokens)
         if x_embeds is not None:
@@ -2152,8 +2178,8 @@ def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
 
     xs = ((params["layers"], jnp.arange(L), lora) if lora is not None
           else (params["layers"], jnp.arange(L)))
-    x, (k_new, v_new) = scan_layers(
-        layer_fn, x, xs, spec,
+    x, (k_new, v_new) = scan_passes(
+        layer_fn, x, xs, spec, params["final_norm"],
         whole_experts=expert_product(b * s, backends) == "grouped")
     with perf.scope("kv.commit"):
         heads, (dk, dv) = spec.kv_entry

@@ -82,7 +82,11 @@ log = get_logger("flight")
 # A block with recurrent layers adds
 # "ssm_row_steps" (live rows summed over the window's steps, on the device:
 # the rows whose recurrent state a step had to touch); 0 for every other
-# block. Beside "rows", taken at the same instant (the
+# block. A looped stack adds "loop_passes" (passes over the layers that
+# the live rows took, summed over the window's steps where the passes run,
+# on the device) and "loop_row_steps" (the live rows themselves, summed
+# over the steps: loop_passes over it is the passes a token took); 0 for
+# every other block. Beside "rows", taken at the same instant (the
 # window's DISPATCH): "prefilling", the slots a request held without a row
 # in this window (in chunked prefill, or stalled for pages, frozen for a
 # preemption, or owed nothing but its first token's readback), so that
@@ -98,7 +102,8 @@ FIELDS = ("t_mono", "dur_s", "active", "waiting", "free_pages",
           "moe_load", "moe_layer_steps", "moe_local_picks", "moe_picks",
           "attn_selected", "attn_context", "prefilling", "admit_stop",
           "spec_drafted", "spec_accepted", "spec_row_steps",
-          "ssm_row_steps", "attn_index_read")
+          "ssm_row_steps", "attn_index_read", "loop_passes",
+          "loop_row_steps")
 _INT_FIELDS = ("active", "waiting", "free_pages", "chunk_tokens",
                "chunks_inflight", "preempts", "brownout", "step", "tokens",
                "rows", "page_bucket", "missed", "prefilling", "admit_stop")
@@ -122,6 +127,8 @@ COUNTS = {
              ("attn_context", "attn_context_total"),
              ("attn_index_read", "attn_index_read_total")),
     "ssm": (("ssm_row_steps", "ssm_row_steps_total"),),
+    "loop": (("loop_passes", "loop_passes_total"),
+             ("loop_row_steps", "loop_row_steps_total")),
     "spec": (("spec_drafted", None), ("spec_accepted", None),
              ("spec_row_steps", None)),
 }

@@ -968,3 +968,70 @@ def test_sala_programs_compile_for_v5e_with_the_state_where_it_lies(v5e):
         s((1, _PF_HDR + bucket + bucket // page + 1), jnp.int32),
         s(key.shape, key.dtype), state=state).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+def _looped_runner(v5e, rows=4, pages=339):
+    """A runner that places nothing, with Ouro-2.6B's layers, passes and
+    attention geometry (48 layers run 4 times: a pool of 192 layers of 16
+    KV heads of 128, pages of 16) and a narrow MLP and vocabulary."""
+    from dynamo_tpu.engine.config import EngineConfig, OuroSpec
+    from dynamo_tpu.engine.model import param_shapes
+    from dynamo_tpu.engine.runner import ModelRunner
+    spec = OuroSpec(name="ouro", vocab_size=1024, hidden_size=2048,
+                    intermediate_size=1024, num_layers=48, num_heads=16,
+                    num_kv_heads=16, head_dim=128, loop_passes=4)
+    runner = object.__new__(ModelRunner)
+    runner.spec = spec
+    runner.config = EngineConfig(model=spec, page_size="auto",
+                                 num_pages=pages, max_num_seqs=rows)
+    runner.quant_kv, runner.lora = None, None
+    runner._window_cache, runner._prefill_cache = {}, {}
+    runner.mesh = None
+    runner.backends = choose(runner.config, spec, "tpu", 1, None)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    params = jax.tree.map(lambda shape: s(shape, jnp.bfloat16),
+                          param_shapes(spec),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    return runner, spec, params, s
+
+
+def test_looped_programs_compile_for_v5e_with_the_pool_where_it_lies(v5e):
+    """The window program of a looped stack at Ouro-2.6B's geometry: the
+    reader at ONE query row a KV head over pages of 16 (one call inside the
+    scan over passes and layers), the commit in place by layer ranges (six
+    calls of 32 pool layers: 192 at once are 50 MB of tiles and windows),
+    nothing of the pool's shape copied; and a prefill program whose fresh K
+    and V of all 192 (pass, layer) pairs stay under the runner's bound."""
+    from dynamo_tpu.engine.runner import _PF_HDR, PK_PREFIX
+    rows, pages, table = 4, 339, 128
+    runner, spec, params, s = _looped_runner(v5e, rows, pages)
+    # On a described chip the page is what "auto" derives on the chip.
+    page = runner.config.resolve_page_size("tpu")
+    assert page == PAGE == runner.config.page_size
+    assert (runner.backends.attention, runner.backends.kv_commit) == (
+        "pallas", "in_place")
+    pool = (192, 16, pages, page, 128)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    cache = s(pool, jnp.bfloat16)
+    fn = runner._get_window(4, table)
+    text = fn.lower(params, cache, cache, s((rows,), jnp.int32),
+                    s((rows, PK_PREFIX + table), jnp.int32),
+                    s(key.shape, key.dtype)).compile().as_text()
+    assert text.count("tpu_custom_call") == 7
+    assert text.count("output_to_operand_aliasing") >= 6
+    assert pool_sized_ops(text, pool) == []
+    bucket = 512
+    compiled = runner._get_prefill(bucket, 1, False).lower(
+        params, cache, cache,
+        s((1, _PF_HDR + bucket + bucket // page), jnp.int32),
+        s(key.shape, key.dtype)).compile()
+    # The pages' update in place, a pool (the update and the fusion it is
+    # the root of): no copy.
+    assert sorted(kind for _, kind in pool_sized_ops(
+        compiled.as_text(), pool)) == ["dynamic-update-slice"] * 2 + [
+            "fusion"] * 2
+    # 0.75 GiB of fresh K and V and as much of their page blocks.
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
