@@ -23,6 +23,20 @@ page is as large as makes one copy worth its descriptor
 (config.resolve_page_size: 64 tokens at 4 KV heads of 128; PERF.md section
 6, PR 31: 2.77 ms at pages of 16, 2.00 at 64, of which the dots are 1.35).
 
+A chunk turn comes in two forms, chosen by reader_turn from the reader's
+shapes (PR 49). "rows": a KV head at a time, its query rows against the
+head's chunk; what every geometry of 7 or 16 query rows a head takes.
+"heads": where a head has one or two query rows (Ouro-2.6B: 16 KV heads of
+ONE) the rows turn is 2 x nkv small products and nkv flash updates over
+tiles that use an eighth of their sublanes, 1.80 us a turn of 16 heads
+with no page copied; there the chunk buffer as it lies, [heads x tokens,
+128], streams past ONE tile of all heads' queries, a constant mask keeps
+lane h of head h's slab, the folded tile is transposed to [heads, tokens],
+which is the rows turn's layout for one head of nkv rows, the flash update
+runs once, and every head's page of V meets all heads' probabilities (its
+own rows kept): 1.01 us of products, under the 1.50 us its page copies
+take (scripts/kv_reader_bench.py; PERF.md section 6, PR 49).
+
 Measured on one v5e (PERF.md section 6, PR 26), attention of one decode
 step of Qwen2.5-7B, 17 live rows of 32 at about 950 tokens: the gather
 21.4 ms, this kernel 3 ms (as it stood before that PR, a program per
@@ -148,6 +162,80 @@ def pages_per_chunk(page_size: int, nkv: int, d: int, itemsize: int) -> int:
     return ppc
 
 
+def reader_turn(q_per_kv: int, nkv: int, tpr: int, quantized: bool) -> str:
+    """Which chunk turn _decode_kernel takes, from the reader's shapes alone:
+    "rows" (a KV head at a time: its q_per_kv query rows against the head's
+    chunk, scores and values, a flash update a head) or "heads" (every
+    head's keys stream past ONE tile of all queries, the flash update runs
+    once over [heads x rows, tokens], and every head's page of V meets all
+    of them: _heads_scores, _heads_values).
+
+    Placed by the reader alone on one v5e (scripts/kv_reader_bench.py;
+    PERF.md section 6, PR 49, call 2): four rows of 200 to 1,000 tokens,
+    pages of 16, microseconds a chunk turn, rows | heads, and in brackets
+    the same with no page copied (the products alone):
+
+        KV heads x query rows   rows   heads    [rows   heads]
+        16 x 1 (Ouro-2.6B)      2.14   1.54     [1.80   1.01]
+         8 x 1                  2.62   1.84     [2.02   1.18]
+        32 x 1                  3.86   2.90     [3.40   1.74]
+        16 x 2                  2.17   1.59     [1.83   1.15]
+         4 x 1                  2.56   2.56     [1.54   1.51]
+         4 x 7 (Qwen2.5-7B)     2.69   2.66     [1.65   1.61]
+         4 x 7 at pages of 64   1.40   1.49     [1.08   1.12]
+         8 x 16 (Command A+)    2.77   2.66     [2.13   2.02]
+         2 x 16 (Nemotron)      3.23   3.45     [1.68   1.94]
+
+    At one or two query rows a head the rows turn spends itself on 2 x nkv
+    small products and nkv flash updates over tiles that use one or two of
+    their eight sublanes; from 8 heads up the heads turn is a quarter to
+    three tenths shorter and then waits for its page copies (1.50 us a turn
+    at 16 x 1 with both products taken out). At 4 heads a turn is 64 page
+    copies of 16 KB and either form waits for them; at 7 or 16 rows a head
+    the rows turn's tiles are full and the heads turn buys nothing (level
+    to a fifteenth worse), so every such geometry stays where it was."""
+    if quantized or tpr != 1:
+        # An int8 page's scales multiply scores laid [rows of one head,
+        # tokens], and a packed head's row groups are not heads: neither
+        # was measured on the heads turn.
+        return "rows"
+    return ("heads" if q_per_kv <= 2 and nkv >= 8 and nkv * q_per_kv <= 128
+            else "rows")
+
+
+def _heads_scores(q, k_all, nkv: int, qpk: int, rows: int):
+    """Every head's scores of a chunk in ONE product with the keys as the
+    streamed operand: k_all [nkv * tokens, 128] (the chunk buffer as it
+    lies) against q [128, 128] (row h * qpk + i: query i of head h; zeros
+    past the heads). Row (h, t) of the product holds head h's scores in
+    lanes h * qpk .. and other heads' queries against this head's keys in
+    the others, which a constant mask drops as the nkv slabs fold into one
+    tile [tokens, rows]; its transpose is what the parent's turn holds for
+    ONE head of ``rows`` query rows: [rows, tokens] float32."""
+    tokens = k_all.shape[0] // nkv
+    r = jax.lax.dot_general(k_all, q, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    head = jax.lax.broadcasted_iota(jnp.int32, (tokens, 128), 1) // qpk
+    s = r[:tokens]
+    for h in range(1, nkv):
+        s = jnp.where(head == h, r[h * tokens:(h + 1) * tokens], s)
+    return s.T[:rows]
+
+
+def _heads_values(p, v_of, nkv: int, qpk: int):
+    """The value product of all heads' probabilities p [rows, tokens]: a
+    head's page of V is still the MXU's weights (v_of(h): [tokens, 128]),
+    but all rows stream past it and the head's own rows are kept."""
+    head = jax.lax.broadcasted_iota(jnp.int32, (p.shape[0], 128), 0) // qpk
+    out = None
+    for h in range(nkv):
+        v = v_of(h)
+        o = jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        out = o if out is None else jnp.where(head == h, o, out)
+    return out
+
+
 def _fetch_pipeline(page_table_ref, seq_lens_ref, cur_ref, pools, sems,
                     layer, nb, page_size: int, first_chunk):
     """The page fetch both readers run, ONE pipeline across the whole grid:
@@ -233,7 +321,8 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
                    *rest,  # [lo_ref if windowed], q2 VMEM block, k/v packed
                    # (ANY), [ks_ref, vs_ref if quantized], outputs, scratch
                    page_size: int, tpr: int, qpk: int,
-                   quantized: bool = False, windowed: bool = False):
+                   quantized: bool = False, windowed: bool = False,
+                   heads: bool = False):
     """One grid program per batch row, all KV heads inside it. The K/V
     fetch is ONE pipeline across the whole grid (_fetch_pipeline): while
     chunk g is multiplied the chunks after it are in flight, be they this
@@ -245,7 +334,14 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
     vector, lo [B], is the first token each row's query still sees in THIS
     layer (0 in a full layer). The walk starts at the chunk that holds lo,
     so chunks wholly before the window are never fetched, and tokens before
-    lo inside that chunk are masked."""
+    lo inside that chunk are masked.
+
+    ``heads`` (reader_turn's "heads"; tpr 1, bfloat16 pages): q_ref is
+    [1, heads padded to a bfloat16 tile, 128] and so are the outputs; a
+    turn multiplies every head's keys in one product (_heads_scores), runs
+    the flash update ONCE over [heads, tokens] and takes the values of all
+    heads' probabilities (_heads_values). The walk, the fetch pipeline, the
+    masks and the update's arithmetic are the other turn's."""
     if windowed:
         lo_ref, *rest = rest
     q_ref, k_hbm, v_hbm, *rest = rest
@@ -273,7 +369,8 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
 
     chunk0 = jnp.where(seq_len > 0, first_chunk(b), 0)  # a dead row: 0..0
 
-    n = tpr * qpk
+    # Score rows of one flash update: a KV head's query rows, or all heads'.
+    n = q_ref.shape[1] if heads else tpr * qpk
     d = 128 // tpr
     scale = 1.0 / (d ** 0.5)
 
@@ -295,7 +392,8 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
 
     # token index of (row-group t, packed row r) is chunk_start + r*tpr + t
     # where t = sublane // qpk.
-    group = jax.lax.broadcasted_iota(jnp.int32, (n, rows), 0) // qpk
+    group = (0 if heads else
+             jax.lax.broadcasted_iota(jnp.int32, (n, rows), 0) // qpk)
     row = jax.lax.broadcasted_iota(jnp.int32, (n, rows), 1)
 
     def score_scales(s_ref, h, c):
@@ -319,6 +417,13 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
 
     pl.when(b == 0)(prime)
 
+    if heads:
+        # All heads' queries as the scores' stationary tile, once a row.
+        q_all = q_ref[0]
+        if n < 128:
+            q_all = jnp.concatenate(
+                [q_all, jnp.zeros((128 - n, 128), q_all.dtype)], axis=0)
+
     def body(c, carry, g0):
         slot = jax.lax.rem(g0 + c - chunk0, SLOTS)
         wait_fetch(b, c, slot)
@@ -326,6 +431,20 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
         live = token_idx < seq_len
         if windowed:
             live = live & (token_idx >= lo_ref[b])
+        if heads:
+            m, l, acc = carry
+            scores = _heads_scores(
+                q_all, k_buf[slot].reshape(nkv * rows, 128), nkv, qpk,
+                n) * scale
+            scores = jnp.where(live, scores, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+            p = jnp.exp(scores - m_new)
+            alpha = jnp.exp(m - m_new)
+            l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_new = acc * alpha + _heads_values(
+                p, lambda h: pages_of(v_buf, slot, h, q_all.dtype), nkv, qpk)
+            issue_fetch()
+            return m_new, l_new, acc_new
         out = []
         for h in range(nkv):
             m, l, acc = carry[3 * h:3 * h + 3]
@@ -360,7 +479,7 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
 
     init = (jnp.full((n, 1), NEG_INF, jnp.float32),
             jnp.zeros((n, 1), jnp.float32),
-            jnp.zeros((n, 128), jnp.float32)) * nkv
+            jnp.zeros((n, 128), jnp.float32)) * (1 if heads else nkv)
 
     # A row without history walks no chunk and emits the neutral triple,
     # which the wrapper's merge weighs exp(-inf).
@@ -368,6 +487,12 @@ def _decode_kernel(layer_ref, page_table_ref, seq_lens_ref,  # SMEM prefetch
     stats = jax.lax.fori_loop(chunk0, num_chunks,
                               functools.partial(body, g0=g0), init)
     g_ref[0] = g0 + num_chunks - chunk0
+    if heads:
+        m, l, acc = stats
+        acc_ref[0] = acc
+        m_ref[0] = jnp.broadcast_to(m, (n, 128))
+        l_ref[0] = jnp.broadcast_to(l, (n, 128))
+        return
     for h in range(nkv):
         m, l, acc = stats[3 * h:3 * h + 3]
         acc_ref[0, h] = acc
@@ -444,6 +569,14 @@ def _hist_flash_pallas(q, k_cache, v_cache, layer, page_table, hist_lens,
         for t in range(tpr):
             q2 = q2.at[:, :, t * qpk:(t + 1) * qpk, t * d:(t + 1) * d].set(qg)
 
+    heads = reader_turn(qpk, nkv, tpr, quantized) == "heads"
+    if heads:
+        # All heads' rows in one tile, h * qpk + i as q has them, padded
+        # with zero queries to whole bfloat16 tiles (none at 16 heads).
+        n = -(-nkv * qpk // 16) * 16
+        q2 = q if n == nkv * qpk else jnp.pad(
+            q, ((0, 0), (0, n - nkv * qpk), (0, 0)))
+
     windowed = lo is not None
     # A row whose window starts past its history sees none of it.
     prefetch = [layer_arr, page_table,
@@ -452,7 +585,10 @@ def _hist_flash_pallas(q, k_cache, v_cache, layer, page_table, hist_lens,
     if windowed:
         prefetch.append(jnp.minimum(lo, jnp.maximum(seq_lens - 1, 0))
                         .astype(jnp.int32))
-    blk = pl.BlockSpec((1, nkv, n, 128), lambda i, *_: (i, 0, 0, 0))
+    if heads:
+        blk = pl.BlockSpec((1, n, 128), lambda i, *_: (i, 0, 0))
+    else:
+        blk = pl.BlockSpec((1, nkv, n, 128), lambda i, *_: (i, 0, 0, 0))
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [blk, any_spec, any_spec]
     operands = [q2, kp, vp]
@@ -475,8 +611,9 @@ def _hist_flash_pallas(q, k_cache, v_cache, layer, page_table, hist_lens,
     )
     kernel = functools.partial(_decode_kernel, page_size=page_size, tpr=tpr,
                                qpk=qpk, quantized=quantized,
-                               windowed=windowed)
-    shape = jax.ShapeDtypeStruct((b, nkv, n, 128), jnp.float32)
+                               windowed=windowed, heads=heads)
+    shape = jax.ShapeDtypeStruct((b, n, 128) if heads else (b, nkv, n, 128),
+                                 jnp.float32)
     acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -487,6 +624,8 @@ def _hist_flash_pallas(q, k_cache, v_cache, layer, page_table, hist_lens,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*prefetch, *operands)
+    if heads:
+        acc, m, l = (x[:, :nkv * qpk] for x in (acc, m, l))
     m = m[..., :1]  # broadcast lanes -> scalar stat per row
     l = l[..., :1]
     if tpr == 1:

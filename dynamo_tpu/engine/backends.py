@@ -43,6 +43,10 @@ class Backends:
     #: pages walks these under the same page table); None: a block whose
     #: queries choose nothing.
     index: str | None = None
+    #: The chunk turn the K-and-V reader's kernel takes where it is not a
+    #: KV head at a time: "heads" (attention.reader_turn, the ONE rule, on
+    #: the reader's shapes); None: that turn, or no such kernel.
+    kv_reader_turn: str | None = None
     #: How the decode window writes its tokens into the pool: "in_place"
     #: (attention.commit_window_pallas) | "scatter".
     kv_commit: str = "scatter"
@@ -125,6 +129,8 @@ class Backends:
                    "kv_commit_backend": self.kv_commit,
                    "page_size": self.page_size,
                    **({"index_backend": self.index} if self.index else {}),
+                   **({"kv_reader_turn": self.kv_reader_turn}
+                      if self.kv_reader_turn else {}),
                    # Who drafts inside this program's steps.
                    "draft": self.draft}
         if self.routed and expert_product is not None:
@@ -187,8 +193,9 @@ def choose(config: EngineConfig, spec: ModelSpec, platform: str,
     which would interpret the kernel; a mesh, which never has the Pallas
     reader and is refused for such a block (config.block_refusals); and a
     runner asked for the XLA reader, which is XLA's throughout
-    (chip_smoke.py compares the two on the chip). The experts are whole on
-    a mesh of one device. Interpret mode exists for the CPU backend only."""
+    (chip_smoke.py compares the two on the chip). The K-and-V kernel's
+    chunk turn is attention.reader_turn's, asked with the spec's heads. The
+    experts are whole on a mesh of one device. Interpret mode exists for the CPU backend only."""
     reader, writer = pool_access(config.attention_backend, platform,
                                  mesh_size, spec.head_dim, quant_kv,
                                  spec.latent)
@@ -199,11 +206,18 @@ def choose(config: EngineConfig, spec: ModelSpec, platform: str,
         refusal = pallas_refusal(spec, config.page_size, mesh_size, quant_kv)
         if refusal is not None:
             raise ValueError(f"attention_backend='pallas' {refusal}")
+    heads_turn = False
+    if reader == "pallas" and not spec.latent:
+        from dynamo_tpu.engine.attention import reader_turn
+        heads_turn = reader_turn(
+            spec.num_heads // spec.num_kv_heads, spec.num_kv_heads,
+            max(1, 128 // spec.head_dim), quant_kv is not None) == "heads"
     ssm = None
     if spec.recurrent:
         ssm = "kernel" if reader == "pallas" and platform == "tpu" else "xla"
     return Backends(
         attention=reader,
+        kv_reader_turn="heads" if heads_turn else None,
         index=reader if (spec.latent and spec.index_topk
                          or spec.compressed_keys) else None,
         kv_commit=writer, ssm=ssm, experts_whole=mesh_size == 1,

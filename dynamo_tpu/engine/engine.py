@@ -1100,6 +1100,10 @@ class TPUEngine(AsyncEngine):
             **{key: window.get(key) for key in (
                 "attention_backend", "kv_commit_backend", "index_backend",
                 "draft", "page_size")},
+            # The K-and-V kernel's chunk turn, where it is not a KV head
+            # at a time (attention.reader_turn).
+            **({"kv_reader_turn": window["kv_reader_turn"]}
+               if "kv_reader_turn" in window else {}),
             # Engine-thread self time by loop phase, seconds since the
             # loop started (engine_phase_seconds_total on /metrics).
             "phases": {k: round(v, 6)

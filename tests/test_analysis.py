@@ -1213,6 +1213,16 @@ def test_cli_stats_line(tmp_path):
 
 # -- analyzer performance budget ----------------------------------------------
 
+#: Functions the full-repo run must analyse a CPU-second. The bound is on
+#: WORK DONE, not on seconds: the package grows (1,809 functions in 124
+#: modules at PR 49, 6.4 to 8.7 CPU-seconds alone here and 10.0 to 10.7
+#: under the driver's six workers: 170 to 280 a second), and a bound in
+#: seconds failed for that alone (ROADMAP D11). A pass that stops sharing
+#: its parse, its call graph or its dataflow falls under this several times
+#: over at any size.
+LINT_FUNCTIONS_PER_CPU_S = 75.0
+
+
 def test_full_repo_lint_under_budget():
     """Single-pass sharing keeps the full-repo interprocedural run fast
     (parse once, one call graph + one dataflow for all 18 rules).
@@ -1220,8 +1230,8 @@ def test_full_repo_lint_under_budget():
     analyzing thread's CPU seconds, measured inside run_analysis — not
     wall time, so cache-cold imports, a saturated 1-core box, and
     background threads left by earlier suites in the same pytest
-    process can't flake tier-1. Generous bound; locally the analysis
-    is ~4-6 s."""
+    process can't flake tier-1; and judge it against the functions the
+    run analysed, so the bound follows the package."""
     import dynamo_tpu
     from pathlib import Path
 
@@ -1230,8 +1240,11 @@ def test_full_repo_lint_under_budget():
     assert set(run.timings) >= {"parse_s", "graph_s", "dataflow_s",
                                 "rules_s", "analysis_s",
                                 "analysis_cpu_s"}
-    assert run.timings["analysis_cpu_s"] < 10.0, \
-        f"full-repo analysis took {run.timings['analysis_cpu_s']:.1f}s CPU"
+    functions, cpu_s = len(run.graph.functions), run.timings["analysis_cpu_s"]
+    assert functions > 1000 and len(run.modules) > 100   # the whole package
+    assert functions / cpu_s >= LINT_FUNCTIONS_PER_CPU_S, (
+        f"full-repo analysis took {cpu_s:.1f}s CPU for {functions} "
+        f"functions: {functions / cpu_s:.0f} a CPU-second")
 
 
 # =============================================================================

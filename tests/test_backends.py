@@ -28,6 +28,8 @@ BENCH = os.path.join(os.path.dirname(__file__), "..", "benchmark")
 #: What a token leaves in the pool, as backends.choose reads a spec.
 POOLS = {
     "kv": dict(latent=False, index_topk=0, recurrent=False),
+    "kv 16 x 1": dict(latent=False, index_topk=0, recurrent=False,
+                      num_heads=16, num_kv_heads=16),
     "indexed": dict(latent=True, index_topk=2048, recurrent=False),
     "latent": dict(latent=True, index_topk=0, recurrent=False),
     "recurrent": dict(latent=False, index_topk=0, recurrent=True),
@@ -50,6 +52,17 @@ def on(platform, mesh=1, **kw):
         ("tpu", 1, 128, None, "kv", "auto",
          on("tpu", attention="pallas", kv_commit="in_place")),
         ("tpu", 1, 128, "int8", "kv", "auto", on("tpu", attention="pallas")),
+        # ... whose chunk turn follows the heads (attention.reader_turn):
+        # 16 KV heads of one query row take the turn over all heads.
+        ("tpu", 1, 128, None, "kv 16 x 1", "auto",
+         on("tpu", attention="pallas", kv_commit="in_place",
+            kv_reader_turn="heads")),
+        ("tpu", 1, 128, "int8", "kv 16 x 1", "auto",
+         on("tpu", attention="pallas")),
+        ("tpu", 1, 128, None, "kv 16 x 1", "xla", on("tpu")),
+        ("cpu", 1, 128, None, "kv 16 x 1", "pallas",
+         on("cpu", attention="pallas", kv_commit="in_place",
+            kv_reader_turn="heads")),
         ("tpu", 1, 64, None, "kv", "auto", on("tpu")),
         ("tpu", 1, 96, None, "kv", "auto", on("tpu")),
         ("tpu", 4, 128, None, "kv", "auto", on("tpu", 4)),
@@ -118,7 +131,8 @@ def test_the_record_is_decided_from_what_a_runner_observes(
     config = SimpleNamespace(attention_backend=asked, page_size=16,
                              max_pages_per_seq=TABLE, spec_decode=None)
     spec = SimpleNamespace(**{**dict(head_dim=head_dim, num_experts=0,
-                                     compressed_keys=False), **POOLS[pool]})
+                                     compressed_keys=False, num_heads=28,
+                                     num_kv_heads=4), **POOLS[pool]})
     if isinstance(want, str):
         with pytest.raises(ValueError) as refused:
             choose(config, spec, platform, mesh, quant_kv)
@@ -148,6 +162,38 @@ def test_the_default_record_is_xla_s_on_any_platform():
     assert XLA.latent_readers() == (None, None)
     assert model.expert_product(10 ** 6, XLA) == "masked"
     assert XLA.labels("prefill", "masked") == {}   # a dense block: no label
+
+
+def test_the_reader_s_turn_is_named_only_where_it_is_taken():
+    """``kv_reader_turn`` is a label of the window program where the
+    kernel's turn runs over all heads at once, and of no other program."""
+    heads = Backends(attention="pallas", kv_reader_turn="heads")
+    assert heads.labels("decode_window")["kv_reader_turn"] == "heads"
+    assert "kv_reader_turn" not in heads.labels("prefill")
+    assert "kv_reader_turn" not in Backends(attention="pallas").labels(
+        "decode_window")
+
+
+@pytest.mark.parametrize("kv_heads, want", [(8, "heads"), (2, None)])
+def test_debug_perf_names_the_reader_s_turn_where_it_is_taken(kv_heads,
+                                                             want):
+    """/debug/perf and the window program's labels carry ``kv_reader_turn``
+    for a runner whose kernel takes the turn over all heads (8 KV heads of
+    one query row at head_dim 128) and do not have the key for one that
+    multiplies a KV head at a time."""
+    spec = ModelSpec(name="turn", vocab_size=128, hidden_size=1024,
+                     intermediate_size=128, num_layers=1, num_heads=8,
+                     num_kv_heads=kv_heads)
+    engine = TPUEngine(EngineConfig(
+        model=spec, num_pages=16, max_pages_per_seq=4, max_num_seqs=2,
+        attention_backend="pallas", page_size=16))
+    try:
+        assert engine.runner.backends.kv_reader_turn == want
+        assert engine.perf_status().get("kv_reader_turn") == want
+        assert engine.runner._get_window(4, 4)._labels.get(
+            "kv_reader_turn") == want
+    finally:
+        engine.stop()
 
 
 # -- (b) the names the benchmark prints ------------------------------------------

@@ -229,7 +229,8 @@ def _picked(attention_backend, mesh_size, head_dim, quant_kv):
                              spec_decode=None)
     spec = SimpleNamespace(head_dim=head_dim, latent=False, recurrent=False,
                            compressed_keys=False,
-                           index_topk=0, num_experts=0)
+                           index_topk=0, num_experts=0, num_heads=28,
+                           num_kv_heads=4)
     return choose(config, spec, "tpu", mesh_size, quant_kv).kv_commit
 
 

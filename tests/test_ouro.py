@@ -526,6 +526,73 @@ def test_the_reader_at_one_query_row_a_head_of_128():
                                np.asarray(want, np.float32), atol=2e-2)
 
 
+#: (tokens a row holds, the first token a row still sees or None): rows of
+#: 16 KV heads x one query row over pages of 16, a chunk of 128 tokens.
+HEADS_TURN_CASES = {
+    "a row of no history": ([0, 40], None),
+    "a row that ends inside a page": ([57, 3], None),
+    "a row of exactly one chunk": ([128], None),
+    "several chunks, the last partial": ([300, 129], None),
+    "a dead slot between live rows": ([200, 0, 0, 130, 0, 17], None),
+    "a window that starts inside a chunk": ([300, 90], [170, 20]),
+    "a window that starts past a chunk": ([400, 257, 50], [300, 256, 60]),
+}
+
+
+@pytest.mark.parametrize("case", list(HEADS_TURN_CASES))
+def test_the_reader_s_turn_over_all_heads_at_once(case):
+    """The chunk turn the published geometry takes (attention.reader_turn:
+    "heads"), interpreted: every head's keys in one product, the flash
+    update once over [heads, tokens], against XLA's gather."""
+    hist, lo = HEADS_TURN_CASES[case]
+    nkv, d, L, b = 16, 128, 2, len(hist)
+    assert attention.reader_turn(1, nkv, 1, False) == "heads"
+    rng = np.random.default_rng(len(case))
+    maxp = -(-max(hist) // PAGE)
+    pages = b * maxp + 1
+    k, v = (jnp.asarray(rng.standard_normal((L, nkv, pages, PAGE, d)),
+                        jnp.bfloat16) for _ in range(2))
+    q, ks, vs = (jnp.asarray(rng.standard_normal((b, nkv, d)), jnp.bfloat16)
+                 for _ in range(3))
+    kw, vw = (jnp.asarray(rng.standard_normal((nkv, b, 4, d)), jnp.bfloat16)
+              for _ in range(2))
+    # Every row's pages apart and out of order; page 0 under the padding.
+    table = np.zeros((b, maxp), np.int32)
+    free = iter(rng.permutation(pages - 1) + 1)
+    for r, n in enumerate(hist):
+        for j in range(-(-n // PAGE)):
+            table[r, j] = next(free)
+    args = (q, k, v, jnp.int32(1), jnp.asarray(table),
+            jnp.asarray(hist, jnp.int32), kw, vw, jnp.int32(2), ks, vs)
+    kwargs = {} if lo is None else {"lo": jnp.asarray(lo, jnp.int32)}
+    want = model.paged_window_attention_xla(*args, 1, **kwargs)
+    got = attention.paged_window_attention_pallas(
+        *args, q_per_kv=1, interpret=True, **kwargs)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=2e-2)
+
+
+#: What the benchmark's cells hand the K-and-V reader (query rows a KV
+#: head, KV heads, tokens a 128-lane row, int8 pages) and the turn each
+#: takes: the rule reads shapes, never a name.
+READER_GEOMETRIES = {
+    "qwen2.5-7b": ((7, 4, 1, False), "rows"),
+    "smallthinker-21b-a3b, windowed": ((7, 4, 1, False), "rows"),
+    "command-a-plus": ((16, 8, 1, False), "rows"),
+    "nemotron-3-nano-30b-a3b": ((16, 2, 1, False), "rows"),
+    "minicpm-sala-9b, chosen blocks": ((16, 2, 1, False), "rows"),
+    "ouro-2.6b": ((1, 16, 1, False), "heads"),
+    "ouro's heads over int8 pages": ((1, 16, 1, True), "rows"),
+    "ouro's heads at head_dim 64": ((1, 16, 2, False), "rows"),
+}
+
+
+@pytest.mark.parametrize("name", list(READER_GEOMETRIES))
+def test_the_turn_is_chosen_by_the_reader_s_shapes(name):
+    geometry, want = READER_GEOMETRIES[name]
+    assert attention.reader_turn(*geometry) == want
+
+
 # -- the engine ----------------------------------------------------------------------
 
 PROMPT = prompt_of(40, 7)

@@ -1001,7 +1001,7 @@ def _looped_runner(v5e, rows=4, pages=339):
 def test_looped_programs_compile_for_v5e_with_the_pool_where_it_lies(v5e):
     """The window program of a looped stack at Ouro-2.6B's geometry: the
     reader at ONE query row a KV head over pages of 16 (one call inside the
-    scan over passes and layers), the commit in place by layer ranges (six
+    scan over passes and layers; its chunk turn the one over all heads), the commit in place by layer ranges (six
     calls of 32 pool layers: 192 at once are 50 MB of tiles and windows),
     nothing of the pool's shape copied; and a prefill program whose fresh K
     and V of all 192 (pass, layer) pairs stay under the runner's bound."""
@@ -1017,6 +1017,9 @@ def test_looped_programs_compile_for_v5e_with_the_pool_where_it_lies(v5e):
     key = jax.eval_shape(lambda: jax.random.key(0))
     cache = s(pool, jnp.bfloat16)
     fn = runner._get_window(4, table)
+    # The reader's turn over all 16 heads at once (attention.reader_turn),
+    # compiled by Mosaic inside the program below.
+    assert fn._labels["kv_reader_turn"] == "heads"
     text = fn.lower(params, cache, cache, s((rows,), jnp.int32),
                     s((rows, PK_PREFIX + table), jnp.int32),
                     s(key.shape, key.dtype)).compile().as_text()

@@ -131,7 +131,8 @@ def _picked(platform, mesh_size, head_dim, backend="auto", page_size=16):
                              max_pages_per_seq=8, spec_decode=None)
     spec = SimpleNamespace(head_dim=head_dim, latent=False, recurrent=False,
                            compressed_keys=False,
-                           index_topk=0, num_experts=0)
+                           index_topk=0, num_experts=0, num_heads=28,
+                           num_kv_heads=4)
     record = choose(config, spec, platform, mesh_size, None)
     return (record.attention, model.kv_attention(record, window=False),
             model.kv_attention(record, window=True))
