@@ -49,7 +49,7 @@ from dynamo_tpu.runtime.context import Context
 from dynamo_tpu.runtime.engine import AsyncEngine
 from dynamo_tpu.runtime.logging import current_trace, get_logger
 from dynamo_tpu.runtime.tracing import (_LATENCY_BUCKETS, get_recorder,
-                                        phase_metrics)
+                                        phase_metrics, startup_stage)
 
 log = get_logger("tpu_engine")
 
@@ -1080,6 +1080,8 @@ class TPUEngine(AsyncEngine):
             "role": "engine",
             "compiles": compiles,
             "window": self._perf.window_snapshot(),
+            # Where the start's seconds went: stages, first calls a family.
+            "startup": perf_plane.startup_status(),
             "roofline": {
                 "weight_read_step_ms": round(self._step_floor_ms, 4)
                 or None,
@@ -1260,7 +1262,6 @@ class TPUEngine(AsyncEngine):
         Warmup work is inert: all-zero packed rows are inactive
         (PK_SEQLEN=0) and prefill rows write only the reserved scratch
         page 0."""
-        t0 = time.monotonic()
         bucket_pages = self.runner.bucket_pages_for(1)
         packed = np.zeros((self.config.max_num_seqs,
                            PK_PREFIX + bucket_pages), np.int32)
@@ -1273,56 +1274,39 @@ class TPUEngine(AsyncEngine):
             outs = self.runner.decode_spec_window(
                 packed, self.spec_m_outer, self.config.spec_k)
             np.asarray(outs[0])
-            log.info("warmed spec window program m=%d k=%d in %.1fs "
-                     "(covers greedy + sampled + seeded verify)",
-                     self.spec_m_outer, self.config.spec_k,
-                     time.monotonic() - t0)
-            t0 = time.monotonic()
-            bucket = self.config.prefill_buckets[0]
-            seq = PrefillSeq(tokens=np.zeros(min(4, bucket), np.int32),
-                             start_pos=0,
-                             chunk_pages=np.zeros(1, np.int32),
-                             hist_pages=None, sampling=(0.0, 0, 1.0))
-            self.runner.prefill_batch([seq])
-            log.info("warmed prefill bucket %d in %.1fs", bucket,
-                     time.monotonic() - t0)
-            self._warmup_prefill_ladder()
-            return
-        outs = self.runner.decode_window(packed, self.decode_window)
-        np.asarray(outs[0])  # force compile + execute
-        # The penalized variant too: a first penalized request must not
-        # stall every in-flight stream on its compile. One inactive row
-        # with penalty bits set selects it; inactive rows do no work. (The
-        # drafting window has none: penalties are refused at validation.)
-        # TWICE: under tp > 1, GSPMD re-shards counts_dev in the first
-        # penalized program's output (replicated P() in, vocab-sharded
-        # out), so the SECOND call traces a new input signature — warm
-        # both here or the first real penalized request still pays that
-        # second compile (found by the perf plane's recompile detector).
-        variants = [({PK_SEEDED: 1}, 1)]
-        if not self.mtp:
-            pen = {PK_FREQPEN: np.float32(1.0).view(np.int32)}
-            variants = [(pen, 2), *variants, ({PK_SEEDED: 1, **pen}, 2)]
-        for columns, times in variants:
-            packed_var = packed.copy()
-            for column, value in columns.items():
-                packed_var[0, column] = value
-            for _ in range(times):
-                outs = self.runner.decode_window(packed_var,
-                                                 self.decode_window)
-                np.asarray(outs[0])
-        log.info("warmed window programs M=%d in %.1fs", self.decode_window,
-                 time.monotonic() - t0)
-        t0 = time.monotonic()
+        else:
+            outs = self.runner.decode_window(packed, self.decode_window)
+            np.asarray(outs[0])  # force compile + execute
+            # The penalized variant too: a first penalized request must not
+            # stall every in-flight stream on its compile. One inactive row
+            # with penalty bits set selects it; inactive rows do no work.
+            # (The drafting window has none: penalties are refused at
+            # validation.)
+            # TWICE: under tp > 1, GSPMD re-shards counts_dev in the first
+            # penalized program's output (replicated P() in, vocab-sharded
+            # out), so the SECOND call traces a new input signature — warm
+            # both here or the first real penalized request still pays that
+            # second compile (found by the perf plane's recompile detector).
+            variants = [({PK_SEEDED: 1}, 1)]
+            if not self.mtp:
+                pen = {PK_FREQPEN: np.float32(1.0).view(np.int32)}
+                variants = [(pen, 2), *variants, ({PK_SEEDED: 1, **pen}, 2)]
+            for columns, times in variants:
+                packed_var = packed.copy()
+                for column, value in columns.items():
+                    packed_var[0, column] = value
+                for _ in range(times):
+                    outs = self.runner.decode_window(packed_var,
+                                                     self.decode_window)
+                    np.asarray(outs[0])
         bucket = self.config.prefill_buckets[0]
         seq = PrefillSeq(tokens=np.zeros(min(4, bucket), np.int32),
                          start_pos=0,
                          chunk_pages=np.zeros(1, np.int32),  # scratch page
                          hist_pages=None, sampling=(0.0, 0, 1.0))
         self.runner.prefill_batch([seq])  # slots=None blocks until done
-        log.info("warmed prefill bucket %d in %.1fs", bucket,
-                 time.monotonic() - t0)
-        self._warmup_prefill_ladder()
+        with startup_stage("startup.prefill_ladder"):
+            self._warmup_prefill_ladder()
 
     def _warmup_prefill_ladder(self) -> None:
         """Pre-compile EVERY prefill bucket, with and without history
@@ -1331,14 +1315,14 @@ class TPUEngine(AsyncEngine):
         compile per bucket while every live decode slot waits. Warmup
         rows are inert: zero tokens, all writes to the reserved scratch
         page 0. jit
-        COMPILATION blocks the caller, so each call here really pays
-        (and logs) its compile; the inert executions drain async."""
+        COMPILATION blocks the caller, so each call here really pays its
+        compile (a first-call record each, engine/perf.py); the inert
+        executions drain async."""
         if not self.config.warmup_prefill_ladder:
             return
         page = self.config.page_size
         for bucket in self.config.prefill_buckets:
             for with_h in (False, True):
-                t0 = time.monotonic()
                 seq = PrefillSeq(
                     tokens=np.zeros(bucket, np.int32),
                     start_pos=page if with_h else 0,
@@ -1347,17 +1331,18 @@ class TPUEngine(AsyncEngine):
                                 else None),
                     sampling=(0.0, 0, 1.0))
                 self.runner.prefill_batch([seq], fetch=False)
-                log.info("warmed prefill bucket %d%s in %.1fs", bucket,
-                         " +history" if with_h else "",
-                         time.monotonic() - t0)
 
     def _engine_loop(self) -> None:
         log.info("engine loop starting (slots=%d pages=%d window=%d)",
                  self.config.max_num_seqs, self.runner.num_pages,
                  self.decode_window)
         if self.config.warmup_windows:
+            t0 = time.monotonic()
             try:
-                self._warmup_window_programs()
+                # A stage of the launcher's start-up trace (and whatever a
+                # subclass runs in here before ready falls inside it).
+                with startup_stage("startup.warmup"):
+                    self._warmup_window_programs()
             except Exception as exc:  # noqa: BLE001 — reported, then fatal
                 # A program that does not compile or run at warm-up will
                 # not do so for a request either: fail the start-up
@@ -1377,6 +1362,12 @@ class TPUEngine(AsyncEngine):
         # legitimately; only SAME-signature recompiles are flagged).
         self._perf.mark_ready()
         self._ready.set()
+        start = tracing.last_startup()
+        if self.config.warmup_windows and not (start and start.open):
+            # No launcher tells this start in its ready line: the engine's
+            # own ONE line, in place of a line a bucket.
+            log.info("warm-up %.1f s (%s)", time.monotonic() - t0,
+                     perf_plane.describe_first_calls())
         depth = max(1, self.config.pipeline_depth)
         # Each phase below is a TraceAnnotation on this thread's line of a
         # profiler trace and self time in phase_clock; what an iteration

@@ -11,13 +11,25 @@ this chip: PERF.md section 5 and PERF_LEDGER.jsonl):
 - ``CompileRegistry``: every ``jax.jit`` program in the serving path is
   built through :func:`instrumented_jit` (enforced by the
   ``unregistered-jit`` lint rule), which wraps the jitted callable and
-  detects REAL XLA compiles via ``jax.monitoring``'s backend-compile
-  events — dispatch-cache churn (e.g. committed-ness changes) does not
-  count (falling back to first-call counting when the monitoring API is
-  unavailable). Per program family it records compile counts, actual
-  backend-compile seconds, the set of shape-signature keys seen, and a
-  one-time FLOPs/bytes cost estimate from ``lower().cost_analysis()``
-  (with a typed error fallback on backends without the API).
+  detects BUILDS via ``jax.monitoring``'s backend-compile events: the
+  executable was compiled, or loaded from the persistent cache (the
+  event is opened around ``compile_or_get_cached``, so it fires on a
+  cache hit too) — dispatch-cache churn (e.g. committed-ness changes)
+  does not count (falling back to first-call counting when the
+  monitoring API is unavailable). Per program family it records build
+  counts and backend seconds, beside them the cache loads among those
+  builds (``cache_loads`` / ``cache_load_seconds``), the set of
+  shape-signature keys seen, and a one-time FLOPs/bytes cost estimate
+  from ``lower().cost_analysis()`` (with a typed error fallback on
+  backends without the API).
+- **First calls** (``CompileRegistry.first_calls``): one record a
+  wrapper's first call, and one a later call in which something was
+  built: wall seconds split into Python trace, lowering, cache load and
+  compile from the same events, ``when`` it happened (``startup`` before
+  ``mark_ready()``, ``serving`` after) and whether the persistent cache
+  answered (``hit`` | ``miss`` | ``off``). A warm start is some 150 of
+  them; :func:`startup_status` is the ``/debug/perf`` ``startup`` body
+  (docs/OBSERVABILITY.md "Start-up").
 - **Unexpected-recompile detector** — the runtime twin of the
   ``jit-recompile-hazard`` lint rule: the SAME wrapper (one program
   instance, one shape signature) compiling again after ``mark_ready()``
@@ -69,7 +81,7 @@ import weakref
 
 import jax
 
-from dynamo_tpu.runtime import flight
+from dynamo_tpu.runtime import flight, tracing
 from dynamo_tpu.runtime.logging import (generate_span_id, generate_trace_id,
                                         get_logger)
 
@@ -77,6 +89,9 @@ log = get_logger("perf")
 
 #: EWMA smoothing for the per-window series (0.2 = ~5-window memory).
 _EWMA = 0.2
+#: First-call records kept (a start makes some 150; only a program that
+#: keeps rebuilding could reach this, and the family sums go on counting).
+_FIRST_CALLS_KEPT = 4096
 
 
 # -- the programs' regions --------------------------------------------------------
@@ -222,44 +237,100 @@ def _cost_mode() -> str:
     return os.environ.get("DTPU_PERF_COST", "lower").strip().lower()
 
 
+#: A first-call record's seconds, in the order the family sums keep them.
+FIRST_CALL_PARTS = ("wall_s", "trace_s", "lower_s", "cache_load_s",
+                    "compile_s")
+
+
 class _Program:
     """Plain-int per-program-family telemetry (engine-thread writers;
     snapshot readers tolerate torn reads — these are gauges/counters,
     not invariants)."""
 
     __slots__ = ("name", "compiles", "compile_seconds", "unexpected",
-                 "sigs", "cost", "last_compile_ts")
+                 "sigs", "cost", "last_compile_ts", "cache_loads",
+                 "cache_load_seconds", "cache_misses", "first_call_seconds")
 
     def __init__(self, name: str):
         self.name = name
-        self.compiles = 0
+        self.compiles = 0          # builds: compiled OR loaded from the cache
         self.compile_seconds = 0.0
         self.unexpected = 0
         self.sigs: dict = {}      # signature key -> compile count
         self.cost: dict | None = None  # one-time FLOPs/bytes estimate
         self.last_compile_ts = 0.0
+        self.cache_loads = 0       # of the builds, persistent-cache hits
+        self.cache_load_seconds = 0.0
+        self.cache_misses = 0      # asked of the cache and compiled
+        self.first_call_seconds = dict.fromkeys(FIRST_CALL_PARTS, 0.0)
 
 
-# -- compile detection probe ---------------------------------------------------
+# -- build detection probe -----------------------------------------------------
 # jax.monitoring fires ``/jax/core/compile/backend_compile_duration``
-# synchronously in the calling thread for every REAL XLA compile — the
-# only signal that separates compiles from dispatch-cache churn (the
+# synchronously in the calling thread for every BUILD of an executable:
+# pxla opens the event around ``compile_or_get_cached``, so it fires when
+# XLA compiled and when the persistent cache handed the executable over —
+# the only signal that separates builds from dispatch-cache churn (the
 # private ``_cache_size`` probe also grows on fast-path entries for
 # committed-ness changes, which produced false recompile alarms). The
-# listener feeds a thread-local accumulator the wrappers snapshot
-# around each call.
+# same thread receives the trace and lowering events and the cache's own
+# (requests, hits, retrieval seconds). The listeners keep ONE immutable
+# tuple a thread, ``_tls.totals``, replaced on every event: a wrapper reads
+# it before and after a call and knows by identity that nothing was built.
 
 _tls = threading.local()
 
+#: Indices into ``_tls.totals``. ``_N`` / ``_S`` count the backend event as
+#: before (``compiles`` / ``compile_seconds``); ``_TRACE``, ``_LOWER`` and
+#: ``_BACKEND`` are SELF seconds (an eager program built while another is
+#: traced is the inner one's; a nested trace is counted once), so they add
+#: up to no more than the wall time around them.
+_N, _S, _TRACE, _LOWER, _BACKEND, _LOAD, _ASKED, _HITS = range(8)
+_ZERO = (0, 0.0, 0.0, 0.0, 0.0, 0.0, 0, 0)
+#: Event names of the installed jax (jax/_src/dispatch.py, compiler.py). A
+#: jax without one of them leaves that part of a record 0.
+_SPAN_EVENTS = {"/jax/core/compile/jaxpr_trace_duration": _TRACE,
+                "/jax/core/compile/jaxpr_to_mlir_module_duration": _LOWER,
+                "/jax/core/compile/backend_compile_duration": _BACKEND}
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_ASKED_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+#: Ended spans a thread remembers, for the one that may still end around
+#: them: a program's trace holds a span a jitted helper it calls (hundreds),
+#: and each ended top-level span stays as one entry.
+_SPANS_KEPT = 8192
 
-def _probe() -> tuple[int, float]:
-    return (getattr(_tls, "n", 0), getattr(_tls, "s", 0.0))
+
+def _bump(index: int, amount) -> None:
+    totals = list(getattr(_tls, "totals", _ZERO))
+    totals[index] += amount
+    _tls.totals = tuple(totals)
 
 
 def _on_compile_event(event: str, duration: float, **_kw) -> None:
     if "backend_compile" in event:
-        _tls.n = getattr(_tls, "n", 0) + 1
-        _tls.s = getattr(_tls, "s", 0.0) + duration
+        _bump(_N, 1)
+        _bump(_S, duration)
+    elif event == _CACHE_LOAD_EVENT:
+        _bump(_LOAD, duration)
+
+
+def _on_compile_span(event: str, start: float, end: float, **_kw) -> None:
+    """Self seconds of a trace, a lowering or a backend build. The events
+    arrive as each ends, an inner one before the one around it: what the
+    spans inside this one already took stays theirs."""
+    kind = _SPAN_EVENTS.get(event)
+    if kind is None:
+        return
+    done = _tls.__dict__.setdefault("done", [])  # (start, seconds taken)
+    inside = 0.0
+    while done and done[-1][0] >= start:
+        inside += done.pop()[1]
+    own = max(0.0, (end - start) - inside)
+    if len(done) >= _SPANS_KEPT:    # the oldest enclose nothing to come
+        del done[:_SPANS_KEPT // 2]
+    done.append((start, inside + own))
+    _bump(kind, own)
 
 
 _PROBE_OK = False
@@ -269,6 +340,11 @@ try:  # pragma: no branch — registration is once at import
 except Exception:  # noqa: BLE001 — older jax: degrade to first-call counting
     log.info("jax.monitoring unavailable; compile observatory degrades "
              "to first-call counting")
+try:
+    jax.monitoring.register_event_time_span_listener(_on_compile_span)
+except Exception:  # noqa: BLE001 — a first-call record keeps wall_s alone
+    log.info("jax.monitoring has no time spans; first-call records carry "
+             "wall seconds alone")
 
 
 # -- persistent compile cache ---------------------------------------------------
@@ -284,8 +360,11 @@ _cache_events = {"hits": 0, "misses": 0}
 
 
 def _on_cache_event(event: str, **_kw) -> None:
-    if event == "/jax/compilation_cache/cache_hits":
+    if event == _CACHE_HIT_EVENT:
         _cache_events["hits"] += 1
+        _bump(_HITS, 1)
+    elif event == _CACHE_ASKED_EVENT:
+        _bump(_ASKED, 1)
     elif event == "/jax/compilation_cache/cache_misses":
         _cache_events["misses"] += 1
 
@@ -363,21 +442,61 @@ class _InstrumentedJit:
         self._scopes = None     # ops_by_scope(), built on demand
 
     def __call__(self, *args, **kwargs):
-        n0, s0 = _probe()
-        t0 = time.monotonic()
         if self._signature is None:
-            # Shapes, dtypes and shardings only: the arrays themselves are
-            # donated. Once per wrapper; ops_by_scope() lowers from it.
-            self._signature = jax.tree.map(_abstract, (args, kwargs))
+            return self._first_call(args, kwargs)
+        before = getattr(_tls, "totals", _ZERO)
+        t0 = time.monotonic()
         out = self._fn(*args, **kwargs)
-        dt = time.monotonic() - t0
         self._calls += 1
-        if _PROBE_OK:
-            n1, s1 = _probe()
-            compiled = n1 > n0
-            dt = s1 - s0  # actual backend-compile seconds, not wall time
+        if getattr(_tls, "totals", _ZERO) is not before:
+            # This thread traced, lowered or built something meanwhile.
+            self._note_built(before, t0, args, kwargs)
+        return out
+
+    def _first_call(self, args, kwargs):
+        """The call that traces, lowers and loads or compiles the program:
+        the unit a start is made of. After ``mark_ready`` (a bucket drawn
+        lazily) it is also an annotation on the profiler's host line, so
+        that an idle gap of the device names the program that caused it."""
+        before = getattr(_tls, "totals", _ZERO)
+        t0 = time.monotonic()
+        # Shapes, dtypes and shardings only: the arrays themselves are
+        # donated. Once per wrapper; ops_by_scope() lowers from it.
+        self._signature = jax.tree.map(_abstract, (args, kwargs))
+        if self._registry.warmup_complete:
+            with jax.profiler.TraceAnnotation(
+                    "program.first_call", program=self._program,
+                    key=repr(self._key)):
+                out = self._fn(*args, **kwargs)
         else:
-            compiled = self._calls == 1
+            out = self._fn(*args, **kwargs)
+        self._calls += 1
+        self._note_built(before, t0, args, kwargs)
+        return out
+
+    def _note_built(self, before: tuple, t0: float, args, kwargs) -> None:
+        """One first-call record, and the build counted as before."""
+        wall = time.monotonic() - t0
+        after = getattr(_tls, "totals", _ZERO)
+        asked = after[_ASKED] - before[_ASKED]
+        hits = after[_HITS] - before[_HITS]
+        load = after[_LOAD] - before[_LOAD]
+        registry = self._registry
+        registry.note_first_call({
+            "program": self._program, "key": self._key,
+            "labels": self._labels,
+            "when": "serving" if registry.warmup_complete else "startup",
+            "t_mono": t0, "wall_s": wall,
+            "trace_s": after[_TRACE] - before[_TRACE],
+            "lower_s": after[_LOWER] - before[_LOWER],
+            # Did the persistent cache hold what was built here? "off": it
+            # was not asked (switched off, or nothing was built).
+            "cache": ("off" if not asked else
+                      "hit" if hits == asked else "miss"),
+            "cache_load_s": load,
+            "compile_s": max(0.0, after[_BACKEND] - before[_BACKEND] - load),
+            "builds": after[_N] - before[_N]}, hits, asked - hits)
+        compiled = after[_N] > before[_N] if _PROBE_OK else self._calls == 1
         if compiled:
             # Unexpected = THIS wrapper (one program instance, one
             # shape signature) compiling again AFTER warmup declared
@@ -389,12 +508,15 @@ class _InstrumentedJit:
             # whose input shardings converge only after the first run
             # (e.g. the penalized window's counts under tp > 1).
             unexpected = (self._key is not None and self._compiles >= 1
-                          and self._registry.warmup_complete)
+                          and registry.warmup_complete)
             self._compiles += 1
-            self._registry.note_compile(self._program, self._key, dt,
-                                        unexpected=unexpected)
-            self._registry.maybe_cost(self._program, self._fn, args, kwargs)
-        return out
+            # The backend event's seconds (a cache load among them), not
+            # wall time.
+            registry.note_compile(
+                self._program, self._key,
+                after[_S] - before[_S] if _PROBE_OK else wall,
+                unexpected=unexpected)
+            registry.maybe_cost(self._program, self._fn, args, kwargs)
 
     def lower(self, *args, **kwargs):
         return self._fn.lower(*args, **kwargs)
@@ -425,6 +547,10 @@ class CompileRegistry:
         self._lock = threading.Lock()  # compile bookkeeping only (rare)
         self._programs: dict[str, _Program] = {}
         self._wrappers: dict[str, list] = {}  # program -> weakrefs
+        # One record a wrapper's first call and a later call that built
+        # (_InstrumentedJit._note_built): a plain list, as long as the
+        # process has programs, never a ring.
+        self.first_calls: list[dict] = []
         self.warmup_complete = False
         self.warmup_complete_ts = 0.0
         # Per-window series (single engine-thread writer, lock-free).
@@ -461,6 +587,29 @@ class CompileRegistry:
             return None
         return max(live, key=lambda w: w._calls).ops_by_scope()
 
+    def note_first_call(self, record: dict, cache_hits: int = 0,
+                        cache_misses: int = 0) -> None:
+        """Keep ``record`` and add it to its family's sums. One taken after
+        ``mark_ready`` is also a span ``program.first_call``: a
+        ``compiles_in_window`` other than 0 then names its program."""
+        with self._lock:
+            prog = self._programs.setdefault(record["program"],
+                                             _Program(record["program"]))
+            for part in FIRST_CALL_PARTS:
+                prog.first_call_seconds[part] += record[part]
+            prog.cache_loads += cache_hits
+            prog.cache_load_seconds += record["cache_load_s"]
+            prog.cache_misses += cache_misses
+            if len(self.first_calls) < _FIRST_CALLS_KEPT:
+                self.first_calls.append(record)
+        if record["when"] == "serving":
+            tracing.get_recorder().add(
+                "program.first_call", generate_trace_id(), None,
+                record["t_mono"], record["t_mono"] + record["wall_s"],
+                attrs={"program": record["program"],
+                       "key": repr(record["key"]), "cache": record["cache"],
+                       "wall_s": round(record["wall_s"], 4)})
+
     def note_compile(self, program: str, key, seconds: float,
                      unexpected: bool | None = None) -> None:
         """``key`` is the caller's shape-signature cache key. The
@@ -492,7 +641,6 @@ class CompileRegistry:
             "invalidated (dtype/weak-type drift, donation mismatch, or a "
             "shape leak); decode pays XLA time on the hot path", program,
             key, seconds)
-        from dynamo_tpu.runtime import tracing
         rec = tracing.get_recorder()
         if rec.enabled:
             now = time.monotonic()
@@ -534,9 +682,16 @@ class CompileRegistry:
     def mark_ready(self) -> None:
         """Warmup boundary: compiles recorded after this are post-warmup
         (the pane surfaces the flag; the recompile detector itself is
-        per-signature and needs no boundary)."""
+        per-signature and needs no boundary), and first calls read
+        ``when: "serving"``."""
         self.warmup_complete = True
         self.warmup_complete_ts = time.time()
+
+    def mark_starting(self) -> None:
+        """The launcher is about to build an engine: what is built from
+        here to the next ``mark_ready`` is a start's, also in a process
+        that served before."""
+        self.warmup_complete = False
 
     # -- roofline-attributed window timing ------------------------------------
     def note_window(self, window_s: float, tokens: int, active: int,
@@ -588,6 +743,8 @@ class CompileRegistry:
                 name: {
                     "compiles": p.compiles,
                     "compile_seconds": round(p.compile_seconds, 4),
+                    "cache_loads": p.cache_loads,
+                    "cache_load_seconds": round(p.cache_load_seconds, 4),
                     "signatures": len(p.sigs),
                     "unexpected_recompiles": p.unexpected,
                     "cost": p.cost,
@@ -601,6 +758,8 @@ class CompileRegistry:
             "compiles_total": sum(v["compiles"] for v in programs.values()),
             "compile_seconds_total": round(
                 sum(v["compile_seconds"] for v in programs.values()), 4),
+            "cache_loads_total": sum(v["cache_loads"]
+                                     for v in programs.values()),
             "unexpected_recompiles_total": sum(
                 v["unexpected_recompiles"] for v in programs.values()),
             "warmup_complete": self.warmup_complete,
@@ -640,6 +799,7 @@ class CompileRegistry:
         with self._lock:
             self._programs.clear()
             self._wrappers.clear()
+            self.first_calls.clear()
         self.warmup_complete = False
         self.warmup_complete_ts = 0.0
         self.windows_total = 0
@@ -673,13 +833,73 @@ def instrumented_jit(program: str, fun, *, key=None, registry=None,
                     labels=labels)
 
 
+def first_calls_by_family(records: list[dict]) -> dict:
+    """``records`` summed: in all and a family (``programs``, the five
+    seconds of FIRST_CALL_PARTS, ``hits``, ``misses``), with the ten
+    longest by ``wall_s``."""
+    def summed(rows: list[dict]) -> dict:
+        return {"programs": len(rows),
+                **{part: round(sum(r[part] for r in rows), 4)
+                   for part in FIRST_CALL_PARTS},
+                "hits": sum(r["cache"] == "hit" for r in rows),
+                "misses": sum(r["cache"] == "miss" for r in rows)}
+
+    families: dict[str, list] = {}
+    for r in records:
+        families.setdefault(r["program"], []).append(r)
+    longest = sorted(records, key=lambda r: r["wall_s"], reverse=True)[:10]
+    return {**summed(records),
+            "families": {name: summed(rows)
+                         for name, rows in sorted(families.items())},
+            "longest": [{**r, "key": repr(r["key"]),
+                         **{part: round(r[part], 4)
+                            for part in FIRST_CALL_PARTS}}
+                        for r in longest]}
+
+
+def startup_status() -> dict:
+    """The /debug/perf ``startup`` body: the newest start's stages
+    (runtime/tracing.py ``Startup.summary``; none in a process no launcher
+    started) and the first calls made since it began and before
+    ``mark_ready``, a family; beside them the programs first called while
+    serving."""
+    start = tracing.last_startup()
+    reg = get_registry()
+    with reg._lock:
+        records = list(reg.first_calls)
+    body = {"status": None, "stages": []}
+    if start is not None:   # this start's calls, not an earlier engine's
+        body = start.summary()
+        records = [r for r in records
+                   if r["t_mono"] >= start.root.start_mono]
+    body["first_calls"] = first_calls_by_family(
+        [r for r in records if r["when"] == "startup"])
+    body["first_calls_serving"] = first_calls_by_family(
+        [r for r in records if r["when"] == "serving"])
+    return body
+
+
+def describe_first_calls() -> str:
+    """What was first called before ``mark_ready``, in a log line's words:
+    "131 programs: trace 9.8, lower 6.1, cache 11.2 (131 hits), compile
+    0.0"."""
+    calls = startup_status()["first_calls"]
+    return ("%d programs: trace %.1f, lower %.1f, cache %.1f (%d hits), "
+            "compile %.1f%s" % (
+                calls["programs"], calls["trace_s"], calls["lower_s"],
+                calls["cache_load_s"], calls["hits"], calls["compile_s"],
+                " (%d MISSED the cache)" % calls["misses"]
+                if calls["misses"] else ""))
+
+
 def process_perf_status() -> dict:
     """Fallback /debug/perf body for a process without an engine (a
     frontend in proxy mode, a bare status server): the compile
     observatory is process-global, so it still answers."""
     reg = get_registry()
     return {"role": "process", "compiles": reg.snapshot(),
-            "window": reg.window_snapshot(), "hbm": {}, "memory": {}}
+            "window": reg.window_snapshot(), "startup": startup_status(),
+            "hbm": {}, "memory": {}}
 
 
 class PerfMetricsUpdater:
@@ -695,17 +915,39 @@ class PerfMetricsUpdater:
         self._next = 0.0
         self._last: dict[tuple, float] = {}
         self.c_compiles = registry.counter(
-            "perf_compiles_total", "XLA compiles per jit program family",
-            ["program"])
+            "perf_compiles_total", "Builds per jit program family: XLA "
+            "compiles AND loads from the persistent compile cache (the "
+            "backend event fires for both; perf_cache_loads_total tells "
+            "them apart)", ["program"])
         self.c_compile_seconds = registry.counter(
-            "perf_compile_seconds_total", "Wall-clock seconds spent in XLA "
-            "compiles per jit program family", ["program"])
+            "perf_compile_seconds_total", "Seconds of the backend event "
+            "per jit program family: compiling, or on a cache hit "
+            "retrieving the executable", ["program"])
         self.c_unexpected = registry.counter(
             "perf_unexpected_recompiles_total", "Compiles of an "
             "already-seen (program, signature) after first use — the "
             "runtime twin of the jit-recompile-hazard lint rule; any "
             "nonzero rate in steady state is a serving-path bug",
             ["program"])
+        self.c_cache_loads = registry.counter(
+            "perf_cache_loads_total", "Builds that asked the persistent "
+            "compile cache, per jit program family: result hit (the "
+            "executable was loaded; perf_compiles_total counts it too) or "
+            "miss (XLA compiled and the entry was written); a warm start "
+            "has no miss", ["program", "result"])
+        self.c_first_call = registry.counter(
+            "perf_first_call_seconds_total", "Seconds of the calls that "
+            "built a program (a wrapper's first call, or a later one that "
+            "rebuilt), per jit program family and part: wall, and inside "
+            "it trace (Python), lower (to MLIR), cache_load and compile",
+            ["program", "part"])
+        self.g_startup = registry.gauge(
+            "startup_seconds", "Seconds of the newest start by stage "
+            "(runtime/tracing.py Startup: the span of that name under the "
+            "root startup), set once at ready; stage startup is launcher "
+            "entry to ready, unattributed what its stages leave uncovered",
+            ["stage"])
+        self._startup_told = None   # the Startup whose stages are exported
         self.g_step_seconds = registry.gauge(
             "perf_step_seconds", "EWMA seconds per decode step (a "
             "window's period while the pipe is full, else dispatch to "
@@ -891,14 +1133,33 @@ class PerfMetricsUpdater:
         self._next = now + self.min_interval_s
         reg = get_registry()
         with reg._lock:
-            per_prog = [(p.name, p.compiles, p.compile_seconds, p.unexpected)
+            per_prog = [(p.name, p.compiles, p.compile_seconds, p.unexpected,
+                         p.cache_loads, p.cache_misses,
+                         dict(p.first_call_seconds))
                         for p in reg._programs.values()]
-        for name, compiles, seconds, unexpected in per_prog:
+        for (name, compiles, seconds, unexpected, loads, misses,
+             first) in per_prog:
             self._delta(self.c_compiles, ("c", name), compiles, program=name)
             self._delta(self.c_compile_seconds, ("s", name), seconds,
                         program=name)
             self._delta(self.c_unexpected, ("u", name), unexpected,
                         program=name)
+            self._delta(self.c_cache_loads, ("ch", name), loads,
+                        program=name, result="hit")
+            self._delta(self.c_cache_loads, ("cm", name), misses,
+                        program=name, result="miss")
+            for part, value in first.items():
+                self._delta(self.c_first_call, ("fc", name, part), value,
+                            program=name, part=part.removesuffix("_s"))
+        start = tracing.last_startup()
+        if (start is not None and not start.open
+                and start is not self._startup_told):
+            self._startup_told = start
+            told = start.summary()
+            for stage in told["stages"]:
+                self.g_startup.set(stage["seconds"], stage=stage["name"])
+            self.g_startup.set(told["ready_s"], stage="startup")
+            self.g_startup.set(told["unattributed_s"], stage="unattributed")
         clock = getattr(engine, "phase_clock", None)
         if clock is not None:
             for name, seconds in clock.totals().items():

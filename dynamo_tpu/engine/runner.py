@@ -54,6 +54,7 @@ from dynamo_tpu.engine.model import (
 )
 from dynamo_tpu.engine.sampler import sample_tokens, sample_tokens_per_row
 from dynamo_tpu.runtime.logging import get_logger
+from dynamo_tpu.runtime.tracing import startup_stage
 
 log = get_logger("runner")
 
@@ -222,6 +223,28 @@ class ModelRunner:
                 f"sp={config.sp}: every prefill bucket "
                 f"({config.prefill_buckets}) must be divisible by sp")
         self.spec = spec
+        with startup_stage("startup.mesh"):
+            self._place(config, spec, devices)
+        with startup_stage("startup.pool_sizing") as stage:
+            self._sized_pages(self.device)
+            stage.set(num_pages=self.num_pages)
+        if spec.loop_passes > 1:
+            log.info("looped stack: %d passes over %d layers, %d pool "
+                     "layers, %d B of K and V a token", spec.loop_passes,
+                     spec.num_layers, spec.pool_layers,
+                     config.kv_token_bytes())
+        with startup_stage("startup.weights",
+                           source="random" if params is None else "given"
+                           ) as stage:
+            self._load_params(spec, params, seed)
+            stage.set(bytes=sum(
+                leaf.nbytes for leaf in jax.tree.leaves(self.params)))
+        with startup_stage("startup.pool_alloc") as stage:
+            self._allocate(config, spec, seed)
+            stage.set(bytes=self.kv_pool_bytes + self.ssm_state_bytes)
+
+    def _place(self, config: EngineConfig, spec, devices) -> None:
+        """The compile cache, the mesh and who runs what."""
         # Every entry point builds a runner before its first compile, so
         # this is the one place the persistent compile cache is set up.
         perf.configure_compile_cache()
@@ -252,14 +275,9 @@ class ModelRunner:
         # each call.
         self.moe_grouped_pairs = 0
         self.page_size = config.page_size
-        self._sized_pages(self.device)
-        if spec.loop_passes > 1:
-            log.info("looped stack: %d passes over %d layers, %d pool "
-                     "layers, %d B of K and V a token", spec.loop_passes,
-                     spec.num_layers, spec.pool_layers,
-                     config.kv_token_bytes())
 
-        # Shard or init parameters.
+    def _load_params(self, spec, params, seed: int) -> None:
+        """Shard the parameters handed over, or init them."""
         pspecs = param_specs(spec)
         shardings = jax.tree.map(
             lambda s: NamedSharding(self.mesh, s), pspecs,
@@ -289,6 +307,9 @@ class ModelRunner:
             params = quantize_params(params)
         self.params = jax.tree.map(_mh_put, params, shardings)
 
+    def _allocate(self, config: EngineConfig, spec, seed: int) -> None:
+        """The pool, the state beside it and the small arrays that chain on
+        the device between programs."""
         # KV cache arrays [L, Nkv, P, page, D]: layers sharded over pp
         # (pages live with their layer's stage), kv heads over tp, and
         # [page, D] contiguous per (head, page) for clean Pallas DMAs.

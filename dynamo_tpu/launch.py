@@ -26,10 +26,12 @@ from dynamo_tpu.llm.model_card import (DEFAULT_CHAT_TEMPLATE,
 from dynamo_tpu.llm.preprocessor import OpenAIPreprocessor
 from dynamo_tpu.llm.protocols import ChatCompletionRequest
 from dynamo_tpu.llm.tokenizer import Tokenizer, make_test_tokenizer
+from dynamo_tpu.runtime import tracing
 from dynamo_tpu.runtime.config import RuntimeConfig
 from dynamo_tpu.runtime.context import Context
 from dynamo_tpu.runtime.distributed import DistributedRuntime
 from dynamo_tpu.runtime.logging import get_logger
+from dynamo_tpu.runtime.tracing import startup_stage
 
 log = get_logger("launch")
 
@@ -156,24 +158,40 @@ def _build_engine(args, metrics_registry=None):
         eng = MockerEngine(MockerConfig(speedup_ratio=10.0))
         eng.start()
         return eng, make_test_tokenizer()
-    # out=tpu: the real engine, in-process.
-    from dynamo_tpu.backends.tpu import build_engine_config
-    from dynamo_tpu.engine.engine import TPUEngine
-    from dynamo_tpu.engine.weights import load_hf_weights
-    cfg = build_engine_config(args)
+    # out=tpu: the real engine, in-process. Each step is a stage of the
+    # start-up trace (runtime/tracing.py Startup; a no-op for a caller that
+    # opened none); the runner's and the engine thread's stages lie under
+    # startup.engine and beside it.
+    with startup_stage("startup.config"):
+        from dynamo_tpu.backends.tpu import build_engine_config
+        from dynamo_tpu.engine import perf
+        from dynamo_tpu.engine.engine import TPUEngine
+        from dynamo_tpu.engine.weights import load_hf_weights
+        # What is built from here to the engine's mark_ready is this
+        # start's, also in a process that served before.
+        perf.get_registry().mark_starting()
+        cfg = build_engine_config(args)
     ckpt = args.resolved_checkpoint
     params = None
     if ckpt is not None:
-        params = load_hf_weights(cfg.model, ckpt)
-        tokenizer = Tokenizer.from_pretrained_dir(ckpt)
-    elif args.tokenizer:
-        tokenizer = Tokenizer.from_file(args.tokenizer)
-    else:
-        tokenizer = make_test_tokenizer()
-    engine = TPUEngine(cfg, params=params,
-                       metrics_registry=metrics_registry)
+        with startup_stage("startup.checkpoint", source="checkpoint",
+                           path=str(ckpt)):
+            params = load_hf_weights(cfg.model, ckpt)
+    with startup_stage("startup.tokenizer"):
+        if ckpt is not None:
+            tokenizer = Tokenizer.from_pretrained_dir(ckpt)
+        elif args.tokenizer:
+            tokenizer = Tokenizer.from_file(args.tokenizer)
+        else:
+            tokenizer = make_test_tokenizer()
+    with startup_stage("startup.engine"):
+        engine = TPUEngine(cfg, params=params,
+                           metrics_registry=metrics_registry)
     engine.start()
-    engine.wait_ready()  # a warm-up or compile failure fails the launch
+    # The warm-up runs on the engine thread (startup.warmup); this thread
+    # waits for it, and for the device to finish the warm-up's runs.
+    with startup_stage("startup.wait_ready"):
+        engine.wait_ready()  # a warm-up or compile failure fails the launch
     return engine, tokenizer
 
 
@@ -188,6 +206,12 @@ def build_local_served(args, metrics_registry=None
     if getattr(args, "lora", None) and args.output != "tpu":
         raise SystemExit("--lora needs the real engine (out=tpu)")
     engine, tokenizer = _build_engine(args, metrics_registry)
+    with startup_stage("startup.model_card"):
+        return _local_served(args, engine, tokenizer), engine
+
+
+def _local_served(args, engine, tokenizer) -> ServedModel:
+    """The card, the preprocessor and the adapters' models over an engine."""
     name = args.model_name or os.path.basename(args.model.rstrip("/"))
     card = ModelDeploymentCard(
         name=name, chat_template=DEFAULT_CHAT_TEMPLATE,
@@ -219,7 +243,7 @@ def build_local_served(args, metrics_registry=None
         apre = OpenAIPreprocessor(acard, tokenizer, inner=backend)
         served.adapter_served.append(
             ServedModel(aentry, apre, client=None, router=None))
-    return served, engine
+    return served
 
 
 async def run_text_repl(served: ServedModel) -> None:
@@ -330,70 +354,88 @@ async def run(args, ready=None) -> None:
     """Assemble and serve until shutdown. ``ready(runtime, service,
     engine)`` is called once the HTTP service listens (an embedding
     caller — chip_smoke.py, a test — gets the bound port and the handle
-    to ``runtime.shutdown()`` without scraping stdout)."""
-    if args.output == "dyn":
-        cfg = RuntimeConfig.from_settings()
-        if args.coordinator_url:
-            cfg.coordinator_url = args.coordinator_url
-        runtime = await DistributedRuntime.from_settings(cfg)
-        manager = ModelManager()
-        watcher = ModelWatcher(runtime, manager)
-        await watcher.start()
-        engine = None
-    else:
-        runtime = await DistributedRuntime.detached(RuntimeConfig())
-        manager = ModelManager()
-        served, engine = build_local_served(
-            args, runtime.metrics.namespace("local").component(args.output))
-        manager.models[served.name] = served
-        for extra in getattr(served, "adapter_served", []):
-            manager.models[extra.name] = extra
-        watcher = None
-    # SLO plane + accounting ledger + flight-bundle context: the static
-    # pipeline gets the same decision-grade observability the
-    # distributed frontend does (DTPU_SLO_* / [slo] TOML configurable).
-    from dynamo_tpu.frontend.main import init_observability
-    if args.slo_ttft_p99_ms is not None:
-        runtime.config.slo.ttft_p99_ms = args.slo_ttft_p99_ms
-    if args.request_log is not None:
-        runtime.config.slo.request_log_path = args.request_log
-    init_observability(runtime.config, runtime)
+    to ``runtime.shutdown()`` without scraping stdout).
+
+    The start is ONE trace (runtime/tracing.py ``Startup``): the root
+    ``startup`` from here to the instant the engine is ready and the
+    service listens, a stage where each step's work happens; a start that
+    raises closes the root with ``status="error"`` and the stage."""
+    start = tracing.begin_startup()
+    runtime = engine = watcher = None
     try:
+        with start.stage("startup.runtime"):
+            if args.output == "dyn":
+                cfg = RuntimeConfig.from_settings()
+                if args.coordinator_url:
+                    cfg.coordinator_url = args.coordinator_url
+                runtime = await DistributedRuntime.from_settings(cfg)
+                manager = ModelManager()
+                watcher = ModelWatcher(runtime, manager)
+                await watcher.start()
+            else:
+                runtime = await DistributedRuntime.detached(RuntimeConfig())
+                manager = ModelManager()
+        if args.output != "dyn":
+            served, engine = build_local_served(
+                args,
+                runtime.metrics.namespace("local").component(args.output))
+            manager.models[served.name] = served
+            for extra in getattr(served, "adapter_served", []):
+                manager.models[extra.name] = extra
+        # SLO plane + accounting ledger + flight-bundle context: the static
+        # pipeline gets the same decision-grade observability the
+        # distributed frontend does (DTPU_SLO_* / [slo] TOML configurable).
+        with start.stage("startup.observability"):
+            from dynamo_tpu.frontend.main import init_observability
+            if args.slo_ttft_p99_ms is not None:
+                runtime.config.slo.ttft_p99_ms = args.slo_ttft_p99_ms
+            if args.request_log is not None:
+                runtime.config.slo.request_log_path = args.request_log
+            init_observability(runtime.config, runtime)
         if args.input in ("text", "batch"):
             if args.output == "dyn":
                 raise SystemExit(f"in={args.input} requires a local out= "
                                  "engine")
+            _started(start, args, engine)
             if args.input == "text":
                 await run_text_repl(served)
             else:
                 await run_batch(served, args)
             return
         if args.input == "grpc":
-            from dynamo_tpu.grpc.kserve import make_server
-            server, port = make_server(manager, host=args.http_host,
-                                       port=args.http_port)
-            await server.start()
+            with start.stage("startup.grpc"):
+                from dynamo_tpu.grpc.kserve import make_server
+                server, port = make_server(manager, host=args.http_host,
+                                           port=args.http_port)
+                await server.start()
+            _started(start, args, engine)
             print(f"LAUNCH_READY in=grpc out={args.output} port={port}",
                   flush=True)
             await runtime.wait_for_shutdown()
             await server.stop(grace=1.0)
             return
-        # Overload defense (runtime/overload.py): same adaptive
-        # admission the distributed frontend gets, DTPU_OVERLOAD_*
-        # configurable (DTPU_OVERLOAD_ENABLED=0 disables).
-        from dynamo_tpu.runtime.overload import AdaptiveLimiter
-        ov = runtime.config.overload
-        limiter = (AdaptiveLimiter(ov, metrics=runtime.metrics)
-                   if ov.enabled else None)
-        service = HttpService(runtime, manager, host=args.http_host,
-                              port=args.http_port, overload=limiter)
-        await service.start()
+        with start.stage("startup.http"):
+            # Overload defense (runtime/overload.py): same adaptive
+            # admission the distributed frontend gets, DTPU_OVERLOAD_*
+            # configurable (DTPU_OVERLOAD_ENABLED=0 disables).
+            from dynamo_tpu.runtime.overload import AdaptiveLimiter
+            ov = runtime.config.overload
+            limiter = (AdaptiveLimiter(ov, metrics=runtime.metrics)
+                       if ov.enabled else None)
+            service = HttpService(runtime, manager, host=args.http_host,
+                                  port=args.http_port, overload=limiter)
+            await service.start()
+        _started(start, args, engine)
         print(f"LAUNCH_READY in={args.input} out={args.output} "
               f"port={service.port}", flush=True)
         if ready is not None:
             ready(runtime, service, engine)
         await runtime.wait_for_shutdown()
         await service.stop()
+    except BaseException as exc:
+        if start.open:      # raised before ready: the start failed
+            _started(start, args, None, error=exc)
+        raise
     finally:
         if watcher is not None:
             await watcher.stop()
@@ -403,7 +445,23 @@ async def run(args, ready=None) -> None:
                 res = stop()
                 if asyncio.iscoroutine(res):
                     await res
-        await runtime.close()
+        if runtime is not None:
+            await runtime.close()
+
+
+def _started(start: tracing.Startup, args, engine, error=None) -> None:
+    """Ready (or failed): close the root, say where the start's seconds
+    went in ONE line, and put the stages on /metrics."""
+    start.finish(error)
+    programs = ""
+    if args.output == "tpu":    # the compile registry's first calls
+        from dynamo_tpu.engine import perf
+        programs = perf.describe_first_calls()
+    (log.info if error is None else log.error)(
+        "%s", start.ready_line(programs))
+    updater = getattr(engine, "perf_metrics", None)
+    if updater is not None:
+        updater.update(engine, force=True)
 
 
 def main() -> None:

@@ -25,6 +25,12 @@ is answerable without a debugger. Pieces:
   profiler's clock, beside the device planes, when a profiler session
   runs; nothing otherwise) and self time in a preallocated accumulator.
   No ``Span``, no lock, not the span ring.
+- ``Startup`` — one start of a worker as ONE trace: a root span
+  ``startup`` from the launcher's entry to ready, a child where each
+  stage's work happens (``startup_stage(name)``: on whichever thread, the
+  stage open on that thread is the parent, else the root), kept by the
+  object as well as recorded in the ring, so ``/debug/perf`` still has a
+  start's stages after the ring turned over.
 - ``capture_profile(...)`` — the on-demand ``jax.profiler`` hook behind
   ``POST /debug/profile``, degrading to a span-recorder dump when JAX
   profiling is unavailable; its reply carries the engine phases' seconds
@@ -399,6 +405,190 @@ class span:
 
     async def __aexit__(self, exc_type, exc, tb) -> bool:
         return self.__exit__(exc_type, exc, tb)
+
+
+# -- a worker's start as one trace ------------------------------------------------
+
+class _Stage:
+    """One stage of a start: a span under the stage open on this thread
+    (else under the root), recorded as it ends."""
+
+    __slots__ = ("_start", "_span")
+
+    def __init__(self, start: "Startup", name: str, attrs: dict):
+        self._start = start
+        self._span = Span(start.trace_id, generate_span_id(), None, name,
+                          0.0, 0.0, attrs or None)
+
+    def set(self, **attrs) -> None:
+        if self._span.attrs is None:
+            self._span.attrs = {}
+        self._span.attrs.update(attrs)
+
+    def __enter__(self) -> "_Stage":
+        s, start = self._span, self._start
+        open_here = start._open_on_this_thread()
+        s.parent_span_id = (open_here[-1].span_id if open_here
+                            else start.span_id)
+        s.thread_id = threading.get_ident()
+        s.start_wall, s.start_mono = time.time(), time.monotonic()
+        open_here.append(s)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        s, start = self._span, self._start
+        s.end_mono = time.monotonic()
+        start._open_on_this_thread().pop()
+        if exc_type is not None:
+            s.status = "error"
+            self.set(error=f"{exc_type.__name__}: {exc}")
+            if start.failed_stage is None:   # the innermost raises first
+                start.failed_stage = s.name
+        start._keep(s)
+        return False
+
+
+class Startup:
+    """One start of a worker, from the launcher's entry to the instant the
+    engine is ready and the service listens: the root span ``startup`` and
+    the stages under it, on ``time.monotonic()``. A stage's self time is
+    its span less what its children cover; what the root's direct children
+    leave uncovered is ``unattributed_s``. The spans go to the recorder
+    (``/debug/traces?trace_id=``) and stay here (``/debug/perf``)."""
+
+    def __init__(self, recorder: SpanRecorder | None = None):
+        self.recorder = recorder if recorder is not None else _RECORDER
+        self.trace_id = generate_trace_id()
+        self.span_id = generate_span_id()
+        self.root = Span(self.trace_id, self.span_id, None, "startup",
+                         time.time(), time.monotonic())
+        self.spans: list[Span] = []     # ended stages, then the root
+        self.failed_stage: str | None = None
+        self._lock = threading.Lock()
+        self._local = threading.local()
+
+    @property
+    def open(self) -> bool:
+        return self.root.end_mono is None
+
+    def _open_on_this_thread(self) -> list:
+        return self._local.__dict__.setdefault("stages", [])
+
+    def _keep(self, span: Span) -> None:
+        with self._lock:
+            self.spans.append(span)
+        self.recorder.record(span)
+
+    def stage(self, name: str, **attrs):
+        """``with start.stage("startup.weights", source="given") as st:``
+        (``st.set(bytes=n)``); a no-op once the start has ended."""
+        return _Stage(self, name, attrs) if self.open else NULL_SPAN
+
+    def finish(self, error: BaseException | None = None) -> None:
+        """Close the root: ready, or failed with the stage that raised."""
+        if not self.open:
+            return
+        root = self.root
+        root.end_mono = time.monotonic()
+        if error is not None:
+            root.status = "error"
+            root.attrs = {"error": f"{type(error).__name__}: {error}",
+                          "failed_stage": self.failed_stage}
+        self._keep(root)
+
+    def summary(self) -> dict:
+        """The stages in the order they began, each with its seconds and
+        its self seconds, ``ready_s`` and ``unattributed_s``."""
+        with self._lock:
+            spans = list(self.spans)
+        root = self.root
+        end = root.end_mono if root.end_mono is not None else time.monotonic()
+        stages = sorted((s for s in spans if s is not root),
+                        key=lambda s: s.start_mono)
+        names = {s.span_id: s.name for s in stages}
+        by_parent: dict = {}
+        for s in stages:
+            by_parent.setdefault(s.parent_span_id, []).append(
+                (s.start_mono, s.end_mono))
+        rows = [{"name": s.name,
+                 "parent": names.get(s.parent_span_id, "startup"),
+                 "at_s": round(s.start_mono - root.start_mono, 4),
+                 "seconds": round(s.duration_s, 4),
+                 "self_s": round(s.duration_s - covered_seconds(
+                     by_parent.get(s.span_id, ())), 4),
+                 **({"status": s.status} if s.status != "ok" else {}),
+                 **({"attrs": s.attrs} if s.attrs else {})}
+                for s in stages]
+        ready = end - root.start_mono
+        return {"trace_id": self.trace_id,
+                "status": "starting" if self.open else root.status,
+                "failed_stage": self.failed_stage,
+                "t_start_mono": root.start_mono,
+                "ready_s": round(ready, 4),
+                "unattributed_s": round(ready - covered_seconds(
+                    by_parent.get(root.span_id, ())), 4),
+                "stages": rows}
+
+
+    def ready_line(self, programs: str = "") -> str:
+        """The ONE log line of a start: the root's stages in order, the
+        stages under each in brackets (``programs``, what the compile
+        registry says of the first calls, after the warm-up's), and what
+        no stage covers."""
+        told = self.summary()
+        under: dict[str, list] = {}
+        for stage in told["stages"]:
+            under.setdefault(stage["parent"], []).append(stage)
+
+        def said(stage: dict) -> str:
+            name = stage["name"].removeprefix("startup.")
+            source = (stage.get("attrs") or {}).get("source")
+            inner = [said(child) for child in under.get(stage["name"], ())]
+            if name == "warmup" and programs:
+                inner.append(programs)
+            notes = [n for n in (source, ", ".join(inner)) if n]
+            return "%s %.1f%s" % (name, stage["seconds"],
+                                  " (%s)" % "; ".join(notes) if notes else "")
+
+        return "%s in %.1f s: %s, unattributed %.1f" % (
+            "start-up FAILED at %s" % self.failed_stage
+            if told["status"] == "error" else "ready", told["ready_s"],
+            ", ".join(said(stage) for stage in under.get("startup", ())),
+            told["unattributed_s"])
+
+
+def covered_seconds(intervals) -> float:
+    """Length of the union of (start, end) intervals."""
+    total, reach = 0.0, None
+    for lo, hi in sorted(intervals):
+        if reach is None or lo > reach:
+            total += hi - lo
+            reach = hi
+        elif hi > reach:
+            total += hi - reach
+            reach = hi
+    return total
+
+
+#: The process's newest start (launch.run makes one at its entry).
+_STARTUP: Startup | None = None
+
+
+def begin_startup() -> Startup:
+    global _STARTUP
+    _STARTUP = Startup()
+    return _STARTUP
+
+
+def last_startup() -> Startup | None:
+    return _STARTUP
+
+
+def startup_stage(name: str, **attrs):
+    """A stage of the start that is under way, wherever its work happens
+    (runner, engine thread); a no-op where no launcher opened one."""
+    start = _STARTUP
+    return start.stage(name, **attrs) if start is not None else NULL_SPAN
 
 
 # -- the engine thread's phases -------------------------------------------------

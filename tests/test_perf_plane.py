@@ -63,7 +63,8 @@ def test_registry_warmup_marker_and_reset():
     reg.reset()
     assert reg.snapshot() == {
         "programs": {}, "compiles_total": 0, "compile_seconds_total": 0,
-        "unexpected_recompiles_total": 0, "warmup_complete": False}
+        "cache_loads_total": 0, "unexpected_recompiles_total": 0,
+        "warmup_complete": False}
 
 
 def test_instrumented_jit_real_compile_detection():
