@@ -73,6 +73,7 @@ program family) | ``off``.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import re
 import threading
@@ -81,6 +82,7 @@ import weakref
 
 import jax
 
+from dynamo_tpu.engine import program_store
 from dynamo_tpu.runtime import flight, tracing
 from dynamo_tpu.runtime.logging import (generate_span_id, generate_trace_id,
                                         get_logger)
@@ -240,6 +242,19 @@ def _cost_mode() -> str:
 #: A first-call record's seconds, in the order the family sums keep them.
 FIRST_CALL_PARTS = ("wall_s", "trace_s", "lower_s", "cache_load_s",
                     "compile_s")
+#: What the program store (engine/program_store.py) can answer a wrapper: the
+#: executable was loaded (``hit``); there was none, it was built and written
+#: (``miss``); there was one and it could not be used: it went, and the
+#: program was built and written as on a miss (``reject``); a LATER call
+#: brought arguments the loaded executable does not take and the wrapper went
+#: back to its jit for good (``fallback``).
+STORE_COUNTERS = {"hit": "store_hits", "miss": "store_misses",
+                  "reject": "store_rejects", "fallback": "store_fallbacks"}
+STORE_RESULTS = tuple(STORE_COUNTERS)
+#: Arguments of ``jax.jit`` under which a wrapper has no store: a static
+#: argument is part of the program and no part of a ``Compiled``'s call. The
+#: others (the donation, shardings) are in the key by their ``repr``.
+_UNSTORABLE_JIT_KWARGS = frozenset({"static_argnums", "static_argnames"})
 
 
 class _Program:
@@ -249,7 +264,8 @@ class _Program:
 
     __slots__ = ("name", "compiles", "compile_seconds", "unexpected",
                  "sigs", "cost", "last_compile_ts", "cache_loads",
-                 "cache_load_seconds", "cache_misses", "first_call_seconds")
+                 "cache_load_seconds", "cache_misses", "first_call_seconds",
+                 "store", "store_reject_reason")
 
     def __init__(self, name: str):
         self.name = name
@@ -263,6 +279,10 @@ class _Program:
         self.cache_load_seconds = 0.0
         self.cache_misses = 0      # asked of the cache and compiled
         self.first_call_seconds = dict.fromkeys(FIRST_CALL_PARTS, 0.0)
+        # What the program store answered (STORE_RESULTS), and why it last
+        # threw an entry away.
+        self.store = dict.fromkeys(STORE_RESULTS, 0)
+        self.store_reject_reason: str | None = None
 
 
 # -- build detection probe -----------------------------------------------------
@@ -421,15 +441,22 @@ class _InstrumentedJit:
     """Transparent wrapper around one jitted callable: forwards calls,
     counts compiles, triggers one-time cost analysis. One wrapper per
     (program, signature key) — the runner's shape-bucket caches store
-    these in place of the raw jitted function."""
+    these in place of the raw jitted function. A wrapper whose builder
+    gave it a store context (:func:`program_context`) takes its
+    executable from the program store at its first call, or builds it and
+    writes it there (engine/program_store.py)."""
 
-    __slots__ = ("_fn", "_registry", "_program", "_key", "_calls",
+    __slots__ = ("_fn", "_run", "_registry", "_program", "_key", "_calls",
                  "_compiles", "_signature", "_scopes", "_labels",
-                 "__weakref__")
+                 "_context", "_jit_kwargs", "__weakref__")
 
     def __init__(self, registry: "CompileRegistry", program: str,
-                 fn, key, labels: dict | None = None):
+                 fn, key, labels: dict | None = None, context=None,
+                 jit_kwargs: dict | None = None):
         self._fn = fn
+        # What a call runs: the jit, or the executable the store loaded or
+        # the store's miss path built (a ``jax.stages.Compiled``).
+        self._run = fn
         # What the builder chose statically for this program (the window
         # program's kv_commit_backend: Backends.labels): a name, not a count.
         self._labels = dict(labels or {})
@@ -440,47 +467,157 @@ class _InstrumentedJit:
         self._compiles = 0
         self._signature = None  # the first call's arguments, as shapes
         self._scopes = None     # ops_by_scope(), built on demand
+        self._jit_kwargs = dict(jit_kwargs or {})
+        self._context = (None if _UNSTORABLE_JIT_KWARGS & set(self._jit_kwargs)
+                         else context)
 
     def __call__(self, *args, **kwargs):
         if self._signature is None:
             return self._first_call(args, kwargs)
         before = getattr(_tls, "totals", _ZERO)
         t0 = time.monotonic()
-        out = self._fn(*args, **kwargs)
+        store = None
+        try:
+            out = self._run(*args, **kwargs)
+        except (TypeError, ValueError) as exc:
+            # A stored executable checks its arguments before it runs or
+            # donates anything (other shardings than the first call's: the
+            # penalised window's counts in the warm-up); the jit takes them.
+            if self._run is self._fn:
+                raise
+            out, store = self._fall_back(exc, args, kwargs), "fallback"
         self._calls += 1
         if getattr(_tls, "totals", _ZERO) is not before:
             # This thread traced, lowered or built something meanwhile.
-            self._note_built(before, t0, args, kwargs)
+            self._note_built(before, t0, args, kwargs, store)
         return out
 
     def _first_call(self, args, kwargs):
-        """The call that traces, lowers and loads or compiles the program:
-        the unit a start is made of. After ``mark_ready`` (a bucket drawn
-        lazily) it is also an annotation on the profiler's host line, so
-        that an idle gap of the device names the program that caused it."""
+        """The call that loads the program from the store, or traces,
+        lowers and loads or compiles it: the unit a start is made of. After
+        ``mark_ready`` (a bucket drawn lazily) it is also an annotation on
+        the profiler's host line, so that an idle gap of the device names
+        the program that caused it."""
         before = getattr(_tls, "totals", _ZERO)
         t0 = time.monotonic()
         # Shapes, dtypes and shardings only: the arrays themselves are
         # donated. Once per wrapper; ops_by_scope() lowers from it.
         self._signature = jax.tree.map(_abstract, (args, kwargs))
-        if self._registry.warmup_complete:
-            with jax.profiler.TraceAnnotation(
-                    "program.first_call", program=self._program,
-                    key=repr(self._key)):
-                out = self._fn(*args, **kwargs)
-        else:
-            out = self._fn(*args, **kwargs)
+        entry = self._store_entry(args, kwargs)
+        with (jax.profiler.TraceAnnotation(
+                "program.first_call", program=self._program,
+                key=repr(self._key))
+              if self._registry.warmup_complete
+              else contextlib.nullcontext()):
+            if entry is None:
+                out, store, load_s = self._fn(*args, **kwargs), None, 0.0
+            else:
+                out, store, load_s = self._open(entry, args, kwargs)
         self._calls += 1
-        self._note_built(before, t0, args, kwargs)
+        self._note_built(before, t0, args, kwargs, store, load_s)
         return out
 
-    def _note_built(self, before: tuple, t0: float, args, kwargs) -> None:
-        """One first-call record, and the build counted as before."""
+    # -- the program store -----------------------------------------------------
+    def _store_entry(self, args, kwargs):
+        """This wrapper's place in the store; None for a wrapper without a
+        context, or first called while a module of the package holds code
+        no key can see (program_store.foreign_code)."""
+        context = self._context
+        if context is None:
+            return None
+        try:
+            foreign = program_store.foreign_code()
+            if foreign:
+                log.info("program store: closed for %s %r: %s is bound to "
+                         "code from outside the package", self._program,
+                         self._key, ", ".join(foreign[:4]))
+                return None
+            return program_store.Entry(context, self._program, (
+                program_store.key_text(
+                    context, self._program, self._key, self._labels,
+                    self._jit_kwargs, args, kwargs, SCOPES_VERSION)))
+        except Exception:  # noqa: BLE001 — a start never fails for the store
+            log.exception("program store: no key for %s %r", self._program,
+                          self._key)
+            return None
+
+    def _open(self, entry, args, kwargs):
+        """(the call's result, what the store answered, seconds of the read
+        and the load). A hit runs the loaded executable. Anything else
+        traces and lowers the program as jax would have, COMPILES it (jax's
+        persistent cache stepped around), runs what was built and writes
+        it."""
+        registry, program = self._registry, self._program
+        t0 = time.monotonic()
+        store, found = "miss", None
+        try:
+            found = entry.load()
+        except program_store.Reject as exc:
+            store = "reject"
+            registry.note_store(program, "reject", str(exc))
+            log.warning("program store: %s %r: entry thrown away (%s)",
+                        program, self._key, exc)
+        load_s = time.monotonic() - t0
+        if found is not None:
+            compiled, header = found
+            try:
+                out = compiled(*args, **kwargs)
+            except (TypeError, ValueError) as exc:
+                # The key holds the signature, so this is a key's fault:
+                # the entry goes and the program is built.
+                entry.delete()
+                store = "reject"
+                registry.note_store(program, "reject",
+                                    f"{type(exc).__name__}: {exc}"[:200])
+                log.warning("program store: %s %r: the loaded executable "
+                            "refused its first call (%s)", program,
+                            self._key, exc)
+            else:
+                self._run = compiled
+                registry.note_store(program, "hit")
+                registry.adopt_cost(program, header.get("cost"))
+                return out, "hit", load_s
+        lowered = self._fn.trace(*args, **kwargs).lower()
+        with _persistent_cache_stepped_around():
+            compiled = lowered.compile()
+        try:
+            out = compiled(*args, **kwargs)
+        except (TypeError, ValueError) as exc:
+            return self._fall_back(exc, args, kwargs), "fallback", 0.0
+        self._run = compiled
+        if store == "miss":
+            registry.note_store(program, "miss")
+        cost = registry.maybe_cost(program, lambda: lowered)
+        try:    # after the call: the device runs while the host serializes
+            entry.save(compiled, cost)
+        except Exception as exc:  # noqa: BLE001 — a start never fails for the store
+            log.warning("program store: %s %r not written (%s: %s)", program,
+                        self._key, type(exc).__name__, str(exc)[:200])
+        return out, store, 0.0
+
+    def _fall_back(self, exc, args, kwargs):
+        """The stored executable refused a call's arguments: the jit takes
+        this call and every later one."""
+        self._run = self._fn
+        self._registry.note_store(self._program, "fallback")
+        log.info("program store: %s %r goes back to its jit (%s)",
+                 self._program, self._key, str(exc).splitlines()[0][:200])
+        return self._fn(*args, **kwargs)
+
+    def _note_built(self, before: tuple, t0: float, args, kwargs,
+                    store: str | None = None, load_s: float = 0.0) -> None:
+        """One first-call record, and the build counted as before.
+        ``store`` is what the program store answered this call (one of
+        STORE_RESULTS; None for a wrapper without one), ``load_s`` the
+        seconds of its read and load."""
         wall = time.monotonic() - t0
         after = getattr(_tls, "totals", _ZERO)
         asked = after[_ASKED] - before[_ASKED]
         hits = after[_HITS] - before[_HITS]
         load = after[_LOAD] - before[_LOAD]
+        builds = after[_N] - before[_N]
+        loaded = store == "hit"
+        missed = store in ("miss", "reject")
         registry = self._registry
         registry.note_first_call({
             "program": self._program, "key": self._key,
@@ -489,14 +626,27 @@ class _InstrumentedJit:
             "t_mono": t0, "wall_s": wall,
             "trace_s": after[_TRACE] - before[_TRACE],
             "lower_s": after[_LOWER] - before[_LOWER],
-            # Did the persistent cache hold what was built here? "off": it
-            # was not asked (switched off, or nothing was built).
-            "cache": ("off" if not asked else
-                      "hit" if hits == asked else "miss"),
-            "cache_load_s": load,
+            # Did a cache hold what was built here? The program store's
+            # answer reads as the persistent cache's (which is not asked on
+            # the store's miss path). "off": no cache was asked (switched
+            # off, or nothing was built).
+            "cache": ("hit" if loaded else
+                      "miss" if missed or asked > hits else
+                      "hit" if asked else "off"),
+            # The store's read and load are timed here (jax's retrieval
+            # event fires for its own cache alone).
+            "cache_load_s": load + (load_s if loaded else 0.0),
             "compile_s": max(0.0, after[_BACKEND] - before[_BACKEND] - load),
-            "builds": after[_N] - before[_N]}, hits, asked - hits)
-        compiled = after[_N] > before[_N] if _PROBE_OK else self._calls == 1
+            "builds": builds,
+            "store": store,
+            # Where the executable of this call came from.
+            "source": ("store" if loaded else
+                       "fallback" if store == "fallback" else
+                       "compiled" if missed else
+                       "jax_cache" if asked and hits == asked else
+                       "compiled" if builds else "retrace")},
+            hits + loaded, asked - hits + missed)
+        compiled = loaded or (builds > 0 if _PROBE_OK else self._calls == 1)
         if compiled:
             # Unexpected = THIS wrapper (one program instance, one
             # shape signature) compiling again AFTER warmup declared
@@ -510,27 +660,33 @@ class _InstrumentedJit:
             unexpected = (self._key is not None and self._compiles >= 1
                           and registry.warmup_complete)
             self._compiles += 1
-            # The backend event's seconds (a cache load among them), not
-            # wall time.
+            # The backend event's seconds (a cache load among them) or the
+            # store's load, not wall time.
             registry.note_compile(
                 self._program, self._key,
+                load_s if loaded else
                 after[_S] - before[_S] if _PROBE_OK else wall,
                 unexpected=unexpected)
-            registry.maybe_cost(self._program, self._fn, args, kwargs)
+            if self._run is self._fn:   # the store's paths have their own
+                registry.maybe_cost(
+                    self._program,
+                    lambda: self._fn.lower(*args, **kwargs))
 
     def lower(self, *args, **kwargs):
         return self._fn.lower(*args, **kwargs)
 
     def ops_by_scope(self) -> dict | None:
         """scopes_of_hlo() of this wrapper's executable; None before the
-        first call. The executable comes from jax's caches (this process
-        compiled or loaded it already), so its names are those of the
-        program that RUNS, also where the persistent cache handed over
-        what another tree had compiled."""
+        first call. A stored executable answers for itself; a jit's comes
+        from jax's caches (this process compiled or loaded it already), so
+        the names are those of the program that RUNS, also where the
+        persistent cache handed over what another tree had compiled."""
         if self._scopes is None and self._signature is not None:
             args, kwargs = self._signature
             try:
-                text = self._fn.lower(*args, **kwargs).compile().as_text()
+                text = (self._run.as_text() if self._run is not self._fn
+                        else self._fn.lower(*args, **kwargs).compile()
+                        .as_text())
             except Exception:  # noqa: BLE001 — the perf plane never raises
                 # (a program that needs the runner's mesh context to lower)
                 log.exception("ops_by_scope: %s %r does not lower here",
@@ -553,6 +709,8 @@ class CompileRegistry:
         self.first_calls: list[dict] = []
         self.warmup_complete = False
         self.warmup_complete_ts = 0.0
+        # A launcher's start is under way (mark_starting .. mark_ready).
+        self.starting = False
         # Per-window series (single engine-thread writer, lock-free).
         self.windows_total = 0
         self.window_seconds_total = 0.0
@@ -562,9 +720,11 @@ class CompileRegistry:
         self.roofline_frac = 0.0       # EWMA achieved / weight-read roofline
 
     # -- compile observatory ---------------------------------------------------
-    def wrap(self, program: str, fn, key=None,
-             labels: dict | None = None) -> _InstrumentedJit:
-        wrapper = _InstrumentedJit(self, program, fn, key, labels)
+    def wrap(self, program: str, fn, key=None, labels: dict | None = None,
+             context=None, jit_kwargs: dict | None = None
+             ) -> _InstrumentedJit:
+        wrapper = _InstrumentedJit(self, program, fn, key, labels, context,
+                                   jit_kwargs)
         with self._lock:
             self._programs.setdefault(program, _Program(program))
             refs = self._wrappers.setdefault(program, [])
@@ -649,23 +809,26 @@ class CompileRegistry:
                     attrs={"program": program, "key": repr(key),
                            "compile_s": round(seconds, 4)})
 
-    def maybe_cost(self, program: str, fn, args, kwargs) -> None:
-        """One-time FLOPs/bytes estimate per program family. Cheap path
-        (``lower().cost_analysis()``) traces but never XLA-compiles;
-        the ``compile`` mode pays a real second compile for optimized
-        numbers. Every failure is recorded, never raised — the perf
-        plane must not be able to take down serving."""
+    def maybe_cost(self, program: str, lower) -> dict | None:
+        """One-time FLOPs/bytes estimate per program family; returns what
+        the family has (None while another thread works on it, or with the
+        mode ``off``). ``lower()`` gives the ``Lowered`` program: the jit's
+        path lowers once more for it, the store's miss path has it at hand.
+        Cheap path (``cost_analysis()`` of the lowered module) never
+        XLA-compiles; the ``compile`` mode pays a real second compile for
+        optimized numbers. Every failure is recorded, never raised — the
+        perf plane must not be able to take down serving."""
         mode = _cost_mode()
         if mode == "off":
-            return
+            return None
         with self._lock:
             prog = self._programs.setdefault(program, _Program(program))
             if prog.cost is not None:
-                return
+                return None if prog.cost.get("pending") else prog.cost
             prog.cost = {"pending": True}  # claim before the slow work
         cost: dict
         try:
-            lowered = fn.lower(*args, **kwargs)
+            lowered = lower()
             raw = (lowered.compile().cost_analysis() if mode == "compile"
                    else lowered.cost_analysis())
             if isinstance(raw, (list, tuple)):  # compiled returns per-device
@@ -678,6 +841,27 @@ class CompileRegistry:
                     "source": mode}
         with self._lock:
             prog.cost = cost
+        return cost
+
+    def adopt_cost(self, program: str, cost: dict | None) -> None:
+        """The estimate a stored executable's header carries (its family's,
+        as of the start that wrote it), where the family has none yet: a
+        start that loads every program lowers none for an estimate."""
+        if cost:
+            with self._lock:
+                prog = self._programs.setdefault(program, _Program(program))
+                if prog.cost is None:
+                    prog.cost = cost
+
+    def note_store(self, program: str, result: str,
+                   reason: str | None = None) -> None:
+        """The program store answered a wrapper of ``program`` (``result``
+        one of STORE_RESULTS; ``reason`` why an entry was thrown away)."""
+        with self._lock:
+            prog = self._programs.setdefault(program, _Program(program))
+            prog.store[result] += 1
+            if reason is not None:
+                prog.store_reject_reason = reason
 
     def mark_ready(self) -> None:
         """Warmup boundary: compiles recorded after this are post-warmup
@@ -686,12 +870,15 @@ class CompileRegistry:
         ``when: "serving"``."""
         self.warmup_complete = True
         self.warmup_complete_ts = time.time()
+        self.starting = False
 
     def mark_starting(self) -> None:
         """The launcher is about to build an engine: what is built from
         here to the next ``mark_ready`` is a start's, also in a process
-        that served before."""
+        that served before; a runner built in between may keep its
+        programs in the program store (:func:`program_context`)."""
         self.warmup_complete = False
+        self.starting = True
 
     # -- roofline-attributed window timing ------------------------------------
     def note_window(self, window_s: float, tokens: int, active: int,
@@ -750,6 +937,9 @@ class CompileRegistry:
                     "cost": p.cost,
                     "last_compile_ts": p.last_compile_ts,
                     "labels": self._labels_of(name),
+                    **{STORE_COUNTERS[result]: n
+                       for result, n in p.store.items()},
+                    "store_reject_reason": p.store_reject_reason,
                 }
                 for name, p in sorted(self._programs.items())
             }
@@ -802,6 +992,7 @@ class CompileRegistry:
             self.first_calls.clear()
         self.warmup_complete = False
         self.warmup_complete_ts = 0.0
+        self.starting = False
         self.windows_total = 0
         self.window_seconds_total = 0.0
         self.window_tokens_total = 0
@@ -818,31 +1009,97 @@ def get_registry() -> CompileRegistry:
 
 
 def instrumented_jit(program: str, fun, *, key=None, registry=None,
-                     labels: dict | None = None, **jit_kwargs):
+                     labels: dict | None = None, context=None,
+                     **jit_kwargs):
     """The ONE sanctioned way to build a serving-path jit program:
     ``jax.jit`` + compile observatory in a drop-in wrapper. ``program``
     is the family label (``prefill``, ``decode_window``, ...); ``key``
     the shape-signature cache key the caller memoizes under (the
     recompile detector treats a second compile of the same key as
     unexpected); ``labels`` what the caller chose statically for the
-    family (the snapshot's ``labels``). Extra kwargs go straight to
-    ``jax.jit``."""
+    family (the snapshot's ``labels``); ``context`` the caller's
+    :func:`program_context` (everything ``fun`` closes over, for the
+    program store's key; None, or no argument: the program is traced and
+    built at every start). Extra kwargs go straight to ``jax.jit``."""
     reg = registry if registry is not None else _REGISTRY
     # dtpu: ignore[jit-recompile-hazard] until=2027-08-01 -- this IS the caching chokepoint: every caller memoizes the returned wrapper by its shape key
     return reg.wrap(program, jax.jit(fun, **jit_kwargs), key=key,
-                    labels=labels)
+                    labels=labels, context=context, jit_kwargs=jit_kwargs)
+
+
+# -- the program store's rule of engagement ------------------------------------
+_store_closed = 0   # depth of program_store_closed()
+_config_lock = threading.Lock()
+
+
+def program_context(*closed_over, mesh, registry=None):
+    """What a runner hands :func:`instrumented_jit` as ``context``: the
+    ``repr`` of everything its programs close over (``ModelSpec``,
+    ``EngineConfig``, ``Backends``: plain dataclasses) and its mesh. None,
+    and so no store, unless this process can see that a stale executable
+    has no way in: a launcher marked a start that is still under way (a
+    runner a test or a script builds directly may run patched model code
+    under an unchanged configuration), the persistent compile cache is on
+    (its directory holds the store) and the process is the only one
+    (multi-controller serving compiles in lockstep)."""
+    reg = registry if registry is not None else _REGISTRY
+    if (not reg.starting or _store_closed
+            or not jax.config.jax_enable_compilation_cache
+            or jax.process_count() != 1):
+        return None
+    text = "\n".join([*(repr(part) for part in closed_over),
+                      f"mesh={dict(mesh.shape)!r}"])
+    return program_store.Context(
+        text, mesh.devices.flat,
+        os.path.join(compile_cache_dir(), program_store.SUBDIR))
+
+
+@contextlib.contextmanager
+def program_store_closed():
+    """No runner built inside gets a store context: for a test that goes
+    through the launcher AND patches what its programs trace in a way
+    program_store.foreign_code cannot see."""
+    global _store_closed
+    _store_closed += 1
+    try:
+        yield
+    finally:
+        _store_closed -= 1
+
+
+@contextlib.contextmanager
+def _persistent_cache_stepped_around():
+    """A compile inside is a real compile: jax's persistent cache is neither
+    asked nor written. What the program store is about to write must come
+    from the compiler: an executable the cache handed over does not
+    serialize back (on the CPU its payload loads and then fails its first
+    run: "Function ... not found"), and one it kept would lie in the
+    directory twice. The switch is the process's, so a compile another
+    thread makes meanwhile is stepped around too (it compiles; nothing
+    breaks)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    with _config_lock:
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
+            compilation_cache.reset_cache()
 
 
 def first_calls_by_family(records: list[dict]) -> dict:
     """``records`` summed: in all and a family (``programs``, the five
-    seconds of FIRST_CALL_PARTS, ``hits``, ``misses``), with the ten
-    longest by ``wall_s``."""
+    seconds of FIRST_CALL_PARTS, ``hits``, ``misses``, and what the program
+    store answered: STORE_COUNTERS), with the ten longest by ``wall_s``."""
     def summed(rows: list[dict]) -> dict:
         return {"programs": len(rows),
                 **{part: round(sum(r[part] for r in rows), 4)
                    for part in FIRST_CALL_PARTS},
                 "hits": sum(r["cache"] == "hit" for r in rows),
-                "misses": sum(r["cache"] == "miss" for r in rows)}
+                "misses": sum(r["cache"] == "miss" for r in rows),
+                **{name: sum(r.get("store") == result for r in rows)
+                   for result, name in STORE_COUNTERS.items()}}
 
     families: dict[str, list] = {}
     for r in records:
@@ -881,13 +1138,14 @@ def startup_status() -> dict:
 
 def describe_first_calls() -> str:
     """What was first called before ``mark_ready``, in a log line's words:
-    "131 programs: trace 9.8, lower 6.1, cache 11.2 (131 hits), compile
-    0.0"."""
+    "131 programs: trace 9.8, lower 6.1, cache 11.2 (131 hits, 21 from the
+    program store), compile 0.0"."""
     calls = startup_status()["first_calls"]
-    return ("%d programs: trace %.1f, lower %.1f, cache %.1f (%d hits), "
-            "compile %.1f%s" % (
+    return ("%d programs: trace %.1f, lower %.1f, cache %.1f (%d hits, %d "
+            "from the program store), compile %.1f%s" % (
                 calls["programs"], calls["trace_s"], calls["lower_s"],
-                calls["cache_load_s"], calls["hits"], calls["compile_s"],
+                calls["cache_load_s"], calls["hits"], calls["store_hits"],
+                calls["compile_s"],
                 " (%d MISSED the cache)" % calls["misses"]
                 if calls["misses"] else ""))
 
@@ -935,6 +1193,16 @@ class PerfMetricsUpdater:
             "executable was loaded; perf_compiles_total counts it too) or "
             "miss (XLA compiled and the entry was written); a warm start "
             "has no miss", ["program", "result"])
+        self.c_program_store = registry.counter(
+            "perf_program_store_total", "What the program store "
+            "(engine/program_store.py: executables kept under a key that "
+            "costs no trace) answered a wrapper's first call, per jit "
+            "program family: result hit (loaded: no trace, no lowering), "
+            "miss (built and written), reject (an entry that could not be "
+            "used: deleted, built and written) or fallback (a LATER call's "
+            "arguments the loaded executable does not take: the wrapper "
+            "went back to its jit); a warm start through the launcher is "
+            "all hits", ["program", "result"])
         self.c_first_call = registry.counter(
             "perf_first_call_seconds_total", "Seconds of the calls that "
             "built a program (a wrapper's first call, or a later one that "
@@ -1135,10 +1403,10 @@ class PerfMetricsUpdater:
         with reg._lock:
             per_prog = [(p.name, p.compiles, p.compile_seconds, p.unexpected,
                          p.cache_loads, p.cache_misses,
-                         dict(p.first_call_seconds))
+                         dict(p.first_call_seconds), dict(p.store))
                         for p in reg._programs.values()]
         for (name, compiles, seconds, unexpected, loads, misses,
-             first) in per_prog:
+             first, store) in per_prog:
             self._delta(self.c_compiles, ("c", name), compiles, program=name)
             self._delta(self.c_compile_seconds, ("s", name), seconds,
                         program=name)
@@ -1151,6 +1419,9 @@ class PerfMetricsUpdater:
             for part, value in first.items():
                 self._delta(self.c_first_call, ("fc", name, part), value,
                             program=name, part=part.removesuffix("_s"))
+            for result, n in store.items():
+                self._delta(self.c_program_store, ("ps", name, result), n,
+                            program=name, result=result)
         start = tracing.last_startup()
         if (start is not None and not start.open
                 and start is not self._startup_told):

@@ -168,6 +168,11 @@ PREFILL_FRESH_KV_BYTES = 1 << 30
 
 
 class ModelRunner:
+    #: The program store's context (perf.program_context, set in _place):
+    #: none for a runner that no launcher's start builds, nor for one put
+    #: together without __init__ (tests/test_tpu_compile.py).
+    _store_context = None
+
     def __init__(self, config: EngineConfig, params=None,
                  devices: list | None = None, seed: int = 0):
         self.config = config
@@ -275,6 +280,10 @@ class ModelRunner:
         # each call.
         self.moe_grouped_pairs = 0
         self.page_size = config.page_size
+        # What every program below closes over, for the program store's key
+        # (None, and no store, for a runner no launcher's start builds).
+        self._store_context = perf.program_context(
+            spec, config, self.backends, self.quant_kv, mesh=self.mesh)
 
     def _load_params(self, spec, params, seed: int) -> None:
         """Shard the parameters handed over, or init them."""
@@ -706,6 +715,7 @@ class ModelRunner:
             return sampled, lp, top_v, top_i, logits, k_cache, v_cache, rng
 
         fn = perf.instrumented_jit("prefill", step, key=key,
+                                   context=self._store_context,
                                    donate_argnums=(1, 2),
                                    **({"donate_argnames": ("state",)}
                                       if recurrent else {}),
@@ -997,7 +1007,8 @@ class ModelRunner:
             return sampled, k_cache, v_cache, rng
 
         self._decode_fn = perf.instrumented_jit(
-            "decode_step", step, key="decode_step", donate_argnums=(1, 2))
+            "decode_step", step, key="decode_step", donate_argnums=(1, 2),
+            context=self._store_context)
         return self._decode_fn
 
     def _get_window(self, window: int, bucket_pages: int,
@@ -1025,7 +1036,8 @@ class ModelRunner:
             fn = perf.instrumented_jit(
                 "decode_window",
                 self._get_mtp_window(window, bucket_pages, seeded), key=key,
-                donate_argnums=(1, 2), labels=labels)
+                donate_argnums=(1, 2), labels=labels,
+                context=self._store_context)
             self._window_cache[key] = fn
             return fn
 
@@ -1215,8 +1227,8 @@ class ModelRunner:
         donate = (1, 2, 6) if penalized else (1, 2)
         fn = perf.instrumented_jit(
             "decode_window", run_window, key=key, donate_argnums=donate,
-            labels=labels, **({"donate_argnames": ("state",)}
-                              if recurrent else {}))
+            labels=labels, context=self._store_context,
+            **({"donate_argnames": ("state",)} if recurrent else {}))
         self._window_cache[key] = fn
         return fn
 
@@ -1394,7 +1406,8 @@ class ModelRunner:
                     k_cache, v_cache, rng)
 
         fn = perf.instrumented_jit("spec_window", run_spec, key=key,
-                                   donate_argnums=(1, 2, 4))
+                                   donate_argnums=(1, 2, 4),
+                                   context=self._store_context)
         self._window_cache[key] = fn
         return fn
 
@@ -1467,7 +1480,8 @@ class ModelRunner:
                 return hist, pos_dev
 
             fn = perf.instrumented_jit("seed_history", scatter, key=key,
-                                       donate_argnums=(0, 1))
+                                       donate_argnums=(0, 1),
+                                       context=self._store_context)
             self._seed_hist_cache[key] = fn
         with self.mesh:
             self.hist_dev, self.positions_dev = fn(
@@ -1495,7 +1509,8 @@ class ModelRunner:
                 return jax.tree.map(
                     lambda dst, src: dst.at[:, s].set(src), lora, host)
             fn = perf.instrumented_jit("lora_load", scatter, key=key,
-                                       donate_argnums=(0,))
+                                       donate_argnums=(0,),
+                                       context=self._store_context)
             self._window_cache[key] = fn
         dev = {}
         for k, (a, b) in host.items():
@@ -1851,7 +1866,8 @@ class ModelRunner:
         if fn is None:
             fn = perf.instrumented_jit(
                 "embed", lambda p, t, sl: embed_forward(
-                    p, spec, t, sl, pooling=pooling), key=key)
+                    p, spec, t, sl, pooling=pooling), key=key,
+                context=self._store_context)
             self._window_cache[key] = fn
         toks = np.zeros((bp, bucket), np.int32)
         lens = np.ones((bp,), np.int32)
@@ -1885,9 +1901,11 @@ class ModelRunner:
                 # multi-host mode (round-3 VERDICT missing #2).
                 fn = perf.instrumented_jit(
                     "extract", gather, key=key,
+                    context=self._store_context,
                     out_shardings=NamedSharding(self.mesh, P()))
             else:
-                fn = perf.instrumented_jit("extract", gather, key=key)
+                fn = perf.instrumented_jit("extract", gather, key=key,
+                                           context=self._store_context)
             self._window_cache[key] = fn
         return fn
 
@@ -1910,7 +1928,8 @@ class ModelRunner:
                     v_cache = v_cache.at[:, :, pages].set(kv[1])
                     return k_cache, v_cache
             fn = perf.instrumented_jit("insert", scatter, key=key,
-                                       donate_argnums=(0, 1))
+                                       donate_argnums=(0, 1),
+                                       context=self._store_context)
             self._window_cache[key] = fn
         return fn
 

@@ -10,7 +10,12 @@ duration events (trace, lowering, backend build, cache retrieval) are summed
 by thread and name by a listener of this script's own, so a tree WITHOUT the
 program's first-call records (the parent of PR 50) reads the same way as one
 with them: run it from the parent's and the change's checkout in turn, in one
-chip call, to see where a start's seconds differ. ``VARIANT=nospans`` clears
+chip call, to see where a start's seconds differ. Beside jax's events it
+prints what the program store answered a family (``program_store``: hits,
+misses, rejects with the last reason, fallbacks; run it twice from one
+checkout for a cold and a warm start) and the bytes the start left in the
+compile cache's directory, jax's entries and the store's apart
+(``cache_dir_bytes``). ``VARIANT=nospans`` clears
 jax's time-span listeners after the program registered its own (what the
 records' self times cost). ``REHEARSE=1`` runs the cell's toy on the CPU.
 One line, ``PROBE {json}``, also appended to ``chiprun_out/startup_probe.jsonl``
@@ -24,6 +29,19 @@ import sys
 import threading
 
 sys.path.insert(0, os.getcwd())
+
+
+def directory_bytes(path: str) -> dict:
+    """Bytes under the compile cache's directory: jax's own entries, and
+    the program store's (the sub-directory ``programs``)."""
+    out = {"jax_cache": 0, "program_store": 0, "program_store_entries": 0}
+    for root, _dirs, files in os.walk(path):
+        store = os.path.basename(root) == "programs"
+        for name in files:
+            size = os.path.getsize(os.path.join(root, name))
+            out["program_store" if store else "jax_cache"] += size
+            out["program_store_entries"] += store
+    return out
 
 
 def main() -> int:
@@ -55,6 +73,15 @@ def main() -> int:
             calls = status()["first_calls"]
             out["first_calls"] = {k: v for k, v in calls.items()
                                   if k != "longest"}
+            # The program store's counters a family (none before PR 51),
+            # and what the start left in the compile cache's directory.
+            out["program_store"] = {
+                name: {k: v for k, v in family.items()
+                       if k.startswith("store_")}
+                for name, family in
+                perf.get_registry().snapshot()["programs"].items()
+                if family.get("store_hits") is not None}
+            out["cache_dir_bytes"] = directory_bytes(perf.compile_cache_dir())
         line = "PROBE " + json.dumps(out)
         print(line, flush=True)
         os.makedirs("chiprun_out", exist_ok=True)

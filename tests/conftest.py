@@ -55,3 +55,29 @@ def async_test(fn=None, *, timeout: float = 120):
 @pytest.fixture
 def anyio_backend():
     return "asyncio"
+
+
+#: One tiny engine behind the unified launcher, on a port of its own.
+TINY_LAUNCH = ["in=http", "out=tpu", "--model", "tiny-test", "--http-host",
+               "127.0.0.1", "--http-port", "0", "--num-pages", "64",
+               "--max-num-seqs", "4"]
+
+
+async def launched(argv, inside):
+    """``launch.run`` to ready, ``await inside(runtime, service, engine)``,
+    shutdown; what ``inside`` returned."""
+    from dynamo_tpu import launch
+    args = launch.parse_args(argv)
+    ready = asyncio.get_running_loop().create_future()
+    task = asyncio.create_task(
+        launch.run(args, ready=lambda *a: ready.set_result(a)))
+    await asyncio.wait({task, ready}, return_when=asyncio.FIRST_COMPLETED)
+    if not ready.done():
+        task.result()   # raises what the start raised
+        raise AssertionError("launch.run returned before it was ready")
+    runtime, service, engine = ready.result()
+    try:
+        return await inside(runtime, service, engine)
+    finally:
+        runtime.shutdown()
+        await task

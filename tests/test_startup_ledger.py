@@ -17,39 +17,16 @@ import time
 import jax
 import jax.numpy as jnp
 import pytest
+from conftest import TINY_LAUNCH as LAUNCH
 from conftest import async_test
+from conftest import launched as _launched
 
-from dynamo_tpu import launch
 from dynamo_tpu.engine import perf
 from dynamo_tpu.engine.perf import (FIRST_CALL_PARTS, CompileRegistry,
                                     instrumented_jit)
 from dynamo_tpu.runtime import tracing
 from dynamo_tpu.runtime.context import Context
 from dynamo_tpu.runtime.tracing import SpanRecorder, Startup, covered_seconds
-
-LAUNCH = ["in=http", "out=tpu", "--model", "tiny-test", "--http-host",
-          "127.0.0.1", "--http-port", "0", "--num-pages", "64",
-          "--max-num-seqs", "4"]
-
-
-async def _launched(argv, inside):
-    """``launch.run`` to ready, ``await inside(runtime, service, engine)``,
-    shutdown; what ``inside`` returned."""
-    args = launch.parse_args(argv)
-    ready = asyncio.get_running_loop().create_future()
-    task = asyncio.create_task(
-        launch.run(args, ready=lambda *a: ready.set_result(a)))
-    await asyncio.wait({task, ready}, return_when=asyncio.FIRST_COMPLETED)
-    if not ready.done():
-        task.result()   # raises what the start raised
-        raise AssertionError("launch.run returned before it was ready")
-    runtime, service, engine = ready.result()
-    try:
-        return await inside(runtime, service, engine)
-    finally:
-        runtime.shutdown()
-        await task
-
 
 async def _get(session, service, path, json_body=True):
     async with session.get(
@@ -59,9 +36,12 @@ async def _get(session, service, path, json_body=True):
 
 
 @pytest.fixture(scope="module")
-def started(caplog_lines):
+def started(caplog_lines, tmp_path_factory):
     """One start with the prefill ladder, then ONE long request (its page
-    bucket was not warmed: a program drawn lazily), and what both left."""
+    bucket was not warmed: a program drawn lazily), and what both left. The
+    start is COLD: its compile cache, and so its program store, is an empty
+    directory of this module's own (tests/test_program_store.py has the warm
+    one)."""
     import aiohttp
 
     from dynamo_tpu.llm.protocols import PreprocessedRequest
@@ -91,8 +71,11 @@ def started(caplog_lines):
             out["perf_after"] = await _get(session, service, "/debug/perf")
         return out
 
-    out = asyncio.run(asyncio.wait_for(_launched(
-        [*LAUNCH, "--warmup-prefill-ladder"], inside), 600))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setenv("JAX_COMPILATION_CACHE_DIR",
+                     str(tmp_path_factory.mktemp("compile_cache")))
+        out = asyncio.run(asyncio.wait_for(_launched(
+            [*LAUNCH, "--warmup-prefill-ladder"], inside), 600))
     out["records"] = list(registry.first_calls[before:])
     out["compiles"] = registry.compiles_total - compiles_before
     out["log"] = list(caplog_lines)
@@ -357,7 +340,9 @@ async def test_a_start_that_raises_closes_the_root_with_the_stage(
 async def test_a_second_start_of_the_same_shapes_loads_from_the_cache(
         tmp_path, monkeypatch):
     """A persistent cache in a directory of the test's own: the first start
-    compiles and writes, the second (same shapes, fresh closures) loads."""
+    compiles and writes, the second (same shapes, fresh closures) loads:
+    through the launcher, from the program store inside that directory, and
+    the records read as a load from jax's cache did."""
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     registry = perf.get_registry()
 
@@ -373,7 +358,8 @@ async def test_a_second_start_of_the_same_shapes_loads_from_the_cache(
         await _launched(LAUNCH, records)
         walls.append({
             "records": [r for r in registry.first_calls[before:]
-                        if r["when"] == "startup" and r["builds"]],
+                        if r["when"] == "startup"
+                        and (r["builds"] or r["source"] == "store")],
             "loads": registry.snapshot()["cache_loads_total"] - loads,
             "builds": registry.compiles_total - builds})
     cold, warm = walls
