@@ -33,7 +33,10 @@ program's init seed), and checks what comes out by the repo's own means:
              lies by slot beside the pool, on the device, and is carried
              through chunked prefill and the windows; two-matrix relu2
              experts, a share of them: the grouped product in a 256-row
-             chunk, the masked one in the window);
+             chunk, the masked one in the window), and on the Solar-Open2
+             block at one period (* K K K; 64 delta-rule heads of 128 x
+             128: the recurrence's second form, which reads the decayed
+             state before it writes it, against XLA's ``delta_update``);
              ``tpu_custom_call`` must be in the compiled window program
              wherever a kernel runs
   disagg     prefill engine -> KV plane -> decode engine on the one chip;
@@ -640,9 +643,25 @@ async def phase_kernels(args, jax, rng, keep: dict):
         name="smoke-looped", vocab_size=2048, hidden_size=512,
         intermediate_size=1024, num_layers=3, num_heads=16, num_kv_heads=16,
         head_dim=128, rope_theta=1e6, rms_norm_eps=1e-6, loop_passes=3)
+    # The Solar-Open2 block at one period (* K K K, an expert layer behind
+    # each): its attention geometry (64 query heads over 8 KV heads of 128,
+    # gated: a page of 32) and its delta-rule heads as published (64 of 128
+    # x 128, a convolution over 24,576 channels), a share of SwiGLU experts:
+    # on the chip the recurrence's SECOND form (the decayed state read
+    # before it is written, in the one visit) against XLA's delta_update;
+    # the longest prompt's chunks solve the delta rule over a carried state.
+    from dynamo_tpu.engine.config import SolarOpen2Spec
+    delta = SolarOpen2Spec(
+        name="smoke-delta", vocab_size=2048, hidden_size=512,
+        intermediate_size=1024, num_layers=4, num_heads=64, num_kv_heads=8,
+        head_dim=128, rms_norm_eps=1e-5, num_experts=4,
+        num_experts_per_tok=2, moe_intermediate_size=256,
+        num_routed_experts=8, first_expert=4, num_shared_experts=1,
+        layer_pattern="*EKEKEKE", ssm_heads=64, ssm_head_dim=128,
+        ssm_groups=64, ssm_state=128, ssm_conv=4, ssm_low_rank=128)
     pages = {wide.name: 64, share.name: 32, latent.name: 64,
              indexed.name: 64, hybrid.name: 128, sala.name: 128,
-             looped.name: 16}  # derived
+             looped.name: 16, delta.name: 32}  # derived
     short = (20, 70, 150) if args.rehearse_cpu else (24, 200, 700)
     past_topk = (20, 2100) if args.rehearse_cpu else (24, 2200, 2600)
     assert max(short) + 64 < indexed.index_topk < min(past_topk[1:])
@@ -660,7 +679,8 @@ async def phase_kernels(args, jax, rng, keep: dict):
             (indexed, None, None, ("xla", kernels), past_topk, 4096),
             (hybrid, None, None, ("xla", "auto"), short, 1024),
             (sala, None, None, ("xla", "auto"), short, 1024),
-            (looped, None, None, ("xla", "auto"), short, 1024)):
+            (looped, None, None, ("xla", "auto"), short, 1024),
+            (delta, None, None, ("xla", "auto"), short, 1024)):
         prompts = [rng.integers(2, spec_r.vocab_size, size=n).tolist()
                    for n in lengths]
         runs = {}

@@ -145,6 +145,13 @@ class ModelSpec:
     ssm_conv = 0
     ssm_chunk = 0
     shared_intermediate_size = None     # a shared expert is expert_size wide
+    # What the Solar-Open2 block states (SolarOpen2Spec): the rank of the
+    # low-rank pairs behind a delta-rule mixer's decay and output gate, what
+    # its beta is multiplied by (2: I - beta k k^T may reflect), and whether
+    # a * layer's output is gated by sigmoid(u W_z).
+    ssm_low_rank = 0
+    ssm_beta_scale = 1.0
+    attn_gate = False
     # What the MiniCPM-SALA block states (MiniCPMSALASpec): the muP scalars
     # (the embedding's factor, what a sublayer's output is multiplied by
     # ahead of the residual, what divides the final norm's output ahead of
@@ -217,10 +224,17 @@ class ModelSpec:
 
     @property
     def ssm_layers(self) -> int:
-        """Layers that keep a state a row: a pattern's M (Mamba-2) and L
-        (lightning linear attention)."""
+        """Layers that keep a state a row: a pattern's M (Mamba-2), L
+        (lightning linear attention) and K (the gated delta rule)."""
         pattern = self.layer_pattern or ""
-        return pattern.count("M") + pattern.count("L")
+        return sum(pattern.count(kind) for kind in RECURRENT_KINDS)
+
+    @property
+    def ssm_kind(self) -> str | None:
+        """The letter of the pattern's recurrent mixer (one kind a model:
+        their leaves share the ``ssm_`` names); None without one."""
+        pattern = self.layer_pattern or ""
+        return next((k for k in RECURRENT_KINDS if k in pattern), None)
 
     @property
     def compressed_keys(self) -> bool:
@@ -238,7 +252,9 @@ class ModelSpec:
 
     @property
     def ssm_channels(self) -> int:
-        """Inputs of the recurrent layer's convolution: x | B | C."""
+        """Inputs of the recurrent layer's convolution: x | B | C (Mamba-2),
+        q | k | v (the delta rule: q and k of ``ssm_state`` a head, v of
+        ``ssm_head_dim``; the same sum with a group a head)."""
         return (self.ssm_heads * self.ssm_head_dim
                 + 2 * self.ssm_groups * self.ssm_state)
 
@@ -431,6 +447,8 @@ class ModelSpec:
             return cls._from_minicpm_sala(cfg, path)
         if cfg.get("model_type") == "ouro":
             return cls._from_ouro(cfg, path)
+        if cfg.get("model_type") == "solar_open2":
+            return cls._from_solar_open2(cfg, path)
         return cls(
             name=cfg.get("_name_or_path", os.path.basename(os.path.dirname(path))),
             vocab_size=cfg["vocab_size"],
@@ -950,6 +968,86 @@ class ModelSpec:
             early_exit_threshold=float(cfg.get("early_exit_threshold", 1.0)),
         )
 
+    @classmethod
+    def _from_solar_open2(cls, cfg: dict, path: str) -> "ModelSpec":
+        """Solar-Open2's keys (upstage/Solar-Open2-250B ``config.json``,
+        ``solar_open2``): ``gqa_layers`` names the layers whose mixer is
+        softmax attention (*, no rotary embedding, gated where
+        ``use_gqa_gate``); every other layer's is a gated delta-rule
+        recurrence (K: Kimi Delta Attention, ``linear_attn_config`` and the
+        ``kda_*`` keys); every layer an expert layer behind its mixer
+        (``first_k_dense_replace`` 0). ``n_routed_experts`` and
+        ``expert_parallel`` as ``_from_deepseek_v32`` reads them.
+        ``gqa_interval``, the rope keys and ``intermediate_size`` (a dense
+        layer's width, which no layer has) are carried and not read."""
+        reader = "the config reader"
+        for key, want, why in (
+                ("use_rope", False, "the attention layers rotate nothing"),
+                ("kda_use_full_proj", False, "the decay's and the output "
+                 "gate's projections are written down as low-rank pairs"),
+                ("first_k_dense_replace", 0, "every layer's feed-forward is "
+                 "an expert layer"),
+                ("n_shared_experts", 1, "ONE shared expert is added to the "
+                 "routed sum"),
+                ("scoring_func", "sigmoid", "the router scores with a "
+                 "sigmoid"),
+                ("n_group", 1, "the router chooses among all experts"),
+                ("hidden_act", "silu", "the experts are SwiGLU"),
+                ("attention_bias", False, "no projection has a bias leaf")):
+            got = cfg.get(key, want)
+            if got != want:
+                raise UnsupportedBlockError(
+                    reader, f"solar_open2 with {key} {got!r}: {why}")
+        linear = cfg["linear_attn_config"]
+        if linear.get("num_kv_heads") not in (None, linear["num_heads"]):
+            raise UnsupportedBlockError(
+                reader, f"solar_open2 whose linear layers have "
+                f"{linear['num_kv_heads']} key heads for "
+                f"{linear['num_heads']}: one state a head with keys of its "
+                "own is what is written down")
+        layers = cfg["num_hidden_layers"]
+        softmax = set(cfg["gqa_layers"])
+        if not softmax <= set(range(layers)):
+            raise UnsupportedBlockError(
+                reader, f"solar_open2 whose gqa_layers {sorted(softmax)} "
+                f"are not among its {layers} layers")
+        share = cfg.get("expert_parallel") or {}
+        return SolarOpen2Spec(
+            name=cfg.get("_name_or_path")
+            or os.path.basename(os.path.dirname(path)),
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=layers,
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg["num_key_value_heads"],
+            head_dim=cfg.get("head_dim"),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            max_position_embeddings=cfg.get("max_position_embeddings", 8192),
+            num_experts=cfg["n_routed_experts"],
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            moe_intermediate_size=cfg["moe_intermediate_size"],
+            norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
+            num_routed_experts=share.get("routed_experts",
+                                         cfg["n_routed_experts"]),
+            first_expert=share.get("first_expert", 0),
+            num_shared_experts=1,
+            routed_scaling_factor=float(cfg.get("routed_scaling_factor")
+                                        or 1.0),
+            layer_pattern="".join(("*" if i in softmax else "K") + "E"
+                                  for i in range(layers)),
+            ssm_heads=linear["num_heads"],
+            ssm_head_dim=linear["head_dim"],
+            ssm_groups=linear["num_heads"],
+            ssm_state=linear["head_dim"],
+            ssm_conv=linear["short_conv_kernel_size"],
+            ssm_chunk=cfg.get("chunk_size", 32),
+            ssm_low_rank=linear["head_dim"],
+            ssm_beta_scale=2.0 if cfg.get("kda_allow_neg_eigval") else 1.0,
+            attn_gate=bool(cfg.get("use_gqa_gate", False)),
+        )
+
 
 @dataclasses.dataclass
 class SmallThinkerSpec(ModelSpec):
@@ -1154,16 +1252,26 @@ class NemotronHSpec(Cohere2MoeSpec):
                              f"{self.ssm_groups} groups")
 
 
-#: A GROUP of a ``layer_pattern``: at most one recurrent mixer (M Mamba-2, L
-#: lightning linear attention), at most one attention layer behind it (* over
+#: The letters of a recurrent mixer: M Mamba-2, L lightning linear attention,
+#: K the gated delta rule (Kimi Delta Attention). One kind a model.
+RECURRENT_KINDS = "MLK"
+#: What a start-up fact calls each (engine.perf_status ``ssm.kind``).
+RECURRENT_NAMES = {"M": "mamba2", "L": "lightning", "K": "delta_rule"}
+#: A GROUP of a ``layer_pattern``: at most one recurrent mixer
+#: (RECURRENT_KINDS), at most one attention layer behind it (* over
 #: every earlier key, S over chosen blocks of keys), then ONE feed-forward (E
 #: an expert layer, D a dense one). The programs scan the stacked groups
 #: (hybrid.groups_of): Nemotron-H's pairs of M and E with a * between some
 #: are one instance, a layer of a mixer and its feed-forward another.
-GROUP = r"[ML]?[*S]?[ED]"
+GROUP = rf"[{RECURRENT_KINDS}]?[*S]?[ED]"
 
 
 def _check_groups(pattern: str) -> None:
+    if len(set(pattern) & set(RECURRENT_KINDS)) > 1:
+        raise UnsupportedBlockError(
+            "the layer scan", f"layer_pattern {pattern!r} has recurrent "
+            "mixers of more than one kind: a model's recurrent layers share "
+            "ONE set of leaves and ONE pair of state arrays")
     if not re.fullmatch(f"({GROUP})+", pattern):
         raise UnsupportedBlockError(
             "the layer scan", f"layer_pattern {pattern!r} is not groups of "
@@ -1234,6 +1342,59 @@ class MiniCPMSALASpec(ModelSpec):
                 f"{self.sparse_window} keys every query keeps is under two "
                 "blocks (the block a window's tokens are written in is kept "
                 "by the window alone)")
+
+
+@dataclasses.dataclass
+class SolarOpen2Spec(Cohere2MoeSpec):
+    """The Solar-Open2 block (upstage/Solar-Open2-250B, ``solar_open2``):
+    every layer is ``h + Mixer(RMS(h))`` then ``h + MoE(RMS(h))``, the mixer
+    a gated delta-rule recurrence (K) or softmax attention without a rotary
+    embedding (*) by the layer; the sigmoid router with a selection bias,
+    the share of a wider router and the shared expert that Cohere2MoeSpec
+    and NemotronHSpec state, at this block's values (SwiGLU experts), and
+    what it states beyond them. The programs are engine/hybrid.py's, which
+    has the equations."""
+    norm_kind: str = "rms"
+    parallel_block: bool = False
+    rope_interleaved: bool = False      # nothing rotates
+    ffn_act: str = "silu"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    moe_select_bias: bool = True
+    # Two letters a layer, one a SUBLAYER: K or *, then E (config.GROUP).
+    layer_pattern: str | None = None
+    # The delta-rule mixer: ssm_heads heads, q and k of ssm_state and v of
+    # ssm_head_dim a head, a causal depthwise convolution of ssm_conv taps
+    # over q | k | v. A row keeps S [heads, head_dim (v), state (k)] in
+    # float32 and the convolution's last ssm_conv - 1 inputs; prefill solves
+    # the delta rule in chunks of ssm_chunk tokens (hybrid.delta_chunked:
+    # 32, the float32 decays between every pair of a chunk's tokens are 8 KB
+    # a pair and head).
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 0
+    ssm_chunk: int = 32
+    # The decay's and the output gate's projections go through this rank.
+    ssm_low_rank: int = 0
+    # beta = ssm_beta_scale * sigmoid(u W_b): 2 where kda_allow_neg_eigval.
+    ssm_beta_scale: float = 2.0
+    # A * layer's output times sigmoid(u W_z) (use_gqa_gate).
+    attn_gate: bool = True
+
+    def __post_init__(self):
+        super().__post_init__()
+        pattern = self.layer_pattern or ""
+        if len(pattern) != 2 * self.num_layers or set(pattern) - set("K*E"):
+            raise ValueError(f"layer_pattern {pattern!r} does not give "
+                             f"{self.num_layers} layers of K or *, then E")
+        _check_groups(pattern)
+        if self.ssm_groups != self.ssm_heads:
+            raise ValueError("a delta-rule head's keys are its own: "
+                             f"{self.ssm_groups} groups for "
+                             f"{self.ssm_heads} heads")
 
 
 @dataclasses.dataclass

@@ -30,8 +30,8 @@ from typing import AsyncIterator
 
 import numpy as np
 
-from dynamo_tpu.engine.config import (EngineConfig, block_refusals,
-                                      device_peaks)
+from dynamo_tpu.engine.config import (RECURRENT_NAMES, EngineConfig,
+                                      block_refusals, device_peaks)
 from dynamo_tpu.engine.kv_cache import PageAllocator
 from dynamo_tpu.engine.runner import (
     ModelRunner, PrefillSeq, PK_OVERRIDE, PK_TOKEN, PK_POS, PK_SEQLEN,
@@ -1180,6 +1180,8 @@ class TPUEngine(AsyncEngine):
                 # Recurrent layers, and what a row (a slot) keeps over them
                 # beside its pages (float32 S, the convolution's inputs).
                 "layers": spec.ssm_layers,
+                # The mixer's kind: mamba2 | lightning | delta_rule.
+                "kind": RECURRENT_NAMES[spec.ssm_kind],
                 "state_bytes_per_row": spec.ssm_state_bytes_per_row,
                 "state_dtype": window["ssm_state"],
                 # Who updates S in a decode step: "kernel" (the live slots,

@@ -22,14 +22,35 @@ or feed-forward each, every sublayer ``h <- h + a f(RMS(h; w, eps))`` with
   ``C = q / sqrt(d)``, a group a head, no convolution and no D. ``o`` is
   RMS-normalised a head (times a weight), times ``sigmoid(z)``; ``out = o
   W_out``. The SAME state arrays, chunked prefill and decode kernel as M.
+- **K, the gated delta rule** (Kimi Delta Attention; the Solar-Open2
+  block). ``[q | k | v] = silu(conv(u W_in))`` (causal, depthwise, K taps,
+  zeros before the sequence, no bias; ``ssm_heads`` heads, q and k of
+  ``ssm_state``, v of ``ssm_head_dim``); q and k unit vectors a head, q over
+  sqrt(state); ``g_t = -exp(A_log[h]) softplus((u W_fa) W_fb + dt_bias)``, a
+  log-decay a CHANNEL of the k axis and a token; ``beta_t = ssm_beta_scale
+  sigmoid(u W_b)`` a head (up to 2: ``I - beta k k^T`` may reflect); state S
+  [head_dim (v), state (k)] float32 a head: ``S' = S_{t-1} Diag(e^g_t)``,
+  ``S_t = S' + beta_t (v_t - S' k_t) (x) k_t``: the update READS the decayed
+  state before it writes it, which neither recurrence above does; ``o_t =
+  S_t q_t``, RMS-normalised a head (times ONE weight of head_dim), times
+  ``sigmoid((u W_ga) W_gb)``; ``out = o W_out``. A row keeps S and the last K
+  - 1 inputs of the convolution, as M does, in the same two arrays. A
+  decode step is ``delta_update`` (XLA, every slot: the definition) or the
+  kernel's second form (engine/recurrence.py ``delta_state_step``: the
+  live slots, ONE visit of a state); prefill solves a chunk of
+  ``ssm_chunk`` tokens by ONE triangular system a head (``delta_chunked``),
+  every exponent a difference of cumulative log-decays that is never
+  positive.
 - **E, an expert layer:** model.ffn_block (sigmoid router with a selection
-  bias, two-matrix relu2 experts, one shared expert of its own width); a
+  bias; two-matrix relu2 experts and one shared expert of its own width, or
+  SwiGLU experts and a shared one of theirs, by ``spec.ffn_act``); a
   prefill over model.MOE_DENSE_MAX_ROWS rows sends each row to its own
   experts (``scan_groups`` hands the kernel of engine/experts.py the expert
   stacks whole), a window step multiplies every held expert. **D, a dense
   feed-forward:** the same function's dense branch (SwiGLU).
 - **\\*, attention:** grouped-query, causal, NO rotary embedding; K and V go
-  to the pool, whose layers are the attention layers alone.
+  to the pool, whose layers are the attention layers alone; where
+  ``spec.attn_gate`` the output is gated by ``sigmoid(u W_z)`` as S's is.
 - **S, attention over chosen BLOCKS of keys** (InfLLM-V2): as \\*, q and k
   RMS-normalised a head, and a query attends ``sparse_topk`` blocks of
   ``sparse_block`` keys a KV group: a compressed key is the mean of
@@ -62,7 +83,9 @@ or feed-forward each, every sublayer ``h <- h + a f(RMS(h; w, eps))`` with
 
 A group is at most one recurrent mixer, at most one attention layer, then a
 feed-forward (``groups_of``): Nemotron-H's pairs of M and E with a * between
-some, MiniCPM-SALA's layers of L or S and D. Every program here is ONE scan
+some, MiniCPM-SALA's layers of L or S and D, Solar-Open2's of K or * and E.
+A model has recurrent mixers of ONE kind (``spec.ssm_kind``: they share the
+``ssm_`` leaves and the state arrays). Every program here is ONE scan
 over the stacked groups in which a sublayer that only some groups have lies
 under a ``lax.cond`` that indexes its own stack: 52 layers unrolled, eight
 steps a window, do not compile in a set-up anyone waits for. The recurrent
@@ -76,8 +99,9 @@ inside a scan as in model.py.
 Prefill computes the recurrence in chunks of ``spec.ssm_chunk`` tokens as
 matrix products (``chunked_recurrence``: within a chunk the decayed (C_l .
 B_s) matrix times dt x, between chunks the state at each border); decode
-takes one token (``ssm_step``, ``lightning_step``). Past a row's last real
-token dt is 0 (the state stands: exp(0) S + 0) and the convolution keeps the
+takes one token (``ssm_step``, ``lightning_step``, ``delta_step``). Past a
+row's last real token dt is 0, and for K g and beta (the state stands:
+exp(0) S + 0), and the convolution keeps the
 last real inputs, so a padded batch, a dead slot and a frozen row leave a
 state exactly as it was.
 
@@ -88,8 +112,9 @@ which is handed the stack over all layers where it lies and visits the
 live slots of one layer (``ssm_step_live``: a dead slot is neither read nor
 written, and the new state is read by C in the pass that writes it);
 everywhere else (the CPU backend, any mesh, a runner asked for the XLA
-reader) XLA through ``ssm_step`` / ``lightning_step``, over every slot of
-the layer's slice: the definitions the kernel is held to. Prefill is XLA's
+reader) XLA through ``ssm_step`` / ``lightning_step`` / ``delta_step``,
+over every slot of the layer's slice: the definitions the kernel's two
+forms are held to. Prefill is XLA's
 under either, and so is the convolution's state.
 """
 
@@ -103,7 +128,8 @@ import jax
 import jax.numpy as jnp
 
 from dynamo_tpu.engine.backends import XLA, Backends
-from dynamo_tpu.engine.config import GROUP, ModelSpec
+from dynamo_tpu.engine.config import (GROUP, RECURRENT_KINDS,
+                                      ModelSpec)
 from dynamo_tpu.engine.kv_quant import gather_pages_folded, scatter_pages
 from dynamo_tpu.engine.model import (LATENT_SCORE_BYTES, Params, _split_heads,
                                      apply_rope, dense_causal_attention,
@@ -112,7 +138,7 @@ from dynamo_tpu.engine.model import (LATENT_SCORE_BYTES, Params, _split_heads,
                                      layer_of, lm_logits, mm, rms_norm,
                                      rope_tables, whole_expert_leaves)
 from dynamo_tpu.engine.perf import scope
-from dynamo_tpu.engine.recurrence import state_step
+from dynamo_tpu.engine.recurrence import delta_state_step, state_step
 
 ATTN_LEAVES = ("wq", "wk", "wv", "wo", "wz", "q_norm", "k_norm")
 FFN_LEAVES = ("w_gate", "w_up", "w_down")
@@ -135,7 +161,8 @@ def groups_of(spec: ModelSpec) -> Groups:
     mixers = attns = 0
     for found in re.finditer(GROUP, spec.layer_pattern):
         at, kinds = found.start(), found.group()
-        mixer, attn = kinds[0] in "ML", any(c in "*S" for c in kinds)
+        mixer = kinds[0] in RECURRENT_KINDS
+        attn = any(c in "*S" for c in kinds)
         out.mixer_layer.append(at if mixer else -1)
         out.mixer_index.append(mixers if mixer else -1)
         out.attn_layer.append(at + mixer if attn else -1)
@@ -492,6 +519,240 @@ def lightning_recurrence(parts: tuple, spec: ModelSpec, state: jax.Array,
     state, y = jax.lax.scan(block, state, tuple(
         blocks(a) for a in (q, k, v, valid)))
     return jnp.moveaxis(y, 0, 1).reshape(b, s, -1), state
+
+
+# ---------------------------------------------------------------------------
+# The gated delta-rule mixer
+# ---------------------------------------------------------------------------
+
+#: What is added under a key's or a query's sum of squares ahead of the
+#: root (the l2 norm a head).
+L2_EPS = 1e-6
+#: Rows of a prompt batch whose chunk the delta rule solves at once: the
+#: float32 decays between every pair of a chunk's tokens are ``chunk`` x
+#: ``state`` x 4 bytes a token and head (1 MB a token at 64 heads, chunks
+#: of 32 and keys of 128: 34 MB a row and chunk), and further rows go in
+#: turns (``jax.lax.map``).
+DELTA_ROWS = 8
+
+
+def _delta_project(h: jax.Array, lp: dict, spec: ModelSpec):
+    """Everything that reads a matrix ahead of the state: (q | k | v [...,
+    channels] as the convolution takes them, the decay's f [..., heads x
+    state], beta's logit [..., heads], the output gate's logit [...,
+    inner]) of normed h [..., hidden]."""
+    qkv = mm(h, lp["ssm_w_in"], "...h,hd->...d")
+    with scope("ssm.gates"):
+        f = mm(mm(h, lp["ssm_w_fa"], "...h,hr->...r"), lp["ssm_w_fb"],
+               "...r,rd->...d")
+        gate = mm(mm(h, lp["ssm_w_ga"], "...h,hr->...r"), lp["ssm_w_gb"],
+                  "...r,rd->...d")
+        return qkv, f, mm(h, lp["ssm_w_beta"], "...h,hd->...d"), gate
+
+
+def _delta_qkv(x: jax.Array, spec: ModelSpec):
+    """The convolution's output x [..., channels] float32 (before its
+    SiLU) as (q, k [..., heads, state], v [..., heads, head_dim]) float32:
+    q and k unit vectors a head, q over sqrt(state)."""
+    n, dk, dv = spec.ssm_heads, spec.ssm_state, spec.ssm_head_dim
+    q, k, v = jnp.split(jax.nn.silu(x), [n * dk, 2 * n * dk], axis=-1)
+    unit = lambda a: a * jax.lax.rsqrt(  # noqa: E731
+        jnp.sum(a * a, axis=-1, keepdims=True) + L2_EPS)
+    q = unit(q.reshape(*q.shape[:-1], n, dk)) * dk ** -0.5
+    return q, unit(k.reshape(*k.shape[:-1], n, dk)), v.reshape(
+        *v.shape[:-1], n, dv)
+
+
+def _delta_gates(f: jax.Array, beta: jax.Array, lp: dict, spec: ModelSpec,
+                 live: jax.Array):
+    """(g [..., heads, state] float32: a channel's log-decay ``-exp(A_log)
+    softplus(f + dt_bias)``, beta [..., heads] float32: ``ssm_beta_scale x
+    sigmoid``), both 0 where ``live`` [...] is not: the state stands."""
+    with scope("ssm.gates"):
+        n, dk = spec.ssm_heads, spec.ssm_state
+        rate = jnp.exp(lp["ssm_a_log"][0].astype(jnp.float32))     # [heads]
+        g = jax.nn.softplus(f.astype(jnp.float32)
+                            + lp["ssm_dt_bias"][:, 0].astype(jnp.float32))
+        g = -rate[:, None] * g.reshape(*g.shape[:-1], n, dk)
+        beta = spec.ssm_beta_scale * jax.nn.sigmoid(beta.astype(jnp.float32))
+        return (jnp.where(live[..., None, None], g, 0.0),
+                jnp.where(live[..., None], beta, 0.0))
+
+
+def _delta_out(y: jax.Array, parts: tuple, lp: dict, spec: ModelSpec):
+    """y [..., inner] float32 RMS-normalised a head, times the weight (one
+    of head_dim for every head), times sigmoid of the gate, through W_out."""
+    n = spec.ssm_heads
+    y = _rms_within(y, n, spec.rms_norm_eps)
+    y = (y.reshape(*y.shape[:-1], n, -1) * lp["ssm_out_norm"]).reshape(y.shape)
+    with scope("ssm.gates"):
+        y = y * jax.nn.sigmoid(parts[3].astype(jnp.float32)).astype(
+            jnp.bfloat16)
+    return mm(y, lp["ssm_w_out"], "...d,dh->...h")
+
+
+def _delta_token_of(parts: tuple, lp: dict, spec: ModelSpec, conv: jax.Array,
+                    live: jax.Array):
+    """What one token a row hands the delta rule: (q, k [B, heads, state],
+    v [B, heads, head_dim], g [B, heads, state], beta [B, heads], all
+    float32; conv with the token behind its last inputs where the row is
+    ``live``) of its projections ``parts`` (``_delta_project``)."""
+    qkv, f, beta, _ = parts
+    with scope("ssm.conv"):
+        full = jnp.concatenate([conv, qkv[:, None].astype(conv.dtype)],
+                               axis=1)
+        taps = lp["ssm_conv_w"].astype(jnp.float32)            # [K, C]
+        q, k, v = _delta_qkv(jnp.sum(full.astype(jnp.float32) * taps,
+                                     axis=1), spec)
+        conv = jnp.where(live[:, None, None], full[:, 1:], conv)
+    return (q, k, v, *_delta_gates(f, beta, lp, spec, live), conv)
+
+
+def delta_update(state: jax.Array, q: jax.Array, k: jax.Array, v: jax.Array,
+                 g: jax.Array, beta: jax.Array):
+    """One token of the gated delta rule over every row, XLA's: state [B,
+    heads, head_dim (v), state (k)] float32, q, k and g [B, heads, state],
+    v [B, heads, head_dim], beta [B, heads]: ``S' = S Diag(exp g)`` (a
+    channel of the k axis its own decay), ``S <- S' + beta (v - S' k) (x)
+    k``; returns (``S q`` [B, heads, head_dim], S). Where g and beta are 0
+    the state stands bit for bit. What the kernel of engine/recurrence.py
+    (``delta_state_step``) is held to."""
+    decayed = state * jnp.exp(g)[:, :, None, :]
+    read = jnp.sum(decayed * k[:, :, None, :], axis=-1)
+    d = beta[..., None] * (v - read)
+    state = decayed + d[..., None] * k[:, :, None, :]
+    return jnp.sum(state * q[:, :, None, :], axis=-1), state
+
+
+def delta_step(h: jax.Array, lp: dict, spec: ModelSpec, state: jax.Array,
+               conv: jax.Array, live: jax.Array):
+    """One token a row, XLA's: the definition. h [B, hidden] (normed),
+    state [B, heads, head_dim, state] float32, conv [B, K - 1, channels],
+    live [B]. Returns (out [B, hidden], state, conv); a row that is not
+    live keeps both."""
+    parts = _delta_project(h, lp, spec)
+    q, k, v, g, beta, conv = _delta_token_of(parts, lp, spec, conv, live)
+    with scope("ssm.state"):
+        y, state = delta_update(state, q, k, v, g, beta)
+    return (_delta_out(y.reshape(h.shape[0], -1), parts, lp, spec), state,
+            conv)
+
+
+def delta_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+                  beta: jax.Array, state: jax.Array, chunk: int):
+    """The gated delta rule over a chunk of a prompt a row, ``chunk`` tokens
+    at a time. q, k and g [B, S, heads, state], v [B, S, heads, head_dim],
+    beta [B, S, heads] (g and beta 0 past a row's last real token: the
+    state stands there), all float32; ``state`` [B, heads, head_dim, state]
+    what the rows hold as the chunk starts. Returns (y [B, S, heads x
+    head_dim] float32, the state at each row's LAST REAL token).
+
+    Within a chunk, with G_t the log-decay summed from the chunk's start
+    through token t (a vector over the k axis), S_0 the state the chunk
+    finds and w_t = beta_t (v_t - S'_t k_t) what token t writes along k_t:
+    ``S_t = S_0 Diag(e^G_t) + sum_{s <= t} w_s (x) (k_s e^(G_t - G_s))``, so
+    ``(I + tril(Diag(beta) A, -1)) W = Diag(beta) (V - (K e^G) S_0^T)`` with
+    ``A_ts = sum_c k_tc k_sc e^(G_tc - G_sc)``: ONE triangular solve a head
+    and chunk (forward substitution: with beta up to 2 the powers of the
+    strict triangle grow and a doubling series loses the result), then
+    ``y_t = (q_t e^G_t) S_0^T + sum_{s <= t} (q_t . k_s e^(G_t - G_s)) w_s``.
+    Every exponent is a difference G_t - G_s with s <= t, taken a channel
+    and a pair of tokens BEFORE the sum over channels, so none is positive:
+    a channel may decay by e^-40 a token, and a product of e^(G_t) by
+    e^(-G_s) would be 0 times infinity. That tensor is chunk x state floats
+    a token and head, which is what keeps the chunk at 32 (config.
+    SolarOpen2Spec.ssm_chunk); the sums over it are the VPU's, every
+    product that remains float32 at the highest precision (they are a
+    thousandth of the layer's projections)."""
+    b, s, n, dk = k.shape
+    if b > DELTA_ROWS:          # rows are independent: a group at a time
+        y, state = jax.lax.map(
+            lambda x: delta_chunked(*(a[None] for a in x), chunk),
+            (q, k, v, g, beta, state), batch_size=DELTA_ROWS)
+        return y[:, 0], state[:, 0]
+    c = min(chunk, s)
+    pad = -s % c
+    if pad:
+        q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad))
+                                    + ((0, 0),) * (a.ndim - 2))
+                            for a in (q, k, v, g, beta))
+    nc = (s + pad) // c
+    # [nc, B, heads, C, ...]: a chunk a step of the scan, a head's tokens
+    # in rows.
+    chunks = lambda a: jnp.moveaxis(  # noqa: E731
+        a.reshape(b, nc, c, *a.shape[2:]), (1, 3), (0, 2))
+    lower = jnp.arange(c)[:, None] >= jnp.arange(c)[None, :]
+    strict = jnp.arange(c)[:, None] > jnp.arange(c)[None, :]
+    hi = jax.lax.Precision.HIGHEST
+
+    def one(carried, x):
+        q_c, k_c, v_c, g_c, beta_c = x
+        cum = jnp.cumsum(g_c, axis=2)                       # [B,H,C,K]
+        between = jnp.where(
+            lower[:, :, None],
+            jnp.exp(jnp.minimum(cum[:, :, :, None] - cum[:, :, None], 0.0)),
+            0.0)                                            # [B,H,C,C,K]
+        keys = between * k_c[:, :, None]
+        kk = jnp.sum(keys * k_c[:, :, :, None], axis=-1)    # [B,H,C,C]
+        qk = jnp.sum(keys * q_c[:, :, :, None], axis=-1)
+        through = jnp.exp(cum)                              # [B,H,C,K]
+        found = jnp.einsum("bhtk,bhvk->bhtv", k_c * through, carried,
+                           precision=hi)
+        system = (jnp.where(strict, beta_c[..., None] * kk, 0.0)
+                  + jnp.eye(c, dtype=jnp.float32))
+        w = jax.lax.linalg.triangular_solve(
+            system, beta_c[..., None] * (v_c - found), left_side=True,
+            lower=True, unit_diagonal=True)                 # [B,H,C,V]
+        y = (jnp.einsum("bhtk,bhvk->bhtv", q_c * through, carried,
+                        precision=hi)
+             + jnp.einsum("bhts,bhsv->bhtv", qk, w, precision=hi))
+        to_end = jnp.exp(cum[:, :, -1:] - cum)
+        carried = (carried * through[:, :, -1][:, :, None, :]
+                   + jnp.einsum("bhsv,bhsk->bhvk", w, k_c * to_end,
+                                precision=hi))
+        return carried, y
+
+    state, y = jax.lax.scan(one, state, tuple(
+        chunks(a) for a in (q, k, v, g, beta)))
+    # [nc, B, heads, C, V] -> [B, S, heads x V]
+    y = jnp.moveaxis(y, (0, 2), (1, 3)).reshape(b, nc * c, -1)
+    return y[:, :s], state
+
+
+def _delta_chunk(parts: tuple, lp: dict, spec: ModelSpec, state: jax.Array,
+                 conv: jax.Array, valid: jax.Array, seq_lens: jax.Array):
+    """A chunk of a prompt a row between its projections (``_delta_project``
+    of h [B, S, hidden]): (y [B, S, inner] float32, state and conv at each
+    row's LAST REAL token). ``state`` and ``conv`` what the rows hold as
+    the chunk starts, valid [B, S], seq_lens [B] the real tokens."""
+    qkv, f, beta, _ = parts
+    s, taps_n = qkv.shape[1], spec.ssm_conv
+    with scope("ssm.conv"):
+        full = jnp.concatenate([conv, qkv.astype(conv.dtype)], axis=1)
+        taps = lp["ssm_conv_w"].astype(jnp.float32)
+        acc = taps[0] * full[:, 0:s].astype(jnp.float32)
+        for j in range(1, taps_n):
+            acc = acc + taps[j] * full[:, j:j + s].astype(jnp.float32)
+        q, k, v = _delta_qkv(acc, spec)
+        # The last K - 1 real inputs: input t lies at t + K - 1 of ``full``.
+        last = seq_lens[:, None] + jnp.arange(taps_n - 1)[None, :]
+        conv = jnp.take_along_axis(full, last[:, :, None], axis=1)
+    g, beta = _delta_gates(f, beta, lp, spec, valid)
+    with scope("ssm.chunk"):
+        y, state = delta_chunked(q, k, v, g, beta, state, spec.ssm_chunk)
+    return y, state, conv
+
+
+def delta_prefill(h: jax.Array, lp: dict, spec: ModelSpec, state: jax.Array,
+                  conv: jax.Array, valid: jax.Array, seq_lens: jax.Array):
+    """A chunk of a prompt a row. h [B, S, hidden] (normed), ``state`` and
+    ``conv`` what the rows hold as the chunk starts (zeros at position 0).
+    Returns (out [B, S, hidden], state and conv at each row's LAST REAL
+    token)."""
+    parts = _delta_project(h, lp, spec)
+    y, state, conv = _delta_chunk(parts, lp, spec, state, conv, valid,
+                                  seq_lens)
+    return _delta_out(y, parts, lp, spec), state, conv
 
 
 # ---------------------------------------------------------------------------
@@ -1017,7 +1278,16 @@ def prefill(params: Params, spec: ModelSpec, k_cache: jax.Array,
             return (y, *out)
         return update
 
-    if spec.ssm_conv:
+    if spec.ssm_kind == "K":
+        mixer = Mixer(
+            lambda h, lp: _delta_project(h, lp, spec),
+            lambda parts, lp, s_all, c_all, p, on: of_rows(
+                lambda s_rows, c_rows: _delta_chunk(
+                    parts, lp, spec, s_rows, c_rows, live(on),
+                    seq_lens if on is None else jnp.where(on, seq_lens, 0)),
+                on)(s_all, c_all, p),
+            lambda y, parts, lp: _delta_out(y, parts, lp, spec))
+    elif spec.ssm_conv:
         mixer = Mixer(
             lambda h, lp: _project(h, lp, spec),
             lambda parts, lp, s_all, c_all, p, on: of_rows(
@@ -1134,7 +1404,26 @@ def window_step(params: Params, spec: ModelSpec, k_cache: jax.Array,
     def on_rows(on):
         return live if on is None else live & on
 
-    if spec.ssm_conv:
+    if spec.ssm_kind == "K":
+        def delta(parts, lp, s_all, c_all, p, on):
+            q, k, v, g, beta, conv = _delta_token_of(
+                parts, lp, spec, _index(c_all, p), on_rows(on))
+            with scope("ssm.state"):
+                if kernel:
+                    s_all, y = delta_state_step(
+                        s_all, p, walk[0],
+                        walk[1] if on is None else jnp.where(on, walk[1], 0),
+                        jnp.exp(g), k, q, v, beta,
+                        interpret=backends.interpret)
+                else:
+                    y, s_all = in_layer(lambda s_rows: delta_update(
+                        s_rows, q, k, v, g, beta))(s_all, p)
+            return (y.reshape(b, -1), s_all,
+                    jax.lax.dynamic_update_index_in_dim(c_all, conv, p, 0))
+
+        mixer = Mixer(lambda h, lp: _delta_project(h, lp, spec), delta,
+                      lambda y, parts, lp: _delta_out(y, parts, lp, spec))
+    elif spec.ssm_conv:
         def mamba(parts, lp, s_all, c_all, p, on):
             x, bb, cc, dt, da, conv = _token_of(
                 parts, lp, spec, _index(c_all, p), on_rows(on))

@@ -220,6 +220,33 @@ def _lightning_shapes(spec: ModelSpec, L: int) -> dict:
             "ssm_w_out": (L, inner, h)}
 
 
+def _delta_shapes(spec: ModelSpec, L: int) -> dict:
+    """The leaves of L gated delta-rule mixers (engine/hybrid.py has the
+    equations): ONE in-projection q | k | v (whole lane tiles), the taps of
+    the convolution over those channels [tap, channel] (no bias), the
+    low-rank pairs behind the decay (``fa``, ``fb``) and the output gate
+    (``ga``, ``gb``), beta's projection (a column a head), the
+    out-projection; the output norm's weight a head (a ``_norm`` leaf:
+    ones). ``ssm_dt_bias`` a channel ends in a 1 so that a generator drawing
+    normal / sqrt(shape[-2]) draws it small; ``ssm_a_log`` a head lies [1,
+    heads] so that the same law draws it of unit size: a head's decay rate
+    exp(A_log) then spreads over a decade, some heads forget within a token
+    and some keep a dozen (of unit size in EVERY head a state halves a
+    token and the delta rule's correction reads nothing back:
+    benchmark/references/solar_open2.py)."""
+    h, nh, r = spec.hidden_size, spec.ssm_heads, spec.ssm_low_rank
+    inner, chan = nh * spec.ssm_head_dim, spec.ssm_channels
+    return {"ssm_w_in": (L, h, chan),                   # q | k | v
+            "ssm_conv_w": (L, spec.ssm_conv, chan),
+            "ssm_w_fa": (L, h, r), "ssm_w_fb": (L, r, nh * spec.ssm_state),
+            "ssm_w_ga": (L, h, r), "ssm_w_gb": (L, r, inner),
+            "ssm_w_beta": (L, h, nh),
+            "ssm_a_log": (L, 1, nh),
+            "ssm_dt_bias": (L, nh * spec.ssm_state, 1),
+            "ssm_out_norm": (L, spec.ssm_head_dim),
+            "ssm_w_out": (L, inner, h)}
+
+
 def _pattern_shapes(spec: ModelSpec) -> dict:
     """``params["layers"]`` of a block whose sublayers are ONE mixer or
     feed-forward each (``spec.layer_pattern``, config.GROUP): a stack a
@@ -235,6 +262,8 @@ def _pattern_shapes(spec: ModelSpec) -> dict:
         shapes.update(_recurrent_shapes(spec, pattern.count("M")))
     if "L" in pattern:
         shapes.update(_lightning_shapes(spec, pattern.count("L")))
+    if "K" in pattern:
+        shapes.update(_delta_shapes(spec, pattern.count("K")))
     if "E" in pattern:
         shapes.update(_expert_shapes(spec, pattern.count("E")))
     if "D" in pattern:
@@ -246,6 +275,9 @@ def _pattern_shapes(spec: ModelSpec) -> dict:
         n, d = pattern.count("S"), spec.head_dim
         shapes.update({"q_norm": (n, d), "k_norm": (n, d),
                        "wz": (n, spec.hidden_size, spec.num_heads * d)})
+    if spec.attn_gate:      # a * layer gated by sigmoid(u W_z)
+        shapes["wz"] = (pattern.count("*"), spec.hidden_size,
+                        spec.num_heads * spec.head_dim)
     return shapes
 
 

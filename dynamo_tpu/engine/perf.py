@@ -127,7 +127,14 @@ BLOCK_SCOPES = ("attn.index", "mtp", "ssm", "attn.compress", "loop.norm")
 #: keeps its scope and names the sub-scope after it (``mlp+moe.experts``), so
 #: a reader that sums ``mlp`` still counts it. Dense programs have none:
 #: their names, and so SCOPES_VERSION, stand.
-SUBSCOPES = ("moe.router", "moe.experts", "moe.shared")
+SUBSCOPES = ("moe.router", "moe.experts", "moe.shared",
+             # Inside ``ssm``, in programs of a block with delta-rule mixers
+             # alone (engine/hybrid.py): the convolution over q | k | v and
+             # their norms; the decay, beta and the output gate; the
+             # state's update and read (the kernel of engine/recurrence.py
+             # on the chip, ``delta_update`` under XLA) and NOTHING else;
+             # the chunked solve of a prefill.
+             "ssm.conv", "ssm.gates", "ssm.state", "ssm.chunk")
 #: Bump when SCOPES or where a scope is drawn changes. jax's persistent
 #: cache key leaves debug info out (jax/_src/cache_key.py strips it), so an
 #: executable cached by a tree with other scopes would be loaded with ITS

@@ -51,7 +51,11 @@ _LATENT_KEYS = ("wq_a", "wq_b", "wkv_a", "wk_b", "wv_b", "index_wq_b",
                 "index_wk")
 # The Nemotron-H block's recurrent layer: its two projections (the
 # convolution, A, D, dt's bias and the gated norm stay bf16).
-_RECURRENT_KEYS = ("ssm_w_in", "ssm_w_dt", "ssm_w_out")
+_RECURRENT_KEYS = ("ssm_w_in", "ssm_w_dt", "ssm_w_out",
+                   # The delta-rule mixer's low-rank pairs and beta's
+                   # projection (the taps, A_log and dt's bias stay bf16).
+                   "ssm_w_fa", "ssm_w_fb", "ssm_w_ga", "ssm_w_gb",
+                   "ssm_w_beta")
 # The output gate of an attention layer over chosen blocks (MiniCPM-SALA).
 _GATE_KEYS = ("wz",)
 QUANT_LAYER_KEYS = (_BLOCK_KEYS + _LATENT_KEYS + _RECURRENT_KEYS

@@ -38,8 +38,8 @@ def run(tree: str, out_dir: str, cell: str, pallas: bool) -> None:
     kept = []
     wrap = perf.CompileRegistry.wrap
 
-    def keeping(self, program, fn, key=None, labels=None):
-        w = wrap(self, program, fn, key=key, labels=labels)
+    def keeping(self, program, fn, **kw):
+        w = wrap(self, program, fn, **kw)
         kept.append(w)
         return w
 

@@ -49,20 +49,26 @@ def window(runner, pos: int, pages: list[int]):
     return np.asarray(toks)[:, 0].tolist(), np.asarray(lps)[:, 0].tolist()
 
 
-def main(argv: list[str]) -> int:
+def main(argv: list[str], cell: str = CELL, num_pages: int = 339) -> int:
+    """``cell``'s check over the seeds of ``argv`` through a runner whose
+    pool has ``num_pages`` pages (scripts/solar_ref_seeds.py: another
+    cell's)."""
     seeds = [int(s) for s in argv[0].split(",")]
     controls = argv[1].split(",") if len(argv) > 1 else []
-    files = manifest.cell_files(manifest.load_manifest(), CELL)
+    files = manifest.cell_files(manifest.load_manifest(), cell)
     spec = server.model_spec(files["cell"]["config"], files["config"],
                              files["config"]["launch"]["quant"])
     ref = manifest.load_module("references", files["config"]["reference"])
-    config = EngineConfig(model=spec, page_size="auto", num_pages=339,
+    config = EngineConfig(model=spec, page_size="auto", num_pages=num_pages,
                           max_num_seqs=4, decode_window=WINDOW)
     mesh = weights.runner_mesh(config)
     per_prompt = -(-(PROMPT_TOKENS + DECODED) // config.page_size) + 1
     runner = None
+    params = None
     for seed in seeds:
         t0 = time.time()
+        if runner is not None:      # a share of 9.5 GB does not fit twice
+            runner.params = params = None
         params = weights.make_params(spec, mesh, seed)
         if runner is None:
             runner = ModelRunner(config, params=params)

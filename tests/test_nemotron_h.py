@@ -636,7 +636,8 @@ async def test_the_engine_serves_what_the_reference_computes():
         assert engine.allocator.stats()["reuse_hit_blocks"] == 0
         status = engine.perf_status()
         assert status["ssm"] == {
-            "layers": 3, "state_bytes_per_row": SPEC.ssm_state_bytes_per_row,
+            "layers": 3, "kind": "mamba2",
+            "state_bytes_per_row": SPEC.ssm_state_bytes_per_row,
             "state_dtype": "float32", "backend": "xla",
             "row_steps": status["ssm"]["row_steps"],
             "prefix_reuse": "off (recurrent state has no snapshot)"}

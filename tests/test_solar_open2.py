@@ -1,0 +1,772 @@
+"""The Solar-Open2 block (``solar_open2``) at a toy size on the CPU, held to
+benchmark/references/solar_open2.py: every layer a mixer (a gated delta-rule
+recurrence, or gated attention without a rotary embedding) and an expert
+layer of SwiGLU experts, a recurrent state a SLOT beside the pages, a share
+of the routed experts.
+
+What is held: served logprobs against the reference's full forward after a
+whole-prompt prefill, after a prefill in chunks and for the rows of a padded
+batch of unequal prompts; the chunked solve equals the step recurrence at
+every border, alone and over a carried state, with beta at 2; the kernel
+(interpreted) equals the step and leaves dead slots bit for bit; a slot's
+next request answers as a cold run; a preempted row resumes; the eight
+shares add up to the uncut layer; the reader makes the catalog row's spec;
+each refusal names what is lacking.
+"""
+
+import asyncio
+import dataclasses
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from conftest import async_test
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmark.lib import manifest  # noqa: E402
+from dynamo_tpu.engine import hybrid, model, recurrence  # noqa: E402
+from dynamo_tpu.engine.backends import Backends  # noqa: E402
+from dynamo_tpu.engine.config import (EngineConfig, ModelSpec,  # noqa: E402
+                                      SolarOpen2Spec, UnsupportedBlockError,
+                                      block_refusals)
+from dynamo_tpu.engine.engine import TPUEngine  # noqa: E402
+from dynamo_tpu.engine.runner import (PK_CAP, PK_LOGPROB, PK_POS,  # noqa: E402
+                                      PK_PREFIX, PK_SEQLEN, PK_TOPP,
+                                      ModelRunner, PrefillSeq)
+from dynamo_tpu.llm.protocols import PreprocessedRequest  # noqa: E402
+from dynamo_tpu.runtime.context import Context  # noqa: E402
+
+ref = manifest.load_module("references", "solar_open2")
+
+PAGE = 16
+CHUNK = 8
+#: The catalog row's keys at a toy size: both mixers, two periods * K K K,
+#: experts 4 to 7 of 32 held (share 1 of 8), a solve every 8 tokens.
+TOY = {
+    "model_type": "solar_open2", "partial_rotary_factor": 1,
+    "linear_attn_config": {"short_conv_kernel_size": 4, "head_dim": 16,
+                           "num_heads": 4, "num_kv_heads": None},
+    "hidden_size": 64, "num_hidden_layers": 8, "num_attention_heads": 4,
+    "head_dim": 16, "num_key_value_heads": 2, "vocab_size": 48,
+    "intermediate_size": 32, "moe_intermediate_size": 32,
+    "rms_norm_eps": 1e-05, "rope_theta": 10000,
+    "tie_word_embeddings": False, "max_position_embeddings": 2048,
+    "first_k_dense_replace": 0, "use_rope": False, "gqa_interval": 3,
+    "gqa_layers": [0, 4], "use_gqa_gate": True, "kda_use_full_proj": False,
+    "kda_allow_neg_eigval": True, "n_routed_experts": 4,
+    "n_shared_experts": 1, "norm_topk_prob": True,
+    "routed_scaling_factor": 1, "num_experts_per_tok": 4,
+    "chunk_size": CHUNK,
+    "expert_parallel": {"routed_experts": 32, "first_expert": 4},
+}
+
+
+def read_spec(cfg: dict) -> ModelSpec:
+    import tempfile
+    with tempfile.TemporaryDirectory() as d:
+        path = os.path.join(d, "config.json")
+        with open(path, "w", encoding="utf-8") as fh:
+            json.dump({k: v for k, v in cfg.items()
+                       if not (k == "expert_parallel" and v is None)}, fh)
+        return dataclasses.replace(ModelSpec.from_hf_config(path), name="sol")
+
+
+def seeded_params(spec, seed: int):
+    """init_params, then what it draws as ones drawn small from the seed
+    (dt_bias a channel, the selection bias), and a decisive router (with
+    logits of unit size the choice of 4 of 32 flips between two roundings
+    of one state every few tokens)."""
+    params = model.init_params(spec, jax.random.key(seed))
+    key = jax.random.key(seed + 100)
+    layers = params["layers"]
+    for i, name in enumerate(("ssm_dt_bias", "moe_bias")):
+        layers[name] = (0.1 * jax.random.normal(
+            jax.random.fold_in(key, i), layers[name].shape)).astype(
+            jnp.bfloat16)
+    layers["moe_gate"] = layers["moe_gate"] * 8.0
+    return params
+
+
+SPEC = read_spec(TOY)
+PARAMS = seeded_params(SPEC, 11)
+
+
+def config(**kw) -> EngineConfig:
+    defaults = dict(model=SPEC, page_size=PAGE, num_pages=128,
+                    max_pages_per_seq=16, max_num_seqs=4,
+                    prefill_buckets=(32, 64, 128), max_prefill_tokens=64,
+                    attention_backend="xla", decode_window=4,
+                    pipeline_depth=2)
+    defaults.update(kw)
+    return EngineConfig(**defaults)
+
+
+def prompt_of(n: int, seed: int) -> list[int]:
+    return np.random.default_rng(seed).integers(
+        0, SPEC.vocab_size, size=n).tolist()
+
+
+async def collect(engine, prompt, max_tokens, logprobs=None):
+    req = PreprocessedRequest(model="m", token_ids=list(prompt))
+    req.stop_conditions.max_tokens = max_tokens
+    req.stop_conditions.ignore_eos = True
+    if logprobs is not None:
+        req.sampling_options.logprobs = logprobs
+    toks, lps, finish = [], [], None
+    async for out in engine.generate(req, Context()):
+        toks.extend(out.get("token_ids", []))
+        lps.extend(out.get("log_probs") or [])
+        if out.get("finish_reason"):
+            finish = out["finish_reason"]
+            break
+    return toks, lps, finish
+
+
+def close(a, b) -> bool:
+    """Two lists of logprobs of the same tokens agree: the median within
+    0.16 nat and nine in ten within 0.5. Sixteen sublayers 64 wide carry
+    bfloat16's rounding far: the reference computed in bfloat16 stands 0.03
+    to 0.07 (median; 0.2 to 0.9 at worst) from itself in float32 over this
+    file's prompts and the served path 0.05 to 0.14, while one equation
+    switched (``delta``, ``channel_decay``, ``conv``, ``gqa_gate`` false)
+    stands 0.4 to 2.2: ``wrong`` holds the limits to that."""
+    d = np.abs(np.asarray(a, np.float64) - np.asarray(b, np.float64))
+    return bool(np.median(d) < 0.16
+                and (d > 0.5).sum() <= max(1, len(d) // 10))
+
+
+def wrong(served, prompt, generated) -> bool:
+    """No control is ``close`` to what was served: the limits tell a port
+    with one equation wrong from the program."""
+    return not any(close(served, ref.control_logprobs(
+        PARAMS, SPEC, prompt, generated, **switch))
+        for switch in ({"delta": False}, {"channel_decay": False},
+                       {"conv": False}, {"gqa_gate": False}))
+
+
+def reference_logits(params, spec, tokens, **switches):
+    """The reference's logits [len(tokens), vocab], float32."""
+    from benchmark.lib.reference import rms_norm
+    layer = ref.layer_of(spec, **switches)
+    with jax.default_matmul_precision("highest"):
+        x = params["embed"][np.asarray(tokens)].astype(jnp.float32)
+        for i in range(spec.num_layers):
+            x = layer(x, params["layers"], i)
+        h = rms_norm(x, params["final_norm"], spec.rms_norm_eps)
+        return h @ params["lm_head"].astype(jnp.float32)
+
+
+def router_margins(params, spec, tokens) -> np.ndarray:
+    """[len(tokens)]: how far, in the reference, the LAST expert chosen
+    stands above the first one left out, the smallest over the layers."""
+    from benchmark.lib.reference import rms_norm
+    layer, terms_of = ref.layer_of(spec), ref.layer_of(spec, parts=True)
+    layers, k = params["layers"], spec.num_experts_per_tok
+    least = np.full(len(tokens), np.inf)
+    with jax.default_matmul_precision("highest"):
+        x = params["embed"][np.asarray(tokens)].astype(jnp.float32)
+        for i in range(spec.num_layers):
+            # The expert layer of layer i reads what its mixer leaves: the
+            # whole layer less the expert layer's own terms.
+            after = layer(x, layers, i)
+            terms = terms_of(x, layers, i)
+            mixed = after - terms["routed"] - terms["shared"]
+            u = rms_norm(mixed, layers["mixer_norm"][2 * i + 1],
+                         spec.rms_norm_eps)
+            score = jax.nn.sigmoid(
+                u @ layers["moe_gate"][i].astype(jnp.float32)) \
+                + layers["moe_bias"][i][:, 0].astype(jnp.float32)
+            ranked = np.sort(np.asarray(score), axis=-1)[:, ::-1]
+            least = np.minimum(least, ranked[:, k - 1] - ranked[:, k])
+            x = after
+    return least
+
+
+def close_up_to_a_tie(served, prompt, generated) -> bool:
+    """``close`` to the reference's logprobs; or close up to the first
+    token that is half a nat off, and that token (or one of the two
+    before it) stands where the reference's router chose by less than
+    0.02 (tests/test_nemotron_h.py has the why)."""
+    want = ref.reference_logprobs(PARAMS, SPEC, prompt, generated)
+    if close(served, want):
+        return True
+    d = np.abs(np.asarray(served, np.float64) - np.asarray(want, np.float64))
+    at = int(np.argmax(d > 0.5))
+    tokens = list(prompt) + list(generated[:-1])
+    where = len(prompt) - 1 + at
+    margins = router_margins(PARAMS, SPEC, tokens)[max(where - 2, 0):
+                                                   where + 1]
+    return bool((at < 2 or close(served[:at], want[:at]))
+                and margins.min() < 0.02)
+
+
+def same_up_to_a_tie(got, want, prompt) -> bool:
+    """Two greedy streams of one prompt are the same, or part where the
+    reference holds the two tokens within 0.08 of each other."""
+    if list(got) == list(want):
+        return True
+    at = next(i for i, (a, b) in enumerate(zip(got, want)) if a != b)
+    logits = reference_logits(PARAMS, SPEC, list(prompt) + list(want[:at]))
+    return bool(abs(float(logits[-1, got[at]] - logits[-1, want[at]]))
+                < 0.08)
+
+
+# -- the reader ----------------------------------------------------------------
+
+def catalog_row() -> dict:
+    path = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if not os.path.exists(path):
+        pytest.skip("no catalog here")
+    with open(path, encoding="utf-8") as fh:
+        rows = [json.loads(line) for line in fh if line.strip()]
+    return next(r for r in rows if r["name"] == "Solar-Open2-250B")
+
+
+def test_the_reader_makes_the_catalog_row_s_spec():
+    spec = read_spec(catalog_row()["config"])
+    assert isinstance(spec, SolarOpen2Spec)
+    assert spec.layer_pattern == "*EKEKEKE" * 12 and spec.ssm_kind == "K"
+    groups = hybrid.groups_of(spec)
+    assert (spec.num_layers, spec.ssm_layers, spec.expert_layers,
+            spec.pool_layers) == (48, 36, 48, 12)
+    assert groups.mixer_index[:5] == (-1, 0, 1, 2, -1)
+    assert groups.attn_index[:5] == (0, -1, -1, -1, 1)
+    assert spec.kv_entry == (8, (128, 128))
+    assert spec.ssm_state_shapes == ((64, 128, 128), (3, 24576))
+    assert (spec.router_width, spec.num_experts, spec.num_experts_per_tok,
+            spec.expert_size, spec.num_shared_experts) == (320, 320, 8, 1280,
+                                                           1)
+    assert (spec.ffn_act, spec.moe_router, spec.moe_select_bias,
+            spec.attn_gate, spec.ssm_beta_scale, spec.ssm_low_rank) \
+        == ("silu", "sigmoid_topk", True, True, 2.0, 128)
+    shapes = model.param_shapes(spec)["layers"]
+    assert shapes["ssm_w_in"] == (36, 4096, 24576)
+    assert shapes["ssm_conv_w"] == (36, 4, 24576)
+    assert shapes["ssm_w_fb"] == shapes["ssm_w_gb"] == (36, 128, 8192)
+    assert shapes["ssm_a_log"] == (36, 1, 64)
+    assert shapes["wz"] == shapes["wq"] == (12, 4096, 8192)
+    assert shapes["moe_w_gate"] == (48, 320, 4096, 1280)
+    assert shapes["mixer_norm"] == (96, 4096)
+    assert abs(spec.num_params() / 250.3e9 - 1) < 2e-3
+    # The chip's share: three periods, 40 of 320 experts, an eighth of the
+    # vocabulary.
+    cut = read_spec({**catalog_row()["config"], "num_hidden_layers": 12,
+                     "gqa_layers": [0, 4, 8], "n_routed_experts": 40,
+                     "vocab_size": 24576,
+                     "expert_parallel": {"routed_experts": 320,
+                                         "first_expert": 0}})
+    assert cut.layer_pattern == "*EKEKEKE" * 3
+    assert abs(cut.num_params() / 9.52e9 - 1) < 2e-3
+    assert cut.ssm_state_bytes_per_row == 9 * (64 * 128 * 128 * 4
+                                               + 3 * 24576 * 2)
+    assert cut.kv_bytes_per_token() == 12288
+
+
+def test_the_benchmark_s_file_reads_as_the_share():
+    path = os.path.join(manifest.BENCH, "configs",
+                        "solar-open2-250b-ep8-int8.json")
+    spec = ModelSpec.from_hf_config(path)
+    assert (spec.layer_pattern, spec.num_experts, spec.router_width,
+            spec.first_expert, spec.vocab_size) == (
+        "*EKEKEKE" * 3, 40, 320, 0, 24576)
+
+
+@pytest.mark.parametrize("key,value,names", [
+    ("use_rope", True, "rotate nothing"),
+    ("kda_use_full_proj", True, "low-rank"),
+    ("first_k_dense_replace", 1, "expert layer"),
+    ("n_shared_experts", 2, "shared"),
+    ("scoring_func", "softmax", "sigmoid"),
+    ("gqa_layers", [0, 9], "not among"),
+    ("linear_attn_config", {**TOY["linear_attn_config"], "num_kv_heads": 2},
+     "keys of its own"),
+])
+def test_the_reader_refuses_what_is_not_written_down(key, value, names):
+    with pytest.raises(UnsupportedBlockError, match=names):
+        read_spec({**TOY, key: value})
+
+
+def test_a_model_has_recurrent_mixers_of_one_kind():
+    from dynamo_tpu.engine.config import _check_groups
+    with pytest.raises(UnsupportedBlockError, match="more than one kind"):
+        _check_groups("*EKEMEKE")
+    with pytest.raises(ValueError, match="layers of K or"):
+        dataclasses.replace(SPEC, layer_pattern="*EKEMEKE" * 2)
+    assert not ModelSpec().ssm_kind and SPEC.ssm_kind == "K"
+
+
+# -- refusals ------------------------------------------------------------------
+
+@pytest.mark.parametrize("asked,names", [
+    (dict(spec_decode="ngram", spec_k=2), "rejected draft"),
+    (dict(spec_decode="mtp", spec_k=1), "rejected draft"),
+    (dict(host_cache_pages=8), "recurrent state"),
+    (dict(kv_disk_cache_dir="/tmp/x"), "recurrent state"),
+    (dict(tp=2), "partitioning rule"),
+    (dict(tp=2), "ONE share"),
+    (dict(ring_attention=True, sp=2), "hand-over"),
+    (dict(pp_microbatch=True, pp=2), "recurrent state"),
+    (dict(max_adapters=2), "have none of them"),
+    (dict(quant_kv="int8"), "bfloat16 pool"),
+])
+def test_each_engine_path_that_lacks_the_state_is_refused(asked, names):
+    refusals = block_refusals(SPEC, config(**asked))
+    assert any(names in str(r) for r in refusals), [str(r) for r in refusals]
+    with pytest.raises(UnsupportedBlockError):
+        ModelRunner(config(**asked), params=PARAMS)
+
+
+@pytest.mark.parametrize("asked,names", [
+    (dict(kv_transfer=True), "has no parcel"),
+    (dict(checkpoint=True), "tensor-name map"),
+    (dict(embeddings=True), "token rows alone"),
+])
+def test_a_parcel_a_checkpoint_and_embeddings_are_refused(asked, names):
+    refusals = block_refusals(SPEC, **asked)
+    assert any(names in str(r) for r in refusals), [str(r) for r in refusals]
+
+
+# -- the recurrence --------------------------------------------------------------
+
+def _mixer_inputs(rows: int, tokens: int, seed: int, carried: bool = False):
+    layers = PARAMS["layers"]
+    lp = {k: v[1] for k, v in layers.items() if k.startswith("ssm_")}
+    key = jax.random.key(seed)
+    h = jax.random.normal(key, (rows, tokens, SPEC.hidden_size)).astype(
+        jnp.bfloat16)
+    s_shape, c_shape = SPEC.ssm_state_shapes
+    if carried:
+        return (lp, h, jax.random.normal(jax.random.fold_in(key, 1),
+                                         (rows, *s_shape), jnp.float32),
+                jax.random.normal(jax.random.fold_in(key, 2),
+                                  (rows, *c_shape)).astype(jnp.bfloat16))
+    return lp, h, jnp.zeros((rows, *s_shape), jnp.float32), jnp.zeros(
+        (rows, *c_shape), jnp.bfloat16)
+
+
+@pytest.mark.parametrize("carried", [False, True],
+                         ids=["alone", "over a carried state"])
+@pytest.mark.parametrize("tokens", [1, CHUNK - 1, CHUNK, CHUNK + 1,
+                                    3 * CHUNK + 5])
+def test_the_chunked_solve_equals_the_step_recurrence(tokens, carried):
+    """Chunks of 8 through the triangular solve against one token at a
+    time: the outputs, the state and the convolution's inputs after the
+    last token; a row of a padded batch stops at its own last token and a
+    row of NO token keeps state and taps bit for bit."""
+    lp, h, state, conv = _mixer_inputs(3, tokens, 3, carried)
+    lens = jnp.asarray([tokens, max(tokens - 3, 1), 0])
+    valid = jnp.arange(tokens)[None, :] < lens[:, None]
+    out, s_end, c_end = hybrid.delta_prefill(h, lp, SPEC, state, conv, valid,
+                                             lens)
+    held_s, held_c = state, conv
+    outs = []
+    for t in range(tokens):
+        o, state, conv = hybrid.delta_step(h[:, t], lp, SPEC, state, conv,
+                                           valid[:, t])
+        outs.append(o)
+    steps = jnp.stack(outs, axis=1).astype(jnp.float32)
+    got = out.astype(jnp.float32)
+    scale = float(jnp.abs(steps).max())
+    for row, n in enumerate(np.asarray(lens)):
+        np.testing.assert_allclose(got[row, :n], steps[row, :n],
+                                   atol=0.02 * scale)
+    np.testing.assert_allclose(s_end, state, rtol=1e-4, atol=1e-4 * max(
+        1.0, float(jnp.abs(state).max())))
+    np.testing.assert_array_equal(np.asarray(c_end, np.float32),
+                                  np.asarray(conv, np.float32))
+    np.testing.assert_array_equal(np.asarray(s_end[2]).view(np.int32),
+                                  np.asarray(held_s[2]).view(np.int32))
+    np.testing.assert_array_equal(np.asarray(c_end[2], np.float32),
+                                  np.asarray(held_c[2], np.float32))
+
+
+def _delta_terms(rows: int, tokens: int, seed: int, beta_logit: float = 0.0,
+                 rate: float = 1.0):
+    n, dk, dv = SPEC.ssm_heads, SPEC.ssm_state, SPEC.ssm_head_dim
+    keys = jax.random.split(jax.random.key(seed), 6)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(jax.random.normal(keys[0], (rows, tokens, n, dk))) * dk ** -0.5
+    k = unit(jax.random.normal(keys[1], (rows, tokens, n, dk)))
+    v = jax.random.normal(keys[2], (rows, tokens, n, dv))
+    g = -rate * jnp.exp(jax.random.normal(keys[3], (rows, tokens, n, dk)))
+    beta = 2.0 * jax.nn.sigmoid(
+        beta_logit + jax.random.normal(keys[4], (rows, tokens, n)))
+    state = jax.random.normal(keys[5], (rows, n, dv, dk))
+    return q, k, v, g, beta, state
+
+
+def _by_steps(q, k, v, g, beta, state):
+    ys = []
+    for t in range(q.shape[1]):
+        y, state = hybrid.delta_update(state, q[:, t], k[:, t], v[:, t],
+                                       g[:, t], beta[:, t])
+        ys.append(y)
+    return jnp.stack(ys, axis=1).reshape(*q.shape[:2], -1), state
+
+
+@pytest.mark.parametrize("case,beta_logit,rate", [
+    ("a reflection: beta at 2 and hardly any decay", 12.0, 1e-3),
+    ("a channel decays by e^-40 a token", 0.0, 40.0),
+    ("a slow state under a unit beta", 0.0, 0.05),
+])
+def test_the_solve_holds_where_a_product_of_exponents_would_not(
+        case, beta_logit, rate):
+    """``delta_chunked`` against ``delta_update`` a token at a time where
+    the form matters: with beta at 2 every write is a reflection (I - 2 k
+    k^T keeps the state's size, and powers of the strict triangle grow by
+    orders of magnitude before they cancel), and with a decay of e^-40 a
+    token e^(-G_s) overflows float32 inside one chunk."""
+    q, k, v, g, beta, state = _delta_terms(2, 3 * CHUNK + 5, 5, beta_logit,
+                                           rate)
+    if beta_logit:
+        assert float(beta.min()) > 1.999
+    want, s_want = _by_steps(q, k, v, g, beta, state)
+    got, s_got = hybrid.delta_chunked(q, k, v, g, beta, state, CHUNK)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4 * float(
+        jnp.abs(want).max()))
+    np.testing.assert_allclose(s_got, s_want, rtol=2e-4, atol=2e-4 * float(
+        jnp.abs(s_want).max()))
+    if beta_logit:      # the reflection kept the state's size
+        assert 0.5 < float(jnp.linalg.norm(s_want)
+                           / jnp.linalg.norm(state)) < 3.0
+
+
+def test_rows_past_the_limit_go_in_turns():
+    q, k, v, g, beta, state = _delta_terms(hybrid.DELTA_ROWS + 3, 11, 6)
+    want, s_want = _by_steps(q, k, v, g, beta, state)
+    got, s_got = hybrid.delta_chunked(q, k, v, g, beta, state, CHUNK)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=1e-4)
+    np.testing.assert_allclose(s_got, s_want, rtol=2e-4, atol=1e-4)
+
+
+def test_each_control_moves_the_logits():
+    """Under init_params' law every equation the reference can switch is a
+    first-order part of the output: a wrong port shows."""
+    tokens = prompt_of(40, 5)
+    full = reference_logits(PARAMS, SPEC, tokens)
+    for switch in ({"delta": False}, {"neg_eigval": False},
+                   {"channel_decay": False}, {"conv": False},
+                   {"qk_l2norm": False}, {"gqa_gate": False},
+                   {"shared": False}, {"scaling": 2.0}, {"bias": False}):
+        wrong = reference_logits(PARAMS, SPEC, tokens, **switch)
+        assert float(jnp.abs(wrong - full).mean()) > 0.01, switch
+
+
+# -- the kernel of the decode step ------------------------------------------------
+
+WALKS = {
+    "every row live": [True] * 6,
+    "no row live": [False] * 6,
+    "live rows scattered, the last slot among them":
+        [False, True, False, False, True, True],
+    "one live row": [False, False, True, False, False, False],
+}
+
+
+def _bits(a) -> np.ndarray:
+    return np.asarray(a).view(np.int32)
+
+
+@pytest.mark.parametrize("walk", list(WALKS))
+def test_the_kernel_updates_the_live_rows_and_touches_no_other(walk):
+    """engine/recurrence.py's second form through the Pallas interpreter
+    against ``hybrid.delta_update`` at the toy's widths, three steps of one
+    layer of a stack of three: a live row's output and new state are the
+    definition's to float32 rounding; a dead slot (NaN in its state from
+    the start: whoever reads or writes it shows) and the other layers keep
+    their state BITWISE."""
+    on = np.asarray(WALKS[walk])
+    rows, layer = len(on), 1
+    q, k, v, g, beta, _ = _delta_terms(rows, 3, 7)
+    s_shape, _ = SPEC.ssm_state_shapes
+    states = 0.5 * jax.random.normal(jax.random.key(8),
+                                     (3, rows, *s_shape), jnp.float32)
+    states = jnp.where(on[None, :, None, None, None], states, jnp.nan)
+    live = jnp.asarray(on)
+    walked = hybrid.live_walk(live)
+    want = jnp.where(on[:, None, None, None], states[layer], 0.0)
+    for t in range(3):
+        held = states
+        g_t = jnp.where(live[:, None, None], g[:, t], 0.0)
+        beta_t = jnp.where(live[:, None], beta[:, t], 0.0)
+        states, y = recurrence.delta_state_step(
+            states, jnp.int32(layer), *walked, jnp.exp(g_t), k[:, t],
+            q[:, t], v[:, t], beta_t, interpret=True)
+        y_want, want = hybrid.delta_update(want, q[:, t], k[:, t], v[:, t],
+                                           g_t, beta_t)
+        np.testing.assert_allclose(np.asarray(y)[on], np.asarray(y_want)[on],
+                                   rtol=1e-5, atol=1e-5)
+        assert not np.asarray(y)[~on].any()
+        np.testing.assert_allclose(np.asarray(states[layer])[on],
+                                   np.asarray(want)[on], rtol=2e-6, atol=1e-6)
+        np.testing.assert_array_equal(_bits(states[layer])[~on],
+                                      _bits(held[layer])[~on])
+        np.testing.assert_array_equal(_bits(states[::2]), _bits(held[::2]))
+
+
+def test_the_window_step_walks_the_rows_it_counts():
+    """hybrid.window_step with the kernel (interpreted) against XLA's
+    ``delta_update``: the live rows' logits, both state arrays and the
+    count of live rows agree."""
+    rows, window, pages = 4, 4, 8
+    nkv, d = SPEC.num_kv_heads, SPEC.head_dim
+    pool = jnp.zeros((SPEC.pool_layers, nkv, pages, PAGE, d), jnp.bfloat16)
+    buf = jnp.zeros((SPEC.pool_layers, nkv, rows, window, d), jnp.bfloat16)
+    s_shape, c_shape = SPEC.ssm_state_shapes
+    state = (jax.random.normal(jax.random.key(1),
+                               (SPEC.ssm_layers, rows, *s_shape)),
+             jnp.zeros((SPEC.ssm_layers, rows, *c_shape), jnp.bfloat16))
+    live = jnp.asarray([True, False, True, True])
+    args = (PARAMS, SPEC, pool, pool, buf, buf, jnp.int32(0),
+            jnp.asarray([3, 0, 5, 7]), jnp.zeros((rows, 2), jnp.int32),
+            jnp.zeros(rows, jnp.int32), state, live)
+    want = hybrid.window_step(*args)
+    got = hybrid.window_step(*args, backends=Backends(
+        ssm="kernel", interpret=True))
+    on = np.asarray(live)
+    np.testing.assert_allclose(np.asarray(got[0], np.float32)[on],
+                               np.asarray(want[0], np.float32)[on],
+                               atol=0.02 * float(jnp.abs(want[0]).max()))
+    for a, b in zip(got[3], want[3]):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), rtol=2e-6,
+                                   atol=1e-6)
+    # A dead row's state stands under XLA's update too: g and beta are 0.
+    np.testing.assert_array_equal(_bits(want[3][0][:, 1]),
+                                  _bits(state[0][:, 1]))
+    assert float(got[4]["ssm"][0, 0]) == float(want[4]["ssm"][0, 0]) == 3.0
+
+
+# -- the runner ------------------------------------------------------------------
+
+def _window(runner, rows: dict, steps: int):
+    packed = np.zeros((runner.config.max_num_seqs, PK_PREFIX + 8), np.int32)
+    packed[:, PK_TOPP] = np.float32(1.0).view(np.int32)
+    for slot, (pos, pages) in rows.items():
+        packed[slot, PK_POS] = pos
+        packed[slot, PK_SEQLEN] = pos + 1
+        packed[slot, PK_CAP] = len(pages) * PAGE
+        packed[slot, PK_LOGPROB] = 1
+        packed[slot, PK_PREFIX:PK_PREFIX + len(pages)] = pages
+    toks, lps, _, _, counted = runner.decode_window(packed, steps)
+    return np.asarray(toks), np.asarray(lps), counted
+
+
+def test_a_padded_batch_of_unequal_prompts_and_its_windows():
+    """Three prompts of 9, 21 and 30 tokens in one bucket of 32, then two
+    windows over their slots with a dead slot between them: each row's
+    logits and logprobs are the reference's for its own tokens, the dead
+    slot's state stays as it was, and the window counts its live rows."""
+    runner = ModelRunner(config(), params=PARAMS)
+    assert runner.backends.ssm == "xla"
+    prompts = [prompt_of(n, 30 + n) for n in (9, 21, 30)]
+    slots, pages = [0, 1, 3], [[1, 2], [3, 4], [5, 6, 7]]
+    seqs = [PrefillSeq(tokens=np.asarray(p, np.int32), start_pos=0,
+                       chunk_pages=np.asarray(pg[:2], np.int32),
+                       hist_pages=None, sampling=(0.0, 0, 1.0))
+            for p, pg in zip(prompts, pages)]
+    runner.ssm_state = runner.ssm_state.at[:, 2].set(7.0)
+    runner.conv_state = runner.conv_state.at[:, 2].set(3.0)
+    first = np.asarray(runner.prefill_batch(seqs, slots=slots)["tokens"])
+    logits = np.asarray(runner.last_prefill_logits, np.float32)
+    for row, prompt in enumerate(prompts):
+        want = reference_logits(PARAMS, SPEC, prompt)[-1]
+        assert float(np.abs(logits[row] - want).max()) < 0.15 * float(
+            np.abs(want).max())
+    rows = {s: (len(p), pg) for s, p, pg in zip(slots, prompts, pages)}
+    toks, lps = [], []
+    for w in range(2):
+        t, lp, counted = _window(
+            runner, {s: (pos + 4 * w, pg) for s, (pos, pg) in rows.items()},
+            4)
+        toks.append(t)
+        lps.append(lp)
+        assert float(np.asarray(counted["ssm"])[0]) == 12.0
+    toks, lps = np.concatenate(toks), np.concatenate(lps)
+    for row, (slot, prompt) in enumerate(zip(slots, prompts)):
+        assert close_up_to_a_tie(
+            lps[:, slot], prompt + [int(first[row])],
+            [int(t) for t in toks[:, slot]]), (slot, lps[:, slot])
+    assert float(jnp.abs(runner.ssm_state[:, 2] - 7.0).max()) == 0.0
+    assert float(jnp.abs(runner.conv_state[:, 2].astype(jnp.float32)
+                         - 3.0).max()) == 0.0
+    memory = runner.memory_breakdown()
+    assert memory["ssm_state_bytes"] == 4 * SPEC.ssm_state_bytes_per_row \
+        == runner.ssm_state.nbytes + runner.conv_state.nbytes
+
+
+def test_the_window_program_with_the_kernel_is_the_xla_program_s():
+    """The runner's window program with the kernel interpreted inside its
+    scan against XLA's: the same tokens, logprobs to rounding."""
+    got = {}
+    for ssm in ("xla", "kernel"):
+        runner = ModelRunner(config(), params=PARAMS)
+        runner.backends = dataclasses.replace(runner.backends, ssm=ssm)
+        prompt = prompt_of(21, 51)
+        seq = PrefillSeq(tokens=np.asarray(prompt, np.int32), start_pos=0,
+                         chunk_pages=np.asarray([1, 2], np.int32),
+                         hist_pages=None, sampling=(0.0, 0, 1.0))
+        runner.prefill_batch([seq], slots=[2])
+        toks, lps, _ = _window(runner, {2: (len(prompt), [1, 2, 3])}, 4)
+        assert runner._get_window(4, 4)._labels["ssm_backend"] == ssm
+        got[ssm] = (toks[:, 2], lps[:, 2])
+    np.testing.assert_array_equal(got["xla"][0], got["kernel"][0])
+    np.testing.assert_allclose(got["xla"][1], got["kernel"][1], atol=2e-3)
+
+
+# -- the engine ------------------------------------------------------------------
+
+@async_test
+async def test_the_engine_serves_what_the_reference_computes():
+    """Whole-prompt prefill then decode windows; a prompt past the chunk
+    budget in three chunks (state and taps carried across chunk borders,
+    the attention layers over history pages); facts, counters and scopes."""
+    engine = TPUEngine(config(max_prefill_tokens=32), params=PARAMS)
+    engine.start()
+    try:
+        for seed, n, cap in ((1, 19, 21), (2, 31, 14), (3, 80, 18),
+                             (4, 66, 12)):
+            prompt = prompt_of(n, seed)
+            got, lps, finish = await collect(engine, prompt, cap, logprobs=1)
+            assert len(got) == cap and finish == "length"
+            assert close_up_to_a_tie(lps, prompt, got), (n, lps)
+            assert wrong(lps, prompt, got), n
+        assert engine.chunk_dispatch_count >= 6     # 80 and 66 in threes
+        assert engine.prefix_hit_blocks == 0
+        status = engine.perf_status()
+        assert status["ssm"] == {
+            "layers": 6, "kind": "delta_rule",
+            "state_bytes_per_row": SPEC.ssm_state_bytes_per_row,
+            "state_dtype": "float32", "backend": "xla",
+            "row_steps": status["ssm"]["row_steps"],
+            "prefix_reuse": "off (recurrent state has no snapshot)"}
+        assert status["ssm"]["row_steps"] >= 21 + 14 + 18 + 12 - 4
+        assert status["moe"]["experts"] == 4
+        fn = max(engine.runner._window_cache.values(),
+                 key=lambda w: w._calls)
+        drawn = {part for name in fn.ops_by_scope().values() if name
+                 for part in name.split("+")}
+        assert {"ssm", "ssm.state", "ssm.conv", "ssm.gates", "mlp",
+                "moe.experts", "attn.core"} <= drawn, drawn
+        fn = max(engine.runner._prefill_cache.values(),
+                 key=lambda w: w._calls)
+        drawn = {part for name in fn.ops_by_scope().values() if name
+                 for part in name.split("+")}
+        assert {"ssm", "ssm.chunk", "ssm.conv"} <= drawn, drawn
+    finally:
+        engine.stop()
+
+
+@async_test
+async def test_a_slot_s_next_request_answers_as_a_cold_run():
+    cold = TPUEngine(config(max_num_seqs=1), params=PARAMS)
+    cold.start()
+    try:
+        want, want_lp, _ = await collect(cold, prompt_of(27, 8), 12,
+                                         logprobs=1)
+    finally:
+        cold.stop()
+    engine = TPUEngine(config(max_num_seqs=1), params=PARAMS)
+    engine.start()
+    try:
+        first, first_lp, _ = await collect(engine, prompt_of(40, 7), 6,
+                                           logprobs=1)
+        got, got_lp, _ = await collect(engine, prompt_of(27, 8), 12,
+                                       logprobs=1)
+        assert got == want
+        np.testing.assert_allclose(got_lp, want_lp, atol=1e-5)
+        again, again_lp, _ = await collect(engine, prompt_of(40, 7), 6,
+                                           logprobs=1)
+        assert again == first
+        np.testing.assert_allclose(again_lp, first_lp, atol=1e-5)
+        assert engine.prefix_hit_blocks == 0
+        assert not engine.allocator.cached      # no hash was registered
+    finally:
+        engine.stop()
+
+
+@async_test
+async def test_a_preempted_row_resumes_token_for_token():
+    prompts = [prompt_of(24, 40 + i) for i in range(3)]
+    alone = TPUEngine(config(), params=PARAMS)
+    alone.start()
+    try:
+        want = [(await collect(alone, p, 40))[0] for p in prompts]
+    finally:
+        alone.stop()
+    engine = TPUEngine(config(num_pages=9), params=PARAMS)
+    engine.start()
+    try:
+        tasks = []
+        for prompt in prompts:
+            tasks.append(asyncio.ensure_future(collect(engine, prompt, 40)))
+            await asyncio.sleep(0.05)
+        results = await asyncio.gather(*tasks)
+        assert engine.preempt_count > 0
+        for prompt, (toks, _, _), alone_toks in zip(prompts, results, want):
+            assert len(toks) == 40
+            assert same_up_to_a_tie(toks, alone_toks, prompt), (toks,
+                                                                alone_toks)
+    finally:
+        engine.stop()
+
+
+# -- the share -------------------------------------------------------------------
+
+def test_the_eight_shares_add_up_to_the_uncut_layer():
+    """The routed parts of all eight shares, ONE shared expert and the
+    mixer and norms counted once are what the uncut reference gives for the
+    whole layer; and the program's expert layer over one share is that
+    share's part."""
+    whole = read_spec({**TOY, "n_routed_experts": 32,
+                       "expert_parallel": None})
+    params = seeded_params(whole, 5)
+    n = 24
+    x = jax.random.normal(jax.random.key(9), (n, whole.hidden_size))
+    x = x.astype(jnp.bfloat16).astype(jnp.float32)
+    index = 1                               # a K layer and its experts
+    stacks = ("moe_w_gate", "moe_w_up", "moe_w_down")
+    with jax.default_matmul_precision("highest"):
+        total = ref.layer_of(whole)(x, params["layers"], index)
+        parts = ref.layer_of(whole, parts=True)(x, params["layers"], index)
+        routed, mixed = 0.0, None
+        for first in range(0, 32, 4):
+            spec = dataclasses.replace(whole, num_experts=4,
+                                       first_expert=first)
+            layers = dict(params["layers"])
+            for key in stacks:
+                layers[key] = layers[key][:, first:first + 4]
+            share = ref.layer_of(spec, parts=True)(x, layers, index)
+            full = ref.layer_of(spec)(x, layers, index)
+            # What a share's layer leaves less its own expert terms: the
+            # mixer's output on the stream, the same on every share.
+            after_mixer = full - share["routed"] - share["shared"]
+            if mixed is not None:
+                np.testing.assert_allclose(after_mixer, mixed, atol=1e-5)
+            mixed = after_mixer
+            routed = routed + share["routed"]
+            np.testing.assert_allclose(share["shared"], parts["shared"],
+                                       atol=1e-6)
+            if first == 4:
+                mine, mine_layers, mine_spec = share, layers, spec
+    np.testing.assert_allclose(routed, parts["routed"], atol=1e-5)
+    np.testing.assert_allclose(mixed + routed + parts["shared"], total,
+                               atol=1e-5)
+    assert float(jnp.abs(parts["routed"]).mean()) > 0.02
+    assert float(jnp.abs(mixed - x).mean()) > 0.02
+    # The program's expert layer over share 1 of 8.
+    from benchmark.lib.reference import rms_norm
+    lp = {k: v[index] for k, v in mine_layers.items()
+          if k.startswith(("moe_", "shared_"))}
+    h = rms_norm(mixed, mine_layers["mixer_norm"][2 * index + 1],
+                 whole.rms_norm_eps)
+    got = model.ffn_block(h.astype(jnp.bfloat16), lp, mine_spec)
+    want = mine["routed"] + mine["shared"]
+    assert float(jnp.abs(got.astype(jnp.float32) - want).max()) \
+        < 0.05 * float(jnp.abs(want).max())

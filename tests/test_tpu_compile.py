@@ -1038,3 +1038,106 @@ def test_looped_programs_compile_for_v5e_with_the_pool_where_it_lies(v5e):
             "fusion"] * 2
     # 0.75 GiB of fresh K and V and as much of their page blocks.
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+def _delta_runner(v5e, rows=32, pages=3000):
+    """A ModelRunner that places nothing, for the Solar-Open2 block at its
+    published widths (delta-rule mixers of 64 heads over a state of 128 x
+    128 a head, a convolution over 24,576 channels; 64 query heads over 8
+    KV heads of 128, gated; 4 SwiGLU experts of 1,280 held of a router over
+    320 and one shared) and ONE period of its layers (* K K K, an expert
+    layer behind each), a narrow vocabulary, int8 weights. Returns (runner,
+    spec, params as shapes, s)."""
+    from dynamo_tpu.engine.config import EngineConfig, SolarOpen2Spec
+    from dynamo_tpu.engine.model import param_shapes
+    from dynamo_tpu.engine.quant import QUANT_LAYER_KEYS, QTensor
+    from dynamo_tpu.engine.runner import ModelRunner
+    spec = SolarOpen2Spec(
+        name="delta", vocab_size=1024, hidden_size=4096,
+        intermediate_size=10240, num_layers=4, num_heads=64, num_kv_heads=8,
+        head_dim=128, rms_norm_eps=1e-5, num_experts=4,
+        num_experts_per_tok=8, moe_intermediate_size=1280,
+        num_routed_experts=320, num_shared_experts=1,
+        layer_pattern="*EKEKEKE", ssm_heads=64, ssm_head_dim=128,
+        ssm_groups=64, ssm_state=128, ssm_conv=4, ssm_low_rank=128,
+        quant="int8")
+    assert (spec.pool_layers, spec.ssm_layers, spec.kv_entry) == (
+        1, 3, (8, (128, 128)))
+    runner = object.__new__(ModelRunner)
+    runner.spec = spec
+    runner.config = EngineConfig(model=spec, num_pages=pages,
+                                 max_num_seqs=rows)
+    page = runner.config.resolve_page_size("tpu")
+    assert page == 32       # as Command A+'s 8 KV heads
+    runner.config = EngineConfig(model=spec, page_size=page, num_pages=pages,
+                                 max_num_seqs=rows)
+    runner.quant_kv, runner.lora, runner.draft_dev = None, None, None
+    runner._window_cache, runner._prefill_cache = {}, {}
+    runner.backends = choose(runner.config, spec, "tpu", 1, None)
+    assert (runner.backends.attention, runner.backends.kv_commit,
+            runner.backends.ssm) == ("pallas", "in_place", "kernel")
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def q(shape):
+        return QTensor(s(shape, jnp.int8),
+                       s((*shape[:-2], 1, shape[-1]), jnp.float32))
+
+    shapes = param_shapes(spec)
+    params = {"layers": {k: q(v) if k in QUANT_LAYER_KEYS
+                         else s(v, jnp.bfloat16)
+                         for k, v in shapes["layers"].items()},
+              "embed": QTensor(s(shapes["embed"], jnp.int8),
+                               s((1, shapes["embed"][1]), jnp.float32)),
+              "final_norm": s(shapes["final_norm"], jnp.bfloat16),
+              "lm_head": q(shapes["lm_head"])}
+    return runner, spec, params, s
+
+
+def test_delta_programs_compile_for_v5e_with_the_state_where_it_lies(v5e):
+    """The window program of the Solar-Open2 block at its published widths,
+    one period, pool and state donated: the delta rule's kernel
+    (engine/recurrence.py ``delta_state_step``: three row buffers of 4 MB,
+    the decay, k, q and v of every slot 1 MB each in VMEM) compiles for
+    Mosaic, is handed the float32 state (32 slots x 3 layers x 4 MB) whole
+    and aliased to its output, and NOTHING else in the optimised program has
+    the state's shape (the update reads the state before it writes it: in
+    VMEM, not by a second pass over HBM). And a prefill program of 2 x 512
+    tokens: the chunked solve (``triangular_solve`` a chunk of 32) compiles
+    for the chip within a quarter of what is free beside weights, state
+    and pool."""
+    from dynamo_tpu.engine.runner import _PF_HDR, PK_PREFIX
+    rows, window, pages = 32, 8, 3000
+    runner, spec, params, s = _delta_runner(v5e, rows, pages)
+    page = runner.config.page_size
+    table = runner.config.max_pages_per_seq // 2
+    pool = (1, 8, pages, page, 128)
+    s_shape, c_shape = spec.ssm_state_shapes
+    assert (s_shape, c_shape) == ((64, 128, 128), (3, 24576))
+    state = (3, rows, *s_shape)
+    arrays = (s(state, jnp.float32), s((3, rows, *c_shape), jnp.bfloat16))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    fn = runner._get_window(window, table)
+    assert fn._labels["ssm_backend"] == "kernel"
+    lowered = fn.lower(
+        params, s(pool, jnp.bfloat16), s(pool, jnp.bfloat16),
+        s((rows,), jnp.int32), s((rows, PK_PREFIX + table), jnp.int32),
+        s(key.shape, key.dtype), state=arrays)
+    assert lowered.as_text().count(
+        "func.func private @delta_state_step") == 1
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+    shape = "f32[" + ",".join(map(str, state)) + "]"
+    aliased = [line for line in text.splitlines()
+               if "ssm_delta_step" in line and "custom-call(" in line]
+    assert len(aliased) == 1 and shape in aliased[0] \
+        and "output_to_operand_aliasing" in aliased[0], aliased
+    assert pool_sized_ops(text, state) == []
+    bucket, batch = 512, 2
+    fn = runner._get_prefill(bucket, batch, False)
+    compiled = fn.lower(
+        params, s(pool, jnp.bfloat16), s(pool, jnp.bfloat16),
+        s((batch, _PF_HDR + bucket + bucket // page + 1), jnp.int32),
+        s(key.shape, key.dtype), state=arrays).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 600 << 20
