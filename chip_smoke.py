@@ -650,12 +650,18 @@ async def phase_kernels(args, jax, rng, keep: dict):
     # on the chip the recurrence's SECOND form (the decayed state read
     # before it is written, in the one visit) against XLA's delta_update;
     # the longest prompt's chunks solve the delta rule over a carried state.
+    # Every routed expert chosen (8 of 8, as the latent block's): with 2 of
+    # 8 a router that stands within 0.01 of a tie chooses another expert
+    # under the other runner's rounding, and 9 of 18 pairs of two correct
+    # runners read 0.15 to 0.75 nat at such a token; with all 8 none of 18
+    # passes 0.046 (PERF.md section 6, PR 53). The rounds of the Cohere2-MoE
+    # and Nemotron-H blocks are where a router's choice is compared.
     from dynamo_tpu.engine.config import SolarOpen2Spec
     delta = SolarOpen2Spec(
         name="smoke-delta", vocab_size=2048, hidden_size=512,
         intermediate_size=1024, num_layers=4, num_heads=64, num_kv_heads=8,
         head_dim=128, rms_norm_eps=1e-5, num_experts=4,
-        num_experts_per_tok=2, moe_intermediate_size=256,
+        num_experts_per_tok=8, moe_intermediate_size=256,
         num_routed_experts=8, first_expert=4, num_shared_experts=1,
         layer_pattern="*EKEKEKE", ssm_heads=64, ssm_head_dim=128,
         ssm_groups=64, ssm_state=128, ssm_conv=4, ssm_low_rank=128)

@@ -263,10 +263,20 @@ class ModelSpec:
         """What ONE row keeps in ONE recurrent layer: the state S [heads,
         head_dim, state] (float32) and the convolution's last inputs
         [ssm_conv - 1, channels] (bfloat16; None for a mixer without a
-        convolution, ``ssm_conv`` 0: there is no second array)."""
+        convolution, ``ssm_conv`` 0: there is no second array; the
+        runner's array of them over all slots: ``conv_state_shape``)."""
         return ((self.ssm_heads, self.ssm_head_dim, self.ssm_state),
                 (self.ssm_conv - 1, self.ssm_channels) if self.ssm_conv
                 else None)
+
+    def conv_state_shape(self, slots: int) -> tuple:
+        """The convolution's carried inputs of ``slots`` rows over every
+        recurrent layer: [layers, ssm_conv - 1, slots, channels] bfloat16,
+        TAPS-MAJOR: a tap is a whole plane [slots, channels] whose tiles
+        are full (row-major a tile of 16 rows held a row's 3), and a
+        decode step shifts planes (``hybrid.conv_token``)."""
+        taps, channels = self.ssm_state_shapes[1]
+        return (self.ssm_layers, taps, slots, channels)
 
     @property
     def ssm_state_bytes_per_row(self) -> int:

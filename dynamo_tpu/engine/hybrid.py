@@ -12,7 +12,10 @@ or feed-forward each, every sublayer ``h <- h + a f(RMS(h; w, eps))`` with
   float32: ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t[g]``,
   ``y_t = S_t C_t[g] + D[h] x_t``; ``y = y silu(z)``, RMS-normalised within
   each group, times a weight; ``out = y W_out``. A row keeps S and the last
-  K - 1 inputs of the convolution, a layer (``spec.ssm_state_shapes``).
+  K - 1 inputs of the convolution, a layer (``spec.ssm_state_shapes``); the
+  runner's array of the latter is TAPS-MAJOR, [M, K - 1, slots, channels]
+  (``spec.conv_state_shape``): a tap is a whole plane [slots, channels]
+  whose tiles are full, and a step shifts planes.
 - **L, lightning linear attention.** ``[q | k | v | z] = u W_in``, heads of
   ``ssm_head_dim``; q and k RMS-normalised a head (times a weight) and
   rotated (rotate-half, every lane); a head's constant decay ``lambda_h``
@@ -114,8 +117,10 @@ written, and the new state is read by C in the pass that writes it);
 everywhere else (the CPU backend, any mesh, a runner asked for the XLA
 reader) XLA through ``ssm_step`` / ``lightning_step`` / ``delta_step``,
 over every slot of the layer's slice: the definitions the kernel's two
-forms are held to. Prefill is XLA's
-under either, and so is the convolution's state.
+forms are held to. Prefill is XLA's under either, and so are the
+convolution's carried inputs (``conv_token`` over the layer's slice of the
+taps-major stack: a kernel over it won 0.2 and 0.4 % of a step and was not
+kept, PERF.md section 6, PR 53).
 """
 
 from __future__ import annotations
@@ -224,26 +229,40 @@ def _gated_out(y: jax.Array, z: jax.Array, lp: dict, spec: ModelSpec):
     return mm(y, lp["ssm_w_out"], "...d,dh->...h")
 
 
+def conv_token(conv: jax.Array, new: jax.Array, taps: jax.Array,
+               live: jax.Array):
+    """One token of the causal depthwise convolution over every row. conv
+    [K - 1, B, C] the carried inputs of ONE layer, taps-major (the
+    oldest first), new [B, C] the token's inputs, taps [K, C], live [B].
+    Returns (acc [B, C] float32: ``sum_j taps[j] plane[j]`` over the
+    carried planes and ``new``, from the planes' dtype in float32 and in
+    tap order, before any bias; conv with the token behind its last inputs
+    where the row is ``live``, as it was where it is not)."""
+    full = jnp.concatenate([conv, new[None].astype(conv.dtype)], axis=0)
+    taps = taps.astype(jnp.float32)
+    acc = taps[0] * full[0].astype(jnp.float32)
+    for j in range(1, full.shape[0]):
+        acc = acc + taps[j] * full[j].astype(jnp.float32)
+    return acc, jnp.where(live[None, :, None], full[1:], conv)
+
+
 def _token(h: jax.Array, lp: dict, spec: ModelSpec, conv: jax.Array,
            live: jax.Array):
-    """``_token_of`` of h's projections, behind their z."""
+    """``_token_of`` of h's projections behind their z, and conv [K - 1, B,
+    C] one token on (``conv_token``)."""
     parts = _project(h, lp, spec)
-    return (parts[0], *_token_of(parts, lp, spec, conv, live))
+    acc, conv = conv_token(conv, parts[1], lp["ssm_conv_w"], live)
+    return (parts[0], *_token_of(parts, lp, spec, acc, live), conv)
 
 
-def _token_of(parts: tuple, lp: dict, spec: ModelSpec, conv: jax.Array,
+def _token_of(parts: tuple, lp: dict, spec: ModelSpec, acc: jax.Array,
               live: jax.Array):
     """What one token a row hands the recurrence: (x [B,G,Hg,P], B and C
-    [B,G,N], dt and dt A [B, heads], all float32; conv with the token
-    behind its last inputs where the row is ``live``) of its projections
-    ``parts`` (``_project``)."""
-    _, xbc, dt_raw = parts
-    full = jnp.concatenate([conv, xbc[:, None].astype(conv.dtype)], axis=1)
-    taps = lp["ssm_conv_w"].astype(jnp.float32)                # [K, C]
-    xbc = jax.nn.silu(jnp.sum(full.astype(jnp.float32) * taps, axis=1)
-                      + lp["ssm_conv_bias"][:, 0].astype(jnp.float32))
-    conv = jnp.where(live[:, None, None], full[:, 1:], conv)
-    return (*_split_xbc(xbc, spec), *_steps(dt_raw, lp, live), conv)
+    [B,G,N], dt and dt A [B, heads], all float32) of its projections
+    ``parts`` (``_project``) and the convolution's sum ``acc`` [B, C]
+    float32 (``conv_token``)."""
+    xbc = jax.nn.silu(acc + lp["ssm_conv_bias"][:, 0].astype(jnp.float32))
+    return (*_split_xbc(xbc, spec), *_steps(parts[2], lp, live))
 
 
 def _skip(lp: dict, x: jax.Array):
@@ -269,7 +288,7 @@ def state_update(state: jax.Array, decay: jax.Array, dx: jax.Array,
 def ssm_step(h: jax.Array, lp: dict, spec: ModelSpec, state: jax.Array,
              conv: jax.Array, live: jax.Array):
     """One token a row. h [B, hidden] (normed), state [B, heads, head_dim,
-    state] float32, conv [B, K - 1, channels], live [B]. Returns (out [B,
+    state] float32, conv [K - 1, B, channels], live [B]. Returns (out [B,
     hidden], state, conv); a row that is not live keeps both."""
     b = h.shape[0]
     z, x, bb, cc, dt, da, conv = _token(h, lp, spec, conv, live)
@@ -373,9 +392,10 @@ def chunked_recurrence(x: jax.Array, bb: jax.Array, cc: jax.Array,
 def ssm_chunked(h: jax.Array, lp: dict, spec: ModelSpec, state: jax.Array,
                 conv: jax.Array, valid: jax.Array, seq_lens: jax.Array):
     """A chunk of a prompt a row. h [B, S, hidden] (normed), ``state`` and
-    ``conv`` what the rows hold as the chunk starts (zeros at position 0),
-    valid [B, S], seq_lens [B] the real tokens. Returns (out [B, S,
-    hidden], state and conv at each row's LAST REAL token)."""
+    ``conv`` what the rows hold as the chunk starts (zeros at position 0;
+    conv [B, K - 1, channels], a ROW first: prefill's ``of_rows`` turns the
+    taps-major stack's), valid [B, S], seq_lens [B] the real tokens. Returns
+    (out [B, S, hidden], state and conv at each row's LAST REAL token)."""
     parts = _project(h, lp, spec)
     y, state, conv = _ssm_chunk(parts, lp, spec, state, conv, valid,
                                 seq_lens)
@@ -591,21 +611,16 @@ def _delta_out(y: jax.Array, parts: tuple, lp: dict, spec: ModelSpec):
     return mm(y, lp["ssm_w_out"], "...d,dh->...h")
 
 
-def _delta_token_of(parts: tuple, lp: dict, spec: ModelSpec, conv: jax.Array,
+def _delta_token_of(parts: tuple, lp: dict, spec: ModelSpec, acc: jax.Array,
                     live: jax.Array):
     """What one token a row hands the delta rule: (q, k [B, heads, state],
     v [B, heads, head_dim], g [B, heads, state], beta [B, heads], all
-    float32; conv with the token behind its last inputs where the row is
-    ``live``) of its projections ``parts`` (``_delta_project``)."""
-    qkv, f, beta, _ = parts
+    float32) of its projections ``parts`` (``_delta_project``) and the
+    convolution's sum ``acc`` [B, C] float32 (``conv_token``)."""
+    _, f, beta, _ = parts
     with scope("ssm.conv"):
-        full = jnp.concatenate([conv, qkv[:, None].astype(conv.dtype)],
-                               axis=1)
-        taps = lp["ssm_conv_w"].astype(jnp.float32)            # [K, C]
-        q, k, v = _delta_qkv(jnp.sum(full.astype(jnp.float32) * taps,
-                                     axis=1), spec)
-        conv = jnp.where(live[:, None, None], full[:, 1:], conv)
-    return (q, k, v, *_delta_gates(f, beta, lp, spec, live), conv)
+        q, k, v = _delta_qkv(acc, spec)
+    return (q, k, v, *_delta_gates(f, beta, lp, spec, live))
 
 
 def delta_update(state: jax.Array, q: jax.Array, k: jax.Array, v: jax.Array,
@@ -627,11 +642,13 @@ def delta_update(state: jax.Array, q: jax.Array, k: jax.Array, v: jax.Array,
 def delta_step(h: jax.Array, lp: dict, spec: ModelSpec, state: jax.Array,
                conv: jax.Array, live: jax.Array):
     """One token a row, XLA's: the definition. h [B, hidden] (normed),
-    state [B, heads, head_dim, state] float32, conv [B, K - 1, channels],
+    state [B, heads, head_dim, state] float32, conv [K - 1, B, channels],
     live [B]. Returns (out [B, hidden], state, conv); a row that is not
     live keeps both."""
     parts = _delta_project(h, lp, spec)
-    q, k, v, g, beta, conv = _delta_token_of(parts, lp, spec, conv, live)
+    with scope("ssm.conv"):
+        acc, conv = conv_token(conv, parts[0], lp["ssm_conv_w"], live)
+    q, k, v, g, beta = _delta_token_of(parts, lp, spec, acc, live)
     with scope("ssm.state"):
         y, state = delta_update(state, q, k, v, g, beta)
     return (_delta_out(y.reshape(h.shape[0], -1), parts, lp, spec), state,
@@ -724,7 +741,8 @@ def _delta_chunk(parts: tuple, lp: dict, spec: ModelSpec, state: jax.Array,
     """A chunk of a prompt a row between its projections (``_delta_project``
     of h [B, S, hidden]): (y [B, S, inner] float32, state and conv at each
     row's LAST REAL token). ``state`` and ``conv`` what the rows hold as
-    the chunk starts, valid [B, S], seq_lens [B] the real tokens."""
+    the chunk starts (conv [B, K - 1, channels], a ROW first), valid [B, S],
+    seq_lens [B] the real tokens."""
     qkv, f, beta, _ = parts
     s, taps_n = qkv.shape[1], spec.ssm_conv
     with scope("ssm.conv"):
@@ -1209,10 +1227,17 @@ def _embed(params: Params, spec: ModelSpec, tokens: jax.Array):
         return x if spec.scale_emb == 1.0 else x * spec.scale_emb
 
 
+#: The axis of the slot in each stack a scan carries (``split_state``): S [M,
+#: slots, heads, head_dim, state]; the convolution's carried inputs [M, K -
+#: 1, slots, channels], taps-major.
+SLOT_AXIS = (1, 2)
+
+
 def split_state(spec: ModelSpec, state: tuple) -> tuple:
     """The runner's state arrays as (what a scan carries: the recurrent
-    layers' stacks by slot, the compressed-key array or None: the pool's
-    third array, read where it lies and written at a commit)."""
+    layers' stacks by slot (``SLOT_AXIS``), the compressed-key array or
+    None: the pool's third array, read where it lies and written at a
+    commit)."""
     if spec.compressed_keys:
         return tuple(state[:-1]), state[-1]
     return tuple(state), None
@@ -1254,26 +1279,31 @@ def prefill(params: Params, spec: ModelSpec, k_cache: jax.Array,
     def of_rows(step, on):
         """``step(*rows) -> (y, *rows)`` over the chunk's rows of ONE layer
         as ``Mixer.update`` takes the state stacks whole: each row's state
-        read out of its slot (zeros where its chunk starts at position 0),
-        and what it leaves written back there, a row a copy in place (a
-        gather of every layer's rows ahead of the scan is 50 MB a row kept
-        through it; a scatter of the batch may copy the arrays); nothing is
-        written where ``on`` is False (a group without a mixer)."""
+        read out of its slot, the ROW first ([B, ...]: a row's carried
+        inputs [K - 1, channels] out of the taps-major stack; zeros where
+        its chunk starts at position 0), and what it leaves written back
+        there, a row a copy in place (a gather of every layer's rows ahead
+        of the scan is 50 MB a row kept through it; a scatter of the batch
+        may copy the arrays); nothing is written where ``on`` is False (a
+        group without a mixer)."""
         kept = keep if on is None else keep & on
         def update(*state_p):
             *stacks, p = state_p
             y, *new = step(*(jnp.where(
-                fresh.reshape(b, *(1,) * (a.ndim - 2)), 0, a[p, rows])
-                for a in stacks))
+                fresh.reshape(b, *(1,) * (a.ndim - 2)), 0,
+                a[(p, *(slice(None),) * (axis - 1), rows)])
+                for axis, a in zip(SLOT_AXIS, stacks)))
             out = []
-            for whole, rows_new in zip(stacks, new):
+            for axis, whole, rows_new in zip(SLOT_AXIS, stacks, new):
                 for i in range(b):
-                    at = (p, rows[i]) + (0,) * (whole.ndim - 2)
+                    at = ((p,) + (0,) * (axis - 1) + (rows[i],)
+                          + (0,) * (whole.ndim - axis - 1))
                     old = jax.lax.dynamic_slice(
-                        whole, at, (1, 1, *whole.shape[2:]))
+                        whole, at, (1, *whole.shape[1:axis], 1,
+                                    *whole.shape[axis + 1:]))
                     whole = jax.lax.dynamic_update_slice(
-                        whole, jnp.where(kept[i], rows_new[i][None, None],
-                                         old), at)
+                        whole, jnp.where(kept[i], jnp.expand_dims(
+                            rows_new[i], (0, axis)), old), at)
                 out.append(whole)
             return (y, *out)
         return update
@@ -1391,9 +1421,8 @@ def window_step(params: Params, spec: ModelSpec, k_cache: jax.Array,
         rows alone under the kernel, none where ``on`` is False."""
         dx, bb, cc, decay = terms
         if kernel:
-            count = walk[1] if on is None else jnp.where(on, walk[1], 0)
             s_all, y = state_step(
-                s_all, p, walk[0], count, decay,
+                s_all, p, walk[0], visited(on), decay,
                 dx.reshape(b, decay.shape[1], -1), bb, cc,
                 interpret=backends.interpret)
             return y.reshape(b, -1), s_all
@@ -1404,34 +1433,42 @@ def window_step(params: Params, spec: ModelSpec, k_cache: jax.Array,
     def on_rows(on):
         return live if on is None else live & on
 
+    def visited(on):
+        return walk[1] if on is None else jnp.where(on, walk[1], 0)
+
+    def convolve(c_all, p, on, new, taps):
+        """One token of layer p's convolution: (its sum [B, C] float32,
+        c_all with the live rows' carried inputs one token on)."""
+        with scope("ssm.conv"):
+            acc, conv = conv_token(_index(c_all, p), new, taps, on_rows(on))
+            return acc, jax.lax.dynamic_update_index_in_dim(c_all, conv, p,
+                                                            0)
+
     if spec.ssm_kind == "K":
         def delta(parts, lp, s_all, c_all, p, on):
-            q, k, v, g, beta, conv = _delta_token_of(
-                parts, lp, spec, _index(c_all, p), on_rows(on))
+            acc, c_all = convolve(c_all, p, on, parts[0], lp["ssm_conv_w"])
+            q, k, v, g, beta = _delta_token_of(parts, lp, spec, acc,
+                                               on_rows(on))
             with scope("ssm.state"):
                 if kernel:
                     s_all, y = delta_state_step(
-                        s_all, p, walk[0],
-                        walk[1] if on is None else jnp.where(on, walk[1], 0),
-                        jnp.exp(g), k, q, v, beta,
-                        interpret=backends.interpret)
+                        s_all, p, walk[0], visited(on), jnp.exp(g), k, q, v,
+                        beta, interpret=backends.interpret)
                 else:
                     y, s_all = in_layer(lambda s_rows: delta_update(
                         s_rows, q, k, v, g, beta))(s_all, p)
-            return (y.reshape(b, -1), s_all,
-                    jax.lax.dynamic_update_index_in_dim(c_all, conv, p, 0))
+            return y.reshape(b, -1), s_all, c_all
 
         mixer = Mixer(lambda h, lp: _delta_project(h, lp, spec), delta,
                       lambda y, parts, lp: _delta_out(y, parts, lp, spec))
     elif spec.ssm_conv:
         def mamba(parts, lp, s_all, c_all, p, on):
-            x, bb, cc, dt, da, conv = _token_of(
-                parts, lp, spec, _index(c_all, p), on_rows(on))
+            acc, c_all = convolve(c_all, p, on, parts[1], lp["ssm_conv_w"])
+            x, bb, cc, dt, da = _token_of(parts, lp, spec, acc, on_rows(on))
             y, s_all = update((dt.reshape(*x.shape[:3], 1) * x, bb, cc,
                                jnp.exp(da)), s_all, p, on)
             y = y.reshape(x.shape) + _skip(lp, x)
-            return (y.reshape(b, -1), s_all,
-                    jax.lax.dynamic_update_index_in_dim(c_all, conv, p, 0))
+            return y.reshape(b, -1), s_all, c_all
 
         mixer = Mixer(lambda h, lp: _project(h, lp, spec), mamba,
                       lambda y, parts, lp: _gated_out(y, parts[0], lp, spec))

@@ -133,7 +133,11 @@ SUBSCOPES = ("moe.router", "moe.experts", "moe.shared",
              # their norms; the decay, beta and the output gate; the
              # state's update and read (the kernel of engine/recurrence.py
              # on the chip, ``delta_update`` under XLA) and NOTHING else;
-             # the chunked solve of a prefill.
+             # the chunked solve of a prefill. ``ssm.conv`` is also drawn
+             # in a Mamba-2 block's WINDOW program, around the step of its
+             # convolution's carried inputs alone (``hybrid.conv_token``):
+             # those programs changed with it (PR 53), so no executable of
+             # an older tree is theirs and SCOPES_VERSION stands.
              "ssm.conv", "ssm.gates", "ssm.state", "ssm.chunk")
 #: Bump when SCOPES or where a scope is drawn changes. jax's persistent
 #: cache key leaves debug info out (jax/_src/cache_key.py strips it), so an

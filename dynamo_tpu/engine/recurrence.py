@@ -48,6 +48,15 @@ in once and out once, as the first form does, whatever the update reads
 in between. The decay, k and q arrive as rows of lanes [slots, heads,
 state] and are broadcast down the sublanes for nothing; beta a head is a
 scalar from SMEM.
+
+**No third form.** What a row keeps beside S, the last K - 1 inputs of the
+mixer's convolution, is stepped by XLA (``hybrid.conv_token``) over a
+layer's slice of a TAPS-MAJOR stack [M, K - 1, slots, channels], whose
+planes fill their tiles. A kernel over that stack (dense planes, a grid over
+blocks of 2,048 channels, the stack held to HBM and aliased) ran within 1 us
+a layer of its own copies and won 0.04 ms of a 17.3 ms step with 9 such
+layers and 0.06 of 16.9 with 23: the layout was the gain, not the call
+(PERF.md section 6, PR 53).
 """
 
 from __future__ import annotations

@@ -350,7 +350,8 @@ class ModelRunner:
                                      self.kv_sharding)
         # Recurrent state beside the pages (a block with ModelSpec.recurrent
         # layers): what a ROW holds, by slot, a layer: the state S in
-        # float32 and the convolution's last inputs. Donated to and
+        # float32 and the convolution's last inputs (taps-major: the slot
+        # is the THIRD axis there, spec.conv_state_shape). Donated to and
         # returned by every program that writes them (prefill at a row's
         # last real token, the window once a step), never copied whole.
         # A mixer without a convolution keeps S alone. The pool's third
@@ -364,8 +365,9 @@ class ModelRunner:
             self.ssm_state = _mh_zeros((*rows, *s_shape),
                                        jnp.dtype(SSM_STATE_DTYPE), whole)
             if c_shape is not None:
-                self.conv_state = _mh_zeros((*rows, *c_shape), jnp.bfloat16,
-                                            whole)
+                self.conv_state = _mh_zeros(
+                    spec.conv_state_shape(config.max_num_seqs), jnp.bfloat16,
+                    whole)
             if spec.compressed_keys:
                 self.comp_keys = _mh_zeros(
                     spec.comp_key_shape(self.num_pages, config.page_size),

@@ -338,10 +338,12 @@ def test_the_chunked_scan_equals_the_step_recurrence(tokens):
     out, s_end, c_end = hybrid.ssm_chunked(h, lp, SPEC, state, conv, valid,
                                            lens)
     outs = []
+    planes = jnp.moveaxis(conv, 0, 1)       # a step's are taps-major
     for t in range(tokens):
-        o, state, conv = hybrid.ssm_step(h[:, t], lp, SPEC, state, conv,
-                                         valid[:, t])
+        o, state, planes = hybrid.ssm_step(h[:, t], lp, SPEC, state, planes,
+                                           valid[:, t])
         outs.append(o)
+    conv = jnp.moveaxis(planes, 0, 1)
     steps = jnp.stack(outs, axis=1).astype(jnp.float32)
     got = out.astype(jnp.float32)
     scale = float(jnp.abs(steps).max())
@@ -416,6 +418,7 @@ def test_the_kernel_updates_the_live_rows_and_touches_no_other(walk):
     seq_lens0, positions, cap = (np.asarray(a) for a in WALKS[walk])
     rows, layer = len(cap), 1
     lp, h, _, conv = _mixer_inputs(rows, 4, 7)
+    conv = jnp.moveaxis(conv, 0, 1)         # a step's are taps-major
     s_shape, _ = SPEC.ssm_state_shapes
     states = 0.5 * jax.random.normal(jax.random.key(8),
                                      (3, rows, *s_shape), jnp.float32)
@@ -463,6 +466,53 @@ def test_the_kernel_updates_the_live_rows_and_touches_no_other(walk):
         assert not on[2] and np.isfinite(np.asarray(states[layer, 2])).all()
 
 
+#: Which of six slots a step finds live.
+MASKS = {"no row live": [False] * 6, "every row live": [True] * 6,
+         "holes": [False, True, False, False, True, True]}
+
+
+def _bits16(a) -> np.ndarray:
+    return np.asarray(a).view(np.uint16)
+
+
+@pytest.mark.parametrize("mask", list(MASKS))
+def test_a_token_s_convolution_over_planes_is_the_sum_over_a_row_s_inputs(
+        mask):
+    """``hybrid.conv_token`` at the toy's widths (x | B | C, a bias behind
+    the sum), two tokens over a layer's taps-major planes [K - 1, B, C]:
+    the float32 sum is, to rounding, the definition's until PR 53 (a row's
+    K inputs first, summed over that axis), and so is what ``_token_of``
+    makes of it; a live row's planes move one tap on with the token's
+    inputs the last; a dead row's come back BITWISE."""
+    on = np.asarray(MASKS[mask])
+    rows = len(on)
+    lp, h, _, _ = _mixer_inputs(rows, 2, 11)
+    taps_n, chan = SPEC.ssm_state_shapes[1]
+    planes = jax.random.normal(jax.random.key(12), (taps_n, rows, chan)
+                               ).astype(jnp.bfloat16)
+    live, taps = jnp.asarray(on), lp["ssm_conv_w"]
+    for t in range(2):
+        parts = hybrid._project(h[:, t], lp, SPEC)
+        held = planes
+        acc, planes = hybrid.conv_token(held, parts[1], taps, live)
+        full = jnp.concatenate([jnp.moveaxis(held, 0, 1),
+                                parts[1][:, None].astype(jnp.bfloat16)],
+                               axis=1)
+        old = jnp.sum(full.astype(jnp.float32) * taps.astype(jnp.float32),
+                      axis=1)
+        np.testing.assert_allclose(acc, old, rtol=1e-5, atol=1e-5)
+        for got, was in zip(hybrid._token_of(parts, lp, SPEC, acc, live),
+                            hybrid._token_of(parts, lp, SPEC, old, live)):
+            np.testing.assert_allclose(got, was, rtol=1e-5, atol=1e-5)
+        np.testing.assert_array_equal(
+            _bits16(planes[-1])[on],
+            _bits16(parts[1].astype(jnp.bfloat16))[on])
+        np.testing.assert_array_equal(_bits16(planes[:-1])[:, on],
+                                      _bits16(held[1:])[:, on])
+        np.testing.assert_array_equal(_bits16(planes)[:, ~on],
+                                      _bits16(held)[:, ~on])
+
+
 def test_the_window_step_walks_the_rows_it_counts():
     """hybrid.window_step with the kernel (interpreted) against XLA's
     ``ssm_step``: the live rows' logits, both state arrays and the count
@@ -474,7 +524,7 @@ def test_the_window_step_walks_the_rows_it_counts():
     s_shape, c_shape = SPEC.ssm_state_shapes
     state = (jax.random.normal(jax.random.key(1),
                                (SPEC.ssm_layers, rows, *s_shape)),
-             jnp.zeros((SPEC.ssm_layers, rows, *c_shape), jnp.bfloat16))
+             jnp.zeros(SPEC.conv_state_shape(rows), jnp.bfloat16))
     live = jnp.asarray([True, False, True, True])
     args = (PARAMS, SPEC, pool, pool, buf, buf, jnp.int32(0),
             jnp.asarray([3, 0, 5, 7]), jnp.zeros((rows, 2), jnp.int32),
@@ -524,7 +574,7 @@ def test_a_padded_batch_of_unequal_prompts_and_its_windows():
             for p, pg in zip(prompts, pages)]
     # The dead slot holds something a wrong program would disturb.
     runner.ssm_state = runner.ssm_state.at[:, 2].set(7.0)
-    runner.conv_state = runner.conv_state.at[:, 2].set(3.0)
+    runner.conv_state = runner.conv_state.at[:, :, 2].set(3.0)
     first = np.asarray(runner.prefill_batch(seqs, slots=slots)["tokens"])
     logits = np.asarray(runner.last_prefill_logits, np.float32)
     for row, prompt in enumerate(prompts):
@@ -547,11 +597,55 @@ def test_a_padded_batch_of_unequal_prompts_and_its_windows():
             lps[:, slot], prompt + [int(first[row])],
             [int(t) for t in toks[:, slot]]), (slot, lps[:, slot])
     assert float(jnp.abs(runner.ssm_state[:, 2] - 7.0).max()) == 0.0
-    assert float(jnp.abs(runner.conv_state[:, 2].astype(jnp.float32)
+    assert float(jnp.abs(runner.conv_state[:, :, 2].astype(jnp.float32)
                          - 3.0).max()) == 0.0
     memory = runner.memory_breakdown()
     assert memory["ssm_state_bytes"] == 4 * SPEC.ssm_state_bytes_per_row \
         == runner.ssm_state.nbytes + runner.conv_state.nbytes
+
+
+def test_the_windows_leave_in_a_slot_s_planes_what_a_prefill_leaves():
+    """A prompt's chunk, then two windows (under XLA's update and under the
+    kernel's, interpreted): the carried inputs a row holds in its slot of
+    the taps-major stack are what a prefill of the same 29 tokens writes
+    there, as far as bfloat16 rounding lets two paths agree (values of 0.8:
+    half of them equal, one in a hundred 0.024 apart; the planes in another
+    order 0.38), under either update the same, and a slot nobody served
+    keeps its own."""
+    got = {}
+    for who in ("xla", "kernel"):
+        runner = ModelRunner(config(), params=PARAMS)
+        runner.backends = dataclasses.replace(runner.backends, ssm=who)
+        assert runner.conv_state.shape == SPEC.conv_state_shape(
+            runner.config.max_num_seqs)
+        runner.conv_state = runner.conv_state.at[:, :, 0].set(3.0)
+        prompt = prompt_of(21, 51)
+        seq = PrefillSeq(tokens=np.asarray(prompt, np.int32), start_pos=0,
+                         chunk_pages=np.asarray([1, 2], np.int32),
+                         hist_pages=None, sampling=(0.0, 0, 1.0))
+        first = int(np.asarray(
+            runner.prefill_batch([seq], slots=[2])["tokens"])[0])
+        toks, lps = zip(*(_window(runner, {2: (len(prompt) + 4 * w,
+                                               [1, 2, 3])}, 4)[:2]
+                          for w in range(2)))
+        got[who] = (np.concatenate(toks)[:, 2], np.concatenate(lps)[:, 2],
+                    np.asarray(runner.conv_state, np.float32), first)
+    np.testing.assert_array_equal(got["xla"][0], got["kernel"][0])
+    np.testing.assert_allclose(got["xla"][1], got["kernel"][1], atol=2e-3)
+    np.testing.assert_allclose(got["xla"][2], got["kernel"][2], atol=0.02)
+    assert (got["kernel"][2][:, :, 0] == 3.0).all()
+    # The same 29 tokens as ONE prompt: the chunk's last K - 1 inputs.
+    toks, _, planes, first = got["xla"]
+    whole = prompt_of(21, 51) + [first] + [int(t) for t in toks[:7]]
+    runner = ModelRunner(config(), params=PARAMS)
+    runner.prefill_batch([PrefillSeq(
+        tokens=np.asarray(whole, np.int32), start_pos=0,
+        chunk_pages=np.asarray([1, 2], np.int32), hist_pages=None,
+        sampling=(0.0, 0, 1.0))], slots=[2])
+    want = np.asarray(runner.conv_state, np.float32)[:, :, 2]
+    apart = np.abs(planes[:, :, 2] - want)
+    assert np.abs(want).mean() > 0.3
+    assert np.median(apart) < 0.01 and np.percentile(apart, 99) < 0.1
 
 
 def test_a_long_batch_s_rows_go_to_their_own_experts():

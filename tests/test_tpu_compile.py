@@ -767,7 +767,8 @@ def test_hybrid_window_program_keeps_the_state_where_it_lies_for_v5e(v5e):
     slots of one layer where they lie: NOTHING else in the optimised
     program has the state's shape (no copy: at the cell's depth one is 1.5
     GB, 3.8 ms a step; no ``dynamic-update-slice`` of a layer's slice; no
-    fusion that reads it a second time)."""
+    fusion that reads it a second time). The convolution's carried inputs
+    are taps-major, [3, 3, 32, 6144] bfloat16."""
     from dynamo_tpu.engine.runner import PK_PREFIX
     # A pool past the chip's 128 MiB of VMEM, as the cell's is: one of 600
     # pages (39 MB) the compiler prefetches whole into VMEM, a copy-start of
@@ -777,8 +778,9 @@ def test_hybrid_window_program_keeps_the_state_where_it_lies_for_v5e(v5e):
     page = runner.config.page_size
     table = runner.config.max_pages_per_seq // 2
     pool = (1, 2, pages, page, 128)
-    s_shape, c_shape = spec.ssm_state_shapes
-    state = (3, rows, *s_shape)
+    s_shape, _ = spec.ssm_state_shapes
+    state, carried = (3, rows, *s_shape), spec.conv_state_shape(rows)
+    assert carried == (3, 3, 32, 6144)
     key = jax.eval_shape(lambda: jax.random.key(0))
     fn = runner._get_window(window, table)
     assert fn._labels["prefix_reuse"].startswith("off")
@@ -788,7 +790,7 @@ def test_hybrid_window_program_keeps_the_state_where_it_lies_for_v5e(v5e):
         params, s(pool, jnp.bfloat16), s(pool, jnp.bfloat16),
         s((rows,), jnp.int32), s((rows, PK_PREFIX + table), jnp.int32),
         s(key.shape, key.dtype),
-        state=(s(state, jnp.float32), s((3, rows, *c_shape), jnp.bfloat16)))
+        state=(s(state, jnp.float32), s(carried, jnp.bfloat16)))
     # ONE kernel for the mixers of every pair and step.
     assert lowered.as_text().count("func.func private @state_step") == 1
     text = lowered.compile().as_text()
@@ -826,7 +828,7 @@ def test_hybrid_prefill_program_reads_the_expert_stacks_where_they_lie(
     runner, spec, params, s = _hybrid_runner(v5e, experts=experts)
     page, bucket, batch = runner.config.page_size, 256, 2
     pool = (1, 2, 3000, page, 128)
-    s_shape, c_shape = spec.ssm_state_shapes
+    s_shape, _ = spec.ssm_state_shapes
     key = jax.eval_shape(lambda: jax.random.key(0))
     assert runner._get_prefill(128, 1, False)._labels[
         "expert_product"] == "masked"
@@ -837,7 +839,7 @@ def test_hybrid_prefill_program_reads_the_expert_stacks_where_they_lie(
         s((batch, _PF_HDR + bucket + bucket // page + 1), jnp.int32),
         s(key.shape, key.dtype),
         state=(s((3, 32, *s_shape), jnp.float32),
-               s((3, 32, *c_shape), jnp.bfloat16)))
+               s(spec.conv_state_shape(32), jnp.bfloat16)))
     compiled = lowered.compile()
     text = compiled.as_text()
     # Two calls a pair, traced once each inside the scan's body.
@@ -1103,7 +1105,8 @@ def test_delta_programs_compile_for_v5e_with_the_state_where_it_lies(v5e):
     Mosaic, is handed the float32 state (32 slots x 3 layers x 4 MB) whole
     and aliased to its output, and NOTHING else in the optimised program has
     the state's shape (the update reads the state before it writes it: in
-    VMEM, not by a second pass over HBM). And a prefill program of 2 x 512
+    VMEM, not by a second pass over HBM); the convolution's carried inputs
+    are taps-major, [3, 3, 32, 24576] bfloat16. And a prefill program of 2 x 512
     tokens: the chunked solve (``triangular_solve`` a chunk of 32) compiles
     for the chip within a quarter of what is free beside weights, state
     and pool."""
@@ -1115,8 +1118,9 @@ def test_delta_programs_compile_for_v5e_with_the_state_where_it_lies(v5e):
     pool = (1, 8, pages, page, 128)
     s_shape, c_shape = spec.ssm_state_shapes
     assert (s_shape, c_shape) == ((64, 128, 128), (3, 24576))
-    state = (3, rows, *s_shape)
-    arrays = (s(state, jnp.float32), s((3, rows, *c_shape), jnp.bfloat16))
+    state, carried = (3, rows, *s_shape), spec.conv_state_shape(rows)
+    assert carried == (3, 3, 32, 24576)
+    arrays = (s(state, jnp.float32), s(carried, jnp.bfloat16))
     key = jax.eval_shape(lambda: jax.random.key(0))
     fn = runner._get_window(window, table)
     assert fn._labels["ssm_backend"] == "kernel"
