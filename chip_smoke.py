@@ -36,7 +36,11 @@ program's init seed), and checks what comes out by the repo's own means:
              chunk, the masked one in the window), and on the Solar-Open2
              block at one period (* K K K; 64 delta-rule heads of 128 x
              128: the recurrence's second form, which reads the decayed
-             state before it writes it, against XLA's ``delta_update``);
+             state before it writes it, against XLA's ``delta_update``), and
+             on the Falcon-H1 block (every layer a Mamba-2 mixer of 32 heads
+             of 128 x 256, ONE head a copy of the kernel, AND rotary
+             attention side by side on one normed input: a state a slot and
+             pages a token in every layer);
              ``tpu_custom_call`` must be in the compiled window program
              wherever a kernel runs
   disagg     prefill engine -> KV plane -> decode engine on the one chip;
@@ -665,9 +669,28 @@ async def phase_kernels(args, jax, rng, keep: dict):
         num_routed_experts=8, first_expert=4, num_shared_experts=1,
         layer_pattern="*EKEKEKE", ssm_heads=64, ssm_head_dim=128,
         ssm_groups=64, ssm_state=128, ssm_conv=4, ssm_low_rank=128)
+    # The Falcon-H1 block: its Mamba-2 heads as published (32 of 128 over a
+    # state of 256 in 2 groups: a head is ONE copy of the recurrence's
+    # kernel, two lane tiles a row of S) and its attention geometry (20
+    # query heads over 4 KV heads of 128, rotary: a page of 64) SIDE BY SIDE
+    # on one normed input in every layer, the muP constants as published
+    # but the head's 1/128 (under it every logprob is the uniform one to a
+    # hundredth and a wrong kernel would not show).
+    from dynamo_tpu.engine.config import FalconH1Spec
+    parallel = FalconH1Spec(
+        name="smoke-parallel", vocab_size=2048, hidden_size=512,
+        intermediate_size=1024, num_layers=3, num_heads=20, num_kv_heads=4,
+        head_dim=128, rope_theta=1e11, rms_norm_eps=1e-5,
+        layer_pattern="M*D" * 3, ssm_heads=32, ssm_head_dim=128,
+        ssm_groups=2, ssm_state=256, ssm_conv=4, ssm_chunk=128,
+        scale_emb=5.6569,
+        key_multiplier=0.011049, attn_out_multiplier=0.0375,
+        ssm_in_multiplier=0.25, ssm_out_multiplier=0.088388,
+        ssm_multipliers=(0.35355, 0.25, 0.17678, 0.5, 0.35355),
+        mlp_multipliers=(0.17678, 0.011161))
     pages = {wide.name: 64, share.name: 32, latent.name: 64,
              indexed.name: 64, hybrid.name: 128, sala.name: 128,
-             looped.name: 16, delta.name: 32}  # derived
+             looped.name: 16, delta.name: 32, parallel.name: 64}  # derived
     short = (20, 70, 150) if args.rehearse_cpu else (24, 200, 700)
     past_topk = (20, 2100) if args.rehearse_cpu else (24, 2200, 2600)
     assert max(short) + 64 < indexed.index_topk < min(past_topk[1:])
@@ -686,7 +709,8 @@ async def phase_kernels(args, jax, rng, keep: dict):
             (hybrid, None, None, ("xla", "auto"), short, 1024),
             (sala, None, None, ("xla", "auto"), short, 1024),
             (looped, None, None, ("xla", "auto"), short, 1024),
-            (delta, None, None, ("xla", "auto"), short, 1024)):
+            (delta, None, None, ("xla", "auto"), short, 1024),
+            (parallel, None, None, ("xla", "auto"), short, 1024)):
         prompts = [rng.integers(2, spec_r.vocab_size, size=n).tolist()
                    for n in lengths]
         runs = {}

@@ -174,6 +174,22 @@ class ModelSpec:
     loop_passes = 1
     sandwich_norm = False
     early_exit_threshold = 1.0
+    # What the Falcon-H1 block states (FalconH1Spec): that a group's
+    # recurrent mixer and its attention layer run SIDE BY SIDE on one normed
+    # input and are summed into one residual; that a * layer rotates q and
+    # k; and the muP constants: on k, on attention's input and output, on
+    # the Mamba-2 mixer's input, on the five segments of its in-projection's
+    # output (z, x, B, C, dt) and on its output, on the SwiGLU's gate
+    # pre-activation and down product.
+    parallel_mixers = False
+    attn_rope = False
+    key_multiplier = 1.0
+    attn_in_multiplier = 1.0
+    attn_out_multiplier = 1.0
+    ssm_in_multiplier = 1.0
+    ssm_multipliers = None
+    ssm_out_multiplier = 1.0
+    mlp_multipliers = None
     # Weight-only quantization: None (bf16) or "int8" (engine/quant.py —
     # int8 storage, bf16 MXU compute; halves the weight-read roofline and
     # fits full llama-3-8b on one 16 GB v5e).
@@ -459,6 +475,8 @@ class ModelSpec:
             return cls._from_ouro(cfg, path)
         if cfg.get("model_type") == "solar_open2":
             return cls._from_solar_open2(cfg, path)
+        if cfg.get("model_type") == "falcon_h1":
+            return cls._from_falcon_h1(cfg, path)
         return cls(
             name=cfg.get("_name_or_path", os.path.basename(os.path.dirname(path))),
             vocab_size=cfg["vocab_size"],
@@ -1058,6 +1076,87 @@ class ModelSpec:
             attn_gate=bool(cfg.get("use_gqa_gate", False)),
         )
 
+    @classmethod
+    def _from_falcon_h1(cls, cfg: dict, path: str) -> "ModelSpec":
+        """Falcon-H1's keys (tiiuae/Falcon-H1-34B-Instruct ``config.json``,
+        ``falcon_h1``): every layer a Mamba-2 mixer (the ``mamba_*`` keys)
+        AND rotary attention side by side on one normed input, summed into
+        one residual, then a dense SwiGLU; the muP scalars as published
+        (``embedding_multiplier`` -> ``scale_emb``, ``lm_head_multiplier``
+        -> 1 / ``logit_divisor``, the others under their own names).
+        ``mamba_expand`` is not read (``mamba_d_ssm`` states the inner
+        width), nor ``mlp_expansion_factor`` (``intermediate_size`` does)."""
+        reader = "the config reader"
+        for key, want, why in (
+                ("attention_bias", False, "no projection has a bias leaf"),
+                ("mamba_proj_bias", False, "the recurrent layer's "
+                 "projections have no bias leaves"),
+                ("mlp_bias", False, "the feed-forward has no bias leaves"),
+                ("projectors_bias", False, "no projection has a bias leaf"),
+                ("mamba_conv_bias", True, "the convolution is written down "
+                 "with its bias"),
+                ("hidden_act", "silu", "the feed-forward is SwiGLU and the "
+                 "mixer's activation SiLU"),
+                ("mamba_rms_norm", True, "the mixer's output is RMS-"
+                 "normalised within its groups"),
+                ("mamba_norm_before_gate", False, "the gate is applied "
+                 "BEFORE the grouped norm"),
+                ("mamba_use_mlp", True, "every layer has its feed-forward"),
+                ("attn_layer_indices", None, "EVERY layer attends: a pool "
+                 "layer a layer"),
+                ("rope_scaling", None, "the rotation has plain frequencies "
+                 "theta ** (-2i / d)")):
+            got = cfg.get(key, want)
+            if got != want:
+                raise UnsupportedBlockError(
+                    reader, f"falcon_h1 with {key} {got!r}: {why}")
+        heads, p = cfg["mamba_n_heads"], cfg["mamba_d_head"]
+        if cfg.get("mamba_d_ssm", heads * p) != heads * p:
+            raise UnsupportedBlockError(
+                reader, f"falcon_h1 whose mamba_d_ssm {cfg['mamba_d_ssm']} "
+                f"is not mamba_n_heads x mamba_d_head ({heads} x {p})")
+        segments = tuple(float(m) for m in cfg.get("ssm_multipliers",
+                                                   (1.0,) * 5))
+        mlp = tuple(float(m) for m in cfg.get("mlp_multipliers", (1.0, 1.0)))
+        if len(segments) != 5 or len(mlp) != 2:
+            raise UnsupportedBlockError(
+                reader, f"falcon_h1 with {len(segments)} ssm_multipliers and "
+                f"{len(mlp)} mlp_multipliers: five segments (z, x, B, C, dt) "
+                "and the pair (gate, down) are what is written down")
+        layers = cfg["num_hidden_layers"]
+        return FalconH1Spec(
+            name=cfg.get("_name_or_path")
+            or os.path.basename(os.path.dirname(path)),
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=layers,
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg["num_key_value_heads"],
+            head_dim=cfg.get("head_dim"),
+            rope_theta=float(cfg.get("rope_theta", 10000.0)),
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", False),
+            max_position_embeddings=cfg.get("max_position_embeddings", 8192),
+            layer_pattern="M*D" * layers,
+            ssm_heads=heads,
+            ssm_head_dim=p,
+            ssm_groups=cfg["mamba_n_groups"],
+            ssm_state=cfg["mamba_d_state"],
+            ssm_conv=cfg["mamba_d_conv"],
+            ssm_chunk=cfg.get("mamba_chunk_size", 128),
+            scale_emb=float(cfg.get("embedding_multiplier", 1.0)),
+            logit_divisor=1.0 / float(cfg.get("lm_head_multiplier", 1.0)),
+            key_multiplier=float(cfg.get("key_multiplier", 1.0)),
+            attn_in_multiplier=float(cfg.get("attention_in_multiplier", 1.0)),
+            attn_out_multiplier=float(cfg.get("attention_out_multiplier",
+                                              1.0)),
+            ssm_in_multiplier=float(cfg.get("ssm_in_multiplier", 1.0)),
+            ssm_multipliers=segments,
+            ssm_out_multiplier=float(cfg.get("ssm_out_multiplier", 1.0)),
+            mlp_multipliers=mlp,
+        )
+
 
 @dataclasses.dataclass
 class SmallThinkerSpec(ModelSpec):
@@ -1273,6 +1372,9 @@ RECURRENT_NAMES = {"M": "mamba2", "L": "lightning", "K": "delta_rule"}
 #: an expert layer, D a dense one). The programs scan the stacked groups
 #: (hybrid.groups_of): Nemotron-H's pairs of M and E with a * between some
 #: are one instance, a layer of a mixer and its feed-forward another.
+#: Whether a group's mixer and attention layer run one after the other, each
+#: behind its own norm and residual sum, or SIDE BY SIDE on one normed input
+#: (``ModelSpec.parallel_mixers``) is the block's and not a letter's.
 GROUP = rf"[{RECURRENT_KINDS}]?[*S]?[ED]"
 
 
@@ -1405,6 +1507,61 @@ class SolarOpen2Spec(Cohere2MoeSpec):
             raise ValueError("a delta-rule head's keys are its own: "
                              f"{self.ssm_groups} groups for "
                              f"{self.ssm_heads} heads")
+
+
+@dataclasses.dataclass
+class FalconH1Spec(ModelSpec):
+    """The Falcon-H1 block (tiiuae/Falcon-H1-34B-Instruct, ``falcon_h1``):
+    every layer ``u = RMS(h)``, ``h <- h + a_s SSM(b_s u) + a_a Attn(b_a
+    u)`` (a Mamba-2 mixer and rotary attention SIDE BY SIDE on ONE normed
+    input, summed into ONE residual), then ``h <- h + MLP(RMS(h))``, under
+    the model's muP constants; what it states beyond ModelSpec's fields.
+    The programs are engine/hybrid.py's, which has the equations."""
+    # Three letters a layer, one a SUBLAYER (config.GROUP): M, * and D. The
+    # letters name each sublayer's KIND (its leaves, its state a row, its
+    # pool layer); that M and * of a group are wired in parallel is this
+    # block's, stated once here and not by the letters.
+    layer_pattern: str | None = None
+    parallel_mixers: bool = True
+    # A * layer rotates q and k (rotate-half, every lane, rope_theta).
+    attn_rope: bool = True
+    # The Mamba-2 mixer, as NemotronHSpec states it.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 0
+    ssm_chunk: int = 128
+    # muP: x0 = scale_emb * E[token]; logits = RMS(h) W_head / logit_divisor;
+    # k = key_multiplier * (u W_k) ahead of the rotation and of the pool;
+    # attention reads attn_in_multiplier * u and its output is multiplied by
+    # attn_out_multiplier; the mixer reads ssm_in_multiplier * u, the
+    # segments z, x, B, C, dt of its in-projection's output are multiplied
+    # by ssm_multipliers, its output by ssm_out_multiplier; the SwiGLU's
+    # gate pre-activation and its down product by mlp_multipliers.
+    scale_emb: float = 1.0
+    logit_divisor: float = 1.0
+    key_multiplier: float = 1.0
+    attn_in_multiplier: float = 1.0
+    attn_out_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
+    ssm_out_multiplier: float = 1.0
+    mlp_multipliers: tuple = (1.0, 1.0)
+
+    def __post_init__(self):
+        super().__post_init__()
+        pattern = self.layer_pattern or ""
+        if pattern != "M*D" * self.num_layers:
+            raise ValueError(f"layer_pattern {pattern!r} does not give "
+                             f"{self.num_layers} layers of M and * side by "
+                             "side, then D")
+        _check_groups(pattern)
+        if self.ssm_heads % self.ssm_groups:
+            raise ValueError(f"{self.ssm_heads} heads do not divide into "
+                             f"{self.ssm_groups} groups")
+        self.ssm_multipliers = tuple(self.ssm_multipliers)
+        self.mlp_multipliers = tuple(self.mlp_multipliers)
 
 
 @dataclasses.dataclass

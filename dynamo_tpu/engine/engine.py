@@ -1182,6 +1182,10 @@ class TPUEngine(AsyncEngine):
                 "layers": spec.ssm_layers,
                 # The mixer's kind: mamba2 | lightning | delta_rule.
                 "kind": RECURRENT_NAMES[spec.ssm_kind],
+                # Whether a group's mixer and its attention layer read ONE
+                # normed input side by side (then a row holds a state AND
+                # pages in every such layer).
+                "parallel": bool(spec.parallel_mixers),
                 "state_bytes_per_row": spec.ssm_state_bytes_per_row,
                 "state_dtype": window["ssm_state"],
                 # Who updates S in a decode step: "kernel" (the live slots,

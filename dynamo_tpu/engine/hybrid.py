@@ -51,9 +51,15 @@ or feed-forward each, every sublayer ``h <- h + a f(RMS(h; w, eps))`` with
   experts (``scan_groups`` hands the kernel of engine/experts.py the expert
   stacks whole), a window step multiplies every held expert. **D, a dense
   feed-forward:** the same function's dense branch (SwiGLU).
-- **\\*, attention:** grouped-query, causal, NO rotary embedding; K and V go
-  to the pool, whose layers are the attention layers alone; where
-  ``spec.attn_gate`` the output is gated by ``sigmoid(u W_z)`` as S's is.
+- **\\*, attention:** grouped-query, causal; K and V go to the pool, whose
+  layers are the attention layers alone; where ``spec.attn_gate`` the
+  output is gated by ``sigmoid(u W_z)`` as S's is. NO rotary embedding,
+  unless the block says that its * layers rotate (``spec.attn_rope``, the
+  Falcon-H1 block): then q and k are turned by the rows' positions
+  (rotate-half, every lane, ``rope_theta``) in prefill, whole prompts and
+  chunks over history alike, and in a window step, and k is multiplied by
+  ``spec.key_multiplier`` AHEAD of the rotation and of the pool: what a page
+  holds is what is attended.
 - **S, attention over chosen BLOCKS of keys** (InfLLM-V2): as \\*, q and k
   RMS-normalised a head, and a query attends ``sparse_topk`` blocks of
   ``sparse_block`` keys a KV group: a compressed key is the mean of
@@ -87,6 +93,32 @@ or feed-forward each, every sublayer ``h <- h + a f(RMS(h; w, eps))`` with
 A group is at most one recurrent mixer, at most one attention layer, then a
 feed-forward (``groups_of``): Nemotron-H's pairs of M and E with a * between
 some, MiniCPM-SALA's layers of L or S and D, Solar-Open2's of K or * and E.
+Its mixers are wired one of two ways:
+
+- **one after the other** (every block above): ``h <- h + a M(RMS(h;
+  w_m))``, then ``h <- h + a Attn(RMS(h; w_a))``: each sublayer behind its
+  own norm and its own residual sum, a sublayer that only some groups have
+  under a ``lax.cond``;
+- **side by side** (``spec.parallel_mixers``, the Falcon-H1 block, whose
+  every group is M * D): ``u = RMS(h; w_m)`` feeds BOTH, ``h <- h + a_s
+  SSM(b_s u) + a_a Attn(b_a u)``: ONE norm in, ONE sum out, no conditional
+  (every group has both), and the * sublayers have no norm of their own
+  (``mixer_norm`` holds two rows a group). The muP constants sit where the
+  model publishes them: ``b_s`` (``ssm_in_multiplier``) and the five
+  segment constants of ``ssm_multipliers`` (z, x, B, C, dt) as ONE constant
+  a column behind the in-projection (``_project``: the product is linear,
+  XLA fuses the vector); ``a_s`` (``ssm_out_multiplier``) behind the
+  out-projection (``_gated_out``); ``b_a`` (``attn_in_multiplier``) on the
+  attention's input, ``key_multiplier`` on k ahead of the rotation
+  (``_qkv``), ``a_a`` (``attn_out_multiplier``) behind W_o (``_attn_out``);
+  the SwiGLU's two (``mlp_multipliers``) in model.ffn_block's dense branch.
+  A row of such a block holds a state a slot AND K/V pages a token in every
+  layer. It is spelled as an attribute of the spec over the pattern ``M*D``
+  and not as a letter of its own: the letters name a sublayer's KIND, and
+  everything that counts by kind (the ``ssm_`` leaves and the state arrays
+  by M, the pool's layers by *, the feed-forward's stack by D) holds
+  unedited; how a group's two mixers are wired is one fact of the block,
+  read in ONE place, ``scan_groups.group``.
 A model has recurrent mixers of ONE kind (``spec.ssm_kind``: they share the
 ``ssm_`` leaves and the state arrays). Every program here is ONE scan
 over the stacked groups in which a sublayer that only some groups have lies
@@ -131,6 +163,7 @@ from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from dynamo_tpu.engine.backends import XLA, Backends
 from dynamo_tpu.engine.config import (GROUP, RECURRENT_KINDS,
@@ -141,7 +174,7 @@ from dynamo_tpu.engine.model import (LATENT_SCORE_BYTES, Params, _split_heads,
                                      embed_lookup, expert_product, ffn_block,
                                      history_attention, kv_attention,
                                      layer_of, lm_logits, mm, rms_norm,
-                                     rope_tables, whole_expert_leaves)
+                                     rope_tables, times, whole_expert_leaves)
 from dynamo_tpu.engine.perf import scope
 from dynamo_tpu.engine.recurrence import delta_state_step, state_step
 
@@ -187,7 +220,18 @@ def _project(h: jax.Array, lp: dict, spec: ModelSpec):
     model._recurrent_shapes)."""
     zx = mm(h, lp["ssm_w_in"], "...h,hd->...d")
     z, xbc = jnp.split(zx, [spec.ssm_heads * spec.ssm_head_dim], axis=-1)
-    return z, xbc, mm(h, lp["ssm_w_dt"], "...h,hd->...d")
+    dt = mm(h, lp["ssm_w_dt"], "...h,hd->...d")
+    if spec.ssm_multipliers is not None:
+        # muP: the mixer reads ssm_in_multiplier * h, and the segments z, x,
+        # B, C, dt of what it projects are multiplied by a constant each: one
+        # constant a column BEHIND the product (the product is linear).
+        on_z, on_x, on_b, on_c, on_dt = (
+            m * spec.ssm_in_multiplier for m in spec.ssm_multipliers)
+        inner, bc = z.shape[-1], spec.ssm_groups * spec.ssm_state
+        z, dt = times(z, on_z), times(dt, on_dt)
+        xbc = times(xbc, np.repeat(np.float32([on_x, on_b, on_c]),
+                                   [inner, bc, bc]))
+    return z, xbc, dt
 
 
 def _steps(dt_raw: jax.Array, lp: dict, live: jax.Array):
@@ -226,7 +270,8 @@ def _gated_out(y: jax.Array, z: jax.Array, lp: dict, spec: ModelSpec):
     y = y * jax.nn.silu(z.astype(jnp.float32))
     y = (_rms_within(y, spec.ssm_groups, spec.rms_norm_eps)
          * lp["ssm_gate_norm"])
-    return mm(y, lp["ssm_w_out"], "...d,dh->...h")
+    return times(mm(y, lp["ssm_w_out"], "...d,dh->...h"),
+                 spec.ssm_out_multiplier)
 
 
 def conv_token(conv: jax.Array, new: jax.Array, taps: jax.Array,
@@ -1130,16 +1175,36 @@ def scan_groups(layers: dict, spec: ModelSpec, x: jax.Array, state: tuple,
     # A stack with a sublayer a group is sliced by the scan; one that only
     # some groups have is indexed under their conditional.
     every = all(i >= 0 for i in groups.mixer_index)
+    beside = spec.parallel_mixers   # every group: M and * on ONE normed input
     mixed = jnp.asarray([i >= 0 for i in groups.mixer_index])
     starred = jnp.asarray([a >= 0 for a in groups.attn_index])
     nth = lambda idx: jnp.asarray([max(i, 0) for i in idx])  # noqa: E731
-    at = lambda idx: norms[nth(idx)]  # noqa: E731
+    # The norm of sublayer i is row i of the stack; side by side the *
+    # sublayers have none, and a row is a sublayer less those before it.
+    at = lambda idx: norms[nth(  # noqa: E731
+        [i - spec.layer_pattern[:max(i, 0)].count("*") for i in idx]
+        if beside else idx)]
     add = (lambda x, out: x + out) if scale == 1.0 else (
         lambda x, out: x + out * scale)
 
     def group(carry, xs):
         x, *state = carry
         lp_m, lp_f, norm_m, norm_a, norm_f, has_m, star, a, p = xs
+        if beside:
+            # ONE norm in, ONE sum out (both drawn under ``ssm``); each
+            # branch's muP constant sits on its own output (``_gated_out``,
+            # ``_attn_out``).
+            with scope("ssm"):
+                h = rms_norm(x, norm_m, eps)
+                parts = mixer.project(h, lp_m)
+                y, *state = mixer.update(parts, lp_m, *state, p, None)
+                out_s = mixer.output(y, parts, lp_m)
+            out_a, kv = attn_fn(h, lp_m, p)
+            with scope("ssm"):
+                x = add(x, out_s + out_a)
+            with scope("mlp"):
+                x = add(x, ffn_block(rms_norm(x, norm_f, eps), lp_f, spec))
+            return (x, *state), tuple(kv)
         with scope("ssm"):
             if every:
                 h = rms_norm(x, norm_m, eps)
@@ -1182,7 +1247,8 @@ def scan_groups(layers: dict, spec: ModelSpec, x: jax.Array, state: tuple,
     n = len(groups.ffn_layer)
     (x, *state), out = jax.lax.scan(
         group, (x, *state),
-        (ssm if every else None, ffn, at(groups.mixer_layer),
+        ({**ssm, **attn} if beside else ssm if every else None, ffn,
+         at(groups.mixer_layer),
          at(groups.attn_layer), at(groups.ffn_layer), mixed, starred,
          nth(groups.attn_index),
          jnp.arange(n) if every else nth(groups.mixer_index)))
@@ -1195,22 +1261,31 @@ def _like(*shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _qkv(h: jax.Array, ap: dict, spec: ModelSpec):
+def _qkv(h: jax.Array, ap: dict, spec: ModelSpec,
+         positions: jax.Array | None = None):
     """q [..., Nh, D], k and v [..., Nkv, D] of normed h; q and k
-    RMS-normalised a head where the layer has the weights."""
+    RMS-normalised a head where the layer has the weights, and where the
+    block's * layers rotate (``spec.attn_rope``) turned by ``positions``
+    [...]: k times ``spec.key_multiplier`` AHEAD of the rotation, so that
+    what a page holds is what is attended."""
     with scope("attn.qkv"):
         d = spec.head_dim
+        h = times(h, spec.attn_in_multiplier)
         q = _split_heads(mm(h, ap["wq"], "...h,hd->...d"), spec.num_heads, d)
         k = _split_heads(mm(h, ap["wk"], "...h,hd->...d"),
                          spec.num_kv_heads, d)
         if "q_norm" in ap:
             q = rms_norm(q, ap["q_norm"], spec.rms_norm_eps)
             k = rms_norm(k, ap["k_norm"], spec.rms_norm_eps)
+        k = times(k, spec.key_multiplier)
+        if spec.attn_rope:
+            cos, sin = rope_tables(positions, d, spec.rope_theta)
+            q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
         return q, k, _split_heads(mm(h, ap["wv"], "...h,hd->...d"),
                                   spec.num_kv_heads, d)
 
 
-def _attn_out(attn: jax.Array, h: jax.Array, ap: dict):
+def _attn_out(attn: jax.Array, h: jax.Array, ap: dict, spec: ModelSpec):
     """attn [..., Nh * D] through W_o, gated by sigmoid(h W_z) where the
     layer has the gate."""
     with scope("attn.out"):
@@ -1218,7 +1293,8 @@ def _attn_out(attn: jax.Array, h: jax.Array, ap: dict):
             gate = jax.nn.sigmoid(mm(h, ap["wz"], "...h,hd->...d")
                                   .astype(jnp.float32))
             attn = attn * gate.astype(attn.dtype)
-        return mm(attn, ap["wo"], "...d,dh->...h")
+        return times(mm(attn, ap["wo"], "...d,dh->...h"),
+                     spec.attn_out_multiplier)
 
 
 def _embed(params: Params, spec: ModelSpec, tokens: jax.Array):
@@ -1335,7 +1411,7 @@ def prefill(params: Params, spec: ModelSpec, k_cache: jax.Array,
             lambda y, parts, lp: _lightning_out(y, parts, lp, spec))
 
     def attn_fn(h, ap, a):
-        q, k, v = _qkv(h, ap, spec)
+        q, k, v = _qkv(h, ap, spec, positions)
         old = ()
         if hist is not None:
             with scope("attn.kv_gather"):
@@ -1348,7 +1424,7 @@ def prefill(params: Params, spec: ModelSpec, k_cache: jax.Array,
                            hist[1])
             attn, stripes = sparse_prefill_attention(
                 q, k, v, positions, valid, spec, old or None)
-            return _attn_out(attn, h, ap), (k, v, stripes)
+            return _attn_out(attn, h, ap, spec), (k, v, stripes)
         with scope("attn.core"):
             if hist is None:
                 attn = dense_causal_attention(
@@ -1356,7 +1432,7 @@ def prefill(params: Params, spec: ModelSpec, k_cache: jax.Array,
             else:
                 attn = history_attention(q, k, v, *old, positions, valid,
                                          hist[1], spec)
-        return _attn_out(attn, h, ap), (k, v)
+        return _attn_out(attn, h, ap, spec), (k, v)
 
     nkv, d = spec.num_kv_heads, spec.head_dim
     kv_like = (_like(b, s, nkv, d),) * 2
@@ -1420,14 +1496,15 @@ def window_step(params: Params, spec: ModelSpec, k_cache: jax.Array,
         [B,G,N], the decay [B, heads]) -> (y [B, inner], s_all). The live
         rows alone under the kernel, none where ``on`` is False."""
         dx, bb, cc, decay = terms
-        if kernel:
-            s_all, y = state_step(
-                s_all, p, walk[0], visited(on), decay,
-                dx.reshape(b, decay.shape[1], -1), bb, cc,
-                interpret=backends.interpret)
-            return y.reshape(b, -1), s_all
-        y, s_all = in_layer(lambda s_rows: state_update(
-            s_rows, decay, dx, bb, cc))(s_all, p)
+        with scope("ssm.state"):
+            if kernel:
+                s_all, y = state_step(
+                    s_all, p, walk[0], visited(on), decay,
+                    dx.reshape(b, decay.shape[1], -1), bb, cc,
+                    interpret=backends.interpret)
+            else:
+                y, s_all = in_layer(lambda s_rows: state_update(
+                    s_rows, decay, dx, bb, cc))(s_all, p)
         return y.reshape(b, -1), s_all
 
     def on_rows(on):
@@ -1483,16 +1560,16 @@ def window_step(params: Params, spec: ModelSpec, k_cache: jax.Array,
             lambda y, parts, lp: _lightning_out(y, parts, lp, spec))
 
     def attn_fn(h, ap, a):
-        q, k, v = _qkv(h, ap, spec)
+        q, k, v = _qkv(h, ap, spec, positions)
         at = (k_cache, v_cache, a, page_table, hist_lens, _index(k_buf, a),
               _index(v_buf, a), m, k, v)
         if comp is not None:
             attn, counts = sparse_window_attention(
                 q, k_cache, v_cache, comp, *at[2:], spec, live, backends)
-            return _attn_out(attn.reshape(b, -1), h, ap), (k, v, counts)
+            return _attn_out(attn.reshape(b, -1), h, ap, spec), (k, v, counts)
         with scope("attn.core"):
             attn = attend(q, *at, spec.q_per_kv).reshape(b, -1)
-        return _attn_out(attn, h, ap), (k, v)
+        return _attn_out(attn, h, ap, spec), (k, v)
 
     kv_like = (_like(b, spec.num_kv_heads, spec.head_dim),) * 2
     if comp is not None:

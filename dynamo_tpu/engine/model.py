@@ -47,6 +47,15 @@ def mm(x: jax.Array, w, pattern: str) -> jax.Array:
     return jnp.einsum(pattern, x, w, preferred_element_type=jnp.bfloat16)
 
 
+def times(x: jax.Array, constant) -> jax.Array:
+    """x times one of a block's muP constants (a number, or a vector along
+    x's last axis), in float32, as x's dtype; a constant of 1 is no
+    operation at all, so a block without the constant keeps its program."""
+    if isinstance(constant, (int, float)) and constant == 1:
+        return x
+    return (x.astype(jnp.float32) * constant).astype(x.dtype)
+
+
 def lora_delta(x: jax.Array, ll: dict, ids: jax.Array) -> jax.Array:
     """Gathered batched low-rank correction ``x @ A[ids] @ B[ids]`` —
     the S-LoRA / Punica batched-heterogeneous-adapter step, as two
@@ -257,7 +266,10 @@ def _pattern_shapes(spec: ModelSpec) -> dict:
     pattern = spec.layer_pattern
     attn = {k: v for k, v in _attention_shapes(spec, spec.pool_layers).items()
             if not k.endswith("_norm")}
-    shapes = {"mixer_norm": (len(pattern), spec.hidden_size)}
+    # A norm a sublayer; side by side (``spec.parallel_mixers``) M and *
+    # share the mixer's, and the * sublayers have none.
+    norms = len(pattern) - (pattern.count("*") if spec.parallel_mixers else 0)
+    shapes = {"mixer_norm": (norms, spec.hidden_size)}
     if "M" in pattern:
         shapes.update(_recurrent_shapes(spec, pattern.count("M")))
     if "L" in pattern:
@@ -545,13 +557,17 @@ def moe_route(router: jax.Array, spec: ModelSpec,
     return jax.nn.softmax(top_v, axis=-1), top_i           # over top-k
 
 
-def _gate_act(gate: jax.Array, spec: ModelSpec) -> jax.Array:
-    """SwiGLU's SiLU or ReGLU's ReLU on the gate projection, in float32;
+def _gate_act(gate: jax.Array, spec: ModelSpec,
+              multiplier: float = 1.0) -> jax.Array:
+    """SwiGLU's SiLU or ReGLU's ReLU on the gate projection (times
+    ``multiplier``, muP's constant on the pre-activation), in float32;
     "relu2": the squared ReLU of a two-matrix expert's one projection.
     (``jnp.maximum``, not ``jax.nn.relu``: behind the latter XLA's CPU
     backend folds the converts away and is left with a bf16 x bf16 -> f32
     batched dot it cannot execute; the tests run there.)"""
     g = gate.astype(jnp.float32)
+    if multiplier != 1.0:
+        g = g * multiplier
     if spec.ffn_act == "relu2":
         return jnp.square(jnp.maximum(g, 0.0)).astype(jnp.bfloat16)
     act = jnp.maximum(g, 0.0) if spec.ffn_act == "relu" else jax.nn.silu(g)
@@ -667,11 +683,14 @@ def ffn_block(h2: jax.Array, lp: dict, spec: ModelSpec, ll: dict | None = None,
         if mlp_lora:
             gate = gate + lora_delta(h2, ll["w_gate"], ids)
             up = up + lora_delta(h2, ll["w_up"], ids)
-        ff = _gate_act(gate, spec) * up
+        # muP (``spec.mlp_multipliers``): a constant on the gate's
+        # pre-activation and one on the down product.
+        on_gate, on_down = spec.mlp_multipliers or (1.0, 1.0)
+        ff = _gate_act(gate, spec, on_gate) * up
         down = mm(ff, lp["w_down"], "...i,ih->...h")
         if mlp_lora:
             down = down + lora_delta(ff, ll["w_down"], ids)
-        return down
+        return times(down, on_down)
     orig = h2.shape
     x = h2.reshape(-1, orig[-1])                       # [T, H]
     with scope("moe.router"):

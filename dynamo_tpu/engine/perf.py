@@ -118,7 +118,12 @@ SCOPES = ("embed", "attn.qkv", "attn.kv_gather", "attn.core", "attn.out",
 #: (``attn.compress``: a prefill chunk's stripes into their pages, a
 #: window's completed stripes at its commit; the choice of blocks over them
 #: is ``attn.index``); the norm between two passes of a looped stack
-#: (``loop.norm``, model.scan_passes). The other blocks' programs
+#: (``loop.norm``, model.scan_passes). In a block whose recurrent mixer and
+#: attention layer run SIDE BY SIDE (``ModelSpec.parallel_mixers``, the
+#: Falcon-H1 block) the mixer's branch is ``ssm`` and the attention's
+#: ``attn.qkv`` / ``attn.kv_gather`` / ``attn.core`` / ``attn.out``; the ONE
+#: norm that feeds both and the ONE residual sum behind both are drawn under
+#: ``ssm``. The other blocks' programs
 #: have none, so their names, and SCOPES_VERSION, stand.
 BLOCK_SCOPES = ("attn.index", "mtp", "ssm", "attn.compress", "loop.norm")
 #: Regions INSIDE a scope, drawn only in programs of a routed block (the
@@ -138,13 +143,20 @@ SUBSCOPES = ("moe.router", "moe.experts", "moe.shared",
              # convolution's carried inputs alone (``hybrid.conv_token``):
              # those programs changed with it (PR 53), so no executable of
              # an older tree is theirs and SCOPES_VERSION stands.
+             # ``ssm.state`` is drawn in EVERY recurrent block's window
+             # program since PR 54: around the first form's update and read
+             # too (``hybrid.window_step`` ``update``: the kernel
+             # ``state_step`` on the chip, ``state_update`` and the layer's
+             # slice in and out under XLA), metadata alone in the Mamba-2
+             # and lightning blocks' programs, hence SCOPES_VERSION 3.
              "ssm.conv", "ssm.gates", "ssm.state", "ssm.chunk")
 #: Bump when SCOPES or where a scope is drawn changes. jax's persistent
 #: cache key leaves debug info out (jax/_src/cache_key.py strips it), so an
 #: executable cached by a tree with other scopes would be loaded with ITS
 #: names; the version is a sub-directory of the cache directory, and a
 #: change of vocabulary costs one cold start instead. 2: ``ssm`` (PR 41).
-SCOPES_VERSION = 2
+#: 3: ``ssm.state`` around the first form's update (PR 54).
+SCOPES_VERSION = 3
 
 
 def scope(name: str):
@@ -1385,8 +1397,10 @@ class PerfMetricsUpdater:
             "perf_ssm_state_info", "1 under the labels of what a row (a "
             "slot) of this worker keeps beside its pages over all recurrent "
             "layers: bytes_per_row (the state and the convolution's last "
-            "inputs) and dtype (the state's); no sample for a block whose "
-            "whole per-request state is pages", ["bytes_per_row", "dtype"])
+            "inputs), dtype (the state's) and parallel (1 where a layer's "
+            "recurrent mixer and its attention layer read ONE normed input "
+            "side by side); no sample for a block whose whole per-request "
+            "state is pages", ["bytes_per_row", "dtype", "parallel"])
         self.g_kv_entry = registry.gauge(
             "perf_kv_entry_info", "1 under the labels of what a token "
             "holds in this worker's KV pool over all layers: kind "
@@ -1478,7 +1492,8 @@ class PerfMetricsUpdater:
         if recurrent:
             self.g_ssm_state.set(
                 1, bytes_per_row=str(runner.spec.ssm_state_bytes_per_row),
-                dtype=str(runner.ssm_state.dtype))
+                dtype=str(runner.ssm_state.dtype),
+                parallel=str(int(bool(runner.spec.parallel_mixers))))
         spec = getattr(runner, "spec", None)
         if getattr(spec, "loop_passes", 1) > 1 and config is not None:
             self.g_loop.set(1, passes=str(spec.loop_passes),

@@ -77,7 +77,16 @@ BUFFERS = 3
 #: 2.89 ms a step at 128 KB, 3.01 at 256, 3.09 at 512, 3.25 at 2 MB (the
 #: copies alone 2.83 to 2.87 at every size; this one 2.92 at 128 KB;
 #: PERF.md section 6, PR 42). Under 128 KB a copy's issue (58 ns, PR 37)
-#: shows against its bytes.
+#: shows against its bytes. At a head of [128, 256] float32 (the Falcon-H1
+#: block: 32 heads in 2 groups) ONE head is a copy, 32 copies a row, two
+#: lane tiles a row of S, a group's 16 heads unrolled under one B and C, and
+#: the form holds its floor: alone, 12 layers x 17 live rows of 32, 2.689 ms
+#: a step against 2.684 for its copies without arithmetic (636 GB/s of the
+#: live rows' state read and written, 78 % of the chip's bandwidth; XLA's
+#: ``state_update`` over every slot 7.14), and the [64, 128] heads above on
+#: the same machine 2.774 against 2.767 (626 GB/s; XLA 6.91); in its cell
+#: 2.637 ms a step, 79.2 % of the state's roofline (my chip run, PR 54,
+#: call 5; the scratch bench lies in the git-ignored ``_chip/``).
 CHUNK_BYTES = 128 << 10
 VMEM_LIMIT_BYTES = 32 << 20
 

@@ -730,7 +730,7 @@ async def test_the_engine_serves_what_the_reference_computes():
         assert engine.allocator.stats()["reuse_hit_blocks"] == 0
         status = engine.perf_status()
         assert status["ssm"] == {
-            "layers": 3, "kind": "mamba2",
+            "layers": 3, "kind": "mamba2", "parallel": False,
             "state_bytes_per_row": SPEC.ssm_state_bytes_per_row,
             "state_dtype": "float32", "backend": "xla",
             "row_steps": status["ssm"]["row_steps"],

@@ -736,7 +736,7 @@ async def test_the_engine_serves_what_the_reference_computes():
         assert engine.prefix_hit_blocks == 0
         status = engine.perf_status()
         assert status["ssm"] == {
-            "layers": 6, "kind": "delta_rule",
+            "layers": 6, "kind": "delta_rule", "parallel": False,
             "state_bytes_per_row": SPEC.ssm_state_bytes_per_row,
             "state_dtype": "float32", "backend": "xla",
             "row_steps": status["ssm"]["row_steps"],

@@ -1145,3 +1145,108 @@ def test_delta_programs_compile_for_v5e_with_the_state_where_it_lies(v5e):
         s((batch, _PF_HDR + bucket + bucket // page + 1), jnp.int32),
         s(key.shape, key.dtype), state=arrays).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 600 << 20
+
+
+def _parallel_runner(v5e, rows=32, pages=3000):
+    """A ModelRunner that places nothing, for the Falcon-H1 block at its
+    published widths (every layer a Mamba-2 mixer of 32 heads of 128 over a
+    state of 256, 2 groups, AND 20 query heads over 4 KV heads of 128,
+    rotary, on one normed input; a dense SwiGLU of 21,504; the muP
+    constants as published), 3 layers, a narrow vocabulary, int8 weights.
+    Returns (runner, spec, params as shapes, s)."""
+    from dynamo_tpu.engine.config import EngineConfig, FalconH1Spec
+    from dynamo_tpu.engine.model import param_shapes
+    from dynamo_tpu.engine.quant import QUANT_LAYER_KEYS, QTensor
+    from dynamo_tpu.engine.runner import ModelRunner
+    spec = FalconH1Spec(
+        name="parallel", vocab_size=1024, hidden_size=5120,
+        intermediate_size=21504, num_layers=3, num_heads=20, num_kv_heads=4,
+        head_dim=128, rope_theta=1e11, rms_norm_eps=1e-5,
+        layer_pattern="M*D" * 3, ssm_heads=32, ssm_head_dim=128,
+        ssm_groups=2, ssm_state=256, ssm_conv=4, ssm_chunk=128,
+        scale_emb=5.6569, logit_divisor=128.0, key_multiplier=0.011049,
+        attn_out_multiplier=0.0375, ssm_in_multiplier=0.25,
+        ssm_multipliers=(0.35355, 0.25, 0.17678, 0.5, 0.35355),
+        ssm_out_multiplier=0.088388, mlp_multipliers=(0.17678, 0.011161),
+        quant="int8")
+    assert (spec.pool_layers, spec.ssm_layers, spec.kv_entry) == (
+        3, 3, (4, (128, 128)))
+    runner = object.__new__(ModelRunner)
+    runner.spec = spec
+    runner.config = EngineConfig(model=spec, num_pages=pages,
+                                 max_num_seqs=rows)
+    page = runner.config.resolve_page_size("tpu")
+    assert page == 64       # as Qwen2.5-7B's 4 KV heads of 128
+    runner.config = EngineConfig(model=spec, page_size=page, num_pages=pages,
+                                 max_num_seqs=rows)
+    runner.quant_kv, runner.lora, runner.draft_dev = None, None, None
+    runner._window_cache, runner._prefill_cache = {}, {}
+    runner.backends = choose(runner.config, spec, "tpu", 1, None)
+    assert (runner.backends.attention, runner.backends.kv_commit,
+            runner.backends.ssm) == ("pallas", "in_place", "kernel")
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def q(shape):
+        return QTensor(s(shape, jnp.int8),
+                       s((*shape[:-2], 1, shape[-1]), jnp.float32))
+
+    shapes = param_shapes(spec)
+    assert shapes["layers"]["wk"] == (3, 5120, 512)
+    assert shapes["layers"]["ssm_w_in"] == (3, 5120, 9216)
+    params = {"layers": {k: q(v) if k in QUANT_LAYER_KEYS
+                         else s(v, jnp.bfloat16)
+                         for k, v in shapes["layers"].items()},
+              "embed": QTensor(s(shapes["embed"], jnp.int8),
+                               s((1, shapes["embed"][1]), jnp.float32)),
+              "final_norm": s(shapes["final_norm"], jnp.bfloat16),
+              "lm_head": q(shapes["lm_head"])}
+    return runner, spec, params, s
+
+
+def test_parallel_programs_compile_for_v5e_with_state_and_pool_in_place(v5e):
+    """The window program of the Falcon-H1 block at its published widths,
+    three layers, pool and state donated: EVERY layer reads the pool through
+    the Pallas reader (5 query rows a KV head) AND hands the float32 state
+    (32 slots x 3 layers x 4 MB: a head of [128, 256] is ONE copy of 128 KB,
+    two lane tiles a row of S, three row buffers of 4 MB in VMEM) whole and
+    aliased to the recurrence's kernel, which compiles for Mosaic at this
+    head; NOTHING else in the optimised program has the state's shape or
+    the pool's. And a prefill program of 2 x 512 tokens compiles beside
+    them."""
+    from dynamo_tpu.engine.runner import _PF_HDR, PK_PREFIX
+    rows, window, pages = 32, 8, 3000
+    runner, spec, params, s = _parallel_runner(v5e, rows, pages)
+    page = runner.config.page_size
+    table = runner.config.max_pages_per_seq // 2
+    pool = (3, 4, pages, page, 128)
+    s_shape, c_shape = spec.ssm_state_shapes
+    assert (s_shape, c_shape) == ((32, 128, 256), (3, 5120))
+    state, carried = (3, rows, *s_shape), spec.conv_state_shape(rows)
+    assert carried == (3, 3, 32, 5120)
+    arrays = (s(state, jnp.float32), s(carried, jnp.bfloat16))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    fn = runner._get_window(window, table)
+    assert fn._labels["ssm_backend"] == "kernel"
+    lowered = fn.lower(
+        params, s(pool, jnp.bfloat16), s(pool, jnp.bfloat16),
+        s((rows,), jnp.int32), s((rows, PK_PREFIX + table), jnp.int32),
+        s(key.shape, key.dtype), state=arrays)
+    assert lowered.as_text().count("func.func private @state_step") == 1
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert pool_sized_ops(text, pool) == []
+    shape = "f32[" + ",".join(map(str, state)) + "]"
+    aliased = [line for line in text.splitlines()
+               if "ssm_state_step" in line and "custom-call(" in line]
+    assert len(aliased) == 1 and shape in aliased[0] \
+        and "output_to_operand_aliasing" in aliased[0], aliased
+    assert pool_sized_ops(text, state) == []
+    bucket, batch = 512, 2
+    fn = runner._get_prefill(bucket, batch, False)
+    compiled = fn.lower(
+        params, s(pool, jnp.bfloat16), s(pool, jnp.bfloat16),
+        s((batch, _PF_HDR + bucket + bucket // page + 1), jnp.int32),
+        s(key.shape, key.dtype), state=arrays).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 600 << 20
