@@ -34,6 +34,14 @@ def sums(r, span=None):
     return float(cols["moe_touched"].sum()), float(cols["moe_load"].sum()), n
 
 
+def per_layer_step(r):
+    """Distinct held experts ONE layer-step's live rows chose: the mean the
+    program counted over the traced seconds, else over the window; None
+    where it counted none. What the two rooflines take as ``touched``."""
+    got = sums(r, r.trace_mono) or sums(r)
+    return None if got is None else got[0] / got[2]
+
+
 def experts_of(model: dict):
     return model.get("moe_num_primary_experts") or model.get(
         "num_local_experts")

@@ -13,11 +13,16 @@ One decode step of a batch must at least
   * do 2 floating-point operations per weight per sequence, and 4 per live
     token per head dimension for attention.
 The least time is the larger of bytes / peak bandwidth and operations /
-peak rate; ``decode_step_floor`` says which.
+peak rate; ``decode_step_floor`` says which. A block with routed experts
+must read an expert only if some row chose it: ``decode_step_floor`` takes
+the program's own count of experts a layer-step touched and hands it to the
+configuration's counting module where that takes one (``takes_touched``);
+this module, the dense block's, takes none.
 """
 
 from __future__ import annotations
 
+import inspect
 import sys
 
 from benchmark.lib import manifest
@@ -105,27 +110,44 @@ def counting(cfg: dict, root: str = manifest.ROOT) -> tuple:
     is: the one its file names (``"roofline": "<name>"`` ->
     ``benchmark/rooflines/<name>.py`` with ``decode_step_bytes`` and
     ``decode_step_flops`` of the signatures above, one file per block
-    kind), else this module, the dense block's. A name without its file is
+    kind; a routed block's ``decode_step_bytes`` may take ``touched=None``
+    besides), else this module, the dense block's. A name without its file is
     a ManifestError."""
     return manifest.config_module(
         cfg, "roofline", sys.modules[__name__],
         ("decode_step_bytes", "decode_step_flops"), root)
 
 
+def takes_touched(counts) -> bool:
+    """Whether a counting module's ``decode_step_bytes`` takes the count of
+    experts a layer-step touched (a ``touched`` parameter): the routed
+    modules do, the dense block's and the unrouted ones take none."""
+    return "touched" in inspect.signature(counts.decode_step_bytes).parameters
+
+
 def decode_step_floor(cfg: dict, quant: str | None, tp: int, rows: float,
                       context_tokens: float, peaks: dict,
-                      root: str = manifest.ROOT) -> dict:
+                      root: str = manifest.ROOT,
+                      touched: float | None = None) -> dict:
     """The least seconds one decode step can take on one chip, which bound
-    gives it, and which module counted (``counting``)."""
+    gives it, and which module counted (``counting``). ``touched`` is the
+    mean number of distinct HELD experts one expert layer's live rows chose
+    in one decode step (the program's own count, ``moe_roofline``'s unit);
+    it reaches the counting module only where its ``decode_step_bytes``
+    takes it (``takes_touched``), and ``experts_touched`` says what was
+    used: the count, or None (every held expert, or no expert layer)."""
     counts, where = counting(cfg, root)
-    t_bytes = (counts.decode_step_bytes(cfg, quant, tp, rows, context_tokens)
+    used = touched if touched is not None and takes_touched(counts) else None
+    more = {} if used is None else {"touched": used}
+    t_bytes = (counts.decode_step_bytes(cfg, quant, tp, rows, context_tokens,
+                                        **more)
                / (peaks["hbm_gbps"] * 1e9))
     t_flops = (counts.decode_step_flops(cfg, tp, rows, context_tokens)
                / (peaks["bf16_tflops"] * 1e12))
     return {"seconds": max(t_bytes, t_flops),
             "bound": "bandwidth" if t_bytes >= t_flops else "compute",
             "bytes_seconds": t_bytes, "flops_seconds": t_flops,
-            "counted_by": where}
+            "counted_by": where, "experts_touched": used}
 
 
 def peaks_of(device_kind: str, table: dict) -> dict:

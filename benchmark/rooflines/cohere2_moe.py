@@ -6,17 +6,19 @@ experts HELD here (``num_experts``) and the shared experts
 (``num_shared_experts``), all of width ``intermediate_size``; one norm a
 layer (a parallel block) and a tied head over the vocabulary rows held.
 
-Two counts of the expert bytes, kept apart as rooflines/smallthinker.py
-keeps them:
-  * ``decode_step_bytes``, the floor under ``decode_window_roofline``: this
-    chip's weights AS STORED, every held expert and every shared expert of
-    every layer. It takes no count and no expectation of how rows route.
-  * ``expert_layer_bytes(cfg, quant, touched)``, under ``moe_roofline``: the
-    router and ``touched`` HELD experts' matrices, ``touched`` the program's
-    own count of distinct held experts a layer-step's live rows chose. The
-    shared experts are NOT in it (``shared_layer_bytes`` counts them, under
+The expert bytes, as rooflines/smallthinker.py counts them:
+  * ``expert_layer_bytes(cfg, quant, touched)``: the router and ``touched``
+    HELD experts' matrices, ``touched`` the program's own count of distinct
+    held experts a layer-step's live rows chose. The shared experts are NOT
+    in it (``shared_layer_bytes`` counts them, under
     ``moe_shared_roofline``), so ``moe_roofline`` stays the routed product's
     share of its roofline.
+  * ``decode_step_bytes(..., touched=None)``, the floor under
+    ``decode_window_roofline``: this chip's weights AS STORED, every shared
+    expert of every layer, and of the held experts the ``touched`` a layer
+    that some row chose (the same count, handed on by lib/roofline.py
+    ``decode_step_floor``); every held expert where no count is given
+    (``None``). No function takes an expectation of how rows route.
 Even routing would touch held * (1 - (1 - k/routed) ** rows) of the held
 experts, 70 % at 19 rows; no function here takes that expectation.
 
@@ -70,6 +72,20 @@ def shared_layer_bytes(cfg: dict, quant: str | None) -> float:
                                                      quant)
 
 
+def expert_layers(cfg: dict) -> int:
+    """Expert layers of the model: what a count of touched experts is a
+    mean over, and what ``moe_roofline`` multiplies a layer's bytes by."""
+    return cfg["num_hidden_layers"]
+
+
+def experts_read(cfg: dict, touched: float | None) -> float:
+    """Held experts ONE expert layer reads in a step: every one it holds
+    where no count is given, else the count, and a step cannot touch more
+    experts than it has (nor fewer than none)."""
+    held = cfg["num_experts"]
+    return held if touched is None else min(max(float(touched), 0.0), held)
+
+
 def kv_tokens_read(cfg: dict, rows: float, context_tokens: float) -> float:
     """(layer, token) pairs of K and V a step reads: every live token in a
     full layer, at most the window's a row in a window layer."""
@@ -82,14 +98,15 @@ def kv_tokens_read(cfg: dict, rows: float, context_tokens: float) -> float:
 
 
 def decode_step_bytes(cfg: dict, quant: str | None, tp: int, rows: float,
-                      context_tokens: float) -> float:
+                      context_tokens: float, touched: float | None = None
+                      ) -> float:
     if tp != 1:
         raise ValueError("the Cohere2-MoE share is served on one device")
     sizes = _sizes(cfg)
     h = cfg["hidden_size"]
     per_value = 1 if quant == "int8" else 2
     layer = (stored(sizes["attention"], quant) + h * 2       # the one norm
-             + expert_layer_bytes(cfg, quant, cfg["num_experts"])
+             + expert_layer_bytes(cfg, quant, experts_read(cfg, touched))
              + shared_layer_bytes(cfg, quant))
     return (cfg["num_hidden_layers"] * layer + stored(sizes["head"], quant)
             + h * 2                                          # final norm

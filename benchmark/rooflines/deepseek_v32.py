@@ -26,10 +26,14 @@ configuration the entries are under 5 % of a floor that 8.4 GB of weights
 set. ``attn_sparse_roofline`` has the exact count from the program's
 ``attn_selected`` and is the one that judges the attention.
 
-Two counts of the expert bytes, kept apart as rooflines/cohere2_moe.py keeps
-them: ``decode_step_bytes`` takes every held expert; ``expert_layer_bytes(cfg,
-quant, touched)`` the router and ``touched`` held experts; the shared expert
-is ``shared_layer_bytes``. No tp: the program refuses this block on a mesh.
+The expert bytes as rooflines/cohere2_moe.py counts them:
+``expert_layer_bytes(cfg, quant, touched)`` is the router and ``touched`` held
+experts, the shared expert is ``shared_layer_bytes``, and
+``decode_step_bytes(..., touched=None)`` takes the program's count of held
+experts a layer-step touched for its ``expert_layers`` (every held expert
+where no count is given); the leading dense layers, routers, selection biases
+and shared experts are counted whole. No tp: the program refuses this block
+on a mesh.
 """
 
 from __future__ import annotations
@@ -137,6 +141,20 @@ def shared_layer_bytes(cfg: dict, quant: str | None) -> float:
                                                    quant)
 
 
+def expert_layers(cfg: dict) -> int:
+    """Expert layers of the model: what a count of touched experts is a
+    mean over, and what ``moe_roofline`` multiplies a layer's bytes by."""
+    return cfg["num_hidden_layers"] - cfg.get("first_k_dense_replace", 0)
+
+
+def experts_read(cfg: dict, touched: float | None) -> float:
+    """Held experts ONE expert layer reads in a step: every one it holds
+    where no count is given, else the count, and a step cannot touch more
+    experts than it has (nor fewer than none)."""
+    held = cfg["n_routed_experts"]
+    return held if touched is None else min(max(float(touched), 0.0), held)
+
+
 def _selected(cfg: dict, rows: float, context_tokens: float) -> float:
     """Entries a layer's attention reads, from the means alone: at or above
     the true mean (the module's docstring)."""
@@ -144,7 +162,8 @@ def _selected(cfg: dict, rows: float, context_tokens: float) -> float:
 
 
 def decode_step_bytes(cfg: dict, quant: str | None, tp: int, rows: float,
-                      context_tokens: float) -> float:
+                      context_tokens: float, touched: float | None = None
+                      ) -> float:
     if tp != 1:
         raise ValueError("the DeepSeek-V3.2 share is served on one device")
     sizes = _sizes(cfg)
@@ -153,13 +172,13 @@ def decode_step_bytes(cfg: dict, quant: str | None, tp: int, rows: float,
     per_value = 1 if quant == "int8" else 2
     every = (stored(sizes["attention"], quant) + sizes["norms"] * 2
              + index_layer_bytes(cfg, quant))
-    expert = (expert_layer_bytes(cfg, quant, cfg["n_routed_experts"])
+    expert = (expert_layer_bytes(cfg, quant, experts_read(cfg, touched))
               + shared_layer_bytes(cfg, quant))
     pool = layers * (
         _selected(cfg, rows, context_tokens) * entry_bytes(cfg)
         + context_tokens * index_key_bytes(cfg)
         + rows * (entry_bytes(cfg) + index_key_bytes(cfg)))   # written
-    return (layers * every + (layers - dense) * expert
+    return (layers * every + expert_layers(cfg) * expert
             + dense * stored(sizes["dense"], quant)
             + stored(sizes["head"], quant) + h * 2            # final norm
             + max(1, round(rows)) * h * per_value             # embedding rows

@@ -10,7 +10,10 @@ ONE run of the module (engine/runner.py ``_get_mtp_window``). It must at least
   * read every weight of the share once, as stored (int8 values and their
     float32 scales; norms, router and bias bf16): the model's layers, the
     module's (``draft_module_bytes``), and the head TWICE (once for the k + 1
-    verified positions together, once for the module's draft);
+    verified positions together, once for the module's draft); of the held
+    experts, those some position chose (``decode_step_bytes``'s ``touched``,
+    handed on by lib/roofline.py ``decode_step_floor``; EVERY held expert
+    where no count is given);
   * read each live row's latent entries (``entry_bytes`` a token a layer) over
     the model's layers and the module's ONCE, whatever k (one walk of the
     pool serves every query position), and write the entries of the positions
@@ -20,6 +23,16 @@ ONE run of the module (engine/runner.py ``_get_mtp_window``). It must at least
     of the head and one more for the draft; and the attention's products a
     position a key.
 Without ``launch.spec_decode`` the same counts at k = 0 and no module.
+
+``touched`` is the program's own count of distinct held experts a layer-step's
+live positions chose, a mean over the layer-steps the program counted. The
+drafting window counts the MODULE's expert layer beside the model's 46
+(engine/runner.py ``_get_mtp_window``: ``counted = counts.sum(0) +
+mcounts``, the verify's per-layer sums plus ``mtp_block``'s), so the mean is
+over 47 layers a step and the count replaces the held experts in all 47
+(``counted_expert_layers``): 47 x the mean is the program's sum to the
+expert. ``expert_layers`` is the model's 46 alone, the ones under the ``moe``
+scope that ``moe_roofline`` times (the module's runs under ``mtp``).
 
 ``sparse_attention_counts(cfg, keys)``: ``keys`` is the program's
 ``attn_selected`` a step: for this block a live row's keys in context counted
@@ -103,21 +116,45 @@ def shared_layer_bytes(cfg: dict, quant: str | None) -> float:
                                                    quant)
 
 
-def _expert_layer(cfg: dict, quant: str | None) -> float:
+def expert_layers(cfg: dict) -> int:
+    """Expert layers of the model, the ones the ``moe`` scope times: what
+    ``moe_roofline`` multiplies a layer's bytes by."""
+    return cfg["num_hidden_layers"] - cfg.get("first_k_dense_replace", 0)
+
+
+def experts_read(cfg: dict, touched: float | None) -> float:
+    """Held experts ONE expert layer reads in a step: every one it holds
+    where no count is given, else the count, and a step cannot touch more
+    experts than it has (nor fewer than none)."""
+    held = cfg["n_routed_experts"]
+    return held if touched is None else min(max(float(touched), 0.0), held)
+
+
+def counted_expert_layers(cfg: dict) -> int:
+    """Expert layers the program's count is a mean over, and the floor's
+    count applies to: the model's and, where it drafts, the module's."""
+    return expert_layers(cfg) + (
+        cfg.get("num_nextn_predict_layers", 0) if drafts(cfg) else 0)
+
+
+def _expert_layer(cfg: dict, quant: str | None,
+                  touched: float | None = None) -> float:
     sizes = _sizes(cfg)
     return (stored(sizes["attention"], quant) + sizes["norms"] * 2
-            + expert_layer_bytes(cfg, quant, cfg["n_routed_experts"])
+            + expert_layer_bytes(cfg, quant, experts_read(cfg, touched))
             + shared_layer_bytes(cfg, quant))
 
 
-def draft_module_bytes(cfg: dict, quant: str | None) -> float:
+def draft_module_bytes(cfg: dict, quant: str | None,
+                       touched: float | None = None) -> float:
     """Bytes ONE run of the prediction module reads in weights: its
     projection of [embedding ; hidden], its three norms, its whole expert
-    layer (every held expert) and the model's head, which it reads for its
-    draft."""
+    layer (every held expert, or the ``touched`` a count gives) and the
+    model's head, which it reads for its draft."""
     sizes = _sizes(cfg)
     return (stored(sizes["eh"], quant) + 3 * cfg["hidden_size"] * 2
-            + _expert_layer(cfg, quant) + stored(sizes["head"], quant))
+            + _expert_layer(cfg, quant, touched)
+            + stored(sizes["head"], quant))
 
 
 def sparse_attention_counts(cfg: dict, keys: float) -> tuple[float, float]:
@@ -132,7 +169,8 @@ def sparse_attention_counts(cfg: dict, keys: float) -> tuple[float, float]:
 
 
 def decode_step_bytes(cfg: dict, quant: str | None, tp: int, rows: float,
-                      context_tokens: float) -> float:
+                      context_tokens: float, touched: float | None = None
+                      ) -> float:
     if tp != 1:
         raise ValueError("the GLM-4.7-Flash share is served on one device")
     sizes = _sizes(cfg)
@@ -140,11 +178,11 @@ def decode_step_bytes(cfg: dict, quant: str | None, tp: int, rows: float,
     dense = cfg.get("first_k_dense_replace", 0)
     per_value = 1 if quant == "int8" else 2
     k = drafts(cfg)
-    model = ((layers - dense) * _expert_layer(cfg, quant)
+    model = (expert_layers(cfg) * _expert_layer(cfg, quant, touched)
              + dense * (stored(sizes["attention"], quant)
                         + sizes["norms"] * 2 + stored(sizes["dense"], quant))
              + stored(sizes["head"], quant) + h * 2)          # final norm
-    module = draft_module_bytes(cfg, quant) if k else 0.0
+    module = draft_module_bytes(cfg, quant, touched) if k else 0.0
     # Entries: read once a step whatever k; a row commits 1 to k + 1.
     pool = pool_layers(cfg) * (context_tokens + rows) * entry_bytes(cfg)
     embed = (1 + 2 * k) * max(1, round(rows)) * h * per_value

@@ -7,8 +7,11 @@ one shared expert of its own width, * an attention layer), an untied head.
 One decode step must at least
   * read every weight of the share once, as stored (int8 values and their
     float32 scales; norms, router, selection bias, the convolution and a
-    head's vectors bf16), EVERY held expert among them, and of the
-    embedding one row a sequence;
+    head's vectors bf16), of the held experts those some row chose
+    (``decode_step_bytes``'s ``touched``: the program's count of distinct
+    held experts a layer-step's live rows chose, a mean over the E layers,
+    handed on by lib/roofline.py ``decode_step_floor``; EVERY held expert
+    where no count is given), and of the embedding one row a sequence;
   * read AND write the recurrent state of every live row in every M layer
     (``state_bytes_per_row``: S [heads, head_dim, state] float32 and the
     convolution's last ``conv_kernel - 1`` inputs in bfloat16, a layer);
@@ -101,6 +104,20 @@ def shared_layer_bytes(cfg: dict, quant: str | None) -> float:
                                                    quant)
 
 
+def expert_layers(cfg: dict) -> int:
+    """Expert layers of the model: what a count of touched experts is a
+    mean over, and what ``moe_roofline`` multiplies a layer's bytes by."""
+    return kinds(cfg)["E"]
+
+
+def experts_read(cfg: dict, touched: float | None) -> float:
+    """Held experts ONE expert layer reads in a step: every one it holds
+    where no count is given, else the count, and a step cannot touch more
+    experts than it has (nor fewer than none)."""
+    held = cfg["n_routed_experts"]
+    return held if touched is None else min(max(float(touched), 0.0), held)
+
+
 def ssm_layer_bytes(cfg: dict, quant: str | None, row_steps: float) -> float:
     """Bytes ONE decode step's M layers move, all of them together: their
     weights as stored (the two projections, the small bf16 leaves, the norm
@@ -114,15 +131,16 @@ def ssm_layer_bytes(cfg: dict, quant: str | None, row_steps: float) -> float:
 
 
 def decode_step_bytes(cfg: dict, quant: str | None, tp: int, rows: float,
-                      context_tokens: float) -> float:
+                      context_tokens: float, touched: float | None = None
+                      ) -> float:
     if tp != 1:
         raise ValueError("the Nemotron-H share is served on one device")
     sizes = _sizes(cfg)
     h, n = cfg["hidden_size"], kinds(cfg)
     per_value = 1 if quant == "int8" else 2
-    experts = n["E"] * (expert_layer_bytes(cfg, quant,
-                                           cfg["n_routed_experts"])
-                        + shared_layer_bytes(cfg, quant) + h * 2)
+    experts = expert_layers(cfg) * (
+        expert_layer_bytes(cfg, quant, experts_read(cfg, touched))
+        + shared_layer_bytes(cfg, quant) + h * 2)
     attention = n["*"] * (stored(sizes["attention"], quant) + h * 2)
     head = stored(sizes["head"], quant) + h * 2             # final norm
     pool = (context_tokens + rows) * kv_bytes_per_token(cfg)

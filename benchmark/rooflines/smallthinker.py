@@ -3,20 +3,20 @@ shapes alone, on one chip: lib/roofline.py's reckoning with the dense
 feed-forward replaced by a router (hidden x experts, bf16) and experts of
 their own width (``moe_ffn_hidden_size``).
 
-Two counts of the expert bytes, kept apart:
-  * ``decode_step_bytes``, the floor under ``decode_window_roofline``, is
-    lib/roofline.py's own definition carried over: this chip's weights AS
-    STORED, every resident expert of every layer. It is what a step reads
-    that streams the experts it holds, as the program's masked product
-    does; it takes no count and no expectation of how rows route. A step
-    has to read an expert's three matrices only if some row chose it, so a
-    program that skips the others reads LESS than this floor and its share
-    would pass 100 %: the floor then has to take the program's count
-    (lib/roofline.py hands this function rows and context only; PERF.md
-    section 7).
-  * ``expert_layer_bytes(cfg, quant, touched)``, under ``moe_roofline``:
-    the router and ``touched`` experts' matrices, ``touched`` the program's
-    own count of distinct experts a layer-step's live rows chose.
+One count of the expert bytes, ``expert_layer_bytes(cfg, quant, touched)``:
+the router and ``touched`` experts' matrices. A step has to read an expert's
+three matrices only if some row chose it, so
+  * ``decode_step_bytes(..., touched=None)``, the floor under
+    ``decode_window_roofline``, takes the program's own count of distinct
+    experts a layer-step's live rows chose (``touched``, a mean over the
+    ``expert_layers``; lib/roofline.py ``decode_step_floor`` hands it on
+    where the program reports one): what a step MUST read, so a program
+    that skips the experts no row chose still reads at most 100 %. Without
+    a count (``None``) it is every resident expert of every layer, which is
+    what the program's masked product streams; the attention, norms, head
+    and K and V are counted whole either way;
+  * ``moe_roofline`` takes the same function and the same count for the
+    expert layers alone.
 Even routing would touch E * (1 - (1 - k/E) ** rows) experts, 83 % at 18
 rows; the chip's counters read 66 % on random weights (PERF.md section 6),
 so no function here takes that expectation.
@@ -57,6 +57,20 @@ def expert_layer_bytes(cfg: dict, quant: str | None, touched: float
             + touched * stored(_sizes(cfg)["expert"], quant))
 
 
+def expert_layers(cfg: dict) -> int:
+    """Expert layers of the model: what a count of touched experts is a
+    mean over, and what ``moe_roofline`` multiplies a layer's bytes by."""
+    return cfg["num_hidden_layers"]
+
+
+def experts_read(cfg: dict, touched: float | None) -> float:
+    """Held experts ONE expert layer reads in a step: every one it holds
+    where no count is given, else the count, and a step cannot touch more
+    experts than it has (nor fewer than none)."""
+    held = cfg["moe_num_primary_experts"]
+    return held if touched is None else min(max(float(touched), 0.0), held)
+
+
 def kv_tokens_read(cfg: dict, rows: float, context_tokens: float) -> float:
     """(layer, token) pairs of K and V a step reads: every live token in a
     full layer, at most the window's a row in a window layer."""
@@ -70,14 +84,15 @@ def kv_tokens_read(cfg: dict, rows: float, context_tokens: float) -> float:
 
 
 def decode_step_bytes(cfg: dict, quant: str | None, tp: int, rows: float,
-                      context_tokens: float) -> float:
+                      context_tokens: float, touched: float | None = None
+                      ) -> float:
     if tp != 1:
         raise ValueError("the SmallThinker block is served on one device")
     sizes = _sizes(cfg)
     h = cfg["hidden_size"]
     per_value = 1 if quant == "int8" else 2
     layer = (stored(sizes["attention"], quant) + 2 * h * 2   # two norms
-             + expert_layer_bytes(cfg, quant, cfg["moe_num_primary_experts"]))
+             + expert_layer_bytes(cfg, quant, experts_read(cfg, touched)))
     return (cfg["num_hidden_layers"] * layer + stored(sizes["head"], quant)
             + h * 2                                          # final norm
             + max(1, round(rows)) * h * per_value            # embedding rows

@@ -190,8 +190,15 @@ def test_a_program_without_the_ledger_reads_nothing(ring, monkeypatch):
 def test_the_manifest_lists_the_eight_for_every_cell():
     man = manifest.load_manifest()
     entries = {m["name"]: m for m in man["per_layer"]}
+    cells = {w["name"] for w in man["workloads"]}
     for name in NEW:
-        assert "workloads" not in entries[name], name
+        # A reader that finds nothing to read in a later PR's cell (callers
+        # too few for the limiter to judge) lists the cells it reads in:
+        # the cells of PR 36 are ON that list, wherever it ends.
+        assert set(entries[name].get("workloads", cells)) >= {
+            "qwen2.5-7b.reasoning", "smallthinker-21b-a3b.reasoning",
+            "command-a-plus.reasoning",
+            "deepseek-v3.2-exp.reasoning-long"}, name
         assert entries[name]["moves"] == "out_tok_s"
     # Whatever a later PR appends: the eight are there, once, in order.
     assert [m["name"] for m in man["per_layer"]

@@ -96,7 +96,7 @@ def test_the_manifest_finds_every_new_file():
                 module.SOURCE, module.LAYER) == (
             name, entry["unit"], entry["better"], entry["moves"],
             entry["source"], entry["layer"])
-        assert entry["workloads"] == [CELL]
+        assert CELL in entry["workloads"]
     assert {m["name"] for m in manifest.metrics_of(MAN, "end_to_end", CELL)
             } == {"tpot_p50_ms", "out_tok_s", "setup_s"}
 

@@ -98,10 +98,12 @@ def test_the_manifest_finds_every_new_file():
     assert "rehearsal_model" not in toy
     listed = {m["name"] for m in manifest.metrics_of(MAN, "per_layer", CELL)}
     assert set(NEW_READERS) <= listed and set(JOINED) <= listed
-    # Both multiply a layer's bytes by num_hidden_layers, which is wrong by
-    # 9/8 where a layer is dense: not this cell's (PERF.md section 7).
-    assert not {"moe_roofline", "moe_shared_roofline",
-                "moe_experts_touched_pct"} & listed
+    # ``moe_roofline`` counts the expert layers the module states since PR
+    # 55 and lists this cell; ``moe_shared_roofline`` still multiplies by
+    # num_hidden_layers, wrong by 9/8 where a layer is dense: not this
+    # cell's (PERF.md section 7).
+    assert "moe_roofline" in listed
+    assert not {"moe_shared_roofline", "moe_experts_touched_pct"} & listed
     for name in NEW_READERS:
         module = manifest.load_module("layer_metrics", name)
         entry = manifest.find_named(MAN["per_layer"], name, "metric")
@@ -109,10 +111,10 @@ def test_the_manifest_finds_every_new_file():
                 module.SOURCE, module.LAYER) == (
             name, entry["unit"], entry["better"], entry["moves"],
             entry["source"], entry["layer"])
-        assert entry["workloads"] == [CELL]
+        assert CELL in entry["workloads"]
     for name in JOINED:
         entry = manifest.find_named(MAN["per_layer"], name, "metric")
-        assert entry["workloads"][-1] == CELL
+        assert CELL in entry["workloads"]
     assert {m["name"] for m in manifest.metrics_of(MAN, "end_to_end", CELL)
             } == {"tpot_p50_ms", "out_tok_s", "setup_s"}
 

@@ -97,10 +97,13 @@ def test_the_manifest_finds_every_new_file():
     assert toy["launch"]["spec_decode"] == "mtp"
     listed = {m["name"] for m in manifest.metrics_of(MAN, "per_layer", CELL)}
     assert set(NEW_READERS) <= listed and set(JOINED) <= listed
-    # Both multiply a layer's bytes by num_hidden_layers, wrong where a
-    # leading layer is dense: not this cell's (PERF.md section 7).
-    assert not {"moe_roofline", "moe_shared_roofline",
-                "attn_index_roofline", "attn_index_ms_per_step"} & listed
+    # ``moe_roofline`` counts the expert layers the module states since PR
+    # 55 and lists this cell; ``moe_shared_roofline`` still multiplies by
+    # num_hidden_layers, wrong where a leading layer is dense: not this
+    # cell's (PERF.md section 7).
+    assert "moe_roofline" in listed
+    assert not {"moe_shared_roofline", "attn_index_roofline",
+                "attn_index_ms_per_step"} & listed
     for name in NEW_READERS:
         module = manifest.load_module("layer_metrics", name)
         entry = manifest.find_named(MAN["per_layer"], name, "metric")
@@ -108,18 +111,18 @@ def test_the_manifest_finds_every_new_file():
                 module.SOURCE, module.LAYER) == (
             name, entry["unit"], entry["better"], entry["moves"],
             entry["source"], entry["layer"])
-        assert entry["workloads"] == [CELL]
+        assert CELL in entry["workloads"]
     for name in JOINED:
         entry = manifest.find_named(MAN["per_layer"], name, "metric")
-        assert entry["workloads"][-1] == CELL
+        assert CELL in entry["workloads"]
     for cell in OLDER:      # nothing of the older cells' lists moved
         older = {m["name"] for m in manifest.metrics_of(MAN, "per_layer",
                                                         cell)}
         assert not set(NEW_READERS) & older
     assert {m["name"] for m in manifest.metrics_of(MAN, "end_to_end", CELL)
             } == {"tpot_p50_ms", "out_tok_s", "setup_s"}
-    assert MAN["workloads"][-1]["name"] == CELL
-    assert MAN["configs"][-1]["name"] == FILES["cell"]["config"]
+    assert CELL in [w["name"] for w in MAN["workloads"]]
+    assert FILES["cell"]["config"] in [c["name"] for c in MAN["configs"]]
 
 
 # -- the roofline's counts ------------------------------------------------------

@@ -92,7 +92,12 @@ def test_what_a_configuration_names_exists_and_has_the_signatures(root):
         assert (where == "lib/roofline.py") == ("roofline" not in config)
         for name in ("decode_step_bytes", "decode_step_flops"):
             assert parameters(getattr(counts, name)) == parameters(
-                getattr(roofline, name))
+                getattr(roofline, name)) + (
+                    ["touched"] if name == "decode_step_bytes"
+                    and roofline.takes_touched(counts) else [])
+        # A count of touched experts goes to a routed block's module alone.
+        assert roofline.takes_touched(counts) == hasattr(
+            counts, "expert_layers")
         toy = config.get("rehearsal_model", {})
         assert set(toy) <= set(config), "toy sizes under the public keys"
         for key in ("reference", "roofline"):

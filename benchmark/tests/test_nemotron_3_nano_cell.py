@@ -103,9 +103,11 @@ def test_the_manifest_finds_every_new_file():
     assert "rehearsal_model" not in toy
     listed = {m["name"] for m in manifest.metrics_of(MAN, "per_layer", CELL)}
     assert set(NEW_READERS) <= listed and set(JOINED) <= listed
-    # Both multiply ONE layer's bytes by num_hidden_layers: 52 where 23
-    # are expert layers. Not this cell's.
-    assert not {"moe_roofline", "moe_shared_roofline", "mtp_roofline",
+    # ``moe_roofline`` counts the 23 expert layers the module states since
+    # PR 55 and lists this cell; ``moe_shared_roofline`` still multiplies
+    # ONE layer's bytes by num_hidden_layers, 52: not this cell's.
+    assert "moe_roofline" in listed
+    assert not {"moe_shared_roofline", "mtp_roofline",
                 "attn_sparse_roofline", "attn_index_roofline"} & listed
     for name in NEW_READERS:
         module = manifest.load_module("layer_metrics", name)

@@ -76,7 +76,7 @@ def test_default_floor_of_the_dense_cell_is_the_number_of_pr_26():
         "seconds": 0.010028853450549451, "bound": "bandwidth",
         "bytes_seconds": 0.010028853450549451,
         "flops_seconds": 0.0013323764876345178,
-        "counted_by": "lib/roofline.py"}
+        "counted_by": "lib/roofline.py", "experts_touched": None}
 
 
 def test_unknown_device_kind_is_an_error():

@@ -83,11 +83,14 @@ def test_the_manifest_finds_reference_roofline_and_rehearsal_model():
     dense = manifest.cell_files(MAN, "qwen2.5-7b.reasoning")["config"]
     assert reference.for_config(dense)["module"] == "lib/reference.py"
     assert roofline.counting(dense)[1] == "lib/roofline.py"
-    for entry in MAN["per_layer"]:
-        if entry["name"].startswith("moe_"):
-            assert entry["workloads"] == [CELL]
-        else:
-            assert "workloads" not in entry
+    # The cell is ON the list of each of its four readers, wherever later
+    # cells stand there; a reader of every cell keeps no list.
+    for name in ("moe_ms_per_step", "moe_roofline",
+                 "moe_experts_touched_pct", "moe_expert_load_max_over_mean"):
+        entry = manifest.find_named(MAN["per_layer"], name, "metric")
+        assert CELL in entry["workloads"], name
+    assert "workloads" not in manifest.find_named(
+        MAN["per_layer"], "decode_window_roofline", "metric")
     with open(os.path.join(manifest.BENCH, "traffic", "reasoning.json"),
               "rb") as fh:
         import hashlib
