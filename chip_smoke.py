@@ -33,7 +33,7 @@ program's init seed), and checks what comes out by the repo's own means:
              lies by slot beside the pool, on the device, and is carried
              through chunked prefill and the windows; two-matrix relu2
              experts, a share of them: the grouped product in a 256-row
-             chunk, the masked one in the window), and on the Solar-Open2
+             chunk, the walk over the touched ones in the window), and on the Solar-Open2
              block at one period (* K K K; 64 delta-rule heads of 128 x
              128: the recurrence's second form, which reads the decayed
              state before it writes it, against XLA's ``delta_update``), and
@@ -802,9 +802,9 @@ async def phase_kernels(args, jax, rng, keep: dict):
                       f"tpu_custom_call in the window program: "
                       f"{custom_call} on {jax.devices()[0].platform}")
             # A routed block's prefill chunks (256 rows on the chip) take
-            # the grouped expert product, its window's rows the masked one
-            # (model.MOE_DENSE_MAX_ROWS; the rehearsal's 64-row chunks stay
-            # masked): the programs' own label.
+            # the grouped expert product, its window's rows the walk over
+            # the touched experts (model.expert_product; the rehearsal's
+            # 64-row chunks take the walk too): the programs' own label.
             products = {
                 family: sorted({fn._labels["expert_product"]
                                 for fn in cache.values()})
@@ -815,7 +815,7 @@ async def phase_kernels(args, jax, rng, keep: dict):
             grouped = not args.rehearse_cpu
             check(products is None or (
                 ("grouped" in products["prefill"]) == grouped
-                and products["decode_window"] == ["masked"]),
+                and products["decode_window"] == ["touched"]),
                 f"expert products of {spec_r.name}: {products}")
             state_on = ssm = None
             if spec_r.recurrent:

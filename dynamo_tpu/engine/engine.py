@@ -1126,7 +1126,7 @@ class TPUEngine(AsyncEngine):
                                              3) if n else None,
                 "load_max_over_mean": round(load / n, 4) if n else None,
                 # The product each program family's expert layers take
-                # (static by a program's rows: model.MOE_DENSE_MAX_ROWS),
+                # (static by a program's rows: model.expert_product),
                 # and the pairs a layer the grouped prefill calls sorted.
                 "expert_product": self._perf.label_values("expert_product"),
                 "grouped_pairs": self.runner.moe_grouped_pairs,

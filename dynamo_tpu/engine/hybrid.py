@@ -48,8 +48,9 @@ or feed-forward each, every sublayer ``h <- h + a f(RMS(h; w, eps))`` with
   bias; two-matrix relu2 experts and one shared expert of its own width, or
   SwiGLU experts and a shared one of theirs, by ``spec.ffn_act``); a
   prefill over model.MOE_DENSE_MAX_ROWS rows sends each row to its own
-  experts (``scan_groups`` hands the kernel of engine/experts.py the expert
-  stacks whole), a window step multiplies every held expert. **D, a dense
+  experts and a window step walks the held experts its live rows chose
+  (``scan_groups`` hands either kernel of engine/experts.py the expert
+  stacks whole; on a mesh every held expert is multiplied). **D, a dense
   feed-forward:** the same function's dense branch (SwiGLU).
 - **\\*, attention:** grouped-query, causal; K and V go to the pool, whose
   layers are the attention layers alone; where ``spec.attn_gate`` the
@@ -1155,8 +1156,8 @@ def scan_groups(layers: dict, spec: ModelSpec, x: jax.Array, state: tuple,
     (``mixer``); ``attn_fn(h, ap, a) -> (out, kv)`` for attention layer a
     of its stack (kv a tuple of arrays like ``kv_like``, ``_like``'s: what
     the layer leaves a token and what it counted); ``live`` and
-    ``backends`` as model.ffn_block takes them: where x's rows take the
-    grouped product the expert stacks
+    ``backends`` as model.ffn_block takes them: where x's rows take a
+    kernel of engine/experts.py (model.expert_product) the expert stacks
     are not sliced a group but handed whole with the group's index, as
     model.scan_layers hands them (sliced ahead of a custom call a layer's
     experts are COPIED: 160 MB a matrix of 32 x 2,688 x 1,856). Returns (x,
@@ -1169,7 +1170,7 @@ def scan_groups(layers: dict, spec: ModelSpec, x: jax.Array, state: tuple,
     ffn = {k: v for k, v in layers.items()
            if k.startswith(("moe_", "shared_")) or k in FFN_LEAVES}
     whole = {}
-    if expert_product(math.prod(x.shape[:-1]), backends) == "grouped":
+    if expert_product(math.prod(x.shape[:-1]), backends) != "masked":
         ffn, whole = whole_expert_leaves(ffn)
     attn = {k: layers[k] for k in ATTN_LEAVES if k in layers}
     # A stack with a sublayer a group is sliced by the scan; one that only
@@ -1189,7 +1190,7 @@ def scan_groups(layers: dict, spec: ModelSpec, x: jax.Array, state: tuple,
 
     def group(carry, xs):
         x, *state = carry
-        lp_m, lp_f, norm_m, norm_a, norm_f, has_m, star, a, p = xs
+        lp_m, lp_f, norm_m, norm_a, norm_f, has_m, star, a, p, g = xs
         if beside:
             # ONE norm in, ONE sum out (both drawn under ``ssm``); each
             # branch's muP constant sits on its own output (``_gated_out``,
@@ -1238,7 +1239,7 @@ def scan_groups(layers: dict, spec: ModelSpec, x: jax.Array, state: tuple,
         x, kv = jax.lax.cond(star, attend, skip, x)
         with scope("mlp"):
             out = ffn_block(rms_norm(x, norm_f, eps),
-                            {**lp_f, **layer_of(whole, p)}, spec, live=live,
+                            {**lp_f, **layer_of(whole, g)}, spec, live=live,
                             backends=backends)
             out, load = out if isinstance(out, tuple) else (out, None)
             x = add(x, out)
@@ -1251,7 +1252,10 @@ def scan_groups(layers: dict, spec: ModelSpec, x: jax.Array, state: tuple,
          at(groups.mixer_layer),
          at(groups.attn_layer), at(groups.ffn_layer), mixed, starred,
          nth(groups.attn_index),
-         jnp.arange(n) if every else nth(groups.mixer_index)))
+         jnp.arange(n) if every else nth(groups.mixer_index),
+         # The group's own index: its expert layer's in the stacks handed
+         # whole (a group without a mixer shares ``p`` with its neighbour).
+         jnp.arange(n)))
     kv, load = out if isinstance(out[0], tuple) else (out, None)
     held = jnp.asarray([p for p, a in enumerate(groups.attn_index) if a >= 0])
     return x, tuple(state), tuple(a[held] for a in kv), load
@@ -1575,7 +1579,8 @@ def window_step(params: Params, spec: ModelSpec, k_cache: jax.Array,
     if comp is not None:
         kv_like += (_like(3, dtype=jnp.float32),)
     x, state, (k_new, v_new, *counts), load = scan_groups(
-        params["layers"], spec, x, state, mixer, attn_fn, kv_like, live=live)
+        params["layers"], spec, x, state, mixer, attn_fn, kv_like, live=live,
+        backends=backends)
     with scope("lm_head"):
         x = rms_norm(x, params["final_norm"], spec.rms_norm_eps)
         logits = lm_logits(x, params, spec)

@@ -438,48 +438,57 @@ def param_specs(spec: ModelSpec) -> dict:
     return specs
 
 
-#: Rows up to which a routed block's expert layer multiplies every row by
-#: every resident expert under the gate mask; above it, where the experts
-#: are whole on one device, the (row, choice) pairs are sorted by held expert
-#: and multiplied by their own experts only (engine/experts.py: a kernel
-#: that reads each int8 expert once, as stored). One layer alone on one v5e,
-#: int8 leaves (PERF.md section 6, PR 40, call 1, scripts/expert_layer_bench.py;
-#: ms, masked | kernel under the routing of random weights [a balanced one];
-#: weight tiles of 1 MiB: the 2 MiB of experts.TILE_ELEMS are 2 to 5 % faster):
-#:  rows  64 of 2,560 x 768, 6 a row   16 held of 64 of 2,048 x     16 held of 128 of 4,096 x
-#:                                     1,536, 4 a row               4,096, 8 a row
-#:    32  0.52 | 0.73 [0.52 | 0.76]    0.22 | 0.29 [0.23 | 0.33]    1.11 | 1.29 [1.11 | 1.58]
-#:    64  0.53 | 0.79 [0.52 | 0.79]    0.22 | 0.33 [0.22 | 0.33]    1.14 | 1.51 [1.15 | 1.59]
-#:   128  0.70 | 0.84 [0.64 | 0.82]    0.25 | 0.37 [0.25 | 0.35]    1.40 | 1.71 [1.40 | 1.63]
-#:   256  1.17 | 0.94 [1.08 | 0.91]    0.47 | 0.40 [0.47 | 0.38]    2.49 | 1.78 [2.48 | 1.69]
-#:   512  2.65 | 1.14 [2.40 | 1.06]    1.03 | 0.53 [1.02 | 0.48]    5.31 | 2.21 [5.31 | 1.98]
-#: 1,024  5.34 | 1.64 [4.97 | 1.49]    2.05 | 0.69 [2.04 | 0.55]    10.8 | 3.25 [10.8 | 2.67]
-#: 2,048  11.2 | 3.23 [9.93 | 3.00]    4.47 | 0.90 [4.45 | 0.68]    21.7 | 4.85 [21.6 | 3.80]
-#: 4,096  22.4 | 5.50 [19.6 | 5.19]    8.36 | 2.24 [8.92 | 2.08]    43.3 | 8.06 [43.2 | 7.52]
-#: A fourth shape, 32 held of 128 two-matrix relu2 experts of 2,688 x 1,856,
-#: 6 a row (PERF.md section 6, PR 43, call 1; the kernel reads the up stack
-#: as the chip holds it, experts.lies_turned): 32 rows 0.44 | 0.56 [0.44 |
-#: 0.66], 64 0.44 | 0.58 [0.44 | 0.67], 128 0.59 | 0.70 [0.59 | 0.70], 256
-#: 0.97 | 0.78 [0.97 | 0.76], 512 1.98 | 0.92 [1.97 | 0.88], 1,024 4.77 |
-#: 1.28 [4.75 | 1.22], 2,048 8.97 | 2.58 [8.94 | 2.40], 4,096 17.8 | 4.50
-#: [17.7 | 4.31]: the same crossing.
-#: The masked product streams the layer (377, 151 and 805 MB in 0.52, 0.22
-#: and 1.11 ms) and its work rides under that read to about 100 rows, then
-#: grows with the rows (10.7 times the chosen work where all 64 are held,
-#: 16 times in the two shares, whose rows pick ONE held expert each on
-#: average). The kernel costs a (group, row tile) visit
-#: whatever the group holds: rows / 128 + experts - 1 of them at most, each
-#: a weight tile's copy, conversion and product for 128 rows (12 us a visit
-#: of 2,560 x 768 x 3: 62 visits at 32 rows 0.75 ms, 87 at 512 rows 1.05),
-#: plus XLA's sort and two gathers (0.1 ms at 512 rows). The two cross
-#: between 128 and 256 rows in all four geometries under either routing,
-#: so the constant is 128, the largest power of two at which the masked
-#: product is still no slower in all four. It stays at 64 or above whatever
-#: a later table says: a decode step's rows (32) and a verify step's (64)
-#: keep the masked product, whose every-resident-expert read is what
-#: decode_window_roofline's floor counts (ROADMAP S9).
+#: Rows up to which an expert layer whose experts are whole on one device
+#: walks the held experts its live rows chose (experts.touched_product: ONE
+#: custom call a layer, every row by each touched expert, read once as
+#: stored); above it the (row, choice) pairs are sorted by held expert and
+#: multiplied by their own experts only (experts.pairs_product, two calls a
+#: layer). The product over every resident expert under the gate mask
+#: ("masked") is left to a mesh, whose expert axis may be partitioned.
+#: One layer INSIDE a scan over stacked layers on one v5e, int8 leaves, ms a
+#: layer, masked | touched | grouped (PERF.md section 6, PR 56, calls 1 and
+#: 2, scripts/expert_layer_bench.py --layers; [n]: held experts touched;
+#: call 7 read the touched column at 32 (19) again on the tree as it is, ONE
+#: product a tile and no loop over K-chunks: level to the third digit but
+#: 4,096 x 1,280, 0.343 -> 0.367 here and 0.413 -> 0.375 alone, its cell level):
+#:  rows (live), routing     64 of 2,560 x 768, 6 a row          16 held of 128 of 4,096 x 4,096, 8
+#:   32 (19) random          0.515 | 0.450 | 0.652 [52]           1.193 | 0.848 | 1.163 [12]
+#:   32 (19) balanced        0.512 | 0.511 | 0.738 [64]           1.190 | 1.124 | 1.530 [16]
+#:   32 (32) random          0.514 | 0.496 | 0.725 [62]
+#:   64 (64) random          0.518 | 0.514 | 0.774 [64]           1.183 | 1.128 [15]
+#:  128 (128) random         0.638 | 0.534 | 0.815 [64]           1.448 | 1.169 [16]
+#:  256 (256) random         1.202 | 1.020 | 0.908 [64]
+#: and masked | touched at 32 (19) random [balanced], 64 and 128 rows:
+#:  16 held of 256 of 7,168 x 2,048, 8 a row: 0.954 | 0.395 [0.945 | 1.001], 0.968 | 0.905, 1.250 | 1.038
+#:  16 held of 64 of 2,048 x 1,536, 4 a row:  0.212 | 0.144 [0.209 | 0.211], 0.213 | 0.213, 0.281 | 0.227
+#:  32 held of 128 two-matrix relu2 of 2,688 x 1,856, 6 a row (the up stack
+#:  read as the chip holds it, experts.lies_turned):
+#:                                            0.443 | 0.270 [0.439 | 0.411], 0.445 | 0.435, 0.581 | 0.464
+#:  40 held of 320 of 4,096 x 1,280, 8 a row: 0.874 | 0.343 [0.865 | 0.413], 0.893 | 0.692, 1.138 | 0.914
+#: The masked product streams the layer whatever was chosen (377 MB in 0.51
+#: ms: 735 GB/s) and its work rides under that read to about 100 rows, then
+#: grows with the rows. A visit of the walk costs its bytes at the same rate
+#: (64 visits of 5.9 MB in 0.511 ms: 7.9 us, 740 GB/s; a tile's conversion
+#: runs inside its product, experts._product) whatever the rows up to the
+#: MXU's edge: it is level with the masked product where every held expert
+#: is touched, ahead of it by what was not touched, and 16 to 25 % ahead at
+#: 128 rows in all six geometries under either routing. ONE geometry loses
+#: where ALL its experts are touched: 7,168 x 2,048 in tiles of 256 columns,
+#: 1.00 against 0.95 ms at 32 rows and 0.96 at 64 (its cell touches 4 to 6 of
+#: 16). The walk multiplies EVERY row by every touched expert, so past the
+#: MXU's edge it pays twice (256 rows: 1.02) where the grouped product pays
+#: a visit a (group, row tile) (0.91; at 128 rows 0.82 against 0.53): the
+#: two cross between 128 and 256 rows, and the constant is 128. The grouped
+#: product further up, ms a layer ALONE, masked | grouped at 512 | 1,024 |
+#: 2,048 | 4,096 rows under the routing of random weights (PR 40, call 1;
+#: the grouped column before PR 56 moved the conversion into its product,
+#: which took 19 | 12 | 3.5 % off it at 256 | 1,024 | 4,096 rows: PR 56,
+#: call 2): 2.65 | 1.14, 5.34 | 1.64, 11.2 | 3.23, 22.4 | 5.50 (64 of 2,560
+#: x 768); 1.03 | 0.53, 2.05 | 0.69, 4.47 | 0.90, 8.36 | 2.24 (16 of 2,048 x
+#: 1,536); 5.31 | 2.21, 10.8 | 3.25, 21.7 | 4.85, 43.3 | 8.06 (16 of 4,096
+#: x 4,096); 1.98 | 0.92, 4.77 | 1.28, 8.97 | 2.58, 17.8 | 4.50 (32 of 2,688
+#: x 1,856; PR 43, call 1).
 MOE_DENSE_MAX_ROWS = 128
-
 
 class LayerOf(NamedTuple):
     """A leaf of ``params["layers"]`` handed to a layer WHOLE: the stack
@@ -507,11 +516,17 @@ def layer_of(stacks: dict, layer) -> dict:
 
 def expert_product(rows: int, backends: Backends) -> str:
     """The product an expert layer of ``rows`` rows takes, a static fact of
-    its program (the label ``expert_product``): "grouped" above
-    MOE_DENSE_MAX_ROWS where the runner's record says the experts are whole
-    on one device, else "masked"."""
-    return ("grouped" if backends.experts_whole and rows > MOE_DENSE_MAX_ROWS
-            else "masked")
+    its program (the label ``expert_product``). Where the runner's record
+    says the experts are whole on one device, a kernel of engine/experts.py
+    that reads each chosen expert once as stored: "touched" up to
+    MOE_DENSE_MAX_ROWS rows (a window's step, a short chunk: every row by
+    the experts the live rows chose), "grouped" above (sorted pairs, each
+    by its own expert); on any mesh "masked" at every size. A program whose
+    layers take a kernel hands them the expert stacks whole
+    (``scan_layers``)."""
+    if not backends.experts_whole:
+        return "masked"
+    return "touched" if rows <= MOE_DENSE_MAX_ROWS else "grouped"
 
 
 def moe_route(router: jax.Array, spec: ModelSpec,
@@ -574,26 +589,52 @@ def _gate_act(gate: jax.Array, spec: ModelSpec,
     return act.astype(jnp.bfloat16)
 
 
-def moe_load_stats(one_hot: jax.Array, live: jax.Array, spec: ModelSpec
-                   ) -> jax.Array:
-    """What one expert layer's routing did to the rows that are ``live``
-    [T] (bool), counted over the experts HELD: float32 [3] = (distinct
-    held experts chosen, the fullest held expert's tokens over the mean an
-    expert of the router's width would get, 1 if any row was live else 0).
-    one_hot [T, k, E] over the held experts (a choice that fell on an
-    expert held elsewhere is a row of zeros). A layer that is told its
-    share (num_routed_experts) adds two: the (row, choice) pairs that fell
-    on held experts, and all pairs."""
+def held_load(one_hot: jax.Array, live: jax.Array):
+    """What the ``live`` rows [T] (bool) chose of the experts HELD: (load
+    [E] float32, their picks an expert; walk [E] int32, the experts with a
+    pick in rising order; count, how many those are: experts.touched).
+    one_hot [T, k, E] over the held experts: a choice that fell on an
+    expert held elsewhere is a row of zeros and counts nowhere. ONE place
+    finds the set: the counter ``moe_touched`` and the visits of
+    experts.touched_product are the same number by construction."""
+    from dynamo_tpu.engine.experts import touched
     load = jnp.einsum("tke,t->e", one_hot, live.astype(jnp.float32))
+    return (load, *touched(load))
+
+
+def moe_load_stats(load: jax.Array, count: jax.Array, live: jax.Array,
+                   spec: ModelSpec) -> jax.Array:
+    """What one expert layer's routing did to the rows that are ``live``
+    [T] (bool), counted over the experts HELD (``held_load``'s load and
+    count): float32 [3] = (distinct held experts chosen, the fullest held
+    expert's tokens over the mean an expert of the router's width would
+    get, 1 if any row was live else 0). A layer that is told its share
+    (num_routed_experts) adds two: the (row, choice) pairs that fell on
+    held experts, and all pairs."""
     rows = jnp.sum(live.astype(jnp.float32))
     mean = rows * spec.num_experts_per_tok / spec.router_width
     some = rows > 0
-    stats = [jnp.sum(load > 0).astype(jnp.float32),
+    stats = [count.astype(jnp.float32),
              jnp.where(some, jnp.max(load) / jnp.maximum(mean, 1e-9), 0.0),
              some.astype(jnp.float32)]
     if spec.num_routed_experts is not None:
         stats += [jnp.sum(load), rows * spec.num_experts_per_tok]
     return jnp.stack(stats)
+
+
+def _expert_stacks(lp: dict, *keys):
+    """(stacks over all layers, their scales or None, the layer) of a
+    layer's expert leaves ``keys`` as a kernel of engine/experts.py takes
+    them: as scan_layers hands them whole, else this layer's as a stack of
+    one."""
+    ws, layer = [lp[key] for key in keys], 0
+    if isinstance(ws[0], LayerOf):
+        ws, layer = [w.stack for w in ws], ws[0].layer
+    else:
+        ws = [jax.tree.map(lambda a: a[None], w) for w in ws]
+    if isinstance(ws[0], QTensor):
+        return tuple(w.q for w in ws), tuple(w.s for w in ws), layer
+    return tuple(ws), None, layer
 
 
 def _grouped_experts(x: jax.Array, gates: jax.Array, top_i: jax.Array,
@@ -617,22 +658,10 @@ def _grouped_experts(x: jax.Array, gates: jax.Array, top_i: jax.Array,
     rows = jnp.pad(x[order // k], ((0, -(t * k) % ROW_TILE), (0, 0)))
     walk = visits(sizes, rows.shape[0])
 
-    def leaves(*keys):
-        """(stacks over all layers, their scales or None, the layer): as
-        scan_layers hands them whole, else this layer's as a stack of one."""
-        ws, layer = [lp[key] for key in keys], 0
-        if isinstance(ws[0], LayerOf):
-            ws, layer = [w.stack for w in ws], ws[0].layer
-        else:
-            ws = [jax.tree.map(lambda a: a[None], w) for w in ws]
-        if isinstance(ws[0], QTensor):
-            return tuple(w.q for w in ws), tuple(w.s for w in ws), layer
-        return tuple(ws), None, layer
-
     ff = pairs_product(
-        rows, *leaves(*(k for k in EXPERT_LEAVES[:2] if k in lp)), walk,
-        act=spec.ffn_act, interpret=interpret)
-    down = pairs_product(ff, *leaves("moe_w_down"), walk,
+        rows, *_expert_stacks(lp, *(k for k in EXPERT_LEAVES[:2] if k in lp)),
+        walk, act=spec.ffn_act, interpret=interpret)
+    down = pairs_product(ff, *_expert_stacks(lp, "moe_w_down"), walk,
                          interpret=interpret)                # [T*k+, H] f32
     # Back to (token, choice) order by a gather, then the gated sum over k;
     # a pair of no group reads whatever the kernel's buffers held: zeros.
@@ -649,20 +678,25 @@ def ffn_block(h2: jax.Array, lp: dict, spec: ModelSpec, ll: dict | None = None,
 
     Routed formulation (TPU-first): ``moe_route`` gates the chosen experts;
     the router reads ``router_in`` (SmallThinker: the layer's input) or h2.
-    Up to MOE_DENSE_MAX_ROWS rows, and at every size where the expert axis
-    is partitioned, every RESIDENT expert computes the whole token batch
-    and the combine contracts over the expert axis under the gate mask:
-    with experts sharded over "tp" each device runs E/tp experts and XLA
-    inserts the psum, i.e. expert parallelism without a dynamic all-to-all.
-    Above it, where the runner's record says the experts are whole on one
-    device (``backends.experts_whole``: its mesh has one device;
-    ``backends.interpret``: the CPU, which interprets the kernel), every
-    routed kind,
-    gated experts and two-matrix ones ("relu2": no gate leaf) alike,
-    multiplies the (row, choice) pairs by their own experts only
-    (``_grouped_experts``: a Pallas kernel, which GSPMD cannot partition);
-    a layer that holds a SHARE of a wider router's experts does the same
-    with the pairs that fell on the experts it holds.
+    ``expert_product`` says how the experts are multiplied. Where the
+    expert axis may be partitioned (any mesh), at every size: every
+    RESIDENT expert computes the whole token batch and the combine
+    contracts over the expert axis under the gate mask: with experts
+    sharded over "tp" each device runs E/tp experts and XLA inserts the
+    psum, i.e. expert parallelism without a dynamic all-to-all. Where the
+    runner's record says the experts are whole on one device
+    (``backends.experts_whole``: its mesh has one device;
+    ``backends.interpret``: the CPU, which interprets the kernels), every
+    routed kind, gated experts and two-matrix ones ("relu2": no gate leaf)
+    alike, reads the chosen experts alone through a Pallas kernel of
+    engine/experts.py (which GSPMD cannot partition): up to
+    MOE_DENSE_MAX_ROWS rows every row is multiplied by the held experts
+    that a row which counts chose (``experts.touched_product``; the rows
+    that count are the ``live`` ones, every row without ``live``: a slot
+    that is not live makes no visit and its output is zero), above it the
+    (row, choice) pairs are multiplied by their own experts only
+    (``_grouped_experts``); a layer that holds a SHARE of a wider router's
+    experts does the same with the picks that fell on the experts it holds.
 
     The router is as wide as the deployment has experts and its gates are
     normalised over all the chosen; this device multiplies the experts it
@@ -705,17 +739,32 @@ def ffn_block(h2: jax.Array, lp: dict, spec: ModelSpec, ll: dict | None = None,
             # an expert held elsewhere has no column in one_hot.
             top_i = top_i - spec.first_expert
         one_hot = jax.nn.one_hot(top_i, spec.num_experts, dtype=jnp.float32)
+        # The rows that count: a window's live slots, else every row.
+        on = (jnp.ones(x.shape[:1], jnp.bool_) if live is None
+              else live.reshape(-1))
+        load, walk, count = held_load(one_hot, on)
         stats = (None if live is None
-                 else moe_load_stats(one_hot, live.reshape(-1), spec))
+                 else moe_load_stats(load, count, on, spec))
     with scope("moe.experts"):
-        if expert_product(x.shape[0], backends) == "grouped":
+        product = expert_product(x.shape[0], backends)
+        if product == "grouped":
             out = _grouped_experts(x, gates, top_i, lp, spec,
                                    interpret=backends.interpret)
         else:
             w_te = jnp.einsum("tk,tke->te", gates, one_hot)  # [T, E] sparse-ish
-            down = _every_expert(x, lp.get("moe_w_gate"), lp["moe_w_up"],
-                                 lp["moe_w_down"], spec)
-            out = jnp.einsum("eth,te->th", down, w_te)
+            if product == "touched":
+                # A slot that is not live chooses nothing: no visit on its
+                # account, and its output is zero.
+                from dynamo_tpu.engine.experts import touched_product
+                out = touched_product(
+                    x, jnp.where(on[:, None], w_te, 0.0), *_expert_stacks(
+                        lp, *(k for k in EXPERT_LEAVES if k in lp)),
+                    walk, count, act=spec.ffn_act,
+                    interpret=backends.interpret)
+            else:
+                down = _every_expert(x, lp.get("moe_w_gate"), lp["moe_w_up"],
+                                     lp["moe_w_down"], spec)
+                out = jnp.einsum("eth,te->th", down, w_te)
     if spec.num_shared_experts:
         with scope("moe.shared"):
             # Every row through every shared expert; their mean joins the
@@ -1702,7 +1751,9 @@ def decode_verify_step(params: Params, spec: ModelSpec, k_cache: jax.Array,
             x, lp, spec, cos, sin, attend, live=live, backends=backends)
         return x, (k, counts)
 
-    x, ys = scan_layers(layer_fn, x, (params["layers"], jnp.arange(L)), spec)
+    x, ys = scan_layers(
+        layer_fn, x, (params["layers"], jnp.arange(L)), spec,
+        whole_experts=expert_product(b * s, backends) != "masked")
     with scope("lm_head"):
         hidden = norm(x, params["final_norm"], spec)
         logits = lm_logits(hidden.reshape(b * s, -1), params, spec)
@@ -1844,8 +1895,9 @@ def scan_layers(layer_fn, x: jax.Array, xs, spec: ModelSpec,
     joined along the layer axis, what only the expert layers give (their
     load) follows.
 
-    ``whole_experts`` (the caller's layers take the grouped expert product:
-    ``expert_product``): the EXPERT_LEAVES are not sliced a layer; the
+    ``whole_experts`` (the caller's layers take a kernel of
+    engine/experts.py: ``expert_product`` is not "masked"): the
+    EXPERT_LEAVES are not sliced a layer; the
     layer reads them as ``LayerOf`` (the whole stack and its index), which
     the kernel's index maps follow. Sliced ahead of a custom call a layer's
     experts are COPIED (126 MB a matrix of 64 x 2,560 x 768; XLA fuses such
@@ -2031,7 +2083,7 @@ def prefill_forward(params: Params, spec: ModelSpec,
         xs = (xs, jnp.arange(spec.num_layers))
     x, (k_new, v_new) = scan_passes(
         layer_fn, x, xs, spec, params["final_norm"],
-        whole_experts=expert_product(b * s, backends) == "grouped")
+        whole_experts=expert_product(b * s, backends) != "masked")
     # k_new [L,B,S,Nkv,D] -> page blocks [L,Nkv,B*S/page,page,D]; one
     # in-place scatter per cache covers every layer (of every pass).
     with scope("kv.commit"):
@@ -2265,8 +2317,9 @@ def decode_forward(params: Params, spec: ModelSpec,
 
     xs = ((params["layers"], jnp.arange(L), lora) if lora is not None
           else (params["layers"], jnp.arange(L)))
-    x, (k_new, v_new) = scan_passes(layer_fn, x, xs, spec,
-                                    params["final_norm"])
+    x, (k_new, v_new) = scan_passes(
+        layer_fn, x, xs, spec, params["final_norm"],
+        whole_experts=expert_product(b, backends) != "masked")
     # One in-place scatter: [L,Nkv,B,D] at (dest_page[b], page_off[b]).
     k_cache = scatter_tokens(k_cache, k_new.transpose(0, 2, 1, 3),
                              dest_page, page_off)
@@ -2476,8 +2529,10 @@ def decode_window_step(params: Params, spec: ModelSpec,
     xs = ((params["layers"], jnp.arange(L), k_buf, v_buf, lora)
           if lora is not None
           else (params["layers"], jnp.arange(L), k_buf, v_buf))
-    x, ys = scan_passes(layer_fn, x, xs, spec, params["final_norm"],
-                        live=live if spec.loop_passes > 1 else None)
+    x, ys = scan_passes(
+        layer_fn, x, xs, spec, params["final_norm"],
+        live=live if spec.loop_passes > 1 else None,
+        whole_experts=expert_product(b, backends) != "masked")
     with scope("lm_head"):
         x = norm(x, params["final_norm"], spec)
         logits = lm_logits(x, params, spec)

@@ -1353,8 +1353,9 @@ class PerfMetricsUpdater:
             "perf_expert_product_info", "1 under the labels of a program "
             "family of a routed block (prefill, decode_window) and the "
             "product its expert layers take, static by the program's rows "
-            "(model.MOE_DENSE_MAX_ROWS): grouped (sorted pairs, each by its "
-            "own expert) or masked (every row by every resident expert)",
+            "(model.expert_product): touched (a step's rows by the experts "
+            "its live rows chose), grouped (sorted pairs, each by its own "
+            "expert) or masked (every row by every resident expert)",
             ["program", "kind"])
         self.g_moe_experts = registry.gauge(
             "moe_experts_info", "Expert layer told its share: experts the "

@@ -2222,7 +2222,7 @@ def _prefill_with_history(params, spec, k_cache, v_cache, tokens, positions,
           else (params["layers"], jnp.arange(L)))
     x, (k_new, v_new) = scan_passes(
         layer_fn, x, xs, spec, params["final_norm"],
-        whole_experts=expert_product(b * s, backends) == "grouped")
+        whole_experts=expert_product(b * s, backends) != "masked")
     with perf.scope("kv.commit"):
         heads, (dk, dv) = spec.kv_entry
         k_blocks = (k_new.reshape(L, b * (s // page), page, heads, dk)

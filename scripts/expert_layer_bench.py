@@ -1,28 +1,34 @@
 #!/usr/bin/env python3
 """One expert layer alone on the device this process holds: the masked
-product (every row by every resident expert) against the grouped one (the
-kernel of dynamo_tpu/engine/experts.py), the table at
-``model.MOE_DENSE_MAX_ROWS``.
+product (every row by every resident expert) against the two kernels of
+dynamo_tpu/engine/experts.py, the grouped one over sorted pairs and the
+one over the touched experts: the tables at ``model.MOE_DENSE_MAX_ROWS``.
 
     chiprun -- python3 scripts/expert_layer_bench.py [--rows 32,64,...]
-        [--geometry smallthinker,glm,command,nemotron]
+        [--live 19] [--geometry smallthinker,glm,command,nemotron,...]
         [--tiles 128x1048576,...] [--out-tiles 640,...]
 
-Four geometries, as the benchmark's routed cells hold them (int8 leaves):
+Six geometries, as the benchmark's routed cells hold them (int8 leaves):
 ``smallthinker`` 64 experts of 2,560 x 768 all held, 6 a row, ReGLU;
 ``glm`` 16 held of 64 of 2,048 x 1,536, 4 a row; ``command`` 16 held of 128
 of 4,096 x 4,096, 8 a row; ``nemotron`` 32 held of 128 two-matrix relu2
-experts of 2,688 x 1,856 (no whole number of lane tiles), 6 a row. Two
-routings: ``random`` (the router of random
+experts of 2,688 x 1,856 (no whole number of lane tiles), 6 a row;
+``deepseek`` 16 held of 256 of 7,168 x 2,048, 8 a row; ``solar`` 40 held of
+320 of 4,096 x 1,280, 8 a row. ``--live n``: the first n of the rows are
+live, as a window's step has them (the others choose nothing under any
+product). Two routings: ``random`` (the router of random
 weights over random rows, what the benchmark's cells route by) and
 ``balanced`` (row t takes experts t, t + R/k, ... mod R: every expert the
 same load). One JSON line a (geometry, routing, rows) with the milliseconds
-of ``model.ffn_block`` under either product, the largest difference of
-their outputs, and the kernel's two calls alone; ``--tiles`` times the
-grouped product under other (ROW_TILE, TILE_ELEMS) and ``--out-tiles``
+of ``model.ffn_block`` under each product, the largest difference of
+their outputs from the masked one's, the experts the live rows touched, and
+the grouped kernel's two calls alone; ``--tiles`` times the
+grouped product under other (ROW_TILE, TILE_ELEMS), ``--out-tiles``
 under other output tiles of a width that is no whole number of lane tiles
 (a ragged last tile in place of the whole width); ``--layers`` adds the
-time a layer of a scan over stacked layers, as a served program runs them. A time is the least of
+time a layer of a scan over stacked layers, as a served program runs them.
+The decode candidate PR 56 dropped is the grouped product at row tiles of
+8 to 32: ``--products masked,grouped --tiles 32x2097152,...``. A time is the least of
 three loops' mean, each loop ending in ``block_until_ready``."""
 
 from __future__ import annotations
@@ -63,7 +69,16 @@ GEOMETRIES = {
         hidden_size=2688, intermediate_size=1856, moe_intermediate_size=1856,
         num_experts=32, num_experts_per_tok=6, num_routed_experts=128,
         ffn_act="relu2", **_ATTN),
+    "deepseek": Cohere2MoeSpec(
+        hidden_size=7168, intermediate_size=2048, moe_intermediate_size=2048,
+        num_experts=16, num_experts_per_tok=8, num_routed_experts=256,
+        **_ATTN),
+    "solar": Cohere2MoeSpec(
+        hidden_size=4096, intermediate_size=1280, moe_intermediate_size=1280,
+        num_experts=40, num_experts_per_tok=8, num_routed_experts=320,
+        **_ATTN),
 }
+PRODUCTS = ("masked", "grouped", "touched")
 ROWS = (32, 64, 128, 256, 512, 1024, 2048, 4096)
 
 
@@ -112,25 +127,34 @@ def timed(fn, *args, loops=3):
     return best * 1e3, out
 
 
-def products(spec, lp, x, backends):
+def forced(product):
+    """``model.expert_product`` answering ``product`` at every size: the
+    threshold is what is being measured."""
+    model.expert_product = lambda rows, backends: product
+
+
+def products(spec, lp, x, live, backends, product):
+    forced(product)
     f = jax.jit(lambda x, lp: model.ffn_block(x, lp, spec, router_in=x,
-                                              backends=backends))
+                                              live=live, backends=backends))
     try:
-        return timed(f, x, lp)
+        ms, (out, stats) = timed(f, x, lp)
+        return ms, out, stats
     except Exception as e:  # noqa: BLE001 -- out of memory at a size: a hole in the table
         print(f"# {type(e).__name__}: {str(e)[:200]}", file=sys.stderr)
-        return None, None
+        return None, None, None
 
 
-def scanned(spec, lps, x, backends, whole):
+def scanned(spec, lps, x, live, backends, product, whole):
     """Milliseconds a layer of ``model.scan_layers`` over the stacked
     layers ``lps`` (as a served program runs them), the experts sliced a
     layer or handed whole."""
     n = lps["moe_gate"].shape[0]
+    forced(product)
 
     def body(x, lp):
-        return x + model.ffn_block(x, lp, spec, router_in=x,
-                                   backends=backends), None
+        return x + model.ffn_block(x, lp, spec, router_in=x, live=live,
+                                   backends=backends)[0], None
 
     f = jax.jit(lambda x, lps: model.scan_layers(
         body, x, lps, spec, whole_experts=whole)[0])
@@ -178,20 +202,23 @@ def main() -> int:
     ap.add_argument("--out-tiles", default="",
                     help="output tiles of a width that is no whole number "
                     "of lane tiles, in place of the whole width")
+    ap.add_argument("--live", type=int, default=0,
+                    help="how many of the rows are live (0: every row)")
+    ap.add_argument("--products", default=",".join(PRODUCTS))
     ap.add_argument("--layers", type=int, default=0,
                     help="also scan this many stacked layers: ms a layer "
-                    "masked, grouped over sliced experts, grouped over whole")
+                    "masked, grouped over sliced experts, grouped over "
+                    "whole, touched over whole")
     ap.add_argument("--out", default="chiprun_out/expert_layer.jsonl")
     args = ap.parse_args()
     dev = jax.devices()[0]
     device = {"platform": dev.platform, "kind": dev.device_kind}
     os.makedirs(os.path.dirname(args.out), exist_ok=True)
     real_route = model.moe_route
-    # The threshold is what is being measured: either product at every size.
-    model.MOE_DENSE_MAX_ROWS = 0
     # The record of a runner on one device: its experts are whole (the
-    # CPU interprets the kernel); XLA: the masked product at every size.
+    # CPU interprets the kernels).
     local = Backends(experts_whole=True, interpret=dev.platform == "cpu")
+    wanted = args.products.split(",")
     with open(args.out, "a") as sink:
         def say(line):
             text = json.dumps({**line, "device": device})
@@ -206,38 +233,59 @@ def main() -> int:
                 lambda *a: jnp.stack(a), *(layer(spec, jax.random.key(i))
                                            for i in range(args.layers)))
             for routing in args.routing.split(","):
-                model.moe_route = (balanced(spec) if routing == "balanced"
-                                   else real_route)
+                route = balanced(spec) if routing == "balanced" else real_route
                 for rows in map(int, args.rows.split(",")):
+                    n_live = min(args.live or rows, rows)
+                    live = jnp.arange(rows) < n_live
+
+                    def live_route(router, spec, bias=None, live=live):
+                        """A row that is not live chooses nothing, under
+                        every product."""
+                        gates, top_i = route(router, spec, bias)
+                        return gates, jnp.where(live[:, None], top_i, -1)
+
+                    model.moe_route = live_route
                     x = jax.random.normal(jax.random.key(rows),
                                           (rows, spec.hidden_size),
                                           jnp.bfloat16)
                     line = {"geometry": name, "routing": routing,
-                            "rows": rows}
-                    masked_ms, a = products(spec, lp, x, XLA)
-                    grouped_ms, b = products(spec, lp, x, local)
-                    line["masked_ms"] = masked_ms and round(masked_ms, 4)
-                    line["grouped_ms"] = grouped_ms and round(grouped_ms, 4)
-                    if a is not None and b is not None:
-                        a, b = (np.asarray(v, np.float32) for v in (a, b))
-                        line["mean_abs"] = float(np.abs(a).mean())
-                        line["max_diff"] = float(np.abs(a - b).max())
-                    if routing == "random" and grouped_ms is not None:
+                            "rows": rows, "live": n_live}
+                    outs = {}
+                    for product in wanted:
+                        ms, out, stats = products(spec, lp, x, live, local,
+                                                  product)
+                        line[f"{product}_ms"] = ms and round(ms, 4)
+                        if out is not None:
+                            outs[product] = np.asarray(out, np.float32)
+                            line["touched"] = int(stats[0])
+                    if "masked" in outs:
+                        line["mean_abs"] = float(np.abs(outs["masked"]).mean())
+                        for product, out in outs.items():
+                            if product != "masked":
+                                line[f"{product}_max_diff"] = float(
+                                    np.abs(out - outs["masked"]).max())
+                    if (routing == "random" and n_live == rows
+                            and line.get("grouped_ms") is not None):
+                        model.moe_route = route
                         line.update(kernel_calls(spec, lp, x,
                                                  local.interpret))
+                        model.moe_route = live_route
                     if args.layers:
-                        line["scan_masked_ms"] = scanned(spec, lps, x, XLA,
-                                                         False)
-                        line["scan_sliced_ms"] = scanned(spec, lps, x, local,
-                                                         False)
-                        line["scan_whole_ms"] = scanned(spec, lps, x, local,
-                                                        True)
+                        for key, product, whole in (
+                                ("scan_masked_ms", "masked", False),
+                                ("scan_sliced_ms", "grouped", False),
+                                ("scan_whole_ms", "grouped", True),
+                                ("scan_touched_sliced_ms", "touched", False),
+                                ("scan_touched_ms", "touched", True)):
+                            if product in wanted:
+                                line[key] = scanned(spec, lps, x, live, local,
+                                                    product, whole)
                     for variant in filter(None, args.tiles.split(",")):
                         tm, elems = map(int, variant.split("x"))
                         keep = experts.ROW_TILE, experts.TILE_ELEMS
                         experts.ROW_TILE, experts.TILE_ELEMS = tm, elems
                         jax.clear_caches()
-                        ms, _ = products(spec, lp, x, local)
+                        ms = products(spec, lp, x, live, local, "grouped")[0]
                         line[f"grouped_ms@{variant}"] = ms and round(ms, 4)
                         experts.ROW_TILE, experts.TILE_ELEMS = keep
                         jax.clear_caches()
@@ -247,7 +295,7 @@ def main() -> int:
                         experts.out_tile = lambda k, n, tn=tn, keep=keep: (
                             tn if n % 128 else keep(k, n))
                         jax.clear_caches()
-                        ms, _ = products(spec, lp, x, local)
+                        ms = products(spec, lp, x, live, local, "grouped")[0]
                         line[f"grouped_ms@tn{tn}"] = ms and round(ms, 4)
                         if ms is not None and routing == "random":
                             line[f"gate_up_ms@tn{tn}"] = kernel_calls(

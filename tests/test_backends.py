@@ -201,8 +201,9 @@ def test_debug_perf_names_the_reader_s_turn_where_it_is_taken(kv_heads,
 OFF = "off (recurrent state has no snapshot)"
 #: configuration -> the labels of its window programs and of its prefill
 #: programs at 32 and at 256 rows, on the CPU under "auto" (XLA's reader;
-#: the experts whole on the one device, so 256 rows take the grouped
-#: product), as the cell launches it.
+#: the experts whole on the one device, so a window's step and 32 rows walk
+#: the touched experts and 256 rows take the grouped product), as the cell
+#: launches it.
 NAMES = {
     "qwen2.5-7b-int8": {
         "decode_window": {"attention_backend": "xla",
@@ -212,35 +213,35 @@ NAMES = {
     "smallthinker-21b-a3b-int8": {
         "decode_window": {"attention_backend": "xla",
                           "kv_commit_backend": "scatter", "page_size": 16,
-                          "draft": "none", "expert_product": "masked"},
-        "prefill": ({"expert_product": "masked"},
+                          "draft": "none", "expert_product": "touched"},
+        "prefill": ({"expert_product": "touched"},
                     {"expert_product": "grouped"})},
     "command-a-plus-ep8-int8": {
         "decode_window": {"attention_backend": "xla",
                           "kv_commit_backend": "scatter", "page_size": 16,
-                          "draft": "none", "expert_product": "masked"},
-        "prefill": ({"expert_product": "masked"},
+                          "draft": "none", "expert_product": "touched"},
+        "prefill": ({"expert_product": "touched"},
                     {"expert_product": "grouped"})},
     "deepseek-v3.2-exp-ep16-int8": {
         "decode_window": {"attention_backend": "xla",
                           "kv_commit_backend": "scatter", "page_size": 16,
                           "index_backend": "xla", "draft": "none",
-                          "expert_product": "masked"},
-        "prefill": ({"expert_product": "masked"},
+                          "expert_product": "touched"},
+        "prefill": ({"expert_product": "touched"},
                     {"expert_product": "grouped"})},
     "glm-4.7-flash-ep4-int8": {
         "decode_window": {"attention_backend": "xla",
                           "kv_commit_backend": "scatter", "page_size": 16,
-                          "draft": "mtp", "expert_product": "masked"},
-        "prefill": ({"expert_product": "masked"},
+                          "draft": "mtp", "expert_product": "touched"},
+        "prefill": ({"expert_product": "touched"},
                     {"expert_product": "grouped"})},
     "nemotron-3-nano-30b-a3b-ep4-int8": {
         "decode_window": {"attention_backend": "xla",
                           "kv_commit_backend": "scatter", "page_size": 16,
-                          "draft": "none", "expert_product": "masked",
+                          "draft": "none", "expert_product": "touched",
                           "ssm_state": "float32", "prefix_reuse": OFF,
                           "ssm_backend": "xla"},
-        "prefill": ({"expert_product": "masked", "ssm_state": "float32",
+        "prefill": ({"expert_product": "touched", "ssm_state": "float32",
                      "prefix_reuse": OFF},
                     {"expert_product": "grouped", "ssm_state": "float32",
                      "prefix_reuse": OFF})},
@@ -355,7 +356,8 @@ def test_the_names_a_block_kind_s_programs_are_published_under(name):
             assert "ssm" not in status
         if "expert_product" in window:
             assert status["moe"]["expert_product"] == {
-                "decode_window": ["masked"], "prefill": ["masked", "grouped"]}
+                "decode_window": ["touched"],
+                "prefill": ["touched", "grouped"]}
         else:
             assert "moe" not in status
     finally:

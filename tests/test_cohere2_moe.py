@@ -433,14 +433,16 @@ def test_load_stats_count_the_held_experts():
     one_hot = jax.nn.one_hot(top_i - spec.first_expert, spec.num_experts)
     live = jnp.asarray([True, True, True, False])
     touched, load, some, local, picks = np.asarray(
-        model.moe_load_stats(one_hot, live, spec))
+        model.moe_load_stats(*model.held_load(one_hot, live)[::2], live,
+                             spec))
     assert (touched, some, local, picks) == (3, 1, 5, 9)
     # Expert 4 holds 3 tokens; an even router gives 3 rows x 3 / 8 each.
     assert load == pytest.approx(3 / (9 / 8))
     # A block that holds every expert keeps its three sums.
     mixtral = ModelSpec(num_experts=8, num_experts_per_tok=3)
-    assert model.moe_load_stats(jax.nn.one_hot(top_i, 8), live,
-                                mixtral).shape == (3,)
+    assert model.moe_load_stats(
+        *model.held_load(jax.nn.one_hot(top_i, 8), live)[::2], live,
+        mixtral).shape == (3,)
 
 
 def test_a_long_batch_of_a_share_takes_the_kernel_by_its_own_pairs(
@@ -457,12 +459,11 @@ def test_a_long_batch_of_a_share_takes_the_kernel_by_its_own_pairs(
     monkeypatch.setattr(model, "_grouped_experts",
                         lambda *a, **kw: calls.append(1) or real(*a, **kw))
     outs = []
-    for limit in (32, 10 ** 9):
-        monkeypatch.setattr(model, "MOE_DENSE_MAX_ROWS", limit)
+    monkeypatch.setattr(model, "MOE_DENSE_MAX_ROWS", 32)
+    for record in (Backends(experts_whole=True, interpret=True), Backends()):
         outs.append(np.asarray(jax.jit(lambda x: model.ffn_block(
-            x, lp, spec, backends=Backends(
-                experts_whole=True, interpret=True)))(x), np.float32))
-        assert len(calls) == 1          # taken above the threshold alone
+            x, lp, spec, backends=record))(x), np.float32))
+        assert len(calls) == 1          # where the experts are whole alone
     assert np.abs(outs[1]).mean() > 0.1
     np.testing.assert_allclose(outs[0], outs[1], atol=0.02)
 

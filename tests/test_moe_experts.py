@@ -1,7 +1,8 @@
-"""The grouped expert product (engine/experts.py): sorted (row, choice)
-pairs, each multiplied by its own expert as stored, against the masked
-product over every resident expert; the kernel runs interpreted here, as
-the attention kernels' tests run theirs."""
+"""The two kernels of engine/experts.py against the masked product over
+every resident expert: the grouped one (sorted (row, choice) pairs, each
+multiplied by its own expert as stored) and the one a window's step takes
+(every row by the experts the live rows chose, one visit an expert); they
+run interpreted here, as the attention kernels' tests run theirs."""
 
 import dataclasses
 import json
@@ -13,7 +14,7 @@ import numpy as np
 import pytest
 
 from dynamo_tpu.engine import experts, model
-from dynamo_tpu.engine.backends import Backends
+from dynamo_tpu.engine.backends import XLA, Backends
 from dynamo_tpu.engine.config import Cohere2MoeSpec, EngineConfig, ModelSpec
 from dynamo_tpu.engine.quant import QTensor, quantize_params
 
@@ -89,11 +90,11 @@ def test_the_kernel_gives_the_masked_product(held, quant, act, rows,
     assert load[-1] == 0 and load[0] == rows >= experts.ROW_TILE
     x = jax.random.normal(jax.random.key(rows), (rows, H), jnp.bfloat16)
     outs = {}
-    for product, limit in (("grouped", 64), ("masked", 10 ** 9)):
-        monkeypatch.setattr(model, "MOE_DENSE_MAX_ROWS", limit)
-        assert model.expert_product(rows, WHOLE) == product
+    monkeypatch.setattr(model, "MOE_DENSE_MAX_ROWS", 64)
+    for product, record in (("grouped", WHOLE), ("masked", XLA)):
+        assert model.expert_product(rows, record) == product
         outs[product] = np.asarray(jax.jit(lambda x: model.ffn_block(
-            x, lp, spec, router_in=x, backends=WHOLE))(x),
+            x, lp, spec, router_in=x, backends=record))(x),
             np.float32)
     assert np.abs(outs["masked"]).mean() > 0.05
     np.testing.assert_allclose(outs["grouped"], outs["masked"], atol=0.05)
@@ -128,6 +129,106 @@ def test_the_walk_visits_each_group_on_each_of_its_row_tiles():
     assert experts.out_tile(768, 2560) == 2560
     assert experts.out_tile(4096, 4096) == 512
     assert experts.out_tile(64, 48) == 48
+
+
+# -- a window's step: the experts its live rows chose ---------------------------
+
+#: How many of a step's rows are live: every row, 19 of 32 (the cells'
+#: share of their slots), one, none.
+LIVE = {"every row live": lambda rows: rows,
+        "19 of 32": lambda rows: rows * 19 // 32,
+        "one": lambda rows: 1, "none": lambda rows: 0}
+_STEP = {}
+
+
+def stepped(spec: ModelSpec, rows: int):
+    """A routing under which later rows choose later experts: row t takes
+    the k experts from the (t // (rows / 8))-th held on, so the rows that
+    are not live choose experts no live row chose, and the last five held
+    are nobody's; a share's last choice falls on an expert held elsewhere."""
+    k, first = spec.num_experts_per_tok, spec.first_expert
+    t = np.arange(rows)
+    top_i = first + (t // max(rows // 8, 1))[:, None] + np.arange(k)[None, :]
+    if spec.holds_share:
+        top_i[:, -1] = first + spec.num_experts + t % 16
+    gates = np.random.default_rng(rows).uniform(0.1, 1.0, top_i.shape)
+
+    def route(router, spec, bias=None):
+        return (jnp.asarray(gates / gates.sum(-1, keepdims=True),
+                            jnp.float32), jnp.asarray(top_i, jnp.int32))
+    return route, top_i
+
+
+def _step(held, quant, act, rows, product, monkeypatch):
+    """(spec, clean leaves, the routing's choices, jitted ``ffn_block`` of
+    (x, leaves, live)) of a case, one trace a (shape, product)."""
+    spec = Cohere2MoeSpec(**{**_ATTN, "moe_intermediate_size": WIDTH.get(
+        act, I)}, **HELD[held], ffn_act=act)
+    route, top_i = stepped(spec, rows)
+    monkeypatch.setattr(model, "moe_route", route)
+    record = WHOLE if product == "touched" else XLA
+    assert model.expert_product(rows, record) == product
+    key = (held, quant, act, rows, product)
+    if key not in _STEP:
+        _STEP[key] = jax.jit(lambda x, lp, live: model.ffn_block(
+            x, lp, spec, router_in=x, live=live, backends=record))
+    return spec, layer(spec, quant), top_i, _STEP[key]
+
+
+def poisoned(lp: dict, spare: np.ndarray) -> dict:
+    """The leaves with every expert in ``spare`` (bool [E]) unreadable: a
+    NaN in its scales (int8) or its weights. A product that reads such an
+    expert at all returns NaN in every row (0 x NaN)."""
+    def spoil(w):
+        if isinstance(w, QTensor):
+            return QTensor(w.q, jnp.where(spare[:, None, None], jnp.nan, w.s))
+        return jnp.where(spare[:, None, None], jnp.nan, w)
+    return {k: spoil(v) if k.startswith("moe_w_") else v
+            for k, v in lp.items()}
+
+
+@pytest.mark.parametrize("live", list(LIVE))
+@pytest.mark.parametrize("rows", [8, 32, 64])
+@pytest.mark.parametrize("act", ACTS)
+@pytest.mark.parametrize("quant", [True, False], ids=["int8", "bf16"])
+@pytest.mark.parametrize("held", list(HELD))
+def test_a_step_walks_the_experts_its_live_rows_chose(held, quant, act, rows,
+                                                      live, monkeypatch):
+    """``experts.touched_product`` against the masked product: the live
+    rows' outputs agree, a row that is not live comes back zero, the visits
+    are ``moe_load_stats``' distinct count, and an expert no LIVE row chose
+    is never read: its leaves hold NaN here (a dead slot's choices fall on
+    such experts too), under which the masked product returns nothing but
+    NaN. With no live row the kernel runs no step and returns zeros."""
+    n = LIVE[live](rows)
+    alive = np.arange(rows) < n
+    spec, lp, top_i, walk = _step(held, quant, act, rows, "touched",
+                                  monkeypatch)
+    local = top_i[alive] - spec.first_expert
+    chosen = np.unique(local[(local >= 0) & (local < spec.num_experts)])
+    spare = ~np.isin(np.arange(spec.num_experts), chosen)
+    dead = top_i[~alive] - spec.first_expert
+    assert spare.sum() >= 5
+    if n < rows:    # the dead slots chose experts no live row did
+        assert np.isin(dead, np.flatnonzero(spare)).any()
+    x = jax.random.normal(jax.random.key(rows), (rows, H), jnp.bfloat16)
+    out, stats = walk(x, poisoned(lp, spare), jnp.asarray(alive))
+    out = np.asarray(out, np.float32)
+    assert stats[0] == len(chosen) and stats[2] == (n > 0)
+    _, order, count = model.held_load(jax.nn.one_hot(
+        top_i - spec.first_expert, spec.num_experts), jnp.asarray(alive))
+    assert int(count) == len(chosen)
+    assert order[:len(chosen)].tolist() == chosen.tolist()
+    assert np.isfinite(out).all() and not out[~alive].any()
+    spec, lp, _, masked = _step(held, quant, act, rows, "masked", monkeypatch)
+    want, want_stats = masked(x, lp, jnp.asarray(alive))
+    np.testing.assert_array_equal(stats, want_stats)
+    if n:
+        want = np.asarray(want, np.float32)[alive]
+        assert np.abs(want).mean() > 0.05
+        np.testing.assert_allclose(out[alive], want, atol=0.05)
+        assert np.isnan(np.asarray(masked(x, poisoned(lp, spare), jnp.asarray(
+            alive))[0], np.float32)).all() or not spare.any()
 
 
 def decided(params):
@@ -174,13 +275,16 @@ def rehearsal(name: str | None):
         jax.tree.map(np.asarray, params)))
 
 
+@pytest.mark.parametrize("product", ["grouped", "touched"])
 @pytest.mark.parametrize("name", [
     "smallthinker-21b-a3b-int8", "command-a-plus-ep8-int8",
     "deepseek-v3.2-exp-ep16-int8", "glm-4.7-flash-ep4-int8", None,
     "a share of two-matrix experts"])
-def test_prefill_gives_the_masked_products_logits(name, monkeypatch):
-    """``prefill_forward`` of each routed rehearsal model with the threshold
-    on either side of the prompt's rows: the same logits."""
+def test_prefill_gives_the_masked_products_logits(name, product, monkeypatch):
+    """``prefill_forward`` of each routed rehearsal model with the prompt's
+    rows on either side of MOE_DENSE_MAX_ROWS (over it the grouped product;
+    within it the walk, every row live): the masked product's logits (the
+    record of a mesh: XLA's)."""
     spec, params = rehearsal(name)
     b, s, page = 2, 16, 4
     heads, (dk, dv) = spec.kv_entry
@@ -191,28 +295,31 @@ def test_prefill_gives_the_masked_products_logits(name, monkeypatch):
         jax.random.key(4), (b, s), 1, spec.vocab_size), np.int32)
     pos = np.broadcast_to(np.arange(s, dtype=np.int32), (b, s))
     table = (1 + np.arange(b * s // page, dtype=np.int32)).reshape(b, -1)
+    monkeypatch.setattr(model, "MOE_DENSE_MAX_ROWS",
+                        b * s - (product == "grouped"))
     logits = {}
-    for product, limit in (("grouped", b * s - 1), ("masked", b * s)):
-        monkeypatch.setattr(model, "MOE_DENSE_MAX_ROWS", limit)
-        assert model.expert_product(b * s, WHOLE) == product
-        logits[product] = np.asarray(jax.jit(
+    for taken, record in ((product, WHOLE), ("masked", XLA)):
+        assert model.expert_product(b * s, record) == taken
+        logits[taken] = np.asarray(jax.jit(
             lambda p, k, v: model.prefill_forward(
                 p, spec, k, v, tokens, pos, table, np.full((b,), s, np.int32),
-                backends=WHOLE)[0])(params, *pools), np.float32)
+                backends=record)[0])(params, *pools), np.float32)
     assert np.abs(logits["masked"]).mean() > 0.05
     # Eight layers deep, two roundings of each layer's gate and up apart
     # (the masked product rounds a product to bfloat16 before its scale).
-    np.testing.assert_allclose(logits["grouped"], logits["masked"],
+    np.testing.assert_allclose(logits[product], logits["masked"],
                                atol=0.1, rtol=0.05)
 
 
 def test_a_chunk_over_history_reads_the_expert_stacks_whole(monkeypatch):
-    """The runner's with-history prefill (a prompt longer than a bucket)
-    above the threshold: its layer scan hands the kernel the expert stacks
-    whole (``scan_layers(whole_experts=True)``), which changes WHERE the
-    kernel reads and nothing else: the logits are those of the same kernel
-    over experts sliced a layer, bit for bit, and the masked product's to
-    two chunks' roundings at this toy's width."""
+    """The runner's with-history prefill (a prompt longer than a bucket) on
+    either side of the threshold: its layer scan hands the kernel the
+    expert stacks whole (``scan_layers(whole_experts=True)``), which changes
+    WHERE the kernel reads and nothing else: the logits are those of the
+    same kernel over experts sliced a layer, bit for bit; the walk's are the
+    grouped product's at this toy's width (one arithmetic), and the masked
+    product's (a runner told its experts may be partitioned) to two chunks'
+    roundings."""
     spec, params = rehearsal("smallthinker-21b-a3b-int8")
     tokens = np.arange(1, 31, dtype=np.int32)
     real, handed = model.scan_layers, []
@@ -222,20 +329,24 @@ def test_a_chunk_over_history_reads_the_expert_stacks_whole(monkeypatch):
         return real(*a, **kw)
 
     logits = {}
-    for name, limit, scan in (("whole", 8, real), ("sliced", 8, sliced),
-                              ("masked", 10 ** 9, real)):
+    for name, product, limit, scan in (
+            ("whole", "grouped", 8, real), ("sliced", "grouped", 8, sliced),
+            ("walked", "touched", 16, real), ("masked", "masked", 16, real)):
         monkeypatch.setattr(model, "MOE_DENSE_MAX_ROWS", limit)
         monkeypatch.setattr(model, "scan_layers", scan)
         runner = _runner(spec, params)
+        if product == "masked":
+            runner.backends = dataclasses.replace(runner.backends,
+                                                  experts_whole=False)
         runner.prefill(tokens[:16], 0, np.arange(1, 5), None, (0.0, 0, 1.0))
         _, out = runner.prefill(tokens[16:], 16, np.arange(5, 9),
                                 np.arange(1, 5), (0.0, 0, 1.0))
         assert {fn._labels["expert_product"] for fn
-                in runner._prefill_cache.values()} == {
-                    "masked" if name == "masked" else "grouped"}
+                in runner._prefill_cache.values()} == {product}
         logits[name] = np.asarray(out, np.float32)
     assert handed == [True, True]       # both programs asked for the stacks
     np.testing.assert_array_equal(logits["whole"], logits["sliced"])
+    np.testing.assert_array_equal(logits["whole"], logits["walked"])
     assert np.abs(logits["masked"]).mean() > 0.3
     np.testing.assert_allclose(logits["whole"], logits["masked"], atol=0.25)
 
@@ -274,13 +385,17 @@ def test_the_label_says_which_product_a_program_takes(monkeypatch):
     assert {key[:2]: v for key, v in labels.items()} == {
         (32, 1): {"expert_product": "grouped"},
         (32, 2): {"expert_product": "grouped"},
-        (16, 1): {"expert_product": "masked"}}
+        (16, 1): {"expert_product": "touched"}}
     window = runner._get_window(2, 4)
-    assert window._labels["expert_product"] == "masked"   # 2 rows a step
+    assert window._labels["expert_product"] == "touched"  # 2 rows a step
     assert perf.get_registry().label_values("expert_product") == {
-        "prefill": ["grouped", "masked"], "decode_window": ["masked"]}
+        "prefill": ["grouped", "touched"], "decode_window": ["touched"]}
     # Any mesh keeps the masked product at every size.
-    assert not _runner(spec, params, tp=2).backends.experts_whole
+    meshed = _runner(spec, params, tp=2)
+    assert not meshed.backends.experts_whole
+    assert meshed._get_window(2, 4)._labels["expert_product"] == "masked"
+    assert {model.expert_product(rows, meshed.backends)
+            for rows in (2, 32, 64, 128, 4096)} == {"masked"}
     # A dense block has no such label.
     perf.get_registry().reset()
     dense = ModelSpec(vocab_size=64, hidden_size=32, intermediate_size=16,
@@ -291,3 +406,188 @@ def test_the_label_says_which_product_a_program_takes(monkeypatch):
     assert all("expert_product" not in fn._labels
                for fn in runner._prefill_cache.values())
     assert "expert_product" not in runner._get_window(2, 4)._labels
+
+
+# -- the window programs take the walk --------------------------------------------
+
+#: block kind -> (rehearsal model, what its cell's launch adds).
+WINDOWS = {"dense block": ("smallthinker-21b-a3b-int8", {}),
+           "hybrid": ("nemotron-3-nano-30b-a3b-ep4-int8", {}),
+           "delta rule": ("solar-open2-250b-ep8-int8", {}),
+           "drafting": ("glm-4.7-flash-ep4-int8",
+                        dict(spec_decode="mtp", spec_k=1))}
+
+
+def _windows(name, launch, dead_token=None, windows=2, steps=4, whole=True):
+    """Two prompts into slots 0 and 2 of four, then ``windows`` windows over
+    them; the slots between hold ``dead_token`` at a position of their own
+    where it is given (and no sequence: they are not live). ``whole``
+    False: a runner told that its experts may be partitioned (the masked
+    product). Returns the runner and, a live slot, its emitted tokens and
+    their logprobs."""
+    from dynamo_tpu.engine.runner import (PK_CAP, PK_LOGPROB, PK_OVERRIDE,
+                                          PK_POS, PK_PREFIX, PK_SEQLEN,
+                                          PK_TOKEN, PK_TOPP, ModelRunner,
+                                          PrefillSeq)
+    spec, params = rehearsal(name)
+    page = 4
+    runner = ModelRunner(EngineConfig(
+        model=spec, page_size=page, num_pages=64, max_pages_per_seq=8,
+        max_num_seqs=4, prefill_buckets=(16, 32), attention_backend="xla",
+        decode_window=steps, **launch), params=params)
+    runner.backends = dataclasses.replace(runner.backends,
+                                          experts_whole=whole)
+    prompts = {0: (np.arange(3, 14) * 5) % spec.vocab_size,
+               2: (np.arange(2, 9) * 7) % spec.vocab_size}
+    pages = {0: np.arange(1, 9, dtype=np.int32),
+             2: np.arange(9, 17, dtype=np.int32)}
+    seqs = [PrefillSeq(tokens=np.asarray(p, np.int32), start_pos=0,
+                       chunk_pages=pages[s][:-(-len(p) // page)],
+                       hist_pages=None, sampling=(0.0, 0, 1.0),
+                       next_page=int(pages[s][len(p) // page]))
+            for s, p in prompts.items()]
+    runner.prefill_batch(seqs, slots=list(prompts))
+    pos = {s: len(p) for s, p in prompts.items()}
+    toks, lps = {s: [] for s in prompts}, {s: [] for s in prompts}
+    for _ in range(windows):
+        packed = np.zeros((4, PK_PREFIX + 8), np.int32)
+        packed[:, PK_TOPP] = np.float32(1.0).view(np.int32)
+        for s in prompts:
+            packed[s, PK_POS], packed[s, PK_SEQLEN] = pos[s], pos[s] + 1
+            packed[s, PK_CAP], packed[s, PK_LOGPROB] = 8 * page, 1
+            packed[s, PK_PREFIX:] = pages[s]
+        if dead_token is not None:
+            for s in (1, 3):
+                packed[s, PK_OVERRIDE], packed[s, PK_TOKEN] = 1, dead_token
+                packed[s, PK_POS], packed[s, PK_CAP] = 5 + s, 8 * page
+                packed[s, PK_PREFIX:] = 17 + 8 * (s // 2) + np.arange(8)
+        out = runner.decode_window(packed, steps)
+        t, lp = np.asarray(out[0]), np.asarray(out[1])
+        for s in prompts:
+            if "spec_decode" not in launch:
+                toks[s] += t[:, s].tolist()
+                lps[s] += lp[:, s].tolist()
+                pos[s] += steps
+                continue
+            for m, e in enumerate(np.asarray(out[4]["emit"])[:, s]):
+                toks[s] += t[m, s, :e].tolist()
+                lps[s] += lp[m, s, :e].tolist()
+                pos[s] += int(e)
+    return runner, toks, lps
+
+
+# (The delta rule's rehearsal model carries a rounding far, sixteen
+# sublayers behind a sharpened router: its streams part from the masked
+# run's at the third token. It is held to its own experts, to the label and
+# to the dead slots below, not to logprobs.)
+@pytest.mark.parametrize("kind", ["dense block", "hybrid", "drafting"])
+def test_a_window_program_takes_the_walk_and_gives_the_masked_logprobs(
+        kind, monkeypatch):
+    """The window program of each routed rehearsal model (the dense block's
+    scan, the hybrid's groups, the drafting window's verify step and its
+    module's own expert layer) with two of four slots live: labelled
+    ``touched``, its emitted tokens are the masked run's up to a tie and
+    their logprobs agree as the grouped product's logits do; every expert layer's stacks reach
+    ONE kernel call whole (no ``[None]`` of a layer's slice but the
+    module's, a stack of one)."""
+    from dynamo_tpu.engine import experts
+    name, launch = WINDOWS[kind]
+    calls = []
+    real = experts.touched_product
+    monkeypatch.setattr(experts, "touched_product", lambda x, g, ws, *a, **kw: (
+        calls.append(ws[0].shape[0]) or real(x, g, ws, *a, **kw)))
+    got = {}
+    for product in ("touched", "masked"):
+        calls.clear()
+        runner, toks, lps = _windows(name, launch,
+                                     whole=product == "touched")
+        label, = {fn._labels["expert_product"]
+                  for fn in runner._window_cache.values()}
+        assert label == product
+        got[product] = toks, lps
+        if product == "masked":
+            assert not calls
+            continue
+        layers = runner.spec.num_layers - runner.spec.first_k_dense
+        if runner.spec.recurrent:
+            layers = sum(c == "E" for c in runner.spec.layer_pattern) or len(
+                [c for c in runner.spec.layer_pattern if c == "*"]) or layers
+        # Traced once a scan (a prefill program of 16 rows takes the walk
+        # too): each call sees the stack over all its layers.
+        assert calls and set(calls) <= {layers, 1}, (calls, layers)
+    for slot in (0, 2):
+        (a, la), (b, lb) = ((got[p][0][slot], got[p][1][slot])
+                            for p in ("touched", "masked"))
+        # Greedy over random weights: where the two part it is on a tie
+        # (the tokens differ, their logprobs do not), and what follows is
+        # another sequence.
+        n = next((i for i, (x, y) in enumerate(zip(a, b)) if x != y), len(a))
+        assert len(a) >= 8 and n >= 4, (a, b)
+        np.testing.assert_allclose(la[:n + 1], lb[:n + 1], atol=0.1,
+                                   rtol=0.05)
+
+
+@pytest.mark.parametrize("product", ["grouped", "touched"])
+def test_a_group_without_a_mixer_reads_its_own_experts(product, monkeypatch):
+    """The delta-rule block's first group is attention and an expert layer,
+    no mixer: the index its scan carries for the mixers' stacks is NOT the
+    group's, and the expert stacks handed whole are read by the group's own
+    (hybrid.scan_groups): the logits are those of the same kernel over
+    experts sliced a group, bit for bit. Until PR 56 the stacks were read by
+    the mixers' index: a prompt over MOE_DENSE_MAX_ROWS rows multiplied
+    group g by the experts of group g - 1 (no cell's check sent such a
+    prompt; a window step takes a kernel since PR 56, and the cell's check
+    then read 0.23 nat at the median against 0.07 allowed)."""
+    from dynamo_tpu.engine import hybrid
+    from dynamo_tpu.engine.runner import ModelRunner, PrefillSeq
+    spec, params = rehearsal(WINDOWS["delta rule"][0])
+    assert spec.layer_pattern[0] == "*" and "K" in spec.layer_pattern
+    monkeypatch.setattr(model, "MOE_DENSE_MAX_ROWS",
+                        8 if product == "grouped" else 128)
+    tokens = (np.arange(3, 17) * 5) % spec.vocab_size
+    real, logits = hybrid.whole_expert_leaves, {}
+    for name, whole, leaves in (("whole", True, real),
+                                ("sliced", True, lambda ffn: (ffn, {})),
+                                ("masked", False, real)):
+        monkeypatch.setattr(hybrid, "whole_expert_leaves", leaves)
+        runner = ModelRunner(EngineConfig(
+            model=spec, page_size=4, num_pages=64, max_pages_per_seq=8,
+            max_num_seqs=4, prefill_buckets=(16, 32),
+            attention_backend="xla"), params=params)
+        runner.backends = dataclasses.replace(runner.backends,
+                                              experts_whole=whole)
+        runner.prefill_batch([PrefillSeq(
+            tokens=np.asarray(tokens, np.int32), start_pos=0,
+            chunk_pages=np.arange(1, 5, dtype=np.int32), hist_pages=None,
+            sampling=(0.0, 0, 1.0))], slots=[1])
+        label, = {fn._labels["expert_product"]
+                  for fn in runner._prefill_cache.values()}
+        assert label == (product if whole else "masked")
+        logits[name] = np.asarray(runner.last_prefill_logits, np.float32)[0]
+    np.testing.assert_array_equal(logits["whole"], logits["sliced"])
+    assert np.abs(logits["masked"]).max() > 1.0
+    np.testing.assert_allclose(logits["whole"], logits["masked"], atol=0.3)
+
+
+@pytest.mark.parametrize("kind", list(WINDOWS))
+def test_a_live_row_does_not_depend_on_what_dead_slots_hold(kind):
+    """Two runs of the same windows whose dead slots hold other tokens at
+    other positions: the live rows' tokens and logprobs, the pool (K, V or
+    the latent entries) and the recurrent state of the live slots are the
+    same bit for bit. A dead slot chooses experts too; under the walk its
+    choices make no visit and move no live row's sum."""
+    name, launch = WINDOWS[kind]
+    runs = [_windows(name, launch, dead_token=t) for t in (3, 41)]
+    assert {fn._labels["expert_product"] for r, _, _ in runs
+            for fn in r._window_cache.values()} == {"touched"}
+    (a, ta, la), (b, tb, lb) = runs
+    assert ta == tb and la == lb
+    for pool in ("k_cache", "v_cache"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(a, pool)[:, :, 1:17], np.float32),
+            np.asarray(getattr(b, pool)[:, :, 1:17], np.float32))
+    # S [layers, slots, ...] and the carried inputs [layers, taps, slots, C].
+    for x, y in zip(a.state_arrays, b.state_arrays):
+        np.testing.assert_array_equal(*(
+            np.take(np.asarray(s, np.float32), [0, 2], axis=s.ndim // 2 - 1)
+            for s in (x, y)))

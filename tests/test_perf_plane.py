@@ -261,14 +261,14 @@ def test_the_expert_product_is_an_info_series_and_a_counter(monkeypatch):
                           labels={"expert_product": kind})
             for rows, kind in ((512, "grouped"), (128, "masked"))]
     kept.append(registry.wrap("decode_window", lambda x: x, key=(8,),
-                              labels={"expert_product": "masked"}))
+                              labels={"expert_product": "touched"}))
     eng.runner.moe_grouped_pairs = 3072
     up.update(eng, force=True)
     info = samples("dynamo_tpu_perf_expert_product_info{")
     assert len(info) == 3 and all(line.endswith(" 1.0") for line in info)
     assert up.g_expert_product.get(program="prefill", kind="grouped") == 1
     assert up.g_expert_product.get(program="decode_window",
-                                   kind="masked") == 1
+                                   kind="touched") == 1
     assert up.c_moe_grouped_pairs.get() == 3072
     eng.runner.moe_grouped_pairs += 1536
     up.update(eng, force=True)

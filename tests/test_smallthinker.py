@@ -433,10 +433,11 @@ def test_grouped_product_matches_the_masked_product(quant, monkeypatch):
     chosen = set(np.asarray(model.moe_route(router, spec)[1]).ravel())
     assert len(chosen) < spec.num_experts          # some group is empty
     outs = []
-    for limit in (64, 10 ** 9):
-        monkeypatch.setattr(model, "MOE_DENSE_MAX_ROWS", limit)
+    monkeypatch.setattr(model, "MOE_DENSE_MAX_ROWS", 64)
+    for product, record in (("grouped", WHOLE), ("masked", XLA)):
+        assert model.expert_product(96, record) == product
         outs.append(np.asarray(jax.jit(lambda x, rin: model.ffn_block(
-            x, lp, spec, router_in=rin, backends=WHOLE))(x, rin),
+            x, lp, spec, router_in=rin, backends=record))(x, rin),
             np.float32))
     assert np.abs(outs[1]).mean() > 0.2
     np.testing.assert_allclose(outs[0], outs[1], atol=0.05)
@@ -445,33 +446,38 @@ def test_grouped_product_matches_the_masked_product(quant, monkeypatch):
 @pytest.mark.parametrize("local", [True, False])
 def test_the_product_is_chosen_by_rows_and_by_where_the_experts_are(
         local, monkeypatch):
-    """One rule for every routed kind: the grouped product above
-    MOE_DENSE_MAX_ROWS rows where the caller says the experts are whole on
-    one device (the runner: a mesh of one), the masked product below it and
-    wherever the expert axis may be partitioned. A Mixtral-style spec takes
-    the same fork; no model's name decides. The constant stays at 64 or
-    above: a decode step's rows (32) and a verify step's (64) keep the
-    masked product (ROADMAP S9)."""
+    """One rule for every routed kind: where the caller says the experts
+    are whole on one device (the runner: a mesh of one) a kernel reads the
+    chosen experts alone, the walk over the touched experts up to
+    MOE_DENSE_MAX_ROWS rows and the grouped product above it; the masked
+    product wherever the expert axis may be partitioned. A Mixtral-style
+    spec takes the same fork; no model's name decides. A decode step's rows
+    (32) and a verify step's (64) take the walk (ROADMAP S9)."""
     assert model.MOE_DENSE_MAX_ROWS >= 64
+    from dynamo_tpu.engine import experts
     calls = []
-    real = model._grouped_experts
-    monkeypatch.setattr(model, "_grouped_experts",
-                        lambda *a, **kw: calls.append(1) or real(*a, **kw))
+    for name, owner in (("_grouped_experts", model),
+                        ("touched_product", experts)):
+        monkeypatch.setattr(owner, name, (lambda real, name: lambda *a, **kw: (
+            calls.append(name) or real(*a, **kw)))(getattr(owner, name),
+                                                   name))
     mixtral = ModelSpec(vocab_size=64, hidden_size=32, intermediate_size=16,
                         num_layers=1, num_heads=2, num_kv_heads=1,
                         num_experts=4, num_experts_per_tok=2)
     lp = jax.tree.map(lambda a: a[0], model.init_params(
         mixtral, jax.random.key(0))["layers"])
     out = {}
-    for rows in (model.MOE_DENSE_MAX_ROWS, model.MOE_DENSE_MAX_ROWS + 8):
+    for rows, product in ((32, "touched"), (64, "touched"),
+                          (model.MOE_DENSE_MAX_ROWS, "touched"),
+                          (model.MOE_DENSE_MAX_ROWS + 8, "grouped")):
         x = jax.random.normal(jax.random.key(rows), (rows, 32), jnp.bfloat16)
         calls.clear()
         record = WHOLE if local else XLA
         out[rows] = model.ffn_block(x, lp, mixtral, backends=record)
-        grouped = local and rows > model.MOE_DENSE_MAX_ROWS
-        assert bool(calls) == grouped
-        assert model.expert_product(rows, record) == (
-            "grouped" if grouped else "masked")
+        product = product if local else "masked"
+        assert calls == {"masked": [], "grouped": ["_grouped_experts"],
+                         "touched": ["touched_product"]}[product]
+        assert model.expert_product(rows, record) == product
         masked = model.ffn_block(x, lp, mixtral)
         np.testing.assert_allclose(
             np.asarray(out[rows], np.float32),

@@ -230,40 +230,90 @@ def _expert_layer(v5e, spec, h, i, held, routed, shared=0):
     return (lambda rows: s((rows, h), jnp.bfloat16)), lp
 
 
-@pytest.mark.parametrize("rows", [32, 64, 512, 4096],
-                         ids=["a decode step", "masked at the floor",
-                              "a prompt", "a prefill group"])
+@pytest.mark.parametrize("rows", [32, 64, 128, 512, 4096],
+                         ids=["a decode step", "a verify step",
+                              "at the threshold", "a prompt",
+                              "a prefill group"])
 def test_smallthinker_expert_layer_compiles_for_v5e(v5e, rows):
     """The expert layer at the published widths (64 int8 experts of 2560 x
-    768) on both sides of MOE_DENSE_MAX_ROWS: the product over resident
-    experts under the gate mask, and the kernel over pairs sorted by expert
-    (engine/experts.py: two custom calls, Mosaic's), whose operations XLA
-    counts no more (a custom call's are its own) and whose temporaries are
-    the sorted pairs', never an [experts, rows, width] intermediate. A
-    decode step's 32 rows and a verify step's 64 stay masked whatever the
-    table at the constant says."""
+    768) on both sides of MOE_DENSE_MAX_ROWS: the walk over the touched
+    experts up to it (a decode step's 32 rows and a verify step's 64: ONE
+    custom call, Mosaic's, and no temporary of a matrix's size) and the
+    kernel over pairs sorted by expert above it (two custom calls), whose
+    operations XLA counts no more (a custom call's are its own) and whose
+    temporaries are the sorted pairs', never an [experts, rows, width]
+    intermediate; the product over every resident expert under the gate
+    mask where the record says the experts may be partitioned."""
     from dynamo_tpu.engine import model
+    from dynamo_tpu.engine.backends import XLA
     from dynamo_tpu.engine.config import SmallThinkerSpec
     spec = SmallThinkerSpec(
         hidden_size=2560, intermediate_size=768, num_layers=4, num_heads=28,
         num_kv_heads=4, head_dim=128, num_experts=64, num_experts_per_tok=6,
         moe_intermediate_size=768, quant="int8")
     x, lp = _expert_layer(v5e, spec, 2560, 768, 64, 64)
-    compiled = jax.jit(lambda x, lp: model.ffn_block(
-        x, lp, spec, router_in=x, backends=WHOLE)).lower(
-            x(rows), lp).compile()
-    flops = compiled.cost_analysis()["flops"]
     chosen = 2 * rows * 6 * 3 * 2560 * 768
-    kernels = compiled.as_text().count("tpu_custom_call")
+
+    def compiled(record):
+        c = jax.jit(lambda x, lp: model.ffn_block(
+            x, lp, spec, router_in=x, backends=record)).lower(
+                x(rows), lp).compile()
+        return (c.as_text().count("tpu_custom_call"),
+                c.cost_analysis()["flops"],
+                c.memory_analysis().temp_size_in_bytes)
+
+    kernels, flops, temp = compiled(WHOLE)
     if rows > model.MOE_DENSE_MAX_ROWS:
         assert model.expert_product(rows, WHOLE) == "grouped"
         assert kernels == 2 and flops < 0.1 * chosen, (kernels, flops)
         # The pairs' rows gathered, their gated unit, their outputs twice.
-        assert compiled.memory_analysis().temp_size_in_bytes < (
+        assert temp < (
             rows * 6 * (2560 * 2 + 768 * 2 + 2 * 2560 * 4) * 1.2 + (1 << 20))
-    else:
-        assert rows <= 64 or model.MOE_DENSE_MAX_ROWS > 64
-        assert kernels == 0 and flops > 64 / 6 * 0.9 * chosen, (flops, chosen)
+        return
+    assert model.expert_product(rows, WHOLE) == "touched"
+    assert kernels == 1 and flops < 0.1 * chosen, (kernels, flops)
+    assert temp < 1 << 20, temp     # the gates, the walk, the output
+    assert model.expert_product(rows, XLA) == "masked"
+    kernels, flops, _ = compiled(XLA)
+    assert kernels == 0 and flops > 64 / 6 * 0.9 * chosen, (flops, chosen)
+
+
+#: The six routed cells' expert shapes as one chip holds them: (hidden,
+#: expert width, experts held, matrices an expert, activation).
+CELL_EXPERTS = {"smallthinker": (2560, 768, 64, 3, "relu"),
+                "command-a-plus": (4096, 4096, 16, 3, "silu"),
+                "deepseek-v3.2": (7168, 2048, 16, 3, "silu"),
+                "glm-4.7-flash": (2048, 1536, 16, 3, "silu"),
+                "nemotron-3-nano": (2688, 1856, 32, 2, "relu2"),
+                "solar-open2": (4096, 1280, 40, 3, "silu")}
+
+
+@pytest.mark.parametrize("rows", [32, 64], ids=["a decode step",
+                                                "a verify step"])
+@pytest.mark.parametrize("cell", list(CELL_EXPERTS))
+def test_the_walk_compiles_at_the_six_cells_widths_for_v5e(v5e, cell, rows):
+    """``experts.touched_product`` over int8 stacks of three layers at each
+    routed cell's expert shape: Mosaic compiles it within
+    experts.VMEM_LIMIT_BYTES (an expert's three matrices whole at 2,560 x
+    768; tiles of the expert's width where they do not fit: 512 of 4,096,
+    256 of 2,048 under a hidden size of 7,168; a two-matrix expert's 1,856
+    whole, its way up as the chip holds it), ONE custom call, and the
+    stacks reach it where they lie: no temporary."""
+    from dynamo_tpu.engine import experts
+    h, i, e, n_w, act = CELL_EXPERTS[cell]
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    ws = (*[s((3, e, h, i), jnp.int8)] * (n_w - 1), s((3, e, i, h), jnp.int8))
+    scales = (*[s((3, e, 1, i), jnp.float32)] * (n_w - 1),
+              s((3, e, 1, h), jnp.float32))
+    compiled = jax.jit(lambda *a: experts.touched_product(
+        *a, act=act)).lower(
+            s((rows, h), jnp.bfloat16), s((rows, e), jnp.float32), ws, scales,
+            s((), jnp.int32), s((e,), jnp.int32), s((), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
 @pytest.mark.parametrize("whole", [True, False],
@@ -305,11 +355,12 @@ def test_a_shares_expert_layer_compiles_for_v5e(v5e, rows, widths):
     """An expert layer told its share at the published widths as one chip
     holds it (Command A+: a router over 128, 16 int8 experts of 4096 x 4096
     held, 4 shared; GLM-4.7-Flash: a router over 64, 16 of 2048 x 1536 held
-    from the sixteenth on, 1 shared): the masked product over the held
-    experts at a decode step's rows; above MOE_DENSE_MAX_ROWS the kernel
-    over the pairs sorted by held expert (a share TAKES it: pairs held
-    elsewhere sort behind the last group and are never visited), and the
-    shared experts' mean as XLA's product either way."""
+    from the sixteenth on, 1 shared): the walk over the held
+    experts it touched at a decode step's rows (ONE custom call: a choice
+    held elsewhere touches nothing here); above MOE_DENSE_MAX_ROWS the
+    kernel over the pairs sorted by held expert (a share TAKES it: pairs
+    held elsewhere sort behind the last group and are never visited), and
+    the shared experts' mean as XLA's product either way."""
     from dynamo_tpu.engine import model
     from dynamo_tpu.engine.config import Cohere2MoeSpec
     h, i, routed, k, shared, first = {
@@ -325,10 +376,12 @@ def test_a_shares_expert_layer_compiles_for_v5e(v5e, rows, widths):
         x, lp, spec, backends=WHOLE)).lower(x(rows), lp).compile()
     flops = compiled.cost_analysis()["flops"]
     grouped = rows > model.MOE_DENSE_MAX_ROWS
-    assert compiled.as_text().count("tpu_custom_call") == 2 * grouped
-    # The shared experts over every row; the 16 held over every row too
-    # under the mask, in the kernel (uncounted) above the threshold.
-    every = 2 * 3 * h * i * rows * (shared + (0 if grouped else 16))
+    assert model.expert_product(rows, WHOLE) == (
+        "grouped" if grouped else "touched")
+    assert compiled.as_text().count("tpu_custom_call") == 1 + grouped
+    # The shared experts over every row; the held ones in a kernel
+    # (uncounted) on either side.
+    every = 2 * 3 * h * i * rows * shared
     assert 0.9 * every < flops < 1.2 * every, (flops, every)
     assert compiled.memory_analysis().temp_size_in_bytes < 5 << 28
 
@@ -784,7 +837,7 @@ def test_hybrid_window_program_keeps_the_state_where_it_lies_for_v5e(v5e):
     key = jax.eval_shape(lambda: jax.random.key(0))
     fn = runner._get_window(window, table)
     assert fn._labels["prefix_reuse"].startswith("off")
-    assert fn._labels["expert_product"] == "masked"
+    assert fn._labels["expert_product"] == "touched"
     assert fn._labels["ssm_backend"] == "kernel"
     lowered = fn.lower(
         params, s(pool, jnp.bfloat16), s(pool, jnp.bfloat16),
@@ -816,7 +869,7 @@ def test_hybrid_prefill_program_reads_the_expert_stacks_where_they_lie(
     the shape of a stack or of a layer's slice of one but the arguments and
     their bitcasts (sliced a pair ahead of a custom call a layer's experts
     are copied; handed as [.., K, N] the whole ``up`` stack is: it lies
-    with K minor); the program at 128 rows takes the masked product. At 16
+    with K minor); the program at 128 rows walks the touched experts. At 16
     experts held the stack is past the chip's 128 MiB of VMEM, as the
     cell's is, and the rule holds to the letter. At 4 (60 MB a stack) the
     compiler MAY place the ``up`` stack in VMEM ahead of the scan: since
@@ -831,7 +884,7 @@ def test_hybrid_prefill_program_reads_the_expert_stacks_where_they_lie(
     s_shape, _ = spec.ssm_state_shapes
     key = jax.eval_shape(lambda: jax.random.key(0))
     assert runner._get_prefill(128, 1, False)._labels[
-        "expert_product"] == "masked"
+        "expert_product"] == "touched"
     fn = runner._get_prefill(bucket, batch, False)
     assert fn._labels["expert_product"] == "grouped"
     lowered = fn.lower(
@@ -854,6 +907,156 @@ def test_hybrid_prefill_program_reads_the_expert_stacks_where_they_lie(
             found = pool_sized_ops(text, (*lead, *stack))
             assert {kind for _, kind in found} <= vmem, (lead, stack, found)
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 4 * 2688 * 1856
+
+
+# -- a routed block's window program: the walk over the stacks where they lie ----
+
+def _smallthinker_runner(v5e, rows=32, pages=1500):
+    """A ModelRunner that places nothing, for SmallThinker-21B-A3B's block
+    three layers deep at the published widths (64 int8 experts of 2,560 x
+    768, 6 a row, routed by the layer's input; 28 query heads over 4 KV
+    heads of 128, a page of 64), a narrow vocabulary. Returns (runner, spec,
+    params as shapes, s)."""
+    from dynamo_tpu.engine.config import EngineConfig, SmallThinkerSpec
+    from dynamo_tpu.engine.model import param_shapes
+    from dynamo_tpu.engine.quant import QUANT_LAYER_KEYS, QTensor
+    from dynamo_tpu.engine.runner import ModelRunner
+    spec = SmallThinkerSpec(
+        name="routed", vocab_size=1024, hidden_size=2560,
+        intermediate_size=768, num_layers=3, num_heads=28, num_kv_heads=4,
+        head_dim=128, num_experts=64, num_experts_per_tok=6,
+        moe_intermediate_size=768, quant="int8")
+    runner = object.__new__(ModelRunner)
+    runner.spec = spec
+    runner.config = EngineConfig(model=spec, page_size=64, num_pages=pages,
+                                 max_num_seqs=rows)
+    runner.quant_kv, runner.lora = None, None
+    runner._window_cache, runner._prefill_cache = {}, {}
+    runner.backends = choose(runner.config, spec, "tpu", 1, None)
+    assert runner.backends.experts_whole
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def q(shape):
+        return QTensor(s(shape, jnp.int8),
+                       s((*shape[:-2], 1, shape[-1]), jnp.float32))
+
+    shapes = param_shapes(spec)
+    params = {"layers": {k: q(v) if k in QUANT_LAYER_KEYS
+                         else s(v, jnp.bfloat16)
+                         for k, v in shapes["layers"].items()},
+              "embed": QTensor(s(shapes["embed"], jnp.int8),
+                               s((1, shapes["embed"][1]), jnp.float32)),
+              "final_norm": s(shapes["final_norm"], jnp.bfloat16)}
+    if "lm_head" in shapes:
+        params["lm_head"] = q(shapes["lm_head"])
+    return runner, spec, params, s
+
+
+def _routed_window(v5e, kind):
+    """(the lowered window program of a routed block of ``kind`` on one
+    described chip, its expert stacks' (layers, held, [matrices])).
+    "dense block": SmallThinker's; "hybrid": the recurrent block's, 16
+    experts held (a stack past the chip's VMEM, as the cell's is);
+    "drafting": the latent block's with its prediction module, whose verify
+    step multiplies 64 rows and whose module has an expert layer of its
+    own."""
+    from dynamo_tpu.engine.model import param_shapes
+    from dynamo_tpu.engine.runner import PK_PREFIX
+    rows, window = 32, 8
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    if kind == "drafting":
+        runner, spec, page, pages = _glm_runner(v5e, "mtp")
+
+        def s(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+        params = jax.tree.map(lambda shape: s(shape, jnp.bfloat16),
+                              param_shapes(spec),
+                              is_leaf=lambda x: isinstance(x, tuple))
+        table = runner.config.max_pages_per_seq // 2
+        pool = (4, 1, pages, page, 640)
+        fn = runner._get_window(window, table)
+        lowered = fn.lower(
+            params, s(pool, jnp.bfloat16), s((*pool[:-1], 0), jnp.bfloat16),
+            *[s((rows,), jnp.int32)] * 3,
+            s((pages, spec.hidden_size), jnp.bfloat16),
+            s((rows, PK_PREFIX + table), jnp.int32), s(key.shape, key.dtype))
+        return fn, lowered, (2, 4, [(2048, 256), (256, 2048)])
+    if kind == "hybrid":
+        pages = 3000
+        runner, spec, params, s = _hybrid_runner(v5e, rows, pages, experts=16)
+        table = runner.config.max_pages_per_seq // 2
+        pool = (1, 2, pages, runner.config.page_size, 128)
+        fn = runner._get_window(window, table)
+        lowered = fn.lower(
+            params, s(pool, jnp.bfloat16), s(pool, jnp.bfloat16),
+            s((rows,), jnp.int32), s((rows, PK_PREFIX + table), jnp.int32),
+            s(key.shape, key.dtype),
+            state=(s((3, rows, *spec.ssm_state_shapes[0]), jnp.float32),
+                   s(spec.conv_state_shape(rows), jnp.bfloat16)))
+        return fn, lowered, (3, 16, [(2688, 1856), (1856, 2688)])
+    runner, spec, params, s = _smallthinker_runner(v5e, rows)
+    table = runner.config.max_pages_per_seq // 4
+    pool = (3, 4, runner.config.num_pages, 64, 128)
+    fn = runner._get_window(window, table)
+    lowered = fn.lower(
+        params, s(pool, jnp.bfloat16), s(pool, jnp.bfloat16),
+        s((rows,), jnp.int32), s((rows, PK_PREFIX + table), jnp.int32),
+        s(key.shape, key.dtype))
+    return fn, lowered, (3, 64, [(2560, 768), (768, 2560)])
+
+
+def stack_shaped_ops(text: str, stacks: tuple) -> list:
+    """Every instruction of an optimised program whose result has the shape
+    of an expert stack, of a layer's slice of one or of such a slice as a
+    stack of one (``pool_sized_ops``: arguments and bitcasts are none)."""
+    layers, held, matrices = stacks
+    return [(lead, matrix, found) for matrix in matrices
+            for lead in ((layers, held), (1, held), (held,))
+            if (found := pool_sized_ops(text, (*lead, *matrix)))]
+
+
+@pytest.mark.parametrize("kind", ["dense block", "hybrid", "drafting"])
+def test_a_routed_window_program_walks_the_stacks_where_they_lie(v5e, kind):
+    """The window program of each routed block kind on one chip is labelled
+    ``touched`` and holds ONE custom call an expert layer (traced once in
+    the layer scan's body; the drafting window's second is its module's own
+    layer, 32 rows where the verify step has 64), which reads the expert
+    stacks over ALL layers with the layer's index: nothing in the optimised
+    program has the shape of a stack, of a layer's slice or of a slice as a
+    stack of one but the arguments and their bitcasts, and no temporary is
+    the size of a layer's matrix."""
+    import re
+    fn, lowered, stacks = _routed_window(v5e, kind)
+    assert fn._labels["expert_product"] == "touched"
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and re.search(
+                 r'op_name="[^"]*moe\.experts/[^"]*pallas_call', line)]
+    assert len(calls) == (2 if kind == "drafting" else 1), calls
+    assert all("while/body" in line for line in calls)
+    assert stack_shaped_ops(text, stacks) == []
+    _, held, (matrix, _) = stacks
+    assert compiled.memory_analysis().temp_size_in_bytes < max(
+        held * matrix[0] * matrix[1], 64 << 20)
+
+
+def test_the_stack_guard_sees_a_layer_s_slice_ahead_of_the_walk(
+        v5e, monkeypatch):
+    """The same check on the same program whose layer scan slices the
+    expert leaves a layer FAILS: a layer's three matrices are copied ahead
+    of the custom call (126 MB each)."""
+    from dynamo_tpu.engine import model
+    real = model.scan_layers
+    monkeypatch.setattr(model, "scan_layers", lambda *a, whole_experts=False,
+                        **kw: real(*a, **kw))
+    _, lowered, stacks = _routed_window(v5e, "dense block")
+    compiled = lowered.compile()
+    assert stack_shaped_ops(compiled.as_text(), stacks)
+    assert compiled.memory_analysis().temp_size_in_bytes >= 2 * 64 * 2560 * 768
 
 
 def _sala_runner(v5e, rows=24, pages=3000):
