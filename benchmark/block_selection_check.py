@@ -11,7 +11,9 @@ run the driver makes:
     python3 benchmark/block_selection_check.py --workload <cell> --seed <n> \\
         [--prompt-tokens 6000] [--decode 16] [--rehearse-cpu]
 
-Same server, seams and weights as benchmark/run.py. The served sets come
+Same server, seams and weight law as benchmark/run.py, the weights drawn
+from this tool's own ``--seed`` (run.py serves a cell's ONE draw,
+lib/weights.py). The served sets come
 from ``engine.hybrid.choose_blocks`` itself: the name is bound, before any
 program is traced, to a wrapper that hands what it returns to the host
 (``jax.debug.callback``, ordered: a query's layers arrive in the model's

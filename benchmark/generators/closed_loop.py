@@ -8,8 +8,9 @@ Parameters (traffic file, overridden by the cell's file):
   prompt_tokens, output_tokens   as in open_loop
 
 Sizes are the quantiles of their laws, dealt to the clients in ONE order
-(``ORDER``): the cell replays one trace, and the run's seed draws only the
-weights and the prompts' words. Which sequences make up the first wave of
+(``ORDER``): a cell replays one trace, words and all, over one draw of the
+weights (``lib/weights.py CELL_WEIGHTS_SEED``, PR 58), and the run's seed
+draws the check's prompts. Which sequences make up the first wave of
 streams decides how the run unfolds (when rows end together, how long the
 widest row is past a page bucket): with the clients turned round by the
 seed, each turn gave its own throughput, 5 % apart and the same again in a

@@ -9,8 +9,9 @@ Parameters (traffic file, overridden by the cell's file):
 
 Inter-arrival gaps are the quantiles of an exponential law (a Poisson
 process's gaps) and lengths the quantiles of their laws, tied into requests
-in ONE order (``ORDER``): a cell replays one trace, and the run's seed draws
-only the weights and the prompts' words. With the schedule turned
+in ONE order (``ORDER``): a cell replays one trace, words and all, over one
+draw of the weights (``lib/weights.py CELL_WEIGHTS_SEED``, PR 58), and the
+run's seed draws the check's prompts. With the schedule turned
 round by the seed, the same seed read the same TTFT twice to 0.3 % and six
 seeds spread by 3.7 % (PERF.md, Findings): where the window's edges fell in
 the cycle was changing the work.

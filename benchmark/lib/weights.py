@@ -18,6 +18,23 @@ from __future__ import annotations
 
 import numpy as np
 
+#: The ONE draw of the weights every cell of BENCHMARK.json is served with
+#: (run.py hands it to server.Seams) and of the timed prompts' words
+#: (run.py ``timed_words``); ``--seed`` draws the check's prompts.
+#: Since PR 56 a decode step reads only the experts its live rows chose, so
+#: its time follows the routing, and the routing is the draw's: constant
+#: inside a run and another between two draws, which no longer window
+#: averages out (five routed cells spread 1.3 to 5.4 % over six seeds, ledger,
+#: PR 57). With the weights held and the words still the seed's, two cells
+#: spread 0.59 % over three seeds where one seed twice read 0.01 % (my chip
+#: run, PR 58, call 1): the words choose among the draw's experts, so they
+#: are the replay's too. The sibling of generators/closed_loop.py ``ORDER``:
+#: a cell replays one trace, words and all, over one draw of the weights.
+#: The builders' tools that read a tolerance over many seeds (long_prompt.py,
+#: selection_check.py, block_selection_check.py, draft_check.py) give
+#: ``Seams`` their own.
+CELL_WEIGHTS_SEED = 58
+
 
 def runner_mesh(config, devices=None):
     """The mesh ModelRunner builds for this EngineConfig."""

@@ -10,7 +10,9 @@ the control. A builder's chip run, not a run the driver makes:
     python3 benchmark/long_prompt.py --workload <cell> --seed <n> \\
         [--prompt-tokens 5000] [--decode 16] [--control use_window=false]
 
-Same server, seams and weights as benchmark/run.py; the warm-up covers one
+Same server, seams and weight law as benchmark/run.py, the weights drawn
+from this tool's own ``--seed`` (a dozen seeds are a dozen draws; run.py
+serves a cell's ONE, lib/weights.py); the warm-up covers one
 row of every prefill bucket and page-table width up to the prompt. Prints
 one JSON line a step and the verdict last; exits 1 where the served
 logprobs fall outside the tolerance or a control inside it.

@@ -5,10 +5,12 @@
 
 One cell of BENCHMARK.json, one run: the cell's configuration served through
 ``dynamo_tpu.launch`` over HTTP inside this process (the chip belongs to one
-process), with weights made on the device from ``--seed``; the cell's traffic
-offered by a client process of its own; the last line of standard output is
-the result. ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1``
-its per-layer metrics, with a profiler trace of a few steady seconds.
+process), with weights made on the device from ONE draw
+(``lib/weights.py CELL_WEIGHTS_SEED``); the cell's traffic, one replayed trace
+with its words, offered by a client process of its own (``--seed`` draws the
+check's prompts); the last line of standard output is the result.
+``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` its
+per-layer metrics, with a profiler trace of a few steady seconds.
 
 This file knows no cell, configuration, traffic mix or metric by name: it
 finds ``benchmark/configs/<config>.json``, ``benchmark/traffic/<mix>.json``,
@@ -39,7 +41,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark.lib import manifest, measure  # noqa: E402
+from benchmark.lib import manifest, measure, weights  # noqa: E402
 from benchmark.lib import tokenizer as bench_tok  # noqa: E402
 from benchmark.lib.lengths import prompt_ids  # noqa: E402
 
@@ -291,6 +293,15 @@ def probe_faults(runner, judged: dict, checked: dict) -> None:
                                                       faulty))
 
 
+def timed_words(req: dict, overhead: int, vocab: int) -> list[int]:
+    """The word ids of a timed request, exactly the length drawn (template
+    included): the replayed trace's, drawn from the cell's constant whatever
+    ``--seed`` is (lib/weights.py says why)."""
+    return req.get("prompt_ids") or prompt_ids(
+        weights.CELL_WEIGHTS_SEED, req["id"],
+        max(1, req["prompt_len"] - overhead), vocab, bench_tok.RESERVED)
+
+
 def check_requests(reading: measure.Reading) -> dict:
     """Check (b): every completed request carried the tokens asked for, the
     prompt had the length drawn, and the words counted on the stream are
@@ -347,12 +358,14 @@ async def run_cell(args, files: dict, man: dict, jax) -> dict:
                    else min(server.PREFILL_GROUP_ROWS,
                             len(plan["sequences"]))))
 
-    seams = server.Seams(name, spec, args.seed, shapes)
+    # One draw of the weights a cell, whatever --seed is (lib/weights.py).
+    seams = server.Seams(name, spec, weights.CELL_WEIGHTS_SEED, shapes)
     seams.install()
     try:
         argv = launch_argv(name, config, tok_path)
         emit("launch", argv=argv, cell=cell["name"], seed=args.seed,
-             seconds=seconds, trace=args.trace, generator=files["generator"],
+             weights_seed=seams.seed, seconds=seconds, trace=args.trace,
+             generator=files["generator"],
              requests_planned=len(all_reqs), shapes=vars(shapes),
              reference=judged["module"], roofline=counted_by,
              compile_cache=perf.compile_cache_status())
@@ -386,14 +399,11 @@ async def _serve_and_measure(args, files, man, jax, srv, seams, plan,
          memory=runner.memory_breakdown(),
          compile_cache=perf.compile_cache_status())
 
-    # Prompts: exactly the length drawn, template included.
     requests = (plan["requests"] if open_loop
                 else [r for seq in plan["sequences"] for r in seq])
     prompt_keys = {}
     for req in requests:
-        ids = req.get("prompt_ids") or prompt_ids(
-            args.seed, req["id"], max(1, req["prompt_len"] - overhead),
-            spec.vocab_size, bench_tok.RESERVED)
+        ids = timed_words(req, overhead, spec.vocab_size)
         req["content"] = bench_tok.text_of(ids)
         prompt_keys[req["id"]] = tuple(ids[:6])
 
@@ -544,6 +554,23 @@ async def _serve_and_measure(args, files, man, jax, srv, seams, plan,
          kv_cache_as_configured=kv_as_configured, platform=platform)
     if args.probe_faults:
         probe_faults(runner, judged, checked)
+    # Every number ``correct`` compared, beside its limit (main() prints
+    # them last on standard error; they are the result line's last key).
+    compared = {f"logprob_{k}_nats": {"value": checked[f"{k}_nats"],
+                                      "limit": checked["allowed_nats"][k]}
+                for k in checked["allowed_nats"]}
+    inside = all(pair["value"] <= pair["limit"] for pair in compared.values())
+    for name, value in (
+            # a count or a sign of the served logprobs that is off
+            ("logprobs_malformed", int(inside and not checked["ok"])),
+            ("requests_off_what_was_asked", by_request["n_bad"]),
+            ("compiles_in_window",
+             after["compiles_total"] - before["compiles_total"]),
+            ("unexpected_recompiles", after["unexpected_recompiles"]),
+            ("arrays_off_device", int(not on_device)),
+            ("kv_cache_not_as_configured", int(not kv_as_configured)),
+            ("no_request_measured", int(not measured))):
+        compared[name] = {"value": value, "limit": 0}
 
     entries = manifest.metrics_of(
         man, "per_layer" if args.trace else "end_to_end", cell["name"])
@@ -578,6 +605,7 @@ async def _serve_and_measure(args, files, man, jax, srv, seams, plan,
         shown = measure.breakdown(reading)
         if shown:
             out["breakdown"] = shown
+    out["compared"] = compared
     return out
 
 
@@ -620,11 +648,17 @@ def main(argv=None) -> int:
                        f"JAX reports {len(devices)}")
     _count_backend_compiles()
     out = asyncio.run(run_cell(args, files, man, jax))
+    compared = out.pop("compared")
     if args.rehearse_cpu:
         # A CPU number is never written under a device metric's name.
         emit("rehearsal.cpu_numbers_not_device_metrics", **out["metrics"])
         out["metrics"] = {}
         out["rehearsal"] = True
+    for name, pair in compared.items():
+        print(f"compared {name}: {pair['value']} (limit {pair['limit']})",
+              file=sys.stderr)
+    sys.stderr.flush()
+    out["compared"] = compared  # the result line's last key
     print(json.dumps(out), flush=True)
     return 0
 

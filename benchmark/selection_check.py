@@ -9,7 +9,9 @@ what they cost. A builder's chip run, not a run the driver makes:
         [--seeds 2] [--prompt-tokens 5000] [--decode 16] \\
         [--control select=false] [--rehearse-cpu]
 
-Same server, seams and weights as benchmark/run.py. The served sets come
+Same server, seams and weight law as benchmark/run.py, the weights drawn
+from this tool's own ``--seed`` (run.py serves a cell's ONE draw,
+lib/weights.py). The served sets come
 from ``engine.model.select_topk`` itself: the name is bound, before any
 program is traced, to a wrapper that hands the mask it returns to the host
 (``jax.debug.callback``, ordered: a query's layers arrive in the model's

@@ -53,7 +53,7 @@ def test_closed_loop_plan():
     gen = manifest.load_module("generators", "closed_loop")
     a, b = gen.plan(REASONING, 11, 45.0), gen.plan(REASONING, 11, 45.0)
     assert a == b
-    # Another seed: the same schedule (the words differ, drawn in run.py).
+    # Another seed: the same schedule (and the same words: run.timed_words).
     assert gen.plan(REASONING, 12, 45.0) == a
     assert len(a["sequences"]) == 32
     assert a["lead_seconds"] == REASONING["ramp_seconds"]
@@ -79,6 +79,30 @@ def test_quantiles_and_prompt_ids():
     assert ids == prompt_ids(2**31 + 5, 3, 50, 152064)
     assert len(ids) == 50 and min(ids) >= 16 and max(ids) < 152064
     assert ids != prompt_ids(2**31 + 5, 4, 50, 152064)
+    # --seed draws the check's prompts (run.py numbers them from 900,000):
+    # another seed, other words.
+    assert (prompt_ids(2**31 + 5, 900_000, 50, 152064)
+            != prompt_ids(2**31 + 6, 900_000, 50, 152064))
+
+
+def test_the_timed_words_are_the_replays_whatever_the_seed():
+    import inspect
+
+    from benchmark import run
+    from benchmark.lib import weights
+    gen = manifest.load_module("generators", "closed_loop")
+    req = gen.plan(REASONING, 2**31 + 5, 45.0)["sequences"][3][1]
+    words = run.timed_words(req, 5, 152064)
+    assert len(words) == req["prompt_len"] - 5
+    assert words == prompt_ids(weights.CELL_WEIGHTS_SEED, req["id"],
+                               len(words), 152064)
+    assert run.timed_words({**req, "prompt_ids": [17, 18]}, 5, 152064) == [
+        17, 18]  # a generator's own words (a shared prefix) stand
+    # No seed reaches them: the one other caller of prompt_ids is the check.
+    assert "seed" not in inspect.signature(run.timed_words).parameters
+    source = inspect.getsource(run)
+    assert source.count("prompt_ids(") == 2
+    assert "prompt_ids(seed, 900_000 + k" in source
 
 
 def test_late_starts_are_timed_from_the_due_time():

@@ -153,3 +153,91 @@ def test_a_scope_outside_precedence_passes_by_its_own_name():
     assert scopes.primary("moe.experts") == "moe.experts"
     assert scopes.primary("moe.router+moe.experts") == "moe.router"
     assert scopes.primary("moe.experts+mlp") == "mlp"
+
+
+# -- PR 58: a cell replays one trace over ONE draw of the weights --------------
+
+class _Launched(Exception):
+    """The fake server's: everything up to the launch has run."""
+
+
+@pytest.fixture
+def seams_given(monkeypatch):
+    """The seeds ``server.Seams`` is built with; no server is started."""
+    given = []
+
+    class Seams:
+        def __init__(self, name, spec, seed, shapes):
+            self.seed, self.timings = seed, {}
+            given.append(seed)
+
+        def install(self):
+            pass
+
+        restore = install
+
+    def no_server(argv):
+        raise _Launched
+
+    monkeypatch.setattr(server, "Seams", Seams)
+    monkeypatch.setattr(server, "Server", no_server)
+    return given
+
+
+@pytest.mark.parametrize("seed", [7, 3_000_000_019])
+def test_run_serves_the_cells_one_draw_whatever_the_seed(
+        seed, seams_given, capsys):
+    import argparse
+    import asyncio
+    import json
+
+    from benchmark.lib import weights
+    args = argparse.Namespace(seed=seed, seconds=8, trace=0)
+    with pytest.raises(_Launched):
+        asyncio.run(run.run_cell(args, run.rehearsal_cut(DENSE), MAN, None))
+    assert seams_given == [weights.CELL_WEIGHTS_SEED] == [58]
+    launch = [json.loads(line) for line in capsys.readouterr().out.split("\n")
+              if '"launch"' in line]
+    assert len(launch) == 1
+    assert (launch[0]["seed"], launch[0]["weights_seed"]) == (seed, 58)
+    # One value in use, one constant: no flag, key or variable selects it.
+    source = inspect.getsource(run)
+    # the docstring, run_cell's weights, timed_words' words
+    assert source.count("CELL_WEIGHTS_SEED") == 3
+    assert "weights" not in " ".join(vars(run.parse_args(
+        ["--workload", "x"])))
+
+
+def test_a_builders_tool_draws_the_weights_from_its_own_seed(seams_given):
+    import argparse
+    import asyncio
+
+    from benchmark import long_prompt
+    args = argparse.Namespace(seed=4_100_000_007, prompt_tokens=40, decode=4,
+                              control=[])
+    with pytest.raises(_Launched):
+        asyncio.run(long_prompt.check(args, run.rehearsal_cut(DENSE)))
+    assert seams_given == [4_100_000_007]
+
+
+def test_the_cells_draw_is_one_set_of_weights():
+    import jax
+    import numpy as np
+    from dynamo_tpu.engine.config import EngineConfig
+
+    from benchmark.lib import weights
+    spec = spec_of(run.rehearsal_cut(ROUTED))  # a router among its leaves
+    mesh = weights.runner_mesh(EngineConfig(model=spec))
+    here, again, other = (
+        jax.tree_util.tree_leaves_with_path(weights.make_params(
+            spec, mesh, seed))
+        for seed in (weights.CELL_WEIGHTS_SEED, weights.CELL_WEIGHTS_SEED, 59))
+    differ = set()
+    for (path, leaf), (_, same), (_, drawn_anew) in zip(here, again, other):
+        assert np.array_equal(leaf, same)
+        if not np.array_equal(leaf, drawn_anew):
+            differ.add(jax.tree_util.keystr(path))
+    # The router is what the rule is about; norm scales are one under any seed.
+    assert "['layers']['moe_gate']" in differ
+    assert {"['embed'].q", "['lm_head'].q", "['layers']['wq'].q",
+            "['layers']['moe_w_up'].q"} <= differ

@@ -91,15 +91,20 @@ def test_the_manifest_finds_reference_roofline_and_rehearsal_model():
         assert CELL in entry["workloads"], name
     assert "workloads" not in manifest.find_named(
         MAN["per_layer"], "decode_window_roofline", "metric")
-    with open(os.path.join(manifest.BENCH, "traffic", "reasoning.json"),
-              "rb") as fh:
-        import hashlib
-        assert hashlib.sha256(fh.read()).hexdigest() == TRAFFIC_SHA256
+    import hashlib
+    import json
+    mix = manifest.load_json(os.path.join(manifest.BENCH, "traffic",
+                                          "reasoning.json"))
+    assert mix["generator"] == "closed_loop"
+    assert hashlib.sha256(json.dumps(mix["params"], sort_keys=True).encode()
+                          ).hexdigest() == TRAFFIC_PARAMS_SHA256
 
 
-#: benchmark/traffic/reasoning.json as PR 27 left it: the two cells share it.
-TRAFFIC_SHA256 = (
-    "7a06119675ccc61e316d706992b4c039db9499b2c1b4924be845c3315ab107cf")
+#: The parameters of benchmark/traffic/reasoning.json as PR 27 left them: the
+#: cells share them (PR 58 corrected the file's ``about``: --seed no longer
+#: draws the weights; until then the whole file's bytes were pinned).
+TRAFFIC_PARAMS_SHA256 = (
+    "c5a231910a762f1dcf4860270e21321646b7aa247645010e3cc6b3356e3054c5")
 
 
 # -- the roofline's arithmetic: 24 layers, 18 rows, int8, by hand ---------------------
